@@ -44,7 +44,9 @@ def main():
     from ray_tpu import data as rt_data
     from ray_tpu import serve
     from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
+    from ray_tpu.util.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     # logical CPUs oversubscribed: the gang worker holds one while the
     # Dataset's read/map tasks need their own — on a small host a 1-CPU
     # default would starve the data plane behind the trainer

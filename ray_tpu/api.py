@@ -178,10 +178,11 @@ def _detect_local_tpu_chips() -> float:
     TPUAcceleratorManager.get_current_node_num_accelerators`)."""
     try:
         import jax
-
-        return float(len([d for d in jax.devices() if d.platform not in ("cpu",)]))
-    except Exception:
+    except ImportError:
         return 0.0
+    # a backend that fails to start (e.g. a chip another process holds)
+    # propagates: reading it as "no TPU" would run the job on the host
+    return float(len([d for d in jax.devices() if d.platform != "cpu"]))
 
 
 def shutdown() -> None:

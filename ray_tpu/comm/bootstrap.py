@@ -100,10 +100,7 @@ def init_distributed(
     # opt in before the backend is created. Real TPU paths are untouched.
     platforms = os.environ.get("JAX_PLATFORMS", "")
     if "cpu" in platforms.split(","):
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass  # jax version without the flag: keep the old behavior
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     if coordinator_address is None:
         if process_id == 0:

@@ -51,6 +51,9 @@ def _child_main(req_q, resp_q, log_dir: str = "") -> None:
     from ._pdeathsig import set_pdeathsig
 
     set_pdeathsig()  # die with the runtime, never orphan (chaos tests)
+    # the runtime process owns the chip; a child that touches jax gets
+    # the CPU backend, never a second open of the parent's device
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["RAY_TPU_IN_POOL_WORKER"] = "1"  # api.py guards private inits
     if log_dir:
         try:

@@ -655,9 +655,12 @@ class NodeAgent:
     def _should_isolate(self, spec: TaskSpec) -> bool:
         """Actor-isolation policy (reference: every actor IS a worker
         process). CPU actors with serial mailboxes isolate; device actors
-        are exempt by contract (a child importing jax races the parent for
-        the TPU client), and high-concurrency actors (serve replicas, trial
-        runners — streaming returns, shared batchers) stay in-process."""
+        stay in the runtime process, which is the one process that owns
+        the chip: every forkserver child (actor_process._child_main,
+        process_pool._worker_main) sets JAX_PLATFORMS=cpu before any user
+        code, so jax in a child is CPU-only. High-concurrency actors
+        (serve replicas, trial runners — streaming returns, shared
+        batchers) stay in-process too."""
         if _is_async_actor(spec.func):
             # the event loop and its coroutines cannot cross an
             # ActorProcess boundary; async actors are in-process by mode

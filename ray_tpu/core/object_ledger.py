@@ -49,7 +49,7 @@ from .metrics import Counter, Gauge
 
 logger = get_logger("object_ledger")
 
-# -- pin-reason taxonomy ----------------------------------------------------
+# -- pin reasons ------------------------------------------------------------
 # Why is this object held alive? (README "Object plane introspection")
 PIN_USER_PUT = "user_put"            # driver ray_tpu.put(); freed by ref GC
 PIN_CACHE = "cache"                  # pull-through replica on a puller node

@@ -98,6 +98,9 @@ def _oid(tag: bytes) -> bytes:
 # ---------------------------------------------------------------------------
 
 
+_OOB_MIN_BYTES = 1 << 16  # below this a buffer travels in the pickle itself
+
+
 def _dump(store, obj: Any, *, use_cloudpickle: bool) -> Tuple[bytes, List[bytes], Optional[bytes]]:
     """-> (payload_or_empty, buffer_ids, inline_payload).
 
@@ -106,6 +109,17 @@ def _dump(store, obj: Any, *, use_cloudpickle: bool) -> Tuple[bytes, List[bytes]
     back to fully-inline pickling (buffers in-band through the pipe)."""
     buffers: List[pickle.PickleBuffer] = []
     dumps = _cloudpickle_dumps if use_cloudpickle else pickle.dumps
+
+    def out_of_band(buf: pickle.PickleBuffer) -> bool:
+        # pickle's contract: a true return keeps the buffer in-band. Small
+        # buffers stay in the pipe: a block of row dicts holds thousands
+        # of tiny arrays, and one shm object each fills the store's object
+        # table, whose LRU then evicts sealed buffers no worker has read
+        # yet ("shm buffer ... missing").
+        if buf.raw().nbytes < _OOB_MIN_BYTES:
+            return True
+        buffers.append(buf)
+        return False
 
     def inline(o):
         # pickling-phase failures (any exception type — reducers can raise
@@ -119,7 +133,7 @@ def _dump(store, obj: Any, *, use_cloudpickle: bool) -> Tuple[bytes, List[bytes]
             raise TaskNotSerializableError(repr(e)) from e
 
     try:
-        payload = dumps(obj, protocol=5, buffer_callback=buffers.append)
+        payload = dumps(obj, protocol=5, buffer_callback=out_of_band)
     except TaskNotSerializableError:
         raise  # inline retry would serialize everything again just to re-raise
     except Exception:
@@ -257,6 +271,9 @@ def _worker_main(store_name: str, req_q, resp_q, log_dir: str = "") -> None:
     from .shm_store import ShmObjectStore
 
     set_pdeathsig()  # die with the forkserver/runtime, never orphan
+    # the runtime process owns the chip; a child that touches jax gets
+    # the CPU backend, never a second open of the parent's device
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
     # Runtime API calls inside a pool worker would _auto_init a PRIVATE
     # runtime whose refs/handles are meaningless to the parent; api.py

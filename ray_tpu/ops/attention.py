@@ -31,11 +31,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .dispatch import interpret_mode, platform_dispatch, tpu_compiler_params, use_pallas
+from .dispatch import interpret_mode, platform_dispatch, use_pallas
 
 _NEG_INF = -2.0e30
 _LANES = 128
 _MAX_BLOCK = 1024  # measured knee on v5e: 1024² blocks ~3.4x faster than 128²
+# The backward kernels hold four [block_q, block_k] f32 tiles (s, p, dp, ds)
+# beside their double-buffered operands: 16.46 MiB at 1024² blocks, which
+# the chip's compiler refuses under its default 16 MiB scoped-VMEM limit
+# (seen at T=8192 compiled for a described v5e). A v5e has 128 MiB of VMEM.
+_BWD_VMEM_LIMIT = 32 * 1024 * 1024
 
 
 def _auto_block(t: int) -> int:
@@ -181,7 +186,7 @@ def _flash_fwd_pallas(q, k, v, *, causal, scale, block_q, block_k, return_lse=Fa
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -327,8 +332,9 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, causal, scale, block_q, block_k,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_BWD_VMEM_LIMIT,
         ),
         cost_estimate=pl.CostEstimate(
             flops=int(6 * B * H * Tq * Tk * D * (0.5 if causal else 1.0)),
@@ -359,8 +365,9 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, causal, scale, block_q, block_k,
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_BWD_VMEM_LIMIT,
         ),
         cost_estimate=pl.CostEstimate(
             flops=int(8 * B * H * Tq * Tk * D * (0.5 if causal else 1.0)),

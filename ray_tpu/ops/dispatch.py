@@ -18,29 +18,6 @@ import jax
 _interp_override = threading.local()
 
 
-def tpu_compiler_params(**kwargs):
-    """pltpu.CompilerParams across jax versions (older releases name it
-    TPUCompilerParams); kernels must build it through here or they break
-    on one side of the rename."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
-
-
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """jax.shard_map across versions (older: jax.experimental.shard_map;
-    check_vma was check_rep). Kernel wraps disable the replication/vma
-    checker either way — pallas_call outputs carry no annotations for it."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def _forced() -> "bool | None":
     """RAY_TPU_FORCE_PALLAS=1 forces Pallas (interpret mode off-TPU — used
     by kernel correctness tests), =0 forces the XLA fallback everywhere."""

@@ -25,8 +25,6 @@ from jax.experimental.pallas import tpu as pltpu
 from .dispatch import (
     interpret_mode,
     platform_dispatch,
-    shard_map_compat,
-    tpu_compiler_params,
     use_pallas,
 )
 
@@ -188,7 +186,7 @@ def _paged_pallas(q, k_pages, v_pages, page_table, lengths, scale):
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, g, D), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret_mode(),
@@ -288,7 +286,7 @@ def _chunk_pallas(q, k_pages, v_pages, page_table, meta, scale):
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((KVH, rows, D), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret_mode(),
@@ -448,7 +446,7 @@ def _verify_pallas(q, k_pages, v_pages, page_table, positions, scale):
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, rows, D), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret_mode(),
@@ -514,15 +512,16 @@ def paged_attention_verify(
     if tp > 1:
         from jax.sharding import PartitionSpec as P
 
-        return shard_map_compat(
+        return jax.shard_map(
             dispatch,
-            mesh,
+            mesh=mesh,
             in_specs=(
                 P(None, None, tp_axis, None),  # q: heads sharded
                 P(tp_axis), P(tp_axis),        # page pools: KVH sharded
                 P(), P(),                      # table/positions replicated
             ),
             out_specs=P(None, None, tp_axis, None),
+            check_vma=False,
         )(q, k_pages, v_pages, page_table, positions)
     return dispatch(q, k_pages, v_pages, page_table, positions)
 
@@ -579,9 +578,9 @@ def paged_attention_decode(
     if tp > 1:
         from jax.sharding import PartitionSpec as P
 
-        return shard_map_compat(
+        return jax.shard_map(
             dispatch,
-            mesh,
+            mesh=mesh,
             in_specs=(
                 P(None, tp_axis, None),        # q: heads sharded
                 P(tp_axis), P(tp_axis),        # page pools: KVH sharded
@@ -590,5 +589,6 @@ def paged_attention_decode(
             # no collectives in the body; pallas_call outputs don't carry
             # vma annotations, so the varying-axes checker can't see through
             out_specs=P(None, tp_axis, None),
+            check_vma=False,
         )(q, k_pages, v_pages, page_table, lengths)
     return dispatch(q, k_pages, v_pages, page_table, lengths)

@@ -135,6 +135,30 @@ def constrain(x: jax.Array, axes: Sequence[Optional[str]], rules: Optional[Rules
     return jax.lax.with_sharding_constraint(x, sharding_for(axes, mesh, rules))
 
 
+def per_shard(fn, in_axes: Sequence[Sequence[Optional[str]]],
+              out_axes: Sequence[Optional[str]], *args,
+              mesh: Optional[Mesh] = None):
+    """fn(*args) with each device running fn on its own shard.
+
+    GSPMD cannot partition a Pallas call ("Mosaic kernels cannot be
+    automatically partitioned"), so on a multi-device mesh a kernel op runs
+    under shard_map, its operands laid out by their logical axes — the
+    layout constrain() already gives them. fn must be independent along
+    every sharded axis (attention over batch and heads, a norm over rows).
+    No mesh, one device, or already inside a shard_map body
+    (no_constrain): plain call."""
+    mesh = mesh if mesh is not None else _current_mesh()
+    if (mesh is None or mesh.size == 1
+            or getattr(_constrain_disabled, "on", False)):
+        return fn(*args)
+    return jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=tuple(sharding_for(a, mesh).spec for a in in_axes),
+        out_specs=sharding_for(out_axes, mesh).spec,
+        check_vma=False,
+    )(*args)
+
+
 def _current_mesh() -> Optional[Mesh]:
     try:
         env = jax._src.mesh.thread_resources.env  # set by `with mesh:`

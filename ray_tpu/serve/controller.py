@@ -172,10 +172,7 @@ class ServeController:
 
     def _drain(self, state: _DeploymentState) -> None:
         for r in state.replicas:
-            try:
-                api.kill(r)
-            except Exception:
-                pass
+            _retire(r)
         if state.replicas:
             state.membership += 1
         state.replicas = []
@@ -338,11 +335,7 @@ class ServeController:
                     pass
             while len(state.replicas) > state.target:
                 changed = True
-                victim = state.replicas.pop()
-                try:
-                    api.kill(victim)
-                except Exception:
-                    pass
+                _retire(state.replicas.pop())
             if changed:
                 with self._lock:
                     state.membership += 1
@@ -399,6 +392,19 @@ class ServeController:
                 state.target -= 1
                 state._last_scale_down = now
                 logger.info("autoscale %s -> %d (avg load %.2f)", state.name, state.target, avg)
+
+
+def _retire(replica) -> None:
+    """Stop a replica that is being drained or scaled away: first let its
+    callable release what it holds (ServeReplica.shutdown), then kill."""
+    try:
+        api.get(replica.shutdown.remote(), timeout=5.0)
+    except Exception:  # noqa: BLE001 — dead or hung: the kill is what is left
+        pass
+    try:
+        api.kill(replica)
+    except Exception:  # noqa: BLE001 — already gone
+        pass
 
 
 def get_or_create_controller():

@@ -110,6 +110,12 @@ class LLMServer:
         # warming every bucket would multiply startup time)
         self.engine.warmup(buckets=[])
 
+    def shutdown(self) -> None:
+        """Replica retirement: stop the engine's threads and let go of it,
+        or they keep its weights and KV pool on the device for good."""
+        engine, self.engine = self.engine, None
+        engine.stop()
+
     def __call__(self, request: Dict[str, Any]) -> Dict[str, Any]:
         return self.engine.generate(
             prompt=list(request["prompt_ids"]),

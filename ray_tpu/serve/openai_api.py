@@ -178,6 +178,12 @@ class OpenAIServer:
             stops.append([int(tid)])
         return stops or None
 
+    def shutdown(self) -> None:
+        """Replica retirement: see LLMServer.shutdown."""
+        engine, self.engine = self.engine, None
+        if engine is not None:  # coordinator mode has no local engine
+            engine.stop()
+
     def _generate(self, ids, max_tokens, temperature, top_p, stop):
         if self._coordinator is not None:
             return self._coordinator.generate(

@@ -101,6 +101,15 @@ class ServeReplica:
             out["multiplexed_models"] = models
         return out
 
+    def shutdown(self) -> None:
+        """Retirement hook (the controller calls it before the kill): the
+        callable's own `shutdown()` releases what outlives a dropped
+        reference — an LLM engine's threads pin its weights on the device."""
+        target, self._callable = self._callable, None
+        hook = None if self._is_function else getattr(target, "shutdown", None)
+        if callable(hook):
+            hook()
+
     def health_check(self) -> bool:
         chk = getattr(self._callable, "check_health", None)
         if chk is not None:

@@ -8,7 +8,6 @@ XLA emitting the ICI collectives.
 
 from __future__ import annotations
 
-import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -93,16 +92,28 @@ def init_train_state(
     mesh: Mesh,
     key: jax.Array,
     optimizer: optax.GradientTransformation,
+    param_dtype=None,
 ) -> Tuple[TrainState, Any]:
     """Sharded-from-birth init: params materialize directly into their
     NamedShardings (jit + out_shardings), never resident on one device.
 
+    param_dtype casts the float parameters inside the init jit (bf16
+    masters with factored optimizer statistics: the one-chip recipe for
+    billion-parameter state), so an f32 tree of the whole model never
+    exists on the device. Default: init_params' own f32.
+
     Returns (state, state_shardings) — pass the latter to jit and to
     checkpoint resharding restore.
     """
+    def init_p(key):
+        params = init_params(cfg, key)
+        if param_dtype is None:
+            return params
+        return jax.tree.map(lambda x: x.astype(param_dtype), params)
+
     axes = param_axes(cfg)
     p_shardings = tree_shardings(axes, mesh)
-    p_shapes = jax.eval_shape(functools.partial(init_params, cfg), key)
+    p_shapes = jax.eval_shape(init_p, key)
     o_shapes = jax.eval_shape(optimizer.init, p_shapes)
     o_shardings = _match_shardings_by_shape(o_shapes, p_shardings, p_shapes, mesh)
     replicated = NamedSharding(mesh, PartitionSpec())
@@ -112,9 +123,8 @@ def init_train_state(
         "opt_state": o_shardings,
     }
 
-    @functools.partial(jax.jit, out_shardings=state_shardings)
     def _init(key):
-        params = init_params(cfg, key)
+        params = init_p(key)
         return {
             "step": jnp.zeros((), jnp.int32),
             "params": params,

@@ -525,6 +525,42 @@ class TestEngine:
         ).result(timeout=300)
         assert len(out["token_ids"]) == 4
 
+    def test_deleted_llm_deployment_releases_its_engine(self, serve_session):
+        """A retired replica must let go of its engine: the engine's
+        threads pin its weights and KV pool (on a chip: gigabytes of HBM)
+        until stop() — found by chip_smoke.py on the v5e."""
+        import gc
+        import weakref
+
+        from ray_tpu.serve.engine import InferenceEngine
+
+        def engines():
+            gc.collect()
+            return [o for o in gc.get_objects()
+                    if isinstance(o, InferenceEngine)]
+
+        known = {id(e) for e in engines()}
+        app = serve.LLMServer.options(name="llm-retire").bind(
+            model_name="tiny-llama",
+            engine_config=dict(
+                max_batch_size=2, page_size=8, max_pages=32, max_seq_len=64,
+                prefill_buckets=(16,),
+            ),
+        )
+        handle = serve.run(app, name="llmretire")
+        handle.remote({"prompt_ids": [1, 2, 3], "max_tokens": 2}).result(
+            timeout=300)
+        mine = [weakref.ref(e) for e in engines() if id(e) not in known]
+        assert len(mine) == 1
+        serve.delete("llmretire")
+        deadline = time.monotonic() + 10.0  # the loop threads poll at 0.5s
+        while time.monotonic() < deadline:
+            gc.collect()
+            if mine[0]() is None:
+                break
+            time.sleep(0.1)
+        assert mine[0]() is None
+
 
 class TestOpenAI:
     """OpenAI-compatible surface (reference: ray.serve.llm build_openai_app)."""
