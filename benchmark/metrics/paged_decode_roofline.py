@@ -1,0 +1,25 @@
+"""The paged decode-attention kernel's share of its roofline: memory-bound
+(it reads every cached key and value once). The cached tokens read are
+counted from the client's records: a token j of a request with prompt p,
+arriving inside the traced part, was one decode step over p + j cached
+tokens, in every layer. Bytes and operations by benchmark/flops.py."""
+
+from benchmark import flops, trace_reduce
+
+
+def read(ctx):
+    run = ctx["run"]
+    seconds, _ = trace_reduce.group_seconds(ctx["trace"], "paged_decode")
+    lo, hi = run.get("traced_from_s"), run.get("traced_to_s")
+    if not seconds or lo is None:
+        return None
+    context = 0
+    for q, r in zip(run["requests"], run["records"]):
+        for j, t in enumerate(r["token_s"]):
+            if j and lo <= t < hi:  # token 0 comes from the prefill program
+                context += q["prompt_len"] + j
+    if not context:
+        return None
+    work = flops.paged_decode(ctx["spec"], context)
+    work = {k: v * ctx["spec"]["num_hidden_layers"] for k, v in work.items()}
+    return 100.0 * flops.roofline_seconds(work, ctx["peaks"])["seconds"] / seconds
