@@ -44,6 +44,29 @@ class _Metric:
         does not orphan module-level metric objects."""
         raise NotImplementedError
 
+    def labels(self, **tags: str) -> "_Bound":
+        """A child bound to one tag set, resolved once: `inc`/`set`/
+        `observe` on it skip the per-call dict sort, for observations
+        inside a loop (the engine's phases)."""
+        return _Bound(self, _tags(tags))
+
+
+class _Bound:
+    __slots__ = ("_metric", "_tags")
+
+    def __init__(self, metric: _Metric, tags: TagMap):
+        self._metric = metric
+        self._tags = tags
+
+    def inc(self, value: float = 1.0) -> None:
+        self._metric._inc(self._tags, value)
+
+    def set(self, value: float) -> None:
+        self._metric._set(self._tags, value)
+
+    def observe(self, value: float) -> None:
+        self._metric._observe(self._tags, value)
+
 
 class Counter(_Metric):
     kind = "counter"
@@ -53,9 +76,11 @@ class Counter(_Metric):
         super().__init__(name, description, registry_)
 
     def inc(self, value: float = 1.0, tags: Optional[Dict[str, str]] = None) -> None:
+        self._inc(_tags(tags), value)
+
+    def _inc(self, key: TagMap, value: float) -> None:
         if value < 0:
             raise ValueError("counters only increase")
-        key = _tags(tags)
         with self._lock:
             self._values[key] = self._values.get(key, 0.0) + value
 
@@ -80,8 +105,11 @@ class Gauge(_Metric):
         super().__init__(name, description, registry_)
 
     def set(self, value: float, tags: Optional[Dict[str, str]] = None) -> None:
+        self._set(_tags(tags), value)
+
+    def _set(self, key: TagMap, value: float) -> None:
         with self._lock:
-            self._values[_tags(tags)] = float(value)
+            self._values[key] = float(value)
 
     def add(self, delta: float, tags: Optional[Dict[str, str]] = None) -> None:
         key = _tags(tags)
@@ -133,7 +161,9 @@ class Histogram(_Metric):
         super().__init__(name, description, registry_)
 
     def observe(self, value: float, tags: Optional[Dict[str, str]] = None) -> None:
-        key = _tags(tags)
+        self._observe(_tags(tags), value)
+
+    def _observe(self, key: TagMap, value: float) -> None:
         with self._lock:
             counts = self._counts.setdefault(key, [0] * len(self.buckets))
             idx = bisect.bisect_left(self.buckets, value)

@@ -67,10 +67,6 @@ _m_rows = Counter(
 _m_tasks = Counter(
     "ingest_preprocess_tasks_total",
     "Preprocess block tasks executed on ingest workers, per tenant.")
-_m_preproc_s = Counter(
-    "ingest_preprocess_seconds_total",
-    "Seconds ingest workers spent reading + transforming blocks, per "
-    "tenant.")
 _m_bytes = Counter(
     "ingest_tenant_bytes_total",
     "Output bytes of completed ingest blocks, per tenant (the fair-share "
@@ -132,7 +128,6 @@ class IngestWorker:
     def run_block(self, reg_id: str, idx: int, tenant: str,
                   block: Optional[Block] = None) -> Block:
         read_tasks, stages = self._pipelines[reg_id]
-        t0 = time.perf_counter()
         if block is None:
             out = read_tasks[idx]()
             if hasattr(out, "__next__"):
@@ -144,7 +139,6 @@ class IngestWorker:
             block = stage(block)
         tags = {"tenant": tenant}
         _m_tasks.inc(1.0, tags=tags)
-        _m_preproc_s.inc(time.perf_counter() - t0, tags=tags)
         try:
             _m_rows.inc(float(BlockAccessor(block).num_rows()), tags=tags)
         except Exception:  # noqa: BLE001 — exotic block types still flow

@@ -16,7 +16,6 @@ from __future__ import annotations
 import collections
 import queue as _queue
 import threading
-import time
 import weakref
 from typing import Any, Callable, Dict, Iterator, Optional
 
@@ -24,6 +23,7 @@ import numpy as np
 
 from .. import api
 from ..core.config import config
+from ..util import tracing
 from .block import BlockAccessor
 from .executor import _m_stall
 
@@ -75,7 +75,7 @@ class PrefetchIterator:
         self._stop = threading.Event()
         self._closed = False
         self._stage = stage
-        self._tenant = tenant
+        self._stall = _m_stall.labels(stage=stage, tenant=tenant)
         self._make_iter = make_iter
         # the thread target closes over the queue + stop event ONLY, never
         # self: a bound-method target would keep the iterator reachable
@@ -94,10 +94,9 @@ class PrefetchIterator:
     def __next__(self) -> Any:
         if self._closed:
             raise StopIteration
-        t0 = time.perf_counter()
-        kind, item = self._q.get()
-        _m_stall.inc(time.perf_counter() - t0,
-                     tags={"stage": self._stage, "tenant": self._tenant})
+        with tracing.region("data.next", stage=self._stage) as wait:
+            kind, item = self._q.get()
+        self._stall.inc(wait.elapsed_s)
         if kind is _DONE:
             self.close()
             raise StopIteration
@@ -368,7 +367,8 @@ class DataIterator:
 
         window: collections.deque = collections.deque()
         for batch in host_batches:
-            window.append(put(batch))  # async dispatch; no host block
+            with tracing.region("data.device_put"):
+                window.append(put(batch))  # async dispatch; no host block
             if len(window) > prefetch:
                 yield window.popleft()
         while window:

@@ -279,15 +279,26 @@ def _moe_ffn(x, lp, cfg):
 
 def _moe_ffn_dense(x, lp, cfg):
     dtype = x.dtype
-    disp, combine, aux = _moe_dispatch(x, lp["router"], cfg)
-    expert_in = jnp.einsum("btd,btec->becd", x, disp.astype(dtype))
-    expert_in = constrain(expert_in, ("batch", "expert", None, "embed"))
-    h = jnp.einsum("becd,edf->becf", expert_in, lp["w_in"].astype(dtype))
-    g = jnp.einsum("becd,edf->becf", expert_in, lp["w_gate"].astype(dtype))
-    h = constrain(jax.nn.silu(g) * h, ("batch", "expert", None, "expert_mlp"))
-    y = jnp.einsum("becf,efd->becd", h, lp["w_out"].astype(dtype))
-    out = jnp.einsum("becd,btec->btd", y, combine.astype(dtype))
-    return constrain(out, ("batch", "seq", "embed")), aux
+    with jax.named_scope("route"):
+        disp, combine, aux = _moe_dispatch(x, lp["router"], cfg)
+    with jax.named_scope("dispatch"):
+        expert_in = jnp.einsum("btd,btec->becd", x, disp.astype(dtype))
+        expert_in = constrain(expert_in, ("batch", "expert", None, "embed"))
+    y = _experts(expert_in, lp, dtype)
+    with jax.named_scope("combine"):
+        out = jnp.einsum("becd,btec->btd", y, combine.astype(dtype))
+        return constrain(out, ("batch", "seq", "embed")), aux
+
+
+def _experts(expert_in, lp, dtype):
+    """SwiGLU over every expert's rows: [B,E,C,D] -> [B,E,C,D]."""
+    with jax.named_scope("experts"):
+        h = jnp.einsum("becd,edf->becf", expert_in, lp["w_in"].astype(dtype))
+        g = jnp.einsum("becd,edf->becf", expert_in,
+                       lp["w_gate"].astype(dtype))
+        h = constrain(jax.nn.silu(g) * h,
+                      ("batch", "expert", None, "expert_mlp"))
+        return jnp.einsum("becf,efd->becd", h, lp["w_out"].astype(dtype))
 
 
 def _moe_ffn_gather(x, lp, cfg):
@@ -304,43 +315,48 @@ def _moe_ffn_gather(x, lp, cfg):
     dtype = x.dtype
     B, T, D = x.shape
     E = cfg.num_experts
-    logits, weights, expert_ids, flat_ids, my_pos, keep, capacity = _moe_route(
-        x, lp["router"], cfg)
-    k = cfg.num_selected_experts
-    safe = jnp.where(keep, my_pos, capacity)  # overflow slot sliced off
-    bi = jnp.arange(B)[:, None]
-    tok = jnp.broadcast_to((jnp.arange(T * k) // k)[None, :], (B, T * k))
-    # slot tables [B,E,C]: source token, validity, combine weight
-    tok_of = jnp.zeros((B, E, capacity + 1), jnp.int32).at[
-        bi, flat_ids, safe].set(tok)[:, :, :capacity]
-    valid = jnp.zeros((B, E, capacity + 1), jnp.float32).at[
-        bi, flat_ids, safe].set(1.0)[:, :, :capacity]
-    w_of = jnp.zeros((B, E, capacity + 1), jnp.float32).at[
-        bi, flat_ids, safe].set(weights.reshape(B, T * k))[:, :, :capacity]
-
-    gath = jax.vmap(lambda xb, ib: xb[ib])(x, tok_of.reshape(B, E * capacity))
-    expert_in = gath.reshape(B, E, capacity, D) * valid[..., None].astype(dtype)
-    expert_in = constrain(expert_in, ("batch", "expert", None, "embed"))
-    h = jnp.einsum("becd,edf->becf", expert_in, lp["w_in"].astype(dtype))
-    g = jnp.einsum("becd,edf->becf", expert_in, lp["w_gate"].astype(dtype))
-    h = constrain(jax.nn.silu(g) * h, ("batch", "expert", None, "expert_mlp"))
-    y = jnp.einsum("becf,efd->becd", h, lp["w_out"].astype(dtype))
-    yw = y * (w_of * valid)[..., None].astype(dtype)
-    out = jax.vmap(lambda ib, yb: jnp.zeros((T, D), dtype).at[ib].add(yb))(
-        tok_of.reshape(B, E * capacity), yw.reshape(B, E * capacity, D))
-    return (constrain(out, ("batch", "seq", "embed")),
-            _moe_aux(logits, expert_ids, E))
+    with jax.named_scope("route"):
+        (logits, weights, expert_ids, flat_ids, my_pos, keep,
+         capacity) = _moe_route(x, lp["router"], cfg)
+        k = cfg.num_selected_experts
+        safe = jnp.where(keep, my_pos, capacity)  # overflow slot sliced off
+        bi = jnp.arange(B)[:, None]
+        tok = jnp.broadcast_to((jnp.arange(T * k) // k)[None, :], (B, T * k))
+        # slot tables [B,E,C]: source token, validity, combine weight
+        tok_of = jnp.zeros((B, E, capacity + 1), jnp.int32).at[
+            bi, flat_ids, safe].set(tok)[:, :, :capacity]
+        valid = jnp.zeros((B, E, capacity + 1), jnp.float32).at[
+            bi, flat_ids, safe].set(1.0)[:, :, :capacity]
+        w_of = jnp.zeros((B, E, capacity + 1), jnp.float32).at[
+            bi, flat_ids, safe].set(weights.reshape(B, T * k))[:, :, :capacity]
+        aux = _moe_aux(logits, expert_ids, E)
+    with jax.named_scope("dispatch"):
+        gath = jax.vmap(lambda xb, ib: xb[ib])(
+            x, tok_of.reshape(B, E * capacity))
+        expert_in = gath.reshape(B, E, capacity, D) \
+            * valid[..., None].astype(dtype)
+        expert_in = constrain(expert_in, ("batch", "expert", None, "embed"))
+    y = _experts(expert_in, lp, dtype)
+    with jax.named_scope("combine"):
+        yw = y * (w_of * valid)[..., None].astype(dtype)
+        out = jax.vmap(lambda ib, yb: jnp.zeros((T, D), dtype).at[ib].add(yb))(
+            tok_of.reshape(B, E * capacity), yw.reshape(B, E * capacity, D))
+        return constrain(out, ("batch", "seq", "embed")), aux
 
 
 def _block(x, lp, cfg, rope_tables, positions, mesh=None):
-    h = _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
-    x = x + _attention(h, lp, cfg, rope_tables, positions, mesh)
-    h = _norm(x, lp["ln2"], lp.get("ln2_b"), cfg)
-    if cfg.is_moe:
-        y, aux = _moe_ffn(h, lp, cfg)
-    else:
-        y, aux = _dense_ffn(h, lp, cfg), jnp.zeros((), jnp.float32)
-    return x + y, aux
+    # scope names are what a profile's readers key on; the engine's
+    # hand-written decode and chunk bodies (serve/engine.py) use the same
+    with jax.named_scope("attn"):
+        h = _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
+        x = x + _attention(h, lp, cfg, rope_tables, positions, mesh)
+    with jax.named_scope("moe" if cfg.is_moe else "ffn"):
+        h = _norm(x, lp["ln2"], lp.get("ln2_b"), cfg)
+        if cfg.is_moe:
+            y, aux = _moe_ffn(h, lp, cfg)
+        else:
+            y, aux = _dense_ffn(h, lp, cfg), jnp.zeros((), jnp.float32)
+        return x + y, aux
 
 
 # ---------------------------------------------------------------------------
@@ -375,24 +391,28 @@ def _prologue(params, tokens, cfg, positions=None, mesh=None):
     """Shared embed + positional prologue -> (x [B,T,D], rope_tables)."""
     dtype = jnp.dtype(cfg.dtype)
     T = tokens.shape[1]
-    x = _embed_lookup(params["embed"], tokens, dtype, mesh=mesh)
-    if cfg.positional == "learned":
-        pos = positions if positions is not None else jnp.arange(T)[None, :]
-        x = x + params["pos_emb"][pos].astype(dtype)
-        rope_tables = None
-    else:
-        rope_tables = rope_frequencies(cfg.hdim, cfg.max_seq_len, cfg.rope_theta)
-    return constrain(x, ("batch", "seq", "embed")), rope_tables
+    with jax.named_scope("embed"):
+        x = _embed_lookup(params["embed"], tokens, dtype, mesh=mesh)
+        if cfg.positional == "learned":
+            pos = positions if positions is not None else jnp.arange(T)[None, :]
+            x = x + params["pos_emb"][pos].astype(dtype)
+            rope_tables = None
+        else:
+            rope_tables = rope_frequencies(
+                cfg.hdim, cfg.max_seq_len, cfg.rope_theta)
+        return constrain(x, ("batch", "seq", "embed")), rope_tables
 
 
 def _lm_head(x, params, cfg) -> jax.Array:
     """Shared final-norm + head epilogue -> logits [B,T,V] f32."""
-    x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("btd,dv->btv", x.astype(jnp.float32), head.astype(jnp.float32))
-    if cfg.logits_softcap:
-        logits = cfg.logits_softcap * jnp.tanh(logits / cfg.logits_softcap)
-    return constrain(logits, ("batch", "seq", "vocab"))
+    with jax.named_scope("lm_head"):
+        x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = jnp.einsum("btd,dv->btv", x.astype(jnp.float32),
+                            head.astype(jnp.float32))
+        if cfg.logits_softcap:
+            logits = cfg.logits_softcap * jnp.tanh(logits / cfg.logits_softcap)
+        return constrain(logits, ("batch", "seq", "vocab"))
 
 
 def run_layers(
@@ -533,14 +553,16 @@ def loss_from_logits(
     activations rather than a full forward)."""
     if mask is None:
         mask = jnp.ones_like(targets, jnp.float32)
-    lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    true_logit = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    nll = (lse - true_logit) * mask
-    denom = jnp.maximum(mask.sum(), 1.0)
-    ce = nll.sum() / denom
-    z_loss = z_loss_coef * jnp.sum(jnp.square(lse) * mask) / denom
-    total = ce + z_loss + cfg.router_aux_coef * aux
-    acc = jnp.sum((jnp.argmax(logits, -1) == targets) * mask) / denom
+    with jax.named_scope("loss"):
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        true_logit = jnp.take_along_axis(
+            logits, targets[..., None], axis=-1)[..., 0]
+        nll = (lse - true_logit) * mask
+        denom = jnp.maximum(mask.sum(), 1.0)
+        ce = nll.sum() / denom
+        z_loss = z_loss_coef * jnp.sum(jnp.square(lse) * mask) / denom
+        total = ce + z_loss + cfg.router_aux_coef * aux
+        acc = jnp.sum((jnp.argmax(logits, -1) == targets) * mask) / denom
     return total, {
         "loss": total,
         "ce_loss": ce,
@@ -643,42 +665,49 @@ def prefill(
     """
     dtype = jnp.dtype(cfg.dtype)
     B, T = tokens.shape
-    x = _embed_lookup(params["embed"], tokens, dtype)
-    if cfg.positional == "learned":
-        x = x + params["pos_emb"][jnp.arange(T)][None].astype(dtype)
-        rope_tables = None
-    else:
-        rope_tables = rope_frequencies(cfg.hdim, cfg.max_seq_len, cfg.rope_theta)
+    with jax.named_scope("embed"):
+        x = _embed_lookup(params["embed"], tokens, dtype)
+        if cfg.positional == "learned":
+            x = x + params["pos_emb"][jnp.arange(T)][None].astype(dtype)
+            rope_tables = None
+        else:
+            rope_tables = rope_frequencies(
+                cfg.hdim, cfg.max_seq_len, cfg.rope_theta)
 
     def body(carry, lp):
         x = carry
-        h = _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
-        q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(dtype))
-        k = jnp.einsum("btd,dhk->bthk", h, lp["wk"].astype(dtype))
-        v = jnp.einsum("btd,dhk->bthk", h, lp["wv"].astype(dtype))
-        if cfg.positional == "rope":
-            cos, sin = rope_tables
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-        o = _flash(q, k, v)
-        x = x + jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(dtype))
-        h = _norm(x, lp["ln2"], lp.get("ln2_b"), cfg)
-        if cfg.is_moe:
-            y, _ = _moe_ffn(h, lp, cfg)
-        else:
-            y = _dense_ffn(h, lp, cfg)
+        with jax.named_scope("attn"):
+            h = _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
+            q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(dtype))
+            k = jnp.einsum("btd,dhk->bthk", h, lp["wk"].astype(dtype))
+            v = jnp.einsum("btd,dhk->bthk", h, lp["wv"].astype(dtype))
+            if cfg.positional == "rope":
+                cos, sin = rope_tables
+                q = apply_rope(q, cos, sin)
+                k = apply_rope(k, cos, sin)
+            o = _flash(q, k, v)
+            x = x + jnp.einsum("bthk,hkd->btd", o, lp["wo"].astype(dtype))
+        with jax.named_scope("moe" if cfg.is_moe else "ffn"):
+            h = _norm(x, lp["ln2"], lp.get("ln2_b"), cfg)
+            if cfg.is_moe:
+                y, _ = _moe_ffn(h, lp, cfg)
+            else:
+                y = _dense_ffn(h, lp, cfg)
         kpad = jnp.zeros((B, max_len, *k.shape[2:]), dtype).at[:, :T].set(k)
         vpad = jnp.zeros((B, max_len, *v.shape[2:]), dtype).at[:, :T].set(v)
         return x + y, (kpad, vpad)
 
     x, (kc, vc) = jax.lax.scan(body, x, params["layers"])
-    x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg)
-    if last_index is None:
-        x_last = x[:, -1]
-    else:
-        x_last = jnp.take_along_axis(x, last_index[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bd,dv->bv", x_last.astype(jnp.float32), head.astype(jnp.float32))
-    if cfg.logits_softcap:
-        logits = cfg.logits_softcap * jnp.tanh(logits / cfg.logits_softcap)
+    with jax.named_scope("lm_head"):
+        x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg)
+        if last_index is None:
+            x_last = x[:, -1]
+        else:
+            x_last = jnp.take_along_axis(
+                x, last_index[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = jnp.einsum("bd,dv->bv", x_last.astype(jnp.float32),
+                            head.astype(jnp.float32))
+        if cfg.logits_softcap:
+            logits = cfg.logits_softcap * jnp.tanh(logits / cfg.logits_softcap)
     return logits, {"k": kc, "v": vc}

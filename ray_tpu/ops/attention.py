@@ -194,6 +194,7 @@ def _flash_fwd_pallas(q, k, v, *, causal, scale, block_q, block_k, return_lse=Fa
             bytes_accessed=int((q.size + k.size + v.size + q.size) * q.dtype.itemsize),
             transcendentals=int(B * H * Tq * Tk),
         ),
+        name="flash_fwd",
         interpret=interpret_mode(),
     )(q, k, v)
     if return_lse:
@@ -341,6 +342,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, causal, scale, block_q, block_k,
             bytes_accessed=int(3 * q.size * q.dtype.itemsize),
             transcendentals=int(B * H * Tq * Tk),
         ),
+        name="flash_bwd_dq",
         interpret=interpret_mode(),
     )(q, k, v, lse_rep, delta_rep, do)
 
@@ -374,6 +376,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, causal, scale, block_q, block_k,
             bytes_accessed=int(4 * q.size * q.dtype.itemsize),
             transcendentals=int(B * H * Tq * Tk),
         ),
+        name="flash_bwd_dkv",
         interpret=interpret_mode(),
     )(q, k, v, lse_rep, delta_rep, do)
 

@@ -46,6 +46,7 @@ def _rms_pallas(x2d, w, eps, block_rows):
         ],
         out_specs=pl.BlockSpec((block_rows, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, D), x2d.dtype),
+        name="rms_norm",
         interpret=interpret_mode(),
     )(x2d, w.reshape(1, D))
 

@@ -189,6 +189,7 @@ def _paged_pallas(q, k_pages, v_pages, page_table, lengths, scale):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
+        name="paged_decode",
         interpret=interpret_mode(),
     )(page_table.reshape(-1), lengths, q4, k_pages, v_pages)
     return out.reshape(B, H, D)
@@ -289,6 +290,7 @@ def _chunk_pallas(q, k_pages, v_pages, page_table, meta, scale):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
+        name="paged_chunk",
         interpret=interpret_mode(),
     )(page_table, meta, qr, k_pages, v_pages)
     # [KVH, C*g, D] -> [C, H, D]
@@ -449,6 +451,7 @@ def _verify_pallas(q, k_pages, v_pages, page_table, positions, scale):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
+        name="paged_verify",
         interpret=interpret_mode(),
     )(page_table.reshape(-1), positions, qr, k_pages, v_pages)
     # [B, KVH, S*g, D] -> [B, S, H, D]

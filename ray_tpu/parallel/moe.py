@@ -84,29 +84,34 @@ def moe_layer_local(
     # pad capacity to a friendly multiple for MXU tiling
     capacity = -(-capacity // 4) * 4
 
-    logits = x @ router_w  # [T, E]
-    weights, expert_ids = top_k_gating(logits, num_selected)
-    disp, combine = _dispatch_mask(expert_ids, weights, E, capacity)
+    # scope names as models/transformer.py's MoE paths: a profile's
+    # readers key on them
+    with jax.named_scope("route"):
+        logits = x @ router_w  # [T, E]
+        weights, expert_ids = top_k_gating(logits, num_selected)
+        disp, combine = _dispatch_mask(expert_ids, weights, E, capacity)
 
-    expert_inputs = jnp.einsum("td,tec->ecd", x, disp)  # [E, C, D]
-    # route: split expert axis across ranks -> all_to_all over the ep ring
-    expert_inputs = expert_inputs.reshape(n, E_local, capacity, D)
-    routed = jax.lax.all_to_all(
-        expert_inputs, axis_name, split_axis=0, concat_axis=0, tiled=False
-    )  # [n, E_local, C, D] — now grouped by *source* rank for MY experts
-    routed = routed.reshape(n, E_local, capacity, D)
+    with jax.named_scope("dispatch"):
+        expert_inputs = jnp.einsum("td,tec->ecd", x, disp)  # [E, C, D]
+        # split expert axis across ranks -> all_to_all over the ep ring
+        expert_inputs = expert_inputs.reshape(n, E_local, capacity, D)
+        routed = jax.lax.all_to_all(
+            expert_inputs, axis_name, split_axis=0, concat_axis=0, tiled=False
+        )  # [n, E_local, C, D] — now grouped by *source* rank for MY experts
+        routed = routed.reshape(n, E_local, capacity, D)
 
     # expert FFN (SwiGLU): batched einsum over local experts — MXU-friendly
-    h = jnp.einsum("necd,edf->necf", routed, w_in)
-    g = jnp.einsum("necd,edf->necf", routed, w_gate)
-    y = jnp.einsum("necf,efd->necd", activation(g) * h, w_out)
+    with jax.named_scope("experts"):
+        h = jnp.einsum("necd,edf->necf", routed, w_in)
+        g = jnp.einsum("necd,edf->necf", routed, w_gate)
+        y = jnp.einsum("necf,efd->necd", activation(g) * h, w_out)
 
     # route back and combine
-    returned = jax.lax.all_to_all(
-        y, axis_name, split_axis=0, concat_axis=0, tiled=False
-    ).reshape(E, capacity, D)
-    out = jnp.einsum("ecd,tec->td", returned, combine)
-    return out
+    with jax.named_scope("combine"):
+        returned = jax.lax.all_to_all(
+            y, axis_name, split_axis=0, concat_axis=0, tiled=False
+        ).reshape(E, capacity, D)
+        return jnp.einsum("ecd,tec->td", returned, combine)
 
 
 def aux_load_balance_loss(router_logits: jax.Array, expert_ids: jax.Array, num_experts: int) -> jax.Array:

@@ -45,7 +45,7 @@ import numpy as np
 from ..core.config import config
 from ..core.logging import get_logger
 from ..core.metrics import Counter, Gauge, Histogram
-from ..util import slo
+from ..util import slo, tracing
 from ..models import ModelConfig
 from ..models.transformer import (
     _dense_ffn,
@@ -98,6 +98,115 @@ _m_step_phase = Histogram(
     buckets=(0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
              0.25, 1.0, 5.0),
 )
+# Where the two engine threads spend their wall time. The decode thread's
+# phases tile one iteration (`iter`: chunk, install, cancel_check, build,
+# dispatch, readback, commit; propose/propose_wait under speculation) and
+# `idle` is the wait for work; the prefill thread's are admit, dispatch,
+# readback, publish, idle. `readback` (and `chunk_readback`, the last
+# chunk's share of `chunk`) block on the device, the rest is host time.
+# Buckets reach 30 s so a standstill is one observation in one phase.
+_m_loop = Histogram(
+    "serve_engine_loop_seconds",
+    "Engine thread wall time by {thread, phase}; same readings as the "
+    "engine.* / prefill.* tracing regions.",
+    buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
+             0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0),
+)
+# A request's life in stages that tile submitted_at -> finished_at:
+# pending (queued for the prefill thread), waiting_for_pages (parked, pool
+# full), chunk_wait (on the chunk queue behind other prompts), prefill
+# (first dispatch -> first token, decode spans between its chunks
+# included), ready (first token -> decode slot), decode; kv_import on a
+# disaggregated decode replica (begin_kv_import -> ready). The first four
+# tile submitted_at -> first_token_at. One observation per stage visit.
+_m_stage = Histogram(
+    "serve_request_stage_seconds",
+    "Time requests spent in each engine stage, by stage.",
+    buckets=(0.001, 0.005, 0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
+             10.0, 30.0, 60.0),
+)
+_m_slot_steps = Counter(
+    "serve_decode_slot_steps",
+    "Decode slots x steps dispatched, by state (active: the slot held a "
+    "request; empty: it was padding).")
+_m_page_steps = Counter(
+    "serve_kv_page_steps",
+    "KV pages summed over engine iterations, by state (reserved: held by "
+    "a slot, a chunked prompt or a prefill awaiting install; written: "
+    "those that hold at least one cached token).")
+_m_deferred = Counter(
+    "serve_requests_deferred",
+    "Requests parked at admission, by reason (no_pages: the pool could "
+    "not hold prompt + max_tokens).")
+_m_front = Histogram(
+    "serve_front_seconds",
+    "What the serve front adds around the engine, by leg (inbound: the "
+    "proxy's receipt of the POST -> engine.add_request; outbound: the "
+    "engine's first token -> the first SSE chunk written).",
+    buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
+             0.05, 0.1, 0.5, 1.0, 5.0),
+)
+_STAGES = ("pending", "waiting_for_pages", "chunk_wait", "prefill", "ready",
+           "decode", "kv_import")
+_stage_child = {s: _m_stage.labels(stage=s) for s in _STAGES}
+_step_phase = {(p, m): _m_step_phase.labels(phase=p, mode=m)
+               for p in ("cancellation_check", "propose", "propose_wait",
+                         "propose_compute", "verify", "sample",
+                         "cache_bookkeeping")
+               for m in ("plain", "spec")}
+_slot_active = _m_slot_steps.labels(state="active")
+_slot_empty = _m_slot_steps.labels(state="empty")
+_pages_reserved = _m_page_steps.labels(state="reserved")
+_pages_written = _m_page_steps.labels(state="written")
+_deferred_no_pages = _m_deferred.labels(reason="no_pages")
+_front_inbound = _m_front.labels(leg="inbound")
+_front_outbound = _m_front.labels(leg="outbound")
+
+
+def observe_front_outbound(first_token_ns: int) -> None:
+    """The proxy's half of `serve_front_seconds`: called once a stream's
+    first chunk is on the wire."""
+    _front_outbound.observe((tracing.now_ns() - first_token_ns) * 1e-9)
+
+
+def _phases(thread: str, prefix: str, names) -> Dict[str, tuple]:
+    return {n: (f"{prefix}.{n}",
+                _m_loop.labels(thread=thread, phase=n.replace(".", "_")))
+            for n in names}
+
+
+_DECODE_PHASES = _phases("decode", "engine", (
+    "iter", "idle", "chunk", "chunk.readback", "install", "cancel_check",
+    "build", "dispatch", "readback", "commit", "propose", "propose_wait"))
+_PREFILL_PHASES = _phases("prefill", "prefill", (
+    "idle", "admit", "dispatch", "readback", "publish"))
+
+
+def decode_phase(name: str) -> "_Phase":
+    """`engine.<name>` on the decode thread (spec_decode.py times its
+    propose/verify legs through this too)."""
+    return _Phase(*_DECODE_PHASES[name])
+
+
+def _prefill_phase(name: str) -> "_Phase":
+    return _Phase(*_PREFILL_PHASES[name])
+
+
+class _Phase(tracing.region):
+    """A region that also feeds its `serve_engine_loop_seconds` child."""
+
+    __slots__ = ("_sink",)
+
+    def __init__(self, name: str, sink):
+        super().__init__(name)
+        self._sink = sink
+
+    def __exit__(self, *exc) -> bool:
+        super().__exit__(*exc)
+        self._sink.observe(self.elapsed_ns * 1e-9)
+        return False
+
+
 _m_tokens_per_step = Gauge(
     "serve_tokens_per_decode_step",
     "Cumulative committed tokens per slot-step of decode participation.")
@@ -254,9 +363,23 @@ class Request:
     done: threading.Event = dataclasses.field(default_factory=threading.Event)
     error: Optional[str] = None
     finish_reason: Optional[str] = None  # "stop" (eos) | "length"
-    submitted_at: float = dataclasses.field(default_factory=time.monotonic)
+    # seconds on tracing.now_ns()'s clock, each the same reading as the
+    # stage boundary it coincides with (so differences tile exactly)
+    submitted_at: float = 0.0
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
+    # the open stage, when it began (ns), and the seconds of closed
+    # stages by name (see serve_request_stage_seconds); _enter_stage moves
+    stage: Optional[str] = "pending"
+    stage_ns: int = 0
+    stage_seconds: Dict[str, float] = dataclasses.field(default_factory=dict)
+    # receipt instant at the serve front (tracing.now_ns on the proxy),
+    # carried in the payload's reserved "_received_ns" key; None when the
+    # request did not come through the proxy
+    received_ns: Optional[int] = None
+    # root of this request's stage spans when it is traced (the caller's
+    # thread carried a span at add_request, or the sampler fired)
+    span: Optional[Any] = None
     # streaming consumers: tokens pushed as generated, None terminates
     stream_q: Optional["queue.Queue"] = None
     # set by engine.cancel(): the request finishes ("cancelled") at its
@@ -285,9 +408,61 @@ class Request:
     # all layers per frame), or "" to follow config.kv_frame_layout
     kv_frame_layout: str = ""
 
+    def __post_init__(self) -> None:
+        self.stage_ns = tracing.now_ns()
+        self.submitted_at = self.stage_ns * 1e-9
+
     def _emit(self, tok: Optional[int]) -> None:
         if self.stream_q is not None:
             self.stream_q.put(tok)
+
+    def enter_stage(self, stage: Optional[str], now_ns: int) -> None:
+        """Close the open stage at `now_ns` (counter, and a child span
+        when traced) and open `stage` (None: the request is finished)."""
+        prev = self.stage
+        if prev is not None:
+            seconds = (now_ns - self.stage_ns) * 1e-9
+            _stage_child[prev].observe(seconds)
+            self.stage_seconds[prev] = self.stage_seconds.get(prev, 0.0) \
+                + seconds
+            if self.span is not None:
+                tracing.record_child(self.span, "engine.stage." + prev,
+                                     self.stage_ns, now_ns)
+        self.stage, self.stage_ns = stage, now_ns
+
+
+class TokenStream:
+    """Iterator over a streaming request's tokens (first at TTFT, not at
+    completion); raises the request's error, if any, at the end. An
+    object, not a generator, so the serve front can read the request's
+    `first_token_ns` once the first chunk is on the wire."""
+
+    def __init__(self, request: Request, timeout_s: float):
+        self.request = request
+        self._timeout_s = timeout_s
+        self._done = False
+
+    def __iter__(self) -> "TokenStream":
+        return self
+
+    def __next__(self) -> int:
+        if self._done:
+            raise StopIteration
+        tok = self.request.stream_q.get(timeout=self._timeout_s)
+        if tok is None:
+            self._done = True
+            if self.request.error:
+                raise ValueError(self.request.error)
+            raise StopIteration
+        return tok
+
+    def close(self) -> None:
+        self._done = True
+
+    @property
+    def first_token_ns(self) -> Optional[int]:
+        at = self.request.first_token_at
+        return None if at is None else int(at * 1e9)
 
 
 class _ChunkState:
@@ -551,6 +726,10 @@ class InferenceEngine:
         self._requests: Dict[str, Request] = {}  # live (uncompleted) ids
         self._req_lock = threading.Lock()
 
+    # spec_decode.py times its legs of an iteration through the engine's
+    # own phases (it cannot import this module: this one imports it)
+    phase = staticmethod(decode_phase)
+
     # ------------------------------------------------------------- compiled
 
     def _build_decode(self):
@@ -570,14 +749,16 @@ class InferenceEngine:
             """tokens/positions [B]; page_tables [B, pages_per_seq]."""
             dtype = jnp.dtype(cfg.dtype)
             B = tokens.shape[0]
-            x = _embed_lookup(
-                params["embed"], tokens[:, None], dtype, mesh=self.mesh
-            )  # [B,1,D]; one-hot matmul form when the table is sharded
-            if cfg.positional == "learned":
-                x = x + params["pos_emb"][positions][:, None].astype(dtype)
-                rope_tables = None
-            else:
-                rope_tables = rope_frequencies(cfg.hdim, cfg.max_seq_len, cfg.rope_theta)
+            with jax.named_scope("embed"):
+                x = _embed_lookup(
+                    params["embed"], tokens[:, None], dtype, mesh=self.mesh
+                )  # [B,1,D]; one-hot matmul form when the table is sharded
+                if cfg.positional == "learned":
+                    x = x + params["pos_emb"][positions][:, None].astype(dtype)
+                    rope_tables = None
+                else:
+                    rope_tables = rope_frequencies(
+                        cfg.hdim, cfg.max_seq_len, cfg.rope_theta)
             pos2d = positions[:, None]
             page_idx = page_tables[jnp.arange(B), positions // ps]  # [B]
             slot_idx = positions % ps
@@ -585,59 +766,56 @@ class InferenceEngine:
             def body(carry, xs):
                 x = carry
                 lp, kp, vp = xs  # kp/vp [KVH, P, ps, hd]
-                h = _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
-                q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(dtype))
-                k = jnp.einsum("btd,dhk->bthk", h, lp["wk"].astype(dtype))
-                v = jnp.einsum("btd,dhk->bthk", h, lp["wv"].astype(dtype))
-                if cfg.positional == "rope":
-                    cos, sin = rope_tables
-                    q = apply_rope(q, cos, sin, pos2d)
-                    k = apply_rope(k, cos, sin, pos2d)
-                # write this token's kv into its page slot
-                kp = kp.at[:, page_idx, slot_idx].set(
-                    k[:, 0].transpose(1, 0, 2).astype(kp.dtype)
-                )
-                vp = vp.at[:, page_idx, slot_idx].set(
-                    v[:, 0].transpose(1, 0, 2).astype(vp.dtype)
-                )
-                o = paged_attention_decode(
-                    q[:, 0], kp, vp, page_tables, positions + 1,
-                    mesh=tp_mesh,
-                )
-                o = jnp.einsum("bhk,hkd->bd", o, lp["wo"].astype(dtype))[:, None]
-                x = x + o
-                h = _norm(x, lp["ln2"], lp.get("ln2_b"), cfg)
-                if cfg.is_moe:
-                    y, _ = _moe_ffn(h, lp, cfg)
-                else:
-                    y = _dense_ffn(h, lp, cfg)
-                return x + y, (kp, vp)
+                with jax.named_scope("attn"):
+                    h = _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
+                    q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(dtype))
+                    k = jnp.einsum("btd,dhk->bthk", h, lp["wk"].astype(dtype))
+                    v = jnp.einsum("btd,dhk->bthk", h, lp["wv"].astype(dtype))
+                    if cfg.positional == "rope":
+                        cos, sin = rope_tables
+                        q = apply_rope(q, cos, sin, pos2d)
+                        k = apply_rope(k, cos, sin, pos2d)
+                    with jax.named_scope("kv_write"):
+                        # write this token's kv into its page slot
+                        kp = kp.at[:, page_idx, slot_idx].set(
+                            k[:, 0].transpose(1, 0, 2).astype(kp.dtype)
+                        )
+                        vp = vp.at[:, page_idx, slot_idx].set(
+                            v[:, 0].transpose(1, 0, 2).astype(vp.dtype)
+                        )
+                    o = paged_attention_decode(
+                        q[:, 0], kp, vp, page_tables, positions + 1,
+                        mesh=tp_mesh,
+                    )
+                    o = jnp.einsum("bhk,hkd->bd", o,
+                                   lp["wo"].astype(dtype))[:, None]
+                    x = x + o
+                x = x + _ffn(x, lp, cfg)
+                return x, (kp, vp)
 
             x, (new_k, new_v) = jax.lax.scan(
                 body, x, (params["layers"], k_pages, v_pages)
             )
-            x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg)
-            head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-            logits = jnp.einsum(
-                "bd,dv->bv", x[:, 0].astype(jnp.float32), head.astype(jnp.float32)
-            )
-            if cfg.logits_softcap:
-                logits = cfg.logits_softcap * jnp.tanh(logits / cfg.logits_softcap)
-            if advanced:
-                toks = _device_sample_topk_topp(logits, temps, top_ps,
-                                                top_ks, key)
-            else:
-                # per-slot sampling: temp<=0 -> greedy
-                greedy = jnp.argmax(logits, axis=-1)
-                scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-                sampled = jax.random.categorical(key, scaled, axis=-1)
-                toks = jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
-            # logprob of the sampled token under the RAW distribution
-            # (negligible next to the lm_head matmul, so it is computed
-            # unconditionally rather than doubling the program cache)
-            logps = jnp.take_along_axis(
-                jax.nn.log_softmax(logits, axis=-1),
-                toks[:, None].astype(jnp.int32), axis=-1)[:, 0]
+            with jax.named_scope("lm_head"):
+                logits = _head_logits(x, lambda x: x[:, 0], params, cfg,
+                                      "bd,dv->bv")
+            with jax.named_scope("sample"):
+                if advanced:
+                    toks = _device_sample_topk_topp(logits, temps, top_ps,
+                                                    top_ks, key)
+                else:
+                    # per-slot sampling: temp<=0 -> greedy
+                    greedy = jnp.argmax(logits, axis=-1)
+                    scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+                    sampled = jax.random.categorical(key, scaled, axis=-1)
+                    toks = jnp.where(temps > 0, sampled,
+                                     greedy).astype(jnp.int32)
+                # logprob of the sampled token under the RAW distribution
+                # (negligible next to the lm_head matmul, so it is computed
+                # unconditionally rather than doubling the program cache)
+                logps = jnp.take_along_axis(
+                    jax.nn.log_softmax(logits, axis=-1),
+                    toks[:, None].astype(jnp.int32), axis=-1)[:, 0]
             return toks, logps, new_k, new_v
 
         def decode_span(params, k_pages, v_pages, tokens, positions,
@@ -666,8 +844,11 @@ class InferenceEngine:
             key_ = (n_steps, advanced)
             if key_ not in cache:
                 cache[key_] = self._under_mesh(jax.jit(
-                    functools.partial(decode_span, n_steps=n_steps,
-                                      advanced=advanced),
+                    tracing.named(
+                        functools.partial(decode_span, n_steps=n_steps,
+                                          advanced=advanced),
+                        f"decode_span_{n_steps}"
+                        + ("_adv" if advanced else "")),
                     donate_argnums=(1, 2),
                 ))
             return cache[key_]
@@ -699,52 +880,52 @@ class InferenceEngine:
             would queue behind whatever decode span is in flight)."""
             dtype = jnp.dtype(cfg.dtype)
             C = tokens.shape[0]
-            x = _embed_lookup(params["embed"], tokens[None, :], dtype,
-                              mesh=self.mesh)  # [1,C,D]
             positions = start + jnp.arange(C)
-            if cfg.positional == "learned":
-                x = x + params["pos_emb"][positions][None].astype(dtype)
-                rope_tables = None
-            else:
-                rope_tables = rope_frequencies(
-                    cfg.hdim, cfg.max_seq_len, cfg.rope_theta)
+            with jax.named_scope("embed"):
+                x = _embed_lookup(params["embed"], tokens[None, :], dtype,
+                                  mesh=self.mesh)  # [1,C,D]
+                if cfg.positional == "learned":
+                    x = x + params["pos_emb"][positions][None].astype(dtype)
+                    rope_tables = None
+                else:
+                    rope_tables = rope_frequencies(
+                        cfg.hdim, cfg.max_seq_len, cfg.rope_theta)
             page_idx = page_table[positions // ps]  # [C]
             slot_idx = positions % ps
 
             def body(carry, xs):
                 x = carry
                 lp, kp, vp = xs  # kp/vp [KVH, P, ps, hd]
-                h = _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
-                q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(dtype))
-                k = jnp.einsum("btd,dhk->bthk", h, lp["wk"].astype(dtype))
-                v = jnp.einsum("btd,dhk->bthk", h, lp["wv"].astype(dtype))
-                if cfg.positional == "rope":
-                    cos, sin = rope_tables
-                    q = apply_rope(q, cos, sin, positions[None])
-                    k = apply_rope(k, cos, sin, positions[None])
-                kp = kp.at[:, page_idx, slot_idx].set(
-                    k[0].transpose(1, 0, 2).astype(kp.dtype))
-                vp = vp.at[:, page_idx, slot_idx].set(
-                    v[0].transpose(1, 0, 2).astype(vp.dtype))
-                # key j visible to query row c iff j <= start + c (prefix
-                # + causal intra-chunk); pad rows past true_len write KV
-                # but are never selected by last_idx and are invisible to
-                # later decode (position bound)
-                o = paged_attention_chunk(
-                    q[0], kp, vp, page_table, start, start + C,
-                    force_xla=tp_force_xla,
-                ).astype(dtype)
-                o = jnp.einsum("chk,hkd->cd", o, lp["wo"].astype(dtype))[None]
-                x = x + o
-                h = _norm(x, lp["ln2"], lp.get("ln2_b"), cfg)
-                if cfg.is_moe:
-                    y, _ = _moe_ffn(h, lp, cfg)
-                else:
-                    y = _dense_ffn(h, lp, cfg)
+                with jax.named_scope("attn"):
+                    h = _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
+                    q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(dtype))
+                    k = jnp.einsum("btd,dhk->bthk", h, lp["wk"].astype(dtype))
+                    v = jnp.einsum("btd,dhk->bthk", h, lp["wv"].astype(dtype))
+                    if cfg.positional == "rope":
+                        cos, sin = rope_tables
+                        q = apply_rope(q, cos, sin, positions[None])
+                        k = apply_rope(k, cos, sin, positions[None])
+                    with jax.named_scope("kv_write"):
+                        kp = kp.at[:, page_idx, slot_idx].set(
+                            k[0].transpose(1, 0, 2).astype(kp.dtype))
+                        vp = vp.at[:, page_idx, slot_idx].set(
+                            v[0].transpose(1, 0, 2).astype(vp.dtype))
+                    # key j visible to query row c iff j <= start + c
+                    # (prefix + causal intra-chunk); pad rows past true_len
+                    # write KV but are never selected by last_idx and are
+                    # invisible to later decode (position bound)
+                    o = paged_attention_chunk(
+                        q[0], kp, vp, page_table, start, start + C,
+                        force_xla=tp_force_xla,
+                    ).astype(dtype)
+                    o = jnp.einsum("chk,hkd->cd", o,
+                                   lp["wo"].astype(dtype))[None]
+                    x = x + o
+                x = x + _ffn(x, lp, cfg)
                 if export:
-                    return x + y, (kp, vp, k[0].astype(kp.dtype),
-                                   v[0].astype(vp.dtype))
-                return x + y, (kp, vp)
+                    return x, (kp, vp, k[0].astype(kp.dtype),
+                               v[0].astype(vp.dtype))
+                return x, (kp, vp)
 
             if export:
                 x, (new_k, new_v, chunk_k, chunk_v) = jax.lax.scan(
@@ -754,15 +935,9 @@ class InferenceEngine:
                 x, (new_k, new_v) = jax.lax.scan(
                     body, x, (params["layers"], k_pages, v_pages)
                 )
-            x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg)
-            head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-            logits = jnp.einsum(
-                "d,dv->v",
-                x[0, last_idx].astype(jnp.float32), head.astype(jnp.float32),
-            )
-            if cfg.logits_softcap:
-                logits = cfg.logits_softcap * jnp.tanh(
-                    logits / cfg.logits_softcap)
+            with jax.named_scope("lm_head"):
+                logits = _head_logits(x, lambda x: x[0, last_idx], params,
+                                      cfg, "d,dv->v")
             if export:
                 return logits, new_k, new_v, chunk_k, chunk_v
             return logits, new_k, new_v
@@ -773,7 +948,9 @@ class InferenceEngine:
             key = (C, export)
             if key not in cache:
                 cache[key] = self._under_mesh(jax.jit(
-                    functools.partial(chunk_step, export=export),
+                    tracing.named(
+                        functools.partial(chunk_step, export=export),
+                        f"chunk_prefill_{C}" + ("_export" if export else "")),
                     donate_argnums=(1, 2)))
             return cache[key]
 
@@ -856,9 +1033,11 @@ class InferenceEngine:
             cfg = self.cfg
 
             def run(params, tokens, true_len):
-                return prefill(
-                    params, cfg, tokens, max_len=bucket, last_index=true_len - 1
-                )
+                # the module stays `jit_run` (the benchmark's trace_names
+                # keys on it); the scope says which shape class it is
+                with jax.named_scope(f"prefill_bucket_{bucket}x{batch}"):
+                    return prefill(params, cfg, tokens, max_len=bucket,
+                                   last_index=true_len - 1)
 
             self._prefill_cache[key] = self._under_mesh(jax.jit(run))
         return self._prefill_cache[key]
@@ -1104,6 +1283,7 @@ class InferenceEngine:
                 req.prompt, T // self.ecfg.page_size)
         with self._req_lock:
             self._requests[req.request_id] = req
+        req.enter_stage("kv_import", tracing.now_ns())
         deadline = time.monotonic() + timeout_s
         pages = None
         while True:
@@ -1201,6 +1381,7 @@ class InferenceEngine:
                 req._held.append(first)  # hold-back from token 1
             else:
                 req._emit(first)
+        req.enter_stage("ready", tracing.now_ns())
         with self._ready_lock:
             self._ready.append((req, st["pages"], cache, st["T"]))
         self._work.set()
@@ -1298,6 +1479,13 @@ class InferenceEngine:
             req.done.set()
             req._emit(None)
             return
+        if req.received_ns is not None:
+            _front_inbound.observe(
+                (tracing.now_ns() - req.received_ns) * 1e-9)
+        # traced when the caller's thread carries a span or the sampler
+        # fires: the root of the request's stage spans, finished with it
+        req.span = tracing.maybe_begin("engine.request",
+                                       {"request_id": req.request_id})
         with self._req_lock:
             self._requests[req.request_id] = req
         self.pending.put(req)
@@ -1347,7 +1535,12 @@ class InferenceEngine:
         else:
             req.finish_reason = reason
             _m_requests.inc(tags={"finish_reason": reason})
-        req.finished_at = time.monotonic()
+        now = tracing.now_ns()
+        req.enter_stage(None, now)
+        req.finished_at = now * 1e-9
+        if req.span is not None:
+            req.span.attrs["finish_reason"] = reason or "error"
+            req.span.finish(now)
         if self._slo_on and error is None and reason != "cancelled":
             self._slo_digest("serve_e2e_seconds").add(
                 req.finished_at - req.submitted_at)
@@ -1393,13 +1586,15 @@ class InferenceEngine:
         _work event (clear → recheck → wait, so a prefill publishing to
         _ready between the recheck and the wait still wakes it)."""
         while not self._stop.is_set():
-            progressed = self.step()
-            if progressed:
+            if self._has_work():  # then step() progresses
+                with decode_phase("iter"):
+                    self.step()
                 continue
             self._work.clear()
             if self._has_work() or self._stop.is_set():
                 continue
-            self._work.wait(timeout=0.5)
+            with decode_phase("idle"):
+                self._work.wait(timeout=0.5)
 
     # ------------------------------------------------------------- prefill
     # Runs on its own thread so a long prompt never stalls the decode
@@ -1414,10 +1609,11 @@ class InferenceEngine:
         K serial [1, bucket] calls — the MXU sees one big matmul and queue
         TTFT drops accordingly."""
         while not self._stop.is_set():
-            try:
-                req = self.pending.get(timeout=0.2)
-            except queue.Empty:
-                continue
+            with _prefill_phase("idle"):
+                try:
+                    req = self.pending.get(timeout=0.2)
+                except queue.Empty:
+                    continue
             batch = [req]
             # drain the WHOLE burst (up to the largest compiled tier):
             # one padded dispatch beats serial rounds for every waiter
@@ -1450,7 +1646,9 @@ class InferenceEngine:
                 pages = self.prefix.release_and_filter(pages)
             self.allocator.free(pages)
             waiting, self._waiting = self._waiting, []
+        now = tracing.now_ns() if waiting else 0
         for w in waiting:
+            w.enter_stage("pending", now)
             self.pending.put(w)
 
     def _alloc_with_reclaim(self, n: int) -> Optional[List[int]]:
@@ -1498,6 +1696,8 @@ class InferenceEngine:
                     cancelled = True
                 else:
                     # no capacity; revived by _maybe_finish on page frees
+                    req.enter_stage("waiting_for_pages", tracing.now_ns())
+                    _deferred_no_pages.inc()
                     self._waiting.append(req)
                     return None
             else:
@@ -1531,33 +1731,8 @@ class InferenceEngine:
         """Admit + prefill a drained batch. Never raises: each request
         ends this call deferred (_waiting), published (_ready), or failed
         (error set, pages freed) — independently of its batch-mates."""
-        admitted: List[tuple] = []
-        for req in reqs:
-            if req.cancelled.is_set():  # cancelled while queued
-                self._finish_request(req, "cancelled")
-                continue
-            try:
-                out = self._admit_for_prefill(req)
-            except Exception as e:  # noqa: BLE001 — fail just this request
-                logger.warning("admission failed for %s", req.request_id,
-                               exc_info=True)
-                self._fail_request(req, f"prefill admission failed: {e!r}")
-                continue
-            if out is not None:
-                admitted.append((req, *out))
-        chunked = [it for it in admitted if it[3] is None]
-        admitted = [it for it in admitted if it[3] is not None]
-        if chunked:
-            pps = self.ecfg.pages_per_seq
-            C = self.ecfg.prefill_chunk
-            with self._chunk_lock:
-                for req, pages, T, _b, cached_len in chunked:
-                    table = np.zeros((pps,), np.int32)
-                    table[: len(pages)] = pages
-                    st = _ChunkState(req, pages, table, T)
-                    st.next_chunk = cached_len // C  # resume past the hits
-                    self._chunk_queue.append(st)
-            self._work.set()  # the decode thread runs the chunks
+        with _prefill_phase("admit"):
+            admitted = self._admit_batch(reqs)
         by_bucket: Dict[int, List[tuple]] = {}
         for item in admitted:
             by_bucket.setdefault(item[3], []).append(item)
@@ -1573,6 +1748,39 @@ class InferenceEngine:
                     if not req.done.is_set():
                         self._fail_request(req, f"prefill failed: {e!r}")
 
+    def _admit_batch(self, reqs: List[Request]) -> List[tuple]:
+        """Pages for each request; chunked prompts go to the decode
+        thread's chunk queue. -> the bucket-path admissions."""
+        admitted: List[tuple] = []
+        for req in reqs:
+            if req.cancelled.is_set():  # cancelled while queued
+                self._finish_request(req, "cancelled")
+                continue
+            try:
+                out = self._admit_for_prefill(req)
+            except Exception as e:  # noqa: BLE001 — fail just this request
+                logger.warning("admission failed for %s", req.request_id,
+                               exc_info=True)
+                self._fail_request(req, f"prefill admission failed: {e!r}")
+                continue
+            if out is not None:
+                admitted.append((req, *out))
+        chunked = [it for it in admitted if it[3] is None]
+        if chunked:
+            pps = self.ecfg.pages_per_seq
+            C = self.ecfg.prefill_chunk
+            now = tracing.now_ns()
+            with self._chunk_lock:
+                for req, pages, T, _b, cached_len in chunked:
+                    table = np.zeros((pps,), np.int32)
+                    table[: len(pages)] = pages
+                    st = _ChunkState(req, pages, table, T)
+                    st.next_chunk = cached_len // C  # resume past the hits
+                    req.enter_stage("chunk_wait", now)
+                    self._chunk_queue.append(st)
+            self._work.set()  # the decode thread runs the chunks
+        return [it for it in admitted if it[3] is not None]
+
     def _prefill_group(self, bucket: int, group: List[tuple],
                        tiers: List[int]) -> None:
         B = len(group)
@@ -1583,39 +1791,45 @@ class InferenceEngine:
             self._prefill_group(bucket, group[:Bpad], tiers)
             self._prefill_group(bucket, group[Bpad:], tiers)
             return
-        padded = np.zeros((Bpad, bucket), np.int32)
-        lens = np.ones((Bpad,), np.int32)  # dummy rows: true_len 1
-        for i, (req, _pages, T, _b, _cl) in enumerate(group):
-            padded[i, :T] = req.prompt
-            lens[i] = T
-        logits, cache = self._prefill_fn(bucket, Bpad)(
-            self.params, jnp.asarray(padded), jnp.asarray(lens)
-        )
+        with _prefill_phase("dispatch") as ph:
+            padded = np.zeros((Bpad, bucket), np.int32)
+            lens = np.ones((Bpad,), np.int32)  # dummy rows: true_len 1
+            for i, (req, _pages, T, _b, _cl) in enumerate(group):
+                padded[i, :T] = req.prompt
+                lens[i] = T
+                req.enter_stage("prefill", ph.start_ns)
+            logits, cache = self._prefill_fn(bucket, Bpad)(
+                self.params, jnp.asarray(padded), jnp.asarray(lens)
+            )
         # first generated tokens: one small readback, on THIS thread.
         # Sample every row BEFORE emitting/publishing anything: if this
         # raises, the caller's failure path can still free every page
         # safely because no request has been published to _ready yet.
-        logits_host = np.asarray(logits)
-        firsts = [
-            _sample_host(logits_host[i], req.temperature,
-                         req.top_p, req.top_k)
-            for i, (req, _p, _T, _b, _cl) in enumerate(group)
-        ]
-        first_lps = [_host_logprob(logits_host[i], firsts[i])
-                     for i in range(len(group))]
+        with _prefill_phase("readback"):
+            logits_host = np.asarray(logits)
+            firsts = [
+                _sample_host(logits_host[i], req.temperature,
+                             req.top_p, req.top_k)
+                for i, (req, _p, _T, _b, _cl) in enumerate(group)
+            ]
+            first_lps = [_host_logprob(logits_host[i], firsts[i])
+                         for i in range(len(group))]
+        with _prefill_phase("publish") as ph:
+            self._publish_group(group, firsts, first_lps, cache,
+                                ph.start_ns)
+
+    def _publish_group(self, group: List[tuple], firsts, first_lps, cache,
+                       now_ns: int) -> None:
+        """First tokens to their requests, and the group to `_ready` (or,
+        for streamed exports, its KV frames to the sinks)."""
         wv = self.weights_version  # generation stamp: sampled under these
-        now = time.monotonic()
         streamed = [i for i, it in enumerate(group)
                     if it[0].prefill_only and it[0].kv_sink is not None]
         eos = self.ecfg.eos_token_id
         with self._ready_lock:
             for i, (req, pages, T, _b, _cl) in enumerate(group):
                 first = firsts[i]
-                req.first_token_at = now
-                _m_ttft.observe(now - req.submitted_at)
-                if self._slo_on:
-                    self._slo_digest("serve_ttft_seconds").add(
-                        now - req.submitted_at)
+                self._note_first_token(req, now_ns)
                 _m_tokens.inc()
                 req.output.append(int(first))
                 req.output_logprobs.append(first_lps[i])
@@ -1767,6 +1981,7 @@ class InferenceEngine:
             slot.pages = pages
             slot.position = T  # the sampled token will be written at T
             slot.generated = 1
+            req.enter_stage("decode", tracing.now_ns())
             if self._spec is not None:
                 # draft proposer: prefill the prompt into the slot's draft
                 # pages (runs on the decode thread — donated draft pools
@@ -1800,6 +2015,8 @@ class InferenceEngine:
         is_last = start + C >= st.true_len
         last_idx = (st.true_len - 1 - start) if is_last else C - 1
         req = st.request
+        if req.stage == "chunk_wait":  # its first chunk goes out now
+            req.enter_stage("prefill", tracing.now_ns())
         streaming = req.prefill_only and req.kv_sink is not None
         chunk_kv = None
         if streaming:
@@ -1838,14 +2055,11 @@ class InferenceEngine:
             return True
         with self._chunk_lock:
             self._chunk_queue.pop(0)
-        logits_host = np.asarray(logits)
+        with decode_phase("chunk.readback"):
+            logits_host = np.asarray(logits)
         first = _sample_host(logits_host, req.temperature,
                              req.top_p, req.top_k)
-        now = time.monotonic()
-        req.first_token_at = now
-        _m_ttft.observe(now - req.submitted_at)
-        if self._slo_on:
-            self._slo_digest("serve_ttft_seconds").add(now - req.submitted_at)
+        self._note_first_token(req, tracing.now_ns())
         _m_tokens.inc()
         req.output.append(int(first))
         req.output_logprobs.append(_host_logprob(logits_host, int(first)))
@@ -1894,23 +2108,74 @@ class InferenceEngine:
 
         Every iteration with active slots observes the per-phase timing
         histogram (serve_decode_step_phase_seconds, tagged phase+mode)."""
-        chunked = self._advance_chunk()
-        installed = self._install_ready()
+        with decode_phase("chunk"):
+            chunked = self._advance_chunk()
+        with decode_phase("install"):
+            installed = self._install_ready()
         # Cancellation sweep: a request cancelled mid-decode (or mid-
         # speculation round) frees its slot at this step boundary instead
         # of riding out the span / the committed draft prefix.
-        t0 = time.monotonic()
-        for s in self.slots:
-            if s.request is not None and s.request.cancelled.is_set():
-                self._maybe_finish(s, -1)
-        t_cancel = time.monotonic() - t0
-        active = self._active()
+        with decode_phase("cancel_check") as ph:
+            for s in self.slots:
+                if s.request is not None and s.request.cancelled.is_set():
+                    self._maybe_finish(s, -1)
+            active = self._active()
         if not active:
             return installed or chunked
         mode = "spec" if self._spec is not None else "plain"
-        _m_step_phase.observe(
-            t_cancel, tags={"phase": "cancellation_check", "mode": mode})
+        _step_phase["cancellation_check", mode].observe(ph.elapsed_s)
 
+        with decode_phase("build"):
+            (tokens, positions, tables, temps, top_ps, top_ks,
+             advanced) = self._build_batch()
+            self._step_count += 1
+            key = jax.random.fold_in(self._base_key, self._step_count)
+            # Adaptive span (VERDICT r3 #2): while prefill work is queued
+            # or running, shrink the span so the device yields between
+            # decode dispatches and arriving requests get their first
+            # token (emitted by the prefill program) without waiting out a
+            # long span.
+            if self.ecfg.adaptive_span and (
+                self._prefill_inflight > 0
+                or not self.pending.empty()
+                or self._chunk_queue  # racy read is fine: pressure hint only
+                or self._importing > 0  # streamed KV imports staged (disagg)
+            ):
+                span = max(1, self.ecfg.busy_span)
+            else:
+                span = max(1, self.ecfg.decode_span)
+            self._count_pages()
+        if self._spec is not None:
+            if self._step_spec(tokens, positions, tables, temps, top_ps,
+                               top_ks, advanced, key, len(active)):
+                return True
+            # zero-draft fallback: the (cheap) proposer found nothing to
+            # draft anywhere in the batch this round — the plain span
+            # below commits span tokens per slot where the S-wide verify
+            # would commit exactly one
+        with decode_phase("dispatch") as ph:
+            seq, logps, self.k_pages, self.v_pages = self._decode(
+                span, advanced)(
+                self.params, self.k_pages, self.v_pages,
+                jnp.asarray(tokens), jnp.asarray(positions),
+                jnp.asarray(tables), jnp.asarray(temps),
+                jnp.asarray(top_ps), jnp.asarray(top_ks), key,
+            )
+        _step_phase["verify", "plain"].observe(ph.elapsed_s)
+        with decode_phase("readback") as ph:
+            seq = np.asarray(seq)  # [span, B] — one readback per span
+            logps = np.asarray(logps)  # [span, B]
+        _step_phase["sample", "plain"].observe(ph.elapsed_s)
+        with decode_phase("commit") as ph:
+            n_active = len(active)
+            self._count_slot_steps(n_active, span)
+            committed = self._commit_span(seq, logps, span)
+            self._note_tokens_per_step(committed, span * n_active)
+        _step_phase["cache_bookkeeping", "plain"].observe(ph.elapsed_s)
+        return True
+
+    def _build_batch(self):
+        """The decode batch as numpy arrays, one row per slot."""
         B = self.ecfg.max_batch_size
         pps = self.ecfg.pages_per_seq
         tokens = np.zeros((B,), np.int32)
@@ -1932,40 +2197,11 @@ class InferenceEngine:
             if s.request.temperature > 0 and (
                     s.request.top_p < 1.0 or s.request.top_k > 0):
                 advanced = True  # the sort-based sampler program runs
-        self._step_count += 1
-        key = jax.random.fold_in(self._base_key, self._step_count)
-        if self._spec is not None:
-            if self._step_spec(tokens, positions, tables, temps, top_ps,
-                               top_ks, advanced, key, len(active)):
-                return True
-            # zero-draft fallback: the (cheap) proposer found nothing to
-            # draft anywhere in the batch this round — the plain span
-            # below commits span tokens per slot where the S-wide verify
-            # would commit exactly one
-        # Adaptive span (VERDICT r3 #2): while prefill work is queued or
-        # running, shrink the span so the device yields between decode
-        # dispatches and arriving requests get their first token (emitted
-        # by the prefill program) without waiting out a long span.
-        if self.ecfg.adaptive_span and (
-            self._prefill_inflight > 0
-            or not self.pending.empty()
-            or self._chunk_queue  # racy read is fine: pressure hint only
-            or self._importing > 0  # streamed KV imports staged (disagg)
-        ):
-            span = max(1, self.ecfg.busy_span)
-        else:
-            span = max(1, self.ecfg.decode_span)
-        t0 = time.monotonic()
-        seq, logps, self.k_pages, self.v_pages = self._decode(span, advanced)(
-            self.params, self.k_pages, self.v_pages,
-            jnp.asarray(tokens), jnp.asarray(positions), jnp.asarray(tables),
-            jnp.asarray(temps), jnp.asarray(top_ps), jnp.asarray(top_ks), key,
-        )
-        t1 = time.monotonic()
-        seq = np.asarray(seq)  # [span, B] — one readback per span
-        logps = np.asarray(logps)  # [span, B]
-        t2 = time.monotonic()
-        n_participating = span * len(active)
+        return tokens, positions, tables, temps, top_ps, top_ks, advanced
+
+    def _commit_span(self, seq, logps, span: int) -> int:
+        """The host loop after a span's readback: tokens to their
+        requests, finished slots retired. -> tokens committed."""
         committed = 0
         for t in range(span):
             for i, s in enumerate(self.slots):
@@ -1991,15 +2227,32 @@ class InferenceEngine:
                     else:
                         s.request._emit(tok)
                 self._maybe_finish(s, tok)
-        t3 = time.monotonic()
-        _m_step_phase.observe(t1 - t0, tags={"phase": "verify",
-                                             "mode": "plain"})
-        _m_step_phase.observe(t2 - t1, tags={"phase": "sample",
-                                             "mode": "plain"})
-        _m_step_phase.observe(t3 - t2, tags={"phase": "cache_bookkeeping",
-                                             "mode": "plain"})
-        self._note_tokens_per_step(committed, n_participating)
-        return True
+        return committed
+
+    def _count_slot_steps(self, n_active: int, steps: int) -> None:
+        _slot_active.inc(n_active * steps)
+        _slot_empty.inc((self.ecfg.max_batch_size - n_active) * steps)
+
+    def _count_pages(self) -> None:
+        """Once an iteration: pages held by slots, chunked prompts and
+        prefills awaiting install, and how many of them hold a token."""
+        ps = self.ecfg.page_size
+        reserved = written = 0
+        for s in self.slots:
+            if s.request is not None:
+                reserved += len(s.pages)
+                written += -(-s.position // ps)
+        C = self.ecfg.prefill_chunk
+        with self._chunk_lock:
+            for st in self._chunk_queue:
+                reserved += len(st.pages)
+                written += -(-min(st.next_chunk * C, st.true_len) // ps)
+        with self._ready_lock:
+            for _req, pages, _cache, T in self._ready:
+                reserved += len(pages)
+                written += -(-T // ps)
+        _pages_reserved.inc(reserved)
+        _pages_written.inc(written)
 
     def _step_spec(self, tokens, positions, tables, temps, top_ps, top_ks,
                    advanced, key, n_active) -> bool:
@@ -2025,10 +2278,22 @@ class InferenceEngine:
             advanced, key)
         if committed is None:
             for phase in ("propose", "propose_wait", "propose_compute"):
-                _m_step_phase.observe(times[phase], tags={"phase": phase,
-                                                          "mode": "spec"})
+                _step_phase[phase, "spec"].observe(times[phase])
             return False
-        t0 = time.monotonic()
+        with decode_phase("commit") as ph:
+            self._count_slot_steps(n_active, 1)
+            n_tokens = self._commit_spec(committed, n_comm, n_draft)
+            self._note_tokens_per_step(n_tokens, n_active)
+        for phase in ("propose", "propose_wait", "propose_compute",
+                      "verify", "sample"):
+            _step_phase[phase, "spec"].observe(times[phase])
+        _step_phase["cache_bookkeeping", "spec"].observe(ph.elapsed_s)
+        return True
+
+    def _commit_spec(self, committed, n_comm, n_draft) -> int:
+        """The host loop after a verify round: each slot's accepted
+        prefix plus its bonus token. -> tokens committed."""
+        spec, ecfg = self._spec, self.ecfg
         proposed = accepted = n_tokens = 0
         for i, s in enumerate(self.slots):
             if s.request is None:
@@ -2058,16 +2323,18 @@ class InferenceEngine:
                     else:
                         s.request._emit(tok)
                 self._maybe_finish(s, tok)
-        t1 = time.monotonic()
         spec.record(proposed, accepted)
-        for phase in ("propose", "propose_wait", "propose_compute",
-                      "verify", "sample"):
-            _m_step_phase.observe(times[phase], tags={"phase": phase,
-                                                      "mode": "spec"})
-        _m_step_phase.observe(t1 - t0, tags={"phase": "cache_bookkeeping",
-                                             "mode": "spec"})
-        self._note_tokens_per_step(n_tokens, n_active)
-        return True
+        return n_tokens
+
+    def _note_first_token(self, req: Request, now_ns: int) -> None:
+        """The first-token instant: closes the request's `prefill` stage
+        and is the TTFT every surface reports."""
+        req.enter_stage("ready", now_ns)
+        req.first_token_at = now_ns * 1e-9
+        ttft = req.first_token_at - req.submitted_at
+        _m_ttft.observe(ttft)
+        if self._slo_on:
+            self._slo_digest("serve_ttft_seconds").add(ttft)
 
     def _slo_digest(self, name: str) -> "slo.Digest":
         d = self._slo.get(name)
@@ -2155,10 +2422,9 @@ class InferenceEngine:
         top_p: float = 1.0,
         top_k: int = 0,
         stop: Optional[List[List[int]]] = None,
+        received_ns: Optional[int] = None,
     ) -> Dict[str, Any]:
         import uuid
-
-        from ..util import tracing
 
         req = Request(
             request_id=request_id or uuid.uuid4().hex,
@@ -2168,15 +2434,14 @@ class InferenceEngine:
             top_p=top_p,
             top_k=top_k,
             stop=stop,
+            received_ns=received_ns,
         )
-        with tracing.span_if_traced("engine.generate",
-                                    {"request_id": req.request_id}):
-            self.add_request(req)
-            if not req.done.wait(timeout_s):
-                # the caller is gone: cancel so the slot/pages free instead
-                # of decoding to max_tokens for nobody
-                self.cancel(req.request_id)
-                raise TimeoutError(f"request {req.request_id} timed out")
+        self.add_request(req)
+        if not req.done.wait(timeout_s):
+            # the caller is gone: cancel so the slot/pages free instead
+            # of decoding to max_tokens for nobody
+            self.cancel(req.request_id)
+            raise TimeoutError(f"request {req.request_id} timed out")
         if req.error:
             raise ValueError(req.error)
         return {
@@ -2199,9 +2464,10 @@ class InferenceEngine:
         top_p: float = 1.0,
         top_k: int = 0,
         stop: Optional[List[List[int]]] = None,
+        received_ns: Optional[int] = None,
     ):
-        """-> (Request, token generator). The request object exposes
-        finish_reason/error/timing after the generator is exhausted."""
+        """-> (Request, token stream). The request object exposes
+        finish_reason/error/timing after the stream is exhausted."""
         import uuid
 
         req = Request(
@@ -2213,19 +2479,10 @@ class InferenceEngine:
             top_k=top_k,
             stop=stop,
             stream_q=queue.Queue(),
+            received_ns=received_ns,
         )
         self.add_request(req)
-
-        def gen():
-            while True:
-                tok = req.stream_q.get(timeout=timeout_s)
-                if tok is None:
-                    break
-                yield tok
-            if req.error:
-                raise ValueError(req.error)
-
-        return req, gen()
+        return req, TokenStream(req, timeout_s)
 
     def generate_stream(
         self,
@@ -2237,15 +2494,16 @@ class InferenceEngine:
         top_p: float = 1.0,
         top_k: int = 0,
         stop: Optional[List[List[int]]] = None,
+        received_ns: Optional[int] = None,
     ):
         """Yield token ids as they are generated (first at TTFT, not at
         completion). Raises the request's error, if any, after the stream."""
-        _, gen = self.open_stream(
+        _, stream = self.open_stream(
             prompt, max_tokens=max_tokens, temperature=temperature,
             request_id=request_id, timeout_s=timeout_s,
-            top_p=top_p, top_k=top_k, stop=stop,
+            top_p=top_p, top_k=top_k, stop=stop, received_ns=received_ns,
         )
-        return gen
+        return stream
 
     def update_params(self, params, version: Optional[int] = None) -> int:
         """Live weight swap without draining. Transfers the new tree to
@@ -2275,6 +2533,13 @@ class InferenceEngine:
     def stats(self) -> Dict[str, Any]:
         with self._ready_lock:
             ready = len(self._ready)
+        with self._chunk_lock:
+            chunk_queue = len(self._chunk_queue)
+            head = self._chunk_queue[0] if chunk_queue else None
+            # the head prompt's next chunk index of its total
+            chunking = ([head.next_chunk,
+                         -(-head.true_len // self.ecfg.prefill_chunk)]
+                        if head is not None else None)
         with self._alloc_lock:
             waiting = len(self._waiting)
             free_pages = self.allocator.num_free
@@ -2287,6 +2552,8 @@ class InferenceEngine:
             "active": len(self._active()),
             "pending": self.pending.qsize(),
             "ready": ready,
+            "chunk_queue": chunk_queue,
+            "chunking": chunking,
             "waiting_for_pages": waiting,
             "free_pages": free_pages + prefix.get("reusable_pages", 0),
             **prefix,
@@ -2331,8 +2598,7 @@ def _kv_layer_groups(L: int, groups: int = 4) -> List[tuple]:
     return out
 
 
-@jax.jit
-def _gather_pages_jit(k_pages, v_pages, page_arr):
+def gather_pages(k_pages, v_pages, page_arr):
     """pages[:, :, page_arr] -> token-contiguous [L, n*ps, KVH, hd].
     NOT donating: the pools stay live for the decode loop. Compiles per
     distinct page count — fine for the (host-bound) migration path."""
@@ -2343,8 +2609,7 @@ def _gather_pages_jit(k_pages, v_pages, page_arr):
     return k, v
 
 
-@functools.partial(jax.jit, static_argnums=(5, 6), donate_argnums=(0, 1))
-def _scatter_pages_jit(k_pages, v_pages, k, v, page_arr, n_full, ps):
+def scatter_pages(k_pages, v_pages, k, v, page_arr, n_full, ps):
     """k/v [L, Tpad, KVH, hd] -> pages[:, :, page_arr]."""
     L, Tpad, KVH, hd = k.shape
     kb = k[:, : n_full * ps].reshape(L, n_full, ps, KVH, hd).transpose(0, 3, 1, 2, 4)
@@ -2352,6 +2617,33 @@ def _scatter_pages_jit(k_pages, v_pages, k, v, page_arr, n_full, ps):
     k_pages = k_pages.at[:, :, page_arr].set(kb.astype(k_pages.dtype))
     v_pages = v_pages.at[:, :, page_arr].set(vb.astype(v_pages.dtype))
     return k_pages, v_pages
+
+
+_gather_pages_jit = jax.jit(gather_pages)
+_scatter_pages_jit = jax.jit(scatter_pages, static_argnums=(5, 6),
+                             donate_argnums=(0, 1))
+
+
+def _ffn(x, lp, cfg: ModelConfig):
+    """Second half of a block on the engine's hand-written decode, chunk
+    and verify bodies, under the scope names of models/transformer.py."""
+    with jax.named_scope("moe" if cfg.is_moe else "ffn"):
+        h = _norm(x, lp["ln2"], lp.get("ln2_b"), cfg)
+        if cfg.is_moe:
+            return _moe_ffn(h, lp, cfg)[0]
+        return _dense_ffn(h, lp, cfg)
+
+
+def _head_logits(x, pick, params, cfg: ModelConfig, einsum: str):
+    """Final norm of x [B,T,D], then the head in f32 (+ softcap) on the
+    rows `pick` keeps."""
+    x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg)
+    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    logits = jnp.einsum(einsum, pick(x).astype(jnp.float32),
+                        head.astype(jnp.float32))
+    if cfg.logits_softcap:
+        logits = cfg.logits_softcap * jnp.tanh(logits / cfg.logits_softcap)
+    return logits
 
 
 def prompt_page_fingerprints(prompt, page_size: int) -> List[str]:

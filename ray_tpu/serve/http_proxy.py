@@ -16,6 +16,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Dict, Optional
 
 from ..core.logging import get_logger
+from ..util import tracing
 
 logger = get_logger("serve.proxy")
 
@@ -74,6 +75,7 @@ class HTTPProxy:
                 return self._send(404, {"error": f"no route {self.path}"})
 
             def do_POST(self):
+                received_ns = tracing.now_ns()
                 parts = [p for p in self.path.split("/") if p]
                 # longest-prefix route match (route prefixes may span
                 # several segments, e.g. /api/v9); remaining segments map
@@ -90,18 +92,27 @@ class HTTPProxy:
                     payload = json.loads(raw) if raw.strip() else {}
                 except json.JSONDecodeError as e:
                     return self._send(400, {"error": f"bad json: {e}"})
-                try:
-                    result = handle.remote(payload).result(timeout=300.0)
-                    if _is_stream(result):
-                        return self._send_sse(
-                            result, getattr(result, "request_id", None))
-                    rid = (result.get("id")
-                           if isinstance(result, dict) else None)
-                    return self._send(200, {"result": _jsonable(result)},
-                                      request_id=rid)
-                except Exception as e:
-                    logger.warning("request failed", exc_info=True)
-                    return self._send(500, {"error": str(e)})
+                rid = ""
+                if isinstance(payload, dict):
+                    rid = str(payload.get("request_id") or "")
+                    # reserved key: the receipt instant rides with the
+                    # request, so the engine can say what the front added
+                    # before add_request (serve_front_seconds, inbound)
+                    payload["_received_ns"] = received_ns
+                with tracing.region("front.request", route=self.path,
+                                    request_id=rid):
+                    try:
+                        result = handle.remote(payload).result(timeout=300.0)
+                        if _is_stream(result):
+                            return self._send_sse(
+                                result, getattr(result, "request_id", None))
+                        rid = (result.get("id")
+                               if isinstance(result, dict) else None)
+                        return self._send(200, {"result": _jsonable(result)},
+                                          request_id=rid)
+                    except Exception as e:
+                        logger.warning("request failed", exc_info=True)
+                        return self._send(500, {"error": str(e)})
 
             def _send_sse(self, chunks, request_id: Optional[str] = None):
                 """Server-sent events: one `data:` line per chunk, then
@@ -112,12 +123,16 @@ class HTTPProxy:
                 if request_id:
                     self.send_header("X-Request-Id", str(request_id))
                 self.end_headers()
+                first = True
                 try:
                     try:
                         for chunk in chunks:
                             data = json.dumps(_jsonable(chunk))
                             self.wfile.write(f"data: {data}\n\n".encode())
                             self.wfile.flush()
+                            if first:
+                                first = False
+                                _note_first_chunk(chunks)
                     except (BrokenPipeError, ConnectionResetError):
                         raise  # client went away: outer handler, no spam
                     except Exception as e:  # noqa: BLE001
@@ -154,6 +169,17 @@ class HTTPProxy:
             self._server.shutdown()
             self._server.server_close()
             self._server = None
+
+
+def _note_first_chunk(chunks: Any) -> None:
+    """serve_front_seconds{leg="outbound"}: a stream that knows its
+    engine's first-token instant (engine.TokenStream) says how long the
+    first chunk took from there to the wire."""
+    first_token_ns = getattr(chunks, "first_token_ns", None)
+    if first_token_ns is not None:
+        from .engine import observe_front_outbound
+
+        observe_front_outbound(first_token_ns)
 
 
 def _is_stream(x: Any) -> bool:

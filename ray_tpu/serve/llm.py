@@ -125,6 +125,7 @@ class LLMServer:
             top_k=int(request.get("top_k", 0)),
             stop=request.get("stop_token_ids"),
             request_id=request.get("request_id"),
+            received_ns=request.get("_received_ns"),
         )
 
     def stream(self, request: Dict[str, Any]):
@@ -138,6 +139,7 @@ class LLMServer:
             top_k=int(request.get("top_k", 0)),
             stop=request.get("stop_token_ids"),
             request_id=request.get("request_id"),
+            received_ns=request.get("_received_ns"),
         )
 
     # ---------------------------------------------------------- disagg

@@ -62,7 +62,6 @@ is position-bounded) until overwritten.
 from __future__ import annotations
 
 import functools
-import time
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -71,9 +70,10 @@ import numpy as np
 
 from ..core.config import config
 from ..core.logging import get_logger
-from ..core.metrics import Counter, Gauge
+from ..core.metrics import Gauge
 from ..models import get_config, init_params
 from ..models.transformer import _dense_ffn, _embed_lookup, _moe_ffn, _norm
+from ..util import tracing
 from ..ops import (
     apply_rope,
     paged_attention_chunk,
@@ -85,12 +85,6 @@ from .config import SpeculationConfig
 
 logger = get_logger("serve.spec_decode")
 
-_m_spec_proposed = Counter(
-    "serve_spec_proposed_tokens",
-    "Draft tokens proposed to the verify forward.")
-_m_spec_accepted = Counter(
-    "serve_spec_accepted_tokens",
-    "Draft tokens accepted by the verify forward.")
 _m_spec_accept_rate = Gauge(
     "serve_spec_acceptance_rate",
     "Cumulative accepted/proposed draft-token ratio.")
@@ -709,7 +703,9 @@ class SpecDecoder:
         def for_mode(advanced: bool):
             if advanced not in cache:
                 cache[advanced] = eng._under_mesh(jax.jit(
-                    functools.partial(verify, advanced=advanced),
+                    tracing.named(
+                        functools.partial(verify, advanced=advanced),
+                        f"verify_{self.k}" + ("_adv" if advanced else "")),
                     donate_argnums=(1, 2)))
             return cache[advanced]
 
@@ -786,16 +782,19 @@ class SpecDecoder:
         plain decode span instead, which commits span tokens at plain
         cost where the S-wide verify would commit exactly one."""
         eng = self.engine
-        t0 = time.monotonic()
+        decode_phase = eng.phase
         wait = compute = 0.0
-        pf = (self.proposer.take_prefetch(eng, positions)
-              if self.overlap else None)
+        with decode_phase("propose_wait") as ph:
+            pf = (self.proposer.take_prefetch(eng, positions)
+                  if self.overlap else None)
         if pf is not None:
             drafts, n_prop = pf
-            wait = time.monotonic() - t0
+            wait = ph.elapsed_s
         else:
-            drafts, n_prop = self.proposer.propose(eng, tokens, positions)
-            compute = time.monotonic() - t0
+            with decode_phase("propose") as ph:
+                drafts, n_prop = self.proposer.propose(eng, tokens,
+                                                       positions)
+            compute = ph.elapsed_s
         n_draft = np.minimum(n_prop, caps).astype(np.int32)
         if getattr(self.proposer, "cheap", False) and not n_draft.any():
             return None, None, n_draft, {
@@ -807,41 +806,40 @@ class SpecDecoder:
         # Floor of 1 draft row: K=0 would make the accept op's rejected-
         # draft gather degenerate (an all-zero-cap round still verifies
         # one draft row it then ignores via n_draft=0)
-        m = max(1, self._pick_span(n_draft, caps))
-        n_draft = np.minimum(n_draft, m)
-        if isinstance(drafts, np.ndarray):
-            toks_bs = jnp.asarray(
-                np.concatenate([tokens[:, None], drafts[:, :m]], axis=1))
-        else:
-            toks_bs = jnp.concatenate(
-                [jnp.asarray(tokens)[:, None], drafts[:, :m]], axis=1)
-        t1 = time.monotonic()
-        committed, n_comm, eng.k_pages, eng.v_pages = self._verify(advanced)(
-            eng.params, eng.k_pages, eng.v_pages, toks_bs,
-            jnp.asarray(positions), jnp.asarray(tables),
-            jnp.asarray(n_draft), jnp.asarray(temps),
-            jnp.asarray(top_ps), jnp.asarray(top_ks), key)
-        t2 = time.monotonic()
-        committed = np.asarray(committed)
-        n_comm = np.asarray(n_comm)
-        t3 = time.monotonic()
+        with decode_phase("build"):
+            m = max(1, self._pick_span(n_draft, caps))
+            n_draft = np.minimum(n_draft, m)
+            if isinstance(drafts, np.ndarray):
+                toks_bs = jnp.asarray(
+                    np.concatenate([tokens[:, None], drafts[:, :m]], axis=1))
+            else:
+                toks_bs = jnp.concatenate(
+                    [jnp.asarray(tokens)[:, None], drafts[:, :m]], axis=1)
+        with decode_phase("dispatch") as verify:
+            committed, n_comm, eng.k_pages, eng.v_pages = self._verify(
+                advanced)(
+                eng.params, eng.k_pages, eng.v_pages, toks_bs,
+                jnp.asarray(positions), jnp.asarray(tables),
+                jnp.asarray(n_draft), jnp.asarray(temps),
+                jnp.asarray(top_ps), jnp.asarray(top_ks), key)
+        with decode_phase("readback") as readback:
+            committed = np.asarray(committed)
+            n_comm = np.asarray(n_comm)
         if self.overlap:
             # dispatch next round's propose NOW: it executes on device
             # while the engine runs its host-side commit loop
-            self.proposer.prefetch(eng, tokens, positions, committed, n_comm)
-            compute += time.monotonic() - t3
+            with decode_phase("propose") as ph:
+                self.proposer.prefetch(eng, tokens, positions, committed,
+                                       n_comm)
+            compute += ph.elapsed_s
         return committed, n_comm, n_draft, {
             "propose_wait": wait, "propose_compute": compute,
             "propose": wait + compute,
-            "verify": t2 - t1, "sample": t3 - t2}
+            "verify": verify.elapsed_s, "sample": readback.elapsed_s}
 
     def record(self, proposed: int, accepted: int) -> None:
         self.proposed_total += int(proposed)
         self.accepted_total += int(accepted)
-        if proposed:
-            _m_spec_proposed.inc(proposed)
-            if accepted:
-                _m_spec_accepted.inc(accepted)
         if self.proposed_total:
             _m_spec_accept_rate.set(
                 self.accepted_total / self.proposed_total)

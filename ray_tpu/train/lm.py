@@ -17,6 +17,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..models import ModelConfig, init_params, loss_fn, param_axes
 from ..parallel.sharding import sharding_for, tree_shardings
+from ..util import tracing
 
 TrainState = Dict[str, Any]  # {"step", "params", "opt_state"}
 
@@ -150,10 +151,11 @@ def make_train_step(cfg: ModelConfig, optimizer: optax.GradientTransformation,
             return loss_fn(params, batch, cfg, forward_fn=forward_fn)
 
         (_, metrics), grads = jax.value_and_grad(lossf, has_aux=True)(state["params"])
-        updates, new_opt = optimizer.update(
-            grads, state["opt_state"], state["params"]
-        )
-        new_params = optax.apply_updates(state["params"], updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(
+                grads, state["opt_state"], state["params"]
+            )
+            new_params = optax.apply_updates(state["params"], updates)
         metrics = dict(metrics)
         metrics["grad_norm"] = optax.global_norm(grads)
         metrics["step"] = state["step"]
@@ -220,4 +222,5 @@ def make_global_batch(batch: Dict[str, Any], shardings: Dict[str, Any]):
         x = np.asarray(x)
         return jax.make_array_from_callback(x.shape, s, lambda idx: x[idx])
 
-    return jax.tree.map(put, batch, shardings)
+    with tracing.region("train.place_batch"):
+        return jax.tree.map(put, batch, shardings)

@@ -66,15 +66,17 @@ class _TrainSession:
         self.finished = False
 
     def report(self, metrics: Dict[str, Any], checkpoint: Optional[Checkpoint] = None):
-        from ..util import timeline
+        from ..util import timeline, tracing
 
-        timeline.record(
-            "train/report", "i", cat="train", pid="train",
-            tid=f"rank{self.context.world_rank}",
-            args={k: v for k, v in metrics.items()
-                  if isinstance(v, (int, float, str))},
-        )
-        self._reports.put(_Report(dict(metrics), checkpoint, self.context.world_rank))
+        with tracing.region("train.report"):
+            timeline.record(
+                "train/report", "i", cat="train", pid="train",
+                tid=f"rank{self.context.world_rank}",
+                args={k: v for k, v in metrics.items()
+                      if isinstance(v, (int, float, str))},
+            )
+            self._reports.put(_Report(dict(metrics), checkpoint,
+                                      self.context.world_rank))
 
     def drain(self) -> List[_Report]:
         out = []

@@ -24,15 +24,15 @@ from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
 from ..core.config import config
+from . import tracing
 
 _lock = threading.Lock()
 _events: "deque[Dict[str, Any]]" = deque(maxlen=10_000)
 _total = 0  # events ever recorded (monotone; the ring may have dropped some)
-_t0_us = time.time() * 1e6 - time.perf_counter() * 1e6
 
 
 def _now_us() -> float:
-    return _t0_us + time.perf_counter() * 1e6
+    return tracing.now_ns() / 1e3  # the one clock spans and regions use
 
 
 def configure() -> None:

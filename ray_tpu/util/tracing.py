@@ -46,17 +46,31 @@ wrap the body in try/finally.
 Propagation is on only while a span is active — zero overhead otherwise
 (the spec field stays None). Serve entry points additionally open root
 spans for a `config.trace_sample_rate` fraction of requests (default 0:
-off, the zero-overhead fast path)."""
+off, the zero-overhead fast path).
+
+Hot paths (the engine's loop phases, the data iterator's wait, the
+trainer's batch placement) use `region(name, **attrs)` instead of a
+span: it times its body ONCE on `now_ns()` — the one clock `Span` and
+`util/timeline.py` share — and hands the reading to the caller's
+counter (`r.elapsed_s`). While a JAX profiler session is open the region
+is also a `jax.profiler.TraceAnnotation`, i.e. an event on the calling
+thread's line of the xplane's `/host:CPU` plane, on the device's clock
+by construction; while the thread carries a (sampled or propagated)
+span it is a child `Span` in the buffer. With neither it costs a flag
+test and two clock reads. A backend compile inside a region is counted
+as `xla_compiles{under=<region name>}` (see `_on_jax_duration`)."""
 
 from __future__ import annotations
 
 import os
 import random
+import sys
 import threading
 import time
-import uuid
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional, Tuple
+
+from ..core.metrics import Counter
 
 _local = threading.local()
 _lock = threading.Lock()
@@ -68,8 +82,26 @@ _MAX_SPANS = 10_000
 _flight_sink = None
 
 
-def _now_us() -> float:
-    return time.time() * 1e6
+# The one clock of spans, regions and the timeline: monotonic
+# (perf_counter), anchored to the wall once per process so records from
+# different processes line up to wall-clock accuracy and never step.
+_ANCHOR_NS = time.time_ns() - time.perf_counter_ns()
+
+
+def now_ns() -> int:
+    return _ANCHOR_NS + time.perf_counter_ns()
+
+
+# Ids: 128/64 random bits from a per-process generator (a uuid4 per id
+# cost two urandom syscalls a span); a forked child draws its own seed.
+_ids = random.Random(os.urandom(16))
+os.register_at_fork(after_in_child=lambda: _ids.seed(os.urandom(16)))
+
+_m_compiles = Counter(
+    "xla_compiles",
+    "XLA backend compilations (a persistent-cache load counts: it raises "
+    "the same jax event), by the innermost tracing.region open on the "
+    "compiling thread (`under`, or \"none\").")
 
 
 class Span:
@@ -78,23 +110,24 @@ class Span:
 
     def __init__(self, name: str, trace_id: Optional[str] = None,
                  parent_id: Optional[str] = None,
-                 attrs: Optional[Dict[str, Any]] = None):
-        self.trace_id = trace_id or uuid.uuid4().hex
-        self.span_id = uuid.uuid4().hex[:16]
+                 attrs: Optional[Dict[str, Any]] = None,
+                 start_ns: Optional[int] = None):
+        self.trace_id = trace_id or "%032x" % _ids.getrandbits(128)
+        self.span_id = "%016x" % _ids.getrandbits(64)
         self.parent_id = parent_id
         self.name = name
         self.attrs = dict(attrs or {})
-        self.start_us = _now_us()
+        self.start_us = (now_ns() if start_ns is None else start_ns) / 1e3
         self.end_us: Optional[float] = None
 
     def context(self) -> Dict[str, str]:
         """The wire form (W3C traceparent shape, dict-framed)."""
         return {"trace_id": self.trace_id, "span_id": self.span_id}
 
-    def finish(self) -> None:
+    def finish(self, end_ns: Optional[int] = None) -> None:
         if self.end_us is not None:
             return  # idempotent: stream teardown paths may race
-        self.end_us = _now_us()
+        self.end_us = (now_ns() if end_ns is None else end_ns) / 1e3
         rec = {
             "trace_id": self.trace_id, "span_id": self.span_id,
             "parent_id": self.parent_id, "name": self.name,
@@ -131,6 +164,114 @@ class _RemoteParent:
 
 def current_span() -> Optional[Span]:
     return getattr(_local, "span", None)
+
+
+def record_child(parent, name: str, start_ns: int, end_ns: int,
+                 attrs: Optional[Dict[str, Any]] = None) -> None:
+    """Buffer a finished child of `parent` (a Span or remote context
+    object) from two readings the caller already took — how an owner
+    that stamps instants (the engine's per-request stages) turns them
+    into spans without timing anything twice."""
+    Span(name, trace_id=parent.trace_id, parent_id=parent.span_id,
+         attrs=attrs, start_ns=start_ns).finish(end_ns)
+
+
+def named(fn, name: str):
+    """`fn` under a function name. jax names a jit's XLA module after the
+    traced function (`jit_<name>` in a profile); a functools.partial has
+    none and every such program reads `jit__unknown`."""
+
+    def call(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    call.__name__ = call.__qualname__ = name
+    return call
+
+
+# jax.profiler.TraceAnnotation once jax is imported (never imported from
+# here: a process that runs no jax pays nothing for it)
+_annotation = None
+
+
+def _resolve_annotation():
+    global _annotation
+    if "jax" not in sys.modules:
+        return None
+    import jax
+
+    with _lock:
+        if _annotation is None:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_jax_duration)
+            _annotation = jax.profiler.TraceAnnotation
+    return _annotation
+
+
+def _on_jax_duration(event: str, seconds: float, **_kw: Any) -> None:
+    """A backend compile on this thread: count it under the innermost
+    open region, and put an `xla.compile` child span into a traced
+    request's tree — "which step recompiled" as a counter and a span."""
+    if event != "/jax/core/compile/backend_compile_duration":
+        return
+    inner = getattr(_local, "region", None)
+    _m_compiles.inc(tags={"under": inner.name if inner is not None
+                          else "none"})
+    parent = getattr(_local, "span", None)
+    if parent is not None:
+        end = now_ns()
+        record_child(parent, "xla.compile", end - int(seconds * 1e9), end)
+
+
+class region:
+    """Time the body once; see the module docstring for the sinks.
+
+        with tracing.region("engine.dispatch", span=16) as r:
+            ...
+        histogram_child.observe(r.elapsed_s)
+    """
+
+    __slots__ = ("name", "attrs", "start_ns", "elapsed_ns", "_outer",
+                 "_ann", "_span", "_parent")
+
+    def __init__(self, name: str, **attrs: Any):
+        self.name = name
+        self.attrs = attrs
+        self.elapsed_ns = 0
+
+    def __enter__(self) -> "region":
+        loc = _local
+        self._outer = getattr(loc, "region", None)
+        loc.region = self
+        ann = _annotation or _resolve_annotation()
+        if ann is not None and ann.is_enabled():  # a profiler session is open
+            ann = ann(self.name, **self.attrs)
+            ann.__enter__()
+        else:
+            ann = None
+        self._ann = ann
+        parent = self._parent = getattr(loc, "span", None)
+        self.start_ns = now_ns()
+        if parent is not None:
+            loc.span = self._span = Span(
+                self.name, trace_id=parent.trace_id,
+                parent_id=parent.span_id, attrs=self.attrs,
+                start_ns=self.start_ns)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        end = now_ns()
+        self.elapsed_ns = end - self.start_ns
+        if self._parent is not None:
+            self._span.finish(end)
+            _local.span = self._parent
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        _local.region = self._outer
+        return False
+
+    @property
+    def elapsed_s(self) -> float:
+        return self.elapsed_ns * 1e-9
 
 
 def current_context() -> Optional[Dict[str, str]]:

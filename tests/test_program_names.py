@@ -1,0 +1,170 @@
+"""Names on the device side: every program the engine and the trainer jit
+has a module name that says what it is, every Pallas kernel a `name=`, and
+the parts of a block a `named_scope`. Lowered on the CPU; a profile on the
+chip shows the same names (PERF.md section 3)."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.models import get_config, init_params
+from ray_tpu.serve.engine import (EngineConfig, InferenceEngine,
+                                  _gather_pages_jit, _scatter_pages_jit)
+
+B = 4
+
+
+def _scoped(text: str, scope: str) -> bool:
+    """`scope` is a component of some location's name stack (under a
+    gradient the forward's scopes read `jvp(scope)`, the backward's
+    `transpose(jvp(scope))`)."""
+    return re.search(r'loc\("(?:[^"]*[/(])?%s[/")]' % re.escape(scope),
+                     text) is not None
+
+
+def _module(lowered) -> str:
+    (name,) = re.findall(r"module @(\S+)", lowered.as_text())
+    return name
+
+
+def _pallas_names(fn, *args):
+    names = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.append(eqn.params["name"])
+                continue
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (list, tuple)) else [value]:
+                    inner = getattr(sub, "jaxpr", None)
+                    if inner is not None:
+                        walk(getattr(inner, "jaxpr", inner))
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return names
+
+
+@pytest.fixture(scope="module", params=["tiny-llama", "tiny-moe"])
+def engine(request):
+    cfg = get_config(request.param)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    return InferenceEngine(params, cfg, EngineConfig(
+        max_batch_size=B, max_pages=64, max_seq_len=160,
+        prefill_buckets=(16, 32), prefill_chunk=32,
+        speculation={"mode": "ngram", "num_speculative_tokens": 3}))
+
+
+def _decode_args(eng):
+    pps = eng.ecfg.pages_per_seq
+    return (eng.params, eng.k_pages, eng.v_pages, jnp.zeros((B,), jnp.int32),
+            jnp.zeros((B,), jnp.int32), jnp.zeros((B, pps), jnp.int32),
+            jnp.zeros((B,), jnp.float32), jnp.ones((B,), jnp.float32),
+            jnp.zeros((B,), jnp.int32), jax.random.PRNGKey(0))
+
+
+def _chunk_args(eng, C):
+    return (eng.params, eng.k_pages, eng.v_pages, jnp.zeros((C,), jnp.int32),
+            jnp.int32(0), jnp.zeros((eng.ecfg.pages_per_seq,), jnp.int32),
+            jnp.int32(C - 1))
+
+
+def test_engine_programs_have_module_names_and_scopes(engine):
+    ffn = "moe" if engine.cfg.is_moe else "ffn"
+    for span, adv, want in ((4, False, "jit_decode_span_4"),
+                            (16, True, "jit_decode_span_16_adv")):
+        low = engine._decode(span, adv).lower(*_decode_args(engine))
+        assert _module(low) == want
+    text = low.as_text(debug_info=True)
+    for scope in ("embed", "attn", "kv_write", ffn, "lm_head", "sample"):
+        assert _scoped(text, scope), scope
+    assert re.search(r'loc\("(?:[^"]*/)?attn/kv_write/', text)
+    if engine.cfg.is_moe:
+        for scope in ("route", "dispatch", "experts", "combine"):
+            assert re.search(r'loc\("(?:[^"]*/)?moe/%s[/"]' % scope, text)
+    for export, want in ((False, "jit_chunk_prefill_32"),
+                         (True, "jit_chunk_prefill_32_export")):
+        low = engine._chunk_fn(32, export).lower(*_chunk_args(engine, 32))
+        assert _module(low) == want
+    text = low.as_text(debug_info=True)
+    for scope in ("embed", "attn", "kv_write", ffn, "lm_head"):
+        assert _scoped(text, scope), scope
+    # the bucket program keeps the module name the benchmark keys on and
+    # carries its shape class as a scope
+    low = engine._prefill_fn(16, 2).lower(
+        engine.params, jnp.ones((2, 16), jnp.int32), jnp.ones((2,), jnp.int32))
+    assert _module(low) == "jit_run"
+    text = low.as_text(debug_info=True)
+    for scope in ("prefill_bucket_16x2", "embed", "attn", ffn, "lm_head"):
+        assert _scoped(text, scope), scope
+
+
+def test_page_and_verify_programs_have_module_names(engine):
+    cache = jnp.zeros((engine.cfg.n_layers, 32, engine.cfg.kv_heads,
+                       engine.cfg.hdim), engine.k_pages.dtype)
+    pages = jnp.arange(1, 3, dtype=jnp.int32)
+    low = _scatter_pages_jit.lower(engine.k_pages, engine.v_pages, cache,
+                                   cache, pages, 2, engine.ecfg.page_size)
+    assert _module(low) == "jit_scatter_pages"
+    low = _gather_pages_jit.lower(engine.k_pages, engine.v_pages, pages)
+    assert _module(low) == "jit_gather_pages"
+    pps = engine.ecfg.pages_per_seq
+    low = engine._spec._verify(False).lower(
+        engine.params, engine.k_pages, engine.v_pages,
+        jnp.zeros((B, 4), jnp.int32), jnp.zeros((B,), jnp.int32),
+        jnp.zeros((B, pps), jnp.int32), jnp.zeros((B,), jnp.int32),
+        jnp.zeros((B,), jnp.float32), jnp.ones((B,), jnp.float32),
+        jnp.zeros((B,), jnp.int32), jax.random.PRNGKey(0))
+    assert _module(low) == "jit_verify_3"
+
+
+def test_the_train_step_keeps_its_module_name_and_gains_scopes():
+    from ray_tpu.train.lm import make_optimizer, make_train_step
+
+    cfg = get_config("tiny-moe")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    opt = make_optimizer(learning_rate=1e-3, warmup_steps=1, total_steps=10)
+    state = {"step": jnp.zeros((), jnp.int32), "params": params,
+             "opt_state": opt.init(params)}
+    toks = jnp.ones((2, 32), jnp.int32)
+    low = jax.jit(make_train_step(cfg, opt)).lower(
+        state, {"tokens": toks, "targets": toks})
+    assert _module(low) == "jit_step"  # the benchmark's `train_step` group
+    text = low.as_text(debug_info=True)
+    for scope in ("embed", "attn", "moe", "route", "dispatch", "experts",
+                  "combine", "lm_head", "loss", "optimizer"):
+        assert _scoped(text, scope), scope
+    # the backward pass carries the forward's scopes
+    assert re.search(r'loc\("[^"]*transpose\(jvp\(lm_head\)\)/', text)
+
+
+def test_every_pallas_kernel_is_named():
+    from ray_tpu.ops import (flash_attention, paged_attention_chunk,
+                             paged_attention_decode, paged_attention_verify,
+                             rms_norm)
+
+    q = jnp.ones((1, 128, 2, 128), jnp.bfloat16)
+    kv = jnp.ones((1, 128, 1, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).sum().astype(jnp.float32)
+
+    assert _pallas_names(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv) == [
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
+    assert _pallas_names(lambda x, w: rms_norm(x, w, eps=1e-5),
+                         jnp.ones((8, 128)), jnp.ones((128,))) == ["rms_norm"]
+    pages = jnp.ones((1, 9, 16, 128), jnp.bfloat16)  # [KVH, P, ps, hd]
+    table = jnp.zeros((2, 4), jnp.int32)
+    assert _pallas_names(
+        lambda q: paged_attention_decode(q, pages, pages, table,
+                                         jnp.ones((2,), jnp.int32)),
+        jnp.ones((2, 2, 128), jnp.bfloat16)) == ["paged_decode"]
+    assert _pallas_names(
+        lambda q: paged_attention_chunk(q, pages, pages, table[0], 0, 16),
+        jnp.ones((16, 2, 128), jnp.bfloat16)) == ["paged_chunk"]
+    assert _pallas_names(
+        lambda q: paged_attention_verify(q, pages, pages, table,
+                                         jnp.zeros((2,), jnp.int32)),
+        jnp.ones((2, 3, 2, 128), jnp.bfloat16)) == ["paged_verify"]
