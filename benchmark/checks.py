@@ -1,9 +1,9 @@
 """How `correct` is decided: the program's outputs against the plain
-reference (benchmark/reference/model.py), outside the timed window, on the
-run's own seeded weights. Every number compared is printed beside its
-limit; the limits are data in the cell's file (`check.limits`), each set
-from the two readings PERF.md gives: the largest that sound runs gave and
-the smallest that the control gave.
+reference of the configuration's family (benchmark/families/), outside the
+timed window, on the run's own seeded weights. Every number compared is
+printed beside its limit; the limits are data in the cell's file
+(`check.limits`), each set from the two readings PERF.md gives: the largest
+that sound runs gave and the smallest that the control gave.
 
 The numbers are chosen to be steady from seed to seed and to move with
 precision: per-position quantities reduced by a root mean square, never a
@@ -16,9 +16,6 @@ from typing import Any, Dict, List, Sequence
 import numpy as np
 
 from . import common, weights
-from .reference import model as ref
-
-PAD_TO = ref.Q_BLOCK
 
 
 def judge(numbers: Dict[str, float], limits: Dict[str, float]) -> bool:
@@ -78,11 +75,13 @@ def reference_logits(params, spec, prompt: Sequence[int],
     the positions that predict each output token."""
     import jax.numpy as jnp
 
+    family = common.family(spec)
     seq = list(prompt) + list(output)
-    padded = np.zeros((-(-len(seq) // PAD_TO) * PAD_TO,), np.int32)
+    padded = np.zeros((-(-len(seq) // family.PAD_TO) * family.PAD_TO,),
+                      np.int32)
     padded[: len(seq)] = seq  # right padding is invisible to causal attention
     at = len(prompt) - 1 + np.arange(len(output))
-    return np.asarray(ref.logits_at(
+    return np.asarray(family.logits_at(
         params, jnp.asarray(padded), jnp.asarray(at), spec, mode))
 
 
@@ -113,32 +112,6 @@ def train_numbers(nll, grads, ref_nll, ref_grads) -> Dict[str, float]:
             "grad_rel_err": float(np.linalg.norm(g - rg) / np.linalg.norm(rg))}
 
 
-def program_probe(cfg, params, tokens, targets):
-    """The program's own forward and backward (models.forward, the function
-    the train step differentiates: flash kernels, remat, bf16) on one row:
-    per-position negative log-likelihood [T], and the gradient of its mean
-    with respect to every layer's first norm weight [L, D]."""
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu.models import forward
-
-    def probe(params, tokens, targets):
-        def mean_nll(ln1):
-            p = {**params, "layers": {**params["layers"], "ln1": ln1}}
-            logits, _ = forward(p, tokens[None], cfg)
-            lse = jax.scipy.special.logsumexp(logits[0], axis=-1)
-            picked = jnp.take_along_axis(logits[0], targets[:, None], -1)[:, 0]
-            nll = lse - picked
-            return jnp.mean(nll), nll
-
-        ln1 = params["layers"]["ln1"].astype(jnp.float32)
-        (_, nll), g = jax.value_and_grad(mean_nll, has_aux=True)(ln1)
-        return nll, g
-
-    return jax.jit(probe)(params, tokens, targets)
-
-
 def train(cell: Dict[str, Any], seed: int, first_batch: np.ndarray,
           first_metrics: Dict[str, float]) -> bool:
     """first_batch [rows, T + 1]; first_metrics: what the step reported for
@@ -146,14 +119,16 @@ def train(cell: Dict[str, Any], seed: int, first_batch: np.ndarray,
     import jax.numpy as jnp
 
     spec = cell["config"]
-    cfg = weights.model_config(spec)
+    family = common.family(spec)
+    cfg = family.model_config(spec)
     params = weights.make_weights(spec, seed)
     rows = first_batch[: cell["check"]["rows"]]
     numbers, ref_means = [], []
     for row in rows:
         tokens, targets = jnp.asarray(row[:-1]), jnp.asarray(row[1:])
-        nll, g = program_probe(cfg, params, tokens, targets)
-        ref_nll, ref_g = ref.nll_and_norm_grads(params, tokens, targets, spec)
+        nll, g = family.program_probe(cfg, params, tokens, targets)
+        ref_nll, ref_g = family.nll_and_norm_grads(params, tokens, targets,
+                                                   spec)
         numbers.append(train_numbers(nll, g, ref_nll, ref_g))
         ref_means.append(float(jnp.mean(ref_nll)))
     out = {k: max(n[k] for n in numbers) for k in numbers[0]}

@@ -1,14 +1,17 @@
 """What every driver of the benchmark shares: finding a cell's files by the
-names in BENCHMARK.json, the device check, the compile watcher, the result
-line, and leaving no process behind."""
+names in BENCHMARK.json, finding a configuration's family by the path in its
+file, the device check, the compile watcher, the result line, and leaving no
+process behind."""
 
 from __future__ import annotations
 
 import ctypes
+import functools
 import importlib.util
 import json
 import os
 import time
+from types import ModuleType
 from typing import Any, Dict, List, Optional
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -32,23 +35,32 @@ def load_manifest() -> Dict[str, Any]:
         return json.load(f)
 
 
-def load_cell(name: str) -> Dict[str, Any]:
+def load_cell(name: str, manifest: Optional[Dict[str, Any]] = None,
+              tree: str = HERE) -> Dict[str, Any]:
     """One entry of BENCHMARK.json's `workloads`, joined with the files it
     names: the cell's own file, its configuration, its traffic mix and the
-    metrics that list it."""
-    manifest = load_manifest()
+    metrics that list it. The configuration's family is loaded here, before
+    anything is timed. `manifest` and `tree` are for tests: a manifest of
+    their own, and the directory that holds its workloads/ and traffic/."""
+    manifest = manifest or load_manifest()
     entry = next((w for w in manifest["workloads"] if w["name"] == name), None)
     if entry is None:
         raise BenchFailure(f"no workload {name!r} in BENCHMARK.json; have "
                            f"{[w['name'] for w in manifest['workloads']]}")
     config_entry = next(c for c in manifest["configs"]
                         if c["name"] == entry["config"])
-    with open(os.path.join(ROOT, config_entry["file"])) as f:
-        config = json.load(f)
-    cell = dict(load_json("workloads", name + ".json"))
+
+    def read(*parts: str) -> Dict[str, Any]:
+        with open(os.path.join(*parts)) as f:
+            return json.load(f)
+
+    config = read(ROOT, config_entry["file"])
+    cell = read(tree, "workloads", name + ".json")
+    _holds(family(config),
+           FAMILY_HOLDS_TO_TRAIN if cell["kind"] == "train" else ())
     cell.update(name=name, chips=entry["chips"], config_name=entry["config"],
                 config=config, traffic_name=entry["traffic"],
-                traffic=load_json("traffic", entry["traffic"] + ".json"))
+                traffic=read(tree, "traffic", entry["traffic"] + ".json"))
 
     def listed(metric):
         return name in metric.get("workloads", [name])
@@ -58,15 +70,57 @@ def load_cell(name: str) -> Dict[str, Any]:
     return cell
 
 
-def load_reader(metric_name: str):
-    """benchmark/metrics/<name>.py, which holds `read(ctx) -> float | None`."""
-    path = os.path.join(HERE, "metrics", metric_name + ".py")
+def _load_file(kind: str, name: str, path: str) -> ModuleType:
     spec = importlib.util.spec_from_file_location(
-        "benchmark_metric_" + metric_name.replace(".", "_").replace("-", "_"),
-        path)
+        f"benchmark_{kind}_" + name.replace(".", "_").replace("-", "_"), path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return module
+
+
+def load_reader(metric_name: str):
+    """benchmark/metrics/<name>.py, which holds `read(ctx) -> float | None`."""
+    return _load_file("metric", metric_name, os.path.join(
+        HERE, "metrics", metric_name + ".py")).read
+
+
+# what a family's file has to hold (benchmark/families/mistral.py states the
+# contract), and what more where a train cell uses it
+FAMILY_HOLDS = ("model_config", "init_weights", "PAD_TO", "logits_at",
+                "modes", "work", "calls_per_pass", "tiny")
+FAMILY_HOLDS_TO_TRAIN = ("nll_and_norm_grads", "program_probe",
+                         "train_flops_per_token")
+
+
+def _holds(module: ModuleType, names) -> None:
+    missing = [a for a in names if not hasattr(module, a)]
+    if missing:
+        raise BenchFailure(f"family file {module.__file__} lacks {missing}")
+
+
+@functools.lru_cache(maxsize=None)
+def load_family(path: str) -> ModuleType:
+    """The family's file, by its path from the repo's root: how the
+    benchmark reaches a model of that family."""
+    full = os.path.join(ROOT, path)
+    if not os.path.isfile(full):
+        folder = os.path.dirname(full)
+        found = sorted(f for f in os.listdir(folder) if f.endswith(".py")) \
+            if os.path.isdir(folder) else []
+        raise BenchFailure(f"no family file {path}; {os.path.dirname(path)}/ "
+                           f"has {found}")
+    module = _load_file("family", os.path.basename(path)[:-3], full)
+    _holds(module, FAMILY_HOLDS)
+    return module
+
+
+def family(spec: Dict[str, Any]) -> ModuleType:
+    """The family whose file the configuration's own file names
+    (`"family"`, as it names its `"reference"`)."""
+    if "family" not in spec:
+        raise BenchFailure("the configuration's file names no \"family\"; "
+                           "see benchmark/families/")
+    return load_family(spec["family"])
 
 
 def peaks_for(kind: str) -> Dict[str, float]:

@@ -76,7 +76,8 @@ def measure(cell: Dict[str, Any], args, device: Dict[str, Any], watch,
 
 
 def per_layer(cell, out, device) -> Dict[str, Dict[str, Any]]:
-    ctx = {"cell": cell, "spec": cell["config"], "chips": cell["chips"],
+    ctx = {"cell": cell, "spec": cell["config"],
+           "family": common.family(cell["config"]), "chips": cell["chips"],
            "peaks": common.peaks_for(device["kind"]), "run": out["run"],
            "trace": out["trace"], "counters": out.get("counters")}
     metrics = {}

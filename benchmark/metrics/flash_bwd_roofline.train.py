@@ -12,6 +12,7 @@ def read(ctx):
     if not seconds or not calls:
         return None
     mix = ctx["cell"]["traffic"]
-    work = flops.flash_backward(ctx["spec"], mix["rows_per_step"], mix["row_tokens"])
+    work = ctx["family"].work["flash_bwd"](
+        ctx["spec"], mix["rows_per_step"], mix["row_tokens"])
     ideal = flops.roofline_seconds(work, ctx["peaks"])["seconds"]
     return 100.0 * ideal * calls / seconds

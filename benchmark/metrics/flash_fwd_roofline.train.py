@@ -1,7 +1,7 @@
 """The flash-attention forward kernel's share of its roofline in the train
 step: the least time the chip could take for the calls the trace holds
-(operations and bytes from shapes, benchmark/flops.py) over the time they
-took. Compute-bound at these shapes."""
+(operations and bytes from shapes, by the configuration's family) over the
+time they took. Compute-bound at these shapes."""
 
 from benchmark import flops, trace_reduce
 
@@ -11,6 +11,7 @@ def read(ctx):
     if not seconds:
         return None
     mix = ctx["cell"]["traffic"]
-    work = flops.flash_forward(ctx["spec"], mix["rows_per_step"], mix["row_tokens"])
+    work = ctx["family"].work["flash_fwd"](
+        ctx["spec"], mix["rows_per_step"], mix["row_tokens"])
     ideal = flops.roofline_seconds(work, ctx["peaks"])["seconds"]
     return 100.0 * ideal * calls / seconds

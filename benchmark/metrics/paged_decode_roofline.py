@@ -2,7 +2,8 @@
 (it reads every cached key and value once). The cached tokens read are
 counted from the client's records: a token j of a request with prompt p,
 arriving inside the traced part, was one decode step over p + j cached
-tokens, in every layer. Bytes and operations by benchmark/flops.py."""
+tokens, in every layer that calls the kernel. Bytes and operations of a
+call, and the calls of a step, by the configuration's family."""
 
 from benchmark import flops, trace_reduce
 
@@ -20,6 +21,8 @@ def read(ctx):
                 context += q["prompt_len"] + j
     if not context:
         return None
-    work = flops.paged_decode(ctx["spec"], context)
-    work = {k: v * ctx["spec"]["num_hidden_layers"] for k, v in work.items()}
+    family, spec = ctx["family"], ctx["spec"]
+    calls = family.calls_per_pass(spec, "paged_decode")
+    work = {k: v * calls
+            for k, v in family.work["paged_decode"](spec, context).items()}
     return 100.0 * flops.roofline_seconds(work, ctx["peaks"])["seconds"] / seconds

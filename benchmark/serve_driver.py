@@ -35,7 +35,8 @@ def start_server(cell: Dict[str, Any], seed: int):
     spec = cell["config"]
 
     def seeded_weights():
-        return weights.make_weights(spec, seed), weights.model_config(spec)
+        return (weights.make_weights(spec, seed),
+                common.family(spec).model_config(spec))
 
     ray_tpu.init()
     app = serve.LLMServer.bind(params_fn=seeded_weights,

@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import checks, weights
+from benchmark import checks, common, weights
 from benchmark.reference import model as ref
 from benchmark.tests.tiny import tiny_spec
 
@@ -24,7 +24,7 @@ def test_reference_agrees_with_the_programs_forward(config):
     from ray_tpu.models import forward
 
     spec = tiny_spec(config)
-    cfg = weights.model_config(spec, dtype="float32")
+    cfg = common.family(spec).model_config(spec, dtype="float32")
     params = _params(spec)
     tokens = jnp.asarray(np.random.default_rng(0).integers(0, 256, 48), jnp.int32)
     with jax.default_matmul_precision("highest"):
@@ -35,7 +35,7 @@ def test_reference_agrees_with_the_programs_forward(config):
 
 def test_sparse_reference_is_dropless_and_top2():
     spec = tiny_spec("mixtral-8x7b")
-    cfg = weights.model_config(spec, dtype="float32")
+    cfg = common.family(spec).model_config(spec, dtype="float32")
     assert cfg.capacity_factor == 4.0  # experts / selected: capacity == T
     lp = jax.tree.map(lambda a: a[0], _params(spec)["layers"])
     # every token the same: all route to the same two experts, the case a
@@ -93,11 +93,12 @@ def test_control_is_told_apart_train():
 
 def test_program_probe_agrees_with_reference_gradients():
     spec = tiny_spec("mistral-7b")
-    cfg = weights.model_config(spec, dtype="float32")
+    family = common.family(spec)
+    cfg = family.model_config(spec, dtype="float32")
     params = _params(spec)
     row = jnp.asarray(np.random.default_rng(3).integers(3, 256, 129), jnp.int32)
     with jax.default_matmul_precision("highest"):
-        nll, g = checks.program_probe(cfg, params, row[:-1], row[1:])
+        nll, g = family.program_probe(cfg, params, row[:-1], row[1:])
     ref_nll, ref_g = ref.nll_and_norm_grads(params, row[:-1], row[1:], spec)
     n = checks.train_numbers(nll, g, ref_nll, ref_g)
     assert n["nll_rms_err"] < 1e-4 and n["grad_rel_err"] < 1e-3

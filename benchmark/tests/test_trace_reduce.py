@@ -1,7 +1,8 @@
 """The reduction from the profiler's xplane file to numbers, on a small
 trace recorded on a TPU v5e (benchmark/tools/record_tiny_trace.py: two
 steps of a toy train step with flash attention forward and backward at the
-published head geometry, T = 1024, an idle pause after each)."""
+published head geometry, T = 1024, an idle pause after each; recorded anew
+in PR 27, since PR 24's file predated the kernels' names)."""
 
 import os
 
@@ -18,24 +19,24 @@ def trace():
 
 
 def test_busy_time_is_the_union_and_self_times_add_up_to_it(trace):
-    assert trace["busy_s"] == pytest.approx(0.001528795, rel=1e-6)
+    assert trace["busy_s"] == pytest.approx(0.001528744, rel=1e-6)
     assert sum(v[0] for v in trace["ops"].values()) == pytest.approx(
         trace["busy_s"], rel=1e-9)
     # two steps with a 20 ms pause: the device is idle most of the window
     assert trace["busy_s"] < 0.1 * trace["window_s"]
     (name, (seconds, calls)), = trace["modules"].items()
     assert trace_reduce.short_name(name) == "jit_toy_step"
-    assert seconds == pytest.approx(0.001529396) and calls == 2
+    assert seconds == pytest.approx(0.001529349) and calls == 2
     # every operation is filed under the program that ran it
     assert set(trace["module_ops"][name]) == set(trace["ops"])
 
 
-def test_kernels_are_found_by_their_signature(trace):
+def test_kernels_are_found_by_their_names(trace):
     fwd = trace_reduce.group_seconds(trace, "flash_fwd")
     bwd = trace_reduce.group_seconds(trace, "flash_bwd")
     count = trace_reduce.group_seconds(trace, "flash_bwd_count")
     assert fwd[1] == 2 and bwd[1] == 4 and count[1] == 2  # per step: 1, 2, 1
-    assert fwd[0] == pytest.approx(0.000249391, rel=1e-6)
+    assert fwd[0] == pytest.approx(0.00024939, rel=1e-6)
     assert bwd[0] == pytest.approx(0.000644167, rel=1e-6)
     assert trace_reduce.group_seconds(trace, "no_such_group") == (0.0, 0.0)
 
@@ -44,7 +45,8 @@ def test_roofline_share_of_the_recorded_kernels_is_below_100(trace):
     spec = common.load_json("configs", "mistral-7b.json")
     peaks = common.peaks_for("TPU v5 lite")
     seconds, calls = trace_reduce.group_seconds(trace, "flash_fwd")
-    ideal = flops.roofline_seconds(flops.flash_forward(spec, 1, 1024), peaks)
+    work = common.family(spec).work["flash_fwd"](spec, 1, 1024)
+    ideal = flops.roofline_seconds(work, peaks)
     share = 100 * ideal["seconds"] * calls / seconds
     assert ideal["bound"] == "compute" and 20 < share < 100
 

@@ -1,24 +1,21 @@
 """Tiny sizes for the CPU: the published configurations with every size
-shrunk. Tests only; no cell of the benchmark may use these."""
+shrunk, by the cut their family gives (`tiny(spec)`), so a cell of a new
+family is rehearsed by its entry in the manifest alone. Tests only; no cell
+of the benchmark may use these."""
 
 import copy
 
 from benchmark import common
 
-SHRINK = dict(hidden_size=64, intermediate_size=128, num_hidden_layers=2,
-              num_attention_heads=4, num_key_value_heads=2, head_dim=16,
-              vocab_size=256, max_position_embeddings=512)
-
 
 def tiny_spec(config_name: str):
-    spec = dict(common.load_json("configs", config_name + ".json"))
-    spec.update(SHRINK)
-    return spec
+    spec = common.load_json("configs", config_name + ".json")
+    return common.family(spec).tiny(spec)
 
 
-def tiny_cell(name: str):
-    cell = copy.deepcopy(common.load_cell(name))
-    cell["config"].update(SHRINK)
+def tiny_cell(name: str, manifest=None, tree: str = common.HERE):
+    cell = copy.deepcopy(common.load_cell(name, manifest, tree))
+    cell["config"] = common.family(cell["config"]).tiny(cell["config"])
     if cell["kind"] == "train":
         mix = cell["traffic"]
         mix.update(row_tokens=128, docs_in_pool=64)

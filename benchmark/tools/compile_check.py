@@ -28,7 +28,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-from benchmark import common, weights  # noqa: E402
+from benchmark import common  # noqa: E402
 
 GIB = 2 ** 30
 
@@ -60,7 +60,7 @@ def train_cell(cell, one):
     from ray_tpu.train.lm import make_optimizer, make_train_step
 
     spec, mix = cell["config"], cell["traffic"]
-    cfg = weights.model_config(spec)
+    cfg = common.family(spec).model_config(spec)
     opt = make_optimizer(**cell["recipe"])
     state = shaped(jax.eval_shape(lambda k: initial_state(spec, opt, k),
                                   jax.random.PRNGKey(0)), one)
@@ -75,12 +75,13 @@ def serve_cell(cell, one):
     from ray_tpu.serve.engine import EngineConfig, InferenceEngine
 
     spec = cell["config"]
-    cfg = weights.model_config(spec)
+    family = common.family(spec)
+    cfg = family.model_config(spec)
     ecfg = EngineConfig(**cell["engine"])
     eng = object.__new__(InferenceEngine)
     eng.cfg, eng.ecfg, eng.mesh, eng._tp, eng._prefill_cache = cfg, ecfg, None, 1, {}
     params = shaped(jax.eval_shape(
-        lambda k: weights.init_weights(spec, k), jax.random.PRNGKey(0)), one)
+        lambda k: family.init_weights(spec, k), jax.random.PRNGKey(0)), one)
     B, pps = ecfg.max_batch_size, ecfg.pages_per_seq
     pool = jax.ShapeDtypeStruct(
         (cfg.n_layers, cfg.kv_heads, ecfg.max_pages, ecfg.page_size, cfg.hdim),

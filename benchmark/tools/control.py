@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
 """The control of `correct`, on the chip at a cell's own size: the plain
-reference put in the program's place and computed in the nearest precision
-below the configuration's bfloat16 (int8 and fp8 weights; with --modes
+reference of the configuration's family put in the program's place and
+computed in the nearest precision below the configuration's bfloat16 (the
+family's `modes`: for mistral int8 and fp8 weights; with --modes
 kv-int8,kv-fp8 an int8 or fp8 cache of keys and values), compared with the
 float32 reference exactly as a run compares the program. Its numbers have
 to come out far ABOVE the limits in the cell's file; PERF.md records the
@@ -25,7 +26,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
 import numpy as np  # noqa: E402
 
 from benchmark import checks, common, traffic, weights  # noqa: E402
-from benchmark.reference import model as ref  # noqa: E402
 
 
 def log_softmax(logits):
@@ -62,13 +62,14 @@ def train_control(cell, seed, modes):
     import jax.numpy as jnp
 
     spec = cell["config"]
+    reference = common.family(spec).nll_and_norm_grads
     params = weights.make_weights(spec, seed)
     row = traffic.packed_rows(cell["traffic"], seed, 1, spec["vocab_size"])[0]
     tokens, targets = jnp.asarray(row[:-1]), jnp.asarray(row[1:])
-    exact = ref.nll_and_norm_grads(params, tokens, targets, spec)
+    exact = reference(params, tokens, targets, spec)
     out = {}
     for mode in modes:
-        low = ref.nll_and_norm_grads(params, tokens, targets, spec, mode)
+        low = reference(params, tokens, targets, spec, mode)
         out[mode] = checks.train_numbers(*low, *exact)
         out[mode]["step_loss_err"] = abs(float(jnp.mean(low[0]))
                                          - float(jnp.mean(exact[0])))
@@ -84,6 +85,9 @@ def main() -> int:
     args = parser.parse_args()
     modes = args.modes.split(",")
     cell = common.load_cell(args.workload)
+    unknown = set(modes) - set(common.family(cell["config"]).modes)
+    if unknown:
+        parser.error(f"the family's reference has no mode {sorted(unknown)}")
     common.require_tpu(cell["chips"])
     common.enable_cache()
     for seed in [int(s) for s in args.seeds.split(",")]:

@@ -3,14 +3,22 @@ operation and by XLA module, and the longest idle gaps by what the host was
 doing. Every PR's per-layer metrics go through this one reduction.
 
 Which operation belongs to which kernel or program is data:
-benchmark/trace_names.json maps a group to regular expressions over
-operation and module names, one entry per name, so a PR that gives the
-program `named_scope`s adds entries and edits nothing."""
+benchmark/trace_names.json and every benchmark/trace_names/*.json map a
+group to regular expressions over operation and module names. The files
+are merged group by group, so a PR that brings a kernel or a family adds a
+file, which can add groups and entries to groups and can remove nothing.
+Kernels are keyed on the Pallas `name=` that reaches the trace as the HLO
+instruction's name (`%paged_decode.5 = ...`), programs on their XLA module
+name (`jit_step`)."""
 
 from __future__ import annotations
 
 import bisect
 import collections
+import functools
+import glob
+import json
+import os
 import re
 from typing import Any, Dict, List, Tuple
 
@@ -63,6 +71,23 @@ def short_name(name: str) -> str:
     if m:
         return f"{m.group(1)} {m.group(2)}"
     return re.sub(r"\(\d+\)$", "", name)[:80]
+
+
+@functools.lru_cache(maxsize=None)
+def load_names(tree: str = common.HERE) -> Dict[str, Any]:
+    """<tree>/trace_names.json and <tree>/trace_names/*.json (in the order
+    of their names), merged: a later file's entries are appended to the
+    group of the same name, its `host_waiting` to that list."""
+    paths = [os.path.join(tree, "trace_names.json")] + sorted(
+        glob.glob(os.path.join(tree, "trace_names", "*.json")))
+    merged: Dict[str, Any] = {"groups": {}, "host_waiting": []}
+    for path in paths:
+        with open(path) as f:
+            table = json.load(f)
+        for group, entries in table.get("groups", {}).items():
+            merged["groups"].setdefault(group, []).extend(entries)
+        merged["host_waiting"].extend(table.get("host_waiting", []))
+    return merged
 
 
 def _line_events(line) -> List[Tuple[str, int, int]]:
@@ -133,8 +158,7 @@ def _gaps(merged: List[Tuple[int, int]], host_events) -> List[List[Any]]:
     """The idle gaps of the first chip, summed by what the host was doing:
     for each gap the host event that overlaps it most (the shortest such,
     so the most specific)."""
-    waiting = [re.compile(e["match"]) for e in
-               common.load_json("trace_names.json")["host_waiting"]]
+    waiting = [re.compile(e["match"]) for e in load_names()["host_waiting"]]
     host_events = [ev for ev in host_events
                    if not any(w.search(ev[0]) for w in waiting)]
     gaps = [(merged[i][1], merged[i + 1][0]) for i in range(len(merged) - 1)]
@@ -163,9 +187,9 @@ def breakdown(trace: Dict[str, Any]) -> Dict[str, Any]:
 
 
 def label(op_name: str) -> str:
-    """The short name, with the group trace_names.json puts it in."""
-    table = common.load_json("trace_names.json")["groups"]
-    for group, entries in table.items():
+    """The short name, with the first group of the names' files that holds
+    it."""
+    for group, entries in load_names()["groups"].items():
         if any(e["where"] == "ops" and re.search(e["match"], op_name)
                for e in entries):
             return f"{group}: {short_name(op_name)}"
@@ -183,19 +207,23 @@ def group_share(trace: Dict[str, Any], group: str):
 
 def group_seconds(trace: Dict[str, Any], group: str) -> Tuple[float, float]:
     """(seconds, calls) per chip of the operations or modules that the
-    group's entries in trace_names.json match. An entry matches names of
+    group's entries in the names' files match. An entry matches names of
     `where` ('ops' or 'modules'); with `contains` true it matches a module
-    by the names of the operations that ran inside it."""
-    table = common.load_json("trace_names.json")
+    by the names of the operations that ran inside it. A name that several
+    entries of the group match is counted once, by the first of them."""
     seconds = calls = 0.0
-    for entry in table["groups"].get(group, []):
+    counted = set()
+    for entry in load_names()["groups"].get(group, []):
         pattern = re.compile(entry["match"])
         for name, (s, c) in trace[entry["where"]].items():
             # a module may be matched by an operation it holds (`contains`):
-            # the engine's programs are all named jit__unknown
+            # a span of 4 and one of 16 steps, or the chunk program of any
+            # width, are one group whatever their module names
             names = (trace["module_ops"].get(name, [])
                      if entry.get("contains") else [name])
-            if any(pattern.search(x) for x in names):
+            if (entry["where"], name) not in counted and any(
+                    pattern.search(x) for x in names):
+                counted.add((entry["where"], name))
                 seconds += s
                 calls += c * entry.get("calls", 1)
     return seconds, calls

@@ -45,7 +45,7 @@ def initial_state(spec: Dict[str, Any], opt, key):
     """The train state of the seed, from the benchmark's own weights."""
     import jax.numpy as jnp
 
-    params = weights.init_weights(spec, key)
+    params = common.family(spec).init_weights(spec, key)
     return {"step": jnp.zeros((), jnp.int32), "params": params,
             "opt_state": opt.init(params)}
 
@@ -60,7 +60,8 @@ def build_step(cell: Dict[str, Any], cfg, mesh, seed: int):
     spec = cell["config"]
     opt = make_optimizer(**cell["recipe"])
     key = weights.seed_key(seed)
-    p_shapes = jax.eval_shape(lambda k: weights.init_weights(spec, k), key)
+    p_shapes = jax.eval_shape(
+        lambda k: common.family(spec).init_weights(spec, k), key)
     shardings = _state_shardings(cfg, mesh, opt, p_shapes)
     with mesh:
         state = jax.jit(lambda k: initial_state(spec, opt, k),
@@ -81,7 +82,7 @@ def train_loop(config: Dict[str, Any]) -> None:
 
     cell, seconds = _SHARED["cell"], config["seconds"]
     watch = _SHARED["watch"]
-    cfg = weights.model_config(cell["config"])
+    cfg = common.family(cell["config"]).model_config(cell["config"])
     mesh_axes = cell["mesh_axes"]
     n_dev = math.prod(mesh_axes.values())
     mesh = build_mesh(MeshSpec.create(**mesh_axes),
