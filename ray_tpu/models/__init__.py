@@ -6,7 +6,13 @@ the model zoo, so these are first-class: GPT-2, Llama-3, Mixtral configs
 over one sharded JAX transformer.
 """
 
-from .config import ModelConfig, get_config, list_configs, register  # noqa: F401
+from .config import (  # noqa: F401
+    ModelConfig,
+    StackConfig,
+    get_config,
+    list_configs,
+    register,
+)
 from .generate import generate, sample_token  # noqa: F401
 from .transformer import (  # noqa: F401
     decode_step,

@@ -4,13 +4,17 @@ Covers the BASELINE.md workload set: GPT-2 125M, Llama-3 8B, Mixtral 8x7B,
 plus tiny variants for tests. One config class drives all families —
 differences (norm type, activation, positional scheme, GQA, MoE) are fields,
 not subclasses, so the same sharded forward/train/serve path covers every
-family.
+family. A model whose layers differ names each layer's mixer in
+`layer_kinds` (models/stack.py runs it; serve only).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
+
+# what a layer's mixer can be when `layer_kinds` names them (models/stack.py)
+LAYER_KINDS = ("mamba", "window", "full", "gmu", "cross")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,8 +60,12 @@ class ModelConfig:
     def is_moe(self) -> bool:
         return self.num_experts > 0
 
+    # what StackConfig (below) answers otherwise
+    is_stack = False
+    has_state = False
+
     def param_count(self) -> int:
-        """Approximate parameter count (embeddings included once if tied)."""
+        """Parameter count (embeddings included once if tied)."""
         D, F, L, V = self.d_model, self.d_ff, self.n_layers, self.vocab_size
         H, KVH, hd = self.n_heads, self.kv_heads, self.hdim
         attn = D * H * hd + 2 * D * KVH * hd + H * hd * D
@@ -70,7 +78,120 @@ class ModelConfig:
         norms = 2 * D * (2 if self.norm == "layernorm" else 1)
         emb = V * D * (1 if self.tie_embeddings else 2)
         pos = self.max_seq_len * D if self.positional == "learned" else 0
-        return L * (attn + ffn + norms) + emb + pos + D
+        final = D * (2 if self.norm == "layernorm" else 1)
+        return L * (attn + ffn + norms) + emb + pos + final
+
+
+@dataclasses.dataclass(frozen=True)
+class StackConfig(ModelConfig):
+    """A model whose layers differ. A subclass and not more fields of
+    ModelConfig: the benchmark pins the one-block models' ModelConfig field
+    by field (benchmark/tests/test_families.py)."""
+
+    # A stack of unlike layers (models/stack.py): one mixer kind per layer,
+    # each followed by the dense FFN. "mamba": selective state space;
+    # "window" / "full": attention over the last `window` keys / all keys,
+    # "full" writing THE cache that every later "cross" layer (queries
+    # only) reads; "gmu": gates the last mamba layer's scan output.
+    # Attention here is differential over pairs of heads, its projections
+    # carry biases, and nothing encodes positions: what the one family
+    # that needs a stack has; another gets a field when it comes.
+    layer_kinds: Tuple[str, ...] = ()
+    window: int = 0
+    ssm_inner: int = 0        # mamba / gmu inner width
+    ssm_state: int = 16
+    ssm_conv: int = 4
+    ssm_dt_rank: int = 0
+
+    def __post_init__(self) -> None:
+        kinds = tuple(self.layer_kinds)
+        object.__setattr__(self, "layer_kinds", kinds)
+        bad = sorted(set(kinds) - set(LAYER_KINDS))
+        if bad or len(kinds) != self.n_layers:
+            raise ValueError(
+                f"layer_kinds must name one of {LAYER_KINDS} for each of "
+                f"{self.n_layers} layers; got {len(kinds)} with {bad}")
+        for kind, needs in (("gmu", "mamba"), ("cross", "full")):
+            if kind in kinds and needs not in kinds[:kinds.index(kind)]:
+                raise ValueError(f"a {kind!r} layer needs a {needs!r} "
+                                 "layer before it")
+        if self.is_moe or self.norm != "layernorm":
+            raise ValueError("a mixed stack is dense and LayerNorm'd")
+        if "window" in kinds and self.window <= 0:
+            raise ValueError("window layers need `window` > 0")
+        if (self.n_heads % 2 or self.kv_heads % 2
+                or (self.n_heads // 2) % (self.kv_heads // 2)):
+            raise ValueError("differential attention pairs heads: n_heads "
+                             "and kv_heads even, q pairs a multiple of kv "
+                             "pairs")
+
+    is_stack = True
+
+    @property
+    def has_state(self) -> bool:
+        """Some layer keeps per-sequence state that is not keys and values."""
+        return "mamba" in self.layer_kinds
+
+    def count(self, kind: str) -> int:
+        return self.layer_kinds.count(kind)
+
+    @property
+    def pool_heads(self) -> int:
+        """KV heads as a cache holds them: a differential pair is one row."""
+        return self.kv_heads // 2
+
+    @property
+    def pool_dim(self) -> int:
+        return self.hdim * 2
+
+    @property
+    def pool_row(self) -> int:
+        """A token's row in a cache: every KV head side by side (the
+        packed layout of ops/paged_attention.py)."""
+        return self.pool_heads * self.pool_dim
+
+    def segments(self) -> Tuple[Tuple[int, Tuple[str, ...], int], ...]:
+        """The stack as runs of whole periods: (first layer, the period's
+        kinds, repeats). A run of two or more equal periods (the shortest
+        period that repeats wins) is scanned; a layer that belongs to none
+        is a run of its own, once. Mamba/window pairs, then one mamba and
+        one full layer, then gmu/cross pairs: three scans' worth of
+        programs, whatever the depth."""
+        kinds, out, i = self.layer_kinds, [], 0
+        while i < len(kinds):
+            best = (1, 1)
+            for p in range(1, 5):
+                r = 1
+                while kinds[i + r * p:i + (r + 1) * p] == kinds[i:i + p]:
+                    r += 1
+                if r >= 2:
+                    best = (p, r)
+                    break
+            out.append((i, kinds[i:i + best[0]], best[1]))
+            i += best[0] * best[1]
+        return tuple(out)
+
+    def _mixer_params(self, kind: str) -> int:
+        D, H, KVH, hd = self.d_model, self.n_heads, self.kv_heads, self.hdim
+        Di, N, R = self.ssm_inner, self.ssm_state, self.ssm_dt_rank
+        # q and o with biases, four lambda vectors, the pair norm's weight
+        q_o = 2 * D * H * hd + H * hd + D + 4 * hd + 2 * hd
+        if kind == "mamba":
+            return (D * 2 * Di + Di * (self.ssm_conv + 1) + Di * (R + 2 * N)
+                    + R * Di + Di + N * Di + Di + Di * D)
+        if kind == "gmu":
+            return 2 * D * Di
+        if kind == "cross":
+            return q_o
+        return q_o + 2 * D * KVH * hd + 2 * KVH * hd
+
+    def param_count(self) -> int:
+        """Parameter count of the mixed stack (the tied table once)."""
+        D, F, L, V = self.d_model, self.d_ff, self.n_layers, self.vocab_size
+        per_layer = 3 * D * F + 4 * D  # swiglu + two LayerNorms
+        return (sum(self._mixer_params(k) for k in self.layer_kinds)
+                + L * per_layer + V * D * (1 if self.tie_embeddings else 2)
+                + 2 * D)
 
 
 _REGISTRY = {}
@@ -189,4 +310,41 @@ register(ModelConfig(
     d_model=64, n_layers=2, n_heads=4, n_kv_heads=4, d_ff=128,
     max_seq_len=128,
     num_experts=4, num_selected_experts=2, dtype="float32", remat=False,
+))
+
+
+def _sambay_kinds(n_layers: int) -> Tuple[str, ...]:
+    """SambaY's order: mamba / window pairs up to the middle, then one mamba
+    and THE full-attention layer, then gmu / cross pairs."""
+    half = n_layers // 2
+    return tuple(
+        ("mamba" if l % 2 == 0 else "window") if l < half
+        else "mamba" if l == half else "full" if l == half + 1
+        else ("gmu" if l % 2 == 0 else "cross")
+        for l in range(n_layers))
+
+
+register(StackConfig(
+    name="phi4-mini-flash",
+    # microsoft/Phi-4-mini-flash-reasoning (arXiv:2507.06607): 3.85 B
+    # parameters, no positional encoding, one KV cache read by 8 layers
+    vocab_size=200064,
+    d_model=2560, n_layers=32, n_heads=40, n_kv_heads=20, head_dim=64,
+    d_ff=10240, max_seq_len=262144,
+    norm="layernorm", activation="swiglu", positional="none",
+    tie_embeddings=True, norm_eps=1e-5,
+    layer_kinds=_sambay_kinds(32), window=512, ssm_inner=5120, ssm_state=16, ssm_conv=4,
+    ssm_dt_rank=160,
+))
+
+register(StackConfig(
+    name="tiny-sambay",
+    # the same stack's shape at toy widths: three mamba / window pairs,
+    # the one-off mamba and full layers at 6 and 7, two gmu / cross pairs
+    vocab_size=512,
+    d_model=64, n_layers=12, n_heads=8, n_kv_heads=4, head_dim=8, d_ff=128,
+    max_seq_len=128, dtype="float32", remat=False,
+    norm="layernorm", activation="swiglu", positional="none",
+    tie_embeddings=True, norm_eps=1e-5,
+    layer_kinds=_sambay_kinds(12), window=8, ssm_inner=128, ssm_state=4, ssm_conv=4, ssm_dt_rank=4,
 ))

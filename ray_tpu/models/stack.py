@@ -1,0 +1,596 @@
+"""A stack whose layers differ (ModelConfig.layer_kinds): every layer is
+`x + Mix(LN(x))` then `x + FFN(LN(x))`, and Mix is one of
+
+  mamba   selective state space (Mamba-1): conv tail and scan state per
+          sequence; hands its scan output on to the gmu layers after it
+  window  attention over the last `window` keys
+  full    attention over every key; its keys and values are THE cache that
+          the cross layers after it read
+  gmu     gated memory unit: gates the last mamba layer's scan output
+  cross   queries only, over the last full layer's keys and values
+
+Each mixer is written ONCE, over a small state interface (a *mode*), and
+`forward`, the engine's bucket prefill, its chunk program and its decode
+program all run `run_stack` with their own mode:
+
+  Seq(...)     whole sequences [B, T]: no cache (forward), state kept for
+               the engine (bucket prefill), or one chunk of one sequence
+               from carried state with the full layer's keys in pages
+  Decode(...)  one token for every slot: state per slot, window keys in a
+               ring of pages per slot, the full layer's keys in the pool
+
+What a mode reads and writes travels in `carry`, a dict threaded through
+the layer scans, so pools and state arrays are updated in place. Layers
+run as `cfg.segments()`: whole periods scanned, one-off layers once.
+
+Differential attention rides on the plain kernels: a KV pair is stored as
+one row [k1 ; k2] (and [v1 ; v2]) of twice the head size, and a query head
+is padded with zeros on the side of the other softmax, so `[q1 ; 0]` scores
+against k1 alone and `[0 ; q2]` against k2, and one pass over the pages
+feeds both softmaxes. The engine's pools are PACKED: a token's row holds
+all its KV pairs side by side ([.., 1, pages, page_size, pairs * 128]), so
+a page is one DMA and a decode call one grid program a sequence (a call's
+time is mostly a fixed cost per grid program); in decode a query head is
+zero outside its own pair's lanes, the chunk kernel reads its pair's tile
+of each page. Serve only: no sharding rules, no training path.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict
+
+import jax
+import jax.numpy as jnp
+
+from ..ops import (
+    flash_attention,
+    paged_attention_chunk,
+    paged_attention_decode,
+    write_then_attend,
+)
+from ..ops.ssm import ssm_scan, ssm_step
+from .config import ModelConfig
+
+Params = Dict[str, Any]
+_F32 = jnp.float32
+_STATEFUL = ("mamba", "window", "full")
+
+
+# ---------------------------------------------------------------------------
+# parameters
+# ---------------------------------------------------------------------------
+
+
+def layer_shapes(cfg: ModelConfig, kind: str) -> Dict[str, tuple]:
+    """name -> (shape, init) of one layer of `kind`; init is "w" (normal),
+    "out" (normal, scaled down with depth), "one", "zero" or a constant."""
+    D, F, H, KVH, hd = cfg.d_model, cfg.d_ff, cfg.n_heads, cfg.kv_heads, cfg.hdim
+    Di, N, R, K = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank, cfg.ssm_conv
+    out = {"ln1": ((D,), "one"), "ln1_b": ((D,), "zero"),
+           "ln2": ((D,), "one"), "ln2_b": ((D,), "zero"),
+           "w_in": ((D, F), "w"), "w_gate": ((D, F), "w"),
+           "w_out": ((F, D), "out")}
+    if kind == "mamba":
+        out.update(m_in=((D, 2 * Di), "w"), m_conv=((K, Di), "w"),
+                   m_conv_b=((Di,), "zero"), m_x=((Di, R + 2 * N), "w"),
+                   m_dt=((R, Di), "w"), m_dt_b=((Di,), "zero"),
+                   m_A_log=((N, Di), "zero"), m_D=((Di,), "one"),
+                   m_out=((Di, D), "out"))
+    elif kind == "gmu":
+        out.update(g_in=((D, Di), "w"), g_out=((Di, D), "out"))
+    else:
+        out.update(wq=((D, H, hd), "w"), bq=((H, hd), "zero"),
+                   wo=((H, hd, D), "out"), bo=((D,), "zero"),
+                   sub_w=((2 * hd,), "one"),
+                   **{n: ((hd,), "w") for n in
+                      ("lam_q1", "lam_k1", "lam_q2", "lam_k2")})
+        if kind != "cross":
+            out.update(wk=((D, KVH, hd), "w"), bk=((KVH, hd), "zero"),
+                       wv=((D, KVH, hd), "w"), bv=((KVH, hd), "zero"))
+    return out
+
+
+def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
+    """Random float32 parameters. `layers` is a list of segments
+    (cfg.segments()), each a tuple with one dict per layer of the period,
+    every leaf stacked over the segment's repeats."""
+    out_scale = 0.02 / (2 * cfg.n_layers) ** 0.5
+
+    def leaf(k, shape, init):
+        if init in ("w", "out"):
+            scale = 0.02 if init == "w" else out_scale
+            return jax.random.normal(k, shape, _F32) * scale
+        return jnp.full(shape, 1.0 if init == "one" else 0.0, _F32)
+
+    def layer(k, kind):
+        shapes = layer_shapes(cfg, kind)
+        ks = jax.random.split(k, len(shapes))
+        return {n: leaf(ks[i], *shapes[n]) for i, n in enumerate(sorted(shapes))}
+
+    k_emb, k_layers = jax.random.split(key)
+    segments = []
+    for first, kinds, repeats in cfg.segments():
+        ks = jax.random.split(jax.random.fold_in(k_layers, first),
+                              repeats * len(kinds)).reshape(
+                                  repeats, len(kinds), -1)
+        segments.append(tuple(
+            jax.vmap(lambda k, kind=kind: layer(k, kind))(ks[:, i])
+            for i, kind in enumerate(kinds)))
+    return {"embed": jax.random.normal(k_emb, (cfg.vocab_size, cfg.d_model),
+                                       _F32) * 0.02,
+            "layers": segments,
+            "final_norm": jnp.ones((cfg.d_model,), _F32),
+            "final_norm_b": jnp.zeros((cfg.d_model,), _F32)}
+
+
+# ---------------------------------------------------------------------------
+# state: what the engine holds per slot, and what a prefill hands it
+# ---------------------------------------------------------------------------
+
+
+def ring_pages(cfg: ModelConfig, page_size: int) -> int:
+    """Pages a sequence holds in each window layer, whatever its length:
+    `window` keys span at most window / page_size + 1 pages."""
+    if cfg.window % page_size:
+        raise ValueError(f"window {cfg.window} must be a multiple of the "
+                         f"page size {page_size}")
+    return cfg.window // page_size + 1
+
+
+def new_request_state(cfg: ModelConfig, batch: int, dtype) -> Params:
+    """State a prefill hands over: conv tails [M,B,K-1,Di], scan state
+    [M,B,N,Di] (float32), and the last `window` keys and values of every
+    window layer [W,B,window,KVH,D]. Zeros are a sequence's start."""
+    M, NW = cfg.count("mamba"), cfg.count("window")
+    kv = (NW, batch, cfg.window, cfg.pool_heads, cfg.pool_dim)
+    return {"conv": jnp.zeros((M, batch, cfg.ssm_conv - 1, cfg.ssm_inner), dtype),
+            "ssm": jnp.zeros((M, batch, cfg.ssm_state, cfg.ssm_inner), _F32),
+            "wk": jnp.zeros(kv, dtype), "wv": jnp.zeros(kv, dtype)}
+
+
+def new_engine_state(cfg: ModelConfig, batch: int, page_size: int,
+                     act_dtype, cache_dtype) -> Params:
+    """What the engine holds for `batch` decode slots beside the page pool:
+    conv tails and scan state per slot, and for the window layers a packed
+    pool [W, 1, 1 + batch * ring, page_size, KVH * D] in which slot b owns
+    pages 1 + b * ring .. (page 0 is never read)."""
+    st = new_request_state(cfg, batch, act_dtype)
+    pool = (cfg.count("window"), 1, 1 + batch * ring_pages(cfg, page_size),
+            page_size, cfg.pool_row)
+    return {"conv": st["conv"], "ssm": st["ssm"],
+            "wk": jnp.zeros(pool, cache_dtype),
+            "wv": jnp.zeros(pool, cache_dtype)}
+
+
+def install_state(state: Params, rs: Params, slot, length,
+                  cfg: ModelConfig, page_size: int) -> Params:
+    """A prefilled sequence of `length` tokens takes decode slot `slot`:
+    its conv tails and scan state overwrite the slot's (whatever the last
+    occupant left), and its last `window` keys go to the slot's ring, each
+    at the place its position has there."""
+    ring = ring_pages(cfg, page_size)
+    span = ring * page_size
+    r = jnp.arange(span)
+    pos = r + span * jnp.floor_divide(length - 1 - r, span)
+    at = jnp.clip(pos - (length - cfg.window), 0, cfg.window - 1)
+
+    def image(tail):  # [W,1,window,KVH,D] -> packed [W,1,ring,ps,KVH*D]
+        img = tail[:, 0][:, at]
+        return img.reshape(img.shape[0], 1, ring, page_size, cfg.pool_row)
+
+    out = dict(state)
+    for name in ("conv", "ssm"):
+        out[name] = jax.lax.dynamic_update_slice_in_dim(
+            state[name], rs[name].astype(state[name].dtype), slot, 1)
+    for name in ("wk", "wv"):
+        out[name] = jax.lax.dynamic_update_slice_in_dim(
+            state[name], image(rs[name]).astype(state[name].dtype),
+            1 + slot * ring, 2)
+    return out
+
+
+# ---------------------------------------------------------------------------
+# modes: the state interface
+# ---------------------------------------------------------------------------
+
+
+def _dense_attend(q, k, v, scale, window=None):
+    """q [B,T,H,D], k/v [B,T,KVH,D], causal (and windowed). The flash
+    kernel wherever the window cannot bind; else a plain masked softmax."""
+    T = q.shape[1]
+    if window is None or T <= window:
+        # under the kernel's smallest automatic block the sequence is one
+        # block (left to itself flash_attention takes its XLA path there)
+        block = T if T < 128 else None
+        return flash_attention(q, k, v, causal=True, scale=scale,
+                               block_q=block, block_k=block)
+    B, _, H, D = q.shape
+    KVH = k.shape[2]
+    with jax.named_scope("window_attn_dense"):
+        qf = q.reshape(B, T, KVH, H // KVH, D).astype(_F32)
+        s = jnp.einsum("bqcgd,bkcd->bcgqk", qf, k.astype(_F32)) * scale
+        qp, kp = jnp.arange(T)[:, None], jnp.arange(T)[None, :]
+        s = jnp.where((kp <= qp) & (kp > qp - window), s, -2e30)
+        o = jnp.einsum("bcgqk,bkcd->bqcgd", jax.nn.softmax(s, axis=-1),
+                       v.astype(_F32))
+        return o.reshape(B, T, H, D).astype(q.dtype)
+
+
+def _row(x):
+    """[..., KVH, D] -> [..., 1, KVH * D]: a token's row of a packed pool."""
+    return x.reshape(*x.shape[:-2], 1, -1)
+
+
+class Seq:
+    """Whole sequences [B, T], right-padded to T with `n_valid` [B] real
+    tokens each (None: all). keep=False is the plain forward. keep=True
+    also leaves in `carry` what the engine needs to go on decoding (see
+    new_request_state) and the full layers' keys and values. `chunk`
+    (start, page_table) makes it ONE sequence's prefill chunk: mamba and
+    window layers start from the state in `carry`, the full layer writes
+    and reads the page pool in `carry`."""
+
+    def __init__(self, cfg: ModelConfig, n_valid=None, keep: bool = False,
+                 chunk=None, page_size: int = 0):
+        self.cfg, self.n_valid, self.keep, self.chunk = cfg, n_valid, keep, chunk
+        self.ps = page_size
+
+    def init_carry(self, x, pools=None, state=None) -> Params:
+        cfg = self.cfg
+        B, T, _ = x.shape
+        carry = {"mem": jnp.zeros((B, T, cfg.ssm_inner), x.dtype)}
+        if self.chunk is not None:
+            carry.update(state, k_pages=pools[0], v_pages=pools[1])
+            return carry
+        if self.keep:
+            carry.update(new_request_state(cfg, B, x.dtype))
+        kv = (cfg.count("full"), B, T, cfg.pool_heads, cfg.pool_dim)
+        carry.update(k=jnp.zeros(kv, x.dtype), v=jnp.zeros(kv, x.dtype))
+        return carry
+
+    # -- mamba
+    def valid(self, T):
+        """[B,T,1] bool: which positions are real tokens (None: all)."""
+        if self.n_valid is None:
+            return None
+        return (jnp.arange(T)[None, :] < self.n_valid[:, None])[..., None]
+
+    def _lengths(self, B, T):
+        return jnp.full((B,), T) if self.n_valid is None else self.n_valid
+
+    def conv(self, carry, mi, u):
+        """-> [tail ; u] along time, and the new tail kept."""
+        B, T, Di = u.shape
+        K = self.cfg.ssm_conv
+        tail = (carry["conv"][mi].astype(u.dtype) if self.chunk is not None
+                else jnp.zeros((B, K - 1, Di), u.dtype))
+        ext = jnp.concatenate([tail, u], axis=1)
+        if self.keep:
+            n = self._lengths(B, T)
+            new = jnp.take_along_axis(
+                ext, (n[:, None] + jnp.arange(K - 1)[None])[..., None], axis=1)
+            carry = {**carry, "conv": carry["conv"].at[mi].set(
+                new.astype(carry["conv"].dtype))}
+        return ext, carry
+
+    def scan(self, carry, mi, u, dt, A, Bm, Cm, D):
+        s0 = (carry["ssm"][mi] if self.chunk is not None
+              else jnp.zeros((u.shape[0], *A.shape), _F32))
+        y, s1 = ssm_scan(u, dt, A, Bm, Cm, D, s0)
+        if self.keep:
+            carry = {**carry, "ssm": carry["ssm"].at[mi].set(s1)}
+        return y, carry
+
+    # -- attention
+    def attend_window(self, carry, wi, q, k, v, scale):
+        cfg = self.cfg
+        W = cfg.window
+        if self.chunk is None:
+            o = _dense_attend(q, k, v, scale, W)
+            if self.keep:
+                B, T = k.shape[:2]
+                n = self._lengths(B, T)
+                at = jnp.clip(n[:, None] - W + jnp.arange(W)[None], 0, T - 1)
+                for name, new in (("wk", k), ("wv", v)):
+                    tail = jnp.take_along_axis(new, at[:, :, None, None], 1)
+                    carry = {**carry, name: carry[name].at[wi].set(
+                        tail.astype(carry[name].dtype))}
+            return o, carry
+        # a chunk: the kept tail and the chunk's own keys, side by side,
+        # are a little page pool that the chunk kernel reads with the
+        # window's bound; keys before the sequence's start are not seen
+        start = self.chunk[0]
+        C = k.shape[1]
+        n_pages = (W + C) // self.ps
+        bufs = []
+        for name, new in (("wk", k), ("wv", v)):
+            buf = jnp.concatenate(
+                [carry[name][wi][0].astype(new.dtype), new[0]], axis=0)
+            bufs.append(buf)
+            carry = {**carry, name: carry[name].at[wi, 0].set(
+                jax.lax.dynamic_slice_in_dim(buf, self.n_valid[0], W, 0)
+                .astype(carry[name].dtype))}
+        pool = [b.reshape(n_pages, self.ps, *b.shape[1:])
+                .transpose(2, 0, 1, 3)[None] for b in bufs]
+        o = paged_attention_chunk(
+            q[0], *pool, jnp.arange(n_pages, dtype=jnp.int32), W, W + C, 0,
+            scale=scale, window=W, first=jnp.maximum(W - start, 0))
+        return o[None].astype(q.dtype), carry
+
+    def attend_full(self, carry, fi, q, k, v, scale):
+        """k is None: a cross layer, which reads and writes nothing."""
+        if self.chunk is None:
+            if k is not None:
+                carry = {**carry,
+                         "k": carry["k"].at[fi].set(k.astype(carry["k"].dtype)),
+                         "v": carry["v"].at[fi].set(v.astype(carry["v"].dtype))}
+            return _dense_attend(q, carry["k"][fi], carry["v"][fi], scale), carry
+        start, table = self.chunk
+        C = q.shape[1]
+
+        def attend(q, kp, vp, layer):
+            return paged_attention_chunk(q, kp, vp, table, start, start + C,
+                                         layer, scale=scale,
+                                         heads=self.cfg.pool_heads)
+
+        kp, vp = carry["k_pages"], carry["v_pages"]
+        if k is None:
+            return attend(q[0], kp, vp, fi)[None].astype(q.dtype), carry
+        pos = start + jnp.arange(C)
+        o, kp, vp = write_then_attend(
+            attend, q[0], _row(k[0]), _row(v[0]), kp, vp, fi,
+            table[pos // self.ps], pos % self.ps)
+        return o[None].astype(q.dtype), {**carry, "k_pages": kp, "v_pages": vp}
+
+
+class Decode:
+    """One token for every decode slot [B, 1]: `positions` [B] is where it
+    goes, `page_tables` [B, pages] the full layer's pages. `carry` holds
+    the engine's pools and state whole (new_engine_state + the pool)."""
+
+    def __init__(self, cfg: ModelConfig, positions, page_tables,
+                 page_size: int):
+        self.cfg, self.pos, self.tables, self.ps = (
+            cfg, positions, page_tables, page_size)
+        B = positions.shape[0]
+        self.ring = ring_pages(cfg, page_size) if cfg.count("window") else 1
+        self.ring_table = (1 + jnp.arange(B)[:, None] * self.ring
+                           + jnp.arange(self.ring)[None, :]).astype(jnp.int32)
+
+    def init_carry(self, x, pools, state) -> Params:
+        return {"mem": jnp.zeros((*x.shape[:2], self.cfg.ssm_inner), x.dtype),
+                **state, "k_pages": pools[0], "v_pages": pools[1]}
+
+    def valid(self, T):
+        return None
+
+    def conv(self, carry, mi, u):
+        ext = jnp.concatenate([carry["conv"][mi].astype(u.dtype), u], axis=1)
+        return ext, {**carry, "conv": carry["conv"].at[mi].set(
+            ext[:, 1:].astype(carry["conv"].dtype))}
+
+    def scan(self, carry, mi, u, dt, A, Bm, Cm, D):
+        y, ssm = ssm_step(carry["ssm"], mi, u[:, 0], dt[:, 0], A, Bm[:, 0],
+                          Cm[:, 0], D)
+        return y[:, None], {**carry, "ssm": ssm}
+
+    def attend_window(self, carry, wi, q, k, v, scale):
+        page = jnp.take_along_axis(
+            self.ring_table, ((self.pos // self.ps) % self.ring)[:, None], 1)
+
+        def attend(q, kp, vp, layer):
+            return paged_attention_decode(
+                q, kp, vp, self.ring_table, self.pos + 1, layer, scale=scale,
+                window=self.cfg.window)
+
+        o, wk, wv = write_then_attend(
+            attend, self._wide(q[:, 0]), _row(k[:, 0]), _row(v[:, 0]),
+            carry["wk"], carry["wv"], wi, page[:, 0], self.pos % self.ps)
+        return self._own(o)[:, None], {**carry, "wk": wk, "wv": wv}
+
+    def attend_full(self, carry, fi, q, k, v, scale):
+        def attend(q, kp, vp, layer):
+            return paged_attention_decode(q, kp, vp, self.tables,
+                                          self.pos + 1, layer, scale=scale)
+
+        kp, vp = carry["k_pages"], carry["v_pages"]
+        if k is None:
+            return self._own(attend(self._wide(q[:, 0]), kp, vp, fi))[:, None], carry
+        B = q.shape[0]
+        o, kp, vp = write_then_attend(
+            attend, self._wide(q[:, 0]), _row(k[:, 0]), _row(v[:, 0]), kp, vp,
+            fi, self.tables[jnp.arange(B), self.pos // self.ps],
+            self.pos % self.ps)
+        return self._own(o)[:, None], {**carry, "k_pages": kp, "v_pages": vp}
+
+    # a packed pool is ONE kv head as wide as all of them: a query head is
+    # zero outside its own kv head's lanes, and of the output row it keeps
+    # those lanes
+    def _lanes(self, H):
+        KVH = self.cfg.pool_heads
+        return jnp.arange(H)[:, None] // (H // KVH) == jnp.arange(KVH)[None, :]
+
+    def _wide(self, q):  # [B,H,D] -> [B,H,KVH*D]
+        B, H, D = q.shape
+        own = self._lanes(H).astype(q.dtype)
+        return (q[:, :, None, :] * own[None, :, :, None]).reshape(B, H, -1)
+
+    def _own(self, o):  # [B,H,KVH*D] -> [B,H,D]
+        B, H, _ = o.shape
+        KVH = self.cfg.pool_heads
+        own = self._lanes(H).astype(o.dtype)
+        return jnp.einsum("bhcd,hc->bhd", o.reshape(B, H, KVH, -1), own)
+
+
+# ---------------------------------------------------------------------------
+# mixers: one function a kind
+# ---------------------------------------------------------------------------
+
+
+def _mamba(h, lp, cfg, mi, mode, carry):
+    dtype = h.dtype
+    Di, N, R = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank
+    T = h.shape[1]
+    uz = jnp.einsum("btd,de->bte", h, lp["m_in"].astype(dtype))
+    u, z = uz[..., :Di], uz[..., Di:]
+    ext, carry = mode.conv(carry, mi, u)
+    w = lp["m_conv"].astype(_F32)
+    conv = sum(ext[:, j:j + T].astype(_F32) * w[j]
+               for j in range(cfg.ssm_conv)) + lp["m_conv_b"].astype(_F32)
+    u = jax.nn.silu(conv)
+    xdbc = jnp.einsum("bte,er->btr", u.astype(dtype), lp["m_x"].astype(dtype),
+                      preferred_element_type=_F32)
+    dt = jax.nn.softplus(
+        jnp.einsum("btr,re->bte", xdbc[..., :R].astype(dtype),
+                   lp["m_dt"].astype(dtype), preferred_element_type=_F32)
+        + lp["m_dt_b"].astype(_F32))
+    valid = mode.valid(T)
+    if valid is not None:
+        dt = jnp.where(valid, dt, 0.0)  # padding leaves the state alone
+    y, carry = mode.scan(
+        carry, mi, u, dt, -jnp.exp(lp["m_A_log"].astype(_F32)),
+        xdbc[..., R:R + N], xdbc[..., R + N:], lp["m_D"].astype(_F32))
+    out = jnp.einsum("bte,ed->btd",
+                     (y * jax.nn.silu(z.astype(_F32))).astype(dtype),
+                     lp["m_out"].astype(dtype))
+    return out, {**carry, "mem": y.astype(dtype)}
+
+
+def _gmu(h, lp, cfg, carry):
+    dtype = h.dtype
+    g = jnp.einsum("btd,de->bte", h, lp["g_in"].astype(dtype))
+    y = carry["mem"].astype(_F32) * jax.nn.silu(g.astype(_F32))
+    return jnp.einsum("bte,ed->btd", y.astype(dtype), lp["g_out"].astype(dtype))
+
+
+def _project(h, lp, w, b):
+    return (jnp.einsum("btd,dhk->bthk", h, lp[w].astype(h.dtype))
+            + lp[b].astype(h.dtype))
+
+
+def _attention(h, lp, cfg, kind, layer, idx, mode, carry):
+    """window / full / cross: differential attention through the modes'
+    plain attends, with heads twice as wide (module docstring)."""
+    dtype = h.dtype
+    B, T, _ = h.shape
+    H, hd = cfg.n_heads, cfg.hdim
+    q = _project(h, lp, "wq", "bq")
+    zero = jnp.zeros_like(q)
+    even = (jnp.arange(H) % 2 == 0)[:, None]
+    q = jnp.where(even, jnp.concatenate([q, zero], -1),
+                  jnp.concatenate([zero, q], -1))
+    k = v = None
+    if kind != "cross":  # pairs (2g, 2g+1) side by side: one row a pair
+        k = _project(h, lp, "wk", "bk").reshape(
+            B, T, cfg.pool_heads, cfg.pool_dim)
+        v = _project(h, lp, "wv", "bv").reshape(
+            B, T, cfg.pool_heads, cfg.pool_dim)
+    attend = mode.attend_window if kind == "window" else mode.attend_full
+    o, carry = attend(carry, idx, q, k, v, hd ** -0.5)
+    o = o.reshape(B, T, H // 2, 2, 2 * hd).astype(_F32)
+    lam0 = 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(layer, _F32))
+    lam = (jnp.exp(jnp.sum(lp["lam_q1"].astype(_F32) * lp["lam_k1"].astype(_F32)))
+           - jnp.exp(jnp.sum(lp["lam_q2"].astype(_F32) * lp["lam_k2"].astype(_F32)))
+           + lam0)
+    a = o[..., 0, :] - lam * o[..., 1, :]
+    a = a * jax.lax.rsqrt(jnp.mean(a * a, -1, keepdims=True) + cfg.norm_eps)
+    o = (a * lp["sub_w"].astype(_F32) * (1.0 - lam0)).reshape(B, T, H, hd)
+    return (jnp.einsum("bthk,hkd->btd", o.astype(dtype), lp["wo"].astype(dtype))
+            + lp["bo"].astype(dtype)), carry
+
+
+def _layer(x, lp, cfg, kind, layer, idx, mode, carry):
+    from .transformer import _dense_ffn, _norm
+
+    with jax.named_scope(kind):
+        h = _norm(x, lp["ln1"], lp["ln1_b"], cfg)
+        if kind == "mamba":
+            o, carry = _mamba(h, lp, cfg, idx, mode, carry)
+        elif kind == "gmu":
+            o = _gmu(h, lp, cfg, carry)
+        else:
+            o, carry = _attention(h, lp, cfg, kind, layer, idx, mode, carry)
+        x = x + o
+    with jax.named_scope("ffn"):
+        return x + _dense_ffn(_norm(x, lp["ln2"], lp["ln2_b"], cfg), lp, cfg), carry
+
+
+def run_stack(layers, x, cfg: ModelConfig, mode, carry):
+    """Every layer of the stack over x [B,T,D] -> (x, carry). A segment of
+    r > 1 periods is one `lax.scan`; which mamba, window or full layer a
+    layer is (its row in the state arrays and pools) is counted from the
+    layers before it."""
+    seen = dict.fromkeys(_STATEFUL, 0)
+    for (first, kinds, repeats), seg in zip(cfg.segments(), layers):
+        per = {k: kinds.count(k) for k in seen}
+
+        def period(c, xs, first=first, kinds=kinds, base=dict(seen), per=per):
+            x, carry = c
+            lps, rep = xs
+            idx = {k: base[k] + rep * per[k] for k in base}
+            for i, (kind, lp) in enumerate(zip(kinds, lps)):
+                # a cross layer reads the newest full layer's cache
+                at = idx["full"] - 1 if kind == "cross" else idx.get(kind)
+                x, carry = _layer(x, lp, cfg, kind,
+                                  first + rep * len(kinds) + i, at, mode, carry)
+                if kind in idx:
+                    idx[kind] = idx[kind] + 1
+            return (x, carry), None
+
+        if repeats == 1:
+            (x, carry), _ = period(
+                (x, carry), (jax.tree.map(lambda a: a[0], seg), 0))
+        else:
+            (x, carry), _ = jax.lax.scan(period, (x, carry),
+                                         (seg, jnp.arange(repeats)))
+        for k in seen:
+            seen[k] += per[k] * repeats
+    return x, carry
+
+
+# ---------------------------------------------------------------------------
+# whole-sequence entry points
+# ---------------------------------------------------------------------------
+
+
+def _embed(params, tokens, cfg):
+    from .transformer import _embed_lookup
+
+    with jax.named_scope("embed"):
+        return _embed_lookup(params["embed"], tokens, jnp.dtype(cfg.dtype))
+
+
+def forward(params: Params, tokens: jax.Array, cfg: ModelConfig):
+    """tokens [B,T] -> (logits [B,T,V] float32, 0): no cache, no state."""
+    from .transformer import _lm_head
+
+    x = _embed(params, tokens, cfg)
+    mode = Seq(cfg)
+    x, _ = run_stack(params["layers"], x, cfg, mode, mode.init_carry(x))
+    return _lm_head(x, params, cfg), jnp.zeros((), _F32)
+
+
+def run_paged(layers, x, cfg: ModelConfig, mode, pools, state):
+    """The engine's decode and chunk programs: `run_stack` over the page
+    pool `pools` (k, v) and `state` (per slot for Decode, one sequence's
+    for a Seq chunk). -> (x, k_pages, v_pages, state)."""
+    x, carry = run_stack(layers, x, cfg, mode,
+                         mode.init_carry(x, pools, state))
+    k_pages, v_pages = carry.pop("k_pages"), carry.pop("v_pages")
+    del carry["mem"]
+    return x, k_pages, v_pages, carry
+
+
+def prefill(params: Params, cfg: ModelConfig, tokens: jax.Array,
+            true_len: jax.Array):
+    """The engine's bucket prefill: tokens [B,T] right-padded, true_len [B].
+    -> (hidden state [B,T,D] before the final norm, cache): the full
+    layers' keys and values `k`, `v` [F,B,T,1,KVH*D] and the state of
+    new_request_state, every leaf with the batch on axis 1."""
+    x = _embed(params, tokens, cfg)
+    mode = Seq(cfg, n_valid=true_len, keep=True)
+    x, carry = run_stack(params["layers"], x, cfg, mode, mode.init_carry(x))
+    carry.pop("mem")
+    for name in ("k", "v"):  # rows as the engine's packed pool holds them
+        carry[name] = _row(carry[name])
+    return x, carry

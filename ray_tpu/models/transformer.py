@@ -40,8 +40,21 @@ Params = Dict[str, Any]
 # ---------------------------------------------------------------------------
 
 
+def _one_block_only(cfg: ModelConfig, what: str) -> None:
+    if cfg.is_stack:
+        raise NotImplementedError(
+            f"{what} knows the one block of this file; {cfg.name!r} is a "
+            "stack of unlike layers (models/stack.py), which has `forward` "
+            "and the serving engine's programs and no more yet: no sharding "
+            "rules, no training path, no contiguous-cache generate")
+
+
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     """Random-init parameters (f32 master copy; cast at use sites)."""
+    if cfg.is_stack:
+        from . import stack
+
+        return stack.init_params(cfg, key)
     D, F, L, V = cfg.d_model, cfg.d_ff, cfg.n_layers, cfg.vocab_size
     H, KVH, hd = cfg.n_heads, cfg.kv_heads, cfg.hdim
     k_emb, k_pos, k_head, k_layers = jax.random.split(key, 4)
@@ -104,6 +117,7 @@ def param_axes(cfg: ModelConfig) -> Params:
     pp-sharded from birth, so the pipelined train step round-trips state
     without resharding); unsharded on every other mesh.
     """
+    _one_block_only(cfg, "param_axes")
     layer = {
         "ln1": ("stage", "norm"),
         "wq": ("stage", "embed", "heads", None),
@@ -430,6 +444,7 @@ def run_layers(
     math is position-independent because rope tables / positions come in
     from the caller. One compiled scan regardless of slice length.
     """
+    _one_block_only(cfg, "run_layers")
 
     def body(carry, lp):
         y, aux = _block(carry, lp, cfg, rope_tables, positions)
@@ -448,6 +463,10 @@ def forward(
     positions: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """tokens [B, T] -> (logits [B, T, V] f32, aux_loss scalar)."""
+    if cfg.is_stack:
+        from . import stack
+
+        return stack.forward(params, tokens, cfg)
     x, rope_tables = _prologue(params, tokens, cfg, positions)
     x, aux = run_layers(params["layers"], x, cfg, rope_tables, positions)
     return _lm_head(x, params, cfg), aux
@@ -608,6 +627,7 @@ def decode_step(
 ):
     """One token per sequence. tokens [B], positions [B] (0-based index of
     this token). Returns (logits [B,V] f32, new_cache)."""
+    _one_block_only(cfg, "decode_step")
     dtype = jnp.dtype(cfg.dtype)
     B = tokens.shape[0]
     x = _embed_lookup(params["embed"], tokens[:, None], dtype)  # [B,1,D]
@@ -663,6 +683,7 @@ def prefill(
     logits are returned — pass true_len-1 when prompts are right-padded to
     a compile bucket. Returns (last_logits [B,V], cache dict).
     """
+    _one_block_only(cfg, "prefill")
     dtype = jnp.dtype(cfg.dtype)
     B, T = tokens.shape
     with jax.named_scope("embed"):

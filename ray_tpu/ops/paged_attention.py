@@ -17,6 +17,23 @@ and the XLA references gather `pool[layer, :, page_table]`, so no caller
 ever holds one layer's slab as a value: a slab sliced out of the pool and
 put back costs a copy of the pool per decode step. The layer rides as the
 last entry of a kernel's second scalar-prefetch array.
+
+`window` (static; None: every cached key): a query at position p sees keys
+p - window + 1 .. p only. The kernels then start at the window's first page
+and, in decode, take page ids modulo the table's width, so a table may be a
+RING of window / page_size + 1 pages that a sequence overwrites as it grows
+(the engine's window layers). A head may be as wide as two differential
+heads side by side: the ops take any D that is a multiple of 128 and an
+explicit `scale`.
+
+A pool may also hold ALL of a token's kv heads side by side in one row,
+[L, 1, P, ps, heads * D] ("packed"): a page is then one contiguous DMA,
+and decode runs as ONE grid program a sequence over the whole row (the
+caller pads each query head with zeros outside its kv head's lanes), which
+matters because a decode call's time is mostly a fixed cost per grid
+program (PERF.md, PR 26 and PR 28). The chunk op reads such a pool head by
+head (`heads=`): one [ps, D] tile of each page, as contiguous as a
+head-major page.
 """
 
 from __future__ import annotations
@@ -59,7 +76,8 @@ def _gather_layer(pages, layer, page_table):
                      page_table.shape[-1] * page_size, D)
 
 
-def _paged_reference(q, k_pages, v_pages, page_table, lengths, layer, scale):
+def _paged_reference(q, k_pages, v_pages, page_table, lengths, layer, scale,
+                     window=None):
     """Gather-based fallback. q [B,H,D] -> o [B,H,D]."""
     B, H, D = q.shape
     KVH, page_size = k_pages.shape[1], k_pages.shape[3]
@@ -69,7 +87,15 @@ def _paged_reference(q, k_pages, v_pages, page_table, lengths, layer, scale):
     vg = _gather_layer(v_pages, layer, page_table)
     qf = q.reshape(B, KVH, g, D).astype(jnp.float32)
     s = jnp.einsum("bcgd,bctd->bcgt", qf, kg.astype(jnp.float32)) * scale
-    mask = jnp.arange(ctx)[None, :] < lengths[:, None]
+    if window is None:
+        mask = jnp.arange(ctx)[None, :] < lengths[:, None]
+    else:
+        # the table may be a ring: entry r holds the newest position that
+        # is r modulo ctx
+        r = jnp.arange(ctx)[None, :]
+        n = lengths[:, None]
+        pos = r + ctx * jnp.floor_divide(n - 1 - r, ctx)
+        mask = (pos >= 0) & (pos >= n - window)
     s = jnp.where(mask[:, None, None, :], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bcgt,bctd->bcgd", p, vg.astype(jnp.float32))
@@ -79,7 +105,7 @@ def _paged_reference(q, k_pages, v_pages, page_table, lengths, layer, scale):
 def _flash_page_loop(
     q2d, n_pages, page_id_fn, mask_fn, layer, c,
     k_hbm, v_hbm, k_buf, v_buf, acc_ref, m_ref, l_ref, sem_ref,
-    *, page_size, scale,
+    *, page_size, scale, packed=False,
 ):
     """The shared double-buffered page-DMA flash loop: stream this layer
     and kv head's pages HBM->VMEM two-deep while the MXU runs the
@@ -89,10 +115,17 @@ def _flash_page_loop(
     rotation, the exp-underflow guard, the l==0 epilogue division — is
     one implementation serving both decode and chunk prefill."""
 
+    def page_of(pool, page):
+        if not packed:
+            return pool.at[layer, c, page]
+        # a packed pool: head c is lanes c * D .. of every row
+        D = q2d.shape[-1]
+        return pool.at[layer, 0, page, :, pl.ds(pl.multiple_of(c * D, D), D)]
+
     def page_dma(slot, i):
         page = page_id_fn(i)
-        kcp = pltpu.make_async_copy(k_hbm.at[layer, c, page], k_buf.at[slot], sem_ref.at[slot, 0])
-        vcp = pltpu.make_async_copy(v_hbm.at[layer, c, page], v_buf.at[slot], sem_ref.at[slot, 1])
+        kcp = pltpu.make_async_copy(page_of(k_hbm, page), k_buf.at[slot], sem_ref.at[slot, 0])
+        vcp = pltpu.make_async_copy(page_of(v_hbm, page), v_buf.at[slot], sem_ref.at[slot, 1])
         return kcp, vcp
 
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
@@ -158,7 +191,7 @@ def _paged_kernel(
     o_ref,
     # scratch
     k_buf, v_buf, acc_ref, m_ref, l_ref, sem_ref,
-    *, page_size, pages_per_seq, scale, batch,
+    *, page_size, pages_per_seq, scale, batch, window=None,
 ):
     b = pl.program_id(0)
     c = pl.program_id(1)
@@ -166,22 +199,39 @@ def _paged_kernel(
     length = len_ref[b]
     layer = len_ref[batch]
     n_pages = jax.lax.div(length + page_size - 1, page_size)
+    if window is None:
+        def mask(i):
+            pos = i * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, (g, page_size), 1)
+            return pos < length
 
-    def mask(i):
-        pos = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (g, page_size), 1)
-        return pos < length
+        def page_id(i):
+            return pt_ref[b * pages_per_seq + i]
+    else:
+        first = jnp.maximum(length - window, 0)
+        page0 = jax.lax.div(first, page_size)
+        n_pages = n_pages - page0
+
+        def mask(i):
+            pos = (page0 + i) * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, (g, page_size), 1)
+            return (pos < length) & (pos >= first)
+
+        def page_id(i):
+            return pt_ref[b * pages_per_seq
+                          + jax.lax.rem(page0 + i, pages_per_seq)]
 
     out = _flash_page_loop(
         q_ref[0, 0].astype(jnp.float32), n_pages,
-        lambda i: pt_ref[b * pages_per_seq + i], mask, layer, c,
+        page_id, mask, layer, c,
         k_hbm, v_hbm, k_buf, v_buf, acc_ref, m_ref, l_ref, sem_ref,
         page_size=page_size, scale=scale,
     )
     o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
-def _paged_pallas(q, k_pages, v_pages, page_table, lengths_layer, scale):
+def _paged_pallas(q, k_pages, v_pages, page_table, lengths_layer, scale,
+                  window=None):
     """lengths_layer s32[B+1]: the B lengths, then the layer index."""
     B, H, D = q.shape
     KVH, page_size = k_pages.shape[1], k_pages.shape[3]
@@ -210,24 +260,33 @@ def _paged_pallas(q, k_pages, v_pages, page_table, lengths_layer, scale):
     out = pl.pallas_call(
         functools.partial(
             _paged_kernel, page_size=page_size, pages_per_seq=pages_per_seq,
-            scale=scale, batch=B,
+            scale=scale, batch=B, window=window,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, g, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-        name="paged_decode",
+        name="paged_decode" if window is None else "paged_decode_window",
         interpret=interpret_mode(),
     )(page_table.reshape(-1), lengths_layer, q4, k_pages, v_pages)
     return out.reshape(B, H, D)
 
 
+def _unpacked(pages, heads):
+    """[L,1,P,ps,heads*D] -> [L,heads,P,ps,D] (a copy: references only)."""
+    L, _, P, ps, row = pages.shape
+    return pages.reshape(L, P, ps, heads, row // heads).transpose(0, 3, 1, 2, 4)
+
+
 def _chunk_reference(q, k_pages, v_pages, page_table, start, total, layer,
-                     scale):
+                     scale, window=None, first=0, heads=None):
     """Gather-based fallback for ONE sequence's prefill chunk.
     q [C,H,D] -> o [C,H,D]; key j visible to query row c iff
-    j <= start + c and j < total."""
+    j <= start + c and j < total (and, with a window, j > start + c -
+    window and j >= first)."""
+    if heads is not None:
+        k_pages, v_pages = _unpacked(k_pages, heads), _unpacked(v_pages, heads)
     C, H, D = q.shape
     KVH, page_size = k_pages.shape[1], k_pages.shape[3]
     g = H // KVH
@@ -239,6 +298,9 @@ def _chunk_reference(q, k_pages, v_pages, page_table, start, total, layer,
     keypos = jnp.arange(ctx)
     qpos = start + jnp.arange(C)
     mask = (keypos[None, :] <= qpos[:, None]) & (keypos[None, :] < total)
+    if window is not None:
+        mask &= (keypos[None, :] > qpos[:, None] - window) \
+            & (keypos[None, :] >= first)
     s = jnp.where(mask[:, None, None, :], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     p = jnp.where(mask[:, None, None, :], p, 0.0)
@@ -255,38 +317,51 @@ def _chunk_kernel(
     o_ref,
     # scratch
     k_buf, v_buf, acc_ref, m_ref, l_ref, sem_ref,
-    *, page_size, scale, rows, group,
+    *, page_size, scale, rows, group, window=None, packed=False,
 ):
     """One kv head's chunk attention: q block [rows=C*g, D] vs the
     sequence's paged prefix (chunk KV already written into pages by the
     caller). The shared _flash_page_loop with a per-ROW causal bound
-    instead of the decode kernel's one scalar length."""
+    instead of the decode kernel's one scalar length. With a window the
+    loop starts at the page of the first key any row sees (meta[3] and the
+    first row's window, whichever is later)."""
     c = pl.program_id(0)
     start = meta_ref[0]
     total = meta_ref[1]
     layer = meta_ref[2]
     n_pages = jax.lax.div(total + page_size - 1, page_size)
+    page = lambda i: i  # noqa: E731 — loop index -> the sequence's page
+    if window is not None:
+        first = jnp.maximum(meta_ref[3], start - window + 1)
+        page0 = jax.lax.div(jnp.maximum(first, 0), page_size)
+        n_pages = n_pages - page0
+        page = lambda i: page0 + i  # noqa: E731
 
     def mask(i):
-        keypos = i * page_size + jax.lax.broadcasted_iota(
+        keypos = page(i) * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (rows, page_size), 1)
         qpos = start + jax.lax.broadcasted_iota(
             jnp.int32, (rows, page_size), 0) // group
-        return (keypos <= qpos) & (keypos < total)
+        seen = (keypos <= qpos) & (keypos < total)
+        if window is not None:
+            seen &= (keypos > qpos - window) & (keypos >= meta_ref[3])
+        return seen
 
     out = _flash_page_loop(
         q_ref[0].astype(jnp.float32), n_pages,
-        lambda i: pt_ref[i], mask, layer, c,
+        lambda i: pt_ref[page(i)], mask, layer, c,
         k_hbm, v_hbm, k_buf, v_buf, acc_ref, m_ref, l_ref, sem_ref,
-        page_size=page_size, scale=scale,
+        page_size=page_size, scale=scale, packed=packed,
     )
     o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _chunk_pallas(q, k_pages, v_pages, page_table, meta, scale):
-    """meta s32[3]: start, total, layer."""
+def _chunk_pallas(q, k_pages, v_pages, page_table, meta, scale, window=None,
+                  heads=None):
+    """meta s32[3]: start, total, layer (with a window s32[4]: and the
+    first valid key). heads: the pool is packed with that many kv heads."""
     C, H, D = q.shape
-    KVH, page_size = k_pages.shape[1], k_pages.shape[3]
+    KVH, page_size = heads or k_pages.shape[1], k_pages.shape[3]
     g = H // KVH
     rows = C * g
     # [C,H,D] -> [KVH, C*g, D]: each kv head's q rows contiguous
@@ -313,14 +388,14 @@ def _chunk_pallas(q, k_pages, v_pages, page_table, meta, scale):
     out = pl.pallas_call(
         functools.partial(
             _chunk_kernel, page_size=page_size, scale=scale,
-            rows=rows, group=g,
+            rows=rows, group=g, window=window, packed=heads is not None,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((KVH, rows, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
-        name="paged_chunk",
+        name="paged_chunk" if window is None else "paged_chunk_window",
         interpret=interpret_mode(),
     )(page_table, meta, qr, k_pages, v_pages)
     # [KVH, C*g, D] -> [C, H, D]
@@ -337,6 +412,9 @@ def paged_attention_chunk(
     layer,
     scale: float | None = None,
     force_xla: bool = False,
+    window: int | None = None,
+    first=0,
+    heads: int | None = None,
 ) -> jax.Array:
     """Chunked-prefill attention for ONE sequence over its paged KV.
 
@@ -355,22 +433,28 @@ def paged_attention_chunk(
       start: scalar int — the chunk's first token position.
       total: scalar int — visibility cap (usually start + C).
       layer: scalar int — which layer of the pool to attend over.
+      window / first: with a (static) window, row c also needs
+        ``j > start + c - window`` and ``j >= first`` (scalar int: keys
+        before it are not the sequence's).
+      heads: the pool is packed, [L, 1, P, ps, heads * D] (see the
+        module's head); None: head-major.
     Returns [C, H, D].
     """
     C, H, D = q.shape
-    KVH = k_pages.shape[1]
+    KVH = heads or k_pages.shape[1]
     if scale is None:
         scale = D**-0.5
     kernel_ok = use_pallas() and D % _LANES == 0 and H % KVH == 0
     if force_xla or not kernel_ok:
-        return _chunk_reference(q, k_pages, v_pages, page_table,
-                                start, total, layer, scale)
+        return _chunk_reference(q, k_pages, v_pages, page_table, start,
+                                total, layer, scale, window, first, heads)
+    meta = (start, total, layer) + (() if window is None else (first,))
     return platform_dispatch(
-        lambda *a: _chunk_pallas(*a, scale),
+        lambda *a: _chunk_pallas(*a, scale, window, heads),
         lambda q, kp, vp, pt, _m: _chunk_reference(
-            q, kp, vp, pt, start, total, layer, scale),
+            q, kp, vp, pt, start, total, layer, scale, window, first, heads),
         q, k_pages, v_pages, page_table,
-        jnp.stack([jnp.asarray(x, jnp.int32) for x in (start, total, layer)]),
+        jnp.stack([jnp.asarray(x, jnp.int32) for x in meta]),
     )
 
 
@@ -588,6 +672,7 @@ def paged_attention_decode(
     force_xla: bool = False,
     mesh=None,
     tp_axis: str = "tp",
+    window: int | None = None,
 ) -> jax.Array:
     """One decode step of attention over a paged KV cache.
 
@@ -600,9 +685,15 @@ def paged_attention_decode(
       force_xla: skip the Pallas kernel entirely (tests/debug).
       mesh/tp_axis: tensor-parallel serving: under tp>1 the kernel runs
         inside shard_map over the tp axis (see _batched).
+      window: static; only the last `window` of the `lengths` keys are
+        seen, and `page_table` may be a ring (see the module's head).
     Returns [B, H, D].
     """
-    return _batched(_paged_pallas, _paged_reference, q, k_pages, v_pages,
+    pallas_fn, reference_fn = _paged_pallas, _paged_reference
+    if window is not None:
+        pallas_fn = functools.partial(_paged_pallas, window=window)
+        reference_fn = functools.partial(_paged_reference, window=window)
+    return _batched(pallas_fn, reference_fn, q, k_pages, v_pages,
                     page_table, lengths, layer, scale, force_xla, mesh,
                     tp_axis)
 

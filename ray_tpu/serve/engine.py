@@ -46,7 +46,7 @@ from ..core.config import config
 from ..core.logging import get_logger
 from ..core.metrics import Counter, Gauge, Histogram
 from ..util import slo, tracing
-from ..models import ModelConfig
+from ..models import ModelConfig, stack
 from ..models.transformer import (
     _dense_ffn,
     _embed_lookup,
@@ -159,6 +159,22 @@ _slot_active = _m_slot_steps.labels(state="active")
 _slot_empty = _m_slot_steps.labels(state="empty")
 _pages_reserved = _m_page_steps.labels(state="reserved")
 _pages_written = _m_page_steps.labels(state="written")
+# a stack of unlike layers (models/stack.py) has two pools: the pages of
+# its full-attention layer, and the window layers' ring per decode slot
+_pages_by_pool = {
+    (pool, st): _m_page_steps.labels(state=st, pool=pool)
+    for pool in ("full", "window") for st in ("reserved", "written")}
+_m_window_pages = Counter(
+    "serve_window_page_steps",
+    "Per window layer, summed over engine iterations: pages that active "
+    "sequences hold keys in (state=held) against the most they may, "
+    "active sequences x (window / page_size + 1) (state=bound).")
+_window_held = _m_window_pages.labels(state="held")
+_window_bound = _m_window_pages.labels(state="bound")
+_m_state_slots = Counter(
+    "serve_state_slots_installed",
+    "Decode slots whose recurrent and window state a prefilled sequence "
+    "overwrote at install (the slot's reset).")
 _deferred_no_pages = _m_deferred.labels(reason="no_pages")
 _front_inbound = _m_front.labels(leg="inbound")
 _front_outbound = _m_front.labels(leg="outbound")
@@ -308,6 +324,10 @@ class EngineConfig:
 
     @property
     def pages_per_seq(self) -> int:
+        """Width of a sequence's page table in THE pool (`max_pages`): every
+        layer's pages for the one-block models, the full-attention layer's
+        for a stack of unlike layers, whose window layers hold a fixed ring
+        per decode slot beside it (models/stack.py: ring_pages)."""
         return -(-self.max_seq_len // self.page_size)
 
     def prefill_tiers(self) -> List[int]:
@@ -470,7 +490,7 @@ class _ChunkState:
     """One long prompt mid-chunked-prefill."""
 
     __slots__ = ("request", "pages", "table", "true_len", "next_chunk",
-                 "emitted_upto", "sink_seq")
+                 "emitted_upto", "sink_seq", "state")
 
     def __init__(self, request: Request, pages: List[int], table, true_len: int):
         self.request = request
@@ -482,6 +502,9 @@ class _ChunkState:
         # (page-aligned except after the final frame) and the frame seq
         self.emitted_upto = 0
         self.sink_seq = 0
+        # a stack with recurrent state: what the chunks so far left behind
+        # (stack.new_request_state), handed from chunk to chunk
+        self.state = None
 
 
 class _Slot:
@@ -637,6 +660,25 @@ class InferenceEngine:
         L, KVH, hd = model_cfg.n_layers, model_cfg.kv_heads, model_cfg.hdim
         P, ps = engine_cfg.max_pages, engine_cfg.page_size
         dtype = jnp.dtype(engine_cfg.cache_dtype)
+        # A stack of unlike layers: THE pool holds its full-attention
+        # layers alone, packed (a token's KV heads in one row); conv tails, scan
+        # state and the window layers' rings are `self.state`, per decode
+        # slot, sized by max_batch_size and the model.
+        self.state = None
+        if self._stack:
+            self._refuse_for_stack(mesh, engine_cfg)
+            L, KVH, hd = model_cfg.count("full"), 1, model_cfg.pool_row
+            self.state = stack.new_engine_state(
+                model_cfg, B, ps, jnp.dtype(model_cfg.dtype), dtype)
+            # a sequence's start, shared by every chunked prompt's first
+            # chunk (never donated: a chunk hands back a new state)
+            self._request_start = stack.new_request_state(
+                model_cfg, 1, jnp.dtype(model_cfg.dtype))
+            self._install_state = jax.jit(
+                tracing.named(functools.partial(
+                    stack.install_state, cfg=model_cfg, page_size=ps),
+                    "install_state"),
+                donate_argnums=(0,))
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
 
@@ -663,9 +705,12 @@ class InferenceEngine:
             self.k_pages = jnp.zeros((L, KVH, P, ps, hd), dtype)
             self.v_pages = jnp.zeros((L, KVH, P, ps, hd), dtype)
         self.allocator = PageAllocator(P)
+        # off by derivation where layers keep recurrent state: a page hit
+        # without the state at that boundary would be wrong
         self.prefix = (PrefixCache(ps)
                        if engine_cfg.prefix_caching
-                       and engine_cfg.chunked_prefill else None)
+                       and engine_cfg.chunked_prefill
+                       and not model_cfg.has_state else None)
         self.slots = [_Slot() for _ in range(B)]
         self.pending: "queue.Queue[Request]" = queue.Queue()
         self._step_count = 0
@@ -731,6 +776,37 @@ class InferenceEngine:
     # own phases (it cannot import this module: this one imports it)
     phase = staticmethod(decode_phase)
 
+    @property
+    def _stack(self) -> bool:
+        return self.cfg.is_stack
+
+    def _refuse_for_stack(self, mesh, ecfg: EngineConfig) -> None:
+        """What assumes that pages are the whole state of a request, or
+        one block in every layer, and is not made right for a stack of
+        unlike layers yet: refused here, with the reason."""
+        name = self.cfg.name
+        if mesh is not None:
+            raise ValueError(
+                f"{name!r}: a stack of unlike layers has no sharding rules "
+                "yet (models/stack.py); serve it on one chip, mesh=None")
+        scfg = ecfg.speculation
+        if scfg is not None and scfg.enabled:
+            raise ValueError(
+                f"{name!r}: speculative decoding rewinds rejected drafts by "
+                "position alone; recurrent and window state cannot be "
+                "rewound that way. Serve it with speculation off")
+
+    def _refuse_kv_transfer(self, what: str) -> None:
+        if self._stack:
+            raise ValueError(
+                f"{what}: {self.cfg.name!r} keeps state beside its pages "
+                "(conv tails, scan state, window rings) that the KV wire "
+                "does not carry; disaggregated roles and KV export/import "
+                "are refused for it")
+
+    def _state_args(self) -> tuple:
+        return (self.state,) if self._stack else ()
+
     # ------------------------------------------------------------- compiled
 
     def _build_decode(self):
@@ -746,8 +822,10 @@ class InferenceEngine:
         tp_mesh = self.mesh if self._tp > 1 else None
 
         def decode(params, k_pages, v_pages, tokens, positions, page_tables,
-                   temps, key, top_ps=None, top_ks=None, advanced=False):
-            """tokens/positions [B]; page_tables [B, pages_per_seq]."""
+                   temps, key, top_ps=None, top_ks=None, advanced=False,
+                   state=None):
+            """tokens/positions [B]; page_tables [B, pages_per_seq]; `state`
+            (a stack of unlike layers only): the engine's per-slot state."""
             dtype = jnp.dtype(cfg.dtype)
             B = tokens.shape[0]
             with jax.named_scope("embed"):
@@ -757,7 +835,7 @@ class InferenceEngine:
                 if cfg.positional == "learned":
                     x = x + params["pos_emb"][positions][:, None].astype(dtype)
                     rope_tables = None
-                else:
+                elif cfg.positional == "rope":
                     rope_tables = rope_frequencies(
                         cfg.hdim, cfg.max_seq_len, cfg.rope_theta)
             pos2d = positions[:, None]
@@ -791,9 +869,15 @@ class InferenceEngine:
                 x = x + _ffn(x, lp, cfg)
                 return (x, kp, vp), None
 
-            (x, new_k, new_v), _ = jax.lax.scan(
-                body, (x, k_pages, v_pages),
-                (params["layers"], jnp.arange(cfg.n_layers)))
+            if cfg.is_stack:
+                x, new_k, new_v, state = stack.run_paged(
+                    params["layers"], x, cfg,
+                    stack.Decode(cfg, positions, page_tables, ps),
+                    (k_pages, v_pages), state)
+            else:
+                (x, new_k, new_v), _ = jax.lax.scan(
+                    body, (x, k_pages, v_pages),
+                    (params["layers"], jnp.arange(cfg.n_layers)))
             with jax.named_scope("lm_head"):
                 logits = _head_logits(x, lambda x: x[:, 0], params, cfg,
                                       "bd,dv->bv")
@@ -814,24 +898,26 @@ class InferenceEngine:
                 logps = jnp.take_along_axis(
                     jax.nn.log_softmax(logits, axis=-1),
                     toks[:, None].astype(jnp.int32), axis=-1)[:, 0]
-            return toks, logps, new_k, new_v
+            return toks, logps, new_k, new_v, state
 
         def decode_span(params, k_pages, v_pages, tokens, positions,
-                        page_tables, temps, top_ps, top_ks, key, n_steps,
-                        advanced):
+                        page_tables, temps, top_ps, top_ks, key, state=None,
+                        *, n_steps, advanced):
             def sub(carry, i):
-                toks_in, pos, kp, vp = carry
+                toks_in, pos, kp, vp, st = carry
                 ki = jax.random.fold_in(key, i)
-                toks, lps, kp, vp = decode(
+                toks, lps, kp, vp, st = decode(
                     params, kp, vp, toks_in, pos, page_tables, temps, ki,
-                    top_ps, top_ks, advanced,
+                    top_ps, top_ks, advanced, st,
                 )
-                return (toks, pos + 1, kp, vp), (toks, lps)
+                return (toks, pos + 1, kp, vp, st), (toks, lps)
 
-            (_, _, kp, vp), (seq, logps) = jax.lax.scan(
-                sub, (tokens, positions, k_pages, v_pages), jnp.arange(n_steps)
+            (_, _, kp, vp, state), (seq, logps) = jax.lax.scan(
+                sub, (tokens, positions, k_pages, v_pages, state),
+                jnp.arange(n_steps)
             )
-            return seq, logps, kp, vp  # seq/logps [n_steps, B]
+            # seq/logps [n_steps, B]; a stack's state rides last
+            return (seq, logps, kp, vp) + (() if state is None else (state,))
 
         cache: Dict[Any, Any] = {}
 
@@ -847,7 +933,7 @@ class InferenceEngine:
                                           advanced=advanced),
                         f"decode_span_{n_steps}"
                         + ("_adv" if advanced else "")),
-                    donate_argnums=(1, 2),
+                    donate_argnums=(1, 2, 10) if self._stack else (1, 2),
                 ))
             return cache[key_]
 
@@ -869,13 +955,16 @@ class InferenceEngine:
         tp_force_xla = self._tp > 1
 
         def chunk_step(params, k_pages, v_pages, tokens, start, page_table,
-                       last_idx, export=False):
+                       last_idx, state=None, export=False):
             """tokens [C]; start/last_idx scalars; page_table [pps].
             Returns (logits_at_last_idx, k_pages, v_pages); with
             export=True (static) also the chunk's own KV slabs
             [L, C, KVH, hd] in the pool dtype, so streamed export ships
             this chunk without a separate page-gather dispatch (which
-            would queue behind whatever decode span is in flight)."""
+            would queue behind whatever decode span is in flight). `state`
+            (a stack of unlike layers only): what the sequence's chunks so
+            far left behind; the chunk's own comes back last. Rows past
+            last_idx are padding."""
             dtype = jnp.dtype(cfg.dtype)
             C = tokens.shape[0]
             positions = start + jnp.arange(C)
@@ -885,9 +974,19 @@ class InferenceEngine:
                 if cfg.positional == "learned":
                     x = x + params["pos_emb"][positions][None].astype(dtype)
                     rope_tables = None
-                else:
+                elif cfg.positional == "rope":
                     rope_tables = rope_frequencies(
                         cfg.hdim, cfg.max_seq_len, cfg.rope_theta)
+            if cfg.is_stack:
+                x, new_k, new_v, state = stack.run_paged(
+                    params["layers"], x, cfg,
+                    stack.Seq(cfg, n_valid=(last_idx + 1)[None], keep=True,
+                              chunk=(start, page_table), page_size=ps),
+                    (k_pages, v_pages), state)
+                with jax.named_scope("lm_head"):
+                    logits = _head_logits(x, lambda x: x[0, last_idx],
+                                          params, cfg, "d,dv->v")
+                return logits, new_k, new_v, state
             page_idx = page_table[positions // ps]  # [C]
             slot_idx = positions % ps
 
@@ -940,6 +1039,8 @@ class InferenceEngine:
         def for_chunk(C: int, export: bool = False):
             key = (C, export)
             if key not in cache:
+                if export:
+                    self._refuse_kv_transfer("chunk export")
                 cache[key] = self._under_mesh(jax.jit(
                     tracing.named(
                         functools.partial(chunk_step, export=export),
@@ -999,26 +1100,39 @@ class InferenceEngine:
             # Both sampler modes compile: the first top-p/top-k request
             # must not jit inside the decode loop under live traffic.
             for advanced in (False, True):
-                seq, _lps, self.k_pages, self.v_pages = self._decode(
-                    span, advanced)(
+                seq = self._run_decode(self._decode(span, advanced)(
                     self.params, self.k_pages, self.v_pages,
                     jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
                     jnp.zeros((B, pps), jnp.int32),
                     jnp.zeros((B,), jnp.float32),
                     jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.int32),
-                    jax.random.PRNGKey(0),
-                )
+                    jax.random.PRNGKey(0), *self._state_args(),
+                ))[0]
                 _np.asarray(seq)  # block until compiled + executed
         if self.ecfg.chunked_prefill:
             C = self.ecfg.prefill_chunk
-            logits, self.k_pages, self.v_pages = self._chunk_fn(C)(
+            logits, self.k_pages, self.v_pages, *_ = self._chunk_fn(C)(
                 self.params, self.k_pages, self.v_pages,
                 jnp.zeros((C,), jnp.int32), jnp.int32(0),
                 jnp.zeros((pps,), jnp.int32), jnp.int32(C - 1),
+                *((self._request_start,) if self._stack else ()),
             )
             _np.asarray(logits)
+            if self._stack:  # and the program that hands a slot its state
+                self.state = self._install_state(
+                    self.state, self._request_start, jnp.int32(0),
+                    jnp.int32(1))
         if self._spec is not None:
             self._spec.warmup()
+
+    def _run_decode(self, out) -> tuple:
+        """Take back what a decode program was handed by donation: the
+        pool and, for a stack of unlike layers, the per-slot state.
+        -> (seq, logps)."""
+        seq, logps, self.k_pages, self.v_pages, *state = out
+        if state:
+            self.state = state[0]
+        return seq, logps
 
     def _prefill_fn(self, bucket: int, batch: int = 1):
         key = (bucket, batch)
@@ -1029,6 +1143,12 @@ class InferenceEngine:
                 # the module stays `jit_run` (the benchmark's trace_names
                 # keys on it); the scope says which shape class it is
                 with jax.named_scope(f"prefill_bucket_{bucket}x{batch}"):
+                    if cfg.is_stack:
+                        x, cache = stack.prefill(params, cfg, tokens, true_len)
+                        at = (true_len - 1)[:, None, None].astype(jnp.int32)
+                        return _head_logits(
+                            x, lambda x: jnp.take_along_axis(x, at, 1)[:, 0],
+                            params, cfg, "bd,dv->bv"), cache
                     return prefill(params, cfg, tokens, max_len=bucket,
                                    last_index=true_len - 1)
 
@@ -1084,6 +1204,7 @@ class InferenceEngine:
         """Block until a prefill_only request finishes and return its KV
         blob (see _export_blob). The blob is engine-agnostic: it can be
         imported into a pool with a different page_size/max_pages."""
+        self._refuse_kv_transfer("export_kv_pages")
         if not req.done.wait(timeout_s):
             self.cancel(req.request_id)
             raise TimeoutError(f"request {req.request_id} timed out")
@@ -1226,6 +1347,7 @@ class InferenceEngine:
         import_kv_pages' failure contract). `meta` carries the frame-0
         header fields (layers/kv_heads/head_dim/dtype)."""
         try:
+            self._refuse_kv_transfer("begin_kv_import")
             req.stop = _normalize_stops(req.stop)
         except ValueError as e:
             self._finish_request(req, error=str(e))
@@ -1413,6 +1535,7 @@ class InferenceEngine:
         PREFILL thread, which would prefill the prompt a second time and
         append a duplicate first token."""
         try:
+            self._refuse_kv_transfer("import_kv_pages")
             req.stop = _normalize_stops(req.stop)
         except ValueError as e:
             self._finish_request(req, error=str(e))
@@ -1447,6 +1570,8 @@ class InferenceEngine:
     def add_request(self, req: Request) -> None:
         try:
             req.stop = _normalize_stops(req.stop)
+            if req.prefill_only:
+                self._refuse_kv_transfer("prefill_only request")
         except ValueError as e:
             self._finish_request(req, error=str(e))
             return
@@ -1835,10 +1960,9 @@ class InferenceEngine:
                     req._emit(int(first))
                 if i in streamed:
                     continue  # frames pushed below; never parks in _ready
-                row_cache = {
-                    "k": cache["k"][:, i:i + 1],
-                    "v": cache["v"][:, i:i + 1],
-                }
+                # every leaf has the batch on axis 1 (a stack's cache also
+                # holds its state beside pages)
+                row_cache = jax.tree.map(lambda a: a[:, i:i + 1], cache)
                 self._ready.append((req, pages, row_cache, T))
         self._work.set()  # revive the decode thread if it is idle-waiting
         if streamed:
@@ -1960,8 +2084,17 @@ class InferenceEngine:
                 self._finish_request(req, "prefill_done")
                 installed = True
                 continue
-            if cache is not None:  # chunked prefills wrote pages directly
+            # chunked prefills wrote pages directly
+            if cache is not None and "k" in cache:
                 self._scatter_prefill(cache, pages, T)
+            slot = free_slots[0]
+            if self._stack:
+                # the slot's reset: the sequence's conv tails, scan state
+                # and window keys overwrite what the last occupant left
+                self.state = self._install_state(
+                    self.state, {n: cache[n] for n in self.state},
+                    jnp.int32(self.slots.index(slot)), jnp.int32(T))
+                _m_state_slots.inc()
             if self.prefix is not None:
                 # the prompt's full pages are now valid: offer them to the
                 # cache so later prompts sharing the prefix skip prefill
@@ -1969,7 +2102,6 @@ class InferenceEngine:
                 hashes = getattr(req, "_page_hashes", None)
                 with self._alloc_lock:
                     self.prefix.register(req.prompt, pages, hashes=hashes)
-            slot = free_slots[0]
             slot.request = req
             slot.pages = pages
             slot.position = T  # the sampled token will be written at T
@@ -2023,10 +2155,15 @@ class InferenceEngine:
             )
             chunk_kv = (ck, cv, start)
         else:
-            logits, self.k_pages, self.v_pages = self._chunk_fn(C)(
+            if self._stack and st.state is None:
+                st.state = self._request_start
+            logits, self.k_pages, self.v_pages, *state = self._chunk_fn(C)(
                 self.params, self.k_pages, self.v_pages, jnp.asarray(padded),
                 jnp.int32(start), jnp.asarray(st.table), jnp.int32(last_idx),
+                *(() if st.state is None else (st.state,)),
             )
+            if state:
+                st.state = state[0]
         st.next_chunk += 1
         if not is_last:
             if streaming:
@@ -2080,8 +2217,9 @@ class InferenceEngine:
             self._finish_request(req, "prefill_done")
             return True
         with self._ready_lock:
-            # cache=None: this prompt's KV is already in its pages
-            self._ready.append((req, st.pages, None, st.true_len))
+            # cache=None: this prompt's KV is already in its pages (a
+            # stack's state beside pages still has to reach its slot)
+            self._ready.append((req, st.pages, st.state, st.true_len))
         return True
 
     def step(self) -> bool:
@@ -2147,13 +2285,13 @@ class InferenceEngine:
             # below commits span tokens per slot where the S-wide verify
             # would commit exactly one
         with decode_phase("dispatch") as ph:
-            seq, logps, self.k_pages, self.v_pages = self._decode(
-                span, advanced)(
+            seq, logps = self._run_decode(self._decode(span, advanced)(
                 self.params, self.k_pages, self.v_pages,
                 jnp.asarray(tokens), jnp.asarray(positions),
                 jnp.asarray(tables), jnp.asarray(temps),
                 jnp.asarray(top_ps), jnp.asarray(top_ks), key,
-            )
+                *self._state_args(),
+            ))
         _step_phase["verify", "plain"].observe(ph.elapsed_s)
         with decode_phase("readback") as ph:
             seq = np.asarray(seq)  # [span, B] — one readback per span
@@ -2244,8 +2382,26 @@ class InferenceEngine:
             for _req, pages, _cache, T in self._ready:
                 reserved += len(pages)
                 written += -(-T // ps)
-        _pages_reserved.inc(reserved)
-        _pages_written.inc(written)
+        if not self._stack:
+            _pages_reserved.inc(reserved)
+            _pages_written.inc(written)
+            return
+        _pages_by_pool["full", "reserved"].inc(reserved)
+        _pages_by_pool["full", "written"].inc(written)
+        # window layers: a slot owns its ring whatever it holds; what an
+        # active sequence holds keys in are the pages its window spans
+        if self.cfg.count("window"):
+            ring = stack.ring_pages(self.cfg, ps)
+            W = self.cfg.window
+            held = sum((s.position - 1) // ps - max(s.position - W, 0) // ps
+                       + 1 for s in self.slots
+                       if s.request is not None and s.position > 0)
+            n_active = sum(1 for s in self.slots if s.request is not None)
+            _window_held.inc(held)
+            _window_bound.inc(n_active * ring)
+            layers = self.cfg.count("window")
+            _pages_by_pool["window", "reserved"].inc(n_active * ring * layers)
+            _pages_by_pool["window", "written"].inc(held * layers)
 
     def _step_spec(self, tokens, positions, tables, temps, top_ps, top_ks,
                    advanced, key, n_active) -> bool:
@@ -2548,6 +2704,12 @@ class InferenceEngine:
             "chunk_queue": chunk_queue,
             "chunking": chunking,
             "waiting_for_pages": waiting,
+            # pages of THE pool (EngineConfig.pages_per_seq says whose)
+            "page_pool": ("full-attention layers" if self._stack
+                          else "every layer"),
+            **({"window_ring_pages": stack.ring_pages(
+                self.cfg, self.ecfg.page_size)}
+               if self._stack and self.cfg.count("window") else {}),
             "free_pages": free_pages + prefix.get("reusable_pages", 0),
             **prefix,
             "steps": self._step_count,
@@ -2632,8 +2794,14 @@ def _head_logits(x, pick, params, cfg: ModelConfig, einsum: str):
     rows `pick` keeps."""
     x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum(einsum, pick(x).astype(jnp.float32),
-                        head.astype(jnp.float32))
+    if cfg.tie_embeddings and head.dtype != jnp.float32:
+        # the tied table as it is stored, accumulated in f32: a float32
+        # copy of a 200k-row table is 2 GB a step
+        logits = jnp.einsum(einsum, pick(x).astype(head.dtype), head,
+                            preferred_element_type=jnp.float32)
+    else:
+        logits = jnp.einsum(einsum, pick(x).astype(jnp.float32),
+                            head.astype(jnp.float32))
     if cfg.logits_softcap:
         logits = cfg.logits_softcap * jnp.tanh(logits / cfg.logits_softcap)
     return logits

@@ -74,6 +74,11 @@ class LLMServer:
         else:
             cfg = get_config(model_name, **(model_overrides or {}))
             params = init_params(cfg, jax.random.PRNGKey(0))
+        if role != "colocated" and cfg.is_stack:
+            raise ValueError(
+                f"role={role!r}: {cfg.name!r} keeps state beside its KV "
+                "pages (conv tails, scan state, window rings) that the KV "
+                "wire does not carry; serve it colocated")
         engine_config = dict(engine_config or {})
         if speculation is not None:
             if engine_config.get("speculation") is not None:

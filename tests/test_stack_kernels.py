@@ -1,0 +1,187 @@
+"""The kernels a stack of unlike layers runs on the chip, in Pallas
+interpret mode against their XLA forms: the paged decode and chunk kernels
+with a window bound (a ring of pages in decode), at the width of a
+differential pair (two 64-wide heads side by side in one 128-wide row), and
+the selective scan with its one-token decode form."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import paged_attention as pa
+from ray_tpu.ops import ssm
+
+TOL = 5e-6  # float32, same arithmetic in another order
+
+
+@pytest.fixture(autouse=True)
+def pallas_everywhere(monkeypatch):
+    monkeypatch.setenv("RAY_TPU_FORCE_PALLAS", "1")
+
+
+def keys(n):
+    return jax.random.split(jax.random.PRNGKey(28), n)
+
+
+H, KVH, D, PS, W = 8, 2, 128, 4, 8
+RING = W // PS + 1
+
+
+@pytest.mark.parametrize("lengths", [(1, 3, 8), (9, 12, 13), (30, 41, 57)])
+def test_paged_decode_window_over_a_ring(lengths):
+    """Below the window, at its edge, and after the ring has lapped."""
+    k = keys(3)
+    B = len(lengths)
+    kp = jax.random.normal(k[0], (2, KVH, 1 + B * RING, PS, D))
+    vp = jax.random.normal(k[1], (2, KVH, 1 + B * RING, PS, D))
+    q = jax.random.normal(k[2], (B, H, D))
+    table = (1 + jnp.arange(B)[:, None] * RING
+             + jnp.arange(RING)[None]).astype(jnp.int32)
+    lens = jnp.asarray(lengths, jnp.int32)
+    got = jax.jit(lambda *a: pa.paged_attention_decode(
+        *a, layer=1, window=W, scale=0.125))(q, kp, vp, table, lens)
+    want = pa._paged_reference(q, kp, vp, table, lens, 1, 0.125, window=W)
+    np.testing.assert_allclose(got, want, atol=TOL)
+    # and against the keys laid out by position, no ring, no pages
+    for b, n in enumerate(lengths):
+        pos = range(max(0, n - W), n)
+        kk = jnp.stack([kp[1, :, table[b, (p // PS) % RING], p % PS]
+                        for p in pos], 1)
+        vv = jnp.stack([vp[1, :, table[b, (p // PS) % RING], p % PS]
+                        for p in pos], 1)
+        s = jnp.einsum("cgd,ctd->cgt", q[b].reshape(KVH, H // KVH, D), kk) / 8
+        o = jnp.einsum("cgt,ctd->cgd", jax.nn.softmax(s, -1), vv)
+        np.testing.assert_allclose(got[b], o.reshape(H, D), atol=TOL)
+
+
+@pytest.mark.parametrize("first", [0, 3, 8])
+def test_paged_chunk_window_over_the_kept_tail(first):
+    """The chunk program's window layers: tail and chunk side by side as a
+    little pool, rows `first`.. the sequence's own."""
+    k = keys(3)
+    C = 8
+    n = (W + C) // PS
+    kb = jax.random.normal(k[0], (1, KVH, n, PS, D))
+    vb = jax.random.normal(k[1], (1, KVH, n, PS, D))
+    q = jax.random.normal(k[2], (C, H, D))
+    table = jnp.arange(n, dtype=jnp.int32)
+    got = jax.jit(lambda q, kb, vb, f: pa.paged_attention_chunk(
+        q, kb, vb, table, W, W + C, 0, scale=0.125, window=W, first=f))(
+            q, kb, vb, jnp.int32(first))
+    want = pa._chunk_reference(q, kb, vb, table, W, W + C, 0, 0.125, W, first)
+    np.testing.assert_allclose(got, want, atol=TOL)
+
+
+def test_a_differential_pair_rides_one_row():
+    """[q1 ; 0] and [0 ; q2] against rows [k1 ; k2], [v1 ; v2] give the two
+    softmaxes of differential attention over 64-wide heads, in one pass."""
+    k = keys(5)
+    B, hd, n = 2, 64, 11
+    q1, q2 = (jax.random.normal(k[i], (B, hd)) for i in (0, 1))
+    kk = jax.random.normal(k[2], (B, n, 2 * hd))
+    vv = jax.random.normal(k[3], (B, n, 2 * hd))
+    pages = -(-n // PS)
+    pad = jnp.zeros((B, pages * PS - n, 2 * hd))
+
+    def pool(x):  # [B,n,128] -> [1,1,1+B*pages,PS,128]
+        x = jnp.concatenate([x, pad], 1).reshape(B * pages, PS, 2 * hd)
+        return jnp.concatenate([jnp.zeros((1, PS, 2 * hd)), x])[None, None]
+
+    zero = jnp.zeros_like(q1)
+    q = jnp.stack([jnp.concatenate([q1, zero], -1),
+                   jnp.concatenate([zero, q2], -1)], 1)  # [B,2,128]
+    table = (1 + jnp.arange(B)[:, None] * pages
+             + jnp.arange(pages)[None]).astype(jnp.int32)
+    got = jax.jit(lambda *a: pa.paged_attention_decode(
+        *a, layer=0, scale=hd ** -0.5))(q, pool(kk), pool(vv), table,
+                                        jnp.full((B,), n, jnp.int32))
+    for h, (qh, half) in enumerate(((q1, slice(0, hd)), (q2, slice(hd, None)))):
+        s = jnp.einsum("bd,btd->bt", qh, kk[..., half]) / hd ** 0.5
+        want = jnp.einsum("bt,btd->bd", jax.nn.softmax(s, -1), vv)
+        np.testing.assert_allclose(got[:, h], want, atol=TOL)
+
+
+def _scan_inputs(B, T, Di, N):
+    k = keys(7)
+    return (jax.random.normal(k[0], (B, T, Di)),
+            jax.nn.softplus(jax.random.normal(k[1], (B, T, Di))),
+            -jnp.exp(jax.random.normal(k[2], (N, Di))),
+            jax.random.normal(k[3], (B, T, N)),
+            jax.random.normal(k[4], (B, T, N)),
+            jax.random.normal(k[5], (Di,)),
+            jax.random.normal(k[6], (B, N, Di)))
+
+
+@pytest.mark.parametrize("T,Di", [(8, 128), (32, 256), (256, 512)])
+def test_ssm_scan_kernel_against_the_plain_scan(T, Di):
+    """One time chunk, several, and several inner-width blocks: the state
+    stays resident between chunks and comes out with the last."""
+    args = _scan_inputs(2, T, Di, 4)
+    y, s1 = jax.jit(ssm.ssm_scan)(*args)
+    y_ref, s_ref = ssm.ssm_scan_reference(*args)
+    np.testing.assert_allclose(y, y_ref, atol=2e-5)
+    np.testing.assert_allclose(s1, s_ref, atol=2e-5)
+
+
+def test_ssm_scan_passes_over_padding():
+    """dt = 0 leaves the state as it was: how padded positions are skipped."""
+    u, dt, A, Bm, Cm, D, s0 = _scan_inputs(1, 16, 128, 4)
+    dt = dt.at[:, 11:].set(0.0)
+    _, s1 = jax.jit(ssm.ssm_scan)(u, dt, A, Bm, Cm, D, s0)
+    _, want = ssm.ssm_scan_reference(u[:, :11], dt[:, :11], A, Bm[:, :11],
+                                     Cm[:, :11], D, s0)
+    np.testing.assert_allclose(s1, want, atol=TOL)
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+def test_ssm_step_updates_one_layer_in_place(layer):
+    u, dt, A, Bm, Cm, D, _ = _scan_inputs(8, 1, 256, 4)
+    state = jax.random.normal(keys(9)[8], (3, 8, 4, 256))
+    args = (state, jnp.int32(layer), u[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], D)
+    y, new = jax.jit(ssm.ssm_step)(*args)
+    y_ref, new_ref = ssm.ssm_step_reference(*args)
+    np.testing.assert_allclose(y, y_ref, atol=TOL)
+    np.testing.assert_allclose(new, new_ref, atol=TOL)
+    others = np.asarray([l for l in range(3) if l != layer])
+    np.testing.assert_array_equal(np.asarray(new)[others],
+                                  np.asarray(state)[others])
+
+
+def test_paged_chunk_over_a_packed_pool_reads_its_heads_tile():
+    """[L, 1, P, ps, heads * D]: kv head c is lanes c * D .. of every row."""
+    k = keys(3)
+    C, P = 8, 7
+    kp = jax.random.normal(k[0], (2, 1, P, PS, KVH * D))
+    vp = jax.random.normal(k[1], (2, 1, P, PS, KVH * D))
+    q = jax.random.normal(k[2], (C, H, D))
+    table = jnp.asarray([3, 1, 5, 2, 0, 0], jnp.int32)
+    got = jax.jit(lambda q, kp, vp: pa.paged_attention_chunk(
+        q, kp, vp, table, 8, 16, 1, heads=KVH))(q, kp, vp)
+    unpack = lambda x: x.reshape(2, P, PS, KVH, D).transpose(0, 3, 1, 2, 4)  # noqa: E731
+    want = pa._chunk_reference(q, unpack(kp), unpack(vp), table, 8, 16, 1,
+                               D ** -0.5)
+    np.testing.assert_allclose(got, want, atol=TOL)
+
+
+def test_decode_over_a_packed_pool_is_one_program_a_sequence():
+    """models/stack.py's Decode: a query head zero outside its kv head's
+    lanes against whole packed rows gives what the head-major pool gives."""
+    from ray_tpu.models import get_config, stack
+
+    cfg = get_config("tiny-sambay", n_heads=8, n_kv_heads=4, head_dim=64)
+    k = keys(3)
+    B, P, pages = 3, 9, 4
+    KVHp, Dp = cfg.pool_heads, cfg.pool_dim  # 2 pairs of 128
+    kp = jax.random.normal(k[0], (1, KVHp, P, PS, Dp))
+    vp = jax.random.normal(k[1], (1, KVHp, P, PS, Dp))
+    q = jax.random.normal(k[2], (B, cfg.n_heads, Dp))
+    table = jnp.asarray([[1, 2, 3, 4], [5, 6, 0, 0], [7, 8, 0, 0]], jnp.int32)
+    lens = jnp.asarray([13, 5, 8], jnp.int32)
+    want = pa._paged_reference(q, kp, vp, table, lens, 0, 0.125)
+    pack = lambda x: x.transpose(0, 2, 3, 1, 4).reshape(1, 1, P, PS, KVHp * Dp)  # noqa: E731
+    mode = stack.Decode(cfg, lens - 1, table, PS)
+    got = jax.jit(lambda q, kp, vp: mode._own(pa.paged_attention_decode(
+        mode._wide(q), kp, vp, table, lens, 0, scale=0.125)))(
+            q, pack(kp), pack(vp))
+    np.testing.assert_allclose(got, want, atol=TOL)

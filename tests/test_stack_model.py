@@ -1,0 +1,296 @@
+"""A stack of unlike layers (models/stack.py, `tiny-sambay`) on the serving
+engine, against the plain reference of its family
+(benchmark/reference/sambay.py: float32, `highest`, no kernel, no cache,
+nothing imported from the program), on seeded weights.
+
+Tolerances. Weights are the family's bfloat16 draws cast to float32 and the
+tiny model runs in float32, so program and reference differ only in the
+order of float32 sums (CPU matmuls at default precision against `highest`,
+one-pass against blockwise softmax, a carried state against one scan):
+log-probabilities agree to LOGPROB_TOL. The control rounds the same weights
+to fp8 and must land far outside it."""
+
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import common
+from benchmark.tests.tiny import tiny_spec
+from ray_tpu.models import forward, get_config, init_params
+from ray_tpu.models import stack
+from ray_tpu.serve.engine import EngineConfig, InferenceEngine, Request
+
+# float32 on both sides: 2e-5 is 50x the largest difference seen over the
+# cases below (3.8e-7, engine against reference); the fp8 control reads
+# 1.2e-2 at the most and 5.3e-3 rms
+LOGPROB_TOL = 2e-5
+WINDOW, PAGE = 16, 4  # the tiny cut's window; pages of 4: a ring of 5
+STATE_GAIN = 8.0
+
+
+@pytest.fixture(scope="module")
+def model():
+    spec = tiny_spec("phi-4-mini-flash")
+    family = common.family(spec)
+    params = jax.tree.map(
+        lambda a: a.astype(jnp.float32),
+        jax.jit(lambda k: family.init_weights(spec, k))(jax.random.PRNGKey(28)))
+    # At 64 wide the scan state hardly reaches the logits (2.5e-7: a test
+    # of conv tails, scan state and slot reset would have no teeth); with
+    # the Mamba input and B / C / dt projections 8x larger, dropping the
+    # state path moves them by 0.15, as much as they are large.
+    params["layers"] = [
+        tuple({n: w * (STATE_GAIN if n in ("m_in", "m_x") else 1.0)
+               for n, w in lp.items()} for lp in segment)
+        for segment in params["layers"]]
+    cfg = family.model_config(spec, dtype="float32")
+    assert cfg.window == WINDOW
+    return spec, family, cfg, params
+
+
+def engine_for(cfg, params, **kw):
+    ecfg = dict(max_batch_size=2, page_size=PAGE, max_pages=96, max_seq_len=96,
+                prefill_buckets=(8, 16), prefill_chunk=16, decode_span=4,
+                busy_span=2, cache_dtype="float32")
+    ecfg.update(kw)
+    return InferenceEngine(params, cfg, EngineConfig(**ecfg))
+
+
+def reference_logprobs(model, prompt, output, mode=None):
+    """log-softmax of the reference's logits at the positions that predict
+    `output`, in one cache-less pass over prompt + output."""
+    spec, family, _, params = model
+    seq = list(prompt) + list(output)
+    padded = np.zeros((-(-len(seq) // family.PAD_TO) * family.PAD_TO,), np.int32)
+    padded[:len(seq)] = seq
+    at = len(prompt) - 1 + np.arange(len(output))
+    logits = np.asarray(family.logits_at(params, jnp.asarray(padded),
+                                         jnp.asarray(at), spec, mode), np.float64)
+    return logits - np.log(np.exp(logits - logits.max(-1, keepdims=True))
+                           .sum(-1, keepdims=True)) - logits.max(-1, keepdims=True)
+
+
+def prompts(n, lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(3, 256, t).tolist() for t in lengths[:n]]
+
+
+def test_forward_agrees_with_the_plain_reference(model):
+    spec, family, cfg, params = model
+    tokens = np.asarray(prompts(1, [family.PAD_TO])[0], np.int32)
+    got, _ = jax.jit(lambda p, t: forward(p, t, cfg))(params, tokens[None])
+    at = np.arange(len(tokens))
+    want = family.logits_at(params, jnp.asarray(tokens), jnp.asarray(at), spec)
+    # logits, every position: past the window, the gmu and the cross layers
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want),
+                               atol=LOGPROB_TOL, rtol=0)
+
+
+@pytest.mark.parametrize("path,length", [
+    ("bucket", 5),     # one bucket, shorter than the window
+    ("bucket", 16),    # a whole bucket: the window exactly full
+    ("chunked", 21),   # two chunks, the last one padded, past the window
+    ("chunked", 48),   # three whole chunks
+])
+def test_prefill_and_decode_agree_with_the_plain_reference(model, path, length):
+    """Both prefill paths, then 30 decoded tokens: the decode crosses the
+    window's edge (position 16), page boundaries (every 4) and laps the
+    ring (5 pages = 20 positions)."""
+    _, _, cfg, params = model
+    eng = engine_for(cfg, params)
+    try:
+        assert (length > eng.ecfg.prefill_chunk) == (path == "chunked")
+        prompt = prompts(1, [length], seed=length)[0]
+        out = eng.generate(prompt, max_tokens=30)
+    finally:
+        eng.stop()
+    want = reference_logprobs(model, prompt, out["token_ids"])
+    served = np.asarray(out["logprobs"])
+    picked = want[np.arange(30), out["token_ids"]]
+    assert np.abs(served - picked).max() < LOGPROB_TOL
+    # greedy: the served token is the reference's best (or within rounding)
+    assert (want.max(-1) - picked).max() < LOGPROB_TOL
+
+
+def test_two_requests_share_a_batch_and_a_slot_is_reused(model):
+    """Three requests on two slots: two decode side by side, the third
+    takes the slot of whichever finishes first, so its state must be the
+    third's own (install overwrites conv tails, scan state and the ring)."""
+    _, _, cfg, params = model
+    eng = engine_for(cfg, params)
+    try:
+        ps = prompts(3, [11, 19, 7], seed=3)
+        budgets = [6, 24, 26]
+        reqs = [Request(request_id=f"r{i}", prompt=p, max_tokens=m)
+                for i, (p, m) in enumerate(zip(ps, budgets))]
+        for r in reqs:
+            eng.add_request(r)
+        for r in reqs:
+            assert r.done.wait(300) and r.error is None
+        installed = common.counters().get(
+            ("serve_state_slots_installed", ()), 0)
+    finally:
+        eng.stop()
+    assert installed >= 3
+    for r, p in zip(reqs, ps):
+        want = reference_logprobs(model, p, r.output)
+        picked = want[np.arange(len(r.output)), r.output]
+        assert np.abs(np.asarray(r.output_logprobs) - picked).max() < LOGPROB_TOL
+
+
+def test_a_lower_precision_than_stated_fails(model):
+    """The control: the reference with fp8 weights in the program's place
+    lands far outside the tolerance that the program meets."""
+    prompt = prompts(1, [24], seed=9)[0]
+    output = prompts(1, [24], seed=10)[0]
+    exact = reference_logprobs(model, prompt, output)
+    low = reference_logprobs(model, prompt, output, mode="fp8")
+    at = np.arange(len(output))
+    err = np.abs(low[at, output] - exact[at, output])
+    assert np.sqrt(np.mean(err ** 2)) > 100 * LOGPROB_TOL
+
+
+def test_window_layers_hold_a_bounded_ring(model):
+    """Whatever the sequence's length a window layer holds window / page + 1
+    pages a slot: the pool's shape says so, and the counter pair never
+    reads held over bound."""
+    _, _, cfg, params = model
+    ring = WINDOW // PAGE + 1
+    eng = engine_for(cfg, params)
+    try:
+        assert stack.ring_pages(cfg, PAGE) == ring
+        assert eng.state["wk"].shape == (cfg.count("window"), 1,
+                                         1 + 2 * ring, PAGE, cfg.pool_row)
+        assert eng.k_pages.shape[0] == 1  # ONE full layer's pages
+        before = common.counters()
+        out = eng.generate(prompts(1, [30], seed=5)[0], max_tokens=50)
+        after = common.counters()
+        assert eng.stats()["window_ring_pages"] == ring
+        assert eng.stats()["page_pool"] == "full-attention layers"
+    finally:
+        eng.stop()
+    assert len(out["token_ids"]) == 50  # 80 positions through a 20-position ring
+    held = common.counter_delta(before, after, "serve_window_page_steps",
+                                state="held")
+    bound = common.counter_delta(before, after, "serve_window_page_steps",
+                                 state="bound")
+    assert 0 < held <= bound
+    full = common.counter_delta(before, after, "serve_kv_page_steps",
+                                pool="full", state="reserved")
+    window = common.counter_delta(before, after, "serve_kv_page_steps",
+                                  pool="window", state="reserved")
+    assert full > 0 and window > 0
+
+
+# -- what assumes that pages are a request's whole state refuses -------------
+
+
+def test_prefix_hits_are_off_by_derivation(model):
+    _, _, cfg, params = model
+    eng = engine_for(cfg, params, prefix_caching=True)
+    try:
+        assert eng.prefix is None
+        assert eng.prefix_digest()["hashes"] == []
+    finally:
+        eng.stop()
+
+
+REFUSALS = {
+    "speculation": lambda cfg, p: engine_for(
+        cfg, p, speculation={"mode": "ngram", "num_speculative_tokens": 2}),
+    "mesh": lambda cfg, p: InferenceEngine(
+        p, cfg, EngineConfig(page_size=PAGE), mesh=jax.sharding.Mesh(
+            np.array(jax.devices()[:1]), ("tp",))),
+    "param_axes": lambda cfg, p: __import__(
+        "ray_tpu.models", fromlist=["param_axes"]).param_axes(cfg),
+    "generate": lambda cfg, p: __import__(
+        "ray_tpu.models", fromlist=["prefill"]).prefill(
+            p, cfg, jnp.zeros((1, 4), jnp.int32), 8),
+}
+
+
+@pytest.mark.parametrize("what", sorted(REFUSALS))
+def test_refused_at_construction_with_the_reason(model, what):
+    _, _, cfg, params = model
+    with pytest.raises((ValueError, NotImplementedError),
+                       match="stack of unlike layers|recurrent"):
+        REFUSALS[what](cfg, params)
+
+
+@pytest.mark.parametrize("call", ["prefill_only", "import_kv_pages",
+                                  "begin_kv_import", "export_kv_pages"])
+def test_kv_transfer_is_refused_at_the_call(model, call):
+    _, _, cfg, params = model
+    eng = engine_for(cfg, params)
+    req = Request(request_id="x", prompt=[3, 4, 5], max_tokens=2,
+                  prefill_only=(call == "prefill_only"))
+    try:
+        if call == "prefill_only":
+            eng.add_request(req)
+        elif call == "import_kv_pages":
+            eng.import_kv_pages(req, {})
+        elif call == "begin_kv_import":
+            assert eng.begin_kv_import(req, 3, {}) is False
+        else:
+            with pytest.raises(ValueError, match="state beside its pages"):
+                eng.export_kv_pages(req)
+            return
+        assert req.done.is_set() and "state beside its pages" in req.error
+    finally:
+        eng.stop()
+
+
+def test_a_disaggregated_role_is_refused():
+    from ray_tpu.serve.llm import LLMServer
+
+    with pytest.raises(ValueError, match="serve it colocated"):
+        LLMServer._target(model_name="tiny-sambay", role="prefill")
+
+
+def test_registered_configs_count_and_segment():
+    big = get_config("phi4-mini-flash")
+    assert round(big.param_count() / 1e9, 2) == 3.85
+    assert big.segments() == (
+        (0, ("mamba", "window"), 8), (16, ("mamba",), 1),
+        (17, ("full",), 1), (18, ("gmu", "cross"), 7))
+    tiny = get_config("tiny-sambay")
+    params = init_params(tiny, jax.random.PRNGKey(0))
+    assert sum(a.size for a in jax.tree.leaves(params)) == tiny.param_count()
+    assert [r for _, _, r in tiny.segments()] == [3, 1, 1, 2]
+    # the one-block models' counts, a tied LayerNorm'd one included
+    for name in ("tiny-gpt2", "tiny-llama", "tiny-moe"):
+        cfg = get_config(name)
+        tree = init_params(cfg, jax.random.PRNGKey(0))
+        assert sum(a.size for a in jax.tree.leaves(tree)) == cfg.param_count()
+
+
+# -- the one-block families are untouched ------------------------------------
+
+PARENT_OUTPUTS = {  # tokens and log-probabilities on the parent (00b9113)
+    "tiny-llama": "250841512fd4b4a6c499cc813c075d3ea6ec788314129bda363e9b5b25b23ef1",
+    "tiny-gpt2": "7f331bb85af78aef6035861468f81ed4b26c5707a16e2d5470def17eea86eb86",
+    "tiny-moe": "caa7e68b31f3d0c341101403d6e3259c317ab490462598cf483a6305cd8c5898",
+}
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_OUTPUTS))
+def test_old_families_serve_the_parents_tokens_and_logprobs(name):
+    cfg = get_config(name)
+    params = init_params(cfg, jax.random.PRNGKey(7))
+    eng = InferenceEngine(params, cfg, EngineConfig(
+        max_batch_size=4, page_size=4, max_pages=64, max_seq_len=96,
+        prefill_buckets=(8, 16), prefill_chunk=8, decode_span=4, busy_span=2))
+    rng = np.random.default_rng(28)
+    h = hashlib.sha256()
+    try:
+        for T in (5, 13, 22):
+            r = eng.generate(rng.integers(3, cfg.vocab_size, T).tolist(),
+                             max_tokens=9)
+            h.update(np.asarray(r["token_ids"], np.int64).tobytes())
+            h.update(np.asarray(r["logprobs"], np.float64).tobytes())
+    finally:
+        eng.stop()
+    assert h.hexdigest() == PARENT_OUTPUTS[name]
