@@ -411,6 +411,7 @@ def kernel_cases(cfg, page_size: int, seq: int, batch: int = 8,
         paged_attention_chunk,
         paged_attention_decode,
         paged_attention_verify,
+        pool_shape,
         rms_norm,
         rms_norm_reference,
     )
@@ -443,8 +444,8 @@ def kernel_cases(cfg, page_size: int, seq: int, batch: int = 8,
     n_pages = batch * pages_per_seq + 1
     # a pool of two layers; the ops attend over its last one
     layer = 1
-    k_pages = rand(2, KVH, n_pages, page_size, D)
-    v_pages = rand(2, KVH, n_pages, page_size, D)
+    k_pages = rand(*pool_shape(2, n_pages, page_size, KVH, D))
+    v_pages = rand(*pool_shape(2, n_pages, page_size, KVH, D))
     rng = np.random.default_rng(seed)
     table = jnp.asarray(
         rng.permutation(n_pages - 1)[: batch * pages_per_seq].reshape(

@@ -60,6 +60,12 @@ class ModelConfig:
     def is_moe(self) -> bool:
         return self.num_experts > 0
 
+    @property
+    def cache_dims(self) -> Tuple[int, int, int]:
+        """(layers, kv heads, head size) of the keys and values a serving
+        cache holds for this model."""
+        return self.n_layers, self.kv_heads, self.hdim
+
     # what StackConfig (below) answers otherwise
     is_stack = False
     has_state = False
@@ -145,10 +151,9 @@ class StackConfig(ModelConfig):
         return self.hdim * 2
 
     @property
-    def pool_row(self) -> int:
-        """A token's row in a cache: every KV head side by side (the
-        packed layout of ops/paged_attention.py)."""
-        return self.pool_heads * self.pool_dim
+    def cache_dims(self) -> Tuple[int, int, int]:
+        """The full-attention layers alone, a differential pair a head."""
+        return self.count("full"), self.pool_heads, self.pool_dim
 
     def segments(self) -> Tuple[Tuple[int, Tuple[str, ...], int], ...]:
         """The stack as runs of whole periods: (first layer, the period's

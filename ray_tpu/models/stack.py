@@ -27,12 +27,10 @@ Differential attention rides on the plain kernels: a KV pair is stored as
 one row [k1 ; k2] (and [v1 ; v2]) of twice the head size, and a query head
 is padded with zeros on the side of the other softmax, so `[q1 ; 0]` scores
 against k1 alone and `[0 ; q2]` against k2, and one pass over the pages
-feeds both softmaxes. The engine's pools are PACKED: a token's row holds
-all its KV pairs side by side ([.., 1, pages, page_size, pairs * 128]), so
-a page is one DMA and a decode call one grid program a sequence (a call's
-time is mostly a fixed cost per grid program); in decode a query head is
-zero outside its own pair's lanes, the chunk kernel reads its pair's tile
-of each page. Serve only: no sharding rules, no training path.
+feeds both softmaxes. The pools and rings are the page pool of
+ops/paged_attention.py (a token's KV pairs side by side in one row), whose
+ops take the plain queries and pairs of this file: the layout is theirs.
+Serve only: no sharding rules, no training path.
 """
 
 from __future__ import annotations
@@ -46,6 +44,7 @@ from ..ops import (
     flash_attention,
     paged_attention_chunk,
     paged_attention_decode,
+    pool_shape,
     write_then_attend,
 )
 from ..ops.ssm import ssm_scan, ssm_step
@@ -151,12 +150,13 @@ def new_request_state(cfg: ModelConfig, batch: int, dtype) -> Params:
 def new_engine_state(cfg: ModelConfig, batch: int, page_size: int,
                      act_dtype, cache_dtype) -> Params:
     """What the engine holds for `batch` decode slots beside the page pool:
-    conv tails and scan state per slot, and for the window layers a packed
-    pool [W, 1, 1 + batch * ring, page_size, KVH * D] in which slot b owns
-    pages 1 + b * ring .. (page 0 is never read)."""
+    conv tails and scan state per slot, and for the window layers a page
+    pool of 1 + batch * ring pages in which slot b owns pages
+    1 + b * ring .. (page 0 is never read)."""
     st = new_request_state(cfg, batch, act_dtype)
-    pool = (cfg.count("window"), 1, 1 + batch * ring_pages(cfg, page_size),
-            page_size, cfg.pool_row)
+    pool = pool_shape(cfg.count("window"),
+                      1 + batch * ring_pages(cfg, page_size), page_size,
+                      cfg.pool_heads, cfg.pool_dim)
     return {"conv": st["conv"], "ssm": st["ssm"],
             "wk": jnp.zeros(pool, cache_dtype),
             "wv": jnp.zeros(pool, cache_dtype)}
@@ -174,9 +174,10 @@ def install_state(state: Params, rs: Params, slot, length,
     pos = r + span * jnp.floor_divide(length - 1 - r, span)
     at = jnp.clip(pos - (length - cfg.window), 0, cfg.window - 1)
 
-    def image(tail):  # [W,1,window,KVH,D] -> packed [W,1,ring,ps,KVH*D]
+    def image(tail):  # [W,1,window,KVH,D] -> the ring's pages
         img = tail[:, 0][:, at]
-        return img.reshape(img.shape[0], 1, ring, page_size, cfg.pool_row)
+        return img.reshape(pool_shape(img.shape[0], ring, page_size,
+                                      cfg.pool_heads, cfg.pool_dim))
 
     out = dict(state)
     for name in ("conv", "ssm"):
@@ -214,11 +215,6 @@ def _dense_attend(q, k, v, scale, window=None):
         o = jnp.einsum("bcgqk,bkcd->bqcgd", jax.nn.softmax(s, axis=-1),
                        v.astype(_F32))
         return o.reshape(B, T, H, D).astype(q.dtype)
-
-
-def _row(x):
-    """[..., KVH, D] -> [..., 1, KVH * D]: a token's row of a packed pool."""
-    return x.reshape(*x.shape[:-2], 1, -1)
 
 
 class Seq:
@@ -310,8 +306,9 @@ class Seq:
             carry = {**carry, name: carry[name].at[wi, 0].set(
                 jax.lax.dynamic_slice_in_dim(buf, self.n_valid[0], W, 0)
                 .astype(carry[name].dtype))}
-        pool = [b.reshape(n_pages, self.ps, *b.shape[1:])
-                .transpose(2, 0, 1, 3)[None] for b in bufs]
+        # [W+C, KVH, D] holds the pool's own rows in order: no copy
+        pool = [b.reshape(pool_shape(1, n_pages, self.ps, *b.shape[1:]))
+                for b in bufs]
         o = paged_attention_chunk(
             q[0], *pool, jnp.arange(n_pages, dtype=jnp.int32), W, W + C, 0,
             scale=scale, window=W, first=jnp.maximum(W - start, 0))
@@ -330,15 +327,14 @@ class Seq:
 
         def attend(q, kp, vp, layer):
             return paged_attention_chunk(q, kp, vp, table, start, start + C,
-                                         layer, scale=scale,
-                                         heads=self.cfg.pool_heads)
+                                         layer, scale=scale)
 
         kp, vp = carry["k_pages"], carry["v_pages"]
         if k is None:
             return attend(q[0], kp, vp, fi)[None].astype(q.dtype), carry
         pos = start + jnp.arange(C)
         o, kp, vp = write_then_attend(
-            attend, q[0], _row(k[0]), _row(v[0]), kp, vp, fi,
+            attend, q[0], k[0], v[0], kp, vp, fi,
             table[pos // self.ps], pos % self.ps)
         return o[None].astype(q.dtype), {**carry, "k_pages": kp, "v_pages": vp}
 
@@ -384,9 +380,9 @@ class Decode:
                 window=self.cfg.window)
 
         o, wk, wv = write_then_attend(
-            attend, self._wide(q[:, 0]), _row(k[:, 0]), _row(v[:, 0]),
+            attend, q[:, 0], k[:, 0], v[:, 0],
             carry["wk"], carry["wv"], wi, page[:, 0], self.pos % self.ps)
-        return self._own(o)[:, None], {**carry, "wk": wk, "wv": wv}
+        return o[:, None], {**carry, "wk": wk, "wv": wv}
 
     def attend_full(self, carry, fi, q, k, v, scale):
         def attend(q, kp, vp, layer):
@@ -395,31 +391,13 @@ class Decode:
 
         kp, vp = carry["k_pages"], carry["v_pages"]
         if k is None:
-            return self._own(attend(self._wide(q[:, 0]), kp, vp, fi))[:, None], carry
+            return attend(q[:, 0], kp, vp, fi)[:, None], carry
         B = q.shape[0]
         o, kp, vp = write_then_attend(
-            attend, self._wide(q[:, 0]), _row(k[:, 0]), _row(v[:, 0]), kp, vp,
-            fi, self.tables[jnp.arange(B), self.pos // self.ps],
+            attend, q[:, 0], k[:, 0], v[:, 0], kp, vp, fi,
+            self.tables[jnp.arange(B), self.pos // self.ps],
             self.pos % self.ps)
-        return self._own(o)[:, None], {**carry, "k_pages": kp, "v_pages": vp}
-
-    # a packed pool is ONE kv head as wide as all of them: a query head is
-    # zero outside its own kv head's lanes, and of the output row it keeps
-    # those lanes
-    def _lanes(self, H):
-        KVH = self.cfg.pool_heads
-        return jnp.arange(H)[:, None] // (H // KVH) == jnp.arange(KVH)[None, :]
-
-    def _wide(self, q):  # [B,H,D] -> [B,H,KVH*D]
-        B, H, D = q.shape
-        own = self._lanes(H).astype(q.dtype)
-        return (q[:, :, None, :] * own[None, :, :, None]).reshape(B, H, -1)
-
-    def _own(self, o):  # [B,H,KVH*D] -> [B,H,D]
-        B, H, _ = o.shape
-        KVH = self.cfg.pool_heads
-        own = self._lanes(H).astype(o.dtype)
-        return jnp.einsum("bhcd,hc->bhd", o.reshape(B, H, KVH, -1), own)
+        return o[:, None], {**carry, "k_pages": kp, "v_pages": vp}
 
 
 # ---------------------------------------------------------------------------
@@ -585,12 +563,10 @@ def prefill(params: Params, cfg: ModelConfig, tokens: jax.Array,
             true_len: jax.Array):
     """The engine's bucket prefill: tokens [B,T] right-padded, true_len [B].
     -> (hidden state [B,T,D] before the final norm, cache): the full
-    layers' keys and values `k`, `v` [F,B,T,1,KVH*D] and the state of
+    layers' keys and values `k`, `v` [F,B,T,KVH,D] and the state of
     new_request_state, every leaf with the batch on axis 1."""
     x = _embed(params, tokens, cfg)
     mode = Seq(cfg, n_valid=true_len, keep=True)
     x, carry = run_stack(params["layers"], x, cfg, mode, mode.init_carry(x))
     carry.pop("mem")
-    for name in ("k", "v"):  # rows as the engine's packed pool holds them
-        carry[name] = _row(carry[name])
     return x, carry

@@ -17,8 +17,11 @@ from .attention import flash_attention, mha_reference  # noqa: F401
 from .norm import layer_norm, rms_norm, rms_norm_reference  # noqa: F401
 from .rope import apply_rope, rope_frequencies  # noqa: F401
 from .paged_attention import (  # noqa: F401
+    gather_pages,
     paged_attention_chunk,
     paged_attention_decode,
     paged_attention_verify,
+    pool_shape,
+    scatter_pages,
     write_then_attend,
 )

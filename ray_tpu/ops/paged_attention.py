@@ -8,15 +8,27 @@ dot products — decode is bandwidth-bound, so overlapping the page fetch is
 the whole game. XLA fallback gathers pages (simple, memory-hungry) for CPU
 tests and odd shapes.
 
-Cache layout: k_pages / v_pages are the WHOLE pool,
-[L, KVH, num_pages, page_size, D] — layer, then head major, so one
-(layer, head, page) slab is a contiguous [page_size, D] DMA. Every op
-takes the pool whole and a `layer` index (a traced scalar inside the
-engine's layer scan): the kernels fetch `pool[layer, head, page]` by DMA
-and the XLA references gather `pool[layer, :, page_table]`, so no caller
-ever holds one layer's slab as a value: a slab sliced out of the pool and
-put back costs a copy of the pool per decode step. The layer rides as the
-last entry of a kernel's second scalar-prefetch array.
+Cache layout, the ONE layout in the tree: k_pages / v_pages are the WHOLE
+pool, [L, 1, num_pages, page_size, KVH * D] — a token's kv heads side by
+side in one row, head c in lanes c*D .. (c+1)*D. A (layer, page) is then
+one contiguous [page_size, KVH*D] DMA, a token's keys ONE row to write, and
+decode runs as ONE grid program a sequence over the whole row, which
+matters because a decode call's time is mostly a fixed cost per grid
+program and a write's a fixed cost per row (PERF.md, PR 26, 28 and 29).
+The layout is this module's own business: callers hand the ops plain
+queries [.., H, D] and `write_then_attend` plain keys and values
+[.., KVH, D], and the number of kv heads is the row's width over q's D.
+The decode kernel widens each query head to the row inside VMEM (zero
+outside its kv head's lanes, `_wide`) and keeps of the output row those
+lanes (`_own`); the chunk and verify kernels read a pool head by head: one
+[page_size, D] tile of each page.
+
+Every op takes the pool whole and a `layer` index (a traced scalar inside
+the engine's layer scan): the kernels fetch `pool[layer, 0, page]` by DMA
+and the XLA references gather the ROWS `pool[layer, 0, page_table]`, so no
+caller ever holds one layer's slab as a value: a slab sliced out of the
+pool and put back costs a copy of the pool per decode step. The layer rides
+as the last entry of a kernel's second scalar-prefetch array.
 
 `window` (static; None: every cached key): a query at position p sees keys
 p - window + 1 .. p only. The kernels then start at the window's first page
@@ -25,15 +37,6 @@ RING of window / page_size + 1 pages that a sequence overwrites as it grows
 (the engine's window layers). A head may be as wide as two differential
 heads side by side: the ops take any D that is a multiple of 128 and an
 explicit `scale`.
-
-A pool may also hold ALL of a token's kv heads side by side in one row,
-[L, 1, P, ps, heads * D] ("packed"): a page is then one contiguous DMA,
-and decode runs as ONE grid program a sequence over the whole row (the
-caller pads each query head with zeros outside its kv head's lanes), which
-matters because a decode call's time is mostly a fixed cost per grid
-program (PERF.md, PR 26 and PR 28). The chunk op reads such a pool head by
-head (`heads=`): one [ps, D] tile of each page, as contiguous as a
-head-major page.
 """
 
 from __future__ import annotations
@@ -65,27 +68,71 @@ def _with_layer(scalars, layer):
         jnp.asarray(layer, jnp.int32).reshape(1)])
 
 
-def _gather_layer(pages, layer, page_table):
-    """pages [L,KVH,P,ps,D], page_table [..., n] -> that layer's pages
-    per kv head, contiguous: [..., KVH, n*ps, D]. One gather out of the
-    whole pool (the scalar `layer` and `page_table` index together, so
-    their dims lead): the layer's slab is never materialised."""
-    _, KVH, _, page_size, D = pages.shape
-    g = jnp.moveaxis(pages[layer, :, page_table], -3, -4)
-    return g.reshape(*page_table.shape[:-1], KVH,
-                     page_table.shape[-1] * page_size, D)
+def pool_shape(layers: int, num_pages: int, page_size: int, kv_heads: int,
+               head_dim: int) -> Tuple[int, ...]:
+    """The shape of a page pool: whoever allocates one asks here."""
+    return (layers, 1, num_pages, page_size, kv_heads * head_dim)
+
+
+def _row(x):
+    """[..., KVH, D] -> [..., KVH * D]: a token's row of the pool."""
+    return x.reshape(*x.shape[:-2], -1)
+
+
+def _lanes(shape, group, c):
+    """bool `shape` [.., H, D]: the query heads (rows) of kv head c, whose
+    lanes of a pool row are c*D .. (c+1)*D. Static in c: compares alone."""
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 2)
+    return (row >= c * group) & (row < (c + 1) * group)
+
+
+def _wide(q, kv_heads):
+    """[.., H, D] -> [.., H, KVH*D]: every query head as wide as a pool
+    row, zero outside its own kv head's lanes, so ONE product with a page's
+    rows scores each head against its own kv head alone. Runs on values
+    inside the decode kernel (VMEM: the wide form never touches HBM)."""
+    if kv_heads == 1:
+        return q
+    g = q.shape[-2] // kv_heads
+    return jnp.concatenate(
+        [jnp.where(_lanes(q.shape, g, c), q, 0) for c in range(kv_heads)],
+        axis=-1)
+
+
+def _own(o, kv_heads):
+    """[.., H, KVH*D] -> [.., H, D]: of each head's output row the lanes
+    of its own kv head (the others hold other heads' values)."""
+    D = o.shape[-1] // kv_heads
+    g = o.shape[-2] // kv_heads
+    out = o[..., :D]
+    for c in range(1, kv_heads):
+        part = o[..., c * D:(c + 1) * D]
+        out = jnp.where(_lanes(part.shape, g, c), part, out)
+    return out
+
+
+def _gather_rows(pages, layer, page_table, D):
+    """pages [L,1,P,ps,KVH*D], page_table [..., n] -> that layer's tokens
+    in table order per kv head, [..., KVH, n*ps, D]. One gather of ROWS
+    out of the whole pool (the scalar `layer` and `page_table` index
+    together, so their dims lead): neither the layer's slab nor a relaid
+    pool is ever materialised; what is split into heads is the gathered
+    tokens alone."""
+    page_size, row = pages.shape[3], pages.shape[4]
+    g = pages[layer, 0, page_table]  # [..., n, ps, KVH*D]
+    g = g.reshape(*page_table.shape[:-1],
+                  page_table.shape[-1] * page_size, row // D, D)
+    return jnp.moveaxis(g, -2, -3)
 
 
 def _paged_reference(q, k_pages, v_pages, page_table, lengths, layer, scale,
                      window=None):
     """Gather-based fallback. q [B,H,D] -> o [B,H,D]."""
     B, H, D = q.shape
-    KVH, page_size = k_pages.shape[1], k_pages.shape[3]
-    g = H // KVH
-    ctx = page_table.shape[1] * page_size
-    kg = _gather_layer(k_pages, layer, page_table)  # [B, KVH, ctx, D]
-    vg = _gather_layer(v_pages, layer, page_table)
-    qf = q.reshape(B, KVH, g, D).astype(jnp.float32)
+    kg = _gather_rows(k_pages, layer, page_table, D)  # [B, KVH, ctx, D]
+    vg = _gather_rows(v_pages, layer, page_table, D)
+    KVH, ctx = kg.shape[1], kg.shape[2]
+    qf = q.reshape(B, KVH, H // KVH, D).astype(jnp.float32)
     s = jnp.einsum("bcgd,bctd->bcgt", qf, kg.astype(jnp.float32)) * scale
     if window is None:
         mask = jnp.arange(ctx)[None, :] < lengths[:, None]
@@ -105,20 +152,21 @@ def _paged_reference(q, k_pages, v_pages, page_table, lengths, layer, scale,
 def _flash_page_loop(
     q2d, n_pages, page_id_fn, mask_fn, layer, c,
     k_hbm, v_hbm, k_buf, v_buf, acc_ref, m_ref, l_ref, sem_ref,
-    *, page_size, scale, packed=False,
+    *, page_size, scale,
 ):
-    """The shared double-buffered page-DMA flash loop: stream this layer
-    and kv head's pages HBM->VMEM two-deep while the MXU runs the
-    online-softmax update for q2d [rows, D]. Kernels differ only in how a loop index
-    maps to a page id (page_id_fn) and in the validity mask
-    (mask_fn(i) -> [rows, page_size] bool); everything else — slot
-    rotation, the exp-underflow guard, the l==0 epilogue division — is
-    one implementation serving both decode and chunk prefill."""
+    """The shared double-buffered page-DMA flash loop: stream this layer's
+    pages HBM->VMEM two-deep while the MXU runs the online-softmax update
+    for q2d [rows, W]. `c` None: whole rows of the pool (W = KVH*D, the
+    decode kernel's widened queries); else kv head c's lanes of every row
+    (W = D). Kernels differ besides only in how a loop index maps to a
+    page id (page_id_fn) and in the validity mask (mask_fn(i) ->
+    [rows, page_size] bool); everything else — slot rotation, the
+    exp-underflow guard, the l==0 epilogue division — is one
+    implementation serving decode, chunk prefill and verify."""
 
     def page_of(pool, page):
-        if not packed:
-            return pool.at[layer, c, page]
-        # a packed pool: head c is lanes c * D .. of every row
+        if c is None:
+            return pool.at[layer, 0, page]
         D = q2d.shape[-1]
         return pool.at[layer, 0, page, :, pl.ds(pl.multiple_of(c * D, D), D)]
 
@@ -191,18 +239,20 @@ def _paged_kernel(
     o_ref,
     # scratch
     k_buf, v_buf, acc_ref, m_ref, l_ref, sem_ref,
-    *, page_size, pages_per_seq, scale, batch, window=None,
+    *, page_size, pages_per_seq, scale, batch, kv_heads, window=None,
 ):
+    """One sequence's decode attention, every head at once: the query
+    heads [H, D] are widened to the pool's row, so a page is ONE DMA and
+    one pair of products whatever the number of kv heads."""
     b = pl.program_id(0)
-    c = pl.program_id(1)
-    g = q_ref.shape[2]
+    H = q_ref.shape[1]
     length = len_ref[b]
     layer = len_ref[batch]
     n_pages = jax.lax.div(length + page_size - 1, page_size)
     if window is None:
         def mask(i):
             pos = i * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, (g, page_size), 1)
+                jnp.int32, (H, page_size), 1)
             return pos < length
 
         def page_id(i):
@@ -214,7 +264,7 @@ def _paged_kernel(
 
         def mask(i):
             pos = (page0 + i) * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, (g, page_size), 1)
+                jnp.int32, (H, page_size), 1)
             return (pos < length) & (pos >= first)
 
         def page_id(i):
@@ -222,78 +272,65 @@ def _paged_kernel(
                           + jax.lax.rem(page0 + i, pages_per_seq)]
 
     out = _flash_page_loop(
-        q_ref[0, 0].astype(jnp.float32), n_pages,
-        page_id, mask, layer, c,
+        _wide(q_ref[0].astype(jnp.float32), kv_heads), n_pages,
+        page_id, mask, layer, None,
         k_hbm, v_hbm, k_buf, v_buf, acc_ref, m_ref, l_ref, sem_ref,
         page_size=page_size, scale=scale,
     )
-    o_ref[0, 0] = out.astype(o_ref.dtype)
+    o_ref[0] = _own(out, kv_heads).astype(o_ref.dtype)
 
 
 def _paged_pallas(q, k_pages, v_pages, page_table, lengths_layer, scale,
                   window=None):
     """lengths_layer s32[B+1]: the B lengths, then the layer index."""
     B, H, D = q.shape
-    KVH, page_size = k_pages.shape[1], k_pages.shape[3]
-    g = H // KVH
+    page_size, row = k_pages.shape[3], k_pages.shape[4]
     pages_per_seq = page_table.shape[1]
-    q4 = q.reshape(B, KVH, g, D)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KVH),
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, 1, g, D), lambda b, c, *_: (b, c, 0, 0)),
+            pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, D), lambda b, c, *_: (b, c, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, page_size, D), k_pages.dtype),
-            pltpu.VMEM((2, page_size, D), v_pages.dtype),
-            pltpu.VMEM((g, D), jnp.float32),
-            pltpu.VMEM((g, _LANES), jnp.float32),
-            pltpu.VMEM((g, _LANES), jnp.float32),
+            pltpu.VMEM((2, page_size, row), k_pages.dtype),
+            pltpu.VMEM((2, page_size, row), v_pages.dtype),
+            pltpu.VMEM((H, row), jnp.float32),
+            pltpu.VMEM((H, _LANES), jnp.float32),
+            pltpu.VMEM((H, _LANES), jnp.float32),
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(
             _paged_kernel, page_size=page_size, pages_per_seq=pages_per_seq,
-            scale=scale, batch=B, window=window,
+            scale=scale, batch=B, kv_heads=row // D, window=window,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KVH, g, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("parallel",),
         ),
         name="paged_decode" if window is None else "paged_decode_window",
         interpret=interpret_mode(),
-    )(page_table.reshape(-1), lengths_layer, q4, k_pages, v_pages)
-    return out.reshape(B, H, D)
-
-
-def _unpacked(pages, heads):
-    """[L,1,P,ps,heads*D] -> [L,heads,P,ps,D] (a copy: references only)."""
-    L, _, P, ps, row = pages.shape
-    return pages.reshape(L, P, ps, heads, row // heads).transpose(0, 3, 1, 2, 4)
+    )(page_table.reshape(-1), lengths_layer, q, k_pages, v_pages)
 
 
 def _chunk_reference(q, k_pages, v_pages, page_table, start, total, layer,
-                     scale, window=None, first=0, heads=None):
+                     scale, window=None, first=0):
     """Gather-based fallback for ONE sequence's prefill chunk.
     q [C,H,D] -> o [C,H,D]; key j visible to query row c iff
     j <= start + c and j < total (and, with a window, j > start + c -
     window and j >= first)."""
-    if heads is not None:
-        k_pages, v_pages = _unpacked(k_pages, heads), _unpacked(v_pages, heads)
     C, H, D = q.shape
-    KVH, page_size = k_pages.shape[1], k_pages.shape[3]
-    g = H // KVH
-    ctx = page_table.shape[0] * page_size
-    kg = _gather_layer(k_pages, layer, page_table)  # [KVH, ctx, D]
-    vg = _gather_layer(v_pages, layer, page_table)
-    qf = q.reshape(C, KVH, g, D).astype(jnp.float32)
+    kg = _gather_rows(k_pages, layer, page_table, D)  # [KVH, ctx, D]
+    vg = _gather_rows(v_pages, layer, page_table, D)
+    KVH, ctx = kg.shape[0], kg.shape[1]
+    qf = q.reshape(C, KVH, H // KVH, D).astype(jnp.float32)
     s = jnp.einsum("ckgd,ktd->ckgt", qf, kg.astype(jnp.float32)) * scale
     keypos = jnp.arange(ctx)
     qpos = start + jnp.arange(C)
@@ -317,14 +354,14 @@ def _chunk_kernel(
     o_ref,
     # scratch
     k_buf, v_buf, acc_ref, m_ref, l_ref, sem_ref,
-    *, page_size, scale, rows, group, window=None, packed=False,
+    *, page_size, scale, rows, group, window=None,
 ):
-    """One kv head's chunk attention: q block [rows=C*g, D] vs the
-    sequence's paged prefix (chunk KV already written into pages by the
-    caller). The shared _flash_page_loop with a per-ROW causal bound
-    instead of the decode kernel's one scalar length. With a window the
-    loop starts at the page of the first key any row sees (meta[3] and the
-    first row's window, whichever is later)."""
+    """One kv head's chunk attention: q block [rows=C*g, D] vs that head's
+    lanes of the sequence's paged prefix (chunk KV already written into
+    pages by the caller). The shared _flash_page_loop with a per-ROW
+    causal bound instead of the decode kernel's one scalar length. With a
+    window the loop starts at the page of the first key any row sees
+    (meta[3] and the first row's window, whichever is later)."""
     c = pl.program_id(0)
     start = meta_ref[0]
     total = meta_ref[1]
@@ -351,17 +388,16 @@ def _chunk_kernel(
         q_ref[0].astype(jnp.float32), n_pages,
         lambda i: pt_ref[page(i)], mask, layer, c,
         k_hbm, v_hbm, k_buf, v_buf, acc_ref, m_ref, l_ref, sem_ref,
-        page_size=page_size, scale=scale, packed=packed,
+        page_size=page_size, scale=scale,
     )
     o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _chunk_pallas(q, k_pages, v_pages, page_table, meta, scale, window=None,
-                  heads=None):
+def _chunk_pallas(q, k_pages, v_pages, page_table, meta, scale, window=None):
     """meta s32[3]: start, total, layer (with a window s32[4]: and the
-    first valid key). heads: the pool is packed with that many kv heads."""
+    first valid key)."""
     C, H, D = q.shape
-    KVH, page_size = heads or k_pages.shape[1], k_pages.shape[3]
+    page_size, KVH = k_pages.shape[3], k_pages.shape[4] // D
     g = H // KVH
     rows = C * g
     # [C,H,D] -> [KVH, C*g, D]: each kv head's q rows contiguous
@@ -388,7 +424,7 @@ def _chunk_pallas(q, k_pages, v_pages, page_table, meta, scale, window=None,
     out = pl.pallas_call(
         functools.partial(
             _chunk_kernel, page_size=page_size, scale=scale,
-            rows=rows, group=g, window=window, packed=heads is not None,
+            rows=rows, group=g, window=window,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((KVH, rows, D), q.dtype),
@@ -400,6 +436,18 @@ def _chunk_pallas(q, k_pages, v_pages, page_table, meta, scale, window=None,
     )(page_table, meta, qr, k_pages, v_pages)
     # [KVH, C*g, D] -> [C, H, D]
     return out.reshape(KVH, C, g, D).transpose(1, 0, 2, 3).reshape(C, H, D)
+
+
+def _kernel_ok(q, k_pages, tp=1):
+    """The kernels' shape gate: heads of whole 128-lane tiles, rows of
+    whole heads, query heads grouped evenly over them; under tp>1 each
+    shard holds whole kv heads (H = g*KVH makes H % tp == 0 follow)."""
+    H, D = q.shape[-2:]
+    row = k_pages.shape[-1]
+    if not use_pallas() or D % _LANES or row % D:
+        return False
+    KVH = row // D
+    return H % KVH == 0 and KVH % tp == 0
 
 
 def paged_attention_chunk(
@@ -414,7 +462,6 @@ def paged_attention_chunk(
     force_xla: bool = False,
     window: int | None = None,
     first=0,
-    heads: int | None = None,
 ) -> jax.Array:
     """Chunked-prefill attention for ONE sequence over its paged KV.
 
@@ -427,8 +474,8 @@ def paged_attention_chunk(
 
     Args:
       q: [C, H, D] — the chunk's queries (rope applied).
-      k_pages/v_pages: [L, KVH, num_pages, page_size, D], the whole pool
-        (chunk KV written).
+      k_pages/v_pages: [L, 1, num_pages, page_size, KVH * D], the whole
+        pool (chunk KV written).
       page_table: [pages_per_seq] int32 page ids for this sequence.
       start: scalar int — the chunk's first token position.
       total: scalar int — visibility cap (usually start + C).
@@ -436,23 +483,19 @@ def paged_attention_chunk(
       window / first: with a (static) window, row c also needs
         ``j > start + c - window`` and ``j >= first`` (scalar int: keys
         before it are not the sequence's).
-      heads: the pool is packed, [L, 1, P, ps, heads * D] (see the
-        module's head); None: head-major.
     Returns [C, H, D].
     """
     C, H, D = q.shape
-    KVH = heads or k_pages.shape[1]
     if scale is None:
         scale = D**-0.5
-    kernel_ok = use_pallas() and D % _LANES == 0 and H % KVH == 0
-    if force_xla or not kernel_ok:
+    if force_xla or not _kernel_ok(q, k_pages):
         return _chunk_reference(q, k_pages, v_pages, page_table, start,
-                                total, layer, scale, window, first, heads)
+                                total, layer, scale, window, first)
     meta = (start, total, layer) + (() if window is None else (first,))
     return platform_dispatch(
-        lambda *a: _chunk_pallas(*a, scale, window, heads),
+        lambda *a: _chunk_pallas(*a, scale, window),
         lambda q, kp, vp, pt, _m: _chunk_reference(
-            q, kp, vp, pt, start, total, layer, scale, window, first, heads),
+            q, kp, vp, pt, start, total, layer, scale, window, first),
         q, k_pages, v_pages, page_table,
         jnp.stack([jnp.asarray(x, jnp.int32) for x in meta]),
     )
@@ -463,12 +506,10 @@ def _verify_reference(q, k_pages, v_pages, page_table, positions, layer,
     """Gather-based fallback for speculative verify. q [B,S,H,D] ->
     o [B,S,H,D]; key j visible to query (b, s) iff j <= positions[b] + s."""
     B, S, H, D = q.shape
-    KVH, page_size = k_pages.shape[1], k_pages.shape[3]
-    g = H // KVH
-    ctx = page_table.shape[1] * page_size
-    kg = _gather_layer(k_pages, layer, page_table)  # [B, KVH, ctx, D]
-    vg = _gather_layer(v_pages, layer, page_table)
-    qf = q.reshape(B, S, KVH, g, D).astype(jnp.float32)
+    kg = _gather_rows(k_pages, layer, page_table, D)  # [B, KVH, ctx, D]
+    vg = _gather_rows(v_pages, layer, page_table, D)
+    KVH, ctx = kg.shape[1], kg.shape[2]
+    qf = q.reshape(B, S, KVH, H // KVH, D).astype(jnp.float32)
     s = jnp.einsum("bscgd,bctd->bscgt", qf, kg.astype(jnp.float32)) * scale
     keypos = jnp.arange(ctx)
     qpos = positions[:, None] + jnp.arange(S)[None, :]  # [B, S]
@@ -490,12 +531,12 @@ def _verify_kernel(
     k_buf, v_buf, acc_ref, m_ref, l_ref, sem_ref,
     *, page_size, pages_per_seq, scale, rows, group, span, batch,
 ):
-    """Speculative-verify attention for one (sequence, kv head): the
-    decode kernel generalized from one query token to a span of S=k+1
-    (last committed + k draft tokens, KV already written into the
-    sequence's pages by the caller). Same double-buffered page streaming;
-    the mask becomes the chunk kernel's per-ROW causal bound anchored at
-    this sequence's start position."""
+    """Speculative-verify attention for one (sequence, kv head): a span of
+    S=k+1 query tokens (last committed + k draft tokens, KV already
+    written into the sequence's pages by the caller) against that head's
+    lanes of the sequence's pages, as the chunk kernel reads them. Same
+    double-buffered page streaming; the mask is the chunk kernel's
+    per-ROW causal bound anchored at this sequence's start position."""
     b = pl.program_id(0)
     c = pl.program_id(1)
     start = pos_ref[b]
@@ -527,7 +568,7 @@ def _verify_kernel(
 def _verify_pallas(q, k_pages, v_pages, page_table, positions_layer, scale):
     """positions_layer s32[B+1]: the B positions, then the layer index."""
     B, S, H, D = q.shape
-    KVH, page_size = k_pages.shape[1], k_pages.shape[3]
+    page_size, KVH = k_pages.shape[3], k_pages.shape[4] // D
     g = H // KVH
     pages_per_seq = page_table.shape[1]
     rows = S * g
@@ -577,23 +618,15 @@ def _batched(pallas_fn, reference_fn, q, k_pages, v_pages, page_table,
     """What decode and verify share: the shape gate, the Pallas / XLA
     dispatch, and under tp>1 the shard_map wrap (a bare pallas_call
     cannot be partitioned by GSPMD: each shard runs the same kernel on
-    its contiguous block of q heads and kv heads, the pool sharded on its
-    KVH axis — requires tp | KVH, which the engine enforces; table and
-    scalars replicate). `per_seq` [B] is lengths or positions; q's heads
-    are its second-to-last axis."""
-    D = q.shape[-1]
-    KVH = k_pages.shape[1]
+    its contiguous block of q heads and on its kv heads' lanes of every
+    row, the pool sharded on its LAST axis — requires tp | KVH, which the
+    engine enforces; table and scalars replicate; whatever widens q does
+    so inside the body, on the shard's own row). `per_seq` [B] is lengths
+    or positions; q's heads are its second-to-last axis."""
     if scale is None:
-        scale = D**-0.5
+        scale = q.shape[-1]**-0.5
     tp = int(mesh.shape.get(tp_axis, 1)) if mesh is not None else 1
-    # tp | KVH is the only TP constraint: H = g*KVH makes H % tp == 0 follow
-    kernel_ok = (
-        use_pallas()
-        and D % _LANES == 0
-        and q.shape[-2] % KVH == 0
-        and (tp == 1 or KVH % tp == 0)
-    )
-    if force_xla or not kernel_ok:
+    if force_xla or not _kernel_ok(q, k_pages, tp):
         return reference_fn(q, k_pages, v_pages, page_table, per_seq, layer,
                             scale)
 
@@ -611,10 +644,11 @@ def _batched(pallas_fn, reference_fn, q, k_pages, v_pages, page_table,
     from jax.sharding import PartitionSpec as P
 
     heads = P(*[None] * (q.ndim - 2), tp_axis, None)
+    pool = P(None, None, None, None, tp_axis)
     return jax.shard_map(
         dispatch,
         mesh=mesh,
-        in_specs=(heads, P(None, tp_axis), P(None, tp_axis), P(), P()),
+        in_specs=(heads, pool, pool, P(), P()),
         # no collectives in the body; pallas_call outputs don't carry
         # vma annotations, so the varying-axes checker can't see through
         out_specs=heads,
@@ -645,15 +679,15 @@ def paged_attention_verify(
 
     Args:
       q: [B, S, H, D] — span queries per sequence (rope applied).
-      k_pages/v_pages: [L, KVH, num_pages, page_size, D], the whole pool
-        (span KV written).
+      k_pages/v_pages: [L, 1, num_pages, page_size, KVH * D], the whole
+        pool (span KV written).
       page_table: [B, pages_per_seq] int32 page ids.
       positions: [B] int32 — position of each sequence's row 0 (== its
         committed length; rows past a shorter draft are masked by the
         caller's accept logic, not here).
       layer: scalar int — which layer of the pool to attend over.
       mesh/tp_axis: tensor-parallel serving, same shard_map wrap as
-        paged_attention_decode (q heads + the pool's KVH axis sharded).
+        paged_attention_decode (q heads + the pool's rows sharded).
     Returns [B, S, H, D].
     """
     return _batched(_verify_pallas, _verify_reference, q, k_pages, v_pages,
@@ -678,7 +712,8 @@ def paged_attention_decode(
 
     Args:
       q: [B, H, D] — current token's query per sequence.
-      k_pages/v_pages: [L, KVH, num_pages, page_size, D], the whole pool.
+      k_pages/v_pages: [L, 1, num_pages, page_size, KVH * D], the whole
+        pool.
       page_table: [B, pages_per_seq] int32 page ids (unused tail arbitrary).
       lengths: [B] int32 valid context length per sequence.
       layer: scalar int — which layer of the pool to attend over.
@@ -701,25 +736,49 @@ def paged_attention_decode(
 def write_then_attend(attend, q, k, v, k_pages, v_pages, layer, page_idx,
                       slot_idx):
     """The one place a token's keys and values enter the pool: write
-    k, v [*idx, KVH, D] at ``[layer, :, page_idx, slot_idx]`` of the whole
-    pool (`page_idx` / `slot_idx` int32 [*idx]: one page slot per token),
-    then ``attend(q, k_pages, v_pages, layer)`` over the written pool.
-    Returns (o, k_pages, v_pages).
+    k, v [*idx, KVH, D] as the rows ``[layer, 0, page_idx, slot_idx]`` of
+    the whole pool (`page_idx` / `slot_idx` int32 [*idx]: one page slot
+    per token), then ``attend(q, k_pages, v_pages, layer)`` over the
+    written pool. Returns (o, k_pages, v_pages).
 
     Every layer body of the engine's programs calls this with the pool it
     CARRIES through its layer scan, so the write is a scatter into the
     carried buffer and the kernel reads that buffer by DMA: nothing holds
     a layer's slab, and XLA updates the one donated pool in place.
 
-    The write names every index of a row but the minor one: one [D] row
-    per (token, kv head), B x KVH rows. A write with a [KVH, D] window
-    (``.at[layer, :, page, slot]``) is fewer rows, but XLA's TPU layout
-    assignment then moves KVH next to D, the Pallas call demands the
-    default layout, and the pool is relaid (copied whole) before and
-    after every kernel call (PERF.md section 6, PR 26)."""
-    at = (layer, jnp.arange(k_pages.shape[1]),
-          page_idx[..., None], slot_idx[..., None])
+    ONE [KVH*D] row a token (a write costs about 100 ns a row whatever it
+    holds), and it names every index but the minor one. A window of two
+    axes (a head-major pool's ``.at[layer, :, page, slot]``) made XLA's
+    TPU layout assignment move the window next to the minor axis, the
+    Pallas call demands the default layout, and the pool was relaid
+    (copied whole) before and after every kernel call (PERF.md section 6,
+    PR 26)."""
+    at = (layer, 0, page_idx, slot_idx)
     with jax.named_scope("kv_write"):
-        k_pages = k_pages.at[at].set(k.astype(k_pages.dtype))
-        v_pages = v_pages.at[at].set(v.astype(v_pages.dtype))
+        k_pages = k_pages.at[at].set(_row(k).astype(k_pages.dtype))
+        v_pages = v_pages.at[at].set(_row(v).astype(v_pages.dtype))
     return attend(q, k_pages, v_pages, layer), k_pages, v_pages
+
+
+def gather_pages(k_pages, v_pages, page_arr, kv_heads):
+    """The pages `page_arr` [n] of every layer, token-contiguous:
+    k, v [L, n*ps, KVH, D]. A page's rows ARE its tokens: a gather and a
+    reshape, no transpose."""
+    def tokens(pages):
+        g = pages[:, 0, page_arr]  # [L, n, ps, KVH*D]
+        return g.reshape(g.shape[0], -1, kv_heads, g.shape[-1] // kv_heads)
+
+    return tokens(k_pages), tokens(v_pages)
+
+
+def scatter_pages(k_pages, v_pages, k, v, page_arr):
+    """k, v [L, T, KVH, D] -> the pages `page_arr` [n] of every layer:
+    the first n whole pages of tokens (T >= n * ps; a tail is left out)."""
+    n, ps = page_arr.shape[0], k_pages.shape[3]
+
+    def put(pages, x):
+        rows = _row(x[:, : n * ps])
+        rows = rows.reshape(rows.shape[0], n, ps, -1)
+        return pages.at[:, 0, page_arr].set(rows.astype(pages.dtype))
+
+    return put(k_pages, k), put(v_pages, v)

@@ -56,9 +56,12 @@ from ..models.transformer import (
 )
 from ..ops import (
     apply_rope,
+    gather_pages,
     paged_attention_chunk,
     paged_attention_decode,
+    pool_shape,
     rope_frequencies,
+    scatter_pages,
     write_then_attend,
 )
 from .config import SpeculationConfig
@@ -657,19 +660,21 @@ class InferenceEngine:
         self.mesh = mesh
         self._tp = 1
         B = engine_cfg.max_batch_size
-        L, KVH, hd = model_cfg.n_layers, model_cfg.kv_heads, model_cfg.hdim
+        # THE pool, one layout for every model (ops/paged_attention.py:
+        # a token's kv heads in one row): the layers that cache keys and
+        # values, which for a stack of unlike layers are its full-attention
+        # layers alone
+        KVH = model_cfg.cache_dims[1]
         P, ps = engine_cfg.max_pages, engine_cfg.page_size
-        dtype = jnp.dtype(engine_cfg.cache_dtype)
-        # A stack of unlike layers: THE pool holds its full-attention
-        # layers alone, packed (a token's KV heads in one row); conv tails, scan
-        # state and the window layers' rings are `self.state`, per decode
-        # slot, sized by max_batch_size and the model.
+        pool = self.abstract_pool()
+        # A stack of unlike layers: conv tails, scan state and the window
+        # layers' rings are `self.state`, per decode slot, sized by
+        # max_batch_size and the model.
         self.state = None
         if self._stack:
             self._refuse_for_stack(mesh, engine_cfg)
-            L, KVH, hd = model_cfg.count("full"), 1, model_cfg.pool_row
             self.state = stack.new_engine_state(
-                model_cfg, B, ps, jnp.dtype(model_cfg.dtype), dtype)
+                model_cfg, B, ps, jnp.dtype(model_cfg.dtype), pool.dtype)
             # a sequence's start, shared by every chunked prompt's first
             # chunk (never donated: a chunk hands back a new state)
             self._request_start = stack.new_request_state(
@@ -694,16 +699,18 @@ class InferenceEngine:
             self.params = jax.device_put(
                 params, tree_shardings(param_axes(model_cfg), mesh)
             )
+            # tp>1: each shard holds its kv heads' lanes of every row
             kv_sharding = NamedSharding(
                 mesh,
-                PartitionSpec(None, "tp" if self._tp > 1 else None),
+                PartitionSpec(None, None, None, None,
+                              "tp" if self._tp > 1 else None),
             )
-            self.k_pages = jax.device_put(jnp.zeros((L, KVH, P, ps, hd), dtype), kv_sharding)
-            self.v_pages = jax.device_put(jnp.zeros((L, KVH, P, ps, hd), dtype), kv_sharding)
+            self.k_pages = jax.device_put(jnp.zeros(pool.shape, pool.dtype), kv_sharding)
+            self.v_pages = jax.device_put(jnp.zeros(pool.shape, pool.dtype), kv_sharding)
         else:
             self.params = params
-            self.k_pages = jnp.zeros((L, KVH, P, ps, hd), dtype)
-            self.v_pages = jnp.zeros((L, KVH, P, ps, hd), dtype)
+            self.k_pages = jnp.zeros(pool.shape, pool.dtype)
+            self.v_pages = jnp.zeros(pool.shape, pool.dtype)
         self.allocator = PageAllocator(P)
         # off by derivation where layers keep recurrent state: a page hit
         # without the state at that boundary would be wrong
@@ -806,6 +813,16 @@ class InferenceEngine:
 
     def _state_args(self) -> tuple:
         return (self.state,) if self._stack else ()
+
+    def abstract_pool(self, sharding=None) -> jax.ShapeDtypeStruct:
+        """k_pages / v_pages as this engine's programs take them, from its
+        two configs alone: a tool that compiles the programs for a
+        described chip asks here and builds no pool by hand."""
+        layers, kv_heads, head_dim = self.cfg.cache_dims
+        return jax.ShapeDtypeStruct(
+            pool_shape(layers, self.ecfg.max_pages, self.ecfg.page_size,
+                       kv_heads, head_dim),
+            jnp.dtype(self.ecfg.cache_dtype), sharding=sharding)
 
     # ------------------------------------------------------------- compiled
 
@@ -1165,7 +1182,7 @@ class InferenceEngine:
         n_full = min(n, Tpad // ps)
         page_arr = jnp.asarray(pages[:n_full], jnp.int32)
         self.k_pages, self.v_pages = _scatter_pages_jit(
-            self.k_pages, self.v_pages, k, v, page_arr, n_full, ps
+            self.k_pages, self.v_pages, k, v, page_arr
         )
 
     def _export_blob(self, req: Request, pages: List[int], cache,
@@ -1183,7 +1200,8 @@ class InferenceEngine:
         else:
             # chunked prefill wrote pages directly: gather and trim
             page_arr = jnp.asarray(pages, jnp.int32)
-            k, v = _gather_pages_jit(self.k_pages, self.v_pages, page_arr)
+            k, v = _gather_pages_jit(self.k_pages, self.v_pages, page_arr,
+                                     self.cfg.kv_heads)
             k = np.asarray(k[:, :T])
             v = np.asarray(v[:, :T])
         return {
@@ -1318,7 +1336,8 @@ class InferenceEngine:
             p0 = st.emitted_upto // ps  # emitted_upto is page-aligned here
             p1 = -(-upto // ps)
             page_arr = jnp.asarray(st.pages[p0:p1], jnp.int32)
-            k, v = _gather_pages_jit(self.k_pages, self.v_pages, page_arr)
+            k, v = _gather_pages_jit(self.k_pages, self.v_pages, page_arr,
+                                     self.cfg.kv_heads)
             k = np.asarray(k[:, : upto - p0 * ps])
             v = np.asarray(v[:, : upto - p0 * ps])
         if self._kv_layout(st.request) == "layer":
@@ -2753,30 +2772,11 @@ def _kv_layer_groups(L: int, groups: int = 4) -> List[tuple]:
     return out
 
 
-def gather_pages(k_pages, v_pages, page_arr):
-    """pages[:, :, page_arr] -> token-contiguous [L, n*ps, KVH, hd].
-    NOT donating: the pools stay live for the decode loop. Compiles per
-    distinct page count — fine for the (host-bound) migration path."""
-    L, KVH, _P, ps, hd = k_pages.shape
-    n = page_arr.shape[0]
-    k = k_pages[:, :, page_arr].transpose(0, 2, 3, 1, 4).reshape(L, n * ps, KVH, hd)
-    v = v_pages[:, :, page_arr].transpose(0, 2, 3, 1, 4).reshape(L, n * ps, KVH, hd)
-    return k, v
-
-
-def scatter_pages(k_pages, v_pages, k, v, page_arr, n_full, ps):
-    """k/v [L, Tpad, KVH, hd] -> pages[:, :, page_arr]."""
-    L, Tpad, KVH, hd = k.shape
-    kb = k[:, : n_full * ps].reshape(L, n_full, ps, KVH, hd).transpose(0, 3, 1, 2, 4)
-    vb = v[:, : n_full * ps].reshape(L, n_full, ps, KVH, hd).transpose(0, 3, 1, 2, 4)
-    k_pages = k_pages.at[:, :, page_arr].set(kb.astype(k_pages.dtype))
-    v_pages = v_pages.at[:, :, page_arr].set(vb.astype(v_pages.dtype))
-    return k_pages, v_pages
-
-
-_gather_pages_jit = jax.jit(gather_pages)
-_scatter_pages_jit = jax.jit(scatter_pages, static_argnums=(5, 6),
-                             donate_argnums=(0, 1))
+# NOT donating the gather: the pools stay live for the decode loop. It
+# compiles per distinct page count — fine for the (host-bound) migration
+# path.
+_gather_pages_jit = jax.jit(gather_pages, static_argnums=(3,))
+_scatter_pages_jit = jax.jit(scatter_pages, donate_argnums=(0, 1))
 
 
 def _ffn(x, lp, cfg: ModelConfig):

@@ -79,6 +79,7 @@ from ..ops import (
     paged_attention_chunk,
     paged_attention_decode,
     paged_attention_verify,
+    pool_shape,
     rope_frequencies,
     write_then_attend,
 )
@@ -342,11 +343,11 @@ class DraftModelProposer:
         for i in range(B):
             tables[i, : self.pps] = 1 + i * self.pps + np.arange(self.pps)
         self._tables = jnp.asarray(tables)
-        L, KVH, hd = self.cfg.n_layers, self.cfg.kv_heads, self.cfg.hdim
-        P = 1 + B * self.pps
+        pool = pool_shape(self.cfg.n_layers, 1 + B * self.pps, ps,
+                          self.cfg.kv_heads, self.cfg.hdim)
         dtype = jnp.dtype(ecfg.cache_dtype)
-        self.k_pages = jnp.zeros((L, KVH, P, ps, hd), dtype)
-        self.v_pages = jnp.zeros((L, KVH, P, ps, hd), dtype)
+        self.k_pages = jnp.zeros(pool, dtype)
+        self.v_pages = jnp.zeros(pool, dtype)
         self._chunk_fn = self._build_chunk()
         self._propose_fn = self._build_propose()
 

@@ -83,6 +83,44 @@ class TestKvRoundTrip:
         finally:
             src.stop(), dst.stop(), ref.stop()
 
+    @pytest.mark.parametrize("n,path", [(13, "bucket"), (40, "chunked")])
+    def test_the_wire_holds_tokens_by_head_whatever_the_pool_holds(
+            self, tiny, n, path):
+        """The blob is [layers, true_len, kv_heads, head_dim] on both export
+        paths, and it is what the pool's rows hold: a token's row is its
+        heads side by side, so a page gathered is its tokens in order."""
+        cfg, params = tiny
+        kw = dict(page_size=8, prefill_buckets=(16,), prefill_chunk=16,
+                  max_seq_len=96, max_pages=96, cache_dtype="float32")
+        src, dst = _engine(cfg, params, **kw), _engine(cfg, params, **kw)
+        ref = _engine(cfg, params, **kw)
+        try:
+            prompt = _mixed_prompts(cfg, (n,))[0]
+            want = ref.generate(prompt, max_tokens=8)["token_ids"]
+            req = Request(request_id=f"wire-{path}", prompt=list(prompt),
+                          max_tokens=8, prefill_only=True)
+            src.add_request(req)
+            blob = src.export_kv_pages(req, timeout_s=120.0)
+            L, KVH, hd = cfg.n_layers, cfg.kv_heads, cfg.hdim
+            assert blob["k"].shape == blob["v"].shape == (L, n, KVH, hd)
+            assert (blob["layers"], blob["kv_heads"], blob["head_dim"]) == (
+                L, KVH, hd)
+            dreq = Request(request_id=f"wire-{path}-dst", prompt=list(prompt),
+                           max_tokens=8)
+            dst.import_kv_pages(dreq, blob)
+            assert dreq.done.wait(120.0) and dreq.error is None, dreq.error
+            assert list(dreq.output) == want
+            # the importing pool's rows ARE the blob's tokens: the ref
+            # engine wrote the same prompt through its own programs
+            pages = np.asarray(ref.k_pages)  # [L, 1, P, ps, KVH*hd]
+            rows = pages.reshape(L, -1, KVH * hd)
+            flat = np.asarray(blob["k"]).reshape(L, n, KVH * hd)
+            for t in (0, n - 1):  # each token's row is somewhere in the pool
+                hit = np.isclose(rows[0], flat[0, t], atol=1e-5).all(-1)
+                assert hit.any(), (path, t)
+        finally:
+            src.stop(), dst.stop(), ref.stop()
+
     def test_chunked_prefill_export_token_exact(self, tiny):
         """Long prompt prefilled in chunks on the source: export gathers
         straight from the paged pools (the non-bucketed path)."""

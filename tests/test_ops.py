@@ -1,6 +1,7 @@
 """Kernel correctness: Pallas (interpret mode on CPU) and XLA fallbacks vs
 O(T^2) references, plus gradient checks for the custom VJPs."""
 
+import functools
 import os
 
 import jax
@@ -186,14 +187,47 @@ class TestRope:
         np.testing.assert_allclose(score(5, 3), score(102, 100), atol=1e-4)
 
 
+def _pool(key, L, KVH, P, ps, D):
+    """A random page pool in the layout the ops own."""
+    from ray_tpu.ops import pool_shape
+
+    return _rand(key, pool_shape(L, P, ps, KVH, D))
+
+
+def _tokens(pool, layer, pages, KVH):
+    """numpy [n*ps, KVH, D]: the tokens of `pages`, in order. Written out
+    here and not taken from the ops: head c is lanes c*D .. of a row."""
+    rows = np.asarray(pool)[layer, 0][np.asarray(pages)]  # [n, ps, KVH*D]
+    rows = rows.reshape(-1, rows.shape[-1])
+    D = rows.shape[-1] // KVH
+    return np.stack([rows[:, c * D:(c + 1) * D] for c in range(KVH)], axis=1)
+
+
+def _plain(q, k, v, seen, scale):
+    """Per-head softmax over the visible tokens, in numpy, sharing no code
+    with the ops. q [R,H,D], k/v [T,KVH,D], seen [R,T] bool -> [R,H,D]."""
+    R, H, D = q.shape
+    g = H // k.shape[1]
+    out = np.zeros((R, H, D), np.float64)
+    for r in range(R):
+        if not seen[r].any():
+            continue
+        for h in range(H):
+            s = (k[seen[r], h // g].astype(np.float64)
+                 @ q[r, h].astype(np.float64)) * scale
+            p = np.exp(s - s.max())
+            out[r, h] = (p / p.sum()) @ v[seen[r], h // g]
+    return out
+
+
 class TestPagedAttention:
     def _setup(self, B=3, H=4, KVH=2, D=128, page_size=16, pages_per_seq=8):
         total_pages = B * pages_per_seq + 1
         ks = jax.random.split(jax.random.PRNGKey(0), 3)
         q = _rand(ks[0], (B, H, D))
         # a pool of one layer: the ops take the pool whole and a layer
-        k_pages = _rand(ks[1], (KVH, total_pages, page_size, D))[None]
-        v_pages = _rand(ks[2], (KVH, total_pages, page_size, D))[None]
+        k_pages = _pool(ks[1], 1, KVH, total_pages, page_size, D)
+        v_pages = _pool(ks[2], 1, KVH, total_pages, page_size, D)
         # Page 0 reserved; each seq uses disjoint pages.
         page_table = (
             1 + jnp.arange(B * pages_per_seq, dtype=jnp.int32)
@@ -214,49 +248,162 @@ class TestPagedAttention:
         q, kp, vp, pt, _ = self._setup(B, H, KVH, D, ps, pps)
         lens = jnp.array([64, 33], dtype=jnp.int32)
         out = paged_attention_decode(q, kp, vp, pt, lens, layer=0)
-        ctx = pps * ps
-        kg = jnp.moveaxis(kp[0][:, pt], 1, 0).reshape(B, KVH, ctx, D)
-        vg = jnp.moveaxis(vp[0][:, pt], 1, 0).reshape(B, KVH, ctx, D)
         for b in range(B):
             L = int(lens[b])
             o_ref = mha_reference(
                 q[b][None, None],  # [1, 1, H, D]
-                jnp.swapaxes(kg[b, :, :L], 0, 1)[None],
-                jnp.swapaxes(vg[b, :, :L], 0, 1)[None],
+                jnp.asarray(_tokens(kp, 0, pt[b], KVH)[:L])[None],
+                jnp.asarray(_tokens(vp, 0, pt[b], KVH)[:L])[None],
                 causal=False,
             )
             np.testing.assert_allclose(out[b], o_ref[0, 0], atol=2e-3, rtol=2e-3)
 
 
+# the cells' shapes (8 kv heads, groups of 4), multi-query, a pair, and a
+# row that is no power of two wide
+_HEADS = [(32, 8), (4, 1), (4, 2), (20, 10)]
+
+
+class TestPoolRowAgainstPlainSoftmax:
+    """Decode, chunk and verify on the pool (a token's kv heads in one row)
+    against `_plain`, which shares no code with the ops."""
+
+    B, D, PS, PPS, L = 3, 128, 16, 4, 2
+
+    def _pool_and_table(self, KVH):
+        ks = jax.random.split(jax.random.PRNGKey(11), 3)
+        P = self.B * self.PPS + 1
+        # a table in no particular order: a page's place is the table's say
+        table = 1 + jax.random.permutation(ks[2], self.B * self.PPS).reshape(
+            self.B, self.PPS).astype(jnp.int32)
+        return (_pool(ks[0], self.L, KVH, P, self.PS, self.D),
+                _pool(ks[1], self.L, KVH, P, self.PS, self.D), table)
+
+    @pytest.mark.parametrize("window", [None, 24])
+    @pytest.mark.parametrize("H,KVH", _HEADS)
+    def test_decode(self, kernel_mode, H, KVH, window):
+        kp, vp, pt = self._pool_and_table(KVH)
+        q = _rand(jax.random.PRNGKey(12), (self.B, H, self.D))
+        lens = np.array([5, 33, 64], np.int32)
+        out = paged_attention_decode(q, kp, vp, pt, jnp.asarray(lens), layer=1,
+                                     window=window)
+        pos = np.arange(self.PPS * self.PS)
+        for b in range(self.B):
+            seen = pos < lens[b]
+            if window is not None:
+                seen &= pos >= lens[b] - window
+            ref = _plain(np.asarray(q[b:b + 1]), _tokens(kp, 1, pt[b], KVH),
+                         _tokens(vp, 1, pt[b], KVH), seen[None],
+                         self.D ** -0.5)
+            np.testing.assert_allclose(out[b], ref[0], atol=2e-3, rtol=2e-3)
+
+    @pytest.mark.parametrize("window", [None, 24])
+    @pytest.mark.parametrize("H,KVH", _HEADS)
+    def test_chunk(self, kernel_mode, H, KVH, window):
+        from ray_tpu.ops import paged_attention_chunk
+
+        kp, vp, pt = self._pool_and_table(KVH)
+        C, start = 16, 20
+        q = _rand(jax.random.PRNGKey(13), (C, H, self.D))
+        out = paged_attention_chunk(q, kp, vp, pt[1], start, start + C,
+                                    layer=1, window=window)
+        pos = np.arange(self.PPS * self.PS)[None]
+        qpos = start + np.arange(C)[:, None]
+        seen = (pos <= qpos) & (pos < start + C)
+        if window is not None:
+            seen &= pos > qpos - window
+        ref = _plain(np.asarray(q), _tokens(kp, 1, pt[1], KVH),
+                     _tokens(vp, 1, pt[1], KVH), seen, self.D ** -0.5)
+        np.testing.assert_allclose(out, ref, atol=2e-3, rtol=2e-3)
+
+    @pytest.mark.parametrize("H,KVH", _HEADS)
+    def test_verify(self, kernel_mode, H, KVH):
+        from ray_tpu.ops import paged_attention_verify
+
+        kp, vp, pt = self._pool_and_table(KVH)
+        S = 3
+        q = _rand(jax.random.PRNGKey(14), (self.B, S, H, self.D))
+        at = np.array([4, 30, 50], np.int32)
+        out = paged_attention_verify(q, kp, vp, pt, jnp.asarray(at), layer=1)
+        pos = np.arange(self.PPS * self.PS)[None]
+        for b in range(self.B):
+            seen = pos <= at[b] + np.arange(S)[:, None]
+            ref = _plain(np.asarray(q[b]), _tokens(kp, 1, pt[b], KVH),
+                         _tokens(vp, 1, pt[b], KVH), seen, self.D ** -0.5)
+            np.testing.assert_allclose(out[b], ref, atol=2e-3, rtol=2e-3)
+
+    @pytest.mark.parametrize("KVH", [1, 2, 8, 10])
+    def test_scatter_then_gather_returns_its_input(self, KVH):
+        from ray_tpu.ops import gather_pages, scatter_pages
+
+        kp, vp, _ = self._pool_and_table(KVH)
+        ks = jax.random.split(jax.random.PRNGKey(15), 2)
+        L, n = self.L, 3
+        # 3 whole pages and a tail that the scatter leaves out
+        k = _rand(ks[0], (L, n * self.PS + 5, KVH, self.D))
+        v = _rand(ks[1], (L, n * self.PS + 5, KVH, self.D))
+        pages = jnp.array([7, 2, 9], jnp.int32)
+        kp2, vp2 = scatter_pages(kp, vp, k, v, pages)
+        gk, gv = gather_pages(kp2, vp2, pages, KVH)
+        np.testing.assert_array_equal(gk, k[:, : n * self.PS])
+        np.testing.assert_array_equal(gv, v[:, : n * self.PS])
+        # a token's row is its heads side by side, and no other page moved
+        np.testing.assert_array_equal(
+            _tokens(kp2, 1, pages, KVH), np.asarray(k[1, : n * self.PS]))
+        untouched = np.setdiff1d(np.arange(kp.shape[2]), np.asarray(pages))
+        np.testing.assert_array_equal(np.asarray(kp2)[:, :, untouched],
+                                      np.asarray(kp)[:, :, untouched])
+
+
 class TestPagedAttentionTP:
-    def test_kernel_under_tp_shard_map(self, kernel_mode):
+    @pytest.mark.parametrize("kernel", ["decode", "decode_window", "verify"])
+    def test_kernel_under_tp_shard_map(self, kernel_mode, kernel):
         # D=128 so the Pallas branch is taken (interpret on CPU): the kernel
-        # must partition over tp via shard_map and match the reference
+        # must partition over tp via shard_map, each shard on its kv heads'
+        # lanes of every row, and equal the one-device call bit for bit
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from ray_tpu.comm.mesh import MeshSpec, build_mesh
-        from ray_tpu.ops.paged_attention import paged_attention_decode
+        from ray_tpu.ops import paged_attention_verify
 
-        B, H, KVH, D = 2, 4, 2, 128
+        B, H, KVH, D = 2, 8, 4, 128
         PGS, ps = 8, 8
-        q = _rand(jax.random.PRNGKey(0), (B, H, D))
-        kp = _rand(jax.random.PRNGKey(1), (KVH, PGS, ps, D))[None]
-        vp = _rand(jax.random.PRNGKey(2), (KVH, PGS, ps, D))[None]
+        kp = _pool(jax.random.PRNGKey(1), 1, KVH, PGS, ps, D)
+        vp = _pool(jax.random.PRNGKey(2), 1, KVH, PGS, ps, D)
         table = jnp.array([[1, 2, 0, 0], [3, 4, 0, 0]], jnp.int32)
         lengths = jnp.array([13, 9], jnp.int32)
-        ref = _paged_reference(q, kp, vp, table, lengths, 0, D**-0.5)
+        if kernel == "verify":
+            q = _rand(jax.random.PRNGKey(0), (B, 3, H, D))
+            op = paged_attention_verify
+        else:
+            q = _rand(jax.random.PRNGKey(0), (B, H, D))
+            op = functools.partial(
+                paged_attention_decode,
+                window=6 if kernel == "decode_window" else None)
+        one = jax.jit(lambda *a: op(*a, layer=0))(q, kp, vp, table, lengths)
+        seen = np.arange(4 * ps)[None] < np.asarray(lengths)[:, None]
+        if kernel == "decode":  # and the one-device call is right
+            for b in range(B):
+                ref = _plain(np.asarray(q[b:b + 1]), _tokens(kp, 0, table[b], KVH),
+                             _tokens(vp, 0, table[b], KVH), seen[b:b + 1],
+                             D ** -0.5)
+                np.testing.assert_allclose(one[b], ref[0], atol=2e-3, rtol=2e-3)
 
         mesh = build_mesh(MeshSpec.create(tp=2), devices=jax.devices("cpu")[:2])
-        qs = jax.device_put(q, NamedSharding(mesh, P(None, "tp", None)))
-        # the pool shards on its KVH axis, axis 1
-        kps = jax.device_put(kp, NamedSharding(mesh, P(None, "tp")))
-        vps = jax.device_put(vp, NamedSharding(mesh, P(None, "tp")))
+        heads = P(*[None] * (q.ndim - 2), "tp", None)
+        qs = jax.device_put(q, NamedSharding(mesh, heads))
+        # the pool shards on its last axis: a shard's row is its kv heads'
+        rows = NamedSharding(mesh, P(None, None, None, None, "tp"))
+        kps, vps = jax.device_put(kp, rows), jax.device_put(vp, rows)
         ts = jax.device_put(table, NamedSharding(mesh, P()))
         ls = jax.device_put(lengths, NamedSharding(mesh, P()))
         out = jax.jit(
-            lambda *a: paged_attention_decode(*a, layer=0, mesh=mesh)
+            lambda *a: op(*a, layer=0, mesh=mesh)
         )(qs, kps, vps, ts, ls)
-        np.testing.assert_allclose(out, ref, atol=2e-2, rtol=2e-2)
+        if kernel_mode == "pallas":
+            np.testing.assert_array_equal(out, one)
+        else:  # GSPMD partitions the reference's einsums as it likes
+            np.testing.assert_allclose(out, one, atol=1e-5, rtol=1e-5)
 
 
 class TestPagedAttentionChunk:
@@ -268,8 +415,8 @@ class TestPagedAttentionChunk:
     def _setup(self, C=32, H=6, KVH=2, D=128, page_size=16, pages_per_seq=8):
         ks = jax.random.split(jax.random.PRNGKey(3), 3)
         q = _rand(ks[0], (C, H, D))
-        kp = _rand(ks[1], (KVH, pages_per_seq + 4, page_size, D))[None]
-        vp = _rand(ks[2], (KVH, pages_per_seq + 4, page_size, D))[None]
+        kp = _pool(ks[1], 1, KVH, pages_per_seq + 4, page_size, D)
+        vp = _pool(ks[2], 1, KVH, pages_per_seq + 4, page_size, D)
         pt = (1 + jnp.arange(pages_per_seq, dtype=jnp.int32))
         return q, kp, vp, pt
 
@@ -296,19 +443,17 @@ class TestPagedAttentionChunk:
         C, H, KVH, D, ps = 32, 4, 4, 128, 16
         q, kp, vp, pt = self._setup(C, H, KVH, D, ps, pages_per_seq=2)
         out = paged_attention_chunk(q, kp, vp, pt, 0, C, layer=0)
-        kg = kp[0][:, pt].reshape(KVH, 2 * ps, D)[:, :C]
-        vg = vp[0][:, pt].reshape(KVH, 2 * ps, D)[:, :C]
         o_ref = mha_reference(
             q[None],  # [1, C, H, D]
-            jnp.swapaxes(kg, 0, 1)[None],
-            jnp.swapaxes(vg, 0, 1)[None],
+            jnp.asarray(_tokens(kp, 0, pt, KVH)[:C])[None],
+            jnp.asarray(_tokens(vp, 0, pt, KVH)[:C])[None],
             causal=True,
         )
         np.testing.assert_allclose(out, o_ref[0], atol=2e-3, rtol=2e-3)
 
 
 class TestPagedLayerOfTheWholePool:
-    """The ops take the pool whole, [L, KVH, P, ps, D], and a layer: the
+    """The ops take the pool whole, [L, 1, P, ps, KVH*D], and a layer: the
     result is the XLA reference's on that layer's slab alone, and no
     other layer is read (they hold NaN)."""
 
@@ -316,10 +461,10 @@ class TestPagedLayerOfTheWholePool:
 
     def _pool(self):
         ks = jax.random.split(jax.random.PRNGKey(7), 2)
-        shape = (self.L, self.KVH, self.B * self.PPS + 1, self.PS, self.D)
+        dims = (self.L, self.KVH, self.B * self.PPS + 1, self.PS, self.D)
         table = (1 + jnp.arange(self.B * self.PPS, dtype=jnp.int32)
                  ).reshape(self.B, self.PPS)
-        return _rand(ks[0], shape), _rand(ks[1], shape), table
+        return _pool(ks[0], *dims), _pool(ks[1], *dims), table
 
     def _cases(self):
         from ray_tpu.ops.paged_attention import (
@@ -397,8 +542,10 @@ class TestPagedLayerOfTheWholePool:
                                         slot)
         assert o == 1.0 and seen["pool"] == (kp2, vp2, 1)
         want_k, want_v = np.array(kp), np.array(vp)
-        for i in np.ndindex(*idx_shape):
-            want_k[1, :, int(page[i]), int(slot[i])] = np.asarray(k[i])
-            want_v[1, :, int(page[i]), int(slot[i])] = np.asarray(v[i])
+        for i in np.ndindex(*idx_shape):  # a token's heads side by side
+            want_k[1, 0, int(page[i]), int(slot[i])] = np.concatenate(
+                list(np.asarray(k[i])))
+            want_v[1, 0, int(page[i]), int(slot[i])] = np.concatenate(
+                list(np.asarray(v[i])))
         np.testing.assert_array_equal(kp2, want_k)
         np.testing.assert_array_equal(vp2, want_v)

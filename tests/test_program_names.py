@@ -115,9 +115,10 @@ def test_page_and_verify_programs_have_module_names(engine):
                        engine.cfg.hdim), engine.k_pages.dtype)
     pages = jnp.arange(1, 3, dtype=jnp.int32)
     low = _scatter_pages_jit.lower(engine.k_pages, engine.v_pages, cache,
-                                   cache, pages, 2, engine.ecfg.page_size)
+                                   cache, pages)
     assert _module(low) == "jit_scatter_pages"
-    low = _gather_pages_jit.lower(engine.k_pages, engine.v_pages, pages)
+    low = _gather_pages_jit.lower(engine.k_pages, engine.v_pages, pages,
+                                  engine.cfg.kv_heads)
     assert _module(low) == "jit_gather_pages"
     low = engine._spec._verify(False).lower(*_verify_args(engine))
     assert _module(low) == "jit_verify_3"
@@ -235,7 +236,7 @@ def test_every_pallas_kernel_is_named():
         "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
     assert _pallas_names(lambda x, w: rms_norm(x, w, eps=1e-5),
                          jnp.ones((8, 128)), jnp.ones((128,))) == ["rms_norm"]
-    pages = jnp.ones((1, 1, 9, 16, 128), jnp.bfloat16)  # [L, KVH, P, ps, hd]
+    pages = jnp.ones((1, 1, 9, 16, 128), jnp.bfloat16)  # [L, 1, P, ps, KVH*hd]
     table = jnp.zeros((2, 4), jnp.int32)
     assert _pallas_names(
         lambda q: paged_attention_decode(q, pages, pages, table,

@@ -441,6 +441,72 @@ class TestEngine:
         # pages really are distributed over tp
         assert len(sharded.k_pages.sharding.device_set) == 2
 
+    @pytest.mark.parametrize(
+        "model", ["tiny-llama", "tiny-gpt2", "tiny-moe", "tiny-sambay"])
+    def test_every_model_has_the_one_pool_layout(self, model):
+        # a token's kv heads side by side in one row, whatever the model:
+        # the ops say the shape, the engine's abstract pool repeats it,
+        # and what is allocated is that
+        from ray_tpu.ops import pool_shape
+        from ray_tpu.serve import EngineConfig, InferenceEngine
+
+        cfg = get_config(model)
+        params = init_params(cfg, jax.random.PRNGKey(0))
+        ecfg = EngineConfig(max_batch_size=2, page_size=4, max_pages=16,
+                            max_seq_len=32, prefill_buckets=(8,),
+                            cache_dtype="float32")
+        engine = InferenceEngine(params, cfg, ecfg)
+        try:
+            layers, kv_heads, head_dim = cfg.cache_dims
+            want = pool_shape(layers, 16, 4, kv_heads, head_dim)
+            assert want == (layers, 1, 16, 4, kv_heads * head_dim)
+            pool = engine.abstract_pool()
+            assert pool.shape == want and pool.dtype == jnp.float32
+            for pages in (engine.k_pages, engine.v_pages):
+                assert (pages.shape, pages.dtype) == (pool.shape, pool.dtype)
+            # a bare engine object answers too: tools compile its programs
+            # for a described chip without allocating anything
+            bare = object.__new__(InferenceEngine)
+            bare.cfg, bare.ecfg = cfg, ecfg
+            assert bare.abstract_pool().shape == want
+        finally:
+            engine.stop()
+
+    def test_tp_pool_shards_on_its_rows_on_both_prefill_paths(self):
+        # tp=2: each shard holds its kv heads' lanes of every row; a
+        # bucketed and a chunked prompt decode the one-device tokens
+        from jax.sharding import PartitionSpec
+
+        from ray_tpu.comm.mesh import MeshSpec, build_mesh
+        from ray_tpu.serve import EngineConfig, InferenceEngine
+
+        cfg = get_config("tiny-llama")
+        params = init_params(cfg, jax.random.PRNGKey(0))
+        ecfg = EngineConfig(
+            max_batch_size=2, page_size=8, max_pages=32, max_seq_len=64,
+            prefill_buckets=(16,), prefill_chunk=16,
+        )
+        mesh = build_mesh(
+            MeshSpec.create(tp=2), devices=jax.devices("cpu")[:2]
+        )
+        sharded = InferenceEngine(params, cfg, ecfg, mesh=mesh)
+        plain = InferenceEngine(params, cfg, ecfg)
+        try:
+            assert sharded.k_pages.sharding.spec == PartitionSpec(
+                None, None, None, None, "tp")
+            shard = sharded.k_pages.addressable_shards[0].data
+            assert shard.shape[-1] * 2 == sharded.k_pages.shape[-1]
+            rng = np.random.default_rng(29)
+            for n in (5, 37):
+                prompt = rng.integers(1, cfg.vocab_size, n).tolist()
+                out_tp = sharded.generate(prompt, max_tokens=6)
+                out_1d = plain.generate(prompt, max_tokens=6)
+                assert out_tp["token_ids"] == out_1d["token_ids"]
+            assert sharded.k_pages.sharding.spec == PartitionSpec(
+                None, None, None, None, "tp")  # and the programs keep it so
+        finally:
+            sharded.stop(), plain.stop()
+
     def test_prefill_does_not_block_decode(self, monkeypatch):
         # While a (artificially slow) prefill runs for request B, the decode
         # cadence of an already-active request A must keep advancing: tokens

@@ -9,7 +9,11 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import get_config, init_params
-from ray_tpu.ops import paged_attention_decode, paged_attention_verify
+from ray_tpu.ops import (
+    paged_attention_decode,
+    paged_attention_verify,
+    pool_shape,
+)
 from ray_tpu.ops.paged_attention import _verify_reference
 from ray_tpu.serve import EngineConfig, InferenceEngine, SpeculationConfig
 from ray_tpu.serve.spec_decode import (
@@ -225,8 +229,8 @@ class TestVerifyOp:
         ks = jax.random.split(jax.random.PRNGKey(0), 3)
         q = _rand(ks[0], (B, S, H, D))
         # a pool of one layer: the ops take the pool whole and a layer
-        kp = _rand(ks[1], (KVH, B * pps + 1, ps, D))[None]
-        vp = _rand(ks[2], (KVH, B * pps + 1, ps, D))[None]
+        kp = _rand(ks[1], pool_shape(1, B * pps + 1, ps, KVH, D))
+        vp = _rand(ks[2], pool_shape(1, B * pps + 1, ps, KVH, D))
         pt = (1 + jnp.arange(B * pps, dtype=jnp.int32)).reshape(B, pps)
         positions = jnp.array([10, 37], jnp.int32)[:B]
         return q, kp, vp, pt, positions

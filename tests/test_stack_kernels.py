@@ -28,13 +28,23 @@ H, KVH, D, PS, W = 8, 2, 128, 4, 8
 RING = W // PS + 1
 
 
+def pool(key, layers, pages):
+    """A random page pool, a token's KVH heads side by side in one row."""
+    return jax.random.normal(key, pa.pool_shape(layers, pages, PS, KVH, D))
+
+
+def heads(row):
+    """[.., KVH * D] -> [.., KVH, D], written out: head c is lanes c*D .. ."""
+    return jnp.stack([row[..., c * D:(c + 1) * D] for c in range(KVH)], -2)
+
+
 @pytest.mark.parametrize("lengths", [(1, 3, 8), (9, 12, 13), (30, 41, 57)])
 def test_paged_decode_window_over_a_ring(lengths):
     """Below the window, at its edge, and after the ring has lapped."""
     k = keys(3)
     B = len(lengths)
-    kp = jax.random.normal(k[0], (2, KVH, 1 + B * RING, PS, D))
-    vp = jax.random.normal(k[1], (2, KVH, 1 + B * RING, PS, D))
+    kp = pool(k[0], 2, 1 + B * RING)
+    vp = pool(k[1], 2, 1 + B * RING)
     q = jax.random.normal(k[2], (B, H, D))
     table = (1 + jnp.arange(B)[:, None] * RING
              + jnp.arange(RING)[None]).astype(jnp.int32)
@@ -46,9 +56,9 @@ def test_paged_decode_window_over_a_ring(lengths):
     # and against the keys laid out by position, no ring, no pages
     for b, n in enumerate(lengths):
         pos = range(max(0, n - W), n)
-        kk = jnp.stack([kp[1, :, table[b, (p // PS) % RING], p % PS]
+        kk = jnp.stack([heads(kp[1, 0, table[b, (p // PS) % RING], p % PS])
                         for p in pos], 1)
-        vv = jnp.stack([vp[1, :, table[b, (p // PS) % RING], p % PS]
+        vv = jnp.stack([heads(vp[1, 0, table[b, (p // PS) % RING], p % PS])
                         for p in pos], 1)
         s = jnp.einsum("cgd,ctd->cgt", q[b].reshape(KVH, H // KVH, D), kk) / 8
         o = jnp.einsum("cgt,ctd->cgd", jax.nn.softmax(s, -1), vv)
@@ -62,8 +72,7 @@ def test_paged_chunk_window_over_the_kept_tail(first):
     k = keys(3)
     C = 8
     n = (W + C) // PS
-    kb = jax.random.normal(k[0], (1, KVH, n, PS, D))
-    vb = jax.random.normal(k[1], (1, KVH, n, PS, D))
+    kb, vb = pool(k[0], 1, n), pool(k[1], 1, n)
     q = jax.random.normal(k[2], (C, H, D))
     table = jnp.arange(n, dtype=jnp.int32)
     got = jax.jit(lambda q, kb, vb, f: pa.paged_attention_chunk(
@@ -148,40 +157,71 @@ def test_ssm_step_updates_one_layer_in_place(layer):
                                   np.asarray(state)[others])
 
 
-def test_paged_chunk_over_a_packed_pool_reads_its_heads_tile():
-    """[L, 1, P, ps, heads * D]: kv head c is lanes c * D .. of every row."""
+def test_paged_chunk_reads_its_heads_tile_of_every_row():
+    """kv head c is lanes c * D .. of every row: against the keys laid out
+    by position and split by hand, no pages."""
     k = keys(3)
     C, P = 8, 7
-    kp = jax.random.normal(k[0], (2, 1, P, PS, KVH * D))
-    vp = jax.random.normal(k[1], (2, 1, P, PS, KVH * D))
+    kp, vp = pool(k[0], 2, P), pool(k[1], 2, P)
     q = jax.random.normal(k[2], (C, H, D))
     table = jnp.asarray([3, 1, 5, 2, 0, 0], jnp.int32)
     got = jax.jit(lambda q, kp, vp: pa.paged_attention_chunk(
-        q, kp, vp, table, 8, 16, 1, heads=KVH))(q, kp, vp)
-    unpack = lambda x: x.reshape(2, P, PS, KVH, D).transpose(0, 3, 1, 2, 4)  # noqa: E731
-    want = pa._chunk_reference(q, unpack(kp), unpack(vp), table, 8, 16, 1,
-                               D ** -0.5)
-    np.testing.assert_allclose(got, want, atol=TOL)
+        q, kp, vp, table, 8, 16, 1))(q, kp, vp)
+    kk = heads(kp[1, 0, table[:4]].reshape(16, KVH * D))  # [16, KVH, D]
+    vv = heads(vp[1, 0, table[:4]].reshape(16, KVH * D))
+    s = jnp.einsum("qcgd,tcd->qcgt", q.reshape(C, KVH, H // KVH, D), kk) * D ** -0.5
+    seen = jnp.arange(16)[None] <= 8 + jnp.arange(C)[:, None]
+    s = jnp.where(seen[:, None, None], s, -jnp.inf)
+    want = jnp.einsum("qcgt,tcd->qcgd", jax.nn.softmax(s, -1), vv)
+    np.testing.assert_allclose(got, want.reshape(C, H, D), atol=TOL)
 
 
-def test_decode_over_a_packed_pool_is_one_program_a_sequence():
-    """models/stack.py's Decode: a query head zero outside its kv head's
-    lanes against whole packed rows gives what the head-major pool gives."""
-    from ray_tpu.models import get_config, stack
+def _grids(fn, *args):
+    """The grid of every pallas_call in fn's jaxpr, nested calls included."""
+    out = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                out.append(tuple(eqn.params["grid_mapping"].grid))
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return out
+
+
+@pytest.mark.parametrize("window", [None, W])
+def test_decode_is_one_grid_program_a_sequence(window):
+    """A differential stack's heads (pairs of 128) and any other model's:
+    the decode call's grid is the batch, whatever the number of kv heads,
+    and each query head still meets its own kv head alone."""
+    from ray_tpu.models import get_config
 
     cfg = get_config("tiny-sambay", n_heads=8, n_kv_heads=4, head_dim=64)
     k = keys(3)
-    B, P, pages = 3, 9, 4
-    KVHp, Dp = cfg.pool_heads, cfg.pool_dim  # 2 pairs of 128
-    kp = jax.random.normal(k[0], (1, KVHp, P, PS, Dp))
-    vp = jax.random.normal(k[1], (1, KVHp, P, PS, Dp))
-    q = jax.random.normal(k[2], (B, cfg.n_heads, Dp))
+    B, P = 3, 9
+    assert (cfg.pool_heads, cfg.pool_dim) == (KVH, D)  # 2 pairs of 128
+    kp, vp = pool(k[0], 1, P), pool(k[1], 1, P)
+    q = jax.random.normal(k[2], (B, cfg.n_heads, D))
     table = jnp.asarray([[1, 2, 3, 4], [5, 6, 0, 0], [7, 8, 0, 0]], jnp.int32)
     lens = jnp.asarray([13, 5, 8], jnp.int32)
-    want = pa._paged_reference(q, kp, vp, table, lens, 0, 0.125)
-    pack = lambda x: x.transpose(0, 2, 3, 1, 4).reshape(1, 1, P, PS, KVHp * Dp)  # noqa: E731
-    mode = stack.Decode(cfg, lens - 1, table, PS)
-    got = jax.jit(lambda q, kp, vp: mode._own(pa.paged_attention_decode(
-        mode._wide(q), kp, vp, table, lens, 0, scale=0.125)))(
-            q, pack(kp), pack(vp))
-    np.testing.assert_allclose(got, want, atol=TOL)
+
+    def decode(q, kp, vp):
+        return pa.paged_attention_decode(q, kp, vp, table, lens, 0,
+                                         scale=0.125, window=window)
+
+    # (one call per lowering platform of the dispatch)
+    assert set(_grids(decode, q, kp, vp)) == {(B,)}
+    got = jax.jit(decode)(q, kp, vp)
+    for b in range(B):
+        n = int(lens[b])
+        pos = range(max(0, n - (window or n)), n)
+        kk = jnp.stack([heads(kp[0, 0, table[b, p // PS], p % PS]) for p in pos], 1)
+        vv = jnp.stack([heads(vp[0, 0, table[b, p // PS], p % PS]) for p in pos], 1)
+        s = jnp.einsum("cgd,ctd->cgt", q[b].reshape(KVH, H // KVH, D), kk) / 8
+        o = jnp.einsum("cgt,ctd->cgd", jax.nn.softmax(s, -1), vv)
+        np.testing.assert_allclose(got[b], o.reshape(H, D), atol=TOL)

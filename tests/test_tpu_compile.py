@@ -21,6 +21,7 @@ from ray_tpu.ops import (
     paged_attention_chunk,
     paged_attention_decode,
     paged_attention_verify,
+    pool_shape,
     rms_norm,
 )
 
@@ -69,13 +70,19 @@ def _qkv(t):
     return [((1, t, H, D), BF16), ((1, t, KVH, D), BF16), ((1, t, KVH, D), BF16)]
 
 
-# the ops take the pool whole, [L, KVH, P, ps, D], and a layer of it
+# the ops take the pool whole (a token's kv heads in one row) and a layer
 LAYERS, LAYER = 2, 1
-_POOL = [((LAYERS, KVH, N_PAGES, PAGE, D), BF16)] * 2
 
 
-def _paged(batch, *q_shape):
-    return ([((batch, *q_shape, H, D), BF16)] + _POOL
+def _pool(kv_heads=KVH):
+    return [(pool_shape(LAYERS, N_PAGES, PAGE, kv_heads, D), BF16)] * 2
+
+
+_POOL = _pool()
+
+
+def _paged(batch, *q_shape, heads=(H, KVH)):
+    return ([((batch, *q_shape, heads[0], D), BF16)] + _pool(heads[1])
             + [((batch, PAGES_PER_SEQ), I32), ((batch,), I32)])
 
 
@@ -90,6 +97,13 @@ CASES = {
     "flash_bwd_t2048": (_flash_bwd, _qkv(2048), 3),
     "flash_bwd_t8192": (_flash_bwd, _qkv(8192), 3),
     "paged_decode_b8": (_at_layer(paged_attention_decode), _paged(8), 1),
+    # the serve cells' batch; ten differential pairs a row under a window
+    "paged_decode_b64": (_at_layer(paged_attention_decode), _paged(64), 1),
+    "paged_decode_window_10_pairs": (
+        lambda *a: paged_attention_decode(*a, layer=LAYER, window=512),
+        _paged(64, heads=(40, 10)), 1),
+    "paged_decode_one_kv_head": (
+        _at_layer(paged_attention_decode), _paged(8, heads=(8, 1)), 1),
     "paged_chunk_c256": (
         lambda q, kp, vp, pt: paged_attention_chunk(
             q, kp, vp, pt, 512, 768, layer=LAYER),
@@ -121,9 +135,9 @@ def test_paged_decode_compiles_under_tp4_mesh(topo, no_persistent_cache):
 
     args = [
         spec((8, H, D), BF16, None, "tp"),
-        # the pool shards on its KVH axis, axis 1
-        spec((LAYERS, KVH, N_PAGES, PAGE, D), BF16, None, "tp"),
-        spec((LAYERS, KVH, N_PAGES, PAGE, D), BF16, None, "tp"),
+        # the pool shards on its last axis: a shard's row is its kv heads'
+        spec(*_POOL[0], None, None, None, None, "tp"),
+        spec(*_POOL[0], None, None, None, None, "tp"),
         spec((8, PAGES_PER_SEQ), I32),
         spec((8,), I32),
     ]
@@ -158,7 +172,8 @@ def _engine_programs(one_chip):
     params = jax.tree.map(  # bf16 weights, as a deployment holds them
         lambda a: s(a.shape, BF16),
         jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0)))
-    pool = s((LAYERS, KVH, POOL_PAGES, PAGE, D), BF16)
+    pool = eng.abstract_pool(one_chip)  # the engine's own say
+    assert pool.shape == pool_shape(LAYERS, POOL_PAGES, PAGE, KVH, D)
     pps = ecfg.pages_per_seq
     f32 = jnp.float32
     return pool, {
@@ -198,6 +213,7 @@ def test_engine_program_updates_the_pool_in_place(
     aliased = re.findall(r"\{\d+\}: \((\d+), \{\}, may-alias\)", header)
     assert len(aliased) == 2
     assert "tpu_custom_call" in text
-    # no operation copies something pool-shaped
-    shape = ",".join(map(str, pool.shape))
-    assert not re.search(r"= bf16\[%s\]\S* copy\(" % shape, text)
+    # no operation copies something pool-shaped (XLA drops the unit axis)
+    L, _, pages, ps, row = pool.shape
+    assert not re.search(
+        r"= bf16\[%d,(1,)?%d,%d,%d\]\S* copy\(" % (L, pages, ps, row), text)
