@@ -5,7 +5,8 @@ plus tiny variants for tests. One config class drives all families —
 differences (norm type, activation, positional scheme, GQA, MoE) are fields,
 not subclasses, so the same sharded forward/train/serve path covers every
 family. A model whose layers differ names each layer's mixer in
-`layer_kinds` (models/stack.py runs it; serve only).
+`layer_kinds` (a StackConfig; serve only). models/stack.py runs both on the
+serve path: a plain ModelConfig is the stack whose every layer is "attn".
 """
 
 from __future__ import annotations
@@ -13,7 +14,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
-# what a layer's mixer can be when `layer_kinds` names them (models/stack.py)
+# what a layer's mixer can be when a StackConfig's `layer_kinds` names them
+# (models/stack.py); a plain ModelConfig's layers are all "attn"
 LAYER_KINDS = ("mamba", "window", "full", "gmu", "cross")
 
 
@@ -66,9 +68,40 @@ class ModelConfig:
         cache holds for this model."""
         return self.n_layers, self.kv_heads, self.hdim
 
-    # what StackConfig (below) answers otherwise
+    # what StackConfig (below) answers otherwise: one kind of layer, rotary
+    # (or learned-position) attention over every layer's own pages and then
+    # the FFN or the experts, and no state beside the pages
     is_stack = False
     has_state = False
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        return ("attn",) * self.n_layers
+
+    def count(self, kind: str) -> int:
+        return self.layer_kinds.count(kind)
+
+    def segments(self) -> Tuple[Tuple[int, Tuple[str, ...], int], ...]:
+        """The stack as runs of whole periods: (first layer, the period's
+        kinds, repeats). A run of two or more equal periods (the shortest
+        period that repeats wins) is scanned; a layer that belongs to none
+        is a run of its own, once. Equal layers are ONE scan; SambaY's
+        mamba/window pairs, then one mamba and one full layer, then
+        gmu/cross pairs are three scans' worth of programs, whatever the
+        depth."""
+        kinds, out, i = self.layer_kinds, [], 0
+        while i < len(kinds):
+            best = (1, 1)
+            for p in range(1, 5):
+                r = 1
+                while kinds[i + r * p:i + (r + 1) * p] == kinds[i:i + p]:
+                    r += 1
+                if r >= 2:
+                    best = (p, r)
+                    break
+            out.append((i, kinds[i:i + best[0]], best[1]))
+            i += best[0] * best[1]
+        return tuple(out)
 
     def param_count(self) -> int:
         """Parameter count (embeddings included once if tied)."""
@@ -138,9 +171,6 @@ class StackConfig(ModelConfig):
         """Some layer keeps per-sequence state that is not keys and values."""
         return "mamba" in self.layer_kinds
 
-    def count(self, kind: str) -> int:
-        return self.layer_kinds.count(kind)
-
     @property
     def pool_heads(self) -> int:
         """KV heads as a cache holds them: a differential pair is one row."""
@@ -154,27 +184,6 @@ class StackConfig(ModelConfig):
     def cache_dims(self) -> Tuple[int, int, int]:
         """The full-attention layers alone, a differential pair a head."""
         return self.count("full"), self.pool_heads, self.pool_dim
-
-    def segments(self) -> Tuple[Tuple[int, Tuple[str, ...], int], ...]:
-        """The stack as runs of whole periods: (first layer, the period's
-        kinds, repeats). A run of two or more equal periods (the shortest
-        period that repeats wins) is scanned; a layer that belongs to none
-        is a run of its own, once. Mamba/window pairs, then one mamba and
-        one full layer, then gmu/cross pairs: three scans' worth of
-        programs, whatever the depth."""
-        kinds, out, i = self.layer_kinds, [], 0
-        while i < len(kinds):
-            best = (1, 1)
-            for p in range(1, 5):
-                r = 1
-                while kinds[i + r * p:i + (r + 1) * p] == kinds[i:i + p]:
-                    r += 1
-                if r >= 2:
-                    best = (p, r)
-                    break
-            out.append((i, kinds[i:i + best[0]], best[1]))
-            i += best[0] * best[1]
-        return tuple(out)
 
     def _mixer_params(self, kind: str) -> int:
         D, H, KVH, hd = self.d_model, self.n_heads, self.kv_heads, self.hdim

@@ -1,6 +1,9 @@
-"""A stack whose layers differ (ModelConfig.layer_kinds): every layer is
-`x + Mix(LN(x))` then `x + FFN(LN(x))`, and Mix is one of
+"""The layers of every model the serving engine runs, as a stack of layer
+kinds (`cfg.layer_kinds`): every layer is `x + Mix(norm(x))` and then its
+second half, `x + FFN(norm(x))` or the experts, and Mix is one of
 
+  attn    the one-block models' (a plain ModelConfig: every layer): rotary
+          or learned-position GQA over the layer's own pages
   mamba   selective state space (Mamba-1): conv tail and scan state per
           sequence; hands its scan output on to the gmu layers after it
   window  attention over the last `window` keys
@@ -10,27 +13,36 @@
   cross   queries only, over the last full layer's keys and values
 
 Each mixer is written ONCE, over a small state interface (a *mode*), and
-`forward`, the engine's bucket prefill, its chunk program and its decode
-program all run `run_stack` with their own mode:
+`forward`, the engine's bucket prefill, its chunk program, its decode
+program and the speculative programs all run `run_stack` with their own
+mode:
 
-  Seq(...)     whole sequences [B, T]: no cache (forward), state kept for
-               the engine (bucket prefill), or one chunk of one sequence
-               from carried state with the full layer's keys in pages
+  Seq(...)     whole sequences [B, T]: no cache (forward), keys and state
+               kept for the engine (bucket prefill), or one chunk of one
+               sequence from carried state with its keys in pages
   Decode(...)  one token for every slot: state per slot, window keys in a
-               ring of pages per slot, the full layer's keys in the pool
+               ring of pages per slot, the other keys in the pool
+  Verify(...)  Decode for S tokens a slot (speculation: pages only)
 
 What a mode reads and writes travels in `carry`, a dict threaded through
-the layer scans, so pools and state arrays are updated in place. Layers
-run as `cfg.segments()`: whole periods scanned, one-off layers once.
+the layer scans, so pools and state arrays are updated in place; it holds
+what the stack at hand needs and no more (pages alone for the one-block
+models). Layers run as `cfg.segments()`: whole periods scanned, one-off
+layers once; the one-block models' stacked parameter dict is their one
+segment as it is.
 
-Differential attention rides on the plain kernels: a KV pair is stored as
-one row [k1 ; k2] (and [v1 ; v2]) of twice the head size, and a query head
-is padded with zeros on the side of the other softmax, so `[q1 ; 0]` scores
-against k1 alone and `[0 ; q2]` against k2, and one pass over the pages
-feeds both softmaxes. The pools and rings are the page pool of
-ops/paged_attention.py (a token's KV pairs side by side in one row), whose
-ops take the plain queries and pairs of this file: the layout is theirs.
-Serve only: no sharding rules, no training path.
+Differential attention (window / full / cross) rides on the plain kernels:
+a KV pair is stored as one row [k1 ; k2] (and [v1 ; v2]) of twice the head
+size, and a query head is padded with zeros on the side of the other
+softmax, so `[q1 ; 0]` scores against k1 alone and `[0 ; q2]` against k2,
+and one pass over the pages feeds both softmaxes. The pools and rings are
+the page pool of ops/paged_attention.py (a token's KV heads or pairs side
+by side in one row), whose ops take the plain queries and keys of this
+file: the layout is theirs. The "attn" layers shard by the one-block
+models' rules (a `tp` mesh reaches the paged calls through the mode) and
+train through models/transformer.py's own loop over the same projections
+and second half; the other five kinds are serve only: no sharding rules,
+no training path.
 """
 
 from __future__ import annotations
@@ -41,18 +53,27 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import (
-    flash_attention,
     paged_attention_chunk,
     paged_attention_decode,
+    paged_attention_verify,
     pool_shape,
     write_then_attend,
 )
 from ..ops.ssm import ssm_scan, ssm_step
 from .config import ModelConfig
+from .transformer import (
+    _ffn_half,
+    _flash,
+    _lm_head,
+    _norm,
+    _prologue,
+    _qkv,
+)
 
 Params = Dict[str, Any]
 _F32 = jnp.float32
-_STATEFUL = ("mamba", "window", "full")
+# kinds that own rows of state arrays or pools, counted as layers go by
+_COUNTED = ("attn", "mamba", "window", "full")
 
 
 # ---------------------------------------------------------------------------
@@ -137,14 +158,21 @@ def ring_pages(cfg: ModelConfig, page_size: int) -> int:
 
 
 def new_request_state(cfg: ModelConfig, batch: int, dtype) -> Params:
-    """State a prefill hands over: conv tails [M,B,K-1,Di], scan state
-    [M,B,N,Di] (float32), and the last `window` keys and values of every
-    window layer [W,B,window,KVH,D]. Zeros are a sequence's start."""
+    """State a prefill hands over beside keys and values: conv tails
+    [M,B,K-1,Di] and scan state [M,B,N,Di] (float32) where there are mamba
+    layers, and the last `window` keys and values of every window layer
+    [W,B,window,KVH,D] where there are those. Zeros are a sequence's start;
+    the one-block models have none (the empty tree)."""
     M, NW = cfg.count("mamba"), cfg.count("window")
-    kv = (NW, batch, cfg.window, cfg.pool_heads, cfg.pool_dim)
-    return {"conv": jnp.zeros((M, batch, cfg.ssm_conv - 1, cfg.ssm_inner), dtype),
-            "ssm": jnp.zeros((M, batch, cfg.ssm_state, cfg.ssm_inner), _F32),
-            "wk": jnp.zeros(kv, dtype), "wv": jnp.zeros(kv, dtype)}
+    out = {}
+    if M:
+        out.update(
+            conv=jnp.zeros((M, batch, cfg.ssm_conv - 1, cfg.ssm_inner), dtype),
+            ssm=jnp.zeros((M, batch, cfg.ssm_state, cfg.ssm_inner), _F32))
+    if NW:
+        kv = (NW, batch, cfg.window, cfg.pool_heads, cfg.pool_dim)
+        out.update(wk=jnp.zeros(kv, dtype), wv=jnp.zeros(kv, dtype))
+    return out
 
 
 def new_engine_state(cfg: ModelConfig, batch: int, page_size: int,
@@ -154,12 +182,13 @@ def new_engine_state(cfg: ModelConfig, batch: int, page_size: int,
     pool of 1 + batch * ring pages in which slot b owns pages
     1 + b * ring .. (page 0 is never read)."""
     st = new_request_state(cfg, batch, act_dtype)
-    pool = pool_shape(cfg.count("window"),
-                      1 + batch * ring_pages(cfg, page_size), page_size,
-                      cfg.pool_heads, cfg.pool_dim)
-    return {"conv": st["conv"], "ssm": st["ssm"],
-            "wk": jnp.zeros(pool, cache_dtype),
-            "wv": jnp.zeros(pool, cache_dtype)}
+    if "wk" in st:
+        pool = pool_shape(cfg.count("window"),
+                          1 + batch * ring_pages(cfg, page_size), page_size,
+                          cfg.pool_heads, cfg.pool_dim)
+        st.update(wk=jnp.zeros(pool, cache_dtype),
+                  wv=jnp.zeros(pool, cache_dtype))
+    return st
 
 
 def install_state(state: Params, rs: Params, slot, length,
@@ -168,6 +197,13 @@ def install_state(state: Params, rs: Params, slot, length,
     its conv tails and scan state overwrite the slot's (whatever the last
     occupant left), and its last `window` keys go to the slot's ring, each
     at the place its position has there."""
+    out = dict(state)
+    for name in ("conv", "ssm"):
+        if name in state:
+            out[name] = jax.lax.dynamic_update_slice_in_dim(
+                state[name], rs[name].astype(state[name].dtype), slot, 1)
+    if "wk" not in state:
+        return out
     ring = ring_pages(cfg, page_size)
     span = ring * page_size
     r = jnp.arange(span)
@@ -179,10 +215,6 @@ def install_state(state: Params, rs: Params, slot, length,
         return img.reshape(pool_shape(img.shape[0], ring, page_size,
                                       cfg.pool_heads, cfg.pool_dim))
 
-    out = dict(state)
-    for name in ("conv", "ssm"):
-        out[name] = jax.lax.dynamic_update_slice_in_dim(
-            state[name], rs[name].astype(state[name].dtype), slot, 1)
     for name in ("wk", "wv"):
         out[name] = jax.lax.dynamic_update_slice_in_dim(
             state[name], image(rs[name]).astype(state[name].dtype),
@@ -203,8 +235,7 @@ def _dense_attend(q, k, v, scale, window=None):
         # under the kernel's smallest automatic block the sequence is one
         # block (left to itself flash_attention takes its XLA path there)
         block = T if T < 128 else None
-        return flash_attention(q, k, v, causal=True, scale=scale,
-                               block_q=block, block_k=block)
+        return _flash(q, k, v, scale=scale, block_q=block, block_k=block)
     B, _, H, D = q.shape
     KVH = k.shape[2]
     with jax.named_scope("window_attn_dense"):
@@ -217,31 +248,59 @@ def _dense_attend(q, k, v, scale, window=None):
         return o.reshape(B, T, H, D).astype(q.dtype)
 
 
-class Seq:
+class _Mode:
+    """What the modes share: where the tokens of x [B,T] are."""
+
+    def embed(self, params: Params, tokens: jax.Array) -> jax.Array:
+        """tokens [B,T] -> x [B,T,D]; the mode keeps the tokens' positions
+        `at` ([B,T]; None: 0..T-1) and the rotary tables for its attn
+        layers."""
+        self.at = self.positions(tokens.shape[1])
+        x, self.rope = _prologue(params, tokens, self.cfg, self.at, self.mesh)
+        return x
+
+
+class Seq(_Mode):
     """Whole sequences [B, T], right-padded to T with `n_valid` [B] real
     tokens each (None: all). keep=False is the plain forward. keep=True
-    also leaves in `carry` what the engine needs to go on decoding (see
-    new_request_state) and the full layers' keys and values. `chunk`
-    (start, page_table) makes it ONE sequence's prefill chunk: mamba and
-    window layers start from the state in `carry`, the full layer writes
-    and reads the page pool in `carry`."""
+    also leaves in `carry` what the engine needs to go on decoding: the
+    state of new_request_state and the keys and values of every layer that
+    caches them. `chunk` (start, page_table) makes it ONE sequence's
+    prefill chunk: mamba and window layers start from the state in `carry`,
+    the layers that cache keys write and read the page pool in `carry`
+    (by XLA under `tp` > 1 of `mesh`: GSPMD cannot partition the kernel),
+    and only `export` leaves the chunk's own keys and values beside it, in
+    the pool's dtype."""
 
     def __init__(self, cfg: ModelConfig, n_valid=None, keep: bool = False,
-                 chunk=None, page_size: int = 0):
+                 chunk=None, page_size: int = 0, mesh=None,
+                 export: bool = False):
         self.cfg, self.n_valid, self.keep, self.chunk = cfg, n_valid, keep, chunk
-        self.ps = page_size
+        self.ps, self.mesh, self.export = page_size, mesh, export
+        self.by_xla = mesh is not None and mesh.shape.get("tp", 1) > 1
+
+    def positions(self, T):
+        return None if self.chunk is None else (
+            self.chunk[0] + jnp.arange(T))[None]
 
     def init_carry(self, x, pools=None, state=None) -> Params:
         cfg = self.cfg
         B, T, _ = x.shape
-        carry = {"mem": jnp.zeros((B, T, cfg.ssm_inner), x.dtype)}
+        carry, dtype = {}, x.dtype
+        if cfg.count("mamba"):
+            carry["mem"] = jnp.zeros((B, T, cfg.ssm_inner), x.dtype)
         if self.chunk is not None:
             carry.update(state, k_pages=pools[0], v_pages=pools[1])
-            return carry
-        if self.keep:
+            dtype = pools[0].dtype
+            # where the chunk's keys go in the pool
+            self.page = self.chunk[1][self.at[0] // self.ps]
+            self.slot = self.at[0] % self.ps
+        elif self.keep:
             carry.update(new_request_state(cfg, B, x.dtype))
-        kv = (cfg.count("full"), B, T, cfg.pool_heads, cfg.pool_dim)
-        carry.update(k=jnp.zeros(kv, x.dtype), v=jnp.zeros(kv, x.dtype))
+        if self.chunk is None or self.export:
+            layers, kv_heads, head_dim = cfg.cache_dims
+            kv = (layers, B, T, kv_heads, head_dim)
+            carry.update(k=jnp.zeros(kv, dtype), v=jnp.zeros(kv, dtype))
         return carry
 
     # -- mamba
@@ -315,47 +374,63 @@ class Seq:
         return o[None].astype(q.dtype), carry
 
     def attend_full(self, carry, fi, q, k, v, scale):
-        """k is None: a cross layer, which reads and writes nothing."""
+        """Attention over every key so far, the layer's own (written as
+        row `fi` of what caches them) or, k is None: a cross layer, which
+        reads row `fi` and writes nothing."""
+        if k is not None and "k" in carry:
+            carry = {**carry,
+                     "k": carry["k"].at[fi].set(k.astype(carry["k"].dtype)),
+                     "v": carry["v"].at[fi].set(v.astype(carry["v"].dtype))}
         if self.chunk is None:
-            if k is not None:
-                carry = {**carry,
-                         "k": carry["k"].at[fi].set(k.astype(carry["k"].dtype)),
-                         "v": carry["v"].at[fi].set(v.astype(carry["v"].dtype))}
-            return _dense_attend(q, carry["k"][fi], carry["v"][fi], scale), carry
+            if k is None:
+                k, v = carry["k"][fi], carry["v"][fi]
+            return _dense_attend(q, k, v, scale), carry
         start, table = self.chunk
         C = q.shape[1]
 
         def attend(q, kp, vp, layer):
+            # key j is seen by query row c iff j <= start + c (the prefix
+            # and the chunk so far); rows past n_valid write keys that no
+            # later position bound lets anything see
             return paged_attention_chunk(q, kp, vp, table, start, start + C,
-                                         layer, scale=scale)
+                                         layer, scale=scale,
+                                         force_xla=self.by_xla)
 
         kp, vp = carry["k_pages"], carry["v_pages"]
         if k is None:
             return attend(q[0], kp, vp, fi)[None].astype(q.dtype), carry
-        pos = start + jnp.arange(C)
         o, kp, vp = write_then_attend(
-            attend, q[0], k[0], v[0], kp, vp, fi,
-            table[pos // self.ps], pos % self.ps)
+            attend, q[0], k[0], v[0], kp, vp, fi, self.page, self.slot)
         return o[None].astype(q.dtype), {**carry, "k_pages": kp, "v_pages": vp}
 
 
-class Decode:
+class Decode(_Mode):
     """One token for every decode slot [B, 1]: `positions` [B] is where it
-    goes, `page_tables` [B, pages] the full layer's pages. `carry` holds
-    the engine's pools and state whole (new_engine_state + the pool)."""
+    goes, `page_tables` [B, pages] the pages of the layers that cache keys.
+    `carry` holds the engine's pools and state whole (new_engine_state +
+    the pool). Under `tp` > 1 of `mesh` the paged kernel runs per shard."""
 
     def __init__(self, cfg: ModelConfig, positions, page_tables,
-                 page_size: int):
-        self.cfg, self.pos, self.tables, self.ps = (
-            cfg, positions, page_tables, page_size)
+                 page_size: int, mesh=None):
+        self.cfg, self.pos, self.tables, self.ps, self.mesh = (
+            cfg, positions, page_tables, page_size, mesh)
         B = positions.shape[0]
+        # where this token's keys go in the pool
+        self.page = page_tables[jnp.arange(B), positions // page_size]
+        self.slot = positions % page_size
         self.ring = ring_pages(cfg, page_size) if cfg.count("window") else 1
         self.ring_table = (1 + jnp.arange(B)[:, None] * self.ring
                            + jnp.arange(self.ring)[None, :]).astype(jnp.int32)
 
+    def positions(self, T):
+        return self.pos[:, None]
+
     def init_carry(self, x, pools, state) -> Params:
-        return {"mem": jnp.zeros((*x.shape[:2], self.cfg.ssm_inner), x.dtype),
-                **state, "k_pages": pools[0], "v_pages": pools[1]}
+        carry = {**state, "k_pages": pools[0], "v_pages": pools[1]}
+        if self.cfg.count("mamba"):
+            carry["mem"] = jnp.zeros(
+                (*x.shape[:2], self.cfg.ssm_inner), x.dtype)
+        return carry
 
     def valid(self, T):
         return None
@@ -387,17 +462,52 @@ class Decode:
     def attend_full(self, carry, fi, q, k, v, scale):
         def attend(q, kp, vp, layer):
             return paged_attention_decode(q, kp, vp, self.tables,
-                                          self.pos + 1, layer, scale=scale)
+                                          self.pos + 1, layer, scale=scale,
+                                          mesh=self.mesh)
 
         kp, vp = carry["k_pages"], carry["v_pages"]
         if k is None:
             return attend(q[:, 0], kp, vp, fi)[:, None], carry
-        B = q.shape[0]
+        # this token's keys into their page slot, then attention
         o, kp, vp = write_then_attend(
             attend, q[:, 0], k[:, 0], v[:, 0], kp, vp, fi,
-            self.tables[jnp.arange(B), self.pos // self.ps],
-            self.pos % self.ps)
+            self.page, self.slot)
         return o[:, None], {**carry, "k_pages": kp, "v_pages": vp}
+
+
+class Verify(Decode):
+    """Decode for S tokens a slot [B, S] at `positions` [B] + 0..S-1
+    (speculation: the last committed token and its drafts): rows past a
+    slot's `n_draft` [B] write to the trash page, and row s sees the keys
+    up to its own. Pages only: what keeps state beside its pages cannot
+    be rewound to the accepted draft, and the engine refuses it."""
+
+    def __init__(self, cfg: ModelConfig, positions, page_tables,
+                 page_size: int, n_draft, mesh=None):
+        super().__init__(cfg, positions, page_tables, page_size, mesh)
+        self.n_draft = n_draft
+
+    def positions(self, T):
+        return self.pos[:, None] + jnp.arange(T)[None, :]
+
+    def init_carry(self, x, pools, state) -> Params:
+        B, S = self.at.shape
+        row_valid = jnp.arange(S)[None, :] <= self.n_draft[:, None]
+        self.page = jnp.where(
+            row_valid,
+            self.tables[jnp.arange(B)[:, None], self.at // self.ps], 0)
+        self.slot = self.at % self.ps
+        return super().init_carry(x, pools, state)
+
+    def attend_full(self, carry, fi, q, k, v, scale):
+        def attend(q, kp, vp, layer):
+            return paged_attention_verify(q, kp, vp, self.tables, self.pos,
+                                          layer, scale=scale, mesh=self.mesh)
+
+        o, kp, vp = write_then_attend(
+            attend, q, k, v, carry["k_pages"], carry["v_pages"], fi,
+            self.page, self.slot)
+        return o, {**carry, "k_pages": kp, "v_pages": vp}
 
 
 # ---------------------------------------------------------------------------
@@ -477,28 +587,43 @@ def _attention(h, lp, cfg, kind, layer, idx, mode, carry):
             + lp["bo"].astype(dtype)), carry
 
 
-def _layer(x, lp, cfg, kind, layer, idx, mode, carry):
-    from .transformer import _dense_ffn, _norm
+def _attn(h, lp, cfg, idx, mode, carry):
+    """The one-block models' mixer: q, k, v turned to the tokens' positions
+    (models/transformer.py's, the training block's too), then the mode's
+    attention over the layer's own keys."""
+    q, k, v = _qkv(h, lp, cfg, mode.rope, mode.at)
+    o, carry = mode.attend_full(carry, idx, q, k, v, cfg.hdim ** -0.5)
+    return jnp.einsum("bthk,hkd->btd", o.astype(h.dtype),
+                      lp["wo"].astype(h.dtype)), carry
 
+
+def _layer(x, lp, cfg, kind, layer, idx, mode, carry):
+    # the scopes are what a profile's readers key on: the mixer's kind
+    # ("attn" as in the training block), then "ffn" or "moe"
     with jax.named_scope(kind):
-        h = _norm(x, lp["ln1"], lp["ln1_b"], cfg)
-        if kind == "mamba":
+        h = _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
+        if kind == "attn":
+            o, carry = _attn(h, lp, cfg, idx, mode, carry)
+        elif kind == "mamba":
             o, carry = _mamba(h, lp, cfg, idx, mode, carry)
         elif kind == "gmu":
             o = _gmu(h, lp, cfg, carry)
         else:
             o, carry = _attention(h, lp, cfg, kind, layer, idx, mode, carry)
         x = x + o
-    with jax.named_scope("ffn"):
-        return x + _dense_ffn(_norm(x, lp["ln2"], lp["ln2_b"], cfg), lp, cfg), carry
+    return _ffn_half(x, lp, cfg)[0], carry
 
 
 def run_stack(layers, x, cfg: ModelConfig, mode, carry):
-    """Every layer of the stack over x [B,T,D] -> (x, carry). A segment of
-    r > 1 periods is one `lax.scan`; which mamba, window or full layer a
-    layer is (its row in the state arrays and pools) is counted from the
-    layers before it."""
-    seen = dict.fromkeys(_STATEFUL, 0)
+    """Every layer of the stack over x [B,T,D] -> (x, carry). `layers` is
+    one entry a segment of `cfg.segments()` (a tuple with one stacked dict
+    per layer of the period), or the one-block models' stacked dict, their
+    one segment. A segment of r > 1 periods is one `lax.scan`; which attn,
+    mamba, window or full layer a layer is (its row in the state arrays
+    and pools) is counted from the layers before it."""
+    if isinstance(layers, dict):
+        layers = [(layers,)]
+    seen = dict.fromkeys(_COUNTED, 0)
     for (first, kinds, repeats), seg in zip(cfg.segments(), layers):
         per = {k: kinds.count(k) for k in seen}
 
@@ -531,42 +656,37 @@ def run_stack(layers, x, cfg: ModelConfig, mode, carry):
 # ---------------------------------------------------------------------------
 
 
-def _embed(params, tokens, cfg):
-    from .transformer import _embed_lookup
-
-    with jax.named_scope("embed"):
-        return _embed_lookup(params["embed"], tokens, jnp.dtype(cfg.dtype))
+def _run(params: Params, tokens: jax.Array, cfg: ModelConfig, mode, *carried):
+    """tokens [B,T] embedded and through every layer -> (x, carry)."""
+    x = mode.embed(params, tokens)
+    x, carry = run_stack(params["layers"], x, cfg, mode,
+                         mode.init_carry(x, *carried))
+    carry.pop("mem", None)
+    return x, carry
 
 
 def forward(params: Params, tokens: jax.Array, cfg: ModelConfig):
     """tokens [B,T] -> (logits [B,T,V] float32, 0): no cache, no state."""
-    from .transformer import _lm_head
-
-    x = _embed(params, tokens, cfg)
-    mode = Seq(cfg)
-    x, _ = run_stack(params["layers"], x, cfg, mode, mode.init_carry(x))
+    x, _ = _run(params, tokens, cfg, Seq(cfg))
     return _lm_head(x, params, cfg), jnp.zeros((), _F32)
 
 
-def run_paged(layers, x, cfg: ModelConfig, mode, pools, state):
-    """The engine's decode and chunk programs: `run_stack` over the page
-    pool `pools` (k, v) and `state` (per slot for Decode, one sequence's
-    for a Seq chunk). -> (x, k_pages, v_pages, state)."""
-    x, carry = run_stack(layers, x, cfg, mode,
-                         mode.init_carry(x, pools, state))
-    k_pages, v_pages = carry.pop("k_pages"), carry.pop("v_pages")
-    del carry["mem"]
-    return x, k_pages, v_pages, carry
+def run_paged(params: Params, tokens: jax.Array, cfg: ModelConfig, mode,
+              pools, state=None):
+    """What the engine's decode and chunk programs and the speculative
+    programs share: tokens [B,T] through every layer over the page pool
+    `pools` (k, v) and `state` (per slot for Decode, one sequence's for a
+    Seq chunk; None or the empty tree where pages are all there is).
+    -> (x [B,T,D] before the final norm, k_pages, v_pages, state, and
+    whatever else the mode kept: an export's `k` and `v`)."""
+    x, carry = _run(params, tokens, cfg, mode, pools, state or {})
+    return x, carry.pop("k_pages"), carry.pop("v_pages"), carry
 
 
 def prefill(params: Params, cfg: ModelConfig, tokens: jax.Array,
             true_len: jax.Array):
     """The engine's bucket prefill: tokens [B,T] right-padded, true_len [B].
-    -> (hidden state [B,T,D] before the final norm, cache): the full
-    layers' keys and values `k`, `v` [F,B,T,KVH,D] and the state of
-    new_request_state, every leaf with the batch on axis 1."""
-    x = _embed(params, tokens, cfg)
-    mode = Seq(cfg, n_valid=true_len, keep=True)
-    x, carry = run_stack(params["layers"], x, cfg, mode, mode.init_carry(x))
-    carry.pop("mem")
-    return x, carry
+    -> (hidden state [B,T,D] before the final norm, cache): the keys and
+    values `k`, `v` [layers,B,T,KVH,D] of the layers that cache them and
+    the state of new_request_state, every leaf with the batch on axis 1."""
+    return _run(params, tokens, cfg, Seq(cfg, n_valid=true_len, keep=True))

@@ -43,10 +43,11 @@ Params = Dict[str, Any]
 def _one_block_only(cfg: ModelConfig, what: str) -> None:
     if cfg.is_stack:
         raise NotImplementedError(
-            f"{what} knows the one block of this file; {cfg.name!r} is a "
-            "stack of unlike layers (models/stack.py), which has `forward` "
-            "and the serving engine's programs and no more yet: no sharding "
-            "rules, no training path, no contiguous-cache generate")
+            f"{what} runs one kind of layer, rotary attention and an FFN; "
+            f"{cfg.name!r} is a stack of unlike layers, which models/stack.py "
+            "runs, on the serve path alone (`forward` and the engine's "
+            "programs): no sharding rules, no training path, no "
+            "contiguous-cache generate")
 
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
@@ -173,13 +174,16 @@ def _norm(x, w, b, cfg):
 _QKV_AXES = ("batch", None, "heads", None)  # seq gathered: flash sees all keys
 
 
-def _flash(q, k, v, mesh=None):
+def _flash(q, k, v, mesh=None, **kernel):
     return per_shard(
-        functools.partial(flash_attention, causal=True),
+        functools.partial(flash_attention, causal=True, **kernel),
         (_QKV_AXES,) * 3, _QKV_AXES, q, k, v, mesh=mesh)
 
 
-def _attention(x, lp, cfg, rope_tables, positions, mesh=None):
+def _qkv(x, lp, cfg, rope_tables, positions):
+    """x [B,T,D] -> q [B,T,H,hd], k, v [B,T,KVH,hd], q and k turned to
+    `positions` [B,T] (None: 0..T-1) where the model is rotary. Shared by
+    the training block below and the serve path's (models/stack.py)."""
     dtype = x.dtype
     q = jnp.einsum("btd,dhk->bthk", x, lp["wq"].astype(dtype))
     k = jnp.einsum("btd,dhk->bthk", x, lp["wk"].astype(dtype))
@@ -188,6 +192,12 @@ def _attention(x, lp, cfg, rope_tables, positions, mesh=None):
         cos, sin = rope_tables
         q = apply_rope(q, cos, sin, positions)
         k = apply_rope(k, cos, sin, positions)
+    return q, k, v
+
+
+def _attention(x, lp, cfg, rope_tables, positions, mesh=None):
+    dtype = x.dtype
+    q, k, v = _qkv(x, lp, cfg, rope_tables, positions)
     q = constrain(q, ("batch", "seq", "heads", None))
     k = constrain(k, ("batch", "seq", "heads", None))
     v = constrain(v, ("batch", "seq", "heads", None))
@@ -358,12 +368,10 @@ def _moe_ffn_gather(x, lp, cfg):
         return constrain(out, ("batch", "seq", "embed")), aux
 
 
-def _block(x, lp, cfg, rope_tables, positions, mesh=None):
-    # scope names are what a profile's readers key on; the engine's
-    # hand-written decode and chunk bodies (serve/engine.py) use the same
-    with jax.named_scope("attn"):
-        h = _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
-        x = x + _attention(h, lp, cfg, rope_tables, positions, mesh)
+def _ffn_half(x, lp, cfg):
+    """A layer's second half, x + FFN(norm(x)) or the experts in its place
+    -> (x, aux loss). Shared by the training block below and every layer
+    of the serve path (models/stack.py)."""
     with jax.named_scope("moe" if cfg.is_moe else "ffn"):
         h = _norm(x, lp["ln2"], lp.get("ln2_b"), cfg)
         if cfg.is_moe:
@@ -371,6 +379,17 @@ def _block(x, lp, cfg, rope_tables, positions, mesh=None):
         else:
             y, aux = _dense_ffn(h, lp, cfg), jnp.zeros((), jnp.float32)
         return x + y, aux
+
+
+def _block(x, lp, cfg, rope_tables, positions, mesh=None):
+    # The training layer: its own loop (run_layers: remat, sharding
+    # constraints, ring attention) over the projections and the second
+    # half that the serve path's "attn" layer (models/stack.py) runs too.
+    # Scope names are what a profile's readers key on, here as there.
+    with jax.named_scope("attn"):
+        h = _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
+        x = x + _attention(h, lp, cfg, rope_tables, positions, mesh)
+    return _ffn_half(x, lp, cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -407,11 +426,11 @@ def _prologue(params, tokens, cfg, positions=None, mesh=None):
     T = tokens.shape[1]
     with jax.named_scope("embed"):
         x = _embed_lookup(params["embed"], tokens, dtype, mesh=mesh)
+        rope_tables = None
         if cfg.positional == "learned":
             pos = positions if positions is not None else jnp.arange(T)[None, :]
             x = x + params["pos_emb"][pos].astype(dtype)
-            rope_tables = None
-        else:
+        elif cfg.positional == "rope":
             rope_tables = rope_frequencies(
                 cfg.hdim, cfg.max_seq_len, cfg.rope_theta)
         return constrain(x, ("batch", "seq", "embed")), rope_tables
@@ -427,6 +446,24 @@ def _lm_head(x, params, cfg) -> jax.Array:
         if cfg.logits_softcap:
             logits = cfg.logits_softcap * jnp.tanh(logits / cfg.logits_softcap)
         return constrain(logits, ("batch", "seq", "vocab"))
+
+
+def _head_logits(x, pick, params, cfg, einsum: str):
+    """The serve path's head: final norm of x [B,T,D], then the head in f32
+    (+ softcap) on the rows `pick` keeps alone."""
+    x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg)
+    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    if cfg.tie_embeddings and head.dtype != jnp.float32:
+        # the tied table as it is stored, accumulated in f32: a float32
+        # copy of a 200k-row table is 2 GB a step
+        logits = jnp.einsum(einsum, pick(x).astype(head.dtype), head,
+                            preferred_element_type=jnp.float32)
+    else:
+        logits = jnp.einsum(einsum, pick(x).astype(jnp.float32),
+                            head.astype(jnp.float32))
+    if cfg.logits_softcap:
+        logits = cfg.logits_softcap * jnp.tanh(logits / cfg.logits_softcap)
+    return logits
 
 
 def run_layers(

@@ -12,6 +12,11 @@ Execution shapes are static: the decode batch is a fixed-size slot array
 attention by length=0), so the whole serving loop reuses two compiled
 programs (prefill-per-bucket + one decode).
 
+The layers themselves are not here: every program builds a mode of
+models/stack.py (Decode, a Seq chunk, a whole Seq) and runs the model's
+layer kinds through `stack.run_paged` / `stack.prefill`, whatever the
+family; this file owns slots, pages, per-slot state and sampling.
+
 Two execution threads, so prefill never blocks decode cadence (TTFT vs
 ITL isolation — the role of vLLM's separate prefill scheduling): a
 prefill thread runs prompt compute and samples the first token; the
@@ -47,23 +52,8 @@ from ..core.logging import get_logger
 from ..core.metrics import Counter, Gauge, Histogram
 from ..util import slo, tracing
 from ..models import ModelConfig, stack
-from ..models.transformer import (
-    _dense_ffn,
-    _embed_lookup,
-    _moe_ffn,
-    _norm,
-    prefill,
-)
-from ..ops import (
-    apply_rope,
-    gather_pages,
-    paged_attention_chunk,
-    paged_attention_decode,
-    pool_shape,
-    rope_frequencies,
-    scatter_pages,
-    write_then_attend,
-)
+from ..models.transformer import _head_logits
+from ..ops import gather_pages, pool_shape, scatter_pages
 from .config import SpeculationConfig
 from .spec_decode import SpecDecoder
 
@@ -505,7 +495,7 @@ class _ChunkState:
         # (page-aligned except after the final frame) and the frame seq
         self.emitted_upto = 0
         self.sink_seq = 0
-        # a stack with recurrent state: what the chunks so far left behind
+        # what the chunks so far left behind beside the pages
         # (stack.new_request_state), handed from chunk to chunk
         self.state = None
 
@@ -667,23 +657,22 @@ class InferenceEngine:
         KVH = model_cfg.cache_dims[1]
         P, ps = engine_cfg.max_pages, engine_cfg.page_size
         pool = self.abstract_pool()
-        # A stack of unlike layers: conv tails, scan state and the window
-        # layers' rings are `self.state`, per decode slot, sized by
-        # max_batch_size and the model.
-        self.state = None
-        if self._stack:
-            self._refuse_for_stack(mesh, engine_cfg)
-            self.state = stack.new_engine_state(
-                model_cfg, B, ps, jnp.dtype(model_cfg.dtype), pool.dtype)
-            # a sequence's start, shared by every chunked prompt's first
-            # chunk (never donated: a chunk hands back a new state)
-            self._request_start = stack.new_request_state(
-                model_cfg, 1, jnp.dtype(model_cfg.dtype))
-            self._install_state = jax.jit(
-                tracing.named(functools.partial(
-                    stack.install_state, cfg=model_cfg, page_size=ps),
-                    "install_state"),
-                donate_argnums=(0,))
+        self._refuse_for_stack(mesh, engine_cfg)
+        # What the model's layers keep per decode slot beside their pages
+        # (conv tails, scan state, the window layers' rings), sized by
+        # max_batch_size and the model: the empty tree for the one-block
+        # models. Every program takes it and hands it back.
+        self.state = stack.new_engine_state(
+            model_cfg, B, ps, jnp.dtype(model_cfg.dtype), pool.dtype)
+        # a sequence's start, shared by every chunked prompt's first
+        # chunk (never donated: a chunk hands back a new state)
+        self._request_start = stack.new_request_state(
+            model_cfg, 1, jnp.dtype(model_cfg.dtype))
+        self._install_state = jax.jit(
+            tracing.named(functools.partial(
+                stack.install_state, cfg=model_cfg, page_size=ps),
+                "install_state"),
+            donate_argnums=(0,))
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
 
@@ -783,14 +772,12 @@ class InferenceEngine:
     # own phases (it cannot import this module: this one imports it)
     phase = staticmethod(decode_phase)
 
-    @property
-    def _stack(self) -> bool:
-        return self.cfg.is_stack
-
     def _refuse_for_stack(self, mesh, ecfg: EngineConfig) -> None:
         """What assumes that pages are the whole state of a request, or
-        one block in every layer, and is not made right for a stack of
-        unlike layers yet: refused here, with the reason."""
+        the one-block models' sharding rules, and is not made right for a
+        stack of unlike layers yet: refused here, with the reason."""
+        if not self.cfg.is_stack:
+            return
         name = self.cfg.name
         if mesh is not None:
             raise ValueError(
@@ -804,15 +791,12 @@ class InferenceEngine:
                 "rewound that way. Serve it with speculation off")
 
     def _refuse_kv_transfer(self, what: str) -> None:
-        if self._stack:
+        if self.cfg.is_stack:
             raise ValueError(
                 f"{what}: {self.cfg.name!r} keeps state beside its pages "
                 "(conv tails, scan state, window rings) that the KV wire "
                 "does not carry; disaggregated roles and KV export/import "
                 "are refused for it")
-
-    def _state_args(self) -> tuple:
-        return (self.state,) if self._stack else ()
 
     def abstract_pool(self, sharding=None) -> jax.ShapeDtypeStruct:
         """k_pages / v_pages as this engine's programs take them, from its
@@ -831,110 +815,52 @@ class InferenceEngine:
         device-side sampling feeding the next step. One dispatch + one
         [K,B] readback per span. Cached per K (K varies only near request
         completion)."""
-        cfg, ecfg = self.cfg, self.ecfg
-        ps = ecfg.page_size
-        # tp>1: the Pallas kernel runs inside shard_map over the tp axis
-        # (paged_attention_decode handles the wrap) instead of falling back
-        # to the XLA reference path
-        tp_mesh = self.mesh if self._tp > 1 else None
-
-        def decode(params, k_pages, v_pages, tokens, positions, page_tables,
-                   temps, key, top_ps=None, top_ks=None, advanced=False,
-                   state=None):
-            """tokens/positions [B]; page_tables [B, pages_per_seq]; `state`
-            (a stack of unlike layers only): the engine's per-slot state."""
-            dtype = jnp.dtype(cfg.dtype)
-            B = tokens.shape[0]
-            with jax.named_scope("embed"):
-                x = _embed_lookup(
-                    params["embed"], tokens[:, None], dtype, mesh=self.mesh
-                )  # [B,1,D]; one-hot matmul form when the table is sharded
-                if cfg.positional == "learned":
-                    x = x + params["pos_emb"][positions][:, None].astype(dtype)
-                    rope_tables = None
-                elif cfg.positional == "rope":
-                    rope_tables = rope_frequencies(
-                        cfg.hdim, cfg.max_seq_len, cfg.rope_theta)
-            pos2d = positions[:, None]
-            page_idx = page_tables[jnp.arange(B), positions // ps]  # [B]
-            slot_idx = positions % ps
-
-            def attend(q, kp, vp, layer):
-                return paged_attention_decode(
-                    q, kp, vp, page_tables, positions + 1, layer,
-                    mesh=tp_mesh)
-
-            def body(carry, xs):
-                x, kp, vp = carry  # kp/vp: the whole pool, carried
-                lp, layer = xs
-                with jax.named_scope("attn"):
-                    h = _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
-                    q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(dtype))
-                    k = jnp.einsum("btd,dhk->bthk", h, lp["wk"].astype(dtype))
-                    v = jnp.einsum("btd,dhk->bthk", h, lp["wv"].astype(dtype))
-                    if cfg.positional == "rope":
-                        cos, sin = rope_tables
-                        q = apply_rope(q, cos, sin, pos2d)
-                        k = apply_rope(k, cos, sin, pos2d)
-                    # this token's kv into its page slot, then attention
-                    o, kp, vp = write_then_attend(
-                        attend, q[:, 0], k[:, 0], v[:, 0], kp, vp, layer,
-                        page_idx, slot_idx)
-                    o = jnp.einsum("bhk,hkd->bd", o,
-                                   lp["wo"].astype(dtype))[:, None]
-                    x = x + o
-                x = x + _ffn(x, lp, cfg)
-                return (x, kp, vp), None
-
-            if cfg.is_stack:
-                x, new_k, new_v, state = stack.run_paged(
-                    params["layers"], x, cfg,
-                    stack.Decode(cfg, positions, page_tables, ps),
-                    (k_pages, v_pages), state)
-            else:
-                (x, new_k, new_v), _ = jax.lax.scan(
-                    body, (x, k_pages, v_pages),
-                    (params["layers"], jnp.arange(cfg.n_layers)))
-            with jax.named_scope("lm_head"):
-                logits = _head_logits(x, lambda x: x[:, 0], params, cfg,
-                                      "bd,dv->bv")
-            with jax.named_scope("sample"):
-                if advanced:
-                    toks = _device_sample_topk_topp(logits, temps, top_ps,
-                                                    top_ks, key)
-                else:
-                    # per-slot sampling: temp<=0 -> greedy
-                    greedy = jnp.argmax(logits, axis=-1)
-                    scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-                    sampled = jax.random.categorical(key, scaled, axis=-1)
-                    toks = jnp.where(temps > 0, sampled,
-                                     greedy).astype(jnp.int32)
-                # logprob of the sampled token under the RAW distribution
-                # (negligible next to the lm_head matmul, so it is computed
-                # unconditionally rather than doubling the program cache)
-                logps = jnp.take_along_axis(
-                    jax.nn.log_softmax(logits, axis=-1),
-                    toks[:, None].astype(jnp.int32), axis=-1)[:, 0]
-            return toks, logps, new_k, new_v, state
+        cfg, ps = self.cfg, self.ecfg.page_size
 
         def decode_span(params, k_pages, v_pages, tokens, positions,
                         page_tables, temps, top_ps, top_ks, key, state=None,
                         *, n_steps, advanced):
-            def sub(carry, i):
-                toks_in, pos, kp, vp, st = carry
-                ki = jax.random.fold_in(key, i)
-                toks, lps, kp, vp, st = decode(
-                    params, kp, vp, toks_in, pos, page_tables, temps, ki,
-                    top_ps, top_ks, advanced, st,
-                )
-                return (toks, pos + 1, kp, vp, st), (toks, lps)
+            """tokens/positions [B]; page_tables [B, pages_per_seq]; `state`:
+            what the layers keep per slot beside their pages (None: the
+            empty tree). -> seq/logps [n_steps, B], the pool, the state."""
 
-            (_, _, kp, vp, state), (seq, logps) = jax.lax.scan(
-                sub, (tokens, positions, k_pages, v_pages, state),
-                jnp.arange(n_steps)
-            )
-            # seq/logps [n_steps, B]; a stack's state rides last
-            return (seq, logps, kp, vp) + (() if state is None else (state,))
+            def step(carry, i):
+                tokens, positions, k_pages, v_pages, state = carry
+                # tp>1: the paged kernel runs inside shard_map over the tp
+                # axis (the mode hands it the mesh), not by XLA's fallback
+                x, k_pages, v_pages, state = stack.run_paged(
+                    params, tokens[:, None], cfg,
+                    stack.Decode(cfg, positions, page_tables, ps, self.mesh),
+                    (k_pages, v_pages), state)
+                with jax.named_scope("lm_head"):
+                    logits = _head_logits(x, lambda x: x[:, 0], params, cfg,
+                                          "bd,dv->bv")
+                with jax.named_scope("sample"):
+                    ki = jax.random.fold_in(key, i)
+                    if advanced:
+                        toks = _device_sample_topk_topp(logits, temps, top_ps,
+                                                        top_ks, ki)
+                    else:
+                        # per-slot sampling: temp<=0 -> greedy
+                        greedy = jnp.argmax(logits, axis=-1)
+                        scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+                        sampled = jax.random.categorical(ki, scaled, axis=-1)
+                        toks = jnp.where(temps > 0, sampled,
+                                         greedy).astype(jnp.int32)
+                    # logprob of the sampled token under the RAW distribution
+                    # (negligible next to the lm_head matmul, so it is
+                    # computed unconditionally rather than doubling the
+                    # program cache)
+                    logps = jnp.take_along_axis(
+                        jax.nn.log_softmax(logits, axis=-1),
+                        toks[:, None].astype(jnp.int32), axis=-1)[:, 0]
+                return (toks, positions + 1, k_pages, v_pages, state), (
+                    toks, logps)
+
+            (_, _, k_pages, v_pages, state), (seq, logps) = jax.lax.scan(
+                step, (tokens, positions, k_pages, v_pages, state or {}),
+                jnp.arange(n_steps))
+            return seq, logps, k_pages, v_pages, state
 
         cache: Dict[Any, Any] = {}
 
@@ -950,106 +876,48 @@ class InferenceEngine:
                                           advanced=advanced),
                         f"decode_span_{n_steps}"
                         + ("_adv" if advanced else "")),
-                    donate_argnums=(1, 2, 10) if self._stack else (1, 2),
+                    donate_argnums=(1, 2, 10),
                 ))
             return cache[key_]
 
         return for_span
 
     def _build_chunk_prefill(self):
-        """Jit a C-token prefill chunk: compute the chunk's qkv, scatter
-        its KV into the sequence's pages, and attend q over the paged
-        prefix (per-row causal bound). Attention runs the Pallas chunk
-        kernel (ops.paged_attention_chunk: double-buffered page DMAs,
-        reads only the valid prefix pages) where shapes allow; the XLA
-        gather fallback — which touches the whole table — covers CPU
+        """Jit a C-token prefill chunk of one sequence: the chunk's keys
+        and values go straight into the sequence's pages and its queries
+        attend over the paged prefix (per-row causal bound), through the
+        layers of models/stack.py in its chunk mode. Attention runs the
+        Pallas chunk kernel (ops.paged_attention_chunk: double-buffered
+        page DMAs, reads only the valid prefix pages) where shapes allow;
+        the XLA gather fallback, which touches the whole table, covers CPU
         tests, odd head dims, and TP meshes (GSPMD partitions the
         fallback's einsums; a bare pallas_call it cannot)."""
-        cfg, ecfg = self.cfg, self.ecfg
-        ps = ecfg.page_size
-        pps = ecfg.pages_per_seq
-        hd = cfg.hdim
-        tp_force_xla = self._tp > 1
+        cfg, ps = self.cfg, self.ecfg.page_size
 
         def chunk_step(params, k_pages, v_pages, tokens, start, page_table,
                        last_idx, state=None, export=False):
-            """tokens [C]; start/last_idx scalars; page_table [pps].
-            Returns (logits_at_last_idx, k_pages, v_pages); with
-            export=True (static) also the chunk's own KV slabs
-            [L, C, KVH, hd] in the pool dtype, so streamed export ships
-            this chunk without a separate page-gather dispatch (which
-            would queue behind whatever decode span is in flight). `state`
-            (a stack of unlike layers only): what the sequence's chunks so
-            far left behind; the chunk's own comes back last. Rows past
-            last_idx are padding."""
-            dtype = jnp.dtype(cfg.dtype)
-            C = tokens.shape[0]
-            positions = start + jnp.arange(C)
-            with jax.named_scope("embed"):
-                x = _embed_lookup(params["embed"], tokens[None, :], dtype,
-                                  mesh=self.mesh)  # [1,C,D]
-                if cfg.positional == "learned":
-                    x = x + params["pos_emb"][positions][None].astype(dtype)
-                    rope_tables = None
-                elif cfg.positional == "rope":
-                    rope_tables = rope_frequencies(
-                        cfg.hdim, cfg.max_seq_len, cfg.rope_theta)
-            if cfg.is_stack:
-                x, new_k, new_v, state = stack.run_paged(
-                    params["layers"], x, cfg,
-                    stack.Seq(cfg, n_valid=(last_idx + 1)[None], keep=True,
-                              chunk=(start, page_table), page_size=ps),
-                    (k_pages, v_pages), state)
-                with jax.named_scope("lm_head"):
-                    logits = _head_logits(x, lambda x: x[0, last_idx],
-                                          params, cfg, "d,dv->v")
-                return logits, new_k, new_v, state
-            page_idx = page_table[positions // ps]  # [C]
-            slot_idx = positions % ps
-
-            def attend(q, kp, vp, layer):
-                # key j visible to query row c iff j <= start + c
-                # (prefix + causal intra-chunk); pad rows past true_len
-                # write KV but are never selected by last_idx and are
-                # invisible to later decode (position bound)
-                return paged_attention_chunk(
-                    q, kp, vp, page_table, start, start + C, layer,
-                    force_xla=tp_force_xla)
-
-            def body(carry, xs):
-                x, kp, vp = carry  # kp/vp: the whole pool, carried
-                lp, layer = xs
-                with jax.named_scope("attn"):
-                    h = _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
-                    q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(dtype))
-                    k = jnp.einsum("btd,dhk->bthk", h, lp["wk"].astype(dtype))
-                    v = jnp.einsum("btd,dhk->bthk", h, lp["wv"].astype(dtype))
-                    if cfg.positional == "rope":
-                        cos, sin = rope_tables
-                        q = apply_rope(q, cos, sin, positions[None])
-                        k = apply_rope(k, cos, sin, positions[None])
-                    o, kp, vp = write_then_attend(
-                        attend, q[0], k[0], v[0], kp, vp, layer,
-                        page_idx, slot_idx)
-                    o = jnp.einsum("chk,hkd->cd", o.astype(dtype),
-                                   lp["wo"].astype(dtype))[None]
-                    x = x + o
-                x = x + _ffn(x, lp, cfg)
-                # export stacks the chunk's OWN keys and values
-                # [C, KVH, hd] (small); the pool is never a stacked output
-                return (x, kp, vp), (
-                    (k[0].astype(kp.dtype), v[0].astype(vp.dtype))
-                    if export else None)
-
-            (x, new_k, new_v), chunk_kv = jax.lax.scan(
-                body, (x, k_pages, v_pages),
-                (params["layers"], jnp.arange(cfg.n_layers)))
+            """tokens [C]; start/last_idx scalars; page_table [pps]; `state`:
+            what the sequence's chunks so far left behind beside its pages
+            (None: a sequence's start where pages are all there is).
+            Returns (logits_at_last_idx, k_pages, v_pages, state); with
+            export=True (static) the chunk's own KV slabs [L, C, KVH, hd]
+            in the pool dtype come before the state, so streamed export
+            ships this chunk without a separate page-gather dispatch
+            (which would queue behind whatever decode span is in flight).
+            Rows past last_idx are padding."""
+            x, new_k, new_v, state = stack.run_paged(
+                params, tokens[None, :], cfg,
+                stack.Seq(cfg, n_valid=(last_idx + 1)[None], keep=True,
+                          chunk=(start, page_table), page_size=ps,
+                          mesh=self.mesh, export=export),
+                (k_pages, v_pages), state)
             with jax.named_scope("lm_head"):
                 logits = _head_logits(x, lambda x: x[0, last_idx], params,
                                       cfg, "d,dv->v")
             if export:
-                return logits, new_k, new_v, *chunk_kv
-            return logits, new_k, new_v
+                return (logits, new_k, new_v, state.pop("k")[:, 0],
+                        state.pop("v")[:, 0], state)
+            return logits, new_k, new_v, state
 
         cache: Dict[Any, Any] = {}
 
@@ -1123,19 +991,19 @@ class InferenceEngine:
                     jnp.zeros((B, pps), jnp.int32),
                     jnp.zeros((B,), jnp.float32),
                     jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.int32),
-                    jax.random.PRNGKey(0), *self._state_args(),
+                    jax.random.PRNGKey(0), self.state,
                 ))[0]
                 _np.asarray(seq)  # block until compiled + executed
         if self.ecfg.chunked_prefill:
             C = self.ecfg.prefill_chunk
-            logits, self.k_pages, self.v_pages, *_ = self._chunk_fn(C)(
+            logits, self.k_pages, self.v_pages, _ = self._chunk_fn(C)(
                 self.params, self.k_pages, self.v_pages,
                 jnp.zeros((C,), jnp.int32), jnp.int32(0),
                 jnp.zeros((pps,), jnp.int32), jnp.int32(C - 1),
-                *((self._request_start,) if self._stack else ()),
+                self._request_start,
             )
             _np.asarray(logits)
-            if self._stack:  # and the program that hands a slot its state
+            if self.state:  # and the program that hands a slot its state
                 self.state = self._install_state(
                     self.state, self._request_start, jnp.int32(0),
                     jnp.int32(1))
@@ -1144,11 +1012,8 @@ class InferenceEngine:
 
     def _run_decode(self, out) -> tuple:
         """Take back what a decode program was handed by donation: the
-        pool and, for a stack of unlike layers, the per-slot state.
-        -> (seq, logps)."""
-        seq, logps, self.k_pages, self.v_pages, *state = out
-        if state:
-            self.state = state[0]
+        pool and the per-slot state. -> (seq, logps)."""
+        seq, logps, self.k_pages, self.v_pages, self.state = out
         return seq, logps
 
     def _prefill_fn(self, bucket: int, batch: int = 1):
@@ -1160,14 +1025,12 @@ class InferenceEngine:
                 # the module stays `jit_run` (the benchmark's trace_names
                 # keys on it); the scope says which shape class it is
                 with jax.named_scope(f"prefill_bucket_{bucket}x{batch}"):
-                    if cfg.is_stack:
-                        x, cache = stack.prefill(params, cfg, tokens, true_len)
-                        at = (true_len - 1)[:, None, None].astype(jnp.int32)
+                    x, cache = stack.prefill(params, cfg, tokens, true_len)
+                    at = (true_len - 1)[:, None, None].astype(jnp.int32)
+                    with jax.named_scope("lm_head"):
                         return _head_logits(
                             x, lambda x: jnp.take_along_axis(x, at, 1)[:, 0],
                             params, cfg, "bd,dv->bv"), cache
-                    return prefill(params, cfg, tokens, max_len=bucket,
-                                   last_index=true_len - 1)
 
             self._prefill_cache[key] = self._under_mesh(jax.jit(run))
         return self._prefill_cache[key]
@@ -1193,7 +1056,7 @@ class InferenceEngine:
         apply the same elementwise dtype cast the colocated scatter path
         does, so import → decode continues token-exactly."""
         dtype = self.k_pages.dtype
-        if cache is not None:
+        if "k" in cache:
             # bucketed prefill: the row cache IS the KV; no scatter needed
             k = np.asarray(cache["k"][:, 0, :T].astype(dtype))
             v = np.asarray(cache["v"][:, 0, :T].astype(dtype))
@@ -2093,7 +1956,7 @@ class InferenceEngine:
                 if self.prefix is not None:
                     # the prefill fleet still benefits from prefix hits:
                     # land the KV in pages and offer them to the cache
-                    if cache is not None:
+                    if "k" in cache:
                         self._scatter_prefill(cache, pages, T)
                     hashes = getattr(req, "_page_hashes", None)
                     with self._alloc_lock:
@@ -2104,10 +1967,10 @@ class InferenceEngine:
                 installed = True
                 continue
             # chunked prefills wrote pages directly
-            if cache is not None and "k" in cache:
+            if "k" in cache:
                 self._scatter_prefill(cache, pages, T)
             slot = free_slots[0]
-            if self._stack:
+            if self.state:
                 # the slot's reset: the sequence's conv tails, scan state
                 # and window keys overwrite what the last occupant left
                 self.state = self._install_state(
@@ -2162,27 +2025,19 @@ class InferenceEngine:
         if req.stage == "chunk_wait":  # its first chunk goes out now
             req.enter_stage("prefill", tracing.now_ns())
         streaming = req.prefill_only and req.kv_sink is not None
-        chunk_kv = None
-        if streaming:
-            # export variant: the SAME dispatch also returns this chunk's
-            # KV slabs, so the streamed frames below need no page-gather
-            # program (which would queue behind in-flight decode spans)
-            logits, self.k_pages, self.v_pages, ck, cv = self._chunk_fn(
-                C, True)(
-                self.params, self.k_pages, self.v_pages, jnp.asarray(padded),
-                jnp.int32(start), jnp.asarray(st.table), jnp.int32(last_idx),
-            )
-            chunk_kv = (ck, cv, start)
-        else:
-            if self._stack and st.state is None:
-                st.state = self._request_start
-            logits, self.k_pages, self.v_pages, *state = self._chunk_fn(C)(
-                self.params, self.k_pages, self.v_pages, jnp.asarray(padded),
-                jnp.int32(start), jnp.asarray(st.table), jnp.int32(last_idx),
-                *(() if st.state is None else (st.state,)),
-            )
-            if state:
-                st.state = state[0]
+        if st.state is None:
+            st.state = self._request_start
+        # export variant (streaming): the SAME dispatch also returns this
+        # chunk's KV slabs, so the streamed frames below need no
+        # page-gather program (which would queue behind in-flight decode
+        # spans)
+        logits, self.k_pages, self.v_pages, *kv, st.state = self._chunk_fn(
+            C, streaming)(
+            self.params, self.k_pages, self.v_pages, jnp.asarray(padded),
+            jnp.int32(start), jnp.asarray(st.table), jnp.int32(last_idx),
+            st.state,
+        )
+        chunk_kv = (*kv, start) if streaming else None
         st.next_chunk += 1
         if not is_last:
             if streaming:
@@ -2236,8 +2091,8 @@ class InferenceEngine:
             self._finish_request(req, "prefill_done")
             return True
         with self._ready_lock:
-            # cache=None: this prompt's KV is already in its pages (a
-            # stack's state beside pages still has to reach its slot)
+            # no keys in the cache: this prompt's KV is already in its pages
+            # (state beside pages still has to reach its slot)
             self._ready.append((req, st.pages, st.state, st.true_len))
         return True
 
@@ -2308,8 +2163,7 @@ class InferenceEngine:
                 self.params, self.k_pages, self.v_pages,
                 jnp.asarray(tokens), jnp.asarray(positions),
                 jnp.asarray(tables), jnp.asarray(temps),
-                jnp.asarray(top_ps), jnp.asarray(top_ks), key,
-                *self._state_args(),
+                jnp.asarray(top_ps), jnp.asarray(top_ks), key, self.state,
             ))
         _step_phase["verify", "plain"].observe(ph.elapsed_s)
         with decode_phase("readback") as ph:
@@ -2401,7 +2255,8 @@ class InferenceEngine:
             for _req, pages, _cache, T in self._ready:
                 reserved += len(pages)
                 written += -(-T // ps)
-        if not self._stack:
+        layers = self.cfg.count("window")
+        if not layers:  # one pool
             _pages_reserved.inc(reserved)
             _pages_written.inc(written)
             return
@@ -2409,18 +2264,16 @@ class InferenceEngine:
         _pages_by_pool["full", "written"].inc(written)
         # window layers: a slot owns its ring whatever it holds; what an
         # active sequence holds keys in are the pages its window spans
-        if self.cfg.count("window"):
-            ring = stack.ring_pages(self.cfg, ps)
-            W = self.cfg.window
-            held = sum((s.position - 1) // ps - max(s.position - W, 0) // ps
-                       + 1 for s in self.slots
-                       if s.request is not None and s.position > 0)
-            n_active = sum(1 for s in self.slots if s.request is not None)
-            _window_held.inc(held)
-            _window_bound.inc(n_active * ring)
-            layers = self.cfg.count("window")
-            _pages_by_pool["window", "reserved"].inc(n_active * ring * layers)
-            _pages_by_pool["window", "written"].inc(held * layers)
+        ring = stack.ring_pages(self.cfg, ps)
+        W = self.cfg.window
+        held = sum((s.position - 1) // ps - max(s.position - W, 0) // ps
+                   + 1 for s in self.slots
+                   if s.request is not None and s.position > 0)
+        n_active = sum(1 for s in self.slots if s.request is not None)
+        _window_held.inc(held)
+        _window_bound.inc(n_active * ring)
+        _pages_by_pool["window", "reserved"].inc(n_active * ring * layers)
+        _pages_by_pool["window", "written"].inc(held * layers)
 
     def _step_spec(self, tokens, positions, tables, temps, top_ps, top_ks,
                    advanced, key, n_active) -> bool:
@@ -2724,11 +2577,11 @@ class InferenceEngine:
             "chunking": chunking,
             "waiting_for_pages": waiting,
             # pages of THE pool (EngineConfig.pages_per_seq says whose)
-            "page_pool": ("full-attention layers" if self._stack
-                          else "every layer"),
+            "page_pool": ("every layer" if self.cfg.count("attn")
+                          else "full-attention layers"),
             **({"window_ring_pages": stack.ring_pages(
                 self.cfg, self.ecfg.page_size)}
-               if self._stack and self.cfg.count("window") else {}),
+               if self.cfg.count("window") else {}),
             "free_pages": free_pages + prefix.get("reusable_pages", 0),
             **prefix,
             "steps": self._step_count,
@@ -2777,34 +2630,6 @@ def _kv_layer_groups(L: int, groups: int = 4) -> List[tuple]:
 # path.
 _gather_pages_jit = jax.jit(gather_pages, static_argnums=(3,))
 _scatter_pages_jit = jax.jit(scatter_pages, donate_argnums=(0, 1))
-
-
-def _ffn(x, lp, cfg: ModelConfig):
-    """Second half of a block on the engine's hand-written decode, chunk
-    and verify bodies, under the scope names of models/transformer.py."""
-    with jax.named_scope("moe" if cfg.is_moe else "ffn"):
-        h = _norm(x, lp["ln2"], lp.get("ln2_b"), cfg)
-        if cfg.is_moe:
-            return _moe_ffn(h, lp, cfg)[0]
-        return _dense_ffn(h, lp, cfg)
-
-
-def _head_logits(x, pick, params, cfg: ModelConfig, einsum: str):
-    """Final norm of x [B,T,D], then the head in f32 (+ softcap) on the
-    rows `pick` keeps."""
-    x = _norm(x, params["final_norm"], params.get("final_norm_b"), cfg)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    if cfg.tie_embeddings and head.dtype != jnp.float32:
-        # the tied table as it is stored, accumulated in f32: a float32
-        # copy of a 200k-row table is 2 GB a step
-        logits = jnp.einsum(einsum, pick(x).astype(head.dtype), head,
-                            preferred_element_type=jnp.float32)
-    else:
-        logits = jnp.einsum(einsum, pick(x).astype(jnp.float32),
-                            head.astype(jnp.float32))
-    if cfg.logits_softcap:
-        logits = cfg.logits_softcap * jnp.tanh(logits / cfg.logits_softcap)
-    return logits
 
 
 def prompt_page_fingerprints(prompt, page_size: int) -> List[str]:

@@ -71,18 +71,10 @@ import numpy as np
 from ..core.config import config
 from ..core.logging import get_logger
 from ..core.metrics import Gauge
-from ..models import get_config, init_params
-from ..models.transformer import _dense_ffn, _embed_lookup, _moe_ffn, _norm
+from ..models import get_config, init_params, stack
+from ..models.transformer import _head_logits
 from ..util import tracing
-from ..ops import (
-    apply_rope,
-    paged_attention_chunk,
-    paged_attention_decode,
-    paged_attention_verify,
-    pool_shape,
-    rope_frequencies,
-    write_then_attend,
-)
+from ..ops import pool_shape
 from .config import SpeculationConfig
 
 logger = get_logger("serve.spec_decode")
@@ -360,50 +352,10 @@ class DraftModelProposer:
         ps = self.ps
 
         def chunk_step(params, k_pages, v_pages, tokens, start, page_table):
-            dtype = jnp.dtype(cfg.dtype)
-            C = tokens.shape[0]
-            x = _embed_lookup(params["embed"], tokens[None, :], dtype)
-            positions = start + jnp.arange(C)
-            if cfg.positional == "learned":
-                x = x + params["pos_emb"][positions][None].astype(dtype)
-                rope_tables = None
-            else:
-                rope_tables = rope_frequencies(
-                    cfg.hdim, cfg.max_seq_len, cfg.rope_theta)
-            page_idx = page_table[positions // ps]
-            slot_idx = positions % ps
-
-            def attend(q, kp, vp, layer):
-                return paged_attention_chunk(
-                    q, kp, vp, page_table, start, start + C, layer)
-
-            def body(carry, xs):
-                x, kp, vp = carry  # kp/vp: the whole pool, carried
-                lp, layer = xs
-                h = _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
-                q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(dtype))
-                k = jnp.einsum("btd,dhk->bthk", h, lp["wk"].astype(dtype))
-                v = jnp.einsum("btd,dhk->bthk", h, lp["wv"].astype(dtype))
-                if cfg.positional == "rope":
-                    cos, sin = rope_tables
-                    q = apply_rope(q, cos, sin, positions[None])
-                    k = apply_rope(k, cos, sin, positions[None])
-                o, kp, vp = write_then_attend(
-                    attend, q[0], k[0], v[0], kp, vp, layer,
-                    page_idx, slot_idx)
-                o = jnp.einsum("chk,hkd->cd", o.astype(dtype),
-                               lp["wo"].astype(dtype))[None]
-                x = x + o
-                h = _norm(x, lp["ln2"], lp.get("ln2_b"), cfg)
-                if cfg.is_moe:
-                    y, _ = _moe_ffn(h, lp, cfg)
-                else:
-                    y = _dense_ffn(h, lp, cfg)
-                return (x + y, kp, vp), None
-
-            (_, new_k, new_v), _ = jax.lax.scan(
-                body, (x, k_pages, v_pages),
-                (params["layers"], jnp.arange(cfg.n_layers)))
+            _, new_k, new_v, _ = stack.run_paged(
+                params, tokens[None, :], cfg,
+                stack.Seq(cfg, chunk=(start, page_table), page_size=ps),
+                (k_pages, v_pages))
             return new_k, new_v
 
         cache: Dict[int, Any] = {}
@@ -423,57 +375,12 @@ class DraftModelProposer:
 
         def one_step(params, k_pages, v_pages, tokens, positions,
                      page_tables):
-            dtype = jnp.dtype(cfg.dtype)
-            B = tokens.shape[0]
-            x = _embed_lookup(params["embed"], tokens[:, None], dtype)
-            if cfg.positional == "learned":
-                x = x + params["pos_emb"][positions][:, None].astype(dtype)
-                rope_tables = None
-            else:
-                rope_tables = rope_frequencies(
-                    cfg.hdim, cfg.max_seq_len, cfg.rope_theta)
-            pos2d = positions[:, None]
-            page_idx = page_tables[jnp.arange(B), positions // ps]
-            slot_idx = positions % ps
-
-            def attend(q, kp, vp, layer):
-                return paged_attention_decode(
-                    q, kp, vp, page_tables, positions + 1, layer)
-
-            def body(carry, xs):
-                x, kp, vp = carry  # kp/vp: the whole pool, carried
-                lp, layer = xs
-                h = _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
-                q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(dtype))
-                k = jnp.einsum("btd,dhk->bthk", h, lp["wk"].astype(dtype))
-                v = jnp.einsum("btd,dhk->bthk", h, lp["wv"].astype(dtype))
-                if cfg.positional == "rope":
-                    cos, sin = rope_tables
-                    q = apply_rope(q, cos, sin, pos2d)
-                    k = apply_rope(k, cos, sin, pos2d)
-                o, kp, vp = write_then_attend(
-                    attend, q[:, 0], k[:, 0], v[:, 0], kp, vp, layer,
-                    page_idx, slot_idx)
-                o = jnp.einsum(
-                    "bhk,hkd->bd", o, lp["wo"].astype(dtype))[:, None]
-                x = x + o
-                h = _norm(x, lp["ln2"], lp.get("ln2_b"), cfg)
-                if cfg.is_moe:
-                    y, _ = _moe_ffn(h, lp, cfg)
-                else:
-                    y = _dense_ffn(h, lp, cfg)
-                return (x + y, kp, vp), None
-
-            (x, new_k, new_v), _ = jax.lax.scan(
-                body, (x, k_pages, v_pages),
-                (params["layers"], jnp.arange(cfg.n_layers)))
-            x = _norm(x, params["final_norm"], params.get("final_norm_b"),
-                      cfg)
-            head = (params["embed"].T if cfg.tie_embeddings
-                    else params["lm_head"])
-            logits = jnp.einsum(
-                "bd,dv->bv", x[:, 0].astype(jnp.float32),
-                head.astype(jnp.float32))
+            x, new_k, new_v, _ = stack.run_paged(
+                params, tokens[:, None], cfg,
+                stack.Decode(cfg, positions, page_tables, ps),
+                (k_pages, v_pages))
+            logits = _head_logits(x, lambda x: x[:, 0], params, cfg,
+                                  "bd,dv->bv")
             return jnp.argmax(logits, axis=-1).astype(jnp.int32), new_k, new_v
 
         def propose(params, k_pages, v_pages, prev_tokens, tokens, positions,
@@ -636,7 +543,6 @@ class SpecDecoder:
         eng = self.engine
         cfg = eng.cfg
         ps = eng.ecfg.page_size
-        tp_mesh = eng.mesh if eng._tp > 1 else None
 
         def verify(params, k_pages, v_pages, tokens, positions, page_tables,
                    n_draft, temps, top_ps, top_ks, key, advanced=False):
@@ -645,61 +551,12 @@ class SpecDecoder:
             round's max draft count + 1 (the jit cache re-specializes per
             width), so a round where every slot drafted short never pays
             the full k+1-wide forward."""
-            dtype = jnp.dtype(cfg.dtype)
-            B, S = tokens.shape
-            x = _embed_lookup(params["embed"], tokens, dtype, mesh=eng.mesh)
-            pos2d = positions[:, None] + jnp.arange(S)[None, :]  # [B,S]
-            if cfg.positional == "learned":
-                x = x + params["pos_emb"][pos2d].astype(dtype)
-                rope_tables = None
-            else:
-                rope_tables = rope_frequencies(
-                    cfg.hdim, cfg.max_seq_len, cfg.rope_theta)
-            row_valid = jnp.arange(S)[None, :] <= n_draft[:, None]
-            page_idx = jnp.where(
-                row_valid,
-                page_tables[jnp.arange(B)[:, None], pos2d // ps], 0)
-            slot_idx = pos2d % ps
-
-            def attend(q, kp, vp, layer):
-                return paged_attention_verify(
-                    q, kp, vp, page_tables, positions, layer, mesh=tp_mesh)
-
-            def body(carry, xs):
-                x, kp, vp = carry  # kp/vp: the whole pool, carried
-                lp, layer = xs
-                h = _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
-                q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(dtype))
-                k = jnp.einsum("btd,dhk->bthk", h, lp["wk"].astype(dtype))
-                v = jnp.einsum("btd,dhk->bthk", h, lp["wv"].astype(dtype))
-                if cfg.positional == "rope":
-                    cos, sin = rope_tables
-                    q = apply_rope(q, cos, sin, pos2d)
-                    k = apply_rope(k, cos, sin, pos2d)
-                o, kp, vp = write_then_attend(
-                    attend, q, k, v, kp, vp, layer, page_idx, slot_idx)
-                o = jnp.einsum("bshk,hkd->bsd", o, lp["wo"].astype(dtype))
-                x = x + o
-                h = _norm(x, lp["ln2"], lp.get("ln2_b"), cfg)
-                if cfg.is_moe:
-                    y, _ = _moe_ffn(h, lp, cfg)
-                else:
-                    y = _dense_ffn(h, lp, cfg)
-                return (x + y, kp, vp), None
-
-            (x, new_k, new_v), _ = jax.lax.scan(
-                body, (x, k_pages, v_pages),
-                (params["layers"], jnp.arange(cfg.n_layers)))
-            x = _norm(x, params["final_norm"], params.get("final_norm_b"),
-                      cfg)
-            head = (params["embed"].T if cfg.tie_embeddings
-                    else params["lm_head"])
-            logits = jnp.einsum(
-                "bsd,dv->bsv", x.astype(jnp.float32),
-                head.astype(jnp.float32))
-            if cfg.logits_softcap:
-                logits = cfg.logits_softcap * jnp.tanh(
-                    logits / cfg.logits_softcap)
+            x, new_k, new_v, _ = stack.run_paged(
+                params, tokens, cfg,
+                stack.Verify(cfg, positions, page_tables, ps, n_draft,
+                             eng.mesh),
+                (k_pages, v_pages))
+            logits = _head_logits(x, lambda x: x, params, cfg, "bsd,dv->bsv")
             committed, n_comm = _accept_commit(
                 logits, tokens, n_draft, temps, top_ps, top_ks, key,
                 advanced)

@@ -261,14 +261,127 @@ def test_registered_configs_count_and_segment():
     params = init_params(tiny, jax.random.PRNGKey(0))
     assert sum(a.size for a in jax.tree.leaves(params)) == tiny.param_count()
     assert [r for _, _, r in tiny.segments()] == [3, 1, 1, 2]
-    # the one-block models' counts, a tied LayerNorm'd one included
+    # the one-block models' counts, a tied LayerNorm'd one included; by
+    # derivation each is the stack whose every layer is "attn": ONE scan
     for name in ("tiny-gpt2", "tiny-llama", "tiny-moe"):
         cfg = get_config(name)
         tree = init_params(cfg, jax.random.PRNGKey(0))
         assert sum(a.size for a in jax.tree.leaves(tree)) == cfg.param_count()
+        assert cfg.layer_kinds == ("attn",) * cfg.n_layers
+        assert cfg.segments() == ((0, ("attn",), cfg.n_layers),)
+        assert (cfg.count("attn"), cfg.count("window")) == (cfg.n_layers, 0)
+        assert stack.new_engine_state(cfg, 2, PAGE, "float32", "float32") == {}
 
 
-# -- the one-block families are untouched ------------------------------------
+# -- the one-block families: the "attn" kind -----------------------------------
+
+
+@pytest.mark.parametrize("mode", ["chunk", "decode", "verify"])
+@pytest.mark.parametrize("name", ["tiny-llama", "tiny-moe"])
+def test_the_attn_kind_agrees_with_the_contiguous_reference(name, mode):
+    """`stack.run_paged` over a page pool, in each of the modes the engine
+    and the speculative programs build, against the contiguous-cache
+    reference (`transformer.prefill` / `decode_step`, which the serve path
+    no longer calls): a 13-token prompt in two chunks of 8, the last one
+    padded, then three more tokens one at a time or as one verify span."""
+    from ray_tpu.models import decode_step, prefill
+    from ray_tpu.models.transformer import _head_logits
+
+    cfg = get_config(name)
+    params = init_params(cfg, jax.random.PRNGKey(3))
+    rng = np.random.default_rng(30)
+    T, C, more, max_len = 13, 8, 3, 32
+    tokens = jnp.asarray(rng.integers(3, cfg.vocab_size, T + more), jnp.int32)
+    layers, kvh, hd = cfg.cache_dims
+    pools = (jnp.zeros(pool_shape(layers, 9, PAGE, kvh, hd), jnp.float32),) * 2
+    table = jnp.arange(1, 9, dtype=jnp.int32)  # page 0 is the trash page
+
+    def head(x):  # every row's logits
+        return _head_logits(x, lambda x: x, params, cfg, "btd,dv->btv")
+
+    def chunk(pools, start):
+        padded = jnp.zeros((C,), jnp.int32).at[:min(C, T - start)].set(
+            tokens[start:min(start + C, T)])
+        x, k, v, state = stack.run_paged(
+            params, padded[None], cfg,
+            stack.Seq(cfg, chunk=(jnp.int32(start), table), page_size=PAGE),
+            pools)
+        assert state == {}  # pages are all there is
+        return head(x)[0], (k, v)
+
+    first, pools = chunk(pools, 0)
+    second, pools = chunk(pools, C)
+    if mode == "chunk":  # the prompt's logits, position by position
+        want = np.stack([np.asarray(prefill(
+            params, cfg, tokens[None, :T], max_len,
+            last_index=jnp.asarray([t]))[0][0]) for t in range(T)])
+        got = np.concatenate([first, second[:T - C]])
+        np.testing.assert_allclose(got, want, atol=LOGPROB_TOL, rtol=0)
+        return
+    # the reference: the contiguous cache stepped through the next tokens
+    _, cache = prefill(params, cfg, tokens[None, :T], max_len)
+    want = []
+    for i in range(more):
+        logits, cache = decode_step(params, cfg, cache, tokens[None, T + i],
+                                    jnp.asarray([T + i]))
+        want.append(np.asarray(logits[0]))
+    positions, tables = jnp.asarray([T, 0]), jnp.stack([table, table * 0])
+    if mode == "decode":  # slot 1 is idle: position 0, the trash page
+        got = []
+        for i in range(more):
+            feed = jnp.stack([tokens[T + i], jnp.int32(0)])[:, None]
+            x, k, v, _ = stack.run_paged(
+                params, feed, cfg,
+                stack.Decode(cfg, positions + jnp.asarray([i, 0]), tables,
+                             PAGE), pools)
+            pools = (k, v)
+            got.append(np.asarray(head(x)[0, 0]))
+    else:  # one span of 3 for slot 0, of which the last row is no draft
+        feed = jnp.stack([tokens[T:T + more], jnp.zeros((more,), jnp.int32)])
+        x, k, v, _ = stack.run_paged(
+            params, feed, cfg,
+            stack.Verify(cfg, positions, tables, PAGE,
+                         jnp.asarray([more - 2, 0])), pools)
+        got, want = np.asarray(head(x)[0, :more - 1]), want[:more - 1]
+        # a row past the slot's drafts wrote the trash page, not its own
+        # (where the padded chunk's rows still are)
+
+        def rewritten(pos):
+            at = (slice(None), 0, table[pos // PAGE], pos % PAGE)
+            return bool((k[at] != pools[0][at]).any())
+
+        assert rewritten(T + more - 2) and not rewritten(T + more - 1)
+    np.testing.assert_allclose(np.stack(got), np.stack(want),
+                               atol=LOGPROB_TOL, rtol=0)
+
+
+def test_the_serve_path_holds_no_layer_body_and_no_family_fork():
+    """serve/engine.py and serve/spec_decode.py reach the layers through
+    models/stack.py alone: no projection, rotary turn or FFN is written
+    out there, and nothing reads `is_stack` but the two refusals (state
+    beside pages that speculation and the KV wire cannot carry, and no
+    sharding rules yet)."""
+    import inspect
+    import re
+
+    from ray_tpu.serve import engine, spec_decode
+
+    refusals = "".join(inspect.getsource(f) for f in (
+        InferenceEngine._refuse_for_stack, InferenceEngine._refuse_kv_transfer))
+    for module in (engine, spec_decode):
+        src = inspect.getsource(module)
+        for body in ('lp["wq"]', "apply_rope", "_dense_ffn", "_moe_ffn"):
+            assert body not in src, (module.__name__, body)
+        assert "run_paged" in src
+        forks = len(re.findall(r"is_stack|\b_stack\b", src))
+        assert forks == (2 if module is engine else 0), module.__name__
+    assert len(re.findall(r"is_stack", refusals)) == 2
+    assert "stack.prefill" in inspect.getsource(InferenceEngine._prefill_fn)
+    for gone in ("_ffn", "_state_args"):
+        assert not hasattr(engine, gone) and not hasattr(InferenceEngine, gone)
+
+
+# -- the one-block families serve what they served ---------------------------
 
 PARENT_OUTPUTS = {  # tokens and log-probabilities on PR 29's parent (57b8106),
     # whose pool was head-major: the layout moved, the served tokens did not

@@ -153,11 +153,12 @@ POOL_PAGES, BATCH, CHUNK = 16385, 64, 256
 
 
 def _engine_programs(one_chip):
-    """-> the pool's shape and {name: () -> lowered} of decode_span_16 and
-    chunk_prefill_256, from a bare engine object: shapes only, nothing is
-    allocated."""
+    """-> the pool's shape and {name: () -> lowered} of decode_span_16,
+    chunk_prefill_256 and the speculative verify_3, from a bare engine
+    object: shapes only, nothing is allocated."""
     from ray_tpu.models import get_config, init_params
     from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+    from ray_tpu.serve.spec_decode import SpecDecoder
 
     cfg = get_config("llama3-8b", n_layers=LAYERS, vocab_size=32768,
                      max_seq_len=4096, rope_theta=1e6, dtype="bfloat16")
@@ -165,6 +166,8 @@ def _engine_programs(one_chip):
                         max_pages=POOL_PAGES, prefill_chunk=CHUNK)
     eng = object.__new__(InferenceEngine)
     eng.cfg, eng.ecfg, eng.mesh, eng._tp = cfg, ecfg, None, 1
+    spec = object.__new__(SpecDecoder)
+    spec.engine, spec.k = eng, 3
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -184,10 +187,16 @@ def _engine_programs(one_chip):
         "chunk_prefill_256": lambda: eng._build_chunk_prefill()(CHUNK).lower(
             params, pool, pool, s((CHUNK,), I32), s((), I32), s((pps,), I32),
             s((), I32)),
+        # S = k + 1 tokens a slot through the same layers (stack.Verify)
+        "verify_3": lambda: spec._build_verify()(False).lower(
+            params, pool, pool, s((BATCH, 4), I32), s((BATCH,), I32),
+            s((BATCH, pps), I32), s((BATCH,), I32), s((BATCH,), f32),
+            s((BATCH,), f32), s((BATCH,), I32), s((2,), jnp.uint32)),
     }
 
 
-@pytest.mark.parametrize("program", ["decode_span_16", "chunk_prefill_256"])
+@pytest.mark.parametrize(
+    "program", ["decode_span_16", "chunk_prefill_256", "verify_3"])
 def test_engine_program_updates_the_pool_in_place(
         program, topo, no_persistent_cache):
     """The compiled program holds ONE pool: the donated inputs are the
@@ -217,3 +226,51 @@ def test_engine_program_updates_the_pool_in_place(
     L, _, pages, ps, row = pool.shape
     assert not re.search(
         r"= bf16\[%d,(1,)?%d,%d,%d\]\S* copy\(" % (L, pages, ps, row), text)
+
+
+def test_decode_span_under_tp4_updates_its_shard_of_the_pool_in_place(
+        topo, no_persistent_cache):
+    """The same decode span on a `tp=4` mesh: the mode hands the paged call
+    the mesh (models/stack.py: Decode), so the kernel runs per shard on the
+    shard's lanes of every row, and each device holds a quarter of the pool,
+    donated and handed back, with nothing of that shape copied."""
+    from ray_tpu.models import get_config, init_params
+    from ray_tpu.models.transformer import param_axes
+    from ray_tpu.parallel.sharding import tree_shardings
+    from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+
+    mesh = Mesh(topo.devices[:4], ("tp",))
+    cfg = get_config("llama3-8b", n_layers=LAYERS, vocab_size=32768,
+                     max_seq_len=4096, rope_theta=1e6, dtype="bfloat16")
+    ecfg = EngineConfig(max_seq_len=4096, max_batch_size=BATCH,
+                        max_pages=POOL_PAGES, prefill_chunk=CHUNK)
+    eng = object.__new__(InferenceEngine)
+    eng.cfg, eng.ecfg, eng.mesh, eng._tp = cfg, ecfg, mesh, 4
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P()))
+
+    params = jax.tree.map(
+        lambda a, sharding: jax.ShapeDtypeStruct(a.shape, BF16,
+                                                 sharding=sharding),
+        jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0)),
+        tree_shardings(param_axes(cfg), mesh))
+    pool = eng.abstract_pool(
+        NamedSharding(mesh, P(None, None, None, None, "tp")))
+    with mesh:  # as `_under_mesh` runs it; the jitted program is beneath
+        compiled = eng._build_decode()(16).__wrapped__.lower(
+            params, pool, pool, s((BATCH,), I32), s((BATCH,), I32),
+            s((BATCH, ecfg.pages_per_seq), I32), s((BATCH,), jnp.float32),
+            s((BATCH,), jnp.float32), s((BATCH,), I32),
+            s((2,), jnp.uint32)).compile()
+    memory = compiled.memory_analysis()  # per device
+    shard_bytes = 2 * pool.size * pool.dtype.itemsize // 4  # k and v
+    assert memory.alias_size_in_bytes == shard_bytes
+    assert memory.temp_size_in_bytes < 0.25 * shard_bytes
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    L, _, pages, ps, row = pool.shape
+    assert not re.search(
+        r"= bf16\[%d,(1,)?%d,%d,%d\]\S* copy\(" % (L, pages, ps, row // 4),
+        text)
