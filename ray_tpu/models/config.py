@@ -44,6 +44,8 @@ class ModelConfig:
     router_aux_coef: float = 0.01
     # training numerics
     dtype: str = "bfloat16"
+    # True: the backward recomputes a layer's norms and FFN and keeps its
+    # attention half (models/transformer.py `_remat`); False keeps everything
     remat: bool = True
     logits_softcap: Optional[float] = None
     # attention implementation: "flash" (Pallas/XLA blockwise, seq gathered)
@@ -289,7 +291,8 @@ register(ModelConfig(
 register(ModelConfig(
     name="llama-2b",
     # ~2B Llama-3 family member: the single-chip scale stepping stone
-    # toward llama3-8b (BASELINE.md workload #2). remat (on by default)
+    # toward llama3-8b (BASELINE.md workload #2). remat (on by default;
+    # it keeps a layer's attention half: `ModelConfig.remat`)
     # plus a FACTORED optimizer (train.lm.make_optimizer(factored=True),
     # adafactor second moments) is what fits f32 master state + grads in
     # one 16GB v5e chip — adamw moments alone would be 2x params.

@@ -26,8 +26,10 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import apply_rope, flash_attention, layer_norm, rms_norm, rope_frequencies
+from ..ops.attention import FLASH_RESIDUAL_NAMES
 from ..parallel.moe import top_k_gating
 from ..parallel.sharding import _current_mesh, constrain, per_shard
 from .config import ModelConfig
@@ -198,9 +200,9 @@ def _qkv(x, lp, cfg, rope_tables, positions):
 def _attention(x, lp, cfg, rope_tables, positions, mesh=None):
     dtype = x.dtype
     q, k, v = _qkv(x, lp, cfg, rope_tables, positions)
-    q = constrain(q, ("batch", "seq", "heads", None))
-    k = constrain(k, ("batch", "seq", "heads", None))
-    v = constrain(v, ("batch", "seq", "heads", None))
+    q = checkpoint_name(constrain(q, ("batch", "seq", "heads", None)), "attn_q")
+    k = checkpoint_name(constrain(k, ("batch", "seq", "heads", None)), "attn_k")
+    v = checkpoint_name(constrain(v, ("batch", "seq", "heads", None)), "attn_v")
     if cfg.attn_impl == "ring":
         from ..comm.mesh import get_mesh
         from ..parallel.ring import ring_attention
@@ -388,8 +390,28 @@ def _block(x, lp, cfg, rope_tables, positions, mesh=None):
     # Scope names are what a profile's readers key on, here as there.
     with jax.named_scope("attn"):
         h = _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
-        x = x + _attention(h, lp, cfg, rope_tables, positions, mesh)
+        x = checkpoint_name(
+            x + _attention(h, lp, cfg, rope_tables, positions, mesh), "attn_half")
     return _ffn_half(x, lp, cfg)
+
+
+# What a layer keeps for its backward under `cfg.remat`: the attention
+# half (the flash kernel's output and log-sum-exp, the turned q, k, v it
+# read, and the residual stream after the o projection), so the backward
+# recomputes the two norms and the FFN (or the experts) and runs no
+# attention kernel or projection a second time. Per layer and row of T
+# tokens that is T x (2 x d_model + (H + 2 x KVH) x head) activations +
+# 4 x H x T bytes of lse; the FFN's gate / up products ([.., d_ff], the
+# larger half) are what stays recomputed. `remat=False` keeps everything.
+_KEPT_UNDER_REMAT = FLASH_RESIDUAL_NAMES + ("attn_q", "attn_k", "attn_v", "attn_half")
+
+
+def _remat(body, cfg):
+    """The layer loop's body as `cfg.remat` wants it differentiated."""
+    if not cfg.remat:
+        return body
+    return jax.checkpoint(
+        body, policy=jax.checkpoint_policies.save_only_these_names(*_KEPT_UNDER_REMAT))
 
 
 # ---------------------------------------------------------------------------
@@ -487,9 +509,7 @@ def run_layers(
         y, aux = _block(carry, lp, cfg, rope_tables, positions)
         return y, aux
 
-    if cfg.remat:
-        body = jax.checkpoint(body)
-    x, aux = jax.lax.scan(body, x, layer_params)
+    x, aux = jax.lax.scan(_remat(body, cfg), x, layer_params)
     return x, jnp.sum(aux)
 
 
@@ -553,9 +573,7 @@ def forward_pp(
                 y, aux = _block(carry, lp, cfg, rope_tables, None)
                 return y, aux
 
-            if cfg.remat:
-                body = jax.checkpoint(body)
-            h, aux = jax.lax.scan(body, h, lp_stage)
+            h, aux = jax.lax.scan(_remat(body, cfg), h, lp_stage)
             if cfg.is_moe:
                 return h, jnp.sum(aux)  # this stage's layers, this microbatch
             return h

@@ -28,6 +28,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -41,6 +42,9 @@ _MAX_BLOCK = 1024  # measured knee on v5e: 1024² blocks ~3.4x faster than 128²
 # the chip's compiler refuses under its default 16 MiB scoped-VMEM limit
 # (seen at T=8192 compiled for a described v5e). A v5e has 128 MiB of VMEM.
 _BWD_VMEM_LIMIT = 32 * 1024 * 1024
+# What `_flash_fwd_rule` names its output and log-sum-exp, for a
+# `jax.checkpoint` policy that saves by name (models/transformer.py).
+FLASH_RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 
 def _auto_block(t: int) -> int:
@@ -569,8 +573,16 @@ def _flash_bhtd(q, k, v, causal, scale, block_q, block_k):
 
 def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k):
     # Both branches of the dispatch return (o, lse[B,H,Tq] f32); the lse
-    # residual feeds the fused Pallas backward (no fwd recompute).
+    # residual feeds the fused Pallas backward (no fwd recompute). Both are
+    # named HERE, output and residual alike: a `jax.checkpoint` whose policy
+    # saves these names then keeps what the backward reads and does not run
+    # the kernel again (named outside the rule, `o` would be kept and the
+    # kernel run again for `lse` alone). Outside a checkpoint a name is the
+    # identity. `_flash_lse_fwd_rule` below names nothing: ring attention
+    # calls it once a ring step, and every step's partials would be kept.
     o, lse = _fwd_lse_dispatch(q, k, v, causal, scale, block_q, block_k)
+    o = checkpoint_name(o, FLASH_RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse, FLASH_RESIDUAL_NAMES[1])
     return o, (q, k, v, o, lse)
 
 

@@ -8,6 +8,7 @@ a test of it has started — never while a module is imported, and never in
 a child process.
 """
 
+import collections
 import os
 import re
 
@@ -274,3 +275,42 @@ def test_decode_span_under_tp4_updates_its_shard_of_the_pool_in_place(
     assert not re.search(
         r"= bf16\[%d,(1,)?%d,%d,%d\]\S* copy\(" % (L, pages, ps, row // 4),
         text)
+
+
+def test_train_step_backward_runs_no_flash_forward(topo, no_persistent_cache):
+    """Two layers of the train cell's widths, one row of 8192, bf16, the
+    factored optimizer: under `remat` the scan's checkpoint keeps the flash
+    kernel's output and log-sum-exp by name (models/transformer.py
+    `_remat`), so the compiled step runs the forward kernel ONCE a layer.
+    The blanket checkpoint of PR 30's parent read 2 here: one in the
+    forward loop's body, one beside `flash_bwd_dq` in the backward's."""
+    from ray_tpu.models import get_config, init_params
+    from ray_tpu.train.lm import make_optimizer, make_train_step
+
+    T = 8192
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    cfg = get_config("llama3-8b", n_layers=LAYERS, vocab_size=32768,
+                     max_seq_len=T, rope_theta=1e6, dtype="bfloat16")
+    assert cfg.remat
+    opt = make_optimizer(learning_rate=5e-6, warmup_steps=1, factored=True)
+
+    def state_of(key):
+        params = jax.tree.map(lambda a: a.astype(BF16), init_params(cfg, key))
+        return {"step": jnp.zeros((), I32), "params": params,
+                "opt_state": opt.init(params)}
+
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(state_of, jax.random.PRNGKey(0)))
+    batch = {k: jax.ShapeDtypeStruct((1, T), I32, sharding=one_chip)
+             for k in ("tokens", "targets")}
+    text = jax.jit(make_train_step(cfg, opt), donate_argnums=0).lower(
+        state, batch).compile().as_text()
+
+    # one computation a `{ ... }` block at column 0; the flash kernels of each
+    loops = [kernels for kernels in (
+        collections.Counter(re.findall(
+            r"%(flash_[a-z_]+)(?:\.\d+)? = [^\n]*tpu_custom_call", block))
+        for block in re.split(r"\n}\n", text)) if kernels]
+    assert sorted(loops, key=len) == [  # two loops, not unrolled
+        {"flash_fwd": 1}, {"flash_bwd_dq": 1, "flash_bwd_dkv": 1}]
