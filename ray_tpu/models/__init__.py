@@ -3,7 +3,14 @@
 The reference ships no models of its own (its Train/Serve/RLlib examples
 pull torch models from HF/DeepSpeed/vLLM); a TPU-native framework must own
 the model zoo, so these are first-class: GPT-2, Llama-3, Mixtral configs
-over one sharded JAX transformer.
+over one sharded JAX transformer, and on the serve path stacks of unlike
+layers (`StackConfig`, models/stack.py): `phi4-mini-flash` (Mamba, window
+and cross differential attention, gated memory units) and `lfm2-8b-a1b`
+(the `"conv"` kind, a gated short convolution, beside `"attn"` layers with
+normalised queries and keys; two dense layers, then experts routed by
+sigmoid score + bias), each with a tiny twin for the CPU (`tiny-sambay`,
+`tiny-lfm2`). The benchmark reaches them through benchmark/families/
+(`mistral.py`, `sambay.py`, `shortconv_moe.py`).
 """
 
 from .config import (  # noqa: F401

@@ -5,7 +5,8 @@ plus tiny variants for tests. One config class drives all families —
 differences (norm type, activation, positional scheme, GQA, MoE) are fields,
 not subclasses, so the same sharded forward/train/serve path covers every
 family. A model whose layers differ names each layer's mixer in
-`layer_kinds` (a StackConfig; serve only). models/stack.py runs both on the
+`layer_kinds` and says which second halves are dense (a StackConfig; serve
+only). models/stack.py runs both on the
 serve path: a plain ModelConfig is the stack whose every layer is "attn".
 """
 
@@ -16,7 +17,9 @@ from typing import Optional, Tuple
 
 # what a layer's mixer can be when a StackConfig's `layer_kinds` names them
 # (models/stack.py); a plain ModelConfig's layers are all "attn"
-LAYER_KINDS = ("mamba", "window", "full", "gmu", "cross")
+LAYER_KINDS = ("attn", "conv", "mamba", "window", "full", "gmu", "cross")
+# the kinds whose attention is differential over pairs of heads
+_DIFFERENTIAL = ("window", "full", "cross")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,31 +75,53 @@ class ModelConfig:
 
     # what StackConfig (below) answers otherwise: one kind of layer, rotary
     # (or learned-position) attention over every layer's own pages and then
-    # the FFN or the experts, and no state beside the pages
+    # the FFN or the experts (top k of the router's logits, softmax over
+    # the chosen), no norm on queries and keys, and no state beside the pages
     is_stack = False
     has_state = False
+    qk_norm = False
+    router = "softmax"
+    n_dense_layers = 0
+    conv_tail = (0, 0, 0)
 
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
         return ("attn",) * self.n_layers
+
+    @property
+    def second_halves(self) -> Tuple[str, ...]:
+        """Each layer's second half: "ffn", or "moe" where the model has
+        experts and the layer is past the leading dense ones."""
+        return tuple("moe" if self.is_moe and l >= self.n_dense_layers
+                     else "ffn" for l in range(self.n_layers))
+
+    @property
+    def expert_ff(self) -> int:
+        """An expert's width (the dense FFN's unless the model says)."""
+        return self.d_ff
 
     def count(self, kind: str) -> int:
         return self.layer_kinds.count(kind)
 
     def segments(self) -> Tuple[Tuple[int, Tuple[str, ...], int], ...]:
         """The stack as runs of whole periods: (first layer, the period's
-        kinds, repeats). A run of two or more equal periods (the shortest
-        period that repeats wins) is scanned; a layer that belongs to none
-        is a run of its own, once. Equal layers are ONE scan; SambaY's
-        mamba/window pairs, then one mamba and one full layer, then
-        gmu/cross pairs are three scans' worth of programs, whatever the
-        depth."""
+        kinds, repeats). A layer is a (mixer, second half) pair and a
+        period a period of pairs; every repeat of a period has its first
+        repeat's second halves (`second_halves[first + i]`). A run of two
+        or more equal periods (the shortest period that repeats wins) is
+        scanned; a layer that belongs to none is a run of its own, once.
+        Equal layers are ONE scan; SambaY's mamba/window pairs, then one
+        mamba and one full layer, then gmu/cross pairs are three scans'
+        worth of programs, whatever the depth; two leading dense conv
+        layers and then attn/conv/conv/conv periods of expert layers are
+        two."""
         kinds, out, i = self.layer_kinds, [], 0
-        while i < len(kinds):
+        pairs = tuple(zip(kinds, self.second_halves))
+        while i < len(pairs):
             best = (1, 1)
             for p in range(1, 5):
                 r = 1
-                while kinds[i + r * p:i + (r + 1) * p] == kinds[i:i + p]:
+                while pairs[i + r * p:i + (r + 1) * p] == pairs[i:i + p]:
                     r += 1
                 if r >= 2:
                     best = (p, r)
@@ -130,19 +155,39 @@ class StackConfig(ModelConfig):
     by field (benchmark/tests/test_families.py)."""
 
     # A stack of unlike layers (models/stack.py): one mixer kind per layer,
-    # each followed by the dense FFN. "mamba": selective state space;
+    # each followed by its second half, the dense FFN or (past the
+    # `n_dense_layers` leading ones, where there are experts) the experts.
+    # Two families need one. The first: "mamba": selective state space;
     # "window" / "full": attention over the last `window` keys / all keys,
     # "full" writing THE cache that every later "cross" layer (queries
-    # only) reads; "gmu": gates the last mamba layer's scan output.
-    # Attention here is differential over pairs of heads, its projections
-    # carry biases, and nothing encodes positions: what the one family
-    # that needs a stack has; another gets a field when it comes.
+    # only) reads; "gmu": gates the last mamba layer's scan output; its
+    # attention is differential over pairs of heads with biases on its
+    # projections, its norms LayerNorm, and nothing encodes positions. The
+    # second: "conv": a gated short convolution (`conv_taps` taps a
+    # channel, the model's width) beside "attn", the one-block models'
+    # rotary GQA over the layer's own pages, here with RMS-normalised
+    # queries and keys (`qk_norm`); RMSNorm; two dense layers and then
+    # experts of their own width (`d_ff_expert`), chosen by sigmoid scores
+    # plus a per-expert bias (`router="sigmoid"`). A rule below belongs to
+    # the kind it names, not to a stack; another family gets a field when
+    # it comes.
     layer_kinds: Tuple[str, ...] = ()
     window: int = 0
     ssm_inner: int = 0        # mamba / gmu inner width
     ssm_state: int = 16
     ssm_conv: int = 4
     ssm_dt_rank: int = 0
+    conv_taps: int = 3        # the "conv" kind's kernel length
+    qk_norm: bool = False     # "attn": RMSNorm each head of q and k
+    n_dense_layers: int = 0   # leading layers whose second half is dense
+    d_ff_expert: int = 0      # an expert's width (0: d_ff)
+    # "softmax": top k of the logits, softmax over the chosen. "sigmoid":
+    # sigmoid scores, the choice made on score + a per-expert bias, the
+    # weights the chosen scores WITHOUT it, over their sum (+ 1e-6) if
+    # `norm_topk`, times `routed_scale` (parallel/moe.py)
+    router: str = "softmax"
+    norm_topk: bool = True
+    routed_scale: float = 1.0
 
     def __post_init__(self) -> None:
         kinds = tuple(self.layer_kinds)
@@ -156,22 +201,43 @@ class StackConfig(ModelConfig):
             if kind in kinds and needs not in kinds[:kinds.index(kind)]:
                 raise ValueError(f"a {kind!r} layer needs a {needs!r} "
                                  "layer before it")
-        if self.is_moe or self.norm != "layernorm":
-            raise ValueError("a mixed stack is dense and LayerNorm'd")
         if "window" in kinds and self.window <= 0:
             raise ValueError("window layers need `window` > 0")
-        if (self.n_heads % 2 or self.kv_heads % 2
+        if set(kinds) & set(_DIFFERENTIAL) and (
+                self.n_heads % 2 or self.kv_heads % 2
                 or (self.n_heads // 2) % (self.kv_heads // 2)):
             raise ValueError("differential attention pairs heads: n_heads "
                              "and kv_heads even, q pairs a multiple of kv "
                              "pairs")
+        # ONE pool and one array of conv tails: their rows are one shape
+        for a, b, what in (("attn", "full", "layers that cache keys"),
+                           ("conv", "mamba", "convolution tails")):
+            if a in kinds and b in kinds:
+                raise ValueError(f"{a!r} and {b!r} layers in one stack: "
+                                 f"two shapes of {what}")
+        if self.router not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router {self.router!r}")
 
     is_stack = True
 
     @property
     def has_state(self) -> bool:
-        """Some layer keeps per-sequence state that is not keys and values."""
-        return "mamba" in self.layer_kinds
+        """Some layer keeps per-sequence state that is not keys and values
+        in THE pool: conv tails, scan state, a window layer's ring."""
+        return bool({"mamba", "conv", "window"} & set(self.layer_kinds))
+
+    @property
+    def expert_ff(self) -> int:
+        return self.d_ff_expert or self.d_ff
+
+    @property
+    def conv_tail(self) -> Tuple[int, int, int]:
+        """(layers, rows, width) of the convolution tails a sequence keeps:
+        the last taps - 1 inputs of each mamba or conv layer's
+        convolution."""
+        if "conv" in self.layer_kinds:
+            return self.count("conv"), self.conv_taps - 1, self.d_model
+        return self.count("mamba"), self.ssm_conv - 1, self.ssm_inner
 
     @property
     def pool_heads(self) -> int:
@@ -184,7 +250,10 @@ class StackConfig(ModelConfig):
 
     @property
     def cache_dims(self) -> Tuple[int, int, int]:
-        """The full-attention layers alone, a differential pair a head."""
+        """The layers that cache keys alone: the "attn" layers' own heads,
+        or the "full" layers', a differential pair a head."""
+        if "attn" in self.layer_kinds:
+            return self.count("attn"), self.kv_heads, self.hdim
         return self.count("full"), self.pool_heads, self.pool_dim
 
     def _mixer_params(self, kind: str) -> int:
@@ -192,6 +261,11 @@ class StackConfig(ModelConfig):
         Di, N, R = self.ssm_inner, self.ssm_state, self.ssm_dt_rank
         # q and o with biases, four lambda vectors, the pair norm's weight
         q_o = 2 * D * H * hd + H * hd + D + 4 * hd + 2 * hd
+        if kind == "attn":
+            return (2 * D * H * hd + 2 * D * KVH * hd
+                    + (2 * hd if self.qk_norm else 0))
+        if kind == "conv":
+            return D * 3 * D + self.conv_taps * D + D * D
         if kind == "mamba":
             return (D * 2 * Di + Di * (self.ssm_conv + 1) + Di * (R + 2 * N)
                     + R * Di + Di + N * Di + Di + Di * D)
@@ -203,11 +277,15 @@ class StackConfig(ModelConfig):
 
     def param_count(self) -> int:
         """Parameter count of the mixed stack (the tied table once)."""
-        D, F, L, V = self.d_model, self.d_ff, self.n_layers, self.vocab_size
-        per_layer = 3 * D * F + 4 * D  # swiglu + two LayerNorms
+        D, F, V = self.d_model, self.d_ff, self.vocab_size
+        E, Fe = self.num_experts, self.expert_ff
+        norm = D * (2 if self.norm == "layernorm" else 1)
+        half = {"ffn": 3 * D * F,
+                "moe": E * 3 * D * Fe + D * E
+                + (E if self.router == "sigmoid" else 0)}
         return (sum(self._mixer_params(k) for k in self.layer_kinds)
-                + L * per_layer + V * D * (1 if self.tie_embeddings else 2)
-                + 2 * D)
+                + sum(half[h] + 2 * norm for h in self.second_halves)
+                + V * D * (1 if self.tie_embeddings else 2) + norm)
 
 
 _REGISTRY = {}
@@ -352,6 +430,42 @@ register(StackConfig(
     tie_embeddings=True, norm_eps=1e-5,
     layer_kinds=_sambay_kinds(32), window=512, ssm_inner=5120, ssm_state=16, ssm_conv=4,
     ssm_dt_rank=160,
+))
+
+def _lfm2_kinds(n_layers: int) -> Tuple[str, ...]:
+    """LFM2-8B-A1B's published `layer_types`, cut to its first layers."""
+    attn = (2, 6, 10, 14, 18, 21)
+    return tuple("attn" if l in attn else "conv" for l in range(n_layers))
+
+
+register(StackConfig(
+    name="lfm2-8b-a1b",
+    # LiquidAI/LFM2-8B-A1B: 8.3 B parameters, 1.5 B active a token: 18
+    # gated short convolutions and 6 GQA layers (heads of 64, queries and
+    # keys normalised), two dense layers and then 32 experts of 1792, 4 a
+    # token by sigmoid score + bias
+    vocab_size=65536,
+    d_model=2048, n_layers=24, n_heads=32, n_kv_heads=8, head_dim=64,
+    d_ff=7168, max_seq_len=128000,
+    norm="rmsnorm", activation="swiglu", positional="rope",
+    rope_theta=1000000.0, tie_embeddings=True, norm_eps=1e-5,
+    num_experts=32, num_selected_experts=4, capacity_factor=8.0,
+    layer_kinds=_lfm2_kinds(24), conv_taps=3, qk_norm=True,
+    n_dense_layers=2, d_ff_expert=1792, router="sigmoid",
+))
+
+register(StackConfig(
+    name="tiny-lfm2",
+    # the same stack's shape at toy widths: two dense conv layers, then
+    # two attn / conv / conv / conv periods of expert layers
+    vocab_size=512,
+    d_model=64, n_layers=10, n_heads=8, n_kv_heads=4, head_dim=8, d_ff=128,
+    max_seq_len=128, dtype="float32", remat=False,
+    norm="rmsnorm", activation="swiglu", positional="rope",
+    rope_theta=10000.0, tie_embeddings=True, norm_eps=1e-5,
+    num_experts=8, num_selected_experts=2, capacity_factor=4.0,
+    layer_kinds=_lfm2_kinds(10), conv_taps=3, qk_norm=True,
+    n_dense_layers=2, d_ff_expert=32, router="sigmoid",
 ))
 
 register(StackConfig(

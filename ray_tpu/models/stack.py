@@ -1,9 +1,15 @@
 """The layers of every model the serving engine runs, as a stack of layer
 kinds (`cfg.layer_kinds`): every layer is `x + Mix(norm(x))` and then its
-second half, `x + FFN(norm(x))` or the experts, and Mix is one of
+second half, `x + FFN(norm(x))` or the experts (`cfg.second_halves`: a
+stack may lead with dense layers), and Mix is one of
 
   attn    the one-block models' (a plain ModelConfig: every layer): rotary
-          or learned-position GQA over the layer's own pages
+          or learned-position GQA over the layer's own pages, queries and
+          keys normalised per head where `cfg.qk_norm`
+  conv    gated short convolution: [B ; C ; x] = h W_in, a causal
+          depthwise convolution of B * x over `conv_taps` positions, gated
+          by C, then W_out; its tail (the last taps - 1 rows of B * x) per
+          sequence
   mamba   selective state space (Mamba-1): conv tail and scan state per
           sequence; hands its scan output on to the gmu layers after it
   window  attention over the last `window` keys
@@ -38,11 +44,11 @@ softmax, so `[q1 ; 0]` scores against k1 alone and `[0 ; q2]` against k2,
 and one pass over the pages feeds both softmaxes. The pools and rings are
 the page pool of ops/paged_attention.py (a token's KV heads or pairs side
 by side in one row), whose ops take the plain queries and keys of this
-file: the layout is theirs. The "attn" layers shard by the one-block
-models' rules (a `tp` mesh reaches the paged calls through the mode) and
-train through models/transformer.py's own loop over the same projections
-and second half; the other five kinds are serve only: no sharding rules,
-no training path.
+file: the layout is theirs. The "attn" layers of a plain ModelConfig shard
+by the one-block models' rules (a `tp` mesh reaches the paged calls through
+the mode) and train through models/transformer.py's own loop over the same
+projections and second half; a StackConfig, whatever its kinds, is serve
+only: no sharding rules, no training path.
 """
 
 from __future__ import annotations
@@ -73,7 +79,7 @@ from .transformer import (
 Params = Dict[str, Any]
 _F32 = jnp.float32
 # kinds that own rows of state arrays or pools, counted as layers go by
-_COUNTED = ("attn", "mamba", "window", "full")
+_COUNTED = ("attn", "conv", "mamba", "window", "full")
 
 
 # ---------------------------------------------------------------------------
@@ -81,16 +87,34 @@ _COUNTED = ("attn", "mamba", "window", "full")
 # ---------------------------------------------------------------------------
 
 
-def layer_shapes(cfg: ModelConfig, kind: str) -> Dict[str, tuple]:
-    """name -> (shape, init) of one layer of `kind`; init is "w" (normal),
-    "out" (normal, scaled down with depth), "one", "zero" or a constant."""
+def layer_shapes(cfg: ModelConfig, kind: str,
+                 half: str = "ffn") -> Dict[str, tuple]:
+    """name -> (shape, init) of one layer of `kind` whose second half is
+    `half` ("ffn" or "moe"); init is "w" (normal), "out" (normal, scaled
+    down with depth), "one" or "zero"."""
     D, F, H, KVH, hd = cfg.d_model, cfg.d_ff, cfg.n_heads, cfg.kv_heads, cfg.hdim
     Di, N, R, K = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank, cfg.ssm_conv
-    out = {"ln1": ((D,), "one"), "ln1_b": ((D,), "zero"),
-           "ln2": ((D,), "one"), "ln2_b": ((D,), "zero"),
-           "w_in": ((D, F), "w"), "w_gate": ((D, F), "w"),
-           "w_out": ((F, D), "out")}
-    if kind == "mamba":
+    out = {"ln1": ((D,), "one"), "ln2": ((D,), "one")}
+    if cfg.norm == "layernorm":
+        out.update(ln1_b=((D,), "zero"), ln2_b=((D,), "zero"))
+    if half == "moe":
+        E, Fe = cfg.num_experts, cfg.expert_ff
+        out.update(router=((D, E), "w"), w_in=((E, D, Fe), "w"),
+                   w_gate=((E, D, Fe), "w"), w_out=((E, Fe, D), "out"))
+        if cfg.router == "sigmoid":
+            out.update(router_bias=((E,), "zero"))
+    else:
+        out.update(w_in=((D, F), "w"), w_gate=((D, F), "w"),
+                   w_out=((F, D), "out"))
+    if kind == "attn":
+        out.update(wq=((D, H, hd), "w"), wk=((D, KVH, hd), "w"),
+                   wv=((D, KVH, hd), "w"), wo=((H, hd, D), "out"))
+        if cfg.qk_norm:
+            out.update(q_norm=((hd,), "one"), k_norm=((hd,), "one"))
+    elif kind == "conv":
+        out.update(c_in=((D, 3 * D), "w"), c_conv=((cfg.conv_taps, D), "w"),
+                   c_out=((D, D), "out"))
+    elif kind == "mamba":
         out.update(m_in=((D, 2 * Di), "w"), m_conv=((K, Di), "w"),
                    m_conv_b=((Di,), "zero"), m_x=((Di, R + 2 * N), "w"),
                    m_dt=((R, Di), "w"), m_dt_b=((Di,), "zero"),
@@ -122,8 +146,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             return jax.random.normal(k, shape, _F32) * scale
         return jnp.full(shape, 1.0 if init == "one" else 0.0, _F32)
 
-    def layer(k, kind):
-        shapes = layer_shapes(cfg, kind)
+    def layer(k, kind, half):
+        shapes = layer_shapes(cfg, kind, half)
         ks = jax.random.split(k, len(shapes))
         return {n: leaf(ks[i], *shapes[n]) for i, n in enumerate(sorted(shapes))}
 
@@ -134,13 +158,16 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
                               repeats * len(kinds)).reshape(
                                   repeats, len(kinds), -1)
         segments.append(tuple(
-            jax.vmap(lambda k, kind=kind: layer(k, kind))(ks[:, i])
+            jax.vmap(lambda k, kind=kind, half=cfg.second_halves[first + i]:
+                     layer(k, kind, half))(ks[:, i])
             for i, kind in enumerate(kinds)))
-    return {"embed": jax.random.normal(k_emb, (cfg.vocab_size, cfg.d_model),
-                                       _F32) * 0.02,
-            "layers": segments,
-            "final_norm": jnp.ones((cfg.d_model,), _F32),
-            "final_norm_b": jnp.zeros((cfg.d_model,), _F32)}
+    out = {"embed": jax.random.normal(k_emb, (cfg.vocab_size, cfg.d_model),
+                                      _F32) * 0.02,
+           "layers": segments,
+           "final_norm": jnp.ones((cfg.d_model,), _F32)}
+    if cfg.norm == "layernorm":
+        out["final_norm_b"] = jnp.zeros((cfg.d_model,), _F32)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -159,15 +186,18 @@ def ring_pages(cfg: ModelConfig, page_size: int) -> int:
 
 def new_request_state(cfg: ModelConfig, batch: int, dtype) -> Params:
     """State a prefill hands over beside keys and values: conv tails
-    [M,B,K-1,Di] and scan state [M,B,N,Di] (float32) where there are mamba
-    layers, and the last `window` keys and values of every window layer
-    [W,B,window,KVH,D] where there are those. Zeros are a sequence's start;
-    the one-block models have none (the empty tree)."""
+    [M,B,K-1,Di] where there are mamba or conv layers (`cfg.conv_tail`),
+    scan state [M,B,N,Di] (float32) where there are mamba layers, and the
+    last `window` keys and values of every window layer [W,B,window,KVH,D]
+    where there are those. Zeros are a sequence's start; the one-block
+    models have none (the empty tree)."""
     M, NW = cfg.count("mamba"), cfg.count("window")
     out = {}
+    if cfg.conv_tail[0]:
+        layers, rows, width = cfg.conv_tail
+        out.update(conv=jnp.zeros((layers, batch, rows, width), dtype))
     if M:
         out.update(
-            conv=jnp.zeros((M, batch, cfg.ssm_conv - 1, cfg.ssm_inner), dtype),
             ssm=jnp.zeros((M, batch, cfg.ssm_state, cfg.ssm_inner), _F32))
     if NW:
         kv = (NW, batch, cfg.window, cfg.pool_heads, cfg.pool_dim)
@@ -316,7 +346,7 @@ class Seq(_Mode):
     def conv(self, carry, mi, u):
         """-> [tail ; u] along time, and the new tail kept."""
         B, T, Di = u.shape
-        K = self.cfg.ssm_conv
+        K = self.cfg.conv_tail[1] + 1
         tail = (carry["conv"][mi].astype(u.dtype) if self.chunk is not None
                 else jnp.zeros((B, K - 1, Di), u.dtype))
         ext = jnp.concatenate([tail, u], axis=1)
@@ -522,10 +552,7 @@ def _mamba(h, lp, cfg, mi, mode, carry):
     uz = jnp.einsum("btd,de->bte", h, lp["m_in"].astype(dtype))
     u, z = uz[..., :Di], uz[..., Di:]
     ext, carry = mode.conv(carry, mi, u)
-    w = lp["m_conv"].astype(_F32)
-    conv = sum(ext[:, j:j + T].astype(_F32) * w[j]
-               for j in range(cfg.ssm_conv)) + lp["m_conv_b"].astype(_F32)
-    u = jax.nn.silu(conv)
+    u = jax.nn.silu(_taps(ext, lp["m_conv"], T) + lp["m_conv_b"].astype(_F32))
     xdbc = jnp.einsum("bte,er->btr", u.astype(dtype), lp["m_x"].astype(dtype),
                       preferred_element_type=_F32)
     dt = jax.nn.softplus(
@@ -542,6 +569,27 @@ def _mamba(h, lp, cfg, mi, mode, carry):
                      (y * jax.nn.silu(z.astype(_F32))).astype(dtype),
                      lp["m_out"].astype(dtype))
     return out, {**carry, "mem": y.astype(dtype)}
+
+
+def _taps(ext, w, T):
+    """The causal depthwise convolution both convolution mixers run: ext
+    [B, K-1+T, C] is [tail ; inputs] along time, w [K, C]; tap K-1
+    multiplies the current position. -> float32 [B, T, C]."""
+    w = w.astype(_F32)
+    return sum(ext[:, j:j + T].astype(_F32) * w[j] for j in range(w.shape[0]))
+
+
+def _short_conv(h, lp, cfg, ci, mode, carry):
+    """The gated short convolution: the state is the mode's conv tail, as
+    the mamba layer's is, here the last taps - 1 rows of B * x."""
+    dtype = h.dtype
+    D, T = cfg.d_model, h.shape[1]
+    bcx = jnp.einsum("btd,de->bte", h, lp["c_in"].astype(dtype))
+    b, c, x = bcx[..., :D], bcx[..., D:2 * D], bcx[..., 2 * D:]
+    ext, carry = mode.conv(carry, ci, b * x)
+    z = c.astype(_F32) * _taps(ext, lp["c_conv"], T)
+    return jnp.einsum("bte,ed->btd", z.astype(dtype),
+                      lp["c_out"].astype(dtype)), carry
 
 
 def _gmu(h, lp, cfg, carry):
@@ -597,13 +645,15 @@ def _attn(h, lp, cfg, idx, mode, carry):
                       lp["wo"].astype(h.dtype)), carry
 
 
-def _layer(x, lp, cfg, kind, layer, idx, mode, carry):
+def _layer(x, lp, cfg, kind, half, layer, idx, mode, carry):
     # the scopes are what a profile's readers key on: the mixer's kind
     # ("attn" as in the training block), then "ffn" or "moe"
     with jax.named_scope(kind):
         h = _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
         if kind == "attn":
             o, carry = _attn(h, lp, cfg, idx, mode, carry)
+        elif kind == "conv":
+            o, carry = _short_conv(h, lp, cfg, idx, mode, carry)
         elif kind == "mamba":
             o, carry = _mamba(h, lp, cfg, idx, mode, carry)
         elif kind == "gmu":
@@ -611,7 +661,7 @@ def _layer(x, lp, cfg, kind, layer, idx, mode, carry):
         else:
             o, carry = _attention(h, lp, cfg, kind, layer, idx, mode, carry)
         x = x + o
-    return _ffn_half(x, lp, cfg)[0], carry
+    return _ffn_half(x, lp, cfg, moe=half == "moe")[0], carry
 
 
 def run_stack(layers, x, cfg: ModelConfig, mode, carry):
@@ -619,7 +669,7 @@ def run_stack(layers, x, cfg: ModelConfig, mode, carry):
     one entry a segment of `cfg.segments()` (a tuple with one stacked dict
     per layer of the period), or the one-block models' stacked dict, their
     one segment. A segment of r > 1 periods is one `lax.scan`; which attn,
-    mamba, window or full layer a layer is (its row in the state arrays
+    conv, mamba, window or full layer a layer is (its row in the state arrays
     and pools) is counted from the layers before it."""
     if isinstance(layers, dict):
         layers = [(layers,)]
@@ -635,6 +685,7 @@ def run_stack(layers, x, cfg: ModelConfig, mode, carry):
                 # a cross layer reads the newest full layer's cache
                 at = idx["full"] - 1 if kind == "cross" else idx.get(kind)
                 x, carry = _layer(x, lp, cfg, kind,
+                                  cfg.second_halves[first + i],
                                   first + rep * len(kinds) + i, at, mode, carry)
                 if kind in idx:
                     idx[kind] = idx[kind] + 1

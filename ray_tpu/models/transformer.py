@@ -30,7 +30,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import apply_rope, flash_attention, layer_norm, rms_norm, rope_frequencies
 from ..ops.attention import FLASH_RESIDUAL_NAMES
-from ..parallel.moe import top_k_gating
+from ..parallel.moe import sigmoid_bias_gating, top_k_gating
 from ..parallel.sharding import _current_mesh, constrain, per_shard
 from .config import ModelConfig
 
@@ -182,14 +182,26 @@ def _flash(q, k, v, mesh=None, **kernel):
         (_QKV_AXES,) * 3, _QKV_AXES, q, k, v, mesh=mesh)
 
 
+def _head_norm(x, w, eps):
+    """RMSNorm over each head's own lanes, x [B,T,heads,hd], w [hd] shared
+    by the heads; float32 inside (XLA fuses it into the rotary turn)."""
+    xf = x.astype(jnp.float32)
+    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (xf * w.astype(jnp.float32)).astype(x.dtype)
+
+
 def _qkv(x, lp, cfg, rope_tables, positions):
-    """x [B,T,D] -> q [B,T,H,hd], k, v [B,T,KVH,hd], q and k turned to
-    `positions` [B,T] (None: 0..T-1) where the model is rotary. Shared by
-    the training block below and the serve path's (models/stack.py)."""
+    """x [B,T,D] -> q [B,T,H,hd], k, v [B,T,KVH,hd], q and k normalised
+    per head where the model says (`cfg.qk_norm`) and turned to `positions`
+    [B,T] (None: 0..T-1) where it is rotary. Shared by the training block
+    below and the serve path's (models/stack.py)."""
     dtype = x.dtype
     q = jnp.einsum("btd,dhk->bthk", x, lp["wq"].astype(dtype))
     k = jnp.einsum("btd,dhk->bthk", x, lp["wk"].astype(dtype))
     v = jnp.einsum("btd,dhk->bthk", x, lp["wv"].astype(dtype))
+    if cfg.qk_norm:
+        q = _head_norm(q, lp["q_norm"], cfg.norm_eps)
+        k = _head_norm(k, lp["k_norm"], cfg.norm_eps)
     if cfg.positional == "rope":
         cos, sin = rope_tables
         q = apply_rope(q, cos, sin, positions)
@@ -234,20 +246,33 @@ def _dense_ffn(x, lp, cfg):
     return constrain(out, ("batch", "seq", "embed"))
 
 
-def _moe_route(x, router_w, cfg):
+def moe_capacity(cfg, T: int) -> int:
+    """Rows each expert computes for a row of T tokens: the capacity factor's
+    share, a multiple of 4 for tiling, T * k at the most. Static: the
+    engine counts a program's padded expert rows with it."""
+    E, k = cfg.num_experts, cfg.num_selected_experts
+    raw = -int(-cfg.capacity_factor * T * k // E)  # ceil
+    return min(max((raw + 3) // 4 * 4, 4), T * k)
+
+
+def _moe_route(x, lp, cfg):
     """Shared routing core for BOTH MoE formulations: router logits ->
-    top-k gating -> cumsum slot assignment under capacity. One
-    implementation so the dense and gather paths can never diverge on
-    capacity/drop semantics (their numerical-parity contract).
+    gating by the model's rule (`cfg.router`: top k softmaxed, or sigmoid
+    scores chosen with a per-expert bias) -> cumsum slot assignment under
+    capacity. One implementation so the dense and gather paths can never
+    diverge on capacity/drop semantics (their numerical-parity contract).
 
     -> (logits, weights [B,T,k], flat_ids [B,T*k], my_pos, keep, capacity)
     """
     B, T, _ = x.shape
     E, k = cfg.num_experts, cfg.num_selected_experts
-    logits = jnp.einsum("btd,de->bte", x.astype(jnp.float32), router_w)
-    weights, expert_ids = top_k_gating(logits, k)  # [B,T,k]
-    raw = -int(-cfg.capacity_factor * T * k // E)  # ceil
-    capacity = min(max((raw + 3) // 4 * 4, 4), T * k)  # mult-of-4 for tiling
+    logits = jnp.einsum("btd,de->bte", x.astype(jnp.float32), lp["router"])
+    if cfg.router == "sigmoid":
+        weights, expert_ids = sigmoid_bias_gating(
+            logits, lp["router_bias"], k, cfg.norm_topk, cfg.routed_scale)
+    else:
+        weights, expert_ids = top_k_gating(logits, k)  # [B,T,k]
+    capacity = moe_capacity(cfg, T)
     flat_ids = expert_ids.reshape(B, T * k)
     onehot = jax.nn.one_hot(flat_ids, E, dtype=jnp.int32)  # [B,T*k,E]
     pos_in_expert = jnp.cumsum(onehot, axis=1) - 1
@@ -267,12 +292,12 @@ def _moe_aux(logits, expert_ids, num_experts):
     return num_experts * jnp.sum(frac_tokens * frac_probs)
 
 
-def _moe_dispatch(x, router_w, cfg):
+def _moe_dispatch(x, lp, cfg):
     """x [B,T,D] -> (dispatch [B,T,E,C] f32, combine [B,T,E,C] f32, aux)."""
     B, T, _ = x.shape
     E, k = cfg.num_experts, cfg.num_selected_experts
     logits, weights, expert_ids, flat_ids, my_pos, keep, capacity = _moe_route(
-        x, router_w, cfg)
+        x, lp, cfg)
     slot = jnp.where(keep, my_pos, 0)
     # ONE big [B,T*k,E,C] mask build; combine reuses it scaled by the
     # slot weight (the second full one-hot product was ~half the
@@ -306,7 +331,7 @@ def _moe_ffn(x, lp, cfg):
 def _moe_ffn_dense(x, lp, cfg):
     dtype = x.dtype
     with jax.named_scope("route"):
-        disp, combine, aux = _moe_dispatch(x, lp["router"], cfg)
+        disp, combine, aux = _moe_dispatch(x, lp, cfg)
     with jax.named_scope("dispatch"):
         expert_in = jnp.einsum("btd,btec->becd", x, disp.astype(dtype))
         expert_in = constrain(expert_in, ("batch", "expert", None, "embed"))
@@ -343,7 +368,7 @@ def _moe_ffn_gather(x, lp, cfg):
     E = cfg.num_experts
     with jax.named_scope("route"):
         (logits, weights, expert_ids, flat_ids, my_pos, keep,
-         capacity) = _moe_route(x, lp["router"], cfg)
+         capacity) = _moe_route(x, lp, cfg)
         k = cfg.num_selected_experts
         safe = jnp.where(keep, my_pos, capacity)  # overflow slot sliced off
         bi = jnp.arange(B)[:, None]
@@ -370,13 +395,15 @@ def _moe_ffn_gather(x, lp, cfg):
         return constrain(out, ("batch", "seq", "embed")), aux
 
 
-def _ffn_half(x, lp, cfg):
+def _ffn_half(x, lp, cfg, moe=None):
     """A layer's second half, x + FFN(norm(x)) or the experts in its place
-    -> (x, aux loss). Shared by the training block below and every layer
-    of the serve path (models/stack.py)."""
-    with jax.named_scope("moe" if cfg.is_moe else "ffn"):
+    (`moe`; None: what the whole model has) -> (x, aux loss). Shared by
+    the training block below and every layer of the serve path
+    (models/stack.py), which says for each layer which it is."""
+    moe = cfg.is_moe if moe is None else moe
+    with jax.named_scope("moe" if moe else "ffn"):
         h = _norm(x, lp["ln2"], lp.get("ln2_b"), cfg)
-        if cfg.is_moe:
+        if moe:
             y, aux = _moe_ffn(h, lp, cfg)
         else:
             y, aux = _dense_ffn(h, lp, cfg), jnp.zeros((), jnp.float32)

@@ -33,6 +33,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .dispatch import interpret_mode, platform_dispatch, use_pallas
+from .paged_attention import _tile_heads, _untile_heads, tile_factor
 
 _NEG_INF = -2.0e30
 _LANES = 128
@@ -709,9 +710,21 @@ def flash_attention(
       block_q/block_k: kernel tile sizes; default picks the largest
         power-of-two <=1024 dividing each sequence length.
     Returns [B, T, H, D] in q's dtype.
+
+    Heads narrower than a 128-lane tile (D = 64, 32, ..) reach the kernel
+    as ops/paged_attention.py's do: 128 / D neighbouring kv heads side by
+    side are one 128-wide head, and a query head is zero outside its own kv
+    head's lanes; 128 / D times the products of a kernel that could tile D.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    KVH = k.shape[2]
+    f = tile_factor(q.shape[2], KVH, q.shape[3])
+    if f > 1:
+        wide = (*k.shape[:2], KVH // f, f * k.shape[3])
+        o = flash_attention(_tile_heads(q, KVH, f), k.reshape(wide),
+                            v.reshape(wide), causal, scale, block_q, block_k)
+        return _untile_heads(o, KVH, f)
     block_q = block_q or _auto_block(q.shape[1])
     block_k = block_k or _auto_block(k.shape[1])
     qt = jnp.swapaxes(q, 1, 2)  # [B,H,T,D]

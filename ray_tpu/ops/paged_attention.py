@@ -35,8 +35,16 @@ p - window + 1 .. p only. The kernels then start at the window's first page
 and, in decode, take page ids modulo the table's width, so a table may be a
 RING of window / page_size + 1 pages that a sequence overwrites as it grows
 (the engine's window layers). A head may be as wide as two differential
-heads side by side: the ops take any D that is a multiple of 128 and an
-explicit `scale`.
+heads side by side: the kernels take any D that is a multiple of 128 and
+an explicit `scale`. A head NARROWER than a 128-lane tile (D = 64, 32, ..)
+rides on them as a differential pair does: 128 / D neighbouring kv heads
+are one 128-wide head of the same row (the pool's bytes do not change) and
+each query head is padded with zeros outside its own kv head's D lanes
+(`_tile_heads`), so its scores see that kv head alone and its output row
+holds its own values in those lanes (`_untile_heads`). The decode kernel
+widens queries to the whole row anyway, so there this costs nothing; the
+chunk and verify kernels, which read a pool head by head, run 128 / D
+times the products of a kernel that could read D-lane tiles.
 """
 
 from __future__ import annotations
@@ -109,6 +117,48 @@ def _own(o, kv_heads):
         part = o[..., c * D:(c + 1) * D]
         out = jnp.where(_lanes(part.shape, g, c), part, out)
     return out
+
+
+def tile_factor(H: int, kv_heads: int, D: int) -> int:
+    """How many kv heads of width D make one 128-lane tile, where the
+    kernels can take them so (1: as they are): D divides 128, whole tiles
+    of kv heads, query heads grouped evenly over them."""
+    f = _LANES // D if D < _LANES and _LANES % D == 0 else 1
+    if not use_pallas() or kv_heads % f or H % kv_heads:
+        return 1
+    return f
+
+
+def _own_lanes(H, kv_heads, f):
+    """bool [H, f]: which of a tile's f slots of D lanes is query head h's
+    own kv head's."""
+    sub = (jnp.arange(H) // (H // kv_heads)) % f
+    return sub[:, None] == jnp.arange(f)[None, :]
+
+
+def _tile_heads(q, kv_heads, f):
+    """[.., H, D] -> [.., H, f*D]: zero outside the head's own D lanes."""
+    H, D = q.shape[-2:]
+    own = _own_lanes(H, kv_heads, f)[..., None]
+    return jnp.where(own, q[..., None, :], 0).reshape(*q.shape[:-1], f * D)
+
+
+def _untile_heads(o, kv_heads, f):
+    """[.., H, f*D] -> [.., H, D]: the head's own D lanes of its row."""
+    H = o.shape[-2]
+    o = o.reshape(*o.shape[:-1], f, o.shape[-1] // f)
+    own = _own_lanes(H, kv_heads, f)[..., None]
+    return jnp.sum(jnp.where(own, o, 0), axis=-2).astype(o.dtype)
+
+
+def _on_tiles(run, q, k_pages):
+    """`run(q)` on the kernels' terms: heads narrower than a tile padded
+    to one and the output's own lanes taken (module docstring)."""
+    (H, D), row = q.shape[-2:], k_pages.shape[-1]
+    f = 1 if row % D else tile_factor(H, row // D, D)
+    if f == 1:
+        return run(q)
+    return _untile_heads(run(_tile_heads(q, row // D, f)), row // D, f)
 
 
 def _gather_rows(pages, layer, page_table, D):
@@ -488,17 +538,21 @@ def paged_attention_chunk(
     C, H, D = q.shape
     if scale is None:
         scale = D**-0.5
-    if force_xla or not _kernel_ok(q, k_pages):
-        return _chunk_reference(q, k_pages, v_pages, page_table, start,
-                                total, layer, scale, window, first)
     meta = (start, total, layer) + (() if window is None else (first,))
-    return platform_dispatch(
-        lambda *a: _chunk_pallas(*a, scale, window),
-        lambda q, kp, vp, pt, _m: _chunk_reference(
-            q, kp, vp, pt, start, total, layer, scale, window, first),
-        q, k_pages, v_pages, page_table,
-        jnp.stack([jnp.asarray(x, jnp.int32) for x in meta]),
-    )
+
+    def run(q):
+        if force_xla or not _kernel_ok(q, k_pages):
+            return _chunk_reference(q, k_pages, v_pages, page_table, start,
+                                    total, layer, scale, window, first)
+        return platform_dispatch(
+            lambda *a: _chunk_pallas(*a, scale, window),
+            lambda q, kp, vp, pt, _m: _chunk_reference(
+                q, kp, vp, pt, start, total, layer, scale, window, first),
+            q, k_pages, v_pages, page_table,
+            jnp.stack([jnp.asarray(x, jnp.int32) for x in meta]),
+        )
+
+    return run(q) if force_xla else _on_tiles(run, q, k_pages)
 
 
 def _verify_reference(q, k_pages, v_pages, page_table, positions, layer,
@@ -626,9 +680,6 @@ def _batched(pallas_fn, reference_fn, q, k_pages, v_pages, page_table,
     if scale is None:
         scale = q.shape[-1]**-0.5
     tp = int(mesh.shape.get(tp_axis, 1)) if mesh is not None else 1
-    if force_xla or not _kernel_ok(q, k_pages, tp):
-        return reference_fn(q, k_pages, v_pages, page_table, per_seq, layer,
-                            scale)
 
     def dispatch(q, kp, vp, pt, meta):
         return platform_dispatch(
@@ -638,22 +689,28 @@ def _batched(pallas_fn, reference_fn, q, k_pages, v_pages, page_table,
             q, kp, vp, pt, meta,
         )
 
-    args = (q, k_pages, v_pages, page_table, _with_layer(per_seq, layer))
-    if tp == 1:
-        return dispatch(*args)
-    from jax.sharding import PartitionSpec as P
+    def run(q):
+        if force_xla or not _kernel_ok(q, k_pages, tp):
+            return reference_fn(q, k_pages, v_pages, page_table, per_seq,
+                                layer, scale)
+        args = (q, k_pages, v_pages, page_table, _with_layer(per_seq, layer))
+        if tp == 1:
+            return dispatch(*args)
+        from jax.sharding import PartitionSpec as P
 
-    heads = P(*[None] * (q.ndim - 2), tp_axis, None)
-    pool = P(None, None, None, None, tp_axis)
-    return jax.shard_map(
-        dispatch,
-        mesh=mesh,
-        in_specs=(heads, pool, pool, P(), P()),
-        # no collectives in the body; pallas_call outputs don't carry
-        # vma annotations, so the varying-axes checker can't see through
-        out_specs=heads,
-        check_vma=False,
-    )(*args)
+        heads = P(*[None] * (q.ndim - 2), tp_axis, None)
+        pool = P(None, None, None, None, tp_axis)
+        return jax.shard_map(
+            dispatch,
+            mesh=mesh,
+            in_specs=(heads, pool, pool, P(), P()),
+            # no collectives in the body; pallas_call outputs don't carry
+            # vma annotations, so the varying-axes checker can't see through
+            out_specs=heads,
+            check_vma=False,
+        )(*args)
+
+    return run(q) if force_xla else _on_tiles(run, q, k_pages)
 
 
 def paged_attention_verify(

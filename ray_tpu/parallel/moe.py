@@ -30,6 +30,26 @@ def top_k_gating(
     return weights, expert_ids
 
 
+def sigmoid_bias_gating(
+    router_logits: jax.Array, bias: jax.Array, num_selected: int,
+    norm_topk: bool = True, scale: float = 1.0,
+) -> Tuple[jax.Array, jax.Array]:
+    """router_logits [T, E] float32, bias [E] -> (weights [T, k],
+    expert_ids [T, k]). Scores are sigmoids; the k experts are chosen by
+    score + bias (the bias balances load and says nothing of how much an
+    expert matters), and the weights are the chosen experts' scores WITHOUT
+    it, over their sum + 1e-6 if `norm_topk`, times `scale`. Feeds the
+    same slot assignment as `top_k_gating` (models/transformer.py
+    `_moe_route`)."""
+    scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
+    _, expert_ids = jax.lax.top_k(scores + bias.astype(jnp.float32),
+                                  num_selected)
+    weights = jnp.take_along_axis(scores, expert_ids, axis=-1)
+    if norm_topk:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-6)
+    return weights * scale, expert_ids
+
+
 def _dispatch_mask(
     expert_ids: jax.Array, weights: jax.Array, num_experts: int, capacity: int
 ) -> Tuple[jax.Array, jax.Array]:

@@ -52,7 +52,7 @@ from ..core.logging import get_logger
 from ..core.metrics import Counter, Gauge, Histogram
 from ..util import slo, tracing
 from ..models import ModelConfig, stack
-from ..models.transformer import _head_logits
+from ..models.transformer import _head_logits, moe_capacity
 from ..ops import gather_pages, pool_shape, scatter_pages
 from .config import SpeculationConfig
 from .spec_decode import SpecDecoder
@@ -168,6 +168,15 @@ _m_state_slots = Counter(
     "serve_state_slots_installed",
     "Decode slots whose recurrent and window state a prefilled sequence "
     "overwrote at install (the slot's reset).")
+_m_moe_rows_computed = Counter(
+    "serve_moe_rows_computed",
+    "Rows the expert products of the dispatched programs computed, over "
+    "every expert layer: rows x experts x capacity, static in a program's "
+    "shape.")
+_m_moe_rows_routed = Counter(
+    "serve_moe_rows_routed",
+    "Rows the live tokens of the dispatched programs were routed to, over "
+    "every expert layer: tokens x experts a token.")
 _deferred_no_pages = _m_deferred.labels(reason="no_pages")
 _front_inbound = _m_front.labels(leg="inbound")
 _front_outbound = _m_front.labels(leg="outbound")
@@ -1801,6 +1810,7 @@ class InferenceEngine:
             logits, cache = self._prefill_fn(bucket, Bpad)(
                 self.params, jnp.asarray(padded), jnp.asarray(lens)
             )
+            self._count_moe_rows(Bpad, bucket, sum(g[2] for g in group))
         # first generated tokens: one small readback, on THIS thread.
         # Sample every row BEFORE emitting/publishing anything: if this
         # raises, the caller's failure path can still free every page
@@ -2037,6 +2047,7 @@ class InferenceEngine:
             jnp.int32(start), jnp.asarray(st.table), jnp.int32(last_idx),
             st.state,
         )
+        self._count_moe_rows(1, C, len(toks))
         chunk_kv = (*kv, start) if streaming else None
         st.next_chunk += 1
         if not is_last:
@@ -2173,6 +2184,7 @@ class InferenceEngine:
         with decode_phase("commit") as ph:
             n_active = len(active)
             self._count_slot_steps(n_active, span)
+            self._count_moe_rows(self.ecfg.max_batch_size, 1, n_active, span)
             committed = self._commit_span(seq, logps, span)
             self._note_tokens_per_step(committed, span * n_active)
         _step_phase["cache_bookkeeping", "plain"].observe(ph.elapsed_s)
@@ -2232,6 +2244,21 @@ class InferenceEngine:
                         s.request._emit(tok)
                 self._maybe_finish(s, tok)
         return committed
+
+    def _count_moe_rows(self, rows: int, row_tokens: int, live: int,
+                        times: int = 1) -> None:
+        """A program over `rows` rows of `row_tokens` tokens, `live` of
+        them real, dispatched `times` over (a span's steps): its expert
+        layers computed rows x experts x capacity rows each for live x k
+        routed. On the host, from the program's static shape."""
+        layers = self.cfg.second_halves.count("moe")
+        if not layers:
+            return
+        _m_moe_rows_computed.inc(
+            times * layers * rows * self.cfg.num_experts
+            * moe_capacity(self.cfg, row_tokens))
+        _m_moe_rows_routed.inc(
+            times * layers * live * self.cfg.num_selected_experts)
 
     def _count_slot_steps(self, n_active: int, steps: int) -> None:
         _slot_active.inc(n_active * steps)
@@ -2577,7 +2604,9 @@ class InferenceEngine:
             "chunking": chunking,
             "waiting_for_pages": waiting,
             # pages of THE pool (EngineConfig.pages_per_seq says whose)
-            "page_pool": ("every layer" if self.cfg.count("attn")
+            "page_pool": ("every layer"
+                          if self.cfg.count("attn") == self.cfg.n_layers
+                          else "attn layers" if self.cfg.count("attn")
                           else "full-attention layers"),
             **({"window_ring_pages": stack.ring_pages(
                 self.cfg, self.ecfg.page_size)}

@@ -130,7 +130,9 @@ def test_a_names_file_in_the_tree_adds_and_removes_nothing():
 
 @pytest.mark.parametrize("metric", [
     "ssm_scan_roofline", "ssm_step_device_share",
-    "hybrid_decode_attn_roofline", "window_pages_held_share"])
+    "hybrid_decode_attn_roofline", "window_pages_held_share",
+    "moe_rows_padding_factor", "short_conv_device_share",
+    "moe_ffn_device_share.tpot"])
 def test_a_new_reader_returns_nothing_where_the_program_has_nothing(metric):
     """On the parent (no such kernel, no such counter) a new reader reads
     nothing and does not raise: the result line leaves its metric out."""
@@ -145,3 +147,94 @@ def test_a_new_reader_returns_nothing_where_the_program_has_nothing(metric):
                "modules": {}, "module_ops": {}},
            "counters": ({}, {})}
     assert common.load_reader(metric)(ctx) is None
+
+
+# -- the LFM2 family (PR 32) -------------------------------------------------
+
+LFM2 = "lfm2-8b-a1b"
+LFM2_ROW = {  # the published config's numbers, key for key, but the depth
+    "conv_L_cache": 3, "conv_bias": False, "hidden_size": 2048,
+    "intermediate_size": 7168, "max_position_embeddings": 128000,
+    "model_type": "lfm2_moe", "moe_intermediate_size": 1792,
+    "norm_eps": 1e-05, "norm_topk_prob": True, "num_attention_heads": 32,
+    "num_dense_layers": 2, "num_experts": 32, "num_experts_per_tok": 4,
+    "num_key_value_heads": 8, "rope_theta": 1000000,
+    "routed_scaling_factor": 1, "use_expert_bias": True, "vocab_size": 65536}
+LFM2_TYPES = ["conv", "conv", "full_attention", "conv", "conv", "conv",
+              "full_attention", "conv", "conv", "conv", "full_attention",
+              "conv", "conv", "conv", "full_attention", "conv", "conv",
+              "conv", "full_attention", "conv", "conv", "full_attention",
+              "conv", "conv"]
+
+
+def test_lfm2_configuration_is_the_published_one_cut_in_depth_alone():
+    spec = common.load_json("configs", LFM2 + ".json")
+    assert {k: spec[k] for k in LFM2_ROW} == LFM2_ROW
+    assert spec["num_hidden_layers"] == 14
+    assert spec["layer_types"] == LFM2_TYPES[:14]
+    assert spec["published"]["layer_types"] == LFM2_TYPES
+    assert sorted(spec["reduced"]) == ["layer_types", "num_hidden_layers"]
+    entry = next(c for c in common.load_manifest()["configs"]
+                 if c["name"] == LFM2)
+    assert sorted(entry["reduced"]) == sorted(spec["reduced"])
+    assert sorted(entry) == ["file", "name", "reduced", "source", "why"]
+    cfg = common.family(spec).model_config(spec)
+    # both dense layers, then three whole periods of sparse layers
+    assert cfg.segments() == ((0, ("conv",), 2),
+                              (2, ("attn", "conv", "conv", "conv"), 3))
+    assert cfg.second_halves == ("ffn",) * 2 + ("moe",) * 12
+    assert round(cfg.param_count() / 1e6) == 4667      # the issue's count
+    assert cfg.cache_dims == (3, 8, 64) and cfg.conv_tail == (11, 2, 2048)
+    assert (cfg.router, cfg.qk_norm, cfg.capacity_factor) == ("sigmoid", True, 8.0)
+
+
+def test_lfm2_readers_reach_the_counts_through_the_family():
+    spec = common.load_json("configs", LFM2 + ".json")
+    family = common.family(spec)
+    assert reference_file(family) == spec["reference"]
+    assert set(family.modes) == {"int8", "fp8"}
+    # 3 of the 14 layers attend and hold a cache
+    assert family.calls_per_pass(spec, "paged_decode") == 3
+    # a cached token is 8 KV heads of 64 bfloat16, keys and values
+    work = family.work["paged_decode"](spec, 1000)
+    assert work["bytes"] == 2 * 8 * 64 * 2 * 1000
+    assert work["flops"] == 2 * 2 * 32 * 64 * 1000
+    cell = common.load_cell(LFM2 + ".serve-chat")
+    assert cell["engine"] == {"max_seq_len": 4096, "max_batch_size": 64,
+                              "max_pages": 16385}
+    assert {m["name"] for m in cell["per_layer"]} >= {
+        "moe_rows_padding_factor", "short_conv_device_share",
+        "moe_ffn_device_share.tpot", "paged_decode_roofline"}
+    # its answers outlast the window: the time per token is what is judged
+    assert [m["name"] for m in cell["end_to_end"]] == ["tpot_mean_ms", "setup_s"]
+    assert all(m["moves"] == "tpot_mean_ms" for m in cell["per_layer"])
+    mixtral = common.load_cell("mixtral-8x7b.serve-chat")
+    assert "moe_rows_padding_factor" in {m["name"] for m in mixtral["per_layer"]}
+
+
+def test_lfm2_weights_are_seeded_bfloat16_and_in_the_programs_layout():
+    from benchmark import weights
+
+    spec = tiny_spec(LFM2)
+    a, b, c = (weights.make_weights(spec, s) for s in (7, 7, 2**31 + 11))
+    leaves = jax.tree.leaves(a)
+    assert all(leaf.dtype == jax.numpy.bfloat16 for leaf in leaves)
+    assert all((x == y).all() for x, y in zip(leaves, jax.tree.leaves(b)))
+    assert any((x != y).any() for x, y in zip(leaves, jax.tree.leaves(c)))
+    cfg = common.family(spec).model_config(spec)
+    assert [len(seg) for seg in a["layers"]] == [
+        len(kinds) for _, kinds, _ in cfg.segments()] == [1, 4]
+    sparse = a["layers"][1][0]
+    # the bias is drawn nonzero, so that the choice and the weights differ
+    assert float(abs(sparse["router_bias"].astype("float32")).max()) > 0.01
+    assert "final_norm_b" not in a
+
+
+def test_the_padding_factor_reads_the_two_counters():
+    read = common.load_reader("moe_rows_padding_factor")
+    before = {("serve_moe_rows_routed", ()): 10.0,
+              ("serve_moe_rows_computed", ()): 100.0}
+    after = {("serve_moe_rows_routed", ()): 110.0,
+             ("serve_moe_rows_computed", ()): 900.0}
+    assert read({"counters": (before, after)}) == 8.0
+    assert read({"counters": None}) is None

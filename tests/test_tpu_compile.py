@@ -87,6 +87,17 @@ def _paged(batch, *q_shape, heads=(H, KVH)):
             + [((batch, PAGES_PER_SEQ), I32), ((batch,), I32)])
 
 
+# heads of 64 (32 query, 8 kv: a pool row of 512 lanes): two kv heads ride
+# one 128-lane tile of the same row (ops/paged_attention.py `_tile_heads`)
+D64 = 64
+_POOL64 = [(pool_shape(LAYERS, N_PAGES, PAGE, KVH, D64), BF16)] * 2
+
+
+def _paged64(batch, *q_shape):
+    return ([((batch, *q_shape, H, D64), BF16)] + _POOL64
+            + [((batch, PAGES_PER_SEQ), I32), ((batch,), I32)])
+
+
 def _at_layer(op):
     return lambda *a: op(*a, layer=LAYER)
 
@@ -111,6 +122,16 @@ CASES = {
         [((256, H, D), BF16)] + _POOL + [((PAGES_PER_SEQ,), I32)], 1),
     "paged_verify_span4": (_at_layer(paged_attention_verify), _paged(8, 4), 1),
     "paged_verify_span8": (_at_layer(paged_attention_verify), _paged(8, 8), 1),
+    "flash_fwd_t256_head64": (
+        _flash_fwd, [((1, 256, H, D64), BF16)] + [((1, 256, KVH, D64), BF16)] * 2, 1),
+    "paged_decode_b64_head64": (
+        _at_layer(paged_attention_decode), _paged64(64), 1),
+    "paged_chunk_c256_head64": (
+        lambda q, kp, vp, pt: paged_attention_chunk(
+            q, kp, vp, pt, 512, 768, layer=LAYER),
+        [((256, H, D64), BF16)] + _POOL64 + [((PAGES_PER_SEQ,), I32)], 1),
+    "paged_verify_span4_head64": (
+        _at_layer(paged_attention_verify), _paged64(8, 4), 1),
     "rms_norm_2048x4096": (
         rms_norm, [((2048, D_MODEL), BF16), ((D_MODEL,), BF16)], 1),
 }
