@@ -247,31 +247,69 @@ def _dense_ffn(x, lp, cfg):
 
 
 def moe_capacity(cfg, T: int) -> int:
-    """Rows each expert computes for a row of T tokens: the capacity factor's
-    share, a multiple of 4 for tiling, T * k at the most. Static: the
-    engine counts a program's padded expert rows with it."""
+    """Slots each expert has for a batch row of T tokens: the capacity
+    factor's share, a multiple of 4 for tiling, T * k at the most. Static.
+    At `capacity >= T` nothing can be dropped (a token's k experts are
+    distinct, so an expert gets at most T of a row's choices), which is
+    the rule `_moe_ffn` picks the dropless form by and `moe_rows_computed`
+    counts by."""
     E, k = cfg.num_experts, cfg.num_selected_experts
     raw = -int(-cfg.capacity_factor * T * k // E)  # ceil
     return min(max((raw + 3) // 4 * 4, 4), T * k)
 
 
-def _moe_route(x, lp, cfg):
-    """Shared routing core for BOTH MoE formulations: router logits ->
-    gating by the model's rule (`cfg.router`: top k softmaxed, or sigmoid
-    scores chosen with a per-expert bias) -> cumsum slot assignment under
-    capacity. One implementation so the dense and gather paths can never
-    diverge on capacity/drop semantics (their numerical-parity contract).
+def _moe_sharded(mesh) -> bool:
+    """Whether a model axis shards tokens, experts or params: such a mesh
+    keeps the dense dispatch, whose einsums partition as sharded
+    contractions under GSPMD (indices across a sharded seq (sp) or expert
+    (ep) axis, or scatter outputs under fsdp/tp layouts, would force
+    per-layer allgathers). Pure data-parallel axes only shard the batch."""
+    return mesh is not None and any(
+        mesh.shape.get(ax, 1) > 1 for ax in ("ep", "sp", "tp", "fsdp"))
 
-    -> (logits, weights [B,T,k], flat_ids [B,T*k], my_pos, keep, capacity)
-    """
-    B, T, _ = x.shape
-    E, k = cfg.num_experts, cfg.num_selected_experts
+
+def _moe_dropless(cfg, T: int, mesh) -> bool:
+    """Whether `_moe_ffn` dispatches nothing for rows of T tokens: no slot
+    can overflow, and the mesh is one the row forms run on."""
+    return moe_capacity(cfg, T) >= T and not _moe_sharded(mesh)
+
+
+def moe_rows_computed(cfg, B: int, T: int, mesh=None) -> int:
+    """Expert rows ONE expert layer computes for a program of B rows of T
+    tokens, whichever form `_moe_ffn` takes: every expert over the
+    program's own B * T tokens when it dispatches nothing, else
+    B x experts x capacity padded slots. The engine's
+    `serve_moe_rows_computed` counts with it."""
+    per_row = T if _moe_dropless(cfg, T, mesh) else moe_capacity(cfg, T)
+    return cfg.num_experts * B * per_row
+
+
+def _moe_gate(x, lp, cfg):
+    """Router logits in float32 -> gating by the model's rule
+    (`cfg.router`: top k softmaxed, or sigmoid scores chosen with a
+    per-expert bias) -> (logits [B,T,E], weights [B,T,k], expert_ids
+    [B,T,k]). One implementation for every MoE formulation."""
+    k = cfg.num_selected_experts
     logits = jnp.einsum("btd,de->bte", x.astype(jnp.float32), lp["router"])
     if cfg.router == "sigmoid":
         weights, expert_ids = sigmoid_bias_gating(
             logits, lp["router_bias"], k, cfg.norm_topk, cfg.routed_scale)
     else:
         weights, expert_ids = top_k_gating(logits, k)  # [B,T,k]
+    return logits, weights, expert_ids
+
+
+def _moe_route(x, lp, cfg):
+    """Shared routing core for BOTH capacity-bound MoE formulations:
+    `_moe_gate` -> cumsum slot assignment under capacity. One
+    implementation so the dense and gather paths can never diverge on
+    capacity/drop semantics (their numerical-parity contract).
+
+    -> (logits, weights [B,T,k], flat_ids [B,T*k], my_pos, keep, capacity)
+    """
+    B, T, _ = x.shape
+    E, k = cfg.num_experts, cfg.num_selected_experts
+    logits, weights, expert_ids = _moe_gate(x, lp, cfg)
     capacity = moe_capacity(cfg, T)
     flat_ids = expert_ids.reshape(B, T * k)
     onehot = jax.nn.one_hot(flat_ids, E, dtype=jnp.int32)  # [B,T*k,E]
@@ -313,19 +351,49 @@ def _moe_dispatch(x, lp, cfg):
 
 
 def _moe_ffn(x, lp, cfg):
+    """One algorithm (the same gating, the same weighted sum) in the form
+    its static shape and mesh allow: where no slot can overflow (every
+    decode step, every dropless chunk and bucket) the dispatch is pure
+    cost and the experts run over the tokens where they lie; under a
+    capacity, rows are gathered to their slots and scattered back; a
+    sharded mesh keeps the dense dispatch."""
     mesh = _current_mesh()
-    # Gather routing only where no model axis shards tokens/experts/
-    # params: indices across a sharded seq (sp) or expert (ep) axis — or
-    # scatter outputs under fsdp/tp layouts — would force per-layer
-    # allgathers. Dense dispatch einsums partition as sharded
-    # contractions under GSPMD, so any such mesh keeps them. Pure
-    # data-parallel axes (dp/dcn_dp and friends) only shard batch, which
-    # the gather path vmaps over.
-    if mesh is not None and any(
-        mesh.shape.get(ax, 1) > 1 for ax in ("ep", "sp", "tp", "fsdp")
-    ):
+    if _moe_dropless(cfg, x.shape[1], mesh):
+        return _moe_ffn_dropless(x, lp, cfg)
+    if _moe_sharded(mesh):
         return _moe_ffn_dense(x, lp, cfg)
     return _moe_ffn_gather(x, lp, cfg)
+
+
+def _moe_ffn_dropless(x, lp, cfg):
+    """The expert layer where `moe_capacity(cfg, T) >= T`, so nothing can
+    be dropped: no slot tables, no gather, no scatter. Every expert runs
+    over the program's own N = B * T tokens (E * N rows, never more than
+    the padded forms' B * E * capacity and 4 x / 2 x fewer in a decode
+    step of 32 top 4 / 8 top 2), and a float32 combine matrix c[N, E], a
+    token's k weights at its k experts and zero elsewhere, sums them:
+    out[n] = sum_e c[n, e] * expert_e(x_n), what the padded forms compute
+    too. The expert axis leads ([E, N, F]) so the weights are read as they
+    lie; x is shared by the experts and never copied E times."""
+    dtype = x.dtype
+    B, T, D = x.shape
+    E = cfg.num_experts
+    with jax.named_scope("route"):
+        logits, weights, expert_ids = _moe_gate(x, lp, cfg)
+        c = jnp.sum(jax.nn.one_hot(expert_ids, E, dtype=jnp.float32)
+                    * weights[..., None], axis=2)  # float32, as the scores
+        aux = _moe_aux(logits, expert_ids, E)
+    xs = x.reshape(B * T, D)
+    with jax.named_scope("experts"):
+        h = jnp.einsum("nd,edf->enf", xs, lp["w_in"].astype(dtype))
+        g = jnp.einsum("nd,edf->enf", xs, lp["w_gate"].astype(dtype))
+        h = jax.nn.silu(g) * h
+        y = jnp.einsum("enf,efd->end", h, lp["w_out"].astype(dtype))
+    with jax.named_scope("combine"):
+        out = jnp.sum(y.astype(jnp.float32)
+                      * c.reshape(B * T, E).T[:, :, None], axis=0)
+        out = out.astype(dtype).reshape(B, T, D)
+        return constrain(out, ("batch", "seq", "embed")), aux
 
 
 def _moe_ffn_dense(x, lp, cfg):
@@ -353,16 +421,20 @@ def _experts(expert_in, lp, dtype):
 
 
 def _moe_ffn_gather(x, lp, cfg):
-    """Gather/scatter token routing (single-chip & non-ep meshes): the
+    """Gather/scatter token routing under a capacity (`capacity < T`:
+    capacity-factor training on a single chip and non-ep meshes; every
+    shape the serve path runs is dropless and dispatches nothing): the
     dense [T,E,C] dispatch/combine einsums cost O(T*E*C*D) MXU flops
-    while routing is really just row movement — this path is O(E*C*D)
-    memory traffic instead. Slot tables come from the same
-    cumsum-position assignment (identical capacity-drop semantics,
-    numerically equal to the dense path, pinned by test parity);
-    expert inputs are a row gather, outputs a row scatter-add; backward
-    is the mirror pair, all static shapes. Speed against the dense path:
-    not measured on today's code; the asymptotic win is at long-context
-    shapes where C grows with T and the dense form scales ~T^2."""
+    while routing is really just row movement, and this path is O(E*C*D)
+    memory traffic instead, over capacity_factor * k * T padded rows
+    where the dropless form would compute E * T. Slot tables come from
+    the same cumsum-position assignment (identical capacity-drop
+    semantics, numerically equal to the dense path, pinned by test
+    parity); expert inputs are a row gather, outputs a row scatter-add;
+    backward is the mirror pair, all static shapes. No benchmark cell
+    runs it (section 7 of PERF.md: `mixtral-8x7b.train-packed` is
+    queued); what its gather and scatter-add cost at a capacity equal to
+    T is PERF.md section 6, PR 33."""
     dtype = x.dtype
     B, T, D = x.shape
     E = cfg.num_experts

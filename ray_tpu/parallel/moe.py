@@ -38,9 +38,9 @@ def sigmoid_bias_gating(
     expert_ids [T, k]). Scores are sigmoids; the k experts are chosen by
     score + bias (the bias balances load and says nothing of how much an
     expert matters), and the weights are the chosen experts' scores WITHOUT
-    it, over their sum + 1e-6 if `norm_topk`, times `scale`. Feeds the
-    same slot assignment as `top_k_gating` (models/transformer.py
-    `_moe_route`)."""
+    it, over their sum + 1e-6 if `norm_topk`, times `scale`. Feeds what
+    `top_k_gating` feeds (models/transformer.py `_moe_gate`: the dropless
+    form's combine matrix, or `_moe_route`'s slot assignment)."""
     scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
     _, expert_ids = jax.lax.top_k(scores + bias.astype(jnp.float32),
                                   num_selected)

@@ -52,7 +52,7 @@ from ..core.logging import get_logger
 from ..core.metrics import Counter, Gauge, Histogram
 from ..util import slo, tracing
 from ..models import ModelConfig, stack
-from ..models.transformer import _head_logits, moe_capacity
+from ..models.transformer import _head_logits, moe_rows_computed
 from ..ops import gather_pages, pool_shape, scatter_pages
 from .config import SpeculationConfig
 from .spec_decode import SpecDecoder
@@ -171,8 +171,8 @@ _m_state_slots = Counter(
 _m_moe_rows_computed = Counter(
     "serve_moe_rows_computed",
     "Rows the expert products of the dispatched programs computed, over "
-    "every expert layer: rows x experts x capacity, static in a program's "
-    "shape.")
+    "every expert layer: experts x the program's tokens where nothing can "
+    "drop, rows x experts x capacity else; static in a program's shape.")
 _m_moe_rows_routed = Counter(
     "serve_moe_rows_routed",
     "Rows the live tokens of the dispatched programs were routed to, over "
@@ -2248,15 +2248,17 @@ class InferenceEngine:
     def _count_moe_rows(self, rows: int, row_tokens: int, live: int,
                         times: int = 1) -> None:
         """A program over `rows` rows of `row_tokens` tokens, `live` of
-        them real, dispatched `times` over (a span's steps): its expert
-        layers computed rows x experts x capacity rows each for live x k
-        routed. On the host, from the program's static shape."""
+        them real, dispatched `times` over (a span's steps): each of its
+        expert layers computed what `moe_rows_computed` says of the form
+        the program took (every expert over the program's tokens, or
+        padded slots under a capacity) for live x k routed. On the host,
+        from the program's static shape."""
         layers = self.cfg.second_halves.count("moe")
         if not layers:
             return
         _m_moe_rows_computed.inc(
-            times * layers * rows * self.cfg.num_experts
-            * moe_capacity(self.cfg, row_tokens))
+            times * layers
+            * moe_rows_computed(self.cfg, rows, row_tokens, self.mesh))
         _m_moe_rows_routed.inc(
             times * layers * live * self.cfg.num_selected_experts)
 

@@ -22,7 +22,7 @@ from benchmark import common
 from benchmark.reference import lfm2 as ref
 from benchmark.tests.tiny import tiny_spec
 from ray_tpu.models import forward, get_config, init_params, stack
-from ray_tpu.models.transformer import _qkv
+from ray_tpu.models.transformer import _qkv, moe_rows_computed
 from ray_tpu.ops import mha_reference
 from ray_tpu.ops import paged_attention as pa
 from ray_tpu.ops.attention import flash_attention
@@ -290,6 +290,14 @@ def test_a_slot_is_reused_and_the_experts_rows_are_counted(model):
     _, _, cfg, params = model
     before = common.counters()
     eng = engine_for(cfg, params)
+    programs = []  # (rows, row_tokens, live, times) of every dispatch
+    count = eng._count_moe_rows
+
+    def counted(rows, row_tokens, live, times=1):
+        programs.append((rows, row_tokens, live, times))
+        count(rows, row_tokens, live, times)
+
+    eng._count_moe_rows = counted
     try:
         ps = prompts(3, [11, 19, 7], seed=3)
         budgets = [6, 24, 26]
@@ -306,9 +314,21 @@ def test_a_slot_is_reused_and_the_experts_rows_are_counted(model):
     computed = common.counter_delta(before, after, "serve_moe_rows_computed")
     routed = common.counter_delta(before, after, "serve_moe_rows_routed")
     # 8 expert layers, 2 experts a token: at least every prompt and output
-    # token was routed, and the padded dispatch computed more rows than that
+    # token was routed, and every expert ran over every token: more rows
     assert routed >= 8 * 2 * (sum(map(len, ps)) + sum(budgets) - 3)
     assert computed > routed
+    # the exact count: every program here is dropless (capacity_factor is
+    # experts / k), so each of the 8 expert layers ran its 8 experts over
+    # the program's own rows x tokens: decode spans of 2 slots x 1, chunks
+    # of 1 x 16, buckets of rows x 8 or 16
+    assert {(r, t) for r, t, _, _ in programs} >= {(2, 1), (1, 16)}
+    assert computed == sum(
+        times * 8 * moe_rows_computed(cfg, rows, row_tokens)
+        for rows, row_tokens, _, times in programs)
+    assert computed == 8 * 8 * sum(
+        times * rows * row_tokens for rows, row_tokens, _, times in programs)
+    assert routed == 8 * 2 * sum(
+        times * live for _, _, live, times in programs)
     for r, p in zip(reqs, ps):
         want = reference_logprobs(model, p, r.output)
         picked = want[np.arange(len(r.output)), r.output]
