@@ -17,7 +17,8 @@ from typing import Optional, Tuple
 
 # what a layer's mixer can be when a StackConfig's `layer_kinds` names them
 # (models/stack.py); a plain ModelConfig's layers are all "attn"
-LAYER_KINDS = ("attn", "conv", "mamba", "window", "full", "gmu", "cross")
+LAYER_KINDS = ("attn", "conv", "mamba", "window", "full", "gmu", "cross",
+               "gdn")
 # the kinds whose attention is differential over pairs of heads
 _DIFFERENTIAL = ("window", "full", "cross")
 
@@ -80,9 +81,12 @@ class ModelConfig:
     is_stack = False
     has_state = False
     qk_norm = False
+    qk_norm_whole = False
+    post_norm = False
     router = "softmax"
     n_dense_layers = 0
     conv_tail = (0, 0, 0)
+    gdn_dims = (0, 0, 0, 0)
 
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
@@ -108,13 +112,15 @@ class ModelConfig:
         kinds, repeats). A layer is a (mixer, second half) pair and a
         period a period of pairs; every repeat of a period has its first
         repeat's second halves (`second_halves[first + i]`). A run of two
-        or more equal periods (the shortest period that repeats wins) is
+        or more equal periods (the period of up to four layers whose
+        repeats cover the most layers wins, the shortest of equals) is
         scanned; a layer that belongs to none is a run of its own, once.
         Equal layers are ONE scan; SambaY's mamba/window pairs, then one
         mamba and one full layer, then gmu/cross pairs are three scans'
         worth of programs, whatever the depth; two leading dense conv
         layers and then attn/conv/conv/conv periods of expert layers are
-        two."""
+        two; gdn/gdn/gdn/attn periods are one (not a scan of three and a
+        layer alone, over and over)."""
         kinds, out, i = self.layer_kinds, [], 0
         pairs = tuple(zip(kinds, self.second_halves))
         while i < len(pairs):
@@ -123,9 +129,8 @@ class ModelConfig:
                 r = 1
                 while pairs[i + r * p:i + (r + 1) * p] == pairs[i:i + p]:
                     r += 1
-                if r >= 2:
+                if r >= 2 and p * r > best[0] * best[1]:
                     best = (p, r)
-                    break
             out.append((i, kinds[i:i + best[0]], best[1]))
             i += best[0] * best[1]
         return tuple(out)
@@ -168,17 +173,32 @@ class StackConfig(ModelConfig):
     # rotary GQA over the layer's own pages, here with RMS-normalised
     # queries and keys (`qk_norm`); RMSNorm; two dense layers and then
     # experts of their own width (`d_ff_expert`), chosen by sigmoid scores
-    # plus a per-expert bias (`router="sigmoid"`). A rule below belongs to
-    # the kind it names, not to a stack; another family gets a field when
-    # it comes.
+    # plus a per-expert bias (`router="sigmoid"`). The third: "gdn": the
+    # gated delta rule, linear attention whose state is a [key, value]
+    # matrix per head and sequence (`gdn_heads` of `gdn_key_dim` x
+    # `gdn_value_dim`), after a short convolution of `conv_taps` taps over
+    # its q, k and v channels, beside "attn" with no positions and queries
+    # and keys normalised over the WHOLE projected vector
+    # (`qk_norm_whole`); its norms follow their sublayers (`post_norm`). A
+    # rule below belongs to the kind it names, not to a stack; another
+    # family gets a field when it comes.
     layer_kinds: Tuple[str, ...] = ()
     window: int = 0
     ssm_inner: int = 0        # mamba / gmu inner width
     ssm_state: int = 16
     ssm_conv: int = 4
     ssm_dt_rank: int = 0
-    conv_taps: int = 3        # the "conv" kind's kernel length
+    conv_taps: int = 3        # the "conv" and "gdn" kinds' kernel length
     qk_norm: bool = False     # "attn": RMSNorm each head of q and k
+    qk_norm_whole: bool = False  # ... or all of q's (k's) heads as one
+    # False: x + Mix(norm(x)), x + FFN(norm(x)); True: the norm follows the
+    # sublayer, x + norm(Mix(x)), x + norm(FFN(x))
+    post_norm: bool = False
+    gdn_heads: int = 0        # "gdn": heads, and each one's state [dk, dv]
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
+    # "gdn": beta = 2 sigmoid(.), so a step's eigenvalue 1 - beta may be < 0
+    gdn_neg_eigval: bool = False
     n_dense_layers: int = 0   # leading layers whose second half is dense
     d_ff_expert: int = 0      # an expert's width (0: d_ff)
     # "softmax": top k of the logits, softmax over the chosen. "sigmoid":
@@ -209,9 +229,15 @@ class StackConfig(ModelConfig):
             raise ValueError("differential attention pairs heads: n_heads "
                              "and kv_heads even, q pairs a multiple of kv "
                              "pairs")
+        if "gdn" in kinds and not (self.gdn_heads and self.gdn_key_dim
+                                   and self.gdn_value_dim):
+            raise ValueError("gdn layers need `gdn_heads`, `gdn_key_dim` "
+                             "and `gdn_value_dim`")
         # ONE pool and one array of conv tails: their rows are one shape
         for a, b, what in (("attn", "full", "layers that cache keys"),
-                           ("conv", "mamba", "convolution tails")):
+                           ("conv", "mamba", "convolution tails"),
+                           ("conv", "gdn", "convolution tails"),
+                           ("mamba", "gdn", "convolution tails")):
             if a in kinds and b in kinds:
                 raise ValueError(f"{a!r} and {b!r} layers in one stack: "
                                  f"two shapes of {what}")
@@ -223,8 +249,10 @@ class StackConfig(ModelConfig):
     @property
     def has_state(self) -> bool:
         """Some layer keeps per-sequence state that is not keys and values
-        in THE pool: conv tails, scan state, a window layer's ring."""
-        return bool({"mamba", "conv", "window"} & set(self.layer_kinds))
+        in THE pool: conv tails, scan state, a delta-rule state matrix, a
+        window layer's ring."""
+        return bool({"mamba", "conv", "window", "gdn"}
+                    & set(self.layer_kinds))
 
     @property
     def expert_ff(self) -> int:
@@ -233,11 +261,22 @@ class StackConfig(ModelConfig):
     @property
     def conv_tail(self) -> Tuple[int, int, int]:
         """(layers, rows, width) of the convolution tails a sequence keeps:
-        the last taps - 1 inputs of each mamba or conv layer's
-        convolution."""
+        the last taps - 1 inputs of each mamba, conv or gdn layer's
+        convolution (a gdn layer's runs over its q, k and v channels)."""
         if "conv" in self.layer_kinds:
             return self.count("conv"), self.conv_taps - 1, self.d_model
+        if "gdn" in self.layer_kinds:
+            _, H, dk, dv = self.gdn_dims
+            return self.count("gdn"), self.conv_taps - 1, H * (2 * dk + dv)
         return self.count("mamba"), self.ssm_conv - 1, self.ssm_inner
+
+    @property
+    def gdn_dims(self) -> Tuple[int, int, int, int]:
+        """(layers, heads, key size, value size) of the delta-rule state a
+        sequence keeps: a float32 [key, value] matrix a head and gdn
+        layer (ops/gdn.py `state_shape` lays them out)."""
+        return (self.count("gdn"), self.gdn_heads, self.gdn_key_dim,
+                self.gdn_value_dim)
 
     @property
     def pool_heads(self) -> int:
@@ -262,8 +301,15 @@ class StackConfig(ModelConfig):
         # q and o with biases, four lambda vectors, the pair norm's weight
         q_o = 2 * D * H * hd + H * hd + D + 4 * hd + 2 * hd
         if kind == "attn":
-            return (2 * D * H * hd + 2 * D * KVH * hd
-                    + (2 * hd if self.qk_norm else 0))
+            qk = ((H + KVH) * hd if self.qk_norm_whole
+                  else 2 * hd if self.qk_norm else 0)
+            return 2 * D * H * hd + 2 * D * KVH * hd + qk
+        if kind == "gdn":
+            _, Hg, dk, dv = self.gdn_dims
+            # q, k, v in one projection and their taps; the gate and the
+            # out-projection; a and b; A_log, dt_bias, the output norm
+            return ((D + self.conv_taps) * Hg * (2 * dk + dv)
+                    + 2 * D * Hg * dv + 2 * D * Hg + 2 * Hg + dv)
         if kind == "conv":
             return D * 3 * D + self.conv_taps * D + D * D
         if kind == "mamba":
@@ -466,6 +512,41 @@ register(StackConfig(
     num_experts=8, num_selected_experts=2, capacity_factor=4.0,
     layer_kinds=_lfm2_kinds(10), conv_taps=3, qk_norm=True,
     n_dense_layers=2, d_ff_expert=32, router="sigmoid",
+))
+
+def _olmo_hybrid_kinds(n_layers: int) -> Tuple[str, ...]:
+    """Three gated delta-rule layers, then one of full attention."""
+    return tuple("attn" if l % 4 == 3 else "gdn" for l in range(n_layers))
+
+
+register(StackConfig(
+    name="olmo-hybrid-7b",
+    # allenai/Olmo-Hybrid-7B: 7.4 B parameters; 24 gated delta-rule layers
+    # (30 heads, a 96 x 192 state matrix each) and 8 of full attention with
+    # no positional encoding (30 heads of 128, no GQA), norms after their
+    # sublayers, untied head
+    vocab_size=100352,
+    d_model=3840, n_layers=32, n_heads=30, n_kv_heads=30, head_dim=128,
+    d_ff=11008, max_seq_len=65536,
+    norm="rmsnorm", activation="swiglu", positional="none",
+    tie_embeddings=False, norm_eps=1e-6,
+    layer_kinds=_olmo_hybrid_kinds(32), conv_taps=4, qk_norm=True,
+    qk_norm_whole=True, post_norm=True, gdn_heads=30, gdn_key_dim=96,
+    gdn_value_dim=192, gdn_neg_eigval=True,
+))
+
+register(StackConfig(
+    name="tiny-olmo-hybrid",
+    # the same stack's shape at toy widths: two gdn / gdn / gdn / attn
+    # periods
+    vocab_size=512,
+    d_model=64, n_layers=8, n_heads=4, n_kv_heads=4, head_dim=16, d_ff=128,
+    max_seq_len=128, dtype="float32", remat=False,
+    norm="rmsnorm", activation="swiglu", positional="none",
+    tie_embeddings=False, norm_eps=1e-6,
+    layer_kinds=_olmo_hybrid_kinds(8), conv_taps=4, qk_norm=True,
+    qk_norm_whole=True, post_norm=True, gdn_heads=4, gdn_key_dim=8,
+    gdn_value_dim=16, gdn_neg_eigval=True,
 ))
 
 register(StackConfig(

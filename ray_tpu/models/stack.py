@@ -1,7 +1,8 @@
 """The layers of every model the serving engine runs, as a stack of layer
 kinds (`cfg.layer_kinds`): every layer is `x + Mix(norm(x))` and then its
 second half, `x + FFN(norm(x))` or the experts (`cfg.second_halves`: a
-stack may lead with dense layers), and Mix is one of
+stack may lead with dense layers); where `cfg.post_norm` the norms follow
+their sublayers, `x + norm(Mix(x))` and `x + norm(FFN(x))`. Mix is one of
 
   attn    the one-block models' (a plain ModelConfig: every layer): rotary
           or learned-position GQA over the layer's own pages, queries and
@@ -17,6 +18,11 @@ stack may lead with dense layers), and Mix is one of
           the cross layers after it read
   gmu     gated memory unit: gates the last mamba layer's scan output
   cross   queries only, over the last full layer's keys and values
+  gdn     gated delta rule: q, k, v through a short convolution and SiLU,
+          q and k L2-normalised, a [key, value] state matrix per head and
+          sequence decayed and corrected a token at a time (ops/gdn.py),
+          the output RMS-normalised per head and gated; conv tail and
+          state matrix per sequence
 
 Each mixer is written ONCE, over a small state interface (a *mode*), and
 `forward`, the engine's bucket prefill, its chunk program, its decode
@@ -27,7 +33,9 @@ mode:
                kept for the engine (bucket prefill), or one chunk of one
                sequence from carried state with its keys in pages
   Decode(...)  one token for every slot: state per slot, window keys in a
-               ring of pages per slot, the other keys in the pool
+               ring of pages per slot, the other keys in the pool; a slot
+               whose page table starts at page 0 (the engine's trash page)
+               is empty, and its delta-rule state is not touched
   Verify(...)  Decode for S tokens a slot (speculation: pages only)
 
 What a mode reads and writes travels in `carry`, a dict threaded through
@@ -65,6 +73,7 @@ from ..ops import (
     pool_shape,
     write_then_attend,
 )
+from ..ops.gdn import gdn_chunk, gdn_step, state_shape
 from ..ops.ssm import ssm_scan, ssm_step
 from .config import ModelConfig
 from .transformer import (
@@ -79,7 +88,7 @@ from .transformer import (
 Params = Dict[str, Any]
 _F32 = jnp.float32
 # kinds that own rows of state arrays or pools, counted as layers go by
-_COUNTED = ("attn", "conv", "mamba", "window", "full")
+_COUNTED = ("attn", "conv", "mamba", "window", "full", "gdn")
 
 
 # ---------------------------------------------------------------------------
@@ -109,8 +118,17 @@ def layer_shapes(cfg: ModelConfig, kind: str,
     if kind == "attn":
         out.update(wq=((D, H, hd), "w"), wk=((D, KVH, hd), "w"),
                    wv=((D, KVH, hd), "w"), wo=((H, hd, D), "out"))
-        if cfg.qk_norm:
+        if cfg.qk_norm_whole:
+            out.update(q_norm=((H, hd), "one"), k_norm=((KVH, hd), "one"))
+        elif cfg.qk_norm:
             out.update(q_norm=((hd,), "one"), k_norm=((hd,), "one"))
+    elif kind == "gdn":
+        _, Hg, dk, dv = cfg.gdn_dims
+        out.update(d_in=((D, Hg * (2 * dk + dv)), "w"),
+                   d_conv=((cfg.conv_taps, Hg * (2 * dk + dv)), "w"),
+                   d_ab=((D, 2 * Hg), "w"), d_A_log=((Hg,), "zero"),
+                   d_dt_b=((Hg,), "zero"), d_norm=((dv,), "one"),
+                   d_gate=((D, Hg * dv), "w"), d_out=((Hg * dv, D), "out"))
     elif kind == "conv":
         out.update(c_in=((D, 3 * D), "w"), c_conv=((cfg.conv_taps, D), "w"),
                    c_out=((D, D), "out"))
@@ -165,6 +183,10 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
                                       _F32) * 0.02,
            "layers": segments,
            "final_norm": jnp.ones((cfg.d_model,), _F32)}
+    if not cfg.tie_embeddings:
+        out["lm_head"] = jax.random.normal(
+            jax.random.fold_in(k_emb, 1), (cfg.d_model, cfg.vocab_size),
+            _F32) * 0.02
     if cfg.norm == "layernorm":
         out["final_norm_b"] = jnp.zeros((cfg.d_model,), _F32)
     return out
@@ -186,9 +208,11 @@ def ring_pages(cfg: ModelConfig, page_size: int) -> int:
 
 def new_request_state(cfg: ModelConfig, batch: int, dtype) -> Params:
     """State a prefill hands over beside keys and values: conv tails
-    [M,B,K-1,Di] where there are mamba or conv layers (`cfg.conv_tail`),
-    scan state [M,B,N,Di] (float32) where there are mamba layers, and the
-    last `window` keys and values of every window layer [W,B,window,KVH,D]
+    [M,B,K-1,Di] where there are mamba, conv or gdn layers
+    (`cfg.conv_tail`), scan state [M,B,N,Di] (float32) where there are
+    mamba layers, the delta-rule state matrices [G,B,dk,H*dv] (float32;
+    ops/gdn.py lays them out) where there are gdn layers, and the last
+    `window` keys and values of every window layer [W,B,window,KVH,D]
     where there are those. Zeros are a sequence's start; the one-block
     models have none (the empty tree)."""
     M, NW = cfg.count("mamba"), cfg.count("window")
@@ -199,6 +223,10 @@ def new_request_state(cfg: ModelConfig, batch: int, dtype) -> Params:
     if M:
         out.update(
             ssm=jnp.zeros((M, batch, cfg.ssm_state, cfg.ssm_inner), _F32))
+    if cfg.gdn_dims[0]:
+        layers, heads, dk, dv = cfg.gdn_dims
+        out.update(
+            gdn=jnp.zeros(state_shape(layers, batch, heads, dk, dv), _F32))
     if NW:
         kv = (NW, batch, cfg.window, cfg.pool_heads, cfg.pool_dim)
         out.update(wk=jnp.zeros(kv, dtype), wv=jnp.zeros(kv, dtype))
@@ -224,11 +252,11 @@ def new_engine_state(cfg: ModelConfig, batch: int, page_size: int,
 def install_state(state: Params, rs: Params, slot, length,
                   cfg: ModelConfig, page_size: int) -> Params:
     """A prefilled sequence of `length` tokens takes decode slot `slot`:
-    its conv tails and scan state overwrite the slot's (whatever the last
-    occupant left), and its last `window` keys go to the slot's ring, each
-    at the place its position has there."""
+    its conv tails, scan state and delta-rule state overwrite the slot's
+    (whatever the last occupant left), and its last `window` keys go to the
+    slot's ring, each at the place its position has there."""
     out = dict(state)
-    for name in ("conv", "ssm"):
+    for name in ("conv", "ssm", "gdn"):
         if name in state:
             out[name] = jax.lax.dynamic_update_slice_in_dim(
                 state[name], rs[name].astype(state[name].dtype), slot, 1)
@@ -366,6 +394,14 @@ class Seq(_Mode):
             carry = {**carry, "ssm": carry["ssm"].at[mi].set(s1)}
         return y, carry
 
+    def delta(self, carry, gi, q, k, v, g, beta):
+        s0 = (carry["gdn"][gi] if self.chunk is not None else jnp.zeros(
+            state_shape(1, v.shape[0], *self.cfg.gdn_dims[1:])[1:], _F32))
+        o, s1 = gdn_chunk(q, k, v, g, beta, s0)
+        if self.keep:
+            carry = {**carry, "gdn": carry["gdn"].at[gi].set(s1)}
+        return o, carry
+
     # -- attention
     def attend_window(self, carry, wi, q, k, v, scale):
         cfg = self.cfg
@@ -448,6 +484,8 @@ class Decode(_Mode):
         # where this token's keys go in the pool
         self.page = page_tables[jnp.arange(B), positions // page_size]
         self.slot = positions % page_size
+        # a slot that holds no sequence has the trash page for a table
+        self.live = page_tables[:, 0] > 0
         self.ring = ring_pages(cfg, page_size) if cfg.count("window") else 1
         self.ring_table = (1 + jnp.arange(B)[:, None] * self.ring
                            + jnp.arange(self.ring)[None, :]).astype(jnp.int32)
@@ -474,6 +512,11 @@ class Decode(_Mode):
         y, ssm = ssm_step(carry["ssm"], mi, u[:, 0], dt[:, 0], A, Bm[:, 0],
                           Cm[:, 0], D)
         return y[:, None], {**carry, "ssm": ssm}
+
+    def delta(self, carry, gi, q, k, v, g, beta):
+        o, state = gdn_step(carry["gdn"], gi, q[:, 0], k[:, 0], v[:, 0],
+                            g[:, 0], beta[:, 0], self.live)
+        return o[:, None], {**carry, "gdn": state}
 
     def attend_window(self, carry, wi, q, k, v, scale):
         page = jnp.take_along_axis(
@@ -592,6 +635,39 @@ def _short_conv(h, lp, cfg, ci, mode, carry):
                       lp["c_out"].astype(dtype)), carry
 
 
+def _gdn(h, lp, cfg, gi, mode, carry):
+    """The gated delta rule: the state is the mode's conv tail (over the
+    q, k and v channels side by side) and its delta-rule state matrix."""
+    dtype = h.dtype
+    B, T, _ = h.shape
+    _, H, dk, dv = cfg.gdn_dims
+    qkv = jnp.einsum("btd,de->bte", h, lp["d_in"].astype(dtype))
+    ext, carry = mode.conv(carry, gi, qkv)
+    qkv = jax.nn.silu(_taps(ext, lp["d_conv"], T))
+
+    def unit(x):  # L2 over a head's lanes
+        return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+
+    q, k, v = (x.reshape(B, T, H, -1)
+               for x in jnp.split(qkv, [H * dk, 2 * H * dk], axis=-1))
+    q, k = unit(q) * dk ** -0.5, unit(k)
+    ab = jnp.einsum("btd,de->bte", h, lp["d_ab"].astype(dtype),
+                    preferred_element_type=_F32)
+    g = -jnp.exp(lp["d_A_log"].astype(_F32)) * jax.nn.softplus(
+        ab[..., :H] + lp["d_dt_b"].astype(_F32))
+    beta = jax.nn.sigmoid(ab[..., H:]) * (2.0 if cfg.gdn_neg_eigval else 1.0)
+    valid = mode.valid(T)
+    if valid is not None:  # padding leaves the state alone
+        g, beta = jnp.where(valid, g, 0.0), jnp.where(valid, beta, 0.0)
+    o, carry = mode.delta(carry, gi, q, k, v, g, beta)
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + cfg.norm_eps)
+    gate = jnp.einsum("btd,de->bte", h, lp["d_gate"].astype(dtype))
+    y = (o * lp["d_norm"].astype(_F32)).reshape(B, T, H * dv) \
+        * jax.nn.silu(gate.astype(_F32))
+    return jnp.einsum("bte,ed->btd", y.astype(dtype),
+                      lp["d_out"].astype(dtype)), carry
+
+
 def _gmu(h, lp, cfg, carry):
     dtype = h.dtype
     g = jnp.einsum("btd,de->bte", h, lp["g_in"].astype(dtype))
@@ -649,9 +725,11 @@ def _layer(x, lp, cfg, kind, half, layer, idx, mode, carry):
     # the scopes are what a profile's readers key on: the mixer's kind
     # ("attn" as in the training block), then "ffn" or "moe"
     with jax.named_scope(kind):
-        h = _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
+        h = x if cfg.post_norm else _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
         if kind == "attn":
             o, carry = _attn(h, lp, cfg, idx, mode, carry)
+        elif kind == "gdn":
+            o, carry = _gdn(h, lp, cfg, idx, mode, carry)
         elif kind == "conv":
             o, carry = _short_conv(h, lp, cfg, idx, mode, carry)
         elif kind == "mamba":
@@ -660,6 +738,8 @@ def _layer(x, lp, cfg, kind, half, layer, idx, mode, carry):
             o = _gmu(h, lp, cfg, carry)
         else:
             o, carry = _attention(h, lp, cfg, kind, layer, idx, mode, carry)
+        if cfg.post_norm:
+            o = _norm(o, lp["ln1"], lp.get("ln1_b"), cfg)
         x = x + o
     return _ffn_half(x, lp, cfg, moe=half == "moe")[0], carry
 
@@ -669,7 +749,7 @@ def run_stack(layers, x, cfg: ModelConfig, mode, carry):
     one entry a segment of `cfg.segments()` (a tuple with one stacked dict
     per layer of the period), or the one-block models' stacked dict, their
     one segment. A segment of r > 1 periods is one `lax.scan`; which attn,
-    conv, mamba, window or full layer a layer is (its row in the state arrays
+    conv, mamba, window, full or gdn layer a layer is (its row in the state arrays
     and pools) is counted from the layers before it."""
     if isinstance(layers, dict):
         layers = [(layers,)]

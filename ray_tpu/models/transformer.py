@@ -183,16 +183,20 @@ def _flash(q, k, v, mesh=None, **kernel):
 
 
 def _head_norm(x, w, eps):
-    """RMSNorm over each head's own lanes, x [B,T,heads,hd], w [hd] shared
-    by the heads; float32 inside (XLA fuses it into the rotary turn)."""
+    """RMSNorm of x [B,T,heads,hd] over the axes its weight has: each
+    head's own lanes (w [hd], shared by the heads) or the whole projected
+    vector (w [heads,hd]); float32 inside (XLA fuses it into the rotary
+    turn)."""
     xf = x.astype(jnp.float32)
-    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    over = tuple(range(-w.ndim, 0))
+    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=over, keepdims=True) + eps)
     return (xf * w.astype(jnp.float32)).astype(x.dtype)
 
 
 def _qkv(x, lp, cfg, rope_tables, positions):
     """x [B,T,D] -> q [B,T,H,hd], k, v [B,T,KVH,hd], q and k normalised
-    per head where the model says (`cfg.qk_norm`) and turned to `positions`
+    where the model says (`cfg.qk_norm`: per head, or over the whole vector
+    where the weights are [heads,hd]) and turned to `positions`
     [B,T] (None: 0..T-1) where it is rotary. Shared by the training block
     below and the serve path's (models/stack.py)."""
     dtype = x.dtype
@@ -469,16 +473,19 @@ def _moe_ffn_gather(x, lp, cfg):
 
 def _ffn_half(x, lp, cfg, moe=None):
     """A layer's second half, x + FFN(norm(x)) or the experts in its place
-    (`moe`; None: what the whole model has) -> (x, aux loss). Shared by
-    the training block below and every layer of the serve path
-    (models/stack.py), which says for each layer which it is."""
+    (`moe`; None: what the whole model has), the norm AFTER the sublayer
+    where the model says (`cfg.post_norm`: x + norm(FFN(x))) -> (x, aux
+    loss). Shared by the training block below and every layer of the serve
+    path (models/stack.py), which says for each layer which it is."""
     moe = cfg.is_moe if moe is None else moe
     with jax.named_scope("moe" if moe else "ffn"):
-        h = _norm(x, lp["ln2"], lp.get("ln2_b"), cfg)
+        h = x if cfg.post_norm else _norm(x, lp["ln2"], lp.get("ln2_b"), cfg)
         if moe:
             y, aux = _moe_ffn(h, lp, cfg)
         else:
             y, aux = _dense_ffn(h, lp, cfg), jnp.zeros((), jnp.float32)
+        if cfg.post_norm:
+            y = _norm(y, lp["ln2"], lp.get("ln2_b"), cfg)
         return x + y, aux
 
 

@@ -14,6 +14,7 @@ CPU mesh. Set RAY_TPU_FORCE_PALLAS=0/1 to override globally.
 """
 
 from .attention import flash_attention, mha_reference  # noqa: F401
+from .gdn import gdn_chunk, gdn_step  # noqa: F401
 from .norm import layer_norm, rms_norm, rms_norm_reference  # noqa: F401
 from .rope import apply_rope, rope_frequencies  # noqa: F401
 from .paged_attention import (  # noqa: F401

@@ -199,18 +199,31 @@ def _paged_reference(q, k_pages, v_pages, page_table, lengths, layer, scale,
     return o.reshape(B, H, D).astype(q.dtype)
 
 
+def _per_head(a, b, heads, contract):
+    """a [heads, ..] and b [.., heads * D]: row c of a against kv head c's
+    lanes of b, one small product a head, the results stacked."""
+    D = b.shape[-1] // heads
+    return jnp.concatenate([
+        jax.lax.dot_general(a[c:c + 1], b[:, c * D:(c + 1) * D],
+                            (((1,), (contract,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+        for c in range(heads)], axis=0)
+
+
 def _flash_page_loop(
     q2d, n_pages, page_id_fn, mask_fn, layer, c,
     k_hbm, v_hbm, k_buf, v_buf, acc_ref, m_ref, l_ref, sem_ref,
-    *, page_size, scale,
+    *, page_size, scale, per_head=0,
 ):
     """The shared double-buffered page-DMA flash loop: stream this layer's
     pages HBM->VMEM two-deep while the MXU runs the online-softmax update
     for q2d [rows, W]. `c` None: whole rows of the pool (W = KVH*D, the
     decode kernel's widened queries); else kv head c's lanes of every row
-    (W = D). Kernels differ besides only in how a loop index maps to a
-    page id (page_id_fn) and in the validity mask (mask_fn(i) ->
-    [rows, page_size] bool); everything else — slot rotation, the
+    (W = D). `per_head` = H > 0 (decode, one query head a kv head): q2d is
+    the plain [H, D] and row c meets kv head c's lanes of a page alone, H
+    products of one row each. Kernels differ besides only in how a loop
+    index maps to a page id (page_id_fn) and in the validity mask
+    (mask_fn(i) -> [rows, page_size] bool); everything else — slot rotation, the
     exp-underflow guard, the l==0 epilogue division — is one
     implementation serving decode, chunk prefill and verify."""
 
@@ -250,11 +263,14 @@ def _flash_page_loop(
             kw.wait()
             vw.wait()
 
-            k = k_buf[slot].astype(jnp.float32)  # [ps, D]
-            s = jax.lax.dot_general(
-                q2d, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # [rows, ps]
+            k = k_buf[slot].astype(jnp.float32)  # [ps, W]
+            if per_head:
+                s = _per_head(q2d, k, per_head, 1) * scale
+            else:
+                s = jax.lax.dot_general(
+                    q2d, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) * scale  # [rows, ps]
             s = jnp.where(mask_fn(i), s, _NEG_INF)
 
             m_prev, l_prev = m_ref[...], l_ref[...]
@@ -265,11 +281,14 @@ def _flash_page_loop(
             p = jnp.where(m_next[:, :1] > _NEG_INF / 2, p, 0.0)
             l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
             m_ref[...] = m_next
-            pv = jax.lax.dot_general(
-                p, v_buf[slot].astype(jnp.float32),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+            v = v_buf[slot].astype(jnp.float32)
+            if per_head:
+                pv = _per_head(p, v, per_head, 0)
+            else:
+                pv = jax.lax.dot_general(
+                    p, v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
             acc_ref[...] = acc_ref[...] * alpha[:, :1] + pv
             return 0
 
@@ -291,9 +310,13 @@ def _paged_kernel(
     k_buf, v_buf, acc_ref, m_ref, l_ref, sem_ref,
     *, page_size, pages_per_seq, scale, batch, kv_heads, window=None,
 ):
-    """One sequence's decode attention, every head at once: the query
-    heads [H, D] are widened to the pool's row, so a page is ONE DMA and
-    one pair of products whatever the number of kv heads."""
+    """One sequence's decode attention, every head at once: a page is ONE
+    DMA whatever the number of kv heads. Where several query heads share a
+    kv head they are widened to the pool's row and a page is one pair of
+    products; where each has its own (group 1, where the widened product
+    would be KVH x padding) query row c meets kv head c's 128-lane slice
+    of the page (PERF.md section 6, PR 34: 19% faster at 30 kv heads and
+    32 to 64 sequences of 700 tokens)."""
     b = pl.program_id(0)
     H = q_ref.shape[1]
     length = len_ref[b]
@@ -321,13 +344,21 @@ def _paged_kernel(
             return pt_ref[b * pages_per_seq
                           + jax.lax.rem(page0 + i, pages_per_seq)]
 
+    q = q_ref[0].astype(jnp.float32)
+    per_head = H if _sliced(H, kv_heads) else 0
     out = _flash_page_loop(
-        _wide(q_ref[0].astype(jnp.float32), kv_heads), n_pages,
+        q if per_head else _wide(q, kv_heads), n_pages,
         page_id, mask, layer, None,
         k_hbm, v_hbm, k_buf, v_buf, acc_ref, m_ref, l_ref, sem_ref,
-        page_size=page_size, scale=scale,
+        page_size=page_size, scale=scale, per_head=per_head,
     )
-    o_ref[0] = _own(out, kv_heads).astype(o_ref.dtype)
+    o_ref[0] = (out if per_head else _own(out, kv_heads)).astype(o_ref.dtype)
+
+
+def _sliced(heads: int, kv_heads: int) -> bool:
+    """Whether the decode kernel takes a page head by head: one query head
+    a kv head, and more than one of them (shapes the kernel can see)."""
+    return heads == kv_heads > 1
 
 
 def _paged_pallas(q, k_pages, v_pages, page_table, lengths_layer, scale,
@@ -349,7 +380,7 @@ def _paged_pallas(q, k_pages, v_pages, page_table, lengths_layer, scale,
         scratch_shapes=[
             pltpu.VMEM((2, page_size, row), k_pages.dtype),
             pltpu.VMEM((2, page_size, row), v_pages.dtype),
-            pltpu.VMEM((H, row), jnp.float32),
+            pltpu.VMEM((H, D if _sliced(H, row // D) else row), jnp.float32),
             pltpu.VMEM((H, _LANES), jnp.float32),
             pltpu.VMEM((H, _LANES), jnp.float32),
             pltpu.SemaphoreType.DMA((2, 2)),

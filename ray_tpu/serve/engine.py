@@ -168,6 +168,15 @@ _m_state_slots = Counter(
     "serve_state_slots_installed",
     "Decode slots whose recurrent and window state a prefilled sequence "
     "overwrote at install (the slot's reset).")
+_m_state_slot_steps = Counter(
+    "serve_recurrent_state_slot_steps",
+    "Decode slots x steps dispatched by a model whose layers keep recurrent "
+    "state per slot (scan state, delta-rule state matrices), by state "
+    "(live: the slot's state belongs to a sequence; held: every slot the "
+    "engine holds state for, max_batch_size): live over held is the share "
+    "of that state a step had to touch.")
+_state_live = _m_state_slot_steps.labels(state="live")
+_state_held = _m_state_slot_steps.labels(state="held")
 _m_moe_rows_computed = Counter(
     "serve_moe_rows_computed",
     "Rows the expert products of the dispatched programs computed, over "
@@ -796,16 +805,18 @@ class InferenceEngine:
         if scfg is not None and scfg.enabled:
             raise ValueError(
                 f"{name!r}: speculative decoding rewinds rejected drafts by "
-                "position alone; recurrent and window state cannot be "
+                "position alone; recurrent state (conv tails, scan state, "
+                "delta-rule state matrices) and window rings cannot be "
                 "rewound that way. Serve it with speculation off")
 
     def _refuse_kv_transfer(self, what: str) -> None:
         if self.cfg.is_stack:
             raise ValueError(
                 f"{what}: {self.cfg.name!r} keeps state beside its pages "
-                "(conv tails, scan state, window rings) that the KV wire "
-                "does not carry; disaggregated roles and KV export/import "
-                "are refused for it")
+                "(conv tails, scan state, delta-rule state matrices, "
+                "window rings) that the KV wire does not carry; "
+                "disaggregated roles and KV export/import are refused "
+                "for it")
 
     def abstract_pool(self, sharding=None) -> jax.ShapeDtypeStruct:
         """k_pages / v_pages as this engine's programs take them, from its
@@ -2265,6 +2276,9 @@ class InferenceEngine:
     def _count_slot_steps(self, n_active: int, steps: int) -> None:
         _slot_active.inc(n_active * steps)
         _slot_empty.inc((self.ecfg.max_batch_size - n_active) * steps)
+        if "ssm" in self.state or "gdn" in self.state:
+            _state_live.inc(n_active * steps)
+            _state_held.inc(self.ecfg.max_batch_size * steps)
 
     def _count_pages(self) -> None:
         """Once an iteration: pages held by slots, chunked prompts and

@@ -132,7 +132,8 @@ def test_a_names_file_in_the_tree_adds_and_removes_nothing():
     "ssm_scan_roofline", "ssm_step_device_share",
     "hybrid_decode_attn_roofline", "window_pages_held_share",
     "moe_rows_padding_factor", "short_conv_device_share",
-    "moe_ffn_device_share.tpot"])
+    "moe_ffn_device_share.tpot", "gdn_step_roofline", "gdn_step_device_share",
+    "gdn_chunk_roofline", "recurrent_state_live_share"])
 def test_a_new_reader_returns_nothing_where_the_program_has_nothing(metric):
     """On the parent (no such kernel, no such counter) a new reader reads
     nothing and does not raise: the result line leaves its metric out."""
@@ -237,4 +238,176 @@ def test_the_padding_factor_reads_the_two_counters():
     after = {("serve_moe_rows_routed", ()): 110.0,
              ("serve_moe_rows_computed", ()): 900.0}
     assert read({"counters": (before, after)}) == 8.0
+    assert read({"counters": None}) is None
+
+
+# -- the Olmo Hybrid family (PR 34) ------------------------------------------
+
+OLMO = "olmo-hybrid-7b"
+OLMO_ROW = {  # the published config's numbers, key for key, but the depth
+    "model_type": "olmo_hybrid", "vocab_size": 100352, "hidden_size": 3840,
+    "intermediate_size": 11008, "num_attention_heads": 30,
+    "num_key_value_heads": 30, "hidden_act": "silu",
+    "max_position_embeddings": 65536, "attention_bias": False,
+    "rms_norm_eps": 1e-06, "tie_word_embeddings": False,
+    "linear_num_key_heads": 30, "linear_num_value_heads": 30,
+    "linear_key_head_dim": 96, "linear_value_head_dim": 192,
+    "linear_conv_kernel_dim": 4, "linear_allow_neg_eigval": True,
+    "rope_parameters": {"rope_theta": None}}
+OLMO_TYPES = ["linear_attention"] * 3 + ["full_attention"]
+
+
+def test_olmo_hybrid_configuration_is_the_published_one_cut_in_depth_alone():
+    spec = common.load_json("configs", OLMO + ".json")
+    assert {k: spec[k] for k in OLMO_ROW} == OLMO_ROW
+    assert spec["num_hidden_layers"] == 16
+    assert spec["layer_types"] == OLMO_TYPES * 4
+    assert spec["published"]["layer_types"] == OLMO_TYPES * 8
+    assert spec["published"]["num_hidden_layers"] == 32
+    assert sorted(spec["reduced"]) == ["layer_types", "num_hidden_layers"]
+    assert {"norm_placement", "qk_norm", "positions", "convolution", "decay",
+            "decay_init", "weights"} <= set(spec["assumed"])
+    entry = next(c for c in common.load_manifest()["configs"]
+                 if c["name"] == OLMO)
+    assert sorted(entry["reduced"]) == sorted(spec["reduced"])
+    assert sorted(entry) == ["file", "name", "reduced", "source", "why"]
+    cfg = common.family(spec).model_config(spec)
+    # four whole periods, ONE scan
+    assert cfg.segments() == ((0, ("gdn", "gdn", "gdn", "attn"), 4),)
+    assert round(cfg.param_count() / 1e7) == 410        # the issue's 4.10 B
+    assert cfg.cache_dims == (4, 30, 128)
+    assert cfg.conv_tail == (12, 3, 11520) and cfg.gdn_dims == (12, 30, 96, 192)
+    assert (cfg.positional, cfg.post_norm, cfg.qk_norm_whole,
+            cfg.gdn_neg_eigval, cfg.tie_embeddings) == (
+                "none", True, True, True, False)
+
+
+def test_olmo_hybrid_readers_reach_the_counts_through_the_family():
+    spec = common.load_json("configs", OLMO + ".json")
+    family = common.family(spec)
+    assert reference_file(family) == spec["reference"]
+    assert set(family.modes) == {"int8", "fp8", "state-bf16"}
+    assert set(family.work) == {"paged_decode", "gdn_chunk", "gdn_step"}
+    # 4 of the 16 layers attend and hold a cache, 12 run the recurrence
+    assert family.calls_per_pass(spec, "paged_decode") == 4
+    assert family.calls_per_pass(spec, "gdn_step") == 12
+    assert family.calls_per_pass(spec, "gdn_chunk") == 12
+    # a cached token is 30 KV heads of 128 bfloat16, keys and values
+    work = family.work["paged_decode"](spec, 1000)
+    assert work["bytes"] == 2 * 30 * 128 * 2 * 1000 == 15360 * 1000
+    assert work["flops"] == 2 * 2 * 30 * 128 * 1000
+    cell = common.load_cell(OLMO + ".serve-reason")
+    assert cell["engine"] == {"max_seq_len": 4096, "max_batch_size": 64,
+                              "max_pages": 3073}
+    assert cell["traffic_name"] == "serve-reason"
+    assert {m["name"] for m in cell["per_layer"]} == {
+        "engine_host_ms_per_step", "engine_loop_host_ms_per_iter",
+        "engine_idle_gap_attributed_share", "pool_copy_device_share",
+        "decode_device_ms_per_step", "prefill_device_ms_per_ktok",
+        "paged_decode_roofline", "gdn_step_roofline", "gdn_step_device_share",
+        "gdn_chunk_roofline", "recurrent_state_live_share"}
+    assert [m["name"] for m in cell["end_to_end"]] == ["tpot_mean_ms", "setup_s"]
+    assert all(m["moves"] == "tpot_mean_ms" for m in cell["per_layer"])
+
+
+def test_the_delta_rules_work_is_a_hand_count_at_one_small_shape():
+    """2 heads of a [4, 8] state: per head, token and state element one
+    product for the decay and a product and a sum each for S'^T k, the
+    correction and S^T q; q, k, v, o, g, beta in float32; the state once a
+    call (prefill) or once a LIVE slot (decode)."""
+    spec = {"linear_num_key_heads": 2, "linear_num_value_heads": 2,
+            "linear_key_head_dim": 4, "linear_value_head_dim": 8}
+    family = common.family(common.load_json("configs", OLMO + ".json"))
+    state = 2 * 4 * 8                       # elements
+    operands = 2 * (4 + 4 + 8 + 8 + 1 + 1)  # q, k, v, o, g, beta a token
+    chunk = family.work["gdn_chunk"](spec, 100)
+    assert chunk["flops"] == 7 * state * 100
+    assert chunk["bytes"] == 4 * (operands * 100 + 2 * state)
+    step = family.work["gdn_step"](spec, 5)
+    assert step["flops"] == 7 * state * 5
+    assert step["bytes"] == 4 * 5 * (operands + 2 * state)
+    assert family.work["gdn_step"](spec, 0) == {"flops": 0, "bytes": 0}
+    # at the published sizes a live slot's state is 2.21 MB, read and written
+    big = common.load_json("configs", OLMO + ".json")
+    assert family.work["gdn_step"](big, 1)["bytes"] == 4 * (
+        2 * 30 * 96 * 192 + 30 * (96 + 96 + 192 + 192 + 2))
+
+
+def test_olmo_hybrid_weights_are_seeded_bfloat16_and_in_the_programs_layout():
+    from benchmark import weights
+
+    spec = tiny_spec(OLMO)
+    a, b, c = (weights.make_weights(spec, s) for s in (7, 7, 2**31 + 11))
+    leaves = jax.tree.leaves(a)
+    assert all(leaf.dtype == jax.numpy.bfloat16 for leaf in leaves)
+    assert all((x == y).all() for x, y in zip(leaves, jax.tree.leaves(b)))
+    assert any((x != y).any() for x, y in zip(leaves, jax.tree.leaves(c)))
+    cfg = common.family(spec).model_config(spec)
+    assert [len(seg) for seg in a["layers"]] == [
+        len(kinds) for _, kinds, _ in cfg.segments()] == [4]
+    linear = a["layers"][0][0]
+    # the decay as its authors draw it: A in (0, 16], a step in [0.001, 0.1]
+    assert float(linear["d_A_log"].astype("float32").max()) <= 2.78
+    step = jax.nn.softplus(linear["d_dt_b"].astype("float32"))
+    assert 5e-4 < float(step.min()) and float(step.max()) < 0.11
+    assert a["lm_head"].shape == (64, 256) and "final_norm_b" not in a
+
+
+def test_the_olmo_names_file_adds_its_groups_and_removes_nothing():
+    with open(os.path.join(common.HERE, "trace_names.json")) as f:
+        base = json.load(f)["groups"]
+    merged = trace_reduce.load_names()["groups"]
+    for group, entries in base.items():
+        assert merged[group][:len(entries)] == entries
+    step = ("%gdn_step.2 = (f32[64,1,5760]{2,1,0}, f32[12,64,96,5760]{3,2,1,0}) "
+            "custom-call(s32[64]{0} %a)")
+    write = ("%fusion.9 = bf16[4,3073,16,3840]{3,2,1,0} "
+             "fusion(bf16[4,3073,16,3840]{3,2,1,0} %p, bf16[64,3840]{1,0} %k)")
+    trace = {"busy_s": 10.0, "ops": {
+        step: [2.0, 12],
+        "%gdn_chunk.1 = (f32[1,30,256,192]{3,2,1,0}) custom-call(f32[1] %a)": [1.0, 3],
+        "%paged_decode.1 = bf16[64,30,128]{2,1,0} custom-call(s32[9]{0} %a)": [3.0, 4],
+        write: [0.5, 8]},
+        "modules": {"jit_decode_span_16(1)": [7.0, 1]},
+        "module_ops": {"jit_decode_span_16(1)": [step]}}
+    assert trace_reduce.group_seconds(trace, "gdn_step") == (2.0, 12.0)
+    assert trace_reduce.group_seconds(trace, "gdn_chunk") == (1.0, 3.0)
+    assert trace_reduce.group_seconds(trace, "pool_copy") == (0.5, 8.0)
+    assert common.load_reader("gdn_step_device_share")({"trace": trace}) == 20.0
+
+
+def test_the_step_roofline_counts_live_slots_alone():
+    """12 calls in the trace, 10 slots live by the engine's own polls over
+    the traced part: the bytes are 12 x 10 live slots' state and operands,
+    whatever `max_batch_size` holds."""
+    spec = common.load_json("configs", OLMO + ".json")
+    family = common.family(spec)
+    peaks = common.peaks_for("TPU v5 lite")
+    step = ("%gdn_step.2 = (f32[64,1,5760]{2,1,0}, f32[12,64,96,5760]{3,2,1,0}) "
+            "custom-call(s32[64]{0} %a)")
+    ctx = {"spec": spec, "family": family, "peaks": peaks,
+           "trace": {"busy_s": 1.0, "ops": {step: [0.002, 12]},
+                     "modules": {}, "module_ops": {}},
+           "run": {"t0": 100.0, "traced_from_s": 1.0, "traced_to_s": 6.0,
+                   "polls": [{"t": 100.5, "active": 50},
+                             {"t": 102.0, "active": 8},
+                             {"t": 104.0, "active": 12},
+                             {"t": 107.0, "active": 60}]}}
+    got = common.load_reader("gdn_step_roofline")(ctx)
+    bytes_moved = family.work["gdn_step"](spec, 12 * 10)["bytes"]
+    assert got == pytest.approx(
+        100.0 * bytes_moved / peaks["hbm_bytes_per_s"] / 0.002)
+    assert 0 < got < 100
+    ctx["run"]["polls"] = []
+    assert common.load_reader("gdn_step_roofline")(ctx) is None
+
+
+def test_the_live_share_reads_the_counter_pair():
+    read = common.load_reader("recurrent_state_live_share")
+    name = "serve_recurrent_state_slot_steps"
+    before = {(name, (("state", "live"),)): 10.0,
+              (name, (("state", "held"),)): 64.0}
+    after = {(name, (("state", "live"),)): 170.0,
+             (name, (("state", "held"),)): 704.0}
+    assert read({"counters": (before, after)}) == 25.0
     assert read({"counters": None}) is None

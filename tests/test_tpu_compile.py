@@ -19,6 +19,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 from ray_tpu.ops import (
     flash_attention,
+    gdn_chunk,
+    gdn_step,
     paged_attention_chunk,
     paged_attention_decode,
     paged_attention_verify,
@@ -102,6 +104,16 @@ def _at_layer(op):
     return lambda *a: op(*a, layer=LAYER)
 
 
+# the gated delta rule at its published sizes: 30 heads, a [96, 192] state
+# matrix each, 64 slots of 12 layers (ops/gdn.py)
+GH, GK, GV, F32 = 30, 96, 192, jnp.float32
+
+
+def _gdn_operands(*lead):
+    return [((*lead, GH, GK), F32), ((*lead, GH, GK), F32),
+            ((*lead, GH, GV), F32), ((*lead, GH), F32), ((*lead, GH), F32)]
+
+
 # name -> (op, [(shape, dtype)], fewest tpu_custom_calls in the program)
 CASES = {
     "flash_fwd_t2048": (_flash_fwd, _qkv(2048), 1),
@@ -132,6 +144,17 @@ CASES = {
         [((256, H, D64), BF16)] + _POOL64 + [((PAGES_PER_SEQ,), I32)], 1),
     "paged_verify_span4_head64": (
         _at_layer(paged_attention_verify), _paged64(8, 4), 1),
+    # 30 kv heads of 128 in one row of 3840 lanes, one query head each
+    "paged_decode_b64_row3840": (
+        _at_layer(paged_attention_decode), _paged(64, heads=(30, 30)), 1),
+    "gdn_chunk_t256": (gdn_chunk, _gdn_operands(1, 256)
+                       + [((1, GK, GH * GV), F32)], 1),
+    "gdn_chunk_t64": (gdn_chunk, _gdn_operands(1, 64)
+                      + [((1, GK, GH * GV), F32)], 1),
+    "gdn_step_b64": (
+        lambda st, *a: gdn_step(st, LAYER, *a),
+        [((12, 64, GK, GH * GV), F32)] + _gdn_operands(64)
+        + [((64,), jnp.bool_)], 1),
     "rms_norm_2048x4096": (
         rms_norm, [((2048, D_MODEL), BF16), ((D_MODEL,), BF16)], 1),
 }
