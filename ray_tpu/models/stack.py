@@ -486,6 +486,9 @@ class Decode(_Mode):
         self.slot = positions % page_size
         # a slot that holds no sequence has the trash page for a table
         self.live = page_tables[:, 0] > 0
+        # the keys a slot attends over, this token's among them: none where
+        # no sequence is, and the paged kernel's program then does nothing
+        self.lengths = jnp.where(self.live, positions + 1, 0)
         self.ring = ring_pages(cfg, page_size) if cfg.count("window") else 1
         self.ring_table = (1 + jnp.arange(B)[:, None] * self.ring
                            + jnp.arange(self.ring)[None, :]).astype(jnp.int32)
@@ -524,7 +527,7 @@ class Decode(_Mode):
 
         def attend(q, kp, vp, layer):
             return paged_attention_decode(
-                q, kp, vp, self.ring_table, self.pos + 1, layer, scale=scale,
+                q, kp, vp, self.ring_table, self.lengths, layer, scale=scale,
                 window=self.cfg.window)
 
         o, wk, wv = write_then_attend(
@@ -535,7 +538,7 @@ class Decode(_Mode):
     def attend_full(self, carry, fi, q, k, v, scale):
         def attend(q, kp, vp, layer):
             return paged_attention_decode(q, kp, vp, self.tables,
-                                          self.pos + 1, layer, scale=scale,
+                                          self.lengths, layer, scale=scale,
                                           mesh=self.mesh)
 
         kp, vp = carry["k_pages"], carry["v_pages"]

@@ -14,6 +14,7 @@ import os
 import threading
 
 import jax
+import jax.numpy as jnp
 
 _interp_override = threading.local()
 
@@ -75,3 +76,17 @@ def platform_dispatch(pallas_fn, xla_fn, *args):
             *args, tpu=tpu_branch, default=_with_interp(pallas_fn, True)
         )
     return jax.lax.platform_dependent(*args, tpu=tpu_branch, default=xla_fn)
+
+
+def slot_order(live):
+    """live bool [B] -> int32 [B]: the slot whose blocks grid program b
+    holds where a program of a slot that is not live does nothing: its own
+    if live, else the last live slot before it, else the first live one
+    (else 0). Consecutive programs then name the same blocks, which the
+    pipeline neither fetches nor writes back again; the programs have to
+    run in order (`"arbitrary"`)."""
+    B = live.shape[0]
+    idx = jnp.arange(B, dtype=jnp.int32)
+    last = jax.lax.cummax(jnp.where(live, idx, -1))
+    first = jnp.min(jnp.where(live, idx, B))
+    return jnp.where(last >= 0, last, jnp.where(first < B, first, 0))

@@ -47,7 +47,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .dispatch import interpret_mode, platform_dispatch, use_pallas
+from .dispatch import (
+    interpret_mode,
+    platform_dispatch,
+    slot_order,
+    use_pallas,
+)
 
 _LANES = 128
 _ROWS = 8
@@ -271,13 +276,8 @@ def _step_kernel(src_ref, live_ref, layer_ref, kq_ref, rows_ref, s_ref,
 def _step_pallas(state, layer, q, k, v, g, beta, live):
     _, B, dk, lanes = state.shape
     H, dv = q.shape[1], v.shape[-1]
-    idx = jnp.arange(B, dtype=jnp.int32)
     live = live.astype(jnp.int32)
-    # the slot whose blocks program b holds: its own if live, else the last
-    # live slot before it, else the first live one (else 0)
-    last = jax.lax.cummax(jnp.where(live > 0, idx, -1))
-    first = jnp.min(jnp.where(live > 0, idx, B))
-    src = jnp.where(last >= 0, last, jnp.where(first < B, first, 0))
+    src = slot_order(live > 0)
 
     def row(a):  # [B,H] -> [B,H*dv]: a head's scalar over its value lanes
         return jnp.repeat(a.astype(_F32), dv, axis=-1)

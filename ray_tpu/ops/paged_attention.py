@@ -3,10 +3,15 @@
 The serving engine stores KV cache in fixed-size pages in HBM (the vLLM
 idea, rebuilt TPU-style): the decode step attends one query token per
 sequence against that sequence's pages. The Pallas kernel scalar-prefetches
-the page table, then double-buffers page DMAs (HBM→VMEM) behind the MXU
-dot products — decode is bandwidth-bound, so overlapping the page fetch is
-the whole game. XLA fallback gathers pages (simple, memory-hungry) for CPU
-tests and odd shapes.
+the page table, then streams the pages HBM→VMEM a BLOCK of N pages a loop
+step, two blocks deep: all the DMAs of the next block are in flight while
+the MXU takes the current block's N * page_size keys in one pair of
+products (`_flash_page_loop`; N by `_block_pages`, a rule on the shapes:
+one page a step keeps 64 KB in flight where the memory's bandwidth times a
+DMA's latency is some 400 KB, and fills an eighth of a vreg's lanes with
+keys). A kernel pays for the bytes it reads: a decode slot of length 0
+(no sequence) costs its grid program nothing. XLA fallback gathers pages
+(simple, memory-hungry) for CPU tests and odd shapes.
 
 Cache layout, the ONE layout in the tree: k_pages / v_pages are the WHOLE
 pool, [L, 1, num_pages, page_size, KVH * D] — a token's kv heads side by
@@ -18,10 +23,14 @@ program and a write's a fixed cost per row (PERF.md, PR 26, 28 and 29).
 The layout is this module's own business: callers hand the ops plain
 queries [.., H, D] and `write_then_attend` plain keys and values
 [.., KVH, D], and the number of kv heads is the row's width over q's D.
-The decode kernel widens each query head to the row inside VMEM (zero
-outside its kv head's lanes, `_wide`) and keeps of the output row those
-lanes (`_own`); the chunk and verify kernels read a pool head by head: one
-[page_size, D] tile of each page.
+The decode kernel fetches whole rows and meets a block head by head in
+VMEM: the query rows of kv head c against that head's 128-lane slice of
+the block (`_per_head`; faster than one product of queries widened to the
+row at every shape the tree has, PERF.md section 6, PR 35); the chunk and
+verify kernels read a pool head by head: one [page_size, D] tile of each
+page. The arithmetic is float32 throughout
+(scores, softmax, accumulation, and p in `p v`); bfloat16 keys, values and
+queries go to the MXU as the values they are (`_scores`, `_weighted`).
 
 Every op takes the pool whole and a `layer` index (a traced scalar inside
 the engine's layer scan): the kernels fetch `pool[layer, 0, page]` by DMA
@@ -41,10 +50,8 @@ rides on them as a differential pair does: 128 / D neighbouring kv heads
 are one 128-wide head of the same row (the pool's bytes do not change) and
 each query head is padded with zeros outside its own kv head's D lanes
 (`_tile_heads`), so its scores see that kv head alone and its output row
-holds its own values in those lanes (`_untile_heads`). The decode kernel
-widens queries to the whole row anyway, so there this costs nothing; the
-chunk and verify kernels, which read a pool head by head, run 128 / D
-times the products of a kernel that could read D-lane tiles.
+holds its own values in those lanes (`_untile_heads`): 128 / D times the
+products of a kernel that could read D-lane tiles.
 """
 
 from __future__ import annotations
@@ -60,6 +67,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .dispatch import (
     interpret_mode,
     platform_dispatch,
+    slot_order,
     use_pallas,
 )
 
@@ -85,38 +93,6 @@ def pool_shape(layers: int, num_pages: int, page_size: int, kv_heads: int,
 def _row(x):
     """[..., KVH, D] -> [..., KVH * D]: a token's row of the pool."""
     return x.reshape(*x.shape[:-2], -1)
-
-
-def _lanes(shape, group, c):
-    """bool `shape` [.., H, D]: the query heads (rows) of kv head c, whose
-    lanes of a pool row are c*D .. (c+1)*D. Static in c: compares alone."""
-    row = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 2)
-    return (row >= c * group) & (row < (c + 1) * group)
-
-
-def _wide(q, kv_heads):
-    """[.., H, D] -> [.., H, KVH*D]: every query head as wide as a pool
-    row, zero outside its own kv head's lanes, so ONE product with a page's
-    rows scores each head against its own kv head alone. Runs on values
-    inside the decode kernel (VMEM: the wide form never touches HBM)."""
-    if kv_heads == 1:
-        return q
-    g = q.shape[-2] // kv_heads
-    return jnp.concatenate(
-        [jnp.where(_lanes(q.shape, g, c), q, 0) for c in range(kv_heads)],
-        axis=-1)
-
-
-def _own(o, kv_heads):
-    """[.., H, KVH*D] -> [.., H, D]: of each head's output row the lanes
-    of its own kv head (the others hold other heads' values)."""
-    D = o.shape[-1] // kv_heads
-    g = o.shape[-2] // kv_heads
-    out = o[..., :D]
-    for c in range(1, kv_heads):
-        part = o[..., c * D:(c + 1) * D]
-        out = jnp.where(_lanes(part.shape, g, c), part, out)
-    return out
 
 
 def tile_factor(H: int, kv_heads: int, D: int) -> int:
@@ -195,37 +171,112 @@ def _paged_reference(q, k_pages, v_pages, page_table, lengths, layer, scale,
         mask = (pos >= 0) & (pos >= n - window)
     s = jnp.where(mask[:, None, None, :], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
+    # a slot of length 0 sees no key: zeros, as the kernel leaves it
+    p = jnp.where(mask[:, None, None, :], p, 0.0)
     o = jnp.einsum("bcgt,bctd->bcgd", p, vg.astype(jnp.float32))
     return o.reshape(B, H, D).astype(q.dtype)
 
 
-def _per_head(a, b, heads, contract):
-    """a [heads, ..] and b [.., heads * D]: row c of a against kv head c's
-    lanes of b, one small product a head, the results stacked."""
-    D = b.shape[-1] // heads
+# The block rule's three sizes. A block's k and v should hold what the
+# memory moves while a page's DMA is under way: on a v5e a decode call
+# still gains from 0.5 to 1 MiB in flight and loses beyond it (PERF.md
+# section 6, PR 35). Its float32 scores [rows, keys] and the buffers (two
+# blocks of k and of v) have to leave the default scoped VMEM (16 MiB)
+# room for the rest.
+_IN_FLIGHT_BYTES = 1024 * 1024
+_SCORE_BYTES = 2 * 1024 * 1024
+_SCRATCH_BYTES = 8 * 1024 * 1024
+
+
+def _block_pages(page_size: int, width: int, dtype, rows: int,
+                 pages_per_seq: int) -> int:
+    """N: the pages a step of `_flash_page_loop` takes, from shapes and
+    dtypes alone. As many pages of [page_size, width] as hold the in-flight
+    target (k and v together), rounded up to whole 128-lane vregs of keys
+    so that the scores are lane-dense; halved while the buffers or the
+    [rows, N * page_size] scores outgrow their room; never more than a
+    table holds. 1 is a loop over single pages."""
+    page = 2 * page_size * width * jnp.dtype(dtype).itemsize
+    dense = max(1, _LANES // page_size)
+    n = -(-_IN_FLIGHT_BYTES // page)
+    n = -(-n // dense) * dense
+    while n > 1 and (2 * n * page > _SCRATCH_BYTES
+                     or 4 * rows * n * page_size > _SCORE_BYTES):
+        n //= 2
+    return max(1, min(n, pages_per_seq))
+
+
+def _per_head(a, b, heads, product):
+    """a [heads * g, ..] and b [keys, heads * D]: the g rows of a that are
+    kv head c's against that head's lanes of b, one small product a head,
+    the results stacked."""
+    g, D = a.shape[0] // heads, b.shape[-1] // heads
     return jnp.concatenate([
-        jax.lax.dot_general(a[c:c + 1], b[:, c * D:(c + 1) * D],
-                            (((1,), (contract,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+        product(a[c * g:(c + 1) * g], b[:, c * D:(c + 1) * D])
         for c in range(heads)], axis=0)
+
+
+def _scores(q, k):
+    """q [rows, W] . k [keys, W] -> float32 [rows, keys]. Two bfloat16
+    operands go to the MXU as they are (a product of two bfloat16 values
+    is exact in float32, and the sum is float32 either way); anything else
+    meets in float32."""
+    precision = jax.lax.Precision.DEFAULT  # one pass: nothing to round
+    if not (q.dtype == k.dtype == jnp.bfloat16):
+        q, k, precision = q.astype(jnp.float32), k.astype(jnp.float32), None
+    return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+def _weighted(p, v):
+    """float32 p [rows, keys] . v [keys, W] -> float32 [rows, W]: p stays
+    float32. Against bfloat16 values its 24 bits go as three bfloat16
+    terms stacked as rows of ONE product (hi + mid + lo is p exactly, each
+    product with a bfloat16 value is exact, the sums are float32), so v
+    passes through the MXU once; a generic float32 product would split v
+    too, into terms that are zero."""
+    if v.dtype != jnp.bfloat16:
+        return jax.lax.dot_general(
+            p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    rows = p.shape[0]
+    hi = p.astype(jnp.bfloat16)
+    rest = p - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    out = jax.lax.dot_general(
+        jnp.concatenate([hi, mid, lo], axis=0), v, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32)
+    return out[:rows] + out[rows:2 * rows] + out[2 * rows:]
 
 
 def _flash_page_loop(
     q2d, n_pages, page_id_fn, mask_fn, layer, c,
     k_hbm, v_hbm, k_buf, v_buf, acc_ref, m_ref, l_ref, sem_ref,
-    *, page_size, scale, per_head=0,
+    *, page_size, scale,
 ):
-    """The shared double-buffered page-DMA flash loop: stream this layer's
-    pages HBM->VMEM two-deep while the MXU runs the online-softmax update
-    for q2d [rows, W]. `c` None: whole rows of the pool (W = KVH*D, the
-    decode kernel's widened queries); else kv head c's lanes of every row
-    (W = D). `per_head` = H > 0 (decode, one query head a kv head): q2d is
-    the plain [H, D] and row c meets kv head c's lanes of a page alone, H
-    products of one row each. Kernels differ besides only in how a loop
-    index maps to a page id (page_id_fn) and in the validity mask
-    (mask_fn(i) -> [rows, page_size] bool); everything else — slot rotation, the
-    exp-underflow guard, the l==0 epilogue division — is one
-    implementation serving decode, chunk prefill and verify."""
+    """The shared page-DMA flash loop: stream this layer's pages HBM->VMEM
+    a BLOCK of N pages a step (N = the buffers' rows over page_size,
+    `_block_pages`), two blocks deep: every DMA of the next block is
+    started before the current block's are waited on, so a block of k and
+    v is in flight while the MXU runs ONE pair of products and ONE
+    online-softmax update over the block's N * page_size keys for q2d
+    [rows, D]. `c` None (decode): whole rows of the pool are fetched
+    (W = KVH*D) and the rows of q2d that are kv head c's meet that head's
+    lanes of a block alone (`_per_head`); else kv head c's lanes of every
+    row are fetched (W = D).
+    Kernels differ besides only in how a loop index maps to a page id
+    (page_id_fn) and in the validity mask (mask_fn(i) -> [rows,
+    N * page_size] bool for the block whose first page is i); everything
+    else (slot rotation, the exp-underflow guard, the l==0 epilogue
+    division) is one implementation serving decode, chunk prefill and
+    verify. A block's tail past page n_pages fetches nothing: its scores
+    are masked like any key past the sequence's end, and its rows of v
+    are zeroed (0 x a stale NaN would be NaN in p v)."""
+    N = k_buf.shape[1] // page_size
+    heads = k_buf.shape[2] // q2d.shape[1]  # kv heads in a fetched row
 
     def page_of(pool, page):
         if c is None:
@@ -233,11 +284,25 @@ def _flash_page_loop(
         D = q2d.shape[-1]
         return pool.at[layer, 0, page, :, pl.ds(pl.multiple_of(c * D, D), D)]
 
-    def page_dma(slot, i):
-        page = page_id_fn(i)
-        kcp = pltpu.make_async_copy(page_of(k_hbm, page), k_buf.at[slot], sem_ref.at[slot, 0])
-        vcp = pltpu.make_async_copy(page_of(v_hbm, page), v_buf.at[slot], sem_ref.at[slot, 1])
-        return kcp, vcp
+    def rows_of(j):  # page j of a block, in the buffers
+        return pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
+
+    def block_dmas(slot, first, start):
+        """Start, or wait for, the DMAs of the block whose first page is
+        `first`: a page each of k and of v, on the slot's two semaphores;
+        none for the block's tail past page n_pages (so none at all for a
+        block past the last)."""
+        def page_dmas(j, _):
+            # a wait needs the copy's shape alone: no table lookup
+            page = page_id_fn(first + j) if start else 0
+            for s, (pool, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                dma = pltpu.make_async_copy(
+                    page_of(pool, page), buf.at[slot, rows_of(j)],
+                    sem_ref.at[slot, s])
+                dma.start() if start else dma.wait()
+            return 0
+
+        jax.lax.fori_loop(0, jnp.minimum(N, n_pages - first), page_dmas, 0)
 
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -245,33 +310,25 @@ def _flash_page_loop(
 
     @pl.when(n_pages > 0)
     def _run():
-        kcp, vcp = page_dma(0, 0)
-        kcp.start()
-        vcp.start()
+        block_dmas(0, 0, True)
 
         def body(i, _):
             slot = jax.lax.rem(i, 2)
-            nslot = jax.lax.rem(i + 1, 2)
+            first = i * N
 
-            @pl.when(i + 1 < n_pages)
-            def _prefetch():
-                kn, vn = page_dma(nslot, i + 1)
-                kn.start()
-                vn.start()
+            block_dmas(1 - slot, first + N, True)  # the next block's
+            block_dmas(slot, first, False)
 
-            kw, vw = page_dma(slot, i)
-            kw.wait()
-            vw.wait()
+            def stale(j, _):  # a page of the tail: nothing was fetched
+                v_buf[slot, rows_of(j), :] = jnp.zeros(
+                    (page_size, v_buf.shape[2]), v_buf.dtype)
+                return 0
 
-            k = k_buf[slot].astype(jnp.float32)  # [ps, W]
-            if per_head:
-                s = _per_head(q2d, k, per_head, 1) * scale
-            else:
-                s = jax.lax.dot_general(
-                    q2d, k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                ) * scale  # [rows, ps]
-            s = jnp.where(mask_fn(i), s, _NEG_INF)
+            jax.lax.fori_loop(n_pages - first, N, stale, 0)
+
+            k, v = k_buf[slot], v_buf[slot]  # [N * ps, W]
+            s = _per_head(q2d, k, heads, _scores) * scale  # [rows, N * ps]
+            s = jnp.where(mask_fn(first), s, _NEG_INF)
 
             m_prev, l_prev = m_ref[...], l_ref[...]
             m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -281,18 +338,11 @@ def _flash_page_loop(
             p = jnp.where(m_next[:, :1] > _NEG_INF / 2, p, 0.0)
             l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
             m_ref[...] = m_next
-            v = v_buf[slot].astype(jnp.float32)
-            if per_head:
-                pv = _per_head(p, v, per_head, 0)
-            else:
-                pv = jax.lax.dot_general(
-                    p, v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
+            pv = _per_head(p, v, heads, _weighted)
             acc_ref[...] = acc_ref[...] * alpha[:, :1] + pv
             return 0
 
-        jax.lax.fori_loop(0, n_pages, body, 0)
+        jax.lax.fori_loop(0, jax.lax.div(n_pages + N - 1, N), body, 0)
 
     l = l_ref[...][:, :1]
     l = jnp.where(l == 0.0, 1.0, l)
@@ -301,104 +351,104 @@ def _flash_page_loop(
 
 def _paged_kernel(
     # scalar prefetch
-    pt_ref, len_ref,
+    pt_ref, meta_ref,
     # inputs
     q_ref, k_hbm, v_hbm,
     # outputs
     o_ref,
     # scratch
     k_buf, v_buf, acc_ref, m_ref, l_ref, sem_ref,
-    *, page_size, pages_per_seq, scale, batch, kv_heads, window=None,
+    *, page_size, pages_per_seq, scale, batch, window=None,
 ):
     """One sequence's decode attention, every head at once: a page is ONE
-    DMA whatever the number of kv heads. Where several query heads share a
-    kv head they are widened to the pool's row and a page is one pair of
-    products; where each has its own (group 1, where the widened product
-    would be KVH x padding) query row c meets kv head c's 128-lane slice
-    of the page (PERF.md section 6, PR 34: 19% faster at 30 kv heads and
-    32 to 64 sequences of 700 tokens)."""
+    DMA whatever the number of kv heads, and the query rows of kv head c
+    meet that head's 128-lane slice of a block (PERF.md section 6, PR 34
+    and PR 35: one product of queries widened to the row, KVH x padding,
+    loses by 5 to 18% at every shape the tree has). A slot of length 0
+    holds no sequence: its program starts no DMA, computes nothing and
+    writes nothing, and its q and o blocks are its live neighbour's
+    (`_paged_pallas`), so nothing moves for it either."""
     b = pl.program_id(0)
     H = q_ref.shape[1]
-    length = len_ref[b]
-    layer = len_ref[batch]
+    keys = k_buf.shape[1]
+    length = meta_ref[b]
+    layer = meta_ref[2 * batch]
     n_pages = jax.lax.div(length + page_size - 1, page_size)
-    if window is None:
-        def mask(i):
-            pos = i * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, (H, page_size), 1)
-            return pos < length
+    first = 0 if window is None else jnp.maximum(length - window, 0)
+    page0 = 0 if window is None else jax.lax.div(first, page_size)
 
-        def page_id(i):
+    def mask(i):
+        pos = (page0 + i) * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (H, keys), 1)
+        return (pos >= first) & (pos < length)
+
+    def page_id(i):
+        if window is None:
             return pt_ref[b * pages_per_seq + i]
-    else:
-        first = jnp.maximum(length - window, 0)
-        page0 = jax.lax.div(first, page_size)
-        n_pages = n_pages - page0
+        # the table may be a ring, and a block may wrap it
+        return pt_ref[b * pages_per_seq
+                      + jax.lax.rem(page0 + i, pages_per_seq)]
 
-        def mask(i):
-            pos = (page0 + i) * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, (H, page_size), 1)
-            return (pos < length) & (pos >= first)
-
-        def page_id(i):
-            return pt_ref[b * pages_per_seq
-                          + jax.lax.rem(page0 + i, pages_per_seq)]
-
-    q = q_ref[0].astype(jnp.float32)
-    per_head = H if _sliced(H, kv_heads) else 0
-    out = _flash_page_loop(
-        q if per_head else _wide(q, kv_heads), n_pages,
-        page_id, mask, layer, None,
-        k_hbm, v_hbm, k_buf, v_buf, acc_ref, m_ref, l_ref, sem_ref,
-        page_size=page_size, scale=scale, per_head=per_head,
-    )
-    o_ref[0] = (out if per_head else _own(out, kv_heads)).astype(o_ref.dtype)
-
-
-def _sliced(heads: int, kv_heads: int) -> bool:
-    """Whether the decode kernel takes a page head by head: one query head
-    a kv head, and more than one of them (shapes the kernel can see)."""
-    return heads == kv_heads > 1
+    @pl.when(length > 0)
+    def _live():
+        out = _flash_page_loop(
+            q_ref[0], n_pages - page0, page_id, mask, layer, None,
+            k_hbm, v_hbm, k_buf, v_buf, acc_ref, m_ref, l_ref, sem_ref,
+            page_size=page_size, scale=scale,
+        )
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 def _paged_pallas(q, k_pages, v_pages, page_table, lengths_layer, scale,
                   window=None):
-    """lengths_layer s32[B+1]: the B lengths, then the layer index."""
+    """lengths_layer s32[B+1]: the B lengths, then the layer index. The
+    kernel's second prefetch array is the lengths, the slot whose q and o
+    blocks each program holds, and the layer: s32[2B+1]."""
     B, H, D = q.shape
     page_size, row = k_pages.shape[3], k_pages.shape[4]
     pages_per_seq = page_table.shape[1]
+    block = _block_pages(page_size, row, k_pages.dtype, H, pages_per_seq)
+    lengths = lengths_layer[:B]
+    # a slot with nothing to read leans on its live neighbour's blocks, so
+    # the pipeline fetches and writes back nothing for it
+    meta = jnp.concatenate(
+        [lengths, slot_order(lengths > 0), lengths_layer[B:]])
+    a_slot = pl.BlockSpec((1, H, D), lambda b, pt, meta: (meta[B + b], 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
+            a_slot,
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, H, D), lambda b, *_: (b, 0, 0)),
+        out_specs=a_slot,
         scratch_shapes=[
-            pltpu.VMEM((2, page_size, row), k_pages.dtype),
-            pltpu.VMEM((2, page_size, row), v_pages.dtype),
-            pltpu.VMEM((H, D if _sliced(H, row // D) else row), jnp.float32),
+            pltpu.VMEM((2, block * page_size, row), k_pages.dtype),
+            pltpu.VMEM((2, block * page_size, row), v_pages.dtype),
+            pltpu.VMEM((H, D), jnp.float32),
             pltpu.VMEM((H, _LANES), jnp.float32),
             pltpu.VMEM((H, _LANES), jnp.float32),
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(
             _paged_kernel, page_size=page_size, pages_per_seq=pages_per_seq,
-            scale=scale, batch=B, kv_heads=row // D, window=window,
+            scale=scale, batch=B, window=window,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
+            # a program of length 0 leans on its neighbour's blocks: in order
+            dimension_semantics=("arbitrary",),
         ),
         name="paged_decode" if window is None else "paged_decode_window",
         interpret=interpret_mode(),
-    )(page_table.reshape(-1), lengths_layer, q, k_pages, v_pages)
+    )(page_table.reshape(-1), meta, q, k_pages, v_pages)
+    # what no program wrote is not a value yet
+    return jnp.where((lengths > 0)[:, None, None], out, 0)
 
 
 def _chunk_reference(q, k_pages, v_pages, page_table, start, total, layer,
@@ -444,30 +494,29 @@ def _chunk_kernel(
     window the loop starts at the page of the first key any row sees
     (meta[3] and the first row's window, whichever is later)."""
     c = pl.program_id(0)
+    keys = k_buf.shape[1]
     start = meta_ref[0]
     total = meta_ref[1]
     layer = meta_ref[2]
     n_pages = jax.lax.div(total + page_size - 1, page_size)
-    page = lambda i: i  # noqa: E731 — loop index -> the sequence's page
+    page0 = 0
     if window is not None:
         first = jnp.maximum(meta_ref[3], start - window + 1)
         page0 = jax.lax.div(jnp.maximum(first, 0), page_size)
-        n_pages = n_pages - page0
-        page = lambda i: page0 + i  # noqa: E731
 
     def mask(i):
-        keypos = page(i) * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, page_size), 1)
+        keypos = (page0 + i) * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, keys), 1)
         qpos = start + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, page_size), 0) // group
+            jnp.int32, (rows, keys), 0) // group
         seen = (keypos <= qpos) & (keypos < total)
         if window is not None:
             seen &= (keypos > qpos - window) & (keypos >= meta_ref[3])
         return seen
 
     out = _flash_page_loop(
-        q_ref[0].astype(jnp.float32), n_pages,
-        lambda i: pt_ref[page(i)], mask, layer, c,
+        q_ref[0], n_pages - page0,
+        lambda i: pt_ref[page0 + i], mask, layer, c,
         k_hbm, v_hbm, k_buf, v_buf, acc_ref, m_ref, l_ref, sem_ref,
         page_size=page_size, scale=scale,
     )
@@ -481,6 +530,8 @@ def _chunk_pallas(q, k_pages, v_pages, page_table, meta, scale, window=None):
     page_size, KVH = k_pages.shape[3], k_pages.shape[4] // D
     g = H // KVH
     rows = C * g
+    block = _block_pages(page_size, D, k_pages.dtype, rows,
+                         page_table.shape[0])
     # [C,H,D] -> [KVH, C*g, D]: each kv head's q rows contiguous
     qr = q.reshape(C, KVH, g, D).transpose(1, 0, 2, 3).reshape(KVH, rows, D)
 
@@ -494,8 +545,8 @@ def _chunk_pallas(q, k_pages, v_pages, page_table, meta, scale, window=None):
         ],
         out_specs=pl.BlockSpec((1, rows, D), lambda c, *_: (c, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, page_size, D), k_pages.dtype),
-            pltpu.VMEM((2, page_size, D), v_pages.dtype),
+            pltpu.VMEM((2, block * page_size, D), k_pages.dtype),
+            pltpu.VMEM((2, block * page_size, D), v_pages.dtype),
             pltpu.VMEM((rows, D), jnp.float32),
             pltpu.VMEM((rows, _LANES), jnp.float32),
             pltpu.VMEM((rows, _LANES), jnp.float32),
@@ -620,8 +671,8 @@ def _verify_kernel(
     S=k+1 query tokens (last committed + k draft tokens, KV already
     written into the sequence's pages by the caller) against that head's
     lanes of the sequence's pages, as the chunk kernel reads them. Same
-    double-buffered page streaming; the mask is the chunk kernel's
-    per-ROW causal bound anchored at this sequence's start position."""
+    streaming of blocks of pages; the mask is the chunk kernel's per-ROW
+    causal bound anchored at this sequence's start position."""
     b = pl.program_id(0)
     c = pl.program_id(1)
     start = pos_ref[b]
@@ -633,16 +684,18 @@ def _verify_kernel(
     # has qpos below pages_per_seq * page_size)
     n_pages = jnp.minimum(
         jax.lax.div(total + page_size - 1, page_size), pages_per_seq)
+    keys = k_buf.shape[1]
 
     def mask(i):
         keypos = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, page_size), 1)
+            jnp.int32, (rows, keys), 1)
         qpos = start + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, page_size), 0) // group
-        return keypos <= qpos
+            jnp.int32, (rows, keys), 0) // group
+        # a block's tail past the table holds no key of the sequence's
+        return (keypos <= qpos) & (keypos < n_pages * page_size)
 
     out = _flash_page_loop(
-        q_ref[0, 0].astype(jnp.float32), n_pages,
+        q_ref[0, 0], n_pages,
         lambda i: pt_ref[b * pages_per_seq + i], mask, layer, c,
         k_hbm, v_hbm, k_buf, v_buf, acc_ref, m_ref, l_ref, sem_ref,
         page_size=page_size, scale=scale,
@@ -657,6 +710,7 @@ def _verify_pallas(q, k_pages, v_pages, page_table, positions_layer, scale):
     g = H // KVH
     pages_per_seq = page_table.shape[1]
     rows = S * g
+    block = _block_pages(page_size, D, k_pages.dtype, rows, pages_per_seq)
     # [B,S,H,D] -> [B, KVH, S*g, D]: each kv head's q rows contiguous,
     # row = s*g + gi so row // g recovers the span offset (mask anchor)
     qr = (q.reshape(B, S, KVH, g, D)
@@ -672,8 +726,8 @@ def _verify_pallas(q, k_pages, v_pages, page_table, positions_layer, scale):
         ],
         out_specs=pl.BlockSpec((1, 1, rows, D), lambda b, c, *_: (b, c, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, page_size, D), k_pages.dtype),
-            pltpu.VMEM((2, page_size, D), v_pages.dtype),
+            pltpu.VMEM((2, block * page_size, D), k_pages.dtype),
+            pltpu.VMEM((2, block * page_size, D), v_pages.dtype),
             pltpu.VMEM((rows, D), jnp.float32),
             pltpu.VMEM((rows, _LANES), jnp.float32),
             pltpu.VMEM((rows, _LANES), jnp.float32),
@@ -705,9 +759,9 @@ def _batched(pallas_fn, reference_fn, q, k_pages, v_pages, page_table,
     cannot be partitioned by GSPMD: each shard runs the same kernel on
     its contiguous block of q heads and on its kv heads' lanes of every
     row, the pool sharded on its LAST axis — requires tp | KVH, which the
-    engine enforces; table and scalars replicate; whatever widens q does
-    so inside the body, on the shard's own row). `per_seq` [B] is lengths
-    or positions; q's heads are its second-to-last axis."""
+    engine enforces; table and scalars replicate; the block of pages a
+    loop step takes follows the shard's own row). `per_seq` [B] is
+    lengths or positions; q's heads are its second-to-last axis."""
     if scale is None:
         scale = q.shape[-1]**-0.5
     tp = int(mesh.shape.get(tp_axis, 1)) if mesh is not None else 1

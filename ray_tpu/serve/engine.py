@@ -907,8 +907,8 @@ class InferenceEngine:
         and values go straight into the sequence's pages and its queries
         attend over the paged prefix (per-row causal bound), through the
         layers of models/stack.py in its chunk mode. Attention runs the
-        Pallas chunk kernel (ops.paged_attention_chunk: double-buffered
-        page DMAs, reads only the valid prefix pages) where shapes allow;
+        Pallas chunk kernel (ops.paged_attention_chunk: blocks of page
+        DMAs, reads only the valid prefix pages) where shapes allow;
         the XLA gather fallback, which touches the whole table, covers CPU
         tests, odd head dims, and TP meshes (GSPMD partitions the
         fallback's einsums; a bare pallas_call it cannot)."""

@@ -432,15 +432,11 @@ def test_a_lower_precision_than_stated_fails(model, mode):
 @pytest.mark.parametrize("window", [None, 8])
 def test_one_query_head_a_kv_head_takes_a_page_head_by_head(
         pallas_everywhere, window):
-    """At group size 1 the decode kernel does not widen queries to the
-    pool's row (a KVH x padded product) but meets kv head c's 128-lane
-    slice of a page with query row c alone: the same numbers as the XLA
-    twin, and as the widened form gives a grouped model."""
+    """At group size 1 the decode kernel meets kv head c's 128-lane slice
+    of a block with query row c alone (as it meets a grouped model's with
+    the group's rows, since PR 35): the same numbers as the XLA twin."""
     from ray_tpu.ops import paged_attention as pa
 
-    assert pa._sliced(30, 30) and pa._sliced(4, 4)
-    assert not pa._sliced(32, 8) and not pa._sliced(40, 10)
-    assert not pa._sliced(1, 1)
     H, D, ps = 4, 128, 4
     k = jax.random.split(jax.random.PRNGKey(30), 3)
     kp = jax.random.normal(k[0], pa.pool_shape(2, 13, ps, H, D))

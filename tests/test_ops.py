@@ -332,6 +332,134 @@ class TestPoolRowAgainstPlainSoftmax:
                          _tokens(vp, 1, pt[b], KVH), seen, self.D ** -0.5)
             np.testing.assert_allclose(out[b], ref, atol=2e-3, rtol=2e-3)
 
+    # A step of the kernels' loop is a BLOCK of pages (`_block_pages`); a
+    # slot of length 0 holds no sequence. `how`: the XLA forms; the
+    # kernels with the block the rule gives (here a whole table) and with
+    # blocks of 2 and 3 pages, each also with NaN in every page that is
+    # not a live sequence's own (a block's tail fetches nothing, and what
+    # it does not fetch reaches neither product).
+
+    @pytest.fixture(params=[("xla", None, False)] + [
+        ("pallas", n, nan) for n in (None, 2, 3) for nan in (False, True)],
+        ids=lambda h: f"{h[0]}-block{h[1]}" + ("-nan" if h[2] else ""))
+    def how(self, request, monkeypatch):
+        mode, block, nan = request.param
+        monkeypatch.setenv("RAY_TPU_FORCE_PALLAS",
+                           "1" if mode == "pallas" else "0")
+        if block is not None:
+            from ray_tpu.ops import paged_attention as pa
+
+            monkeypatch.setattr(
+                pa, "_block_pages",
+                lambda ps, width, dtype, rows, pages: min(block, pages))
+        return block, nan
+
+    BB, PP = 6, 6  # slots and pages a slot of the block cases
+
+    def _edge(self, block):
+        """A length that ends a block exactly, more than one block in."""
+        return (2 if block == 2 else 1) * (block or 3) * self.PS
+
+    def _case(self, KVH, D, own, nan):
+        """Pools and a shuffled table of BB slots; with `nan`, every page
+        but the first own[b] of slot b's holds NaN, in every layer."""
+        ks = jax.random.split(jax.random.PRNGKey(21), 3)
+        n = self.BB * self.PP
+        table = 1 + jax.random.permutation(ks[2], n).reshape(
+            self.BB, self.PP).astype(jnp.int32)
+        kp = _pool(ks[0], self.L, KVH, n + 1, self.PS, D)
+        vp = _pool(ks[1], self.L, KVH, n + 1, self.PS, D)
+        if nan:
+            keep = np.zeros(n + 1, bool)
+            for b, pages in enumerate(own):
+                keep[np.asarray(table[b, :pages])] = True
+            hole = jnp.asarray(~keep)[None, None, :, None, None]
+            kp, vp = (jnp.where(hole, jnp.nan, x) for x in (kp, vp))
+        return kp, vp, table
+
+    @pytest.mark.parametrize("window", [None, 24])
+    @pytest.mark.parametrize("H,KVH,D", [(h, c, 128) for h, c in _HEADS]
+                             + [(8, 4, 64)])
+    def test_decode_blocks_and_slots_without_a_sequence(self, how, H, KVH, D,
+                                                        window):
+        block, nan = how
+        e = self._edge(block)
+        # dead slots before, between and after the live ones; lengths that
+        # end a block exactly, one short and one past
+        lens = np.array([0, e, 0, e - 1, e + 1, 0], np.int32)
+        kp, vp, pt = self._case(KVH, D, -(-lens // self.PS), nan)
+        q = _rand(jax.random.PRNGKey(22), (self.BB, H, D))
+        out = np.asarray(paged_attention_decode(
+            q, kp, vp, pt, jnp.asarray(lens), layer=1, window=window))
+        pos = np.arange(self.PP * self.PS)
+        for b in range(self.BB):
+            if not lens[b]:
+                np.testing.assert_array_equal(out[b], 0)
+                continue
+            seen = pos < lens[b]
+            if window is not None:
+                seen &= pos >= lens[b] - window
+            own = np.asarray(pt[b, :-(-lens[b] // self.PS)])
+            ref = _plain(np.asarray(q[b:b + 1]), _tokens(kp, 1, own, KVH),
+                         _tokens(vp, 1, own, KVH), seen[None, :own.size * self.PS],
+                         D ** -0.5)
+            np.testing.assert_allclose(out[b], ref[0], atol=2e-3, rtol=2e-3)
+
+    @pytest.mark.parametrize("window", [None, 24])
+    def test_decode_of_no_sequence_at_all_is_zeros(self, how, window):
+        _, nan = how
+        kp, vp, pt = self._case(2, self.D, [0] * self.BB, nan)
+        q = _rand(jax.random.PRNGKey(23), (self.BB, 4, self.D))
+        out = paged_attention_decode(
+            q, kp, vp, pt, jnp.zeros((self.BB,), jnp.int32), layer=1,
+            window=window)
+        np.testing.assert_array_equal(np.asarray(out), 0)
+
+    @pytest.mark.parametrize("window", [None, 24])
+    @pytest.mark.parametrize("H,KVH,D", [(32, 8, 128), (4, 1, 128), (8, 4, 64)])
+    def test_chunk_with_total_at_a_blocks_edge(self, how, H, KVH, D, window):
+        from ray_tpu.ops import paged_attention_chunk
+
+        block, nan = how
+        C, e = 16, self._edge(block)
+        q = _rand(jax.random.PRNGKey(24), (C, H, D))
+        pos = np.arange(self.PP * self.PS)[None]
+        for total in (e - 1, e, e + 1):
+            start = total - C
+            own = [0] * self.BB
+            own[1] = -(-total // self.PS)
+            kp, vp, pt = self._case(KVH, D, own, nan)
+            out = paged_attention_chunk(q, kp, vp, pt[1], start, total,
+                                        layer=1, window=window)
+            qpos = start + np.arange(C)[:, None]
+            seen = (pos <= qpos) & (pos < total)
+            if window is not None:
+                seen &= pos > qpos - window
+            pages = np.asarray(pt[1, :own[1]])
+            ref = _plain(np.asarray(q), _tokens(kp, 1, pages, KVH),
+                         _tokens(vp, 1, pages, KVH),
+                         seen[:, :pages.size * self.PS], D ** -0.5)
+            np.testing.assert_allclose(out, ref, atol=2e-3, rtol=2e-3)
+
+    @pytest.mark.parametrize("H,KVH,D", [(32, 8, 128), (4, 1, 128), (8, 4, 64)])
+    def test_verify_with_total_at_a_blocks_edge(self, how, H, KVH, D):
+        from ray_tpu.ops import paged_attention_verify
+
+        block, nan = how
+        S, e = 3, self._edge(block)
+        # spans that end a block exactly, one short and one past
+        at = np.array([5, e - S, 9, e - S - 1, e - S + 1, 0], np.int32)
+        kp, vp, pt = self._case(KVH, D, -(-(at + S) // self.PS), nan)
+        q = _rand(jax.random.PRNGKey(25), (self.BB, S, H, D))
+        out = paged_attention_verify(q, kp, vp, pt, jnp.asarray(at), layer=1)
+        for b in range(self.BB):
+            pages = np.asarray(pt[b, :-(-(at[b] + S) // self.PS)])
+            seen = (np.arange(pages.size * self.PS)[None]
+                    <= at[b] + np.arange(S)[:, None])
+            ref = _plain(np.asarray(q[b]), _tokens(kp, 1, pages, KVH),
+                         _tokens(vp, 1, pages, KVH), seen, D ** -0.5)
+            np.testing.assert_allclose(out[b], ref, atol=2e-3, rtol=2e-3)
+
     @pytest.mark.parametrize("KVH", [1, 2, 8, 10])
     def test_scatter_then_gather_returns_its_input(self, KVH):
         from ray_tpu.ops import gather_pages, scatter_pages
@@ -353,6 +481,54 @@ class TestPoolRowAgainstPlainSoftmax:
         untouched = np.setdiff1d(np.arange(kp.shape[2]), np.asarray(pages))
         np.testing.assert_array_equal(np.asarray(kp2)[:, :, untouched],
                                       np.asarray(kp)[:, :, untouched])
+
+
+class TestBlockRule:
+    """`_block_pages` alone: how many pages a step of the page loop takes."""
+
+    # the rows the tree has (lfm2, dense and Mixtral, phi, Olmo), a tp = 4
+    # shard of the dense row, and the query rows that meet them
+    @pytest.mark.parametrize("width,rows", [(512, 32), (1024, 32), (1280, 40),
+                                            (3840, 30), (256, 8)])
+    def test_a_decode_block_covers_what_is_in_flight_and_fits(self, width, rows):
+        from ray_tpu.ops import paged_attention as pa
+
+        ps = 16
+        n = pa._block_pages(ps, width, jnp.bfloat16, rows, 2048)
+        page = 2 * ps * width * 2  # k and v
+        assert n * page >= pa._IN_FLIGHT_BYTES
+        assert (n * ps) % 128 == 0  # lane-dense scores
+        # both buffers of k and v, the float32 accumulator and the scores:
+        # well under the 16 MiB of scoped VMEM a kernel gets by default
+        assert 2 * n * page <= pa._SCRATCH_BYTES
+        held = 2 * n * page + 4 * rows * (width + n * ps + 256)
+        assert held <= 8 * 2 ** 20 + 2 ** 20
+
+    def test_rows_and_tables_bound_it(self):
+        from ray_tpu.ops import paged_attention as pa
+
+        # the chunk kernel: 256 queries of a group of 4 against one head's
+        # 128 lanes: the float32 scores hold the block, not the bytes
+        n = pa._block_pages(16, 128, jnp.bfloat16, 1024, 2048)
+        assert 4 * 1024 * n * 16 <= pa._SCORE_BYTES < 4 * 1024 * 2 * n * 16
+        # never more than a table holds; one page is the loop of old
+        assert pa._block_pages(16, 1024, jnp.bfloat16, 32, 5) == 5
+        assert pa._block_pages(16, 1024, jnp.bfloat16, 32, 1) == 1
+        # a row so wide that two blocks would not fit gets fewer pages
+        wide = pa._block_pages(16, 64 * 1024, jnp.bfloat16, 32, 2048)
+        assert 1 <= wide < 8
+        assert 2 * wide * 2 * 16 * 64 * 1024 * 2 <= pa._SCRATCH_BYTES or wide == 1
+
+    def test_it_reads_shapes_and_dtypes_alone(self):
+        import inspect
+
+        from ray_tpu.ops import paged_attention as pa
+
+        assert list(inspect.signature(pa._block_pages).parameters) == [
+            "page_size", "width", "dtype", "rows", "pages_per_seq"]
+        assert set(pa._block_pages.__code__.co_names) <= {
+            "jnp", "dtype", "itemsize", "max", "min", "_LANES",
+            "_IN_FLIGHT_BYTES", "_SCORE_BYTES", "_SCRATCH_BYTES"}
 
 
 class TestPagedAttentionTP:

@@ -38,13 +38,27 @@ def heads(row):
     return jnp.stack([row[..., c * D:(c + 1) * D] for c in range(KVH)], -2)
 
 
-@pytest.mark.parametrize("lengths", [(1, 3, 8), (9, 12, 13), (30, 41, 57)])
-def test_paged_decode_window_over_a_ring(lengths):
-    """Below the window, at its edge, and after the ring has lapped."""
+@pytest.fixture(params=[None, 2], ids=["rule", "blocks-of-2"])
+def block(request, monkeypatch):
+    """The pages a step of the kernels' loop takes: what the rule gives (at
+    these sizes a whole table) or 2, so that a ring of 3 wraps INSIDE a
+    block and a window's first page falls in the middle of one."""
+    if request.param:
+        monkeypatch.setattr(pa, "_block_pages",
+                            lambda ps, w, dt, rows, pages: min(2, pages))
+    return request.param
+
+
+@pytest.mark.parametrize("lengths", [(1, 3, 8), (9, 12, 13), (30, 41, 57),
+                                     (0, 23, 0)])
+def test_paged_decode_window_over_a_ring(lengths, block):
+    """Below the window, at its edge, and after the ring has lapped; a slot
+    of length 0 holds no sequence and reads zeros. The trash page and
+    every other layer hold NaN: nothing of them is read."""
     k = keys(3)
     B = len(lengths)
-    kp = pool(k[0], 2, 1 + B * RING)
-    vp = pool(k[1], 2, 1 + B * RING)
+    kp = pool(k[0], 2, 1 + B * RING).at[0].set(jnp.nan).at[:, :, 0].set(jnp.nan)
+    vp = pool(k[1], 2, 1 + B * RING).at[0].set(jnp.nan).at[:, :, 0].set(jnp.nan)
     q = jax.random.normal(k[2], (B, H, D))
     table = (1 + jnp.arange(B)[:, None] * RING
              + jnp.arange(RING)[None]).astype(jnp.int32)
@@ -55,6 +69,9 @@ def test_paged_decode_window_over_a_ring(lengths):
     np.testing.assert_allclose(got, want, atol=TOL)
     # and against the keys laid out by position, no ring, no pages
     for b, n in enumerate(lengths):
+        if not n:
+            np.testing.assert_array_equal(got[b], 0)
+            continue
         pos = range(max(0, n - W), n)
         kk = jnp.stack([heads(kp[1, 0, table[b, (p // PS) % RING], p % PS])
                         for p in pos], 1)
@@ -66,7 +83,7 @@ def test_paged_decode_window_over_a_ring(lengths):
 
 
 @pytest.mark.parametrize("first", [0, 3, 8])
-def test_paged_chunk_window_over_the_kept_tail(first):
+def test_paged_chunk_window_over_the_kept_tail(first, block):
     """The chunk program's window layers: tail and chunk side by side as a
     little pool, rows `first`.. the sequence's own."""
     k = keys(3)
@@ -225,3 +242,35 @@ def test_decode_is_one_grid_program_a_sequence(window):
         s = jnp.einsum("cgd,ctd->cgt", q[b].reshape(KVH, H // KVH, D), kk) / 8
         o = jnp.einsum("cgt,ctd->cgd", jax.nn.softmax(s, -1), vv)
         np.testing.assert_allclose(got[b], o.reshape(H, D), atol=TOL)
+
+
+def test_decode_hands_a_slot_without_a_sequence_the_length_zero(monkeypatch):
+    """Both paged calls of `Decode` (the window rings and the full cache):
+    a slot whose table is the trash page attends over no key, so its grid
+    program does nothing (`serve_decode_slot_steps{state="empty"}` counts
+    them); a live slot over its position and the token it writes."""
+    from ray_tpu.models import get_config, stack
+
+    cfg = get_config("tiny-sambay")
+    handed = {}
+
+    def spy(q, kp, vp, table, lengths, layer, window=None, **_):
+        handed[window] = np.asarray(lengths)
+        return jnp.zeros_like(q)
+
+    monkeypatch.setattr(stack, "paged_attention_decode", spy)
+    ps = 4
+    tables = jnp.asarray([[0, 0, 0], [3, 4, 0], [0, 0, 0], [5, 0, 0]], jnp.int32)
+    mode = stack.Decode(cfg, jnp.asarray([0, 6, 0, 2], jnp.int32), tables, ps)
+    B, Hq, hd = 4, cfg.n_heads, cfg.pool_dim
+    q = jnp.zeros((B, 1, Hq, hd))
+    kv = jnp.zeros((B, 1, cfg.pool_heads, hd))
+    rows = cfg.pool_heads * hd
+    full = jnp.zeros(pa.pool_shape(1, 6, ps, cfg.pool_heads, hd))
+    rings = jnp.zeros(pa.pool_shape(1, 1 + B * mode.ring, ps, cfg.pool_heads, hd))
+    carry = {"k_pages": full, "v_pages": full, "wk": rings, "wv": rings}
+    mode.attend_full(carry, 0, q, None, None, 1.0)
+    mode.attend_window(carry, 0, q, kv, kv, 1.0)
+    assert rows == full.shape[-1]
+    np.testing.assert_array_equal(handed[None], [0, 7, 0, 3])
+    np.testing.assert_array_equal(handed[cfg.window], [0, 7, 0, 3])
