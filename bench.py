@@ -595,9 +595,9 @@ def bench_trace(model: str) -> None:
 def bench_health(model: str) -> None:
     """SLO-digest overhead gate: the SAME colocated serve burst with the
     streaming latency digests off vs on. The digests sit inline on the
-    engine's hot paths (TTFT on first commit, count-weighted TBT once
-    per decode step, e2e on finish) — this row proves the bucket-index
-    math stays under the 2%% tokens/s acceptance line. Rounds strictly
+    engine's hot paths (TTFT on first commit, e2e on finish) — this row
+    proves the bucket-index math stays under the 2%% tokens/s
+    acceptance line. Rounds strictly
     alternate off/on with medians, same discipline as bench_trace; the
     toggle flips the engine's resolved `_slo_on` flag directly so both
     sides run the identical compiled programs. Also emits the raw

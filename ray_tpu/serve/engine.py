@@ -210,34 +210,67 @@ _PREFILL_PHASES = _phases("prefill", "prefill", (
     "idle", "admit", "dispatch", "readback", "publish"))
 
 
-def decode_phase(name: str) -> "_Phase":
-    """`engine.<name>` on the decode thread (spec_decode.py times its
-    propose/verify legs through this too)."""
-    return _Phase(*_DECODE_PHASES[name])
-
-
 def _prefill_phase(name: str) -> "_Phase":
     return _Phase(*_PREFILL_PHASES[name])
 
 
 class _Phase(tracing.region):
-    """A region that also feeds its `serve_engine_loop_seconds` child."""
+    """A region that also feeds its `serve_engine_loop_seconds` child and,
+    on the decode thread, the iteration's row of the token ledger
+    (`InferenceEngine._account`)."""
 
-    __slots__ = ("_sink",)
+    __slots__ = ("_sink", "_ledger")
 
-    def __init__(self, name: str, sink):
-        super().__init__(name)
+    def __init__(self, name: str, sink, ledger=None, **attrs):
+        super().__init__(name, **attrs)
         self._sink = sink
+        self._ledger = ledger
 
     def __exit__(self, *exc) -> bool:
         super().__exit__(*exc)
         self._sink.observe(self.elapsed_ns * 1e-9)
+        if self._ledger is not None:
+            self._ledger[self.name] += self.elapsed_ns
         return False
 
 
-_m_tokens_per_step = Gauge(
-    "serve_tokens_per_decode_step",
-    "Cumulative committed tokens per slot-step of decode participation.")
+# The token ledger: where the time between a sequence's tokens goes, in
+# seconds weighed by the sequences that waited (an iteration with 30 live
+# slots puts its wall time into 30 sequences' gaps). The parts tile the
+# decode thread's time while sequences are live, so their sum is what
+# `serve_request_stage_seconds{stage="decode"}` sums for the same requests.
+_m_token_wait = Counter(
+    "serve_token_wait_seconds",
+    "Decode-thread wall time x the slots that held a sequence meanwhile, by "
+    "part (chunk_host: engine.chunk less its readback; chunk_device_wait: "
+    "engine.chunk.readback; host: install + cancel_check + build + commit, "
+    "propose legs under speculation; dispatch; device_wait: "
+    "engine.readback; loop: the iteration's remainder and the time between "
+    "iterations).")
+_token_wait = {p: _m_token_wait.labels(part=p) for p in (
+    "chunk_host", "chunk_device_wait", "host", "dispatch", "device_wait",
+    "loop")}
+_m_span_seconds = Counter(
+    "serve_decode_span_seconds",
+    "Wall time of decode spans (engine.dispatch + engine.readback), by the "
+    "live slots in the span (live_le) and whether prefill programs were "
+    "dispatched since the last span (prefill=1: a chunk this iteration or "
+    "a bucket program of the prefill thread).")
+_m_span_steps = Counter(
+    "serve_decode_span_steps",
+    "Decode steps of the spans in serve_decode_span_seconds, same labels.")
+# [min((live - 1).bit_length(), 7)][shared with prefill] -> (seconds, steps)
+_span_children = [
+    [(_m_span_seconds.labels(live_le=le, prefill=p),
+      _m_span_steps.labels(live_le=le, prefill=p)) for p in ("0", "1")]
+    for le in ("1", "2", "4", "8", "16", "32", "64", "+Inf")]
+_m_interleaved = Counter(
+    "serve_decode_interleaved_prefill_tokens",
+    "At each decode span, live slots x the prefill tokens (padded, as the "
+    "programs compute them) dispatched since the last span: over committed "
+    "decode tokens, the prefill tokens a decoded token waited behind.")
+
+
 _m_weights_version = Gauge(
     "serve_weights_version",
     "Monotonic generation stamp of the weights an engine is serving "
@@ -763,7 +796,6 @@ class InferenceEngine:
         self.slo_role = "engine"
         self._slo_on = slo.enabled()
         self._slo: Dict[str, slo.Digest] = {}
-        self._last_commit_t = 0.0
         self._decode = self._build_decode()
         self._prefill_cache: Dict[int, Any] = {}
         self._chunk_fn = self._build_chunk_prefill()
@@ -785,10 +817,26 @@ class InferenceEngine:
         self._chunk_lock = threading.Lock()
         self._requests: Dict[str, Request] = {}  # live (uncompleted) ids
         self._req_lock = threading.Lock()
+        # the token ledger's row of the running iteration (`_account`): ns
+        # of each decode phase; the slots live after `install` and at the
+        # last iteration's end; the span dispatched (steps, prefill tokens
+        # since the last span); where the last iteration ended
+        self._phase_ns = {name: 0 for name, _sink in _DECODE_PHASES.values()}
+        self._live = self._live_at_end = 0
+        self._span: Optional[tuple] = None
+        self._iter_end_ns = 0
+        # padded prompt tokens of the bucket programs dispatched so far
+        # (the prefill thread writes, the decode thread reads), what the
+        # last span saw of it, and the chunk tokens dispatched since
+        self._bucket_tokens = 0
+        self._bucket_tokens_seen = 0
+        self._chunk_tokens = 0
 
-    # spec_decode.py times its legs of an iteration through the engine's
-    # own phases (it cannot import this module: this one imports it)
-    phase = staticmethod(decode_phase)
+    def phase(self, name: str, **attrs: Any) -> _Phase:
+        """`engine.<name>` on the decode thread (spec_decode.py times its
+        propose/verify legs through this too: it cannot import this
+        module, which imports it)."""
+        return _Phase(*_DECODE_PHASES[name], self._phase_ns, **attrs)
 
     def _refuse_for_stack(self, mesh, ecfg: EngineConfig) -> None:
         """What assumes that pages are the whole state of a request, or
@@ -1607,14 +1655,76 @@ class InferenceEngine:
         _ready between the recheck and the wait still wakes it)."""
         while not self._stop.is_set():
             if self._has_work():  # then step() progresses
-                with decode_phase("iter"):
-                    self.step()
+                self._iterate()
                 continue
             self._work.clear()
             if self._has_work() or self._stop.is_set():
                 continue
-            with decode_phase("idle"):
+            with self.phase("idle"):
                 self._work.wait(timeout=0.5)
+
+    def _iterate(self) -> None:
+        """One `engine.iter`, and its row of the token ledger."""
+        with self.phase("iter") as it:
+            self.step()
+        self._account(it)
+
+    def _account(self, it: _Phase) -> None:
+        """Close the iteration's row: each phase's time goes to the
+        sequences that waited through it. Through `chunk` and `install`
+        those are the slots live at the iteration's start, after `install`
+        the batch `step()` built (`_live`): a sequence joins part-way
+        through `install` and leaves part-way through `commit` or
+        `cancel_check`, which is what the sum can differ by from the
+        `decode` stage's (a fraction of two phases a request). What the
+        phases leave of the iteration is mostly its tail (a
+        finished request wakes its reader), so it goes to the slots live
+        at the end, like the time to the next iteration's start."""
+        ns, live = self._phase_ns, self._live
+        live_at_start = self._live_at_end  # only this thread frees a slot
+        live_at_end = self._live_at_end = sum(
+            1 for s in self.slots if s.request is not None)
+        if live_at_start or live:
+            chunk, chunk_wait = ns["engine.chunk"], ns["engine.chunk.readback"]
+            install, commit = ns["engine.install"], ns["engine.commit"]
+            dispatch, readback = ns["engine.dispatch"], ns["engine.readback"]
+            other = (ns["engine.cancel_check"] + ns["engine.build"]
+                     + ns["engine.propose"] + ns["engine.propose_wait"])
+            host = live_at_start * install + live * (commit + other)
+            rest = it.elapsed_ns - (chunk + install + other + dispatch
+                                    + readback + commit)
+            between = it.start_ns - self._iter_end_ns
+            for part, slot_ns in (
+                    ("chunk_host", live_at_start * (chunk - chunk_wait)),
+                    ("chunk_device_wait", live_at_start * chunk_wait),
+                    ("host", host),
+                    ("dispatch", live * dispatch),
+                    ("device_wait", live * readback),
+                    ("loop", live_at_start * between + live_at_end * rest)):
+                _token_wait[part].inc(slot_ns * 1e-9)
+            if self._span is not None:
+                steps, prefill_tokens = self._span
+                seconds, n_steps = _span_children[
+                    min((live - 1).bit_length(), 7)][prefill_tokens > 0]
+                seconds.inc((dispatch + readback) * 1e-9)
+                n_steps.inc(steps)
+                _m_interleaved.inc(live * prefill_tokens)
+        for name in ns:
+            ns[name] = 0
+        self._live = 0
+        self._span = None
+        self._iter_end_ns = it.start_ns + it.elapsed_ns
+
+    def _open_span(self, steps: int) -> Dict[str, int]:
+        """A decode dispatch is about to go out: what `engine.dispatch`
+        carries as attributes, and what `_account` files the span under."""
+        bucket_tokens = self._bucket_tokens
+        prefill_tokens = (bucket_tokens - self._bucket_tokens_seen
+                          + self._chunk_tokens)
+        self._bucket_tokens_seen, self._chunk_tokens = bucket_tokens, 0
+        self._span = (steps, prefill_tokens)
+        return {"live": self._live, "steps": steps,
+                "prefill_tokens": prefill_tokens}
 
     # ------------------------------------------------------------- prefill
     # Runs on its own thread so a long prompt never stalls the decode
@@ -1821,6 +1931,7 @@ class InferenceEngine:
             logits, cache = self._prefill_fn(bucket, Bpad)(
                 self.params, jnp.asarray(padded), jnp.asarray(lens)
             )
+            self._bucket_tokens += Bpad * bucket
             self._count_moe_rows(Bpad, bucket, sum(g[2] for g in group))
         # first generated tokens: one small readback, on THIS thread.
         # Sample every row BEFORE emitting/publishing anything: if this
@@ -2052,12 +2163,16 @@ class InferenceEngine:
         # chunk's KV slabs, so the streamed frames below need no
         # page-gather program (which would queue behind in-flight decode
         # spans)
-        logits, self.k_pages, self.v_pages, *kv, st.state = self._chunk_fn(
-            C, streaming)(
-            self.params, self.k_pages, self.v_pages, jnp.asarray(padded),
-            jnp.int32(start), jnp.asarray(st.table), jnp.int32(last_idx),
-            st.state,
-        )
+        with tracing.region("engine.chunk.put"):
+            placed = (jnp.asarray(padded), jnp.int32(start),
+                      jnp.asarray(st.table), jnp.int32(last_idx))
+        with tracing.region("engine.chunk.call"):
+            logits, self.k_pages, self.v_pages, *kv, st.state = \
+                self._chunk_fn(C, streaming)(
+                    self.params, self.k_pages, self.v_pages, *placed,
+                    st.state)
+            del placed  # as in `step()`
+        self._chunk_tokens += C
         self._count_moe_rows(1, C, len(toks))
         chunk_kv = (*kv, start) if streaming else None
         st.next_chunk += 1
@@ -2081,7 +2196,7 @@ class InferenceEngine:
             return True
         with self._chunk_lock:
             self._chunk_queue.pop(0)
-        with decode_phase("chunk.readback"):
+        with self.phase("chunk.readback"):
             logits_host = np.asarray(logits)
         first = _sample_host(logits_host, req.temperature,
                              req.top_p, req.top_k)
@@ -2135,24 +2250,25 @@ class InferenceEngine:
 
         Every iteration with active slots observes the per-phase timing
         histogram (serve_decode_step_phase_seconds, tagged phase+mode)."""
-        with decode_phase("chunk"):
+        with self.phase("chunk"):
             chunked = self._advance_chunk()
-        with decode_phase("install"):
+        with self.phase("install"):
             installed = self._install_ready()
         # Cancellation sweep: a request cancelled mid-decode (or mid-
         # speculation round) frees its slot at this step boundary instead
         # of riding out the span / the committed draft prefix.
-        with decode_phase("cancel_check") as ph:
+        with self.phase("cancel_check") as ph:
             for s in self.slots:
                 if s.request is not None and s.request.cancelled.is_set():
                     self._maybe_finish(s, -1)
             active = self._active()
         if not active:
             return installed or chunked
+        n_active = self._live = len(active)
         mode = "spec" if self._spec is not None else "plain"
         _step_phase["cancellation_check", mode].observe(ph.elapsed_s)
 
-        with decode_phase("build"):
+        with self.phase("build"):
             (tokens, positions, tables, temps, top_ps, top_ks,
              advanced) = self._build_batch()
             self._step_count += 1
@@ -2174,30 +2290,32 @@ class InferenceEngine:
             self._count_pages()
         if self._spec is not None:
             if self._step_spec(tokens, positions, tables, temps, top_ps,
-                               top_ks, advanced, key, len(active)):
+                               top_ks, advanced, key, n_active):
                 return True
             # zero-draft fallback: the (cheap) proposer found nothing to
             # draft anywhere in the batch this round — the plain span
             # below commits span tokens per slot where the S-wide verify
             # would commit exactly one
-        with decode_phase("dispatch") as ph:
-            seq, logps = self._run_decode(self._decode(span, advanced)(
-                self.params, self.k_pages, self.v_pages,
-                jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(tables), jnp.asarray(temps),
-                jnp.asarray(top_ps), jnp.asarray(top_ks), key, self.state,
-            ))
+        with self.phase("dispatch", **self._open_span(span)) as ph:
+            with tracing.region("engine.dispatch.put"):
+                placed = [jnp.asarray(a) for a in (
+                    tokens, positions, tables, temps, top_ps, top_ks)]
+            with tracing.region("engine.dispatch.call"):
+                seq, logps = self._run_decode(self._decode(span, advanced)(
+                    self.params, self.k_pages, self.v_pages, *placed, key,
+                    self.state))
+                # dropped while the program holds them: freed after the
+                # readback they cost 5 ms an iteration (chip, PR 36)
+                del placed
         _step_phase["verify", "plain"].observe(ph.elapsed_s)
-        with decode_phase("readback") as ph:
+        with self.phase("readback") as ph:
             seq = np.asarray(seq)  # [span, B] — one readback per span
             logps = np.asarray(logps)  # [span, B]
         _step_phase["sample", "plain"].observe(ph.elapsed_s)
-        with decode_phase("commit") as ph:
-            n_active = len(active)
+        with self.phase("commit") as ph:
             self._count_slot_steps(n_active, span)
             self._count_moe_rows(self.ecfg.max_batch_size, 1, n_active, span)
-            committed = self._commit_span(seq, logps, span)
-            self._note_tokens_per_step(committed, span * n_active)
+            self._tps_committed += self._commit_span(seq, logps, span)
         _step_phase["cache_bookkeeping", "plain"].observe(ph.elapsed_s)
         return True
 
@@ -2274,6 +2392,7 @@ class InferenceEngine:
             times * layers * live * self.cfg.num_selected_experts)
 
     def _count_slot_steps(self, n_active: int, steps: int) -> None:
+        self._tps_steps += n_active * steps
         _slot_active.inc(n_active * steps)
         _slot_empty.inc((self.ecfg.max_batch_size - n_active) * steps)
         if "ssm" in self.state or "gdn" in self.state:
@@ -2344,10 +2463,10 @@ class InferenceEngine:
             for phase in ("propose", "propose_wait", "propose_compute"):
                 _step_phase[phase, "spec"].observe(times[phase])
             return False
-        with decode_phase("commit") as ph:
+        with self.phase("commit") as ph:
             self._count_slot_steps(n_active, 1)
-            n_tokens = self._commit_spec(committed, n_comm, n_draft)
-            self._note_tokens_per_step(n_tokens, n_active)
+            self._tps_committed += self._commit_spec(committed, n_comm,
+                                                     n_draft)
         for phase in ("propose", "propose_wait", "propose_compute",
                       "verify", "sample"):
             _step_phase[phase, "spec"].observe(times[phase])
@@ -2406,24 +2525,6 @@ class InferenceEngine:
             d = slo.digest(name, {"role": self.slo_role})
             self._slo[name] = d
         return d
-
-    def _note_tokens_per_step(self, committed: int, participations: int
-                              ) -> None:
-        self._tps_committed += committed
-        self._tps_steps += participations
-        if self._tps_steps:
-            _m_tokens_per_step.set(self._tps_committed / self._tps_steps)
-        if committed and self._slo_on:
-            # time-between-tokens, count-weighted once per decode step (a
-            # per-token observe would pay the digest 32x per span for the
-            # same quantile information)
-            now = time.monotonic()
-            last = self._last_commit_t
-            # a gap bound keeps idle time between bursts out of the sketch
-            if last and now - last < 10.0:
-                self._slo_digest("serve_tbt_seconds").add(
-                    (now - last) / committed, n=committed)
-            self._last_commit_t = now
 
     def _maybe_finish(self, slot: _Slot, last_tok: int) -> None:
         req = slot.request

@@ -679,7 +679,7 @@ class SpecDecoder:
             else:
                 toks_bs = jnp.concatenate(
                     [jnp.asarray(tokens)[:, None], drafts[:, :m]], axis=1)
-        with decode_phase("dispatch") as verify:
+        with decode_phase("dispatch", **eng._open_span(1)) as verify:
             committed, n_comm, eng.k_pages, eng.v_pages = self._verify(
                 advanced)(
                 eng.params, eng.k_pages, eng.v_pages, toks_bs,

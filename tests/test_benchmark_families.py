@@ -39,6 +39,11 @@ from benchmark.tests.test_reference import (  # noqa: F401
     test_reference_agrees_with_the_programs_forward,
     test_sparse_reference_is_dropless_and_top2,
 )
+from benchmark.tests.test_token_ledger import (  # noqa: F401
+    test_ledger_readers_on_snapshots_records_and_a_recorded_trace,
+    test_the_eight_are_entries_of_the_five_serve_cells_and_no_train_cell,
+    test_the_recorded_trace_splits_put_from_call_under_their_phases,
+)
 from benchmark.tests.tiny import tiny_spec
 
 SAMBAY = "phi-4-mini-flash"
@@ -305,7 +310,12 @@ def test_olmo_hybrid_readers_reach_the_counts_through_the_family():
         "engine_idle_gap_attributed_share", "pool_copy_device_share",
         "decode_device_ms_per_step", "prefill_device_ms_per_ktok",
         "paged_decode_roofline", "gdn_step_roofline", "gdn_step_device_share",
-        "gdn_chunk_roofline", "recurrent_state_live_share"}
+        "gdn_chunk_roofline", "recurrent_state_live_share",
+        # the token ledger's eight (PR 36), in every serve cell
+        "tpot_device_wait_ms", "tpot_host_ms", "tpot_ready_ms",
+        "tpot_unaccounted_ms", "decode_step_wall_ms.clean",
+        "decode_step_wall_ms.shared", "interleaved_prefill_tokens_per_token",
+        "decode_live_slots.traced"}
     assert [m["name"] for m in cell["end_to_end"]] == ["tpot_mean_ms", "setup_s"]
     assert all(m["moves"] == "tpot_mean_ms" for m in cell["per_layer"])
 

@@ -6,6 +6,7 @@ plane by the benchmark's own reader (benchmark/program_spans.py)."""
 import glob
 import os
 import threading
+import time
 
 import jax
 import numpy as np
@@ -20,10 +21,11 @@ B, SPAN = 4, 4
 PROMPTS = (5, 20, 40, 70, 100, 12, 33, 64, 8, 90, 30, 50)  # 32 splits paths
 
 
-def _counter(name, **tags):
+def _counter(name, suffix="", **tags):
+    """Sum of the metric's samples `name + suffix` whose tags hold `tags`."""
     total = 0.0
     for sample, t, v in registry.get(name).samples():
-        if sample == name and set(tags.items()) <= set(t):
+        if sample == name + suffix and set(tags.items()) <= set(t):
             total += v
     return total
 
@@ -231,3 +233,200 @@ def test_stats_counts_the_chunk_queue(model):
     assert stats["chunking"] == [0, 3]  # 70 tokens: chunk 0 of 3
     engine._advance_chunk()
     assert engine.stats()["chunking"] == [1, 3]
+
+
+# -- the token ledger ---------------------------------------------------------
+
+PARTS = ("chunk_host", "chunk_device_wait", "host", "dispatch",
+         "device_wait", "loop")
+
+
+def _ledger():
+    out = {p: _counter("serve_token_wait_seconds", part=p) for p in PARTS}
+    out["decode_stage"] = _counter("serve_request_stage_seconds", "_sum",
+                                   stage="decode")
+    for shared in ("0", "1"):
+        out["span_s", shared] = _counter("serve_decode_span_seconds",
+                                         prefill=shared)
+        out["span_n", shared] = _counter("serve_decode_span_steps",
+                                         prefill=shared)
+    out["interleaved"] = _counter("serve_decode_interleaved_prefill_tokens")
+    for phase in ("iter", "chunk", "chunk_readback", "install",
+                  "cancel_check", "build", "dispatch", "readback", "commit"):
+        out["loop", phase] = _counter("serve_engine_loop_seconds", "_sum",
+                                      thread="decode", phase=phase)
+    return out
+
+
+def _delta(before, after):
+    return {k: after[k] - before[k] for k in after}
+
+
+def _by_hand(model, **kw):
+    """An engine whose threads never start: the test runs the prefill and
+    each iteration of the decode loop itself."""
+    from ray_tpu.serve.engine import Request
+
+    engine = _engine(model, **kw)
+    engine._ensure_loop = lambda: None
+
+    def prefill(lens, max_tokens, seed):
+        reqs = [Request(f"r{seed}-{i}", p, max_tokens=max_tokens)
+                for i, p in enumerate(_prompts(model[1], lens, seed=seed))]
+        for r in reqs:
+            engine.add_request(r)
+        engine._prefill_batch([engine.pending.get() for _ in reqs])
+        return reqs
+
+    return engine, prefill
+
+
+def test_the_ledgers_parts_sum_to_the_decode_stage(model):
+    engine = _engine(model, max_seq_len=256, max_pages=96)
+    lens = (5, 20, 40, 70, 100, 12, 33, 64)
+
+    def run(seed):
+        prompts = _prompts(model[1], lens, seed=seed)
+        threads = [threading.Thread(
+            target=lambda p=p: engine.generate(p, max_tokens=60))
+            for p in prompts]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=180)
+        # the last iteration closes its row after the last answer is out
+        while engine._has_work() or engine._live_at_end:
+            time.sleep(0.01)
+        time.sleep(0.05)
+
+    run(4)  # every shape compiled, inside no phase of the measured run
+    before = _ledger()
+    run(5)
+    d = _delta(before, _ledger())
+    engine.stop()
+    # 8 answers of 15 spans over 4 slots: they overlap, join and leave
+    assert d["decode_stage"] > 0 and all(d[p] > 0 for p in PARTS)
+    assert sum(d[p] for p in PARTS) == pytest.approx(d["decode_stage"],
+                                                     rel=0.01)
+    # the device's share of a span is in the spans' wall time too
+    assert d["span_s", "0"] + d["span_s", "1"] == pytest.approx(
+        d["loop", "dispatch"] + d["loop", "readback"], rel=1e-6)
+    assert (d["span_n", "0"] + d["span_n", "1"]) % SPAN == 0
+
+
+def test_an_iteration_with_three_live_slots_adds_three_times_its_phases(
+        model):
+    engine, prefill = _by_hand(model)
+    prefill((5, 9, 14), 40, seed=6)
+    engine._iterate()  # installs the three and decodes a span
+    assert engine.stats()["active"] == 3
+    between = tracing.now_ns()
+    time.sleep(0.02)  # the time between two iterations counts
+    before = _ledger()
+    engine._iterate()
+    d = _delta(before, _ledger())
+    engine.stop()
+    loop = {k[1]: v for k, v in d.items() if isinstance(k, tuple)
+            and k[0] == "loop"}
+    assert d["device_wait"] == pytest.approx(3 * loop["readback"])
+    assert d["dispatch"] == pytest.approx(3 * loop["dispatch"])
+    assert d["host"] == pytest.approx(3 * (
+        loop["install"] + loop["cancel_check"] + loop["build"]
+        + loop["commit"]))
+    assert d["chunk_host"] == pytest.approx(3 * loop["chunk"])
+    assert d["chunk_device_wait"] == 0
+    phases = sum(loop[p] for p in ("chunk", "install", "cancel_check",
+                                   "build", "dispatch", "readback", "commit"))
+    waited = (tracing.now_ns() - between) * 1e-9
+    assert 3 * (loop["iter"] - phases + 0.02) <= d["loop"] <= 3 * waited
+    assert sum(d[p] for p in PARTS) <= 3 * waited
+    # three sequences, no prefill since the last span: 4 clean steps
+    assert (d["span_n", "0"], d["span_n", "1"]) == (SPAN, 0)
+    assert d["span_s", "0"] == pytest.approx(
+        loop["dispatch"] + loop["readback"])
+    assert _counter("serve_decode_span_steps", live_le="4", prefill="0") > 0
+
+
+def test_a_span_after_prefill_counts_as_shared_with_its_tokens(model):
+    engine, prefill = _by_hand(model)
+    prefill((5, 9), 40, seed=7)
+    before = _ledger()
+    engine._iterate()
+    d = _delta(before, _ledger())
+    # the bucket program of the two prompts went out before the first span:
+    # 2 rows of the 16-token bucket, and two sequences waited behind them
+    assert (d["span_n", "0"], d["span_n", "1"]) == (0, SPAN)
+    assert d["interleaved"] == 2 * (2 * 16)
+    before = _ledger()
+    engine._iterate()
+    d = _delta(before, _ledger())
+    assert (d["span_n", "0"], d["span_n", "1"]) == (SPAN, 0)
+    assert d["interleaved"] == 0
+    # a prompt of three chunks: each iteration runs one chunk of 32, then
+    # a span of the two live sequences
+    prefill((70,), 4, seed=8)
+    for live in (2, 2, 3):  # the last chunk's sequence joins its span
+        before = _ledger()
+        engine._iterate()
+        d = _delta(before, _ledger())
+        assert (d["span_n", "0"], d["span_n", "1"]) == (0, SPAN)
+        assert d["interleaved"] == live * 32
+        assert d["chunk_host"] > 0
+    assert d["chunk_device_wait"] > 0  # the last chunk reads its logits back
+    engine.stop()
+
+
+def test_dispatch_carries_the_live_slots_and_splits_put_from_call(traced_run):
+    from benchmark import program_spans
+
+    spans = program_spans.read_file(traced_run["xplane"])
+    dispatches = spans.named("engine.dispatch")
+    assert dispatches
+    for r in dispatches:
+        assert 1 <= int(r.attrs["live"]) <= B
+        assert int(r.attrs["steps"]) == SPAN
+        assert int(r.attrs["prefill_tokens"]) % 16 == 0
+        assert [c.name for c in r.children] == [
+            "engine.dispatch.put", "engine.dispatch.call"]
+    # both prefill paths ran beside the decode spans
+    assert any(int(r.attrs["prefill_tokens"]) > 0 for r in dispatches)
+    chunks = [r for r in spans.named("engine.chunk") if r.children]
+    assert chunks
+    for r in chunks:
+        assert [c.name for c in r.children][:2] == [
+            "engine.chunk.put", "engine.chunk.call"]
+
+
+def test_no_annotation_is_built_without_a_profiler_session(model,
+                                                           monkeypatch):
+    built = []
+
+    class Annotation:
+        enabled = False
+
+        def __init__(self, name, **attrs):
+            built.append((name, attrs))
+
+        @classmethod
+        def is_enabled(cls):
+            return cls.enabled
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(tracing, "_annotation", Annotation)
+    engine, prefill = _by_hand(model)
+    prefill((5,), 40, seed=9)
+    engine._iterate()
+    assert built == []
+    Annotation.enabled = True
+    engine._iterate()
+    engine.stop()
+    names = [n for n, _ in built]
+    assert {"engine.iter", "engine.dispatch", "engine.dispatch.put",
+            "engine.dispatch.call"} <= set(names)
+    assert dict(built)["engine.dispatch"] == {
+        "live": 1, "steps": SPAN, "prefill_tokens": 0}
