@@ -40,12 +40,14 @@ import os
 import queue
 import threading
 import time
+import types
 from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from ..core.config import config
 from ..core.logging import get_logger
@@ -217,7 +219,7 @@ def _prefill_phase(name: str) -> "_Phase":
 class _Phase(tracing.region):
     """A region that also feeds its `serve_engine_loop_seconds` child and,
     on the decode thread, the iteration's row of the token ledger
-    (`InferenceEngine._account`)."""
+    (`ledger`: `InferenceEngine._phase_done`, read by `_account`)."""
 
     __slots__ = ("_sink", "_ledger")
 
@@ -230,7 +232,7 @@ class _Phase(tracing.region):
         super().__exit__(*exc)
         self._sink.observe(self.elapsed_ns * 1e-9)
         if self._ledger is not None:
-            self._ledger[self.name] += self.elapsed_ns
+            self._ledger(self.name, self.elapsed_ns)
         return False
 
 
@@ -246,16 +248,19 @@ _m_token_wait = Counter(
     "engine.chunk.readback; host: install + cancel_check + build + commit, "
     "propose legs under speculation; dispatch; device_wait: "
     "engine.readback; loop: the iteration's remainder and the time between "
-    "iterations).")
+    "iterations). A host phase that ends while a dispatched span is still "
+    "unfinished kept no sequence waiting for the host: its seconds are "
+    "device_wait (chunk_device_wait for engine.chunk).")
 _token_wait = {p: _m_token_wait.labels(part=p) for p in (
     "chunk_host", "chunk_device_wait", "host", "dispatch", "device_wait",
     "loop")}
 _m_span_seconds = Counter(
     "serve_decode_span_seconds",
-    "Wall time of decode spans (engine.dispatch + engine.readback), by the "
-    "live slots in the span (live_le) and whether prefill programs were "
-    "dispatched since the last span (prefill=1: a chunk this iteration or "
-    "a bucket program of the prefill thread).")
+    "Wall time of decode spans on the device's queue (from the later of "
+    "the span's dispatch and the readback before it, to its own readback), "
+    "by the slots the span was dispatched with (live_le) and whether "
+    "prefill programs went out before it since the last span (prefill=1: a "
+    "chunk or a bucket program of the prefill thread).")
 _m_span_steps = Counter(
     "serve_decode_span_steps",
     "Decode steps of the spans in serve_decode_span_seconds, same labels.")
@@ -264,6 +269,11 @@ _span_children = [
     [(_m_span_seconds.labels(live_le=le, prefill=p),
       _m_span_steps.labels(live_le=le, prefill=p)) for p in ("0", "1")]
     for le in ("1", "2", "4", "8", "16", "32", "64", "+Inf")]
+_m_ahead = Counter(
+    "serve_decode_ahead_steps",
+    "Decode steps of the spans that were dispatched while the span before "
+    "them was unfinished on the device: over serve_decode_span_steps, the "
+    "share of steps the device found queued when it finished the last.")
 _m_interleaved = Counter(
     "serve_decode_interleaved_prefill_tokens",
     "At each decode span, live slots x the prefill tokens (padded, as the "
@@ -315,26 +325,28 @@ class EngineConfig:
     cache_dtype: str = "bfloat16"
     # Decode steps per device dispatch (vLLM multi-step scheduling
     # analogue): sampling stays on device and K tokens come back per
-    # round-trip, amortizing dispatch/readback latency. Tokens stream in
-    # bursts of K and waiting prefills join between spans; K is clamped to
-    # the smallest remaining token budget among active slots. 1 = classic
+    # readback. Tokens stream in bursts of K, and a span boundary is the
+    # only point where a prefilled request can enter the batch or a
+    # finished one leave it: a slot whose answer ends inside a span decodes
+    # to the span's end for nothing, at most K - 1 steps. 1 = classic
     # per-token stepping.
-    # tokens decoded per jitted call (multi-step span): higher amortizes
-    # dispatch + readback (16 vs 4 measured +43% decode tok/s on v5e, and
-    # wall -35% on the 24-request bench) at the cost of coarser install
-    # granularity — a span boundary is the only point where a prefilled
-    # request can enter the batch. An adaptive short-span-near-FINISH
-    # variant measured WORSE on homogeneous budgets (extra dispatches, no
-    # TTFT win); the adaptive knob that DOES pay is prefill-pressure-based
-    # (below), which round-3 TTFT regression data motivated (VERDICT r3
-    # #2: span=16 held arriving prefills behind 16 uninterruptible steps).
-    decode_span: int = 16
+    # The loop runs one span ahead (`InferenceEngine.step`): the next span
+    # is dispatched from the device's own carry before this one is read
+    # back, so the placements, the call, the readback and the host's
+    # commit pass while the device computes and a longer span buys no
+    # throughput. (16 against 4 once measured +43% decode tok/s on v5e:
+    # that was the dispatch and the readback on every token's path.) What
+    # the length still sets is how many decode steps a program that
+    # arrives in a quiet period finds queued ahead of it: two spans of 8,
+    # where one of 16 was.
+    decode_span: int = 8
     # While a prefill is queued or running, decode spans shrink to this so
     # the single device yields quickly and first tokens (which come from
     # the PREFILL program) aren't pinned behind a long decode span —
     # vLLM-style prefill priority without chunking the prefill itself.
     # Once the prefill backlog drains, spans return to decode_span. At
-    # most two decode programs compile (busy_span and decode_span).
+    # most two decode programs compile (busy_span and decode_span), and a
+    # prefill program finds at most two busy spans queued ahead of it.
     # busy=4 measured best TTFT at ~5% req/s cost vs 16 on the 24-req
     # burst (1.15s vs 1.39s p50); busy=1 stalls decode behind per-token
     # dispatch latency when the backlog is long.
@@ -561,6 +573,34 @@ class _Slot:
         self.generated = 0
 
 
+class _Span:
+    """A decode span from its dispatch to its commit: what came out of the
+    program (still on the device), and what the host needs to commit it
+    without looking at `engine.slots`, which may have moved on."""
+
+    __slots__ = ("seq", "logps", "steps", "members", "prefill_tokens",
+                 "dispatched_ns", "release")
+
+    def __init__(self, steps: int, members: Dict[int, Request],
+                 prefill_tokens: int):
+        self.seq = self.logps = None  # [steps, B], on the device
+        self.steps = steps
+        # slot index -> the request the slot held at dispatch: the span
+        # commits to these and to nobody who took a slot since
+        self.members = members
+        self.prefill_tokens = prefill_tokens  # dispatched since the last span
+        self.dispatched_ns = 0  # the end of its `engine.dispatch`
+        # page lists of members that ended after this span went out with
+        # their tables: freed once it has been read back
+        self.release: List[List[int]] = []
+
+    @property
+    def attrs(self) -> Dict[str, int]:
+        """What the span's `engine.dispatch` region carries."""
+        return {"live": len(self.members), "steps": self.steps,
+                "prefill_tokens": self.prefill_tokens}
+
+
 class PrefixCache:
     """Content-addressed prompt pages (vLLM automatic-prefix-caching
     analogue). A full page's KV is a pure function of the token prefix
@@ -725,8 +765,6 @@ class InferenceEngine:
                 "install_state"),
             donate_argnums=(0,))
         if mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-
             from ..models.transformer import param_axes
             from ..parallel.sharding import tree_shardings
 
@@ -817,14 +855,34 @@ class InferenceEngine:
         self._chunk_lock = threading.Lock()
         self._requests: Dict[str, Request] = {}  # live (uncompleted) ids
         self._req_lock = threading.Lock()
+        # The decode loop runs one span ahead (`step`): the span that is
+        # dispatched and not read back yet; the `tokens` and `positions`
+        # the last span's scan ended on, which the next span's continuing
+        # slots start from without a readback; when the last readback
+        # returned
+        self._inflight: Optional[_Span] = None
+        carry = (jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32))
+        if mesh is not None:
+            carry = jax.device_put(
+                carry, NamedSharding(mesh, PartitionSpec()))
+        self._carry = carry
+        self._read_ns = 0
+        # weight swaps that `update_params` posted for the decode thread,
+        # which drains the span in flight before it rebinds
+        self._swaps: List[Any] = []
+        self._loop_done = False
         # the token ledger's row of the running iteration (`_account`): ns
-        # of each decode phase; the slots live after `install` and at the
-        # last iteration's end; the span dispatched (steps, prefill tokens
-        # since the last span); where the last iteration ended
-        self._phase_ns = {name: 0 for name, _sink in _DECODE_PHASES.values()}
+        # of each decode phase, by whether a dispatched span was unfinished
+        # when the phase ended (`_hidden_ns`: the sequences waited for the
+        # device, not for the host); the slots live after `install` and at
+        # the last iteration's end; where the last iteration ended, and
+        # whether the device was busy when this one began
+        self._phase_ns = {name: 0 for name, _sink in _DECODE_PHASES.values()
+                          if name not in ("engine.iter", "engine.idle")}
+        self._hidden_ns = dict(self._phase_ns)
         self._live = self._live_at_end = 0
-        self._span: Optional[tuple] = None
         self._iter_end_ns = 0
+        self._began_busy = False
         # padded prompt tokens of the bucket programs dispatched so far
         # (the prefill thread writes, the decode thread reads), what the
         # last span saw of it, and the chunk tokens dispatched since
@@ -836,7 +894,23 @@ class InferenceEngine:
         """`engine.<name>` on the decode thread (spec_decode.py times its
         propose/verify legs through this too: it cannot import this
         module, which imports it)."""
-        return _Phase(*_DECODE_PHASES[name], self._phase_ns, **attrs)
+        return _Phase(*_DECODE_PHASES[name],
+                      None if name in ("iter", "idle") else self._phase_done,
+                      **attrs)
+
+    def _device_busy(self) -> bool:
+        """Is a dispatched span still unfinished? Asks the runtime about
+        the output of the one in flight (a span that was read back is
+        finished); no sync."""
+        span = self._inflight
+        return span is not None and not span.seq.is_ready()
+
+    def _phase_done(self, name: str, ns: int) -> None:
+        """A decode phase's time, filed by what the sequences were waiting
+        for while it ran: a host phase that ends with a span unfinished on
+        the device delayed no token."""
+        hidden = not name.endswith("readback") and self._device_busy()
+        (self._hidden_ns if hidden else self._phase_ns)[name] += ns
 
     def _refuse_for_stack(self, mesh, ecfg: EngineConfig) -> None:
         """What assumes that pages are the whole state of a request, or
@@ -880,17 +954,26 @@ class InferenceEngine:
 
     def _build_decode(self):
         """Jit a K-step decode: lax.scan over the single-step body with
-        device-side sampling feeding the next step. One dispatch + one
-        [K,B] readback per span. Cached per K (K varies only near request
-        completion)."""
+        device-side sampling feeding the next step, and the scan's last
+        tokens and positions handed on to the next span on the device. One
+        dispatch + one [K,B] readback per span. Cached per K (decode_span
+        and busy_span)."""
         cfg, ps = self.cfg, self.ecfg.page_size
 
         def decode_span(params, k_pages, v_pages, tokens, positions,
                         page_tables, temps, top_ps, top_ks, key, state=None,
-                        *, n_steps, advanced):
+                        carry=None, *, n_steps, advanced):
             """tokens/positions [B]; page_tables [B, pages_per_seq]; `state`:
             what the layers keep per slot beside their pages (None: the
-            empty tree). -> seq/logps [n_steps, B], the pool, the state."""
+            empty tree); `carry`: (tokens, positions, fresh), the [B] pair
+            the span before ended on and a [B] mask of the slots that take
+            the host's `tokens` / `positions` instead (new since that span;
+            None: all of them). -> seq/logps [n_steps, B], the pool, the
+            state, and the (tokens, positions) this span ended on."""
+            if carry is not None:
+                carried_tokens, carried_positions, fresh = carry
+                tokens = jnp.where(fresh, tokens, carried_tokens)
+                positions = jnp.where(fresh, positions, carried_positions)
 
             def step(carry, i):
                 tokens, positions, k_pages, v_pages, state = carry
@@ -925,12 +1008,20 @@ class InferenceEngine:
                 return (toks, positions + 1, k_pages, v_pages, state), (
                     toks, logps)
 
-            (_, _, k_pages, v_pages, state), (seq, logps) = jax.lax.scan(
-                step, (tokens, positions, k_pages, v_pages, state or {}),
-                jnp.arange(n_steps))
-            return seq, logps, k_pages, v_pages, state
+            (tokens, positions, k_pages, v_pages, state), (seq, logps) = \
+                jax.lax.scan(
+                    step, (tokens, positions, k_pages, v_pages, state or {}),
+                    jnp.arange(n_steps))
+            return seq, logps, k_pages, v_pages, state, (tokens, positions)
 
         cache: Dict[Any, Any] = {}
+        pinned: Dict[str, Any] = {}
+        if self.mesh is not None:
+            # the carry comes back as it goes in, replicated: left to the
+            # partitioner's choice, another sharding would be another
+            # program at the next call, compiled inside traffic
+            whole = NamedSharding(self.mesh, PartitionSpec())
+            pinned["out_shardings"] = (None,) * 5 + ((whole, whole),)
 
         def for_span(n_steps: int, advanced: bool = False):
             # `advanced` compiles the top-k/top-p sampler (one vocab sort
@@ -944,7 +1035,7 @@ class InferenceEngine:
                                           advanced=advanced),
                         f"decode_span_{n_steps}"
                         + ("_adv" if advanced else "")),
-                    donate_argnums=(1, 2, 10),
+                    donate_argnums=(1, 2, 10), **pinned,
                 ))
             return cache[key_]
 
@@ -1060,6 +1151,7 @@ class InferenceEngine:
                     jnp.zeros((B,), jnp.float32),
                     jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.int32),
                     jax.random.PRNGKey(0), self.state,
+                    (*self._carry, jnp.ones((B,), bool)),
                 ))[0]
                 _np.asarray(seq)  # block until compiled + executed
         if self.ecfg.chunked_prefill:
@@ -1079,9 +1171,11 @@ class InferenceEngine:
             self._spec.warmup()
 
     def _run_decode(self, out) -> tuple:
-        """Take back what a decode program was handed by donation: the
-        pool and the per-slot state. -> (seq, logps)."""
-        seq, logps, self.k_pages, self.v_pages, self.state = out
+        """Take back what a decode program was handed by donation, the
+        pool and the per-slot state, and keep what its scan ended on for
+        the next span. -> (seq, logps)."""
+        (seq, logps, self.k_pages, self.v_pages, self.state,
+         self._carry) = out
         return seq, logps
 
     def _prefill_fn(self, bucket: int, batch: int = 1):
@@ -1627,6 +1721,7 @@ class InferenceEngine:
         with self._lock:
             if self._loop_thread is None or not self._loop_thread.is_alive():
                 self._stop.clear()
+                self._loop_done = False
                 self._loop_thread = threading.Thread(
                     target=self._loop, daemon=True, name="engine-decode"
                 )
@@ -1641,6 +1736,8 @@ class InferenceEngine:
         return [s for s in self.slots if s.request is not None]
 
     def _has_work(self) -> bool:
+        if self._inflight is not None or self._swaps:
+            return True  # a span to read back, weights to rebind
         with self._ready_lock:
             if self._ready:
                 return True
@@ -1652,7 +1749,10 @@ class InferenceEngine:
     def _loop(self):
         """Decode thread. Runs until stop(); when idle it blocks on the
         _work event (clear → recheck → wait, so a prefill publishing to
-        _ready between the recheck and the wait still wakes it)."""
+        _ready between the recheck and the wait still wakes it). It leaves
+        nothing behind: the span in flight is read back and committed, and
+        posted weights are bound (`update_params` takes the same lock to
+        learn whether this thread will still do it)."""
         while not self._stop.is_set():
             if self._has_work():  # then step() progresses
                 self._iterate()
@@ -1662,9 +1762,13 @@ class InferenceEngine:
                 continue
             with self.phase("idle"):
                 self._work.wait(timeout=0.5)
+        with self._lock:
+            self._swap_params()
+            self._loop_done = True
 
     def _iterate(self) -> None:
         """One `engine.iter`, and its row of the token ledger."""
+        self._began_busy = self._device_busy()
         with self.phase("iter") as it:
             self.step()
         self._account(it)
@@ -1673,58 +1777,90 @@ class InferenceEngine:
         """Close the iteration's row: each phase's time goes to the
         sequences that waited through it. Through `chunk` and `install`
         those are the slots live at the iteration's start, after `install`
-        the batch `step()` built (`_live`): a sequence joins part-way
-        through `install` and leaves part-way through `commit` or
+        the slots that hold a sequence (`_live`): a sequence joins
+        part-way through `install` and leaves part-way through `commit` or
         `cancel_check`, which is what the sum can differ by from the
         `decode` stage's (a fraction of two phases a request). What the
         phases leave of the iteration is mostly its tail (a
         finished request wakes its reader), so it goes to the slots live
-        at the end, like the time to the next iteration's start."""
-        ns, live = self._phase_ns, self._live
+        at the end, like the time to the next iteration's start.
+
+        WHICH part a phase's time is depends on what the sequences waited
+        for (`_phase_done`): with a dispatched span unfinished at the
+        phase's end the device set their pace, not the host, and the time
+        is `device_wait` (`chunk_device_wait` for `engine.chunk`), like
+        the readbacks'. So `host`, `dispatch`, `chunk_host` and `loop` are
+        what the host still costs a token once the loop runs ahead."""
+        ns, hid, live = self._phase_ns, self._hidden_ns, self._live
         live_at_start = self._live_at_end  # only this thread frees a slot
         live_at_end = self._live_at_end = sum(
             1 for s in self.slots if s.request is not None)
         if live_at_start or live:
-            chunk, chunk_wait = ns["engine.chunk"], ns["engine.chunk.readback"]
-            install, commit = ns["engine.install"], ns["engine.commit"]
-            dispatch, readback = ns["engine.dispatch"], ns["engine.readback"]
-            other = (ns["engine.cancel_check"] + ns["engine.build"]
-                     + ns["engine.propose"] + ns["engine.propose_wait"])
-            host = live_at_start * install + live * (commit + other)
-            rest = it.elapsed_ns - (chunk + install + other + dispatch
-                                    + readback + commit)
-            between = it.start_ns - self._iter_end_ns
+            chunk_wait, readback = (ns["engine.chunk.readback"],
+                                    ns["engine.readback"])
+            chunk, chunk_hid = ns["engine.chunk"], hid["engine.chunk"]
+            if chunk_hid:  # the readback is inside whichever it was
+                chunk_hid -= chunk_wait
+            else:
+                chunk -= chunk_wait
+            others = ("engine.commit", "engine.cancel_check", "engine.build",
+                      "engine.propose", "engine.propose_wait")
+            other, other_hid = (sum(d[n] for n in others) for d in (ns, hid))
+            phases = sum(ns.values()) + sum(hid.values()) - chunk_wait
+            rest = live_at_end * (it.elapsed_ns - phases)
+            between = live_at_start * (it.start_ns - self._iter_end_ns)
+            waited = (live * (readback + other_hid + hid["engine.dispatch"])
+                      + live_at_start * hid["engine.install"])
+            loop = 0
+            for slot_ns, busy in ((between, self._began_busy),
+                                  (rest, self._device_busy())):
+                if busy:
+                    waited += slot_ns
+                else:
+                    loop += slot_ns
             for part, slot_ns in (
-                    ("chunk_host", live_at_start * (chunk - chunk_wait)),
-                    ("chunk_device_wait", live_at_start * chunk_wait),
-                    ("host", host),
-                    ("dispatch", live * dispatch),
-                    ("device_wait", live * readback),
-                    ("loop", live_at_start * between + live_at_end * rest)):
+                    ("chunk_host", live_at_start * chunk),
+                    ("chunk_device_wait",
+                     live_at_start * (chunk_wait + chunk_hid)),
+                    ("host", live_at_start * ns["engine.install"]
+                     + live * other),
+                    ("dispatch", live * ns["engine.dispatch"]),
+                    ("device_wait", waited),
+                    ("loop", loop)):
                 _token_wait[part].inc(slot_ns * 1e-9)
-            if self._span is not None:
-                steps, prefill_tokens = self._span
-                seconds, n_steps = _span_children[
-                    min((live - 1).bit_length(), 7)][prefill_tokens > 0]
-                seconds.inc((dispatch + readback) * 1e-9)
-                n_steps.inc(steps)
-                _m_interleaved.inc(live * prefill_tokens)
-        for name in ns:
-            ns[name] = 0
+        for d in (ns, hid):
+            for name in d:
+                d[name] = 0
         self._live = 0
-        self._span = None
-        self._iter_end_ns = it.start_ns + it.elapsed_ns
+        self._iter_end_ns = it.end_ns
 
-    def _open_span(self, steps: int) -> Dict[str, int]:
-        """A decode dispatch is about to go out: what `engine.dispatch`
-        carries as attributes, and what `_account` files the span under."""
+    def _open_span(self, steps: int,
+                   members: Optional[Dict[int, Request]] = None) -> _Span:
+        """A decode dispatch is about to go out with `members` (slot ->
+        request; None: every live slot): the span, which takes with it
+        the prefill tokens dispatched since the last one."""
+        if members is None:
+            members = {i: s.request for i, s in enumerate(self.slots)
+                       if s.request is not None}
         bucket_tokens = self._bucket_tokens
         prefill_tokens = (bucket_tokens - self._bucket_tokens_seen
                           + self._chunk_tokens)
         self._bucket_tokens_seen, self._chunk_tokens = bucket_tokens, 0
-        self._span = (steps, prefill_tokens)
-        return {"live": self._live, "steps": steps,
-                "prefill_tokens": prefill_tokens}
+        return _Span(steps, members, prefill_tokens)
+
+    def _span_read(self, span: _Span, ph: _Phase) -> None:
+        """`engine.readback` of `span` has returned: file its wall time on
+        the device's queue, which began when it was dispatched or, if the
+        device was still on the span before, when that one's readback
+        returned; under the slots and the prefill tokens it went out with."""
+        read_ns = ph.end_ns
+        live = len(span.members)
+        seconds, n_steps = _span_children[
+            min((live - 1).bit_length(), 7)][span.prefill_tokens > 0]
+        seconds.inc((read_ns - max(span.dispatched_ns, self._read_ns)) * 1e-9)
+        n_steps.inc(span.steps)
+        _m_interleaved.inc(live * span.prefill_tokens)
+        self._read_ns = read_ns
 
     # ------------------------------------------------------------- prefill
     # Runs on its own thread so a long prompt never stalls the decode
@@ -2235,18 +2371,44 @@ class InferenceEngine:
 
     def step(self) -> bool:
         """One engine iteration: advance at most one prefill CHUNK, install
-        finished prefills, then a K-step decode span for the whole active
-        batch (K = decode_span, or busy_span under prefill pressure — at
-        most two decode programs ever compile). A slot that finishes
-        mid-span keeps decoding to span end; its extra tokens are discarded
-        by the host loop, and its extra KV writes are harmless — table
-        entries past the allocated pages are 0 (the reserved trash page),
-        and page frees happen on the host only after this span's readback,
-        so no recycled page can be written. Returns True if work happened.
+        finished prefills, then dispatch a K-step decode span for the
+        active batch (K = decode_span, or busy_span under prefill pressure
+        — at most two decode programs ever compile) and only then read
+        back and commit the span that the iteration BEFORE dispatched.
+        The loop runs one span ahead: span N+1 starts from the `tokens` and
+        `positions` span N's scan ended on, which never leave the device,
+        and the host's phases pass while the device computes. Returns True
+        if work happened.
 
-        With speculation enabled (EngineConfig.speculation) the span is
-        replaced by ONE propose-k/verify-once round per iteration
-        committing 1..k+1 tokens per slot (spec_decode.SpecDecoder).
+        What the host knows at build time decides who is in span N+1:
+        - a slot whose answer ends by `max_tokens` inside span N (unread,
+          but its length is known) is left out, and leaves at commit N;
+        - a slot installed since N went out joins with the host's token
+          and position (`fresh` in the program);
+        - an ending the host cannot foresee (eos, a stop sequence, a
+          cancel) finds the slot in N+1 already. The request finishes at
+          commit N; N+1 commits to the requests it was dispatched WITH
+          (`_Span.members`), so that column is dropped whoever holds the
+          slot by then.
+
+        A slot that finishes mid-span keeps decoding to span end; its
+        extra tokens are discarded by the host loop, and its extra KV
+        writes are harmless: table entries past the allocated pages are 0
+        (the reserved trash page), and a sequence's pages are freed only
+        once the LAST span dispatched with its table has been read back
+        (`_maybe_finish` hands them to that span's `release`), so no
+        recycled page can be written. Every program that writes the pool or
+        the per-slot state is dispatched from this thread and takes both by
+        donation, so the device runs them in this order whatever the host
+        has read.
+
+        Where the loop must know span N's tokens before it acts it drains
+        first, which is this same pipeline at depth 0: speculation
+        (EngineConfig.speculation: ONE propose-k/verify-once round per
+        iteration committing 1..k+1 tokens per slot, spec_decode.SpecDecoder,
+        whose proposer reads committed tokens), `update_params` (a span
+        dispatched under the old weights commits under their
+        `weights_version`), `stop()`, and an engine with nothing live.
 
         Every iteration with active slots observes the per-phase timing
         histogram (serve_decode_step_phase_seconds, tagged phase+mode)."""
@@ -2254,6 +2416,8 @@ class InferenceEngine:
             chunked = self._advance_chunk()
         with self.phase("install"):
             installed = self._install_ready()
+        if self._swaps:
+            self._swap_params()
         # Cancellation sweep: a request cancelled mid-decode (or mid-
         # speculation round) frees its slot at this step boundary instead
         # of riding out the span / the committed draft prefix.
@@ -2262,17 +2426,17 @@ class InferenceEngine:
                 if s.request is not None and s.request.cancelled.is_set():
                     self._maybe_finish(s, -1)
             active = self._active()
+        prev = self._inflight
         if not active:
-            return installed or chunked
+            self._drain()
+            return installed or chunked or prev is not None
         n_active = self._live = len(active)
         mode = "spec" if self._spec is not None else "plain"
         _step_phase["cancellation_check", mode].observe(ph.elapsed_s)
 
         with self.phase("build"):
-            (tokens, positions, tables, temps, top_ps, top_ks,
-             advanced) = self._build_batch()
-            self._step_count += 1
-            key = jax.random.fold_in(self._base_key, self._step_count)
+            (members, tokens, positions, tables, temps, top_ps, top_ks,
+             fresh, advanced) = self._build_batch(prev)
             # Adaptive span (VERDICT r3 #2): while prefill work is queued
             # or running, shrink the span so the device yields between
             # decode dispatches and arriving requests get their first
@@ -2288,7 +2452,12 @@ class InferenceEngine:
             else:
                 span = max(1, self.ecfg.decode_span)
             self._count_pages()
+            if members:
+                self._step_count += 1
+                key = jax.random.fold_in(self._base_key, self._step_count)
         if self._spec is not None:
+            # drained by now: nothing stays in flight under speculation, so
+            # every live slot is a member
             if self._step_spec(tokens, positions, tables, temps, top_ps,
                                top_ks, advanced, key, n_active):
                 return True
@@ -2296,31 +2465,65 @@ class InferenceEngine:
             # draft anywhere in the batch this round — the plain span
             # below commits span tokens per slot where the S-wide verify
             # would commit exactly one
-        with self.phase("dispatch", **self._open_span(span)) as ph:
-            with tracing.region("engine.dispatch.put"):
-                placed = [jnp.asarray(a) for a in (
-                    tokens, positions, tables, temps, top_ps, top_ks)]
-            with tracing.region("engine.dispatch.call"):
-                seq, logps = self._run_decode(self._decode(span, advanced)(
-                    self.params, self.k_pages, self.v_pages, *placed, key,
-                    self.state))
-                # dropped while the program holds them: freed after the
-                # readback they cost 5 ms an iteration (chip, PR 36)
-                del placed
-        _step_phase["verify", "plain"].observe(ph.elapsed_s)
-        with self.phase("readback") as ph:
-            seq = np.asarray(seq)  # [span, B] — one readback per span
-            logps = np.asarray(logps)  # [span, B]
-        _step_phase["sample", "plain"].observe(ph.elapsed_s)
-        with self.phase("commit") as ph:
-            self._count_slot_steps(n_active, span)
-            self._count_moe_rows(self.ecfg.max_batch_size, 1, n_active, span)
-            self._tps_committed += self._commit_span(seq, logps, span)
-        _step_phase["cache_bookkeeping", "plain"].observe(ph.elapsed_s)
+        cur = None  # every live slot may end inside `prev`
+        if members:
+            cur = self._open_span(span, members)
+            with self.phase("dispatch", **cur.attrs) as ph:
+                with tracing.region("engine.dispatch.put"):
+                    placed = [jnp.asarray(a) for a in (
+                        tokens, positions, tables, temps, top_ps, top_ks,
+                        fresh)]
+                with tracing.region("engine.dispatch.call"):
+                    cur.seq, cur.logps = self._run_decode(
+                        self._decode(span, advanced)(
+                            self.params, self.k_pages, self.v_pages,
+                            *placed[:6], key, self.state,
+                            (*self._carry, placed[6])))
+                    # dropped while the program holds them: freed after the
+                    # readback they cost 5 ms an iteration (chip, PR 36)
+                    del placed
+                    if prev is not None and not prev.seq.is_ready():
+                        _m_ahead.inc(span)  # the device never ran dry
+            cur.dispatched_ns = ph.end_ns
+            _step_phase["verify", "plain"].observe(ph.elapsed_s)
+        self._inflight = cur
+        if prev is not None:
+            self._finish_span(prev)
+        if self._spec is not None or self._stop.is_set():
+            self._drain()
         return True
 
-    def _build_batch(self):
-        """The decode batch as numpy arrays, one row per slot."""
+    def _drain(self) -> None:
+        """Read back and commit the span in flight, if any: the pipeline
+        at depth 0."""
+        span, self._inflight = self._inflight, None
+        if span is not None:
+            self._finish_span(span)
+
+    def _finish_span(self, span: _Span) -> None:
+        """Read a dispatched span back and commit it to the requests it
+        went out with; then the pages that rode it are free."""
+        with self.phase("readback") as ph:
+            seq = np.asarray(span.seq)  # [steps, B] — one readback per span
+            logps = np.asarray(span.logps)  # [steps, B]
+        self._span_read(span, ph)
+        _step_phase["sample", "plain"].observe(ph.elapsed_s)
+        with self.phase("commit") as ph:
+            n = len(span.members)
+            self._count_slot_steps(n, span.steps)
+            self._count_moe_rows(self.ecfg.max_batch_size, 1, n, span.steps)
+            self._tps_committed += self._commit_span(span, seq, logps)
+            for pages in span.release:
+                self._free_pages_and_revive(pages)
+        _step_phase["cache_bookkeeping", "plain"].observe(ph.elapsed_s)
+
+    def _build_batch(self, prev: Optional[_Span]):
+        """The next span's members (slot -> request) and its batch as numpy
+        arrays, one row per slot. A slot that rides `prev`, the span still
+        unread, continues from the device's carry (`fresh` False; the host
+        does not know its token yet), unless its answer ends inside `prev`
+        by `max_tokens`: then it is left out, a row of zeros like an empty
+        slot's, which costs its programs nothing."""
         B = self.ecfg.max_batch_size
         pps = self.ecfg.pages_per_seq
         tokens = np.zeros((B,), np.int32)
@@ -2329,48 +2532,62 @@ class InferenceEngine:
         temps = np.zeros((B,), np.float32)
         top_ps = np.ones((B,), np.float32)
         top_ks = np.zeros((B,), np.int32)
+        fresh = np.ones((B,), bool)
         advanced = False
+        members: Dict[int, Request] = {}
+        riding = prev.members if prev is not None else {}
         for i, s in enumerate(self.slots):
-            if s.request is None:
+            req = s.request
+            if req is None:
                 continue
-            tokens[i] = s.request.output[-1]
-            positions[i] = s.position
+            if riding.get(i) is req:
+                if s.generated + prev.steps >= req.max_tokens:
+                    continue
+                fresh[i] = False
+            else:
+                tokens[i] = req.output[-1]
+                positions[i] = s.position
+            members[i] = req
             tables[i, : len(s.pages)] = s.pages
-            temps[i] = s.request.temperature
-            top_ps[i] = s.request.top_p
-            top_ks[i] = s.request.top_k
-            if s.request.temperature > 0 and (
-                    s.request.top_p < 1.0 or s.request.top_k > 0):
+            temps[i] = req.temperature
+            top_ps[i] = req.top_p
+            top_ks[i] = req.top_k
+            if req.temperature > 0 and (req.top_p < 1.0 or req.top_k > 0):
                 advanced = True  # the sort-based sampler program runs
-        return tokens, positions, tables, temps, top_ps, top_ks, advanced
+        return (members, tokens, positions, tables, temps, top_ps, top_ks,
+                fresh, advanced)
 
-    def _commit_span(self, seq, logps, span: int) -> int:
-        """The host loop after a span's readback: tokens to their
-        requests, finished slots retired. -> tokens committed."""
+    def _commit_span(self, span: _Span, seq, logps) -> int:
+        """The host loop after a span's readback: tokens to the requests
+        the span was dispatched with, finished slots retired. A member
+        whose slot has let it go since (it ended in the span before, or was
+        cancelled; the slot may be another's by now) gets nothing.
+        -> tokens committed."""
         committed = 0
-        for t in range(span):
-            for i, s in enumerate(self.slots):
-                if s.request is None:
-                    continue  # finished earlier in this span (or empty slot)
+        eos = self.ecfg.eos_token_id
+        rows = [(i, self.slots[i], req) for i, req in span.members.items()]
+        for t in range(span.steps):
+            for i, s, req in rows:
+                if s.request is not req:
+                    continue  # finished earlier (in this span or before it)
                 s.position += 1
                 tok = int(seq[t, i])
-                if s.generated < s.request.max_tokens and not s.request.done.is_set():
-                    s.request.output.append(tok)
-                    s.request.output_logprobs.append(float(logps[t, i]))
+                if s.generated < req.max_tokens and not req.done.is_set():
+                    req.output.append(tok)
+                    req.output_logprobs.append(float(logps[t, i]))
                     s.generated += 1
                     committed += 1
                     _m_tokens.inc()
-                    eos = self.ecfg.eos_token_id
                     if eos is not None and tok == eos:
                         pass  # eos is control, not content
-                    elif s.request.stop:
+                    elif req.stop:
                         # hold back: _maybe_finish drains tokens that can
                         # no longer be part of a stop match, strips matched
                         # tails, and _finish_request flushes the rest — a
                         # matched stop never leaks to streaming consumers
-                        s.request._held.append(tok)
+                        req._held.append(tok)
                     else:
-                        s.request._emit(tok)
+                        req._emit(tok)
                 self._maybe_finish(s, tok)
         return committed
 
@@ -2558,11 +2775,25 @@ class InferenceEngine:
                                              len(req.output_logprobs)):]
             if req._held:
                 del req._held[-min(stop_len, len(req._held)):]
-        # free BEFORE signalling completion: a caller that returns from
-        # generate() and reads stats() must see this request's pages
-        # already released (and _free_pages_and_revive is the one place
-        # that knows the release/free/revive choreography)
-        self._free_pages_and_revive(slot.pages)
+        # When are the pages free? An ending by `max_tokens` (foreseen: no
+        # unread span went out with this table) and any ending of a drained
+        # loop free them here, BEFORE completion is signalled: that caller
+        # returns from generate() to a stats() that counts them. An ending
+        # the host could not foresee (eos, a stop sequence, a cancel) finds
+        # the sequence in the span in flight, and the device may yet write
+        # those pages: the request finishes now all the same, and the pages
+        # are freed one span later, at that span's readback (`_finish_span`
+        # walks `release`). Until then `stats()["free_pages"]` is short by
+        # them, so a caller that counts the pool after such an answer polls.
+        # Admission never reads the stat: a request that finds the pool
+        # short parks in `_waiting`, and every free, this one too, goes
+        # through `_free_pages_and_revive`, which wakes it.
+        riding = self._inflight
+        if riding is not None and riding.members.get(
+                self.slots.index(slot)) is req:
+            riding.release.append(slot.pages)
+        else:
+            self._free_pages_and_revive(slot.pages)
         if self._spec is not None:
             # proposer hygiene: drop the slot's ngram context / invalidate
             # any prefetched draft row so the next occupant can never see
@@ -2671,12 +2902,15 @@ class InferenceEngine:
         return stream
 
     def update_params(self, params, version: Optional[int] = None) -> int:
-        """Live weight swap without draining. Transfers the new tree to
-        device (re-sharded onto the engine mesh when there is one), waits
-        for the transfer, then atomically rebinds `self.params` — in-flight
-        dispatches keep the old tree (compiled programs do not donate the
-        params argument), and every step launched after the rebind serves
-        the new generation. Returns the new weights_version."""
+        """Live weight swap without stopping the engine. Transfers the new
+        tree to device (re-sharded onto the engine mesh when there is one),
+        waits for the transfer, then has the decode thread rebind
+        `self.params` between two iterations: it first reads back and
+        commits the span in flight, so every token that the old weights
+        computed is committed while `weights_version` still names them, and
+        every span dispatched after the rebind serves the new generation.
+        The caller waits for that, up to one iteration of the loop. Returns
+        the new weights_version."""
         if self.mesh is not None:
             from ..models.transformer import param_axes
             from ..parallel.sharding import tree_shardings
@@ -2686,14 +2920,30 @@ class InferenceEngine:
         else:
             new = jax.tree_util.tree_map(jnp.asarray, params)
         jax.block_until_ready(new)
-        with self._lock:
-            self.params = new
-            self.weights_version = (
-                int(version) if version is not None
+        swap = types.SimpleNamespace(params=new, version=version,
+                                     bound=threading.Event())
+        self._swaps.append(swap)
+        while True:
+            with self._lock:
+                thread = self._loop_thread
+                if thread is None or not thread.is_alive() or self._loop_done:
+                    self._swap_params()  # nobody else is stepping the loop
+            self._work.set()
+            if swap.bound.wait(1.0):  # asks again: the thread may have died
+                return swap.version
+
+    def _swap_params(self) -> None:
+        """Drain, then bind the weights that `update_params` posted."""
+        self._drain()
+        while self._swaps:
+            swap = self._swaps.pop(0)
+            self.params = swap.params
+            self.weights_version = swap.version = (
+                int(swap.version) if swap.version is not None
                 else self.weights_version + 1)
-            v = self.weights_version
-        _m_weights_version.set(float(v), tags={"role": self.slo_role})
-        return v
+            _m_weights_version.set(float(swap.version),
+                                   tags={"role": self.slo_role})
+            swap.bound.set()
 
     def stats(self) -> Dict[str, Any]:
         with self._ready_lock:
@@ -2751,6 +3001,8 @@ class InferenceEngine:
         return {"page_size": self.ecfg.page_size, "hashes": hashes}
 
     def stop(self):
+        """The decode thread ends after the iteration it is in, with the
+        span in flight read back and committed (`_loop`)."""
         self._stop.set()
         self._work.set()  # wake the decode thread so it observes _stop
 

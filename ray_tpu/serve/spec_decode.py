@@ -679,16 +679,19 @@ class SpecDecoder:
             else:
                 toks_bs = jnp.concatenate(
                     [jnp.asarray(tokens)[:, None], drafts[:, :m]], axis=1)
-        with decode_phase("dispatch", **eng._open_span(1)) as verify:
+        span = eng._open_span(1)
+        with decode_phase("dispatch", **span.attrs) as verify:
             committed, n_comm, eng.k_pages, eng.v_pages = self._verify(
                 advanced)(
                 eng.params, eng.k_pages, eng.v_pages, toks_bs,
                 jnp.asarray(positions), jnp.asarray(tables),
                 jnp.asarray(n_draft), jnp.asarray(temps),
                 jnp.asarray(top_ps), jnp.asarray(top_ks), key)
+        span.dispatched_ns = verify.end_ns
         with decode_phase("readback") as readback:
             committed = np.asarray(committed)
             n_comm = np.asarray(n_comm)
+        eng._span_read(span, readback)
         if self.overlap:
             # dispatch next round's propose NOW: it executes on device
             # while the engine runs its host-side commit loop

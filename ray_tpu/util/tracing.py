@@ -273,6 +273,10 @@ class region:
     def elapsed_s(self) -> float:
         return self.elapsed_ns * 1e-9
 
+    @property
+    def end_ns(self) -> int:
+        return self.start_ns + self.elapsed_ns
+
 
 def current_context() -> Optional[Dict[str, str]]:
     """ctx dict to stamp into an outgoing TaskSpec (None when tracing is

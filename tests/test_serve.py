@@ -379,6 +379,44 @@ class TestEngine:
             t.join(timeout=300)
         assert results[0]["token_ids"] == solo["token_ids"]
 
+    def test_eight_overlapping_answers_equal_one_request_at_a_time(self):
+        # the loop runs a span ahead: sequences join from the host, carry
+        # on from the device, leave foreseen (max_tokens) at different
+        # spans, and four wait for a slot. Token for token and
+        # log-probability for log-probability what an engine that never
+        # holds two requests gives (speculation: the same loop, drained)
+        prompts = [[(7 * i + j) % 60 + 1 for j in range(3 + 4 * i)]
+                   for i in range(8)]
+        budgets = [5, 13, 22, 9, 30, 17, 6, 26]
+        solo, _, _ = self._engine(
+            prefill_chunk=16,
+            speculation={"mode": "ngram", "num_speculative_tokens": 2})
+        plain, _, _ = self._engine(prefill_chunk=16)
+        engine, _, _ = self._engine(prefill_chunk=16)
+        results = {}
+
+        def worker(i):
+            results[i] = engine.generate(prompts[i], max_tokens=budgets[i])
+
+        threads = [threading.Thread(target=worker, args=(i,))
+                   for i in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300)
+        engine.stop()
+        for i in range(8):
+            want = plain.generate(prompts[i], max_tokens=budgets[i])
+            assert results[i]["token_ids"] == want["token_ids"], i
+            assert len(want["token_ids"]) == budgets[i]
+            assert results[i]["logprobs"] == pytest.approx(
+                want["logprobs"], abs=1e-5), i
+            drained = solo.generate(prompts[i], max_tokens=budgets[i])
+            assert drained["token_ids"] == want["token_ids"], i
+        plain.stop()
+        solo.stop()
+        assert engine.stats()["free_pages"] == 64 - 1
+
     def test_rejects_oversized(self):
         engine, _, _ = self._engine()
         with pytest.raises(ValueError, match="exceeds"):
@@ -504,6 +542,58 @@ class TestEngine:
                 assert out_tp["token_ids"] == out_1d["token_ids"]
             assert sharded.k_pages.sharding.spec == PartitionSpec(
                 None, None, None, None, "tp")  # and the programs keep it so
+        finally:
+            sharded.stop(), plain.stop()
+
+    def test_tp_loop_a_span_ahead_compiles_nothing_after_warmup(self):
+        # tp=2: a span's carry goes in whole on every device and comes out
+        # so (pinned, not the partitioner's choice), so the spans that
+        # start from the last one's output are the programs warmup
+        # compiled; four overlapping answers over two slots equal the
+        # one-device engine's
+        from ray_tpu.comm.mesh import MeshSpec, build_mesh
+        from ray_tpu.serve import EngineConfig, InferenceEngine
+        from ray_tpu.core.metrics import registry
+
+        def ahead_steps():
+            return sum(v for _s, _t, v in registry.get(
+                "serve_decode_ahead_steps").samples())
+
+        cfg = get_config("tiny-llama")
+        params = init_params(cfg, jax.random.PRNGKey(0))
+        ecfg = EngineConfig(
+            max_batch_size=2, page_size=8, max_pages=32, max_seq_len=64,
+            prefill_buckets=(16,), decode_span=4, busy_span=2)
+        mesh = build_mesh(
+            MeshSpec.create(tp=2), devices=jax.devices("cpu")[:2])
+        sharded = InferenceEngine(params, cfg, ecfg, mesh=mesh)
+        plain = InferenceEngine(params, cfg, ecfg)
+        try:
+            sharded.warmup()
+            programs = [sharded._decode(span, advanced).__wrapped__
+                        for span in (4, 2) for advanced in (False, True)]
+            assert [p._cache_size() for p in programs] == [1, 1, 1, 1]
+            whole = sharded._carry[0].sharding
+            assert whole.is_fully_replicated
+            ahead = ahead_steps()
+            prompts = [[3 + i, 1, 4, 1, 5 + i] for i in range(4)]
+            results = {}
+
+            def worker(i):
+                results[i] = sharded.generate(prompts[i], max_tokens=24)
+
+            threads = [threading.Thread(target=worker, args=(i,))
+                       for i in range(4)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=300)
+            for i in range(4):
+                want = plain.generate(prompts[i], max_tokens=24)
+                assert results[i]["token_ids"] == want["token_ids"], i
+            assert ahead_steps() > ahead  # spans did start from a carry
+            assert [p._cache_size() for p in programs] == [1, 1, 1, 1]
+            assert all(c.sharding == whole for c in sharded._carry)
         finally:
             sharded.stop(), plain.stop()
 

@@ -278,7 +278,8 @@ def test_decode_span_under_tp4_updates_its_shard_of_the_pool_in_place(
     """The same decode span on a `tp=4` mesh: the mode hands the paged call
     the mesh (models/stack.py: Decode), so the kernel runs per shard on the
     shard's lanes of every row, and each device holds a quarter of the pool,
-    donated and handed back, with nothing of that shape copied."""
+    donated and handed back, with nothing of that shape copied. Lowered as
+    the engine calls it, with the carry of the span before."""
     from ray_tpu.models import get_config, init_params
     from ray_tpu.models.transformer import param_axes
     from ray_tpu.parallel.sharding import tree_shardings
@@ -308,7 +309,13 @@ def test_decode_span_under_tp4_updates_its_shard_of_the_pool_in_place(
             params, pool, pool, s((BATCH,), I32), s((BATCH,), I32),
             s((BATCH, ecfg.pages_per_seq), I32), s((BATCH,), jnp.float32),
             s((BATCH,), jnp.float32), s((BATCH,), I32),
-            s((2,), jnp.uint32)).compile()
+            s((2,), jnp.uint32), None,
+            (s((BATCH,), I32), s((BATCH,), I32), s((BATCH,), jnp.bool_)),
+        ).compile()
+    # the carry the next span starts from comes out whole on every device,
+    # as it went in: the same program again, whatever the partitioner likes
+    assert all(out.is_equivalent_to(NamedSharding(mesh, P()), 1)
+               for out in compiled.output_shardings[-1])
     memory = compiled.memory_analysis()  # per device
     shard_bytes = 2 * pool.size * pool.dtype.itemsize // 4  # k and v
     assert memory.alias_size_in_bytes == shard_bytes
