@@ -18,7 +18,10 @@ from typing import Optional, Tuple
 # what a layer's mixer can be when a StackConfig's `layer_kinds` names them
 # (models/stack.py); a plain ModelConfig's layers are all "attn"
 LAYER_KINDS = ("attn", "conv", "mamba", "window", "full", "gmu", "cross",
-               "gdn")
+               "gdn", "mla2")
+# a token's row in a pool of latents: the latent and the shared rotary key
+# side by side, padded with zeros to whole 128-lane tiles
+_LANES = 128
 # the kinds whose attention is differential over pairs of heads
 _DIFFERENTIAL = ("window", "full", "cross")
 
@@ -87,6 +90,29 @@ class ModelConfig:
     n_dense_layers = 0
     conv_tail = (0, 0, 0)
     gdn_dims = (0, 0, 0, 0)
+    # every expert there is is held and computes: none lives on another
+    # chip, none is the identity
+    experts_first = 0
+    experts_zero = 0
+    latent_cache = False
+
+    @property
+    def experts_routed(self) -> int:
+        """Experts with weights that the router chooses among, wherever
+        they are held."""
+        return self.num_experts
+
+    @property
+    def router_width(self) -> int:
+        """The router's outputs: every routed expert, held here or not,
+        and the zero-compute ones after them."""
+        return self.experts_routed + self.experts_zero
+
+    @property
+    def counts_choices(self) -> bool:
+        """Whether a token's choices can fall outside the held experts, so
+        that where they fell is worth counting."""
+        return self.router_width != self.num_experts
 
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
@@ -181,7 +207,18 @@ class StackConfig(ModelConfig):
     # and keys normalised over the WHOLE projected vector
     # (`qk_norm_whole`); its norms follow their sublayers (`post_norm`). A
     # rule below belongs to the kind it names, not to a stack; another
-    # family gets a field when it comes.
+    # family gets a field when it comes. The fourth: "mla2": ONE published
+    # layer that is two blocks, each latent attention (MLA: queries through
+    # a rank-`q_lora_rank` bottleneck, keys and values up-projected from
+    # ONE latent of `kv_lora_rank` a token beside ONE rotary key of
+    # `qk_rope_dim` shared by the heads; that row is all the cache holds,
+    # `latent_cache`) and a dense FFN, with the experts computed on the
+    # first block's normed stream and joined after the second block's FFN
+    # (a shortcut). Its router is a softmax over ALL its outputs
+    # (`router="softmax_all"`): `experts_routed` experts with weights, of
+    # which this model HOLDS `num_experts` from `experts_first` on (the
+    # others' share of the sum is another chip's and is left out), then
+    # `experts_zero` experts that are the identity.
     layer_kinds: Tuple[str, ...] = ()
     window: int = 0
     ssm_inner: int = 0        # mamba / gmu inner width
@@ -205,9 +242,22 @@ class StackConfig(ModelConfig):
     # sigmoid scores, the choice made on score + a per-expert bias, the
     # weights the chosen scores WITHOUT it, over their sum (+ 1e-6) if
     # `norm_topk`, times `routed_scale` (parallel/moe.py)
+    # "softmax_all": softmax over all `router_width` outputs, then as
+    # "sigmoid" (choice by score + bias, weights the scores without it)
     router: str = "softmax"
     norm_topk: bool = True
     routed_scale: float = 1.0
+    n_routed_experts: int = 0  # routed experts that exist (0: num_experts)
+    experts_first: int = 0    # the first routed expert held here
+    experts_zero: int = 0     # identity experts after the routed ones
+    # "mla2": the five sizes of latent attention, and whether the two
+    # normed bottlenecks are scaled by sqrt(d_model / rank)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    mla_scale_lora: bool = False
 
     def __post_init__(self) -> None:
         kinds = tuple(self.layer_kinds)
@@ -241,8 +291,38 @@ class StackConfig(ModelConfig):
             if a in kinds and b in kinds:
                 raise ValueError(f"{a!r} and {b!r} layers in one stack: "
                                  f"two shapes of {what}")
-        if self.router not in ("softmax", "sigmoid"):
+        if self.router not in ("softmax", "sigmoid", "softmax_all"):
             raise ValueError(f"unknown router {self.router!r}")
+        if "mla2" in kinds:
+            if set(kinds) != {"mla2"}:
+                raise ValueError("'mla2' layers beside other kinds in one "
+                                 "stack: two shapes of pool rows")
+            if not (self.q_lora_rank and self.kv_lora_rank
+                    and self.qk_nope_dim and self.qk_rope_dim
+                    and self.v_head_dim) or self.qk_rope_dim % 2:
+                raise ValueError(
+                    "mla2 layers need `q_lora_rank`, `kv_lora_rank`, "
+                    "`qk_nope_dim`, an even `qk_rope_dim` and `v_head_dim`")
+            if not self.is_moe or self.n_dense_layers:
+                raise ValueError("an mla2 layer carries its experts: "
+                                 "`num_experts` > 0 and no leading dense "
+                                 "layers")
+        if self.experts_first + self.num_experts > self.experts_routed:
+            raise ValueError(
+                f"experts {self.experts_first}.. of {self.num_experts} held "
+                f"lie past the {self.experts_routed} routed ones")
+        if self.counts_choices and (self.capacity_factor
+                                    * self.num_selected_experts
+                                    < self.num_experts - 1e-6):
+            raise ValueError(
+                "a layer that holds a share of the experts, or identity "
+                "experts, has the dropless form alone: capacity_factor >= "
+                "num_experts / num_selected_experts")
+        if self.counts_choices and self.router == "softmax":
+            raise ValueError(
+                'router="softmax" renormalises over the chosen experts, '
+                "which a layer that holds a share of them cannot: "
+                '"softmax_all" or "sigmoid"')
 
     is_stack = True
 
@@ -257,6 +337,22 @@ class StackConfig(ModelConfig):
     @property
     def expert_ff(self) -> int:
         return self.d_ff_expert or self.d_ff
+
+    @property
+    def experts_routed(self) -> int:
+        return self.n_routed_experts or self.num_experts
+
+    @property
+    def latent_cache(self) -> bool:
+        """The pool is ONE array of latent rows (there is no pool of
+        values: a value is its row's leading `kv_lora_rank` lanes)."""
+        return "mla2" in self.layer_kinds
+
+    @property
+    def latent_row(self) -> int:
+        """Lanes of a token's row in the latent pool."""
+        used = self.kv_lora_rank + self.qk_rope_dim
+        return -(-used // _LANES) * _LANES
 
     @property
     def conv_tail(self) -> Tuple[int, int, int]:
@@ -293,6 +389,8 @@ class StackConfig(ModelConfig):
         or the "full" layers', a differential pair a head."""
         if "attn" in self.layer_kinds:
             return self.count("attn"), self.kv_heads, self.hdim
+        if self.latent_cache:  # two attentions a layer, one row a token
+            return 2 * self.count("mla2"), 1, self.latent_row
         return self.count("full"), self.pool_heads, self.pool_dim
 
     def _mixer_params(self, kind: str) -> int:
@@ -312,6 +410,15 @@ class StackConfig(ModelConfig):
                     + 2 * D * Hg * dv + 2 * D * Hg + 2 * Hg + dv)
         if kind == "conv":
             return D * 3 * D + self.conv_taps * D + D * D
+        if kind == "mla2":
+            ql, kl = self.q_lora_rank, self.kv_lora_rank
+            qk = self.qk_nope_dim + self.qk_rope_dim
+            mla = (D * ql + ql + ql * H * qk + D * (kl + self.qk_rope_dim)
+                   + kl + kl * H * (self.qk_nope_dim + self.v_head_dim)
+                   + H * self.v_head_dim * D)
+            # two attentions and two dense FFNs with a norm before each;
+            # `param_count` adds the experts and the layer's two norms
+            return 2 * (mla + 3 * D * self.d_ff + D)
         if kind == "mamba":
             return (D * 2 * Di + Di * (self.ssm_conv + 1) + Di * (R + 2 * N)
                     + R * Di + Di + N * Di + Di + Di * D)
@@ -326,9 +433,10 @@ class StackConfig(ModelConfig):
         D, F, V = self.d_model, self.d_ff, self.vocab_size
         E, Fe = self.num_experts, self.expert_ff
         norm = D * (2 if self.norm == "layernorm" else 1)
+        W = self.router_width
         half = {"ffn": 3 * D * F,
-                "moe": E * 3 * D * Fe + D * E
-                + (E if self.router == "sigmoid" else 0)}
+                "moe": E * 3 * D * Fe + D * W
+                + (W if self.router != "softmax" else 0)}
         return (sum(self._mixer_params(k) for k in self.layer_kinds)
                 + sum(half[h] + 2 * norm for h in self.second_halves)
                 + V * D * (1 if self.tie_embeddings else 2) + norm)
@@ -559,4 +667,40 @@ register(StackConfig(
     norm="layernorm", activation="swiglu", positional="none",
     tie_embeddings=True, norm_eps=1e-5,
     layer_kinds=_sambay_kinds(12), window=8, ssm_inner=128, ssm_state=4, ssm_conv=4, ssm_dt_rank=4,
+))
+
+register(StackConfig(
+    name="longcat-flash",
+    # meituan-longcat/LongCat-Flash-Omni's language model (560 B, 27 B
+    # active): 28 double layers of latent attention (64 heads, a 512 + 64
+    # latent row a token), dense FFNs of 12288, and a shortcut expert layer
+    # of 512 experts of 2048 beside 256 identity experts, 12 a token by
+    # softmax score + bias, the chosen scores times 6 and not renormalised.
+    # No chip holds a layer: a deployment's chip holds a share of the
+    # experts (`num_experts` of `n_routed_experts`, from `experts_first`)
+    vocab_size=131072,
+    d_model=6144, n_layers=28, n_heads=64, d_ff=12288, max_seq_len=131072,
+    norm="rmsnorm", activation="swiglu", positional="none",
+    rope_theta=10000000.0, tie_embeddings=False, norm_eps=1e-5,
+    num_experts=16, num_selected_experts=12, capacity_factor=16 / 12,
+    layer_kinds=("mla2",) * 28, d_ff_expert=2048, router="softmax_all",
+    norm_topk=False, routed_scale=6.0, n_routed_experts=512,
+    experts_zero=256, q_lora_rank=1536, kv_lora_rank=512, qk_nope_dim=128,
+    qk_rope_dim=64, v_head_dim=128, mla_scale_lora=True,
+))
+
+register(StackConfig(
+    name="tiny-longcat-flash",
+    # the same stack's shape at toy widths: two double layers, 4 of 8
+    # routed experts held beside 4 identity experts, 3 a token
+    vocab_size=512,
+    d_model=64, n_layers=2, n_heads=4, d_ff=128, max_seq_len=128,
+    dtype="float32", remat=False,
+    norm="rmsnorm", activation="swiglu", positional="none",
+    rope_theta=10000.0, tie_embeddings=False, norm_eps=1e-5,
+    num_experts=4, num_selected_experts=3, capacity_factor=4 / 3,
+    layer_kinds=("mla2",) * 2, d_ff_expert=32, router="softmax_all",
+    norm_topk=False, routed_scale=6.0, n_routed_experts=8, experts_first=0,
+    experts_zero=4, q_lora_rank=32, kv_lora_rank=16, qk_nope_dim=16,
+    qk_rope_dim=8, v_head_dim=16, mla_scale_lora=True,
 ))

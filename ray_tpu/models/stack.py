@@ -23,6 +23,21 @@ their sublayers, `x + norm(Mix(x))` and `x + norm(FFN(x))`. Mix is one of
           sequence decayed and corrected a token at a time (ops/gdn.py),
           the output RMS-normalised per head and gated; conv tail and
           state matrix per sequence
+  mla2    ONE layer that is two blocks and a shortcut: for i in (0, 1):
+          x += MLA_i(norm(x)); b = norm(x); if i == 0: s = Experts(b);
+          x += FFN_i(b); and at the end x += s, so the experts' product
+          passes while the second block runs and the second attention
+          never sees it. MLA is latent attention: queries through a normed
+          bottleneck, keys and values up-projected from ONE normed latent a
+          token beside ONE rotary key the heads share (interleaved pairs);
+          the latent and that key are the token's row in the pool, and
+          there is no pool of values (ops/mla_attention.py). A sequence
+          with no past attends in the plain form (every token's keys and
+          values up-projected once, heads of 192 against values of 128
+          through the flash kernel); a chunk over cached rows and a decode
+          step in the absorbed form (the query carried into the latent's
+          space, nothing up-projected per cached token). The experts are a
+          share layer (models/transformer.py `_moe_ffn_dropless_ids`)
 
 Each mixer is written ONCE, over a small state interface (a *mode*), and
 `forward`, the engine's bucket prefill, its chunk program, its decode
@@ -67,19 +82,24 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import (
+    latent_attention_chunk,
+    latent_attention_decode,
     paged_attention_chunk,
     paged_attention_decode,
     paged_attention_verify,
     pool_shape,
+    write_latent_then_attend,
     write_then_attend,
 )
 from ..ops.gdn import gdn_chunk, gdn_step, state_shape
 from ..ops.ssm import ssm_scan, ssm_step
 from .config import ModelConfig
 from .transformer import (
+    _dense_ffn,
     _ffn_half,
     _flash,
     _lm_head,
+    _moe_ffn_dropless_ids,
     _norm,
     _prologue,
     _qkv,
@@ -88,7 +108,7 @@ from .transformer import (
 Params = Dict[str, Any]
 _F32 = jnp.float32
 # kinds that own rows of state arrays or pools, counted as layers go by
-_COUNTED = ("attn", "conv", "mamba", "window", "full", "gdn")
+_COUNTED = ("attn", "conv", "mamba", "window", "full", "gdn", "mla2")
 
 
 # ---------------------------------------------------------------------------
@@ -107,15 +127,36 @@ def layer_shapes(cfg: ModelConfig, kind: str,
     if cfg.norm == "layernorm":
         out.update(ln1_b=((D,), "zero"), ln2_b=((D,), "zero"))
     if half == "moe":
-        E, Fe = cfg.num_experts, cfg.expert_ff
-        out.update(router=((D, E), "w"), w_in=((E, D, Fe), "w"),
+        # the router is as wide as the experts there are; the weights are
+        # the held experts'
+        E, W, Fe = cfg.num_experts, cfg.router_width, cfg.expert_ff
+        out.update(router=((D, W), "w"), w_in=((E, D, Fe), "w"),
                    w_gate=((E, D, Fe), "w"), w_out=((E, Fe, D), "out"))
-        if cfg.router == "sigmoid":
-            out.update(router_bias=((E,), "zero"))
+        if cfg.router != "softmax":
+            out.update(router_bias=((W,), "zero"))
     else:
         out.update(w_in=((D, F), "w"), w_gate=((D, F), "w"),
                    w_out=((F, D), "out"))
-    if kind == "attn":
+    if kind == "mla2":
+        # the two blocks' leaves are named for the block (`wq_a0`,
+        # `wq_a1`): each is consumed whole where the layer scan hands it
+        # over (a slice of a leaf that led with the block was a copy of the
+        # block's weights every step, chip, PR 39); the norms are each
+        # block's own (before its attention, before its FFN); the latent's
+        # and the rotary key's down-projections, and the keys' and values'
+        # up-projections, are leaves of their own for the same reason
+        ql, kl = cfg.q_lora_rank, cfg.kv_lora_rank
+        N, R, V = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+        del out["ln1"], out["ln2"]
+        block = dict(a_ln=((D,), "one"), p_ln=((D,), "one"),
+                     wq_a=((D, ql), "w"), q_ln=((ql,), "one"),
+                     wq_b=((ql, H, N + R), "w"), wkv_a=((D, kl), "w"),
+                     wkr=((D, R), "w"), kv_ln=((kl,), "one"),
+                     wk_b=((kl, H, N), "w"), wv_b=((kl, H, V), "w"),
+                     wo=((H, V, D), "out"), f_in=((D, F), "w"),
+                     f_gate=((D, F), "w"), f_out=((F, D), "out"))
+        out.update({f"{n}{i}": v for i in (0, 1) for n, v in block.items()})
+    elif kind == "attn":
         out.update(wq=((D, H, hd), "w"), wk=((D, KVH, hd), "w"),
                    wv=((D, KVH, hd), "w"), wo=((H, hd, D), "out"))
         if cfg.qk_norm_whole:
@@ -214,9 +255,14 @@ def new_request_state(cfg: ModelConfig, batch: int, dtype) -> Params:
     ops/gdn.py lays them out) where there are gdn layers, and the last
     `window` keys and values of every window layer [W,B,window,KVH,D]
     where there are those. Zeros are a sequence's start; the one-block
-    models have none (the empty tree)."""
+    models have none (the empty tree). Where a token's choice of experts
+    can fall outside the held ones, `choices` [2] counts those of the
+    request's prefill that fell on identity experts and on held ones: no
+    state of the model's, it rides here to reach the host with the logits."""
     M, NW = cfg.count("mamba"), cfg.count("window")
     out = {}
+    if cfg.counts_choices:
+        out.update(choices=jnp.zeros((2,), _F32))
     if cfg.conv_tail[0]:
         layers, rows, width = cfg.conv_tail
         out.update(conv=jnp.zeros((layers, batch, rows, width), dtype))
@@ -240,6 +286,7 @@ def new_engine_state(cfg: ModelConfig, batch: int, page_size: int,
     pool of 1 + batch * ring pages in which slot b owns pages
     1 + b * ring .. (page 0 is never read)."""
     st = new_request_state(cfg, batch, act_dtype)
+    st.pop("choices", None)  # a span counts its own (the engine's program)
     if "wk" in st:
         pool = pool_shape(cfg.count("window"),
                           1 + batch * ring_pages(cfg, page_size), page_size,
@@ -358,7 +405,9 @@ class Seq(_Mode):
         if self.chunk is None or self.export:
             layers, kv_heads, head_dim = cfg.cache_dims
             kv = (layers, B, T, kv_heads, head_dim)
-            carry.update(k=jnp.zeros(kv, dtype), v=jnp.zeros(kv, dtype))
+            carry.update(k=jnp.zeros(kv, dtype))
+            if not cfg.latent_cache:
+                carry.update(v=jnp.zeros(kv, dtype))
         return carry
 
     # -- mamba
@@ -367,6 +416,11 @@ class Seq(_Mode):
         if self.n_valid is None:
             return None
         return (jnp.arange(T)[None, :] < self.n_valid[:, None])[..., None]
+
+    def counted(self, B, T):
+        """bool [B,T]: the tokens whose choice of experts is counted."""
+        valid = self.valid(T)
+        return jnp.ones((B, T), bool) if valid is None else valid[..., 0]
 
     def _lengths(self, B, T):
         return jnp.full((B,), T) if self.n_valid is None else self.n_valid
@@ -469,6 +523,39 @@ class Seq(_Mode):
             attend, q[0], k[0], v[0], kp, vp, fi, self.page, self.slot)
         return o[None].astype(q.dtype), {**carry, "k_pages": kp, "v_pages": vp}
 
+    def attend_mla(self, carry, fi, q_n, q_r, c, k_r, wk_b, wv_b, scale):
+        """Latent attention of the layer whose rows are row `fi` of the
+        pool: q_n / q_r [B,T,H,.] the heads' plain and rotary queries, c
+        [B,T,L] the tokens' latents, k_r [B,T,R] their shared rotary key,
+        wk_b [L,H,N] / wv_b [L,H,V] what carries a latent up to a head's
+        keys and values. -> the heads' values [B,T,H,V]."""
+        W = self.cfg.latent_row
+        row = _latent_row(c, k_r, W)
+        if "k" in carry:
+            carry = {**carry, "k": carry["k"].at[fi].set(
+                row[:, :, None].astype(carry["k"].dtype))}
+        if self.chunk is None:
+            # no past: each token's keys and values are up-projected once,
+            # and heads of N + R meet values of V in the flash kernel
+            k = jnp.concatenate([
+                jnp.einsum("btl,lhn->bthn", c, wk_b), jnp.broadcast_to(
+                    k_r[:, :, None], (*q_r.shape[:-1], k_r.shape[-1]))], axis=-1)
+            return _dense_attend(jnp.concatenate([q_n, q_r], axis=-1), k,
+                                 jnp.einsum("btl,lhv->bthv", c, wv_b),
+                                 scale), carry
+        start, table = self.chunk
+
+        def attend(q, pool, layer):
+            return latent_attention_chunk(
+                q, pool, table, start, start + q.shape[0], layer, c.shape[-1],
+                scale)
+
+        o, pool = write_latent_then_attend(
+            attend, _absorb(q_n[0], q_r[0], wk_b, W), row[0],
+            carry["k_pages"], fi, self.page, self.slot)
+        return (_unabsorb(o, wv_b)[None].astype(q_n.dtype),
+                {**carry, "k_pages": pool})
+
 
 class Decode(_Mode):
     """One token for every decode slot [B, 1]: `positions` [B] is where it
@@ -505,6 +592,9 @@ class Decode(_Mode):
 
     def valid(self, T):
         return None
+
+    def counted(self, B, T):
+        return jnp.broadcast_to(self.live[:, None], (B, T))
 
     def conv(self, carry, mi, u):
         ext = jnp.concatenate([carry["conv"][mi].astype(u.dtype), u], axis=1)
@@ -550,6 +640,21 @@ class Decode(_Mode):
             self.page, self.slot)
         return o[:, None], {**carry, "k_pages": kp, "v_pages": vp}
 
+    def attend_mla(self, carry, fi, q_n, q_r, c, k_r, wk_b, wv_b, scale):
+        W = self.cfg.latent_row
+
+        def attend(q, pool, layer):
+            return latent_attention_decode(
+                q, pool, self.tables, self.lengths, layer, c.shape[-1], scale)
+
+        # this token's row into its page slot, then the absorbed form: the
+        # row is read once, for its score and its value
+        o, pool = write_latent_then_attend(
+            attend, _absorb(q_n[:, 0], q_r[:, 0], wk_b, W),
+            _latent_row(c, k_r, W)[:, 0], carry["k_pages"], fi,
+            self.page, self.slot)
+        return _unabsorb(o, wv_b)[:, None], {**carry, "k_pages": pool}
+
 
 class Verify(Decode):
     """Decode for S tokens a slot [B, S] at `positions` [B] + 0..S-1
@@ -584,6 +689,12 @@ class Verify(Decode):
             attend, q, k, v, carry["k_pages"], carry["v_pages"], fi,
             self.page, self.slot)
         return o, {**carry, "k_pages": kp, "v_pages": vp}
+
+    def attend_mla(self, carry, fi, q_n, q_r, c, k_r, wk_b, wv_b, scale):
+        raise NotImplementedError(
+            f"{self.cfg.name!r}: no kernel verifies a span of drafts over "
+            "a pool of latents (ops/mla_attention.py has decode and chunk)")
+
 
 
 # ---------------------------------------------------------------------------
@@ -671,6 +782,112 @@ def _gdn(h, lp, cfg, gi, mode, carry):
                       lp["d_out"].astype(dtype)), carry
 
 
+def _turn(x, at, theta):
+    """x [B,T,heads,R] turned to the tokens' positions `at` ([B,T]; None:
+    0..T-1): interleaved pairs (2i, 2i+1) by at * theta^(-2i/R), float32
+    inside (XLA fuses it into the projection)."""
+    B, T, _, R = x.shape
+    inv = 1.0 / theta ** (jnp.arange(0, R, 2, dtype=_F32) / R)
+    at = jnp.arange(T)[None] if at is None else at
+    ang = at.astype(_F32)[..., None, None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(_F32).reshape(*x.shape[:-1], R // 2, 2)
+    a, b = xf[..., 0], xf[..., 1]
+    out = jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _scaled_rms(x, w, eps, scale):
+    """RMSNorm over the last axis, times its weight and `scale`; float32
+    inside, so the scale is not rounded twice."""
+    xf = x.astype(_F32)
+    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (xf * w.astype(_F32) * scale).astype(x.dtype)
+
+
+def _latent_row(c, k_r, W):
+    """[.., L] and [.., R] -> a token's row of the pool [.., W]."""
+    pad = jnp.zeros((*c.shape[:-1], W - c.shape[-1] - k_r.shape[-1]), c.dtype)
+    return jnp.concatenate([c, k_r.astype(c.dtype), pad], axis=-1)
+
+
+def _absorb(q_n, q_r, wk_b, W):
+    """The heads' queries carried into the latent's space and laid out as
+    a row of the pool is: [.., H, N], [.., H, R] -> [.., H, W]."""
+    return _latent_row(jnp.einsum("...hn,lhn->...hl", q_n, wk_b), q_r, W)
+
+
+def _unabsorb(o, wv_b):
+    """A head's weighted sum of latents [.., H, L] -> its values [.., H, V]."""
+    return jnp.einsum("...hl,lhv->...hv", o, wv_b)
+
+
+def _mla(h, lp, cfg, fi, mode, carry):
+    """One block's latent attention over h [B,T,D] (`lp`: the block's own
+    leaves, by their plain names); its rows are row `fi` of the pool."""
+    dtype = h.dtype
+    D, N = cfg.d_model, cfg.qk_nope_dim
+    eps, theta = cfg.norm_eps, cfg.rope_theta
+
+    def up(rank):
+        return (D / rank) ** 0.5 if cfg.mla_scale_lora else 1.0
+
+    cq = jnp.einsum("btd,dr->btr", h, lp["wq_a"].astype(dtype))
+    cq = _scaled_rms(cq, lp["q_ln"], eps, up(cfg.q_lora_rank))
+    q = jnp.einsum("btr,rhk->bthk", cq, lp["wq_b"].astype(dtype))
+    c = _scaled_rms(jnp.einsum("btd,dr->btr", h, lp["wkv_a"].astype(dtype)),
+                    lp["kv_ln"], eps, up(cfg.kv_lora_rank))
+    k_r = jnp.einsum("btd,dr->btr", h, lp["wkr"].astype(dtype))
+    k_r = _turn(k_r[:, :, None], mode.at, theta)[:, :, 0]
+    o, carry = mode.attend_mla(
+        carry, fi, q[..., :N], _turn(q[..., N:], mode.at, theta), c, k_r,
+        lp["wk_b"].astype(dtype), lp["wv_b"].astype(dtype),
+        (N + cfg.qk_rope_dim) ** -0.5)
+    return jnp.einsum("bthv,hvd->btd", o.astype(dtype),
+                      lp["wo"].astype(dtype)), carry
+
+
+def _block_leaves(lp, i):
+    """Block i's leaves of a double layer, by their plain names."""
+    tag = str(i)
+    return {n[:-1]: w for n, w in lp.items() if n.endswith(tag)}
+
+
+def _mla2(x, lp, cfg, idx, mode, carry):
+    """The double layer (module docstring). The scopes are the other
+    layers': "attn", then "moe" or "ffn"."""
+    shortcut = None
+    for i in (0, 1):
+        bp = _block_leaves(lp, i)
+        with jax.named_scope("attn"):
+            o, carry = _mla(_norm(x, bp["a_ln"], None, cfg), bp, cfg,
+                            2 * idx + i, mode, carry)
+            x = x + o
+        b = _norm(x, bp["p_ln"], None, cfg)
+        if i == 0:
+            with jax.named_scope("moe"):
+                shortcut, _, ids = _moe_ffn_dropless_ids(b, lp, cfg)
+                carry = _count_choices(carry, ids, cfg, mode)
+        with jax.named_scope("ffn"):
+            x = x + _dense_ffn(b, {"w_in": bp["f_in"], "w_gate": bp["f_gate"],
+                                   "w_out": bp["f_out"]}, cfg)
+    return x + shortcut, carry
+
+
+def _count_choices(carry, ids, cfg, mode):
+    """Where the counted tokens' choices ids [B,T,k] fell, added to
+    `choices` [2] where the carry holds it: on identity experts, on held
+    ones (the rest fell on experts held elsewhere)."""
+    if "choices" not in carry:
+        return carry
+    counted = mode.counted(*ids.shape[:2])[..., None]
+    held = (ids >= cfg.experts_first) & (
+        ids < cfg.experts_first + cfg.num_experts)
+    fell = jnp.stack([jnp.sum((ids >= cfg.experts_routed) & counted),
+                      jnp.sum(held & counted)])
+    return {**carry, "choices": carry["choices"] + fell.astype(_F32)}
+
+
 def _gmu(h, lp, cfg, carry):
     dtype = h.dtype
     g = jnp.einsum("btd,de->bte", h, lp["g_in"].astype(dtype))
@@ -725,6 +942,8 @@ def _attn(h, lp, cfg, idx, mode, carry):
 
 
 def _layer(x, lp, cfg, kind, half, layer, idx, mode, carry):
+    if kind == "mla2":
+        return _mla2(x, lp, cfg, idx, mode, carry)
     # the scopes are what a profile's readers key on: the mixer's kind
     # ("attn" as in the training block), then "ffn" or "moe"
     with jax.named_scope(kind):

@@ -290,14 +290,20 @@ def moe_rows_computed(cfg, B: int, T: int, mesh=None) -> int:
 
 def _moe_gate(x, lp, cfg):
     """Router logits in float32 -> gating by the model's rule
-    (`cfg.router`: top k softmaxed, or sigmoid scores chosen with a
-    per-expert bias) -> (logits [B,T,E], weights [B,T,k], expert_ids
-    [B,T,k]). One implementation for every MoE formulation."""
+    (`cfg.router`: top k softmaxed, or sigmoid scores, or a softmax over
+    every output, chosen with a per-expert bias) -> (logits [B,T,W],
+    weights [B,T,k], expert_ids [B,T,k]) over all W = `cfg.router_width`
+    outputs, held here or not. One implementation for every MoE
+    formulation."""
     k = cfg.num_selected_experts
     logits = jnp.einsum("btd,de->bte", x.astype(jnp.float32), lp["router"])
     if cfg.router == "sigmoid":
         weights, expert_ids = sigmoid_bias_gating(
             logits, lp["router_bias"], k, cfg.norm_topk, cfg.routed_scale)
+    elif cfg.router == "softmax_all":
+        weights, expert_ids = sigmoid_bias_gating(
+            logits, lp["router_bias"], k, cfg.norm_topk, cfg.routed_scale,
+            softmax_all=True)
     else:
         weights, expert_ids = top_k_gating(logits, k)  # [B,T,k]
     return logits, weights, expert_ids
@@ -364,12 +370,22 @@ def _moe_ffn(x, lp, cfg):
     mesh = _current_mesh()
     if _moe_dropless(cfg, x.shape[1], mesh):
         return _moe_ffn_dropless(x, lp, cfg)
+    if cfg.counts_choices:
+        raise ValueError(
+            f"{cfg.name!r}: a layer that holds a share of the experts, or "
+            "identity experts, has the dropless form alone (slot tables "
+            "over the held experts are not written): capacity_factor >= "
+            "num_experts / num_selected_experts, and no sharded mesh")
     if _moe_sharded(mesh):
         return _moe_ffn_dense(x, lp, cfg)
     return _moe_ffn_gather(x, lp, cfg)
 
 
 def _moe_ffn_dropless(x, lp, cfg):
+    return _moe_ffn_dropless_ids(x, lp, cfg)[:2]
+
+
+def _moe_ffn_dropless_ids(x, lp, cfg):
     """The expert layer where `moe_capacity(cfg, T) >= T`, so nothing can
     be dropped: no slot tables, no gather, no scatter. Every expert runs
     over the program's own N = B * T tokens (E * N rows, never more than
@@ -378,15 +394,26 @@ def _moe_ffn_dropless(x, lp, cfg):
     token's k weights at its k experts and zero elsewhere, sums them:
     out[n] = sum_e c[n, e] * expert_e(x_n), what the padded forms compute
     too. The expert axis leads ([E, N, F]) so the weights are read as they
-    lie; x is shared by the experts and never copied E times."""
+    lie; x is shared by the experts and never copied E times.
+
+    A layer that holds a share of the experts (`cfg.num_experts` of
+    `cfg.experts_routed`, from `cfg.experts_first`) routes over all of
+    them and keeps the held columns of c: the other columns' part of the
+    sum is another chip's, and is left out. A choice that falls on one of
+    the `cfg.experts_zero` identity experts adds its weight times x, with
+    no product. -> (out, aux, expert_ids [B,T,k])."""
     dtype = x.dtype
     B, T, D = x.shape
-    E = cfg.num_experts
+    E, W = cfg.num_experts, cfg.router_width
     with jax.named_scope("route"):
         logits, weights, expert_ids = _moe_gate(x, lp, cfg)
-        c = jnp.sum(jax.nn.one_hot(expert_ids, E, dtype=jnp.float32)
+        c = jnp.sum(jax.nn.one_hot(expert_ids, W, dtype=jnp.float32)
                     * weights[..., None], axis=2)  # float32, as the scores
-        aux = _moe_aux(logits, expert_ids, E)
+        aux = _moe_aux(logits, expert_ids, W)
+        if cfg.experts_zero:
+            identity = jnp.sum(c[..., cfg.experts_routed:], axis=-1)
+        if W != E:
+            c = c[..., cfg.experts_first:cfg.experts_first + E]
     xs = x.reshape(B * T, D)
     with jax.named_scope("experts"):
         h = jnp.einsum("nd,edf->enf", xs, lp["w_in"].astype(dtype))
@@ -396,8 +423,10 @@ def _moe_ffn_dropless(x, lp, cfg):
     with jax.named_scope("combine"):
         out = jnp.sum(y.astype(jnp.float32)
                       * c.reshape(B * T, E).T[:, :, None], axis=0)
+        if cfg.experts_zero:
+            out = out + identity.reshape(B * T, 1) * xs.astype(jnp.float32)
         out = out.astype(dtype).reshape(B, T, D)
-        return constrain(out, ("batch", "seq", "embed")), aux
+        return constrain(out, ("batch", "seq", "embed")), aux, expert_ids
 
 
 def _moe_ffn_dense(x, lp, cfg):

@@ -15,6 +15,11 @@ CPU mesh. Set RAY_TPU_FORCE_PALLAS=0/1 to override globally.
 
 from .attention import flash_attention, mha_reference  # noqa: F401
 from .gdn import gdn_chunk, gdn_step  # noqa: F401
+from .mla_attention import (  # noqa: F401
+    latent_attention_chunk,
+    latent_attention_decode,
+    write_latent_then_attend,
+)
 from .norm import layer_norm, rms_norm, rms_norm_reference  # noqa: F401
 from .rope import apply_rope, rope_frequencies  # noqa: F401
 from .paged_attention import (  # noqa: F401

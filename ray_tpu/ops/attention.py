@@ -715,10 +715,24 @@ def flash_attention(
     as ops/paged_attention.py's do: 128 / D neighbouring kv heads side by
     side are one 128-wide head, and a query head is zero outside its own kv
     head's lanes; 128 / D times the products of a kernel that could tile D.
+    A head wider than a tile that is not whole tiles, or values narrower
+    than keys (v [B, T, KVH, Dv]), are padded with zeros to whole tiles of
+    one width, and the output is Dv wide.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
     KVH = k.shape[2]
+    D, Dv = q.shape[3], v.shape[3]
+    if Dv != D or (D > _LANES and D % _LANES):
+        # keys and values of unlike widths, or a wide head that is not
+        # whole tiles (latent attention's plain form: 192 and 128): zeros
+        # up to whole tiles change no score, and a value's own lanes come
+        # back as they were
+        wide = -(-max(D, Dv) // _LANES) * _LANES
+        q, k, v = (jnp.pad(x, ((0, 0),) * 3 + ((0, wide - x.shape[3]),))
+                   for x in (q, k, v))
+        return flash_attention(q, k, v, causal, scale, block_q,
+                               block_k)[..., :Dv]
     f = tile_factor(q.shape[2], KVH, q.shape[3])
     if f > 1:
         wide = (*k.shape[:2], KVH // f, f * k.shape[3])

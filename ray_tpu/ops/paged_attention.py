@@ -274,9 +274,14 @@ def _flash_page_loop(
     division) is one implementation serving decode, chunk prefill and
     verify. A block's tail past page n_pages fetches nothing: its scores
     are masked like any key past the sequence's end, and its rows of v
-    are zeroed (0 x a stale NaN would be NaN in p v)."""
+    are zeroed (0 x a stale NaN would be NaN in p v). `v_hbm` None (a pool
+    of latents, ops/mla_attention.py): a row is fetched ONCE and is key
+    and value both, the value its leading lanes, as wide as `acc_ref`."""
     N = k_buf.shape[1] // page_size
     heads = k_buf.shape[2] // q2d.shape[1]  # kv heads in a fetched row
+    pools = ((k_hbm, k_buf),) if v_hbm is None else (
+        (k_hbm, k_buf), (v_hbm, v_buf))
+    value_buf = pools[-1][1]
 
     def page_of(pool, page):
         if c is None:
@@ -295,7 +300,7 @@ def _flash_page_loop(
         def page_dmas(j, _):
             # a wait needs the copy's shape alone: no table lookup
             page = page_id_fn(first + j) if start else 0
-            for s, (pool, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+            for s, (pool, buf) in enumerate(pools):
                 dma = pltpu.make_async_copy(
                     page_of(pool, page), buf.at[slot, rows_of(j)],
                     sem_ref.at[slot, s])
@@ -320,13 +325,14 @@ def _flash_page_loop(
             block_dmas(slot, first, False)
 
             def stale(j, _):  # a page of the tail: nothing was fetched
-                v_buf[slot, rows_of(j), :] = jnp.zeros(
-                    (page_size, v_buf.shape[2]), v_buf.dtype)
+                value_buf[slot, rows_of(j), :] = jnp.zeros(
+                    (page_size, value_buf.shape[2]), value_buf.dtype)
                 return 0
 
             jax.lax.fori_loop(n_pages - first, N, stale, 0)
 
-            k, v = k_buf[slot], v_buf[slot]  # [N * ps, W]
+            k = k_buf[slot]  # [N * ps, W]
+            v = k[:, :acc_ref.shape[1]] if v_hbm is None else v_buf[slot]
             s = _per_head(q2d, k, heads, _scores) * scale  # [rows, N * ps]
             s = jnp.where(mask_fn(first), s, _NEG_INF)
 
@@ -907,6 +913,8 @@ def gather_pages(k_pages, v_pages, page_arr, kv_heads):
     k, v [L, n*ps, KVH, D]. A page's rows ARE its tokens: a gather and a
     reshape, no transpose."""
     def tokens(pages):
+        if pages is None:  # a pool of latents has no pool of values
+            return None
         g = pages[:, 0, page_arr]  # [L, n, ps, KVH*D]
         return g.reshape(g.shape[0], -1, kv_heads, g.shape[-1] // kv_heads)
 
@@ -919,6 +927,8 @@ def scatter_pages(k_pages, v_pages, k, v, page_arr):
     n, ps = page_arr.shape[0], k_pages.shape[3]
 
     def put(pages, x):
+        if pages is None:
+            return None
         rows = _row(x[:, : n * ps])
         rows = rows.reshape(rows.shape[0], n, ps, -1)
         return pages.at[:, 0, page_arr].set(rows.astype(pages.dtype))

@@ -32,16 +32,19 @@ def top_k_gating(
 
 def sigmoid_bias_gating(
     router_logits: jax.Array, bias: jax.Array, num_selected: int,
-    norm_topk: bool = True, scale: float = 1.0,
+    norm_topk: bool = True, scale: float = 1.0, softmax_all: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """router_logits [T, E] float32, bias [E] -> (weights [T, k],
-    expert_ids [T, k]). Scores are sigmoids; the k experts are chosen by
-    score + bias (the bias balances load and says nothing of how much an
-    expert matters), and the weights are the chosen experts' scores WITHOUT
-    it, over their sum + 1e-6 if `norm_topk`, times `scale`. Feeds what
-    `top_k_gating` feeds (models/transformer.py `_moe_gate`: the dropless
-    form's combine matrix, or `_moe_route`'s slot assignment)."""
-    scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
+    expert_ids [T, k]). Scores are sigmoids (`softmax_all`: a softmax over
+    all E outputs); the k experts are chosen by score + bias (the bias
+    balances load and says nothing of how much an expert matters), and the
+    weights are the chosen experts' scores WITHOUT it, over their sum +
+    1e-6 if `norm_topk`, times `scale`. Feeds what `top_k_gating` feeds
+    (models/transformer.py `_moe_gate`: the dropless form's combine matrix,
+    or `_moe_route`'s slot assignment)."""
+    score = functools.partial(jax.nn.softmax, axis=-1) if softmax_all \
+        else jax.nn.sigmoid
+    scores = score(router_logits.astype(jnp.float32))
     _, expert_ids = jax.lax.top_k(scores + bias.astype(jnp.float32),
                                   num_selected)
     weights = jnp.take_along_axis(scores, expert_ids, axis=-1)

@@ -188,6 +188,17 @@ _m_moe_rows_routed = Counter(
     "serve_moe_rows_routed",
     "Rows the live tokens of the dispatched programs were routed to, over "
     "every expert layer: tokens x experts a token.")
+_m_moe_choices = Counter(
+    "serve_moe_choices",
+    "Where a layer that holds a share of the experts sent the live tokens' "
+    "choices, over every expert layer, by kind (all: tokens x experts a "
+    "token; zero: on identity experts; held: on experts held here; the "
+    "rest fell on experts held elsewhere). Counted on the device and read "
+    "with the tokens: a span's with its readback, a prefill's with its "
+    "logits.")
+_choices_all = _m_moe_choices.labels(kind="all")
+_choices_zero = _m_moe_choices.labels(kind="zero")
+_choices_held = _m_moe_choices.labels(kind="held")
 _deferred_no_pages = _m_deferred.labels(reason="no_pages")
 _front_inbound = _m_front.labels(leg="inbound")
 _front_outbound = _m_front.labels(leg="outbound")
@@ -312,8 +323,10 @@ class EngineConfig:
     # the three-round version). 0 disables tiering (K stays the cap).
     prefill_max_batch: int = 32
     # Chunked prefill (vLLM-style): prompts longer than prefill_chunk are
-    # processed in prefill_chunk-token chunks ON THE DECODE THREAD, one
-    # chunk per engine iteration with decode spans between — a long
+    # processed in prefill_chunk-token chunks ON THE DECODE THREAD, with
+    # decode spans between: one chunk an engine iteration for each prompt
+    # that waits in the chunk queue, and no more chunks than the span that
+    # follows has steps (`InferenceEngine._advance_chunks`) — a long
     # prompt never monopolizes the device, so running requests keep their
     # inter-token latency AND the long prompt's KV lands straight in its
     # pages (no separate scatter). Also lifts the bucket cap: prompts up
@@ -788,7 +801,10 @@ class InferenceEngine:
         else:
             self.params = params
             self.k_pages = jnp.zeros(pool.shape, pool.dtype)
-            self.v_pages = jnp.zeros(pool.shape, pool.dtype)
+            # a pool of latents is ONE array: a token's row is key and
+            # value both, and every program hands the empty tree through
+            self.v_pages = (None if model_cfg.latent_cache
+                            else jnp.zeros(pool.shape, pool.dtype))
         self.allocator = PageAllocator(P)
         # off by derivation where layers keep recurrent state: a page hit
         # without the state at that boundary would be wrong
@@ -846,7 +862,7 @@ class InferenceEngine:
         # one per active slot per round)
         self._tps_committed = 0
         self._tps_steps = 0
-        # long-prompt chunk states, consumed one chunk per step() by the
+        # long-prompt chunk states, consumed a few chunks per step() by the
         # DECODE thread (chunk programs donate the same page pool the
         # decode program does — two threads dispatching donated updates
         # to one buffer would race; serializing on the decode thread is
@@ -924,6 +940,12 @@ class InferenceEngine:
                 f"{name!r}: a stack of unlike layers has no sharding rules "
                 "yet (models/stack.py); serve it on one chip, mesh=None")
         scfg = ecfg.speculation
+        if scfg is not None and scfg.enabled and self.cfg.latent_cache:
+            raise ValueError(
+                f"{name!r}: no kernel verifies a span of drafts over a pool "
+                "of latents (ops/mla_attention.py has decode and chunk), and "
+                "the draft's pool is keys and values. Serve it with "
+                "speculation off")
         if scfg is not None and scfg.enabled:
             raise ValueError(
                 f"{name!r}: speculative decoding rewinds rejected drafts by "
@@ -932,6 +954,12 @@ class InferenceEngine:
                 "rewound that way. Serve it with speculation off")
 
     def _refuse_kv_transfer(self, what: str) -> None:
+        if self.cfg.latent_cache:
+            raise ValueError(
+                f"{what}: {self.cfg.name!r} caches one latent row a token "
+                "and no values; the KV wire carries keys and values by "
+                "head. Disaggregated roles and KV export/import are "
+                "refused for it")
         if self.cfg.is_stack:
             raise ValueError(
                 f"{what}: {self.cfg.name!r} keeps state beside its pages "
@@ -969,7 +997,11 @@ class InferenceEngine:
             the span before ended on and a [B] mask of the slots that take
             the host's `tokens` / `positions` instead (new since that span;
             None: all of them). -> seq/logps [n_steps, B], the pool, the
-            state, and the (tokens, positions) this span ended on."""
+            state, and the (tokens, positions) this span ended on. Where
+            the live tokens' choices of experts are counted
+            (`cfg.counts_choices`) logps has one row more, whose first two
+            entries are the span's counts: they come back in the readback
+            the tokens come back in."""
             if carry is not None:
                 carried_tokens, carried_positions, fresh = carry
                 tokens = jnp.where(fresh, tokens, carried_tokens)
@@ -1008,10 +1040,18 @@ class InferenceEngine:
                 return (toks, positions + 1, k_pages, v_pages, state), (
                     toks, logps)
 
+            state = state or {}
+            if cfg.counts_choices:
+                state = {**state, "choices": jnp.zeros((2,), jnp.float32)}
             (tokens, positions, k_pages, v_pages, state), (seq, logps) = \
                 jax.lax.scan(
-                    step, (tokens, positions, k_pages, v_pages, state or {}),
+                    step, (tokens, positions, k_pages, v_pages, state),
                     jnp.arange(n_steps))
+            if cfg.counts_choices:
+                state = dict(state)
+                row = jnp.zeros((1, logps.shape[1]), logps.dtype).at[
+                    0, :2].set(state.pop("choices"))
+                logps = jnp.concatenate([logps, row])
             return seq, logps, k_pages, v_pages, state, (tokens, positions)
 
         cache: Dict[Any, Any] = {}
@@ -1073,6 +1113,9 @@ class InferenceEngine:
             with jax.named_scope("lm_head"):
                 logits = _head_logits(x, lambda x: x[0, last_idx], params,
                                       cfg, "d,dv->v")
+            if cfg.counts_choices:
+                # the request's counts so far, read with its last logits
+                logits = jnp.concatenate([logits, state["choices"]])
             if export:
                 return (logits, new_k, new_v, state.pop("k")[:, 0],
                         state.pop("v")[:, 0], state)
@@ -1190,9 +1233,16 @@ class InferenceEngine:
                     x, cache = stack.prefill(params, cfg, tokens, true_len)
                     at = (true_len - 1)[:, None, None].astype(jnp.int32)
                     with jax.named_scope("lm_head"):
-                        return _head_logits(
+                        logits = _head_logits(
                             x, lambda x: jnp.take_along_axis(x, at, 1)[:, 0],
-                            params, cfg, "bd,dv->bv"), cache
+                            params, cfg, "bd,dv->bv")
+                    if cfg.counts_choices:
+                        # the batch's counts behind row 0's logits
+                        tail = jnp.zeros((logits.shape[0], 2),
+                                         logits.dtype).at[0].set(
+                                             cache.pop("choices"))
+                        logits = jnp.concatenate([logits, tail], axis=1)
+                    return logits, cache
 
             self._prefill_cache[key] = self._under_mesh(jax.jit(run))
         return self._prefill_cache[key]
@@ -1202,7 +1252,7 @@ class InferenceEngine:
         ps = self.ecfg.page_size
         n = len(pages)
         k = cache["k"][:, 0]  # [L, Tpad, KVH, hd]
-        v = cache["v"][:, 0]
+        v = cache["v"][:, 0] if "v" in cache else None  # latents: no values
         Tpad = k.shape[1]
         n_full = min(n, Tpad // ps)
         page_arr = jnp.asarray(pages[:n_full], jnp.int32)
@@ -2068,13 +2118,13 @@ class InferenceEngine:
                 self.params, jnp.asarray(padded), jnp.asarray(lens)
             )
             self._bucket_tokens += Bpad * bucket
-            self._count_moe_rows(Bpad, bucket, sum(g[2] for g in group))
         # first generated tokens: one small readback, on THIS thread.
         # Sample every row BEFORE emitting/publishing anything: if this
         # raises, the caller's failure path can still free every page
         # safely because no request has been published to _ready yet.
         with _prefill_phase("readback"):
-            logits_host = np.asarray(logits)
+            logits_host = self._take_choices(
+                np.asarray(logits), Bpad, bucket, sum(g[2] for g in group))
             firsts = [
                 _sample_host(logits_host[i], req.temperature,
                              req.top_p, req.top_k)
@@ -2268,11 +2318,31 @@ class InferenceEngine:
 
     # ------------------------------------------------------------- stepping
 
+    def _advance_chunks(self) -> bool:
+        """The iteration's turn of the chunk queue: as many chunks as prompts
+        wait there, the oldest prompt's first, and no more than the span
+        under prefill pressure has steps. One prompt alone advances a chunk
+        an iteration whatever its length; a deep queue (twelve long contexts
+        asked for the first time, their re-asks behind them) takes turns
+        with the decoders chunk for step, so the time the queue needs does
+        not grow with the decoders' spans between its chunks, and the stall
+        a decoder sees between two spans stays `busy_span` chunks at most
+        (PERF.md section 6, PR 39)."""
+        chunked = False
+        # racy read: the prefill thread appends, only this thread removes
+        for _ in range(max(1, min(len(self._chunk_queue),
+                                  self.ecfg.busy_span))):
+            if not self._advance_chunk():
+                break
+            chunked = True
+        return chunked
+
     def _advance_chunk(self) -> bool:
         """Run ONE prefill chunk of the oldest chunked request (decode
-        thread only — chunk programs donate the page pool). The next
-        decode span runs right after, so a long prompt and the running
-        batch interleave at chunk granularity (vLLM chunked prefill)."""
+        thread only — chunk programs donate the page pool). A decode
+        span follows the iteration's chunks, so long prompts and the
+        running batch interleave at chunk granularity (vLLM chunked
+        prefill)."""
         with self._chunk_lock:
             if not self._chunk_queue:
                 return False
@@ -2283,6 +2353,8 @@ class InferenceEngine:
                 self._finish_request(st.request, "cancelled")
                 return True
         C = self.ecfg.prefill_chunk
+        if self.prefix is not None and not st.request.prefill_only:
+            self._take_late_hits(st)
         start = st.next_chunk * C
         toks = st.request.prompt[start:start + C]
         padded = np.zeros((C,), np.int32)
@@ -2302,16 +2374,27 @@ class InferenceEngine:
         with tracing.region("engine.chunk.put"):
             placed = (jnp.asarray(padded), jnp.int32(start),
                       jnp.asarray(st.table), jnp.int32(last_idx))
-        with tracing.region("engine.chunk.call"):
+        with tracing.region("engine.chunk.call", start=start, tokens=C):
             logits, self.k_pages, self.v_pages, *kv, st.state = \
                 self._chunk_fn(C, streaming)(
                     self.params, self.k_pages, self.v_pages, *placed,
                     st.state)
             del placed  # as in `step()`
         self._chunk_tokens += C
-        self._count_moe_rows(1, C, len(toks))
+        if not is_last:  # the last chunk's are counted with its logits
+            self._count_moe_rows(1, C, len(toks))
         chunk_kv = (*kv, start) if streaming else None
         st.next_chunk += 1
+        if not is_last and self.prefix is not None:
+            # the chunk's pages are written by a program already in the
+            # device's queue: whoever asks for this prefix from now on
+            # (or waits behind this prompt) reads them, not its own. Were
+            # that program to fail, the pool it returns by donation is
+            # gone with it, for every later program, cached pages or not
+            with self._alloc_lock:
+                self.prefix.register(
+                    req.prompt[:start + C], st.pages,
+                    hashes=getattr(req, "_page_hashes", None))
         if not is_last:
             if streaming:
                 # pages for [emitted_upto, start+C) are committed: ship
@@ -2334,6 +2417,9 @@ class InferenceEngine:
             self._chunk_queue.pop(0)
         with self.phase("chunk.readback"):
             logits_host = np.asarray(logits)
+        # where the device counts choices, those of every chunk the request
+        # ran came with these logits
+        logits_host = self._take_choices(logits_host, 1, C, len(toks))
         first = _sample_host(logits_host, req.temperature,
                              req.top_p, req.top_k)
         self._note_first_token(req, tracing.now_ns())
@@ -2369,9 +2455,42 @@ class InferenceEngine:
             self._ready.append((req, st.pages, st.state, st.true_len))
         return True
 
+    def _take_late_hits(self, st: _ChunkState) -> None:
+        """A chunked prompt about to run a chunk looks its prefix up once
+        more: pages that another prompt registered since this one was
+        admitted (the same context, asked a moment earlier and prefilled
+        ahead of it in the queue) take the place of its own, which are
+        freed, and it resumes past them. Without it every ask of a context
+        that arrives while the first is still being prefilled runs the
+        whole prefill again. One dict lookup where nothing is new."""
+        req = st.request
+        C, ps = self.ecfg.prefill_chunk, self.ecfg.page_size
+        hashes = getattr(req, "_page_hashes", None)
+        at = st.next_chunk * C // ps  # the page its next chunk writes first
+        if not hashes or at >= len(hashes):
+            return
+        with self._alloc_lock:
+            if self.prefix.by_hash.get(hashes[at]) in (None, st.pages[at]):
+                return
+            shared = self.prefix.lookup_acquire(req.prompt, C, hashes=hashes)
+            n = len(shared)
+            if n * ps <= st.next_chunk * C:
+                self.prefix.release_and_filter(shared)
+                return
+            own = st.pages[:n]
+            st.pages[:n] = shared
+            st.table[:n] = shared
+        # cached pages among its own drop the ref they held; the others go
+        # back to the allocator (what earlier chunks of this prompt wrote
+        # there is in the shared pages too, by the chain hash)
+        self._free_pages_and_revive(own)
+        _m_prefix_hit_tokens.inc(n * ps - st.next_chunk * C)
+        st.next_chunk = n * ps // C
+
     def step(self) -> bool:
-        """One engine iteration: advance at most one prefill CHUNK, install
-        finished prefills, then dispatch a K-step decode span for the
+        """One engine iteration: advance the chunk queue (`_advance_chunks`:
+        a chunk a waiting prompt, at most `busy_span`), install finished
+        prefills, then dispatch a K-step decode span for the
         active batch (K = decode_span, or busy_span under prefill pressure
         — at most two decode programs ever compile) and only then read
         back and commit the span that the iteration BEFORE dispatched.
@@ -2413,7 +2532,7 @@ class InferenceEngine:
         Every iteration with active slots observes the per-phase timing
         histogram (serve_decode_step_phase_seconds, tagged phase+mode)."""
         with self.phase("chunk"):
-            chunked = self._advance_chunk()
+            chunked = self._advance_chunks()
         with self.phase("install"):
             installed = self._install_ready()
         if self._swaps:
@@ -2511,7 +2630,12 @@ class InferenceEngine:
         with self.phase("commit") as ph:
             n = len(span.members)
             self._count_slot_steps(n, span.steps)
-            self._count_moe_rows(self.ecfg.max_batch_size, 1, n, span.steps)
+            # by keyword, and only where there are any: callers that wrap
+            # this method know its four positional arguments
+            counted = ({"choices": logps[span.steps, :2]}
+                       if self.cfg.counts_choices else {})
+            self._count_moe_rows(self.ecfg.max_batch_size, 1, n, span.steps,
+                                 **counted)
             self._tps_committed += self._commit_span(span, seq, logps)
             for pages in span.release:
                 self._free_pages_and_revive(pages)
@@ -2592,21 +2716,43 @@ class InferenceEngine:
         return committed
 
     def _count_moe_rows(self, rows: int, row_tokens: int, live: int,
-                        times: int = 1) -> None:
+                        times: int = 1, choices=None) -> None:
         """A program over `rows` rows of `row_tokens` tokens, `live` of
         them real, dispatched `times` over (a span's steps): each of its
         expert layers computed what `moe_rows_computed` says of the form
         the program took (every expert over the program's tokens, or
         padded slots under a capacity) for live x k routed. On the host,
-        from the program's static shape."""
+        from the program's static shape. `choices` (zero, held): what the
+        device counted for a layer that holds a share of the experts; the
+        rows routed to THIS layer's products are then the held ones."""
         layers = self.cfg.second_halves.count("moe")
         if not layers:
             return
         _m_moe_rows_computed.inc(
             times * layers
             * moe_rows_computed(self.cfg, rows, row_tokens, self.mesh))
-        _m_moe_rows_routed.inc(
-            times * layers * live * self.cfg.num_selected_experts)
+        chosen = times * layers * live * self.cfg.num_selected_experts
+        if not self.cfg.counts_choices:
+            _m_moe_rows_routed.inc(chosen)
+            return
+        _choices_all.inc(chosen)
+        if choices is not None:  # a chunk's come with its request's last
+            _m_moe_rows_routed.inc(float(choices[1]))
+            _choices_held.inc(float(choices[1]))
+            _choices_zero.inc(float(choices[0]))
+
+    def _take_choices(self, logits_host: np.ndarray, rows: int,
+                      row_tokens: int, live: int) -> np.ndarray:
+        """A prefill program's logits as they came back -> the logits
+        alone, the program's expert rows counted. Where the device counted
+        its tokens' choices of experts (`cfg.counts_choices`) they are the
+        last two entries (of row 0, for a batch)."""
+        if not self.cfg.counts_choices:
+            self._count_moe_rows(rows, row_tokens, live)
+            return logits_host
+        self._count_moe_rows(rows, row_tokens, live,
+                             choices=logits_host[..., -2:].reshape(-1, 2)[0])
+        return logits_host[..., :-2]
 
     def _count_slot_steps(self, n_active: int, steps: int) -> None:
         self._tps_steps += n_active * steps

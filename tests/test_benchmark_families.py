@@ -40,10 +40,10 @@ from benchmark.tests.test_reference import (  # noqa: F401
     test_sparse_reference_is_dropless_and_top2,
 )
 from benchmark.tests import test_token_ledger as ledger_tests
+from benchmark.tests import test_decode_span_ahead_share as ahead_tests
 from benchmark.tests.test_decode_span_ahead_share import (  # noqa: F401
     test_a_tree_without_the_series_reads_nothing,
     test_a_window_shorter_than_the_busy_time_is_that_busy_time,
-    test_it_is_an_entry_of_the_five_serve_cells_and_no_train_cell,
     test_the_share_of_a_known_split,
 )
 from benchmark.tests.test_token_ledger import (  # noqa: F401
@@ -70,12 +70,44 @@ def test_the_eight_are_entries_of_the_five_serve_cells_and_no_train_cell(
     the list (PR 38's `decode_span_ahead_share`; an entry in the middle
     reads as an edit of the accepted ones, and so does an edit of that
     test's file): it is asked of the list up to its own eighth."""
-    manifest = common.load_manifest()
+    manifest = as_of_five_serve_cells()
     names = [m["name"] for m in manifest["per_layer"]]
     upto = names.index("decode_live_slots.traced") + 1
     monkeypatch.setattr(common, "load_manifest", lambda: {
         **manifest, "per_layer": manifest["per_layer"][:upto]})
     ledger_tests.test_the_eight_are_entries_of_the_five_serve_cells_and_no_train_cell()
+
+
+LATER_CELLS = ("longcat-flash-omni.serve-docs",)  # PR 39
+
+
+def as_of_five_serve_cells():
+    """The manifest without the cells that later PRs appended (to
+    `workloads` and to each entry's own list): those two tests of the
+    benchmark's pin the serve cells at five, and an edit of their files
+    reads as a change to the accepted benchmark. That the later cells are
+    listed where they should be is `test_longcat_*`'s to hold."""
+    manifest = common.load_manifest()
+
+    def without(entry):
+        if "workloads" not in entry:
+            return entry
+        return {**entry, "workloads": [w for w in entry["workloads"]
+                                       if w not in LATER_CELLS]}
+
+    return {**manifest,
+            "workloads": [w for w in manifest["workloads"]
+                          if w["name"] not in LATER_CELLS],
+            "end_to_end": [without(m) for m in manifest["end_to_end"]],
+            "per_layer": [without(m) for m in manifest["per_layer"]]}
+
+
+def test_it_is_an_entry_of_the_five_serve_cells_and_no_train_cell(monkeypatch):
+    """The benchmark's own test of `decode_span_ahead_share`, run as it is,
+    of the manifest as PR 38 left it."""
+    manifest = as_of_five_serve_cells()
+    monkeypatch.setattr(common, "load_manifest", lambda: manifest)
+    ahead_tests.test_it_is_an_entry_of_the_five_serve_cells_and_no_train_cell()
 
 
 def test_sambay_configuration_is_the_published_one_uncut():
@@ -444,3 +476,192 @@ def test_the_live_share_reads_the_counter_pair():
              (name, (("state", "held"),)): 704.0}
     assert read({"counters": (before, after)}) == 25.0
     assert read({"counters": None}) is None
+
+
+# -- the LongCat-Flash family (PR 39) ----------------------------------------
+
+LONGCAT = "longcat-flash-omni"
+LONGCAT_CELL = LONGCAT + ".serve-docs"
+LONGCAT_ROW = {  # the catalog's config, key for key, but the three cut
+    "attention_bias": False, "hidden_size": 6144, "ffn_hidden_size": 12288,
+    "expert_ffn_hidden_size": 2048, "num_attention_heads": 64,
+    "kv_lora_rank": 512, "q_lora_rank": 1536, "qk_rope_head_dim": 64,
+    "v_head_dim": 128, "qk_nope_head_dim": 128, "mla_scale_q_lora": True,
+    "mla_scale_kv_lora": True, "routed_scaling_factor": 6,
+    "max_position_embeddings": 131072, "rms_norm_eps": 1e-05,
+    "rope_theta": 10000000, "attention_method": "MLA",
+    "zero_expert_num": 256, "zero_expert_type": "identity", "moe_topk": 12}
+NEW_READERS = ("mla_decode_roofline", "mla_prefill_roofline",
+               "mla_attn_device_share", "prefix_hit_token_share",
+               "moe_zero_choice_share", "moe_held_choice_share")
+
+
+def test_longcat_configuration_is_the_published_one_with_three_cuts():
+    spec = common.load_json("configs", LONGCAT + ".json")
+    assert {k: spec[k] for k in LONGCAT_ROW} == LONGCAT_ROW
+    assert (spec["num_layers"], spec["n_routed_experts"],
+            spec["vocab_size"]) == (4, 16, 16384)
+    assert {k: spec["published"][k] for k in
+            ("num_layers", "n_routed_experts", "vocab_size")} == {
+                "num_layers": 28, "n_routed_experts": 512,
+                "vocab_size": 131072}
+    # the router is not cut: 512 + 256 outputs, top 12
+    assert spec["n_routed_experts_total"] == 512
+    assert spec["held_experts_first"] == 0
+    assert sorted(spec["reduced"]) == ["n_routed_experts", "num_layers",
+                                       "vocab_size"]
+    assert {"norm_topk_prob", "router", "mla_scale", "rotary", "activation",
+            "biases", "head", "order", "weights"} <= set(spec["assumed"])
+    assert "32 chips share each layer" in spec["deployment"]
+    entry = next(c for c in common.load_manifest()["configs"]
+                 if c["name"] == LONGCAT)
+    assert sorted(entry["reduced"]) == sorted(spec["reduced"])
+    assert sorted(entry) == ["file", "name", "reduced", "source", "why"]
+    assert entry["source"] == spec["source"]
+    cfg = common.family(spec).model_config(spec)
+    assert cfg.segments() == ((0, ("mla2",), 4),)       # ONE scan
+    assert round(cfg.param_count() / 1e6, 1) == 5172.7  # the issue's 5172.9 M
+    assert cfg.cache_dims == (8, 1, 640) and cfg.latent_cache
+    assert (cfg.num_experts, cfg.experts_routed, cfg.experts_zero,
+            cfg.router_width, cfg.num_selected_experts) == (16, 512, 256, 768, 12)
+    assert (cfg.router, cfg.norm_topk, cfg.routed_scale, cfg.positional,
+            cfg.tie_embeddings, cfg.has_state) == (
+                "softmax_all", False, 6.0, "none", False, False)
+
+
+def test_longcat_readers_reach_the_counts_through_the_family():
+    spec = common.load_json("configs", LONGCAT + ".json")
+    family = common.family(spec)
+    assert reference_file(family) == spec["reference"]
+    assert set(family.modes) == {"int8", "fp8", "router-bf16"}
+    assert set(family.work) == {"mla_decode", "mla_chunk"}
+    # two attentions a double layer; the decode kernel counts a span's steps
+    for group in ("mla_decode", "mla_chunk", "paged_decode"):
+        assert family.calls_per_pass(spec, group) == 8
+    # a cached token: 2 x 64 x (576 + 512) operations, its 640-lane row once
+    work = family.work["mla_decode"](spec, 1000, 3)
+    assert work["flops"] == 2 * 64 * (576 + 512) * 1000
+    assert work["bytes"] == 1280 * 1000 + 3 * 64 * (640 + 512) * 2
+    assert round(work["flops"] / (1280 * 1000)) == 109  # under the ridge, 240
+    # a chunk of 256 at 8192: row r sees 8192 + r + 1 rows, read once a call
+    chunk = family.work["mla_chunk"](spec, 8192, 256)
+    assert chunk["flops"] == 2 * 64 * 1088 * (256 * 8192 + 256 * 257 / 2)
+    assert chunk["bytes"] == 1280 * 8448 + 256 * 64 * 1152 * 2
+    cell = common.load_cell(LONGCAT_CELL)
+    assert cell["engine"] == {"max_seq_len": 9216, "max_batch_size": 64,
+                              "max_pages": 24577}
+    assert cell["traffic_name"] == "serve-docs"
+    assert cell["traffic"]["shared_prefix"] == {"count": 12, "len": 8192,
+                                                "zipf_s": 0.7}
+    assert {m["name"] for m in cell["per_layer"]} == set(NEW_READERS) | {
+        "engine_host_ms_per_step", "engine_loop_host_ms_per_iter",
+        "engine_idle_gap_attributed_share", "pool_copy_device_share",
+        "decode_device_ms_per_step", "prefill_device_ms_per_ktok",
+        "moe_rows_padding_factor", "moe_ffn_device_share.tpot",
+        "tpot_device_wait_ms", "tpot_host_ms", "tpot_ready_ms",
+        "tpot_unaccounted_ms", "decode_step_wall_ms.clean",
+        "decode_step_wall_ms.shared", "interleaved_prefill_tokens_per_token",
+        "decode_live_slots.traced", "decode_span_ahead_share"}
+    assert [m["name"] for m in cell["end_to_end"]] == ["tpot_mean_ms", "setup_s"]
+    assert all(m["moves"] == "tpot_mean_ms" for m in cell["per_layer"])
+    # appended, never inserted: the new entries end their lists
+    manifest = common.load_manifest()
+    assert manifest["configs"][-1]["name"] == LONGCAT
+    assert manifest["workloads"][-1]["name"] == LONGCAT_CELL
+    assert tuple(m["name"] for m in manifest["per_layer"][-6:]) == NEW_READERS
+    assert all(m["workloads"][-1] == LONGCAT_CELL for m in
+               manifest["per_layer"] if LONGCAT_CELL in m["workloads"])
+
+
+def test_longcat_weights_are_seeded_bfloat16_and_in_the_programs_layout():
+    from benchmark import weights
+
+    spec = tiny_spec(LONGCAT)
+    a = weights.make_weights(spec, 2**31 + 11)
+    leaves = jax.tree.leaves(a)
+    assert all(leaf.dtype == jax.numpy.bfloat16 for leaf in leaves)
+    cfg = common.family(spec).model_config(spec)
+    assert [len(seg) for seg in a["layers"]] == [1]
+    layer = a["layers"][0][0]
+    assert layer["router"].shape == (2, 64, 12)      # every output, held or not
+    assert layer["w_in"].shape == (2, 4, 64, 32)     # the held experts alone
+    assert float(abs(layer["router_bias"].astype("float32")).max()) > 0
+    assert a["lm_head"].shape == (64, 256)
+    assert sum(x.size for x in leaves) == cfg.param_count()
+
+
+def test_the_longcat_names_file_adds_its_groups_and_removes_nothing():
+    with open(os.path.join(common.HERE, "trace_names.json")) as f:
+        base = json.load(f)["groups"]
+    merged = trace_reduce.load_names()["groups"]
+    for group, entries in base.items():
+        assert merged[group][:len(entries)] == entries
+    decode = ("%mla_decode.3 = bf16[64,64,512]{2,1,0} custom-call(s32[36864]{0} %a)")
+    chunk = "%mla_chunk.1 = bf16[16384,512]{1,0} custom-call(s32[576]{0} %a)"
+    write = ("%fusion.9 = bf16[8,24577,16,640]{3,2,1,0} "
+             "fusion(bf16[8,24577,16,640]{3,2,1,0} %p, bf16[64,640]{1,0} %k)")
+    experts = "%fusion.7 = bf16[16,64,2048]{2,1,0} fusion(bf16[64,6144]{1,0} %x)"
+    trace = {"busy_s": 10.0, "ops": {
+        decode: [2.0, 16], chunk: [1.0, 8], write: [0.5, 8], experts: [1.5, 4],
+        "%paged_decode.1 = bf16[64,32,128]{2,1,0} custom-call(s32[9]{0} %a)": [3.0, 4]},
+        "modules": {"jit_decode_span_8(1)": [7.0, 1],
+                    "jit_chunk_prefill_256(2)": [2.0, 1]},
+        "module_ops": {"jit_decode_span_8(1)": [decode],
+                       "jit_chunk_prefill_256(2)": [chunk]}}
+    assert trace_reduce.group_seconds(trace, "mla_decode") == (2.0, 16.0)
+    assert trace_reduce.group_seconds(trace, "mla_chunk") == (1.0, 8.0)
+    # the decode kernel counts steps for the accepted readers too
+    assert trace_reduce.group_seconds(trace, "paged_decode") == (5.0, 20.0)
+    assert trace_reduce.group_seconds(trace, "decode_span") == (7.0, 1.0)
+    assert trace_reduce.group_seconds(trace, "prefill") == (2.0, 1.0)
+    assert trace_reduce.group_seconds(trace, "pool_copy") == (0.5, 8.0)
+    assert trace_reduce.group_seconds(trace, "moe_ffn") == (1.5, 4.0)
+    assert common.load_reader("mla_attn_device_share")({"trace": trace}) == 30.0
+
+
+@pytest.mark.parametrize("metric", NEW_READERS)
+def test_a_longcat_reader_returns_nothing_where_the_program_has_nothing(metric):
+    """On the parent (no such kernel, no such counter, no such attribute) a
+    new reader reads nothing and does not raise."""
+    spec = common.load_json("configs", SAMBAY + ".json")
+    cell = common.load_cell(SAMBAY + ".serve-reason")
+    ctx = {"cell": cell, "spec": spec, "family": common.family(spec),
+           "chips": 1, "peaks": common.peaks_for("TPU v5 lite"),
+           "run": {"requests": [], "records": [], "traced_from_s": 1.0,
+                   "traced_to_s": 6.0},
+           "trace": {"busy_s": 4.0, "ops": {
+               "%paged_decode.1 = bf16[1] custom-call(s32[1] %a)": [1.0, 4]},
+               "modules": {}, "module_ops": {}},
+           "counters": ({}, {})}
+    assert common.load_reader(metric)(ctx) is None
+
+
+def test_the_decode_roofline_counts_rows_once_and_the_choice_shares_their_counters():
+    spec = common.load_json("configs", LONGCAT + ".json")
+    family = common.family(spec)
+    peaks = common.peaks_for("TPU v5 lite")
+    decode = "%mla_decode.3 = bf16[64,64,512]{2,1,0} custom-call(s32[36864]{0} %a)"
+    # one request of 8200 prompt tokens; tokens 1 and 2 fall in the traced part
+    ctx = {"spec": spec, "family": family, "peaks": peaks,
+           "trace": {"busy_s": 1.0, "ops": {decode: [0.001, 16]},
+                     "modules": {}, "module_ops": {}},
+           "run": {"traced_from_s": 1.0, "traced_to_s": 6.0,
+                   "requests": [{"prompt_len": 8200, "due_s": 0.5}],
+                   "records": [{"token_s": [0.9, 1.5, 2.0, 7.0]}]},
+           "counters": None}
+    work = family.work["mla_decode"](spec, 8201 + 8202, 2)
+    want = 100.0 * 8 * work["bytes"] / peaks["hbm_bytes_per_s"] / 0.001
+    assert common.load_reader("mla_decode_roofline")(ctx) == pytest.approx(want)
+    assert 8 * work["flops"] / peaks["bf16_flops"] \
+        < 8 * work["bytes"] / peaks["hbm_bytes_per_s"]   # memory-bound
+    name = "serve_moe_choices"
+    before = {(name, (("kind", k),)): 0.0 for k in ("all", "zero", "held")}
+    after = {(name, (("kind", "all"),)): 1200.0,
+             (name, (("kind", "zero"),)): 400.0,
+             (name, (("kind", "held"),)): 24.0,
+             ("serve_prefix_cache_hit_tokens", ()): 8192.0}
+    ctx["counters"] = (before, after)
+    assert common.load_reader("moe_zero_choice_share")(ctx) == pytest.approx(100 / 3)
+    assert common.load_reader("moe_held_choice_share")(ctx) == 2.0
+    ctx["run"]["requests"] = [{"prompt_len": 8292}, {"prompt_len": 8092}]
+    assert common.load_reader("prefix_hit_token_share")(ctx) == 50.0

@@ -21,6 +21,8 @@ from ray_tpu.ops import (
     flash_attention,
     gdn_chunk,
     gdn_step,
+    latent_attention_chunk,
+    latent_attention_decode,
     paged_attention_chunk,
     paged_attention_decode,
     paged_attention_verify,
@@ -114,6 +116,9 @@ def _gdn_operands(*lead):
             ((*lead, GH, GV), F32), ((*lead, GH), F32), ((*lead, GH), F32)]
 
 
+MLA_H, MLA_W, MLA_V = 64, 640, 512
+_MLA_POOL = (pool_shape(8, 24577, PAGE, 1, MLA_W), BF16)
+
 # name -> (op, [(shape, dtype)], fewest tpu_custom_calls in the program)
 CASES = {
     "flash_fwd_t2048": (_flash_fwd, _qkv(2048), 1),
@@ -155,6 +160,23 @@ CASES = {
         lambda st, *a: gdn_step(st, LAYER, *a),
         [((12, 64, GK, GH * GV), F32)] + _gdn_operands(64)
         + [((64,), jnp.bool_)], 1),
+    # latent attention at its published sizes: 64 heads over ONE 640-lane row
+    # a token (512 of latent, 64 of rotary key, 64 of padding), 8 attentions'
+    # rows in one pool, the cell's table of 576 pages; a chunk of 256 over
+    # 8 k cached rows; the plain form's heads of 192 against values of 128
+    "mla_decode_b64": (
+        lambda q, pool, pt, n: latent_attention_decode(
+            q, pool, pt, n, 3, MLA_V, 192 ** -0.5),
+        [((64, MLA_H, MLA_W), BF16), _MLA_POOL, ((64, 576), I32),
+         ((64,), I32)], 1),
+    "mla_chunk_c256_past8192": (
+        lambda q, pool, pt: latent_attention_chunk(
+            q, pool, pt, 8192, 8448, 3, MLA_V, 192 ** -0.5),
+        [((256, MLA_H, MLA_W), BF16), _MLA_POOL, ((576,), I32)], 1),
+    "flash_fwd_t256_keys192_values128": (
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        scale=192 ** -0.5),
+        [((4, 256, MLA_H, 192), BF16)] * 2 + [((4, 256, MLA_H, 128), BF16)], 1),
     "rms_norm_2048x4096": (
         rms_norm, [((2048, D_MODEL), BF16), ((D_MODEL,), BF16)], 1),
 }
@@ -365,3 +387,60 @@ def test_train_step_backward_runs_no_flash_forward(topo, no_persistent_cache):
         for block in re.split(r"\n}\n", text)) if kernels]
     assert sorted(loops, key=len) == [  # two loops, not unrolled
         {"flash_fwd": 1}, {"flash_bwd_dq": 1, "flash_bwd_dkv": 1}]
+
+
+@pytest.mark.parametrize("program", ["decode_span_8", "chunk_prefill_256"])
+def test_the_latent_cells_programs_hold_one_pool_at_published_widths(
+        program, topo, no_persistent_cache):
+    """`longcat-flash-omni.serve-docs` as the benchmark sizes it: the double
+    layers' program compiles for the chip with ONE pool array (the donated
+    latents come back in place, there is no pool of values), two latent
+    kernels in the scanned layer's body, no XLA attention, and the memory
+    the cell's `pool_filled` quotes: 9.63 GiB of weights + 3.75 GiB of
+    latents as arguments, under 0.25 GiB of temporaries."""
+    from benchmark import common
+    from ray_tpu.models import stack
+    from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    cell = common.load_cell("longcat-flash-omni.serve-docs")
+    spec = cell["config"]
+    family = common.family(spec)
+    cfg = family.model_config(spec)
+    ecfg = EngineConfig(**cell["engine"])
+    eng = object.__new__(InferenceEngine)
+    eng.cfg, eng.ecfg, eng.mesh, eng._tp = cfg, ecfg, None, 1
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: s(a.shape, a.dtype), jax.eval_shape(
+            lambda k: family.init_weights(spec, k), jax.random.PRNGKey(0)))
+    pool = eng.abstract_pool(one_chip)
+    assert pool.shape == (8, 1, 24577, 16, 640)
+    B, pps, C = ecfg.max_batch_size, ecfg.pages_per_seq, ecfg.prefill_chunk
+    f32 = jnp.float32
+    if program == "decode_span_8":
+        lowered = eng._build_decode()(8).lower(
+            params, pool, None, s((B,), I32), s((B,), I32), s((B, pps), I32),
+            s((B,), f32), s((B,), f32), s((B,), I32), s((2,), jnp.uint32),
+            {}, (s((B,), I32), s((B,), I32), s((B,), jnp.bool_)))
+        kernel = "mla_decode"
+    else:
+        rs = jax.tree.map(lambda a: s(a.shape, a.dtype), jax.eval_shape(
+            lambda: stack.new_request_state(cfg, 1, jnp.bfloat16)))
+        lowered = eng._build_chunk_prefill()(C).lower(
+            params, pool, None, s((C,), I32), s((), I32), s((pps,), I32),
+            s((), I32), rs)
+        kernel = "mla_chunk"
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    gib = 2 ** 30
+    pool_bytes = pool.size * pool.dtype.itemsize
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert 13.3 < memory.argument_size_in_bytes / gib < 13.5
+    assert memory.temp_size_in_bytes < 0.25 * gib
+    text = compiled.as_text()
+    assert len(re.findall(r"%%%s(\.\d+)? = " % kernel, text)) == 2
+    assert not re.search(r"= bf16\[8,(1,)?24577,16,640\]\S* copy\(", text)
