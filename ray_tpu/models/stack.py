@@ -544,11 +544,13 @@ class Seq(_Mode):
                                  jnp.einsum("btl,lhv->bthv", c, wv_b),
                                  scale), carry
         start, table = self.chunk
+        # where the chunk's tokens end: a tile of padding rows past it
+        # costs the kernel nothing
+        total = start + self._lengths(1, c.shape[1])[0]
 
         def attend(q, pool, layer):
             return latent_attention_chunk(
-                q, pool, table, start, start + q.shape[0], layer, c.shape[-1],
-                scale)
+                q, pool, table, start, total, layer, c.shape[-1], scale)
 
         o, pool = write_latent_then_attend(
             attend, _absorb(q_n[0], q_r[0], wk_b, W), row[0],

@@ -21,7 +21,8 @@ online softmax, float32 throughout) under two masks:
   mla_decode  one grid program a sequence: its H queries over its pages
   mla_chunk   one grid program a tile of a prefill chunk's tokens: their H
               queries each, tokens x H rows, over the pages up to the
-              tile's last token (the prefix and the chunk so far)
+              tile's last token (the prefix and the chunk so far); a tile
+              past the chunk's last token, padding alone, over none
 XLA references gather the rows (CPU tests and refused shapes).
 """
 
@@ -181,12 +182,15 @@ def _chunk_kernel(pt_ref, meta_ref, q_ref, c_hbm, o_ref,
                   *, page_size, scale, rows, heads):
     """A tile of a chunk's tokens, row = token * heads + head, against the
     sequence's rows up to the tile's last token (the chunk's own are in
-    their pages already)."""
+    their pages already). `total` is where the chunk's tokens end: a tile
+    past it holds padding alone, loops over no page (no DMA, no product)
+    and writes zeros; the padding rows of the tile that holds the last
+    token see every key under `total`, so they come out finite."""
     tokens = max(rows // heads, 1)
     keys = c_buf.shape[1]
     start, total, layer = meta_ref[0], meta_ref[1], meta_ref[2]
     first = start + pl.program_id(0) * rows // heads
-    seen = jnp.minimum(first + tokens, total)
+    seen = jnp.where(first < total, jnp.minimum(first + tokens, total), 0)
 
     def mask(i):
         keypos = i * page_size + jax.lax.broadcasted_iota(
@@ -254,7 +258,10 @@ def latent_attention_chunk(q, pool, page_table, start, total, layer,
                            force_xla: bool = False):
     """ONE sequence's prefill chunk over the pool of latents, the chunk's
     own rows written already: q [C, H, W], page_table [pages_per_seq]; key
-    j is seen by query row c iff j <= start + c and j < total.
+    j is seen by query row c iff j <= start + c and j < total. `total` is
+    where the chunk's TOKENS end (start < total <= start + C): a row at or
+    past it is padding and comes back finite and otherwise unspecified
+    (the kernel runs nothing for a whole tile of them and writes zeros).
     -> [C, H, value_lanes]."""
     if force_xla or not latent_ok(q, pool, value_lanes):
         return _chunk_reference(q, pool, page_table, start, total, layer,

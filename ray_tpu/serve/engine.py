@@ -71,6 +71,14 @@ _m_tokens = Counter("serve_tokens_generated", "Tokens emitted by the engine.")
 _m_prefix_hit_tokens = Counter(
     "serve_prefix_cache_hit_tokens",
     "Prompt tokens served from the prefix cache instead of prefilled.")
+# a chunk is padded to prefill_chunk rows: padding's share of the chunk
+# programs' rows is the first over the second
+_m_chunk_padding_tokens = Counter(
+    "serve_chunk_padding_tokens",
+    "Rows of prefill chunk programs that held no prompt token.")
+_m_chunk_rows = Counter(
+    "serve_chunk_rows",
+    "Rows of the prefill chunk programs dispatched (chunks x prefill_chunk).")
 _m_ttft = Histogram(
     "serve_ttft_seconds", "Time to first token.",
     buckets=(0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0),
@@ -2374,13 +2382,16 @@ class InferenceEngine:
         with tracing.region("engine.chunk.put"):
             placed = (jnp.asarray(padded), jnp.int32(start),
                       jnp.asarray(st.table), jnp.int32(last_idx))
-        with tracing.region("engine.chunk.call", start=start, tokens=C):
+        with tracing.region("engine.chunk.call", start=start,
+                            tokens=len(toks), padded=C):
             logits, self.k_pages, self.v_pages, *kv, st.state = \
                 self._chunk_fn(C, streaming)(
                     self.params, self.k_pages, self.v_pages, *placed,
                     st.state)
             del placed  # as in `step()`
         self._chunk_tokens += C
+        _m_chunk_rows.inc(C)
+        _m_chunk_padding_tokens.inc(C - len(toks))
         if not is_last:  # the last chunk's are counted with its logits
             self._count_moe_rows(1, C, len(toks))
         chunk_kv = (*kv, start) if streaming else None

@@ -656,9 +656,16 @@ def test_dispatch_carries_the_live_slots_and_splits_put_from_call(traced_run):
     assert any(int(r.attrs["prefill_tokens"]) > 0 for r in dispatches)
     chunks = [r for r in spans.named("engine.chunk") if r.children]
     assert chunks
+    held = set()
     for r in chunks:
         assert [c.name for c in r.children][:2] == [
             "engine.chunk.put", "engine.chunk.call"]
+        # a chunk's rows, and the tokens they hold
+        call = r.children[1]
+        assert int(call.attrs["padded"]) == 32
+        assert 0 < int(call.attrs["tokens"]) <= 32
+        held.add(int(call.attrs["tokens"]))
+    assert 32 in held and min(held) < 32  # full chunks, and a prompt's last
 
 
 def test_no_annotation_is_built_without_a_profiler_session(model,

@@ -75,14 +75,20 @@ def engine(model):
     eng.stop()
 
 
-def reference_logprobs(model, prompt, output, mode=None):
+def reference_logits(model, prompt, output, mode=None):
+    """One cache-less pass over prompt + output: the logits each output
+    token was drawn from, float64 [len(output), vocab]."""
     spec, family, _, params = model
     seq = list(prompt) + list(output)
     padded = np.zeros((-(-len(seq) // family.PAD_TO) * family.PAD_TO,), np.int32)
     padded[:len(seq)] = seq
     at = len(prompt) - 1 + np.arange(len(output))
-    logits = np.asarray(family.logits_at(params, jnp.asarray(padded),
-                                         jnp.asarray(at), spec, mode), np.float64)
+    return np.asarray(family.logits_at(params, jnp.asarray(padded),
+                                       jnp.asarray(at), spec, mode), np.float64)
+
+
+def reference_logprobs(model, prompt, output, mode=None):
+    logits = reference_logits(model, prompt, output, mode)
     top = logits.max(-1, keepdims=True)
     lse = np.log(np.exp(logits - top).sum(-1, keepdims=True)) + top
     return (logits - lse)[np.arange(len(output)), output]
@@ -174,6 +180,30 @@ def test_a_prefix_hit_on_latent_pages_gives_the_same_logits(model, engine):
     want = reference_logprobs(model, shared + [9, 10], again["token_ids"])
     assert np.abs(np.asarray(again["logprobs"]) - want).max() < LOGPROB_TOL
     assert first["token_ids"] != again["token_ids"]
+
+
+def test_a_short_question_over_a_cached_prefix_counts_its_padding(model, engine):
+    """A re-ask runs ONE chunk, at the cached prefix's end, that holds the
+    question and is padded to `prefill_chunk`: the attention is told where
+    the question ends, the first token and the logits are the cache-less
+    reference's, and the padding is counted beside the chunk's rows."""
+    C = engine.ecfg.prefill_chunk
+    shared = prompt_of(36, 40)
+    engine.generate(shared + [5, 6, 7], max_tokens=2)
+    question = shared[32:] + [9, 10, 11]
+    prompt = shared[:32] + question
+    before = common.counters()
+    again = engine.generate(prompt, max_tokens=5)
+    delta = lambda name: common.counter_delta(  # noqa: E731
+        before, common.counters(), name)
+    assert delta("serve_prefix_cache_hit_tokens") == 32
+    assert delta("serve_chunk_rows") == C
+    assert delta("serve_chunk_padding_tokens") == C - len(question)
+    want = reference_logprobs(model, prompt, again["token_ids"])
+    assert np.abs(np.asarray(again["logprobs"]) - want).max() < LOGPROB_TOL
+    # the first token is the reference's own choice, not only as likely
+    assert again["token_ids"][0] == int(np.argmax(
+        reference_logits(model, prompt, again["token_ids"][:1])[0]))
 
 
 def test_the_devices_counts_of_choices_reach_the_counters(model, engine):
@@ -292,6 +322,34 @@ def test_the_chunk_kernel_is_its_reference_over_several_tiles(interpreted, start
                                       0.1, force_xla=True)
     assert np.abs(np.asarray(got, np.float32)
                   - np.asarray(want, np.float32)).max() < 2e-2
+
+
+@pytest.mark.parametrize("start", [0, 48])
+@pytest.mark.parametrize("valid", [1, 8, 9, 37, 64])
+def test_a_chunk_pays_for_the_tokens_it_holds(interpreted, start, valid):
+    """64 heads make a tile 8 tokens, as at published widths. A chunk of 64
+    rows that holds `valid` tokens: their rows are, bit for bit, those of
+    the chunk told that all 64 are tokens (same keys, same blocks, a masked
+    key adds exactly 0) and the reference's; a tile past the tokens' end
+    loops over no page and writes zeros; the padding rows of the tile that
+    holds the last token see the real keys and come out finite."""
+    C, H, W, V, tile = 64, 64, 256, 128, 8
+    assert mla._CHUNK_ROWS // H == tile
+    pool = _latent_pool()
+    q = (jax.random.normal(jax.random.PRNGKey(3), (C, H, W)) * 0.3).astype(
+        jnp.bfloat16)
+    table = jnp.asarray(np.random.default_rng(1).permutation(39)[:8] + 1, jnp.int32)
+
+    def chunk(total, **kw):
+        return np.asarray(mla.latent_attention_chunk(
+            q, pool, table, start, total, 0, V, 0.1, **kw), np.float32)
+
+    got = chunk(start + valid)
+    assert np.array_equal(got[:valid], chunk(start + C)[:valid])
+    want = chunk(start + valid, force_xla=True)
+    assert np.abs(got[:valid] - want[:valid]).max() < 2e-2
+    assert not got[-(-valid // tile) * tile:].any()
+    assert np.isfinite(got).all() and np.isfinite(want).all()
 
 
 def test_a_refused_shape_is_said_once_and_takes_the_reference(caplog):
@@ -473,26 +531,65 @@ def test_all_held_none_zero_lowers_to_the_parents_decode_program(name):
     assert decode_digest(name) == PARENT_DECODE[name]
 
 
-def decode_digest(name):
+def _bare_engine(name):
+    """An engine object that builds programs and allocates nothing, with
+    the shapes of its parameters and pool."""
     cfg = get_config(name)
     params = jax.eval_shape(lambda k: stack.init_params(cfg, k)
                             if cfg.is_stack else init_params(cfg, k),
                             jax.random.PRNGKey(0))
     ecfg = EngineConfig(max_batch_size=2, page_size=PAGE, max_pages=16,
-                        max_seq_len=32, cache_dtype="float32")
+                        max_seq_len=32, prefill_chunk=16, cache_dtype="float32")
     eng = object.__new__(InferenceEngine)
     eng.cfg, eng.ecfg, eng.mesh, eng._tp = cfg, ecfg, None, 1
-    pool = eng.abstract_pool()
+    return eng, params, eng.abstract_pool()
+
+
+def _i32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+
+def _digest(lowered):
+    return hashlib.sha256(lowered.as_text().encode()).hexdigest()
+
+
+def decode_digest(name):
+    eng, params, pool = _bare_engine(name)
     state = jax.eval_shape(lambda: stack.new_engine_state(
-        cfg, 2, PAGE, jnp.float32, jnp.float32))
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+        eng.cfg, 2, PAGE, jnp.float32, jnp.float32))
     f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
     with jax.default_matmul_precision("highest"):
-        text = eng._build_decode()(4).lower(
-            params, pool, pool, i32(2), i32(2), i32(2, 8), f32(2), f32(2),
-            i32(2), jax.ShapeDtypeStruct((2,), jnp.uint32), state,
-            (i32(2), i32(2), jax.ShapeDtypeStruct((2,), jnp.bool_))).as_text()
-    return hashlib.sha256(text.encode()).hexdigest()
+        return _digest(eng._build_decode()(4).lower(
+            params, pool, pool, _i32(2), _i32(2), _i32(2, 8), f32(2), f32(2),
+            _i32(2), jax.ShapeDtypeStruct((2,), jnp.uint32), state,
+            (_i32(2), _i32(2), jax.ShapeDtypeStruct((2,), jnp.bool_))))
+
+
+# sha256 of the StableHLO text of `chunk_prefill_16` as the parent of PR 40
+# (efc7c3c) lowers it, taken as PARENT_DECODE was: telling the LATENT chunk
+# kernel where a chunk's tokens end (`attend_mla`) leaves `attend_full`, and
+# with it every other model's chunk program, the text it was
+PARENT_CHUNK = {
+    "tiny-llama":
+        "65aa9a2a4a2d78610995bbeb85057a7a7ba5bcd970484c412e36baafd7c94297",
+    "tiny-moe":
+        "d65450621c89b86d4231fe8c676e2f54dbb65460c538a623aefc78c05259d0b6",
+    "tiny-lfm2":
+        "de080e28e0f11512d82f14000bdc64c87916f9de146e9777b9097e5d640f0114",
+}
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_CHUNK))
+def test_the_other_models_chunk_programs_lower_to_the_parents(name):
+    if jax.__version__ != LOWERED_WITH_JAX:
+        pytest.skip(f"digests were taken with jax {LOWERED_WITH_JAX}")
+    eng, params, pool = _bare_engine(name)
+    state = jax.eval_shape(
+        lambda: stack.new_request_state(eng.cfg, 1, jnp.float32))
+    with jax.default_matmul_precision("highest"):
+        assert _digest(eng._build_chunk_prefill()(16).lower(
+            params, pool, pool, _i32(16), _i32(), _i32(8), _i32(),
+            state)) == PARENT_CHUNK[name]
 
 
 # -- the cell, rehearsed -----------------------------------------------------

@@ -119,6 +119,14 @@ def _gdn_operands(*lead):
 MLA_H, MLA_W, MLA_V = 64, 640, 512
 _MLA_POOL = (pool_shape(8, 24577, PAGE, 1, MLA_W), BF16)
 
+
+def _mla_chunk_past8192(total):
+    """A chunk of 256 rows over 8 k cached rows whose tokens end at `total`."""
+    return (lambda q, pool, pt: latent_attention_chunk(
+                q, pool, pt, 8192, total, 3, MLA_V, 192 ** -0.5),
+            [((256, MLA_H, MLA_W), BF16), _MLA_POOL, ((576,), I32)], 1)
+
+
 # name -> (op, [(shape, dtype)], fewest tpu_custom_calls in the program)
 CASES = {
     "flash_fwd_t2048": (_flash_fwd, _qkv(2048), 1),
@@ -169,10 +177,10 @@ CASES = {
             q, pool, pt, n, 3, MLA_V, 192 ** -0.5),
         [((64, MLA_H, MLA_W), BF16), _MLA_POOL, ((64, 576), I32),
          ((64,), I32)], 1),
-    "mla_chunk_c256_past8192": (
-        lambda q, pool, pt: latent_attention_chunk(
-            q, pool, pt, 8192, 8448, 3, MLA_V, 192 ** -0.5),
-        [((256, MLA_H, MLA_W), BF16), _MLA_POOL, ((576,), I32)], 1),
+    "mla_chunk_c256_past8192": _mla_chunk_past8192(8448),
+    # the same chunk holding a question of 96 tokens: 12 tiles of 8 tokens
+    # run, 20 loop over no page
+    "mla_chunk_c256_past8192_holds96": _mla_chunk_past8192(8288),
     "flash_fwd_t256_keys192_values128": (
         lambda q, k, v: flash_attention(q, k, v, causal=True,
                                         scale=192 ** -0.5),
