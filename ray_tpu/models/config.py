@@ -8,6 +8,13 @@ family. A model whose layers differ names each layer's mixer in
 `layer_kinds` and says which second halves are dense (a StackConfig; serve
 only). models/stack.py runs both on the
 serve path: a plain ModelConfig is the stack whose every layer is "attn".
+
+A window layer's keys are held in one of two ways. The "window" kind
+(differential pairs, one family) keeps them in per-slot state: every decode
+slot owns a fixed ring of pages whatever its sequence holds. The "swa" kind
+keeps them in a second page space that the engine's allocator serves beside
+THE pool: a sequence's ring is a table of pages it was given
+(`window_paged`).
 """
 
 from __future__ import annotations
@@ -18,7 +25,9 @@ from typing import Optional, Tuple
 # what a layer's mixer can be when a StackConfig's `layer_kinds` names them
 # (models/stack.py); a plain ModelConfig's layers are all "attn"
 LAYER_KINDS = ("attn", "conv", "mamba", "window", "full", "gmu", "cross",
-               "gdn", "mla2")
+               "gdn", "mla2", "swa")
+# the second halves' gated activations: down(act(gate x) * (up x))
+GATED_ACTIVATIONS = ("swiglu", "reglu")
 # a token's row in a pool of latents: the latent and the shared rotary key
 # side by side, padded with zeros to whole 128-lane tiles
 _LANES = 128
@@ -39,7 +48,7 @@ class ModelConfig:
     max_seq_len: int = 2048
     # architecture family knobs
     norm: str = "rmsnorm"  # rmsnorm | layernorm
-    activation: str = "swiglu"  # swiglu | gelu
+    activation: str = "swiglu"  # swiglu | reglu | gelu
     positional: str = "rope"  # rope | learned
     rope_theta: float = 10000.0
     tie_embeddings: bool = False
@@ -95,6 +104,8 @@ class ModelConfig:
     experts_first = 0
     experts_zero = 0
     latent_cache = False
+    router_input = "ffn"
+    window_paged = False
 
     @property
     def experts_routed(self) -> int:
@@ -166,7 +177,7 @@ class ModelConfig:
         D, F, L, V = self.d_model, self.d_ff, self.n_layers, self.vocab_size
         H, KVH, hd = self.n_heads, self.kv_heads, self.hdim
         attn = D * H * hd + 2 * D * KVH * hd + H * hd * D
-        if self.activation == "swiglu":
+        if self.activation in GATED_ACTIVATIONS:
             ffn = 3 * D * F
         else:
             ffn = 2 * D * F + F + D  # gelu mlp with biases
@@ -218,7 +229,14 @@ class StackConfig(ModelConfig):
     # (`router="softmax_all"`): `experts_routed` experts with weights, of
     # which this model HOLDS `num_experts` from `experts_first` on (the
     # others' share of the sum is another chip's and is left out), then
-    # `experts_zero` experts that are the identity.
+    # `experts_zero` experts that are the identity. The fifth: "swa":
+    # rotary GQA (`rope_theta`, whatever `positional` says of the "attn"
+    # layers beside it, which here encode no position) over the last
+    # `window` keys, each layer keeping its OWN keys in a second page space
+    # of THE pool's row shape (`window_paged`); its experts are many and
+    # small, gated by a ReLU (`activation="reglu"`), and its router reads
+    # the layer's input stream, before the first norm and the attention
+    # (`router_input="layer"`).
     layer_kinds: Tuple[str, ...] = ()
     window: int = 0
     ssm_inner: int = 0        # mamba / gmu inner width
@@ -250,6 +268,10 @@ class StackConfig(ModelConfig):
     n_routed_experts: int = 0  # routed experts that exist (0: num_experts)
     experts_first: int = 0    # the first routed expert held here
     experts_zero: int = 0     # identity experts after the routed ones
+    # what the router scores: "ffn": the tensor the experts compute on (the
+    # stream after the mixer, normed); "layer": the layer's input stream,
+    # before its first norm, so the choice is made before the mixer runs
+    router_input: str = "ffn"
     # "mla2": the five sizes of latent attention, and whether the two
     # normed bottlenecks are scaled by sqrt(d_model / rank)
     q_lora_rank: int = 0
@@ -271,7 +293,7 @@ class StackConfig(ModelConfig):
             if kind in kinds and needs not in kinds[:kinds.index(kind)]:
                 raise ValueError(f"a {kind!r} layer needs a {needs!r} "
                                  "layer before it")
-        if "window" in kinds and self.window <= 0:
+        if {"window", "swa"} & set(kinds) and self.window <= 0:
             raise ValueError("window layers need `window` > 0")
         if set(kinds) & set(_DIFFERENTIAL) and (
                 self.n_heads % 2 or self.kv_heads % 2
@@ -284,7 +306,10 @@ class StackConfig(ModelConfig):
             raise ValueError("gdn layers need `gdn_heads`, `gdn_key_dim` "
                              "and `gdn_value_dim`")
         # ONE pool and one array of conv tails: their rows are one shape
+        # ("attn" beside "swa" is two page spaces of ONE row shape)
         for a, b, what in (("attn", "full", "layers that cache keys"),
+                           ("swa", "full", "layers that cache keys"),
+                           ("swa", "window", "window layers' keys"),
                            ("conv", "mamba", "convolution tails"),
                            ("conv", "gdn", "convolution tails"),
                            ("mamba", "gdn", "convolution tails")):
@@ -293,6 +318,13 @@ class StackConfig(ModelConfig):
                                  f"two shapes of {what}")
         if self.router not in ("softmax", "sigmoid", "softmax_all"):
             raise ValueError(f"unknown router {self.router!r}")
+        if self.router_input not in ("ffn", "layer"):
+            raise ValueError(f"unknown router_input {self.router_input!r}")
+        if self.router_input == "layer" and (
+                "mla2" in kinds or self.post_norm or self.n_dense_layers):
+            raise ValueError(
+                'router_input="layer" is written for layers of one mixer and '
+                "one expert half, norms first")
         if "mla2" in kinds:
             if set(kinds) != {"mla2"}:
                 raise ValueError("'mla2' layers beside other kinds in one "
@@ -331,8 +363,21 @@ class StackConfig(ModelConfig):
         """Some layer keeps per-sequence state that is not keys and values
         in THE pool: conv tails, scan state, a delta-rule state matrix, a
         window layer's ring."""
-        return bool({"mamba", "conv", "window", "gdn"}
+        return bool({"mamba", "conv", "window", "gdn", "swa"}
                     & set(self.layer_kinds))
+
+    @property
+    def window_paged(self) -> bool:
+        """The window layers' keys live in a second page space that the
+        engine's allocator serves (the "swa" kind), and not in a ring a
+        decode slot owns (the "window" kind)."""
+        return "swa" in self.layer_kinds
+
+    @property
+    def window_cache_dims(self) -> Tuple[int, int, int]:
+        """(layers, kv heads, head size) of the window page space: the
+        "swa" layers' own heads, THE pool's row shape."""
+        return self.count("swa"), self.kv_heads, self.hdim
 
     @property
     def expert_ff(self) -> int:
@@ -398,7 +443,7 @@ class StackConfig(ModelConfig):
         Di, N, R = self.ssm_inner, self.ssm_state, self.ssm_dt_rank
         # q and o with biases, four lambda vectors, the pair norm's weight
         q_o = 2 * D * H * hd + H * hd + D + 4 * hd + 2 * hd
-        if kind == "attn":
+        if kind in ("attn", "swa"):
             qk = ((H + KVH) * hd if self.qk_norm_whole
                   else 2 * hd if self.qk_norm else 0)
             return 2 * D * H * hd + 2 * D * KVH * hd + qk
@@ -655,6 +700,40 @@ register(StackConfig(
     layer_kinds=_olmo_hybrid_kinds(8), conv_taps=4, qk_norm=True,
     qk_norm_whole=True, post_norm=True, gdn_heads=4, gdn_key_dim=8,
     gdn_value_dim=16, gdn_neg_eigval=True,
+))
+
+def _window_full_kinds(n_layers: int) -> Tuple[str, ...]:
+    """One layer of full attention, then three over a window."""
+    return tuple("attn" if l % 4 == 0 else "swa" for l in range(n_layers))
+
+
+register(StackConfig(
+    name="smallthinker-21b-a3b",
+    # PowerInfer/SmallThinker-21BA3B-Instruct: 21.5 B parameters, 3 B active
+    # a token: 13 layers of full attention that encode no position and 39
+    # rotary layers over a window of 4096 (28 heads of 128 over 4 KV heads,
+    # each layer its own keys), and in every layer 64 ReGLU experts of 768,
+    # 6 a token, chosen from the layer's input before the attention runs
+    vocab_size=151936,
+    d_model=2560, n_layers=52, n_heads=28, n_kv_heads=4, head_dim=128,
+    d_ff=768, max_seq_len=16384,
+    norm="rmsnorm", activation="reglu", positional="none",
+    rope_theta=1500000.0, tie_embeddings=False, norm_eps=1e-6,
+    num_experts=64, num_selected_experts=6, capacity_factor=64 / 6,
+    layer_kinds=_window_full_kinds(52), window=4096, router_input="layer",
+))
+
+register(StackConfig(
+    name="tiny-smallthinker",
+    # the same stack's shape at toy widths: two attn / swa / swa / swa
+    # periods over a window of 16, 8 experts top 3
+    vocab_size=512,
+    d_model=64, n_layers=8, n_heads=8, n_kv_heads=2, head_dim=8, d_ff=32,
+    max_seq_len=128, dtype="float32", remat=False,
+    norm="rmsnorm", activation="reglu", positional="none",
+    rope_theta=10000.0, tie_embeddings=False, norm_eps=1e-6,
+    num_experts=8, num_selected_experts=3, capacity_factor=8 / 3,
+    layer_kinds=_window_full_kinds(8), window=16, router_input="layer",
 ))
 
 register(StackConfig(

@@ -13,7 +13,14 @@ their sublayers, `x + norm(Mix(x))` and `x + norm(FFN(x))`. Mix is one of
           sequence
   mamba   selective state space (Mamba-1): conv tail and scan state per
           sequence; hands its scan output on to the gmu layers after it
-  window  attention over the last `window` keys
+  window  attention over the last `window` keys, which a decode slot
+          holds in a ring of pages of its own, whatever its sequence holds
+  swa     rotary GQA over the last `window` keys (`cfg.rope_theta`; the
+          "attn" layers beside it follow `cfg.positional`, and here encode
+          no position), each layer its OWN keys, which a sequence holds in
+          pages of a second page space that the engine's allocator serves:
+          its ring is a table of the pages it was given, as many as its
+          tokens need and window / page_size + a chunk's pages at the most
   full    attention over every key; its keys and values are THE cache that
           the cross layers after it read
   gmu     gated memory unit: gates the last mamba layer's scan output
@@ -56,7 +63,10 @@ mode:
 What a mode reads and writes travels in `carry`, a dict threaded through
 the layer scans, so pools and state arrays are updated in place; it holds
 what the stack at hand needs and no more (pages alone for the one-block
-models). Layers run as `cfg.segments()`: whole periods scanned, one-off
+models). Where `cfg.router_input` is "layer" the router scores the layer's
+input stream, before the first norm, and the choice is made before the mixer
+runs (scope `route`, then the mixer's, then `moe`). Layers run as
+`cfg.segments()`: whole periods scanned, one-off
 layers once; the one-block models' stacked parameter dict is their one
 segment as it is.
 
@@ -92,6 +102,7 @@ from ..ops import (
     write_then_attend,
 )
 from ..ops.gdn import gdn_chunk, gdn_step, state_shape
+from ..ops.rope import rope_frequencies
 from ..ops.ssm import ssm_scan, ssm_step
 from .config import ModelConfig
 from .transformer import (
@@ -100,6 +111,7 @@ from .transformer import (
     _flash,
     _lm_head,
     _moe_ffn_dropless_ids,
+    _moe_gate,
     _norm,
     _prologue,
     _qkv,
@@ -108,7 +120,7 @@ from .transformer import (
 Params = Dict[str, Any]
 _F32 = jnp.float32
 # kinds that own rows of state arrays or pools, counted as layers go by
-_COUNTED = ("attn", "conv", "mamba", "window", "full", "gdn", "mla2")
+_COUNTED = ("attn", "conv", "mamba", "window", "full", "gdn", "mla2", "swa")
 
 
 # ---------------------------------------------------------------------------
@@ -156,7 +168,7 @@ def layer_shapes(cfg: ModelConfig, kind: str,
                      wo=((H, V, D), "out"), f_in=((D, F), "w"),
                      f_gate=((D, F), "w"), f_out=((F, D), "out"))
         out.update({f"{n}{i}": v for i in (0, 1) for n, v in block.items()})
-    elif kind == "attn":
+    elif kind in ("attn", "swa"):
         out.update(wq=((D, H, hd), "w"), wk=((D, KVH, hd), "w"),
                    wv=((D, KVH, hd), "w"), wo=((H, hd, D), "out"))
         if cfg.qk_norm_whole:
@@ -238,13 +250,17 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
 # ---------------------------------------------------------------------------
 
 
-def ring_pages(cfg: ModelConfig, page_size: int) -> int:
-    """Pages a sequence holds in each window layer, whatever its length:
-    `window` keys span at most window / page_size + 1 pages."""
+def ring_pages(cfg: ModelConfig, page_size: int, chunk: int = 0) -> int:
+    """Pages a sequence holds in each window layer at the most: `window`
+    keys span at most window / page_size + 1 pages. Where a prefill chunk
+    of `chunk` tokens writes its keys to the ring before it attends (the
+    "swa" kind), the ring holds the chunk's pages beside the window's, so
+    that the chunk's last keys do not take the place of keys its first
+    rows still see."""
     if cfg.window % page_size:
         raise ValueError(f"window {cfg.window} must be a multiple of the "
                          f"page size {page_size}")
-    return cfg.window // page_size + 1
+    return cfg.window // page_size + max(chunk // page_size, 1)
 
 
 def new_request_state(cfg: ModelConfig, batch: int, dtype) -> Params:
@@ -280,14 +296,21 @@ def new_request_state(cfg: ModelConfig, batch: int, dtype) -> Params:
 
 
 def new_engine_state(cfg: ModelConfig, batch: int, page_size: int,
-                     act_dtype, cache_dtype) -> Params:
+                     act_dtype, cache_dtype, window_pages: int = 0) -> Params:
     """What the engine holds for `batch` decode slots beside the page pool:
     conv tails and scan state per slot, and for the window layers a page
-    pool of 1 + batch * ring pages in which slot b owns pages
-    1 + b * ring .. (page 0 is never read)."""
+    pool: of 1 + batch * ring pages in which slot b owns pages
+    1 + b * ring .. (page 0 is never read), or, where the window layers'
+    pages are allocated (`cfg.window_paged`), of `window_pages` pages that
+    the engine's allocator hands out (page 0 is its trash page)."""
     st = new_request_state(cfg, batch, act_dtype)
     st.pop("choices", None)  # a span counts its own (the engine's program)
-    if "wk" in st:
+    if cfg.window_paged:
+        pool = pool_shape(cfg.window_cache_dims[0], window_pages, page_size,
+                          *cfg.window_cache_dims[1:])
+        st.update(wk=jnp.zeros(pool, cache_dtype),
+                  wv=jnp.zeros(pool, cache_dtype))
+    elif "wk" in st:
         pool = pool_shape(cfg.count("window"),
                           1 + batch * ring_pages(cfg, page_size), page_size,
                           cfg.pool_heads, cfg.pool_dim)
@@ -362,6 +385,9 @@ class _Mode:
         layers."""
         self.at = self.positions(tokens.shape[1])
         x, self.rope = _prologue(params, tokens, self.cfg, self.at, self.mesh)
+        if self.cfg.window_paged:  # rotary whatever the "attn" layers are
+            self.rope = rope_frequencies(
+                self.cfg.hdim, self.cfg.max_seq_len, self.cfg.rope_theta)
         return x
 
 
@@ -375,13 +401,16 @@ class Seq(_Mode):
     the layers that cache keys write and read the page pool in `carry`
     (by XLA under `tp` > 1 of `mesh`: GSPMD cannot partition the kernel),
     and only `export` leaves the chunk's own keys and values beside it, in
-    the pool's dtype."""
+    the pool's dtype. `window_table` [ring]: a chunk's pages in the window
+    page space (`cfg.window_paged`), whose pool `carry` then holds as the
+    engine does (`wk`, `wv`)."""
 
     def __init__(self, cfg: ModelConfig, n_valid=None, keep: bool = False,
                  chunk=None, page_size: int = 0, mesh=None,
-                 export: bool = False):
+                 export: bool = False, window_table=None):
         self.cfg, self.n_valid, self.keep, self.chunk = cfg, n_valid, keep, chunk
         self.ps, self.mesh, self.export = page_size, mesh, export
+        self.window_table = window_table
         self.by_xla = mesh is not None and mesh.shape.get("tp", 1) > 1
 
     def positions(self, T):
@@ -400,8 +429,22 @@ class Seq(_Mode):
             # where the chunk's keys go in the pool
             self.page = self.chunk[1][self.at[0] // self.ps]
             self.slot = self.at[0] % self.ps
+            if self.window_table is not None:
+                # the ring unrolled over the sequence's pages: page p of
+                # the sequence is entry p modulo the ring's width
+                ring = self.window_table.shape[0]
+                self.unrolled = self.window_table[
+                    jnp.arange(self.chunk[1].shape[0]) % ring]
+                self.window_page = self.unrolled[self.at[0] // self.ps]
         elif self.keep:
             carry.update(new_request_state(cfg, B, x.dtype))
+            if cfg.window_paged:
+                # a bucket's window keys, all of them (a bucket is no
+                # longer than the window): the engine scatters them to the
+                # sequence's pages as it does the other layers' keys
+                layers, kv_heads, head_dim = cfg.window_cache_dims
+                kv = (layers, B, T, kv_heads, head_dim)
+                carry.update(wk=jnp.zeros(kv, dtype), wv=jnp.zeros(kv, dtype))
         if self.chunk is None or self.export:
             layers, kv_heads, head_dim = cfg.cache_dims
             kv = (layers, B, T, kv_heads, head_dim)
@@ -493,6 +536,29 @@ class Seq(_Mode):
             scale=scale, window=W, first=jnp.maximum(W - start, 0))
         return o[None].astype(q.dtype), carry
 
+    def attend_paged_window(self, carry, si, q, k, v, scale):
+        """Attention over the last `window` keys of a layer whose keys are
+        row `si` of the window page space."""
+        W = self.cfg.window
+        if self.chunk is None:
+            if "wk" in carry:
+                carry = {**carry, **{
+                    name: carry[name].at[si].set(new.astype(carry[name].dtype))
+                    for name, new in (("wk", k), ("wv", v))}}
+            return _dense_attend(q, k, v, scale, W), carry
+        start, C = self.chunk[0], q.shape[1]
+
+        def attend(q, kp, vp, layer):
+            return paged_attention_chunk(q, kp, vp, self.unrolled, start,
+                                         start + C, layer, scale=scale,
+                                         window=W)
+
+        # the chunk's keys into the sequence's ring, then attention over it
+        o, wk, wv = write_then_attend(
+            attend, q[0], k[0], v[0], carry["wk"], carry["wv"], si,
+            self.window_page, self.slot)
+        return o[None].astype(q.dtype), {**carry, "wk": wk, "wv": wv}
+
     def attend_full(self, carry, fi, q, k, v, scale):
         """Attention over every key so far, the layer's own (written as
         row `fi` of what caches them) or, k is None: a cross layer, which
@@ -563,10 +629,12 @@ class Decode(_Mode):
     """One token for every decode slot [B, 1]: `positions` [B] is where it
     goes, `page_tables` [B, pages] the pages of the layers that cache keys.
     `carry` holds the engine's pools and state whole (new_engine_state +
-    the pool). Under `tp` > 1 of `mesh` the paged kernel runs per shard."""
+    the pool). Under `tp` > 1 of `mesh` the paged kernel runs per shard.
+    `window_tables` [B, ring]: the slots' pages in the window page space
+    (`cfg.window_paged`; None: slot b owns the ring 1 + b * ring ..)."""
 
     def __init__(self, cfg: ModelConfig, positions, page_tables,
-                 page_size: int, mesh=None):
+                 page_size: int, mesh=None, window_tables=None):
         self.cfg, self.pos, self.tables, self.ps, self.mesh = (
             cfg, positions, page_tables, page_size, mesh)
         B = positions.shape[0]
@@ -578,9 +646,14 @@ class Decode(_Mode):
         # the keys a slot attends over, this token's among them: none where
         # no sequence is, and the paged kernel's program then does nothing
         self.lengths = jnp.where(self.live, positions + 1, 0)
-        self.ring = ring_pages(cfg, page_size) if cfg.count("window") else 1
-        self.ring_table = (1 + jnp.arange(B)[:, None] * self.ring
-                           + jnp.arange(self.ring)[None, :]).astype(jnp.int32)
+        if window_tables is not None:
+            self.ring, self.ring_table = window_tables.shape[1], window_tables
+        else:
+            self.ring = (ring_pages(cfg, page_size) if cfg.count("window")
+                         else 1)
+            self.ring_table = (
+                1 + jnp.arange(B)[:, None] * self.ring
+                + jnp.arange(self.ring)[None, :]).astype(jnp.int32)
 
     def positions(self, T):
         return self.pos[:, None]
@@ -626,6 +699,10 @@ class Decode(_Mode):
             attend, q[:, 0], k[:, 0], v[:, 0],
             carry["wk"], carry["wv"], wi, page[:, 0], self.pos % self.ps)
         return o[:, None], {**carry, "wk": wk, "wv": wv}
+
+    # a ring of allocated pages is a ring: what differs is who filled the
+    # table
+    attend_paged_window = attend_window
 
     def attend_full(self, carry, fi, q, k, v, scale):
         def attend(q, kp, vp, layer):
@@ -697,6 +774,11 @@ class Verify(Decode):
             f"{self.cfg.name!r}: no kernel verifies a span of drafts over "
             "a pool of latents (ops/mla_attention.py has decode and chunk)")
 
+    def attend_paged_window(self, carry, si, q, k, v, scale):
+        raise NotImplementedError(
+            f"{self.cfg.name!r}: Verify is not written over two page "
+            "spaces: a rejected draft's keys have overwritten the page "
+            "behind the window, which no position rewinds")
 
 
 # ---------------------------------------------------------------------------
@@ -933,25 +1015,47 @@ def _attention(h, lp, cfg, kind, layer, idx, mode, carry):
             + lp["bo"].astype(dtype)), carry
 
 
-def _attn(h, lp, cfg, idx, mode, carry):
+def _attn(h, lp, cfg, idx, mode, carry, window=False):
     """The one-block models' mixer: q, k, v turned to the tokens' positions
     (models/transformer.py's, the training block's too), then the mode's
-    attention over the layer's own keys."""
-    q, k, v = _qkv(h, lp, cfg, mode.rope, mode.at)
-    o, carry = mode.attend_full(carry, idx, q, k, v, cfg.hdim ** -0.5)
+    attention over the layer's own keys: every one of them, or (`window`:
+    the "swa" kind, rotary whatever the model says of its other layers)
+    the last `cfg.window` in the window page space."""
+    q, k, v = _qkv(h, lp, cfg, mode.rope, mode.at, True if window else None)
+    attend = mode.attend_paged_window if window else mode.attend_full
+    o, carry = attend(carry, idx, q, k, v, cfg.hdim ** -0.5)
     return jnp.einsum("bthk,hkd->btd", o.astype(h.dtype),
                       lp["wo"].astype(h.dtype)), carry
+
+
+def _count_touched(carry, ids, cfg, mode):
+    """Experts that at least one counted token's choices ids [B,T,k] fell
+    on, added to `touched` [1] where the carry holds it (a decode span's):
+    what a product that skips the unchosen experts would still read."""
+    if "touched" not in carry:
+        return carry
+    counted = mode.counted(*ids.shape[:2])[..., None, None]
+    hit = jnp.any(jax.nn.one_hot(ids, cfg.num_experts, dtype=bool) & counted,
+                  axis=(0, 1, 2))
+    return {**carry, "touched": carry["touched"] + jnp.sum(hit, dtype=_F32)}
 
 
 def _layer(x, lp, cfg, kind, half, layer, idx, mode, carry):
     if kind == "mla2":
         return _mla2(x, lp, cfg, idx, mode, carry)
+    gate = None
+    if cfg.router_input == "layer" and half == "moe":
+        # the router reads the layer's input: the choice is made before
+        # the mixer runs, and the experts wait for nothing but their rows
+        with jax.named_scope("route"):
+            gate = _moe_gate(x, lp, cfg)
+            carry = _count_touched(carry, gate[2], cfg, mode)
     # the scopes are what a profile's readers key on: the mixer's kind
     # ("attn" as in the training block), then "ffn" or "moe"
     with jax.named_scope(kind):
         h = x if cfg.post_norm else _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
-        if kind == "attn":
-            o, carry = _attn(h, lp, cfg, idx, mode, carry)
+        if kind in ("attn", "swa"):
+            o, carry = _attn(h, lp, cfg, idx, mode, carry, kind == "swa")
         elif kind == "gdn":
             o, carry = _gdn(h, lp, cfg, idx, mode, carry)
         elif kind == "conv":
@@ -965,7 +1069,7 @@ def _layer(x, lp, cfg, kind, half, layer, idx, mode, carry):
         if cfg.post_norm:
             o = _norm(o, lp["ln1"], lp.get("ln1_b"), cfg)
         x = x + o
-    return _ffn_half(x, lp, cfg, moe=half == "moe")[0], carry
+    return _ffn_half(x, lp, cfg, moe=half == "moe", gate=gate)[0], carry
 
 
 def run_stack(layers, x, cfg: ModelConfig, mode, carry):

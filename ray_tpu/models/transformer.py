@@ -91,7 +91,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         else:
             layer["w_in"] = dense_init(ks[5], (D, F))
             layer["w_out"] = dense_init(ks[7], (F, D), out_scale)
-            if cfg.activation == "swiglu":
+            if cfg.activation in _GATE_ACT:
                 layer["w_gate"] = dense_init(ks[6], (D, F))
             else:
                 layer["b_in"] = jnp.zeros((F,))
@@ -140,7 +140,7 @@ def param_axes(cfg: ModelConfig) -> Params:
     else:
         layer["w_in"] = ("stage", "embed", "mlp")
         layer["w_out"] = ("stage", "mlp", "embed")
-        if cfg.activation == "swiglu":
+        if cfg.activation in _GATE_ACT:
             layer["w_gate"] = ("stage", "embed", "mlp")
         else:
             layer["b_in"] = ("stage", "mlp")
@@ -193,12 +193,13 @@ def _head_norm(x, w, eps):
     return (xf * w.astype(jnp.float32)).astype(x.dtype)
 
 
-def _qkv(x, lp, cfg, rope_tables, positions):
+def _qkv(x, lp, cfg, rope_tables, positions, rotary=None):
     """x [B,T,D] -> q [B,T,H,hd], k, v [B,T,KVH,hd], q and k normalised
     where the model says (`cfg.qk_norm`: per head, or over the whole vector
     where the weights are [heads,hd]) and turned to `positions`
-    [B,T] (None: 0..T-1) where it is rotary. Shared by the training block
-    below and the serve path's (models/stack.py)."""
+    [B,T] (None: 0..T-1) where it is rotary (`rotary`; None: what the model
+    says of all its layers). Shared by the training block below and the
+    serve path's (models/stack.py)."""
     dtype = x.dtype
     q = jnp.einsum("btd,dhk->bthk", x, lp["wq"].astype(dtype))
     k = jnp.einsum("btd,dhk->bthk", x, lp["wk"].astype(dtype))
@@ -206,7 +207,7 @@ def _qkv(x, lp, cfg, rope_tables, positions):
     if cfg.qk_norm:
         q = _head_norm(q, lp["q_norm"], cfg.norm_eps)
         k = _head_norm(k, lp["k_norm"], cfg.norm_eps)
-    if cfg.positional == "rope":
+    if cfg.positional == "rope" if rotary is None else rotary:
         cos, sin = rope_tables
         q = apply_rope(q, cos, sin, positions)
         k = apply_rope(k, cos, sin, positions)
@@ -235,17 +236,23 @@ def _attention(x, lp, cfg, rope_tables, positions, mesh=None):
     return constrain(o, ("batch", "seq", "embed"))
 
 
+# the gate's activation of a gated second half, dense or an expert's:
+# down(act(gate x) * (up x)); `cfg.activation` names it
+_GATE_ACT = {"swiglu": jax.nn.silu, "reglu": jax.nn.relu}
+
+
 def _dense_ffn(x, lp, cfg):
     dtype = x.dtype
     h = jnp.einsum("btd,df->btf", x, lp["w_in"].astype(dtype))
-    if cfg.activation == "swiglu":
+    gated = cfg.activation in _GATE_ACT
+    if gated:
         g = jnp.einsum("btd,df->btf", x, lp["w_gate"].astype(dtype))
-        h = jax.nn.silu(g) * h
+        h = _GATE_ACT[cfg.activation](g) * h
     else:
         h = jax.nn.gelu(h + lp["b_in"].astype(dtype))
     h = constrain(h, ("batch", "seq", "mlp"))
     out = jnp.einsum("btf,fd->btd", h, lp["w_out"].astype(dtype))
-    if cfg.activation != "swiglu":
+    if not gated:
         out = out + lp["b_out"].astype(dtype)
     return constrain(out, ("batch", "seq", "embed"))
 
@@ -309,17 +316,18 @@ def _moe_gate(x, lp, cfg):
     return logits, weights, expert_ids
 
 
-def _moe_route(x, lp, cfg):
+def _moe_route(x, lp, cfg, gate=None):
     """Shared routing core for BOTH capacity-bound MoE formulations:
-    `_moe_gate` -> cumsum slot assignment under capacity. One
-    implementation so the dense and gather paths can never diverge on
-    capacity/drop semantics (their numerical-parity contract).
+    `_moe_gate` (`gate`: its result, where the layer made the choice
+    earlier, from another tensor than x) -> cumsum slot assignment under
+    capacity. One implementation so the dense and gather paths can never
+    diverge on capacity/drop semantics (their numerical-parity contract).
 
     -> (logits, weights [B,T,k], flat_ids [B,T*k], my_pos, keep, capacity)
     """
     B, T, _ = x.shape
     E, k = cfg.num_experts, cfg.num_selected_experts
-    logits, weights, expert_ids = _moe_gate(x, lp, cfg)
+    logits, weights, expert_ids = gate or _moe_gate(x, lp, cfg)
     capacity = moe_capacity(cfg, T)
     flat_ids = expert_ids.reshape(B, T * k)
     onehot = jax.nn.one_hot(flat_ids, E, dtype=jnp.int32)  # [B,T*k,E]
@@ -340,12 +348,12 @@ def _moe_aux(logits, expert_ids, num_experts):
     return num_experts * jnp.sum(frac_tokens * frac_probs)
 
 
-def _moe_dispatch(x, lp, cfg):
+def _moe_dispatch(x, lp, cfg, gate=None):
     """x [B,T,D] -> (dispatch [B,T,E,C] f32, combine [B,T,E,C] f32, aux)."""
     B, T, _ = x.shape
     E, k = cfg.num_experts, cfg.num_selected_experts
     logits, weights, expert_ids, flat_ids, my_pos, keep, capacity = _moe_route(
-        x, lp, cfg)
+        x, lp, cfg, gate)
     slot = jnp.where(keep, my_pos, 0)
     # ONE big [B,T*k,E,C] mask build; combine reuses it scaled by the
     # slot weight (the second full one-hot product was ~half the
@@ -360,16 +368,18 @@ def _moe_dispatch(x, lp, cfg):
     return disp, combine, _moe_aux(logits, expert_ids, E)
 
 
-def _moe_ffn(x, lp, cfg):
+def _moe_ffn(x, lp, cfg, gate=None):
     """One algorithm (the same gating, the same weighted sum) in the form
     its static shape and mesh allow: where no slot can overflow (every
     decode step, every dropless chunk and bucket) the dispatch is pure
     cost and the experts run over the tokens where they lie; under a
     capacity, rows are gathered to their slots and scattered back; a
-    sharded mesh keeps the dense dispatch."""
+    sharded mesh keeps the dense dispatch. `gate`: `_moe_gate`'s result
+    where the layer scored another tensor than the one the experts compute
+    on, x (None: x is scored, here)."""
     mesh = _current_mesh()
     if _moe_dropless(cfg, x.shape[1], mesh):
-        return _moe_ffn_dropless(x, lp, cfg)
+        return _moe_ffn_dropless(x, lp, cfg, gate)
     if cfg.counts_choices:
         raise ValueError(
             f"{cfg.name!r}: a layer that holds a share of the experts, or "
@@ -377,15 +387,15 @@ def _moe_ffn(x, lp, cfg):
             "over the held experts are not written): capacity_factor >= "
             "num_experts / num_selected_experts, and no sharded mesh")
     if _moe_sharded(mesh):
-        return _moe_ffn_dense(x, lp, cfg)
-    return _moe_ffn_gather(x, lp, cfg)
+        return _moe_ffn_dense(x, lp, cfg, gate)
+    return _moe_ffn_gather(x, lp, cfg, gate)
 
 
-def _moe_ffn_dropless(x, lp, cfg):
-    return _moe_ffn_dropless_ids(x, lp, cfg)[:2]
+def _moe_ffn_dropless(x, lp, cfg, gate=None):
+    return _moe_ffn_dropless_ids(x, lp, cfg, gate)[:2]
 
 
-def _moe_ffn_dropless_ids(x, lp, cfg):
+def _moe_ffn_dropless_ids(x, lp, cfg, gate=None):
     """The expert layer where `moe_capacity(cfg, T) >= T`, so nothing can
     be dropped: no slot tables, no gather, no scatter. Every expert runs
     over the program's own N = B * T tokens (E * N rows, never more than
@@ -393,8 +403,9 @@ def _moe_ffn_dropless_ids(x, lp, cfg):
     step of 32 top 4 / 8 top 2), and a float32 combine matrix c[N, E], a
     token's k weights at its k experts and zero elsewhere, sums them:
     out[n] = sum_e c[n, e] * expert_e(x_n), what the padded forms compute
-    too. The expert axis leads ([E, N, F]) so the weights are read as they
-    lie; x is shared by the experts and never copied E times.
+    too; an expert is the gated FFN `cfg.activation` names (`_GATE_ACT`).
+    The expert axis leads ([E, N, F]) so the weights are read as they lie;
+    x is shared by the experts and never copied E times.
 
     A layer that holds a share of the experts (`cfg.num_experts` of
     `cfg.experts_routed`, from `cfg.experts_first`) routes over all of
@@ -406,7 +417,7 @@ def _moe_ffn_dropless_ids(x, lp, cfg):
     B, T, D = x.shape
     E, W = cfg.num_experts, cfg.router_width
     with jax.named_scope("route"):
-        logits, weights, expert_ids = _moe_gate(x, lp, cfg)
+        logits, weights, expert_ids = gate or _moe_gate(x, lp, cfg)
         c = jnp.sum(jax.nn.one_hot(expert_ids, W, dtype=jnp.float32)
                     * weights[..., None], axis=2)  # float32, as the scores
         aux = _moe_aux(logits, expert_ids, W)
@@ -418,7 +429,7 @@ def _moe_ffn_dropless_ids(x, lp, cfg):
     with jax.named_scope("experts"):
         h = jnp.einsum("nd,edf->enf", xs, lp["w_in"].astype(dtype))
         g = jnp.einsum("nd,edf->enf", xs, lp["w_gate"].astype(dtype))
-        h = jax.nn.silu(g) * h
+        h = _GATE_ACT[cfg.activation](g) * h
         y = jnp.einsum("enf,efd->end", h, lp["w_out"].astype(dtype))
     with jax.named_scope("combine"):
         out = jnp.sum(y.astype(jnp.float32)
@@ -429,31 +440,33 @@ def _moe_ffn_dropless_ids(x, lp, cfg):
         return constrain(out, ("batch", "seq", "embed")), aux, expert_ids
 
 
-def _moe_ffn_dense(x, lp, cfg):
+def _moe_ffn_dense(x, lp, cfg, gate=None):
     dtype = x.dtype
     with jax.named_scope("route"):
-        disp, combine, aux = _moe_dispatch(x, lp, cfg)
+        disp, combine, aux = _moe_dispatch(x, lp, cfg, gate)
     with jax.named_scope("dispatch"):
         expert_in = jnp.einsum("btd,btec->becd", x, disp.astype(dtype))
         expert_in = constrain(expert_in, ("batch", "expert", None, "embed"))
-    y = _experts(expert_in, lp, dtype)
+    y = _experts(expert_in, lp, cfg)
     with jax.named_scope("combine"):
         out = jnp.einsum("becd,btec->btd", y, combine.astype(dtype))
         return constrain(out, ("batch", "seq", "embed")), aux
 
 
-def _experts(expert_in, lp, dtype):
-    """SwiGLU over every expert's rows: [B,E,C,D] -> [B,E,C,D]."""
+def _experts(expert_in, lp, cfg):
+    """The gated FFN (`cfg.activation`) over every expert's rows:
+    [B,E,C,D] -> [B,E,C,D]."""
+    dtype = expert_in.dtype
     with jax.named_scope("experts"):
         h = jnp.einsum("becd,edf->becf", expert_in, lp["w_in"].astype(dtype))
         g = jnp.einsum("becd,edf->becf", expert_in,
                        lp["w_gate"].astype(dtype))
-        h = constrain(jax.nn.silu(g) * h,
+        h = constrain(_GATE_ACT[cfg.activation](g) * h,
                       ("batch", "expert", None, "expert_mlp"))
         return jnp.einsum("becf,efd->becd", h, lp["w_out"].astype(dtype))
 
 
-def _moe_ffn_gather(x, lp, cfg):
+def _moe_ffn_gather(x, lp, cfg, gate=None):
     """Gather/scatter token routing under a capacity (`capacity < T`:
     capacity-factor training on a single chip and non-ep meshes; every
     shape the serve path runs is dropless and dispatches nothing): the
@@ -473,7 +486,7 @@ def _moe_ffn_gather(x, lp, cfg):
     E = cfg.num_experts
     with jax.named_scope("route"):
         (logits, weights, expert_ids, flat_ids, my_pos, keep,
-         capacity) = _moe_route(x, lp, cfg)
+         capacity) = _moe_route(x, lp, cfg, gate)
         k = cfg.num_selected_experts
         safe = jnp.where(keep, my_pos, capacity)  # overflow slot sliced off
         bi = jnp.arange(B)[:, None]
@@ -492,7 +505,7 @@ def _moe_ffn_gather(x, lp, cfg):
         expert_in = gath.reshape(B, E, capacity, D) \
             * valid[..., None].astype(dtype)
         expert_in = constrain(expert_in, ("batch", "expert", None, "embed"))
-    y = _experts(expert_in, lp, dtype)
+    y = _experts(expert_in, lp, cfg)
     with jax.named_scope("combine"):
         yw = y * (w_of * valid)[..., None].astype(dtype)
         out = jax.vmap(lambda ib, yb: jnp.zeros((T, D), dtype).at[ib].add(yb))(
@@ -500,17 +513,19 @@ def _moe_ffn_gather(x, lp, cfg):
         return constrain(out, ("batch", "seq", "embed")), aux
 
 
-def _ffn_half(x, lp, cfg, moe=None):
+def _ffn_half(x, lp, cfg, moe=None, gate=None):
     """A layer's second half, x + FFN(norm(x)) or the experts in its place
     (`moe`; None: what the whole model has), the norm AFTER the sublayer
     where the model says (`cfg.post_norm`: x + norm(FFN(x))) -> (x, aux
-    loss). Shared by the training block below and every layer of the serve
-    path (models/stack.py), which says for each layer which it is."""
+    loss). `gate`: the router's choice where the layer made it earlier,
+    from another tensor than the experts compute on (`_moe_ffn`). Shared by
+    the training block below and every layer of the serve path
+    (models/stack.py), which says for each layer which it is."""
     moe = cfg.is_moe if moe is None else moe
     with jax.named_scope("moe" if moe else "ffn"):
         h = x if cfg.post_norm else _norm(x, lp["ln2"], lp.get("ln2_b"), cfg)
         if moe:
-            y, aux = _moe_ffn(h, lp, cfg)
+            y, aux = _moe_ffn(h, lp, cfg, gate)
         else:
             y, aux = _dense_ffn(h, lp, cfg), jnp.zeros((), jnp.float32)
         if cfg.post_norm:

@@ -141,7 +141,8 @@ _m_page_steps = Counter(
 _m_deferred = Counter(
     "serve_requests_deferred",
     "Requests parked at admission, by reason (no_pages: the pool could "
-    "not hold prompt + max_tokens).")
+    "not hold prompt + max_tokens; no_window_pages: the window page space "
+    "could not hold its ring).")
 _m_front = Histogram(
     "serve_front_seconds",
     "What the serve front adds around the engine, by leg (inbound: the "
@@ -162,8 +163,10 @@ _slot_active = _m_slot_steps.labels(state="active")
 _slot_empty = _m_slot_steps.labels(state="empty")
 _pages_reserved = _m_page_steps.labels(state="reserved")
 _pages_written = _m_page_steps.labels(state="written")
-# a stack of unlike layers (models/stack.py) has two pools: the pages of
-# its full-attention layer, and the window layers' ring per decode slot
+# a stack of unlike layers (models/stack.py) may have two pools: the pages
+# of its full-attention layers, and the window layers' rings (a fixed ring
+# per decode slot, or pages of a second space that `_window_allocator`
+# serves: `ModelConfig.window_paged`)
 _pages_by_pool = {
     (pool, st): _m_page_steps.labels(state=st, pool=pool)
     for pool in ("full", "window") for st in ("reserved", "written")}
@@ -171,9 +174,12 @@ _m_window_pages = Counter(
     "serve_window_page_steps",
     "Per window layer, summed over engine iterations: pages that active "
     "sequences hold keys in (state=held) against the most they may, "
-    "active sequences x (window / page_size + 1) (state=bound).")
+    "active sequences x the ring's pages (state=bound), and against what "
+    "caching every key would hold there (state=full_length; counted where "
+    "the window layers' pages are allocated).")
 _window_held = _m_window_pages.labels(state="held")
 _window_bound = _m_window_pages.labels(state="bound")
+_window_full_length = _m_window_pages.labels(state="full_length")
 _m_state_slots = Counter(
     "serve_state_slots_installed",
     "Decode slots whose recurrent and window state a prefilled sequence "
@@ -204,10 +210,21 @@ _m_moe_choices = Counter(
     "rest fell on experts held elsewhere). Counted on the device and read "
     "with the tokens: a span's with its readback, a prefill's with its "
     "logits.")
+_m_moe_experts = Counter(
+    "serve_moe_expert_steps",
+    "Experts x expert layers x decode steps of the spans read back, by "
+    "state (touched: at least one live row chose the expert, counted on "
+    "the device and read with the tokens; held: every expert the layers "
+    "hold). Counted where the router runs in the layer (`router_input` "
+    "\"layer\"): touched over held is what a product that skips the "
+    "unchosen experts would still read.")
+_experts_touched = _m_moe_experts.labels(state="touched")
+_experts_held = _m_moe_experts.labels(state="held")
 _choices_all = _m_moe_choices.labels(kind="all")
 _choices_zero = _m_moe_choices.labels(kind="zero")
 _choices_held = _m_moe_choices.labels(kind="held")
 _deferred_no_pages = _m_deferred.labels(reason="no_pages")
+_deferred_no_window_pages = _m_deferred.labels(reason="no_window_pages")
 _front_inbound = _m_front.labels(leg="inbound")
 _front_outbound = _m_front.labels(leg="outbound")
 
@@ -311,6 +328,10 @@ class EngineConfig:
     max_batch_size: int = 8
     page_size: int = 16
     max_pages: int = 512  # total pages in the cache pool (incl. trash page)
+    # pages of the window page space (incl. its trash page), where the
+    # model's window layers hold their keys in allocated pages
+    # (`ModelConfig.window_paged`); no other model reads it
+    max_window_pages: int = 0
     max_seq_len: int = 1024
     prefill_buckets: tuple = (64, 128, 256, 512, 1024)
     # >1: queued prompts prefill together in padded batches. Helps
@@ -402,9 +423,13 @@ class EngineConfig:
     @property
     def pages_per_seq(self) -> int:
         """Width of a sequence's page table in THE pool (`max_pages`): every
-        layer's pages for the one-block models, the full-attention layer's
-        for a stack of unlike layers, whose window layers hold a fixed ring
-        per decode slot beside it (models/stack.py: ring_pages)."""
+        layer's pages for the one-block models, the full-attention layers'
+        for a stack of unlike layers. Window layers hold their keys beside
+        it, in one of two designs (models/stack.py: ring_pages): a fixed
+        ring per decode slot, sized by `max_batch_size` (the "window"
+        kind), or a ring of pages allocated from a second page space of
+        `max_window_pages` pages, as many as the sequence's tokens need and
+        the ring's width at the most (the "swa" kind)."""
         return -(-self.max_seq_len // self.page_size)
 
     def prefill_tiers(self) -> List[int]:
@@ -567,12 +592,14 @@ class _ChunkState:
     """One long prompt mid-chunked-prefill."""
 
     __slots__ = ("request", "pages", "table", "true_len", "next_chunk",
-                 "emitted_upto", "sink_seq", "state")
+                 "emitted_upto", "sink_seq", "state", "window_table")
 
     def __init__(self, request: Request, pages: List[int], table, true_len: int):
         self.request = request
         self.pages = pages
         self.table = table  # np [pages_per_seq]
+        # np [ring]: its pages in the window page space, where there is one
+        self.window_table = None
         self.true_len = true_len
         self.next_chunk = 0
         # streamed export bookkeeping: tokens already pushed to kv_sink
@@ -727,15 +754,36 @@ class PrefixCache:
                 "reusable_pages": len(self.lru)}
 
 
+class _SeqPages(list):
+    """The pages a sequence holds in THE pool and, beside them, `window`:
+    those it holds in the window page space. It takes them as it grows
+    (`InferenceEngine._grow`: chunk by chunk in prefill, page by page in
+    decode) out of what admission promised it: `promised` and
+    `window_promised` are the pages it may still take. Every station hands
+    a sequence's pages on as one list, and `_free_pages_and_revive` gives
+    back what it holds and what it never took."""
+
+    __slots__ = ("window", "promised", "window_promised")
+
+    def __init__(self, promised: int, window_promised: int):
+        super().__init__()
+        self.window: List[int] = []
+        self.promised, self.window_promised = promised, window_promised
+
+
 class PageAllocator:
     """Free-list over page ids; page 0 is the reserved trash page that
-    inactive decode slots write into."""
+    inactive decode slots write into. A sequence that takes its pages as
+    it grows is admitted by a promise (`promise`): pages that stay on the
+    free list until it takes them (`take`) or ends (`unpromise`), and that
+    nobody else is given meanwhile, so a growing sequence never waits."""
 
     def __init__(self, num_pages: int):
         self._free = list(range(num_pages - 1, 0, -1))
+        self._promised = 0
 
     def alloc(self, n: int) -> Optional[List[int]]:
-        if len(self._free) < n:
+        if self.num_free < n:
             return None
         out = [self._free.pop() for _ in range(n)]
         return out
@@ -743,9 +791,23 @@ class PageAllocator:
     def free(self, pages: List[int]) -> None:
         self._free.extend(pages)
 
+    def promise(self, n: int) -> bool:
+        if self.num_free < n:
+            return False
+        self._promised += n
+        return True
+
+    def take(self, n: int) -> List[int]:
+        self._promised -= n
+        return [self._free.pop() for _ in range(n)]
+
+    def unpromise(self, n: int) -> None:
+        self._promised -= n
+
     @property
     def num_free(self) -> int:
-        return len(self._free)
+        """Pages that are free and promised to nobody."""
+        return len(self._free) - self._promised
 
 
 class InferenceEngine:
@@ -774,8 +836,12 @@ class InferenceEngine:
         # (conv tails, scan state, the window layers' rings), sized by
         # max_batch_size and the model: the empty tree for the one-block
         # models. Every program takes it and hands it back.
-        self.state = stack.new_engine_state(
-            model_cfg, B, ps, jnp.dtype(model_cfg.dtype), pool.dtype)
+        # the window page space (`cfg.window_paged`): the ring's width, and
+        # the allocator that serves it beside `self.allocator`
+        self._ring = self._window_ring()
+        self._window_allocator = (
+            PageAllocator(engine_cfg.max_window_pages) if self._ring else None)
+        self.state = self._new_state()
         # a sequence's start, shared by every chunked prompt's first
         # chunk (never donated: a chunk hands back a new state)
         self._request_start = stack.new_request_state(
@@ -936,6 +1002,29 @@ class InferenceEngine:
         hidden = not name.endswith("readback") and self._device_busy()
         (self._hidden_ns if hidden else self._phase_ns)[name] += ns
 
+    def _window_ring(self) -> int:
+        """Pages of a sequence's ring in the window page space (0: the
+        model has none), and what the two configs must agree on there."""
+        if not self.cfg.window_paged:
+            return 0
+        ecfg, name = self.ecfg, self.cfg.name
+        ring = stack.ring_pages(
+            self.cfg, ecfg.page_size,
+            ecfg.prefill_chunk if ecfg.chunked_prefill else 0)
+        if ecfg.max_window_pages <= ring:
+            raise ValueError(
+                f"{name!r} holds its window layers' keys in allocated "
+                f"pages: EngineConfig.max_window_pages must hold one "
+                f"sequence's ring of {ring} pages and the trash page; got "
+                f"{ecfg.max_window_pages}")
+        if max(ecfg.prefill_buckets) > self.cfg.window:
+            raise ValueError(
+                f"{name!r}: a prefill bucket of {max(ecfg.prefill_buckets)} "
+                f"tokens is longer than the window ({self.cfg.window}): its "
+                "keys would wrap their own ring before they are written; "
+                "longer prompts go through chunked prefill")
+        return ring
+
     def _refuse_for_stack(self, mesh, ecfg: EngineConfig) -> None:
         """What assumes that pages are the whole state of a request, or
         the one-block models' sharding rules, and is not made right for a
@@ -954,6 +1043,12 @@ class InferenceEngine:
                 "of latents (ops/mla_attention.py has decode and chunk), and "
                 "the draft's pool is keys and values. Serve it with "
                 "speculation off")
+        if scfg is not None and scfg.enabled and self.cfg.window_paged:
+            raise ValueError(
+                f"{name!r}: a draft's keys overwrite the page behind the "
+                "window in the sequence's ring, which no position rewinds, "
+                "and Verify is not written over two page spaces "
+                "(models/stack.py). Serve it with speculation off")
         if scfg is not None and scfg.enabled:
             raise ValueError(
                 f"{name!r}: speculative decoding rewinds rejected drafts by "
@@ -968,6 +1063,13 @@ class InferenceEngine:
                 "and no values; the KV wire carries keys and values by "
                 "head. Disaggregated roles and KV export/import are "
                 "refused for it")
+        if self.cfg.window_paged:
+            raise ValueError(
+                f"{what}: {self.cfg.name!r} holds its window layers' keys "
+                "in a second page space, where a page behind the window is "
+                "overwritten: the KV wire carries ONE table of pages that "
+                "stand for every layer. Disaggregated roles and KV "
+                "export/import are refused for it")
         if self.cfg.is_stack:
             raise ValueError(
                 f"{what}: {self.cfg.name!r} keeps state beside its pages "
@@ -986,6 +1088,22 @@ class InferenceEngine:
                        kv_heads, head_dim),
             jnp.dtype(self.ecfg.cache_dtype), sharding=sharding)
 
+    def _new_state(self):
+        """`self.state`, from the two configs alone."""
+        return stack.new_engine_state(
+            self.cfg, self.ecfg.max_batch_size, self.ecfg.page_size,
+            jnp.dtype(self.cfg.dtype), jnp.dtype(self.ecfg.cache_dtype),
+            self.ecfg.max_window_pages)
+
+    def abstract_state(self, sharding=None):
+        """What the programs take beside the pool, `self.state`, as
+        abstract arrays: the window page space's two pools where the model
+        has one (`cfg.window_paged`), and the per-slot state."""
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=sharding),
+            jax.eval_shape(self._new_state))
+
     # ------------------------------------------------------------- compiled
 
     def _build_decode(self):
@@ -999,7 +1117,9 @@ class InferenceEngine:
         def decode_span(params, k_pages, v_pages, tokens, positions,
                         page_tables, temps, top_ps, top_ks, key, state=None,
                         carry=None, *, n_steps, advanced):
-            """tokens/positions [B]; page_tables [B, pages_per_seq]; `state`:
+            """tokens/positions [B]; page_tables [B, pages_per_seq] (where
+            the window layers' pages are allocated, `cfg.window_paged`: a
+            pair, that and the slots' rings [B, ring]); `state`:
             what the layers keep per slot beside their pages (None: the
             empty tree); `carry`: (tokens, positions, fresh), the [B] pair
             the span before ended on and a [B] mask of the slots that take
@@ -1009,7 +1129,12 @@ class InferenceEngine:
             the live tokens' choices of experts are counted
             (`cfg.counts_choices`) logps has one row more, whose first two
             entries are the span's counts: they come back in the readback
-            the tokens come back in."""
+            the tokens come back in. Where the experts that the live rows
+            chose are counted (`cfg.router_input` "layer") the row's first
+            entry is that count."""
+            window_tables = None
+            if cfg.window_paged:
+                page_tables, window_tables = page_tables
             if carry is not None:
                 carried_tokens, carried_positions, fresh = carry
                 tokens = jnp.where(fresh, tokens, carried_tokens)
@@ -1021,7 +1146,8 @@ class InferenceEngine:
                 # axis (the mode hands it the mesh), not by XLA's fallback
                 x, k_pages, v_pages, state = stack.run_paged(
                     params, tokens[:, None], cfg,
-                    stack.Decode(cfg, positions, page_tables, ps, self.mesh),
+                    stack.Decode(cfg, positions, page_tables, ps, self.mesh,
+                                 window_tables),
                     (k_pages, v_pages), state)
                 with jax.named_scope("lm_head"):
                     logits = _head_logits(x, lambda x: x[:, 0], params, cfg,
@@ -1051,15 +1177,19 @@ class InferenceEngine:
             state = state or {}
             if cfg.counts_choices:
                 state = {**state, "choices": jnp.zeros((2,), jnp.float32)}
+            elif cfg.router_input == "layer":
+                state = {**state, "touched": jnp.zeros((1,), jnp.float32)}
             (tokens, positions, k_pages, v_pages, state), (seq, logps) = \
                 jax.lax.scan(
                     step, (tokens, positions, k_pages, v_pages, state),
                     jnp.arange(n_steps))
-            if cfg.counts_choices:
-                state = dict(state)
-                row = jnp.zeros((1, logps.shape[1]), logps.dtype).at[
-                    0, :2].set(state.pop("choices"))
-                logps = jnp.concatenate([logps, row])
+            for name in ("choices", "touched"):
+                if name in state:
+                    state = dict(state)
+                    counts = state.pop(name)
+                    row = jnp.zeros((1, logps.shape[1]), logps.dtype).at[
+                        0, :counts.shape[0]].set(counts)
+                    logps = jnp.concatenate([logps, row])
             return seq, logps, k_pages, v_pages, state, (tokens, positions)
 
         cache: Dict[Any, Any] = {}
@@ -1103,7 +1233,11 @@ class InferenceEngine:
 
         def chunk_step(params, k_pages, v_pages, tokens, start, page_table,
                        last_idx, state=None, export=False):
-            """tokens [C]; start/last_idx scalars; page_table [pps]; `state`:
+            """tokens [C]; start/last_idx scalars; page_table [pps] (where the
+            window layers' pages are allocated, `cfg.window_paged`: a pair,
+            that and the sequence's ring [ring], and `state` is the engine's
+            own, the window page space's pools, handed back like the pool);
+            `state`:
             what the sequence's chunks so far left behind beside its pages
             (None: a sequence's start where pages are all there is).
             Returns (logits_at_last_idx, k_pages, v_pages, state); with
@@ -1112,11 +1246,15 @@ class InferenceEngine:
             ships this chunk without a separate page-gather dispatch
             (which would queue behind whatever decode span is in flight).
             Rows past last_idx are padding."""
+            window_table = None
+            if cfg.window_paged:
+                page_table, window_table = page_table
             x, new_k, new_v, state = stack.run_paged(
                 params, tokens[None, :], cfg,
                 stack.Seq(cfg, n_valid=(last_idx + 1)[None], keep=True,
                           chunk=(start, page_table), page_size=ps,
-                          mesh=self.mesh, export=export),
+                          mesh=self.mesh, export=export,
+                          window_table=window_table),
                 (k_pages, v_pages), state)
             with jax.named_scope("lm_head"):
                 logits = _head_logits(x, lambda x: x[0, last_idx], params,
@@ -1140,7 +1278,10 @@ class InferenceEngine:
                     tracing.named(
                         functools.partial(chunk_step, export=export),
                         f"chunk_prefill_{C}" + ("_export" if export else "")),
-                    donate_argnums=(1, 2)))
+                    # the window page space's pools come and go with THE
+                    # pool; a sequence's own state is handed from chunk to
+                    # chunk and its start is shared
+                    donate_argnums=(1, 2, 7) if cfg.window_paged else (1, 2)))
             return cache[key]
 
         return for_chunk
@@ -1198,7 +1339,8 @@ class InferenceEngine:
                 seq = self._run_decode(self._decode(span, advanced)(
                     self.params, self.k_pages, self.v_pages,
                     jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
-                    jnp.zeros((B, pps), jnp.int32),
+                    self._tables(jnp.zeros((B, pps), jnp.int32),
+                                 jnp.zeros((B, self._ring), jnp.int32)),
                     jnp.zeros((B,), jnp.float32),
                     jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.int32),
                     jax.random.PRNGKey(0), self.state,
@@ -1207,19 +1349,28 @@ class InferenceEngine:
                 _np.asarray(seq)  # block until compiled + executed
         if self.ecfg.chunked_prefill:
             C = self.ecfg.prefill_chunk
-            logits, self.k_pages, self.v_pages, _ = self._chunk_fn(C)(
+            logits, self.k_pages, self.v_pages, state = self._chunk_fn(C)(
                 self.params, self.k_pages, self.v_pages,
                 jnp.zeros((C,), jnp.int32), jnp.int32(0),
-                jnp.zeros((pps,), jnp.int32), jnp.int32(C - 1),
-                self._request_start,
+                self._tables(jnp.zeros((pps,), jnp.int32),
+                             jnp.zeros((self._ring,), jnp.int32)),
+                jnp.int32(C - 1),
+                self.state if self._ring else self._request_start,
             )
             _np.asarray(logits)
-            if self.state:  # and the program that hands a slot its state
+            if self._ring:  # all-zero tables wrote the trash pages alone
+                self.state = state
+            elif self.state:  # the program that hands a slot its state
                 self.state = self._install_state(
                     self.state, self._request_start, jnp.int32(0),
                     jnp.int32(1))
         if self._spec is not None:
             self._spec.warmup()
+
+    def _tables(self, table, window_table):
+        """A program's page tables: THE pool's, and beside it the window
+        page space's where the model has one."""
+        return (table, window_table) if self._ring else table
 
     def _run_decode(self, out) -> tuple:
         """Take back what a decode program was handed by donation, the
@@ -1267,6 +1418,15 @@ class InferenceEngine:
         self.k_pages, self.v_pages = _scatter_pages_jit(
             self.k_pages, self.v_pages, k, v, page_arr
         )
+        if self._ring:
+            # the bucket's window keys, into the sequence's ring: a bucket
+            # is no longer than the window, so its pages are the ring's
+            # first, in order
+            n_ring = min(len(pages.window), Tpad // ps)
+            self.state["wk"], self.state["wv"] = _scatter_pages_jit(
+                self.state["wk"], self.state["wv"], cache["wk"][:, 0],
+                cache["wv"][:, 0], jnp.asarray(pages.window[:n_ring],
+                                               jnp.int32))
 
     def _export_blob(self, req: Request, pages: List[int], cache,
                      T: int) -> Dict[str, Any]:
@@ -1969,11 +2129,36 @@ class InferenceEngine:
             if self.prefix is not None:
                 pages = self.prefix.release_and_filter(pages)
             self.allocator.free(pages)
+            if self._ring:
+                self._window_allocator.free(pages.window)
+                self.allocator.unpromise(pages.promised)
+                self._window_allocator.unpromise(pages.window_promised)
             waiting, self._waiting = self._waiting, []
         now = tracing.now_ns() if waiting else 0
         for w in waiting:
             w.enter_stage("pending", now)
             self.pending.put(w)
+
+    def _grow(self, pages: "_SeqPages", tokens: int) -> None:
+        """A sequence of two page spaces is about to hold `tokens` tokens:
+        it takes the pages they need and it does not hold yet, in both
+        spaces, out of what admission promised it (`ceil(tokens /
+        page_size)` pages of THE pool, and of the window space the ring's
+        width at the most; never more than the promise, so rows past a
+        sequence's end write the trash page as everywhere)."""
+        n = -(-tokens // self.ecfg.page_size)
+        more = min(n - len(pages), pages.promised)
+        window_more = min(min(n, self._ring) - len(pages.window),
+                          pages.window_promised)
+        if more <= 0 and window_more <= 0:
+            return
+        with self._alloc_lock:
+            if more > 0:
+                pages.extend(self.allocator.take(more))
+                pages.promised -= more
+            if window_more > 0:
+                pages.window.extend(self._window_allocator.take(window_more))
+                pages.window_promised -= window_more
 
     def _alloc_with_reclaim(self, n: int) -> Optional[List[int]]:
         """allocator.alloc, reclaiming zero-ref cached pages on miss —
@@ -2008,7 +2193,19 @@ class InferenceEngine:
             if self.prefix is not None:
                 shared = self.prefix.lookup_acquire(req.prompt, C,
                                                     hashes=hashes)
-            pages = self._alloc_with_reclaim(n_pages - len(shared))
+            short = _deferred_no_pages
+            if not self._ring:
+                pages = self._alloc_with_reclaim(n_pages - len(shared))
+            # two page spaces: a promise in both or in neither, and the
+            # pages themselves as the sequence grows (`_grow`). The ring is
+            # as many pages as its tokens need, its width at the most
+            elif not self.allocator.promise(n_pages):
+                pages = None
+            elif not self._window_allocator.promise(min(n_pages, self._ring)):
+                self.allocator.unpromise(n_pages)
+                pages, short = None, _deferred_no_window_pages
+            else:
+                pages = _SeqPages(n_pages, min(n_pages, self._ring))
             if pages is None:
                 if shared:  # drop the refs we just took
                     self.prefix.release_and_filter(shared)
@@ -2021,12 +2218,13 @@ class InferenceEngine:
                 else:
                     # no capacity; revived by _maybe_finish on page frees
                     req.enter_stage("waiting_for_pages", tracing.now_ns())
-                    _deferred_no_pages.inc()
+                    short.inc()
                     self._waiting.append(req)
                     return None
             else:
                 cancelled = False
-                pages = shared + pages
+                if shared:
+                    pages = shared + pages
         if cancelled:
             self._finish_request(req, "cancelled")
             return None
@@ -2099,6 +2297,8 @@ class InferenceEngine:
                     table = np.zeros((pps,), np.int32)
                     table[: len(pages)] = pages
                     st = _ChunkState(req, pages, table, T)
+                    if self._ring:  # filled as the chunks take their pages
+                        st.window_table = np.zeros((self._ring,), np.int32)
                     st.next_chunk = cached_len // C  # resume past the hits
                     req.enter_stage("chunk_wait", now)
                     self._chunk_queue.append(st)
@@ -2294,9 +2494,14 @@ class InferenceEngine:
                 continue
             # chunked prefills wrote pages directly
             if "k" in cache:
+                if self._ring:
+                    # the bucket's pages, in both spaces: those its padded
+                    # rows fill too, which the scatter writes whole (one
+                    # program a bucket) and decode will soon need
+                    self._grow(pages, cache["k"].shape[2])
                 self._scatter_prefill(cache, pages, T)
             slot = free_slots[0]
-            if self.state:
+            if self.state and not self._ring:
                 # the slot's reset: the sequence's conv tails, scan state
                 # and window keys overwrite what the last occupant left
                 self.state = self._install_state(
@@ -2373,21 +2578,34 @@ class InferenceEngine:
         if req.stage == "chunk_wait":  # its first chunk goes out now
             req.enter_stage("prefill", tracing.now_ns())
         streaming = req.prefill_only and req.kv_sink is not None
+        if self._ring:  # this chunk's pages, in both spaces
+            self._grow(st.pages, start + C)
+            st.table[: len(st.pages)] = st.pages
+            st.window_table[: len(st.pages.window)] = st.pages.window
         if st.state is None:
-            st.state = self._request_start
+            # (where the window layers' pages are allocated a sequence
+            # keeps nothing beside its pages: the chunk takes the engine's
+            # state, the window page space's pools, and hands it back)
+            st.state = {} if self._ring else self._request_start
         # export variant (streaming): the SAME dispatch also returns this
         # chunk's KV slabs, so the streamed frames below need no
         # page-gather program (which would queue behind in-flight decode
         # spans)
         with tracing.region("engine.chunk.put"):
             placed = (jnp.asarray(padded), jnp.int32(start),
-                      jnp.asarray(st.table), jnp.int32(last_idx))
+                      jax.tree.map(jnp.asarray, self._tables(
+                          st.table, st.window_table)),
+                      jnp.int32(last_idx))
         with tracing.region("engine.chunk.call", start=start,
                             tokens=len(toks), padded=C):
-            logits, self.k_pages, self.v_pages, *kv, st.state = \
+            logits, self.k_pages, self.v_pages, *kv, state = \
                 self._chunk_fn(C, streaming)(
                     self.params, self.k_pages, self.v_pages, *placed,
-                    st.state)
+                    self.state if self._ring else st.state)
+            if self._ring:
+                self.state = state
+            else:
+                st.state = state
             del placed  # as in `step()`
         self._chunk_tokens += C
         _m_chunk_rows.inc(C)
@@ -2600,7 +2818,7 @@ class InferenceEngine:
             cur = self._open_span(span, members)
             with self.phase("dispatch", **cur.attrs) as ph:
                 with tracing.region("engine.dispatch.put"):
-                    placed = [jnp.asarray(a) for a in (
+                    placed = [jax.tree.map(jnp.asarray, a) for a in (
                         tokens, positions, tables, temps, top_ps, top_ks,
                         fresh)]
                 with tracing.region("engine.dispatch.call"):
@@ -2645,6 +2863,11 @@ class InferenceEngine:
             # this method know its four positional arguments
             counted = ({"choices": logps[span.steps, :2]}
                        if self.cfg.counts_choices else {})
+            if self.cfg.router_input == "layer":
+                _experts_touched.inc(float(logps[span.steps, 0]))
+                _experts_held.inc(
+                    span.steps * self.cfg.num_experts
+                    * self.cfg.second_halves.count("moe"))
             self._count_moe_rows(self.ecfg.max_batch_size, 1, n, span.steps,
                                  **counted)
             self._tps_committed += self._commit_span(span, seq, logps)
@@ -2664,6 +2887,7 @@ class InferenceEngine:
         tokens = np.zeros((B,), np.int32)
         positions = np.zeros((B,), np.int32)
         tables = np.zeros((B, pps), np.int32)  # page 0 = trash
+        window_tables = np.zeros((B, self._ring), np.int32)
         temps = np.zeros((B,), np.float32)
         top_ps = np.ones((B,), np.float32)
         top_ks = np.zeros((B,), np.int32)
@@ -2683,13 +2907,21 @@ class InferenceEngine:
                 tokens[i] = req.output[-1]
                 positions[i] = s.position
             members[i] = req
+            if self._ring:
+                # the pages of the tokens this span may write (after those
+                # of the span still unread), in both spaces
+                ahead = prev.steps if not fresh[i] else 0
+                self._grow(s.pages, s.position + ahead + max(
+                    self.ecfg.decode_span, self.ecfg.busy_span, 1))
+                window_tables[i, : len(s.pages.window)] = s.pages.window
             tables[i, : len(s.pages)] = s.pages
             temps[i] = req.temperature
             top_ps[i] = req.top_p
             top_ks[i] = req.top_k
             if req.temperature > 0 and (req.top_p < 1.0 or req.top_k > 0):
                 advanced = True  # the sort-based sampler program runs
-        return (members, tokens, positions, tables, temps, top_ps, top_ks,
+        return (members, tokens, positions,
+                self._tables(tables, window_tables), temps, top_ps, top_ks,
                 fresh, advanced)
 
     def _commit_span(self, span: _Span, seq, logps) -> int:
@@ -2791,13 +3023,27 @@ class InferenceEngine:
             for _req, pages, _cache, T in self._ready:
                 reserved += len(pages)
                 written += -(-T // ps)
-        layers = self.cfg.count("window")
+        layers = self.cfg.count("window") or self.cfg.count("swa")
         if not layers:  # one pool
             _pages_reserved.inc(reserved)
             _pages_written.inc(written)
             return
         _pages_by_pool["full", "reserved"].inc(reserved)
         _pages_by_pool["full", "written"].inc(written)
+        if self._ring:
+            # allocated rings: a sequence of n tokens holds keys in
+            # ceil(n / ps) pages and the ring's width at the most, where
+            # caching every key would hold ceil(n / ps)
+            live = [s for s in self.slots if s.request is not None]
+            full_length = [-(-s.position // ps) for s in live]
+            held = sum(min(n, self._ring) for n in full_length)
+            _window_held.inc(held)
+            _window_bound.inc(len(live) * self._ring)
+            _window_full_length.inc(sum(full_length))
+            _pages_by_pool["window", "reserved"].inc(
+                layers * sum(len(s.pages.window) for s in live))
+            _pages_by_pool["window", "written"].inc(layers * held)
+            return
         # window layers: a slot owns its ring whatever it holds; what an
         # active sequence holds keys in are the pages its window spans
         ring = stack.ring_pages(self.cfg, ps)
@@ -3115,6 +3361,7 @@ class InferenceEngine:
         with self._alloc_lock:
             waiting = len(self._waiting)
             free_pages = self.allocator.num_free
+            free_window = self._ring and self._window_allocator.num_free
             prefix = self.prefix.stats() if self.prefix is not None else {}
         # free_pages counts SERVEABLE capacity: zero-ref cached pages are
         # reclaimed on demand (_alloc_with_reclaim), so they are free in
@@ -3135,6 +3382,8 @@ class InferenceEngine:
             **({"window_ring_pages": stack.ring_pages(
                 self.cfg, self.ecfg.page_size)}
                if self.cfg.count("window") else {}),
+            **({"window_ring_pages": self._ring,
+                "free_window_pages": free_window} if self._ring else {}),
             "free_pages": free_pages + prefix.get("reusable_pages", 0),
             **prefix,
             "steps": self._step_count,

@@ -78,7 +78,31 @@ def test_the_eight_are_entries_of_the_five_serve_cells_and_no_train_cell(
     ledger_tests.test_the_eight_are_entries_of_the_five_serve_cells_and_no_train_cell()
 
 
-LATER_CELLS = ("longcat-flash-omni.serve-docs",)  # PR 39
+LATER_CELLS = ("longcat-flash-omni.serve-docs",  # PR 39
+               "smallthinker-21b-a3b.serve-mixedlen")  # PR 41
+
+
+def manifest_without(cells):
+    """The manifest without `cells` that later PRs appended: their entries
+    of `workloads`, their names in each metric's own list, the
+    configurations no cell is left for and the metrics no cell is left
+    in."""
+    manifest = common.load_manifest()
+
+    def without(entry):
+        if "workloads" not in entry:
+            return entry
+        return {**entry, "workloads": [w for w in entry["workloads"]
+                                       if w not in cells]}
+
+    workloads = [w for w in manifest["workloads"] if w["name"] not in cells]
+    return {**manifest,
+            "configs": [c for c in manifest["configs"]
+                        if any(w["config"] == c["name"] for w in workloads)],
+            "workloads": workloads,
+            "end_to_end": [without(m) for m in manifest["end_to_end"]],
+            "per_layer": [m for m in map(without, manifest["per_layer"])
+                          if m.get("workloads", True)]}
 
 
 def as_of_five_serve_cells():
@@ -86,20 +110,9 @@ def as_of_five_serve_cells():
     `workloads` and to each entry's own list): those two tests of the
     benchmark's pin the serve cells at five, and an edit of their files
     reads as a change to the accepted benchmark. That the later cells are
-    listed where they should be is `test_longcat_*`'s to hold."""
-    manifest = common.load_manifest()
-
-    def without(entry):
-        if "workloads" not in entry:
-            return entry
-        return {**entry, "workloads": [w for w in entry["workloads"]
-                                       if w not in LATER_CELLS]}
-
-    return {**manifest,
-            "workloads": [w for w in manifest["workloads"]
-                          if w["name"] not in LATER_CELLS],
-            "end_to_end": [without(m) for m in manifest["end_to_end"]],
-            "per_layer": [without(m) for m in manifest["per_layer"]]}
+    listed where they should be is `test_longcat_*`'s and
+    `test_smallthinker_model.py`'s to hold."""
+    return manifest_without(LATER_CELLS)
 
 
 def test_it_is_an_entry_of_the_five_serve_cells_and_no_train_cell(monkeypatch):
@@ -564,8 +577,9 @@ def test_longcat_readers_reach_the_counts_through_the_family():
         "decode_live_slots.traced", "decode_span_ahead_share"}
     assert [m["name"] for m in cell["end_to_end"]] == ["tpot_mean_ms", "setup_s"]
     assert all(m["moves"] == "tpot_mean_ms" for m in cell["per_layer"])
-    # appended, never inserted: the new entries end their lists
-    manifest = common.load_manifest()
+    # appended, never inserted: the new entries ended their lists, before
+    # later PRs appended theirs
+    manifest = manifest_without(LATER_CELLS[1:])
     assert manifest["configs"][-1]["name"] == LONGCAT
     assert manifest["workloads"][-1]["name"] == LONGCAT_CELL
     assert tuple(m["name"] for m in manifest["per_layer"][-6:]) == NEW_READERS

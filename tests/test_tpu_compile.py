@@ -185,6 +185,20 @@ CASES = {
         lambda q, k, v: flash_attention(q, k, v, causal=True,
                                         scale=192 ** -0.5),
         [((4, 256, MLA_H, 192), BF16)] * 2 + [((4, 256, MLA_H, 128), BF16)], 1),
+    # window layers whose rings are allocated pages: 7 query heads a kv head,
+    # 4 kv heads of 128 in a row of 512 lanes, a ring of 272 pages (a
+    # window of 4096 and a chunk of 256) out of the cell's window page
+    # space; the chunk reads the ring unrolled over the sequence's 1024 pages
+    "paged_decode_window4096_ring272": (
+        lambda q, kp, vp, pt, n: paged_attention_decode(
+            q, kp, vp, pt, n, 5, window=4096),
+        [((64, 28, D), BF16)] + [(pool_shape(9, 8193, PAGE, 4, D), BF16)] * 2
+        + [((64, 272), I32), ((64,), I32)], 1),
+    "paged_chunk_window4096_past8192": (
+        lambda q, kp, vp, pt: paged_attention_chunk(
+            q, kp, vp, pt, 8192, 8448, 5, window=4096),
+        [((256, 28, D), BF16)] + [(pool_shape(9, 8193, PAGE, 4, D), BF16)] * 2
+        + [((1024,), I32)], 1),
     "rms_norm_2048x4096": (
         rms_norm, [((2048, D_MODEL), BF16), ((D_MODEL,), BF16)], 1),
 }
@@ -452,3 +466,65 @@ def test_the_latent_cells_programs_hold_one_pool_at_published_widths(
     text = compiled.as_text()
     assert len(re.findall(r"%%%s(\.\d+)? = " % kernel, text)) == 2
     assert not re.search(r"= bf16\[8,(1,)?24577,16,640\]\S* copy\(", text)
+
+
+@pytest.mark.parametrize("program", ["decode_span_8", "chunk_prefill_256"])
+def test_the_window_and_full_cells_programs_hold_two_page_spaces(
+        program, topo, no_persistent_cache):
+    """`smallthinker-21b-a3b.serve-mixedlen` as the benchmark sizes it: the
+    scanned period of four compiles for the chip with one paged kernel over
+    THE pool and three windowed ones over the window page space, both
+    spaces' pools donated and handed back in place, no XLA attention, no
+    copy of a pool, and the memory the cell's `pool_filled` quotes: 10.36
+    GiB of weights + 1.125 GiB of full pages + 2.25 GiB of window pages as
+    arguments, under 0.25 GiB of temporaries."""
+    from benchmark import common
+    from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    cell = common.load_cell("smallthinker-21b-a3b.serve-mixedlen")
+    spec = cell["config"]
+    family = common.family(spec)
+    eng = object.__new__(InferenceEngine)
+    eng.cfg, eng.ecfg, eng.mesh, eng._tp = (
+        family.model_config(spec), EngineConfig(**cell["engine"]), None, 1)
+    ring = eng._window_ring()
+    assert ring == 4096 // 16 + 256 // 16
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: s(a.shape, a.dtype), jax.eval_shape(
+            lambda k: family.init_weights(spec, k), jax.random.PRNGKey(0)))
+    pool, state = eng.abstract_pool(one_chip), eng.abstract_state(one_chip)
+    assert pool.shape == (3, 1, 12289, 16, 512)
+    assert {k: v.shape for k, v in state.items()} == {
+        "wk": (9, 1, 8193, 16, 512), "wv": (9, 1, 8193, 16, 512)}
+    ecfg = eng.ecfg
+    B, pps, C = ecfg.max_batch_size, ecfg.pages_per_seq, ecfg.prefill_chunk
+    f32 = jnp.float32
+    if program == "decode_span_8":
+        lowered = eng._build_decode()(8).lower(
+            params, pool, pool, s((B,), I32), s((B,), I32),
+            (s((B, pps), I32), s((B, ring), I32)), s((B,), f32), s((B,), f32),
+            s((B,), I32), s((2,), jnp.uint32), state,
+            (s((B,), I32), s((B,), I32), s((B,), jnp.bool_)))
+        kernel = "paged_decode"
+    else:
+        lowered = eng._build_chunk_prefill()(C).lower(
+            params, pool, pool, s((C,), I32), s((), I32),
+            (s((pps,), I32), s((ring,), I32)), s((), I32), state)
+        kernel = "paged_chunk"
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    gib = 2 ** 30
+    pools = 2 * pool.size * pool.dtype.itemsize + sum(
+        a.size * a.dtype.itemsize for a in state.values())
+    assert memory.alias_size_in_bytes >= pools
+    assert 13.6 < memory.argument_size_in_bytes / gib < 13.85
+    assert memory.temp_size_in_bytes < 0.25 * gib
+    text = compiled.as_text()
+    assert len(re.findall(r"%%%s(\.\d+)? = " % kernel, text)) == 1
+    assert len(re.findall(r"%%%s_window(\.\d+)? = " % kernel, text)) == 3
+    assert not re.search(r"= bf16\[\d+,(1,)?\d+,16,512\]\S* copy\(", text)
