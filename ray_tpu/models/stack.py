@@ -70,6 +70,17 @@ runs (scope `route`, then the mixer's, then `moe`). Layers run as
 layers once; the one-block models' stacked parameter dict is their one
 segment as it is.
 
+The experts take the form their program's static shape allows (`_experts`).
+A STEP (`Decode`, one token a row: the mode knows which rows hold a
+sequence) visits the experts that at least one live row chose and reads no
+byte of the others (ops/moe.py, models/transformer.py `moe_ffn_step`): a
+step's time is its weights' bytes, and a few live rows choose a few
+experts. Its kernel reads a layer's experts where they lie, so `run_stack`
+hands such a program a segment's `w_in` / `w_gate` / `w_out` stacks whole,
+beside the layer's index, and not as the layer scan's slices. A chunk, a
+bucket, `Verify`, `forward` and a sharded mesh run every expert over their
+tokens (`_moe_ffn`), as they did; no flag, option or model's name decides.
+
 Differential attention (window / full / cross) rides on the plain kernels:
 a KV pair is stored as one row [k1 ; k2] (and [v1 ; v2]) of twice the head
 size, and a query head is padded with zeros on the side of the other
@@ -104,23 +115,29 @@ from ..ops import (
 from ..ops.gdn import gdn_chunk, gdn_step, state_shape
 from ..ops.rope import rope_frequencies
 from ..ops.ssm import ssm_scan, ssm_step
+from ..parallel.sharding import _current_mesh
 from .config import ModelConfig
 from .transformer import (
     _dense_ffn,
     _ffn_half,
     _flash,
     _lm_head,
+    _moe_ffn,
     _moe_ffn_dropless_ids,
     _moe_gate,
     _norm,
     _prologue,
     _qkv,
+    moe_ffn_step,
+    moe_step_visits,
 )
 
 Params = Dict[str, Any]
 _F32 = jnp.float32
 # kinds that own rows of state arrays or pools, counted as layers go by
 _COUNTED = ("attn", "conv", "mamba", "window", "full", "gdn", "mla2", "swa")
+# an expert layer's leaves that a step reads where they lie (`run_stack`)
+_EXPERT_LEAVES = ("w_in", "w_gate", "w_out")
 
 
 # ---------------------------------------------------------------------------
@@ -389,6 +406,11 @@ class _Mode:
             self.rope = rope_frequencies(
                 self.cfg.hdim, self.cfg.max_seq_len, self.cfg.rope_theta)
         return x
+
+    def live_rows(self, T):
+        """bool [B]: which rows of a STEP (T = 1) hold a sequence, where
+        the mode knows; None for every other program."""
+        return None
 
 
 class Seq(_Mode):
@@ -671,6 +693,9 @@ class Decode(_Mode):
     def counted(self, B, T):
         return jnp.broadcast_to(self.live[:, None], (B, T))
 
+    def live_rows(self, T):
+        return self.live if T == 1 else None
+
     def conv(self, carry, mi, u):
         ext = jnp.concatenate([carry["conv"][mi].astype(u.dtype), u], axis=1)
         return ext, {**carry, "conv": carry["conv"].at[mi].set(
@@ -749,6 +774,9 @@ class Verify(Decode):
 
     def positions(self, T):
         return self.pos[:, None] + jnp.arange(T)[None, :]
+
+    def live_rows(self, T):
+        return None  # S tokens a slot, whatever S: not a step
 
     def init_carry(self, x, pools, state) -> Params:
         B, S = self.at.shape
@@ -950,8 +978,7 @@ def _mla2(x, lp, cfg, idx, mode, carry):
         b = _norm(x, bp["p_ln"], None, cfg)
         if i == 0:
             with jax.named_scope("moe"):
-                shortcut, _, ids = _moe_ffn_dropless_ids(b, lp, cfg)
-                carry = _count_choices(carry, ids, cfg, mode)
+                shortcut, carry = _experts(b, lp, cfg, None, mode, carry)
         with jax.named_scope("ffn"):
             x = x + _dense_ffn(b, {"w_in": bp["f_in"], "w_gate": bp["f_gate"],
                                    "w_out": bp["f_out"]}, cfg)
@@ -1028,16 +1055,30 @@ def _attn(h, lp, cfg, idx, mode, carry, window=False):
                       lp["wo"].astype(h.dtype)), carry
 
 
-def _count_touched(carry, ids, cfg, mode):
-    """Experts that at least one counted token's choices ids [B,T,k] fell
-    on, added to `touched` [1] where the carry holds it (a decode span's):
-    what a product that skips the unchosen experts would still read."""
+def _count_touched(carry, visited):
+    """The experts a step's product visited (those that at least one live
+    row chose: `moe_ffn_step`'s own list), added to `touched` [1] where the
+    carry holds it (a decode span's)."""
     if "touched" not in carry:
         return carry
-    counted = mode.counted(*ids.shape[:2])[..., None, None]
-    hit = jnp.any(jax.nn.one_hot(ids, cfg.num_experts, dtype=bool) & counted,
-                  axis=(0, 1, 2))
-    return {**carry, "touched": carry["touched"] + jnp.sum(hit, dtype=_F32)}
+    return {**carry, "touched": carry["touched"] + visited.astype(_F32)}
+
+
+def _experts(h, lp, cfg, gate, mode, carry):
+    """The expert layer over the normed rows h [B,T,D] in the form the
+    program's static shape allows (models/transformer.py `_moe_ffn`): a
+    STEP, where the mode knows its live rows, visits the experts they chose
+    and no others (`lp["experts"]`, which `run_stack` hands such a program);
+    every other program runs the forms it ran. -> (y, carry with what it
+    counts)."""
+    if "experts" in lp:
+        y, ids, visited = moe_ffn_step(h, lp, cfg, gate, mode.live_rows(1))
+        carry = _count_touched(carry, visited)
+    elif cfg.counts_choices:  # a share layer: where the choices fell
+        y, _, ids = _moe_ffn_dropless_ids(h, lp, cfg, gate)
+    else:
+        return _moe_ffn(h, lp, cfg, gate)[0], carry
+    return y, _count_choices(carry, ids, cfg, mode)
 
 
 def _layer(x, lp, cfg, kind, half, layer, idx, mode, carry):
@@ -1049,7 +1090,6 @@ def _layer(x, lp, cfg, kind, half, layer, idx, mode, carry):
         # the mixer runs, and the experts wait for nothing but their rows
         with jax.named_scope("route"):
             gate = _moe_gate(x, lp, cfg)
-            carry = _count_touched(carry, gate[2], cfg, mode)
     # the scopes are what a profile's readers key on: the mixer's kind
     # ("attn" as in the training block), then "ffn" or "moe"
     with jax.named_scope(kind):
@@ -1069,7 +1109,10 @@ def _layer(x, lp, cfg, kind, half, layer, idx, mode, carry):
         if cfg.post_norm:
             o = _norm(o, lp["ln1"], lp.get("ln1_b"), cfg)
         x = x + o
-    return _ffn_half(x, lp, cfg, moe=half == "moe", gate=gate)[0], carry
+    if half == "moe":
+        return _ffn_half(x, lp, cfg, True, lambda h: _experts(
+            h, lp, cfg, gate, mode, carry))
+    return _ffn_half(x, lp, cfg, False)[0], carry
 
 
 def run_stack(layers, x, cfg: ModelConfig, mode, carry):
@@ -1082,14 +1125,31 @@ def run_stack(layers, x, cfg: ModelConfig, mode, carry):
     if isinstance(layers, dict):
         layers = [(layers,)]
     seen = dict.fromkeys(_COUNTED, 0)
+    lifts = (mode.live_rows(x.shape[1]) is not None
+             and moe_step_visits(cfg, _current_mesh()))
     for (first, kinds, repeats), seg in zip(cfg.segments(), layers):
         per = {k: kinds.count(k) for k in seen}
+        stacks = (None,) * len(kinds)
+        if lifts:
+            # a step's kernel reads a layer's experts where they lie, in the
+            # segment's stacks (a slice, the scan's or `a[0]`, before a
+            # custom call is a copy of every expert, every step): they go
+            # to the layers whole, beside the layer's index in them
+            stacks = tuple(
+                {n: lp[n] for n in _EXPERT_LEAVES}
+                if cfg.second_halves[first + i] == "moe" else None
+                for i, lp in enumerate(seg))
+            seg = tuple({n: a for n, a in lp.items() if n not in (held or ())}
+                        for lp, held in zip(seg, stacks))
 
-        def period(c, xs, first=first, kinds=kinds, base=dict(seen), per=per):
+        def period(c, xs, first=first, kinds=kinds, base=dict(seen), per=per,
+                   stacks=stacks):
             x, carry = c
             lps, rep = xs
             idx = {k: base[k] + rep * per[k] for k in base}
             for i, (kind, lp) in enumerate(zip(kinds, lps)):
+                if stacks[i] is not None:
+                    lp = {**lp, "experts": (stacks[i], rep)}
                 # a cross layer reads the newest full layer's cache
                 at = idx["full"] - 1 if kind == "cross" else idx.get(kind)
                 x, carry = _layer(x, lp, cfg, kind,

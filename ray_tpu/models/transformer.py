@@ -28,7 +28,14 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ..ops import apply_rope, flash_attention, layer_norm, rms_norm, rope_frequencies
+from ..ops import (
+    apply_rope,
+    expert_step,
+    flash_attention,
+    layer_norm,
+    rms_norm,
+    rope_frequencies,
+)
 from ..ops.attention import FLASH_RESIDUAL_NAMES
 from ..parallel.moe import sigmoid_bias_gating, top_k_gating
 from ..parallel.sharding import _current_mesh, constrain, per_shard
@@ -285,12 +292,24 @@ def _moe_dropless(cfg, T: int, mesh) -> bool:
     return moe_capacity(cfg, T) >= T and not _moe_sharded(mesh)
 
 
+def moe_step_visits(cfg, mesh) -> bool:
+    """Whether a decode STEP (one token a row, the mode knows which rows
+    are live) runs its experts as `moe_ffn_step`, the experts a live row
+    chose and no others: wherever the model has experts and the step
+    dispatches nothing. The rule is the program's static shape and mesh, as
+    `_moe_dropless` is; the serve path's layers (models/stack.py
+    `run_stack`) and the engine's counters both ask it."""
+    return "moe" in cfg.second_halves and _moe_dropless(cfg, 1, mesh)
+
+
 def moe_rows_computed(cfg, B: int, T: int, mesh=None) -> int:
     """Expert rows ONE expert layer computes for a program of B rows of T
     tokens, whichever form `_moe_ffn` takes: every expert over the
     program's own B * T tokens when it dispatches nothing, else
     B x experts x capacity padded slots. The engine's
-    `serve_moe_rows_computed` counts with it."""
+    `serve_moe_rows_computed` counts with it, but for a decode step that
+    visits (`moe_step_visits`): its rows are the experts VISITED x B, which
+    the device counts and the span's readback brings."""
     per_row = T if _moe_dropless(cfg, T, mesh) else moe_capacity(cfg, T)
     return cfg.num_experts * B * per_row
 
@@ -371,12 +390,21 @@ def _moe_dispatch(x, lp, cfg, gate=None):
 def _moe_ffn(x, lp, cfg, gate=None):
     """One algorithm (the same gating, the same weighted sum) in the form
     its static shape and mesh allow: where no slot can overflow (every
-    decode step, every dropless chunk and bucket) the dispatch is pure
-    cost and the experts run over the tokens where they lie; under a
+    dropless chunk and bucket, `Verify`, a training row) the dispatch is
+    pure cost and the experts run over the tokens where they lie; under a
     capacity, rows are gathered to their slots and scattered back; a
     sharded mesh keeps the dense dispatch. `gate`: `_moe_gate`'s result
     where the layer scored another tensor than the one the experts compute
-    on, x (None: x is scored, here)."""
+    on, x (None: x is scored, here).
+
+    A decode STEP of the serve path does not come here: its mode knows
+    which rows hold a sequence, a few rows touch a few experts, and the
+    step's time is the experts' bytes, so models/stack.py hands it to
+    `moe_ffn_step` (the same sum; the terms of the experts that no live
+    row chose are left out, and their weights unread). A chunk's 256 rows
+    touch every expert (1 - (58 / 64)^256), training and `Verify` know no
+    live rows: every caller without a live mask keeps the forms below. No
+    flag, option or model's name decides: `moe_step_visits`."""
     mesh = _current_mesh()
     if _moe_dropless(cfg, x.shape[1], mesh):
         return _moe_ffn_dropless(x, lp, cfg, gate)
@@ -395,6 +423,25 @@ def _moe_ffn_dropless(x, lp, cfg, gate=None):
     return _moe_ffn_dropless_ids(x, lp, cfg, gate)[:2]
 
 
+def _moe_combine(x, lp, cfg, gate=None):
+    """The gating as the forms that dispatch nothing use it -> (c [B,T,E]
+    float32: a token's k weights at its experts among the E held ones and
+    zero elsewhere; identity [B,T]: the weight its choices put on identity
+    experts, None where the model has none; aux; expert_ids [B,T,k] over
+    all `cfg.router_width` outputs)."""
+    E, W = cfg.num_experts, cfg.router_width
+    logits, weights, expert_ids = gate or _moe_gate(x, lp, cfg)
+    c = jnp.sum(jax.nn.one_hot(expert_ids, W, dtype=jnp.float32)
+                * weights[..., None], axis=2)  # float32, as the scores
+    aux = _moe_aux(logits, expert_ids, W)
+    identity = None
+    if cfg.experts_zero:
+        identity = jnp.sum(c[..., cfg.experts_routed:], axis=-1)
+    if W != E:
+        c = c[..., cfg.experts_first:cfg.experts_first + E]
+    return c, identity, aux, expert_ids
+
+
 def _moe_ffn_dropless_ids(x, lp, cfg, gate=None):
     """The expert layer where `moe_capacity(cfg, T) >= T`, so nothing can
     be dropped: no slot tables, no gather, no scatter. Every expert runs
@@ -405,7 +452,9 @@ def _moe_ffn_dropless_ids(x, lp, cfg, gate=None):
     out[n] = sum_e c[n, e] * expert_e(x_n), what the padded forms compute
     too; an expert is the gated FFN `cfg.activation` names (`_GATE_ACT`).
     The expert axis leads ([E, N, F]) so the weights are read as they lie;
-    x is shared by the experts and never copied E times.
+    x is shared by the experts and never copied E times. Chunks, buckets,
+    `Verify` and training rows take this form; a decode step of the serve
+    path, which knows its live rows, takes `moe_ffn_step` (`_moe_ffn`).
 
     A layer that holds a share of the experts (`cfg.num_experts` of
     `cfg.experts_routed`, from `cfg.experts_first`) routes over all of
@@ -415,16 +464,9 @@ def _moe_ffn_dropless_ids(x, lp, cfg, gate=None):
     no product. -> (out, aux, expert_ids [B,T,k])."""
     dtype = x.dtype
     B, T, D = x.shape
-    E, W = cfg.num_experts, cfg.router_width
+    E = cfg.num_experts
     with jax.named_scope("route"):
-        logits, weights, expert_ids = gate or _moe_gate(x, lp, cfg)
-        c = jnp.sum(jax.nn.one_hot(expert_ids, W, dtype=jnp.float32)
-                    * weights[..., None], axis=2)  # float32, as the scores
-        aux = _moe_aux(logits, expert_ids, W)
-        if cfg.experts_zero:
-            identity = jnp.sum(c[..., cfg.experts_routed:], axis=-1)
-        if W != E:
-            c = c[..., cfg.experts_first:cfg.experts_first + E]
+        c, identity, aux, expert_ids = _moe_combine(x, lp, cfg, gate)
     xs = x.reshape(B * T, D)
     with jax.named_scope("experts"):
         h = jnp.einsum("nd,edf->enf", xs, lp["w_in"].astype(dtype))
@@ -438,6 +480,39 @@ def _moe_ffn_dropless_ids(x, lp, cfg, gate=None):
             out = out + identity.reshape(B * T, 1) * xs.astype(jnp.float32)
         out = out.astype(dtype).reshape(B, T, D)
         return constrain(out, ("batch", "seq", "embed")), aux, expert_ids
+
+
+def moe_ffn_step(x, lp, cfg, gate, live):
+    """`_moe_ffn_dropless_ids` for a decode STEP x [B,1,D] whose rows
+    `live` (bool [B]) hold a sequence: the same gating, the same float32
+    combine and the same rounding points, over the experts that at least
+    one live row chose; the others' terms are zero for every live row, and
+    their weights are not read (ops/moe.py; a dead slot's row chooses too,
+    touches nothing and is discarded by the engine). `lp["experts"]`:
+    (the segment's stacks `w_in`, `w_gate` [layers,E,D,F] and `w_out`
+    [layers,E,F,D], this layer's index in them): the kernel reads the
+    layer where it lies (models/stack.py `run_stack`). Identity experts and
+    the held slice of a share layer act on c, as there.
+    -> (out, expert_ids [B,1,k], experts visited: int32 [])."""
+    dtype = x.dtype
+    B, _, D = x.shape
+    E, first = cfg.num_experts, cfg.experts_first
+    with jax.named_scope("route"):
+        c, identity, _, expert_ids = _moe_combine(x, lp, cfg, gate)
+        chosen = jax.nn.one_hot(expert_ids, cfg.router_width, dtype=bool)
+        hit = jnp.any(chosen & live[:, None, None, None],
+                      axis=(0, 1, 2))[first:first + E]
+    xs = x.reshape(B, D)
+    stacks, layer = lp["experts"]
+    with jax.named_scope("experts"):
+        out, visited = expert_step(
+            xs, c.reshape(B, E), hit, stacks["w_in"], stacks["w_gate"],
+            stacks["w_out"], layer, _GATE_ACT[cfg.activation])
+    with jax.named_scope("combine"):
+        if cfg.experts_zero:
+            out = out + identity.reshape(B, 1) * xs.astype(jnp.float32)
+        out = out.astype(dtype).reshape(B, 1, D)
+        return constrain(out, ("batch", "seq", "embed")), expert_ids, visited
 
 
 def _moe_ffn_dense(x, lp, cfg, gate=None):
@@ -513,19 +588,20 @@ def _moe_ffn_gather(x, lp, cfg, gate=None):
         return constrain(out, ("batch", "seq", "embed")), aux
 
 
-def _ffn_half(x, lp, cfg, moe=None, gate=None):
+def _ffn_half(x, lp, cfg, moe=None, experts=None):
     """A layer's second half, x + FFN(norm(x)) or the experts in its place
     (`moe`; None: what the whole model has), the norm AFTER the sublayer
     where the model says (`cfg.post_norm`: x + norm(FFN(x))) -> (x, aux
-    loss). `gate`: the router's choice where the layer made it earlier,
-    from another tensor than the experts compute on (`_moe_ffn`). Shared by
-    the training block below and every layer of the serve path
-    (models/stack.py), which says for each layer which it is."""
+    loss). `experts`: what runs the experts over the normed rows, h -> (y,
+    what it hands back in the aux loss's place) (None: `_moe_ffn`): the
+    serve path's layers (models/stack.py), which say for each layer which
+    half it has, count in their carry there. Shared by them and the
+    training block below."""
     moe = cfg.is_moe if moe is None else moe
     with jax.named_scope("moe" if moe else "ffn"):
         h = x if cfg.post_norm else _norm(x, lp["ln2"], lp.get("ln2_b"), cfg)
         if moe:
-            y, aux = _moe_ffn(h, lp, cfg, gate)
+            y, aux = experts(h) if experts else _moe_ffn(h, lp, cfg)
         else:
             y, aux = _dense_ffn(h, lp, cfg), jnp.zeros((), jnp.float32)
         if cfg.post_norm:

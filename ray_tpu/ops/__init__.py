@@ -20,6 +20,7 @@ from .mla_attention import (  # noqa: F401
     latent_attention_decode,
     write_latent_then_attend,
 )
+from .moe import expert_step  # noqa: F401
 from .norm import layer_norm, rms_norm, rms_norm_reference  # noqa: F401
 from .rope import apply_rope, rope_frequencies  # noqa: F401
 from .paged_attention import (  # noqa: F401

@@ -54,7 +54,11 @@ from ..core.logging import get_logger
 from ..core.metrics import Counter, Gauge, Histogram
 from ..util import slo, tracing
 from ..models import ModelConfig, stack
-from ..models.transformer import _head_logits, moe_rows_computed
+from ..models.transformer import (
+    _head_logits,
+    moe_rows_computed,
+    moe_step_visits,
+)
 from ..ops import gather_pages, pool_shape, scatter_pages
 from .config import SpeculationConfig
 from .spec_decode import SpecDecoder
@@ -197,7 +201,9 @@ _m_moe_rows_computed = Counter(
     "serve_moe_rows_computed",
     "Rows the expert products of the dispatched programs computed, over "
     "every expert layer: experts x the program's tokens where nothing can "
-    "drop, rows x experts x capacity else; static in a program's shape.")
+    "drop, rows x experts x capacity else, static in a program's shape; "
+    "for a decode step that visits the experts its live rows chose, the "
+    "experts visited x the step's rows, read back with the span.")
 _m_moe_rows_routed = Counter(
     "serve_moe_rows_routed",
     "Rows the live tokens of the dispatched programs were routed to, over "
@@ -213,11 +219,12 @@ _m_moe_choices = Counter(
 _m_moe_experts = Counter(
     "serve_moe_expert_steps",
     "Experts x expert layers x decode steps of the spans read back, by "
-    "state (touched: at least one live row chose the expert, counted on "
+    "state (touched: at least one live row chose the expert, so the step's "
+    "product visited it: the length of the kernel's own list, added up on "
     "the device and read with the tokens; held: every expert the layers "
-    "hold). Counted where the router runs in the layer (`router_input` "
-    "\"layer\"): touched over held is what a product that skips the "
-    "unchosen experts would still read.")
+    "hold). Counted in every family whose decode step visits (no sharded "
+    "mesh): held - touched is the experts whose weights a step left "
+    "unread.")
 _experts_touched = _m_moe_experts.labels(state="touched")
 _experts_held = _m_moe_experts.labels(state="held")
 _choices_all = _m_moe_choices.labels(kind="all")
@@ -1002,6 +1009,13 @@ class InferenceEngine:
         hidden = not name.endswith("readback") and self._device_busy()
         (self._hidden_ns if hidden else self._phase_ns)[name] += ns
 
+    @property
+    def _steps_visit(self) -> bool:
+        """Whether a decode step of this engine visits the experts its live
+        rows chose and no others (models/stack.py `_experts`), so that its
+        spans count them."""
+        return moe_step_visits(self.cfg, self.mesh)
+
     def _window_ring(self) -> int:
         """Pages of a sequence's ring in the window page space (0: the
         model has none), and what the two configs must agree on there."""
@@ -1125,13 +1139,14 @@ class InferenceEngine:
             the span before ended on and a [B] mask of the slots that take
             the host's `tokens` / `positions` instead (new since that span;
             None: all of them). -> seq/logps [n_steps, B], the pool, the
-            state, and the (tokens, positions) this span ended on. Where
-            the live tokens' choices of experts are counted
-            (`cfg.counts_choices`) logps has one row more, whose first two
-            entries are the span's counts: they come back in the readback
-            the tokens come back in. Where the experts that the live rows
-            chose are counted (`cfg.router_input` "layer") the row's first
-            entry is that count."""
+            state, and the (tokens, positions) this span ended on. What
+            the device counts comes back in the readback the tokens come
+            back in, a row of logps more for each: where the live tokens'
+            choices of experts are counted (`cfg.counts_choices`), a row
+            whose first two entries are the span's counts; then, where the
+            steps visit the experts their live rows chose
+            (`self._steps_visit`), a row whose first entry is how many
+            they visited, over the span's steps and layers."""
             window_tables = None
             if cfg.window_paged:
                 page_tables, window_tables = page_tables
@@ -1177,7 +1192,7 @@ class InferenceEngine:
             state = state or {}
             if cfg.counts_choices:
                 state = {**state, "choices": jnp.zeros((2,), jnp.float32)}
-            elif cfg.router_input == "layer":
+            if self._steps_visit:
                 state = {**state, "touched": jnp.zeros((1,), jnp.float32)}
             (tokens, positions, k_pages, v_pages, state), (seq, logps) = \
                 jax.lax.scan(
@@ -2860,14 +2875,13 @@ class InferenceEngine:
             n = len(span.members)
             self._count_slot_steps(n, span.steps)
             # by keyword, and only where there are any: callers that wrap
-            # this method know its four positional arguments
-            counted = ({"choices": logps[span.steps, :2]}
-                       if self.cfg.counts_choices else {})
-            if self.cfg.router_input == "layer":
-                _experts_touched.inc(float(logps[span.steps, 0]))
-                _experts_held.inc(
-                    span.steps * self.cfg.num_experts
-                    * self.cfg.second_halves.count("moe"))
+            # this method know its four positional arguments; the counts'
+            # rows follow the steps' in the order the program appends them
+            counted, row = {}, span.steps
+            if self.cfg.counts_choices:
+                counted["choices"], row = logps[row, :2], row + 1
+            if self._steps_visit:
+                counted["touched"] = float(logps[row, 0])
             self._count_moe_rows(self.ecfg.max_batch_size, 1, n, span.steps,
                                  **counted)
             self._tps_committed += self._commit_span(span, seq, logps)
@@ -2959,21 +2973,30 @@ class InferenceEngine:
         return committed
 
     def _count_moe_rows(self, rows: int, row_tokens: int, live: int,
-                        times: int = 1, choices=None) -> None:
+                        times: int = 1, choices=None, touched=None) -> None:
         """A program over `rows` rows of `row_tokens` tokens, `live` of
         them real, dispatched `times` over (a span's steps): each of its
         expert layers computed what `moe_rows_computed` says of the form
         the program took (every expert over the program's tokens, or
         padded slots under a capacity) for live x k routed. On the host,
-        from the program's static shape. `choices` (zero, held): what the
-        device counted for a layer that holds a share of the experts; the
-        rows routed to THIS layer's products are then the held ones."""
+        from the program's static shape, but for `touched`: the experts
+        the steps of a span visited, over its steps and layers, which the
+        device counted (`self._steps_visit`): each ran over the step's
+        rows, and the others' weights were not read. `choices` (zero,
+        held): what the device counted for a layer that holds a share of
+        the experts; the rows routed to THIS layer's products are then the
+        held ones."""
         layers = self.cfg.second_halves.count("moe")
         if not layers:
             return
-        _m_moe_rows_computed.inc(
-            times * layers
-            * moe_rows_computed(self.cfg, rows, row_tokens, self.mesh))
+        if touched is None:
+            _m_moe_rows_computed.inc(
+                times * layers
+                * moe_rows_computed(self.cfg, rows, row_tokens, self.mesh))
+        else:
+            _m_moe_rows_computed.inc(touched * rows)
+            _experts_touched.inc(touched)
+            _experts_held.inc(times * layers * self.cfg.num_experts)
         chosen = times * layers * live * self.cfg.num_selected_experts
         if not self.cfg.counts_choices:
             _m_moe_rows_routed.inc(chosen)

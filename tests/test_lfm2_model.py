@@ -290,12 +290,14 @@ def test_a_slot_is_reused_and_the_experts_rows_are_counted(model):
     _, _, cfg, params = model
     before = common.counters()
     eng = engine_for(cfg, params)
-    programs = []  # (rows, row_tokens, live, times) of every dispatch
+    # (rows, row_tokens, live, times, experts the steps visited) of every
+    # dispatch; the last is None but for a decode span
+    programs = []
     count = eng._count_moe_rows
 
-    def counted(rows, row_tokens, live, times=1):
-        programs.append((rows, row_tokens, live, times))
-        count(rows, row_tokens, live, times)
+    def counted(rows, row_tokens, live, times=1, touched=None):
+        programs.append((rows, row_tokens, live, times, touched))
+        count(rows, row_tokens, live, times, touched=touched)
 
     eng._count_moe_rows = counted
     try:
@@ -318,17 +320,22 @@ def test_a_slot_is_reused_and_the_experts_rows_are_counted(model):
     assert routed >= 8 * 2 * (sum(map(len, ps)) + sum(budgets) - 3)
     assert computed > routed
     # the exact count: every program here is dropless (capacity_factor is
-    # experts / k), so each of the 8 expert layers ran its 8 experts over
-    # the program's own rows x tokens: decode spans of 2 slots x 1, chunks
-    # of 1 x 16, buckets of rows x 8 or 16
-    assert {(r, t) for r, t, _, _ in programs} >= {(2, 1), (1, 16)}
+    # experts / k), so each of the 8 expert layers of a chunk (1 x 16) or a
+    # bucket (rows x 8 or 16) ran its 8 experts over the program's own rows
+    # x tokens; a decode span of 2 slots x 1 ran the experts its steps
+    # VISITED (those a live row chose: at least the 2 of one row, at most
+    # the 4 of both, a step and layer) over its 2 rows
+    assert {(r, t) for r, t, *_ in programs} >= {(2, 1), (1, 16)}
+    spans = [p for p in programs if p[4] is not None]
+    assert spans and all((r, t) == (2, 1) for r, t, *_ in spans)
+    for _, _, live, times, touched in spans:
+        assert 8 * times * 2 * min(live, 1) <= touched <= 8 * times * 2 * live
     assert computed == sum(
         times * 8 * moe_rows_computed(cfg, rows, row_tokens)
-        for rows, row_tokens, _, times in programs)
-    assert computed == 8 * 8 * sum(
-        times * rows * row_tokens for rows, row_tokens, _, times in programs)
+        for rows, row_tokens, _, times, touched in programs
+        if touched is None) + sum(2 * p[4] for p in spans)
     assert routed == 8 * 2 * sum(
-        times * live for _, _, live, times in programs)
+        times * live for _, _, live, times, _ in programs)
     for r, p in zip(reqs, ps):
         want = reference_logprobs(model, p, r.output)
         picked = want[np.arange(len(r.output)), r.output]

@@ -18,6 +18,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from ray_tpu.ops import (
+    expert_step,
     flash_attention,
     gdn_chunk,
     gdn_step,
@@ -127,6 +128,16 @@ def _mla_chunk_past8192(total):
             [((256, MLA_H, MLA_W), BF16), _MLA_POOL, ((576,), I32)], 1)
 
 
+def _expert_step(E, D_, F_, act=jax.nn.silu):
+    """A decode step's expert product at a cell's shape: 64 rows against
+    layer 1 of a segment's stacks of E experts [D_, F_]."""
+    return (lambda x, c, hit, w_in, w_gate, w_out: expert_step(
+                x, c, hit, w_in, w_gate, w_out, 1, act)[0],
+            [((64, D_), BF16), ((64, E), jnp.float32), ((E,), jnp.bool_),
+             ((2, E, D_, F_), BF16), ((2, E, D_, F_), BF16),
+             ((2, E, F_, D_), BF16)], 1)
+
+
 # name -> (op, [(shape, dtype)], fewest tpu_custom_calls in the program)
 CASES = {
     "flash_fwd_t2048": (_flash_fwd, _qkv(2048), 1),
@@ -199,6 +210,13 @@ CASES = {
             q, kp, vp, pt, 8192, 8448, 5, window=4096),
         [((256, 28, D), BF16)] + [(pool_shape(9, 8193, PAGE, 4, D), BF16)] * 2
         + [((1024,), I32)], 1),
+    # the expert product of a decode step at the four cells' shapes: blocks
+    # of the whole model width by 512, 896, 768 and 256 of the expert width
+    "moe_step_8_experts_4096x14336": _expert_step(8, 4096, 14336),
+    "moe_step_32_experts_2048x1792": _expert_step(32, 2048, 1792),
+    "moe_step_64_experts_2560x768_reglu": _expert_step(
+        64, 2560, 768, jax.nn.relu),
+    "moe_step_16_experts_6144x2048": _expert_step(16, 6144, 2048),
     "rms_norm_2048x4096": (
         rms_norm, [((2048, D_MODEL), BF16), ((D_MODEL,), BF16)], 1),
 }
@@ -528,3 +546,17 @@ def test_the_window_and_full_cells_programs_hold_two_page_spaces(
     assert len(re.findall(r"%%%s(\.\d+)? = " % kernel, text)) == 1
     assert len(re.findall(r"%%%s_window(\.\d+)? = " % kernel, text)) == 3
     assert not re.search(r"= bf16\[\d+,(1,)?\d+,16,512\]\S* copy\(", text)
+    steps = re.findall(r"%moe_step(?:\.\d+)? = [^\n]*", text)
+    if program == "chunk_prefill_256":
+        assert not steps  # a chunk's 256 rows touch every expert
+        return
+    # a decode step's experts: one kernel a layer of the scanned period,
+    # handed the segment's three stacks whole (operands of the loop, not
+    # slices of them), and nothing copies or slices ONE layer's experts
+    assert len(steps) == 4
+    for call in steps:
+        assert len(re.findall(r"bf16\[3,64,2560,768\]\{3,2,1,0\}", call)) == 2
+        assert len(re.findall(r"bf16\[3,64,768,2560\]\{3,2,1,0\}", call)) == 1
+    assert not re.search(
+        r"= bf16\[(1,)?64,(2560,768|768,2560)\]\S* "
+        r"(copy|dynamic-slice|slice|fusion)\(", text)
