@@ -77,9 +77,13 @@ byte of the others (ops/moe.py, models/transformer.py `moe_ffn_step`): a
 step's time is its weights' bytes, and a few live rows choose a few
 experts. Its kernel reads a layer's experts where they lie, so `run_stack`
 hands such a program a segment's `w_in` / `w_gate` / `w_out` stacks whole,
-beside the layer's index, and not as the layer scan's slices. A chunk, a
-bucket, `Verify`, `forward` and a sharded mesh run every expert over their
-tokens (`_moe_ffn`), as they did; no flag, option or model's name decides.
+beside the layer's index, and not as the layer scan's slices. A BUCKET or
+a prefill CHUNK (a `Seq` that keeps its keys: the mode knows which rows hold
+a token) touches every expert, and runs each over the rows that chose it
+and no others (`moe_ffn_groups`, the same stacks handed whole): E / k times
+fewer rows than every expert over every row. `Verify`, `forward` and a
+sharded mesh run every expert over their tokens (`_moe_ffn`), as they did;
+no flag, option or model's name decides.
 
 Differential attention (window / full / cross) rides on the plain kernels:
 a KV pair is stored as one row [k1 ; k2] (and [v1 ; v2]) of twice the head
@@ -128,7 +132,9 @@ from .transformer import (
     _norm,
     _prologue,
     _qkv,
+    moe_ffn_groups,
     moe_ffn_step,
+    moe_seq_groups,
     moe_step_visits,
 )
 
@@ -412,6 +418,12 @@ class _Mode:
         the mode knows; None for every other program."""
         return None
 
+    def kept_rows(self, B, T):
+        """bool [B,T]: which rows hold a token, for a program of the serve
+        path that keeps its keys (a bucket, a prefill chunk); None for
+        every other program."""
+        return None
+
 
 class Seq(_Mode):
     """Whole sequences [B, T], right-padded to T with `n_valid` [B] real
@@ -486,6 +498,11 @@ class Seq(_Mode):
         """bool [B,T]: the tokens whose choice of experts is counted."""
         valid = self.valid(T)
         return jnp.ones((B, T), bool) if valid is None else valid[..., 0]
+
+    def kept_rows(self, B, T):
+        if self.keep or self.chunk is not None:
+            return self.counted(B, T)
+        return None
 
     def _lengths(self, B, T):
         return jnp.full((B,), T) if self.n_valid is None else self.n_valid
@@ -1068,12 +1085,17 @@ def _experts(h, lp, cfg, gate, mode, carry):
     """The expert layer over the normed rows h [B,T,D] in the form the
     program's static shape allows (models/transformer.py `_moe_ffn`): a
     STEP, where the mode knows its live rows, visits the experts they chose
-    and no others (`lp["experts"]`, which `run_stack` hands such a program);
-    every other program runs the forms it ran. -> (y, carry with what it
-    counts)."""
-    if "experts" in lp:
-        y, ids, visited = moe_ffn_step(h, lp, cfg, gate, mode.live_rows(1))
+    and no others; a bucket or a chunk, where the mode knows the rows that
+    hold a token, runs each expert over the rows that chose it
+    (`lp["experts"]`, which `run_stack` hands such programs); every other
+    program runs the forms it ran. -> (y, carry with what it counts)."""
+    live = mode.live_rows(h.shape[1])
+    if "experts" in lp and live is not None:
+        y, ids, visited = moe_ffn_step(h, lp, cfg, gate, live)
         carry = _count_touched(carry, visited)
+    elif "experts" in lp:
+        y, ids = moe_ffn_groups(h, lp, cfg, gate,
+                                mode.kept_rows(*h.shape[:2]))
     elif cfg.counts_choices:  # a share layer: where the choices fell
         y, _, ids = _moe_ffn_dropless_ids(h, lp, cfg, gate)
     else:
@@ -1125,16 +1147,20 @@ def run_stack(layers, x, cfg: ModelConfig, mode, carry):
     if isinstance(layers, dict):
         layers = [(layers,)]
     seen = dict.fromkeys(_COUNTED, 0)
-    lifts = (mode.live_rows(x.shape[1]) is not None
-             and moe_step_visits(cfg, _current_mesh()))
+    B, T = x.shape[:2]
+    mesh = _current_mesh()
+    lifts = ((mode.live_rows(T) is not None and moe_step_visits(cfg, mesh))
+             or (mode.kept_rows(B, T) is not None
+                 and moe_seq_groups(cfg, B, T, mesh)))
     for (first, kinds, repeats), seg in zip(cfg.segments(), layers):
         per = {k: kinds.count(k) for k in seen}
         stacks = (None,) * len(kinds)
         if lifts:
-            # a step's kernel reads a layer's experts where they lie, in the
-            # segment's stacks (a slice, the scan's or `a[0]`, before a
-            # custom call is a copy of every expert, every step): they go
-            # to the layers whole, beside the layer's index in them
+            # the kernels of a step, a bucket and a chunk read a layer's
+            # experts where they lie, in the segment's stacks (a slice, the
+            # scan's or `a[0]`, before a custom call is a copy of every
+            # expert, every call): they go to the layers whole, beside the
+            # layer's index in them
             stacks = tuple(
                 {n: lp[n] for n in _EXPERT_LEAVES}
                 if cfg.second_halves[first + i] == "moe" else None

@@ -30,8 +30,11 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import (
     apply_rope,
+    expert_groups,
     expert_step,
     flash_attention,
+    groups_fit,
+    groups_rows_bound,
     layer_norm,
     rms_norm,
     rope_frequencies,
@@ -302,14 +305,36 @@ def moe_step_visits(cfg, mesh) -> bool:
     return "moe" in cfg.second_halves and _moe_dropless(cfg, 1, mesh)
 
 
-def moe_rows_computed(cfg, B: int, T: int, mesh=None) -> int:
+def moe_seq_groups(cfg, B: int, T: int, mesh) -> bool:
+    """Whether a program of the serve path over B rows of T tokens that
+    keeps its keys (a bucket, a prefill chunk: the mode knows which rows
+    hold a token) runs its experts as `moe_ffn_groups`, each expert over
+    the rows that chose it and no others: wherever the model has experts,
+    the program dispatches nothing and its rows lie whole in the kernel's
+    fast memory (`ops/moe.py groups_fit`). The rule is the program's static
+    shape and mesh, as `moe_step_visits` is; `run_stack` and the engine's
+    counters both ask it."""
+    return ("moe" in cfg.second_halves and _moe_dropless(cfg, T, mesh)
+            and groups_fit(B * T, cfg.d_model, cfg.num_experts,
+                           cfg.expert_ff, jnp.dtype(cfg.dtype).itemsize))
+
+
+def moe_rows_computed(cfg, B: int, T: int, mesh=None, tokens=None) -> int:
     """Expert rows ONE expert layer computes for a program of B rows of T
     tokens, whichever form `_moe_ffn` takes: every expert over the
     program's own B * T tokens when it dispatches nothing, else
     B x experts x capacity padded slots. The engine's
     `serve_moe_rows_computed` counts with it, but for a decode step that
     visits (`moe_step_visits`): its rows are the experts VISITED x B, which
-    the device counts and the span's readback brings."""
+    the device counts and the span's readback brings. `tokens`: how many of
+    the rows hold a token, for a bucket or a chunk of the serve path
+    (`moe_seq_groups`): its rows are the passes of the experts its tokens
+    chose, of which the host knows a BOUND (`ops/moe.py groups_rows_bound`:
+    never less than the kernel's passes cover)."""
+    if tokens is not None and moe_seq_groups(cfg, B, T, mesh):
+        # a share layer: a token's choices that can fall on a held expert
+        k = min(cfg.num_selected_experts, cfg.num_experts)
+        return groups_rows_bound(B * T, cfg.num_experts, k, tokens)
     per_row = T if _moe_dropless(cfg, T, mesh) else moe_capacity(cfg, T)
     return cfg.num_experts * B * per_row
 
@@ -397,14 +422,19 @@ def _moe_ffn(x, lp, cfg, gate=None):
     where the layer scored another tensor than the one the experts compute
     on, x (None: x is scored, here).
 
-    A decode STEP of the serve path does not come here: its mode knows
-    which rows hold a sequence, a few rows touch a few experts, and the
-    step's time is the experts' bytes, so models/stack.py hands it to
-    `moe_ffn_step` (the same sum; the terms of the experts that no live
-    row chose are left out, and their weights unread). A chunk's 256 rows
-    touch every expert (1 - (58 / 64)^256), training and `Verify` know no
-    live rows: every caller without a live mask keeps the forms below. No
-    flag, option or model's name decides: `moe_step_visits`."""
+    The serve path's own programs do not come here; models/stack.py
+    `_experts` hands them to the two forms that read the experts where
+    they lie (ops/moe.py), the same sum with the terms left out that are
+    zero. A decode STEP, whose mode knows which rows hold a sequence: a few
+    rows touch a few experts and the step's time is the experts' bytes, so
+    `moe_ffn_step` visits the experts a live row chose and runs each over
+    all rows (`moe_step_visits`). A BUCKET or a prefill CHUNK, whose mode
+    knows which rows hold a token: 256 rows touch every expert, and each
+    run over all of them multiplies E / k times the rows that chose it, so
+    `moe_ffn_groups` runs each expert over its own rows (`moe_seq_groups`).
+    Training, `Verify`, the plain forward (which may be differentiated) and
+    every program on a sharded mesh keep the forms below. No flag, option
+    or model's name decides."""
     mesh = _current_mesh()
     if _moe_dropless(cfg, x.shape[1], mesh):
         return _moe_ffn_dropless(x, lp, cfg, gate)
@@ -452,9 +482,11 @@ def _moe_ffn_dropless_ids(x, lp, cfg, gate=None):
     out[n] = sum_e c[n, e] * expert_e(x_n), what the padded forms compute
     too; an expert is the gated FFN `cfg.activation` names (`_GATE_ACT`).
     The expert axis leads ([E, N, F]) so the weights are read as they lie;
-    x is shared by the experts and never copied E times. Chunks, buckets,
-    `Verify` and training rows take this form; a decode step of the serve
-    path, which knows its live rows, takes `moe_ffn_step` (`_moe_ffn`).
+    x is shared by the experts and never copied E times. `Verify`, training
+    rows and the plain forward take this form; of the serve path's programs
+    a decode step, which knows its live rows, takes `moe_ffn_step`, and a
+    bucket or a chunk, which knows the rows that hold a token,
+    `moe_ffn_groups` (`_moe_ffn`): the same sum at the same rounding points.
 
     A layer that holds a share of the experts (`cfg.num_experts` of
     `cfg.experts_routed`, from `cfg.experts_first`) routes over all of
@@ -513,6 +545,37 @@ def moe_ffn_step(x, lp, cfg, gate, live):
             out = out + identity.reshape(B, 1) * xs.astype(jnp.float32)
         out = out.astype(dtype).reshape(B, 1, D)
         return constrain(out, ("batch", "seq", "embed")), expert_ids, visited
+
+
+def moe_ffn_groups(x, lp, cfg, gate, held):
+    """`_moe_ffn_dropless_ids` for a program of many tokens x [B,T,D] whose
+    rows `held` (bool [B,T]) hold a token: the same gating, the same float32
+    combine and the same rounding points, each expert over the rows that
+    chose it and no others (ops/moe.py `expert_groups`; the terms left out
+    are zero there). A row of padding chooses too, joins no group, and its
+    output is the identity experts' part alone (nobody reads it).
+    `lp["experts"]`, identity experts and the held slice of a share layer:
+    as in `moe_ffn_step`. -> (out, expert_ids [B,T,k])."""
+    dtype = x.dtype
+    B, T, D = x.shape
+    E, first = cfg.num_experts, cfg.experts_first
+    with jax.named_scope("route"):
+        c, identity, _, expert_ids = _moe_combine(x, lp, cfg, gate)
+        chosen = jnp.any(
+            jax.nn.one_hot(expert_ids, cfg.router_width, dtype=bool), axis=2)
+        member = (chosen[..., first:first + E] & held[..., None])
+    xs = x.reshape(B * T, D)
+    stacks, layer = lp["experts"]
+    with jax.named_scope("experts"):
+        out = expert_groups(
+            xs, c.reshape(B * T, E), member.reshape(B * T, E),
+            stacks["w_in"], stacks["w_gate"], stacks["w_out"], layer,
+            _GATE_ACT[cfg.activation])
+    with jax.named_scope("combine"):
+        if cfg.experts_zero:
+            out = out + identity.reshape(B * T, 1) * xs.astype(jnp.float32)
+        out = out.astype(dtype).reshape(B, T, D)
+        return constrain(out, ("batch", "seq", "embed")), expert_ids
 
 
 def _moe_ffn_dense(x, lp, cfg, gate=None):

@@ -20,7 +20,12 @@ from .mla_attention import (  # noqa: F401
     latent_attention_decode,
     write_latent_then_attend,
 )
-from .moe import expert_step  # noqa: F401
+from .moe import (  # noqa: F401
+    expert_groups,
+    expert_step,
+    groups_fit,
+    groups_rows_bound,
+)
 from .norm import layer_norm, rms_norm, rms_norm_reference  # noqa: F401
 from .rope import apply_rope, rope_frequencies  # noqa: F401
 from .paged_attention import (  # noqa: F401

@@ -2346,8 +2346,10 @@ class InferenceEngine:
         # raises, the caller's failure path can still free every page
         # safely because no request has been published to _ready yet.
         with _prefill_phase("readback"):
-            logits_host = self._take_choices(
-                np.asarray(logits), Bpad, bucket, sum(g[2] for g in group))
+            live = sum(g[2] for g in group)
+            logits_host = self._take_choices(  # a dummy row holds a token
+                np.asarray(logits), Bpad, bucket, live,
+                live + Bpad - len(group))
             firsts = [
                 _sample_host(logits_host[i], req.temperature,
                              req.top_p, req.top_k)
@@ -2626,7 +2628,7 @@ class InferenceEngine:
         _m_chunk_rows.inc(C)
         _m_chunk_padding_tokens.inc(C - len(toks))
         if not is_last:  # the last chunk's are counted with its logits
-            self._count_moe_rows(1, C, len(toks))
+            self._count_moe_rows(1, C, len(toks), held=len(toks))
         chunk_kv = (*kv, start) if streaming else None
         st.next_chunk += 1
         if not is_last and self.prefix is not None:
@@ -2663,7 +2665,8 @@ class InferenceEngine:
             logits_host = np.asarray(logits)
         # where the device counts choices, those of every chunk the request
         # ran came with these logits
-        logits_host = self._take_choices(logits_host, 1, C, len(toks))
+        logits_host = self._take_choices(logits_host, 1, C, len(toks),
+                                         len(toks))
         first = _sample_host(logits_host, req.temperature,
                              req.top_p, req.top_k)
         self._note_first_token(req, tracing.now_ns())
@@ -2973,26 +2976,30 @@ class InferenceEngine:
         return committed
 
     def _count_moe_rows(self, rows: int, row_tokens: int, live: int,
-                        times: int = 1, choices=None, touched=None) -> None:
+                        times: int = 1, choices=None, touched=None,
+                        held=None) -> None:
         """A program over `rows` rows of `row_tokens` tokens, `live` of
         them real, dispatched `times` over (a span's steps): each of its
         expert layers computed what `moe_rows_computed` says of the form
-        the program took (every expert over the program's tokens, or
-        padded slots under a capacity) for live x k routed. On the host,
-        from the program's static shape, but for `touched`: the experts
-        the steps of a span visited, over its steps and layers, which the
-        device counted (`self._steps_visit`): each ran over the step's
-        rows, and the others' weights were not read. `choices` (zero,
-        held): what the device counted for a layer that holds a share of
-        the experts; the rows routed to THIS layer's products are then the
-        held ones."""
+        the program took for live x k routed. On the host, from the
+        program's static shape, but for two forms whose rows are data.
+        `touched`: the experts the steps of a span visited, over its steps
+        and layers, which the device counted (`self._steps_visit`): each
+        ran over the step's rows, and the others' weights were not read.
+        `held`: the tokens the rows of a bucket or a chunk hold (a batch's
+        dummy rows among them): where such a program runs each expert over
+        the rows that chose it (`moe_seq_groups`), its rows are the passes
+        of the kernel, of which the host counts the bound (never less).
+        `choices` (zero, held): what the device counted for a layer that
+        holds a share of the experts; the rows routed to THIS layer's
+        products are then the held ones."""
         layers = self.cfg.second_halves.count("moe")
         if not layers:
             return
         if touched is None:
             _m_moe_rows_computed.inc(
-                times * layers
-                * moe_rows_computed(self.cfg, rows, row_tokens, self.mesh))
+                times * layers * moe_rows_computed(
+                    self.cfg, rows, row_tokens, self.mesh, tokens=held))
         else:
             _m_moe_rows_computed.inc(touched * rows)
             _experts_touched.inc(touched)
@@ -3008,15 +3015,16 @@ class InferenceEngine:
             _choices_zero.inc(float(choices[0]))
 
     def _take_choices(self, logits_host: np.ndarray, rows: int,
-                      row_tokens: int, live: int) -> np.ndarray:
+                      row_tokens: int, live: int, held: int) -> np.ndarray:
         """A prefill program's logits as they came back -> the logits
-        alone, the program's expert rows counted. Where the device counted
-        its tokens' choices of experts (`cfg.counts_choices`) they are the
-        last two entries (of row 0, for a batch)."""
+        alone, the program's expert rows counted (`held`: the tokens its
+        rows hold, dummy rows' too). Where the device counted its tokens'
+        choices of experts (`cfg.counts_choices`) they are the last two
+        entries (of row 0, for a batch)."""
         if not self.cfg.counts_choices:
-            self._count_moe_rows(rows, row_tokens, live)
+            self._count_moe_rows(rows, row_tokens, live, held=held)
             return logits_host
-        self._count_moe_rows(rows, row_tokens, live,
+        self._count_moe_rows(rows, row_tokens, live, held=held,
                              choices=logits_host[..., -2:].reshape(-1, 2)[0])
         return logits_host[..., :-2]
 

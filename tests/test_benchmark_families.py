@@ -686,3 +686,29 @@ def test_the_decode_roofline_counts_rows_once_and_the_choice_shares_their_counte
     assert common.load_reader("moe_held_choice_share")(ctx) == 2.0
     ctx["run"]["requests"] = [{"prompt_len": 8292}, {"prompt_len": 8092}]
     assert common.load_reader("prefix_hit_token_share")(ctx) == 50.0
+
+
+@pytest.mark.parametrize("kernel, operands", [
+    ("moe_step", "bf16[64,2560]{1,0} %x, bf16[3,64,2560,768]{3,2,1,0} %w"),
+    ("moe_groups", "bf16[256,2560]{1,0} %x, bf16[3,64,2560,768]{3,2,1,0} %w"),
+    ("moe_groups", "bf16[256,4096]{1,0} %x, bf16[8,8,4096,14336]{3,2,1,0} %w"),
+])
+def test_the_expert_kernels_names_files_join_the_moe_ffn_group(
+        kernel, operands):
+    """benchmark/trace_names/moe_step.json and moe_groups.json: a step's
+    and a chunk's expert kernels are counted in `moe_ffn`, once each,
+    whatever else in the group matches their operands' shapes; nothing the
+    accepted file holds is removed."""
+    with open(os.path.join(common.HERE, "trace_names.json")) as f:
+        base = json.load(f)["groups"]
+    merged = trace_reduce.load_names()["groups"]
+    for group, entries in base.items():
+        assert merged[group][:len(entries)] == entries
+    call = f"%{kernel}.12 = f32[256,2560]{{1,0}} custom-call({operands})"
+    trace = {"busy_s": 10.0, "ops": {
+        call: [2.5, 48],
+        "%paged_chunk.1 = bf16[256,28,128]{2,1,0} custom-call(s32[9]{0} %a)":
+            [1.0, 12]},
+        "modules": {}, "module_ops": {}}
+    assert trace_reduce.group_seconds(trace, "moe_ffn") == (2.5, 48.0)
+    assert trace_reduce.group_share(trace, "moe_ffn") == 25.0

@@ -290,14 +290,15 @@ def test_a_slot_is_reused_and_the_experts_rows_are_counted(model):
     _, _, cfg, params = model
     before = common.counters()
     eng = engine_for(cfg, params)
-    # (rows, row_tokens, live, times, experts the steps visited) of every
-    # dispatch; the last is None but for a decode span
+    # (rows, row_tokens, live, times, experts the steps visited, tokens
+    # the rows hold) of every dispatch; the last but one is None but for a
+    # decode span, the last None but for a bucket or a chunk
     programs = []
     count = eng._count_moe_rows
 
-    def counted(rows, row_tokens, live, times=1, touched=None):
-        programs.append((rows, row_tokens, live, times, touched))
-        count(rows, row_tokens, live, times, touched=touched)
+    def counted(rows, row_tokens, live, times=1, touched=None, held=None):
+        programs.append((rows, row_tokens, live, times, touched, held))
+        count(rows, row_tokens, live, times, touched=touched, held=held)
 
     eng._count_moe_rows = counted
     try:
@@ -321,21 +322,29 @@ def test_a_slot_is_reused_and_the_experts_rows_are_counted(model):
     assert computed > routed
     # the exact count: every program here is dropless (capacity_factor is
     # experts / k), so each of the 8 expert layers of a chunk (1 x 16) or a
-    # bucket (rows x 8 or 16) ran its 8 experts over the program's own rows
-    # x tokens; a decode span of 2 slots x 1 ran the experts its steps
+    # bucket (rows x 8 or 16) ran each expert over the rows that chose it,
+    # in passes whose rows the host bounds from the tokens the rows hold
+    # (`moe_rows_computed(tokens=)`: never more than its 8 experts over
+    # every row); a decode span of 2 slots x 1 ran the experts its steps
     # VISITED (those a live row chose: at least the 2 of one row, at most
     # the 4 of both, a step and layer) over its 2 rows
     assert {(r, t) for r, t, *_ in programs} >= {(2, 1), (1, 16)}
     spans = [p for p in programs if p[4] is not None]
     assert spans and all((r, t) == (2, 1) for r, t, *_ in spans)
-    for _, _, live, times, touched in spans:
+    for _, _, live, times, touched, _ in spans:
         assert 8 * times * 2 * min(live, 1) <= touched <= 8 * times * 2 * live
+    seqs = [p for p in programs if p[4] is None]
+    assert seqs and all(held is not None and live <= held <= rows * t
+                        for rows, t, live, _, _, held in seqs)
     assert computed == sum(
-        times * 8 * moe_rows_computed(cfg, rows, row_tokens)
-        for rows, row_tokens, _, times, touched in programs
-        if touched is None) + sum(2 * p[4] for p in spans)
+        times * 8 * moe_rows_computed(cfg, rows, row_tokens, tokens=held)
+        for rows, row_tokens, _, times, _, held in seqs) + sum(
+            2 * p[4] for p in spans)
+    for rows, row_tokens, live, _, _, held in seqs:
+        assert 2 * live <= moe_rows_computed(
+            cfg, rows, row_tokens, tokens=held) <= 8 * rows * row_tokens
     assert routed == 8 * 2 * sum(
-        times * live for _, _, live, times, _ in programs)
+        times * live for _, _, live, times, *_ in programs)
     for r, p in zip(reqs, ps):
         want = reference_logprobs(model, p, r.output)
         picked = want[np.arange(len(r.output)), r.output]

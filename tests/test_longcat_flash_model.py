@@ -570,14 +570,16 @@ def decode_digest(name):
 # sha256 of the StableHLO text of `chunk_prefill_16` as the parent of PR 40
 # (efc7c3c) lowers it, taken as PARENT_DECODE was: telling the LATENT chunk
 # kernel where a chunk's tokens end (`attend_mla`) leaves `attend_full`, and
-# with it every other model's chunk program, the text it was
+# with it every other model's chunk program, the text it was (tiny-lfm2's is
+# PR 43's own since its chunks run each expert over the rows that chose it,
+# re-pinned as in tests/test_moe_step.py)
 PARENT_CHUNK = {
     "tiny-llama":
         "65aa9a2a4a2d78610995bbeb85057a7a7ba5bcd970484c412e36baafd7c94297",
     "tiny-moe":
         "d65450621c89b86d4231fe8c676e2f54dbb65460c538a623aefc78c05259d0b6",
     "tiny-lfm2":
-        "de080e28e0f11512d82f14000bdc64c87916f9de146e9777b9097e5d640f0114",
+        "3b026ad5d9e197d3d8acd16a16c9e677ca272364493bd756d4481ce07b2da2df",
 }
 
 

@@ -1,8 +1,11 @@
-"""The expert product of a decode step (ops/moe.py `expert_step`,
+"""The expert products that read the experts where they lie (ops/moe.py),
+against the form that runs every expert over every row
+(`_moe_ffn_dropless_ids`). A decode step (`expert_step`,
 models/transformer.py `moe_ffn_step`): the experts that a live row chose and
-no others, against the form that runs every expert
-(`_moe_ffn_dropless_ids`); which programs take it (a step that knows its
-live rows) and which keep the program they had; what the engine counts."""
+no others. A bucket or a prefill chunk (`expert_groups`, `moe_ffn_groups`):
+each expert over the rows that chose it and no others. Which programs take
+which (a step that knows its live rows, a `Seq` that keeps its keys) and
+which keep the program they had; what the engine counts."""
 
 import dataclasses
 import hashlib
@@ -164,6 +167,115 @@ def test_a_block_is_whole_lane_tiles_that_divide_the_width():
     assert moe.f_tile(8192, 128, 4, block_bytes=1) == 128  # one tile at least
 
 
+# -- a program of many tokens: each expert over the rows that chose it -------
+
+# rows of the program, and how many hold a token (the first ones)
+HELD = {"chunk-of-256-holding-1": ((1, 256), [1]),
+        "chunk-of-256-holding-96": ((1, 256), [96]),
+        "chunk-of-256-holding-256": ((1, 256), [256]),
+        "bucket-of-2x16-short-of-its-rows": ((2, 16), [5, 16])}
+
+
+def _rows(cfg, shape, seed=0):
+    """`_layer`'s leaves with x [B,T,D] rows in place of a step's."""
+    _, _, step_lp, whole_lp = _layer(cfg, seed)
+    ks = jax.random.split(jax.random.PRNGKey(seed + 11), 2)
+    return (jax.random.normal(ks[0], (*shape, D), jnp.float32),
+            jax.random.normal(ks[1], (*shape, D), jnp.float32),
+            step_lp, whole_lp)
+
+
+@pytest.mark.parametrize("held", list(HELD))
+@pytest.mark.parametrize("name", FORMULATIONS)
+def test_the_groups_are_the_dropless_sum_over_the_rows_that_hold_a_token(
+        name, held, kernel):
+    cfg, handed = _formulation(name)
+    shape, n_valid = HELD[held]
+    x, other, step_lp, whole_lp = _rows(cfg, shape)
+    gate = tr._moe_gate(other, whole_lp, cfg) if handed else None
+    mask = np.arange(shape[1])[None, :] < np.asarray(n_valid)[:, None]
+    want, _, want_ids = tr._moe_ffn_dropless_ids(x, whole_lp, cfg, gate)
+    run = jax.jit(lambda x, lp, m: tr.moe_ffn_groups(x, lp, cfg, gate, m))
+    got, ids = run(x, step_lp, jnp.asarray(mask))
+    np.testing.assert_array_equal(ids, want_ids)
+    np.testing.assert_allclose(np.asarray(got)[mask], np.asarray(want)[mask],
+                               atol=TOL, rtol=0)
+    if handed:
+        return  # the choice was made from `other`: x's padding is free
+    # a row of padding joins no group: whatever it holds, and whatever it
+    # would have chosen, no real row's output moves by a bit
+    noise = jnp.where(mask[..., None], x, 7.0 * x + 3.0)
+    again, _ = run(noise, step_lp, jnp.asarray(mask))
+    np.testing.assert_array_equal(np.asarray(again)[mask],
+                                  np.asarray(got)[mask])
+
+
+def _members(case, N, E):
+    rng = np.random.default_rng(5)
+    member = rng.random((N, E)) < 0.3
+    if case == "an-expert-nobody-chose":
+        member[:, [1, 6]] = False
+    elif case == "every-row-chose-one-expert":  # N rows: more than one pass
+        member[:] = False
+        member[:, 3] = True
+    elif case == "no-row-holds-a-token":
+        member[:] = False
+    elif case == "one-row":
+        member[:] = False
+        member[7, [0, 5]] = True
+    return member
+
+
+@pytest.mark.parametrize("case", [
+    "ragged", "an-expert-nobody-chose", "every-row-chose-one-expert",
+    "no-row-holds-a-token", "one-row"])
+@pytest.mark.parametrize("N", [16, 144, 256])
+def test_the_groups_kernel_is_the_xla_form_over_the_members(case, N, kernel):
+    """`expert_groups` alone: the kernel against its XLA form (every expert
+    over every row, times the combine's zeros), every row; rows that are
+    no expert's member read zero. 144 rows: a pass of 128 and a ragged one;
+    256 rows on one expert: two full passes."""
+    ks = jax.random.split(jax.random.PRNGKey(2), 5)
+    E = 8
+    w_in, w_gate = (jax.random.normal(k, (LAYERS, E, D, F)) * 0.1
+                    for k in ks[:2])
+    w_out = jax.random.normal(ks[2], (LAYERS, E, F, D)) * 0.1
+    x = jax.random.normal(ks[3], (N, D))
+    c = jax.random.uniform(ks[4], (N, E))
+    member = _members(case, N, E)
+    args = (x, c, jnp.asarray(member), w_in, w_gate, w_out, LAYER)
+    got = jax.jit(lambda *a: moe.expert_groups(*a, jax.nn.silu))(*args)
+    want = moe.expert_groups(*args, jax.nn.silu, force_xla=True)
+    np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
+    assert not np.asarray(got)[~member.any(axis=1)].any()
+    # the engine's count is a bound on what the passes cover
+    rows = min(128, N)
+    covered = int((-(-member.sum(axis=0) // rows) * rows).sum())
+    tokens, k = int(member.any(axis=1).sum()), int(member.sum(axis=1).max())
+    assert covered <= moe.groups_rows_bound(N, E, max(k, 1), tokens)
+
+
+@pytest.mark.parametrize("E, k, N", [(64, 6, 256), (8, 2, 256), (16, 12, 256),
+                                     (32, 4, 64), (64, 6, 16), (8, 2, 512)])
+def test_the_rows_counted_for_a_grouped_program_are_never_fewer(E, k, N):
+    """`groups_rows_bound` against the passes of drawn groups, from one
+    token held to all, every token choosing k experts or (a share layer)
+    up to k: the host's count never falls short of the kernel's rows, and
+    never passes every expert over every pass."""
+    rng = np.random.default_rng(E + k + N)
+    rows = min(128, N)
+    for tokens in (1, 2, N // 3, N - 1, N):
+        for skew in (1.0, 8.0):
+            p = rng.random(E) ** skew
+            picks = [rng.choice(E, size=rng.integers(1, k + 1) if E == 16
+                                else k, replace=False, p=p / p.sum())
+                     for _ in range(tokens)]
+            held = np.bincount(np.concatenate(picks), minlength=E)
+            covered = int((-(-held // rows) * rows).sum())
+            bound = moe.groups_rows_bound(N, E, k, tokens)
+            assert covered <= bound <= E * -(-N // rows) * rows
+
+
 # -- which programs take the step's form -------------------------------------
 
 
@@ -190,17 +302,64 @@ def test_only_a_step_that_knows_its_live_rows_visits():
     assert tr.moe_step_visits(get_config("tiny-lfm2"), None)
 
 
+def test_only_a_seq_that_keeps_its_keys_groups():
+    """The rule of the grouped form is the static shape a mode sees and the
+    mesh: a `Seq` that keeps its keys (a bucket, a chunk) knows the rows
+    that hold a token; the plain forward (which may be differentiated), a
+    step, `Verify`, a sharded mesh, a model without experts and a program
+    whose rows do not fit the kernel's fast memory keep their forms."""
+    cfg = get_config("tiny-moe")
+    tables = jnp.ones((2, 4), jnp.int32)
+    at = jnp.zeros((2,), jnp.int32)
+    assert stack.Seq(cfg).kept_rows(2, 8) is None
+    assert stack.Decode(cfg, at, tables, 4).kept_rows(2, 1) is None
+    assert stack.Verify(cfg, at, tables, 4, at).kept_rows(2, 4) is None
+    bucket = stack.Seq(cfg, n_valid=jnp.asarray([3, 8]), keep=True)
+    np.testing.assert_array_equal(
+        bucket.kept_rows(2, 8), np.arange(8)[None] < np.array([[3], [8]]))
+    chunk = stack.Seq(cfg, n_valid=jnp.asarray([5]), keep=True,
+                      chunk=(jnp.int32(16), tables[0]), page_size=4)
+    assert np.asarray(chunk.kept_rows(1, 16)).sum() == 5
+    assert np.asarray(stack.Seq(cfg, keep=True).kept_rows(2, 8)).all()
+    # tiny-moe drops rows (capacity factor 1.25 of 4 top 2): with room for
+    # every choice, as the cells' configurations have, nothing is dispatched
+    roomy = dataclasses.replace(cfg, capacity_factor=2.0)
+    assert tr.moe_seq_groups(roomy, 1, 256, None)
+    assert tr.moe_seq_groups(get_config("tiny-lfm2"), 2, 16, None)
+    assert not tr.moe_seq_groups(get_config("tiny-llama"), 1, 256, None)
+    assert not tr.moe_seq_groups(get_config("tiny-sambay"), 1, 256, None)
+
+    class Sharded:
+        shape = {"tp": 2}
+
+    assert not tr.moe_seq_groups(roomy, 1, 256, Sharded())
+    # under a capacity rows can be dropped: the forms that dispatch
+    assert not tr.moe_seq_groups(cfg, 1, 256, None)
+    # the published shapes: 256 and 512 rows lie whole in VMEM, 4096 do not
+    wide = dataclasses.replace(cfg, d_model=2560, d_ff=768, num_experts=64,
+                               num_selected_experts=6, capacity_factor=64 / 6,
+                               dtype="bfloat16")
+    assert tr.moe_seq_groups(wide, 1, 256, None)
+    assert tr.moe_seq_groups(wide, 1, 512, None)
+    assert not tr.moe_seq_groups(wide, 1, 4096, None)
+    # what the engine counts for such a program is the bound of its passes
+    assert tr.moe_rows_computed(wide, 1, 256, tokens=256) == \
+        moe.groups_rows_bound(256, 64, 6, 256) == 6 * 256 + 64 * 127
+    assert tr.moe_rows_computed(wide, 1, 256, tokens=2) == 12 * 128
+    assert tr.moe_rows_computed(wide, 1, 256) == 64 * 256
+    assert tr.moe_rows_computed(wide, 1, 4096, tokens=4096) == 64 * 4096
+
+
 # sha256 of the StableHLO text of the programs that must NOT change, as this
 # tree's parent (c4ad2b2) lowers them for the CPU at `highest` matmul
 # precision, jax as pinned below (tests/test_smallthinker_model.py pins the
 # accepted families' chunk and bucket programs and the dense families' decode
-# programs the same way; these are the ones it lacks): the new family's chunk
-# and bucket, and `Verify` and a training step with and without experts
+# programs the same way; these are the ones it lacks): `Verify` and a
+# training step with and without experts. Since PR 43 a chunk or a bucket of a
+# family whose experts drop nothing runs each expert over the rows that chose
+# it, so those programs moved to GROUPED_PROGRAMS below; tiny-moe's drop rows
+# (capacity factor 1.25) and are still the parent's, there
 PARENT_PROGRAMS = {
-    ("tiny-smallthinker", "chunk"):
-        "65df4485175fe14901acaafed3efe55e18f404861ccbc9aa4145a84c9aacffbc",
-    ("tiny-smallthinker", "bucket"):
-        "99163ff2441238c365bb37e4b94f53cc8f954bce9b37946d44269603d44122d1",
     ("tiny-moe", "verify"):
         "d3757c5f5b5cfb8b9197aa6ddaec8e90f1acc1251c8a48276541d071d5c42e14",
     ("tiny-moe", "train"):
@@ -218,6 +377,25 @@ PARENT_PROGRAMS = {
 DECODE_PROGRAMS = {
     "tiny-smallthinker":
         "b25c20e8ba6129a0ae5584d35968c89d4ba54efd46088d37193585b58f46124e",
+}
+# the chunk and bucket programs of the families whose experts drop nothing
+# DID change in PR 43 (each expert over the rows that chose it; at the tiny
+# width the op's XLA form, every expert times the combine's zeros, with the
+# layer read out of the segment's stacks): this tree's own, so that a later
+# change to them is one that is meant
+GROUPED_PROGRAMS = {
+    ("tiny-smallthinker", "chunk"):
+        "030d699c367b75ad490d036449d4179bfd9dd0e78a97f29efcb4f1acd9fc2358",
+    ("tiny-smallthinker", "bucket"):
+        "38bf8b4999763ffac2fbee832fc945d37579290b491336df115e7c7b071a92cc",
+    ("tiny-lfm2", "chunk"):
+        "3b026ad5d9e197d3d8acd16a16c9e677ca272364493bd756d4481ce07b2da2df",
+    ("tiny-lfm2", "bucket"):
+        "d3a78cc2c6fd2cd4a8f57388f1ad147180733c227c6187a7d0fdc837722b5a31",
+    ("tiny-longcat-flash", "chunk"):
+        "5de8881fa218b12ce7ecf3c7bcfcf117c73cea9e1bed746a636b842db71513e9",
+    ("tiny-longcat-flash", "bucket"):
+        "bd8fd2bc1a51d7aef7f731156f94709dc1112598c675a314cc5ac19ce0ae55d7",
 }
 LOWERED_WITH_JAX = "0.9.0"
 PAGE = 4
@@ -300,6 +478,14 @@ def test_the_expert_families_decode_programs_are_this_trees(name):
     assert _digest(name, "decode") == DECODE_PROGRAMS[name]
 
 
+@pytest.mark.parametrize("name, program", sorted(GROUPED_PROGRAMS))
+def test_the_expert_families_chunk_and_bucket_programs_are_this_trees(
+        name, program):
+    if jax.__version__ != LOWERED_WITH_JAX:
+        pytest.skip(f"digests were taken with jax {LOWERED_WITH_JAX}")
+    assert _digest(name, program) == GROUPED_PROGRAMS[name, program]
+
+
 def _eqns(jaxpr):
     for e in jaxpr.eqns:
         yield e
@@ -307,12 +493,41 @@ def _eqns(jaxpr):
             yield from _eqns(sub)
 
 
-def test_a_steps_kernel_is_handed_the_segments_stacks_whole(kernel):
+def test_the_step_kernels_operations_are_the_parents_in_their_order(kernel):
+    """The profiler's fingerprint of a decode program covers a Pallas
+    kernel's operations in their order, not where they are written (PERF.md
+    section 6, PR 43): `moe_step`'s body and its blocks' index maps, as
+    jaxprs, are those of PR 42's kernel (the parent of PR 43, c4ad2b2 ..
+    8a0fcb9), whatever helpers the body shares with `moe_groups`."""
+    if jax.__version__ != LOWERED_WITH_JAX:
+        pytest.skip(f"the digest was taken with jax {LOWERED_WITH_JAX}")
+    S = jax.ShapeDtypeStruct
+    E = 8
+    jaxpr = jax.make_jaxpr(
+        lambda x, c, hit, a, b, d: moe.expert_step(
+            x, c, hit, a, b, d, LAYER, jax.nn.silu)[0])(
+        S((ROWS, D), jnp.float32), S((ROWS, E), jnp.float32), S((E,), bool),
+        S((LAYERS, E, D, F), jnp.float32), S((LAYERS, E, D, F), jnp.float32),
+        S((LAYERS, E, F, D), jnp.float32))
+    call = next(e for e in _eqns(jaxpr.jaxpr)
+                if e.primitive.name == "pallas_call")
+    assert call.params["name"] == "moe_step"
+    text = str(call.params["jaxpr"]) + "".join(
+        str(b.index_map_jaxpr)
+        for b in call.params["grid_mapping"].block_mappings)
+    # taken from the parent's tree at `highest` matmul precision, as
+    # tests/conftest.py sets it
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "eecb394c47a954af3b88f18f827e716a63fa71785773ad4ef219efbe73794d7e")
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "bucket"])
+def test_a_kernel_is_handed_the_segments_stacks_whole(program, kernel):
     """The weights are read where they lie: every `moe_step` call of a
-    decode program takes the three stacks [repeats, E, D, F] of its
-    segment and the layer as a scalar, never ONE layer's experts, which
-    behind a layer scan is a copy of them every step; a chunk's scan still
-    slices its layer for the form that runs every expert."""
+    decode program, and every `moe_groups` call of a chunk or a bucket,
+    takes the three stacks [repeats, E, D, F] of its segment and the layer
+    as a scalar, never ONE layer's experts, which behind a layer scan is a
+    copy of them every call."""
     cfg = _wide("tiny-lfm2")
     E, F_ = cfg.num_experts, cfg.expert_ff
     params = jax.eval_shape(lambda k: stack.init_params(cfg, k),
@@ -320,17 +535,36 @@ def test_a_steps_kernel_is_handed_the_segments_stacks_whole(kernel):
     pool = jax.ShapeDtypeStruct(
         stack.pool_shape(cfg.count("attn"), 16, PAGE, cfg.kv_heads, cfg.hdim),
         jnp.float32)
-    state = jax.eval_shape(lambda: stack.new_engine_state(
-        cfg, 8, PAGE, jnp.float32, jnp.float32))
-
-    def step(params, pool, state, tokens, at, tables):
-        return stack.run_paged(params, tokens[:, None], cfg,
-                               stack.Decode(cfg, at, tables, PAGE),
-                               (pool, pool), state)
-
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-    jaxpr = jax.make_jaxpr(step)(params, pool, state, i32(8), i32(8),
-                                 i32(8, 4))
+
+    if program == "decode":
+        state = jax.eval_shape(lambda: stack.new_engine_state(
+            cfg, 8, PAGE, jnp.float32, jnp.float32))
+
+        def step(params, pool, state, tokens, at, tables):
+            return stack.run_paged(params, tokens[:, None], cfg,
+                                   stack.Decode(cfg, at, tables, PAGE),
+                                   (pool, pool), state)
+
+        jaxpr = jax.make_jaxpr(step)(params, pool, state, i32(8), i32(8),
+                                     i32(8, 4))
+    elif program == "chunk":
+        state = jax.eval_shape(
+            lambda: stack.new_request_state(cfg, 1, jnp.float32))
+
+        def chunk(params, pool, state, tokens, start, table, last):
+            return stack.run_paged(
+                params, tokens[None], cfg,
+                stack.Seq(cfg, n_valid=(last + 1)[None], keep=True,
+                          chunk=(start, table), page_size=PAGE),
+                (pool, pool), state)
+
+        jaxpr = jax.make_jaxpr(chunk)(params, pool, state, i32(16), i32(),
+                                      i32(8), i32())
+    else:
+        jaxpr = jax.make_jaxpr(
+            lambda p, t, n: stack.prefill(p, cfg, t, n))(
+                params, i32(2, 16), i32(2))
     kernels = [e for e in _eqns(jaxpr.jaxpr)
                if e.primitive.name == "pallas_call" and any(
                    v.aval.shape[1:] == (E, 128, F_) for v in e.invars)]
@@ -341,6 +575,8 @@ def test_a_steps_kernel_is_handed_the_segments_stacks_whole(kernel):
         assert shapes.count((2, E, 128, F_)) == 2  # w_in, w_gate
         assert shapes.count((2, E, F_, 128)) == 1  # w_out
         assert (E, 128, F_) not in shapes
+    names = {e.params["name"] for e in kernels}
+    assert names == {"moe_step" if program == "decode" else "moe_groups"}
 
 
 # -- through the engine ------------------------------------------------------

@@ -356,8 +356,12 @@ def test_refusals_name_the_stack_and_the_reason(model):
 # space leave every one the text it was. Since PR 42 a decode step of a family
 # that holds experts visits the experts its live rows chose
 # (tests/test_moe_step.py), so the decode programs of tiny-moe, tiny-lfm2 and
-# tiny-longcat-flash are PR 42's own (re-pinned there); the other fifteen are
-# still the parent's of PR 41
+# tiny-longcat-flash are PR 42's own (re-pinned there); since PR 43 a chunk or
+# a bucket of a family whose experts drop nothing runs each expert over the
+# rows that chose it, so the chunk and bucket programs of tiny-lfm2 and
+# tiny-longcat-flash are PR 43's own (re-pinned here and in
+# tests/test_moe_step.py; tiny-moe's drop rows and stay); the other eleven
+# are still the parent's of PR 41
 PARENT_PROGRAMS = {
     ("tiny-llama", "decode"):
         "dd392d7dd4c77fda7abfc16aade694d5206c43d1fb8ba937438b4b51dc2b2a48",
@@ -374,9 +378,9 @@ PARENT_PROGRAMS = {
     ("tiny-lfm2", "decode"):
         "409cdb5fa8379b59546b1ade251883ef3247cb6064f13af31f37d3ec50bc09e6",
     ("tiny-lfm2", "chunk"):
-        "de080e28e0f11512d82f14000bdc64c87916f9de146e9777b9097e5d640f0114",
+        "3b026ad5d9e197d3d8acd16a16c9e677ca272364493bd756d4481ce07b2da2df",
     ("tiny-lfm2", "bucket"):
-        "5c6757a44f978f1abc9287b4d4739e7f2fb11ecaf0f4b0a7c152e12cd4c0a029",
+        "d3a78cc2c6fd2cd4a8f57388f1ad147180733c227c6187a7d0fdc837722b5a31",
     ("tiny-olmo-hybrid", "decode"):
         "e6a03b5c0bf24f573422503444c01675b9cc893abdd00f81a10781ff554f9b41",
     ("tiny-olmo-hybrid", "chunk"):
@@ -392,9 +396,9 @@ PARENT_PROGRAMS = {
     ("tiny-longcat-flash", "decode"):
         "f97d5b3480f939fa4a2c03b6d6e8cc7807450e98cdf61a8d6dbe3dfe0de6d6f7",
     ("tiny-longcat-flash", "chunk"):
-        "095f73bc51eab8b744dda8dcb0ff88ceb14149a45fae95b78cb6608f185cb868",
+        "5de8881fa218b12ce7ecf3c7bcfcf117c73cea9e1bed746a636b842db71513e9",
     ("tiny-longcat-flash", "bucket"):
-        "5a4d7a4364466669858f55c586f4fafc090024897788a710dbc8de6830a7e2bc",
+        "bd8fd2bc1a51d7aef7f731156f94709dc1112598c675a314cc5ac19ce0ae55d7",
 }
 LOWERED_WITH_JAX = "0.9.0"
 

@@ -18,6 +18,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from ray_tpu.ops import (
+    expert_groups,
     expert_step,
     flash_attention,
     gdn_chunk,
@@ -138,6 +139,17 @@ def _expert_step(E, D_, F_, act=jax.nn.silu):
              ((2, E, F_, D_), BF16)], 1)
 
 
+def _expert_groups(E, D_, F_, act=jax.nn.silu, rows=256):
+    """A prefill chunk's expert product at a cell's shape: each of E experts
+    [D_, F_] of layer 1 of a segment's stacks over the rows that chose it,
+    of 256 rows that lie whole in VMEM."""
+    return (lambda x, c, member, w_in, w_gate, w_out: expert_groups(
+                x, c, member, w_in, w_gate, w_out, 1, act),
+            [((rows, D_), BF16), ((rows, E), jnp.float32),
+             ((rows, E), jnp.bool_), ((2, E, D_, F_), BF16),
+             ((2, E, D_, F_), BF16), ((2, E, F_, D_), BF16)], 1)
+
+
 # name -> (op, [(shape, dtype)], fewest tpu_custom_calls in the program)
 CASES = {
     "flash_fwd_t2048": (_flash_fwd, _qkv(2048), 1),
@@ -217,6 +229,17 @@ CASES = {
     "moe_step_64_experts_2560x768_reglu": _expert_step(
         64, 2560, 768, jax.nn.relu),
     "moe_step_16_experts_6144x2048": _expert_step(16, 6144, 2048),
+    # the expert product of a chunk of 256 rows at the same four shapes, a
+    # bucket of 64 rows and a chunk of 512 at the narrowest
+    "moe_groups_8_experts_4096x14336": _expert_groups(8, 4096, 14336),
+    "moe_groups_32_experts_2048x1792": _expert_groups(32, 2048, 1792),
+    "moe_groups_64_experts_2560x768_reglu": _expert_groups(
+        64, 2560, 768, jax.nn.relu),
+    "moe_groups_16_experts_6144x2048": _expert_groups(16, 6144, 2048),
+    "moe_groups_64_experts_2560x768_reglu_64_rows": _expert_groups(
+        64, 2560, 768, jax.nn.relu, rows=64),
+    "moe_groups_64_experts_2560x768_reglu_512_rows": _expert_groups(
+        64, 2560, 768, jax.nn.relu, rows=512),
     "rms_norm_2048x4096": (
         rms_norm, [((2048, D_MODEL), BF16), ((D_MODEL,), BF16)], 1),
 }
@@ -547,12 +570,17 @@ def test_the_window_and_full_cells_programs_hold_two_page_spaces(
     assert len(re.findall(r"%%%s_window(\.\d+)? = " % kernel, text)) == 3
     assert not re.search(r"= bf16\[\d+,(1,)?\d+,16,512\]\S* copy\(", text)
     steps = re.findall(r"%moe_step(?:\.\d+)? = [^\n]*", text)
+    groups = re.findall(r"%moe_groups(?:\.\d+)? = [^\n]*", text)
+    # the experts of a decode step (the experts a live row chose, over all
+    # rows) and of a chunk (each expert over the rows that chose it): one
+    # kernel a layer of the scanned period, handed the segment's three
+    # stacks whole (operands of the loop, not slices of them), and nothing
+    # copies or slices ONE layer's experts
     if program == "chunk_prefill_256":
-        assert not steps  # a chunk's 256 rows touch every expert
-        return
-    # a decode step's experts: one kernel a layer of the scanned period,
-    # handed the segment's three stacks whole (operands of the loop, not
-    # slices of them), and nothing copies or slices ONE layer's experts
+        assert not steps
+        steps = groups
+    else:
+        assert not groups
     assert len(steps) == 4
     for call in steps:
         assert len(re.findall(r"bf16\[3,64,2560,768\]\{3,2,1,0\}", call)) == 2
