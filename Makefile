@@ -1,134 +1,25 @@
-# One-command CI for the repo (VERDICT r3 #10: `make check` green in one
-# invocation on the bench box, with the chunking the suite needs baked in).
+# One-command CI for the repo.
 #
-#   make check        fast tier, three chunks (keeps peak RSS + wall sane
-#                     on the 1-CPU bench box) + the shm TSAN gate
+#   make check        the driver's tier-1 command (`pytest -m "not slow"
+#                     tests/`, one call) after the native build, lint and
+#                     three fast smokes, then the shm TSAN gate
 #   make check-slow   the slow tier on top (XLA-fallback kernel variants,
 #                     multi-process gang bootstraps — compile-bound)
 #   make check-all    both tiers + TSAN
 #
-# Chunks mirror how the suite naturally partitions (and how round-3's
-# judge had to run it by hand): core runtime first (fast signal), then
-# the library tier, then the models/parallel compile-heavy tier.
+# check runs every test not marked slow; the marker targets below (chaos,
+# health, fleet, ...) are for iterating on one subsystem. The driver adds
+# six workers: `make check PYTEST="python -m pytest -q -n 6"`.
+#
+# Speed is not measured here: `python3 benchmark/run.py --workload <cell>`
+# on a TPU v5e, cells in BENCHMARK.json, numbers in PERF_LEDGER.jsonl.
 
 PYTEST ?= python -m pytest -q
 FAST ?= -m "not slow"
 
-CORE_TESTS = tests/test_core_runtime.py tests/test_core_utils.py \
-	tests/test_shm_store.py tests/test_process_pool.py \
-	tests/test_actor_process.py tests/test_async_actors.py \
-	tests/test_streaming_returns.py tests/test_rpc.py \
-	tests/test_persistence.py tests/test_object_transfer.py \
-	tests/test_object_plane.py tests/test_broadcast.py \
-	tests/test_cross_host.py tests/test_fault_tolerance.py \
-	tests/test_sched.py tests/test_dag.py tests/test_collectives.py \
-	tests/test_runtime_env.py tests/test_autoscaler.py \
-	tests/test_log_monitor.py tests/test_timeline.py tests/test_cli.py \
-	tests/test_tracing.py tests/test_health.py tests/test_profiler.py \
-	tests/test_object_ledger.py tests/test_raylint.py \
-	tests/test_sanitizer.py tests/test_scale_sim.py
-
-LIB_TESTS = tests/test_data.py tests/test_train.py tests/test_tune.py \
-	tests/test_rl.py tests/test_serve.py tests/test_serve_schema.py \
-	tests/test_serve_cross_host.py tests/test_disagg.py \
-	tests/test_fleet.py tests/test_rl_online.py tests/test_dashboard.py \
-	tests/test_integrations.py tests/test_platform.py \
-	tests/test_microbenchmark.py tests/test_pipeline_trainer.py \
-	tests/test_ingest.py
-
-MODEL_TESTS = tests/test_models.py tests/test_ops.py tests/test_parallel.py \
-	tests/test_pipeline.py tests/test_bootstrap_multiproc.py \
-	tests/test_graft_entry.py tests/test_scale_lowering.py
-
 .PHONY: check check-slow check-all chaos health pipeline profile memory \
 	broadcast fleet rl ingest tsan shm lint spec-smoke shard-smoke scale \
-	status bench-data bench-object bench-serve bench-disagg bench-trace \
-	bench-health bench-pipeline bench-profile bench-sanitize bench-fleet \
-	bench-rl bench-spec bench-scale bench-ingest
-
-# quick data-plane iteration loop: just the data + images bench suites
-# (stall %, rows/s, images/s), merged into BENCH_SUMMARY.json
-bench-data:
-	env RAY_TPU_BENCH_SUITE=data,images python bench.py
-
-# object-plane iteration loop: broadcast 64MB to 4 pullers over the
-# transfer plane (object_broadcast_gbps, object_cache_hit_rate), merged
-# into BENCH_SUMMARY.json
-bench-object:
-	env RAY_TPU_BENCH_SUITE=object python bench.py
-
-# serve iteration loop: continuous-batching burst (req/s, p50/p95 TTFT,
-# decode tok/s) plus the disagg-vs-colocated pass (same burst through a
-# prefill+decode pair with KV streamed during prefill), merged into
-# BENCH_SUMMARY.json
-bench-serve:
-	env RAY_TPU_BENCH_SUITE=serve python bench.py
-
-# speculative-decoding acceptance loop: plain vs ngram-spec engines as
-# alternating same-process rounds with per-round medians — the committed
-# spec tok/s row must BEAT the plain row or the suite raises (no summary
-# commit), merged into BENCH_SUMMARY.json
-bench-spec:
-	env RAY_TPU_BENCH_SUITE=spec python bench.py
-
-# disagg acceptance loop: ONLY the disagg rows — alternating colocated/
-# disagg rounds with per-side medians (box drift hits both sides), a
-# mixed long-prefill/long-decode load row, and the traced migration-
-# overlaps-prefill evidence row, merged into BENCH_SUMMARY.json
-bench-disagg:
-	env RAY_TPU_BENCH_SUITE=disagg python bench.py
-
-# observability-overhead loop: the same disagg serve burst with tracing
-# off (sample rate 0) vs fully on (1.0) — untraced/traced req/s and the
-# overhead %% row, merged into BENCH_SUMMARY.json
-bench-trace:
-	env RAY_TPU_BENCH_SUITE=trace python bench.py
-
-# SLO-digest overhead loop: decode burst with digests off vs on
-# (slo_digest_overhead_pct, acceptance <= 2%) plus the digest-update
-# micro-cost, merged into BENCH_SUMMARY.json
-bench-health:
-	env RAY_TPU_BENCH_SUITE=health python bench.py
-
-# pipeline-trainer iteration loop: 1-stage vs 2-stage tiny LM tokens/s
-# plus the 2-stage bubble fraction, merged into BENCH_SUMMARY.json
-bench-pipeline:
-	env RAY_TPU_BENCH_SUITE=pipeline python bench.py
-
-# sampling-profiler overhead loop: serve burst with the profiler off vs
-# collecting (profiler_overhead_pct, acceptance <= 2%), merged into
-# BENCH_SUMMARY.json
-bench-profile:
-	env RAY_TPU_BENCH_SUITE=profile python bench.py
-
-# concurrency-sanitizer overhead loop: serve burst on tracked vs stock
-# locks (sanitizer_overhead_pct, acceptance <= 2% enabled / 0 disabled),
-# merged into BENCH_SUMMARY.json
-bench-sanitize:
-	env RAY_TPU_BENCH_SUITE=sanitize python bench.py
-
-# fleet chaos loop: streaming burst with a decode replica killed every
-# few seconds — live resume must hold serve_fleet_failed_requests at 0
-# with p95 TTFT within 2x steady-state, merged into BENCH_SUMMARY.json
-bench-fleet:
-	env RAY_TPU_BENCH_SUITE=fleet python bench.py
-
-# online RL loop gate: multi-iteration rollout->reward->train->sync on
-# the serve fleet — reward must improve (rl_reward_delta), no-drain
-# weight re-sync must cost <5%% of loop wall (rl_sync_stall_pct) and hold
-# unrelated serve p95 TTFT within 1.2x (rl_serve_p95_ttft_ratio), merged
-# into BENCH_SUMMARY.json
-bench-rl:
-	env RAY_TPU_BENCH_SUITE=rl python bench.py
-
-# shared ingest gate: three tenants (trainer / RL / batch) off one fixed
-# pool must split throughput within 10%% of their weights
-# (ingest_fair_share_err_pct), a repeat epoch must stream >=3x faster
-# from the object cache (ingest_repeat_epoch_speedup), and a stalling
-# hog tenant must grow the pool within two eval periods
-# (ingest_autoscale_latency_s), merged into BENCH_SUMMARY.json
-bench-ingest:
-	env RAY_TPU_BENCH_SUITE=ingest python bench.py
+	status
 
 # cluster health at a glance (alerts, SLO digests, node liveness) from
 # the in-process health plane; DASH=host:port reads a running head
@@ -144,13 +35,12 @@ shm:
 # `# raylint: disable=<rule>` plus a justification comment
 lint:
 	@echo "== lint: compileall =="
-	python -m compileall -q ray_tpu tests bench.py
+	python -m compileall -q ray_tpu tests
 	@echo "== lint: raylint =="
 	python -m ray_tpu.tools.raylint
 
 # fast spec-decode smoke (<30s): greedy plain-vs-spec equivalence on the
-# ngram proposer — a proposer regression fails tier-1 here instead of
-# only surfacing in the slow bench
+# ngram proposer — a proposer regression fails here first
 spec-smoke:
 	@echo "== spec-decode smoke: greedy plain-vs-spec equivalence =="
 	$(PYTEST) $(FAST) tests/test_spec_decode.py \
@@ -158,19 +48,10 @@ spec-smoke:
 
 # fast federated-control-plane smoke (<30s): 32 simulated node agents
 # over 2 KV shards with a primary SIGKILL'd mid-run — zero lost requests
-# and bounded failover recovery or the harness exits nonzero; the full
-# 8->128 ladder with gates lives in bench-scale
+# and bounded failover recovery or the harness exits nonzero
 scale:
 	@echo "== scale smoke: 32-node federation + shard kill ride-through =="
 	python -m ray_tpu.util.scale_sim --nodes 32 --duration 4 --kill-shard
-
-# federated scale ladder: N=8/32/128 simulated nodes over sharded KV +
-# per-pod aggregators + bottom-up scheduling — head CPU (<1 core at 128),
-# heartbeat lag p95, alert->actuation growth (<=1.5x 8->128), scheduling
-# throughput, and the shard-kill chaos row (zero lost requests), merged
-# into BENCH_SUMMARY.json
-bench-scale:
-	env RAY_TPU_BENCH_SUITE=scale python bench.py
 
 # fast 3D-parallelism smoke: one sharded-stage parity run (dp=2 submesh
 # under the 2-stage pipeline) plus the schedule-generator units — seconds,
@@ -181,12 +62,8 @@ shard-smoke:
 		-k "TestInterleavedSchedule or (sharded_matches_replicated and dp)"
 
 check: shm lint spec-smoke shard-smoke scale
-	@echo "== chunk 1/3: core runtime =="
-	$(PYTEST) $(FAST) $(CORE_TESTS)
-	@echo "== chunk 2/3: libraries (data/train/tune/rl/serve) =="
-	$(PYTEST) $(FAST) $(LIB_TESTS)
-	@echo "== chunk 3/3: models/ops/parallel =="
-	$(PYTEST) $(FAST) $(MODEL_TESTS)
+	@echo "== tier-1: every test not marked slow =="
+	$(PYTEST) $(FAST) tests/
 	$(MAKE) tsan
 
 check-slow:
@@ -201,57 +78,51 @@ chaos:
 	$(PYTEST) -m chaos tests/
 
 # health-plane tier (digests, alert rules, quarantine, postmortems) for
-# iterating on SLO/health work; the fast subset also runs inside check
-# via CORE_TESTS
+# iterating on SLO/health work
 health:
 	@echo "== health tier =="
 	$(PYTEST) -m health tests/
 
 # MPMD pipeline-parallel trainer tier (stage gangs, 1F1B parity, ZeRO-1,
-# channel backpressure) for iterating on pipeline work; the fast subset
-# also runs inside check via LIB_TESTS
+# channel backpressure) for iterating on pipeline work
 pipeline:
 	@echo "== pipeline tier =="
 	$(PYTEST) -m pipeline tests/
 
 # profiling-plane tier (stack dumps, sampling profiles, goodput ledger,
-# hung-worker e2e) for iterating on profiler work; the fast subset also
-# runs inside check via CORE_TESTS
+# hung-worker e2e) for iterating on profiler work
 profile:
 	@echo "== profile tier =="
 	$(PYTEST) -m profile tests/
 
 # object-plane tier (ledger metadata, flow accounting, leak sweep,
-# dead-node locate) for iterating on object observability work; also
-# runs inside check via CORE_TESTS
+# dead-node locate) for iterating on object observability work
 memory:
 	@echo "== object plane tier =="
 	$(PYTEST) -m objects tests/
 
 # collective-broadcast tier (relay trees, partial hygiene, zero-socket
-# shm handoff, api.broadcast e2e) for iterating on dissemination work;
-# the fast subset also runs inside check via CORE_TESTS
+# shm handoff, api.broadcast e2e) for iterating on dissemination work
 broadcast:
 	@echo "== broadcast tier =="
 	$(PYTEST) -m broadcast tests/
 
 # fleet actuation tier (autoscale policy convergence, kill-resume chaos,
-# adapter hot-swap, remediation pipeline) for iterating on fleet work;
-# the fast subset also runs inside check via LIB_TESTS
+# adapter hot-swap, remediation pipeline) for iterating on fleet work
 fleet:
 	@echo "== fleet tier =="
 	$(PYTEST) -m fleet tests/
 
 # online-RL tier (fleet rollouts with logprobs, staleness bounds,
 # no-drain weight re-sync, loop stop hygiene) for iterating on rl/online
-# work; the fast subset also runs inside check via LIB_TESTS
+# work
 rl:
 	@echo "== online RL tier =="
 	$(PYTEST) -m rl tests/
 
 # shared ingest-service tier (prefetch lifecycle, fair-share admission,
 # repeat-epoch cache economics, pool autoscale) for iterating on
-# data/ingest work; also runs inside check via LIB_TESTS
+# data/ingest work
 ingest:
 	@echo "== shared ingest tier =="
 	$(PYTEST) -m ingest tests/

@@ -539,7 +539,7 @@ def train_loop(config: Dict[str, Any]) -> None:
     n_dev = math.prod(config["mesh_axes"].values())
     mesh = build_mesh(MeshSpec.create(**config["mesh_axes"]),
                       devices=jax.devices()[:n_dev])
-    # the bench.py llama-2b recipe: bf16 parameters (hence gradients) and
+    # the llama-2b recipe: bf16 parameters (hence gradients) and
     # factored second moments, through the library's own optimizer
     opt = make_optimizer(learning_rate=plan.learning_rate,
                          warmup_steps=plan.warmup_steps,

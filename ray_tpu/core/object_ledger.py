@@ -32,8 +32,7 @@ one module:
   unless re-asserted) and publishing `object_leaks{kind}` gauges.
 
 Everything here is gated on `config.object_ledger` (cached ~1s —
-`reload_enabled()` after toggling mid-process, as the bench overhead
-suite does).
+`reload_enabled()` after toggling mid-process).
 """
 
 from __future__ import annotations
@@ -121,7 +120,7 @@ def enabled() -> bool:
 
 def reload_enabled() -> None:
     """Invalidate the cached config.object_ledger value (call after
-    toggling the flag mid-process, e.g. the bench overhead suite)."""
+    toggling the flag mid-process)."""
     _enabled_cache[1] = 0.0
 
 

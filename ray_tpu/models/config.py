@@ -539,28 +539,11 @@ register(ModelConfig(
 register(ModelConfig(
     name="llama-600m",
     # Llama-3 family member sized so f32 master params + Adam moments fit a
-    # single 16GB v5e chip — the single-chip bench/flagship-entry config.
+    # single 16GB v5e chip — the single-chip flagship-entry config.
     vocab_size=32000,
     d_model=1536, n_layers=16, n_heads=12, n_kv_heads=4,
     head_dim=128, d_ff=6144,
     max_seq_len=4096,
-    norm="rmsnorm", activation="swiglu", positional="rope",
-    rope_theta=500000.0,
-))
-
-register(ModelConfig(
-    name="moe-1b",
-    # Single-chip MoE bench config (BASELINE.md workload #3's measurable
-    # stand-in for mixtral-8x7b): llama-600m's attention backbone, 8
-    # experts top-2 — ~1.3B total params, ~0.45B active per token. With
-    # factored optimizer + bf16 params it fits one 16GB v5e chip, so the
-    # expert-dispatch path (capacity-factor einsums -> all_to_all on ep
-    # meshes) gets a real tokens/s + overhead%% gate.
-    vocab_size=32000,
-    d_model=1536, n_layers=8, n_heads=12, n_kv_heads=4,
-    head_dim=128, d_ff=4096,
-    max_seq_len=4096,
-    num_experts=8, num_selected_experts=2,
     norm="rmsnorm", activation="swiglu", positional="rope",
     rope_theta=500000.0,
 ))

@@ -1,5 +1,5 @@
 """Operator CLI: ``ray-tpu start|status|list|submit|logs|serve|memory|
-timeline|bench|microbenchmark``.
+timeline|microbenchmark``.
 
 Reference analogue: `python/ray/scripts/scripts.py`. Three ways to reach
 a runtime:
@@ -422,17 +422,6 @@ def cmd_timeline(args) -> int:
     return 0
 
 
-def cmd_bench(args) -> int:
-    import os
-
-    os.environ["RAY_TPU_BENCH_SUITE"] = args.suite
-    sys.path.insert(0, os.getcwd())
-    import bench
-
-    bench.main()
-    return 0
-
-
 def cmd_health(args) -> int:
     import ray_tpu
 
@@ -589,10 +578,6 @@ def main(argv=None) -> int:
     pt.add_argument("--events-dir",
                     help="merge session dumps written via event_log_dir")
     pt.set_defaults(fn=cmd_timeline)
-
-    pb = sub.add_parser("bench", help="run the driver benchmarks")
-    pb.add_argument("--suite", default="train,serve,data")
-    pb.set_defaults(fn=cmd_bench)
 
     pm = sub.add_parser("microbenchmark",
                         help="core task/actor/object-plane throughput canaries")

@@ -13,7 +13,7 @@ Pieces:
 - `DisaggCoordinator` — admits requests, picks one replica per role by
   power-of-two-choices over role-specific load (router.pow2_choice),
   and drives the prefill → migrate → decode pipeline. Works over local
-  `EngineWorker`s (in-process engines: tier-1 tests, bench) or
+  `EngineWorker`s (in-process engines: tier-1 tests) or
   `ReplicaWorker`s wrapping serve replica actors (from_deployments /
   deploy_disagg).
 - KV transfer — kv_transfer="stream" (the default) pipelines page-window
@@ -680,7 +680,7 @@ class _LoadTracker:
 
 class EngineWorker(_LoadTracker):
     """One in-process InferenceEngine acting as a prefill or decode
-    replica — the unit the tier-1 e2e test and bench.py drive."""
+    replica — the unit the tier-1 e2e tests drive."""
 
     def __init__(self, engine: InferenceEngine, name: str = "engine"):
         super().__init__()

@@ -341,22 +341,14 @@ class EngineConfig:
     max_window_pages: int = 0
     max_seq_len: int = 1024
     prefill_buckets: tuple = (64, 128, 256, 512, 1024)
-    # >1: queued prompts prefill together in padded batches. Helps
-    # high-QPS short-prompt fleets (one dispatch amortizes many prompts).
-    # Round-3 measured batch=4 hurting TTFT ~2x — but that was WITH fixed
-    # span 16; combined with adaptive_span (below) batched prefill is the
-    # dominant TTFT win on bursty arrivals (r4, 24-req burst on v5e:
-    # pbs=8+busy=4 gives p50 TTFT 1.15s and 5.8 req/s vs 2.40s / 4.4
-    # req/s fixed). Default stays 1 (steady low-QPS serving pays padding
-    # for nothing); bursty deployments should raise it.
+    # >1: prompts that wait together prefill together, in one padded
+    # [K, bucket] dispatch. No benchmark cell sets it (ROADMAP D4 decides
+    # whether it stays; D15 the batched bucket path it steers).
     prefill_batch_size: int = 1
-    # Burst tiers: with prefill_batch_size=K, padded batch shapes compile
-    # at {1, K, 2K, 4K, ...} up to this cap, and the prefill thread
-    # drains the WHOLE queue into one dispatch at the smallest covering
-    # tier. A 24-request burst then pays ONE [32, bucket] prefill instead
-    # of three serial [8, bucket] rounds with decode spans interleaving —
-    # p50 TTFT collapses to ~one prefill's latency (r5; the r4 shape was
-    # the three-round version). 0 disables tiering (K stays the cap).
+    # With prefill_batch_size=K, padded batch shapes compile at {1, K, 2K,
+    # 4K, ...} up to this cap and the prefill thread drains the whole
+    # queue into one dispatch at the smallest covering tier; 0 leaves K
+    # the cap. No cell sets it (D4).
     prefill_max_batch: int = 32
     # Chunked prefill (vLLM-style): prompts longer than prefill_chunk are
     # processed in prefill_chunk-token chunks ON THE DECODE THREAD, with
@@ -396,9 +388,7 @@ class EngineConfig:
     # Once the prefill backlog drains, spans return to decode_span. At
     # most two decode programs compile (busy_span and decode_span), and a
     # prefill program finds at most two busy spans queued ahead of it.
-    # busy=4 measured best TTFT at ~5% req/s cost vs 16 on the 24-req
-    # burst (1.15s vs 1.39s p50); busy=1 stalls decode behind per-token
-    # dispatch latency when the backlog is long.
+    # No cell sets it or `adaptive_span` (D4).
     busy_span: int = 4
     adaptive_span: bool = True
     # Automatic prefix caching (vLLM APC analogue): full prompt pages are
@@ -927,7 +917,7 @@ class InferenceEngine:
         # telemetry). The serving layer stamps slo_role after construction
         # (llm.LLMServer: colocated/prefill/decode), so digest handles
         # resolve lazily on first observation; the enable switch resolves
-        # once here — the bench health suite gates the hot-path cost.
+        # once here.
         self.slo_role = "engine"
         self._slo_on = slo.enabled()
         self._slo: Dict[str, slo.Digest] = {}

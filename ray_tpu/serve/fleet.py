@@ -17,14 +17,14 @@ capacity or recovery decisions. `FleetController` closes the loop:
   evaluations — one alert burst cannot flap the fleet.
 - **Actuation backends** — a serve-mode fleet scales through
   `ServeController.set_target` (the coordinator's `_sync` picks up the
-  membership change); an in-process fleet (tier-1 tests, bench) scales
+  membership change); an in-process fleet (tier-1 tests) scales
   through injected `spawn_fn`/`retire_fn` callbacks plus the
   coordinator's add_worker/remove_worker graceful pick-set surgery.
 - **Live request resume** rides in the coordinator (disagg.open_stream):
   a decode replica dying mid-stream re-runs the request's remaining
   tokens on a healthy peer — the fleet's chaos story is that a replica
   SIGKILLed every N seconds costs a latency blip, never a failed
-  request (bench.py `fleet` suite: serve_fleet_failed_requests == 0).
+  request (tests/test_fleet.py `TestKillResume`: no stream fails).
 - **LoRA hot-swap** — `distribute_adapter` seals adapter weights into
   the object plane, pre-seeds every host over the `api.broadcast` relay
   tree, then pins them resident per replica; the coordinator's gossiped

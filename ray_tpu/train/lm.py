@@ -35,7 +35,7 @@ def make_optimizer(
     """factored=True swaps adamw for adafactor (factored second moments,
     no first moment): optimizer state shrinks from 2x params to ~O(rows +
     cols) — the standard TPU answer for fitting billion-param single-chip
-    state (T5's recipe), used by the llama-2b bench config. NOTE: the
+    state (T5's recipe), which `chip_smoke.py`'s train phase takes. NOTE: the
     factored path runs momentum-less and undecayed — b1/b2/weight_decay
     do not apply (adafactor's weight_decay_rate is a per-step
     multiplicative decay, not adamw's lr-scaled decoupled decay)."""

@@ -11,7 +11,8 @@ the live NodeAgent uses, with overflow delegated to the head's
 byte on the wire and every line of routing/merge/scheduling code is the
 production path.
 
-Measured as N grows (bench.py `scale` suite gates on these):
+Measured as N grows (tests/test_scale_sim.py and `make scale` hold the
+counts: zero failed requests, a promoted standby, no reconnect spike):
 
 - ``head_cpu_cores``       CPU consumed by head-side work (RPC dispatch,
                            health evaluation, overflow scheduling) per
@@ -364,7 +365,7 @@ def run_scale_sim(nodes: int = 32, nshards: int = 2, duration_s: float = 5.0,
                   pod_size: int = 8, hb_period_s: float = 0.5,
                   tasks_per_round: int = 2,
                   kill_shard: bool = False) -> Dict[str, Any]:
-    """Run one harness pass; returns the measurement row bench.py gates on."""
+    """Run one harness pass; returns its measurement row."""
     reconnects0 = _counter_total(_reconnects_total)
     redials0 = _counter_total(_redials_throttled)
     h = _Harness(nodes, nshards, pod_size, hb_period_s, tasks_per_round)

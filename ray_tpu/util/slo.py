@@ -19,9 +19,9 @@ the slices still inside the window, so a replica that degraded two
 minutes ago but recovered reads healthy now.
 
 `Digest.add` is lock-free by design: it is a handful of list-item
-increments under the GIL on the decode hot path (the bench health suite
-gates it at ≤2% tokens/s). A racing rotation can at worst misplace one
-update into an adjacent 10s slice — harmless for telemetry.
+increments under the GIL on the decode hot path. A racing rotation can at
+worst misplace one update into an adjacent 10s slice — harmless for
+telemetry.
 """
 
 from __future__ import annotations

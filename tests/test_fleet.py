@@ -189,27 +189,36 @@ class TestKillResume:
             co.close()
             pe.stop(), de1.stop(), de2.stop(), ref.stop()
 
-    def test_resume_storm_all_streams_survive(self, tiny):
-        """N concurrent streams on one replica, one death: every stream
-        resumes on the healthy peer and stays token-identical."""
+    @pytest.mark.parametrize("deaths", [1, 2])
+    def test_resume_storm_all_streams_survive(self, tiny, deaths):
+        """N concurrent streams in flight, the replicas under them dying
+        one after another (a healthy peer joins at the first death):
+        every stream resumes on a survivor and stays token-identical.
+        Zero failed requests through two deaths is the whole of what a
+        fleet owes a burst."""
         cfg, params = tiny
         pe = _engine(cfg, params)
-        de1 = _engine(cfg, params)
+        des = [_engine(cfg, params) for _ in range(deaths)]
         de2 = _engine(cfg, params, max_pages=96)
         ref = _engine(cfg, params)
-        mortal = _MortalWorker(de1, "mortal1")
+        mortals = [_MortalWorker(de, f"mortal1{i}")
+                   for i, de in enumerate(des)]
         healthy = EngineWorker(de2, "healthy1")
-        co = DisaggCoordinator([EngineWorker(pe, "prefill1")], [mortal],
+        co = DisaggCoordinator([EngineWorker(pe, "prefill1")], mortals,
                                {"small_blob_bytes": 0})
         try:
-            prompts = _prompts(cfg, (5, 9, 13), seed=11)
+            prompts = _prompts(cfg, (5, 9, 13, 7)[:2 + deaths], seed=11)
             wants = [ref.generate(p, max_tokens=10)["token_ids"]
                      for p in prompts]
             streams = [co.open_stream(p, max_tokens=10) for p in prompts]
             its = [ds.tokens() for ds in streams]
-            heads = [[next(it)] for it in its]  # all in flight on mortal
+            heads = [[next(it)] for it in its]  # all in flight on mortals
             co.add_worker("decode", healthy)
-            mortal.killed.set()
+            for i, mortal in enumerate(mortals):
+                if i:  # the survivors of one death are mid-stream again
+                    for head, it in zip(heads, its):
+                        head.append(next(it))
+                mortal.killed.set()
             outs, errs = {}, {}
 
             def drain(i):
@@ -226,9 +235,13 @@ class TestKillResume:
                 t.join(timeout=120.0)
             assert not errs, f"failed streams: {errs}"
             assert [outs[i] for i in range(len(wants))] == wants
+            assert all(ds.error is None and ds.finish_reason == "length"
+                       for ds in streams)
+            assert all(m.deaths >= 1 for m in mortals), "a kill hit nothing"
         finally:
             co.close()
-            pe.stop(), de1.stop(), de2.stop(), ref.stop()
+            for e in (pe, de2, ref, *des):
+                e.stop()
 
     def test_resume_disabled_propagates_death(self, tiny):
         cfg, params = tiny

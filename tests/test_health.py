@@ -128,6 +128,37 @@ class TestDigest:
         names = [s["name"] for s in slo.snapshot()]
         assert names == ["seen"]
 
+    def test_engine_requests_fill_the_ttft_and_e2e_digests(self):
+        """Every finished request of an engine is one sample of each
+        latency digest under the engine's role, and an engine whose switch
+        is off observes nothing: the alert rules read these counts."""
+        import jax
+
+        from ray_tpu.models import get_config, init_params
+        from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+
+        cfg = get_config("tiny-llama")
+        engine = InferenceEngine(
+            init_params(cfg, jax.random.PRNGKey(0)), cfg,
+            EngineConfig(max_batch_size=4, page_size=8, max_pages=64,
+                         max_seq_len=96, prefill_buckets=(16,)))
+
+        def counts():
+            return {s["name"]: s["count"] for s in slo.snapshot()
+                    if dict(s["tags"]) == {"role": "engine"}}
+
+        three_each = {"serve_ttft_seconds": 3, "serve_e2e_seconds": 3}
+        try:
+            assert engine._slo_on
+            for n in (3, 5, 7):
+                engine.generate(list(range(1, 1 + n)), max_tokens=4)
+            assert counts() == three_each
+            engine._slo_on = False
+            engine.generate([1, 2, 3], max_tokens=4)
+            assert counts() == three_each
+        finally:
+            engine.stop()
+
 
 # ---------------------------------------------------------------------------
 # core/health.py — rule parsing + rule engine

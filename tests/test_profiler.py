@@ -405,55 +405,3 @@ class TestHungWorkerE2E:
         assert pids["agent"] == os.getpid()
         with pytest.raises(KeyError):
             svc.profile_fetch(node="zzzz-no-such-node", kind="pids")
-
-
-# ---------------------------------------------------------------------------
-# Bench history ledger + regression report (satellite: BENCH_HISTORY.jsonl)
-# ---------------------------------------------------------------------------
-
-class TestBenchHistory:
-    def _doc(self, metrics):
-        return {"meta": {"suite": "test"}, "metrics": metrics}
-
-    def test_append_only_history_and_regression_flag(
-            self, tmp_path, monkeypatch, capsys):
-        import bench
-
-        monkeypatch.setattr(bench, "__file__", str(tmp_path / "bench.py"))
-        hist = tmp_path / "BENCH_HISTORY.jsonl"
-
-        monkeypatch.setattr(bench, "_SUMMARY",
-                            {"tok_per_s": 100.0, "overhead_pct": 1.0})
-        monkeypatch.setattr(bench, "_DIRECTION",
-                            {"tok_per_s": False, "overhead_pct": True})
-        bench._append_history(self._doc(dict(bench._SUMMARY)))
-        err = capsys.readouterr().err
-        assert "no previous history row" in err
-        assert len(hist.read_text().splitlines()) == 1
-
-        # second run: throughput collapses 50% and overhead doubles — both
-        # directions of "worse" must be flagged
-        monkeypatch.setattr(bench, "_SUMMARY",
-                            {"tok_per_s": 50.0, "overhead_pct": 2.0})
-        bench._append_history(self._doc(dict(bench._SUMMARY)))
-        err = capsys.readouterr().err
-        assert "REGRESSION" in err
-        assert "tok_per_s" in err and "overhead_pct" in err
-
-        rows = [json.loads(l) for l in hist.read_text().splitlines()]
-        assert len(rows) == 2  # append-only: the first row is untouched
-        assert rows[0]["metrics"]["tok_per_s"] == 100.0
-        assert rows[1]["metrics"]["tok_per_s"] == 50.0
-
-    def test_improvement_is_not_flagged(self, tmp_path, monkeypatch, capsys):
-        import bench
-
-        monkeypatch.setattr(bench, "__file__", str(tmp_path / "bench.py"))
-        monkeypatch.setattr(bench, "_SUMMARY", {"tok_per_s": 100.0})
-        monkeypatch.setattr(bench, "_DIRECTION", {"tok_per_s": False})
-        bench._append_history(self._doc({"tok_per_s": 100.0}))
-        monkeypatch.setattr(bench, "_SUMMARY", {"tok_per_s": 200.0})
-        bench._append_history(self._doc({"tok_per_s": 200.0}))
-        err = capsys.readouterr().err
-        assert "REGRESSION" not in err
-        assert "no regressions" in err

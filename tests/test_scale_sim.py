@@ -348,7 +348,7 @@ class TestDeltaEncoding:
 
 
 # --------------------------------------------------------------------------
-# the harness itself, smoke-sized (full N=128 sweep lives in bench.py)
+# the harness itself, smoke-sized (`make scale` runs it at N=32)
 # --------------------------------------------------------------------------
 
 

@@ -161,7 +161,7 @@ class TestLMTrainStep:
 
 
 def test_factored_optimizer_learns(cpu_mesh_devices):
-    """make_optimizer(factored=True) — the llama-2b bench recipe — must
+    """make_optimizer(factored=True) — chip_smoke.py's train recipe — must
     actually descend, guarding the two adafactor traps (parameter-scale
     multipliers and per-step weight_decay_rate, both of which froze
     learning when first wired)."""
