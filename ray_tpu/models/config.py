@@ -25,7 +25,7 @@ from typing import Optional, Tuple
 # what a layer's mixer can be when a StackConfig's `layer_kinds` names them
 # (models/stack.py); a plain ModelConfig's layers are all "attn"
 LAYER_KINDS = ("attn", "conv", "mamba", "window", "full", "gmu", "cross",
-               "gdn", "mla2", "swa")
+               "gdn", "mla2", "swa", "ssd")
 # the second halves' gated activations: down(act(gate x) * (up x))
 GATED_ACTIVATIONS = ("swiglu", "reglu")
 # a token's row in a pool of latents: the latent and the shared rotary key
@@ -99,6 +99,12 @@ class ModelConfig:
     n_dense_layers = 0
     conv_tail = (0, 0, 0)
     gdn_dims = (0, 0, 0, 0)
+    ssd_dims = (0, 0, 0, 0, 0)
+    # the four scalars of a StackConfig, at the values that emit nothing
+    embedding_multiplier = 1.0
+    attention_multiplier = None
+    residual_multiplier = 1.0
+    logits_scaling = 1.0
     # every expert there is is held and computes: none lives on another
     # chip, none is the identity
     experts_first = 0
@@ -243,6 +249,8 @@ class StackConfig(ModelConfig):
     ssm_state: int = 16
     ssm_conv: int = 4
     ssm_dt_rank: int = 0
+    ssm_heads: int = 0        # "ssd": heads (of ssm_inner / ssm_heads lanes)
+    ssm_groups: int = 1       # "ssd": groups of heads that share B and C
     conv_taps: int = 3        # the "conv" and "gdn" kinds' kernel length
     qk_norm: bool = False     # "attn": RMSNorm each head of q and k
     qk_norm_whole: bool = False  # ... or all of q's (k's) heads as one
@@ -280,6 +288,13 @@ class StackConfig(ModelConfig):
     qk_rope_dim: int = 0
     v_head_dim: int = 0
     mla_scale_lora: bool = False
+    # 1.0 / None emit nothing: x = embedding_multiplier * E[token]; scores
+    # q k^T * attention_multiplier (None: head_dim ** -0.5); x + Mix * r and
+    # x + FFN * r with r = residual_multiplier; logits / logits_scaling
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     def __post_init__(self) -> None:
         kinds = tuple(self.layer_kinds)
@@ -305,6 +320,12 @@ class StackConfig(ModelConfig):
                                    and self.gdn_value_dim):
             raise ValueError("gdn layers need `gdn_heads`, `gdn_key_dim` "
                              "and `gdn_value_dim`")
+        if "ssd" in kinds and not (
+                self.ssm_heads and self.ssm_groups and self.ssm_inner
+                and self.ssm_inner % self.ssm_heads == 0
+                and self.ssm_heads % self.ssm_groups == 0):
+            raise ValueError("ssd layers need `ssm_heads` that divide "
+                             "`ssm_inner` and `ssm_groups` that divide them")
         # ONE pool and one array of conv tails: their rows are one shape
         # ("attn" beside "swa" is two page spaces of ONE row shape)
         for a, b, what in (("attn", "full", "layers that cache keys"),
@@ -312,7 +333,10 @@ class StackConfig(ModelConfig):
                            ("swa", "window", "window layers' keys"),
                            ("conv", "mamba", "convolution tails"),
                            ("conv", "gdn", "convolution tails"),
-                           ("mamba", "gdn", "convolution tails")):
+                           ("mamba", "gdn", "convolution tails"),
+                           ("conv", "ssd", "convolution tails"),
+                           ("mamba", "ssd", "convolution tails"),
+                           ("gdn", "ssd", "convolution tails")):
             if a in kinds and b in kinds:
                 raise ValueError(f"{a!r} and {b!r} layers in one stack: "
                                  f"two shapes of {what}")
@@ -361,9 +385,9 @@ class StackConfig(ModelConfig):
     @property
     def has_state(self) -> bool:
         """Some layer keeps per-sequence state that is not keys and values
-        in THE pool: conv tails, scan state, a delta-rule state matrix, a
-        window layer's ring."""
-        return bool({"mamba", "conv", "window", "gdn", "swa"}
+        in THE pool: conv tails, scan state, a delta-rule or state-space
+        state matrix, a window layer's ring."""
+        return bool({"mamba", "conv", "window", "gdn", "swa", "ssd"}
                     & set(self.layer_kinds))
 
     @property
@@ -402,10 +426,15 @@ class StackConfig(ModelConfig):
     @property
     def conv_tail(self) -> Tuple[int, int, int]:
         """(layers, rows, width) of the convolution tails a sequence keeps:
-        the last taps - 1 inputs of each mamba, conv or gdn layer's
-        convolution (a gdn layer's runs over its q, k and v channels)."""
+        the last taps - 1 inputs of each mamba, conv, gdn or ssd layer's
+        convolution (a gdn layer's runs over its q, k and v channels, an
+        ssd layer's over x and every group's B and C)."""
         if "conv" in self.layer_kinds:
             return self.count("conv"), self.conv_taps - 1, self.d_model
+        if "ssd" in self.layer_kinds:
+            _, _, _, N, G = self.ssd_dims
+            return (self.count("ssd"), self.ssm_conv - 1,
+                    self.ssm_inner + 2 * G * N)
         if "gdn" in self.layer_kinds:
             _, H, dk, dv = self.gdn_dims
             return self.count("gdn"), self.conv_taps - 1, H * (2 * dk + dv)
@@ -418,6 +447,15 @@ class StackConfig(ModelConfig):
         layer (ops/gdn.py `state_shape` lays them out)."""
         return (self.count("gdn"), self.gdn_heads, self.gdn_key_dim,
                 self.gdn_value_dim)
+
+    @property
+    def ssd_dims(self) -> Tuple[int, int, int, int, int]:
+        """(layers, heads, head size, state size, groups) of the
+        state-space state a sequence keeps: a float32 [state, head] matrix
+        a head and ssd layer (ops/ssd.py `state_shape` lays them out)."""
+        return (self.count("ssd"), self.ssm_heads,
+                self.ssm_inner // max(self.ssm_heads, 1), self.ssm_state,
+                self.ssm_groups)
 
     @property
     def pool_heads(self) -> int:
@@ -455,6 +493,13 @@ class StackConfig(ModelConfig):
                     + 2 * D * Hg * dv + 2 * D * Hg + 2 * Hg + dv)
         if kind == "conv":
             return D * 3 * D + self.conv_taps * D + D * D
+        if kind == "ssd":
+            _, Hs, _, _, G = self.ssd_dims
+            conv = Di + 2 * G * N
+            # z, x, B, C and dt in; taps and their bias; dt_bias, A_log, D;
+            # the gated norm; the out-projection
+            return (D * (Di + conv + Hs) + (self.ssm_conv + 1) * conv
+                    + 3 * Hs + Di + Di * D)
         if kind == "mla2":
             ql, kl = self.q_lora_rank, self.kv_lora_rank
             qk = self.qk_nope_dim + self.qk_rope_dim
@@ -717,6 +762,44 @@ register(StackConfig(
     rope_theta=10000.0, tie_embeddings=False, norm_eps=1e-6,
     num_experts=8, num_selected_experts=3, capacity_factor=8 / 3,
     layer_kinds=_window_full_kinds(8), window=16, router_input="layer",
+))
+
+def _granite_hybrid_kinds(n_layers: int) -> Tuple[str, ...]:
+    """granite-4.0-h-micro's published `layer_types`, cut to its first
+    layers: attention at 5, 15, 25, 35."""
+    return tuple("attn" if l % 10 == 5 else "ssd" for l in range(n_layers))
+
+
+register(StackConfig(
+    name="granite-4.0-h-micro",
+    # ibm-granite/granite-4.0-h-micro: 3.19 B parameters; 36 Mamba-2 layers
+    # (64 heads of 64, one scalar decay each, a 128 x 64 state matrix a
+    # head, B and C shared by all heads) and 4 of GQA (32 / 8 heads of 64)
+    # with no positional encoding, a dense SwiGLU of 8192 in every layer,
+    # tied table, and Granite's four scalars
+    vocab_size=100352,
+    d_model=2048, n_layers=40, n_heads=32, n_kv_heads=8, head_dim=64,
+    d_ff=8192, max_seq_len=131072,
+    norm="rmsnorm", activation="swiglu", positional="none",
+    tie_embeddings=True, norm_eps=1e-5,
+    layer_kinds=_granite_hybrid_kinds(40), ssm_inner=4096, ssm_state=128,
+    ssm_conv=4, ssm_heads=64, ssm_groups=1,
+    embedding_multiplier=12.0, attention_multiplier=0.015625,
+    residual_multiplier=0.22, logits_scaling=8.0,
+))
+
+register(StackConfig(
+    name="tiny-granite-hybrid",
+    # the same stack's shape at toy widths: attention at 5, two groups
+    vocab_size=512,
+    d_model=64, n_layers=8, n_heads=8, n_kv_heads=2, head_dim=8, d_ff=128,
+    max_seq_len=128, dtype="float32", remat=False,
+    norm="rmsnorm", activation="swiglu", positional="none",
+    tie_embeddings=True, norm_eps=1e-5,
+    layer_kinds=_granite_hybrid_kinds(8), ssm_inner=128, ssm_state=16,
+    ssm_conv=4, ssm_heads=8, ssm_groups=2,
+    embedding_multiplier=12.0, attention_multiplier=0.015625,
+    residual_multiplier=0.22, logits_scaling=8.0,
 ))
 
 register(StackConfig(

@@ -30,6 +30,13 @@ their sublayers, `x + norm(Mix(x))` and `x + norm(FFN(x))`. Mix is one of
           sequence decayed and corrected a token at a time (ops/gdn.py),
           the output RMS-normalised per head and gated; conv tail and
           state matrix per sequence
+  ssd     scalar-decay state space (Mamba-2): [z ; xBC] = h W_in and
+          dt = h W_dt, a causal convolution with bias and SiLU over x, B
+          and C side by side, per head ONE decay exp(dt A) a token and a
+          [state, head] matrix a sequence (ops/ssd.py: the chunked dual
+          form for a sequence, one step for the live slots), the output
+          plus D x gated by silu(z) and THEN RMS-normalised over a group's
+          lanes; conv tail and state matrix per sequence
   mla2    ONE layer that is two blocks and a shortcut: for i in (0, 1):
           x += MLA_i(norm(x)); b = norm(x); if i == 0: s = Experts(b);
           x += FFN_i(b); and at the end x += s, so the experts' product
@@ -118,6 +125,7 @@ from ..ops import (
 )
 from ..ops.gdn import gdn_chunk, gdn_step, state_shape
 from ..ops.rope import rope_frequencies
+from ..ops.ssd import ssd_chunk, ssd_step, state_shape as ssd_state_shape
 from ..ops.ssm import ssm_scan, ssm_step
 from ..parallel.sharding import _current_mesh
 from .config import ModelConfig
@@ -141,7 +149,8 @@ from .transformer import (
 Params = Dict[str, Any]
 _F32 = jnp.float32
 # kinds that own rows of state arrays or pools, counted as layers go by
-_COUNTED = ("attn", "conv", "mamba", "window", "full", "gdn", "mla2", "swa")
+_COUNTED = ("attn", "conv", "mamba", "window", "full", "gdn", "mla2", "swa",
+            "ssd")
 # an expert layer's leaves that a step reads where they lie (`run_stack`)
 _EXPERT_LEAVES = ("w_in", "w_gate", "w_out")
 
@@ -208,6 +217,17 @@ def layer_shapes(cfg: ModelConfig, kind: str,
     elif kind == "conv":
         out.update(c_in=((D, 3 * D), "w"), c_conv=((cfg.conv_taps, D), "w"),
                    c_out=((D, D), "out"))
+    elif kind == "ssd":
+        # the published in-projection as two leaves: z and x, B, C (the
+        # convolution's channels) in one, dt in another whose product
+        # stays float32
+        _, Hs, _, _, G = cfg.ssd_dims
+        conv = Di + 2 * G * N
+        out.update(s_in=((D, Di + conv), "w"), s_dt=((D, Hs), "w"),
+                   s_conv=((K, conv), "w"), s_conv_b=((conv,), "zero"),
+                   s_dt_b=((Hs,), "zero"), s_A_log=((Hs,), "zero"),
+                   s_D=((Hs,), "one"), s_norm=((Di,), "one"),
+                   s_out=((Di, D), "out"))
     elif kind == "mamba":
         out.update(m_in=((D, 2 * Di), "w"), m_conv=((K, Di), "w"),
                    m_conv_b=((Di,), "zero"), m_x=((Di, R + 2 * N), "w"),
@@ -291,7 +311,9 @@ def new_request_state(cfg: ModelConfig, batch: int, dtype) -> Params:
     [M,B,K-1,Di] where there are mamba, conv or gdn layers
     (`cfg.conv_tail`), scan state [M,B,N,Di] (float32) where there are
     mamba layers, the delta-rule state matrices [G,B,dk,H*dv] (float32;
-    ops/gdn.py lays them out) where there are gdn layers, and the last
+    ops/gdn.py lays them out) where there are gdn layers, the state-space
+    state matrices [S,B,N,H*P] (float32; ops/ssd.py lays them out) where
+    there are ssd layers, and the last
     `window` keys and values of every window layer [W,B,window,KVH,D]
     where there are those. Zeros are a sequence's start; the one-block
     models have none (the empty tree). Where a token's choice of experts
@@ -312,6 +334,10 @@ def new_request_state(cfg: ModelConfig, batch: int, dtype) -> Params:
         layers, heads, dk, dv = cfg.gdn_dims
         out.update(
             gdn=jnp.zeros(state_shape(layers, batch, heads, dk, dv), _F32))
+    if cfg.ssd_dims[0]:
+        layers, heads, head_dim, d_state, _ = cfg.ssd_dims
+        out.update(ssd=jnp.zeros(
+            ssd_state_shape(layers, batch, heads, head_dim, d_state), _F32))
     if NW:
         kv = (NW, batch, cfg.window, cfg.pool_heads, cfg.pool_dim)
         out.update(wk=jnp.zeros(kv, dtype), wv=jnp.zeros(kv, dtype))
@@ -345,11 +371,11 @@ def new_engine_state(cfg: ModelConfig, batch: int, page_size: int,
 def install_state(state: Params, rs: Params, slot, length,
                   cfg: ModelConfig, page_size: int) -> Params:
     """A prefilled sequence of `length` tokens takes decode slot `slot`:
-    its conv tails, scan state and delta-rule state overwrite the slot's
-    (whatever the last occupant left), and its last `window` keys go to the
-    slot's ring, each at the place its position has there."""
+    its conv tails and its scan, delta-rule and state-space state overwrite
+    the slot's (whatever the last occupant left), and its last `window` keys
+    go to the slot's ring, each at the place its position has there."""
     out = dict(state)
-    for name in ("conv", "ssm", "gdn"):
+    for name in ("conv", "ssm", "gdn", "ssd"):
         if name in state:
             out[name] = jax.lax.dynamic_update_slice_in_dim(
                 state[name], rs[name].astype(state[name].dtype), slot, 1)
@@ -408,6 +434,8 @@ class _Mode:
         layers."""
         self.at = self.positions(tokens.shape[1])
         x, self.rope = _prologue(params, tokens, self.cfg, self.at, self.mesh)
+        if self.cfg.embedding_multiplier != 1.0:
+            x = x * self.cfg.embedding_multiplier
         if self.cfg.window_paged:  # rotary whatever the "attn" layers are
             self.rope = rope_frequencies(
                 self.cfg.hdim, self.cfg.max_seq_len, self.cfg.rope_theta)
@@ -537,6 +565,14 @@ class Seq(_Mode):
         if self.keep:
             carry = {**carry, "gdn": carry["gdn"].at[gi].set(s1)}
         return o, carry
+
+    def ssd(self, carry, si, x, dt, A, Bm, Cm):
+        s0 = (carry["ssd"][si] if self.chunk is not None else jnp.zeros(
+            ssd_state_shape(1, x.shape[0], *self.cfg.ssd_dims[1:4])[1:], _F32))
+        y, s1 = ssd_chunk(x, dt, A, Bm, Cm, s0)
+        if self.keep:
+            carry = {**carry, "ssd": carry["ssd"].at[si].set(s1)}
+        return y, carry
 
     # -- attention
     def attend_window(self, carry, wi, q, k, v, scale):
@@ -728,6 +764,11 @@ class Decode(_Mode):
                             g[:, 0], beta[:, 0], self.live)
         return o[:, None], {**carry, "gdn": state}
 
+    def ssd(self, carry, si, x, dt, A, Bm, Cm):
+        y, state = ssd_step(carry["ssd"], si, x[:, 0], dt[:, 0], A, Bm[:, 0],
+                            Cm[:, 0], self.live)
+        return y[:, None], {**carry, "ssd": state}
+
     def attend_window(self, carry, wi, q, k, v, scale):
         page = jnp.take_along_axis(
             self.ring_table, ((self.pos // self.ps) % self.ring)[:, None], 1)
@@ -911,6 +952,39 @@ def _gdn(h, lp, cfg, gi, mode, carry):
                       lp["d_out"].astype(dtype)), carry
 
 
+def _ssd(h, lp, cfg, si, mode, carry):
+    """The scalar-decay state-space mixer: the state is the mode's conv
+    tail (over x and every group's B and C side by side) and its
+    state-space state matrix."""
+    dtype = h.dtype
+    B, T, _ = h.shape
+    _, H, P, N, G = cfg.ssd_dims
+    Di = H * P
+    zxbc = jnp.einsum("btd,de->bte", h, lp["s_in"].astype(dtype))
+    z, xbc = zxbc[..., :Di], zxbc[..., Di:]
+    ext, carry = mode.conv(carry, si, xbc)
+    xbc = jax.nn.silu(_taps(ext, lp["s_conv"], T)
+                      + lp["s_conv_b"].astype(_F32))
+    x = xbc[..., :Di].reshape(B, T, H, P)
+    Bm, Cm = (a.reshape(B, T, G, N)
+              for a in jnp.split(xbc[..., Di:], 2, axis=-1))
+    dt = jax.nn.softplus(
+        jnp.einsum("btd,de->bte", h, lp["s_dt"].astype(dtype),
+                   preferred_element_type=_F32) + lp["s_dt_b"].astype(_F32))
+    valid = mode.valid(T)
+    if valid is not None:
+        dt = jnp.where(valid, dt, 0.0)  # padding leaves the state alone
+    y, carry = mode.ssd(carry, si, x, dt,
+                        -jnp.exp(lp["s_A_log"].astype(_F32)), Bm, Cm)
+    y = (y + lp["s_D"].astype(_F32)[:, None] * x).reshape(B, T, Di)
+    # the gate first, then the norm, over the lanes of a group's heads
+    y = (y * jax.nn.silu(z.astype(_F32))).reshape(B, T, G, Di // G)
+    y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + cfg.norm_eps)
+    y = y.reshape(B, T, Di) * lp["s_norm"].astype(_F32)
+    return jnp.einsum("bte,ed->btd", y.astype(dtype),
+                      lp["s_out"].astype(dtype)), carry
+
+
 def _turn(x, at, theta):
     """x [B,T,heads,R] turned to the tokens' positions `at` ([B,T]; None:
     0..T-1): interleaved pairs (2i, 2i+1) by at * theta^(-2i/R), float32
@@ -1067,7 +1141,9 @@ def _attn(h, lp, cfg, idx, mode, carry, window=False):
     the last `cfg.window` in the window page space."""
     q, k, v = _qkv(h, lp, cfg, mode.rope, mode.at, True if window else None)
     attend = mode.attend_paged_window if window else mode.attend_full
-    o, carry = attend(carry, idx, q, k, v, cfg.hdim ** -0.5)
+    scale = (cfg.hdim ** -0.5 if cfg.attention_multiplier is None
+             else cfg.attention_multiplier)
+    o, carry = attend(carry, idx, q, k, v, scale)
     return jnp.einsum("bthk,hkd->btd", o.astype(h.dtype),
                       lp["wo"].astype(h.dtype)), carry
 
@@ -1120,6 +1196,8 @@ def _layer(x, lp, cfg, kind, half, layer, idx, mode, carry):
             o, carry = _attn(h, lp, cfg, idx, mode, carry, kind == "swa")
         elif kind == "gdn":
             o, carry = _gdn(h, lp, cfg, idx, mode, carry)
+        elif kind == "ssd":
+            o, carry = _ssd(h, lp, cfg, idx, mode, carry)
         elif kind == "conv":
             o, carry = _short_conv(h, lp, cfg, idx, mode, carry)
         elif kind == "mamba":
@@ -1130,6 +1208,8 @@ def _layer(x, lp, cfg, kind, half, layer, idx, mode, carry):
             o, carry = _attention(h, lp, cfg, kind, layer, idx, mode, carry)
         if cfg.post_norm:
             o = _norm(o, lp["ln1"], lp.get("ln1_b"), cfg)
+        if cfg.residual_multiplier != 1.0:
+            o = o * cfg.residual_multiplier
         x = x + o
     if half == "moe":
         return _ffn_half(x, lp, cfg, True, lambda h: _experts(
@@ -1142,8 +1222,8 @@ def run_stack(layers, x, cfg: ModelConfig, mode, carry):
     one entry a segment of `cfg.segments()` (a tuple with one stacked dict
     per layer of the period), or the one-block models' stacked dict, their
     one segment. A segment of r > 1 periods is one `lax.scan`; which attn,
-    conv, mamba, window, full or gdn layer a layer is (its row in the state arrays
-    and pools) is counted from the layers before it."""
+    conv, mamba, window, full, gdn or ssd layer a layer is (its row in the
+    state arrays and pools) is counted from the layers before it."""
     if isinstance(layers, dict):
         layers = [(layers,)]
     seen = dict.fromkeys(_COUNTED, 0)

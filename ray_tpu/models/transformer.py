@@ -669,6 +669,8 @@ def _ffn_half(x, lp, cfg, moe=None, experts=None):
             y, aux = _dense_ffn(h, lp, cfg), jnp.zeros((), jnp.float32)
         if cfg.post_norm:
             y = _norm(y, lp["ln2"], lp.get("ln2_b"), cfg)
+        if cfg.residual_multiplier != 1.0:
+            y = y * cfg.residual_multiplier
         return x + y, aux
 
 
@@ -754,6 +756,8 @@ def _lm_head(x, params, cfg) -> jax.Array:
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
         logits = jnp.einsum("btd,dv->btv", x.astype(jnp.float32),
                             head.astype(jnp.float32))
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
         if cfg.logits_softcap:
             logits = cfg.logits_softcap * jnp.tanh(logits / cfg.logits_softcap)
         return constrain(logits, ("batch", "seq", "vocab"))
@@ -772,6 +776,8 @@ def _head_logits(x, pick, params, cfg, einsum: str):
     else:
         logits = jnp.einsum(einsum, pick(x).astype(jnp.float32),
                             head.astype(jnp.float32))
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     if cfg.logits_softcap:
         logits = cfg.logits_softcap * jnp.tanh(logits / cfg.logits_softcap)
     return logits

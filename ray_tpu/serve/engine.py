@@ -146,7 +146,8 @@ _m_deferred = Counter(
     "serve_requests_deferred",
     "Requests parked at admission, by reason (no_pages: the pool could "
     "not hold prompt + max_tokens; no_window_pages: the window page space "
-    "could not hold its ring).")
+    "could not hold its ring; no_state_room: the sequences that wait for a "
+    "decode slot already hold all the state of their own that they may).")
 _m_front = Histogram(
     "serve_front_seconds",
     "What the serve front adds around the engine, by leg (inbound: the "
@@ -191,7 +192,8 @@ _m_state_slots = Counter(
 _m_state_slot_steps = Counter(
     "serve_recurrent_state_slot_steps",
     "Decode slots x steps dispatched by a model whose layers keep recurrent "
-    "state per slot (scan state, delta-rule state matrices), by state "
+    "state per slot (scan state, delta-rule and state-space state "
+    "matrices), by state "
     "(live: the slot's state belongs to a sequence; held: every slot the "
     "engine holds state for, max_batch_size): live over held is the share "
     "of that state a step had to touch.")
@@ -232,6 +234,7 @@ _choices_zero = _m_moe_choices.labels(kind="zero")
 _choices_held = _m_moe_choices.labels(kind="held")
 _deferred_no_pages = _m_deferred.labels(reason="no_pages")
 _deferred_no_window_pages = _m_deferred.labels(reason="no_window_pages")
+_deferred_no_state_room = _m_deferred.labels(reason="no_state_room")
 _front_inbound = _m_front.labels(leg="inbound")
 _front_outbound = _m_front.labels(leg="outbound")
 
@@ -513,6 +516,9 @@ class Request:
     # prompt, not prompt+max_tokens.
     prefill_only: bool = False
     _kv_export: Optional[Dict[str, Any]] = None
+    # admitted and not yet in a slot: its state (`stack.new_request_state`)
+    # lies outside the slots' (engine `_states_out` counts these)
+    _state_out: bool = False
     # streamed KV export (disaggregated serving): when set on a
     # prefill_only request, KV frames are pushed to this callable as
     # prefill commits them (page-window slices of the bucketed row cache,
@@ -839,6 +845,10 @@ class InferenceEngine:
         self._window_allocator = (
             PageAllocator(engine_cfg.max_window_pages) if self._ring else None)
         self.state = self._new_state()
+        # what the engine holds beside THE pool, whatever the slots hold:
+        # per-slot state (tails, scan, delta-rule and state-space state)
+        # and the window layers' pools
+        self._state_bytes = _tree_bytes(self.state)
         # a sequence's start, shared by every chunked prompt's first
         # chunk (never donated: a chunk hands back a new state)
         self._request_start = stack.new_request_state(
@@ -876,6 +886,21 @@ class InferenceEngine:
             # value both, and every program hands the empty tree through
             self.v_pages = (None if model_cfg.latent_cache
                             else jnp.zeros(pool.shape, pool.dtype))
+        # how many sequences may hold such a state outside the slots
+        # (prefilled and waiting for one, or in the chunk queue): what the
+        # device has left now that it holds the weights, the pools and the
+        # slots' state, HALVED (the other half is the programs' temporaries:
+        # a bucket program's padded rows make state too), over one
+        # sequence's state; admission parks the next one
+        # (`_admit_for_prefill`). Pages bound the sequences in flight where
+        # keys are most of what one holds; a state of tens of MB a sequence
+        # fills the chip long before the pool runs out (chip, PR 45: 72 MB
+        # each, out of memory at 1.5 x the knee). None: a backend that
+        # keeps no count of its memory (the CPU), or no such state
+        held = _tree_bytes(self._request_start)
+        free = _device_free_bytes(self.k_pages) if held else None
+        self._state_room = None if free is None else max(1, free // 2 // held)
+        self._states_out = 0  # such sequences now; under _alloc_lock
         self.allocator = PageAllocator(P)
         # off by derivation where layers keep recurrent state: a page hit
         # without the state at that boundary would be wrong
@@ -1930,6 +1955,7 @@ class InferenceEngine:
             self._slo_digest("serve_e2e_seconds").add(
                 req.finished_at - req.submitted_at)
         self._forget(req)
+        self._state_in(req)
         for tok in req._held:  # flush the stream hold-back (post-strip)
             req._emit(tok)
         req._held.clear()
@@ -2139,6 +2165,9 @@ class InferenceEngine:
                 self.allocator.unpromise(pages.promised)
                 self._window_allocator.unpromise(pages.window_promised)
             waiting, self._waiting = self._waiting, []
+        self._requeue(waiting)
+
+    def _requeue(self, waiting: "list[Request]") -> None:
         now = tracing.now_ns() if waiting else 0
         for w in waiting:
             w.enter_stage("pending", now)
@@ -2199,7 +2228,10 @@ class InferenceEngine:
                 shared = self.prefix.lookup_acquire(req.prompt, C,
                                                     hashes=hashes)
             short = _deferred_no_pages
-            if not self._ring:
+            if (self._state_room is not None
+                    and self._states_out >= self._state_room):
+                pages, short = None, _deferred_no_state_room
+            elif not self._ring:
                 pages = self._alloc_with_reclaim(n_pages - len(shared))
             # two page spaces: a promise in both or in neither, and the
             # pages themselves as the sequence grows (`_grow`). The ring is
@@ -2230,6 +2262,8 @@ class InferenceEngine:
                 cancelled = False
                 if shared:
                     pages = shared + pages
+                req._state_out = True
+                self._states_out += 1
         if cancelled:
             self._finish_request(req, "cancelled")
             return None
@@ -2253,6 +2287,21 @@ class InferenceEngine:
             )
             return None
         return pages, T, bucket, 0
+
+    def _state_in(self, req: Request) -> None:
+        """The sequence holds no state outside the slots any more (a slot
+        took it, or the request ended before one did): one more may be
+        admitted, and those parked are asked again."""
+        if not req._state_out:
+            return
+        waiting: "list[Request]" = []
+        with self._alloc_lock:
+            req._state_out = False
+            if (self._state_room is not None
+                    and self._states_out >= self._state_room):
+                waiting, self._waiting = self._waiting, []  # parked for it
+            self._states_out -= 1
+        self._requeue(waiting)
 
     def _prefill_batch(self, reqs: List[Request]) -> None:
         """Admit + prefill a drained batch. Never raises: each request
@@ -2515,6 +2564,7 @@ class InferenceEngine:
                     self.state, {n: cache[n] for n in self.state},
                     jnp.int32(self.slots.index(slot)), jnp.int32(T))
                 _m_state_slots.inc()
+            self._state_in(req)
             if self.prefix is not None:
                 # the prompt's full pages are now valid: offer them to the
                 # cache so later prompts sharing the prefix skip prefill
@@ -3022,7 +3072,7 @@ class InferenceEngine:
         self._tps_steps += n_active * steps
         _slot_active.inc(n_active * steps)
         _slot_empty.inc((self.ecfg.max_batch_size - n_active) * steps)
-        if "ssm" in self.state or "gdn" in self.state:
+        if {"ssm", "gdn", "ssd"} & set(self.state):
             _state_live.inc(n_active * steps)
             _state_held.inc(self.ecfg.max_batch_size * steps)
 
@@ -3406,6 +3456,8 @@ class InferenceEngine:
             **({"window_ring_pages": self._ring,
                 "free_window_pages": free_window} if self._ring else {}),
             "free_pages": free_pages + prefix.get("reusable_pages", 0),
+            **({"state_bytes": self._state_bytes,
+                "state_room": self._state_room} if self.state else {}),
             **prefix,
             "steps": self._step_count,
             "weights_version": self.weights_version,
@@ -3432,6 +3484,19 @@ class InferenceEngine:
         span in flight read back and committed (`_loop`)."""
         self._stop.set()
         self._work.set()  # wake the decode thread so it observes _stop
+
+
+def _tree_bytes(tree) -> int:
+    return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
+
+
+def _device_free_bytes(tree) -> Optional[int]:
+    """What the device that holds `tree` has left (the fullest of them, for
+    a sharded tree), or None where the backend keeps no count."""
+    stats = [d.memory_stats() for d in jax.tree.leaves(tree)[0].devices()]
+    if not all(st and "bytes_limit" in st for st in stats):
+        return None
+    return min(st["bytes_limit"] - st["bytes_in_use"] for st in stats)
 
 
 def _kv_layer_groups(L: int, groups: int = 4) -> List[tuple]:

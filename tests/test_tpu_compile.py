@@ -31,6 +31,7 @@ from ray_tpu.ops import (
     pool_shape,
     rms_norm,
 )
+from ray_tpu.ops.ssd import ssd_chunk, ssd_step
 
 # Llama-3-8B head geometry, the engine's page size, bf16
 H, KVH, D, PAGE, D_MODEL = 32, 8, 128, 16, 4096
@@ -116,6 +117,16 @@ GH, GK, GV, F32 = 30, 96, 192, jnp.float32
 def _gdn_operands(*lead):
     return [((*lead, GH, GK), F32), ((*lead, GH, GK), F32),
             ((*lead, GH, GV), F32), ((*lead, GH), F32), ((*lead, GH), F32)]
+
+
+# the scalar-decay state space at its published sizes: 64 heads of 64, a
+# [128, 64] state matrix each, one group, 64 slots of 36 layers (ops/ssd.py)
+SH, SP, SN = 64, 64, 128
+
+
+def _ssd_operands(*lead):
+    return [((*lead, SH, SP), F32), ((*lead, SH), F32), ((SH,), F32),
+            ((*lead, 1, SN), F32), ((*lead, 1, SN), F32)]
 
 
 MLA_H, MLA_W, MLA_V = 64, 640, 512
@@ -240,6 +251,17 @@ CASES = {
         64, 2560, 768, jax.nn.relu, rows=64),
     "moe_groups_64_experts_2560x768_reglu_512_rows": _expert_groups(
         64, 2560, 768, jax.nn.relu, rows=512),
+    # the engine's chunk as ONE block of the dual form, and its buckets
+    "ssd_chunk_t256": (ssd_chunk, _ssd_operands(1, 256)
+                       + [((1, SN, SH * SP), F32)], 1),
+    "ssd_chunk_t128": (ssd_chunk, _ssd_operands(1, 128)
+                       + [((1, SN, SH * SP), F32)], 1),
+    "ssd_chunk_t64": (ssd_chunk, _ssd_operands(1, 64)
+                      + [((1, SN, SH * SP), F32)], 1),
+    "ssd_step_b64": (
+        lambda st, *a: ssd_step(st, LAYER, *a),
+        [((36, 64, SN, SH * SP), F32)] + _ssd_operands(64)
+        + [((64,), jnp.bool_)], 1),
     "rms_norm_2048x4096": (
         rms_norm, [((2048, D_MODEL), BF16), ((D_MODEL,), BF16)], 1),
 }
@@ -588,3 +610,75 @@ def test_the_window_and_full_cells_programs_hold_two_page_spaces(
     assert not re.search(
         r"= bf16\[(1,)?64,(2560,768|768,2560)\]\S* "
         r"(copy|dynamic-slice|slice|fusion)\(", text)
+
+
+@pytest.mark.parametrize("program", ["decode_span_8", "chunk_prefill_256"])
+def test_the_state_space_cells_programs_hold_their_state_in_place(
+        program, topo, no_persistent_cache):
+    """`granite-4.0-h-micro.serve-chat-burst` as the benchmark sizes it: the
+    five runs of Mamba-2 layers scan and the four attention layers stand
+    alone; a decode span advances the engine's whole state array
+    [36, 64, 128, 4096] float32 (4.5 GiB) in place, one `ssd_step` a scanned
+    run and one `paged_decode` an attention layer, and a chunk one
+    `ssd_chunk` a run from ONE sequence's state; nothing copies the state
+    or the pool. The memory the cell's `pool_filled` quotes: 5.94 GiB of
+    weights + 1 GiB of pages + 4.5 GiB of state + 0.06 of tails."""
+    from benchmark import common
+    from ray_tpu.models import stack
+    from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    cell = common.load_cell("granite-4.0-h-micro.serve-chat-burst")
+    spec = cell["config"]
+    family = common.family(spec)
+    cfg = family.model_config(spec)
+    eng = object.__new__(InferenceEngine)
+    eng.cfg, eng.ecfg, eng.mesh, eng._tp = (
+        cfg, EngineConfig(**cell["engine"]), None, 1)
+    eng._ring = eng._window_ring()
+    assert not eng._ring
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: s(a.shape, a.dtype), jax.eval_shape(
+            lambda k: family.init_weights(spec, k), jax.random.PRNGKey(0)))
+    pool, state = eng.abstract_pool(one_chip), eng.abstract_state(one_chip)
+    assert pool.shape == (4, 1, 8193, 16, 512)
+    assert {k: (v.shape, v.dtype) for k, v in state.items()} == {
+        "conv": ((36, 64, 3, 4352), BF16),
+        "ssd": ((36, 64, 128, 4096), F32)}
+    ecfg = eng.ecfg
+    B, pps, C = ecfg.max_batch_size, ecfg.pages_per_seq, ecfg.prefill_chunk
+    gib = 2 ** 30
+    held = sum(a.size * a.dtype.itemsize for a in state.values())
+    pools = 2 * pool.size * pool.dtype.itemsize
+    if program == "decode_span_8":
+        lowered = eng._build_decode()(8).lower(
+            params, pool, pool, s((B,), I32), s((B,), I32), s((B, pps), I32),
+            s((B,), F32), s((B,), F32), s((B,), I32), s((2,), jnp.uint32),
+            state, (s((B,), I32), s((B,), I32), s((B,), jnp.bool_)))
+        kernels = {"ssd_step": 5, "paged_decode": 4}
+        aliased, arguments = pools + held, (11.4, 11.7)
+    else:
+        rs = jax.tree.map(lambda a: s(a.shape, a.dtype), jax.eval_shape(
+            lambda: stack.new_request_state(cfg, 1, jnp.bfloat16)))
+        lowered = eng._build_chunk_prefill()(C).lower(
+            params, pool, pool, s((C,), I32), s((), I32), s((pps,), I32),
+            s((), I32), rs)
+        kernels = {"ssd_chunk": 5, "paged_chunk": 4}
+        aliased, arguments = pools, (6.95, 7.15)
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    print(program, "GiB: arguments %.3f aliased %.3f temporaries %.3f" % (
+        memory.argument_size_in_bytes / gib, memory.alias_size_in_bytes / gib,
+        memory.temp_size_in_bytes / gib))
+    assert memory.alias_size_in_bytes >= aliased
+    assert arguments[0] < memory.argument_size_in_bytes / gib < arguments[1]
+    assert memory.temp_size_in_bytes < 0.3 * gib
+    text = compiled.as_text()
+    for kernel, calls in kernels.items():
+        assert len(re.findall(r"%%%s(\.\d+)? = " % kernel, text)) == calls
+    assert not re.search(r"= f32\[36,64,128,4096\]\S* copy\(", text)
+    assert not re.search(r"= bf16\[4,(1,)?8193,16,512\]\S* copy\(", text)
