@@ -299,7 +299,9 @@ def ring_pages(cfg: ModelConfig, page_size: int, chunk: int = 0) -> int:
     of `chunk` tokens writes its keys to the ring before it attends (the
     "swa" kind), the ring holds the chunk's pages beside the window's, so
     that the chunk's last keys do not take the place of keys its first
-    rows still see."""
+    rows still see: `chunk` is the rows of the WIDEST chunk program the
+    engine will run (serve/engine.py `_wide_chunk`), however many of them
+    one call of the attention kernel takes."""
     if cfg.window % page_size:
         raise ValueError(f"window {cfg.window} must be a multiple of the "
                          f"page size {page_size}")
@@ -465,14 +467,19 @@ class Seq(_Mode):
     and only `export` leaves the chunk's own keys and values beside it, in
     the pool's dtype. `window_table` [ring]: a chunk's pages in the window
     page space (`cfg.window_paged`), whose pool `carry` then holds as the
-    engine does (`wk`, `wv`)."""
+    engine does (`wk`, `wv`). `attend_rows`: how many of a chunk's tokens
+    ONE call of the paged chunk kernel takes (0: all of them); a chunk of
+    more writes all its keys and then attends block by block, each block
+    the call a chunk of that many rows at its position would make."""
 
     def __init__(self, cfg: ModelConfig, n_valid=None, keep: bool = False,
                  chunk=None, page_size: int = 0, mesh=None,
-                 export: bool = False, window_table=None):
+                 export: bool = False, window_table=None,
+                 attend_rows: int = 0):
         self.cfg, self.n_valid, self.keep, self.chunk = cfg, n_valid, keep, chunk
         self.ps, self.mesh, self.export = page_size, mesh, export
         self.window_table = window_table
+        self.attend_rows = attend_rows
         self.by_xla = mesh is not None and mesh.shape.get("tp", 1) > 1
 
     def positions(self, T):
@@ -575,6 +582,20 @@ class Seq(_Mode):
         return y, carry
 
     # -- attention
+    def _by_rows(self, q, start, attend):
+        """A chunk's queries q [C,H,D], the first at position `start`,
+        through `attend(rows, first, end)`, the paged chunk kernel over the
+        queries `rows` at positions first .. end: `attend_rows` tokens a
+        call (the kernel holds a call's query rows as ONE block, and the
+        keys of a step shrink as the block grows), the chunk's keys written
+        before any call."""
+        C, R = q.shape[0], self.attend_rows or q.shape[0]
+        if C <= R:
+            return attend(q, start, start + C)
+        return jnp.concatenate(
+            [attend(q[r:r + R], start + r, start + r + R)
+             for r in range(0, C, R)], axis=0)
+
     def attend_window(self, carry, wi, q, k, v, scale):
         cfg = self.cfg
         W = cfg.window
@@ -606,9 +627,9 @@ class Seq(_Mode):
         # [W+C, KVH, D] holds the pool's own rows in order: no copy
         pool = [b.reshape(pool_shape(1, n_pages, self.ps, *b.shape[1:]))
                 for b in bufs]
-        o = paged_attention_chunk(
-            q[0], *pool, jnp.arange(n_pages, dtype=jnp.int32), W, W + C, 0,
-            scale=scale, window=W, first=jnp.maximum(W - start, 0))
+        o = self._by_rows(q[0], W, lambda q, at, end: paged_attention_chunk(
+            q, *pool, jnp.arange(n_pages, dtype=jnp.int32), at, end, 0,
+            scale=scale, window=W, first=jnp.maximum(W - start, 0)))
         return o[None].astype(q.dtype), carry
 
     def attend_paged_window(self, carry, si, q, k, v, scale):
@@ -621,12 +642,12 @@ class Seq(_Mode):
                     name: carry[name].at[si].set(new.astype(carry[name].dtype))
                     for name, new in (("wk", k), ("wv", v))}}
             return _dense_attend(q, k, v, scale, W), carry
-        start, C = self.chunk[0], q.shape[1]
+        start = self.chunk[0]
 
         def attend(q, kp, vp, layer):
-            return paged_attention_chunk(q, kp, vp, self.unrolled, start,
-                                         start + C, layer, scale=scale,
-                                         window=W)
+            return self._by_rows(q, start, lambda q, at, end: (
+                paged_attention_chunk(q, kp, vp, self.unrolled, at, end,
+                                      layer, scale=scale, window=W)))
 
         # the chunk's keys into the sequence's ring, then attention over it
         o, wk, wv = write_then_attend(
@@ -647,15 +668,14 @@ class Seq(_Mode):
                 k, v = carry["k"][fi], carry["v"][fi]
             return _dense_attend(q, k, v, scale), carry
         start, table = self.chunk
-        C = q.shape[1]
 
         def attend(q, kp, vp, layer):
             # key j is seen by query row c iff j <= start + c (the prefix
             # and the chunk so far); rows past n_valid write keys that no
             # later position bound lets anything see
-            return paged_attention_chunk(q, kp, vp, table, start, start + C,
-                                         layer, scale=scale,
-                                         force_xla=self.by_xla)
+            return self._by_rows(q, start, lambda q, at, end: (
+                paged_attention_chunk(q, kp, vp, table, at, end, layer,
+                                      scale=scale, force_xla=self.by_xla)))
 
         kp, vp = carry["k_pages"], carry["v_pages"]
         if k is None:

@@ -290,8 +290,10 @@ def groups_fit(N: int, D: int, E: int, F: int, itemsize: int) -> bool:
     VMEM beside an expert's blocks (x, the output and the accumulator all
     lie there): 512 rows at the four published shapes, 1024 at the two
     narrowest (2048 and 2560 wide). Past it the XLA form runs; no cell's
-    traffic does (a prompt over 256 tokens is chunked, so its rows are 64 to
-    256; measured to 512, PERF.md section 6, PR 43)."""
+    traffic does (a bucket's rows are 64 to 256, a chunk's 256, or 512
+    where the engine asks this function whether its wide chunk fits,
+    serve/engine.py `_wide_chunk`; measured to 512, PERF.md section 6,
+    PR 43 and PR 46)."""
     tf = f_tile(D, F, itemsize)
     return _groups_need(N, D, E, tf, itemsize, itemsize) <= _VMEM_BYTES
 

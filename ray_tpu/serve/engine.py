@@ -57,6 +57,7 @@ from ..models import ModelConfig, stack
 from ..models.transformer import (
     _head_logits,
     moe_rows_computed,
+    moe_seq_groups,
     moe_step_visits,
 )
 from ..ops import gather_pages, pool_shape, scatter_pages
@@ -82,7 +83,12 @@ _m_chunk_padding_tokens = Counter(
     "Rows of prefill chunk programs that held no prompt token.")
 _m_chunk_rows = Counter(
     "serve_chunk_rows",
-    "Rows of the prefill chunk programs dispatched (chunks x prefill_chunk).")
+    "Rows of the prefill chunk programs dispatched (calls x the program's "
+    "rows).")
+# which of the chunk programs ran: prefill_chunk rows, or twice that where
+# the model has the wide one (`InferenceEngine._wide_chunk`)
+_m_chunk_calls = Counter(
+    "serve_chunk_calls", "Prefill chunk programs dispatched, by their rows.")
 _m_ttft = Histogram(
     "serve_ttft_seconds", "Time to first token.",
     buckets=(0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0),
@@ -354,15 +360,21 @@ class EngineConfig:
     # the cap. No cell sets it (D4).
     prefill_max_batch: int = 32
     # Chunked prefill (vLLM-style): prompts longer than prefill_chunk are
-    # processed in prefill_chunk-token chunks ON THE DECODE THREAD, with
-    # decode spans between: one chunk an engine iteration for each prompt
-    # that waits in the chunk queue, and no more chunks than the span that
-    # follows has steps (`InferenceEngine._advance_chunks`) — a long
-    # prompt never monopolizes the device, so running requests keep their
-    # inter-token latency AND the long prompt's KV lands straight in its
-    # pages (no separate scatter). Also lifts the bucket cap: prompts up
-    # to max_seq_len serve even past the largest compiled bucket. Must be
-    # a multiple of page_size.
+    # processed in chunks ON THE DECODE THREAD, with decode spans between:
+    # one chunk an engine iteration for each prompt that waits in the
+    # chunk queue, and no more rows than busy_span x prefill_chunk, the
+    # span that follows a step a chunk (`InferenceEngine._advance_chunks`)
+    # — a long prompt never monopolizes the device, so running requests
+    # keep their inter-token latency AND the long prompt's KV lands
+    # straight in its pages (no separate scatter). Also lifts the bucket
+    # cap: prompts up to max_seq_len serve even past the largest compiled
+    # bucket. prefill_chunk is the prompt length above which a prompt is
+    # chunked, what a prefix-cache hit is aligned to, and the rows of THE
+    # chunk program; a model whose chunk costs its experts' weights
+    # whatever its rows has a second program of twice the rows for a
+    # prompt with more than prefill_chunk tokens left (`_wide_chunk`: a
+    # rule on the model's shapes, no option). Must be a multiple of
+    # page_size.
     chunked_prefill: bool = True
     prefill_chunk: int = 256
     eos_token_id: Optional[int] = None
@@ -594,7 +606,7 @@ class TokenStream:
 class _ChunkState:
     """One long prompt mid-chunked-prefill."""
 
-    __slots__ = ("request", "pages", "table", "true_len", "next_chunk",
+    __slots__ = ("request", "pages", "table", "true_len", "done",
                  "emitted_upto", "sink_seq", "state", "window_table")
 
     def __init__(self, request: Request, pages: List[int], table, true_len: int):
@@ -604,7 +616,9 @@ class _ChunkState:
         # np [ring]: its pages in the window page space, where there is one
         self.window_table = None
         self.true_len = true_len
-        self.next_chunk = 0
+        # prompt tokens whose keys are in its pages (a cache hit's, then
+        # its chunks'): where its next chunk starts
+        self.done = 0
         # streamed export bookkeeping: tokens already pushed to kv_sink
         # (page-aligned except after the final frame) and the frame seq
         self.emitted_upto = 0
@@ -841,6 +855,7 @@ class InferenceEngine:
         # models. Every program takes it and hands it back.
         # the window page space (`cfg.window_paged`): the ring's width, and
         # the allocator that serves it beside `self.allocator`
+        self._wide = self._wide_chunk()
         self._ring = self._window_ring()
         self._window_allocator = (
             PageAllocator(engine_cfg.max_window_pages) if self._ring else None)
@@ -1031,6 +1046,26 @@ class InferenceEngine:
         spans count them."""
         return moe_step_visits(self.cfg, self.mesh)
 
+    def _wide_chunk(self) -> int:
+        """Rows of the second chunk program, twice `prefill_chunk` (0: the
+        model has none). A chunk that runs each expert over the rows that
+        chose it (`moe_seq_groups`: routed experts, no sharded mesh, the
+        rows whole in the kernel's fast memory) costs its experts' WEIGHTS'
+        time whatever its rows, so a prompt with more than `prefill_chunk`
+        tokens left reads them once for twice the rows; a dense chunk is
+        at its products' time at `prefill_chunk` rows already and gains
+        nothing. The rule is the model's shapes and the mesh. Under
+        speculation the chunks stay as they are (no test or cell runs a
+        round's programs beside a wide chunk)."""
+        ecfg = self.ecfg
+        C = ecfg.prefill_chunk
+        scfg = ecfg.speculation
+        if (not ecfg.chunked_prefill or ecfg.busy_span < 2
+                or (scfg is not None and scfg.enabled)):
+            return 0
+        return 2 * C if all(moe_seq_groups(self.cfg, 1, rows, self.mesh)
+                            for rows in (C, 2 * C)) else 0
+
     def _window_ring(self) -> int:
         """Pages of a sequence's ring in the window page space (0: the
         model has none), and what the two configs must agree on there."""
@@ -1039,7 +1074,8 @@ class InferenceEngine:
         ecfg, name = self.ecfg, self.cfg.name
         ring = stack.ring_pages(
             self.cfg, ecfg.page_size,
-            ecfg.prefill_chunk if ecfg.chunked_prefill else 0)
+            max(ecfg.prefill_chunk, self._wide_chunk())
+            if ecfg.chunked_prefill else 0)
         if ecfg.max_window_pages <= ring:
             raise ValueError(
                 f"{name!r} holds its window layers' keys in allocated "
@@ -1253,9 +1289,12 @@ class InferenceEngine:
         """Jit a C-token prefill chunk of one sequence: the chunk's keys
         and values go straight into the sequence's pages and its queries
         attend over the paged prefix (per-row causal bound), through the
-        layers of models/stack.py in its chunk mode. Attention runs the
-        Pallas chunk kernel (ops.paged_attention_chunk: blocks of page
-        DMAs, reads only the valid prefix pages) where shapes allow;
+        layers of models/stack.py in its chunk mode. Whatever C, one call
+        of the attention kernel takes `prefill_chunk` rows: the wide
+        program (`_wide_chunk`) makes the calls two chunks would.
+        Attention runs the Pallas chunk kernel
+        (ops.paged_attention_chunk: blocks of page DMAs, reads only the
+        valid prefix pages) where shapes allow;
         the XLA gather fallback, which touches the whole table, covers CPU
         tests, odd head dims, and TP meshes (GSPMD partitions the
         fallback's einsums; a bare pallas_call it cannot)."""
@@ -1284,7 +1323,8 @@ class InferenceEngine:
                 stack.Seq(cfg, n_valid=(last_idx + 1)[None], keep=True,
                           chunk=(start, page_table), page_size=ps,
                           mesh=self.mesh, export=export,
-                          window_table=window_table),
+                          window_table=window_table,
+                          attend_rows=self.ecfg.prefill_chunk),
                 (k_pages, v_pages), state)
             with jax.named_scope("lm_head"):
                 logits = _head_logits(x, lambda x: x[0, last_idx], params,
@@ -1378,19 +1418,21 @@ class InferenceEngine:
                 ))[0]
                 _np.asarray(seq)  # block until compiled + executed
         if self.ecfg.chunked_prefill:
-            C = self.ecfg.prefill_chunk
-            logits, self.k_pages, self.v_pages, state = self._chunk_fn(C)(
-                self.params, self.k_pages, self.v_pages,
-                jnp.zeros((C,), jnp.int32), jnp.int32(0),
-                self._tables(jnp.zeros((pps,), jnp.int32),
-                             jnp.zeros((self._ring,), jnp.int32)),
-                jnp.int32(C - 1),
-                self.state if self._ring else self._request_start,
-            )
-            _np.asarray(logits)
-            if self._ring:  # all-zero tables wrote the trash pages alone
-                self.state = state
-            elif self.state:  # the program that hands a slot its state
+            # both chunk programs the queue picks from (`_advance_chunk`)
+            for C in filter(None, (self.ecfg.prefill_chunk, self._wide)):
+                logits, self.k_pages, self.v_pages, state = self._chunk_fn(C)(
+                    self.params, self.k_pages, self.v_pages,
+                    jnp.zeros((C,), jnp.int32), jnp.int32(0),
+                    self._tables(jnp.zeros((pps,), jnp.int32),
+                                 jnp.zeros((self._ring,), jnp.int32)),
+                    jnp.int32(C - 1),
+                    self.state if self._ring else self._request_start,
+                )
+                _np.asarray(logits)
+                if self._ring:  # all-zero tables wrote the trash pages alone
+                    self.state = state
+            if not self._ring and self.state:
+                # the program that hands a slot its state
                 self.state = self._install_state(
                     self.state, self._request_start, jnp.int32(0),
                     jnp.int32(1))
@@ -2344,7 +2386,6 @@ class InferenceEngine:
         chunked = [it for it in admitted if it[3] is None]
         if chunked:
             pps = self.ecfg.pages_per_seq
-            C = self.ecfg.prefill_chunk
             now = tracing.now_ns()
             with self._chunk_lock:
                 for req, pages, T, _b, cached_len in chunked:
@@ -2353,7 +2394,7 @@ class InferenceEngine:
                     st = _ChunkState(req, pages, table, T)
                     if self._ring:  # filled as the chunks take their pages
                         st.window_table = np.zeros((self._ring,), np.int32)
-                    st.next_chunk = cached_len // C  # resume past the hits
+                    st.done = cached_len  # resume past the hits
                     req.enter_stage("chunk_wait", now)
                     self._chunk_queue.append(st)
             self._work.set()  # the decode thread runs the chunks
@@ -2590,51 +2631,66 @@ class InferenceEngine:
 
     def _advance_chunks(self) -> bool:
         """The iteration's turn of the chunk queue: as many chunks as prompts
-        wait there, the oldest prompt's first, and no more than the span
-        under prefill pressure has steps. One prompt alone advances a chunk
-        an iteration whatever its length; a deep queue (twelve long contexts
-        asked for the first time, their re-asks behind them) takes turns
-        with the decoders chunk for step, so the time the queue needs does
-        not grow with the decoders' spans between its chunks, and the stall
-        a decoder sees between two spans stays `busy_span` chunks at most
-        (PERF.md section 6, PR 39)."""
+        wait there, the oldest prompt's first, and no more ROWS than the
+        span under prefill pressure has steps of `prefill_chunk`. One prompt
+        alone advances a chunk an iteration whatever its length, a wide one
+        (`_wide_chunk`) while it has more than `prefill_chunk` tokens left;
+        a deep queue (twelve long contexts asked for the first time, their
+        re-asks behind them) takes turns with the decoders chunk for step,
+        so the time the queue needs does not grow with the decoders' spans
+        between its chunks, and the stall a decoder sees between two spans
+        stays `busy_span x prefill_chunk` rows at most, a wide chunk two of
+        them (PERF.md section 6, PR 39 and PR 46)."""
         chunked = False
+        C = self.ecfg.prefill_chunk
+        room = max(1, self.ecfg.busy_span) * C
         # racy read: the prefill thread appends, only this thread removes
         for _ in range(max(1, min(len(self._chunk_queue),
                                   self.ecfg.busy_span))):
-            if not self._advance_chunk():
+            rows = self._advance_chunk(room) if room >= C else None
+            if rows is None:
                 break
             chunked = True
+            room -= rows
         return chunked
 
-    def _advance_chunk(self) -> bool:
+    def _advance_chunk(self, room: Optional[int] = None) -> Optional[int]:
         """Run ONE prefill chunk of the oldest chunked request (decode
         thread only — chunk programs donate the page pool). A decode
         span follows the iteration's chunks, so long prompts and the
         running batch interleave at chunk granularity (vLLM chunked
-        prefill)."""
+        prefill). The wide program where the model has one, the prompt has
+        MORE than `prefill_chunk` tokens left and the turn has `room` for
+        its rows (None: any): exactly where `prefill_chunk` rows would have
+        run twice for the same prompt; a prompt's tail, a cached prompt's
+        one chunk and a streamed export keep `prefill_chunk` rows.
+        -> the rows that ran (0: a cancelled prompt left the queue), None
+        where nothing waits."""
         with self._chunk_lock:
             if not self._chunk_queue:
-                return False
+                return None
             st = self._chunk_queue[0]
             if st.request.cancelled.is_set():  # cancelled between chunks
                 self._chunk_queue.pop(0)
                 self._free_pages_and_revive(st.pages)
                 self._finish_request(st.request, "cancelled")
-                return True
-        C = self.ecfg.prefill_chunk
+                return 0
         if self.prefix is not None and not st.request.prefill_only:
             self._take_late_hits(st)
-        start = st.next_chunk * C
-        toks = st.request.prompt[start:start + C]
+        req = st.request
+        streaming = req.prefill_only and req.kv_sink is not None
+        start = st.done
+        C = self.ecfg.prefill_chunk
+        if (self._wide and st.true_len - start > C and not streaming
+                and (room is None or room >= self._wide)):
+            C = self._wide  # this chunk's rows
+        toks = req.prompt[start:start + C]
         padded = np.zeros((C,), np.int32)
         padded[: len(toks)] = toks
         is_last = start + C >= st.true_len
         last_idx = (st.true_len - 1 - start) if is_last else C - 1
-        req = st.request
         if req.stage == "chunk_wait":  # its first chunk goes out now
             req.enter_stage("prefill", tracing.now_ns())
-        streaming = req.prefill_only and req.kv_sink is not None
         if self._ring:  # this chunk's pages, in both spaces
             self._grow(st.pages, start + C)
             st.table[: len(st.pages)] = st.pages
@@ -2654,7 +2710,7 @@ class InferenceEngine:
                           st.table, st.window_table)),
                       jnp.int32(last_idx))
         with tracing.region("engine.chunk.call", start=start,
-                            tokens=len(toks), padded=C):
+                            tokens=len(toks), padded=C, rows=C):
             logits, self.k_pages, self.v_pages, *kv, state = \
                 self._chunk_fn(C, streaming)(
                     self.params, self.k_pages, self.v_pages, *placed,
@@ -2666,11 +2722,12 @@ class InferenceEngine:
             del placed  # as in `step()`
         self._chunk_tokens += C
         _m_chunk_rows.inc(C)
+        _m_chunk_calls.labels(rows=str(C)).inc()
         _m_chunk_padding_tokens.inc(C - len(toks))
         if not is_last:  # the last chunk's are counted with its logits
             self._count_moe_rows(1, C, len(toks), held=len(toks))
         chunk_kv = (*kv, start) if streaming else None
-        st.next_chunk += 1
+        st.done = start + C
         if not is_last and self.prefix is not None:
             # the chunk's pages are written by a program already in the
             # device's queue: whoever asks for this prefix from now on
@@ -2698,7 +2755,7 @@ class InferenceEngine:
                             self._chunk_queue.remove(st)
                     self._free_pages_and_revive(st.pages)
                     self._fail_request(req, f"kv stream failed: {e!r}")
-            return True
+            return C
         with self._chunk_lock:
             self._chunk_queue.pop(0)
         with self.phase("chunk.readback"):
@@ -2732,15 +2789,15 @@ class InferenceEngine:
                                exc_info=True)
                 self._free_pages_and_revive(st.pages)
                 self._fail_request(req, f"kv stream failed: {e!r}")
-                return True
+                return C
             self._free_pages_and_revive(st.pages)
             self._finish_request(req, "prefill_done")
-            return True
+            return C
         with self._ready_lock:
             # no keys in the cache: this prompt's KV is already in its pages
             # (state beside pages still has to reach its slot)
             self._ready.append((req, st.pages, st.state, st.true_len))
-        return True
+        return C
 
     def _take_late_hits(self, st: _ChunkState) -> None:
         """A chunked prompt about to run a chunk looks its prefix up once
@@ -2753,7 +2810,7 @@ class InferenceEngine:
         req = st.request
         C, ps = self.ecfg.prefill_chunk, self.ecfg.page_size
         hashes = getattr(req, "_page_hashes", None)
-        at = st.next_chunk * C // ps  # the page its next chunk writes first
+        at = st.done // ps  # the page its next chunk writes first
         if not hashes or at >= len(hashes):
             return
         with self._alloc_lock:
@@ -2761,7 +2818,7 @@ class InferenceEngine:
                 return
             shared = self.prefix.lookup_acquire(req.prompt, C, hashes=hashes)
             n = len(shared)
-            if n * ps <= st.next_chunk * C:
+            if n * ps <= st.done:
                 self.prefix.release_and_filter(shared)
                 return
             own = st.pages[:n]
@@ -2771,8 +2828,8 @@ class InferenceEngine:
         # back to the allocator (what earlier chunks of this prompt wrote
         # there is in the shared pages too, by the chain hash)
         self._free_pages_and_revive(own)
-        _m_prefix_hit_tokens.inc(n * ps - st.next_chunk * C)
-        st.next_chunk = n * ps // C
+        _m_prefix_hit_tokens.inc(n * ps - st.done)
+        st.done = n * ps  # whole chunks of `prefill_chunk` (lookup_acquire)
 
     def step(self) -> bool:
         """One engine iteration: advance the chunk queue (`_advance_chunks`:
@@ -3085,11 +3142,10 @@ class InferenceEngine:
             if s.request is not None:
                 reserved += len(s.pages)
                 written += -(-s.position // ps)
-        C = self.ecfg.prefill_chunk
         with self._chunk_lock:
             for st in self._chunk_queue:
                 reserved += len(st.pages)
-                written += -(-min(st.next_chunk * C, st.true_len) // ps)
+                written += -(-min(st.done, st.true_len) // ps)
         with self._ready_lock:
             for _req, pages, _cache, T in self._ready:
                 reserved += len(pages)
@@ -3425,8 +3481,8 @@ class InferenceEngine:
         with self._chunk_lock:
             chunk_queue = len(self._chunk_queue)
             head = self._chunk_queue[0] if chunk_queue else None
-            # the head prompt's next chunk index of its total
-            chunking = ([head.next_chunk,
+            # the head prompt's progress, in chunks of prefill_chunk tokens
+            chunking = ([head.done // self.ecfg.prefill_chunk,
                          -(-head.true_len // self.ecfg.prefill_chunk)]
                         if head is not None else None)
         with self._alloc_lock:

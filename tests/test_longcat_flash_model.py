@@ -708,8 +708,10 @@ def _nothing_leaked(eng, pages=96):
 
 
 def test_a_deep_chunk_queue_takes_turns_with_the_decoders_chunk_for_step(model):
-    """One prompt in the chunk queue advances a chunk an iteration; three
-    advance `busy_span` (2 here) chunks an iteration, the oldest prompt's
+    """One prompt in the chunk queue advances a chunk an iteration, a wide
+    one (32 rows: the model has routed experts) while more than a chunk of
+    it is left; three advance `busy_span` (2 here) chunks of 16 rows an
+    iteration at the most, a wide one or two of 16, the oldest prompt's
     first, a last chunk and another prompt's first in one turn among them;
     every answer's log-probabilities are the reference's."""
     from ray_tpu.serve.engine import Request
@@ -720,9 +722,9 @@ def test_a_deep_chunk_queue_takes_turns_with_the_decoders_chunk_for_step(model):
     turns = []
     one = eng._advance_chunk
 
-    def counted():
-        ran = one()
-        turns[-1] += ran
+    def counted(room=None):
+        ran = one(room)
+        turns[-1] += bool(ran)
         return ran
 
     eng._advance_chunk = counted
@@ -733,7 +735,7 @@ def test_a_deep_chunk_queue_takes_turns_with_the_decoders_chunk_for_step(model):
         while eng._chunk_queue:
             turns.append(0)
             eng._iterate()
-        assert turns == [1, 1, 1]  # 40 tokens: three chunks of 16
+        assert turns == [1, 1]  # 40 tokens: a chunk of 32 and one of 16
         del turns[:]
         rest = _admitted(eng, [
             Request(request_id=f"deep{i}", prompt=p, max_tokens=6)
@@ -741,10 +743,10 @@ def test_a_deep_chunk_queue_takes_turns_with_the_decoders_chunk_for_step(model):
         while eng._chunk_queue:
             turns.append(0)
             eng._iterate()
-        # 3 + 3 + 4 chunks: two a turn while two or more prompts wait (the
-        # second turn is one prompt's last chunk and the next one's first),
-        # one a turn for the last prompt alone
-        assert turns == [2, 2, 2, 1, 1, 1, 1]
+        # 40, 37 and 52 tokens: a wide chunk is a turn of two; the second
+        # turn is one prompt's last chunk and the next one's first, of 16
+        # rows each; one chunk a turn for the last prompt alone
+        assert turns == [1, 2, 1, 1, 1]
         assert eng._active()  # the first ask decoded between the turns
         _run_out(eng, first + rest)
         _nothing_leaked(eng)

@@ -251,6 +251,14 @@ CASES = {
         64, 2560, 768, jax.nn.relu, rows=64),
     "moe_groups_64_experts_2560x768_reglu_512_rows": _expert_groups(
         64, 2560, 768, jax.nn.relu, rows=512),
+    # the wide chunk's 512 rows (serve/engine.py `_wide_chunk`) at the
+    # three other shapes
+    "moe_groups_8_experts_4096x14336_512_rows": _expert_groups(
+        8, 4096, 14336, rows=512),
+    "moe_groups_32_experts_2048x1792_512_rows": _expert_groups(
+        32, 2048, 1792, rows=512),
+    "moe_groups_16_experts_6144x2048_512_rows": _expert_groups(
+        16, 6144, 2048, rows=512),
     # the engine's chunk as ONE block of the dual form, and its buckets
     "ssd_chunk_t256": (ssd_chunk, _ssd_operands(1, 256)
                        + [((1, SN, SH * SP), F32)], 1),
@@ -474,7 +482,8 @@ def test_train_step_backward_runs_no_flash_forward(topo, no_persistent_cache):
         {"flash_fwd": 1}, {"flash_bwd_dq": 1, "flash_bwd_dkv": 1}]
 
 
-@pytest.mark.parametrize("program", ["decode_span_8", "chunk_prefill_256"])
+@pytest.mark.parametrize(
+    "program", ["decode_span_8", "chunk_prefill_256", "chunk_prefill_512"])
 def test_the_latent_cells_programs_hold_one_pool_at_published_widths(
         program, topo, no_persistent_cache):
     """`longcat-flash-omni.serve-docs` as the benchmark sizes it: the double
@@ -482,7 +491,9 @@ def test_the_latent_cells_programs_hold_one_pool_at_published_widths(
     latents come back in place, there is no pool of values), two latent
     kernels in the scanned layer's body, no XLA attention, and the memory
     the cell's `pool_filled` quotes: 9.63 GiB of weights + 3.75 GiB of
-    latents as arguments, under 0.25 GiB of temporaries."""
+    latents as arguments, under 0.25 GiB of temporaries. The wide chunk
+    (the engine's second chunk program: the model has routed experts) is
+    the same two kernels over a grid twice as long."""
     from benchmark import common
     from ray_tpu.models import stack
     from ray_tpu.serve.engine import EngineConfig, InferenceEngine
@@ -504,7 +515,9 @@ def test_the_latent_cells_programs_hold_one_pool_at_published_widths(
             lambda k: family.init_weights(spec, k), jax.random.PRNGKey(0)))
     pool = eng.abstract_pool(one_chip)
     assert pool.shape == (8, 1, 24577, 16, 640)
-    B, pps, C = ecfg.max_batch_size, ecfg.pages_per_seq, ecfg.prefill_chunk
+    B, pps = ecfg.max_batch_size, ecfg.pages_per_seq
+    assert eng._wide_chunk() == 2 * ecfg.prefill_chunk == 512
+    C = int(program.rpartition("_")[2])
     f32 = jnp.float32
     if program == "decode_span_8":
         lowered = eng._build_decode()(8).lower(
@@ -531,7 +544,8 @@ def test_the_latent_cells_programs_hold_one_pool_at_published_widths(
     assert not re.search(r"= bf16\[8,(1,)?24577,16,640\]\S* copy\(", text)
 
 
-@pytest.mark.parametrize("program", ["decode_span_8", "chunk_prefill_256"])
+@pytest.mark.parametrize(
+    "program", ["decode_span_8", "chunk_prefill_256", "chunk_prefill_512"])
 def test_the_window_and_full_cells_programs_hold_two_page_spaces(
         program, topo, no_persistent_cache):
     """`smallthinker-21b-a3b.serve-mixedlen` as the benchmark sizes it: the
@@ -540,7 +554,9 @@ def test_the_window_and_full_cells_programs_hold_two_page_spaces(
     spaces' pools donated and handed back in place, no XLA attention, no
     copy of a pool, and the memory the cell's `pool_filled` quotes: 10.36
     GiB of weights + 1.125 GiB of full pages + 2.25 GiB of window pages as
-    arguments, under 0.25 GiB of temporaries."""
+    arguments, under 0.25 GiB of temporaries. The wide chunk (the engine's
+    second chunk program: the model has routed experts) calls each layer's
+    chunk kernel twice, 256 rows a call, and its experts' kernel once."""
     from benchmark import common
     from ray_tpu.serve.engine import EngineConfig, InferenceEngine
 
@@ -551,8 +567,8 @@ def test_the_window_and_full_cells_programs_hold_two_page_spaces(
     eng = object.__new__(InferenceEngine)
     eng.cfg, eng.ecfg, eng.mesh, eng._tp = (
         family.model_config(spec), EngineConfig(**cell["engine"]), None, 1)
-    ring = eng._window_ring()
-    assert ring == 4096 // 16 + 256 // 16
+    ring = eng._window_ring()  # the window's pages and the wide chunk's
+    assert ring == 4096 // 16 + 512 // 16
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -565,7 +581,9 @@ def test_the_window_and_full_cells_programs_hold_two_page_spaces(
     assert {k: v.shape for k, v in state.items()} == {
         "wk": (9, 1, 8193, 16, 512), "wv": (9, 1, 8193, 16, 512)}
     ecfg = eng.ecfg
-    B, pps, C = ecfg.max_batch_size, ecfg.pages_per_seq, ecfg.prefill_chunk
+    B, pps = ecfg.max_batch_size, ecfg.pages_per_seq
+    C = int(program.rpartition("_")[2])
+    calls = C // ecfg.prefill_chunk or 1  # of a layer's attention kernel
     f32 = jnp.float32
     if program == "decode_span_8":
         lowered = eng._build_decode()(8).lower(
@@ -588,8 +606,9 @@ def test_the_window_and_full_cells_programs_hold_two_page_spaces(
     assert 13.6 < memory.argument_size_in_bytes / gib < 13.85
     assert memory.temp_size_in_bytes < 0.25 * gib
     text = compiled.as_text()
-    assert len(re.findall(r"%%%s(\.\d+)? = " % kernel, text)) == 1
-    assert len(re.findall(r"%%%s_window(\.\d+)? = " % kernel, text)) == 3
+    assert len(re.findall(r"%%%s(\.\d+)? = " % kernel, text)) == calls
+    assert len(re.findall(r"%%%s_window(\.\d+)? = " % kernel, text)) \
+        == 3 * calls
     assert not re.search(r"= bf16\[\d+,(1,)?\d+,16,512\]\S* copy\(", text)
     steps = re.findall(r"%moe_step(?:\.\d+)? = [^\n]*", text)
     groups = re.findall(r"%moe_groups(?:\.\d+)? = [^\n]*", text)
@@ -598,7 +617,7 @@ def test_the_window_and_full_cells_programs_hold_two_page_spaces(
     # kernel a layer of the scanned period, handed the segment's three
     # stacks whole (operands of the loop, not slices of them), and nothing
     # copies or slices ONE layer's experts
-    if program == "chunk_prefill_256":
+    if program.startswith("chunk_prefill"):
         assert not steps
         steps = groups
     else:
