@@ -60,8 +60,11 @@ class ModelConfig:
     router_aux_coef: float = 0.01
     # training numerics
     dtype: str = "bfloat16"
-    # True: the backward recomputes a layer's norms and FFN and keeps its
-    # attention half (models/transformer.py `_remat`); False keeps everything
+    # True: the layer loop's checkpoint keeps a layer's attention half and,
+    # of a dense gated FFN's `gate` and `up` products, as many as the shapes
+    # of the traced step leave room for on the device (models/transformer.py
+    # `kept_under_remat`: none where the backend reports no memory); the
+    # backward recomputes the rest. False keeps everything
     remat: bool = True
     logits_softcap: Optional[float] = None
     # attention implementation: "flash" (Pallas/XLA blockwise, seq gathered)
@@ -597,7 +600,7 @@ register(ModelConfig(
     name="llama-2b",
     # ~2B Llama-3 family member: the single-chip scale stepping stone
     # toward llama3-8b (BASELINE.md workload #2). remat (on by default;
-    # it keeps a layer's attention half: `ModelConfig.remat`)
+    # what it keeps is a rule on shapes and memory: `ModelConfig.remat`)
     # plus a FACTORED optimizer (train.lm.make_optimizer(factored=True),
     # adafactor second moments) is what fits f32 master state + grads in
     # one 16GB v5e chip — adamw moments alone would be 2x params.

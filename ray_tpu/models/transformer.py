@@ -41,7 +41,7 @@ from ..ops import (
 )
 from ..ops.attention import FLASH_RESIDUAL_NAMES
 from ..parallel.moe import sigmoid_bias_gating, top_k_gating
-from ..parallel.sharding import _current_mesh, constrain, per_shard
+from ..parallel.sharding import _current_mesh, constrain, per_shard, split_ways
 from .config import ModelConfig
 
 Params = Dict[str, Any]
@@ -249,14 +249,23 @@ def _attention(x, lp, cfg, rope_tables, positions, mesh=None):
 # the gate's activation of a gated second half, dense or an expert's:
 # down(act(gate x) * (up x)); `cfg.activation` names it
 _GATE_ACT = {"swiglu": jax.nn.silu, "reglu": jax.nn.relu}
+# the two [.., d_ff] products of a dense gated second half, `gate x` first
+_FFN_NAMES = ("ffn_gate", "ffn_up")
 
 
-def _dense_ffn(x, lp, cfg):
+def _dense_ffn(x, lp, cfg, named=False):
+    """`named`: the two products of a gated half carry `_FFN_NAMES` for a
+    checkpoint to save them by (the training layer's; the serve programs
+    take none: a name lowers to nothing but moves the numbers in a lowered
+    program's private function names)."""
     dtype = x.dtype
     h = jnp.einsum("btd,df->btf", x, lp["w_in"].astype(dtype))
     gated = cfg.activation in _GATE_ACT
     if gated:
         g = jnp.einsum("btd,df->btf", x, lp["w_gate"].astype(dtype))
+        if named:
+            g = checkpoint_name(g, _FFN_NAMES[0])
+            h = checkpoint_name(h, _FFN_NAMES[1])
         h = _GATE_ACT[cfg.activation](g) * h
     else:
         h = jax.nn.gelu(h + lp["b_in"].astype(dtype))
@@ -651,7 +660,7 @@ def _moe_ffn_gather(x, lp, cfg, gate=None):
         return constrain(out, ("batch", "seq", "embed")), aux
 
 
-def _ffn_half(x, lp, cfg, moe=None, experts=None):
+def _ffn_half(x, lp, cfg, moe=None, experts=None, named=False):
     """A layer's second half, x + FFN(norm(x)) or the experts in its place
     (`moe`; None: what the whole model has), the norm AFTER the sublayer
     where the model says (`cfg.post_norm`: x + norm(FFN(x))) -> (x, aux
@@ -659,14 +668,15 @@ def _ffn_half(x, lp, cfg, moe=None, experts=None):
     what it hands back in the aux loss's place) (None: `_moe_ffn`): the
     serve path's layers (models/stack.py), which say for each layer which
     half it has, count in their carry there. Shared by them and the
-    training block below."""
+    training block below, which has a dense half's products `named`
+    (`_dense_ffn`)."""
     moe = cfg.is_moe if moe is None else moe
     with jax.named_scope("moe" if moe else "ffn"):
         h = x if cfg.post_norm else _norm(x, lp["ln2"], lp.get("ln2_b"), cfg)
         if moe:
             y, aux = experts(h) if experts else _moe_ffn(h, lp, cfg)
         else:
-            y, aux = _dense_ffn(h, lp, cfg), jnp.zeros((), jnp.float32)
+            y, aux = _dense_ffn(h, lp, cfg, named), jnp.zeros((), jnp.float32)
         if cfg.post_norm:
             y = _norm(y, lp["ln2"], lp.get("ln2_b"), cfg)
         if cfg.residual_multiplier != 1.0:
@@ -683,26 +693,117 @@ def _block(x, lp, cfg, rope_tables, positions, mesh=None):
         h = _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
         x = checkpoint_name(
             x + _attention(h, lp, cfg, rope_tables, positions, mesh), "attn_half")
-    return _ffn_half(x, lp, cfg)
+    return _ffn_half(x, lp, cfg, named=True)
 
 
-# What a layer keeps for its backward under `cfg.remat`: the attention
-# half (the flash kernel's output and log-sum-exp, the turned q, k, v it
-# read, and the residual stream after the o projection), so the backward
-# recomputes the two norms and the FFN (or the experts) and runs no
-# attention kernel or projection a second time. Per layer and row of T
-# tokens that is T x (2 x d_model + (H + 2 x KVH) x head) activations +
-# 4 x H x T bytes of lse; the FFN's gate / up products ([.., d_ff], the
-# larger half) are what stays recomputed. `remat=False` keeps everything.
+# What a layer ALWAYS keeps for its backward under `cfg.remat`: the
+# attention half (the flash kernel's output and log-sum-exp, the turned q,
+# k, v it read, and the residual stream after the o projection), so the
+# backward recomputes the two norms and runs no attention kernel or
+# projection a second time. Per layer and row of T tokens that is
+# T x (2 x d_model + (H + 2 x KVH) x head) activations + 4 x H x T bytes of
+# lse. Of the second half, a dense gated FFN's `gate` and `up` products
+# ([.., d_ff], the larger half) are kept as far as the device has room
+# (`kept_under_remat`); the experts' are recomputed. `remat=False` keeps
+# everything.
 _KEPT_UNDER_REMAT = FLASH_RESIDUAL_NAMES + ("attn_q", "attn_k", "attn_v", "attn_half")
+# the share of the device's limit that the rule's estimate leaves free:
+# the batches an input pipeline holds ahead, what the allocator cannot hand
+# out of a memory in pieces, and whatever the estimate does not know of
+_REMAT_MARGIN = 0.10
 
 
-def _remat(body, cfg):
-    """The layer loop's body as `cfg.remat` wants it differentiated."""
+def _kept_widths(cfg) -> Dict[str, int]:
+    """name -> the bytes a layer keeps under it for ONE position (a row's
+    token) of the traced batch."""
+    act = jnp.dtype(cfg.dtype).itemsize
+    H, KVH, hd = cfg.n_heads, cfg.kv_heads, cfg.hdim
+    flash_out, flash_lse = FLASH_RESIDUAL_NAMES
+    return {flash_out: H * hd * act, flash_lse: 4 * H,
+            "attn_q": H * hd * act, "attn_k": KVH * hd * act,
+            "attn_v": KVH * hd * act, "attn_half": cfg.d_model * act,
+            **{name: cfg.d_ff * act for name in _FFN_NAMES}}
+
+
+def kept_under_remat(cfg, *, layers: int, positions: int, param_itemsize: int,
+                     memory: Optional[Tuple[int, int]]):
+    """The names a checkpointed layer saves, from what a trace can observe:
+    the model's shapes, the `layers` the loop scans, the `positions` (rows x
+    tokens) of the traced batch that ONE device holds, the bytes of a
+    parameter as traced, and the device's `memory` (its limit, what is in
+    use on it now; None: the backend keeps no count, as the CPU and a
+    compile for a described chip). -> (names, held): the attention half,
+    then `gate`, then `up` of a dense gated FFN, each one stack of
+    layers x positions x d_ff, as many as fit beside `held` with
+    `_REMAT_MARGIN` of the limit left over. `held` estimates what the step
+    holds without them: what is on the device now (the train state; the
+    parameters at the least), a gradient the size of the parameters, the
+    stacks of the attention half and of the loop's carry (the layer's
+    input, which every checkpoint keeps), and the larger of two things that
+    are not live together: the float32 logits with their gradient, and a
+    layer's working set in the backward (five values of [positions, d_ff]
+    and its weights' gradient). It leans high: against the chip's
+    compiler's buffer assignment it read +0.3 GB at 1 x 8192 of 8 layers of
+    Mistral-7B's widths (12.62 for 12.30, and 14.50 for 14.18 with `gate`;
+    the chip peaked at 12.20 and 14.08), +0.15 GB at 4 x 2048 of `llama-2b`
+    and +4 GB at 2 x 2048 of a vocabulary of 128256, whose logits the
+    compiler schedules away from the gradient (PR 47). Pure: the same
+    arguments, the same names."""
+    if memory is None or cfg.is_moe or cfg.activation not in _GATE_ACT:
+        return _KEPT_UNDER_REMAT, None
+    limit, in_use = memory
+    widths = _kept_widths(cfg)
+    wide, carry = widths[_FFN_NAMES[0]], widths["attn_half"]
+    params = cfg.param_count() * param_itemsize
+    held = (max(in_use, params) + params
+            + layers * positions * (sum(widths[n] for n in _KEPT_UNDER_REMAT)
+                                    + carry)
+            + max(2 * positions * cfg.vocab_size * 4,
+                  5 * positions * wide + params // cfg.n_layers))
+    room = (1 - _REMAT_MARGIN) * limit - held
+    fit = min(len(_FFN_NAMES), max(0, int(room // (layers * positions * wide))))
+    return _KEPT_UNDER_REMAT + _FFN_NAMES[:fit], held
+
+
+def _kept_now(cfg, layer_params, x) -> Tuple[str, ...]:
+    """`kept_under_remat` for the loop being traced over `layer_params`
+    ([L', ...] leaves) from the carry x [B,T,D], with the device's memory
+    as it reads at this moment; the decision goes out as a gauge of the
+    kept bytes by name and one log line."""
+    from ..util import profiler
+
+    mesh = _current_mesh()
+    layers = layer_params["wq"].shape[0]
+    positions = -(-x.shape[0] * x.shape[1] // split_ways(("batch", "seq"), mesh))
+    memory = profiler.device_memory(
+        mesh.local_devices if mesh is not None else jax.local_devices()[:1])
+    names, held = kept_under_remat(
+        cfg, layers=layers, positions=positions,
+        param_itemsize=layer_params["wq"].dtype.itemsize, memory=memory)
+    kept_bytes = {name: layers * positions * width * (name in names)
+                  for name, width in _kept_widths(cfg).items()}
+    profiler.publish_remat_kept(kept_bytes)
+    if held is not None:
+        from ..core.logging import get_logger
+
+        get_logger("models.transformer").info(
+            "remat keeps %s: %.3f GB a device in %d layers x %d positions; "
+            "without the FFN's the step holds an estimated %.3f GB (%.3f in "
+            "use now) of a limit of %.3f GB, of which %.0f%% stays free",
+            ", ".join(names), sum(kept_bytes.values()) / 1e9, layers,
+            positions, held / 1e9, memory[1] / 1e9, memory[0] / 1e9,
+            100 * _REMAT_MARGIN)
+    return names
+
+
+def _remat(body, cfg, kept=None):
+    """The layer loop's body as `cfg.remat` wants it differentiated: a
+    checkpoint that saves the names `kept` (None: the attention half)."""
     if not cfg.remat:
         return body
+    kept = _KEPT_UNDER_REMAT if kept is None else kept
     return jax.checkpoint(
-        body, policy=jax.checkpoint_policies.save_only_these_names(*_KEPT_UNDER_REMAT))
+        body, policy=jax.checkpoint_policies.save_only_these_names(*kept))
 
 
 # ---------------------------------------------------------------------------
@@ -804,7 +905,8 @@ def run_layers(
         y, aux = _block(carry, lp, cfg, rope_tables, positions)
         return y, aux
 
-    x, aux = jax.lax.scan(_remat(body, cfg), x, layer_params)
+    kept = _kept_now(cfg, layer_params, x) if cfg.remat else None
+    x, aux = jax.lax.scan(_remat(body, cfg, kept), x, layer_params)
     return x, jnp.sum(aux)
 
 
@@ -868,6 +970,8 @@ def forward_pp(
                 y, aux = _block(carry, lp, cfg, rope_tables, None)
                 return y, aux
 
+            # the attention half alone: how many microbatches' stacks the
+            # schedule holds at once is not `kept_under_remat`'s to see
             h, aux = jax.lax.scan(_remat(body, cfg), h, lp_stage)
             if cfg.is_moe:
                 return h, jnp.sum(aux)  # this stage's layers, this microbatch

@@ -135,6 +135,19 @@ def constrain(x: jax.Array, axes: Sequence[Optional[str]], rules: Optional[Rules
     return jax.lax.with_sharding_constraint(x, sharding_for(axes, mesh, rules))
 
 
+def split_ways(axes: Sequence[Optional[str]], mesh: Optional[Mesh] = None) -> int:
+    """Into how many pieces `constrain(x, axes)` cuts x, one a device: 1
+    without a mesh and inside per-shard code, whose arrays are the pieces."""
+    mesh = mesh if mesh is not None else _current_mesh()
+    if mesh is None or getattr(_constrain_disabled, "on", False):
+        return 1
+    ways = 1
+    for part in sharding_for(axes, mesh).spec:
+        for name in (part,) if isinstance(part, str) else part or ():
+            ways *= mesh.shape[name]
+    return ways
+
+
 def per_shard(fn, in_axes: Sequence[Sequence[Optional[str]]],
               out_axes: Sequence[Optional[str]], *args,
               mesh: Optional[Mesh] = None):

@@ -52,7 +52,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from ..core.config import config
 from ..core.logging import get_logger
 from ..core.metrics import Counter, Gauge, Histogram
-from ..util import slo, tracing
+from ..util import profiler, slo, tracing
 from ..models import ModelConfig, stack
 from ..models.transformer import (
     _head_logits,
@@ -3549,10 +3549,8 @@ def _tree_bytes(tree) -> int:
 def _device_free_bytes(tree) -> Optional[int]:
     """What the device that holds `tree` has left (the fullest of them, for
     a sharded tree), or None where the backend keeps no count."""
-    stats = [d.memory_stats() for d in jax.tree.leaves(tree)[0].devices()]
-    if not all(st and "bytes_limit" in st for st in stats):
-        return None
-    return min(st["bytes_limit"] - st["bytes_in_use"] for st in stats)
+    memory = profiler.device_memory(jax.tree.leaves(tree)[0].devices())
+    return None if memory is None else memory[0] - memory[1]
 
 
 def _kv_layer_groups(L: int, groups: int = 4) -> List[tuple]:

@@ -58,7 +58,8 @@ __all__ = [
     "dump_stacks", "format_stacks", "SamplingProfiler", "merge_collapsed",
     "parse_collapsed", "install_child_handlers", "dump_child",
     "toggle_child_profile", "read_child_profile", "stack_path_for",
-    "profile_path_for", "device_memory_snapshot", "update_resource_gauges",
+    "profile_path_for", "device_memory", "publish_remat_kept",
+    "device_memory_snapshot", "update_resource_gauges",
     "goodput_ledger", "ledger_from_samples", "install_auto_dump",
     "start_profile", "fetch_profile", "LEDGER_COMPONENTS",
     "rl_ledger", "rl_ledger_from_samples", "RL_COMPONENTS",
@@ -100,6 +101,11 @@ _g_rss = Gauge("process_rss_bytes",
 _g_dev_bytes = Gauge("device_memory_bytes_in_use",
                      "Backend-reported bytes in use per local device "
                      "(jax memory_stats), tagged device=")
+_g_remat_kept = Gauge("train_remat_kept_bytes",
+                      "Bytes one device keeps from a train step's forward "
+                      "for its backward under remat, by the name the layer "
+                      "loop's checkpoint may save them under, tagged name= "
+                      "(the loop traced last; 0: a name it does not save)")
 _g_live_arrays = Gauge("device_live_array_count",
                        "Number of live jax arrays held by this process")
 _g_live_bytes = Gauge("device_live_array_bytes",
@@ -443,6 +449,22 @@ def read_child_profile(pid: int, session: str,
 # ---------------------------------------------------------------------------
 # Device-memory accounting + host CPU/RSS gauges
 # ---------------------------------------------------------------------------
+
+def device_memory(devices) -> Optional[tuple]:
+    """(bytes_limit, bytes_in_use) of the fullest of `devices`, or None
+    where the backend keeps no count (the CPU) or there is no device."""
+    stats = [d.memory_stats() for d in devices]
+    if not stats or not all(st and "bytes_limit" in st for st in stats):
+        return None
+    return min(((st["bytes_limit"], st["bytes_in_use"]) for st in stats),
+               key=lambda pair: pair[0] - pair[1])
+
+
+def publish_remat_kept(kept_bytes: Dict[str, int]) -> None:
+    """Set `train_remat_kept_bytes`, name by name (0: a name not saved)."""
+    for name, nbytes in kept_bytes.items():
+        _g_remat_kept.set(nbytes, {"name": name})
+
 
 def device_memory_snapshot() -> Dict[str, Any]:
     """Per-process device-memory view, gauge-published for telemetry

@@ -1,11 +1,14 @@
 """What `cfg.remat` keeps (models/transformer.py `_remat`): the attention
-half of a layer by name, so the backward recomputes the norms and the FFN
-and runs no attention kernel or projection twice. CPU, float32 tiny presets:
-sizes and jaxprs, never times."""
+half of a layer by name, so the backward runs no attention kernel or
+projection twice, and of a dense gated FFN's `gate` and `up` products as
+many as `kept_under_remat` finds room for on the device (none where the
+backend reports no memory, as here). CPU, float32 tiny presets: sizes and
+jaxprs, never times."""
 
 import contextlib
 import dataclasses
 import functools
+import logging
 import re
 
 import jax
@@ -16,10 +19,13 @@ from jax._src.ad_checkpoint import saved_residuals
 from jax._src.core import jaxprs_in_params
 
 from ray_tpu.comm.mesh import MeshSpec, build_mesh
+from ray_tpu.core.logging import get_logger
 from ray_tpu.models import get_config, init_params, loss_fn
 from ray_tpu.models import transformer
 from ray_tpu.models.transformer import forward_pp
 from ray_tpu.ops import attention, flash_attention
+from ray_tpu.parallel.sharding import no_constrain, split_ways
+from ray_tpu.util import profiler
 
 B, T = 2, 32
 
@@ -176,3 +182,229 @@ def test_a_name_outside_a_checkpoint_lowers_to_nothing(differentiated,
         return re.sub(r"(@[A-Za-z_]+?)_\d+\b", r"\1", text)
 
     assert unnumbered(lowered()) == unnumbered(named)
+
+
+@pytest.mark.parametrize("differentiated", [False, True])
+def test_the_ffns_names_outside_a_checkpoint_lower_to_nothing(differentiated):
+    """A gradient of the training layer taken outside any checkpoint meets
+    the FFN's names and lowers to the text it has without them, but for the
+    numbers in its private functions' names: so the serve path's layers,
+    which run `_dense_ffn` too, ask for none and hold none."""
+    cfg = get_config("tiny-llama")
+    _, x, lp = _one_layer(cfg)
+
+    def op(named):
+        def op(x, lp):
+            ffn = lambda x: transformer._dense_ffn(x, lp, cfg, named)  # noqa: E731
+            if differentiated:
+                return jax.grad(lambda x: jnp.sum(ffn(x)))(x)
+            return ffn(x)
+        return op
+
+    assert str(jax.make_jaxpr(op(True))(x, lp)).count("name[") == 2
+    assert "name[" not in str(jax.make_jaxpr(
+        lambda x, lp: transformer._ffn_half(x, lp, cfg))(x, lp))
+
+    def unnumbered(text):
+        return re.sub(r"(@[A-Za-z_]+?)_\d+\b", r"\1", text)
+
+    assert unnumbered(jax.jit(op(True)).lower(x, lp).as_text()) \
+        == unnumbered(jax.jit(op(False)).lower(x, lp).as_text())
+
+
+# -- the rule ----------------------------------------------------------------
+
+V5E = 16_909_000_000  # `bytes_limit` of one TPU v5e (chip, PR 45)
+TODAY = transformer._KEPT_UNDER_REMAT
+GATE, UP = transformer._FFN_NAMES
+
+
+def _cell(**over):
+    """The train cell's model: Mistral-7B's widths at 8 layers, bfloat16."""
+    return get_config("llama3-8b", **{
+        "n_layers": 8, "vocab_size": 32768, "max_seq_len": 8192,
+        "dtype": "bfloat16", **over})
+
+
+# case -> (model, rows x tokens on a device, bytes of a parameter, (limit,
+# in use) or None, what is kept of the FFN). The arithmetic, in GB
+# (`kept_under_remat`): what is on the device or the parameters + a gradient
+# + the stacks of the attention half and the carry + the float32 logits
+# twice, then one stack of layers x positions x d_ff a name, under
+# 0.9 x 16.909 = 15.218.
+RULE_CASES = {
+    # 4.07 + 4.03 + 2.42 + 2.15 = 12.67; + 1.88 = 14.55; + 1.88 = 16.43
+    "the_cell": (_cell(), 8192, 2, (V5E, 4_070_000_000), (GATE,)),
+    # the probe of the benchmark's check: parameters alone on the device
+    "the_cell_before_its_state": (_cell(), 8192, 2, (V5E, 0), (GATE,)),
+    # 14.5 + 14.5 + 9.7 + 2.1 = 40.8: no depth of 32 fits one chip at all
+    "the_cell_at_32_layers": (_cell(n_layers=32), 8192, 2, (V5E, 0), ()),
+    # chip_smoke.py's Plan, 2 x 2048 at a vocabulary of 128256:
+    # 5.59 + 5.59 + 1.21 + 4.20 = 16.6 (the compiler's total is 12.62 and
+    # 13.56 with `gate`: the estimate leans high where logits are large)
+    "chip_smokes_plan": (get_config("llama3-8b", n_layers=8), 4096, 2,
+                         (V5E, 0), ()),
+    # on two chips' worth of memory both stacks fit: 12.63 + 3.76 under 30.4
+    "the_cell_on_twice_the_memory": (_cell(), 8192, 2, (2 * V5E, 0),
+                                     (GATE, UP)),
+    # BASELINE.md's 4 x 2048 with bfloat16 masters: 3.66 + 3.66 + 4.55 +
+    # 2.10 = 13.97, and a stack is 2.72 (the compiler's totals: 13.82, and
+    # 15.49 with `gate`, which would leave 8% of the limit)
+    "llama_2b": (get_config("llama-2b"), 4 * 2048, 2, (V5E, 0), ()),
+    # half the rows: 10.65 + 1.36 + 1.36 = 13.36
+    "llama_2b_two_rows": (get_config("llama-2b"), 2 * 2048, 2, (V5E, 0),
+                          (GATE, UP)),
+    # float32 masters: 7.32 + 7.32 alone are 14.6
+    "llama_2b_float32": (get_config("llama-2b"), 4 * 2048, 4, (V5E, 0), ()),
+    "llama3_8b_whole": (get_config("llama3-8b"), 8192, 2, (V5E, 0), ()),
+    "no_memory_reported": (_cell(), 8192, 2, None, ()),
+    "experts": (get_config("mixtral-8x7b", n_layers=1), 8192, 2,
+                (4 * V5E, 0), ()),
+    "no_gate": (get_config("gpt2-125m"), 1024, 2, (4 * V5E, 0), ()),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RULE_CASES))
+def test_the_rule_keeps_what_fits(case):
+    cfg, positions, itemsize, memory, ffn = RULE_CASES[case]
+    ask = dict(layers=cfg.n_layers, positions=positions,
+               param_itemsize=itemsize, memory=memory)
+    names, held = transformer.kept_under_remat(cfg, **ask)
+    assert names == TODAY + ffn
+    assert (names, held) == transformer.kept_under_remat(cfg, **ask)  # pure
+    if memory is not None and not cfg.is_moe and ffn != ():
+        stack = cfg.n_layers * positions * cfg.d_ff * 2
+        free = memory[0] - held - len(ffn) * stack
+        assert free >= transformer._REMAT_MARGIN * memory[0]
+        # and one more stack would not leave the margin, or there is none
+        assert len(ffn) == 2 or free - stack < transformer._REMAT_MARGIN * memory[0]
+
+
+def test_the_rules_estimate_leans_a_little_over_the_chips_reading():
+    """`memory_peak_bytes` of the train cell on the chip: 12.20 GB with the
+    attention half alone (PR 31, set C) and 14.08 GB with `gate` (set D)."""
+    cfg, positions, itemsize, memory, _ = RULE_CASES["the_cell"]
+    _, held = transformer.kept_under_remat(
+        cfg, layers=8, positions=positions, param_itemsize=itemsize,
+        memory=memory)
+    assert 0 <= held - 12.20e9 < 0.6e9
+    assert 0 <= held + 8 * positions * cfg.d_ff * 2 - 14.08e9 < 0.6e9
+
+
+def _room_for(cfg, params, x, stacks):
+    """-> a (limit, in use) under which the rule keeps `stacks` of the FFN's
+    names for this loop, and not one more."""
+    layers, (B, T, _) = params["layers"]["wq"].shape[0], x.shape
+    _, held = transformer.kept_under_remat(
+        cfg, layers=layers, positions=B * T, param_itemsize=4, memory=(1, 0))
+    stack = layers * B * T * cfg.d_ff * jnp.dtype(cfg.dtype).itemsize
+    return (int((held + (stacks + 0.5) * stack)
+                / (1 - transformer._REMAT_MARGIN)), 0)
+
+
+@pytest.mark.parametrize("stacks", [0, 1, 2])
+def test_a_checkpointed_dense_layer_keeps_the_products_the_device_has_room_for(
+        stacks, monkeypatch):
+    cfg = dataclasses.replace(get_config("tiny-llama"), remat=True)
+    H, KVH, hd, D, F = cfg.n_heads, cfg.kv_heads, cfg.hdim, cfg.d_model, cfg.d_ff
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    body, x, lp = _one_layer(cfg)
+    monkeypatch.setattr(profiler, "device_memory",
+                        lambda devices: _room_for(cfg, params, x, stacks))
+    names = transformer._kept_now(cfg, params["layers"], x)
+    assert names == TODAY + transformer._FFN_NAMES[:stacks]
+    checkpointed = transformer._remat(body, cfg, names)
+    kept = [aval.shape for aval, why in saved_residuals(checkpointed, x, lp)
+            if not why.startswith(("from the argument", "from a constant"))]
+    assert sorted(kept) == sorted([
+        (B, T, H, hd), (B, T, KVH, hd), (B, T, KVH, hd),
+        (B, H, T, hd), (B, H, T), (B, T, D)] + [(B, T, F)] * stacks), kept
+
+    # products of [B, T, d_ff] in the gradient: gate and up in the forward,
+    # the gradient into their gated product, and each of the two AGAIN in
+    # the backward unless its name is kept
+    def products(jaxpr):
+        return sum(
+            (eqn.primitive.name == "dot_general"
+             and eqn.outvars[0].aval.shape == (B, T, F))
+            + sum(products(sub) for sub in jaxprs_in_params(eqn.params))
+            for eqn in jaxpr.eqns)
+
+    grad = jax.make_jaxpr(jax.grad(
+        lambda x, lp: jnp.sum(checkpointed(x, lp)[0]), argnums=(0, 1)))(x, lp)
+    assert products(grad.jaxpr) == 5 - stacks
+    # the gauge says what the loop keeps on a device, name by name
+    layers = cfg.n_layers
+    for i, name in enumerate(transformer._FFN_NAMES):
+        assert profiler._g_remat_kept.get({"name": name}) == (
+            layers * B * T * F * 4 if i < stacks else 0)
+    assert profiler._g_remat_kept.get({"name": "attn_half"}) == layers * B * T * D * 4
+
+
+@pytest.mark.parametrize("stacks", [1, 2])
+def test_gradients_with_the_ffn_kept_are_those_without(stacks, monkeypatch):
+    cfg = dataclasses.replace(get_config("tiny-llama"), n_layers=4)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    batch = _batch(cfg, rows=4)
+    x = jnp.zeros((4, T, cfg.d_model))
+    monkeypatch.setattr(profiler, "device_memory",
+                        lambda devices: _room_for(cfg, params, x, stacks))
+    got = {remat: jax.jit(jax.grad(lambda p: loss_fn(
+        p, batch, dataclasses.replace(cfg, remat=remat))[0]))(params)
+        for remat in (True, False)}
+    # the loop that was differentiated kept them
+    assert profiler._g_remat_kept.get({"name": GATE}) > 0
+    assert (profiler._g_remat_kept.get({"name": UP}) > 0) == (stacks == 2)
+    flat = jax.tree.leaves(got[False])
+    assert any(float(jnp.max(jnp.abs(g))) > 0 for g in flat)
+    for kept, plain in zip(jax.tree.leaves(got[True]), flat):
+        np.testing.assert_allclose(kept, plain, rtol=1e-5, atol=1e-6)
+
+
+def test_the_decision_is_logged_with_its_numbers(monkeypatch, caplog):
+    cfg = dataclasses.replace(get_config("tiny-llama"), remat=True)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    _, x, _ = _one_layer(cfg)
+    room = _room_for(cfg, params, x, 1)
+    monkeypatch.setattr(profiler, "device_memory", lambda devices: room)
+    logger = get_logger("models.transformer")  # propagates nothing: its own
+    logger.addHandler(caplog.handler)
+    try:
+        with caplog.at_level(logging.INFO, logger=logger.name):
+            transformer._kept_now(cfg, params["layers"], x)
+    finally:
+        logger.removeHandler(caplog.handler)
+    (line,) = [r.getMessage() for r in caplog.records]
+    assert "ffn_gate" in line and "ffn_up" not in line
+    assert "%.3f GB" % (room[0] / 1e9) in line and "10%" in line
+
+
+# mesh axes -> the pieces a [batch, seq, ..] activation is cut into
+SPLITS = {"dp2_fsdp2": (dict(dp=2, fsdp=2), 4), "dp2_sp2": (dict(dp=2, sp=2), 4),
+          "tp4": (dict(tp=4), 1), "dp2_pp2": (dict(dp=2, pp=2), 2)}
+
+
+@pytest.mark.parametrize("case", sorted(SPLITS))
+def test_the_rule_counts_the_positions_one_device_holds(case, cpu_mesh_devices):
+    axes, ways = SPLITS[case]
+    mesh = build_mesh(MeshSpec.create(**axes), devices=cpu_mesh_devices[:4])
+    assert split_ways(("batch", "seq"), mesh) == ways
+    assert split_ways(("batch", "seq", "mlp"), mesh) == ways * axes.get("tp", 1)
+    with no_constrain():  # per-shard code holds the pieces already
+        assert split_ways(("batch", "seq"), mesh) == 1
+
+
+def test_device_memory_is_the_fullest_devices():
+    class Device:
+        def __init__(self, stats):
+            self.stats = stats
+
+        def memory_stats(self):
+            return self.stats
+
+    assert profiler.device_memory(jax.local_devices()[:1]) is None  # the CPU
+    assert profiler.device_memory([]) is None
+    a = Device({"bytes_limit": 100, "bytes_in_use": 10})
+    b = Device({"bytes_limit": 100, "bytes_in_use": 60})
+    assert profiler.device_memory([a, b]) == (100, 60)
+    assert profiler.device_memory([a, Device(None)]) is None

@@ -443,21 +443,12 @@ def test_decode_span_under_tp4_updates_its_shard_of_the_pool_in_place(
         text)
 
 
-def test_train_step_backward_runs_no_flash_forward(topo, no_persistent_cache):
-    """Two layers of the train cell's widths, one row of 8192, bf16, the
-    factored optimizer: under `remat` the scan's checkpoint keeps the flash
-    kernel's output and log-sum-exp by name (models/transformer.py
-    `_remat`), so the compiled step runs the forward kernel ONCE a layer.
-    The blanket checkpoint of PR 30's parent read 2 here: one in the
-    forward loop's body, one beside `flash_bwd_dq` in the backward's."""
-    from ray_tpu.models import get_config, init_params
-    from ray_tpu.train.lm import make_optimizer, make_train_step
+def _train_step_shapes(cfg, one_chip, rows, T):
+    """-> (the factored optimizer, a bf16 train state and a batch of rows x
+    T as shapes on one described chip): the train cell's recipe."""
+    from ray_tpu.models import init_params
+    from ray_tpu.train.lm import make_optimizer
 
-    T = 8192
-    one_chip = SingleDeviceSharding(topo.devices[0])
-    cfg = get_config("llama3-8b", n_layers=LAYERS, vocab_size=32768,
-                     max_seq_len=T, rope_theta=1e6, dtype="bfloat16")
-    assert cfg.remat
     opt = make_optimizer(learning_rate=5e-6, warmup_steps=1, factored=True)
 
     def state_of(key):
@@ -468,8 +459,27 @@ def test_train_step_backward_runs_no_flash_forward(topo, no_persistent_cache):
     state = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         jax.eval_shape(state_of, jax.random.PRNGKey(0)))
-    batch = {k: jax.ShapeDtypeStruct((1, T), I32, sharding=one_chip)
+    batch = {k: jax.ShapeDtypeStruct((rows, T), I32, sharding=one_chip)
              for k in ("tokens", "targets")}
+    return opt, state, batch
+
+
+def test_train_step_backward_runs_no_flash_forward(topo, no_persistent_cache):
+    """Two layers of the train cell's widths, one row of 8192, bf16, the
+    factored optimizer: under `remat` the scan's checkpoint keeps the flash
+    kernel's output and log-sum-exp by name (models/transformer.py
+    `_remat`), so the compiled step runs the forward kernel ONCE a layer.
+    The blanket checkpoint of PR 30's parent read 2 here: one in the
+    forward loop's body, one beside `flash_bwd_dq` in the backward's."""
+    from ray_tpu.models import get_config
+    from ray_tpu.train.lm import make_train_step
+
+    T = 8192
+    cfg = get_config("llama3-8b", n_layers=LAYERS, vocab_size=32768,
+                     max_seq_len=T, rope_theta=1e6, dtype="bfloat16")
+    assert cfg.remat
+    opt, state, batch = _train_step_shapes(
+        cfg, SingleDeviceSharding(topo.devices[0]), 1, T)
     text = jax.jit(make_train_step(cfg, opt), donate_argnums=0).lower(
         state, batch).compile().as_text()
 
@@ -480,6 +490,52 @@ def test_train_step_backward_runs_no_flash_forward(topo, no_persistent_cache):
         for block in re.split(r"\n}\n", text)) if kernels]
     assert sorted(loops, key=len) == [  # two loops, not unrolled
         {"flash_fwd": 1}, {"flash_bwd_dq": 1, "flash_bwd_dkv": 1}]
+
+
+def test_the_train_cells_step_fits_with_the_gate_kept(
+        topo, no_persistent_cache, monkeypatch, tmp_path):
+    """`mistral-7b.train-packed` as the benchmark sizes it (8 layers of the
+    published widths, 1 x 8192, bf16 masters, the factored optimizer), the
+    rule given the chip's limit and the state's bytes in the place of the
+    memory this backend does not report: it keeps the FFN's `gate` and not
+    `up` (models/transformer.py `kept_under_remat`), the chip's compiler
+    accepts the step, the buffer assignment's total (what the chip holds:
+    `memory_analysis()` counts kept stacks twice) is under 90% of the
+    limit, and the backward's body runs ONE product against `w_gate`'s
+    stack where the forward's has its one: 5 -> 4 products of
+    [8192, 14336] in the step."""
+    from ray_tpu.models import get_config, transformer
+    from ray_tpu.train.lm import make_train_step
+    from ray_tpu.util import profiler
+
+    T, limit = 8192, 16_909_000_000
+    cfg = get_config("llama3-8b", n_layers=8, vocab_size=32768,
+                     max_seq_len=T, rope_theta=1e6, dtype="bfloat16")
+    opt, state, batch = _train_step_shapes(
+        cfg, SingleDeviceSharding(topo.devices[0]), 1, T)
+    in_use = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(state))
+    monkeypatch.setattr(profiler, "device_memory",
+                        lambda devices: (limit, in_use))
+    compiled = jax.jit(make_train_step(cfg, opt), donate_argnums=0).lower(
+        state, batch).compile(compiler_options={
+            "xla_dump_to": str(tmp_path), "xla_dump_hlo_as_text": True})
+    gate, up = transformer._FFN_NAMES
+    stack = 8 * T * cfg.d_ff * 2
+    assert profiler._g_remat_kept.get({"name": gate}) == stack
+    assert profiler._g_remat_kept.get({"name": up}) == 0
+    report = max(tmp_path.glob("*memory-usage-report.txt"),
+                 key=lambda f: f.stat().st_size)
+    total = int(re.match(r"Total bytes used: (\d+)",
+                         report.read_text()).group(1))
+    print(f"buffer assignment: {total / 1e9:.3f} GB of {limit / 1e9:.3f}")
+    assert 13.9e9 < total < 0.9 * limit
+    text = compiled.as_text()
+    # the kept stack, written by the forward loop and read by the backward's
+    assert "bf16[8,1,8192,14336]" in text
+    # gate and up in the forward's body; in the backward's up again and the
+    # gradient into the gated product (a fifth, gate again, with today's set)
+    assert len(re.findall(
+        r"= bf16\[8192,14336\]\S* convolution\(", text)) == 4
 
 
 @pytest.mark.parametrize(
