@@ -25,7 +25,11 @@ from typing import Optional, Tuple
 # what a layer's mixer can be when a StackConfig's `layer_kinds` names them
 # (models/stack.py); a plain ModelConfig's layers are all "attn"
 LAYER_KINDS = ("attn", "conv", "mamba", "window", "full", "gmu", "cross",
-               "gdn", "mla2", "swa", "ssd")
+               "gdn", "mla2", "swa", "ssd", "mla")
+# the kinds whose attention is latent (MLA): a token's row in THE pool is its
+# latent beside the rotary key the heads share, and the pool holds no values.
+# `mla2` writes two rows a layer (two attentions), `mla` one
+LATENT_KINDS = {"mla2": 2, "mla": 1}
 # the second halves' gated activations: down(act(gate x) * (up x))
 GATED_ACTIVATIONS = ("swiglu", "reglu")
 # a token's row in a pool of latents: the latent and the shared rotary key
@@ -115,6 +119,7 @@ class ModelConfig:
     latent_cache = False
     router_input = "ffn"
     window_paged = False
+    d_ff_shared = 0
 
     @property
     def experts_routed(self) -> int:
@@ -245,7 +250,18 @@ class StackConfig(ModelConfig):
     # of THE pool's row shape (`window_paged`); its experts are many and
     # small, gated by a ReLU (`activation="reglu"`), and its router reads
     # the layer's input stream, before the first norm and the attention
-    # (`router_input="layer"`).
+    # (`router_input="layer"`). The sixth: "mla": latent attention as ONE
+    # mixer a layer, followed by the layer's own second half like any other
+    # kind: a leading dense layer (`n_dense_layers`) and then experts chosen
+    # by sigmoid score + bias, renormalised and scaled, beside SHARED experts
+    # that every token passes through with weight 1 (`d_ff_shared`: one gated
+    # FFN of that width; n shared experts of width w are one of n x w). Its
+    # queries are one projection where `q_lora_rank` is 0: no bottleneck and
+    # no norm on the query side. What the fourth family's rules say of latents
+    # (`latent_cache`, `latent_row`, `cache_dims`, one pool and no values, no
+    # other kind beside them in a stack: two shapes of pool rows) are the
+    # LATENT kinds' together (`LATENT_KINDS`); what is `mla2`'s alone is the
+    # double block, its bottleneck on the queries and the experts it carries.
     layer_kinds: Tuple[str, ...] = ()
     window: int = 0
     ssm_inner: int = 0        # mamba / gmu inner width
@@ -267,6 +283,10 @@ class StackConfig(ModelConfig):
     gdn_neg_eigval: bool = False
     n_dense_layers: int = 0   # leading layers whose second half is dense
     d_ff_expert: int = 0      # an expert's width (0: d_ff)
+    # the shared experts' width, all of them together as ONE gated FFN that
+    # every token of an expert layer passes through beside its routed
+    # experts, with weight 1 (0: none, and nothing is emitted)
+    d_ff_shared: int = 0
     # "softmax": top k of the logits, softmax over the chosen. "sigmoid":
     # sigmoid scores, the choice made on score + a per-expert bias, the
     # weights the chosen scores WITHOUT it, over their sum (+ 1e-6) if
@@ -283,8 +303,10 @@ class StackConfig(ModelConfig):
     # stream after the mixer, normed); "layer": the layer's input stream,
     # before its first norm, so the choice is made before the mixer runs
     router_input: str = "ffn"
-    # "mla2": the five sizes of latent attention, and whether the two
-    # normed bottlenecks are scaled by sqrt(d_model / rank)
+    # the latent kinds: the five sizes of latent attention (`q_lora_rank`
+    # 0: the queries are one projection, no bottleneck and no norm: "mla"
+    # alone), and whether the normed bottlenecks are scaled by
+    # sqrt(d_model / rank)
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_dim: int = 0
@@ -348,24 +370,32 @@ class StackConfig(ModelConfig):
         if self.router_input not in ("ffn", "layer"):
             raise ValueError(f"unknown router_input {self.router_input!r}")
         if self.router_input == "layer" and (
-                "mla2" in kinds or self.post_norm or self.n_dense_layers):
+                "mla2" in kinds or self.post_norm or self.n_dense_layers
+                or self.d_ff_shared):
             raise ValueError(
                 'router_input="layer" is written for layers of one mixer and '
                 "one expert half, norms first")
-        if "mla2" in kinds:
-            if set(kinds) != {"mla2"}:
-                raise ValueError("'mla2' layers beside other kinds in one "
-                                 "stack: two shapes of pool rows")
-            if not (self.q_lora_rank and self.kv_lora_rank
-                    and self.qk_nope_dim and self.qk_rope_dim
-                    and self.v_head_dim) or self.qk_rope_dim % 2:
+        latent = sorted(set(kinds) & set(LATENT_KINDS))
+        if latent:
+            if len(set(kinds)) > 1:
+                raise ValueError(f"{latent[0]!r} layers beside other kinds in "
+                                 "one stack: two shapes of pool rows")
+            if not (self.kv_lora_rank and self.qk_nope_dim
+                    and self.qk_rope_dim and self.v_head_dim
+                    ) or self.qk_rope_dim % 2:
                 raise ValueError(
-                    "mla2 layers need `q_lora_rank`, `kv_lora_rank`, "
-                    "`qk_nope_dim`, an even `qk_rope_dim` and `v_head_dim`")
-            if not self.is_moe or self.n_dense_layers:
+                    "latent attention needs `kv_lora_rank`, `qk_nope_dim`, "
+                    "an even `qk_rope_dim` and `v_head_dim`")
+        if "mla2" in kinds:
+            if not self.q_lora_rank:
+                raise ValueError("mla2 layers need `q_lora_rank`")
+            if not self.is_moe or self.n_dense_layers or self.d_ff_shared:
                 raise ValueError("an mla2 layer carries its experts: "
-                                 "`num_experts` > 0 and no leading dense "
-                                 "layers")
+                                 "`num_experts` > 0, no leading dense "
+                                 "layers and no shared experts")
+        if self.d_ff_shared and not self.is_moe:
+            raise ValueError("shared experts stand beside routed ones: "
+                             "`d_ff_shared` needs `num_experts` > 0")
         if self.experts_first + self.num_experts > self.experts_routed:
             raise ValueError(
                 f"experts {self.experts_first}.. of {self.num_experts} held "
@@ -418,7 +448,7 @@ class StackConfig(ModelConfig):
     def latent_cache(self) -> bool:
         """The pool is ONE array of latent rows (there is no pool of
         values: a value is its row's leading `kv_lora_rank` lanes)."""
-        return "mla2" in self.layer_kinds
+        return bool(set(LATENT_KINDS) & set(self.layer_kinds))
 
     @property
     def latent_row(self) -> int:
@@ -475,8 +505,9 @@ class StackConfig(ModelConfig):
         or the "full" layers', a differential pair a head."""
         if "attn" in self.layer_kinds:
             return self.count("attn"), self.kv_heads, self.hdim
-        if self.latent_cache:  # two attentions a layer, one row a token
-            return 2 * self.count("mla2"), 1, self.latent_row
+        if self.latent_cache:  # one row a token and attention
+            return (sum(n * self.count(k) for k, n in LATENT_KINDS.items()),
+                    1, self.latent_row)
         return self.count("full"), self.pool_heads, self.pool_dim
 
     def _mixer_params(self, kind: str) -> int:
@@ -503,12 +534,16 @@ class StackConfig(ModelConfig):
             # the gated norm; the out-projection
             return (D * (Di + conv + Hs) + (self.ssm_conv + 1) * conv
                     + 3 * Hs + Di + Di * D)
-        if kind == "mla2":
+        if kind in LATENT_KINDS:
             ql, kl = self.q_lora_rank, self.kv_lora_rank
             qk = self.qk_nope_dim + self.qk_rope_dim
-            mla = (D * ql + ql + ql * H * qk + D * (kl + self.qk_rope_dim)
+            # the queries through a normed bottleneck, or one projection
+            q = D * ql + ql + ql * H * qk if ql else D * H * qk
+            mla = (q + D * (kl + self.qk_rope_dim)
                    + kl + kl * H * (self.qk_nope_dim + self.v_head_dim)
                    + H * self.v_head_dim * D)
+            if kind == "mla":
+                return mla
             # two attentions and two dense FFNs with a norm before each;
             # `param_count` adds the experts and the layer's two norms
             return 2 * (mla + 3 * D * self.d_ff + D)
@@ -529,7 +564,8 @@ class StackConfig(ModelConfig):
         W = self.router_width
         half = {"ffn": 3 * D * F,
                 "moe": E * 3 * D * Fe + D * W
-                + (W if self.router != "softmax" else 0)}
+                + (W if self.router != "softmax" else 0)
+                + 3 * D * self.d_ff_shared}
         return (sum(self._mixer_params(k) for k in self.layer_kinds)
                 + sum(half[h] + 2 * norm for h in self.second_halves)
                 + V * D * (1 if self.tie_embeddings else 2) + norm)
@@ -851,4 +887,37 @@ register(StackConfig(
     norm_topk=False, routed_scale=6.0, n_routed_experts=8, experts_first=0,
     experts_zero=4, q_lora_rank=32, kv_lora_rank=16, qk_nope_dim=16,
     qk_rope_dim=8, v_head_dim=16, mla_scale_lora=True,
+))
+
+register(StackConfig(
+    name="kanana-2-30b-a3b",
+    # kakaocorp/kanana-2-30b-a3b-instruct-2601 (the DeepSeek-V3 block): 30.7 B
+    # parameters, 3 B active a token: 48 layers of latent attention (32
+    # heads, queries projected directly, a 512 + 64 latent row a token), one
+    # leading dense SwiGLU of 6144 and then 128 experts of 768, 6 a token by
+    # sigmoid score + bias, renormalised, times 2.448, beside two shared
+    # experts (one SwiGLU of 1536) that every token passes through
+    vocab_size=128256,
+    d_model=2048, n_layers=48, n_heads=32, d_ff=6144, max_seq_len=32768,
+    norm="rmsnorm", activation="swiglu", positional="none",
+    rope_theta=1000000.0, tie_embeddings=False, norm_eps=1e-6,
+    num_experts=128, num_selected_experts=6, capacity_factor=128 / 6,
+    layer_kinds=("mla",) * 48, n_dense_layers=1, d_ff_expert=768,
+    d_ff_shared=1536, router="sigmoid", norm_topk=True, routed_scale=2.448,
+    kv_lora_rank=512, qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128,
+))
+
+register(StackConfig(
+    name="tiny-kanana",
+    # the same stack's shape at toy widths: one dense layer, then three
+    # expert layers of 8 experts top 3 beside a shared pair
+    vocab_size=512,
+    d_model=64, n_layers=4, n_heads=4, d_ff=128, max_seq_len=128,
+    dtype="float32", remat=False,
+    norm="rmsnorm", activation="swiglu", positional="none",
+    rope_theta=10000.0, tie_embeddings=False, norm_eps=1e-6,
+    num_experts=8, num_selected_experts=3, capacity_factor=8 / 3,
+    layer_kinds=("mla",) * 4, n_dense_layers=1, d_ff_expert=32,
+    d_ff_shared=64, router="sigmoid", norm_topk=True, routed_scale=2.448,
+    kv_lora_rank=16, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
 ))

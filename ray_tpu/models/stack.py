@@ -52,6 +52,14 @@ their sublayers, `x + norm(Mix(x))` and `x + norm(FFN(x))`. Mix is one of
           step in the absorbed form (the query carried into the latent's
           space, nothing up-projected per cached token). The experts are a
           share layer (models/transformer.py `_moe_ffn_dropless_ids`)
+  mla     that latent attention as ONE mixer a layer, then the layer's own
+          second half as for every other kind (a leading dense FFN, or the
+          experts beside the shared experts, `cfg.d_ff_shared`); where
+          `cfg.q_lora_rank` is 0 the queries are one projection, with no
+          bottleneck and no norm. Its rows are the pool's, one a layer.
+          The kernels run two shapes: 64 query rows a sequence (109
+          operations a byte of row) under `mla2` as published, 32 (54)
+          under `mla`
 
 Each mixer is written ONCE, over a small state interface (a *mode*), and
 `forward`, the engine's bucket prefill, its chunk program, its decode
@@ -150,7 +158,7 @@ Params = Dict[str, Any]
 _F32 = jnp.float32
 # kinds that own rows of state arrays or pools, counted as layers go by
 _COUNTED = ("attn", "conv", "mamba", "window", "full", "gdn", "mla2", "swa",
-            "ssd")
+            "ssd", "mla")
 # an expert layer's leaves that a step reads where they lie (`run_stack`)
 _EXPERT_LEAVES = ("w_in", "w_gate", "w_out")
 
@@ -178,28 +186,41 @@ def layer_shapes(cfg: ModelConfig, kind: str,
                    w_gate=((E, D, Fe), "w"), w_out=((E, Fe, D), "out"))
         if cfg.router != "softmax":
             out.update(router_bias=((W,), "zero"))
+        if cfg.d_ff_shared:  # ONE gated FFN every token passes through
+            Fs = cfg.d_ff_shared
+            out.update(sh_in=((D, Fs), "w"), sh_gate=((D, Fs), "w"),
+                       sh_out=((Fs, D), "out"))
     else:
         out.update(w_in=((D, F), "w"), w_gate=((D, F), "w"),
                    w_out=((F, D), "out"))
-    if kind == "mla2":
-        # the two blocks' leaves are named for the block (`wq_a0`,
-        # `wq_a1`): each is consumed whole where the layer scan hands it
-        # over (a slice of a leaf that led with the block was a copy of the
-        # block's weights every step, chip, PR 39); the norms are each
-        # block's own (before its attention, before its FFN); the latent's
-        # and the rotary key's down-projections, and the keys' and values'
-        # up-projections, are leaves of their own for the same reason
+    if kind in ("mla", "mla2"):
+        # one latent attention's leaves: the latent's and the rotary key's
+        # down-projections, and the keys' and values' up-projections, are
+        # leaves of their own (each is consumed whole where the layer scan
+        # hands it over; a slice of a joint leaf was a copy of the weights
+        # every step, chip, PR 39); the queries through a bottleneck and
+        # its norm, or in one projection where `q_lora_rank` is 0
         ql, kl = cfg.q_lora_rank, cfg.kv_lora_rank
         N, R, V = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+        latent = dict(wkv_a=((D, kl), "w"), wkr=((D, R), "w"),
+                      kv_ln=((kl,), "one"), wk_b=((kl, H, N), "w"),
+                      wv_b=((kl, H, V), "w"), wo=((H, V, D), "out"))
+        if ql:
+            latent.update(wq_a=((D, ql), "w"), q_ln=((ql,), "one"),
+                          wq_b=((ql, H, N + R), "w"))
+        else:
+            latent.update(wq=((D, H, N + R), "w"))
+    if kind == "mla2":
+        # the two blocks' leaves are named for the block (`wq_a0`,
+        # `wq_a1`), for the reason above; the norms are each block's own
+        # (before its attention, before its FFN)
         del out["ln1"], out["ln2"]
-        block = dict(a_ln=((D,), "one"), p_ln=((D,), "one"),
-                     wq_a=((D, ql), "w"), q_ln=((ql,), "one"),
-                     wq_b=((ql, H, N + R), "w"), wkv_a=((D, kl), "w"),
-                     wkr=((D, R), "w"), kv_ln=((kl,), "one"),
-                     wk_b=((kl, H, N), "w"), wv_b=((kl, H, V), "w"),
-                     wo=((H, V, D), "out"), f_in=((D, F), "w"),
-                     f_gate=((D, F), "w"), f_out=((F, D), "out"))
+        block = dict(a_ln=((D,), "one"), p_ln=((D,), "one"), **latent,
+                     f_in=((D, F), "w"), f_gate=((D, F), "w"),
+                     f_out=((F, D), "out"))
         out.update({f"{n}{i}": v for i in (0, 1) for n, v in block.items()})
+    elif kind == "mla":
+        out.update(latent)
     elif kind in ("attn", "swa"):
         out.update(wq=((D, H, hd), "w"), wk=((D, KVH, hd), "w"),
                    wv=((D, KVH, hd), "w"), wo=((H, hd, D), "out"))
@@ -1047,7 +1068,10 @@ def _unabsorb(o, wv_b):
 
 def _mla(h, lp, cfg, fi, mode, carry):
     """One block's latent attention over h [B,T,D] (`lp`: the block's own
-    leaves, by their plain names); its rows are row `fi` of the pool."""
+    leaves, by their plain names); its rows are row `fi` of the pool. The
+    queries come through a normed bottleneck (`wq_a`, `q_ln`, `wq_b`) or,
+    where the model has none (`cfg.q_lora_rank` 0), from ONE projection
+    `wq`."""
     dtype = h.dtype
     D, N = cfg.d_model, cfg.qk_nope_dim
     eps, theta = cfg.norm_eps, cfg.rope_theta
@@ -1055,9 +1079,12 @@ def _mla(h, lp, cfg, fi, mode, carry):
     def up(rank):
         return (D / rank) ** 0.5 if cfg.mla_scale_lora else 1.0
 
-    cq = jnp.einsum("btd,dr->btr", h, lp["wq_a"].astype(dtype))
-    cq = _scaled_rms(cq, lp["q_ln"], eps, up(cfg.q_lora_rank))
-    q = jnp.einsum("btr,rhk->bthk", cq, lp["wq_b"].astype(dtype))
+    if cfg.q_lora_rank:
+        cq = jnp.einsum("btd,dr->btr", h, lp["wq_a"].astype(dtype))
+        cq = _scaled_rms(cq, lp["q_ln"], eps, up(cfg.q_lora_rank))
+        q = jnp.einsum("btr,rhk->bthk", cq, lp["wq_b"].astype(dtype))
+    else:
+        q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(dtype))
     c = _scaled_rms(jnp.einsum("btd,dr->btr", h, lp["wkv_a"].astype(dtype)),
                     lp["kv_ln"], eps, up(cfg.kv_lora_rank))
     k_r = jnp.einsum("btd,dr->btr", h, lp["wkr"].astype(dtype))
@@ -1214,6 +1241,8 @@ def _layer(x, lp, cfg, kind, half, layer, idx, mode, carry):
         h = x if cfg.post_norm else _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
         if kind in ("attn", "swa"):
             o, carry = _attn(h, lp, cfg, idx, mode, carry, kind == "swa")
+        elif kind == "mla":
+            o, carry = _mla(h, lp, cfg, idx, mode, carry)
         elif kind == "gdn":
             o, carry = _gdn(h, lp, cfg, idx, mode, carry)
         elif kind == "ssd":
@@ -1242,8 +1271,8 @@ def run_stack(layers, x, cfg: ModelConfig, mode, carry):
     one entry a segment of `cfg.segments()` (a tuple with one stacked dict
     per layer of the period), or the one-block models' stacked dict, their
     one segment. A segment of r > 1 periods is one `lax.scan`; which attn,
-    conv, mamba, window, full, gdn or ssd layer a layer is (its row in the
-    state arrays and pools) is counted from the layers before it."""
+    conv, mamba, window, full, gdn, ssd or latent layer a layer is (its row
+    in the state arrays and pools) is counted from the layers before it."""
     if isinstance(layers, dict):
         layers = [(layers,)]
     seen = dict.fromkeys(_COUNTED, 0)

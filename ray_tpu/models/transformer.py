@@ -276,6 +276,19 @@ def _dense_ffn(x, lp, cfg, named=False):
     return constrain(out, ("batch", "seq", "embed"))
 
 
+def _shared_experts(x, lp, cfg):
+    """The shared experts over the normed rows x [B,T,D] of an expert
+    layer: ONE gated FFN of width `cfg.d_ff_shared` (n shared experts of
+    width w, each with weight 1, are one of n x w: `sh_in`, `sh_gate`
+    [D, n w] are their columns side by side and `sh_out` [n w, D] their
+    rows) that every row passes through, whatever form the routed experts
+    beside it take: a dense product of its own, one more pass over the
+    rows and the weights."""
+    with jax.named_scope("shared_experts"):
+        return _dense_ffn(x, {"w_in": lp["sh_in"], "w_gate": lp["sh_gate"],
+                              "w_out": lp["sh_out"]}, cfg)
+
+
 def moe_capacity(cfg, T: int) -> int:
     """Slots each expert has for a batch row of T tokens: the capacity
     factor's share, a multiple of 4 for tiling, T * k at the most. Static.
@@ -339,7 +352,10 @@ def moe_rows_computed(cfg, B: int, T: int, mesh=None, tokens=None) -> int:
     the rows hold a token, for a bucket or a chunk of the serve path
     (`moe_seq_groups`): its rows are the passes of the experts its tokens
     chose, of which the host knows a BOUND (`ops/moe.py groups_rows_bound`:
-    never less than the kernel's passes cover)."""
+    never less than the kernel's passes cover). The ROUTED experts' rows
+    alone: the shared experts (`cfg.d_ff_shared`) run over every row of
+    every program once, which the engine counts beside these
+    (`serve_moe_shared_rows`)."""
     if tokens is not None and moe_seq_groups(cfg, B, T, mesh):
         # a share layer: a token's choices that can fall on a held expert
         k = min(cfg.num_selected_experts, cfg.num_experts)
@@ -662,7 +678,8 @@ def _moe_ffn_gather(x, lp, cfg, gate=None):
 
 def _ffn_half(x, lp, cfg, moe=None, experts=None, named=False):
     """A layer's second half, x + FFN(norm(x)) or the experts in its place
-    (`moe`; None: what the whole model has), the norm AFTER the sublayer
+    (`moe`; None: what the whole model has; the shared experts beside them
+    where the model has those, `cfg.d_ff_shared`), the norm AFTER the sublayer
     where the model says (`cfg.post_norm`: x + norm(FFN(x))) -> (x, aux
     loss). `experts`: what runs the experts over the normed rows, h -> (y,
     what it hands back in the aux loss's place) (None: `_moe_ffn`): the
@@ -675,6 +692,8 @@ def _ffn_half(x, lp, cfg, moe=None, experts=None, named=False):
         h = x if cfg.post_norm else _norm(x, lp["ln2"], lp.get("ln2_b"), cfg)
         if moe:
             y, aux = experts(h) if experts else _moe_ffn(h, lp, cfg)
+            if cfg.d_ff_shared:  # beside the routed sum, whatever its form
+                y = y + _shared_experts(h, lp, cfg)
         else:
             y, aux = _dense_ffn(h, lp, cfg, named), jnp.zeros((), jnp.float32)
         if cfg.post_norm:

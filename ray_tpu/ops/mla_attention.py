@@ -12,9 +12,12 @@ business), so its score against a cached token is one product with that
 token's row, and the weighted sum of the rows' leading `value_lanes` lanes
 is carried out again by W_v afterwards. Nothing is up-projected per cached
 token and a row is read once, for scores and values both. Every head reads
-the same row, so a sequence's H query rows are one block of one product
-(109 FLOPs a byte at 64 heads of 576 + 512: near a v5e's ridge of 240, where
-plain GQA decode sits at 1 to 8).
+the same row, so a sequence's H query rows are one block of one product:
+2 H (576 + 512) FLOPs for a row's 1280 bytes. Two shapes run it: 64 heads
+(109 FLOPs a byte: near a v5e's ridge of 240) and 32 heads (54 a byte:
+further under it, so more plainly bound by the rows' bytes), where plain
+GQA decode sits at 1 to 8. A change that helps one shape is measured on the
+other.
 
 The kernels are `_flash_page_loop` (blocks of page DMAs two deep, the
 online softmax, float32 throughout) under two masks:

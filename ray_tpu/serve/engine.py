@@ -76,6 +76,18 @@ _m_tokens = Counter("serve_tokens_generated", "Tokens emitted by the engine.")
 _m_prefix_hit_tokens = Counter(
     "serve_prefix_cache_hit_tokens",
     "Prompt tokens served from the prefix cache instead of prefilled.")
+# a prefix that grows turn by turn is let go between turns: its pages wait
+# in the LRU (`stats()["reusable_pages"]`), and a pool that fills takes them
+# from there (evicted); evicted over registered is the share of the
+# histories' pages that did not survive until they were asked for again
+_m_prefix_registered = Counter(
+    "serve_prefix_cache_registered_pages",
+    "Full prompt pages that prefilled requests entered into the prefix "
+    "cache (pages another request had entered already are not counted).")
+_m_prefix_evicted = Counter(
+    "serve_prefix_cache_evicted_pages",
+    "Cached pages no sequence referenced that the allocator took from the "
+    "prefix cache's LRU because the pool had no free page.")
 # a chunk is padded to prefill_chunk rows: padding's share of the chunk
 # programs' rows is the first over the second
 _m_chunk_padding_tokens = Counter(
@@ -216,6 +228,12 @@ _m_moe_rows_routed = Counter(
     "serve_moe_rows_routed",
     "Rows the live tokens of the dispatched programs were routed to, over "
     "every expert layer: tokens x experts a token.")
+_m_moe_shared_rows = Counter(
+    "serve_moe_shared_rows",
+    "Rows the shared experts of the dispatched programs computed, over "
+    "every expert layer: every row of a program passes through them once, "
+    "whatever the routed experts beside them visit (static in a program's "
+    "shape; a model without shared experts counts none).")
 _m_moe_choices = Counter(
     "serve_moe_choices",
     "Where a layer that holds a share of the experts sent the live tokens' "
@@ -733,6 +751,7 @@ class PrefixCache:
         n_pages = min(len(prompt) // self.ps, len(pages))
         if hashes is None:
             hashes = self.page_hashes(prompt, n_pages)
+        entered = 0
         for h, pid in zip(hashes[:n_pages], pages[:n_pages]):
             if pid in self.by_page:
                 continue  # already cached (this request's shared prefix)
@@ -741,6 +760,9 @@ class PrefixCache:
             self.by_hash[h] = pid
             self.by_page[pid] = h
             self.refs[pid] = self.refs.get(pid, 0) + 1
+            entered += 1
+        if entered:
+            _m_prefix_registered.inc(entered)
 
     def release_and_filter(self, pages: List[int]) -> List[int]:
         """Drop one ref per cached page in `pages`; -> the pages the
@@ -764,6 +786,7 @@ class PrefixCache:
             pid, _ = self.lru.popitem(last=False)
             del self.by_hash[self.by_page.pop(pid)]
             out.append(pid)
+        _m_prefix_evicted.inc(len(out))
         return out
 
     def stats(self) -> Dict[str, int]:
@@ -3089,10 +3112,13 @@ class InferenceEngine:
         of the kernel, of which the host counts the bound (never less).
         `choices` (zero, held): what the device counted for a layer that
         holds a share of the experts; the rows routed to THIS layer's
-        products are then the held ones."""
+        products are then the held ones. Every counter here but
+        `serve_moe_shared_rows` speaks of the ROUTED experts alone."""
         layers = self.cfg.second_halves.count("moe")
         if not layers:
             return
+        if self.cfg.d_ff_shared:
+            _m_moe_shared_rows.inc(times * layers * rows * row_tokens)
         if touched is None:
             _m_moe_rows_computed.inc(
                 times * layers * moe_rows_computed(

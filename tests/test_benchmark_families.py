@@ -80,7 +80,8 @@ def test_the_eight_are_entries_of_the_five_serve_cells_and_no_train_cell(
 
 LATER_CELLS = ("longcat-flash-omni.serve-docs",  # PR 39
                "smallthinker-21b-a3b.serve-mixedlen",  # PR 41
-               "granite-4.0-h-micro.serve-chat-burst")  # PR 45
+               "granite-4.0-h-micro.serve-chat-burst",  # PR 45
+               "kanana-2-30b-a3b.serve-agent")  # PR 48
 
 
 # metrics that later PRs appended for cells that were there already

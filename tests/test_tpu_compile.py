@@ -259,6 +259,26 @@ CASES = {
         32, 2048, 1792, rows=512),
     "moe_groups_16_experts_6144x2048_512_rows": _expert_groups(
         16, 6144, 2048, rows=512),
+    # the widest router: 128 experts of 768 (and 130, were the two shared
+    # experts two more entries that every row visits): a visit list of up to
+    # 128 experts a step, a chunk of 256 and the wide chunk of 512
+    "moe_step_128_experts_2048x768": _expert_step(128, 2048, 768),
+    "moe_step_130_experts_2048x768": _expert_step(130, 2048, 768),
+    "moe_groups_128_experts_2048x768": _expert_groups(128, 2048, 768),
+    "moe_groups_128_experts_2048x768_512_rows": _expert_groups(
+        128, 2048, 768, rows=512),
+    "moe_groups_130_experts_2048x768": _expert_groups(130, 2048, 768),
+    # the latent kernels' second shape: 32 query rows a sequence over the
+    # same 640-lane row, a table of the agent cell's longest history
+    "mla_decode_b64_32_heads": (
+        lambda q, pool, pt, n: latent_attention_decode(
+            q, pool, pt, n, 3, MLA_V, 192 ** -0.5),
+        [((64, 32, MLA_W), BF16), _MLA_POOL, ((64, 768), I32),
+         ((64,), I32)], 1),
+    "mla_chunk_c256_past8192_32_heads": (
+        lambda q, pool, pt: latent_attention_chunk(
+            q, pool, pt, 8192, 8448, 3, MLA_V, 192 ** -0.5),
+        [((256, 32, MLA_W), BF16), _MLA_POOL, ((768,), I32)], 1),
     # the engine's chunk as ONE block of the dual form, and its buckets
     "ssd_chunk_t256": (ssd_chunk, _ssd_operands(1, 256)
                        + [((1, SN, SH * SP), F32)], 1),
@@ -538,24 +558,17 @@ def test_the_train_cells_step_fits_with_the_gate_kept(
         r"= bf16\[8192,14336\]\S* convolution\(", text)) == 4
 
 
-@pytest.mark.parametrize(
-    "program", ["decode_span_8", "chunk_prefill_256", "chunk_prefill_512"])
-def test_the_latent_cells_programs_hold_one_pool_at_published_widths(
-        program, topo, no_persistent_cache):
-    """`longcat-flash-omni.serve-docs` as the benchmark sizes it: the double
-    layers' program compiles for the chip with ONE pool array (the donated
-    latents come back in place, there is no pool of values), two latent
-    kernels in the scanned layer's body, no XLA attention, and the memory
-    the cell's `pool_filled` quotes: 9.63 GiB of weights + 3.75 GiB of
-    latents as arguments, under 0.25 GiB of temporaries. The wide chunk
-    (the engine's second chunk program: the model has routed experts) is
-    the same two kernels over a grid twice as long."""
+def _latent_cell_program(name, program, topo):
+    """A latent cell's `program` (`decode_span_8`, `chunk_prefill_<rows>`)
+    as the benchmark sizes it, compiled for one described chip through the
+    engine's own builders and its own pool (ONE array: there is no pool of
+    values). -> (cell, compiled, pool, the program's rows)."""
     from benchmark import common
     from ray_tpu.models import stack
     from ray_tpu.serve.engine import EngineConfig, InferenceEngine
 
     one_chip = SingleDeviceSharding(topo.devices[0])
-    cell = common.load_cell("longcat-flash-omni.serve-docs")
+    cell = common.load_cell(name)
     spec = cell["config"]
     family = common.family(spec)
     cfg = family.model_config(spec)
@@ -573,31 +586,99 @@ def test_the_latent_cells_programs_hold_one_pool_at_published_widths(
     assert pool.shape == (8, 1, 24577, 16, 640)
     B, pps = ecfg.max_batch_size, ecfg.pages_per_seq
     assert eng._wide_chunk() == 2 * ecfg.prefill_chunk == 512
-    C = int(program.rpartition("_")[2])
     f32 = jnp.float32
     if program == "decode_span_8":
+        rows = B
         lowered = eng._build_decode()(8).lower(
             params, pool, None, s((B,), I32), s((B,), I32), s((B, pps), I32),
             s((B,), f32), s((B,), f32), s((B,), I32), s((2,), jnp.uint32),
             {}, (s((B,), I32), s((B,), I32), s((B,), jnp.bool_)))
-        kernel = "mla_decode"
     else:
+        rows = int(program.rpartition("_")[2])
         rs = jax.tree.map(lambda a: s(a.shape, a.dtype), jax.eval_shape(
             lambda: stack.new_request_state(cfg, 1, jnp.bfloat16)))
-        lowered = eng._build_chunk_prefill()(C).lower(
-            params, pool, None, s((C,), I32), s((), I32), s((pps,), I32),
+        lowered = eng._build_chunk_prefill()(rows).lower(
+            params, pool, None, s((rows,), I32), s((), I32), s((pps,), I32),
             s((), I32), rs)
-        kernel = "mla_chunk"
     compiled = lowered.compile()
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= pool.size * pool.dtype.itemsize
+    return cell, compiled, pool, rows
+
+
+@pytest.mark.parametrize(
+    "program", ["decode_span_8", "chunk_prefill_256", "chunk_prefill_512"])
+def test_the_latent_cells_programs_hold_one_pool_at_published_widths(
+        program, topo, no_persistent_cache):
+    """`longcat-flash-omni.serve-docs` as the benchmark sizes it: the double
+    layers' program compiles for the chip with ONE pool array (the donated
+    latents come back in place, there is no pool of values), two latent
+    kernels in the scanned layer's body, no XLA attention, and the memory
+    the cell's `pool_filled` quotes: 9.63 GiB of weights + 3.75 GiB of
+    latents as arguments, under 0.25 GiB of temporaries. The wide chunk
+    (the engine's second chunk program: the model has routed experts) is
+    the same two kernels over a grid twice as long."""
+    _, compiled, _, _ = _latent_cell_program(
+        "longcat-flash-omni.serve-docs", program, topo)
+    kernel = "mla_decode" if program == "decode_span_8" else "mla_chunk"
     memory = compiled.memory_analysis()
     gib = 2 ** 30
-    pool_bytes = pool.size * pool.dtype.itemsize
-    assert memory.alias_size_in_bytes >= pool_bytes
     assert 13.3 < memory.argument_size_in_bytes / gib < 13.5
     assert memory.temp_size_in_bytes < 0.25 * gib
     text = compiled.as_text()
     assert len(re.findall(r"%%%s(\.\d+)? = " % kernel, text)) == 2
     assert not re.search(r"= bf16\[8,(1,)?24577,16,640\]\S* copy\(", text)
+
+
+@pytest.mark.parametrize(
+    "program", ["decode_span_8", "chunk_prefill_256", "chunk_prefill_512"])
+def test_the_agent_cells_programs_run_their_kernels_at_published_widths(
+        program, topo, no_persistent_cache):
+    """`kanana-2-30b-a3b.serve-agent` as the benchmark sizes it: latent
+    attention as one mixer a layer over ONE pool (donated, back in place, no
+    pool of values); the leading dense layer once (a latent kernel alone)
+    and then ONE scan of seven expert layers whose body holds one latent
+    kernel and one expert kernel, the latter taking the segment's three
+    expert stacks whole. No XLA attention, no XLA expert product, no copy of
+    the pool or of a layer's experts; the shared experts are a dense product
+    of width 1536 beside them, which the `shared_experts` names group finds
+    and nothing else. Memory is the configuration file's `memory_analysis`:
+    9.44 GiB of weights + 3.75 GiB of latents."""
+    from benchmark import trace_reduce
+
+    cell, compiled, _, rows = _latent_cell_program(
+        "kanana-2-30b-a3b.serve-agent", program, topo)
+    step = program == "decode_span_8"
+    latent, experts = (("mla_decode", "moe_step") if step
+                       else ("mla_chunk", "moe_groups"))
+    memory = compiled.memory_analysis()
+    gib = 2 ** 30
+    print(f"{program}: arguments {memory.argument_size_in_bytes / gib:.3f} "
+          f"aliased {memory.alias_size_in_bytes / gib:.3f} "
+          f"temporaries {memory.temp_size_in_bytes / gib:.3f} GiB")
+    wanted = cell["config"]["memory_analysis"][cell["name"]][
+        program.replace("_", " ") + (" x batch 64" if step else "")]
+    assert abs(memory.argument_size_in_bytes / gib - wanted["arguments"]) < 0.01
+    assert abs(memory.temp_size_in_bytes / gib - wanted["temporaries"]) < 0.02
+    text = compiled.as_text()
+    # the dense layer's attention and the scanned body's
+    assert len(re.findall(r"%%%s(\.\d+)? = " % latent, text)) == 2
+    calls = re.findall(r"%%%s(?:\.\d+)? = [^\n]*" % experts, text)
+    assert len(calls) == 1
+    # the kernel reads the segment's stacks where they lie
+    assert calls[0].count("bf16[7,128,2048,768]") == 2
+    assert calls[0].count("bf16[7,128,768,2048]") == 1
+    assert not re.search(r"= bf16\[8,(1,)?24577,16,640\]\S* copy\(", text)
+    assert not re.search(r"= bf16\[(7,)?128,2048,768\]\S* (copy|fusion)\(",
+                         text)
+    # the shared experts: a product of width 1536 over the program's rows
+    assert re.search(r"bf16\[%d,1536\]" % rows, text)
+    group = [re.compile(e["match"]) for e in
+             trace_reduce.load_names()["groups"]["shared_experts"]]
+    named = [line.strip() for line in text.splitlines()
+             if any(g.search(line.strip()) for g in group)]
+    assert named and all("1536" in line for line in named)
+    print("\n".join(line[:200] for line in named))
 
 
 @pytest.mark.parametrize(
