@@ -387,12 +387,12 @@ class EngineConfig:
     # straight in its pages (no separate scatter). Also lifts the bucket
     # cap: prompts up to max_seq_len serve even past the largest compiled
     # bucket. prefill_chunk is the prompt length above which a prompt is
-    # chunked, what a prefix-cache hit is aligned to, and the rows of THE
-    # chunk program; a model whose chunk costs its experts' weights
-    # whatever its rows has a second program of twice the rows for a
-    # prompt with more than prefill_chunk tokens left (`_wide_chunk`: a
-    # rule on the model's shapes, no option). Must be a multiple of
-    # page_size.
+    # chunked, the fewest cached tokens that make a prefix-cache hit (the
+    # hit itself is to the page), and the rows of THE chunk program; a
+    # model whose chunk costs its experts' weights whatever its rows has a
+    # second program of twice the rows for a prompt with more than
+    # prefill_chunk tokens left (`_wide_chunk`: a rule on the model's
+    # shapes, no option). Must be a multiple of page_size.
     chunked_prefill: bool = True
     prefill_chunk: int = 256
     eos_token_id: Optional[int] = None
@@ -444,7 +444,7 @@ class EngineConfig:
             raise ValueError(
                 "prefill_chunk must be a multiple of page_size when "
                 "chunked prefill or prefix caching is enabled (chunk KV "
-                "lands directly in pages and cache hits are chunk-aligned): "
+                "lands directly in pages and a cache hit resumes at a page): "
                 f"prefill_chunk={self.prefill_chunk} "
                 f"page_size={self.page_size}")
         if self.speculation is not None:
@@ -695,7 +695,10 @@ class PrefixCache:
     Safety: only FULL prompt pages are ever registered, and lookups are
     capped below the last prompt token, so every sequence prefills >= 1
     token (producing its first-token logits) and decode never writes into
-    a shared page (first write position >= cached_len + 1)."""
+    a shared page (first write position >= cached_len + 1). The one
+    program that does is a prompt's last chunk that starts earlier to end
+    with the page table (`_advance_chunk`): it writes again, from the same
+    tokens at the same positions, rows that a hit's pages already hold."""
 
     def __init__(self, page_size: int):
         self.ps = page_size
@@ -714,16 +717,18 @@ class PrefixCache:
             out.append(h)
         return out
 
-    def lookup_acquire(self, prompt, align_tokens: int,
+    def lookup_acquire(self, prompt, min_tokens: int,
                        hashes: Optional[List[bytes]] = None) -> List[int]:
-        """Longest cached page run for `prompt`, refs bumped. Capped below
-        the last token (>= 1 token must prefill) and aligned down to
-        `align_tokens` (the chunk size the tail prefill resumes at).
+        """Longest cached page run for `prompt`, to the page, refs bumped.
+        Capped below the last token (>= 1 token must prefill); a run of
+        fewer than `min_tokens` tokens (the chunk program's rows) is no
+        hit: the tail prefill resumes at the run's end in a chunk, and
+        such a prompt keeps the bucket, or the chunks from 0, that it has
+        without a cache.
         `hashes`: precomputed page_hashes (callers hash OUTSIDE the
         engine's _alloc_lock; dict lookups are all that runs inside)."""
         T = len(prompt)
         max_pages = (T - 1) // self.ps  # never the page holding token T-1
-        align_pages = max(1, align_tokens // self.ps)
         if hashes is None:
             hashes = self.page_hashes(prompt, max_pages)
         hashes = hashes[:max_pages]
@@ -732,7 +737,8 @@ class PrefixCache:
             if self.by_hash.get(h) is None:
                 break
             n += 1
-        n = (n // align_pages) * align_pages
+        if n * self.ps < min_tokens:
+            n = 0
         pages = []
         for h in hashes[:n]:
             pid = self.by_hash[h]
@@ -1077,14 +1083,16 @@ class InferenceEngine:
         time whatever its rows, so a prompt with more than `prefill_chunk`
         tokens left reads them once for twice the rows; a dense chunk is
         at its products' time at `prefill_chunk` rows already and gains
-        nothing. The rule is the model's shapes and the mesh. Under
-        speculation the chunks stay as they are (no test or cell runs a
-        round's programs beside a wide chunk)."""
+        nothing. The rule is the model's shapes and the mesh, and a page
+        table that holds the rows (a chunk may not run past it:
+        `_advance_chunk`). Under speculation the chunks stay as they are
+        (no test or cell runs a round's programs beside a wide chunk)."""
         ecfg = self.ecfg
         C = ecfg.prefill_chunk
         scfg = ecfg.speculation
         if (not ecfg.chunked_prefill or ecfg.busy_span < 2
-                or (scfg is not None and scfg.enabled)):
+                or (scfg is not None and scfg.enabled)
+                or 2 * C > ecfg.pages_per_seq * ecfg.page_size):
             return 0
         return 2 * C if all(moe_seq_groups(self.cfg, 1, rows, self.mesh)
                             for rows in (C, 2 * C)) else 0
@@ -2274,7 +2282,7 @@ class InferenceEngine:
 
     def _admit_for_prefill(self, req: Request):
         """-> (pages, T, bucket, cached_len); bucket None = chunked path,
-        cached_len = tokens served by the prefix cache (chunk-aligned).
+        cached_len = tokens served by the prefix cache (whole pages).
         Or None (deferred to _waiting / errored)."""
         T = len(req.prompt)
         total = T + (0 if req.prefill_only else req.max_tokens)
@@ -2707,6 +2715,16 @@ class InferenceEngine:
         if (self._wide and st.true_len - start > C and not streaming
                 and (room is None or room >= self._wide)):
             C = self._wide  # this chunk's rows
+        # A chunk writes all its rows, padding too, to `table[at // ps]`,
+        # and past the table's end the device reads its LAST entry: a real
+        # page of a sequence that holds them all, where a padding row would
+        # land on a prompt's own. Such a chunk (a prompt's last) starts as
+        # many pages earlier as it takes to end with the table, and computes
+        # again rows the pages already hold. (Not where layers keep state
+        # beside the pages, which is the state at `st.done`.)
+        end = len(st.table) * self.ecfg.page_size
+        if start + C > end and not self.cfg.has_state:
+            start = end - C  # >= 0: the table holds either program's rows
         toks = req.prompt[start:start + C]
         padded = np.zeros((C,), np.int32)
         padded[: len(toks)] = toks
@@ -2829,7 +2847,8 @@ class InferenceEngine:
         ahead of it in the queue) take the place of its own, which are
         freed, and it resumes past them. Without it every ask of a context
         that arrives while the first is still being prefilled runs the
-        whole prefill again. One dict lookup where nothing is new."""
+        whole prefill again. It resumes at the page the run ends at, as an
+        admitted hit does. One dict lookup where nothing is new."""
         req = st.request
         C, ps = self.ecfg.prefill_chunk, self.ecfg.page_size
         hashes = getattr(req, "_page_hashes", None)
@@ -2852,7 +2871,7 @@ class InferenceEngine:
         # there is in the shared pages too, by the chain hash)
         self._free_pages_and_revive(own)
         _m_prefix_hit_tokens.inc(n * ps - st.done)
-        st.done = n * ps  # whole chunks of `prefill_chunk` (lookup_acquire)
+        st.done = n * ps
 
     def step(self) -> bool:
         """One engine iteration: advance the chunk queue (`_advance_chunks`:

@@ -221,8 +221,9 @@ def test_served_logprobs_are_the_references(model, engine, n_prompt):
 
 def test_a_later_turn_is_served_from_the_prefix_cache(model, engine):
     """A session's second turn: the first turn's prompt, a stand-in answer
-    and new tokens. The first turn's whole pages are hits, and the logits
-    are the cache-less reference's."""
+    and new tokens. The first turn's whole pages are hits, all nine of them
+    (36 tokens: two chunks and a page), and the logits are the cache-less
+    reference's."""
     _, _, cfg, params = model
     first = prompt_of(36, 7)
     engine.generate(first, max_tokens=5)
@@ -230,7 +231,7 @@ def test_a_later_turn_is_served_from_the_prefix_cache(model, engine):
     before = common.counters()
     again = engine.generate(second, max_tokens=5)
     assert common.counter_delta(before, common.counters(),
-                                "serve_prefix_cache_hit_tokens") == 32
+                                "serve_prefix_cache_hit_tokens") == 36
     want = reference_logprobs(model, second, again["token_ids"])
     assert np.abs(np.asarray(again["logprobs"]) - want).max() < LOGPROB_TOL
     cold = engine_for(cfg, params, prefix_caching=False)
@@ -471,6 +472,50 @@ def test_the_schedules_longest_history_fits_the_engine():
     assert cell["engine"]["max_seq_len"] - longest < 512
     sessions = round(cell["rate_rps"] * 40.0)  # the rate is of sessions
     assert sessions == 64 and len(requests) == 221  # most are later turns
+
+
+def test_the_schedules_hit_tokens_through_the_prefix_cache_alone():
+    """The cell's schedule (the same sizes and instants on every seed)
+    through `PrefixCache` and nothing else: each request in the order it is
+    due looks its prompt up, then registers its whole pages, as the engine
+    does. A later turn finds its session's previous prompt to the page:
+    `prefix_hit_token_share` 93.17 (92.10 while a hit was cut down to a
+    multiple of `prefill_chunk`, which the ledger's PR 48 line read)."""
+    from benchmark import traffic
+    from ray_tpu.serve.engine import PrefixCache
+
+    cell = common.load_cell(CELL)
+    ecfg = EngineConfig(**cell["engine"])
+    requests = traffic.requests(cell["traffic"], 49, cell["rate_rps"], 40.0,
+                                cell["config"]["vocab_size"])
+    cache, held = PrefixCache(ecfg.page_size), 0
+    hit, tails = 0, []
+    for r in requests:
+        prompt = r["prompt_ids"]
+        pages = cache.lookup_acquire(prompt, ecfg.prefill_chunk)
+        hit += len(pages) * ecfg.page_size
+        tails.append(len(prompt) - len(pages) * ecfg.page_size)
+        own = len(prompt) // ecfg.page_size - len(pages)
+        pages += range(held + 1, held + 1 + own)
+        held += own
+        cache.register(prompt, pages)
+        assert cache.release_and_filter(pages) == []
+    sent = sum(len(r["prompt_ids"]) for r in requests)
+    assert (len(requests), sent, hit) == (221, 1566522, 1459488)
+    assert round(100 * hit / sent, 2) == 93.17
+    # four cold system prompts; every other request resumes at a page, and
+    # its tail is the turn's new tokens and the page its history ended in
+    assert sum(t >= 6144 for t in tails) == 4 and sum(tails) == sent - hit
+    assert all(t % ecfg.page_size == len(r["prompt_ids"]) % ecfg.page_size
+               for t, r in zip(tails, requests))
+    # the chunk programs the tails take with nobody else in the queue: the
+    # wide one while more than `prefill_chunk` tokens are left (203 + 119
+    # calls; 250 + 107 under the old rule)
+    C = ecfg.prefill_chunk
+    wide = sum(max(0, -(-(t - C) // (2 * C))) for t in tails)
+    narrow = sum(0 < (t - 1) % (2 * C) + 1 <= C for t in tails)
+    assert (wide, narrow) == (203, 119)
+    assert sum(t <= C for t in tails) == 83  # one narrow chunk and no more
 
 
 def test_the_new_readers_read_their_counters_and_nothing_without_them():

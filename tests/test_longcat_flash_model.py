@@ -166,8 +166,9 @@ def test_a_prefix_hit_on_latent_pages_gives_the_same_logits(model, engine):
     first = engine.generate(shared + [5, 6, 7], max_tokens=5)
     before = common.counters()
     again = engine.generate(shared + [9, 10], max_tokens=5)
+    # the shared 36 tokens are nine whole pages: two chunks and a page
     assert common.counter_delta(before, common.counters(),
-                                "serve_prefix_cache_hit_tokens") == 32
+                                "serve_prefix_cache_hit_tokens") == 36
     cold = engine_for(cfg, params, prefix_caching=False)
     try:
         assert cold.prefix is None
@@ -183,20 +184,21 @@ def test_a_prefix_hit_on_latent_pages_gives_the_same_logits(model, engine):
 
 
 def test_a_short_question_over_a_cached_prefix_counts_its_padding(model, engine):
-    """A re-ask runs ONE chunk, at the cached prefix's end, that holds the
-    question and is padded to `prefill_chunk`: the attention is told where
-    the question ends, the first token and the logits are the cache-less
-    reference's, and the padding is counted beside the chunk's rows."""
+    """A re-ask runs ONE chunk, at the cached prefix's end (its ninth page,
+    no multiple of a chunk), that holds the question and is padded to
+    `prefill_chunk`: the attention is told where the question ends, the
+    first token and the logits are the cache-less reference's, and the
+    padding is counted beside the chunk's rows."""
     C = engine.ecfg.prefill_chunk
     shared = prompt_of(36, 40)
     engine.generate(shared + [5, 6, 7], max_tokens=2)
-    question = shared[32:] + [9, 10, 11]
-    prompt = shared[:32] + question
+    question = [9, 10, 11]
+    prompt = shared + question
     before = common.counters()
     again = engine.generate(prompt, max_tokens=5)
     delta = lambda name: common.counter_delta(  # noqa: E731
         before, common.counters(), name)
-    assert delta("serve_prefix_cache_hit_tokens") == 32
+    assert delta("serve_prefix_cache_hit_tokens") == 36
     assert delta("serve_chunk_rows") == C
     assert delta("serve_chunk_padding_tokens") == C - len(question)
     want = reference_logprobs(model, prompt, again["token_ids"])

@@ -1,6 +1,7 @@
 """Serve tests: deployments/replicas/routing, dynamic batching, HTTP proxy,
 autoscaling targets, and the continuous-batching paged-KV engine."""
 
+import contextlib
 import json
 import threading
 import time
@@ -951,19 +952,28 @@ class TestPrefixCache:
         )
         return InferenceEngine(params, cfg, ecfg), params, cfg
 
-    def test_unit_lookup_align_refs_evict(self):
+    def test_unit_lookup_pages_refs_evict(self):
         from ray_tpu.serve.engine import PrefixCache
 
         pc = PrefixCache(page_size=4)
         prompt = list(range(1, 17))  # 16 tokens = 4 full pages
         pc.register(prompt, [10, 11, 12, 13])
-        # same prefix, longer prompt: full-run hit capped + aligned to 8
-        # tokens (2 pages)
-        got = pc.lookup_acquire(prompt + [99, 98], align_tokens=8)
+        # same prefix, longer prompt: the whole run, to the page
+        got = pc.lookup_acquire(prompt + [99, 98], min_tokens=8)
         assert got == [10, 11, 12, 13]
-        # diverging second page: only page 0 matches -> aligned DOWN to 0
+        # diverging fourth page: three pages match, no multiple of the two
+        # that `min_tokens` makes, and every one counts
+        off = prompt[:12] + [77] * 6
+        assert pc.lookup_acquire(off, min_tokens=8) == [10, 11, 12]
+        assert pc.release_and_filter([10, 11, 12]) == []
+        # capped below the last token: the page that holds it must prefill
+        assert pc.lookup_acquire(prompt, min_tokens=8) == [10, 11, 12]
+        assert pc.release_and_filter([10, 11, 12]) == []
+        # diverging second page: a run of one page is under `min_tokens`,
+        # no hit, and no ref is taken
         div = prompt[:4] + [77] * 12
-        assert pc.lookup_acquire(div, align_tokens=8) == []
+        assert pc.lookup_acquire(div, min_tokens=8) == []
+        assert pc.refs[10] == 2
         # refs pin pages against eviction; release moves them to LRU
         assert pc.evict(4) == []  # all referenced (register ref + acquire)
         rest = pc.release_and_filter([10, 11, 12, 13])  # acquire refs
@@ -971,21 +981,22 @@ class TestPrefixCache:
         rest = pc.release_and_filter([10, 11, 12, 13, 50])  # register refs
         assert rest == [50]  # 50 was never cached: caller still owns it
         assert pc.evict(2) == [10, 11]  # LRU order
-        assert pc.lookup_acquire(prompt, align_tokens=4) == []  # chain broken
+        assert pc.lookup_acquire(prompt, min_tokens=4) == []  # chain broken
 
     def test_repeat_prompt_hits_cache_and_output_identical(self):
         from ray_tpu.serve.engine import _m_prefix_hit_tokens
 
         engine, _, _ = self._engine()
-        prompt = [(i * 7) % 60 + 1 for i in range(40)]  # > chunk, 5 pages
+        prompt = [(i * 7) % 60 + 1 for i in range(44)]  # > chunk, 5 pages
         first = engine.generate(prompt, max_tokens=8, temperature=0.0)
         before = _m_prefix_hit_tokens.get()
         second = engine.generate(prompt, max_tokens=8, temperature=0.0)
         hits = _m_prefix_hit_tokens.get() - before
         engine.stop()
         assert second["token_ids"] == first["token_ids"]
-        # 40 tokens: 4 full pages = 32 tokens, chunk-aligned (16) -> 32
-        assert hits == 32, hits
+        # 44 tokens: all 5 full pages = 40 tokens, two and a half chunks
+        # of 16 (a hit aligned to the chunk was 32)
+        assert hits == 40, hits
 
     def test_shared_prefix_outputs_match_uncached_engine(self):
         sys_prefix = [(i * 3) % 50 + 1 for i in range(24)]
@@ -1013,6 +1024,222 @@ class TestPrefixCache:
         # every page is either allocator-free or reclaimable cache
         assert stats["free_pages"] == 64 - 1, stats
         assert stats["cached_pages"] > 0
+
+
+# a dense model, one whose chunks run routed experts (so its engine has the
+# wide chunk program too; as registered it drops rows: capacity factor 1.25)
+# and one that caches latent rows
+PAGE_HIT_MODELS = {
+    "tiny-llama": {},
+    "tiny-moe": dict(capacity_factor=2.0),
+    "tiny-kanana": {},
+}
+
+
+@pytest.fixture(scope="module")
+def page_hit_models():
+    import dataclasses
+
+    from ray_tpu.models import stack
+
+    made = {}
+
+    def model(name):
+        if name not in made:
+            cfg = dataclasses.replace(get_config(name), dtype="float32",
+                                      **PAGE_HIT_MODELS[name])
+            init = stack.init_params if cfg.is_stack else init_params
+            made[name] = cfg, init(cfg, jax.random.PRNGKey(49))
+        return made[name]
+
+    return model
+
+
+@pytest.mark.parametrize("name", sorted(PAGE_HIT_MODELS))
+class TestPageHits:
+    """A prefix hit is the longest run of cached pages, to the page, from
+    `prefill_chunk` tokens up, and the tail prefill resumes at that page
+    (pages of 4, chunks of 16, and of 32 where the model has the wide
+    program)."""
+
+    @staticmethod
+    @contextlib.contextmanager
+    def _engines(model, max_seq_len=128):
+        """An engine with the prefix cache and one without, stopped at the
+        end."""
+        from ray_tpu.serve import EngineConfig, InferenceEngine
+
+        cfg, params = model
+        cached, plain = (InferenceEngine(params, cfg, EngineConfig(
+            max_batch_size=2, page_size=4, max_pages=96,
+            max_seq_len=max_seq_len, prefill_buckets=(8, 16),
+            prefill_chunk=16, decode_span=4, busy_span=2,
+            cache_dtype="float32", prefix_caching=on)) for on in (True, False))
+        try:
+            yield cached, plain
+        finally:
+            cached.stop(), plain.stop()
+
+    @staticmethod
+    def _prompt(n, seed):
+        return np.random.default_rng(seed).integers(3, 200, n).tolist()
+
+    @staticmethod
+    def _hits():
+        from ray_tpu.serve.engine import _m_prefix_hit_tokens
+
+        return _m_prefix_hit_tokens.get()
+
+    @staticmethod
+    def _starts(engine):
+        """Where each chunk the engine runs from now on found its prompt
+        (`st.done` once the late hits are taken), in order."""
+        seen, take = [], engine._take_late_hits
+
+        def spy(st):
+            take(st)
+            seen.append(st.done)
+
+        engine._take_late_hits = spy
+        return seen
+
+    @staticmethod
+    def _by_hand(engine, prompts, max_tokens, prefilled=None):
+        """`prompts` admitted together on an engine that never starts its
+        loop, and stepped to their ends; `prefilled()` runs between the
+        admission and the first iteration."""
+        from ray_tpu.serve.engine import Request
+
+        engine._ensure_loop = lambda: None
+        reqs = [Request(request_id=f"hand{len(p)}-{i}", prompt=p,
+                        max_tokens=max_tokens) for i, p in enumerate(prompts)]
+        for r in reqs:
+            engine.add_request(r)
+        engine._prefill_batch([engine.pending.get() for _ in reqs])
+        if prefilled is not None:
+            prefilled()
+        for _ in range(300):
+            engine._iterate()
+            if all(r.done.is_set() for r in reqs):
+                break
+        for _ in range(4):  # the last spans' pages go at their readback
+            engine._iterate()
+        assert all(r.done.is_set() and r.error is None for r in reqs)
+        return [{"token_ids": r.output, "logprobs": r.output_logprobs}
+                for r in reqs]
+
+    @staticmethod
+    def _same(got, want):
+        assert got["token_ids"] == want["token_ids"]
+        assert np.abs(np.asarray(got["logprobs"])
+                      - np.asarray(want["logprobs"])).max() < 2e-5
+
+    def test_a_later_turn_resumes_at_the_page_its_history_ends_at(
+            self, name, page_hit_models):
+        """A session's second turn: the first turn's prompt (42 tokens: 10
+        whole pages, two and a half chunks), a stand-in answer and new
+        tokens. All 10 pages are hits, the tail starts at 40, and the answer
+        is the one an engine without a prefix cache gives."""
+        model = page_hit_models(name)
+        first = self._prompt(42, 1)
+        second = first + self._prompt(6, 2) + self._prompt(9, 3)
+        with self._engines(model) as (cached, plain):
+            cached.generate(first, max_tokens=4, temperature=0.0)
+            hits, starts = self._hits(), self._starts(cached)
+            got = cached.generate(second, max_tokens=6, temperature=0.0)
+            assert self._hits() - hits == 10 * 4
+            assert starts[0] == 40  # two and a half chunks
+            self._same(got, plain.generate(second, max_tokens=6,
+                                           temperature=0.0))
+
+    def test_a_cached_run_under_a_chunk_is_no_hit(self, name,
+                                                  page_hit_models):
+        """Three cached pages (12 tokens) under a chunk's 16: a prompt of 15
+        takes the bucket of 16 that it takes without a cache, one of 40 its
+        chunks from 0."""
+        from ray_tpu.serve.engine import _m_chunk_rows
+
+        model = page_hit_models(name)
+        first = self._prompt(14, 4)
+        short = first[:12] + self._prompt(3, 5)
+        long = first[:12] + self._prompt(28, 6)
+        with self._engines(model) as (cached, plain):
+            cached.generate(first, max_tokens=2, temperature=0.0)
+            assert len(cached.prefix.by_page) == 3
+            hits, rows = self._hits(), _m_chunk_rows.get()
+            bucket, starts = cached._bucket_tokens, self._starts(cached)
+            got = cached.generate(short, max_tokens=4, temperature=0.0)
+            assert cached._bucket_tokens - bucket == 16
+            assert _m_chunk_rows.get() == rows and not starts
+            self._same(got, plain.generate(short, max_tokens=4,
+                                           temperature=0.0))
+            got = cached.generate(long, max_tokens=4, temperature=0.0)
+            assert self._hits() == hits and starts[0] == 0
+            assert cached._bucket_tokens - bucket == 16
+            self._same(got, plain.generate(long, max_tokens=4,
+                                           temperature=0.0))
+
+    def test_a_queued_prompt_takes_a_late_hit_at_a_page(self, name,
+                                                         page_hit_models):
+        """Two turns over one cached history of 40 tokens, admitted
+        together, that share 36 tokens more: both resume at 40, the first
+        prefills, and the second at its turn takes the first's pages up to
+        76, where they part: 19 pages, no multiple of a chunk's 4."""
+        model = page_hit_models(name)
+        history = self._prompt(42, 7)
+        new = self._prompt(50, 8)
+        prompts = [history[:40] + new,
+                   history[:40] + new[:36] + self._prompt(6, 9)]
+        with self._engines(model) as (cached, plain):
+            self._by_hand(cached, [history], 2)
+            hits, starts = self._hits(), self._starts(cached)
+            got = self._by_hand(
+                cached, prompts, 4, prefilled=lambda: starts.extend(
+                    st.done for st in cached._chunk_queue))
+            assert starts[:2] == [40, 40]  # at admission
+            assert self._hits() - hits == 40 + 40 + 36
+            assert 76 in starts  # 19 pages: four and three quarter chunks
+            for answer, prompt in zip(got, prompts):
+                self._same(answer, plain.generate(prompt, max_tokens=4,
+                                                  temperature=0.0))
+
+    def test_a_tail_chunk_at_the_tables_end_stays_inside_it(
+            self, name, page_hit_models):
+        """`max_seq_len` 56, no multiple of the chunk: a later turn of 54
+        tokens and an answer of 2 holds all 14 pages of its table, its hit
+        ends at 36, and the chunk that holds its last tokens would run rows
+        past the table, which the device reads as the table's LAST entry:
+        the page of tokens 52 and 53. The chunk starts earlier instead, ends
+        with the table, and the page's rows are bit for bit those that an
+        engine without a prefix cache leaves there."""
+        model = page_hit_models(name)
+        first = self._prompt(38, 10)
+        second = first + self._prompt(16, 11)
+
+        def served(engine):
+            rows = []
+
+            def prefilled():
+                while engine._advance_chunk() is not None:
+                    pass
+                (_, pages, _, true_len), = engine._ready
+                assert (len(pages), true_len) == (14, 54)
+                rows.extend(np.asarray(pool[:, 0, pages[13], :2])
+                            for pool in (engine.k_pages, engine.v_pages)
+                            if pool is not None)
+
+            answer, = self._by_hand(engine, [second], 2, prefilled)
+            return rows, answer["token_ids"]
+
+        with self._engines(model, max_seq_len=56) as (cached, plain):
+            self._by_hand(cached, [first], 2)
+            hits = self._hits()
+            got, tokens = served(cached)
+            assert self._hits() - hits == 36
+            want, plain_tokens = served(plain)
+            assert got and all(np.array_equal(a, b)
+                               for a, b in zip(got, want))
+            assert tokens == plain_tokens
 
 
 class TestCancellation:

@@ -123,9 +123,9 @@ def test_wide_and_narrow_chunks_serve_the_same_answer(
 
 @pytest.mark.parametrize("name", ["tiny-moe", "tiny-longcat-flash"])
 def test_a_prefix_hit_in_front_of_a_wide_chunk(name, models):
-    """The second ask shares 300 tokens with the first: a hit of one whole
-    chunk of `prefill_chunk` tokens, then a wide chunk from token 256 (no
-    multiple of its own rows) and the tail."""
+    """The second ask shares 300 tokens with the first: a hit of its 18
+    whole pages, then a wide chunk from token 288 (no multiple of either
+    program's rows) and the tail."""
     cfg, params = models(name)
     sizes = dict(page_size=16, max_pages=256, max_seq_len=2048,
                  prefill_chunk=256)
@@ -139,7 +139,7 @@ def test_a_prefix_hit_in_front_of_a_wide_chunk(name, models):
         hits = _counter("serve_prefix_cache_hit_tokens")
         wide, narrow = _calls(512), _calls(256)
         got = cached.generate(second, max_tokens=6)
-        assert _counter("serve_prefix_cache_hit_tokens") - hits == 256
+        assert _counter("serve_prefix_cache_hit_tokens") - hits == 288
         assert (_calls(512) - wide, _calls(256) - narrow) == (1, 1)
         _same_answer(got, plain.generate(second, max_tokens=6))
     finally:
