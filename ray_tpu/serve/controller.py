@@ -15,6 +15,8 @@ from typing import Any, Dict, List, Optional
 
 from .. import api
 from ..core.logging import get_logger
+from ..core.metrics import Histogram
+from ..util import tracing
 from .config import AutoscalingConfig, DeploymentConfig
 from .replica import ServeReplica
 
@@ -22,6 +24,17 @@ logger = get_logger("serve.controller")
 
 CONTROLLER_NAME = "SERVE_CONTROLLER"
 _HEALTH_FAIL_THRESHOLD = 3  # consecutive misses before a replica is replaced
+
+_m_replica_ready = Histogram(
+    "serve_replica_ready_seconds",
+    "A replica's spawn by the controller to the end of its __init__ (the "
+    "replica's own reading: `ServeReplica.ready_ns`), by `deployment`: "
+    "what an autoscaler waits for a new replica. The two instants are "
+    "read in two processes, each on its wall-anchored `tracing.now_ns()`: "
+    "across hosts the reading is as good as their clocks' sync (one read "
+    "under 0 is filed as 0). An LLM replica says where the time went in "
+    "serve_replica_start_seconds{phase} and its `replica.start` trace.",
+    buckets=(0.1, 0.5, 1, 2, 5, 10, 20, 30, 60, 120, 300, 600))
 
 
 class _DeploymentState:
@@ -52,7 +65,8 @@ class _DeploymentState:
         # STARTING replicas are replaced only on provable actor death or
         # after startup_timeout_s with no readiness.
         self.started: set = set()
-        self.ready_pending: Dict[Any, Any] = {}  # actor id -> (ref, spawned)
+        # actor id -> (ref of `ready_ns`, spawned, spawned on now_ns())
+        self.ready_pending: Dict[Any, Any] = {}
         self.last_health_check = 0.0
         self.target = config.num_replicas
         self._last_scale_up = 0.0
@@ -205,7 +219,8 @@ class ServeController:
         now = time.monotonic()
         dead: Dict[Any, Any] = {}  # actor id -> handle (deduped)
         by_id = {r._actor_id: r for r in state.replicas}
-        for rid, (ref, spawned) in list(state.ready_pending.items()):
+        for rid, (ref, spawned, spawned_ns) in list(
+                state.ready_pending.items()):
             if rid not in by_id:
                 state.ready_pending.pop(rid, None)
                 continue
@@ -213,9 +228,14 @@ class ServeController:
             if ready:
                 state.ready_pending.pop(rid, None)
                 try:
-                    api.get(ref, timeout=0)
+                    ready_ns = api.get(ref, timeout=0)
                 except Exception:
                     pass  # init raised -> actor-table death handles it
+                else:
+                    # when __init__ ended, not when this loop came by
+                    _m_replica_ready.observe(
+                        max(0, ready_ns - spawned_ns) * 1e-9,
+                        tags={"deployment": state.name})
                 state.started.add(rid)  # STARTING -> RUNNING
             elif now - spawned > cfg.startup_timeout_s:
                 state.ready_pending.pop(rid, None)
@@ -325,12 +345,13 @@ class ServeController:
                     state.config.max_ongoing_requests,
                 )
                 state.replicas.append(replica)
-                # readiness probe: completes when __init__ has finished
-                # (the actor's first task can only run then) — the
-                # STARTING -> RUNNING edge for health accounting
+                # readiness probe: runs when __init__ has finished (the
+                # actor's first task can only run then) and says when that
+                # was: the STARTING -> RUNNING edge for health accounting
                 try:
                     state.ready_pending[replica._actor_id] = (
-                        replica.health_check.remote(), time.monotonic())
+                        replica.ready_ns.remote(), time.monotonic(),
+                        tracing.now_ns())
                 except Exception:
                     pass
             while len(state.replicas) > state.target:

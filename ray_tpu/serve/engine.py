@@ -869,6 +869,12 @@ class InferenceEngine:
         self.ecfg = engine_cfg
         self.mesh = mesh
         self._tp = 1
+        # the `replica.start` trace this engine was built under
+        # (`serve/llm.py start_engine` sets both): its id for `get_trace`,
+        # and the finished tree, which outlives the span ring; None, []:
+        # built bare
+        self.startup_trace_id: Optional[str] = None
+        self.startup_trace: List[Dict[str, Any]] = []
         B = engine_cfg.max_batch_size
         # THE pool, one layout for every model (ops/paged_attention.py:
         # a token's kv heads in one row): the layers that cache keys and
@@ -892,7 +898,7 @@ class InferenceEngine:
         # what the engine holds beside THE pool, whatever the slots hold:
         # per-slot state (tails, scan, delta-rule and state-space state)
         # and the window layers' pools
-        self._state_bytes = _tree_bytes(self.state)
+        self._state_bytes = tree_bytes(self.state)
         # a sequence's start, shared by every chunked prompt's first
         # chunk (never donated: a chunk hands back a new state)
         self._request_start = stack.new_request_state(
@@ -941,7 +947,7 @@ class InferenceEngine:
         # fills the chip long before the pool runs out (chip, PR 45: 72 MB
         # each, out of memory at 1.5 x the knee). None: a backend that
         # keeps no count of its memory (the CPU), or no such state
-        held = _tree_bytes(self._request_start)
+        held = tree_bytes(self._request_start)
         free = _device_free_bytes(self.k_pages) if held else None
         self._state_room = None if free is None else max(1, free // 2 // held)
         self._states_out = 0  # such sequences now; under _alloc_lock
@@ -1413,60 +1419,74 @@ class InferenceEngine:
         determinism warmup. Default compiles every configured bucket —
         pass buckets=[...] to warm only the shapes a deployment serves.
         """
-        import numpy as _np
-
+        # each program under a region of its own, from its first call to
+        # its blocked end: on a traced thread (`serve/llm.py start_engine`)
+        # the `xla.*` spans of what it compiles are that region's children
         bucket_list = list(buckets) if buckets is not None else list(
             self.ecfg.prefill_buckets)
         sizes = (list(batch_sizes) if batch_sizes is not None
                  else self.ecfg.prefill_tiers())
         for bucket in bucket_list:
             for Bp in sizes:
-                self._prefill_fn(bucket, Bp)(
-                    self.params,
-                    jnp.ones((Bp, bucket), jnp.int32),
-                    jnp.ones((Bp,), jnp.int32),
-                )
+                with tracing.region("engine.warmup.program",
+                                    program=f"prefill_bucket_{bucket}x{Bp}",
+                                    rows=Bp * bucket):
+                    jax.block_until_ready(self._prefill_fn(bucket, Bp)(
+                        self.params,
+                        jnp.ones((Bp, bucket), jnp.int32),
+                        jnp.ones((Bp,), jnp.int32),
+                    ))
         B = self.ecfg.max_batch_size
         pps = self.ecfg.pages_per_seq
         spans = {max(1, self.ecfg.decode_span)}
         if self.ecfg.adaptive_span:
             spans.add(max(1, self.ecfg.busy_span))
         for span in sorted(spans):
-            # positions 0 + all-zero page tables write only the reserved
-            # trash page, so a warmup span never touches live cache state.
+            # decode spans take the resident pool (rebound through
+            # _run_decode: the warmup call consumes and replaces it).
             # Both sampler modes compile: the first top-p/top-k request
             # must not jit inside the decode loop under live traffic.
             for advanced in (False, True):
-                seq = self._run_decode(self._decode(span, advanced)(
-                    self.params, self.k_pages, self.v_pages,
-                    jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
-                    self._tables(jnp.zeros((B, pps), jnp.int32),
-                                 jnp.zeros((B, self._ring), jnp.int32)),
-                    jnp.zeros((B,), jnp.float32),
-                    jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.int32),
-                    jax.random.PRNGKey(0), self.state,
-                    (*self._carry, jnp.ones((B,), bool)),
-                ))[0]
-                _np.asarray(seq)  # block until compiled + executed
+                with tracing.region("engine.warmup.program", steps=span,
+                                    program=f"decode_span_{span}"
+                                    + ("_adv" if advanced else "")):
+                    seq = self._run_decode(self._decode(span, advanced)(
+                        self.params, self.k_pages, self.v_pages,
+                        jnp.zeros((B,), jnp.int32),
+                        jnp.zeros((B,), jnp.int32),
+                        self._tables(jnp.zeros((B, pps), jnp.int32),
+                                     jnp.zeros((B, self._ring), jnp.int32)),
+                        jnp.zeros((B,), jnp.float32),
+                        jnp.ones((B,), jnp.float32),
+                        jnp.zeros((B,), jnp.int32),
+                        jax.random.PRNGKey(0), self.state,
+                        (*self._carry, jnp.ones((B,), bool)),
+                    ))[0]
+                    np.asarray(seq)  # block until compiled + executed
         if self.ecfg.chunked_prefill:
             # both chunk programs the queue picks from (`_advance_chunk`)
             for C in filter(None, (self.ecfg.prefill_chunk, self._wide)):
-                logits, self.k_pages, self.v_pages, state = self._chunk_fn(C)(
-                    self.params, self.k_pages, self.v_pages,
-                    jnp.zeros((C,), jnp.int32), jnp.int32(0),
-                    self._tables(jnp.zeros((pps,), jnp.int32),
-                                 jnp.zeros((self._ring,), jnp.int32)),
-                    jnp.int32(C - 1),
-                    self.state if self._ring else self._request_start,
-                )
-                _np.asarray(logits)
+                with tracing.region("engine.warmup.program",
+                                    program=f"chunk_prefill_{C}", rows=C):
+                    logits, self.k_pages, self.v_pages, state = \
+                        self._chunk_fn(C)(
+                            self.params, self.k_pages, self.v_pages,
+                            jnp.zeros((C,), jnp.int32), jnp.int32(0),
+                            self._tables(jnp.zeros((pps,), jnp.int32),
+                                         jnp.zeros((self._ring,), jnp.int32)),
+                            jnp.int32(C - 1),
+                            self.state if self._ring else self._request_start,
+                        )
+                    np.asarray(logits)
                 if self._ring:  # all-zero tables wrote the trash pages alone
                     self.state = state
             if not self._ring and self.state:
                 # the program that hands a slot its state
-                self.state = self._install_state(
-                    self.state, self._request_start, jnp.int32(0),
-                    jnp.int32(1))
+                with tracing.region("engine.warmup.program",
+                                    program="install_state"):
+                    self.state = jax.block_until_ready(self._install_state(
+                        self.state, self._request_start, jnp.int32(0),
+                        jnp.int32(1)))
         if self._spec is not None:
             self._spec.warmup()
 
@@ -3561,6 +3581,7 @@ class InferenceEngine:
                 "state_room": self._state_room} if self.state else {}),
             **prefix,
             "steps": self._step_count,
+            "startup_trace_id": self.startup_trace_id,
             "weights_version": self.weights_version,
             "tokens_per_decode_step": (
                 self._tps_committed / self._tps_steps
@@ -3587,7 +3608,7 @@ class InferenceEngine:
         self._work.set()  # wake the decode thread so it observes _stop
 
 
-def _tree_bytes(tree) -> int:
+def tree_bytes(tree) -> int:
     return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
 
 

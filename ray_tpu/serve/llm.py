@@ -13,9 +13,86 @@ from typing import Any, Dict, List, Optional
 
 import jax
 
+from ..core.metrics import Counter
 from ..models import get_config, init_params
+from ..util import tracing
 from .deployment import deployment
-from .engine import EngineConfig, InferenceEngine
+from .engine import EngineConfig, InferenceEngine, tree_bytes
+
+_m_replica_start = Counter(
+    "serve_replica_start_seconds",
+    "Seconds of a replica's start (`start_engine`), by `phase`: params "
+    "(the weights' loader), engine (InferenceEngine.__init__: pools, "
+    "state, slot tables, jit wrappers) and warmup (the whole of "
+    "`warmup`): the `replica.start` trace's regions, summed.")
+
+
+def start_engine(load_params, engine_config: Dict[str, Any],
+                 tensor_parallel: int = 1, draft_params_fn=None,
+                 role: str = "colocated") -> InferenceEngine:
+    """A replica's start, as ONE trace: the root span `replica.start`
+    (always, one a replica life: not sampled) and under it the regions
+    that tile it, `replica.start.params` (`load_params() -> (params,
+    model_cfg)`, and the draft's weights), `replica.start.engine`
+    (`InferenceEngine.__init__`) and `engine.warmup`, in which `warmup`
+    opens one `engine.warmup.program` a program; the `xla.trace` /
+    `xla.lower` / `xla.compile` spans of what each compiled are its
+    children (`tracing.watch_compiles`). The regions' seconds add up in
+    `serve_replica_start_seconds{phase}`. The trace's id is
+    `engine.stats()["startup_trace_id"]`; the finished tree stays on the
+    engine (`engine.startup_trace`), where the span ring's turning over
+    does not reach it."""
+    tracing.watch_compiles()
+    with tracing.start_span("replica.start", {"role": role}) as root:
+        with tracing.region("replica.start.params") as r:
+            params, cfg = load_params()
+            draft_params = (draft_params_fn()
+                            if draft_params_fn is not None else None)
+            r.note(bytes=tree_bytes(params))
+        _m_replica_start.inc(r.elapsed_s, tags={"phase": "params"})
+        if role != "colocated" and cfg.is_stack:
+            raise ValueError(
+                f"role={role!r}: {cfg.name!r} keeps state beside its KV "
+                "pages (conv tails, scan state, window rings) that the KV "
+                "wire does not carry; serve it colocated")
+        ecfg = EngineConfig(**engine_config)
+        mesh = None
+        if tensor_parallel > 1:
+            from ..comm.mesh import MeshSpec, build_mesh
+
+            devices = jax.devices()
+            if len(devices) < tensor_parallel:
+                raise ValueError(
+                    f"tensor_parallel={tensor_parallel} needs that many local "
+                    f"devices, have {len(devices)}"
+                )
+            mesh = build_mesh(
+                MeshSpec.create(tp=tensor_parallel),
+                devices=devices[:tensor_parallel],
+            )
+        with tracing.region("replica.start.engine") as r:
+            engine = InferenceEngine(params, cfg, ecfg, mesh=mesh,
+                                     draft_params=draft_params)
+            r.note(pool_bytes=tree_bytes((engine.k_pages, engine.v_pages)),
+                   state_bytes=engine._state_bytes)
+        _m_replica_start.inc(r.elapsed_s, tags={"phase": "engine"})
+        engine.startup_trace_id = root.trace_id
+        with tracing.region("engine.warmup") as r:
+            engine.warmup(buckets=[])
+        _m_replica_start.inc(r.elapsed_s, tags={"phase": "warmup"})
+    engine.startup_trace = tracing.get_trace(root.trace_id)
+    return engine
+
+
+def default_params(model_name: str, model_overrides: Optional[Dict[str, Any]]):
+    """-> the loader `start_engine` takes: random-init weights of the
+    named config."""
+
+    def load():
+        cfg = get_config(model_name, **(model_overrides or {}))
+        return init_params(cfg, jax.random.PRNGKey(0)), cfg
+
+    return load
 
 
 @deployment(name="llm", max_ongoing_requests=32)
@@ -69,16 +146,6 @@ class LLMServer:
         self._adapter_capacity = 8
         self._adapter_lock = threading.Lock()
         self._adapter_hits: Dict[str, int] = {}
-        if params_fn is not None:
-            params, cfg = params_fn()
-        else:
-            cfg = get_config(model_name, **(model_overrides or {}))
-            params = init_params(cfg, jax.random.PRNGKey(0))
-        if role != "colocated" and cfg.is_stack:
-            raise ValueError(
-                f"role={role!r}: {cfg.name!r} keeps state beside its KV "
-                "pages (conv tails, scan state, window rings) that the KV "
-                "wire does not carry; serve it colocated")
         engine_config = dict(engine_config or {})
         if speculation is not None:
             if engine_config.get("speculation") is not None:
@@ -86,34 +153,12 @@ class LLMServer:
                     "pass speculation either as the LLMServer kwarg or "
                     "inside engine_config, not both")
             engine_config["speculation"] = speculation
-        ecfg = EngineConfig(**engine_config)
-        mesh = None
-        if tensor_parallel > 1:
-            from ..comm.mesh import MeshSpec, build_mesh
-
-            devices = jax.devices()
-            if len(devices) < tensor_parallel:
-                raise ValueError(
-                    f"tensor_parallel={tensor_parallel} needs that many local "
-                    f"devices, have {len(devices)}"
-                )
-            mesh = build_mesh(
-                MeshSpec.create(tp=tensor_parallel),
-                devices=devices[:tensor_parallel],
-            )
-        draft_params = (draft_params_fn()
-                        if draft_params_fn is not None else None)
-        self.engine = InferenceEngine(params, cfg, ecfg, mesh=mesh,
-                                      draft_params=draft_params)
+        self.engine = start_engine(
+            params_fn or default_params(model_name, model_overrides),
+            engine_config, tensor_parallel, draft_params_fn, role)
         # SLO digests group by serving role (colocated/prefill/decode):
         # the head answers "p95 TTFT per role" from the merged sketches
         self.engine.slo_role = role
-        # compile every decode-span program at replica init: the
-        # adaptive policy's busy_span would otherwise jit mid-traffic,
-        # stalling the whole active batch exactly under prefill
-        # pressure (prefill buckets still compile on first use —
-        # warming every bucket would multiply startup time)
-        self.engine.warmup(buckets=[])
 
     def shutdown(self) -> None:
         """Replica retirement: stop the engine's threads and let go of it,
@@ -286,6 +331,11 @@ class LLMServer:
             out["adapters"] = sorted(self._adapters)
             out["adapter_requests"] = dict(self._adapter_hits)
         return out
+
+    def startup_trace(self, _request: Any = None) -> List[Dict[str, Any]]:
+        """This replica's `replica.start` trace as a tree (the shape of
+        `tracing.get_trace`), however long it has served since."""
+        return self.engine.startup_trace
 
     def check_health(self) -> None:
         pass

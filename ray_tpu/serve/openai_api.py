@@ -21,12 +21,9 @@ import time
 import uuid
 from typing import Any, Dict, Iterable, List, Optional
 
-import jax
-
-from ..models import get_config, init_params
 from ..util import tracing
 from .deployment import deployment
-from .engine import EngineConfig, InferenceEngine
+from .llm import default_params, start_engine
 
 
 class SSEStream:
@@ -123,11 +120,6 @@ class OpenAIServer:
             self.engine = None
             return
         self._coordinator = None
-        if params_fn is not None:
-            params, cfg = params_fn()
-        else:
-            cfg = get_config(model_name, **(model_overrides or {}))
-            params = init_params(cfg, jax.random.PRNGKey(0))
         ecfg_kw = dict(engine_config or {})
         ecfg_kw.setdefault("eos_token_id", self.tokenizer.eos_token_id)
         if speculation is not None:
@@ -136,23 +128,9 @@ class OpenAIServer:
                     "pass speculation either as the OpenAIServer kwarg or "
                     "inside engine_config, not both")
             ecfg_kw["speculation"] = speculation
-        ecfg = EngineConfig(**ecfg_kw)
-        mesh = None
-        if tensor_parallel > 1:
-            from ..comm.mesh import MeshSpec, build_mesh
-
-            devices = jax.devices()[:tensor_parallel]
-            mesh = build_mesh(MeshSpec.create(tp=tensor_parallel), devices=devices)
-        draft_params = (draft_params_fn()
-                        if draft_params_fn is not None else None)
-        self.engine = InferenceEngine(params, cfg, ecfg, mesh=mesh,
-                                      draft_params=draft_params)
-        # compile every decode-span program at replica init: the
-        # adaptive policy's busy_span would otherwise jit mid-traffic,
-        # stalling the whole active batch exactly under prefill
-        # pressure (prefill buckets still compile on first use —
-        # warming every bucket would multiply startup time)
-        self.engine.warmup(buckets=[])
+        self.engine = start_engine(
+            params_fn or default_params(model_name, model_overrides),
+            ecfg_kw, tensor_parallel, draft_params_fn)
 
     # ------------------------------------------------------------- routes
 

@@ -10,6 +10,7 @@ import time
 from typing import Any, Dict, Optional
 
 from .. import api
+from ..util import tracing
 
 
 @api.remote
@@ -109,6 +110,12 @@ class ServeReplica:
         hook = None if self._is_function else getattr(target, "shutdown", None)
         if callable(hook):
             hook()
+
+    def ready_ns(self) -> int:
+        """The controller's readiness probe, queued at spawn: the actor's
+        first task runs when __init__ has ended, so this is when that
+        was, on `tracing.now_ns()` (the wall-anchored clock)."""
+        return tracing.now_ns()
 
     def health_check(self) -> bool:
         chk = getattr(self._callable, "check_health", None)

@@ -435,14 +435,20 @@ class DraftModelProposer:
     def warmup(self, engine) -> None:
         B = engine.ecfg.max_batch_size
         C = self.chunk
-        self.k_pages, self.v_pages = self._chunk_fn(C)(
-            self.params, self.k_pages, self.v_pages,
-            jnp.zeros((C,), jnp.int32), jnp.int32(0), self._tables[0])
-        drafts, self.k_pages, self.v_pages = self._propose_fn(
-            self.params, self.k_pages, self.v_pages,
-            jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
-            jnp.zeros((B,), jnp.int32), self._tables)
-        np.asarray(drafts)
+        with tracing.region("engine.warmup.program",
+                            program="draft.chunk_step", rows=C):
+            self.k_pages, self.v_pages = jax.block_until_ready(
+                self._chunk_fn(C)(
+                    self.params, self.k_pages, self.v_pages,
+                    jnp.zeros((C,), jnp.int32), jnp.int32(0),
+                    self._tables[0]))
+        with tracing.region("engine.warmup.program",
+                            program="draft.propose", steps=self.k):
+            drafts, self.k_pages, self.v_pages = self._propose_fn(
+                self.params, self.k_pages, self.v_pages,
+                jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32),
+                jnp.zeros((B,), jnp.int32), self._tables)
+            np.asarray(drafts)
 
     def _prev_tokens(self, engine, tokens) -> np.ndarray:
         """The token at position-1 per slot (catch-up feed)."""
@@ -592,13 +598,20 @@ class SpecDecoder:
         pps = eng.ecfg.pages_per_seq
         S = self.k + 1
         for advanced in (False, True):
-            committed, _, eng.k_pages, eng.v_pages = self._verify(advanced)(
-                eng.params, eng.k_pages, eng.v_pages,
-                jnp.zeros((B, S), jnp.int32), jnp.zeros((B,), jnp.int32),
-                jnp.zeros((B, pps), jnp.int32), jnp.zeros((B,), jnp.int32),
-                jnp.zeros((B,), jnp.float32), jnp.ones((B,), jnp.float32),
-                jnp.zeros((B,), jnp.int32), jax.random.PRNGKey(0))
-            np.asarray(committed)
+            with tracing.region("engine.warmup.program", rows=B * S,
+                                program=f"verify_{self.k}"
+                                + ("_adv" if advanced else "")):
+                committed, _, eng.k_pages, eng.v_pages = \
+                    self._verify(advanced)(
+                        eng.params, eng.k_pages, eng.v_pages,
+                        jnp.zeros((B, S), jnp.int32),
+                        jnp.zeros((B,), jnp.int32),
+                        jnp.zeros((B, pps), jnp.int32),
+                        jnp.zeros((B,), jnp.int32),
+                        jnp.zeros((B,), jnp.float32),
+                        jnp.ones((B,), jnp.float32),
+                        jnp.zeros((B,), jnp.int32), jax.random.PRNGKey(0))
+                np.asarray(committed)
 
     # verify cost model: one S-wide forward ~ ALPHA + S in single-row
     # units (ALPHA covers dispatch + the fixed host share of a round).

@@ -16,6 +16,7 @@ from typing import Any, Callable, Dict, Optional
 
 from .. import api
 from ..core.logging import get_logger
+from ..util import tracing
 from .checkpoint import Checkpoint, CheckpointManager
 from .config import RunConfig, ScalingConfig
 from .result import Result
@@ -64,6 +65,7 @@ class JaxTrainer:
         return path
 
     def fit(self) -> Result:
+        started_ns = tracing.now_ns()  # `train.start` opens (worker_group)
         api._auto_init()
         storage = self._storage_dir()
         ckpt_cfg = self.run_config.checkpoint_config
@@ -93,12 +95,14 @@ class JaxTrainer:
                 refs = group.run(
                     self.train_loop, base_config, resume,
                     datasets_per_rank=split_datasets,
+                    started_ns=started_ns,
                 )
                 self._stream(group, refs, manager, history)
                 last_metrics = history[-1] if history else {}
                 break
             except (api.RayTaskError, api.RayActorError, api.GetTimeoutError, RuntimeError) as e:
                 failures += 1
+                started_ns = tracing.now_ns()  # the next gang's start
                 resume = manager.latest or resume
                 logger.warning(
                     "training gang failed (%s); failures=%d/%s; resume=%s",

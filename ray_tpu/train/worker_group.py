@@ -15,11 +15,21 @@ from typing import Any, Callable, Dict, List, Optional
 
 from .. import api
 from ..core.logging import get_logger
+from ..core.metrics import Counter
+from ..util import tracing
 from .checkpoint import Checkpoint
 from .config import ScalingConfig
 from .session import TrainContext, _TrainSession, _get_session, _set_session
 
 logger = get_logger("train.worker_group")
+
+_m_start = Counter(
+    "train_start_seconds",
+    "Seconds before the user's loop ran, by `phase`: gang = from "
+    "JaxTrainer.fit()'s entry (a restart: from the failure) to the first "
+    "line of the loop on rank 0: placement group, worker actors, the "
+    "distributed bootstrap, the dataset shards' hand-over. The region "
+    "`train.start`.")
 
 
 @api.remote
@@ -46,10 +56,17 @@ class TrainWorker:
         context: TrainContext,
         resume_checkpoint: Optional[Checkpoint],
         datasets: Optional[Dict[str, Any]] = None,
+        started_ns: Optional[int] = None,
     ) -> Any:
+        """`started_ns`: when the trainer began to start this gang, on
+        `tracing.now_ns()`; the region `train.start` ends here."""
+        tracing.watch_compiles()
         self.session = _TrainSession(context, resume_checkpoint,
                                      datasets=datasets)
         _set_session(self.session)
+        if started_ns is not None and self.rank == 0:
+            _m_start.inc(tracing.region_since("train.start", started_ns),
+                         tags={"phase": "gang"})
         try:
             return train_func(config)
         finally:
@@ -154,6 +171,7 @@ class WorkerGroup:
         config: Dict[str, Any],
         resume_checkpoint: Optional[Checkpoint],
         datasets_per_rank: Optional[Dict[str, List[Any]]] = None,
+        started_ns: Optional[int] = None,
     ) -> List[Any]:
         refs = []
         for rank, w in enumerate(self.workers):
@@ -179,7 +197,8 @@ class WorkerGroup:
                 topology=self._topology_for_rank(rank),
             )
             refs.append(w.run.remote(train_func, cfg, ctx, resume_checkpoint,
-                                     datasets=rank_datasets))
+                                     datasets=rank_datasets,
+                                     started_ns=started_ns))
         return refs
 
     def _topology_for_rank(self, rank: int):

@@ -18,7 +18,11 @@ def enable_compile_cache() -> str:
 
     Where JAX_COMPILATION_CACHE_DIR is set, jax reads it itself and this
     sets no other directory. Otherwise the cache goes to
-    `<checkout>/.jax_cache`."""
+    `<checkout>/.jax_cache`. Either way the compile listeners are on from
+    here (`tracing.watch_compiles`): every program after is timed."""
+    from . import tracing
+
+    tracing.watch_compiles()
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if path:
         return path

@@ -58,7 +58,9 @@ thread's line of the xplane's `/host:CPU` plane, on the device's clock
 by construction; while the thread carries a (sampled or propagated)
 span it is a child `Span` in the buffer. With neither it costs a flag
 test and two clock reads. A backend compile inside a region is counted
-as `xla_compiles{under=<region name>}` (see `_on_jax_duration`)."""
+as `xla_compiles{under=<region name>}`, and every stage of a program's
+way to the device (trace, lower, compile, with the persistent cache's
+answer) is timed and named the same way (see `watch_compiles`)."""
 
 from __future__ import annotations
 
@@ -102,6 +104,20 @@ _m_compiles = Counter(
     "XLA backend compilations (a persistent-cache load counts: it raises "
     "the same jax event), by the innermost tracing.region open on the "
     "compiling thread (`under`, or \"none\").")
+_m_program_seconds = Counter(
+    "xla_program_seconds",
+    "Seconds jax spent bringing programs to the device, by `stage` (trace: "
+    "Python to jaxpr; lower: jaxpr to an MLIR module, Pallas kernels "
+    "included; compile: the backend's compile, or the persistent cache's "
+    "load), by the persistent cache's answer (`cache`: hit, miss = "
+    "compiled and written, off = no cache holds it: every trace and "
+    "lower, and a compile with the cache off or under its thresholds) and "
+    "by the innermost tracing.region open on the thread (`under`). A stage "
+    "is filed once, outermost: what jax traces or compiles inside another "
+    "stage's interval is that stage's time.")
+_m_programs = Counter(
+    "xla_programs",
+    "Events beside xla_program_seconds: stages filed, same tags.")
 
 
 class Span:
@@ -176,6 +192,18 @@ def record_child(parent, name: str, start_ns: int, end_ns: int,
          attrs=attrs, start_ns=start_ns).finish(end_ns)
 
 
+def region_since(name: str, start_ns: int, **attrs: Any) -> float:
+    """Close here a region that another thread or process opened by
+    reading `now_ns()` (the clock is anchored to the wall, so the two
+    readings compare): a child span where this thread carries one.
+    -> its seconds, for the caller's counter."""
+    end = max(now_ns(), start_ns)
+    parent = getattr(_local, "span", None)
+    if parent is not None:
+        record_child(parent, name, start_ns, end, attrs)
+    return (end - start_ns) * 1e-9
+
+
 def named(fn, name: str):
     """`fn` under a function name. jax names a jit's XLA module after the
     traced function (`jit_<name>` in a profile); a functools.partial has
@@ -199,27 +227,97 @@ def _resolve_annotation():
         return None
     import jax
 
-    with _lock:
-        if _annotation is None:
-            jax.monitoring.register_event_duration_secs_listener(
-                _on_jax_duration)
-            _annotation = jax.profiler.TraceAnnotation
+    watch_compiles()
+    _annotation = jax.profiler.TraceAnnotation
     return _annotation
 
 
-def _on_jax_duration(event: str, seconds: float, **_kw: Any) -> None:
-    """A backend compile on this thread: count it under the innermost
-    open region, and put an `xla.compile` child span into a traced
-    request's tree — "which step recompiled" as a counter and a span."""
-    if event != "/jax/core/compile/backend_compile_duration":
+# jax's three stages of a program's way to the device, each raised with
+# `fun_name` as a scalar at its start and as a duration at its end
+_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_ANSWERS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+# a stage shorter than this is counted and not made a span: a start
+# re-traces hundreds of `jnp` wrappers in microseconds each
+_STAGE_SPAN_FLOOR_S = 1e-3
+_watching = False
+
+
+def watch_compiles() -> None:
+    """Register the compile listeners with jax, once a process. Call it
+    before the first jit (`enable_compile_cache()`, `serve/llm.py
+    start_engine` and the trainer's worker do): what compiled before is
+    never seen."""
+    global _watching
+    import jax
+
+    with _lock:
+        if _watching:
+            return
+        _watching = True
+    jax.monitoring.register_scalar_listener(_on_jax_stage_start)
+    jax.monitoring.register_event_listener(_on_jax_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def _on_jax_stage_start(event: str, _value: float, **_kw: Any) -> None:
+    """A stage opens on this thread. jax traces the jits a function calls
+    (every `jnp` function is one) inside its own trace, and compiles what
+    a trace evaluates eagerly inside it: the depth tells the outermost
+    stage, the one that is filed, from what it holds."""
+    if event in _STAGES:
+        _local.stage_depth = getattr(_local, "stage_depth", 0) + 1
+
+
+def _on_jax_event(event: str, **_kw: Any) -> None:
+    """The persistent cache's answer, raised on the compiling thread
+    INSIDE the backend-compile interval it belongs to (a miss: when the
+    compiled program is written): kept for that interval's end."""
+    answer = _CACHE_ANSWERS.get(event)
+    if answer is not None:
+        _local.cache_answer = answer
+
+
+def _on_jax_duration(event: str, seconds: float, fun_name: str = "",
+                     **_kw: Any) -> None:
+    """A stage ends on this thread: count it under the innermost open
+    region, and put an `xla.<stage>` child span that names the program
+    into a traced thread's tree: "which step recompiled", timed."""
+    stage = _STAGES.get(event)
+    if stage is None:
+        if event == "/jax/compilation_cache/cache_retrieval_time_sec":
+            _local.cache_retrieval_s = seconds
         return
-    inner = getattr(_local, "region", None)
-    _m_compiles.inc(tags={"under": inner.name if inner is not None
-                          else "none"})
-    parent = getattr(_local, "span", None)
-    if parent is not None:
+    loc = _local
+    depth = loc.stage_depth = max(0, getattr(loc, "stage_depth", 0) - 1)
+    inner = getattr(loc, "region", None)
+    under = inner.name if inner is not None else "none"
+    cache = "off"
+    attrs: Dict[str, Any] = {}
+    if stage == "compile":
+        _m_compiles.inc(tags={"under": under})
+        cache, loc.cache_answer = getattr(loc, "cache_answer", "off"), "off"
+        if cache == "hit":
+            attrs["retrieval_s"], loc.cache_retrieval_s = getattr(
+                loc, "cache_retrieval_s", 0.0), 0.0
+    if depth:
+        return  # inside another stage's interval: that stage's time
+    tags = {"stage": stage, "cache": cache, "under": under}
+    _m_program_seconds.inc(seconds, tags=tags)
+    _m_programs.inc(tags=tags)
+    parent = getattr(loc, "span", None)
+    if parent is not None and seconds >= _STAGE_SPAN_FLOOR_S:
         end = now_ns()
-        record_child(parent, "xla.compile", end - int(seconds * 1e9), end)
+        # jax names the function at the trace and `jit(<function>)` after
+        program = fun_name[4:-1] if fun_name.startswith("jit(") else fun_name
+        record_child(parent, "xla." + stage, end - int(seconds * 1e9), end,
+                     {"program": program, "cache": cache, **attrs})
 
 
 class region:
@@ -268,6 +366,14 @@ class region:
             self._ann.__exit__(*exc)
         _local.region = self._outer
         return False
+
+    def note(self, **attrs: Any) -> None:
+        """Attributes the body learns (sizes, counts), onto the child
+        span where the thread carries one. The xplane's annotation was
+        written at entry and keeps what it was given there."""
+        self.attrs.update(attrs)
+        if self._parent is not None:
+            self._span.attrs.update(attrs)
 
     @property
     def elapsed_s(self) -> float:

@@ -87,6 +87,13 @@ LATER_CELLS = ("longcat-flash-omni.serve-docs",  # PR 39
 # metrics that later PRs appended for cells that were there already
 LATER_READERS = ("moe_experts_skipped_share",)  # PR 42
 
+# the parts of `setup_s` (PR 50), appended for every serve cell at once:
+# they end the list whatever cells a test leaves out
+SETUP_READERS = ("setup_replica_ready_s", "setup_params_s",
+                 "setup_engine_init_s", "setup_warmup_s",
+                 "setup_trace_lower_s", "setup_compile_s",
+                 "setup_cache_hit_share")
+
 
 def manifest_without(cells):
     """The manifest without `cells` that later PRs appended: their entries
@@ -287,7 +294,7 @@ def test_lfm2_readers_reach_the_counts_through_the_family():
         "moe_ffn_device_share.tpot", "paged_decode_roofline"}
     # its answers outlast the window: the time per token is what is judged
     assert [m["name"] for m in cell["end_to_end"]] == ["tpot_mean_ms", "setup_s"]
-    assert all(m["moves"] == "tpot_mean_ms" for m in cell["per_layer"])
+    assert {m["moves"] for m in cell["per_layer"]} == {"tpot_mean_ms", "setup_s"}
     mixtral = common.load_cell("mixtral-8x7b.serve-chat")
     assert "moe_rows_padding_factor" in {m["name"] for m in mixtral["per_layer"]}
 
@@ -391,9 +398,11 @@ def test_olmo_hybrid_readers_reach_the_counts_through_the_family():
         "decode_step_wall_ms.shared", "interleaved_prefill_tokens_per_token",
         "decode_live_slots.traced",
         # how often the loop stayed a span ahead (PR 38), likewise
-        "decode_span_ahead_share"}
+        "decode_span_ahead_share",
+        # where the set-up's seconds went (PR 50), likewise
+        *SETUP_READERS}
     assert [m["name"] for m in cell["end_to_end"]] == ["tpot_mean_ms", "setup_s"]
-    assert all(m["moves"] == "tpot_mean_ms" for m in cell["per_layer"])
+    assert {m["moves"] for m in cell["per_layer"]} == {"tpot_mean_ms", "setup_s"}
 
 
 def test_the_delta_rules_work_is_a_hand_count_at_one_small_shape():
@@ -583,15 +592,18 @@ def test_longcat_readers_reach_the_counts_through_the_family():
         "tpot_unaccounted_ms", "decode_step_wall_ms.clean",
         "decode_step_wall_ms.shared", "interleaved_prefill_tokens_per_token",
         "decode_live_slots.traced", "decode_span_ahead_share",
-        *LATER_READERS}
+        *LATER_READERS, *SETUP_READERS}
     assert [m["name"] for m in cell["end_to_end"]] == ["tpot_mean_ms", "setup_s"]
-    assert all(m["moves"] == "tpot_mean_ms" for m in cell["per_layer"])
+    assert {m["moves"] for m in cell["per_layer"]} == {"tpot_mean_ms", "setup_s"}
     # appended, never inserted: the new entries ended their lists, before
     # later PRs appended theirs
     manifest = manifest_without(LATER_CELLS[1:])
     assert manifest["configs"][-1]["name"] == LONGCAT
     assert manifest["workloads"][-1]["name"] == LONGCAT_CELL
-    assert tuple(m["name"] for m in manifest["per_layer"][-6:]) == NEW_READERS
+    names = [m["name"] for m in manifest["per_layer"]]
+    at = names.index(NEW_READERS[0])
+    assert tuple(names[at:at + 6]) == NEW_READERS
+    assert names[at + 6:] == [*SETUP_READERS, "setup_trainer_start_s"]
     assert all(m["workloads"][-1] == LONGCAT_CELL for m in
                manifest["per_layer"] if LONGCAT_CELL in m["workloads"])
 

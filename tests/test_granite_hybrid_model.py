@@ -609,7 +609,8 @@ def test_the_family_counts_a_steps_and_a_chunks_work_from_the_equations():
 
 
 def test_the_cell_is_listed_where_its_readers_read():
-    from tests.test_benchmark_families import LATER_CELLS, manifest_without
+    from tests.test_benchmark_families import (LATER_CELLS, SETUP_READERS,
+                                               manifest_without)
 
     # the manifest as this cell's PR left it: later PRs append theirs
     manifest = manifest_without(LATER_CELLS[LATER_CELLS.index(CELL) + 1:])
@@ -618,9 +619,14 @@ def test_the_cell_is_listed_where_its_readers_read():
         CONFIG, "serve-chat-burst", 1)
     assert manifest["workloads"][-1] == entry
     assert manifest["configs"][-1]["name"] == CONFIG
-    assert [m["name"] for m in manifest["per_layer"][-3:]] == [
-        "ssd_step_roofline", "ssd_step_device_share", "ssd_chunk_roofline"]
-    assert all(m["workloads"] == [CELL] for m in manifest["per_layer"][-3:])
+    # its three ended the list; the parts of `setup_s` (PR 50) follow them
+    names = [m["name"] for m in manifest["per_layer"]]
+    at = names.index("ssd_step_roofline")
+    assert names[at:] == [
+        "ssd_step_roofline", "ssd_step_device_share", "ssd_chunk_roofline",
+        *SETUP_READERS, "setup_trainer_start_s"]
+    assert all(m["workloads"] == [CELL]
+               for m in manifest["per_layer"][at:at + 3])
     listing = {m["name"] for m in manifest["per_layer"]
                if CELL in m.get("workloads", ())}
     assert {"recurrent_state_live_share", "paged_decode_roofline",
@@ -630,7 +636,9 @@ def test_the_cell_is_listed_where_its_readers_read():
     # the cell ends the list it was appended to
     for m in manifest["per_layer"]:
         if CELL in m.get("workloads", ()):
-            assert m["moves"] == "tpot_mean_ms" and m["workloads"][-1] == CELL
+            assert m["moves"] == ("setup_s" if m["name"] in SETUP_READERS
+                                  else "tpot_mean_ms")
+            assert m["workloads"][-1] == CELL
     cell = common.load_cell(CELL)
     assert [m["name"] for m in cell["end_to_end"]] == ["tpot_mean_ms", "setup_s"]
     assert cell["engine"] == {"max_seq_len": 2048, "max_batch_size": 64,

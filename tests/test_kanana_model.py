@@ -451,7 +451,7 @@ def test_the_cell_is_an_entry_and_its_readers_list_it():
                    "moe_ffn_device_share.tpot", "moe_rows_padding_factor",
                    "prefill_device_ms_per_ktok", "decode_live_slots.traced"):
         assert wanted in names and CELL in by_name[wanted]["workloads"]
-    assert all(m["moves"] == "tpot_mean_ms" for m in cell["per_layer"])
+    assert {m["moves"] for m in cell["per_layer"]} == {"tpot_mean_ms", "setup_s"}
     mix = cell["traffic"]
     assert mix["sessions"]["turns"]["median"] == 5
     assert mix["shared_prefix"] == {"count": 4, "len": 6144, "zipf_s": 1.0}
