@@ -101,6 +101,16 @@ _m_chunk_rows = Counter(
 # the model has the wide one (`InferenceEngine._wide_chunk`)
 _m_chunk_calls = Counter(
     "serve_chunk_calls", "Prefill chunk programs dispatched, by their rows.")
+# a prompt's last chunk hands its first token to the next span on the
+# device; the host reads it once that span is out. read=behind_span over
+# both is the share of last chunks that the loop did not wait for
+_m_first_reads = Counter(
+    "serve_chunk_first_token_reads",
+    "Last chunks of chunked prompts, by where the host read their first "
+    "token (behind_span: after it had dispatched a decode span behind the "
+    "chunk, so the device stayed fed; drained: with nothing dispatched "
+    "behind the chunk: no sequence was live, the request exports its keys, "
+    "speculation, or the loop drained).")
 _m_ttft = Histogram(
     "serve_ttft_seconds", "Time to first token.",
     buckets=(0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0),
@@ -259,6 +269,8 @@ _choices_held = _m_moe_choices.labels(kind="held")
 _deferred_no_pages = _m_deferred.labels(reason="no_pages")
 _deferred_no_window_pages = _m_deferred.labels(reason="no_window_pages")
 _deferred_no_state_room = _m_deferred.labels(reason="no_state_room")
+_first_behind_span = _m_first_reads.labels(read="behind_span")
+_first_drained = _m_first_reads.labels(read="drained")
 _front_inbound = _m_front.labels(leg="inbound")
 _front_outbound = _m_front.labels(leg="outbound")
 
@@ -318,9 +330,10 @@ _m_token_wait = Counter(
     "engine.chunk.readback; host: install + cancel_check + build + commit, "
     "propose legs under speculation; dispatch; device_wait: "
     "engine.readback; loop: the iteration's remainder and the time between "
-    "iterations). A host phase that ends while a dispatched span is still "
-    "unfinished kept no sequence waiting for the host: its seconds are "
-    "device_wait (chunk_device_wait for engine.chunk).")
+    "iterations). A host phase that ends while a program the decode thread "
+    "dispatched before it, a span or a prefill chunk, is still unfinished "
+    "kept no sequence waiting for the host: its seconds are device_wait "
+    "(chunk_device_wait for engine.chunk).")
 _token_wait = {p: _m_token_wait.labels(part=p) for p in (
     "chunk_host", "chunk_device_wait", "host", "dispatch", "device_wait",
     "loop")}
@@ -341,9 +354,10 @@ _span_children = [
     for le in ("1", "2", "4", "8", "16", "32", "64", "+Inf")]
 _m_ahead = Counter(
     "serve_decode_ahead_steps",
-    "Decode steps of the spans that were dispatched while the span before "
-    "them was unfinished on the device: over serve_decode_span_steps, the "
-    "share of steps the device found queued when it finished the last.")
+    "Decode steps of the spans that were dispatched while the program the "
+    "decode thread dispatched before them, a span or a prefill chunk, was "
+    "unfinished on the device: over serve_decode_span_steps, the share of "
+    "steps the device found queued when it finished what it had.")
 _m_interleaved = Counter(
     "serve_decode_interleaved_prefill_tokens",
     "At each decode span, live slots x the prefill tokens (padded, as the "
@@ -549,6 +563,8 @@ class Request:
     # admitted and not yet in a slot: its state (`stack.new_request_state`)
     # lies outside the slots' (engine `_states_out` counts these)
     _state_out: bool = False
+    # its last chunk's first token while the host has not read it (`_First`)
+    _first: Optional[Any] = None
     # streamed KV export (disaggregated serving): when set on a
     # prefill_only request, KV frames are pushed to this callable as
     # prefill commits them (page-window slices of the bucketed row cache,
@@ -644,6 +660,32 @@ class _ChunkState:
         # what the chunks so far left behind beside the pages
         # (stack.new_request_state), handed from chunk to chunk
         self.state = None
+
+
+class _First:
+    """A chunked prompt's first token from its last chunk's dispatch to the
+    host's read of it (`InferenceEngine._read_first`). The chunk program
+    drew it; the sequence's first span takes it from the device
+    (`_install_ready`), and the host reads it once that span is out."""
+
+    __slots__ = ("request", "token", "row", "rows", "tokens",
+                 "weights_version", "slot", "pages")
+
+    def __init__(self, request: Request, token, row, rows: int, tokens: int,
+                 weights_version: int):
+        self.request = request
+        self.token = token  # int32 scalar, on the device
+        # float32 [2 (+2)], on the device: the token, its log-probability
+        # and, where the device counts them, the request's choices
+        self.row = row
+        # the chunk's rows, and the prompt tokens among them
+        self.rows, self.tokens = rows, tokens
+        self.weights_version = weights_version  # at the chunk's dispatch
+        # the slot that took the sequence with its token unread, if one did
+        self.slot: Optional[int] = None
+        # of a request that takes no slot (`max_tokens` 1: it ends at the
+        # read): its pages, freed there
+        self.pages: Optional[List[int]] = None
 
 
 class _Slot:
@@ -970,6 +1012,10 @@ class InferenceEngine:
         self._base_key = jax.random.PRNGKey(
             int.from_bytes(os.urandom(4), "little")
         )
+        # the chunk programs' draws of first tokens: a stream beside the
+        # spans' (`step` folds in its count of spans, from 1)
+        self._first_key = jax.random.fold_in(self._base_key, 0)
+        self._first_draws = 0
         self._lock = threading.Lock()
         self._alloc_lock = threading.Lock()  # allocator: prefill + decode threads
         self._ready: "list" = []  # prefilled, awaiting a decode slot
@@ -1028,20 +1074,40 @@ class InferenceEngine:
             carry = jax.device_put(
                 carry, NamedSharding(mesh, PartitionSpec()))
         self._carry = carry
+        # a sequence whose first token is still on the device joins its
+        # first span through the carry: its slot's row takes the token and
+        # the position (`_install_ready`), and the decode programs stay as
+        # they are
+        pinned = ({} if mesh is None else {"out_shardings": (
+            NamedSharding(mesh, PartitionSpec()),) * 2})
+        self._join_carry = self._under_mesh(jax.jit(
+            tracing.named(_join_carry, "join_carry"), **pinned))
+        # first tokens the chunk programs drew and the host has not read
+        # (`_First`), in the order of their dispatch; decode thread only
+        self._firsts: List[_First] = []
+        # an output of the program this thread dispatched last, a span or
+        # a chunk: unfinished, the device is busy (`_device_busy`); and what
+        # the last dispatch found of the program BEFORE it, until the phase
+        # that dispatched is filed (`_dispatched`, `_phase_done`)
+        self._last_out = None
+        self._found_busy: Optional[bool] = None
         self._read_ns = 0
         # weight swaps that `update_params` posted for the decode thread,
         # which drains the span in flight before it rebinds
         self._swaps: List[Any] = []
         self._loop_done = False
         # the token ledger's row of the running iteration (`_account`): ns
-        # of each decode phase, by whether a dispatched span was unfinished
-        # when the phase ended (`_hidden_ns`: the sequences waited for the
-        # device, not for the host); the slots live after `install` and at
+        # of each decode phase, by whether a program dispatched before it
+        # was unfinished when the phase ended (`_hidden_ns`: the sequences
+        # waited for the device, not for the host); the slots live after `install` and at
         # the last iteration's end; where the last iteration ended, and
         # whether the device was busy when this one began
         self._phase_ns = {name: 0 for name, _sink in _DECODE_PHASES.values()
                           if name not in ("engine.iter", "engine.idle")}
         self._hidden_ns = dict(self._phase_ns)
+        # the part of `engine.chunk.readback` that ran inside
+        # `engine.chunk` (a first token read where it was dispatched)
+        self._nested_read_ns = 0
         self._live = self._live_at_end = 0
         self._iter_end_ns = 0
         self._began_busy = False
@@ -1061,17 +1127,45 @@ class InferenceEngine:
                       **attrs)
 
     def _device_busy(self) -> bool:
-        """Is a dispatched span still unfinished? Asks the runtime about
-        the output of the one in flight (a span that was read back is
-        finished); no sync."""
-        span = self._inflight
-        return span is not None and not span.seq.is_ready()
+        """Is the program this thread dispatched last, a decode span or a
+        prefill chunk, still unfinished? Asks the runtime about one of its
+        outputs; no sync. The device runs this thread's programs in the
+        order of their dispatch, so while the last one is unfinished the
+        device never ran dry, whatever it is working on: a chunk that went
+        out behind span N keeps it busy long after span N is done."""
+        out = self._last_out
+        if out is None:
+            return False
+        if out.is_ready():
+            self._last_out = None
+            return False
+        return True
+
+    def _dispatched(self, out) -> bool:
+        """This thread has just dispatched a program, a span or a chunk, and
+        `out` is one of its outputs. -> was the program BEFORE it unfinished
+        (the device never ran dry while the host placed and dispatched this
+        one)? The phase that dispatched is filed by that answer
+        (`_phase_done`): its own program is unfinished by construction and
+        says nothing about what the sequences waited for."""
+        found = self._found_busy = self._device_busy()
+        self._last_out = out
+        return found
 
     def _phase_done(self, name: str, ns: int) -> None:
         """A decode phase's time, filed by what the sequences were waiting
-        for while it ran: a host phase that ends with a span unfinished on
-        the device delayed no token."""
-        hidden = not name.endswith("readback") and self._device_busy()
+        for while it ran: a host phase that ends with a program of this
+        thread unfinished on the device (`_device_busy`) delayed no token.
+        A phase that dispatched a program itself (`engine.chunk`,
+        `engine.dispatch`) is asked about the program before its own, as its
+        last dispatch found it (`_dispatched`). A readback leaves nothing of
+        what it read unfinished, so the phase around it (`engine.chunk`
+        around a first token read where its chunk went out) is asked anew."""
+        found, self._found_busy = self._found_busy, None
+        if name.endswith("readback"):
+            hidden = False
+        else:
+            hidden = self._device_busy() if found is None else found
         (self._hidden_ns if hidden else self._phase_ns)[name] += ns
 
     @property
@@ -1336,22 +1430,39 @@ class InferenceEngine:
         tests, odd head dims, and TP meshes (GSPMD partitions the
         fallback's einsums; a bare pallas_call it cannot)."""
         cfg, ps = self.cfg, self.ecfg.page_size
+        if cfg.vocab_size > 1 << 24:
+            raise ValueError(
+                f"{cfg.name!r}: a chunk program hands the host its first "
+                "token in a float32 row, which holds ids under 2**24 exactly; "
+                f"vocab_size is {cfg.vocab_size}")
 
         def chunk_step(params, k_pages, v_pages, tokens, start, page_table,
-                       last_idx, state=None, export=False):
+                       last_idx, state=None, how=None, key=None,
+                       export=False):
             """tokens [C]; start/last_idx scalars; page_table [pps] (where the
             window layers' pages are allocated, `cfg.window_paged`: a pair,
             that and the sequence's ring [ring], and `state` is the engine's
             own, the window page space's pools, handed back like the pool);
             `state`:
             what the sequence's chunks so far left behind beside its pages
-            (None: a sequence's start where pages are all there is).
-            Returns (logits_at_last_idx, k_pages, v_pages, state); with
-            export=True (static) the chunk's own KV slabs [L, C, KVH, hd]
-            in the pool dtype come before the state, so streamed export
-            ships this chunk without a separate page-gather dispatch
-            (which would queue behind whatever decode span is in flight).
-            Rows past last_idx are padding."""
+            (None: a sequence's start where pages are all there is); `how`
+            [3] float32: the request's temperature, top_p and top_k, and
+            `key` what a draw takes (`_sample_first`; the engine hands both
+            to every chunk, and a caller that lowers the program for its
+            sizes alone may leave them out: the argmax).
+            Returns (token, first, k_pages, v_pages, state): the token drawn
+            from the logits at last_idx, an int32 scalar that joins the next
+            decode span without leaving the device (`_install_ready`), and
+            `first`, the float32 row the host reads once that span is out
+            (`_read_first`): the token again, its log-probability under the
+            raw distribution and, where the device counts the tokens' choices
+            of experts (`cfg.counts_choices`), the request's counts so far.
+            With export=True (static) the chunk's own KV slabs
+            [L, C, KVH, hd] in the pool dtype come before the state, so
+            streamed export ships this chunk without a separate page-gather
+            dispatch (which would queue behind whatever decode span is in
+            flight). Rows past last_idx are padding; what a chunk that is
+            not its prompt's last draws is never read."""
             window_table = None
             if cfg.window_paged:
                 page_table, window_table = page_table
@@ -1366,13 +1477,19 @@ class InferenceEngine:
             with jax.named_scope("lm_head"):
                 logits = _head_logits(x, lambda x: x[0, last_idx], params,
                                       cfg, "d,dv->v")
+            with jax.named_scope("sample"):
+                if how is None:
+                    how, key = _how_to_sample(0.0, 1.0, 0), jnp.zeros(
+                        (2,), jnp.uint32)
+                tok, logp = _sample_first(logits, how, key)
+                # a token id is exact in float32 (checked above)
+                first = jnp.stack([tok.astype(jnp.float32), logp])
             if cfg.counts_choices:
-                # the request's counts so far, read with its last logits
-                logits = jnp.concatenate([logits, state["choices"]])
+                first = jnp.concatenate([first, state["choices"]])
             if export:
-                return (logits, new_k, new_v, state.pop("k")[:, 0],
+                return (tok, first, new_k, new_v, state.pop("k")[:, 0],
                         state.pop("v")[:, 0], state)
-            return logits, new_k, new_v, state
+            return tok, first, new_k, new_v, state
 
         cache: Dict[Any, Any] = {}
 
@@ -1468,7 +1585,7 @@ class InferenceEngine:
             for C in filter(None, (self.ecfg.prefill_chunk, self._wide)):
                 with tracing.region("engine.warmup.program",
                                     program=f"chunk_prefill_{C}", rows=C):
-                    logits, self.k_pages, self.v_pages, state = \
+                    token, row, self.k_pages, self.v_pages, state = \
                         self._chunk_fn(C)(
                             self.params, self.k_pages, self.v_pages,
                             jnp.zeros((C,), jnp.int32), jnp.int32(0),
@@ -1476,10 +1593,17 @@ class InferenceEngine:
                                          jnp.zeros((self._ring,), jnp.int32)),
                             jnp.int32(C - 1),
                             self.state if self._ring else self._request_start,
+                            _how_to_sample(0.0, 1.0, 0), self._first_key,
                         )
-                    np.asarray(logits)
+                    np.asarray(row)
                 if self._ring:  # all-zero tables wrote the trash pages alone
                     self.state = state
+            # the program that hands a slot's row of the carry a first
+            # token from where a chunk program left it
+            with tracing.region("engine.warmup.program",
+                                program="join_carry"):
+                self._carry = jax.block_until_ready(self._join_carry(
+                    self._carry, np.int32(0), token, np.int32(0)))
             if not self._ring and self.state:
                 # the program that hands a slot its state
                 with tracing.region("engine.warmup.program",
@@ -1868,19 +1992,11 @@ class InferenceEngine:
             "k": jnp.asarray(st["k"][:, None], dtype),  # [L,1,Tpad,KVH,hd]
             "v": jnp.asarray(st["v"][:, None], dtype),
         }
-        first = int(first_token)
         if not req.output:
-            req.output.append(first)
-            req.output_logprobs.append(
-                float(first_logprob) if first_logprob is not None else None)
-            req.weights_version = self.weights_version
-            eos = self.ecfg.eos_token_id
-            if eos is not None and first == eos:
-                pass  # eos is control
-            elif req.stop:
-                req._held.append(first)  # hold-back from token 1
-            else:
-                req._emit(first)
+            self._give_first_token(
+                req, int(first_token),
+                float(first_logprob) if first_logprob is not None else None,
+                self.weights_version)
         req.enter_stage("ready", tracing.now_ns())
         with self._ready_lock:
             self._ready.append((req, st["pages"], cache, st["T"]))
@@ -2128,11 +2244,19 @@ class InferenceEngine:
         at the end, like the time to the next iteration's start.
 
         WHICH part a phase's time is depends on what the sequences waited
-        for (`_phase_done`): with a dispatched span unfinished at the
-        phase's end the device set their pace, not the host, and the time
-        is `device_wait` (`chunk_device_wait` for `engine.chunk`), like
-        the readbacks'. So `host`, `dispatch`, `chunk_host` and `loop` are
-        what the host still costs a token once the loop runs ahead."""
+        for (`_phase_done`): with a dispatched program of this thread, a
+        span or a chunk, unfinished at the phase's end the device set their
+        pace, not the host, and the time is `device_wait`
+        (`chunk_device_wait` for `engine.chunk`), like the readbacks'. So
+        `host`, `dispatch`, `chunk_host` and `loop` are what the host still
+        costs a token once the loop runs ahead.
+
+        `engine.chunk.readback`, the read of a prompt's first token, is
+        `chunk_device_wait` wherever it runs, weighed like `engine.chunk`
+        by the slots live at the iteration's start: behind the iteration's
+        dispatch, or inside `engine.chunk` (`_nested_read_ns`: the request
+        needs its token where the chunk went out), whose time holds it
+        then."""
         ns, hid, live = self._phase_ns, self._hidden_ns, self._live
         live_at_start = self._live_at_end  # only this thread frees a slot
         live_at_end = self._live_at_end = sum(
@@ -2140,15 +2264,16 @@ class InferenceEngine:
         if live_at_start or live:
             chunk_wait, readback = (ns["engine.chunk.readback"],
                                     ns["engine.readback"])
+            nested = self._nested_read_ns
             chunk, chunk_hid = ns["engine.chunk"], hid["engine.chunk"]
-            if chunk_hid:  # the readback is inside whichever it was
-                chunk_hid -= chunk_wait
+            if chunk_hid:  # a nested read is inside whichever it was
+                chunk_hid -= nested
             else:
-                chunk -= chunk_wait
+                chunk -= nested
             others = ("engine.commit", "engine.cancel_check", "engine.build",
                       "engine.propose", "engine.propose_wait")
             other, other_hid = (sum(d[n] for n in others) for d in (ns, hid))
-            phases = sum(ns.values()) + sum(hid.values()) - chunk_wait
+            phases = sum(ns.values()) + sum(hid.values()) - nested
             rest = live_at_end * (it.elapsed_ns - phases)
             between = live_at_start * (it.start_ns - self._iter_end_ns)
             waited = (live * (readback + other_hid + hid["engine.dispatch"])
@@ -2173,7 +2298,7 @@ class InferenceEngine:
         for d in (ns, hid):
             for name in d:
                 d[name] = 0
-        self._live = 0
+        self._live = self._nested_read_ns = 0
         self._iter_end_ns = it.end_ns
 
     def _open_span(self, steps: int,
@@ -2499,21 +2624,11 @@ class InferenceEngine:
         wv = self.weights_version  # generation stamp: sampled under these
         streamed = [i for i, it in enumerate(group)
                     if it[0].prefill_only and it[0].kv_sink is not None]
-        eos = self.ecfg.eos_token_id
         with self._ready_lock:
             for i, (req, pages, T, _b, _cl) in enumerate(group):
-                first = firsts[i]
                 self._note_first_token(req, now_ns)
                 _m_tokens.inc()
-                req.output.append(int(first))
-                req.output_logprobs.append(first_lps[i])
-                req.weights_version = wv
-                if eos is not None and int(first) == eos:
-                    pass  # eos is control
-                elif req.stop:
-                    req._held.append(int(first))  # hold-back from token 1
-                else:
-                    req._emit(int(first))
+                self._give_first_token(req, int(firsts[i]), first_lps[i], wv)
                 if i in streamed:
                     continue  # frames pushed below; never parks in _ready
                 # every leaf has the batch on axis 1 (a stack's cache also
@@ -2595,7 +2710,12 @@ class InferenceEngine:
 
     def _install_ready(self) -> bool:
         """Decode thread: move finished prefills into free decode slots
-        (KV page scatter + slot bookkeeping only)."""
+        (KV page scatter + slot bookkeeping only). A chunked prompt comes
+        with its first token still on the device (`Request._first`): its
+        slot's row of the carry takes the token from there, so the sequence
+        joins the span this iteration dispatches as one that continues, and
+        what needs the token's VALUE (the stage, an ending it decides)
+        waits for the read behind that span (`_read_firsts`). No sync."""
         installed = False
         while True:
             free_slots = [s for s in self.slots if s.request is None]
@@ -2668,13 +2788,20 @@ class InferenceEngine:
             slot.pages = pages
             slot.position = T  # the sampled token will be written at T
             slot.generated = 1
-            req.enter_stage("decode", tracing.now_ns())
-            if self._spec is not None:
-                # draft proposer: prefill the prompt into the slot's draft
-                # pages (runs on the decode thread — donated draft pools
-                # are only ever touched here and in run_step)
-                self._spec.on_install(self.slots.index(slot), req)
-            self._maybe_finish(slot, req.output[-1])
+            first = req._first
+            if first is not None:  # its token is still on the device
+                first.slot = self.slots.index(slot)
+                self._carry = self._join_carry(
+                    self._carry, np.int32(first.slot), first.token,
+                    np.int32(T))
+            else:
+                req.enter_stage("decode", tracing.now_ns())
+                if self._spec is not None:
+                    # draft proposer: prefill the prompt into the slot's
+                    # draft pages (runs on the decode thread — donated draft
+                    # pools are only ever touched here and in run_step)
+                    self._spec.on_install(self.slots.index(slot), req)
+                self._maybe_finish(slot, req.output[-1])
             installed = True
             _m_running.set(sum(1 for s in self.slots if s.request is not None))
 
@@ -2715,6 +2842,16 @@ class InferenceEngine:
         its rows (None: any): exactly where `prefill_chunk` rows would have
         run twice for the same prompt; a prompt's tail, a cached prompt's
         one chunk and a streamed export keep `prefill_chunk` rows.
+
+        No chunk is waited for here, a prompt's last neither: its program
+        draws the first token with the request's temperature, top_p and
+        top_k, and the request goes to `_ready` with the token on the
+        device (`_First`), to be read behind the iteration's span
+        (`_read_firsts`). Two kinds of request need the token's value
+        where the chunk is dispatched and are read here, with the device
+        dry behind them: a `prefill_only` one (its export carries the
+        token) and any request of an engine that speculates (a round
+        starts from committed tokens).
         -> the rows that ran (0: a cancelled prompt left the queue), None
         where nothing waits."""
         with self._chunk_lock:
@@ -2766,21 +2903,27 @@ class InferenceEngine:
         # page-gather program (which would queue behind in-flight decode
         # spans)
         with tracing.region("engine.chunk.put"):
+            key = self._first_key
+            if is_last and req.temperature > 0:  # a draw of its own
+                self._first_draws += 1
+                key = jax.random.fold_in(key, self._first_draws)
             placed = (jnp.asarray(padded), jnp.int32(start),
                       jax.tree.map(jnp.asarray, self._tables(
                           st.table, st.window_table)),
                       jnp.int32(last_idx))
+            how = _how_to_sample(req.temperature, req.top_p, req.top_k)
         with tracing.region("engine.chunk.call", start=start,
                             tokens=len(toks), padded=C, rows=C):
-            logits, self.k_pages, self.v_pages, *kv, state = \
+            token, row, self.k_pages, self.v_pages, *kv, state = \
                 self._chunk_fn(C, streaming)(
                     self.params, self.k_pages, self.v_pages, *placed,
-                    self.state if self._ring else st.state)
+                    self.state if self._ring else st.state, how, key)
             if self._ring:
                 self.state = state
             else:
                 st.state = state
             del placed  # as in `step()`
+        self._dispatched(row)
         self._chunk_tokens += C
         _m_chunk_rows.inc(C)
         _m_chunk_calls.labels(rows=str(C)).inc()
@@ -2819,26 +2962,28 @@ class InferenceEngine:
             return C
         with self._chunk_lock:
             self._chunk_queue.pop(0)
-        with self.phase("chunk.readback"):
-            logits_host = np.asarray(logits)
-        # where the device counts choices, those of every chunk the request
-        # ran came with these logits
-        logits_host = self._take_choices(logits_host, 1, C, len(toks),
-                                         len(toks))
-        first = _sample_host(logits_host, req.temperature,
-                             req.top_p, req.top_k)
-        self._note_first_token(req, tracing.now_ns())
-        _m_tokens.inc()
-        req.output.append(int(first))
-        req.output_logprobs.append(_host_logprob(logits_host, int(first)))
-        req.weights_version = self.weights_version
-        eos = self.ecfg.eos_token_id
-        if eos is not None and int(first) == eos:
-            pass  # eos is control
-        elif req.stop:
-            req._held.append(int(first))  # hold-back from token 1
-        else:
-            req._emit(int(first))
+        first = req._first = _First(req, token, row, C, len(toks),
+                                    self.weights_version)
+        if not req.prefill_only and self._spec is None:
+            # like any other chunk, the last is not waited for: the token
+            # joins the next span on the device (`_install_ready`) and the
+            # host reads it once that span is out (`_read_firsts`)
+            self._firsts.append(first)
+            if req.max_tokens <= 1:  # known to end there: it takes no slot
+                first.pages = st.pages
+                return C
+            with self._ready_lock:
+                # no keys in the cache: this prompt's KV is already in its
+                # pages (state beside pages still has to reach its slot)
+                self._ready.append((req, st.pages, st.state, st.true_len))
+            return C
+        # The token's VALUE is needed here: an export carries it (the
+        # streamed final frame, the blob), and a round of speculation
+        # starts from committed tokens. Neither joins a span from the carry
+        # (a property of the request and of the engine, no option)
+        first.pages = st.pages  # a read that fails frees them
+        if not self._read_first(first, nested=True):
+            return C
         if streaming:
             # final frame carries first_token; pages free immediately —
             # the request never parks in _ready on the streamed path
@@ -2855,10 +3000,104 @@ class InferenceEngine:
             self._finish_request(req, "prefill_done")
             return C
         with self._ready_lock:
-            # no keys in the cache: this prompt's KV is already in its pages
-            # (state beside pages still has to reach its slot)
             self._ready.append((req, st.pages, st.state, st.true_len))
         return C
+
+    def _read_firsts(self, behind_span: bool = False) -> None:
+        """Read the first tokens that chunk programs drew since the last
+        read, in the order of their dispatch. `step()` comes here once the
+        iteration's span is out (`behind_span`) and before it reads the
+        span before it, so a request has its first token before any token
+        of a span can be committed to it, and the read waits for the chunk
+        alone: the first token reaches its reader no later than it did when
+        the chunk was read where it was dispatched. `_drain()` comes here
+        with nothing behind the chunks.
+
+        What the token decides, it decides here. A request that took no
+        slot (`max_tokens` 1) ends, its pages freed. A sequence whose slot
+        joined the span just dispatched and whose first token ends it (eos,
+        a stop sequence; or a cancel since) is an ending the host could not
+        foresee (`step`): the request finishes now, that span commits
+        nothing to it, and its pages are freed once the span is read. A
+        sequence that still waits in `_ready` is installed with its token
+        known, as a bucket's is."""
+        firsts, self._firsts = self._firsts, []
+        for first in firsts:
+            if not self._read_first(first, behind_span):
+                continue
+            req = first.request
+            if first.pages is not None:  # it took no slot
+                if self.prefix is not None:
+                    with self._alloc_lock:
+                        self.prefix.register(
+                            req.prompt, first.pages,
+                            hashes=getattr(req, "_page_hashes", None))
+                reason = self._ending(req, 1, req.output[-1])
+                self._free_pages_and_revive(first.pages)
+                self._finish_request(req, reason or "length")
+            elif first.slot is not None:
+                slot = self.slots[first.slot]
+                if slot.request is req:
+                    self._maybe_finish(slot, req.output[-1])
+
+    def _read_first(self, first: _First, behind_span: bool = False,
+                    nested: bool = False) -> bool:
+        """Block until a last chunk is done and hand its request the first
+        token: `engine.chunk.readback`, the one place where the host waits
+        for a chunk program (`nested`: inside `engine.chunk`, which
+        `_account` has to know). -> False where there is nobody to hand it to:
+        the request ended meanwhile (a cancel), or the chunk program raised,
+        which surfaces here: that request fails, and what it holds (a slot,
+        a place in `_ready`, its pages) is let go as for a cancel."""
+        req = first.request
+        try:
+            with self.phase("chunk.readback") as ph:
+                row = np.asarray(first.row)
+        except Exception as e:  # noqa: BLE001 — fail this request
+            logger.warning("chunk failed for %s", req.request_id,
+                           exc_info=True)
+            self._fail_first(first, f"prefill failed: {e!r}")
+            return False
+        finally:
+            req._first = None
+        if nested:
+            self._nested_read_ns += ph.elapsed_ns
+        (_first_behind_span if behind_span else _first_drained).inc()
+        # `cancel()` ends a request that waits in `_ready` under this lock
+        with self._ready_lock:
+            if req.done.is_set():
+                return False
+            # where the device counts choices, those of every chunk the
+            # request ran came with its first token
+            counted = ({"choices": row[2:4]} if self.cfg.counts_choices
+                       else {})  # by keyword, where there are any
+            self._count_moe_rows(1, first.rows, first.tokens,
+                                 held=first.tokens, **counted)
+            now = tracing.now_ns()
+            self._note_first_token(req, now)
+            _m_tokens.inc()
+            self._give_first_token(req, int(row[0]), float(row[1]),
+                                   first.weights_version)
+            if first.slot is not None:  # a slot took it before its token
+                req.enter_stage("decode", now)
+        return True
+
+    def _fail_first(self, first: _First, msg: str) -> None:
+        """The chunk program of `first` raised: its request fails and lets
+        go of what it holds."""
+        req = first.request
+        pages = first.pages
+        if first.slot is not None and self.slots[first.slot].request is req:
+            self._retire(self.slots[first.slot])
+        with self._ready_lock:
+            for item in list(self._ready):
+                if item[0] is req:
+                    self._ready.remove(item)
+                    pages = item[1]
+        if pages is not None:
+            self._free_pages_and_revive(pages)
+        if not req.done.is_set():
+            self._fail_request(req, msg)
 
     def _take_late_hits(self, st: _ChunkState) -> None:
         """A chunked prompt about to run a chunk looks its prefix up once
@@ -2910,6 +3149,14 @@ class InferenceEngine:
           but its length is known) is left out, and leaves at commit N;
         - a slot installed since N went out joins with the host's token
           and position (`fresh` in the program);
+        - a slot installed with its first token still on the device (a
+          chunked prompt's: the chunk program drew it, `_install_ready`
+          wrote it into the carry) joins as one that continues. The host
+          reads that token once N+1 is out and before it reads N
+          (`_read_firsts`), so the request has it before any token of N+1
+          can be committed; an ending it decides (eos, a stop sequence) is
+          one the host could not foresee, below. A request that is known
+          to end at its first token (`max_tokens` 1) takes no slot;
         - an ending the host cannot foresee (eos, a stop sequence, a
           cancel) finds the slot in N+1 already. The request finishes at
           commit N; N+1 commits to the requests it was dispatched WITH
@@ -2928,7 +3175,8 @@ class InferenceEngine:
         has read.
 
         Where the loop must know span N's tokens before it acts it drains
-        first, which is this same pipeline at depth 0: speculation
+        first (`_drain`: the first tokens still on the device, then the
+        span), which is this same pipeline at depth 0: speculation
         (EngineConfig.speculation: ONE propose-k/verify-once round per
         iteration committing 1..k+1 tokens per slot, spec_decode.SpecDecoder,
         whose proposer reads committed tokens), `update_params` (a span
@@ -2966,11 +3214,16 @@ class InferenceEngine:
             # or running, shrink the span so the device yields between
             # decode dispatches and arriving requests get their first
             # token (emitted by the prefill program) without waiting out a
-            # long span.
+            # long span. An iteration that dispatched a chunk counts as
+            # such: nobody waited for the chunk, so the prompts that arrive
+            # while it runs are not in the queues yet (chip, PR 51: without
+            # it the build after a last chunk took the long span and the
+            # next prompt's first token came 8 to 25 ms later).
             if self.ecfg.adaptive_span and (
                 self._prefill_inflight > 0
                 or not self.pending.empty()
                 or self._chunk_queue  # racy read is fine: pressure hint only
+                or chunked
                 or self._importing > 0  # streamed KV imports staged (disagg)
             ):
                 span = max(1, self.ecfg.busy_span)
@@ -3007,11 +3260,12 @@ class InferenceEngine:
                     # dropped while the program holds them: freed after the
                     # readback they cost 5 ms an iteration (chip, PR 36)
                     del placed
-                    if prev is not None and not prev.seq.is_ready():
+                    if self._dispatched(cur.seq):
                         _m_ahead.inc(span)  # the device never ran dry
             cur.dispatched_ns = ph.end_ns
             _step_phase["verify", "plain"].observe(ph.elapsed_s)
         self._inflight = cur
+        self._read_firsts(behind_span=cur is not None)
         if prev is not None:
             self._finish_span(prev)
         if self._spec is not None or self._stop.is_set():
@@ -3019,8 +3273,11 @@ class InferenceEngine:
         return True
 
     def _drain(self) -> None:
-        """Read back and commit the span in flight, if any: the pipeline
+        """Read the first tokens that are still on the device, then read
+        back and commit the span in flight, if any (in that order: the span
+        may hold a sequence whose first token is among them): the pipeline
         at depth 0."""
+        self._read_firsts()
         span, self._inflight = self._inflight, None
         if span is not None:
             self._finish_span(span)
@@ -3057,7 +3314,9 @@ class InferenceEngine:
         unread, continues from the device's carry (`fresh` False; the host
         does not know its token yet), unless its answer ends inside `prev`
         by `max_tokens`: then it is left out, a row of zeros like an empty
-        slot's, which costs its programs nothing."""
+        slot's, which costs its programs nothing. A slot installed with its
+        first token unread continues from the carry too: its row holds the
+        token (`_install_ready`)."""
         B = self.ecfg.max_batch_size
         pps = self.ecfg.pages_per_seq
         tokens = np.zeros((B,), np.int32)
@@ -3075,9 +3334,12 @@ class InferenceEngine:
             req = s.request
             if req is None:
                 continue
-            if riding.get(i) is req:
+            rides = riding.get(i) is req
+            if rides:
                 if s.generated + prev.steps >= req.max_tokens:
                     continue
+                fresh[i] = False
+            elif req._first is not None:
                 fresh[i] = False
             else:
                 tokens[i] = req.output[-1]
@@ -3086,7 +3348,7 @@ class InferenceEngine:
             if self._ring:
                 # the pages of the tokens this span may write (after those
                 # of the span still unread), in both spaces
-                ahead = prev.steps if not fresh[i] else 0
+                ahead = prev.steps if rides else 0
                 self._grow(s.pages, s.position + ahead + max(
                     self.ecfg.decode_span, self.ecfg.busy_span, 1))
                 window_tables[i, : len(s.pages.window)] = s.pages.window
@@ -3321,6 +3583,23 @@ class InferenceEngine:
         spec.record(proposed, accepted)
         return n_tokens
 
+    def _give_first_token(self, req: Request, tok: int,
+                          logprob: Optional[float],
+                          weights_version: int) -> None:
+        """`req`'s first output token, sampled under `weights_version`: to
+        its result and to its stream (eos is control; with stops configured
+        the hold-back starts at token 1)."""
+        req.output.append(tok)
+        req.output_logprobs.append(logprob)
+        req.weights_version = weights_version
+        eos = self.ecfg.eos_token_id
+        if eos is not None and tok == eos:
+            pass  # eos is control
+        elif req.stop:
+            req._held.append(tok)
+        else:
+            req._emit(tok)
+
     def _note_first_token(self, req: Request, now_ns: int) -> None:
         """The first-token instant: closes the request's `prefill` stage
         and is the TTFT every surface reports."""
@@ -3342,21 +3621,31 @@ class InferenceEngine:
         req = slot.request
         if req is None:
             return
+        reason = self._ending(req, slot.generated, last_tok)
+        if reason is None:
+            return
+        self._retire(slot)
+        self._finish_request(req, reason)
+
+    def _ending(self, req: Request, generated: int,
+                last_tok: int) -> Optional[str]:
+        """Whether `req` ends with `last_tok`, its `generated`-th token, and
+        as what (None: it goes on, and tokens that can no longer be part of
+        a stop match reach the stream). An ending strips what is control,
+        the eos or the stop sequence, from the result and the hold-back."""
         eos = self.ecfg.eos_token_id
         stopped = eos is not None and last_tok == eos
         stop_len = 0 if stopped else _match_stop(req.output, req.stop)
         stopped = stopped or stop_len > 0
         cancelled = req.cancelled.is_set()
-        if not (slot.generated >= req.max_tokens or stopped or cancelled):
+        if not (generated >= req.max_tokens or stopped or cancelled):
             if req._held:
                 # no match right now: tokens older than the longest
                 # possible stop suffix can safely reach the stream
                 hold = max(len(x) for x in req.stop) - 1
                 while len(req._held) > hold:
                     req._emit(req._held.pop(0))
-            return
-        reason = ("cancelled" if cancelled
-                  else "stop" if stopped else "length")
+            return None
         if eos is not None and req.output and req.output[-1] == eos:
             req.output.pop()
             if req.output_logprobs:
@@ -3370,22 +3659,29 @@ class InferenceEngine:
                                              len(req.output_logprobs)):]
             if req._held:
                 del req._held[-min(stop_len, len(req._held)):]
-        # When are the pages free? An ending by `max_tokens` (foreseen: no
-        # unread span went out with this table) and any ending of a drained
-        # loop free them here, BEFORE completion is signalled: that caller
-        # returns from generate() to a stats() that counts them. An ending
-        # the host could not foresee (eos, a stop sequence, a cancel) finds
-        # the sequence in the span in flight, and the device may yet write
-        # those pages: the request finishes now all the same, and the pages
-        # are freed one span later, at that span's readback (`_finish_span`
-        # walks `release`). Until then `stats()["free_pages"]` is short by
-        # them, so a caller that counts the pool after such an answer polls.
-        # Admission never reads the stat: a request that finds the pool
-        # short parks in `_waiting`, and every free, this one too, goes
-        # through `_free_pages_and_revive`, which wakes it.
+        return ("cancelled" if cancelled
+                else "stop" if stopped else "length")
+
+    def _retire(self, slot: _Slot) -> None:
+        """The slot lets its sequence go; the caller finishes the request.
+
+        When are the pages free? An ending by `max_tokens` (foreseen: no
+        unread span went out with this table) and any ending of a drained
+        loop free them here, BEFORE completion is signalled: that caller
+        returns from generate() to a stats() that counts them. An ending
+        the host could not foresee (eos, a stop sequence, a cancel; a first
+        token read behind its sequence's first span) finds the
+        sequence in the span in flight, and the device may yet write
+        those pages: the request finishes now all the same, and the pages
+        are freed one span later, at that span's readback (`_finish_span`
+        walks `release`). Until then `stats()["free_pages"]` is short by
+        them, so a caller that counts the pool after such an answer polls.
+        Admission never reads the stat: a request that finds the pool
+        short parks in `_waiting`, and every free, this one too, goes
+        through `_free_pages_and_revive`, which wakes it."""
+        index = self.slots.index(slot)
         riding = self._inflight
-        if riding is not None and riding.members.get(
-                self.slots.index(slot)) is req:
+        if riding is not None and riding.members.get(index) is slot.request:
             riding.release.append(slot.pages)
         else:
             self._free_pages_and_revive(slot.pages)
@@ -3393,13 +3689,12 @@ class InferenceEngine:
             # proposer hygiene: drop the slot's ngram context / invalidate
             # any prefetched draft row so the next occupant can never see
             # this request's state
-            self._spec.on_evict(self.slots.index(slot))
+            self._spec.on_evict(index)
         slot.request = None
         slot.pages = []
         slot.position = 0
         slot.generated = 0
         _m_running.set(sum(1 for s in self.slots if s.request is not None))
-        self._finish_request(req, reason)
 
     # ------------------------------------------------------------- blocking
 
@@ -3608,6 +3903,13 @@ class InferenceEngine:
         self._work.set()  # wake the decode thread so it observes _stop
 
 
+def _join_carry(carry, slot, token, position):
+    """The loop's carry with `slot`'s row set to a sequence's first token
+    and the position it is written at."""
+    tokens, positions = carry
+    return tokens.at[slot].set(token), positions.at[slot].set(position)
+
+
 def tree_bytes(tree) -> int:
     return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
 
@@ -3708,6 +4010,28 @@ def _device_sample_topk_topp(logits, temps, top_ps, top_ks, key):
     choice = jax.random.categorical(key, masked, axis=-1)      # sorted index
     sampled = jnp.take_along_axis(order, choice[:, None], axis=-1)[:, 0]
     return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+
+
+def _how_to_sample(temperature: float, top_p: float, top_k: int):
+    """A request's sampling parameters as a chunk program takes them: one
+    placement (a rank cut is exact in float32 wherever a token id is)."""
+    return jnp.asarray(np.array([temperature, top_p, top_k], np.float32))
+
+
+def _sample_first(logits, how, key):
+    """A prompt's first token, drawn on the device from its last row's
+    logits [V] as a decode step draws (`_device_sample_topk_topp` over a
+    batch of one): `how` [3] float32 holds the request's temperature (<= 0:
+    the argmax, and the sort never runs), top_p and top_k. -> the token
+    (int32) and its log-probability under the RAW distribution, as the
+    decode step reports it."""
+    temp, top_p, top_k = how[0], how[1], how[2].astype(jnp.int32)
+    tok = jax.lax.cond(
+        temp > 0,
+        lambda: _device_sample_topk_topp(
+            logits[None], temp[None], top_p[None], top_k[None], key)[0],
+        lambda: jnp.argmax(logits).astype(jnp.int32))
+    return tok, jax.nn.log_softmax(logits)[tok]
 
 
 def _host_logprob(logits: np.ndarray, tok: int) -> float:

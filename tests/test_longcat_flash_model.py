@@ -574,14 +574,16 @@ def decode_digest(name):
 # kernel where a chunk's tokens end (`attend_mla`) leaves `attend_full`, and
 # with it every other model's chunk program, the text it was (tiny-lfm2's is
 # PR 43's own since its chunks run each expert over the rows that chose it,
-# re-pinned as in tests/test_moe_step.py)
+# re-pinned as in tests/test_moe_step.py; since PR 51 every chunk program
+# draws its prompt's first token, so all three are PR 51's own, re-pinned as
+# in tests/test_smallthinker_model.py)
 PARENT_CHUNK = {
     "tiny-llama":
-        "65aa9a2a4a2d78610995bbeb85057a7a7ba5bcd970484c412e36baafd7c94297",
+        "c7969c40ee60741f3f4268b08f055ea2ec0a9e07d096ff3945d6ed44129ac609",
     "tiny-moe":
-        "d65450621c89b86d4231fe8c676e2f54dbb65460c538a623aefc78c05259d0b6",
+        "d7fecbf97654aee0f0a8af43637c3c416930dc451b2e17674b06f1ec08272e16",
     "tiny-lfm2":
-        "3b026ad5d9e197d3d8acd16a16c9e677ca272364493bd756d4481ce07b2da2df",
+        "fe7e59cd01c093342381dea6a936c0a006ead242f4d76cc673cd6ed7d3279037",
 }
 
 

@@ -388,18 +388,19 @@ DECODE_PROGRAMS = {
 # DID change in PR 43 (each expert over the rows that chose it; at the tiny
 # width the op's XLA form, every expert times the combine's zeros, with the
 # layer read out of the segment's stacks): this tree's own, so that a later
-# change to them is one that is meant
+# change to them is one that is meant (the three chunk programs again in PR
+# 51: they draw the prompt's first token where they handed back its logits)
 GROUPED_PROGRAMS = {
     ("tiny-smallthinker", "chunk"):
-        "479838dc9eed6b1ae3499fb92a33b815ba39812d98f644e873e6be4961640501",
+        "a2b11bffde16ae08aed83fc9b07a849b75cd56bb3d36c95ec5d43e1a4d76d90a",
     ("tiny-smallthinker", "bucket"):
         "38bf8b4999763ffac2fbee832fc945d37579290b491336df115e7c7b071a92cc",
     ("tiny-lfm2", "chunk"):
-        "3b026ad5d9e197d3d8acd16a16c9e677ca272364493bd756d4481ce07b2da2df",
+        "fe7e59cd01c093342381dea6a936c0a006ead242f4d76cc673cd6ed7d3279037",
     ("tiny-lfm2", "bucket"):
         "d3a78cc2c6fd2cd4a8f57388f1ad147180733c227c6187a7d0fdc837722b5a31",
     ("tiny-longcat-flash", "chunk"):
-        "5de8881fa218b12ce7ecf3c7bcfcf117c73cea9e1bed746a636b842db71513e9",
+        "da540f5bc662f6b86065eef2d53ef21a26b53d9a237c8ae2cc9e012f300b2db4",
     ("tiny-longcat-flash", "bucket"):
         "bd8fd2bc1a51d7aef7f731156f94709dc1112598c675a314cc5ac19ce0ae55d7",
 }

@@ -5,6 +5,7 @@ import contextlib
 import json
 import threading
 import time
+import types
 import urllib.request
 
 import jax
@@ -717,6 +718,352 @@ class TestEngine:
                 break
             time.sleep(0.1)
         assert mine[0]() is None
+
+
+# a model with pages only, one with state beside its pages (state-space and
+# conv state, handed to the slot at install), one whose device counts its
+# tokens' choices of experts (they come back with the first token)
+FIRST_TOKEN_MODELS = ("tiny-llama", "tiny-granite-hybrid",
+                      "tiny-longcat-flash")
+
+
+@pytest.fixture(scope="module")
+def first_token_models():
+    import dataclasses
+
+    from ray_tpu.models import stack
+
+    made = {}
+
+    def model(name):
+        if name not in made:
+            cfg = dataclasses.replace(get_config(name), dtype="float32")
+            init = stack.init_params if cfg.is_stack else init_params
+            made[name] = cfg, init(cfg, jax.random.PRNGKey(51))
+        return made[name]
+
+    return model
+
+
+class TestFirstTokenBehindASpan:
+    """A prompt's last chunk is not waited for: the chunk program draws the
+    first token, the sequence joins the iteration's span from the device,
+    and the host reads the token once that span is out (pages of 4, chunks
+    of 16, spans of 4; every engine is stepped by hand)."""
+
+    LONG = [(11 * i) % 57 + 3 for i in range(40)]   # three chunks
+    OTHER = [(7 * i) % 53 + 5 for i in range(23)]   # two
+    SHORT = [5, 9, 2, 7, 11]                        # a bucket
+
+    @staticmethod
+    def _engine(model, **kw):
+        from ray_tpu.serve import EngineConfig, InferenceEngine
+
+        cfg, params = model
+        ecfg = dict(max_batch_size=4, page_size=4, max_pages=96,
+                    max_seq_len=128, prefill_buckets=(8, 16),
+                    prefill_chunk=16, decode_span=4, busy_span=2,
+                    cache_dtype="float32", prefix_caching=False)
+        ecfg.update(kw)
+        engine = InferenceEngine(params, cfg, EngineConfig(**ecfg))
+        engine._ensure_loop = lambda: None
+        return engine
+
+    @staticmethod
+    def _admit(engine, *asks):
+        """(prompt, keywords of `Request`) each -> the requests, prefilled
+        or on the chunk queue."""
+        from ray_tpu.serve.engine import Request
+
+        reqs = [Request(request_id=f"ask{i}-{len(p)}", prompt=list(p), **kw)
+                for i, (p, kw) in enumerate(asks)]
+        for r in reqs:
+            engine.add_request(r)
+        engine._prefill_batch([engine.pending.get() for _ in reqs])
+        return reqs
+
+    @staticmethod
+    def _run(engine, reqs, iterate=None, turns=200):
+        for _ in range(turns):
+            (iterate or engine._iterate)()
+            if all(r.done.is_set() for r in reqs):
+                break
+        for _ in range(3):  # the last spans' pages go at their readback
+            engine._iterate()
+        assert all(r.done.is_set() for r in reqs)
+
+    @staticmethod
+    def _reads():
+        from ray_tpu.serve.engine import _m_first_reads
+
+        return {read: _m_first_reads.get(tags={"read": read})
+                for read in ("behind_span", "drained")}
+
+    @staticmethod
+    def _answers(reqs):
+        return [(r.output, r.output_logprobs, r.finish_reason) for r in reqs]
+
+    @pytest.mark.parametrize("name,depth_0", [
+        *((name, "update_params") for name in FIRST_TOKEN_MODELS),
+        ("tiny-llama", "speculation"), ("tiny-llama", "prefill_only")])
+    def test_tokens_and_logprobs_are_the_drained_loops(
+            self, name, depth_0, first_token_models):
+        """The loop a span ahead against the three ways a first token is
+        read with nothing dispatched behind its chunk (`read=drained`): a
+        loop that drains between install and build, as `update_params`
+        makes it; an engine that speculates; a request that exports its
+        keys, which answers with that token alone."""
+        asks = ((self.SHORT, dict(max_tokens=30)),
+                (self.LONG, dict(max_tokens=12)),
+                (self.OTHER, dict(max_tokens=9)))
+        engine = other = self._engine(first_token_models(name))
+        if depth_0 == "speculation":
+            other = self._engine(first_token_models(name), speculation={
+                "mode": "ngram", "num_speculative_tokens": 3})
+
+        def drained():
+            # the same pipeline at depth 0: the loop drains between install
+            # and build, so every first token is read with nothing
+            # dispatched behind its chunk, and its sequence joins with the
+            # host's token (`fresh`)
+            engine._swaps.append(types.SimpleNamespace(
+                params=engine.params, version=engine.weights_version,
+                bound=threading.Event()))
+            engine._iterate()
+
+        exports = dict(prefill_only=depth_0 == "prefill_only")
+        try:
+            before = self._reads()
+            ahead = self._admit(engine, *asks)
+            self._run(engine, ahead)
+            mid = self._reads()
+            assert mid["behind_span"] - before["behind_span"] == 2
+            assert mid["drained"] == before["drained"]
+            at_depth_0 = self._admit(other, asks[0], *(
+                (prompt, dict(kw, **exports)) for prompt, kw in asks[1:]))
+            self._run(other, at_depth_0, drained
+                      if depth_0 == "update_params" else other._iterate)
+            after = self._reads()
+            assert after["drained"] - mid["drained"] == 2
+            assert after["behind_span"] == mid["behind_span"]
+        finally:
+            engine.stop()
+            other.stop()
+        for got, want in zip(self._answers(ahead), self._answers(at_depth_0)):
+            if want[2] == "prefill_done":  # the first token is its answer
+                got = got[0][:1], got[1][:1], "prefill_done"
+            assert got[0] == want[0] and got[2] == want[2]
+            assert len(got[0]) == len(got[1]) == len(want[1])
+            # (a round of speculation reports no log-probability for the
+            # token it draws past its accepted proposals)
+            known = [i for i, lp in enumerate(want[1]) if lp is not None]
+            assert 0 in known and len(known) >= len(want[1]) - 3
+            assert [got[1][i] for i in known] == pytest.approx(
+                [want[1][i] for i in known], abs=1e-5)
+        assert [r.finish_reason for r in ahead] == ["length"] * 3
+        assert other.stats()["free_pages"] == 96 - 1
+
+    @pytest.fixture(scope="class")
+    def undisturbed(self, first_token_models):
+        """What SHORT and LONG answer when nothing ends LONG early."""
+        probe = self._engine(first_token_models("tiny-llama"))
+        reqs = self._admit(probe, (self.SHORT, dict(max_tokens=30)),
+                           (self.LONG, dict(max_tokens=6)))
+        self._run(probe, reqs)
+        probe.stop()
+        return reqs
+
+    @pytest.mark.parametrize(
+        "ending", ["eos", "stop", "max_tokens_1", "cancel", "raised"])
+    def test_an_ending_at_the_first_token_commits_nothing_from_the_span(
+            self, ending, first_token_models, undisturbed):
+        model = first_token_models("tiny-llama")
+        want_short, want_long = undisturbed
+        first = want_long.output[0]
+        engine = self._engine(
+            model, **({"eos_token_id": first} if ending == "eos" else {}))
+        short, = self._admit(engine, (self.SHORT, dict(max_tokens=30)))
+        engine._iterate()
+        long_, = self._admit(engine, (self.LONG, dict(
+            max_tokens=1 if ending == "max_tokens_1" else 6,
+            stop=[[first]] if ending == "stop" else None)))
+        joined, read = [], engine._read_firsts
+
+        def read_firsts(behind_span=False):
+            # the span is out, the first token not read yet
+            if engine._firsts:
+                slot = engine._firsts[0].slot
+                joined.append((slot, slot is not None
+                               and engine._inflight.members.get(slot)))
+                if ending == "cancel":
+                    assert engine.cancel(long_.request_id)
+                if ending == "raised":
+
+                    class Gone:
+                        def __array__(self, *a, **k):
+                            raise RuntimeError("chunk program failed")
+
+                    engine._firsts[0].row = Gone()
+            return read(behind_span)
+
+        engine._read_firsts = read_firsts
+        try:
+            self._run(engine, [short, long_])
+        finally:
+            engine.stop()
+        (slot, member), = joined
+        if ending == "max_tokens_1":  # known to the host: it took no slot
+            assert slot is None
+            assert (long_.output, long_.finish_reason) == ([first], "length")
+        else:  # the span that went out holds it, and commits nothing to it
+            assert member is long_
+            want = {"eos": ([], "stop"), "stop": ([], "stop"),
+                    "cancel": ([first], "cancelled"),
+                    "raised": ([], None)}[ending]
+            assert (long_.output, long_.finish_reason) == want
+        assert (long_.error is not None) == (ending == "raised")
+        assert len(long_.output_logprobs) == len(long_.output)
+        # the sequence beside it never noticed
+        assert short.output == want_short.output
+        assert engine.stats()["free_pages"] == 96 - 1
+        assert engine.stats()["active"] == 0 and not engine._ready
+
+    def test_two_last_chunks_of_one_iteration_join_the_same_span(
+            self, first_token_models):
+        # two turns over one history: a prefix hit each, so ONE chunk each
+        engine = self._engine(first_token_models("tiny-llama"),
+                              prefix_caching=True)
+        try:
+            short, history = self._admit(
+                engine, (self.SHORT, dict(max_tokens=60)),
+                (self.LONG, dict(max_tokens=2)))
+            self._run(engine, [history])
+            two = self._admit(
+                engine, (self.LONG + self.SHORT, dict(max_tokens=5)),
+                (self.LONG + self.SHORT[::-1] + [4, 8], dict(max_tokens=5)))
+            assert [st.done for st in engine._chunk_queue] == [40, 40]
+            before = self._reads()
+            engine._iterate()  # a last chunk each (busy_span 2)
+            assert not engine._chunk_queue and not engine._firsts
+            members = list(engine._inflight.members.values())
+            assert all(any(m is r for m in members) for r in (short, *two))
+            assert self._reads()["behind_span"] - before["behind_span"] == 2
+            assert all(len(r.output) == 1 for r in two)
+            self._run(engine, [short, *two])
+            assert all(len(r.output) == 5 for r in two)
+        finally:
+            engine.stop()
+
+    def test_chunk_programs_compile_once_whatever_the_sampling(
+            self, first_token_models):
+        engine = self._engine(first_token_models("tiny-llama"))
+        try:
+            engine.warmup(buckets=[])
+            programs = [engine._chunk_fn(16), engine._join_carry]
+            assert [p._cache_size() for p in programs] == [1, 1]
+            reqs = self._admit(
+                engine, (self.SHORT, dict(max_tokens=20)),
+                (self.LONG, dict(max_tokens=6)),
+                (self.OTHER, dict(max_tokens=6, temperature=0.8)),
+                (self.OTHER[::-1], dict(max_tokens=6, temperature=1.0,
+                                        top_k=3, top_p=0.9)))
+            self._run(engine, reqs)
+            assert all(r.error is None and len(r.output) == r.max_tokens
+                       for r in reqs)
+            assert [p._cache_size() for p in programs] == [1, 1]
+        finally:
+            engine.stop()
+
+    def test_temperature_one_with_top_k_one_draws_the_argmax(
+            self, first_token_models):
+        engine = self._engine(first_token_models("tiny-llama"))
+        try:
+            greedy, ranked = self._admit(
+                engine, (self.LONG, dict(max_tokens=8)),
+                (self.LONG, dict(max_tokens=8, temperature=1.0, top_k=1)))
+            self._run(engine, [greedy, ranked])
+        finally:
+            engine.stop()
+        assert ranked.output == greedy.output
+        assert ranked.output_logprobs == pytest.approx(
+            greedy.output_logprobs, abs=1e-6)
+
+    @pytest.mark.parametrize("behind", ["nothing", "a_chunk",
+                                        "a_span_then_a_chunk"])
+    def test_a_phase_is_hidden_by_the_program_dispatched_before_its_own(
+            self, behind, first_token_models):
+        """A host phase that ends with only a chunk unfinished is filed
+        hidden and the span after it counts as ahead; the phase that
+        dispatched the chunk, or the span, is asked about the program
+        BEFORE its own. On the CPU a program is over before the host looks,
+        so an output says, by decree, that its program is not."""
+        from ray_tpu.core.metrics import registry
+
+        def counters():
+            ledger = registry.get("serve_token_wait_seconds")
+            out = {p: ledger.get(tags={"part": p})
+                   for p in ("chunk_host", "chunk_device_wait", "dispatch")}
+            out["ahead"] = registry.get("serve_decode_ahead_steps").get()
+            return out
+
+        engine = self._engine(first_token_models("tiny-llama"))
+        chunk_fn, phase_done, hidden = engine._chunk_fn, engine._phase_done, {}
+
+        class Unfinished:
+            def __init__(self, row=None):
+                self.row = row
+
+            def is_ready(self):
+                return False
+
+            def __array__(self, *args, **kwargs):
+                return np.asarray(self.row)
+
+        def unfinished(rows, export=False):
+            def call(*args):
+                token, row, *rest = chunk_fn(rows, export)(*args)
+                return (token, Unfinished(row), *rest)
+            return call
+
+        def filed(name, ns):
+            was = engine._hidden_ns[name]
+            phase_done(name, ns)
+            hidden[name] = engine._hidden_ns[name] > was
+
+        try:
+            short, = self._admit(engine, (self.SHORT, dict(max_tokens=40)))
+            engine._iterate()
+            engine._iterate()
+            reqs = [short]
+            if behind != "nothing":  # two chunks: this one is not its last
+                reqs += self._admit(engine, (self.OTHER, dict(max_tokens=4)))
+                engine._chunk_fn = unfinished
+            jax.block_until_ready(engine._inflight.seq)  # span N is done
+            assert not engine._device_busy()
+            if behind == "a_span_then_a_chunk":
+                engine._last_out = Unfinished()  # span N, by decree, is not
+            engine._phase_done = filed
+            before = counters()
+            engine._iterate()
+            after = counters()
+            engine._phase_done = phase_done
+            grew = {k for k in after if after[k] > before[k]}
+            if behind == "nothing":  # the device was dry at the dispatch
+                assert not hidden["engine.dispatch"]
+                assert grew == {"dispatch", "chunk_host"}  # (an empty queue)
+            else:
+                # the chunk's own phase: by what its dispatch found
+                assert hidden["engine.chunk"] == (behind != "a_chunk")
+                # then only the chunk is unfinished, and that is busy
+                assert all(hidden[f"engine.{name}"] for name in (
+                    "install", "cancel_check", "build", "dispatch"))
+                assert grew == {"ahead", "chunk_host" if behind == "a_chunk"
+                                else "chunk_device_wait"}
+                assert after["ahead"] - before["ahead"] == 2  # busy_span
+            self._run(engine, reqs)
+            assert [len(r.output) for r in reqs] == [40, 4][:len(reqs)]
+        finally:
+            engine.stop()
 
 
 class TestOpenAI:

@@ -361,43 +361,47 @@ def test_refusals_name_the_stack_and_the_reason(model):
 # a bucket of a family whose experts drop nothing runs each expert over the
 # rows that chose it, so the chunk and bucket programs of tiny-lfm2 and
 # tiny-longcat-flash are PR 43's own (re-pinned here and in
-# tests/test_moe_step.py; tiny-moe's drop rows and stay); the other eleven
-# are still the parent's of PR 41
+# tests/test_moe_step.py; tiny-moe's drop rows and stay); since PR 51 a chunk
+# program draws its prompt's first token and hands back a row of two to four
+# numbers in place of the logits, so all six chunk programs are PR 51's own
+# (re-pinned here, in tests/test_moe_step.py and in
+# tests/test_longcat_flash_model.py); the decode and bucket programs that
+# PRs 42 and 43 left alone are still the parent's of PR 41
 PARENT_PROGRAMS = {
     ("tiny-llama", "decode"):
         "dd392d7dd4c77fda7abfc16aade694d5206c43d1fb8ba937438b4b51dc2b2a48",
     ("tiny-llama", "chunk"):
-        "65aa9a2a4a2d78610995bbeb85057a7a7ba5bcd970484c412e36baafd7c94297",
+        "c7969c40ee60741f3f4268b08f055ea2ec0a9e07d096ff3945d6ed44129ac609",
     ("tiny-llama", "bucket"):
         "89e6eca808b25dc8185be474bb000e1754b5f6711e8b35f7c565ac8b23458ea4",
     ("tiny-moe", "decode"):
         "d4c2bc126bde13089c5e43d26cea9782eb226285dd0bff5b81c5aeed04b7bd89",
     ("tiny-moe", "chunk"):
-        "d65450621c89b86d4231fe8c676e2f54dbb65460c538a623aefc78c05259d0b6",
+        "d7fecbf97654aee0f0a8af43637c3c416930dc451b2e17674b06f1ec08272e16",
     ("tiny-moe", "bucket"):
         "a8d171fe1e3f6467256a9cae983b142d438b55d121b1e140760c6c65e13814a8",
     ("tiny-lfm2", "decode"):
         "409cdb5fa8379b59546b1ade251883ef3247cb6064f13af31f37d3ec50bc09e6",
     ("tiny-lfm2", "chunk"):
-        "3b026ad5d9e197d3d8acd16a16c9e677ca272364493bd756d4481ce07b2da2df",
+        "fe7e59cd01c093342381dea6a936c0a006ead242f4d76cc673cd6ed7d3279037",
     ("tiny-lfm2", "bucket"):
         "d3a78cc2c6fd2cd4a8f57388f1ad147180733c227c6187a7d0fdc837722b5a31",
     ("tiny-olmo-hybrid", "decode"):
         "e6a03b5c0bf24f573422503444c01675b9cc893abdd00f81a10781ff554f9b41",
     ("tiny-olmo-hybrid", "chunk"):
-        "4903724c0e87b836fe11ecc05038e1cd0230bada1ffa230a73ed6ccce45bb5cb",
+        "edb88536add3620b995695cc51fea0a8495b5b6374680cb95dd0814d3b7ad44b",
     ("tiny-olmo-hybrid", "bucket"):
         "c9e003a5a9dcef0baebf4c2e4cae0be7a651aeb41769d73009ed6d2eca7b317e",
     ("tiny-sambay", "decode"):
         "d4f18eb84f8260c22ea6f81b7f53d1a7e48503222592ab3cb8214dd6c6dda25a",
     ("tiny-sambay", "chunk"):
-        "3dd6a9f8d220d711279a7c75987658ac8f34aade9c842c59594278636bfcc948",
+        "dd633aec09dac87e37915669ed5a2c80a7efee259ead979e33bc40a02eda58fb",
     ("tiny-sambay", "bucket"):
         "c3200b6a1917e29a8dc50540236327dceceb0ca2618710a8daa43fe3983df1a9",
     ("tiny-longcat-flash", "decode"):
         "f97d5b3480f939fa4a2c03b6d6e8cc7807450e98cdf61a8d6dbe3dfe0de6d6f7",
     ("tiny-longcat-flash", "chunk"):
-        "5de8881fa218b12ce7ecf3c7bcfcf117c73cea9e1bed746a636b842db71513e9",
+        "da540f5bc662f6b86065eef2d53ef21a26b53d9a237c8ae2cc9e012f300b2db4",
     ("tiny-longcat-flash", "bucket"):
         "bd8fd2bc1a51d7aef7f731156f94709dc1112598c675a314cc5ac19ce0ae55d7",
 }

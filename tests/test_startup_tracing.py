@@ -17,10 +17,11 @@ ENGINE = dict(page_size=4, prefill_buckets=(8, 16), prefill_chunk=16,
               max_batch_size=4, max_pages=64)
 # what `warmup` compiles for tiny-lfm2 under ENGINE: both spans x both
 # samplers, both chunk programs (its experts run as groups: `_wide`), and
-# the program that hands a slot its conv state
+# the program that hands a slot its conv state, and the one that hands a
+# slot's row of the loop's carry a first token from the device
 PROGRAMS = {"decode_span_4", "decode_span_4_adv", "decode_span_8",
             "decode_span_8_adv", "chunk_prefill_16", "chunk_prefill_32",
-            "install_state"}
+            "join_carry", "install_state"}
 PHASES = {"params": "replica.start.params", "engine": "replica.start.engine",
           "warmup": "engine.warmup"}
 

@@ -366,7 +366,7 @@ def _engine_programs(one_chip):
             s((BATCH,), I32), s((2,), jnp.uint32)),
         "chunk_prefill_256": lambda: eng._build_chunk_prefill()(CHUNK).lower(
             params, pool, pool, s((CHUNK,), I32), s((), I32), s((pps,), I32),
-            s((), I32)),
+            s((), I32), None, s((3,), f32), s((2,), jnp.uint32)),
         # S = k + 1 tokens a slot through the same layers (stack.Verify)
         "verify_3": lambda: spec._build_verify()(False).lower(
             params, pool, pool, s((BATCH, 4), I32), s((BATCH,), I32),
@@ -599,7 +599,7 @@ def _latent_cell_program(name, program, topo):
             lambda: stack.new_request_state(cfg, 1, jnp.bfloat16)))
         lowered = eng._build_chunk_prefill()(rows).lower(
             params, pool, None, s((rows,), I32), s((), I32), s((pps,), I32),
-            s((), I32), rs)
+            s((), I32), rs, s((3,), f32), s((2,), jnp.uint32))
     compiled = lowered.compile()
     assert compiled.memory_analysis().alias_size_in_bytes \
         >= pool.size * pool.dtype.itemsize
@@ -732,7 +732,8 @@ def test_the_window_and_full_cells_programs_hold_two_page_spaces(
     else:
         lowered = eng._build_chunk_prefill()(C).lower(
             params, pool, pool, s((C,), I32), s((), I32),
-            (s((pps,), I32), s((ring,), I32)), s((), I32), state)
+            (s((pps,), I32), s((ring,), I32)), s((), I32), state,
+            s((3,), f32), s((2,), jnp.uint32))
         kernel = "paged_chunk"
     compiled = lowered.compile()
     memory = compiled.memory_analysis()
@@ -822,7 +823,7 @@ def test_the_state_space_cells_programs_hold_their_state_in_place(
             lambda: stack.new_request_state(cfg, 1, jnp.bfloat16)))
         lowered = eng._build_chunk_prefill()(C).lower(
             params, pool, pool, s((C,), I32), s((), I32), s((pps,), I32),
-            s((), I32), rs)
+            s((), I32), rs, s((3,), F32), s((2,), jnp.uint32))
         kernels = {"ssd_chunk": 5, "paged_chunk": 4}
         aliased, arguments = pools, (6.95, 7.15)
     compiled = lowered.compile()
