@@ -96,11 +96,13 @@ class ModelConfig:
     # what StackConfig (below) answers otherwise: one kind of layer, rotary
     # (or learned-position) attention over every layer's own pages and then
     # the FFN or the experts (top k of the router's logits, softmax over
-    # the chosen), no norm on queries and keys, and no state beside the pages
+    # the chosen), no norm on queries and keys, no gate on the heads'
+    # outputs, and no state beside the pages
     is_stack = False
     has_state = False
     qk_norm = False
     qk_norm_whole = False
+    attn_gate = False
     post_norm = False
     router = "softmax"
     n_dense_layers = 0
@@ -262,6 +264,16 @@ class StackConfig(ModelConfig):
     # other kind beside them in a stack: two shapes of pool rows) are the
     # LATENT kinds' together (`LATENT_KINDS`); what is `mla2`'s alone is the
     # double block, its bottleneck on the queries and the experts it carries.
+    # The seventh: "gdn" whose decay is a VECTOR over the key channels (Kimi
+    # Delta Attention): g = -exp(A_log[head]) softplus(f_b(f_a(x)) + dt_bias)
+    # a head AND key lane through a low-rank pair of `gdn_channel_rank`
+    # (`dt_bias` a lane, beta a projection of its own), and its output gate a
+    # low-rank pair of `gdn_gate_rank` with a bias under a SIGMOID, beside
+    # "attn" with no positions whose heads' outputs are gated lane by lane by
+    # sigmoid(x W_g) before the out-projection (`attn_gate`); every layer's
+    # second half is a SHARE of many small experts (sigmoid score + bias,
+    # renormalised over ALL the chosen, held or not) beside a shared expert
+    # that this chip computes whole, as every chip of the layer would.
     layer_kinds: Tuple[str, ...] = ()
     window: int = 0
     ssm_inner: int = 0        # mamba / gmu inner width
@@ -281,6 +293,14 @@ class StackConfig(ModelConfig):
     gdn_value_dim: int = 0
     # "gdn": beta = 2 sigmoid(.), so a step's eigenvalue 1 - beta may be < 0
     gdn_neg_eigval: bool = False
+    # "gdn": 0: ONE decay a head, [a ; b] = x W_ab. r > 0: a decay a key
+    # channel through the low-rank pair D -> r -> heads x key_dim
+    gdn_channel_rank: int = 0
+    # "gdn": 0: the output gate is silu(x W_gate). r > 0: sigmoid of a
+    # low-rank pair D -> r -> heads x value_dim with a bias
+    gdn_gate_rank: int = 0
+    # "attn" / "swa": the heads' outputs times sigmoid(x W_g), lane by lane
+    attn_gate: bool = False
     n_dense_layers: int = 0   # leading layers whose second half is dense
     d_ff_expert: int = 0      # an expert's width (0: d_ff)
     # the shared experts' width, all of them together as ONE gated FFN that
@@ -345,6 +365,12 @@ class StackConfig(ModelConfig):
                                    and self.gdn_value_dim):
             raise ValueError("gdn layers need `gdn_heads`, `gdn_key_dim` "
                              "and `gdn_value_dim`")
+        if (self.gdn_channel_rank or self.gdn_gate_rank) and "gdn" not in kinds:
+            raise ValueError("`gdn_channel_rank` and `gdn_gate_rank` are the "
+                             "gdn kind's: no gdn layer in `layer_kinds`")
+        if self.attn_gate and not {"attn", "swa"} & set(kinds):
+            raise ValueError("`attn_gate` gates the attn and swa kinds' "
+                             "heads: neither is in `layer_kinds`")
         if "ssd" in kinds and not (
                 self.ssm_heads and self.ssm_groups and self.ssm_inner
                 and self.ssm_inner % self.ssm_heads == 0
@@ -518,13 +544,21 @@ class StackConfig(ModelConfig):
         if kind in ("attn", "swa"):
             qk = ((H + KVH) * hd if self.qk_norm_whole
                   else 2 * hd if self.qk_norm else 0)
-            return 2 * D * H * hd + 2 * D * KVH * hd + qk
+            gate = D * H * hd if self.attn_gate else 0
+            return 2 * D * H * hd + 2 * D * KVH * hd + qk + gate
         if kind == "gdn":
             _, Hg, dk, dv = self.gdn_dims
-            # q, k, v in one projection and their taps; the gate and the
-            # out-projection; a and b; A_log, dt_bias, the output norm
+            rc, rg = self.gdn_channel_rank, self.gdn_gate_rank
+            # a and b and a decay's A_log and dt_bias a head, or the
+            # low-rank pair, b, A_log a head and dt_bias a key lane
+            decay = (rc * (D + Hg * dk) + D * Hg + Hg + Hg * dk if rc
+                     else 2 * D * Hg + 2 * Hg)
+            # the full gate, or its low-rank pair and bias
+            gate = rg * (D + Hg * dv) + Hg * dv if rg else D * Hg * dv
+            # q, k, v in one projection and their taps; the out-projection;
+            # the output norm
             return ((D + self.conv_taps) * Hg * (2 * dk + dv)
-                    + 2 * D * Hg * dv + 2 * D * Hg + 2 * Hg + dv)
+                    + D * Hg * dv + decay + gate + dv)
         if kind == "conv":
             return D * 3 * D + self.conv_taps * D + D * D
         if kind == "ssd":

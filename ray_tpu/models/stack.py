@@ -6,7 +6,8 @@ their sublayers, `x + norm(Mix(x))` and `x + norm(FFN(x))`. Mix is one of
 
   attn    the one-block models' (a plain ModelConfig: every layer): rotary
           or learned-position GQA over the layer's own pages, queries and
-          keys normalised per head where `cfg.qk_norm`
+          keys normalised per head where `cfg.qk_norm`, the heads' outputs
+          times sigmoid(h W_g) lane by lane where `cfg.attn_gate`
   conv    gated short convolution: [B ; C ; x] = h W_in, a causal
           depthwise convolution of B * x over `conv_taps` positions, gated
           by C, then W_out; its tail (the last taps - 1 rows of B * x) per
@@ -29,7 +30,12 @@ their sublayers, `x + norm(Mix(x))` and `x + norm(FFN(x))`. Mix is one of
           q and k L2-normalised, a [key, value] state matrix per head and
           sequence decayed and corrected a token at a time (ops/gdn.py),
           the output RMS-normalised per head and gated; conv tail and
-          state matrix per sequence
+          state matrix per sequence. The decay is one number a head
+          ([a ; b] = h W_ab) or, where `cfg.gdn_channel_rank`, a vector
+          over the key channels through a low-rank pair (`dt_bias` a
+          lane, beta a projection of its own); the gate silu(h W_gate)
+          or, where `cfg.gdn_gate_rank`, the sigmoid of a low-rank pair
+          with a bias
   ssd     scalar-decay state space (Mamba-2): [z ; xBC] = h W_in and
           dt = h W_dt, a causal convolution with bias and SiLU over x, B
           and C side by side, per head ONE decay exp(dt A) a token and a
@@ -228,13 +234,26 @@ def layer_shapes(cfg: ModelConfig, kind: str,
             out.update(q_norm=((H, hd), "one"), k_norm=((KVH, hd), "one"))
         elif cfg.qk_norm:
             out.update(q_norm=((hd,), "one"), k_norm=((hd,), "one"))
+        if cfg.attn_gate:
+            out.update(wg=((D, H, hd), "w"))
     elif kind == "gdn":
         _, Hg, dk, dv = cfg.gdn_dims
         out.update(d_in=((D, Hg * (2 * dk + dv)), "w"),
                    d_conv=((cfg.conv_taps, Hg * (2 * dk + dv)), "w"),
-                   d_ab=((D, 2 * Hg), "w"), d_A_log=((Hg,), "zero"),
-                   d_dt_b=((Hg,), "zero"), d_norm=((dv,), "one"),
-                   d_gate=((D, Hg * dv), "w"), d_out=((Hg * dv, D), "out"))
+                   d_A_log=((Hg,), "zero"), d_norm=((dv,), "one"),
+                   d_out=((Hg * dv, D), "out"))
+        if cfg.gdn_channel_rank:  # a decay a key channel, and b alone
+            r = cfg.gdn_channel_rank
+            out.update(d_fa=((D, r), "w"), d_fb=((r, Hg * dk), "w"),
+                       d_b=((D, Hg), "w"), d_dt_b=((Hg * dk,), "zero"))
+        else:
+            out.update(d_ab=((D, 2 * Hg), "w"), d_dt_b=((Hg,), "zero"))
+        if cfg.gdn_gate_rank:
+            r = cfg.gdn_gate_rank
+            out.update(d_ga=((D, r), "w"), d_gb=((r, Hg * dv), "w"),
+                       d_gb_b=((Hg * dv,), "zero"))
+        else:
+            out.update(d_gate=((D, Hg * dv), "w"))
     elif kind == "conv":
         out.update(c_in=((D, 3 * D), "w"), c_conv=((cfg.conv_taps, D), "w"),
                    c_out=((D, D), "out"))
@@ -976,19 +995,39 @@ def _gdn(h, lp, cfg, gi, mode, carry):
     q, k, v = (x.reshape(B, T, H, -1)
                for x in jnp.split(qkv, [H * dk, 2 * H * dk], axis=-1))
     q, k = unit(q) * dk ** -0.5, unit(k)
-    ab = jnp.einsum("btd,de->bte", h, lp["d_ab"].astype(dtype),
-                    preferred_element_type=_F32)
-    g = -jnp.exp(lp["d_A_log"].astype(_F32)) * jax.nn.softplus(
-        ab[..., :H] + lp["d_dt_b"].astype(_F32))
-    beta = jax.nn.sigmoid(ab[..., H:]) * (2.0 if cfg.gdn_neg_eigval else 1.0)
+    if cfg.gdn_channel_rank:
+        # a decay a key channel: the low-rank pair, A_log a head, dt_bias
+        # a lane; beta is a projection of its own
+        fa = jnp.einsum("btd,dr->btr", h, lp["d_fa"].astype(dtype))
+        f = jnp.einsum("btr,re->bte", fa, lp["d_fb"].astype(dtype),
+                       preferred_element_type=_F32)
+        g = -jnp.exp(lp["d_A_log"].astype(_F32))[:, None] * jax.nn.softplus(
+            f + lp["d_dt_b"].astype(_F32)).reshape(B, T, H, dk)
+        b = jnp.einsum("btd,de->bte", h, lp["d_b"].astype(dtype),
+                       preferred_element_type=_F32)
+    else:
+        ab = jnp.einsum("btd,de->bte", h, lp["d_ab"].astype(dtype),
+                        preferred_element_type=_F32)
+        g = -jnp.exp(lp["d_A_log"].astype(_F32)) * jax.nn.softplus(
+            ab[..., :H] + lp["d_dt_b"].astype(_F32))
+        b = ab[..., H:]
+    beta = jax.nn.sigmoid(b) * (2.0 if cfg.gdn_neg_eigval else 1.0)
     valid = mode.valid(T)
     if valid is not None:  # padding leaves the state alone
-        g, beta = jnp.where(valid, g, 0.0), jnp.where(valid, beta, 0.0)
+        g = jnp.where(valid[..., None] if g.ndim == 4 else valid, g, 0.0)
+        beta = jnp.where(valid, beta, 0.0)
     o, carry = mode.delta(carry, gi, q, k, v, g, beta)
     o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + cfg.norm_eps)
-    gate = jnp.einsum("btd,de->bte", h, lp["d_gate"].astype(dtype))
+    if cfg.gdn_gate_rank:  # a low-rank pair with a bias, under a sigmoid
+        ga = jnp.einsum("btd,dr->btr", h, lp["d_ga"].astype(dtype))
+        gate = jnp.einsum("btr,re->bte", ga, lp["d_gb"].astype(dtype),
+                          preferred_element_type=_F32) + lp["d_gb_b"].astype(_F32)
+        act = jax.nn.sigmoid
+    else:
+        gate = jnp.einsum("btd,de->bte", h, lp["d_gate"].astype(dtype))
+        act = jax.nn.silu
     y = (o * lp["d_norm"].astype(_F32)).reshape(B, T, H * dv) \
-        * jax.nn.silu(gate.astype(_F32))
+        * act(gate.astype(_F32))
     return jnp.einsum("bte,ed->btd", y.astype(dtype),
                       lp["d_out"].astype(dtype)), carry
 
@@ -1191,6 +1230,9 @@ def _attn(h, lp, cfg, idx, mode, carry, window=False):
     scale = (cfg.hdim ** -0.5 if cfg.attention_multiplier is None
              else cfg.attention_multiplier)
     o, carry = attend(carry, idx, q, k, v, scale)
+    if cfg.attn_gate:  # from the mixer's own input, lane by lane
+        gate = jnp.einsum("btd,dhk->bthk", h, lp["wg"].astype(h.dtype))
+        o = o.astype(_F32) * jax.nn.sigmoid(gate.astype(_F32))
     return jnp.einsum("bthk,hkd->btd", o.astype(h.dtype),
                       lp["wo"].astype(h.dtype)), carry
 

@@ -5,7 +5,14 @@ and its one-token decode form.
     S_t  = S'_t + beta_t k_t (v_t - S'_t^T k_t)^T       alpha_t = exp(g_t)
     o_t  = S_t^T q_t
 
-State and arithmetic are float32 whatever the activations' type. The state
+State and arithmetic are float32 whatever the activations' type; the chunk
+kernels' matrix products (`_dot`) are float32 operands at the DEFAULT matmul
+precision, which on the TPU multiplies through bfloat16 passes: against the
+scan at `highest` a chunk's outputs differ by 8e-4 of a scale of 0.25 and
+the state by 7e-3 in both forms, where Precision.HIGHEST reads 5e-7 and 5e-6
+for a sixth more time (the channel form alone on a v5e, PR 52; not taken,
+so that the scalar form stays the program it was). `gdn_step` has no matrix
+product and is exact to float32 rounding. The state
 of all H heads is ONE array [dk, H * dv]: key rows on sublanes, head h's
 value lanes at h*dv .. (h+1)*dv, so a slot's state is one contiguous block
 with no lane padding where H * dv is a multiple of 128 (a [dk, dv] matrix
@@ -27,6 +34,30 @@ Neumann product would cancel badly where neighbouring keys are alike and
 beta nears 2). Every decay is exp of a difference that is <= 0. A position
 with g = 0 and beta = 0 leaves the state as it was, which is how padding is
 passed over. An XLA `lax.scan` of the recurrence elsewhere.
+
+The decay may be a VECTOR over the key channels (Kimi Delta Attention,
+arXiv:2510.26692): g_t in R^dk a head, S'_t = Diag(exp g_t) S_{t-1}, the
+rest as above. Both ops take g [.., H] (the scalar form, the programs it
+always had) or [.., H, dk] (the channel form); which is a static property of
+the call. With G_t the running sum (now a row of dk) the WY form keeps its
+shape and the decays move inside the key products:
+
+    A[t,s] = beta_s sum_i k_t,i k_s,i exp(G_t,i - G_s,i)        (s < t)
+    P[t,s] = beta_s sum_i q_t,i k_s,i exp(G_t,i - G_s,i)        (s <= t)
+    D   = (I + A)^-1 (V - (K * exp G) S_0)
+    O   = (Q * exp G) S_0 + P D
+    S_C = Diag(exp G_C) S_0 + (K * exp(G_C - G) * beta)^T D
+
+A and P no longer factor into a matrix product times exp(G_t - G_s), and the
+factoring (k_t * exp G_t) . (k_s * exp -G_s) overflows float32 inside a
+block (a channel may decay by e^-20 a position). The exponent rule is kept
+by sub-blocks of 16 positions: for t in sub-block b and s in an EARLIER one
+the reference R_b = G at b's first position lies between them, so
+exp(G_t - R_b) and exp(R_b - G_s) are both of exponents <= 0 and the
+off-diagonal sub-blocks stay matrix products (one a sub-block); the 16 x 16
+diagonal sub-blocks are computed pair by pair over the channels on the
+vector unit, a column of all four a pass, exp(min(G_t - G_s, 0)) masked to
+the pairs it is meant for.
 
 `gdn_step` is decode: one token for every slot, updating ONE layer of the
 engine's whole state array [layers, B, dk, H*dv] in place (the layer rides
@@ -57,6 +88,7 @@ from .dispatch import (
 _LANES = 128
 _ROWS = 8
 _BLOCK = 64  # positions a program of the chunk kernel takes
+_SUB = 16    # ... and a sub-block of its channel form (module docstring)
 _F32 = jnp.float32
 
 
@@ -68,8 +100,12 @@ def state_shape(layers: int, slots: int, heads: int, dk: int,
 
 
 def _one_step(S, q, k, v, alpha, beta):
-    """S [B,dk,H,dv]; q, k [B,H,dk]; v [B,H,dv]; alpha, beta [B,H]."""
-    S = S * alpha[:, None, :, None]
+    """S [B,dk,H,dv]; q, k [B,H,dk]; v [B,H,dv]; beta [B,H]; alpha [B,H],
+    or [B,H,dk]: a decay a key channel."""
+    if alpha.ndim == 3:
+        S = S * jnp.swapaxes(alpha, 1, 2)[..., None]
+    else:
+        S = S * alpha[:, None, :, None]
     d = beta[..., None] * (v - jnp.einsum("bihj,bhi->bhj", S, k))
     S = S + jnp.einsum("bhi,bhj->bihj", k, d)
     return S, jnp.einsum("bihj,bhi->bhj", S, q)
@@ -81,8 +117,9 @@ def _one_step(S, q, k, v, alpha, beta):
 
 
 def gdn_chunk_reference(q, k, v, g, beta, s0):
-    """q, k [B,T,H,dk]; v [B,T,H,dv]; g (log decay, <= 0), beta [B,T,H];
-    s0 [B,dk,H*dv] f32 -> (o [B,T,H,dv] f32, s1 [B,dk,H*dv] f32)."""
+    """q, k [B,T,H,dk]; v [B,T,H,dv]; g (log decay, <= 0) [B,T,H] or
+    [B,T,H,dk]; beta [B,T,H]; s0 [B,dk,H*dv] f32 -> (o [B,T,H,dv] f32,
+    s1 [B,dk,H*dv] f32)."""
     B, T, H, dk = q.shape
     dv = v.shape[-1]
 
@@ -190,19 +227,138 @@ def _chunk_pallas(q, k, v, g, beta, s0):
             jnp.moveaxis(s1, 1, 2).reshape(B, dk, H * dv))
 
 
+def _channel_chunk_kernel(q_ref, k_ref, kt_ref, v_ref, g_ref, gt_ref,
+                          bcol_ref, brow_ref, s0_ref, o_ref, s1_ref, s_scr,
+                          *, block, sub, n_blocks):
+    """`_chunk_kernel` for a decay a key channel (module docstring): G
+    [C,dk] a position a sublane, Gt [dk,C] a position a lane."""
+    t = pl.program_id(2)
+
+    @pl.when(t == 0)
+    def _init():
+        s_scr[...] = s0_ref[0, 0]
+
+    C, c = block, sub
+    q, k, kt, v = q_ref[0, 0], k_ref[0, 0], kt_ref[0, 0, 0], v_ref[0, 0]
+    G, Gt = g_ref[0, 0], gt_ref[0, 0, 0]
+    bc, br = bcol_ref[0, 0], brow_ref[0, 0, 0]   # [C,1], [1,C]
+    S = s_scr[...]
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    at = jax.lax.broadcasted_iota(jnp.int32, k.shape, 0)    # a position a row
+    att = jax.lax.broadcasted_iota(jnp.int32, kt.shape, 1)  # ... a lane
+
+    def decayed(x, e, first, end, along):
+        """x * exp(e) at positions first .. end - 1 (`along`: the iota of
+        x's position axis), where e <= 0; 0 elsewhere."""
+        keep = (along >= first) & (along < end)
+        return jnp.where(keep, x * jnp.exp(jnp.minimum(e, 0.0)), 0.0)
+
+    def row_of_each(x, j):
+        """Row j of every sub-block of x [C, w], over that sub-block's rows."""
+        return jnp.concatenate(
+            [jnp.broadcast_to(x[b * c + j:b * c + j + 1, :], (c, x.shape[1]))
+             for b in range(C // c)], axis=0)
+
+    # AT[s,t] = beta_s A[t,s] (s < t) and P[t,s] (s <= t), both before
+    # beta. Off the diagonal sub-blocks, from sub-block b's reference R_b
+    AT = jnp.zeros((C, C), _F32)
+    P = jnp.zeros((C, C), _F32)
+    for b in range(1, C // c):
+        lo, hi = b * c, (b + 1) * c
+        R, Rt = G[lo:lo + 1, :], Gt[:, lo:lo + 1]
+        AT = AT + _dot(decayed(k, R - G, 0, lo, at),
+                       decayed(kt, Gt - Rt, lo, hi, att))
+        P = P + _dot(decayed(q, G - R, lo, hi, at),
+                     decayed(kt, Rt - Gt, 0, lo, att))
+    # the diagonal sub-blocks, column j of all of them at a time
+    for j in range(c):
+        Gj, kj = row_of_each(G, j), row_of_each(k, j)
+        colP = jnp.sum(q * kj * jnp.exp(jnp.minimum(G - Gj, 0.0)),
+                       axis=1, keepdims=True)
+        colA = jnp.sum(k * kj * jnp.exp(jnp.minimum(Gj - G, 0.0)),
+                       axis=1, keepdims=True)
+        mine = col == row - (row & (c - 1)) + j
+        P = jnp.where(mine & (row >= col), colP, P)
+        AT = jnp.where(mine & (row < col), colA, AT)
+    AT, P = AT * bc, P * br
+    Tm = (row == col).astype(_F32)
+    for i in range(1, C):
+        Tm = jnp.where(
+            row == i,
+            Tm - jnp.sum(AT[:, i:i + 1] * Tm, axis=0, keepdims=True), Tm)
+    eg = jnp.exp(G)
+    D = _dot(Tm, v - _dot(k * eg, S))
+    o_ref[0, 0] = _dot(q * eg, S) + _dot(P, D)
+    gC = Gt[:, C - 1:C]                      # [dk,1]: the block's whole decay
+    S = jnp.exp(gC) * S + _dot(kt * jnp.exp(gC - Gt) * br, D)
+    s_scr[...] = S
+
+    @pl.when(t == n_blocks - 1)
+    def _finish():
+        s1_ref[0, 0] = S
+
+
+def _channel_chunk_pallas(q, k, v, g, beta, s0):
+    B, T, H, dk = q.shape
+    dv = v.shape[-1]
+    C = _BLOCK
+    n = T // C
+
+    def heads_first(a):
+        return jnp.moveaxis(a.astype(_F32), 2, 1)  # [B,H,T,..]
+
+    def lanes(a):  # [B,H,T,w] -> [B,H,n,w,C]: a position a lane
+        return jnp.swapaxes(a.reshape(B, H, n, C, a.shape[-1]), -1, -2)
+
+    qh, kh, vh = heads_first(q), heads_first(k), heads_first(v)
+    G = jnp.cumsum(heads_first(g).reshape(B, H, n, C, dk),
+                   axis=3).reshape(B, H, T, dk)
+    bh = heads_first(beta)[..., None]              # [B,H,T,1]
+    s0h = jnp.moveaxis(s0.astype(_F32).reshape(B, dk, H, dv), 2, 1)
+
+    def seq(width):
+        return pl.BlockSpec((1, 1, C, width), lambda b, h, t: (b, h, t, 0))
+
+    def across(width):
+        return pl.BlockSpec((1, 1, 1, width, C),
+                            lambda b, h, t: (b, h, t, 0, 0))
+
+    state = pl.BlockSpec((1, 1, dk, dv), lambda b, h, t: (b, h, 0, 0))
+    o, s1 = pl.pallas_call(
+        functools.partial(_channel_chunk_kernel, block=C, sub=_SUB,
+                          n_blocks=n),
+        grid=(B, H, n),
+        in_specs=[seq(dk), seq(dk), across(dk), seq(dv), seq(dk), across(dk),
+                  seq(1), across(1), state],
+        out_specs=[seq(dv), state],
+        out_shape=[jax.ShapeDtypeStruct((B, H, T, dv), _F32),
+                   jax.ShapeDtypeStruct((B, H, dk, dv), _F32)],
+        scratch_shapes=[pltpu.VMEM((dk, dv), _F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="gdn_chunk",
+        interpret=interpret_mode(),
+    )(qh, kh, lanes(kh), vh, G, lanes(G), bh, lanes(bh), s0h)
+    return (jnp.moveaxis(o, 1, 2),
+            jnp.moveaxis(s1, 1, 2).reshape(B, dk, H * dv))
+
+
 def gdn_chunk(q, k, v, g, beta, s0, force_xla: bool = False):
     """The recurrence of one layer over a sequence, from state s0.
 
     q, k [B,T,H,dk] (normalised and scaled by the caller), v [B,T,H,dv],
-    g [B,T,H] (log decay, <= 0) and beta [B,T,H] (both 0 at a position
-    leave the state as it was: padding), s0 [B,dk,H*dv].
+    g (log decay, <= 0) [B,T,H] or, a decay a key channel, [B,T,H,dk], and
+    beta [B,T,H] (both 0 at a position leave the state as it was: padding),
+    s0 [B,dk,H*dv].
     -> (o [B,T,H,dv] float32, final state [B,dk,H*dv] float32)."""
     T, dk, dv = q.shape[1], q.shape[-1], v.shape[-1]
     ok = (use_pallas() and T % _BLOCK == 0 and dk % _ROWS == 0
           and dv % _ROWS == 0)
     if force_xla or not ok:
         return gdn_chunk_reference(q, k, v, g, beta, s0)
-    return platform_dispatch(_chunk_pallas, gdn_chunk_reference,
+    kernel = _channel_chunk_pallas if g.ndim == q.ndim else _chunk_pallas
+    return platform_dispatch(kernel, gdn_chunk_reference,
                              q, k, v, g, beta, s0)
 
 
@@ -212,9 +368,9 @@ def gdn_chunk(q, k, v, g, beta, s0, force_xla: bool = False):
 
 
 def gdn_step_reference(state, layer, q, k, v, g, beta, live):
-    """state [L,B,dk,H*dv] f32; q, k [B,H,dk]; v [B,H,dv]; g, beta [B,H];
-    live [B] bool -> (o [B,H,dv] f32, state with the live slots of layer
-    `layer` advanced and every other slot untouched)."""
+    """state [L,B,dk,H*dv] f32; q, k [B,H,dk]; v [B,H,dv]; beta [B,H];
+    g [B,H] or [B,H,dk]; live [B] bool -> (o [B,H,dv] f32, state with the
+    live slots of layer `layer` advanced and every other slot untouched)."""
     B, H, dk = q.shape
     dv = v.shape[-1]
     old = jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
@@ -234,7 +390,9 @@ def _unit(dv: int) -> int:
 
 
 def _step_kernel(src_ref, live_ref, layer_ref, kq_ref, rows_ref, s_ref,
-                 o_ref, so_ref, *, heads, dv, unit):
+                 o_ref, so_ref, *, heads, dv, unit, channel=False):
+    """`channel`: the decay is a key channel's, exp(g) a third [dk, H]
+    operand beside k and q, and `rows_ref` holds beta and v alone."""
     del layer_ref  # the block specs read it
     b = pl.program_id(0)
     per = unit // dv
@@ -252,9 +410,14 @@ def _step_kernel(src_ref, live_ref, layer_ref, kq_ref, rows_ref, s_ref,
     @pl.when(live_ref[b] > 0)
     def _advance():
         kt, qt = kq_ref[0, 0], kq_ref[0, 1]           # [dk, H]
+        at_ = kq_ref[0, 2] if channel else None       # exp(g), likewise
         for u in range(heads * dv // unit):
             at = slice(u * unit, (u + 1) * unit)
-            alpha, beta, v = (rows_ref[0, i:i + 1, at] for i in range(3))
+            if channel:
+                beta, v = (rows_ref[0, i:i + 1, at] for i in range(2))
+                alpha = wide(at_, u * per)
+            else:
+                alpha, beta, v = (rows_ref[0, i:i + 1, at] for i in range(3))
             kx, qx = wide(kt, u * per), wide(qt, u * per)
             S = s_ref[0, 0, :, at] * alpha
             d = beta * (v - jnp.sum(S * kx, axis=0, keepdims=True))
@@ -282,19 +445,23 @@ def _step_pallas(state, layer, q, k, v, g, beta, live):
     def row(a):  # [B,H] -> [B,H*dv]: a head's scalar over its value lanes
         return jnp.repeat(a.astype(_F32), dv, axis=-1)
 
-    rows = jnp.stack([row(jnp.exp(g.astype(_F32))), row(beta),
-                      v.astype(_F32).reshape(B, H * dv)], axis=1)
-    kq = jnp.swapaxes(jnp.stack([k, q], axis=1).astype(_F32), -1, -2)
+    channel = g.ndim == q.ndim
+    alpha = jnp.exp(g.astype(_F32))
+    rows = jnp.stack(([] if channel else [row(alpha)]) + [
+        row(beta), v.astype(_F32).reshape(B, H * dv)], axis=1)
+    kq = jnp.swapaxes(jnp.stack(
+        [k, q] + ([alpha] if channel else []), axis=1).astype(_F32), -1, -2)
     slab = pl.BlockSpec((1, 1, dk, lanes),
                         lambda b, src, live, l: (l[0], src[b], 0, 0))
     o, state = pl.pallas_call(
-        functools.partial(_step_kernel, heads=H, dv=dv, unit=_unit(dv)),
+        functools.partial(_step_kernel, heads=H, dv=dv, unit=_unit(dv),
+                          channel=channel),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B,),
             in_specs=[
-                pl.BlockSpec((1, 2, dk, H), lambda b, *_: (b, 0, 0, 0)),
-                pl.BlockSpec((1, 3, lanes), lambda b, *_: (b, 0, 0)),
+                pl.BlockSpec((1, *kq.shape[1:]), lambda b, *_: (b, 0, 0, 0)),
+                pl.BlockSpec((1, *rows.shape[1:]), lambda b, *_: (b, 0, 0)),
                 slab],
             out_specs=[pl.BlockSpec((1, 1, lanes), lambda b, *_: (b, 0, 0)),
                        slab],
@@ -317,7 +484,8 @@ def gdn_step(state, layer, q, k, v, g, beta, live, force_xla: bool = False):
     """Decode: advance layer `layer` of the whole state [L,B,dk,H*dv]
     (float32) by one token for every slot that is `live` [B], in place;
     the others' state is untouched and their output zero. q, k [B,H,dk];
-    v [B,H,dv]; g, beta [B,H]. -> (o [B,H,dv] float32, state)."""
+    v [B,H,dv]; beta [B,H]; g [B,H] or, a decay a key channel, [B,H,dk].
+    -> (o [B,H,dv] float32, state)."""
     dk, lanes = state.shape[2:]
     dv = v.shape[-1]
     ok = (use_pallas() and state.dtype == _F32 and dk % _ROWS == 0

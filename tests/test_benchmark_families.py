@@ -81,11 +81,15 @@ def test_the_eight_are_entries_of_the_five_serve_cells_and_no_train_cell(
 LATER_CELLS = ("longcat-flash-omni.serve-docs",  # PR 39
                "smallthinker-21b-a3b.serve-mixedlen",  # PR 41
                "granite-4.0-h-micro.serve-chat-burst",  # PR 45
-               "kanana-2-30b-a3b.serve-agent")  # PR 48
+               "kanana-2-30b-a3b.serve-agent",  # PR 48
+               "solar-open2-250b.serve-mixedlen")  # PR 52
 
 
 # metrics that later PRs appended for cells that were there already
 LATER_READERS = ("moe_experts_skipped_share",)  # PR 42
+# ... and for ONE cell that was there already: the chunk kernel's share of
+# the device's time, PR 52's, lists Olmo's cell beside PR 52's own
+LATER_READER_OF_OLMO = "gdn_chunk_device_share"
 
 # the parts of `setup_s` (PR 50), appended for every serve cell at once:
 # they end the list whatever cells a test leaves out
@@ -102,7 +106,8 @@ def manifest_without(cells):
     in; and without the LATER_READERS."""
     manifest = common.load_manifest()
     manifest["per_layer"] = [m for m in manifest["per_layer"]
-                             if m["name"] not in LATER_READERS]
+                             if m["name"] not in (*LATER_READERS,
+                                                  LATER_READER_OF_OLMO)]
 
     def without(entry):
         if "workloads" not in entry:
@@ -392,6 +397,7 @@ def test_olmo_hybrid_readers_reach_the_counts_through_the_family():
         "decode_device_ms_per_step", "prefill_device_ms_per_ktok",
         "paged_decode_roofline", "gdn_step_roofline", "gdn_step_device_share",
         "gdn_chunk_roofline", "recurrent_state_live_share",
+        LATER_READER_OF_OLMO,
         # the token ledger's eight (PR 36), in every serve cell
         "tpot_device_wait_ms", "tpot_host_ms", "tpot_ready_ms",
         "tpot_unaccounted_ms", "decode_step_wall_ms.clean",
@@ -726,3 +732,174 @@ def test_the_expert_kernels_names_files_join_the_moe_ffn_group(
         "modules": {}, "module_ops": {}}
     assert trace_reduce.group_seconds(trace, "moe_ffn") == (2.5, 48.0)
     assert trace_reduce.group_share(trace, "moe_ffn") == 25.0
+
+
+# -- the Solar Open 2 family (PR 52) -----------------------------------------
+
+SOLAR = "solar-open2-250b"
+SOLAR_ROW = {  # the published config, key for key, but the four cut keys
+    "model_type": "solar_open2", "partial_rotary_factor": 1,
+    "linear_attn_config": {"short_conv_kernel_size": 4, "head_dim": 128,
+                           "num_heads": 64, "num_kv_heads": None},
+    "hidden_size": 4096, "num_attention_heads": 64, "head_dim": 128,
+    "num_key_value_heads": 8, "intermediate_size": 10240,
+    "moe_intermediate_size": 1280, "rms_norm_eps": 1e-05, "rope_theta": 10000,
+    "tie_word_embeddings": False, "max_position_embeddings": 1048576,
+    "first_k_dense_replace": 0, "use_rope": False, "gqa_interval": 3,
+    "use_gqa_gate": True, "kda_use_full_proj": False,
+    "kda_allow_neg_eigval": True, "n_shared_experts": 1,
+    "norm_topk_prob": True, "routed_scaling_factor": 1,
+    "num_experts_per_tok": 8}
+SOLAR_CUTS = {"num_hidden_layers": (48, 8),
+              "gqa_layers": (list(range(0, 48, 4)), [0, 4]),
+              "n_routed_experts": (320, 20), "vocab_size": (196608, 24576)}
+
+
+def test_solar_open2_configuration_is_the_published_one_with_four_cuts():
+    spec = common.load_json("configs", SOLAR + ".json")
+    assert {k: spec[k] for k in SOLAR_ROW} == SOLAR_ROW
+    for key, (published, held) in SOLAR_CUTS.items():
+        assert spec[key] == held and spec["published"][key] == published
+    assert sorted(spec["reduced"]) == sorted(SOLAR_CUTS)
+    assert (spec["n_routed_experts_total"], spec["held_experts_first"]) == (320, 0)
+    entry = next(c for c in common.load_manifest()["configs"]
+                 if c["name"] == SOLAR)
+    assert sorted(entry["reduced"]) == sorted(spec["reduced"])
+    assert sorted(entry) == ["file", "name", "reduced", "source", "why"]
+    family = common.family(spec)
+    cfg = family.model_config(spec)
+    # every published width, pinned field by field
+    assert (cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.hdim, cfg.d_ff) == (
+        4096, 64, 8, 128, 10240)
+    assert cfg.gdn_dims == (6, 64, 128, 128) and cfg.conv_taps == 4
+    assert (cfg.gdn_channel_rank, cfg.gdn_gate_rank) == (128, 128)
+    assert (cfg.num_experts, cfg.experts_routed, cfg.router_width,
+            cfg.experts_first, cfg.num_selected_experts) == (20, 320, 320, 0, 8)
+    assert (cfg.expert_ff, cfg.d_ff_shared, cfg.n_dense_layers) == (1280, 1280, 0)
+    assert (cfg.router, cfg.norm_topk, cfg.routed_scale) == ("sigmoid", True, 1.0)
+    assert (cfg.positional, cfg.post_norm, cfg.attn_gate, cfg.gdn_neg_eigval,
+            cfg.tie_embeddings, cfg.qk_norm) == (
+                "none", False, True, True, False, False)
+    assert cfg.capacity_factor * 8 >= 20  # dropless over the held experts
+    assert cfg.segments() == ((0, ("attn", "gdn", "gdn", "gdn"), 2),)
+    assert round(cfg.param_count() / 1e7) == 390         # the issue's 3.90 B
+    assert (cfg.vocab_size, cfg.max_seq_len, cfg.norm_eps) == (
+        24576, 1048576, 1e-5)
+
+
+@pytest.mark.parametrize("key,value,says", [
+    ("n_group", 4, "n_group 4"), ("topk_group", 2, "topk_group 2"),
+    ("kda_use_full_proj", True, "kda_use_full_proj True"),
+    ("use_rope", True, "use_rope True")])
+def test_solar_open2_refuses_what_it_does_not_write(key, value, says):
+    spec = dict(common.load_json("configs", SOLAR + ".json"), **{key: value})
+    with pytest.raises(ValueError, match=says):
+        common.family(spec).model_config(spec)
+
+
+def test_solar_open2_takes_an_absent_group_or_one_and_whole_key_heads_alone():
+    spec = common.load_json("configs", SOLAR + ".json")
+    family = common.family(spec)
+    assert "n_group" not in spec
+    family.model_config({**spec, "n_group": 1, "topk_group": 1})
+    shared_keys = {**spec, "linear_attn_config": {
+        **spec["linear_attn_config"], "num_kv_heads": 16}}
+    with pytest.raises(ValueError, match="key heads shared"):
+        family.model_config(shared_keys)
+
+
+def test_solar_open2_readers_reach_the_counts_through_the_family():
+    spec = common.load_json("configs", SOLAR + ".json")
+    family = common.family(spec)
+    assert reference_file(family) == spec["reference"]
+    assert set(family.modes) == {"int8", "fp8", "state-bf16", "router-bf16"}
+    assert set(family.work) == {"paged_decode", "paged_chunk", "gdn_chunk",
+                                "gdn_step"}
+    assert family.calls_per_pass(spec, "paged_decode") == 2
+    assert family.calls_per_pass(spec, "paged_chunk") == 2
+    assert family.calls_per_pass(spec, "gdn_step") == 6
+    assert family.calls_per_pass(spec, "gdn_chunk") == 6
+    # a cached token is 8 KV heads of 128 bfloat16, keys and values
+    work = family.work["paged_decode"](spec, 1000)
+    assert work["bytes"] == 2 * 8 * 128 * 2 * 1000 == 4096 * 1000
+    assert work["flops"] == 2 * 2 * 64 * 128 * 1000
+    # a chunk of 256 from position 512 in both GQA layers: every key up to
+    # each row's own scored, every key read once
+    chunk = family.chunk_attention_work(spec, 512, 256)
+    assert chunk["bytes"] == 2 * 4096 * 768
+    assert chunk["flops"] == 2 * 2 * 2 * 64 * 128 * (256 * 512 + 256 * 257 // 2)
+
+
+def test_the_channel_decays_work_is_a_hand_count_at_one_small_shape():
+    """2 heads of a [4, 4] state whose decay is a key channel's: per head,
+    token and state element the scalar form's 7 operations, and one
+    exponential a key lane; q, k, v, o, the decay's 4 lanes and beta in
+    float32; the state once a call (prefill) or once a LIVE slot (decode)."""
+    spec = {"linear_attn_config": {"num_heads": 2, "head_dim": 4,
+                                   "num_kv_heads": None}}
+    family = common.family(common.load_json("configs", SOLAR + ".json"))
+    state = 2 * 4 * 4                        # elements
+    operands = 2 * (4 + 4 + 4 + 4 + 4 + 1)   # q, k, v, o, g, beta a token
+    chunk = family.work["gdn_chunk"](spec, 100)
+    assert chunk["flops"] == (7 * state + 2 * 4) * 100
+    assert chunk["bytes"] == 4 * (operands * 100 + 2 * state)
+    step = family.work["gdn_step"](spec, 5)
+    assert step["flops"] == (7 * state + 2 * 4) * 5
+    assert step["bytes"] == 4 * 5 * (operands + 2 * state)
+    assert family.work["gdn_step"](spec, 0) == {"flops": 0, "bytes": 0}
+    # at the published sizes a live slot's state is 4.19 MB, read and written
+    big = common.load_json("configs", SOLAR + ".json")
+    assert family.work["gdn_step"](big, 1)["bytes"] == 4 * (
+        2 * 64 * 128 * 128 + 64 * (5 * 128 + 1))
+
+
+def test_solar_open2_weights_are_seeded_bfloat16_and_in_the_programs_layout():
+    from benchmark import weights
+
+    spec = tiny_spec(SOLAR)
+    a, b, c = (weights.make_weights(spec, s) for s in (7, 7, 2**31 + 11))
+    leaves = jax.tree.leaves(a)
+    assert all(leaf.dtype == jax.numpy.bfloat16 for leaf in leaves)
+    assert all((x == y).all() for x, y in zip(leaves, jax.tree.leaves(b)))
+    assert any((x != y).any() for x, y in zip(leaves, jax.tree.leaves(c)))
+    cfg = common.family(spec).model_config(spec)
+    assert [len(seg) for seg in a["layers"]] == [4]
+    gqa, linear = a["layers"][0][0], a["layers"][0][1]
+    assert gqa["wg"].shape == (2, 64, 4, 16)
+    assert linear["router"].shape == (2, 64, 16)    # every output, held or not
+    assert linear["w_in"].shape == (2, 4, 64, 32)   # the held experts alone
+    assert linear["sh_in"].shape == (2, 64, 32)
+    assert float(abs(linear["router_bias"].astype("float32")).max()) > 0
+    assert not linear["d_gb_b"].astype("float32").any()
+    # the decay as Kimi Linear draws it: A in (1, 16) a head, a step in
+    # [0.001, 0.1] a LANE
+    assert linear["d_dt_b"].shape == (2, 32) and linear["d_A_log"].shape == (2, 4)
+    A_log = linear["d_A_log"].astype("float32")
+    assert float(A_log.min()) >= 0 and float(A_log.max()) <= 2.78
+    step = jax.nn.softplus(linear["d_dt_b"].astype("float32"))
+    assert 5e-4 < float(step.min()) and float(step.max()) < 0.11
+    assert sum(x.size for x in leaves) == cfg.param_count()
+
+
+def test_the_solar_names_file_adds_the_shared_experts_width_and_removes_nothing():
+    with open(os.path.join(common.HERE, "trace_names.json")) as f:
+        base = json.load(f)["groups"]
+    merged = trace_reduce.load_names()["groups"]
+    for group, entries in base.items():
+        assert merged[group][:len(entries)] == entries
+    up = ("%fusion.31 = bf16[256,1280]{1,0} fusion(bf16[256,4096]{1,0} %x, "
+          "bf16[2,4096,1280]{2,1,0} %w)")
+    down = ("%fusion.32 = bf16[256,4096]{1,0} fusion(bf16[256,1280]{1,0} %h, "
+            "bf16[2,1280,4096]{2,1,0} %w)")
+    routed = ("%moe_groups.4 = bf16[256,4096]{1,0} custom-call(bf16[256,4096]{1,0} "
+              "%x, bf16[2,20,4096,1280]{3,2,1,0} %w)")
+    loop = "%while.3 = (bf16[256,1280]{1,0}, s32[]) while(%tuple.1)"
+    chunk = "%gdn_chunk.2 = (f32[1,64,256,128]{3,2,1,0}) custom-call(f32[1] %a)"
+    trace = {"busy_s": 10.0, "ops": {up: [1.0, 8], down: [0.5, 8],
+                                     routed: [3.0, 8], loop: [9.0, 1],
+                                     chunk: [2.0, 6]},
+             "modules": {}, "module_ops": {}}
+    assert trace_reduce.group_seconds(trace, "shared_experts") == (1.5, 16.0)
+    assert trace_reduce.group_seconds(trace, "moe_ffn") == (3.0, 8.0)
+    assert common.load_reader("shared_expert_device_share")({"trace": trace}) == 15.0
+    assert common.load_reader("gdn_chunk_device_share")({"trace": trace}) == 20.0

@@ -119,6 +119,15 @@ def _gdn_operands(*lead):
             ((*lead, GH, GV), F32), ((*lead, GH), F32), ((*lead, GH), F32)]
 
 
+# ... and with a decay a key channel: 64 heads, a [128, 128] state matrix each
+KH, KD = 64, 128
+
+
+def _kda_operands(*lead):
+    return [((*lead, KH, KD), F32), ((*lead, KH, KD), F32),
+            ((*lead, KH, KD), F32), ((*lead, KH, KD), F32), ((*lead, KH), F32)]
+
+
 # the scalar-decay state space at its published sizes: 64 heads of 64, a
 # [128, 64] state matrix each, one group, 64 slots of 36 layers (ops/ssd.py)
 SH, SP, SN = 64, 64, 128
@@ -201,6 +210,19 @@ CASES = {
     "gdn_step_b64": (
         lambda st, *a: gdn_step(st, LAYER, *a),
         [((12, 64, GK, GH * GV), F32)] + _gdn_operands(64)
+        + [((64,), jnp.bool_)], 1),
+    # the delta rule whose decay is a key channel's, at its published sizes:
+    # 64 heads, a [128, 128] state each, 64 slots of 6 layers; a chunk of
+    # 256, the wide chunk and a bucket of 64
+    "gdn_chunk_channel_t256": (gdn_chunk, _kda_operands(1, 256)
+                               + [((1, KD, KH * KD), F32)], 1),
+    "gdn_chunk_channel_t512": (gdn_chunk, _kda_operands(1, 512)
+                               + [((1, KD, KH * KD), F32)], 1),
+    "gdn_chunk_channel_t64": (gdn_chunk, _kda_operands(1, 64)
+                              + [((1, KD, KH * KD), F32)], 1),
+    "gdn_step_channel_b64": (
+        lambda st, *a: gdn_step(st, LAYER, *a),
+        [((6, 64, KD, KH * KD), F32)] + _kda_operands(64)
         + [((64,), jnp.bool_)], 1),
     # latent attention at its published sizes: 64 heads over ONE 640-lane row
     # a token (512 of latent, 64 of rotary key, 64 of padding), 8 attentions'
@@ -630,6 +652,30 @@ def test_the_latent_cells_programs_hold_one_pool_at_published_widths(
     assert not re.search(r"= bf16\[8,(1,)?24577,16,640\]\S* copy\(", text)
 
 
+def _names_file_patterns(name, group="shared_experts"):
+    """The patterns ONE file under benchmark/trace_names/ adds to a group
+    (`trace_reduce.load_names` merges every file into every cell's names,
+    so a family's width must be no other cell's)."""
+    import json
+    import os
+
+    from benchmark import common
+    with open(os.path.join(common.HERE, "trace_names", name + ".json")) as f:
+        return [re.compile(e["match"]) for e in json.load(f)["groups"][group]]
+
+
+def _lines_matching(text, patterns):
+    """The compiled module's operations that `patterns` name, less those
+    that take no time on the device and so never appear in a trace (a
+    parameter, a tuple or its element, a bitcast, a constant)."""
+    free = re.compile(r" = \(|\b(parameter|get-tuple-element|tuple|bitcast|"
+                      r"constant)\(")
+    return [line.strip() for line in text.splitlines()
+            if any(p.search(line.strip()) for p in patterns)
+            and not free.search(line)]
+
+
+
 @pytest.mark.parametrize(
     "program", ["decode_span_8", "chunk_prefill_256", "chunk_prefill_512"])
 def test_the_agent_cells_programs_run_their_kernels_at_published_widths(
@@ -679,6 +725,9 @@ def test_the_agent_cells_programs_run_their_kernels_at_published_widths(
              if any(g.search(line.strip()) for g in group)]
     assert named and all("1536" in line for line in named)
     print("\n".join(line[:200] for line in named))
+    # ... and the Solar family's width (1280, merged into these names too)
+    # is no operation's of this cell
+    assert not _lines_matching(text, _names_file_patterns("solar_open2"))
 
 
 @pytest.mark.parametrize(
@@ -839,3 +888,159 @@ def test_the_state_space_cells_programs_hold_their_state_in_place(
         assert len(re.findall(r"%%%s(\.\d+)? = " % kernel, text)) == calls
     assert not re.search(r"= f32\[36,64,128,4096\]\S* copy\(", text)
     assert not re.search(r"= bf16\[4,(1,)?8193,16,512\]\S* copy\(", text)
+
+
+# sha256 (12 digits) of each scalar-form delta-rule kernel's Mosaic module,
+# printed WITHOUT debug locations, as the parent of PR 52 (b728942) lowers
+# it at Olmo's published sizes, at the tests' own matmul precision (`highest`
+# marks the kernels' products; at the default setting the three read
+# dc311e7a2f83, 6f26eff7a408 and the same 2b7c2315d3dc, on both trees: my
+# lowerings, PR 52): the channel form is a second kernel and a
+# static branch, and one decay a head must stay the program it was (a
+# module's serialized bytes carry line numbers, so the lowered text itself
+# moves with any edit above the kernel)
+SCALAR_KERNELS = {
+    "gdn_chunk_t256": "21c069369683", "gdn_chunk_t64": "c4b799a00503",
+    "gdn_step_b64": "2b7c2315d3dc",
+}
+LOWERED_WITH_JAX = "0.9.0"
+
+
+@pytest.mark.parametrize("name", sorted(SCALAR_KERNELS))
+def test_the_scalar_delta_rule_kernels_are_the_parents(
+        name, topo, no_persistent_cache, monkeypatch):
+    import hashlib
+
+    import jax._src.tpu_custom_call as tpu_custom_call
+
+    if jax.__version__ != LOWERED_WITH_JAX:
+        pytest.skip(f"digests were taken with jax {LOWERED_WITH_JAX}")
+    seen = []
+    lower = tpu_custom_call._lower_mosaic_module_to_asm
+
+    def watched(module, **kw):
+        seen.append(hashlib.sha256(module.operation.get_asm(
+            enable_debug_info=False).encode()).hexdigest()[:12])
+        return lower(module, **kw)
+
+    monkeypatch.setattr(tpu_custom_call, "_lower_mosaic_module_to_asm", watched)
+    op, specs, _ = CASES[name]
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    # a function of its own: `jax.jit(op)` answers from the lowering another
+    # test of this process left behind, and no kernel is lowered again
+    jax.jit(lambda *a: op(*a)).lower(*(
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in specs))
+    assert seen == [SCALAR_KERNELS[name]]
+
+
+@pytest.mark.parametrize("program", ["decode_span_8", "chunk_prefill_256",
+                                     "chunk_prefill_512"])
+def test_the_channel_decay_cells_programs_hold_state_and_pool_in_place(
+        program, topo, no_persistent_cache):
+    """`solar-open2-250b.serve-mixedlen` as the benchmark sizes it: ONE scan
+    of two periods (gqa kda kda kda); a decode span advances the engine's
+    whole state array [6, 64, 128, 8192] float32 (1.5 GiB) in place, one
+    `gdn_step` a scanned KDA layer, one `paged_decode` the GQA layer and one
+    `moe_step` a layer over the 20 held experts; a chunk one `gdn_chunk` a
+    KDA layer from ONE sequence's state and one `moe_groups` a layer (the
+    wide chunk attends in two calls of 256 rows); nothing copies the state,
+    the pool or the experts. The memory the cell's `pool_filled` quotes:
+    7.26 GiB of weights + 1.5 GiB of pages + 1.5 GiB of state + 0.05 of
+    tails."""
+    from benchmark import common
+    from ray_tpu.models import stack
+    from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    cell = common.load_cell("solar-open2-250b.serve-mixedlen")
+    spec = cell["config"]
+    family = common.family(spec)
+    cfg = family.model_config(spec)
+    eng = object.__new__(InferenceEngine)
+    eng.cfg, eng.ecfg, eng.mesh, eng._tp = (
+        cfg, EngineConfig(**cell["engine"]), None, 1)
+    eng._ring = eng._window_ring()
+    assert not eng._ring and eng._wide_chunk() == 512
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: s(a.shape, a.dtype), jax.eval_shape(
+            lambda k: family.init_weights(spec, k), jax.random.PRNGKey(0)))
+    pool, state = eng.abstract_pool(one_chip), eng.abstract_state(one_chip)
+    assert pool.shape == (2, 1, 12289, 16, 1024)
+    assert {k: (v.shape, v.dtype) for k, v in state.items()} == {
+        "conv": ((6, 64, 3, 24576), BF16),
+        "gdn": ((6, 64, 128, 8192), F32)}
+    ecfg = eng.ecfg
+    B, pps = ecfg.max_batch_size, ecfg.pages_per_seq
+    gib = 2 ** 30
+    held = sum(a.size * a.dtype.itemsize for a in state.values())
+    pools = 2 * pool.size * pool.dtype.itemsize
+    if program == "decode_span_8":
+        lowered = eng._build_decode()(8).lower(
+            params, pool, pool, s((B,), I32), s((B,), I32), s((B, pps), I32),
+            s((B,), F32), s((B,), F32), s((B,), I32), s((2,), jnp.uint32),
+            state, (s((B,), I32), s((B,), I32), s((B,), jnp.bool_)))
+        kernels = {"gdn_step": 3, "paged_decode": 1, "moe_step": 4}
+        aliased, arguments, temporaries = pools + held, (10.2, 10.45), 0.5
+    else:
+        C = int(program.rsplit("_", 1)[1])
+        rs = jax.tree.map(lambda a: s(a.shape, a.dtype), jax.eval_shape(
+            lambda: stack.new_request_state(cfg, 1, jnp.bfloat16)))
+        lowered = eng._build_chunk_prefill()(C).lower(
+            params, pool, pool, s((C,), I32), s((), I32), s((pps,), I32),
+            s((), I32), rs, s((3,), F32), s((2,), jnp.uint32))
+        kernels = {"gdn_chunk": 3, "paged_chunk": C // 256, "moe_groups": 4}
+        aliased, arguments, temporaries = pools, (8.7, 8.9), 0.25
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    print(program, "GiB: arguments %.3f aliased %.3f temporaries %.3f" % (
+        memory.argument_size_in_bytes / gib, memory.alias_size_in_bytes / gib,
+        memory.temp_size_in_bytes / gib))
+    assert memory.alias_size_in_bytes >= aliased
+    assert arguments[0] < memory.argument_size_in_bytes / gib < arguments[1]
+    assert memory.temp_size_in_bytes < temporaries * gib
+    text = compiled.as_text()
+    for kernel, calls in kernels.items():
+        assert len(re.findall(r"%%%s(\.\d+)? = " % kernel, text)) == calls
+    assert not re.search(r"= f32\[6,64,128,8192\]\S* copy\(", text)
+    assert not re.search(r"= bf16\[2,(1,)?12289,16,1024\]\S* copy\(", text)
+    assert not re.search(r"= bf16\[(2,)?20,4096,1280\]\S* copy\(", text)
+    # the shared expert goes by its width, 1280, which is the routed
+    # experts' too: the names match the shared expert's operations (its
+    # [2, 4096, 1280] / [2, 1280, 4096] stacks, [rows, 1280] activations),
+    # none that touches the 20 held experts' stacks, and the Kanana
+    # family's width (1536, merged into these names too) matches nothing
+    named = _lines_matching(text, _names_file_patterns("solar_open2"))
+    print("\n".join(line[:200] for line in named))
+    assert named and not [line for line in named
+                          if re.search(r"\[(2,)?20,", line)]
+    assert not _lines_matching(text, _names_file_patterns("mla_shared_moe"))
+
+
+def test_the_channel_decay_cells_weights_are_drawn_a_period_at_a_time(
+        topo, no_persistent_cache):
+    """The family's `init_weights` at the cell's size: the router's bias is
+    balanced on a sample passed through the float32 reference AS the layers
+    are drawn, a period a scan iteration, so what is live beside the 7.26
+    GiB of weights is one layer's draw and conversion (2.24 GiB) and not
+    every layer's slice of the finished tree (4.59 GiB in the first form,
+    under which a run of the cell peaked at 15.83 GB of the chip's 16.9:
+    chip, PR 52)."""
+    from benchmark import common
+
+    cell = common.load_cell("solar-open2-250b.serve-mixedlen")
+    spec = cell["config"]
+    family = common.family(spec)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32,
+                               sharding=SingleDeviceSharding(topo.devices[0]))
+    memory = jax.jit(lambda k: family.init_weights(spec, k)).lower(
+        key).compile().memory_analysis()
+    gib = 2 ** 30
+    print("init_weights GiB: outputs %.3f temporaries %.3f" % (
+        memory.output_size_in_bytes / gib, memory.temp_size_in_bytes / gib))
+    wanted = spec["memory_analysis"][cell["name"]]["init_weights"]
+    assert abs(memory.output_size_in_bytes / gib - wanted["outputs"]) < 0.01
+    assert memory.temp_size_in_bytes / gib < wanted["temporaries"] + 0.25
