@@ -432,9 +432,10 @@ class EngineConfig:
     # the single device yields quickly and first tokens (which come from
     # the PREFILL program) aren't pinned behind a long decode span —
     # vLLM-style prefill priority without chunking the prefill itself.
-    # Once the prefill backlog drains, spans return to decode_span. At
-    # most two decode programs compile (busy_span and decode_span), and a
-    # prefill program finds at most two busy spans queued ahead of it.
+    # Once the prefill backlog drains, spans return to decode_span. Both
+    # lengths run the SAME decode program, which takes its step count as
+    # an argument (`InferenceEngine._build_decode`), and a prefill program
+    # finds at most two busy spans queued ahead of it.
     # No cell sets it or `adaptive_span` (D4).
     busy_span: int = 4
     adaptive_span: bool = True
@@ -475,6 +476,14 @@ class EngineConfig:
         `max_window_pages` pages, as many as the sequence's tokens need and
         the ring's width at the most (the "swa" kind)."""
         return -(-self.max_seq_len // self.page_size)
+
+    @property
+    def span_rows(self) -> int:
+        """Rows of a decode span's readback, K: the longest span the loop
+        picks. The decode program writes a span of n <= K steps into rows
+        [:n] and leaves the rest zero."""
+        return max(1, self.decode_span,
+                   self.busy_span if self.adaptive_span else 0)
 
     def prefill_tiers(self) -> List[int]:
         """Compiled padded-batch sizes: {1, K, 2K, 4K, ...} capped at
@@ -708,7 +717,7 @@ class _Span:
 
     def __init__(self, steps: int, members: Dict[int, Request],
                  prefill_tokens: int):
-        self.seq = self.logps = None  # [steps, B], on the device
+        self.seq = self.logps = None  # [K, B], on the device: rows [:steps]
         self.steps = steps
         # slot index -> the request the slot held at dispatch: the span
         # commits to these and to nobody who took a slot since
@@ -724,6 +733,31 @@ class _Span:
         """What the span's `engine.dispatch` region carries."""
         return {"live": len(self.members), "steps": self.steps,
                 "prefill_tokens": self.prefill_tokens}
+
+
+@functools.lru_cache(maxsize=None)
+def _span_count(n: int):
+    """A span's step count as the decode program takes it: a scalar that
+    lies on the device already, made once a length, so that a dispatch
+    places nothing for it."""
+    return jnp.int32(n)
+
+
+class _SpanOf:
+    """A decode program with a span's step count bound: what
+    `InferenceEngine._build_decode()(n, advanced)` hands out. Called (or
+    lowered) with the program's arguments but `n`; `__wrapped__` is the
+    jitted program itself, the same object whatever `n`."""
+
+    def __init__(self, call, jitted, n: int):
+        self._call, self.__wrapped__, self._n = call, jitted, n
+
+    def __call__(self, *args):
+        return self._call(*args, n=_span_count(self._n))
+
+    def lower(self, *args):
+        return self.__wrapped__.lower(
+            *args, n=jax.ShapeDtypeStruct((), jnp.int32))
 
 
 class PrefixCache:
@@ -1302,17 +1336,84 @@ class InferenceEngine:
 
     # ------------------------------------------------------------- compiled
 
-    def _build_decode(self):
-        """Jit a K-step decode: lax.scan over the single-step body with
-        device-side sampling feeding the next step, and the scan's last
-        tokens and positions handed on to the next span on the device. One
-        dispatch + one [K,B] readback per span. Cached per K (decode_span
-        and busy_span)."""
+    @functools.cached_property
+    def _forward(self):
+        """One decode step's layers and head, a jit of its own inside the
+        decode programs: (params, tokens [B], positions [B], page tables,
+        the window tables or None, (k_pages, v_pages), state) -> (logits
+        [B, V], k_pages, v_pages, state). jax keeps an inner jit's trace by
+        its arguments' shapes, whoever calls it, so the two decode programs
+        (`_build_decode`: one a sampler) trace the layers and their Pallas
+        kernels in Python ONCE between them; XLA inlines the call."""
         cfg, ps = self.cfg, self.ecfg.page_size
+
+        def decode_forward(params, tokens, positions, page_tables,
+                           window_tables, pools, state):
+            # tp>1: the paged kernel runs inside shard_map over the tp
+            # axis (the mode hands it the mesh), not by XLA's fallback
+            x, k_pages, v_pages, state = stack.run_paged(
+                params, tokens[:, None], cfg,
+                stack.Decode(cfg, positions, page_tables, ps, self.mesh,
+                             window_tables),
+                pools, state)
+            with jax.named_scope("lm_head"):
+                logits = _head_logits(x, lambda x: x[:, 0], params, cfg,
+                                      "bd,dv->bv")
+            return logits, k_pages, v_pages, state
+
+        return jax.jit(decode_forward)
+
+    def _decode_step(self, params, page_tables, window_tables, sample):
+        """-> step(carry, i): one decode step of the batch as a loop body.
+        `carry` is (tokens [B], positions [B], k_pages, v_pages, state);
+        the step runs the layers over the tokens' pages (`_forward`), draws
+        the next tokens with `sample(logits [B, V], i)` and returns the
+        carry one position on and (tokens, their log-probabilities under
+        the RAW distribution)."""
+
+        def step(carry, i):
+            tokens, positions, k_pages, v_pages, state = carry
+            logits, k_pages, v_pages, state = self._forward(
+                params, tokens, positions, page_tables, window_tables,
+                (k_pages, v_pages), state)
+            with jax.named_scope("sample"):
+                toks = sample(logits, i)
+                # logprob of the sampled token under the RAW distribution
+                # (negligible next to the lm_head matmul, so it is
+                # computed unconditionally)
+                logps = jnp.take_along_axis(
+                    jax.nn.log_softmax(logits, axis=-1),
+                    toks[:, None].astype(jnp.int32), axis=-1)[:, 0]
+            return (toks, positions + 1, k_pages, v_pages, state), (
+                toks, logps)
+
+        return step
+
+    def _build_decode(self):
+        """Jit the decode programs of this engine, one a sampler: a loop of
+        `n` steps over the single-step body (`_decode_step`) with
+        device-side sampling feeding the next step, and the loop's last
+        tokens and positions handed on to the next span on the device. One
+        dispatch + one [K, B] readback per span. `n`, the span the loop
+        chose (`decode_span`, or `busy_span` under prefill pressure), is an
+        ARGUMENT: a length is no program. Until PR 53 two lengths x two
+        samplers were four programs, each traced, lowered and loaded at a
+        replica's start (6 to 8 s each in a stack of unlike layers); now the
+        layers are traced once (`_forward`) and lowered once a sampler.
+        The sampler stays a program of its own (`advanced`, the host's
+        choice a batch): chosen on the device instead, by a conditional in
+        the step or by a second loop beside the first, it cost the plain
+        sampler's steps 0.10 to 0.21 ms in the Mixtral and granite cells
+        (chip, PR 53: the compiler gave the loop it shares a program with
+        less of the fast memory), and a token is paid for at every step, a
+        start once.
+        -> for_span(n, advanced) -> that sampler's program with `n` bound
+        (`_SpanOf`)."""
+        cfg, K = self.cfg, self.ecfg.span_rows
 
         def decode_span(params, k_pages, v_pages, tokens, positions,
                         page_tables, temps, top_ps, top_ks, key, state=None,
-                        carry=None, *, n_steps, advanced):
+                        carry=None, *, n, advanced):
             """tokens/positions [B]; page_tables [B, pages_per_seq] (where
             the window layers' pages are allocated, `cfg.window_paged`: a
             pair, that and the slots' rings [B, ring]); `state`:
@@ -1320,15 +1421,17 @@ class InferenceEngine:
             empty tree); `carry`: (tokens, positions, fresh), the [B] pair
             the span before ended on and a [B] mask of the slots that take
             the host's `tokens` / `positions` instead (new since that span;
-            None: all of them). -> seq/logps [n_steps, B], the pool, the
-            state, and the (tokens, positions) this span ended on. What
+            None: all of them); `n`: the span's steps, an int32 scalar,
+            1 <= n <= K. -> seq/logps [K, B] of which rows [:n] are the
+            span's (the rest zero), the pool, the state, and the (tokens,
+            positions) this span ended on. What
             the device counts comes back in the readback the tokens come
-            back in, a row of logps more for each: where the live tokens'
-            choices of experts are counted (`cfg.counts_choices`), a row
-            whose first two entries are the span's counts; then, where the
-            steps visit the experts their live rows chose
-            (`self._steps_visit`), a row whose first entry is how many
-            they visited, over the span's steps and layers."""
+            back in, a row of logps more for each, behind row K: where the
+            live tokens' choices of experts are counted
+            (`cfg.counts_choices`), a row whose first two entries are the
+            span's counts; then, where the steps visit the experts their
+            live rows chose (`self._steps_visit`), a row whose first entry
+            is how many they visited, over the span's steps and layers."""
             window_tables = None
             if cfg.window_paged:
                 page_tables, window_tables = page_tables
@@ -1337,49 +1440,33 @@ class InferenceEngine:
                 tokens = jnp.where(fresh, tokens, carried_tokens)
                 positions = jnp.where(fresh, positions, carried_positions)
 
-            def step(carry, i):
-                tokens, positions, k_pages, v_pages, state = carry
-                # tp>1: the paged kernel runs inside shard_map over the tp
-                # axis (the mode hands it the mesh), not by XLA's fallback
-                x, k_pages, v_pages, state = stack.run_paged(
-                    params, tokens[:, None], cfg,
-                    stack.Decode(cfg, positions, page_tables, ps, self.mesh,
-                                 window_tables),
-                    (k_pages, v_pages), state)
-                with jax.named_scope("lm_head"):
-                    logits = _head_logits(x, lambda x: x[:, 0], params, cfg,
-                                          "bd,dv->bv")
-                with jax.named_scope("sample"):
-                    ki = jax.random.fold_in(key, i)
-                    if advanced:
-                        toks = _device_sample_topk_topp(logits, temps, top_ps,
-                                                        top_ks, ki)
-                    else:
-                        # per-slot sampling: temp<=0 -> greedy
-                        greedy = jnp.argmax(logits, axis=-1)
-                        scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-                        sampled = jax.random.categorical(ki, scaled, axis=-1)
-                        toks = jnp.where(temps > 0, sampled,
-                                         greedy).astype(jnp.int32)
-                    # logprob of the sampled token under the RAW distribution
-                    # (negligible next to the lm_head matmul, so it is
-                    # computed unconditionally rather than doubling the
-                    # program cache)
-                    logps = jnp.take_along_axis(
-                        jax.nn.log_softmax(logits, axis=-1),
-                        toks[:, None].astype(jnp.int32), axis=-1)[:, 0]
-                return (toks, positions + 1, k_pages, v_pages, state), (
-                    toks, logps)
+            def sample(logits, i):
+                ki = jax.random.fold_in(key, i)
+                if advanced:
+                    return _device_sample_topk_topp(logits, temps, top_ps,
+                                                    top_ks, ki)
+                return _sample_plain(logits, temps, ki)
+
+            step = self._decode_step(params, page_tables, window_tables,
+                                     sample)
+
+            def write(i, loop):
+                *at, seq, logps = loop
+                at, (toks, step_logps) = step(tuple(at), i)
+                return (*at, seq.at[i].set(toks), logps.at[i].set(step_logps))
 
             state = state or {}
             if cfg.counts_choices:
                 state = {**state, "choices": jnp.zeros((2,), jnp.float32)}
             if self._steps_visit:
                 state = {**state, "touched": jnp.zeros((1,), jnp.float32)}
-            (tokens, positions, k_pages, v_pages, state), (seq, logps) = \
-                jax.lax.scan(
-                    step, (tokens, positions, k_pages, v_pages, state),
-                    jnp.arange(n_steps))
+            B = tokens.shape[0]
+            tokens, positions, k_pages, v_pages, state, seq, logps = \
+                jax.lax.fori_loop(
+                    0, n, write,
+                    (tokens, positions, k_pages, v_pages, state,
+                     jnp.zeros((K, B), jnp.int32),
+                     jnp.zeros((K, B), jnp.float32)))
             for name in ("choices", "touched"):
                 if name in state:
                     state = dict(state)
@@ -1389,7 +1476,6 @@ class InferenceEngine:
                     logps = jnp.concatenate([logps, row])
             return seq, logps, k_pages, v_pages, state, (tokens, positions)
 
-        cache: Dict[Any, Any] = {}
         pinned: Dict[str, Any] = {}
         if self.mesh is not None:
             # the carry comes back as it goes in, replicated: left to the
@@ -1397,22 +1483,22 @@ class InferenceEngine:
             # program at the next call, compiled inside traffic
             whole = NamedSharding(self.mesh, PartitionSpec())
             pinned["out_shardings"] = (None,) * 5 + ((whole, whole),)
+        # `advanced` compiles the top-k/top-p sampler (one vocab sort per
+        # step) as a SEPARATE program: default-sampling batches never pay
+        # for it
+        jitted = {advanced: jax.jit(
+            tracing.named(functools.partial(decode_span, advanced=advanced),
+                          "decode_span" + ("_adv" if advanced else "")),
+            donate_argnums=(1, 2, 10), **pinned) for advanced in (False, True)}
+        calls = {advanced: self._under_mesh(program)
+                 for advanced, program in jitted.items()}
 
         def for_span(n_steps: int, advanced: bool = False):
-            # `advanced` compiles the top-k/top-p sampler (one vocab sort
-            # per step) as a SEPARATE program: default-sampling batches
-            # never pay for it
-            key_ = (n_steps, advanced)
-            if key_ not in cache:
-                cache[key_] = self._under_mesh(jax.jit(
-                    tracing.named(
-                        functools.partial(decode_span, n_steps=n_steps,
-                                          advanced=advanced),
-                        f"decode_span_{n_steps}"
-                        + ("_adv" if advanced else "")),
-                    donate_argnums=(1, 2, 10), **pinned,
-                ))
-            return cache[key_]
+            if not 1 <= n_steps <= K:
+                raise ValueError(
+                    f"a span of {n_steps} steps: the decode programs hold "
+                    f"spans of 1 to {K} (decode_span / busy_span)")
+            return _SpanOf(calls[advanced], jitted[advanced], n_steps)
 
         return for_span
 
@@ -1527,8 +1613,9 @@ class InferenceEngine:
 
     def warmup(self, buckets=None, batch_sizes=None) -> None:
         """Compile the serving-path programs off the request path: prefill
-        per (bucket, padded-batch) and EVERY decode span the adaptive
-        policy can pick. Call before admitting traffic (the decode thread
+        per (bucket, padded-batch) and the decode program of each sampler,
+        which runs every span the adaptive policy can pick. Call before
+        admitting traffic (the decode thread
         must be idle: warmup threads the donated KV pages through the
         compiled call exactly like step() does).
 
@@ -1555,31 +1642,29 @@ class InferenceEngine:
                     ))
         B = self.ecfg.max_batch_size
         pps = self.ecfg.pages_per_seq
-        spans = {max(1, self.ecfg.decode_span)}
-        if self.ecfg.adaptive_span:
-            spans.add(max(1, self.ecfg.busy_span))
-        for span in sorted(spans):
-            # decode spans take the resident pool (rebound through
-            # _run_decode: the warmup call consumes and replaces it).
-            # Both sampler modes compile: the first top-p/top-k request
-            # must not jit inside the decode loop under live traffic.
-            for advanced in (False, True):
-                with tracing.region("engine.warmup.program", steps=span,
-                                    program=f"decode_span_{span}"
-                                    + ("_adv" if advanced else "")):
-                    seq = self._run_decode(self._decode(span, advanced)(
-                        self.params, self.k_pages, self.v_pages,
-                        jnp.zeros((B,), jnp.int32),
-                        jnp.zeros((B,), jnp.int32),
-                        self._tables(jnp.zeros((B, pps), jnp.int32),
-                                     jnp.zeros((B, self._ring), jnp.int32)),
-                        jnp.zeros((B,), jnp.float32),
-                        jnp.ones((B,), jnp.float32),
-                        jnp.zeros((B,), jnp.int32),
-                        jax.random.PRNGKey(0), self.state,
-                        (*self._carry, jnp.ones((B,), bool)),
-                    ))[0]
-                    np.asarray(seq)  # block until compiled + executed
+        K = self.ecfg.span_rows
+        # The decode programs, at their longest span: they take the resident
+        # pool (rebound through _run_decode: the warmup call consumes and
+        # replaces it). Another span is another value of an argument. Both
+        # samplers compile: the first top-p/top-k request must not jit
+        # inside the decode loop under live traffic.
+        for advanced in (False, True):
+            with tracing.region("engine.warmup.program", steps=K,
+                                program="decode_span"
+                                + ("_adv" if advanced else "")):
+                seq = self._run_decode(self._decode(K, advanced)(
+                    self.params, self.k_pages, self.v_pages,
+                    jnp.zeros((B,), jnp.int32),
+                    jnp.zeros((B,), jnp.int32),
+                    self._tables(jnp.zeros((B, pps), jnp.int32),
+                                 jnp.zeros((B, self._ring), jnp.int32)),
+                    jnp.zeros((B,), jnp.float32),
+                    jnp.ones((B,), jnp.float32),
+                    jnp.zeros((B,), jnp.int32),
+                    jax.random.PRNGKey(0), self.state,
+                    (*self._carry, jnp.ones((B,), bool)),
+                ))[0]
+                np.asarray(seq)  # block until compiled + executed
         if self.ecfg.chunked_prefill:
             # both chunk programs the queue picks from (`_advance_chunk`)
             for C in filter(None, (self.ecfg.prefill_chunk, self._wide)):
@@ -3135,9 +3220,9 @@ class InferenceEngine:
     def step(self) -> bool:
         """One engine iteration: advance the chunk queue (`_advance_chunks`:
         a chunk a waiting prompt, at most `busy_span`), install finished
-        prefills, then dispatch a K-step decode span for the
-        active batch (K = decode_span, or busy_span under prefill pressure
-        — at most two decode programs ever compile) and only then read
+        prefills, then dispatch an n-step decode span for the
+        active batch (n = decode_span, or busy_span under prefill pressure:
+        an argument of the decode program) and only then read
         back and commit the span that the iteration BEFORE dispatched.
         The loop runs one span ahead: span N+1 starts from the `tokens` and
         `positions` span N's scan ended on, which never leave the device,
@@ -3286,8 +3371,9 @@ class InferenceEngine:
         """Read a dispatched span back and commit it to the requests it
         went out with; then the pages that rode it are free."""
         with self.phase("readback") as ph:
-            seq = np.asarray(span.seq)  # [steps, B] — one readback per span
-            logps = np.asarray(span.logps)  # [steps, B]
+            # one readback per span: [K, B], rows [:steps] the span's
+            seq = np.asarray(span.seq)
+            logps = np.asarray(span.logps)  # and the counts' behind row K
         self._span_read(span, ph)
         _step_phase["sample", "plain"].observe(ph.elapsed_s)
         with self.phase("commit") as ph:
@@ -3295,8 +3381,9 @@ class InferenceEngine:
             self._count_slot_steps(n, span.steps)
             # by keyword, and only where there are any: callers that wrap
             # this method know its four positional arguments; the counts'
-            # rows follow the steps' in the order the program appends them
-            counted, row = {}, span.steps
+            # rows follow the K of the steps in the order the program
+            # appends them
+            counted, row = {}, self.ecfg.span_rows
             if self.cfg.counts_choices:
                 counted["choices"], row = logps[row, :2], row + 1
             if self._steps_visit:
@@ -4009,6 +4096,14 @@ def _device_sample_topk_topp(logits, temps, top_ps, top_ks, key):
     masked = jnp.where(keep, sorted_logits, -jnp.inf)
     choice = jax.random.categorical(key, masked, axis=-1)      # sorted index
     sampled = jnp.take_along_axis(order, choice[:, None], axis=-1)[:, 0]
+    return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+
+
+def _sample_plain(logits, temps, key):
+    """Per-row temperature sampling on device, no cut: temp<=0 is greedy."""
+    greedy = jnp.argmax(logits, axis=-1)
+    scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+    sampled = jax.random.categorical(key, scaled, axis=-1)
     return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
 
 

@@ -518,12 +518,15 @@ def test_refusals_name_the_family(model):
 # tests' own setting), jax as pinned below: "all held, none zero" must stay
 # the program it was. Re-pinned by PR 42, whose decode step visits the experts
 # its live rows chose in every family (tests/test_moe_step.py): the share
-# layer's fields still change nothing for a model that holds every expert
+# layer's fields still change nothing for a model that holds every expert.
+# Re-pinned by PR 53: the span's steps are an argument of the program (4 are
+# asked here) and the layers an inner jit; tests/test_smallthinker_model.py
+# pins the same two
 PARENT_DECODE = {
     "tiny-moe":
-        "d4c2bc126bde13089c5e43d26cea9782eb226285dd0bff5b81c5aeed04b7bd89",
+        "f0880d7635d59adcdb4f2808d0f76d6fe7bd58545d6cfe5a2d42a6901c26aa89",
     "tiny-lfm2":
-        "409cdb5fa8379b59546b1ade251883ef3247cb6064f13af31f37d3ec50bc09e6",
+        "81f95d4cf89d01dd776458be381de23baefc0dcf4f347f04ffae631cddfc8d6b",
 }
 LOWERED_WITH_JAX = "0.9.0"
 

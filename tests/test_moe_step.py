@@ -379,10 +379,12 @@ PARENT_PROGRAMS = {
 # newest family's is pinned here, this tree's own, so that a later change to
 # it is one that is meant. (Re-pinned by PR 46 for its table of the window
 # page space alone: the ring is 12 pages, the window's and the wide chunk's,
-# where it was 8; with `_wide_chunk` held to 0 both digests are the old ones)
+# where it was 8; with `_wide_chunk` held to 0 both digests are the old ones.
+# Re-pinned by PR 53 with every decode program: the span's steps are an
+# argument and the layers an inner jit)
 DECODE_PROGRAMS = {
     "tiny-smallthinker":
-        "00d723895cdc24068fa1e713166c99cf74992e0883fe220a1976cff4c6031f3f",
+        "0fdf84add6bc9b97ee0122983b552421a63ef37bc24a834fef9cc3fcfe12e0cd",
 }
 # the chunk and bucket programs of the families whose experts drop nothing
 # DID change in PR 43 (each expert over the rows that chose it; at the tiny

@@ -82,8 +82,10 @@ def _verify_args(eng, span=4):
 
 def test_engine_programs_have_module_names_and_scopes(engine):
     ffn = "moe" if engine.cfg.is_moe else "ffn"
-    for span, adv, want in ((4, False, "jit_decode_span_4"),
-                            (16, True, "jit_decode_span_16_adv")):
+    # a module a sampler, whatever the span: its length is an argument
+    for span, adv, want in ((4, False, "jit_decode_span"),
+                            (engine.ecfg.span_rows, True,
+                             "jit_decode_span_adv")):
         low = engine._decode(span, adv).lower(*_decode_args(engine))
         assert _module(low) == want
     text = low.as_text(debug_info=True)

@@ -572,9 +572,11 @@ class TestEngine:
         plain = InferenceEngine(params, cfg, ecfg)
         try:
             sharded.warmup()
-            programs = [sharded._decode(span, advanced).__wrapped__
-                        for span in (4, 2) for advanced in (False, True)]
-            assert [p._cache_size() for p in programs] == [1, 1, 1, 1]
+            # a program a sampler, whatever the span (PR 53), and warmup
+            # compiled both
+            programs = {sharded._decode(span, advanced).__wrapped__
+                        for span in (4, 2) for advanced in (False, True)}
+            assert [p._cache_size() for p in programs] == [1, 1]
             whole = sharded._carry[0].sharding
             assert whole.is_fully_replicated
             ahead = ahead_steps()
@@ -594,7 +596,22 @@ class TestEngine:
                 want = plain.generate(prompts[i], max_tokens=24)
                 assert results[i]["token_ids"] == want["token_ids"], i
             assert ahead_steps() > ahead  # spans did start from a carry
-            assert [p._cache_size() for p in programs] == [1, 1, 1, 1]
+            assert [p._cache_size() for p in programs] == [1, 1]
+            # spans of 4, 2 and 4 by hand (another value of an argument),
+            # then the arrival of a top-p request (the other program)
+            for span in (4, 2, 4):
+                sharded._run_decode(sharded._decode(span)(
+                    sharded.params, sharded.k_pages, sharded.v_pages,
+                    *(jnp.zeros((2,), jnp.int32),) * 2,
+                    jnp.zeros((2, ecfg.pages_per_seq), jnp.int32),
+                    jnp.zeros((2,), jnp.float32), jnp.ones((2,), jnp.float32),
+                    jnp.zeros((2,), jnp.int32), jax.random.PRNGKey(1),
+                    sharded.state,
+                    (*sharded._carry, jnp.ones((2,), bool))))
+            sampled = sharded.generate(prompts[0], max_tokens=9,
+                                       temperature=0.8, top_p=0.9)
+            assert len(sampled["token_ids"]) == 9
+            assert [p._cache_size() for p in programs] == [1, 1]
             assert all(c.sharding == whole for c in sharded._carry)
         finally:
             sharded.stop(), plain.stop()

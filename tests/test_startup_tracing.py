@@ -15,13 +15,14 @@ from ray_tpu.util import tracing
 
 ENGINE = dict(page_size=4, prefill_buckets=(8, 16), prefill_chunk=16,
               max_batch_size=4, max_pages=64)
-# what `warmup` compiles for tiny-lfm2 under ENGINE: both spans x both
-# samplers, both chunk programs (its experts run as groups: `_wide`), and
-# the program that hands a slot its conv state, and the one that hands a
-# slot's row of the loop's carry a first token from the device
-PROGRAMS = {"decode_span_4", "decode_span_4_adv", "decode_span_8",
-            "decode_span_8_adv", "chunk_prefill_16", "chunk_prefill_32",
-            "join_carry", "install_state"}
+# what `warmup` compiles for tiny-lfm2 under ENGINE: the decode program of
+# each sampler (a span's length is their argument since PR 53, where two
+# lengths x two samplers were four programs), both chunk programs (its
+# experts run as groups: `_wide`), and the program that hands a slot its conv
+# state, and the one that hands a slot's row of the loop's carry a first
+# token from the device
+PROGRAMS = {"decode_span", "decode_span_adv", "chunk_prefill_16",
+            "chunk_prefill_32", "join_carry", "install_state"}
 PHASES = {"params": "replica.start.params", "engine": "replica.start.engine",
           "warmup": "engine.warmup"}
 
@@ -97,7 +98,8 @@ def test_every_program_warmup_compiled_is_a_region_that_names_it(started):
     assert {r["attrs"]["program"] for r in regions} == PROGRAMS
     assert len(regions) == len(PROGRAMS)
     by_program = {r["attrs"]["program"]: r for r in regions}
-    assert by_program["decode_span_8_adv"]["attrs"]["steps"] == 8
+    # warmed at K, the longest span the loop picks (`EngineConfig.span_rows`)
+    assert by_program["decode_span_adv"]["attrs"]["steps"] == 8
     assert by_program["chunk_prefill_32"]["attrs"]["rows"] == 32
     # the regions tile the warm-up (what is left is the loop around them)
     assert sum(map(_seconds, regions)) == pytest.approx(_seconds(warmup),
@@ -170,7 +172,9 @@ def test_an_engine_built_bare_has_no_startup_trace_and_files_no_phase(
     programs = [c["attrs"] for c in later["children"]
                 if c["name"] == "engine.warmup.program"]
     assert programs[0] == {"program": "prefill_bucket_8x1", "rows": 8}
-    assert {"program": "decode_span_4", "steps": 4} in programs
+    assert [p for p in programs if p["program"].startswith("decode_span")] \
+        == [{"program": "decode_span", "steps": 8},
+            {"program": "decode_span_adv", "steps": 8}]  # one a sampler
     engine.stop()
 
 

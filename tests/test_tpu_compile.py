@@ -365,7 +365,8 @@ def _engine_programs(one_chip):
     cfg = get_config("llama3-8b", n_layers=LAYERS, vocab_size=32768,
                      max_seq_len=4096, rope_theta=1e6, dtype="bfloat16")
     ecfg = EngineConfig(max_seq_len=4096, max_batch_size=BATCH,
-                        max_pages=POOL_PAGES, prefill_chunk=CHUNK)
+                        max_pages=POOL_PAGES, prefill_chunk=CHUNK,
+                        decode_span=16)
     eng = object.__new__(InferenceEngine)
     eng.cfg, eng.ecfg, eng.mesh, eng._tp = cfg, ecfg, None, 1
     spec = object.__new__(SpecDecoder)
@@ -446,7 +447,8 @@ def test_decode_span_under_tp4_updates_its_shard_of_the_pool_in_place(
     cfg = get_config("llama3-8b", n_layers=LAYERS, vocab_size=32768,
                      max_seq_len=4096, rope_theta=1e6, dtype="bfloat16")
     ecfg = EngineConfig(max_seq_len=4096, max_batch_size=BATCH,
-                        max_pages=POOL_PAGES, prefill_chunk=CHUNK)
+                        max_pages=POOL_PAGES, prefill_chunk=CHUNK,
+                        decode_span=16)
     eng = object.__new__(InferenceEngine)
     eng.cfg, eng.ecfg, eng.mesh, eng._tp = cfg, ecfg, mesh, 4
 
@@ -468,6 +470,7 @@ def test_decode_span_under_tp4_updates_its_shard_of_the_pool_in_place(
             s((BATCH,), jnp.float32), s((BATCH,), I32),
             s((2,), jnp.uint32), None,
             (s((BATCH,), I32), s((BATCH,), I32), s((BATCH,), jnp.bool_)),
+            n=s((), I32),  # the span's steps: an argument since PR 53
         ).compile()
     # the carry the next span starts from comes out whole on every device,
     # as it went in: the same program again, whatever the partitioner likes
@@ -888,6 +891,63 @@ def test_the_state_space_cells_programs_hold_their_state_in_place(
         assert len(re.findall(r"%%%s(\.\d+)? = " % kernel, text)) == calls
     assert not re.search(r"= f32\[36,64,128,4096\]\S* copy\(", text)
     assert not re.search(r"= bf16\[4,(1,)?8193,16,512\]\S* copy\(", text)
+
+
+@pytest.mark.parametrize("sampler", ["plain", "sort"])
+def test_a_span_to_a_traced_bound_holds_what_the_static_scan_held(
+        sampler, topo, no_persistent_cache):
+    """A decode program since PR 53 (the span's steps an argument: a loop to
+    a traced bound into rows of a [K, B] pair) compiled for a described v5e
+    at a tiny hybrid's sizes, state beside its pages, against the static
+    scan of K steps it replaced (`static_span`, donating as the engine's
+    did), for each sampler: the pool and the state are updated in place in
+    both, and the bound costs no temporaries (my compiles, PR 53: none
+    against 2.2 MB of the static scan's for the plain sampler's program,
+    64.5 MB either way for the sort's), so what fitted beside a pool still
+    fits."""
+    from test_one_decode_program import static_span
+
+    from ray_tpu.models import get_config, stack
+    from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    cfg = get_config("tiny-granite-hybrid", vocab_size=32768)
+    eng = object.__new__(InferenceEngine)
+    eng.cfg, eng.mesh, eng._tp = cfg, None, 1
+    eng.ecfg = ecfg = EngineConfig(max_batch_size=64, page_size=16,
+                                   max_pages=1025, max_seq_len=512)
+    eng._ring = eng._window_ring()
+    B, pps, K = ecfg.max_batch_size, ecfg.pages_per_seq, ecfg.span_rows
+    assert K == 8
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype), jax.eval_shape(
+        lambda k: stack.init_params(cfg, k), jax.random.PRNGKey(0)))
+    pool, state = eng.abstract_pool(one_chip), eng.abstract_state(one_chip)
+    assert set(state) == {"conv", "ssd"}
+    args = (params, pool, pool, s((B,), I32), s((B,), I32), s((B, pps), I32),
+            s((B,), F32), s((B,), F32), s((B,), I32), s((2,), jnp.uint32),
+            state)
+    program = eng._build_decode()(K, sampler == "sort").lower(
+        *args, (s((B,), I32), s((B,), I32), s((B,), jnp.bool_))).compile()
+    memory = program.memory_analysis()
+    held = 2 * pool.size * pool.dtype.itemsize + sum(
+        a.size * a.dtype.itemsize for a in state.values())
+    assert memory.alias_size_in_bytes >= held  # tiled: a little more
+    text = program.as_text()
+    assert " conditional(" not in text
+    assert (" sort(" in text) == (sampler == "sort")
+    static = jax.jit(static_span(eng, K, sampler).__wrapped__,
+                     donate_argnums=(1, 2, 10)).lower(*args).compile()
+    scan = static.memory_analysis()
+    assert scan.alias_size_in_bytes == memory.alias_size_in_bytes
+    print("%s: temporaries, bytes: to a traced bound %d, the static scan %d"
+          % (sampler, memory.temp_size_in_bytes, scan.temp_size_in_bytes))
+    words = B * cfg.vocab_size * 4  # one [B, vocabulary] buffer
+    assert (scan.temp_size_in_bytes > words) == (sampler == "sort")
+    assert memory.temp_size_in_bytes <= scan.temp_size_in_bytes + 0.05 * words
 
 
 # sha256 (12 digits) of each scalar-form delta-rule kernel's Mosaic module,
