@@ -9,8 +9,16 @@ and cross differential attention, gated memory units) and `lfm2-8b-a1b`
 (the `"conv"` kind, a gated short convolution, beside `"attn"` layers with
 normalised queries and keys; two dense layers, then experts routed by
 sigmoid score + bias), each with a tiny twin for the CPU (`tiny-sambay`,
-`tiny-lfm2`). The benchmark reaches them through benchmark/families/
-(`mistral.py`, `sambay.py`, `shortconv_moe.py`).
+`tiny-lfm2`), and eight more families since (models/config.py registers
+them; models/stack.py's header lists the kinds). A stack whose kinds are
+all `"attn"` or `"swa"` (trained: `config.TRAINABLE_KINDS`) goes through
+`forward` / `loss_fn` / `param_axes` / `make_train_step` like the
+one-block models: `trinity-mini` / `tiny-trinity` (window and full gated
+GQA, a norm on both sides of every sublayer, `norm_place="both"` where
+`post_norm` is the older word for `"post"`; sigmoid-routed experts beside
+a shared one). The other kinds are served only, and refused by name. The
+benchmark reaches them through benchmark/families/ (`mistral.py`,
+`sambay.py`, `shortconv_moe.py`, .., `trinity_afmoe.py`).
 """
 
 from .config import (  # noqa: F401

@@ -5,9 +5,13 @@ plus tiny variants for tests. One config class drives all families —
 differences (norm type, activation, positional scheme, GQA, MoE) are fields,
 not subclasses, so the same sharded forward/train/serve path covers every
 family. A model whose layers differ names each layer's mixer in
-`layer_kinds` and says which second halves are dense (a StackConfig; serve
-only). models/stack.py runs both on the
-serve path: a plain ModelConfig is the stack whose every layer is "attn".
+`layer_kinds` and says which second halves are dense (a StackConfig).
+models/stack.py runs both on the serve path: a plain ModelConfig is the
+stack whose every layer is "attn". A StackConfig whose kinds are all in
+`TRAINABLE_KINDS` ("attn" and "swa", with dense or expert second halves)
+trains too, through the same `forward` / `loss_fn` / `param_axes` /
+`make_train_step` as the one-block models; the other kinds' ops have no
+backward yet (`StackConfig.untrainable`).
 
 A window layer's keys are held in one of two ways. The "window" kind
 (differential pairs, one family) keeps them in per-slot state: every decode
@@ -37,6 +41,17 @@ GATED_ACTIVATIONS = ("swiglu", "reglu")
 _LANES = 128
 # the kinds whose attention is differential over pairs of heads
 _DIFFERENTIAL = ("window", "full", "cross")
+# the kinds a stack can be TRAINED with (every op they run has a backward:
+# the flash kernels with and without a window, the grouped expert product);
+# the others wait for a backward through the op named beside them
+TRAINABLE_KINDS = ("attn", "swa")
+_NO_BACKWARD = {"mamba": "ops/ssm.py", "gmu": "ops/ssm.py",
+                "gdn": "ops/gdn.py", "ssd": "ops/ssd.py",
+                "mla": "ops/mla_attention.py", "mla2": "ops/mla_attention.py",
+                "conv": "the short convolution's tail state",
+                "window": "the differential pairs' plain form",
+                "full": "the differential pairs' plain form",
+                "cross": "the differential pairs' plain form"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -104,6 +119,8 @@ class ModelConfig:
     qk_norm_whole = False
     attn_gate = False
     post_norm = False
+    norm_place = "pre"
+    router_bias_rate = 0.0
     router = "softmax"
     n_dense_layers = 0
     conv_tail = (0, 0, 0)
@@ -286,8 +303,14 @@ class StackConfig(ModelConfig):
     qk_norm: bool = False     # "attn": RMSNorm each head of q and k
     qk_norm_whole: bool = False  # ... or all of q's (k's) heads as one
     # False: x + Mix(norm(x)), x + FFN(norm(x)); True: the norm follows the
-    # sublayer, x + norm(Mix(x)), x + norm(FFN(x))
+    # sublayer, x + norm(Mix(x)), x + norm(FFN(x)): the older spelling of
+    # `norm_place="post"`, which it is folded into (and always equals)
     post_norm: bool = False
+    # where a sublayer's norms stand: "pre" (before it), "post" (after it,
+    # in its place) or "both" (a sandwich: x + norm'(Mix(norm(x))), four
+    # norms a layer: `ln1`, `ln1_post`, `ln2`, `ln2_post`). ONE field says
+    # it; the layers read this and never `post_norm`
+    norm_place: str = "pre"
     gdn_heads: int = 0        # "gdn": heads, and each one's state [dk, dv]
     gdn_key_dim: int = 0
     gdn_value_dim: int = 0
@@ -316,6 +339,11 @@ class StackConfig(ModelConfig):
     router: str = "softmax"
     norm_topk: bool = True
     routed_scale: float = 1.0
+    # "sigmoid" / "softmax_all": after the optimizer's update a TRAIN step
+    # moves each expert's bias by this much towards an even load,
+    # b_e += rate * sign(mean(n) - n_e), n_e the step's count of choices of
+    # expert e; the bias takes no gradient (0: the bias stays as it is)
+    router_bias_rate: float = 0.0
     n_routed_experts: int = 0  # routed experts that exist (0: num_experts)
     experts_first: int = 0    # the first routed expert held here
     experts_zero: int = 0     # identity experts after the routed ones
@@ -344,6 +372,14 @@ class StackConfig(ModelConfig):
     def __post_init__(self) -> None:
         kinds = tuple(self.layer_kinds)
         object.__setattr__(self, "layer_kinds", kinds)
+        if self.norm_place not in ("pre", "post", "both"):
+            raise ValueError(f"unknown norm_place {self.norm_place!r}")
+        if self.post_norm and self.norm_place == "both":
+            raise ValueError('`post_norm` is `norm_place="post"`; the model '
+                             'says "both"')
+        if self.post_norm:
+            object.__setattr__(self, "norm_place", "post")
+        object.__setattr__(self, "post_norm", self.norm_place == "post")
         bad = sorted(set(kinds) - set(LAYER_KINDS))
         if bad or len(kinds) != self.n_layers:
             raise ValueError(
@@ -396,7 +432,8 @@ class StackConfig(ModelConfig):
         if self.router_input not in ("ffn", "layer"):
             raise ValueError(f"unknown router_input {self.router_input!r}")
         if self.router_input == "layer" and (
-                "mla2" in kinds or self.post_norm or self.n_dense_layers
+                "mla2" in kinds or self.norm_place != "pre"
+                or self.n_dense_layers
                 or self.d_ff_shared):
             raise ValueError(
                 'router_input="layer" is written for layers of one mixer and '
@@ -440,6 +477,19 @@ class StackConfig(ModelConfig):
                 '"softmax_all" or "sigmoid"')
 
     is_stack = True
+
+    @property
+    def untrainable(self) -> str:
+        """Why this stack cannot be trained ("": it can): its kinds that
+        have no backward yet, each with the op that lacks one."""
+        bad = sorted(set(self.layer_kinds) - set(TRAINABLE_KINDS))
+        if not bad:
+            return ""
+        return (f"{self.name!r}, a stack of unlike layers, cannot be trained "
+                "yet: a stack trains where every layer is one of "
+                f"{TRAINABLE_KINDS} (with a dense or an expert second half); "
+                "there is no backward through "
+                + ", ".join(f"{k!r} ({_NO_BACKWARD[k]})" for k in bad))
 
     @property
     def has_state(self) -> bool:
@@ -600,8 +650,9 @@ class StackConfig(ModelConfig):
                 "moe": E * 3 * D * Fe + D * W
                 + (W if self.router != "softmax" else 0)
                 + 3 * D * self.d_ff_shared}
+        norms = (4 if self.norm_place == "both" else 2) * norm
         return (sum(self._mixer_params(k) for k in self.layer_kinds)
-                + sum(half[h] + 2 * norm for h in self.second_halves)
+                + sum(half[h] + norms for h in self.second_halves)
                 + V * D * (1 if self.tie_embeddings else 2) + norm)
 
 
@@ -954,4 +1005,51 @@ register(StackConfig(
     layer_kinds=("mla",) * 4, n_dense_layers=1, d_ff_expert=32,
     d_ff_shared=64, router="sigmoid", norm_topk=True, routed_scale=2.448,
     kv_lora_rank=16, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
+))
+
+
+def _trinity_kinds(n_layers: int) -> Tuple[str, ...]:
+    """Three layers over a window, then one of full attention."""
+    return tuple("attn" if l % 4 == 3 else "swa" for l in range(n_layers))
+
+
+register(StackConfig(
+    name="trinity-mini",
+    # arcee-ai/Trinity-Mini (`afmoe`): 26 B parameters, about 3 B active a
+    # token: 32 layers of gated GQA (32 heads of 128 over 4 KV heads, queries
+    # and keys normalised per head, the heads' outputs times sigmoid(x W_g)),
+    # three rotary ones over a window of 2048 then one over every key with
+    # NO positions; a norm on BOTH sides of every sublayer; two dense layers
+    # (6144), then 128 experts of 1024, 8 a token by sigmoid score + bias,
+    # renormalised, times 2.826, beside one shared expert; the embedding
+    # times sqrt(2048). The bias moves towards an even load in training
+    vocab_size=200192,
+    d_model=2048, n_layers=32, n_heads=32, n_kv_heads=4, head_dim=128,
+    d_ff=6144, max_seq_len=131072,
+    norm="rmsnorm", activation="swiglu", positional="none",
+    rope_theta=10000.0, tie_embeddings=False, norm_eps=1e-5,
+    num_experts=128, num_selected_experts=8, capacity_factor=128 / 8,
+    router_aux_coef=0.0,
+    layer_kinds=_trinity_kinds(32), window=2048, qk_norm=True,
+    attn_gate=True, norm_place="both", n_dense_layers=2, d_ff_expert=1024,
+    d_ff_shared=1024, router="sigmoid", norm_topk=True, routed_scale=2.826,
+    router_bias_rate=0.001, embedding_multiplier=2048 ** 0.5,
+))
+
+register(StackConfig(
+    name="tiny-trinity",
+    # the same stack's shape at toy widths: two swa / swa / swa / attn
+    # periods over a window of 16, two dense layers, then 8 experts top 2
+    # beside a shared one
+    vocab_size=512,
+    d_model=128, n_layers=8, n_heads=4, n_kv_heads=2, head_dim=32, d_ff=256,
+    max_seq_len=512, dtype="float32", remat=False,
+    norm="rmsnorm", activation="swiglu", positional="none",
+    rope_theta=10000.0, tie_embeddings=False, norm_eps=1e-5,
+    num_experts=8, num_selected_experts=2, capacity_factor=8 / 2,
+    router_aux_coef=0.0,
+    layer_kinds=_trinity_kinds(8), window=16, qk_norm=True,
+    attn_gate=True, norm_place="both", n_dense_layers=2, d_ff_expert=128,
+    d_ff_shared=128, router="sigmoid", norm_topk=True, routed_scale=2.826,
+    router_bias_rate=0.001, embedding_multiplier=128 ** 0.5,
 ))

@@ -1,13 +1,16 @@
 """The layers of every model the serving engine runs, as a stack of layer
 kinds (`cfg.layer_kinds`): every layer is `x + Mix(norm(x))` and then its
 second half, `x + FFN(norm(x))` or the experts (`cfg.second_halves`: a
-stack may lead with dense layers); where `cfg.post_norm` the norms follow
-their sublayers, `x + norm(Mix(x))` and `x + norm(FFN(x))`. Mix is one of
+stack may lead with dense layers); `cfg.norm_place` says where the norms
+stand: "post" (the older `cfg.post_norm`): they follow their sublayers,
+`x + norm(Mix(x))` and `x + norm(FFN(x))`; "both": a sandwich,
+`x + norm'(Mix(norm(x)))` and `x + norm'(FFN(norm(x)))`, four norms a
+layer. Mix is one of
 
   attn    the one-block models' (a plain ModelConfig: every layer): rotary
           or learned-position GQA over the layer's own pages, queries and
           keys normalised per head where `cfg.qk_norm`, the heads' outputs
-          times sigmoid(h W_g) lane by lane where `cfg.attn_gate`
+          times sigmoid(h W_g) lane by lane where `cfg.attn_gate`; trained
   conv    gated short convolution: [B ; C ; x] = h W_in, a causal
           depthwise convolution of B * x over `conv_taps` positions, gated
           by C, then W_out; its tail (the last taps - 1 rows of B * x) per
@@ -21,7 +24,8 @@ their sublayers, `x + norm(Mix(x))` and `x + norm(FFN(x))`. Mix is one of
           no position), each layer its OWN keys, which a sequence holds in
           pages of a second page space that the engine's allocator serves:
           its ring is a table of the pages it was given, as many as its
-          tokens need and window / page_size + a chunk's pages at the most
+          tokens need and window / page_size + a chunk's pages at the most;
+          trained (whole sequences go through the flash kernels' window)
   full    attention over every key; its keys and values are THE cache that
           the cross layers after it read
   gmu     gated memory unit: gates the last mamba layer's scan output
@@ -116,8 +120,16 @@ by side in one row), whose ops take the plain queries and keys of this
 file: the layout is theirs. The "attn" layers of a plain ModelConfig shard
 by the one-block models' rules (a `tp` mesh reaches the paged calls through
 the mode) and train through models/transformer.py's own loop over the same
-projections and second half; a StackConfig, whatever its kinds, is serve
-only: no sharding rules, no training path.
+projections and second half. A StackConfig whose kinds are all "attn" or
+"swa" (`config.TRAINABLE_KINDS`; dense, expert and shared second halves)
+trains through THIS file: `forward` is what `loss_fn` differentiates, the
+plain `Seq` names what the layer loop's checkpoint keeps (`cfg.remat`: the
+attention half, the grouped experts' two up products and their combined
+result), counts every
+expert layer's choices for the train step (`route_counts`, `move_router_bias`)
+and `param_axes` gives its leaves their logical axes. The other kinds' ops
+have no backward yet, and `param_axes` / `make_train_step` refuse them by
+name.
 """
 
 from __future__ import annotations
@@ -126,6 +138,7 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import (
     latent_attention_chunk,
@@ -141,24 +154,29 @@ from ..ops.gdn import gdn_chunk, gdn_step, state_shape
 from ..ops.rope import rope_frequencies
 from ..ops.ssd import ssd_chunk, ssd_step, state_shape as ssd_state_shape
 from ..ops.ssm import ssm_scan, ssm_step
-from ..parallel.sharding import _current_mesh
+from ..parallel.sharding import _current_mesh, split_ways
 from .config import ModelConfig
 from .transformer import (
+    _KEPT_UNDER_REMAT,
     _dense_ffn,
+    _kept_widths,
     _ffn_half,
     _flash,
     _lm_head,
     _moe_ffn,
-    _moe_ffn_dropless_ids,
     _moe_gate,
     _norm,
     _prologue,
     _qkv,
+    _remat,
     moe_ffn_groups,
+    moe_ffn_ids,
     moe_ffn_step,
+    moe_grouped,
     moe_seq_groups,
     moe_step_visits,
 )
+from ..ops.moe import GROUPED_RESIDUAL_NAMES
 
 Params = Dict[str, Any]
 _F32 = jnp.float32
@@ -167,6 +185,13 @@ _COUNTED = ("attn", "conv", "mamba", "window", "full", "gdn", "mla2", "swa",
             "ssd", "mla")
 # an expert layer's leaves that a step reads where they lie (`run_stack`)
 _EXPERT_LEAVES = ("w_in", "w_gate", "w_out")
+# what a period of layers keeps for its backward under `cfg.remat`: the
+# attention half (models/transformer.py), the two up products of the
+# grouped experts (ops/moe.py `grouped_ffn`) and their combined result; the
+# norms, the head gate, the router, the sort, the experts' down product (the
+# router's gradient reads it), the shared expert and a dense FFN are computed
+# again
+KEPT_UNDER_REMAT = _KEPT_UNDER_REMAT + GROUPED_RESIDUAL_NAMES
 
 
 # ---------------------------------------------------------------------------
@@ -182,6 +207,8 @@ def layer_shapes(cfg: ModelConfig, kind: str,
     D, F, H, KVH, hd = cfg.d_model, cfg.d_ff, cfg.n_heads, cfg.kv_heads, cfg.hdim
     Di, N, R, K = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank, cfg.ssm_conv
     out = {"ln1": ((D,), "one"), "ln2": ((D,), "one")}
+    if cfg.norm_place == "both":  # a norm after each sublayer too
+        out.update(ln1_post=((D,), "one"), ln2_post=((D,), "one"))
     if cfg.norm == "layernorm":
         out.update(ln1_b=((D,), "zero"), ln2_b=((D,), "zero"))
     if half == "moe":
@@ -447,24 +474,15 @@ def install_state(state: Params, rs: Params, slot, length,
 
 
 def _dense_attend(q, k, v, scale, window=None):
-    """q [B,T,H,D], k/v [B,T,KVH,D], causal (and windowed). The flash
-    kernel wherever the window cannot bind; else a plain masked softmax."""
+    """q [B,T,H,D], k/v [B,T,KVH,D], causal (and windowed): the flash
+    kernels, whose forward and backward visit the key blocks a window
+    reaches and no others (ops/attention.py)."""
     T = q.shape[1]
-    if window is None or T <= window:
-        # under the kernel's smallest automatic block the sequence is one
-        # block (left to itself flash_attention takes its XLA path there)
-        block = T if T < 128 else None
-        return _flash(q, k, v, scale=scale, block_q=block, block_k=block)
-    B, _, H, D = q.shape
-    KVH = k.shape[2]
-    with jax.named_scope("window_attn_dense"):
-        qf = q.reshape(B, T, KVH, H // KVH, D).astype(_F32)
-        s = jnp.einsum("bqcgd,bkcd->bcgqk", qf, k.astype(_F32)) * scale
-        qp, kp = jnp.arange(T)[:, None], jnp.arange(T)[None, :]
-        s = jnp.where((kp <= qp) & (kp > qp - window), s, -2e30)
-        o = jnp.einsum("bcgqk,bkcd->bqcgd", jax.nn.softmax(s, axis=-1),
-                       v.astype(_F32))
-        return o.reshape(B, T, H, D).astype(q.dtype)
+    # under the kernel's smallest automatic block the sequence is one
+    # block (left to itself flash_attention takes its XLA path there)
+    block = T if T < 128 else None
+    bound = {} if window is None or T <= window else {"window": window}
+    return _flash(q, k, v, scale=scale, block_q=block, block_k=block, **bound)
 
 
 class _Mode:
@@ -482,6 +500,8 @@ class _Mode:
             self.rope = rope_frequencies(
                 self.cfg.hdim, self.cfg.max_seq_len, self.cfg.rope_theta)
         return x
+
+    plain = False  # only the whole-sequence forward is (`Seq.plain`)
 
     def live_rows(self, T):
         """bool [B]: which rows of a STEP (T = 1) hold a sequence, where
@@ -515,8 +535,9 @@ class Seq(_Mode):
     def __init__(self, cfg: ModelConfig, n_valid=None, keep: bool = False,
                  chunk=None, page_size: int = 0, mesh=None,
                  export: bool = False, window_table=None,
-                 attend_rows: int = 0):
+                 attend_rows: int = 0, route_counts: bool = False):
         self.cfg, self.n_valid, self.keep, self.chunk = cfg, n_valid, keep, chunk
+        self.route_counts = route_counts
         self.ps, self.mesh, self.export = page_size, mesh, export
         self.window_table = window_table
         self.attend_rows = attend_rows
@@ -526,10 +547,19 @@ class Seq(_Mode):
         return None if self.chunk is None else (
             self.chunk[0] + jnp.arange(T))[None]
 
+    @property
+    def plain(self) -> bool:
+        """The plain forward (no cache, no state): what a train step
+        differentiates, so what names the values a checkpoint keeps."""
+        return self.chunk is None and not self.keep
+
     def init_carry(self, x, pools=None, state=None) -> Params:
         cfg = self.cfg
         B, T, _ = x.shape
         carry, dtype = {}, x.dtype
+        if self.route_counts:  # every expert layer's choices, by expert
+            carry["route_counts"] = jnp.zeros(
+                (cfg.second_halves.count("moe"), cfg.router_width), jnp.int32)
         if cfg.count("mamba"):
             carry["mem"] = jnp.zeros((B, T, cfg.ssm_inner), x.dtype)
         if self.chunk is not None:
@@ -1226,6 +1256,9 @@ def _attn(h, lp, cfg, idx, mode, carry, window=False):
     the "swa" kind, rotary whatever the model says of its other layers)
     the last `cfg.window` in the window page space."""
     q, k, v = _qkv(h, lp, cfg, mode.rope, mode.at, True if window else None)
+    if mode.plain:  # what the layer loop's checkpoint keeps (`run_stack`)
+        q, k, v = (checkpoint_name(a, n) for a, n in (
+            (q, "attn_q"), (k, "attn_k"), (v, "attn_v")))
     attend = mode.attend_paged_window if window else mode.attend_full
     scale = (cfg.hdim ** -0.5 if cfg.attention_multiplier is None
              else cfg.attention_multiplier)
@@ -1235,6 +1268,19 @@ def _attn(h, lp, cfg, idx, mode, carry, window=False):
         o = o.astype(_F32) * jax.nn.sigmoid(gate.astype(_F32))
     return jnp.einsum("bthk,hkd->btd", o.astype(h.dtype),
                       lp["wo"].astype(h.dtype)), carry
+
+
+def _count_routes(carry, ids, cfg, lp):
+    """How many of the tokens' choices ids [B,T,k] fell on each of the
+    router's outputs, written to this expert layer's row of `route_counts`
+    where the carry holds it (a train step's forward: `lp["moe_index"]`,
+    the layer's place among the expert layers)."""
+    if "route_counts" not in carry:
+        return carry
+    n = jnp.sum(jax.nn.one_hot(ids, cfg.router_width, dtype=jnp.int32),
+                axis=(0, 1, 2))
+    return {**carry, "route_counts": jax.lax.dynamic_update_index_in_dim(
+        carry["route_counts"], n, lp["moe_index"], 0)}
 
 
 def _count_touched(carry, visited):
@@ -1261,11 +1307,13 @@ def _experts(h, lp, cfg, gate, mode, carry):
     elif "experts" in lp:
         y, ids = moe_ffn_groups(h, lp, cfg, gate,
                                 mode.kept_rows(*h.shape[:2]))
-    elif cfg.counts_choices:  # a share layer: where the choices fell
-        y, _, ids = _moe_ffn_dropless_ids(h, lp, cfg, gate)
+    elif cfg.counts_choices or "route_counts" in carry:
+        # a share layer (where the choices fell), a train step (how many
+        # chose each expert): the form `moe_grouped` picks
+        y, _, ids = moe_ffn_ids(h, lp, cfg, gate)
     else:
         return _moe_ffn(h, lp, cfg, gate)[0], carry
-    return y, _count_choices(carry, ids, cfg, mode)
+    return y, _count_choices(_count_routes(carry, ids, cfg, lp), ids, cfg, mode)
 
 
 def _layer(x, lp, cfg, kind, half, layer, idx, mode, carry):
@@ -1279,8 +1327,9 @@ def _layer(x, lp, cfg, kind, half, layer, idx, mode, carry):
             gate = _moe_gate(x, lp, cfg)
     # the scopes are what a profile's readers key on: the mixer's kind
     # ("attn" as in the training block), then "ffn" or "moe"
+    place = cfg.norm_place
     with jax.named_scope(kind):
-        h = x if cfg.post_norm else _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
+        h = x if place == "post" else _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
         if kind in ("attn", "swa"):
             o, carry = _attn(h, lp, cfg, idx, mode, carry, kind == "swa")
         elif kind == "mla":
@@ -1297,12 +1346,18 @@ def _layer(x, lp, cfg, kind, half, layer, idx, mode, carry):
             o = _gmu(h, lp, cfg, carry)
         else:
             o, carry = _attention(h, lp, cfg, kind, layer, idx, mode, carry)
-        if cfg.post_norm:
+        if place == "post":
             o = _norm(o, lp["ln1"], lp.get("ln1_b"), cfg)
+        elif place == "both":
+            o = _norm(o, lp["ln1_post"], None, cfg)
         if cfg.residual_multiplier != 1.0:
             o = o * cfg.residual_multiplier
         x = x + o
+        if mode.plain:
+            x = checkpoint_name(x, "attn_half")
     if half == "moe":
+        if "route_counts" in carry:  # the layer's place among expert layers
+            lp = {**lp, "moe_index": layer - cfg.n_dense_layers}
         return _ffn_half(x, lp, cfg, True, lambda h: _experts(
             h, lp, cfg, gate, mode, carry))
     return _ffn_half(x, lp, cfg, False)[0], carry
@@ -1356,6 +1411,14 @@ def run_stack(layers, x, cfg: ModelConfig, mode, carry):
                     idx[kind] = idx[kind] + 1
             return (x, carry), None
 
+        if mode.plain and cfg.remat:
+            # a train step's layers: the backward recomputes a period but
+            # for `KEPT_UNDER_REMAT`
+            period = _remat(period, cfg, KEPT_UNDER_REMAT)
+            if first == 0:
+                from ..util import profiler
+
+                profiler.publish_remat_kept(kept_bytes(cfg, B, T, mesh))
         if repeats == 1:
             (x, carry), _ = period(
                 (x, carry), (jax.tree.map(lambda a: a[0], seg), 0))
@@ -1381,10 +1444,114 @@ def _run(params: Params, tokens: jax.Array, cfg: ModelConfig, mode, *carried):
     return x, carry
 
 
-def forward(params: Params, tokens: jax.Array, cfg: ModelConfig):
-    """tokens [B,T] -> (logits [B,T,V] float32, 0): no cache, no state."""
-    x, _ = _run(params, tokens, cfg, Seq(cfg))
-    return _lm_head(x, params, cfg), jnp.zeros((), _F32)
+def forward(params: Params, tokens: jax.Array, cfg: ModelConfig,
+            route_counts: bool = False):
+    """tokens [B,T] -> (logits [B,T,V] float32, 0): no cache, no state.
+    What `loss_fn` differentiates: under `cfg.remat` each period of layers
+    is a checkpoint that keeps `KEPT_UNDER_REMAT`. `route_counts`: a third
+    result, int32 [expert layers, router outputs]: how many of the batch's
+    choices fell on each expert, layer by layer (a train step's, for the
+    router's bias and the counters)."""
+    x, carry = _run(params, tokens, cfg, Seq(cfg, route_counts=route_counts))
+    out = _lm_head(x, params, cfg), jnp.zeros((), _F32)
+    return (*out, carry["route_counts"]) if route_counts else out
+
+
+# ---------------------------------------------------------------------------
+# training: logical axes, what a checkpoint keeps, the router's bias
+# ---------------------------------------------------------------------------
+
+# leaf -> logical axes (parallel/sharding.py's rules: embed -> fsdp, heads /
+# mlp / expert_mlp / vocab -> tp, expert -> ep), without the leading axis of
+# a segment's repeats, which no rule shards
+_LEAF_AXES = {
+    "wq": ("embed", "heads", None), "wk": ("embed", "heads", None),
+    "wv": ("embed", "heads", None), "wg": ("embed", "heads", None),
+    "wo": ("heads", None, "embed"), "router": ("embed", None),
+    "sh_in": ("embed", "mlp"), "sh_gate": ("embed", "mlp"),
+    "sh_out": ("mlp", "embed"),
+}
+_HALF_AXES = {
+    "ffn": {"w_in": ("embed", "mlp"), "w_gate": ("embed", "mlp"),
+            "w_out": ("mlp", "embed")},
+    "moe": {"w_in": ("expert", "embed", "expert_mlp"),
+            "w_gate": ("expert", "embed", "expert_mlp"),
+            "w_out": ("expert", "expert_mlp", "embed")},
+}
+
+
+def param_axes(cfg: ModelConfig) -> Params:
+    """Logical axes of `init_params`' tree, leaf for leaf, for a stack that
+    can be trained (every kind in `config.TRAINABLE_KINDS`; the others are
+    refused by name). Vectors (norms, the router's bias) are replicated."""
+    if cfg.untrainable:
+        raise NotImplementedError(cfg.untrainable)
+
+    def layer(kind, half):
+        axes = {}
+        for name, (shape, _) in layer_shapes(cfg, kind, half).items():
+            known = _HALF_AXES[half].get(name) or _LEAF_AXES.get(name)
+            axes[name] = (None, *(known or (None,) * len(shape)))
+        return axes
+
+    out = {"embed": ("vocab", "embed"), "final_norm": ("norm",),
+           "layers": [tuple(layer(kind, cfg.second_halves[first + i])
+                            for i, kind in enumerate(kinds))
+                      for first, kinds, _ in cfg.segments()]}
+    if not cfg.tie_embeddings:
+        out["lm_head"] = ("embed", "vocab")
+    if cfg.norm == "layernorm":
+        out["final_norm_b"] = ("norm",)
+    return out
+
+
+def kept_bytes(cfg: ModelConfig, B: int, T: int, mesh=None) -> Dict[str, int]:
+    """name -> the bytes ONE device keeps under it for a train step over B
+    rows of T tokens (`KEPT_UNDER_REMAT`): the attention half in every
+    layer, a row a position; the grouped experts' up products in every
+    expert layer, a row of the sorted buffer each, and their combined
+    result, a row a position (none where the experts take another form)."""
+    positions = -(-B * T // split_ways(("batch", "seq"), mesh))
+    act = jnp.dtype(cfg.dtype).itemsize
+    grouped = moe_grouped(cfg, B, T, mesh)
+    rows = grouped[1] if grouped else 0
+    widths = _kept_widths(cfg)
+    out = {name: cfg.n_layers * positions * widths[name]
+           for name in _KEPT_UNDER_REMAT}
+    up, gate, combined = GROUPED_RESIDUAL_NAMES
+    layers = cfg.second_halves.count("moe")
+    out.update({up: layers * rows * cfg.expert_ff * act,
+                gate: layers * rows * cfg.expert_ff * act,
+                combined: layers * positions * cfg.d_model * act * bool(rows)})
+    return out
+
+
+def expert_layers(cfg: ModelConfig):
+    """(segment, place in its period, int32 [repeats]: the rows of
+    `route_counts` its repeats wrote) for every expert layer's leaves."""
+    for s, (first, kinds, repeats) in enumerate(cfg.segments()):
+        for i in range(len(kinds)):
+            if cfg.second_halves[first + i] == "moe":
+                yield s, i, (first + i - cfg.n_dense_layers
+                             + len(kinds) * jnp.arange(repeats))
+
+
+def move_router_bias(params: Params, counts, cfg: ModelConfig) -> Params:
+    """After the optimizer's update: every expert's bias a step of
+    `cfg.router_bias_rate` towards an even load,
+    b_e += rate * sign(mean(n) - n_e), n_e = counts[layer, e] the step's
+    choices of expert e among ALL the router's outputs (a chip that holds a
+    share routes over all of them). The bias enters the choice alone, so it
+    has no gradient; this is the only thing that moves it."""
+    n = counts.astype(_F32)
+    step = cfg.router_bias_rate * jnp.sign(
+        jnp.mean(n, axis=1, keepdims=True) - n)
+    layers = [list(seg) for seg in params["layers"]]
+    for s, i, rows in expert_layers(cfg):
+        lp = layers[s][i]
+        layers[s][i] = {**lp, "router_bias": lp["router_bias"]
+                        + step[rows].astype(lp["router_bias"].dtype)}
+    return {**params, "layers": [tuple(seg) for seg in layers]}
 
 
 def run_paged(params: Params, tokens: jax.Array, cfg: ModelConfig, mode,

@@ -33,6 +33,11 @@ from ..ops import (
     expert_groups,
     expert_step,
     flash_attention,
+    group_rows,
+    grouped_ffn,
+    grouped_fits,
+    grouped_rows_bound,
+    grouped_tile,
     groups_fit,
     groups_rows_bound,
     layer_norm,
@@ -40,6 +45,7 @@ from ..ops import (
     rope_frequencies,
 )
 from ..ops.attention import FLASH_RESIDUAL_NAMES
+from ..ops.moe import GROUPED_MIN_ROWS, GROUPED_RESIDUAL_NAMES
 from ..parallel.moe import sigmoid_bias_gating, top_k_gating
 from ..parallel.sharding import _current_mesh, constrain, per_shard, split_ways
 from .config import ModelConfig
@@ -57,9 +63,10 @@ def _one_block_only(cfg: ModelConfig, what: str) -> None:
         raise NotImplementedError(
             f"{what} runs one kind of layer, rotary attention and an FFN; "
             f"{cfg.name!r} is a stack of unlike layers, which models/stack.py "
-            "runs, on the serve path alone (`forward` and the engine's "
-            "programs): no sharding rules, no training path, no "
-            "contiguous-cache generate")
+            "runs: `forward`, the engine's programs and, where every kind is "
+            "one of config.TRAINABLE_KINDS, `loss_fn` / `param_axes` / "
+            "`make_train_step`; no pipeline stages, no contiguous-cache "
+            "generate")
 
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
@@ -130,7 +137,10 @@ def param_axes(cfg: ModelConfig) -> Params:
     pp-sharded from birth, so the pipelined train step round-trips state
     without resharding); unsharded on every other mesh.
     """
-    _one_block_only(cfg, "param_axes")
+    if cfg.is_stack:
+        from . import stack
+
+        return stack.param_axes(cfg)
     layer = {
         "ln1": ("stage", "norm"),
         "wq": ("stage", "embed", "heads", None),
@@ -317,6 +327,29 @@ def _moe_dropless(cfg, T: int, mesh) -> bool:
     return moe_capacity(cfg, T) >= T and not _moe_sharded(mesh)
 
 
+def moe_grouped(cfg, B: int, T: int, mesh) -> Optional[Tuple[int, int]]:
+    """Whether a program over B rows of T tokens that does not know which
+    of its rows are live (a training row, the plain forward, `Verify`) runs
+    its experts as `_moe_ffn_grouped`, the tokens' choices sorted by expert
+    and each held expert over its own rows: where it dispatches nothing,
+    a held expert can expect `GROUPED_MIN_ROWS` rows or more (under that
+    every expert over every row costs no more than the sort), there are no
+    identity experts, and the widths tile and fit the kernels. -> (tile,
+    bound): the sorted buffer's tile and its rows (ops/moe.py), or None:
+    `_moe_ffn_dropless_ids` runs. The program's static shape and mesh
+    decide, as for `_moe_dropless`."""
+    k, W = cfg.num_selected_experts, cfg.router_width
+    N, D, F = B * T, cfg.d_model, cfg.expert_ff
+    if not ("moe" in cfg.second_halves and _moe_dropless(cfg, T, mesh)
+            and N * k >= GROUPED_MIN_ROWS * W and not cfg.experts_zero
+            and D % 128 == 0 and F % 128 == 0):
+        return None
+    tile = grouped_tile(N * k / W)
+    if not grouped_fits(D, F, jnp.dtype(cfg.dtype).itemsize, tile):
+        return None
+    return tile, grouped_rows_bound(N, k, cfg.num_experts, W, tile)
+
+
 def moe_step_visits(cfg, mesh) -> bool:
     """Whether a decode STEP (one token a row, the mode knows which rows
     are live) runs its experts as `moe_ffn_step`, the experts a live row
@@ -475,7 +508,60 @@ def _moe_ffn(x, lp, cfg, gate=None):
 
 
 def _moe_ffn_dropless(x, lp, cfg, gate=None):
-    return _moe_ffn_dropless_ids(x, lp, cfg, gate)[:2]
+    return moe_ffn_ids(x, lp, cfg, gate)[:2]
+
+
+def moe_ffn_ids(x, lp, cfg, gate=None):
+    """The expert layer of a program that dispatches nothing and does not
+    know its live rows, in the form `moe_grouped` picks: the choices sorted
+    by expert and each held expert over its own rows, or every expert over
+    every row. -> (out, aux, expert_ids [B,T,k])."""
+    grouped = moe_grouped(cfg, *x.shape[:2], _current_mesh())
+    if grouped is None:
+        return _moe_ffn_dropless_ids(x, lp, cfg, gate)
+    return _moe_ffn_grouped(x, lp, cfg, gate, *grouped)
+
+
+def _moe_ffn_grouped(x, lp, cfg, gate, tile: int, bound: int):
+    """`_moe_ffn_dropless_ids` for rows in their thousands: the same
+    gating and the same sum at the same rounding points (each product
+    rounded to the activations' type, the chosen experts' results weighted
+    and summed in float32), over the rows that chose a held expert and no
+    others. The tokens' choices that fall on held experts are sorted by
+    expert into a buffer of `bound` rows in tiles of `tile` (scope `sort`),
+    the grouped product runs each expert over its own tiles (`experts`;
+    ops/moe.py `grouped_ffn`, which has a backward), and a scatter-add
+    puts the weighted rows back at their tokens (`combine`). Dropless: a
+    routing that needs more than `bound` rows (a share layer's alone can:
+    `grouped_rows_bound`) poisons the layer's output with NaN, and the
+    train step, which counts every layer's choices, raises
+    (train/lm.py). -> (out, aux, expert_ids [B,T,k])."""
+    dtype = x.dtype
+    B, T, D = x.shape
+    N, E, k = B * T, cfg.num_experts, cfg.num_selected_experts
+    with jax.named_scope("route"):
+        logits, weights, expert_ids = gate or _moe_gate(x, lp, cfg)
+        aux = _moe_aux(logits, expert_ids, cfg.router_width)
+    xs = x.reshape(N, D)
+    with jax.named_scope("sort"):
+        rows = group_rows(expert_ids.reshape(N, k), weights.reshape(N, k),
+                          cfg.experts_first, E, tile, bound)
+        sorted_x = jnp.take(xs, rows["token"], axis=0, mode="fill",
+                            fill_value=0)
+    with jax.named_scope("experts"):
+        y = grouped_ffn(_GATE_ACT[cfg.activation], tile, sorted_x,
+                        lp["w_in"], lp["w_gate"], lp["w_out"],
+                        rows["tile_expert"], rows["used"])
+    with jax.named_scope("combine"):
+        out = jnp.zeros((N, D), jnp.float32).at[rows["token"]].add(
+            y.astype(jnp.float32) * rows["weight"][:, None], mode="drop")
+        out = jnp.where(rows["rows"] > bound, jnp.nan, out)
+        # kept by a checkpoint that keeps the up products: a norm after the
+        # sublayer reads it in the backward, which then sorts and multiplies
+        # for the router's gradient alone and adds nothing up again
+        out = checkpoint_name(out.astype(dtype), GROUPED_RESIDUAL_NAMES[2])
+        return (constrain(out.reshape(B, T, D), ("batch", "seq", "embed")),
+                aux, expert_ids)
 
 
 def _moe_combine(x, lp, cfg, gate=None):
@@ -688,16 +774,19 @@ def _ffn_half(x, lp, cfg, moe=None, experts=None, named=False):
     training block below, which has a dense half's products `named`
     (`_dense_ffn`)."""
     moe = cfg.is_moe if moe is None else moe
+    place = cfg.norm_place
     with jax.named_scope("moe" if moe else "ffn"):
-        h = x if cfg.post_norm else _norm(x, lp["ln2"], lp.get("ln2_b"), cfg)
+        h = x if place == "post" else _norm(x, lp["ln2"], lp.get("ln2_b"), cfg)
         if moe:
             y, aux = experts(h) if experts else _moe_ffn(h, lp, cfg)
             if cfg.d_ff_shared:  # beside the routed sum, whatever its form
                 y = y + _shared_experts(h, lp, cfg)
         else:
             y, aux = _dense_ffn(h, lp, cfg, named), jnp.zeros((), jnp.float32)
-        if cfg.post_norm:
+        if place == "post":
             y = _norm(y, lp["ln2"], lp.get("ln2_b"), cfg)
+        elif place == "both":
+            y = _norm(y, lp["ln2_post"], None, cfg)
         if cfg.residual_multiplier != 1.0:
             y = y * cfg.residual_multiplier
         return x + y, aux
@@ -934,12 +1023,15 @@ def forward(
     tokens: jax.Array,
     cfg: ModelConfig,
     positions: Optional[jax.Array] = None,
+    route_counts: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
-    """tokens [B, T] -> (logits [B, T, V] f32, aux_loss scalar)."""
+    """tokens [B, T] -> (logits [B, T, V] f32, aux_loss scalar).
+    `route_counts` (a stack that holds experts): a third result, how many
+    choices fell on each expert in each expert layer (models/stack.py)."""
     if cfg.is_stack:
         from . import stack
 
-        return stack.forward(params, tokens, cfg)
+        return stack.forward(params, tokens, cfg, route_counts)
     x, rope_tables = _prologue(params, tokens, cfg, positions)
     x, aux = run_layers(params["layers"], x, cfg, rope_tables, positions)
     return _lm_head(x, params, cfg), aux
@@ -1019,17 +1111,25 @@ def loss_fn(
     cfg: ModelConfig,
     z_loss_coef: float = 1e-4,
     forward_fn=None,
+    route_counts: bool = False,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """batch: tokens [B,T], targets [B,T], optional mask [B,T].
 
     forward_fn overrides the forward (e.g. a pipeline-parallel
-    functools.partial(forward_pp, mesh=..., num_microbatches=...))."""
+    functools.partial(forward_pp, mesh=..., num_microbatches=...)).
+    `route_counts`: -> (loss, (metrics, counts)), `forward`'s third result
+    beside the metrics (the train step's, for the router's bias)."""
     fwd = forward_fn if forward_fn is not None else forward
-    logits, aux = fwd(params, batch["tokens"], cfg)
-    return loss_from_logits(
+    if route_counts:
+        logits, aux, counts = fwd(params, batch["tokens"], cfg,
+                                  route_counts=True)
+    else:
+        logits, aux = fwd(params, batch["tokens"], cfg)
+    total, metrics = loss_from_logits(
         logits, batch["targets"], batch.get("mask"), cfg, aux,
         z_loss_coef=z_loss_coef,
     )
+    return total, ((metrics, counts) if route_counts else metrics)
 
 
 def loss_from_logits(

@@ -23,6 +23,11 @@ from .mla_attention import (  # noqa: F401
 from .moe import (  # noqa: F401
     expert_groups,
     expert_step,
+    group_rows,
+    grouped_ffn,
+    grouped_fits,
+    grouped_rows_bound,
+    grouped_tile,
     groups_fit,
     groups_rows_bound,
 )

@@ -67,8 +67,10 @@ def mha_reference(
     causal: bool = True,
     scale: Optional[float] = None,
     q_offset: int = 0,
+    window: Optional[int] = None,
 ) -> jax.Array:
-    """O(T²) reference attention, [B, T, H, D]; used for tests only."""
+    """O(T²) reference attention, [B, T, H, D]; used for tests only.
+    `window`: a query at position i sees the keys i - window < j <= i."""
     B, Tq, H, D = q.shape
     Tk, KVH = k.shape[1], k.shape[2]
     if scale is None:
@@ -80,10 +82,62 @@ def mha_reference(
     if causal:
         q_pos = q_offset + jnp.arange(Tq)
         mask = q_pos[:, None] >= jnp.arange(Tk)[None, :]
+        if window is not None:
+            mask &= jnp.arange(Tk)[None, :] > q_pos[:, None] - window
         s = jnp.where(mask[None, None, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v)
     return o.reshape(B, Tq, H, D)
+
+
+# ---------------------------------------------------------------------------
+# A window: query i sees the keys i - window < j <= i. The window kernels
+# are the causal ones over a NARROWER grid: a query block's innermost axis
+# runs over the key blocks its window reaches and no others (`_key_span`),
+# a key block's over the query blocks that reach it (`_query_span`); the
+# index maps name those blocks alone, so a block wholly outside the window
+# is never loaded. The diagonal and the window's edge blocks are masked.
+# ---------------------------------------------------------------------------
+
+
+def _key_span(window, block_q, block_k, nq):
+    """How many key blocks a query block's window reaches at the most."""
+    return max((i * block_q + block_q - 1) // block_k
+               - max(i * block_q - window + 1, 0) // block_k + 1
+               for i in range(nq))
+
+
+def _query_span(window, block_q, block_k, nq, nk):
+    """How many query blocks reach a key block at the most."""
+    return max(min((j * block_k + block_k + window - 2) // block_q, nq - 1)
+               - (j * block_k) // block_q + 1 for j in range(nk))
+
+
+def _key_block(i, jj, span, block_q, block_k):
+    """The key block that step jj of `span` is for query block i: the
+    last one is the diagonal's; negative before the sequence's start."""
+    return (i * block_q + block_q - 1) // block_k - (span - 1) + jj
+
+
+def _query_block(j, ii, block_q, block_k):
+    """The query block that step ii is for key block j: the first one is
+    the diagonal's; past the sequence's end it names no block."""
+    return (j * block_k) // block_q + ii
+
+
+def _window_mask(i, j, block_q, block_k, window):
+    q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+    k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    return (q_pos >= k_pos) & (k_pos > q_pos - window)
+
+
+def _window_runs(i, j, block_q, block_k, window, nq=None):
+    """Whether blocks (i, j) hold a pair inside the window: j a key block
+    at or after the sequence's start, i a query block before its end (`nq`
+    blocks; None: the grid names no block past it)."""
+    runs = ((j >= 0) & (i * block_q + block_q - 1 >= j * block_k)
+            & (j * block_k + block_k - 1 > i * block_q - window))
+    return runs if nq is None else runs & (i < nq)
 
 
 # ---------------------------------------------------------------------------
@@ -92,7 +146,8 @@ def mha_reference(
 
 
 def _fwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, *rest, scale, causal, block_q, block_k, return_lse
+    q_ref, k_ref, v_ref, o_ref, *rest, scale, causal, block_q, block_k, return_lse,
+    window=None,
 ):
     if return_lse:
         lse_ref, acc_ref, m_ref, l_ref = rest
@@ -110,7 +165,11 @@ def _fwd_kernel(
     # Blocks strictly above the diagonal contribute nothing under causal
     # masking: skip the MXU work (the tile fetch still happens — acceptable;
     # a bespoke index_map could skip it too).
-    if causal:
+    if window is not None:
+        # the grid's j is a step of the window's span: jb is its key block
+        jb = _key_block(i, j, nk, block_q, block_k)
+        run = _window_runs(i, jb, block_q, block_k, window)
+    elif causal:
         run = i * block_q + block_q - 1 >= j * block_k
     else:
         run = jnp.bool_(True)
@@ -123,7 +182,9 @@ def _fwd_kernel(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
         s = s * scale  # [bq, bk]
-        if causal:
+        if window is not None:
+            s = jnp.where(_window_mask(i, jb, block_q, block_k, window), s, _NEG_INF)
+        elif causal:
             q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
             s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
@@ -157,16 +218,25 @@ def _fwd_kernel(
             lse_ref[0, 0] = m_ref[...] + jnp.log(jnp.where(l_ref[...] == 0.0, 1.0, l_ref[...]))
 
 
-def _flash_fwd_pallas(q, k, v, *, causal, scale, block_q, block_k, return_lse=False):
+def _flash_fwd_pallas(q, k, v, *, causal, scale, block_q, block_k, return_lse=False,
+                      window=None):
     """q [B,H,T,D], k/v [B,KVH,T,D] -> o [B,H,T,D] (and lse [B,H,T] f32)."""
     B, H, Tq, D = q.shape
     KVH, Tk = k.shape[1], k.shape[2]
     g = H // KVH
     grid = (B, H, Tq // block_q, Tk // block_k)
+    kv_at = lambda b, h, i, j: (b, h // g, j, 0)
+    extra = {}
+    if window is not None:
+        span = _key_span(window, block_q, block_k, grid[2])
+        grid = (*grid[:3], span)
+        kv_at = lambda b, h, i, j: (b, h // g, jnp.maximum(
+            _key_block(i, j, span, block_q, block_k), 0), 0)
+        extra = dict(window=window)
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-        return_lse=return_lse,
+        return_lse=return_lse, **extra,
     )
     out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
     out_specs = [pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))]
@@ -181,8 +251,8 @@ def _flash_fwd_pallas(q, k, v, *, causal, scale, block_q, block_k, return_lse=Fa
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h // g, j, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h // g, j, 0)),
+            pl.BlockSpec((1, 1, block_k, D), kv_at),
+            pl.BlockSpec((1, 1, block_k, D), kv_at),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -198,8 +268,12 @@ def _flash_fwd_pallas(q, k, v, *, causal, scale, block_q, block_k, return_lse=Fa
             flops=int(4 * B * H * Tq * Tk * D * (0.5 if causal else 1.0)),
             bytes_accessed=int((q.size + k.size + v.size + q.size) * q.dtype.itemsize),
             transcendentals=int(B * H * Tq * Tk),
+        ) if window is None else pl.CostEstimate(
+            flops=int(4 * B * H * Tq * min(window, Tk) * D),
+            bytes_accessed=int((q.size + k.size + v.size + q.size) * q.dtype.itemsize),
+            transcendentals=int(B * H * Tq * min(window, Tk)),
         ),
-        name="flash_fwd",
+        name="flash_fwd" if window is None else "flash_fwd_window",
         interpret=interpret_mode(),
     )(q, k, v)
     if return_lse:
@@ -215,7 +289,7 @@ def _flash_fwd_pallas(q, k, v, *, causal, scale, block_q, block_k, return_lse=Fa
 
 def _dq_kernel(
     q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref, dq_ref, dq_acc,
-    *, scale, causal, block_q, block_k,
+    *, scale, causal, block_q, block_k, window=None,
 ):
     i, j = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
@@ -224,7 +298,10 @@ def _dq_kernel(
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    if causal:
+    if window is not None:
+        jb = _key_block(i, j, nk, block_q, block_k)
+        run = _window_runs(i, jb, block_q, block_k, window)
+    elif causal:
         run = i * block_q + block_q - 1 >= j * block_k
     else:
         run = jnp.bool_(True)
@@ -238,7 +315,9 @@ def _dq_kernel(
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # [bq, bk]
-        if causal:
+        if window is not None:
+            s = jnp.where(_window_mask(i, jb, block_q, block_k, window), s, _NEG_INF)
+        elif causal:
             q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
             s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
@@ -258,7 +337,7 @@ def _dq_kernel(
 
 def _dkv_kernel(
     q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref, dk_ref, dv_ref,
-    dk_acc, dv_acc, *, scale, causal, block_q, block_k,
+    dk_acc, dv_acc, *, scale, causal, block_q, block_k, window=None, nq=None,
 ):
     j, i = pl.program_id(2), pl.program_id(3)  # kv-major: q blocks innermost
     ni = pl.num_programs(3)
@@ -268,7 +347,12 @@ def _dkv_kernel(
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    if causal:
+    if window is not None:
+        # the grid's i is a step of the span of query blocks that reach
+        # key block j: ib is its query block
+        ib = _query_block(j, i, block_q, block_k)
+        run = _window_runs(ib, j, block_q, block_k, window, nq)
+    elif causal:
         run = i * block_q + block_q - 1 >= j * block_k
     else:
         run = jnp.bool_(True)
@@ -282,7 +366,9 @@ def _dkv_kernel(
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # [bq, bk]
-        if causal:
+        if window is not None:
+            s = jnp.where(_window_mask(ib, j, block_q, block_k, window), s, _NEG_INF)
+        elif causal:
             q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
             s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
@@ -307,7 +393,7 @@ def _dkv_kernel(
 
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, *, causal, scale, block_q, block_k,
-                      dlse=None):
+                      dlse=None, window=None):
     """Fused backward: q/o/do [B,H,Tq,D], k/v [B,KVH,Tk,D], lse [B,H,Tq] f32.
 
     Returns (dq, dk, dv) in the input dtypes. dk/dv are computed per q-head
@@ -328,12 +414,23 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, causal, scale, block_q, block_k,
     q_spec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
     kv_spec = pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h // g, j, 0))
     lane_spec = pl.BlockSpec((1, 1, block_q, _LANES), lambda b, h, i, j: (b, h, i, 0))
+    dq_steps, dkv_steps, extra = nk, nq, {}
+    pairs = 0.5 if causal else 1.0  # of Tq x Tk, what the cost estimates count
+    if window is not None:
+        dq_steps = _key_span(window, block_q, block_k, nq)
+        dkv_steps = _query_span(window, block_q, block_k, nq, nk)
+        kv_spec = pl.BlockSpec(
+            (1, 1, block_k, D), lambda b, h, i, j: (b, h // g, jnp.maximum(
+                _key_block(i, j, dq_steps, block_q, block_k), 0), 0))
+        extra = dict(window=window)
+        pairs = min(window, Tk) / Tk
 
     dq = pl.pallas_call(
         functools.partial(
-            _dq_kernel, scale=scale, causal=causal, block_q=block_q, block_k=block_k
+            _dq_kernel, scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+            **extra
         ),
-        grid=(B, H, nq, nk),
+        grid=(B, H, nq, dq_steps),
         in_specs=[q_spec, kv_spec, kv_spec, lane_spec, lane_spec, q_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -343,11 +440,11 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, causal, scale, block_q, block_k,
             vmem_limit_bytes=_BWD_VMEM_LIMIT,
         ),
         cost_estimate=pl.CostEstimate(
-            flops=int(6 * B * H * Tq * Tk * D * (0.5 if causal else 1.0)),
+            flops=int(6 * B * H * Tq * Tk * D * pairs),
             bytes_accessed=int(3 * q.size * q.dtype.itemsize),
             transcendentals=int(B * H * Tq * Tk),
         ),
-        name="flash_bwd_dq",
+        name="flash_bwd_dq" if window is None else "flash_bwd_window_dq",
         interpret=interpret_mode(),
     )(q, k, v, lse_rep, delta_rep, do)
 
@@ -356,12 +453,22 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, causal, scale, block_q, block_k,
     kv_spec_t = pl.BlockSpec((1, 1, block_k, D), lambda b, h, j, i: (b, h // g, j, 0))
     lane_spec_t = pl.BlockSpec((1, 1, block_q, _LANES), lambda b, h, j, i: (b, h, i, 0))
     dkv_out_spec = pl.BlockSpec((1, 1, block_k, D), lambda b, h, j, i: (b, h, j, 0))
+    if window is not None:
+        def q_block(j, i):
+            return jnp.minimum(_query_block(j, i, block_q, block_k), nq - 1)
+
+        q_spec_t = pl.BlockSpec(
+            (1, 1, block_q, D), lambda b, h, j, i: (b, h, q_block(j, i), 0))
+        lane_spec_t = pl.BlockSpec(
+            (1, 1, block_q, _LANES), lambda b, h, j, i: (b, h, q_block(j, i), 0))
+        extra = dict(window=window, nq=nq)
 
     dk_h, dv_h = pl.pallas_call(
         functools.partial(
-            _dkv_kernel, scale=scale, causal=causal, block_q=block_q, block_k=block_k
+            _dkv_kernel, scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+            **extra
         ),
-        grid=(B, H, nk, nq),
+        grid=(B, H, nk, dkv_steps),
         in_specs=[q_spec_t, kv_spec_t, kv_spec_t, lane_spec_t, lane_spec_t, q_spec_t],
         out_specs=[dkv_out_spec, dkv_out_spec],
         out_shape=[
@@ -377,11 +484,11 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, causal, scale, block_q, block_k,
             vmem_limit_bytes=_BWD_VMEM_LIMIT,
         ),
         cost_estimate=pl.CostEstimate(
-            flops=int(8 * B * H * Tq * Tk * D * (0.5 if causal else 1.0)),
+            flops=int(8 * B * H * Tq * Tk * D * pairs),
             bytes_accessed=int(4 * q.size * q.dtype.itemsize),
             transcendentals=int(B * H * Tq * Tk),
         ),
-        name="flash_bwd_dkv",
+        name="flash_bwd_dkv" if window is None else "flash_bwd_window_dkv",
         interpret=interpret_mode(),
     )(q, k, v, lse_rep, delta_rep, do)
 
@@ -407,7 +514,7 @@ def _pad_kv(k, v, block_k):
     return k, v, Tk
 
 
-def _fwd_xla_blockwise(q, k, v, *, causal, scale, block_k):
+def _fwd_xla_blockwise(q, k, v, *, causal, scale, block_k, window=None):
     """Scan over kv blocks, all q rows at once. [B,H,T,D] layout.
 
     Returns (o, lse) with lse [B,H,T] in f32. Handles any Tk (kv padded to
@@ -439,6 +546,8 @@ def _fwd_xla_blockwise(q, k, v, *, causal, scale, block_k):
         keep = k_pos[None, :] < Tk
         if causal:
             keep = keep & (q_pos[:, None] >= k_pos[None, :])
+        if window is not None:
+            keep = keep & (k_pos[None, :] > q_pos[:, None] - window)
         s = jnp.where(keep, s, _NEG_INF)
         m_cur = jnp.max(s, axis=-1)
         m_next = jnp.maximum(m_prev, m_cur)
@@ -469,7 +578,8 @@ def _fwd_xla_blockwise(q, k, v, *, causal, scale, block_k):
     return o, lse
 
 
-def _bwd_xla_blockwise(q, k, v, o, lse, do, *, causal, scale, block_k, dlse=None):
+def _bwd_xla_blockwise(q, k, v, o, lse, do, *, causal, scale, block_k, dlse=None,
+                       window=None):
     """Flash-2 backward as a scan over kv blocks. [B,H,T,D] layout.
 
     dlse: optional [B,H,Tq] cotangent for the lse output (ring attention
@@ -500,6 +610,8 @@ def _bwd_xla_blockwise(q, k, v, o, lse, do, *, causal, scale, block_k, dlse=None
         keep = k_pos[None, :] < Tk
         if causal:
             keep = keep & (q_pos[:, None] >= k_pos[None, :])
+        if window is not None:
+            keep = keep & (k_pos[None, :] > q_pos[:, None] - window)
         s = jnp.where(keep, s, _NEG_INF)
         p = jnp.exp(s - lse_r[..., None])  # [B,KVH,g,Tq,bk]
         dv_j = jnp.einsum("bcgqk,bcgqd->bckd", p, dof, preferred_element_type=jnp.float32)
@@ -547,19 +659,26 @@ def _xla_bk(block_k: int, k) -> int:
     at the historical 128."""
     return min(block_k, 128, k.shape[2])
 
-def _fwd_dispatch(q, k, v, causal, scale, block_q, block_k):
+def _windowed(window):
+    """The keyword a window adds to a kernel's or a fallback's call: none
+    where there is no window, so that those calls are what they were."""
+    return {} if window is None else {"window": window}
+
+
+def _fwd_dispatch(q, k, v, causal, scale, block_q, block_k, window=None):
     """Pallas kernel when lowering for TPU and shapes tile; XLA otherwise."""
+    w = _windowed(window)
     if not _pallas_ok(q, k, block_q, block_k):
         o, _ = _fwd_xla_blockwise(
-            q, k, v, causal=causal, scale=scale, block_k=_xla_bk(block_k, k)
+            q, k, v, causal=causal, scale=scale, block_k=_xla_bk(block_k, k), **w
         )
         return o
     return platform_dispatch(
         lambda q, k, v: _flash_fwd_pallas(
-            q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k
+            q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k, **w
         ),
         lambda q, k, v: _fwd_xla_blockwise(
-            q, k, v, causal=causal, scale=scale, block_k=_xla_bk(block_k, k)
+            q, k, v, causal=causal, scale=scale, block_k=_xla_bk(block_k, k), **w
         )[0],
         q,
         k,
@@ -567,12 +686,12 @@ def _fwd_dispatch(q, k, v, causal, scale, block_q, block_k):
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_bhtd(q, k, v, causal, scale, block_q, block_k):
-    return _fwd_dispatch(q, k, v, causal, scale, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_bhtd(q, k, v, causal, scale, block_q, block_k, window=None):
+    return _fwd_dispatch(q, k, v, causal, scale, block_q, block_k, window)
 
 
-def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k):
+def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k, window=None):
     # Both branches of the dispatch return (o, lse[B,H,Tq] f32); the lse
     # residual feeds the fused Pallas backward (no fwd recompute). Both are
     # named HERE, output and residual alike: a `jax.checkpoint` whose policy
@@ -581,27 +700,28 @@ def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k):
     # kernel run again for `lse` alone). Outside a checkpoint a name is the
     # identity. `_flash_lse_fwd_rule` below names nothing: ring attention
     # calls it once a ring step, and every step's partials would be kept.
-    o, lse = _fwd_lse_dispatch(q, k, v, causal, scale, block_q, block_k)
+    o, lse = _fwd_lse_dispatch(q, k, v, causal, scale, block_q, block_k, window)
     o = checkpoint_name(o, FLASH_RESIDUAL_NAMES[0])
     lse = checkpoint_name(lse, FLASH_RESIDUAL_NAMES[1])
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd_rule(causal, scale, block_q, block_k, res, do):
+def _flash_bwd_rule(causal, scale, block_q, block_k, window, res, do):
     q, k, v, o, lse = res
+    w = _windowed(window)
     if not _pallas_ok(q, k, block_q, block_k):
         bk = _xla_bk(block_k, k)
         return _bwd_xla_blockwise(
-            q, k, v, o, lse, do, causal=causal, scale=scale, block_k=bk
+            q, k, v, o, lse, do, causal=causal, scale=scale, block_k=bk, **w
         )
     return platform_dispatch(
         lambda q, k, v, o, lse, do: _flash_bwd_pallas(
             q, k, v, o, lse, do, causal=causal, scale=scale,
-            block_q=block_q, block_k=block_k,
+            block_q=block_q, block_k=block_k, **w,
         ),
         lambda q, k, v, o, lse, do: _bwd_xla_blockwise(
             q, k, v, o, lse, do, causal=causal, scale=scale,
-            block_k=_xla_bk(block_k, k)
+            block_k=_xla_bk(block_k, k), **w
         ),
         q, k, v, o, lse, do,
     )
@@ -617,17 +737,18 @@ _flash_bhtd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # ---------------------------------------------------------------------------
 
 
-def _fwd_lse_dispatch(q, k, v, causal, scale, block_q, block_k):
+def _fwd_lse_dispatch(q, k, v, causal, scale, block_q, block_k, window=None):
+    w = _windowed(window)
     if not _pallas_ok(q, k, block_q, block_k):
         bk = _xla_bk(block_k, k)
-        return _fwd_xla_blockwise(q, k, v, causal=causal, scale=scale, block_k=bk)
+        return _fwd_xla_blockwise(q, k, v, causal=causal, scale=scale, block_k=bk, **w)
     return platform_dispatch(
         lambda q, k, v: _flash_fwd_pallas(
             q, k, v, causal=causal, scale=scale,
-            block_q=block_q, block_k=block_k, return_lse=True,
+            block_q=block_q, block_k=block_k, return_lse=True, **w,
         ),
         lambda q, k, v: _fwd_xla_blockwise(
-            q, k, v, causal=causal, scale=scale, block_k=_xla_bk(block_k, k)
+            q, k, v, causal=causal, scale=scale, block_k=_xla_bk(block_k, k), **w
         ),
         q, k, v,
     )
@@ -700,12 +821,17 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Multi-head / grouped-query flash attention.
 
     Args:
       q: [B, T, H, D]; k, v: [B, T, KVH, D] with H % KVH == 0 (GQA).
       causal: apply causal mask.
+      window: a query at position i sees the keys i - window < j <= i
+        (causal, self-attention: q and k of one length). Forward and
+        backward visit the key blocks a query block's window reaches and
+        no others; a window that cannot bind (>= T) is no window.
       scale: score scale, default 1/sqrt(D).
       block_q/block_k: kernel tile sizes; default picks the largest
         power-of-two <=1024 dividing each sequence length.
@@ -721,6 +847,12 @@ def flash_attention(
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    if window is not None:
+        if not causal or q.shape[1] != k.shape[1] or window < 1:
+            raise ValueError("a window is causal self-attention's: causal, "
+                             "q and k of one length, window >= 1")
+        if window >= k.shape[1]:
+            window = None  # cannot bind: the causal kernels as they are
     KVH = k.shape[2]
     D, Dv = q.shape[3], v.shape[3]
     if Dv != D or (D > _LANES and D % _LANES):
@@ -732,17 +864,19 @@ def flash_attention(
         q, k, v = (jnp.pad(x, ((0, 0),) * 3 + ((0, wide - x.shape[3]),))
                    for x in (q, k, v))
         return flash_attention(q, k, v, causal, scale, block_q,
-                               block_k)[..., :Dv]
+                               block_k, window)[..., :Dv]
     f = tile_factor(q.shape[2], KVH, q.shape[3])
     if f > 1:
         wide = (*k.shape[:2], KVH // f, f * k.shape[3])
         o = flash_attention(_tile_heads(q, KVH, f), k.reshape(wide),
-                            v.reshape(wide), causal, scale, block_q, block_k)
+                            v.reshape(wide), causal, scale, block_q, block_k,
+                            window)
         return _untile_heads(o, KVH, f)
     block_q = block_q or _auto_block(q.shape[1])
     block_k = block_k or _auto_block(k.shape[1])
     qt = jnp.swapaxes(q, 1, 2)  # [B,H,T,D]
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
-    o = _flash_bhtd(qt, kt, vt, causal, scale, block_q, block_k)
+    o = _flash_bhtd(qt, kt, vt, causal, scale, block_q, block_k,
+                    *(() if window is None else (window,)))
     return jnp.swapaxes(o, 1, 2)

@@ -38,6 +38,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -81,13 +82,15 @@ def expert_step_reference(act, x, c, w_in, w_gate, w_out, layer, *lists):
     return jnp.sum(y.astype(_F32) * c.T[:, :, None], axis=0)
 
 
+def _mxu_precision(dtype):
+    """Two bfloat16 operands go to the MXU as they are, whatever the
+    process's default precision asks of float32 products."""
+    return jax.lax.Precision.DEFAULT if dtype == jnp.bfloat16 else None
+
+
 def _product(a, w):
-    """a [rows, K] . w [K, M] (cast to a's type) -> float32. Two bfloat16
-    operands go to the MXU as they are, whatever the process's default
-    precision asks of float32 products."""
-    precision = (jax.lax.Precision.DEFAULT if a.dtype == jnp.bfloat16
-                 else None)
-    return jnp.dot(a, w.astype(a.dtype), precision=precision,
+    """a [rows, K] . w [K, M] (cast to a's type) -> float32."""
+    return jnp.dot(a, w.astype(a.dtype), precision=_mxu_precision(a.dtype),
                    preferred_element_type=_F32)
 
 
@@ -415,3 +418,324 @@ def _dispatched(kernel, act, forced, *args):
     return platform_dispatch(
         functools.partial(kernel, act),
         functools.partial(expert_step_reference, act), *args)
+
+
+# ---------------------------------------------------------------------------
+# Training rows: a grouped product that has a backward
+# ---------------------------------------------------------------------------
+#
+# A training step is the other end again: tens of thousands of rows, every
+# held expert chosen by thousands, and a backward. x cannot lie in VMEM, so
+# the rows are SORTED by expert into a buffer in which every expert's group
+# starts on a tile of `tile` rows (`group_rows`): a tile belongs to ONE
+# expert, and the grouped product is a tiled matrix product whose weight
+# block is picked by the tile's expert (scalar-prefetched). Consecutive
+# tiles of an expert name the same block, which the pipeline does not fetch
+# again, so an expert's weights are read once a product. Three kernels:
+#
+#   moe_gmm     out[tile] = x[tile] . w[expert(tile)]        (forward, x3)
+#   moe_gmm_dx  the same with w transposed: the rows' gradient (x3)
+#   moe_gmm_dw  dw[e] = sum over e's tiles of x[tile]^T . dy[tile] (x3)
+#
+# The buffer's size is static (`grouped_rows_bound`); the tiles past the
+# used ones are skipped (no block fetched, zeros written), and a routing
+# that would pass the bound is for the caller to fail on: nothing here
+# drops a row silently (`group_rows` says how many rows the routing needs).
+
+# names for a `jax.checkpoint` policy to save by (models/stack.py): the two
+# up products, which `grouped_ffn`'s forward rule names, and the layer's
+# combined result, which models/transformer.py `_moe_ffn_grouped` names
+GROUPED_RESIDUAL_NAMES = ("moe_up", "moe_gate", "moe_out")
+# the least rows an expert should expect before its rows are sorted: one
+# tile of the smallest size. NOT MEASURED: under a tile an expert every
+# group is mostly its own padding, which is all the reason there is; where
+# the two forms cross on the chip nobody has read (the one shape measured is
+# the train cell's, 2048 rows an expert at tile 512: PERF.md section 5)
+GROUPED_MIN_ROWS = 128
+_ACC_BYTES = 4 * 2 ** 20
+
+
+def grouped_tile(rows_per_expert: float) -> int:
+    """Rows of a tile of the sorted buffer: large where an expert has
+    thousands of rows (a weight block's load hides behind more products),
+    small where the padding of a group's last tile would outweigh that.
+    The thresholds keep a group's padding (half a tile an expert on
+    average) under an eighth of its rows; only 512 at 2048 rows an expert
+    has been timed on the chip, the other two are arithmetic."""
+    return 512 if rows_per_expert >= 2048 else (
+        256 if rows_per_expert >= 512 else 128)
+
+
+def grouped_rows_bound(N: int, k: int, E: int, W: int, tile: int) -> int:
+    """Rows of the sorted buffer for N tokens of k choices each among W
+    experts of which E are held: every choice that can fall on a held
+    expert where all are held (nothing can pass it); TWICE the held
+    experts' even share where the layer holds a share (a routing that
+    piles more than that on this chip's experts fails, loudly); and a tile
+    a held expert for its group's padding (an expert nobody chose keeps
+    one tile of zeros: its weights' gradient is written, as zeros). Never
+    more than every token choosing min(k, E) held experts. Whole tiles."""
+    worst = N * min(k, E)
+    rows = worst if E == W else min(worst, 2 * -(-N * k * E // W))
+    return (-(-rows // tile) + E) * tile
+
+
+def group_rows(expert_ids, weights, first: int, E: int, tile: int,
+               bound: int):
+    """expert_ids [N,k] int32 over all the router's outputs, weights [N,k]
+    float32; the held experts are first .. first + E. -> the sorted
+    buffer's tables: `token` [bound] (the row's token; N: the row is
+    padding), `weight` [bound] float32 (0 for padding), `tile_expert`
+    [bound / tile] (past the used tiles: the last expert), `used` [1] (the
+    tiles that hold a group) and `rows` (the rows the routing NEEDS, which
+    is more than `bound` where it overflows: the rows past the bound are
+    then missing from the tables)."""
+    N, k = expert_ids.shape
+    flat = expert_ids.reshape(-1) - first
+    local = jnp.where((flat >= 0) & (flat < E), flat, E).astype(jnp.int32)
+    # held choices first, expert by expert, a group's tokens in their order
+    _, order = jax.lax.sort_key_val(
+        local, jnp.arange(N * k, dtype=jnp.int32), is_stable=True)
+    counts = jnp.sum(local[:, None] == jnp.arange(E, dtype=jnp.int32)[None],
+                     axis=0, dtype=jnp.int32)
+    tiles = jnp.maximum(-(-counts // tile), 1)
+    tile_end = jnp.cumsum(tiles)
+    used = tile_end[-1]
+    sorted_start = jnp.cumsum(counts) - counts
+    n_tiles = bound // tile
+    t = jnp.arange(n_tiles, dtype=jnp.int32)
+    tile_expert = jnp.minimum(
+        jnp.sum(t[:, None] >= tile_end[None, :], axis=1, dtype=jnp.int32),
+        E - 1)
+    row = jnp.arange(bound, dtype=jnp.int32)
+    e = tile_expert[row // tile]
+    at = row - (tile_end[e] - tiles[e]) * tile
+    valid = (at < counts[e]) & (row // tile < used)
+    choice = order[jnp.clip(sorted_start[e] + at, 0, N * k - 1)]
+    return {"token": jnp.where(valid, choice // k, N),
+            "weight": jnp.where(valid, weights.reshape(-1)[choice], 0.0),
+            "tile_expert": tile_expert,
+            "used": jnp.minimum(used, n_tiles).reshape(1),
+            "rows": used * tile}
+
+
+def _n_tile(K: int, N: int, itemsize: int, limit: int) -> int:
+    """The widest tile of whole 128 lanes that divides N and keeps a
+    [K, tile] block within `limit` bytes."""
+    n = N // _LANES
+    fits = [t for t in range(1, n + 1)
+            if n % t == 0 and K * t * _LANES * itemsize <= limit]
+    return _LANES * max(fits, default=1)
+
+
+def grouped_fits(D: int, F: int, itemsize: int, tile: int) -> bool:
+    """Whether the three kernels' blocks lie in VMEM at these widths: a
+    tile of rows of the wider of D and F (twice), a weight block and the
+    float32 accumulator of the weights' gradient."""
+    K = max(D, F)
+    return (4 * tile * K * itemsize + 2 * _BLOCK_BYTES + 3 * _ACC_BYTES
+            + 8 * tile * _LANES * 8) <= _VMEM_BYTES
+
+
+def _gmm_kernel(te_ref, used_ref, x_ref, w_ref, o_ref, *, transpose):
+    del te_ref  # the block specs read it
+    t = pl.program_id(1)
+
+    @pl.when(t < used_ref[0])
+    def _product():
+        w = w_ref[...]
+        dims = (((1,), (1 if transpose else 0,)), ((), ()))
+        o_ref[...] = jax.lax.dot_general(
+            x_ref[...], w.astype(x_ref.dtype), dims,
+            precision=_mxu_precision(x_ref.dtype),
+            preferred_element_type=_F32).astype(o_ref.dtype)
+
+    @pl.when(t >= used_ref[0])
+    def _unused():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _last_used(t, used):
+    return jnp.minimum(t, used[0] - 1)
+
+
+def _gmm_pallas(x, w, tile_expert, used, *, tile, transpose, name):
+    R, K = x.shape
+    N = w.shape[1] if transpose else w.shape[2]
+    tn = _n_tile(K, N, w.dtype.itemsize, _BLOCK_BYTES)
+    if transpose:
+        w_spec = pl.BlockSpec(
+            (None, tn, K), lambda n, t, te, u: (te[_last_used(t, u)], n, 0))
+    else:
+        w_spec = pl.BlockSpec(
+            (None, K, tn), lambda n, t, te, u: (te[_last_used(t, u)], 0, n))
+    need = (2 * tile * K * x.dtype.itemsize + 2 * K * tn * w.dtype.itemsize
+            + tile * tn * (2 * x.dtype.itemsize + 8))
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, transpose=transpose),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(N // tn, R // tile),
+            in_specs=[pl.BlockSpec((tile, K), lambda n, t, te, u: (
+                _last_used(t, u), 0)), w_spec],
+            out_specs=pl.BlockSpec((tile, tn), lambda n, t, te, u: (t, n)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((R, N), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=min(need + 16 * 2 ** 20, 110 * 2 ** 20)),
+        name=name,
+        interpret=interpret_mode(),
+    )(tile_expert, used, x, w)
+
+
+def _gmm_xla(x, w, tile_expert, used, *, tile, transpose, name):
+    del name
+    R, K = x.shape
+    tiles = R // tile
+    picked = w[tile_expert].astype(x.dtype)  # [tiles, K, N] (or [.., N, K])
+    out = jnp.einsum("tmk,tnk->tmn" if transpose else "tmk,tkn->tmn",
+                     x.reshape(tiles, tile, K), picked,
+                     preferred_element_type=_F32)
+    live = jnp.arange(tiles)[:, None, None] < used[0]
+    return jnp.where(live, out, 0.0).astype(x.dtype).reshape(R, -1)
+
+
+def _tgmm_kernel(te_ref, used_ref, x_ref, dy_ref, o_ref, acc_ref, *, tiles):
+    t, used = pl.program_id(1), used_ref[0]
+    e = te_ref[t]
+    first = (t == 0) | (te_ref[jnp.maximum(t - 1, 0)] != e)
+    last = (t == used - 1) | (te_ref[jnp.minimum(t + 1, tiles - 1)] != e)
+    live = t < used
+
+    @pl.when(live & first)
+    def _start():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(live)
+    def _product():
+        # contract over the rows (axis 0 of both): x^T . dy
+        acc_ref[...] += jax.lax.dot_general(
+            x_ref[...], dy_ref[...], (((0,), (0,)), ((), ())),
+            precision=_mxu_precision(x_ref.dtype),
+            preferred_element_type=_F32)
+
+    @pl.when(live & last)
+    def _finish():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _tgmm_pallas(x, dy, tile_expert, used, *, tile, experts, dtype, name):
+    R, K = x.shape
+    N = dy.shape[1]
+    tn = _n_tile(K, N, 4, _ACC_BYTES)
+    tiles = R // tile
+    need = (2 * tile * (K + tn) * x.dtype.itemsize
+            + K * tn * (8 + 2 * jnp.dtype(dtype).itemsize))
+    return pl.pallas_call(
+        functools.partial(_tgmm_kernel, tiles=tiles),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(N // tn, tiles),
+            in_specs=[
+                pl.BlockSpec((tile, K), lambda n, t, te, u: (
+                    _last_used(t, u), 0)),
+                pl.BlockSpec((tile, tn), lambda n, t, te, u: (
+                    _last_used(t, u), n))],
+            out_specs=pl.BlockSpec((None, K, tn), lambda n, t, te, u: (
+                te[_last_used(t, u)], 0, n)),
+            scratch_shapes=[pltpu.VMEM((K, tn), _F32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((experts, K, N), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=min(need + 16 * 2 ** 20, 110 * 2 ** 20)),
+        name=name,
+        interpret=interpret_mode(),
+    )(tile_expert, used, x, dy)
+
+
+def _tgmm_xla(x, dy, tile_expert, used, *, tile, experts, dtype, name):
+    del name
+    R, K = x.shape
+    tiles = R // tile
+    per_tile = jnp.einsum("tmk,tmn->tkn", x.reshape(tiles, tile, K),
+                          dy.reshape(tiles, tile, -1),
+                          preferred_element_type=_F32)
+    live = jnp.arange(tiles)[:, None, None] < used[0]
+    return jax.ops.segment_sum(jnp.where(live, per_tile, 0.0), tile_expert,
+                               experts).astype(dtype)
+
+
+def _grouped_call(pallas, xla, tiled: bool, *args, **static):
+    """The kernel where lowering for the TPU and the shapes tile, its XLA
+    form (a gather of the tiles' weight blocks: small shapes, tests)
+    everywhere else."""
+    if not (use_pallas() and tiled):
+        return xla(*args, **static)
+    return platform_dispatch(functools.partial(pallas, **static),
+                             functools.partial(xla, **static), *args)
+
+
+def _gmm(x, w, tile_expert, used, tile, transpose=False, name="moe_gmm"):
+    K, N = (w.shape[2], w.shape[1]) if transpose else w.shape[1:]
+    tiled = K % _LANES == 0 and N % _LANES == 0 and tile % 16 == 0
+    return _grouped_call(_gmm_pallas, _gmm_xla, tiled, x, w, tile_expert,
+                         used, tile=tile, transpose=transpose, name=name)
+
+
+def _tgmm(x, dy, tile_expert, used, tile, experts, dtype):
+    tiled = (x.shape[1] % _LANES == 0 and dy.shape[1] % _LANES == 0
+             and tile % 16 == 0)
+    return _grouped_call(_tgmm_pallas, _tgmm_xla, tiled, x, dy, tile_expert,
+                         used, tile=tile, experts=experts, dtype=dtype,
+                         name="moe_gmm_dw")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def grouped_ffn(act, tile, xs, w_in, w_gate, w_out, tile_expert, used):
+    """The held experts' gated FFN over the sorted buffer xs [R, D] (the
+    activations' type; `group_rows` lays it out: tile t of `tile` rows is
+    expert `tile_expert[t]`'s, the first `used[0]` tiles hold groups):
+    row r -> (act(x_r W_gate[e]) * (x_r W_in[e])) W_out[e], e its tile's
+    expert, at the XLA einsums' rounding points (each product rounded to
+    the activations' type). w_in, w_gate [E, D, F], w_out [E, F, D].
+    -> [R, D]; rows of unused tiles are zero.
+
+    Differentiable in xs and the three weights: the rows' gradient is the
+    grouped product with the weights transposed, a weight's the sum over
+    its expert's tiles of rows^T . cotangent, accumulated in float32 and
+    rounded once. The backward reads the two up products again, which the
+    forward rule names (`GROUPED_RESIDUAL_NAMES`) for a checkpoint to keep;
+    the activation's part is recomputed (elementwise)."""
+    return _grouped_fwd(act, tile, xs, w_in, w_gate, w_out, tile_expert,
+                        used)[0]
+
+
+def _grouped_fwd(act, tile, xs, w_in, w_gate, w_out, tile_expert, used):
+    h = _gmm(xs, w_in, tile_expert, used, tile)
+    g = _gmm(xs, w_gate, tile_expert, used, tile)
+    h = checkpoint_name(h, GROUPED_RESIDUAL_NAMES[0])
+    g = checkpoint_name(g, GROUPED_RESIDUAL_NAMES[1])
+    y = _gmm(act(g) * h, w_out, tile_expert, used, tile)
+    return y, (xs, h, g, w_in, w_gate, w_out, tile_expert, used)
+
+
+def _grouped_bwd(act, tile, res, dy):
+    xs, h, g, w_in, w_gate, w_out, tile_expert, used = res
+    E = w_in.shape[0]
+    a, gated = jax.vjp(lambda g, h: act(g) * h, g, h)
+    with jax.named_scope("experts_bwd_rows"):
+        da = _gmm(dy, w_out, tile_expert, used, tile, True, "moe_gmm_dx")
+        dg, dh = gated(da)
+        dxs = (_gmm(dh, w_in, tile_expert, used, tile, True, "moe_gmm_dx")
+               + _gmm(dg, w_gate, tile_expert, used, tile, True,
+                      "moe_gmm_dx"))
+    with jax.named_scope("experts_bwd_weights"):
+        d_in = _tgmm(xs, dh, tile_expert, used, tile, E, w_in.dtype)
+        d_gate = _tgmm(xs, dg, tile_expert, used, tile, E, w_gate.dtype)
+        d_out = _tgmm(a, dy, tile_expert, used, tile, E, w_out.dtype)
+    return dxs, d_in, d_gate, d_out, None, None
+
+
+grouped_ffn.defvjp(_grouped_fwd, _grouped_bwd)

@@ -146,17 +146,29 @@ def make_train_step(cfg: ModelConfig, optimizer: optax.GradientTransformation,
     (donate state for in-place HBM update). forward_fn overrides the model
     forward (see make_pp_train_step)."""
 
+    if cfg.is_stack and cfg.untrainable:
+        raise NotImplementedError(cfg.untrainable)
+    # a stack that holds experts: the step counts every expert layer's
+    # choices, for the router's bias and the `train_moe_*` counters
+    counts_routes = cfg.is_stack and "moe" in cfg.second_halves
+
     def step(state: TrainState, batch: Dict[str, jax.Array]):
         def lossf(params):
-            return loss_fn(params, batch, cfg, forward_fn=forward_fn)
+            return loss_fn(params, batch, cfg, forward_fn=forward_fn,
+                           **({"route_counts": True} if counts_routes else {}))
 
         (_, metrics), grads = jax.value_and_grad(lossf, has_aux=True)(state["params"])
+        if counts_routes:
+            metrics, counts = metrics
         with jax.named_scope("optimizer"):
             updates, new_opt = optimizer.update(
                 grads, state["opt_state"], state["params"]
             )
             new_params = optax.apply_updates(state["params"], updates)
         metrics = dict(metrics)
+        if counts_routes:
+            new_params, moe = _after_update(cfg, new_params, counts, batch)
+            metrics.update(moe)
         metrics["grad_norm"] = optax.global_norm(grads)
         metrics["step"] = state["step"]
         return (
@@ -165,6 +177,46 @@ def make_train_step(cfg: ModelConfig, optimizer: optax.GradientTransformation,
         )
 
     return step
+
+
+def _after_update(cfg, params, counts, batch):
+    """What a step does with its expert layers' `counts` (int32 [expert
+    layers, router outputs]) once the optimizer has moved the weights: the
+    router's bias a step towards an even load (models/stack.py
+    `move_router_bias`: the bias has no gradient, and whatever the
+    optimizer made of its zeros is overwritten), and the step's numbers,
+    each summed over the expert layers: the choices that fell on held
+    experts (the rows the grouped products ran over), the fullest held
+    expert's rows, the sorted buffers' rows, the biases moved up, and the
+    rows the fullest layer's routing needed BEYOND its buffer (0 in a sound
+    step; past 0 the layer's output is NaN) -> (params, the numbers as
+    metrics). They leave the step in its metrics and nowhere else: the
+    device never waits for the host, and a loop that hands what it read to
+    `train.report` (or to `profiler.publish_moe_step` itself) adds them to
+    the `train_moe_*` counters there, where a step past its bound raises."""
+    from ..models import stack
+    from ..models.transformer import moe_grouped
+    from ..parallel.sharding import _current_mesh
+
+    with jax.named_scope("bias_update"):
+        if cfg.router_bias_rate:
+            params = stack.move_router_bias(params, counts, cfg)
+        first, E = cfg.experts_first, cfg.num_experts
+        held = counts[:, first:first + E]
+        n = counts.astype(jnp.float32)
+        moved_up = jnp.sum(jnp.mean(n, axis=1, keepdims=True) > n) \
+            if cfg.router_bias_rate else jnp.zeros((), jnp.int32)
+        grouped = moe_grouped(cfg, *batch["tokens"].shape, _current_mesh())
+        tile, bound = grouped or (1, 0)
+        needed = jnp.max(jnp.sum(
+            jnp.maximum(-(-held // tile), 1) * tile, axis=1))
+        short = jnp.maximum(needed - bound, 0) if bound \
+            else jnp.zeros((), jnp.int32)
+    return params, {
+        "moe_choices_held": jnp.sum(held),
+        "moe_rows_max": jnp.sum(jnp.max(held, axis=1)),
+        "moe_rows_bound": jnp.asarray(bound * counts.shape[0]),
+        "moe_bias_moved": moved_up, "moe_rows_short": short}
 
 
 def make_pp_train_step(

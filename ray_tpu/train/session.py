@@ -66,9 +66,11 @@ class _TrainSession:
         self.finished = False
 
     def report(self, metrics: Dict[str, Any], checkpoint: Optional[Checkpoint] = None):
-        from ..util import timeline, tracing
+        from ..util import profiler, timeline, tracing
 
         with tracing.region("train.report"):
+            # a step's expert layers, where the loop reports its metrics
+            profiler.publish_moe_step(metrics)
             timeline.record(
                 "train/report", "i", cat="train", pid="train",
                 tid=f"rank{self.context.world_rank}",

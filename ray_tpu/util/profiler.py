@@ -52,7 +52,7 @@ import traceback
 from typing import Any, Dict, List, Optional
 
 from ..core.config import config, declare
-from ..core.metrics import Gauge
+from ..core.metrics import Counter, Gauge
 
 __all__ = [
     "dump_stacks", "format_stacks", "SamplingProfiler", "merge_collapsed",
@@ -106,6 +106,25 @@ _g_remat_kept = Gauge("train_remat_kept_bytes",
                       "for its backward under remat, by the name the layer "
                       "loop's checkpoint may save them under, tagged name= "
                       "(the loop traced last; 0: a name it does not save)")
+# a train step's expert layers (train/lm.py), published on the host from the
+# step's metrics (`publish_moe_step`): each summed over the expert layers and
+# added up step by step, so a reader divides by `train_moe_steps`
+_c_moe = {name: Counter(name, text) for name, text in (
+    ("train_moe_steps", "Train steps that published their expert layers"),
+    ("train_moe_choices_held",
+     "Tokens' choices that fell on experts this chip holds: the rows its "
+     "grouped products ran over, before a group's padding to whole tiles"),
+    ("train_moe_rows_max",
+     "Rows of the fullest held expert, an expert layer's summed over the "
+     "expert layers"),
+    ("train_moe_rows_bound",
+     "Rows of the sorted buffers (the static bound a step's routing must "
+     "stay within; 0 where the experts take another form)"),
+    ("train_moe_bias_moved", "Experts whose router bias a step moved UP"),
+    ("train_moe_rows_overflow",
+     "Steps whose routing needed more rows than the buffer has: the step "
+     "fails"),
+)}
 _g_live_arrays = Gauge("device_live_array_count",
                        "Number of live jax arrays held by this process")
 _g_live_bytes = Gauge("device_live_array_bytes",
@@ -464,6 +483,28 @@ def publish_remat_kept(kept_bytes: Dict[str, int]) -> None:
     """Set `train_remat_kept_bytes`, name by name (0: a name not saved)."""
     for name, nbytes in kept_bytes.items():
         _g_remat_kept.set(nbytes, {"name": name})
+
+
+def publish_moe_step(metrics) -> None:
+    """One train step's expert layers into the `train_moe_*` counters, on
+    the host, from the `moe_*` numbers of the step's own metrics as a loop
+    read them (train/lm.py `_after_update`; `train.report` calls this with
+    what it is handed; a dict without them is left alone). A step whose
+    routing needed more rows than a layer's sorted buffer holds RAISES: the
+    layer's output was poisoned, nothing was dropped silently."""
+    if "moe_choices_held" not in metrics:
+        return
+    _c_moe["train_moe_steps"].inc()
+    for name in ("choices_held", "rows_max", "rows_bound", "bias_moved"):
+        _c_moe[f"train_moe_{name}"].inc(float(metrics[f"moe_{name}"]))
+    short = int(metrics["moe_rows_short"])
+    if short:
+        _c_moe["train_moe_rows_overflow"].inc()
+        raise RuntimeError(
+            f"a train step's routing needs {short} rows more than a layer's "
+            "sorted expert buffer holds (ops/moe.py grouped_rows_bound): "
+            "the held experts took more than twice their even share of the "
+            "choices. Nothing is dropped: the step fails")
 
 
 def device_memory_snapshot() -> Dict[str, Any]:

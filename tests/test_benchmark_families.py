@@ -82,7 +82,8 @@ LATER_CELLS = ("longcat-flash-omni.serve-docs",  # PR 39
                "smallthinker-21b-a3b.serve-mixedlen",  # PR 41
                "granite-4.0-h-micro.serve-chat-burst",  # PR 45
                "kanana-2-30b-a3b.serve-agent",  # PR 48
-               "solar-open2-250b.serve-mixedlen")  # PR 52
+               "solar-open2-250b.serve-mixedlen",  # PR 52
+               "trinity-mini.train-packed-x4")  # PR 54: the second TRAIN cell
 
 
 # metrics that later PRs appended for cells that were there already
@@ -903,3 +904,142 @@ def test_the_solar_names_file_adds_the_shared_experts_width_and_removes_nothing(
     assert trace_reduce.group_seconds(trace, "moe_ffn") == (3.0, 8.0)
     assert common.load_reader("shared_expert_device_share")({"trace": trace}) == 15.0
     assert common.load_reader("gdn_chunk_device_share")({"trace": trace}) == 20.0
+
+
+# -- the afmoe family (PR 54): the second TRAIN cell -------------------------
+
+TRINITY = "trinity-mini"
+TRINITY_CELL = TRINITY + ".train-packed-x4"
+TRINITY_READERS = ["flash_window_fwd_roofline.train",
+                   "flash_window_bwd_roofline.train",
+                   "moe_grouped_roofline.train", "moe_ffn_device_share.train",
+                   "flash_attn_device_share.train",
+                   "moe_rows_max_over_mean.train",
+                   "moe_row_buffer_fill_share.train"]
+
+
+def _trinity_trace():
+    """A traced step's operations as the chip names them (chip, PR 54)."""
+    q = "bf16[4,32,8192,128]{3,2,1,0}"
+    return {"busy_s": 10.0, "window_s": 10.5, "modules": {}, "module_ops": {},
+            "ops": {
+                f"%flash_fwd_window.12 = ({q}, f32[4,32,8192,128]) custom-call({q} %a)": [0.3, 24],
+                f"%flash_fwd.1 = ({q}, f32[4,32,8192,128]) custom-call({q} %a)": [0.16, 8],
+                f"%flash_bwd_window_dq.14 = {q} custom-call({q} %a)": [0.28, 24],
+                f"%flash_bwd_window_dkv.14 = (f32[4,32,8192,128]) custom-call({q} %a)": [0.44, 24],
+                f"%flash_bwd_dq.1 = {q} custom-call({q} %a)": [0.18, 8],
+                "%moe_gmm.52 = bf16[73728,2048]{1,0} custom-call(s32[144] %t)": [0.1, 96],
+                "%moe_gmm_dx.43 = bf16[73728,2048]{1,0} custom-call(s32[144] %t)": [0.1, 96],
+                "%moe_gmm_dw.44 = bf16[16,1024,2048]{2,1,0} custom-call(s32[144] %t)": [0.1, 96],
+                "%fusion.1407 = f32[32768,2048]{1,0} fusion(s32[73728]{0} %i, f32[73728,2048]{1,0} %y)": [0.15, 24],
+                "%fusion.1404 = f32[262144]{0} fusion(f32[4,8192,128]{2,1,0} %s, s32[262144]{0} %i)": [0.06, 24],
+                "%fusion.1408 = bf16[4,8192,1024]{2,1,0} fusion(bf16[4,8192,2048]{2,1,0} %h, bf16[2048,1024]{1,0} %w)": [0.02, 24],
+                "%fusion.667 = bf16[4,8192,6144]{2,1,0} fusion(bf16[4,8192,2048]{2,1,0} %h, bf16[2048,6144]{1,0} %w)": [0.03, 8],
+                "%while.7 = (bf16[73728,2048]{1,0}, s32[]) while(%tuple.1)": [9.0, 8],
+            }}
+
+
+def test_the_afmoe_names_file_adds_its_groups_and_removes_nothing():
+    with open(os.path.join(common.HERE, "trace_names.json")) as f:
+        base = json.load(f)["groups"]
+    merged = trace_reduce.load_names()["groups"]
+    for group, entries in base.items():
+        assert merged[group][:len(entries)] == entries
+    trace = _trinity_trace()
+    seconds = lambda group: trace_reduce.group_seconds(trace, group)  # noqa: E731
+    # the full layer's calls and the window layers' are two rooflines
+    assert seconds("flash_fwd") == (0.16, 8.0)
+    assert seconds("flash_window_fwd") == (0.3, 24.0)
+    assert seconds("flash_bwd") == (0.18, 8.0)
+    assert seconds("flash_window_bwd") == (pytest.approx(0.72), 48.0)
+    assert seconds("flash_window_bwd_count") == (0.28, 24.0)
+    assert seconds("moe_grouped") == (pytest.approx(0.3), 288.0)
+    assert seconds("flash_attn_train")[0] == pytest.approx(1.36)
+    # the kernels, the sorted rows' gather and scatter, the router's scores
+    # and choices, the shared expert; not the dense layer, not the loop
+    assert seconds("moe_ffn_train")[0] == pytest.approx(0.3 + 0.15 + 0.06 + 0.02)
+
+
+def test_the_afmoe_readers_reach_the_counts_through_the_family():
+    spec = common.load_json("configs", TRINITY + ".json")
+    cell = common.load_cell(TRINITY_CELL)
+    family = common.family(spec)
+    peaks = common.peaks_for("TPU v5 lite")
+    steps = 8
+    # the step's own metrics on the first batch, as the driver's loop read
+    # them (the expert layers' numbers leave the step nowhere else)
+    first = {"ce_loss": 10.5, "moe_choices_held": 4 * 32768.0,
+             "moe_rows_max": 4 * 4096.0, "moe_rows_bound": 4 * 73728.0,
+             "moe_bias_moved": 250.0, "moe_rows_short": 0.0}
+    ctx = {"cell": cell, "spec": spec, "family": family, "chips": 1,
+           "peaks": peaks, "trace": _trinity_trace(),
+           "run": {"traced_steps": steps, "tokens_per_s": 32768.0,
+                   "first_metrics": first}}
+    read = {name: common.load_reader(name)(ctx) for name in TRINITY_READERS}
+    work = family.work["flash_window_fwd"](spec, 4, 8192)
+    ideal = max(work["flops"] / peaks["bf16_flops"],
+                work["bytes"] / peaks["hbm_bytes_per_s"])
+    assert read["flash_window_fwd_roofline.train"] == pytest.approx(
+        100 * ideal * 24 / 0.3)
+    assert 1 < read["flash_window_bwd_roofline.train"] < 100
+    grouped = family.work["moe_grouped"](spec, 32768)
+    ideal = max(grouped["flops"] / peaks["bf16_flops"],
+                grouped["bytes"] / peaks["hbm_bytes_per_s"])
+    assert read["moe_grouped_roofline.train"] == pytest.approx(
+        100 * ideal * 4 * steps / 0.3)
+    assert read["moe_ffn_device_share.train"] == pytest.approx(5.3)
+    assert read["flash_attn_device_share.train"] == pytest.approx(13.6)
+    assert read["moe_rows_max_over_mean.train"] == pytest.approx(2.0)
+    assert read["moe_row_buffer_fill_share.train"] == pytest.approx(
+        100 * 32768 / 73728)
+    mfu = common.load_reader("train_mfu")(ctx)
+    assert mfu == pytest.approx(100 * family.train_flops_per_token(spec, 8192)
+                                * 32768 / peaks["bf16_flops"])
+
+
+@pytest.mark.parametrize("metric", TRINITY_READERS)
+def test_an_afmoe_reader_returns_nothing_where_the_program_has_nothing(
+        metric, monkeypatch):
+    """On the parent (no such kernel, no such counter) and in a cell of
+    another family a new reader reads nothing and does not raise."""
+    monkeypatch.setattr(common, "counters", lambda: {})
+    spec = common.load_json("configs", "mistral-7b.json")
+    cell = common.load_cell("mistral-7b.train-packed")
+    ctx = {"cell": cell, "spec": spec, "family": common.family(spec),
+           "chips": 1, "peaks": common.peaks_for("TPU v5 lite"),
+           "run": {"traced_steps": 4, "tokens_per_s": 8000.0,
+                   "first_metrics": {"loss": 9.0, "ce_loss": 9.0}},
+           "trace": {"busy_s": 4.0, "ops": {
+               "%paged_decode.1 = bf16[1] custom-call(s32[1] %a)": [1.0, 4]},
+               "modules": {}, "module_ops": {}}}
+    assert common.load_reader(metric)(ctx) is None
+
+
+def test_afmoe_weights_are_seeded_and_in_the_programs_layout():
+    import jax.numpy as jnp
+
+    spec = tiny_spec(TRINITY)
+    family = common.family(spec)
+    make = jax.jit(lambda k: family.init_weights(spec, k))
+    a, b = make(jax.random.PRNGKey(1)), make(jax.random.PRNGKey(1))
+    c = make(jax.random.PRNGKey(2))
+    leaves = jax.tree.leaves(a)
+    assert all(bool(jnp.array_equal(x, y)) for x, y in zip(leaves, jax.tree.leaves(b)))
+    assert not bool(jnp.array_equal(a["embed"], c["embed"]))
+    # every leaf bfloat16 but the router's bias, a float32 buffer
+    for segment in a["layers"]:
+        for lp in segment:
+            for name, leaf in lp.items():
+                assert leaf.dtype == (jnp.float32 if name == "router_bias"
+                                      else jnp.bfloat16), name
+    cfg = family.model_config(spec)
+    from ray_tpu.models import stack
+
+    shapes = jax.eval_shape(lambda k: stack.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    assert jax.tree.map(lambda x: x.shape, a) == jax.tree.map(
+        lambda x: x.shape, shapes)
+    bias = a["layers"][1][0]["router_bias"]
+    assert float(jnp.abs(bias).max()) > 0 and abs(float(bias.mean())) < 1e-3
+    with pytest.raises(ValueError, match="n_group"):
+        family.model_config({**spec, "n_group": 2})

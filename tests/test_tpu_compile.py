@@ -1104,3 +1104,71 @@ def test_the_channel_decay_cells_weights_are_drawn_a_period_at_a_time(
     wanted = spec["memory_analysis"][cell["name"]]["init_weights"]
     assert abs(memory.output_size_in_bytes / gib - wanted["outputs"]) < 0.01
     assert memory.temp_size_in_bytes / gib < wanted["temporaries"] + 0.25
+
+
+def test_the_trained_stacks_step_fits_and_holds_no_score_matrix(
+        topo, no_persistent_cache, tmp_path):
+    """`trinity-mini.train-packed-x4` as the benchmark sizes it (5 layers of
+    the published widths, 16 of 128 experts, 4 x 8192, bf16 masters, the
+    factored optimizer), the program asked for its own shapes: the chip's
+    compiler accepts the step with its window flash kernels and grouped
+    expert products, the buffer assignment's total is what the
+    configuration's file says and under 14.5 GiB, and no program holds a
+    [T, T] score matrix (the masked softmax that stood where a window
+    binds is gone)."""
+    from benchmark import common
+    from ray_tpu.train.lm import make_optimizer, make_train_step
+
+    cell = common.load_cell("trinity-mini.train-packed-x4")
+    spec = cell["config"]
+    family = common.family(spec)
+    cfg = family.model_config(spec)
+    opt = make_optimizer(**cell["recipe"])
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def state_of(key):
+        params = family.init_weights(spec, key)
+        return {"step": jnp.zeros((), I32), "params": params,
+                "opt_state": opt.init(params)}
+
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(state_of, jax.random.PRNGKey(0)))
+    rows, T = cell["traffic"]["rows_per_step"], cell["traffic"]["row_tokens"]
+    batch = {k: jax.ShapeDtypeStruct((rows, T), I32, sharding=one_chip)
+             for k in ("tokens", "targets")}
+    compiled = jax.jit(make_train_step(cfg, opt), donate_argnums=0).lower(
+        state, batch).compile(compiler_options={
+            "xla_dump_to": str(tmp_path), "xla_dump_hlo_as_text": True})
+    report = max(tmp_path.glob("*memory-usage-report.txt"),
+                 key=lambda f: f.stat().st_size).read_text()
+    gib = 2 ** 30
+    total = int(re.match(r"Total bytes used: (\d+)", report).group(1)) / gib
+    wanted = spec["memory_analysis"][cell["name"]]["train_step 4x8192"]
+    print(f"buffer assignment: {total:.3f} GiB; the file says {wanted}")
+    assert abs(total - wanted["total"]) < 0.3 and total < 14.5
+    text = compiled.as_text()
+    calls = collections.Counter(re.findall(
+        r"%(flash_\w+?|moe_gmm\w*?)(?:\.\d+)? = ", text))
+    # a scan's body and a layer alone each hold their kernels once
+    assert calls["flash_fwd_window"] == calls["flash_bwd_window_dq"] == \
+        calls["flash_bwd_window_dkv"] == 2
+    assert calls["flash_fwd"] == calls["flash_bwd_dq"] == 1
+    assert calls["moe_gmm_dx"] == calls["moe_gmm_dw"] == 6
+    assert not re.search(rf"\[[\d,]*{T},{T}\]", text)  # no [T, T] scores
+    # the largest temporaries are the float32 logits and their cotangent
+    assert f"f32[{rows},{T},{spec['vocab_size']}]" in text
+    # `moe_ffn_device_share.train` finds the experts' XLA operations by this
+    # cell's literal shapes (benchmark/trace_names/afmoe.json): each of its
+    # patterns still names an operation the step RUNS (not one inside a
+    # fusion, which the trace never shows). A change to `grouped_rows_bound`,
+    # `grouped_tile` or the rows a step has to re-key that group, or fail here
+    run, fused = [], False
+    for line in text.splitlines():
+        if line.endswith("{"):
+            fused = "fused_computation" in line.split("(")[0]
+        elif not fused and " = " in line:
+            run.append(line.strip().removeprefix("ROOT "))
+    names = common.load_json("trace_names", "afmoe.json")["groups"]
+    for entry in names["moe_ffn_train"]:
+        assert any(re.search(entry["match"], op) for op in run), entry
