@@ -127,6 +127,79 @@ class TestFlashAttention:
         np.testing.assert_allclose(out, ref, atol=2e-3, rtol=2e-3)
 
 
+# The one-pass backward (ops/attention.py `_bwd_kernel`): dq's blocks are
+# read, added to and written back by the kernel's own DMAs, so what matters
+# is how soon a block comes again: (T, block_q, block_k, window, causal, H,
+# KVH). nq of 1, 2, 3 and 8 (the same block in consecutive steps, every
+# other step, ...), GQA groups of 1, 4 and 8, windows whose last span names
+# blocks past the sequence's end, at like and unlike blocks.
+_ONE_PASS = [
+    (128, 128, 128, None, True, 2, 2), (128, 128, 128, None, False, 2, 2),
+    (256, 128, 128, None, True, 4, 1), (256, 128, 128, None, False, 4, 1),
+    (384, 128, 128, None, True, 8, 1), (384, 128, 128, None, False, 8, 1),
+    (1024, 128, 128, None, True, 2, 2), (1024, 128, 128, None, False, 2, 1),
+    (512, 256, 128, None, True, 4, 1), (512, 128, 256, None, True, 4, 1),
+    (384, 128, 128, None, False, 4, 4),
+    (640, 128, 128, 300, True, 4, 1), (640, 128, 128, 129, True, 8, 1),
+    (768, 256, 128, 300, True, 2, 2), (768, 128, 256, 200, True, 4, 1),
+    (1024, 128, 128, 256, True, 2, 1), (256, 128, 128, 100, True, 8, 1),
+]
+
+
+class TestFlashBackwardOnePass:
+    @pytest.fixture(autouse=True)
+    def _kernels(self, monkeypatch):
+        monkeypatch.setenv("RAY_TPU_FORCE_PALLAS", "1")
+
+    @staticmethod
+    def _qkv(T, H, KVH, seed=11):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+        return (_rand(ks[0], (1, T, H, 128)), _rand(ks[1], (1, T, KVH, 128)),
+                _rand(ks[2], (1, T, KVH, 128)), _rand(ks[3], (1, T, H, 128)))
+
+    @pytest.mark.parametrize("T,bq,bk,window,causal,H,KVH", _ONE_PASS)
+    def test_dq_dk_dv_match_reference(self, T, bq, bk, window, causal, H, KVH):
+        q, k, v, do = self._qkv(T, H, KVH)
+
+        def loss(attend):
+            return lambda q, k, v: jnp.sum(attend(q, k, v) * do)
+
+        ours = functools.partial(flash_attention, causal=causal, block_q=bq,
+                                 block_k=bk, window=window)
+        ref = functools.partial(mha_reference, causal=causal, window=window)
+        for got, want in zip(jax.grad(loss(ours), (0, 1, 2))(q, k, v),
+                             jax.grad(loss(ref), (0, 1, 2))(q, k, v)):
+            np.testing.assert_allclose(got, want, atol=2e-5)
+
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("T", [128, 384])
+    def test_an_lse_cotangent_folds_in(self, T, causal):
+        """Ring attention's merge differentiates through lse: the cotangent
+        shifts delta, in the one kernel as in the pair before it."""
+        from ray_tpu.ops.attention import flash_attention_with_lse
+
+        q, k, v, do = self._qkv(T, 4, 2)
+        dl = _rand(jax.random.PRNGKey(12), (1, 4, T))
+
+        def ours(q, k, v):
+            o, lse = flash_attention_with_lse(q, k, v, causal=causal,
+                                              block_q=128, block_k=128)
+            return jnp.sum(o * do) + jnp.sum(lse * dl)
+
+        def ref(q, k, v):
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, 2, axis=2),
+                           precision="highest") * 128 ** -0.5
+            if causal:
+                s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -2e30)
+            lse = jax.scipy.special.logsumexp(s, axis=-1)
+            return (jnp.sum(mha_reference(q, k, v, causal=causal) * do)
+                    + jnp.sum(lse * dl))
+
+        for got, want in zip(jax.grad(ours, (0, 1, 2))(q, k, v),
+                             jax.grad(ref, (0, 1, 2))(q, k, v)):
+            np.testing.assert_allclose(got, want, atol=2e-5)
+
+
 class TestNorms:
     def test_rms_norm(self, kernel_mode):
         x = _rand(jax.random.PRNGKey(0), (4, 256, 256))
