@@ -36,6 +36,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import hashlib
+import itertools
 import os
 import queue
 import threading
@@ -62,6 +63,7 @@ from ..models.transformer import (
 )
 from ..ops import gather_pages, pool_shape, scatter_pages
 from .config import SpeculationConfig
+from .program import Arg, Program
 from .spec_decode import SpecDecoder
 
 logger = get_logger("serve.engine")
@@ -941,10 +943,7 @@ class InferenceEngine:
         mesh=None,
         draft_params=None,
     ):
-        self.cfg = model_cfg
-        self.ecfg = engine_cfg
-        self.mesh = mesh
-        self._tp = 1
+        self._describe(model_cfg, engine_cfg, mesh)
         # the `replica.start` trace this engine was built under
         # (`serve/llm.py start_engine` sets both): its id for `get_trace`,
         # and the finished tree, which outlives the span ring; None, []:
@@ -956,20 +955,16 @@ class InferenceEngine:
         # a token's kv heads in one row): the layers that cache keys and
         # values, which for a stack of unlike layers are its full-attention
         # layers alone
-        KVH = model_cfg.cache_dims[1]
         P, ps = engine_cfg.max_pages, engine_cfg.page_size
         pool = self.abstract_pool()
-        self._refuse_for_stack(mesh, engine_cfg)
+        # the allocator that serves the window page space
+        # (`cfg.window_paged`) beside `self.allocator`
+        self._window_allocator = (
+            PageAllocator(engine_cfg.max_window_pages) if self._ring else None)
         # What the model's layers keep per decode slot beside their pages
         # (conv tails, scan state, the window layers' rings), sized by
         # max_batch_size and the model: the empty tree for the one-block
         # models. Every program takes it and hands it back.
-        # the window page space (`cfg.window_paged`): the ring's width, and
-        # the allocator that serves it beside `self.allocator`
-        self._wide = self._wide_chunk()
-        self._ring = self._window_ring()
-        self._window_allocator = (
-            PageAllocator(engine_cfg.max_window_pages) if self._ring else None)
         self.state = self._new_state()
         # what the engine holds beside THE pool, whatever the slots hold:
         # per-slot state (tails, scan, delta-rule and state-space state)
@@ -977,34 +972,14 @@ class InferenceEngine:
         self._state_bytes = tree_bytes(self.state)
         # a sequence's start, shared by every chunked prompt's first
         # chunk (never donated: a chunk hands back a new state)
-        self._request_start = stack.new_request_state(
-            model_cfg, 1, jnp.dtype(model_cfg.dtype))
-        self._install_state = jax.jit(
-            tracing.named(functools.partial(
-                stack.install_state, cfg=model_cfg, page_size=ps),
-                "install_state"),
-            donate_argnums=(0,))
+        self._request_start = self._new_request_start()
         if mesh is not None:
-            from ..models.transformer import param_axes
-            from ..parallel.sharding import tree_shardings
-
-            axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-            self._tp = int(axis_sizes.get("tp", 1))
-            if self._tp > 1 and KVH % self._tp != 0:
-                raise ValueError(
-                    f"tp={self._tp} must divide kv_heads={KVH} to shard the page pool"
-                )
-            self.params = jax.device_put(
-                params, tree_shardings(param_axes(model_cfg), mesh)
-            )
+            self.params = jax.device_put(params, self._param_shardings())
             # tp>1: each shard holds its kv heads' lanes of every row
-            kv_sharding = NamedSharding(
-                mesh,
-                PartitionSpec(None, None, None, None,
-                              "tp" if self._tp > 1 else None),
-            )
-            self.k_pages = jax.device_put(jnp.zeros(pool.shape, pool.dtype), kv_sharding)
-            self.v_pages = jax.device_put(jnp.zeros(pool.shape, pool.dtype), kv_sharding)
+            self.k_pages = jax.device_put(
+                jnp.zeros(pool.shape, pool.dtype), self._kv_sharding)
+            self.v_pages = jax.device_put(
+                jnp.zeros(pool.shape, pool.dtype), self._kv_sharding)
         else:
             self.params = params
             self.k_pages = jnp.zeros(pool.shape, pool.dtype)
@@ -1076,13 +1051,8 @@ class InferenceEngine:
         self.slo_role = "engine"
         self._slo_on = slo.enabled()
         self._slo: Dict[str, slo.Digest] = {}
-        self._decode = self._build_decode()
-        self._prefill_cache: Dict[int, Any] = {}
-        self._chunk_fn = self._build_chunk_prefill()
-        scfg = engine_cfg.speculation
-        self._spec: Optional[SpecDecoder] = (
-            SpecDecoder(self, scfg, draft_params=draft_params)
-            if scfg is not None and scfg.enabled else None)
+        if self._spec is not None:
+            self._spec.start(draft_params)
         # tokens-per-decode-step accounting: committed tokens over slot
         # participations (plain: span per active slot per dispatch; spec:
         # one per active slot per round)
@@ -1105,17 +1075,8 @@ class InferenceEngine:
         self._inflight: Optional[_Span] = None
         carry = (jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32))
         if mesh is not None:
-            carry = jax.device_put(
-                carry, NamedSharding(mesh, PartitionSpec()))
+            carry = jax.device_put(carry, self._whole)
         self._carry = carry
-        # a sequence whose first token is still on the device joins its
-        # first span through the carry: its slot's row takes the token and
-        # the position (`_install_ready`), and the decode programs stay as
-        # they are
-        pinned = ({} if mesh is None else {"out_shardings": (
-            NamedSharding(mesh, PartitionSpec()),) * 2})
-        self._join_carry = self._under_mesh(jax.jit(
-            tracing.named(_join_carry, "join_carry"), **pinned))
         # first tokens the chunk programs drew and the host has not read
         # (`_First`), in the order of their dispatch; decode thread only
         self._firsts: List[_First] = []
@@ -1151,6 +1112,73 @@ class InferenceEngine:
         self._bucket_tokens = 0
         self._bucket_tokens_seen = 0
         self._chunk_tokens = 0
+
+    def _describe(self, model_cfg: ModelConfig, engine_cfg: EngineConfig,
+                  mesh) -> None:
+        """What an engine is before its first array, from the two configs
+        and the mesh alone: what they refuse, the sizes derived from them
+        (`_tp`, `_wide`, `_ring`), where a mesh places what, and every
+        jitted program (`programs`). `__init__` starts here and `abstract`
+        ends here."""
+        self.cfg, self.ecfg, self.mesh = model_cfg, engine_cfg, mesh
+        self._tp = 1
+        self._refuse_for_stack(mesh, engine_cfg)
+        # the second chunk program's rows, and the ring's width in the
+        # window page space (`cfg.window_paged`)
+        self._wide = self._wide_chunk()
+        self._ring = self._window_ring()
+        self._kv_sharding = self._whole = None
+        pinned: Dict[str, Any] = {}
+        if mesh is not None:
+            axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+            self._tp = int(axis_sizes.get("tp", 1))
+            KVH = model_cfg.cache_dims[1]
+            if self._tp > 1 and KVH % self._tp != 0:
+                raise ValueError(
+                    f"tp={self._tp} must divide kv_heads={KVH} to shard the page pool"
+                )
+            self._kv_sharding = NamedSharding(
+                mesh,
+                PartitionSpec(None, None, None, None,
+                              "tp" if self._tp > 1 else None),
+            )
+            self._whole = NamedSharding(mesh, PartitionSpec())
+            pinned["out_shardings"] = (self._whole,) * 2
+        self._install_state = jax.jit(
+            tracing.named(functools.partial(
+                stack.install_state, cfg=model_cfg,
+                page_size=engine_cfg.page_size),
+                "install_state"),
+            donate_argnums=(0,))
+        self._decode = self._build_decode()
+        self._prefill_cache: Dict[int, Any] = {}
+        self._chunk_fn = self._build_chunk_prefill()
+        # a sequence whose first token is still on the device joins its
+        # first span through the carry: its slot's row takes the token and
+        # the position (`_install_ready`), and the decode programs stay as
+        # they are
+        self._join_carry = self._under_mesh(jax.jit(
+            tracing.named(_join_carry, "join_carry"), **pinned))
+        scfg = engine_cfg.speculation
+        self._spec: Optional[SpecDecoder] = (
+            SpecDecoder(self, scfg)
+            if scfg is not None and scfg.enabled else None)
+
+    @classmethod
+    def abstract(cls, model_cfg: ModelConfig, engine_cfg: EngineConfig,
+                 mesh=None) -> "InferenceEngine":
+        """An engine without arrays: what `__init__` does up to its first
+        allocation. It answers `programs`, `abstract_pool` and
+        `abstract_state`, and serves nothing."""
+        engine = object.__new__(cls)
+        engine._describe(model_cfg, engine_cfg, mesh)
+        return engine
+
+    def _param_shardings(self):
+        from ..models.transformer import param_axes
+        from ..parallel.sharding import tree_shardings
+
+        return tree_shardings(param_axes(self.cfg), self.mesh)
 
     def phase(self, name: str, **attrs: Any) -> _Phase:
         """`engine.<name>` on the decode thread (spec_decode.py times its
@@ -1325,6 +1353,10 @@ class InferenceEngine:
             jnp.dtype(self.cfg.dtype), jnp.dtype(self.ecfg.cache_dtype),
             self.ecfg.max_window_pages)
 
+    def _new_request_start(self):
+        """`self._request_start`, from the model's config alone."""
+        return stack.new_request_state(self.cfg, 1, jnp.dtype(self.cfg.dtype))
+
     def abstract_state(self, sharding=None):
         """What the programs take beside the pool, `self.state`, as
         abstract arrays: the window page space's two pools where the model
@@ -1481,8 +1513,7 @@ class InferenceEngine:
             # the carry comes back as it goes in, replicated: left to the
             # partitioner's choice, another sharding would be another
             # program at the next call, compiled inside traffic
-            whole = NamedSharding(self.mesh, PartitionSpec())
-            pinned["out_shardings"] = (None,) * 5 + ((whole, whole),)
+            pinned["out_shardings"] = (None,) * 5 + ((self._whole,) * 2,)
         # `advanced` compiles the top-k/top-p sampler (one vocab sort per
         # step) as a SEPARATE program: default-sampling batches never pay
         # for it
@@ -1611,93 +1642,166 @@ class InferenceEngine:
 
         return call
 
+    def _programs(self, buckets=None, batch_sizes=None):
+        """Every program a replica compiles before it serves, in the order
+        `warmup` compiles them, each with THE list of what it takes (`Arg`s
+        and names of what the engine keeps: serve/program.py): the bucket
+        prefill a (bucket, padded batch), the decode program of each
+        sampler at its longest span (another span is another value of `n`),
+        both chunk programs the queue picks from (`_advance_chunk`), the
+        program that hands a slot's row of the carry a first token from
+        where a chunk program left it, and the one that hands a slot its
+        state. (A speculative round's verify follows them:
+        `SpecDecoder._programs`.)"""
+        ecfg = self.ecfg
+        B, pps, K = ecfg.max_batch_size, ecfg.pages_per_seq, ecfg.span_rows
+        i32, f32 = jnp.int32, jnp.float32
+        program = functools.partial(Program.under, self.mesh)
+
+        for bucket in (ecfg.prefill_buckets if buckets is None else buckets):
+            for Bp in (ecfg.prefill_tiers() if batch_sizes is None
+                       else batch_sizes):
+                yield program(
+                    f"prefill_bucket_{bucket}x{Bp}",
+                    self._prefill_fn(bucket, Bp),
+                    ("params", Arg((Bp, bucket), i32, 1), Arg((Bp,), i32, 1)),
+                    rows=Bp * bucket)
+        for advanced in (False, True):
+            span = self._decode(K, advanced)
+            yield program(
+                "decode_span" + ("_adv" if advanced else ""), span,
+                ("params", "k_pages", "v_pages",
+                 Arg((B,), i32), Arg((B,), i32),  # tokens, positions
+                 self._tables(Arg((B, pps), i32), Arg((B, self._ring), i32)),
+                 # temperatures, top_p, top_k, the key
+                 Arg((B,), f32), Arg((B,), f32, 1), Arg((B,), i32),
+                 Arg((2,), jnp.uint32), "state",
+                 ("_carry.0", "_carry.1", Arg((B,), jnp.bool_, True))),
+                back=(None, None, "k_pages", "v_pages", "state", "_carry"),
+                jitted=span, steps=K)
+        if ecfg.chunked_prefill:
+            for C in filter(None, (ecfg.prefill_chunk, self._wide)):
+                yield program(
+                    f"chunk_prefill_{C}", self._chunk_fn(C),
+                    ("params", "k_pages", "v_pages",
+                     Arg((C,), i32), Arg((), i32),  # tokens, start
+                     self._tables(Arg((pps,), i32), Arg((self._ring,), i32)),
+                     Arg((), i32, C - 1),  # last_idx
+                     "state" if self._ring else "_request_start",
+                     Arg((3,), f32, (0.0, 1.0, 0.0)),  # `_how_to_sample`
+                     "_first_key"),
+                    # (all-zero tables wrote the trash pages alone)
+                    back=("token", None, "k_pages", "v_pages",
+                          "state" if self._ring else None),
+                    rows=C)
+            yield program(
+                "join_carry", self._join_carry,
+                ("_carry", Arg((), i32, host=True), "token",
+                 Arg((), i32, host=True)),  # the slot, the position
+                back="_carry")
+            if self.cfg.has_state and not self._ring:
+                yield program(
+                    "install_state", self._install_state,
+                    ("state", "_request_start", Arg((), i32),
+                     Arg((), i32, 1)),  # the slot, the sequence's length
+                    back="state")
+
+    def programs(self, params=None, sharding=None, buckets=None,
+                 batch_sizes=None) -> Dict[str, Program]:
+        """What this engine compiles and what each program takes, by the
+        name of its `engine.warmup.program` region and in `warmup`'s
+        order, from the two configs and the mesh alone: nothing is
+        allocated, and an engine without arrays (`abstract`) answers as a
+        running one does. `Program.args` are trees of
+        `jax.ShapeDtypeStruct`; `Program.lower()` lowers the jitted
+        program for them. `params`: the weights' shapes and dtypes, a tree
+        of arrays or of `jax.ShapeDtypeStruct`s (None: this engine's own).
+        `sharding`: where everything lies for an engine without a mesh, as
+        a described chip's `SingleDeviceSharding`; under a mesh the
+        weights, the pool and the rest lie as `__init__` places them."""
+        pool_at = rest = sharding
+        if self.mesh is not None:
+            pool_at, rest = self._kv_sharding, self._whole
+        params = self.params if params is None else params
+        if self.mesh is not None:
+            params = jax.tree.map(
+                lambda a, at: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                   sharding=at),
+                params, self._param_shardings())
+        pool = self.abstract_pool(pool_at)
+        B = self.ecfg.max_batch_size
+        kept = {
+            "params": params, "k_pages": pool,
+            "v_pages": None if self.cfg.latent_cache else pool,
+            "state": self.abstract_state(rest),
+            "_request_start": jax.eval_shape(self._new_request_start),
+            "_carry": (Arg((B,), jnp.int32),) * 2,
+            "_first_key": Arg((2,), jnp.uint32), "token": Arg((), jnp.int32),
+        }
+
+        def abstract(a):
+            if isinstance(a, str):
+                return jax.tree.map(abstract, _kept(kept.__getitem__, a),
+                                    is_leaf=lambda a: isinstance(a, Arg))
+            return jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=getattr(a, "sharding", None) or rest)
+
+        return {p.name: p.bind(abstract) for p in itertools.chain(
+            self._programs(buckets, batch_sizes),
+            self._spec._programs() if self._spec is not None else ())}
+
     def warmup(self, buckets=None, batch_sizes=None) -> None:
         """Compile the serving-path programs off the request path: prefill
         per (bucket, padded-batch) and the decode program of each sampler,
-        which runs every span the adaptive policy can pick. Call before
-        admitting traffic (the decode thread
-        must be idle: warmup threads the donated KV pages through the
-        compiled call exactly like step() does).
+        which runs every span the adaptive policy can pick (both samplers:
+        the first top-p/top-k request must not jit inside the decode loop
+        under live traffic). Call before admitting traffic (the decode
+        thread must be idle: warmup threads the donated KV pages through
+        the compiled call exactly like step() does).
 
         Reference analogue: vLLM's startup CUDA-graph capture /
         determinism warmup. Default compiles every configured bucket —
         pass buckets=[...] to warm only the shapes a deployment serves.
         """
-        # each program under a region of its own, from its first call to
-        # its blocked end: on a traced thread (`serve/llm.py start_engine`)
-        # the `xla.*` spans of what it compiles are that region's children
-        bucket_list = list(buckets) if buckets is not None else list(
-            self.ecfg.prefill_buckets)
-        sizes = (list(batch_sizes) if batch_sizes is not None
-                 else self.ecfg.prefill_tiers())
-        for bucket in bucket_list:
-            for Bp in sizes:
-                with tracing.region("engine.warmup.program",
-                                    program=f"prefill_bucket_{bucket}x{Bp}",
-                                    rows=Bp * bucket):
-                    jax.block_until_ready(self._prefill_fn(bucket, Bp)(
-                        self.params,
-                        jnp.ones((Bp, bucket), jnp.int32),
-                        jnp.ones((Bp,), jnp.int32),
-                    ))
-        B = self.ecfg.max_batch_size
-        pps = self.ecfg.pages_per_seq
-        K = self.ecfg.span_rows
-        # The decode programs, at their longest span: they take the resident
-        # pool (rebound through _run_decode: the warmup call consumes and
-        # replaces it). Another span is another value of an argument. Both
-        # samplers compile: the first top-p/top-k request must not jit
-        # inside the decode loop under live traffic.
-        for advanced in (False, True):
-            with tracing.region("engine.warmup.program", steps=K,
-                                program="decode_span"
-                                + ("_adv" if advanced else "")):
-                seq = self._run_decode(self._decode(K, advanced)(
-                    self.params, self.k_pages, self.v_pages,
-                    jnp.zeros((B,), jnp.int32),
-                    jnp.zeros((B,), jnp.int32),
-                    self._tables(jnp.zeros((B, pps), jnp.int32),
-                                 jnp.zeros((B, self._ring), jnp.int32)),
-                    jnp.zeros((B,), jnp.float32),
-                    jnp.ones((B,), jnp.float32),
-                    jnp.zeros((B,), jnp.int32),
-                    jax.random.PRNGKey(0), self.state,
-                    (*self._carry, jnp.ones((B,), bool)),
-                ))[0]
-                np.asarray(seq)  # block until compiled + executed
-        if self.ecfg.chunked_prefill:
-            # both chunk programs the queue picks from (`_advance_chunk`)
-            for C in filter(None, (self.ecfg.prefill_chunk, self._wide)):
-                with tracing.region("engine.warmup.program",
-                                    program=f"chunk_prefill_{C}", rows=C):
-                    token, row, self.k_pages, self.v_pages, state = \
-                        self._chunk_fn(C)(
-                            self.params, self.k_pages, self.v_pages,
-                            jnp.zeros((C,), jnp.int32), jnp.int32(0),
-                            self._tables(jnp.zeros((pps,), jnp.int32),
-                                         jnp.zeros((self._ring,), jnp.int32)),
-                            jnp.int32(C - 1),
-                            self.state if self._ring else self._request_start,
-                            _how_to_sample(0.0, 1.0, 0), self._first_key,
-                        )
-                    np.asarray(row)
-                if self._ring:  # all-zero tables wrote the trash pages alone
-                    self.state = state
-            # the program that hands a slot's row of the carry a first
-            # token from where a chunk program left it
-            with tracing.region("engine.warmup.program",
-                                program="join_carry"):
-                self._carry = jax.block_until_ready(self._join_carry(
-                    self._carry, np.int32(0), token, np.int32(0)))
-            if not self._ring and self.state:
-                # the program that hands a slot its state
-                with tracing.region("engine.warmup.program",
-                                    program="install_state"):
-                    self.state = jax.block_until_ready(self._install_state(
-                        self.state, self._request_start, jnp.int32(0),
-                        jnp.int32(1)))
+        self._warm(self._programs(buckets, batch_sizes))
         if self._spec is not None:
-            self._spec.warmup()
+            self._spec.proposer.warmup(self)
+            self._warm(self._spec._programs())
+
+    def _warm(self, programs) -> None:
+        """Run each of `programs` once with the values its list names:
+        arrays of the `Arg`s' fills, and what the engine keeps as it
+        stands, taken back from the program where it was donated
+        (`Program.back`). Each program under a region of its own, from its
+        first call to its blocked end: on a traced thread (`serve/llm.py
+        start_engine`) the `xla.*` spans of what it compiles are that
+        region's children."""
+        held: Dict[str, Any] = {}  # handed back, and no attribute's
+
+        def value(a):
+            if isinstance(a, str):
+                return _kept(lambda name: held[name] if name in held
+                             else getattr(self, name), a)
+            made = np.full(a.shape, a.fill, a.dtype)
+            # a jit keeps a numpy scalar and an Array apart; an Array is
+            # placed as the loop places its batch: no program runs for it
+            return made[()] if a.host else jnp.asarray(made)
+
+        def keep(back, out) -> None:
+            if isinstance(back, str):
+                if hasattr(self, back):
+                    setattr(self, back, out)
+                else:
+                    held[back] = out
+            elif back is not None:
+                for b, o in zip(back, out):
+                    keep(b, o)
+
+        for p in programs:
+            with tracing.region("engine.warmup.program", program=p.name,
+                                **p.attrs):
+                out = jax.block_until_ready(p.call(*p.bind(value).args))
+                keep(p.back, out)
 
     def _tables(self, table, window_table):
         """A program's page tables: THE pool's, and beside it the window
@@ -2310,7 +2414,16 @@ class InferenceEngine:
             self._loop_done = True
 
     def _iterate(self) -> None:
-        """One `engine.iter`, and its row of the token ledger."""
+        """One `engine.iter`, and its row of the token ledger. The row
+        opens empty: a phase that ran since the last iteration's end and
+        outside any (the drain under `update_params` on a caller's thread,
+        or at the loop's end) is inside `between`, this iteration's start
+        less the last one's end, and its own time must not be taken from
+        this iteration's a second time: `rest` below turned negative by
+        that drain's readback, and a counter refused it."""
+        for row in (self._phase_ns, self._hidden_ns):
+            row.update(dict.fromkeys(row, 0))
+        self._live = self._nested_read_ns = 0
         self._began_busy = self._device_busy()
         with self.phase("iter") as it:
             self.step()
@@ -2380,10 +2493,6 @@ class InferenceEngine:
                     ("device_wait", waited),
                     ("loop", loop)):
                 _token_wait[part].inc(slot_ns * 1e-9)
-        for d in (ns, hid):
-            for name in d:
-                d[name] = 0
-        self._live = self._nested_read_ns = 0
         self._iter_end_ns = it.end_ns
 
     def _open_span(self, steps: int,
@@ -3889,11 +3998,7 @@ class InferenceEngine:
         The caller waits for that, up to one iteration of the loop. Returns
         the new weights_version."""
         if self.mesh is not None:
-            from ..models.transformer import param_axes
-            from ..parallel.sharding import tree_shardings
-
-            new = jax.device_put(
-                params, tree_shardings(param_axes(self.cfg), self.mesh))
+            new = jax.device_put(params, self._param_shardings())
         else:
             new = jax.tree_util.tree_map(jnp.asarray, params)
         jax.block_until_ready(new)
@@ -3988,6 +4093,12 @@ class InferenceEngine:
         span in flight read back and committed (`_loop`)."""
         self._stop.set()
         self._work.set()  # wake the decode thread so it observes _stop
+
+
+def _kept(get, name: str):
+    """What a program's list names: `get("x")`, or its i-th part ("x.i")."""
+    name, _, part = name.partition(".")
+    return get(name)[int(part)] if part else get(name)
 
 
 def _join_carry(carry, slot, token, position):

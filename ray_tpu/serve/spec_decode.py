@@ -76,6 +76,7 @@ from ..models.transformer import _head_logits
 from ..util import tracing
 from ..ops import pool_shape
 from .config import SpeculationConfig
+from .program import Arg, Program
 
 logger = get_logger("serve.spec_decode")
 
@@ -522,14 +523,24 @@ class SpecDecoder:
     device — the readback is [B,S] committed tokens + [B] counts), and
     the acceptance accounting. The engine drives it from step()."""
 
-    def __init__(self, engine, spec: SpeculationConfig, draft_params=None):
+    def __init__(self, engine, spec: SpeculationConfig):
+        """Its program and its counts, and no array yet (`start`): an
+        engine that is described and never runs builds this much
+        (`InferenceEngine.abstract`)."""
         self.engine = engine
         self.spec = spec
         self.k = spec.num_speculative_tokens
+        self._verify = self._build_verify()
+        self.proposed_total = 0
+        self.accepted_total = 0
+
+    def start(self, draft_params=None) -> None:
+        """The proposer, which may hold a model and a pool of its own."""
+        spec = self.spec
         if spec.mode == "ngram":
             self.proposer = NGramProposer(spec)
         elif spec.mode == "draft":
-            self.proposer = DraftModelProposer(engine, spec, draft_params)
+            self.proposer = DraftModelProposer(self.engine, spec, draft_params)
         else:
             raise ValueError(f"speculation mode {spec.mode!r} is not a "
                              "proposer mode")
@@ -537,9 +548,6 @@ class SpecDecoder:
                    else bool(config.spec_overlap))
         self.overlap = overlap and getattr(
             self.proposer, "supports_prefetch", False)
-        self._verify = self._build_verify()
-        self.proposed_total = 0
-        self.accepted_total = 0
 
     def _build_verify(self):
         """Jit the span forward: embed the S=k+1 fed tokens, write their
@@ -591,27 +599,25 @@ class SpecDecoder:
         if ev is not None:
             ev(self.engine, slot_idx)
 
-    def warmup(self) -> None:
+    def _programs(self):
+        """The round's programs, one a sampler, as `InferenceEngine._programs`
+        lists the engine's own: the widest span, k + 1 tokens a slot. (A
+        draft proposer's two programs take the draft's own pool and stay in
+        its `warmup`.)"""
         eng = self.engine
-        self.proposer.warmup(eng)
-        B = eng.ecfg.max_batch_size
-        pps = eng.ecfg.pages_per_seq
-        S = self.k + 1
+        B, pps, S = eng.ecfg.max_batch_size, eng.ecfg.pages_per_seq, self.k + 1
+        i32, f32 = jnp.int32, jnp.float32
         for advanced in (False, True):
-            with tracing.region("engine.warmup.program", rows=B * S,
-                                program=f"verify_{self.k}"
-                                + ("_adv" if advanced else "")):
-                committed, _, eng.k_pages, eng.v_pages = \
-                    self._verify(advanced)(
-                        eng.params, eng.k_pages, eng.v_pages,
-                        jnp.zeros((B, S), jnp.int32),
-                        jnp.zeros((B,), jnp.int32),
-                        jnp.zeros((B, pps), jnp.int32),
-                        jnp.zeros((B,), jnp.int32),
-                        jnp.zeros((B,), jnp.float32),
-                        jnp.ones((B,), jnp.float32),
-                        jnp.zeros((B,), jnp.int32), jax.random.PRNGKey(0))
-                np.asarray(committed)
+            yield Program.under(
+                eng.mesh, f"verify_{self.k}" + ("_adv" if advanced else ""),
+                self._verify(advanced),
+                ("params", "k_pages", "v_pages",
+                 Arg((B, S), i32), Arg((B,), i32),  # tokens, positions
+                 Arg((B, pps), i32), Arg((B,), i32),  # tables, n_draft
+                 # temperatures, top_p, top_k, the key
+                 Arg((B,), f32), Arg((B,), f32, 1), Arg((B,), i32),
+                 Arg((2,), jnp.uint32)),
+                back=(None, None, "k_pages", "v_pages"), rows=B * S)
 
     # verify cost model: one S-wide forward ~ ALPHA + S in single-row
     # units (ALPHA covers dispatch + the fixed host share of a round).

@@ -106,13 +106,22 @@ def restore_checkpoint(ref, dest: str) -> Checkpoint:
 # ---------------------------------------------------------------------------
 
 
+# orbax names a save by ONE operation id a process (`OperationIdGenerator`:
+# class attributes): of two saves that run at once in one process, as the
+# stage workers of an in-process gang do under one `step_NNNNNN`, each may
+# read the other's id, make the other's temporary directory a second time
+# (`FileExistsError`) or remove it under its writer ("Directory not empty").
+# A process saves one tree at a time; processes save side by side.
+_save_lock = threading.Lock()
+
+
 def save_pytree(tree: Any, path: str, *, force: bool = True) -> str:
     """Write a (possibly sharded) pytree under `path` (orbax OCDBT)."""
     import orbax.checkpoint as ocp
 
     path = os.path.abspath(os.path.expanduser(path))
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-    with ocp.StandardCheckpointer() as ckptr:
+    with _save_lock, ocp.StandardCheckpointer() as ckptr:
         ckptr.save(path, tree, force=force)
     return path
 

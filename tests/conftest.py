@@ -29,6 +29,39 @@ jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_default_matmul_precision", "highest")
 
 
+@pytest.fixture(scope="session", autouse=True)
+def _compile_once_a_run(tmp_path_factory):
+    """jax's persistent compile cache, in a directory that is new every run
+    (a run reads nothing an earlier commit left) and that the run's xdist
+    workers share: a program is compiled once a run, not once a test that
+    builds its engine anew and again in each worker (PR 56: the compiler was
+    three fifths of the engine tests' time). Tests that read the cache's
+    answers point it at a directory of their own (test_region.py) or switch
+    it off (test_tpu_compile.py) and put this back."""
+    base = tmp_path_factory.getbasetemp()
+    if os.environ.get("PYTEST_XDIST_WORKER"):
+        base = base.parent  # the workers' directories lie side by side
+    jax.config.update("jax_compilation_cache_dir", str(base / "jax_cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+
+
+def pytest_terminal_summary(terminalreporter):
+    """The 25 dearest tests of every run (set-up, call and tear-down), so
+    that a session sees what its PR added; it fails nothing."""
+    cost = {}
+    for reports in terminalreporter.stats.values():
+        for r in reports:
+            if hasattr(r, "duration") and hasattr(r, "nodeid"):
+                cost[r.nodeid] = cost.get(r.nodeid, 0.0) + r.duration
+    dearest = sorted(cost.items(), key=lambda kv: -kv[1])[:25]
+    terminalreporter.write_sep(
+        "=", f"the 25 dearest of {len(cost)} tests, {sum(cost.values()):.0f} "
+        "CPU-seconds in all")
+    for nodeid, seconds in dearest:
+        terminalreporter.write_line(f"{seconds:8.1f}s  {nodeid}")
+
+
 @pytest.fixture(autouse=True)
 def _mesh_registry_isolation():
     """A mesh one test registers as the process default must not leak into

@@ -586,6 +586,38 @@ def test_update_params_commits_the_span_in_flight_under_the_old_version(
     assert [t for t, _v in later] != ref[1 + SPAN:1 + 2 * SPAN]
 
 
+def test_update_params_with_no_loop_thread_takes_no_time_from_the_ledger(
+        model):
+    """The drain `update_params` runs on the caller's thread is outside
+    every iteration: its readback was filed into the NEXT iteration's row
+    and taken from that iteration's own time (`rest` in `_account`, some
+    -18 ms), which a part of `serve_token_wait_seconds` then refused
+    (`counters only increase`) whenever the device's state put it beside
+    nothing larger: on a decode thread, the thread's end. 200 rounds of the
+    test above's sequence on one engine: no part ever goes back."""
+    params, cfg = model
+    from ray_tpu.serve.engine import Request
+
+    (prompt,) = _prompts(cfg, (9,), seed=24)
+    engine = _engine(model)
+    engine._ensure_loop = lambda: None
+    parts = _ledger()
+    for i in range(200):
+        req = Request(f"r{i}", prompt, max_tokens=1 + 2 * SPAN)
+        engine.add_request(req)
+        engine._prefill_batch([engine.pending.get()])
+        engine._iterate()  # a span on the device
+        assert engine._inflight is not None
+        assert engine.update_params(params) == i + 1  # drained, outside
+        while engine._has_work():
+            engine._iterate()  # the first of them closes the row at fault
+        assert req.finish_reason == "length" and req.error is None
+        now = _ledger()
+        assert all(now[p] >= parts[p] for p in PARTS), (i, parts, now)
+        parts = now
+    engine.stop()
+
+
 def test_update_params_from_another_thread_while_the_loop_runs(model):
     params, cfg = model
     engine = _engine(model, max_seq_len=256, max_pages=96)

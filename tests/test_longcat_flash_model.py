@@ -13,7 +13,6 @@ log-probabilities agree to LOGPROB_TOL. The control rounds the same weights
 to fp8 and must land far outside it."""
 
 import dataclasses
-import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +33,8 @@ from ray_tpu.ops import pool_shape
 from ray_tpu.ops.attention import flash_attention
 from ray_tpu.parallel.moe import sigmoid_bias_gating
 from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+
+from engine_programs import PINNED, digest
 
 CONFIG = "longcat-flash-omni"
 # float32 on both sides: 2e-5 is over 10x the largest difference seen over
@@ -513,94 +514,20 @@ def test_refusals_name_the_family(model):
 
 # -- the models that share the expert layer ----------------------------------
 
-# sha256 of the StableHLO text of `decode_span` (4 steps) as THIS tree's
-# parent (b29f9ae) lowers it for the CPU at `highest` matmul precision (the
-# tests' own setting), jax as pinned below: "all held, none zero" must stay
-# the program it was. Re-pinned by PR 42, whose decode step visits the experts
-# its live rows chose in every family (tests/test_moe_step.py): the share
-# layer's fields still change nothing for a model that holds every expert.
-# Re-pinned by PR 53: the span's steps are an argument of the program (4 are
-# asked here) and the layers an inner jit; tests/test_smallthinker_model.py
-# pins the same two
-PARENT_DECODE = {
-    "tiny-moe":
-        "f0880d7635d59adcdb4f2808d0f76d6fe7bd58545d6cfe5a2d42a6901c26aa89",
-    "tiny-lfm2":
-        "81f95d4cf89d01dd776458be381de23baefc0dcf4f347f04ffae631cddfc8d6b",
-}
-LOWERED_WITH_JAX = "0.9.0"
-
-
-@pytest.mark.parametrize("name", sorted(PARENT_DECODE))
+# "all held, none zero" must stay the program it was: the share layer's
+# fields change nothing for a model that holds every expert
+# (tests/engine_programs.py: PINNED, and whose text each is)
+@pytest.mark.parametrize("name", ("tiny-lfm2", "tiny-moe"))
 def test_all_held_none_zero_lowers_to_the_parents_decode_program(name):
-    if jax.__version__ != LOWERED_WITH_JAX:
-        pytest.skip(f"digests were taken with jax {LOWERED_WITH_JAX}")
-    assert decode_digest(name) == PARENT_DECODE[name]
+    assert digest(name, "decode") == PINNED[name, "decode"]
 
 
-def _bare_engine(name):
-    """An engine object that builds programs and allocates nothing, with
-    the shapes of its parameters and pool."""
-    cfg = get_config(name)
-    params = jax.eval_shape(lambda k: stack.init_params(cfg, k)
-                            if cfg.is_stack else init_params(cfg, k),
-                            jax.random.PRNGKey(0))
-    ecfg = EngineConfig(max_batch_size=2, page_size=PAGE, max_pages=16,
-                        max_seq_len=32, prefill_chunk=16, cache_dtype="float32")
-    eng = object.__new__(InferenceEngine)
-    eng.cfg, eng.ecfg, eng.mesh, eng._tp = cfg, ecfg, None, 1
-    return eng, params, eng.abstract_pool()
-
-
-def _i32(*shape):
-    return jax.ShapeDtypeStruct(shape, jnp.int32)
-
-
-def _digest(lowered):
-    return hashlib.sha256(lowered.as_text().encode()).hexdigest()
-
-
-def decode_digest(name):
-    eng, params, pool = _bare_engine(name)
-    state = jax.eval_shape(lambda: stack.new_engine_state(
-        eng.cfg, 2, PAGE, jnp.float32, jnp.float32))
-    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
-    with jax.default_matmul_precision("highest"):
-        return _digest(eng._build_decode()(4).lower(
-            params, pool, pool, _i32(2), _i32(2), _i32(2, 8), f32(2), f32(2),
-            _i32(2), jax.ShapeDtypeStruct((2,), jnp.uint32), state,
-            (_i32(2), _i32(2), jax.ShapeDtypeStruct((2,), jnp.bool_))))
-
-
-# sha256 of the StableHLO text of `chunk_prefill_16` as the parent of PR 40
-# (efc7c3c) lowers it, taken as PARENT_DECODE was: telling the LATENT chunk
-# kernel where a chunk's tokens end (`attend_mla`) leaves `attend_full`, and
-# with it every other model's chunk program, the text it was (tiny-lfm2's is
-# PR 43's own since its chunks run each expert over the rows that chose it,
-# re-pinned as in tests/test_moe_step.py; since PR 51 every chunk program
-# draws its prompt's first token, so all three are PR 51's own, re-pinned as
-# in tests/test_smallthinker_model.py)
-PARENT_CHUNK = {
-    "tiny-llama":
-        "c7969c40ee60741f3f4268b08f055ea2ec0a9e07d096ff3945d6ed44129ac609",
-    "tiny-moe":
-        "d7fecbf97654aee0f0a8af43637c3c416930dc451b2e17674b06f1ec08272e16",
-    "tiny-lfm2":
-        "fe7e59cd01c093342381dea6a936c0a006ead242f4d76cc673cd6ed7d3279037",
-}
-
-
-@pytest.mark.parametrize("name", sorted(PARENT_CHUNK))
+# telling the LATENT chunk kernel where a chunk's tokens end (`attend_mla`)
+# leaves `attend_full`, and with it every other model's chunk program, the
+# text it was
+@pytest.mark.parametrize("name", ("tiny-lfm2", "tiny-llama", "tiny-moe"))
 def test_the_other_models_chunk_programs_lower_to_the_parents(name):
-    if jax.__version__ != LOWERED_WITH_JAX:
-        pytest.skip(f"digests were taken with jax {LOWERED_WITH_JAX}")
-    eng, params, pool = _bare_engine(name)
-    state = jax.eval_shape(
-        lambda: stack.new_request_state(eng.cfg, 1, jnp.float32))
-    with jax.default_matmul_precision("highest"):
-        assert _digest(eng._build_chunk_prefill()(16).lower(
-            params, pool, pool, _i32(16), _i32(), _i32(8), _i32(),
-            state)) == PARENT_CHUNK[name]
+    assert digest(name, "chunk") == PINNED[name, "chunk"]
 
 
 # -- the cell, rehearsed -----------------------------------------------------

@@ -21,6 +21,8 @@ from ray_tpu.models import transformer as tr
 from ray_tpu.ops import moe
 from ray_tpu.serve.engine import EngineConfig, InferenceEngine, Request
 
+from engine_programs import LOWERED_WITH_JAX, PINNED, digest
+
 TOL = 2e-5
 D, F, ROWS, LAYERS, LAYER = 128, 256, 8, 3, 1
 
@@ -352,147 +354,62 @@ def test_only_a_seq_that_keeps_its_keys_groups():
 
 # sha256 of the StableHLO text of the programs that must NOT change, as this
 # tree's parent (c4ad2b2) lowers them for the CPU at `highest` matmul
-# precision, jax as pinned below (tests/test_smallthinker_model.py pins the
-# accepted families' chunk and bucket programs and the dense families' decode
-# programs the same way; these are the ones it lacks): `Verify` and a
-# training step with and without experts. Since PR 43 a chunk or a bucket of a
-# family whose experts drop nothing runs each expert over the rows that chose
-# it, so those programs moved to GROUPED_PROGRAMS below; tiny-moe's drop rows
-# (capacity factor 1.25) and are still the parent's, there
-PARENT_PROGRAMS = {
-    ("tiny-moe", "verify"):
-        "d3757c5f5b5cfb8b9197aa6ddaec8e90f1acc1251c8a48276541d071d5c42e14",
-    ("tiny-moe", "train"):
+# precision: `Verify` (tests/engine_programs.py: PINNED, and whose text each
+# engine program is) and a training step with and without experts, taken with
+# jax LOWERED_WITH_JAX. tiny-llama's train step is PR 47's own: the training
+# layer names the FFN's two products for the loop's checkpoint; outside one
+# (`tiny-llama` keeps everything) a name lowers to nothing, but each moves the
+# numbers in the text's private function names, and nothing else
+# (tests/test_remat_policy.py)
+TRAIN_STEPS = {
+    "tiny-moe":
         "3b3c9924d4d7ee999a31808dd0bcf82b8dfc8eaed4b85c3633356b85726fe7a5",
-    ("tiny-llama", "verify"):
-        "c6b1cc0a49ce4ea71c6f3014aeae0654b4d858206b738123ac99babed7ff9135",
-    # PR 47's own: the training layer names the FFN's two products for the
-    # loop's checkpoint; outside one (`tiny-llama` keeps everything) a name
-    # lowers to nothing, but each moves the numbers in the text's private
-    # function names, and nothing else (tests/test_remat_policy.py)
-    ("tiny-llama", "train"):
+    "tiny-llama":
         "2fc8405287500755aa3cb12c89290afebca18e2e0ea99381c889fe7f6f039f1a",
 }
-# the expert families' decode programs DID change (a step visits): tiny-moe's,
-# tiny-lfm2's and tiny-longcat-flash's are re-pinned where they were pinned
-# (tests/test_smallthinker_model.py, tests/test_longcat_flash_model.py); the
-# newest family's is pinned here, this tree's own, so that a later change to
-# it is one that is meant. (Re-pinned by PR 46 for its table of the window
-# page space alone: the ring is 12 pages, the window's and the wide chunk's,
-# where it was 8; with `_wide_chunk` held to 0 both digests are the old ones.
-# Re-pinned by PR 53 with every decode program: the span's steps are an
-# argument and the layers an inner jit)
-DECODE_PROGRAMS = {
-    "tiny-smallthinker":
-        "0fdf84add6bc9b97ee0122983b552421a63ef37bc24a834fef9cc3fcfe12e0cd",
-}
-# the chunk and bucket programs of the families whose experts drop nothing
-# DID change in PR 43 (each expert over the rows that chose it; at the tiny
-# width the op's XLA form, every expert times the combine's zeros, with the
-# layer read out of the segment's stacks): this tree's own, so that a later
-# change to them is one that is meant (the three chunk programs again in PR
-# 51: they draw the prompt's first token where they handed back its logits)
-GROUPED_PROGRAMS = {
-    ("tiny-smallthinker", "chunk"):
-        "a2b11bffde16ae08aed83fc9b07a849b75cd56bb3d36c95ec5d43e1a4d76d90a",
-    ("tiny-smallthinker", "bucket"):
-        "38bf8b4999763ffac2fbee832fc945d37579290b491336df115e7c7b071a92cc",
-    ("tiny-lfm2", "chunk"):
-        "fe7e59cd01c093342381dea6a936c0a006ead242f4d76cc673cd6ed7d3279037",
-    ("tiny-lfm2", "bucket"):
-        "d3a78cc2c6fd2cd4a8f57388f1ad147180733c227c6187a7d0fdc837722b5a31",
-    ("tiny-longcat-flash", "chunk"):
-        "da540f5bc662f6b86065eef2d53ef21a26b53d9a237c8ae2cc9e012f300b2db4",
-    ("tiny-longcat-flash", "bucket"):
-        "bd8fd2bc1a51d7aef7f731156f94709dc1112598c675a314cc5ac19ce0ae55d7",
-}
-LOWERED_WITH_JAX = "0.9.0"
+# the expert families' decode programs DID change (a step visits): the newest
+# family's is pinned too, so that a later change to it is one that is meant;
+# so did the chunk and bucket programs of the families whose experts drop
+# nothing (PR 43: each expert over the rows that chose it; at the tiny width
+# the op's XLA form, every expert times the combine's zeros, with the layer
+# read out of the segment's stacks)
+GROUPED_PROGRAMS = [(name, program)
+                    for name in ("tiny-lfm2", "tiny-longcat-flash",
+                                 "tiny-smallthinker")
+                    for program in ("bucket", "chunk")]
 PAGE = 4
 
 
-def _bare_engine(name):
-    """An engine object that builds programs and allocates nothing."""
-    cfg = get_config(name)
-    params = jax.eval_shape(lambda k: stack.init_params(cfg, k)
-                            if cfg.is_stack else init_params(cfg, k),
-                            jax.random.PRNGKey(0))
-    kw = (dict(max_window_pages=40, prefill_buckets=(8, 16))
-          if cfg.window_paged else {})
-    eng = object.__new__(InferenceEngine)
-    eng.cfg, eng.mesh, eng._tp, eng._prefill_cache = cfg, None, 1, {}
-    eng.ecfg = EngineConfig(max_batch_size=2, page_size=PAGE, max_pages=16,
-                            max_seq_len=32, prefill_chunk=16,
-                            cache_dtype="float32", **kw)
-    pool = eng.abstract_pool()
-    return eng, params, pool, None if cfg.latent_cache else pool
-
-
-def _lowered(name, program):
-    from ray_tpu.serve import spec_decode
-
-    eng, params, k_pool, v_pool = _bare_engine(name)
-    cfg = eng.cfg
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
-    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
-    tables, table = i32(2, 8), i32(8)
-    if cfg.window_paged:
-        eng._ring = ring = eng._window_ring()
-        tables, table = (tables, i32(2, ring)), (table, i32(ring))
-        state = start = eng.abstract_state()
-    else:
-        state = jax.eval_shape(lambda: stack.new_engine_state(
-            cfg, 2, PAGE, jnp.float32, jnp.float32))
-        start = jax.eval_shape(
-            lambda: stack.new_request_state(cfg, 1, jnp.float32))
-    if program == "decode":
-        return eng._build_decode()(4).lower(
-            params, k_pool, v_pool, i32(2), i32(2), tables, f32(2), f32(2),
-            i32(2), key, state,
-            (i32(2), i32(2), jax.ShapeDtypeStruct((2,), jnp.bool_)))
-    if program == "chunk":
-        return eng._build_chunk_prefill()(16).lower(
-            params, k_pool, v_pool, i32(16), i32(), table, i32(), start)
-    if program == "bucket":
-        return eng._prefill_fn(16, 1).lower(params, i32(1, 16), i32(1))
-    if program == "verify":
-        spec = object.__new__(spec_decode.SpecDecoder)
-        spec.engine, spec.k = eng, 3
-        return spec._build_verify()(False).lower(
-            params, k_pool, v_pool, i32(2, 4), i32(2), i32(2, 8), i32(2),
-            f32(2), f32(2), i32(2), key)
-    assert program == "train"
-    batch = {"tokens": i32(2, 16), "targets": i32(2, 16)}
-    return jax.jit(jax.value_and_grad(
-        lambda p, b: tr.loss_fn(p, b, cfg)[0])).lower(params, batch)
-
-
-def _digest(name, program):
-    with jax.default_matmul_precision("highest"):
-        return hashlib.sha256(
-            _lowered(name, program).as_text().encode()).hexdigest()
-
-
-@pytest.mark.parametrize("name, program", sorted(PARENT_PROGRAMS))
+@pytest.mark.parametrize("name, program", [
+    ("tiny-llama", "train"), ("tiny-llama", "verify"),
+    ("tiny-moe", "train"), ("tiny-moe", "verify")])
 def test_programs_that_are_no_step_lower_to_the_parents(name, program):
+    if program == "verify":
+        assert digest(name, program) == PINNED[name, program]
+        return
     if jax.__version__ != LOWERED_WITH_JAX:
         pytest.skip(f"digests were taken with jax {LOWERED_WITH_JAX}")
-    assert _digest(name, program) == PARENT_PROGRAMS[name, program]
+    cfg = get_config(name)
+    params = jax.eval_shape(lambda k: init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    batch = {k: jax.ShapeDtypeStruct((2, 16), jnp.int32)
+             for k in ("tokens", "targets")}
+    with jax.default_matmul_precision("highest"):
+        text = jax.jit(jax.value_and_grad(
+            lambda p, b: tr.loss_fn(p, b, cfg)[0])).lower(
+                params, batch).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == TRAIN_STEPS[name]
 
 
-@pytest.mark.parametrize("name", sorted(DECODE_PROGRAMS))
-def test_the_expert_families_decode_programs_are_this_trees(name):
-    if jax.__version__ != LOWERED_WITH_JAX:
-        pytest.skip(f"digests were taken with jax {LOWERED_WITH_JAX}")
-    assert _digest(name, "decode") == DECODE_PROGRAMS[name]
+def test_the_expert_families_decode_programs_are_this_trees():
+    assert digest("tiny-smallthinker", "decode") == PINNED[
+        "tiny-smallthinker", "decode"]
 
 
-@pytest.mark.parametrize("name, program", sorted(GROUPED_PROGRAMS))
+@pytest.mark.parametrize("name, program", GROUPED_PROGRAMS)
 def test_the_expert_families_chunk_and_bucket_programs_are_this_trees(
         name, program):
-    if jax.__version__ != LOWERED_WITH_JAX:
-        pytest.skip(f"digests were taken with jax {LOWERED_WITH_JAX}")
-    assert _digest(name, program) == GROUPED_PROGRAMS[name, program]
+    assert digest(name, program) == PINNED[name, program]
 
 
 def _eqns(jaxpr):

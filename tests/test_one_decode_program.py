@@ -193,12 +193,6 @@ def test_the_two_programs_trace_the_layers_once(monkeypatch):
     from ray_tpu.models import stack as stack_mod
 
     cfg = get_config("tiny-olmo-hybrid")
-    eng = object.__new__(InferenceEngine)
-    eng.cfg, eng.mesh, eng._tp = cfg, None, 1
-    eng.ecfg = EngineConfig(max_batch_size=B, page_size=PAGE, max_pages=16,
-                            max_seq_len=32, prefill_chunk=16,
-                            cache_dtype="float32")
-    eng._ring = eng._window_ring()
     traced, run_paged = [], stack_mod.run_paged
 
     def counting(params, tokens, cfg, mode, *rest):
@@ -206,16 +200,13 @@ def test_the_two_programs_trace_the_layers_once(monkeypatch):
         return run_paged(params, tokens, cfg, mode, *rest)
 
     monkeypatch.setattr(stack_mod, "run_paged", counting)
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
-    params = jax.eval_shape(lambda k: stack.init_params(cfg, k),
-                            jax.random.PRNGKey(0))
-    pool, decode = eng.abstract_pool(), eng._build_decode()
-    args = (params, pool, pool, i32(B), i32(B), i32(B, 8), f32(B), f32(B),
-            i32(B), jax.ShapeDtypeStruct((2,), jnp.uint32),
-            eng.abstract_state())
-    texts = [decode(n, advanced).lower(*args).as_text()
-             for n, advanced in ((K, False), (4, True))]
+    programs = InferenceEngine.abstract(cfg, EngineConfig(
+        max_batch_size=B, page_size=PAGE, max_pages=16, max_seq_len=32,
+        prefill_chunk=16, cache_dtype="float32")).programs(
+            jax.eval_shape(lambda k: stack.init_params(cfg, k),
+                           jax.random.PRNGKey(0)), buckets=())
+    texts = [programs[name].lower().as_text()
+             for name in ("decode_span", "decode_span_adv")]
     assert traced == ["Decode"]
     assert "stablehlo.sort" not in texts[0] and "stablehlo.sort" in texts[1]
 

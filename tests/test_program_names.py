@@ -57,27 +57,9 @@ def engine(request):
         speculation={"mode": "ngram", "num_speculative_tokens": 3}))
 
 
-def _decode_args(eng):
-    pps = eng.ecfg.pages_per_seq
-    return (eng.params, eng.k_pages, eng.v_pages, jnp.zeros((B,), jnp.int32),
-            jnp.zeros((B,), jnp.int32), jnp.zeros((B, pps), jnp.int32),
-            jnp.zeros((B,), jnp.float32), jnp.ones((B,), jnp.float32),
-            jnp.zeros((B,), jnp.int32), jax.random.PRNGKey(0))
-
-
-def _chunk_args(eng, C):
-    return (eng.params, eng.k_pages, eng.v_pages, jnp.zeros((C,), jnp.int32),
-            jnp.int32(0), jnp.zeros((eng.ecfg.pages_per_seq,), jnp.int32),
-            jnp.int32(C - 1))
-
-
-def _verify_args(eng, span=4):
-    pps = eng.ecfg.pages_per_seq
-    return (eng.params, eng.k_pages, eng.v_pages,
-            jnp.zeros((B, span), jnp.int32), jnp.zeros((B,), jnp.int32),
-            jnp.zeros((B, pps), jnp.int32), jnp.zeros((B,), jnp.int32),
-            jnp.zeros((B,), jnp.float32), jnp.ones((B,), jnp.float32),
-            jnp.zeros((B,), jnp.int32), jax.random.PRNGKey(0))
+def _args(eng, program):
+    """What `program` takes, as the engine itself describes it."""
+    return eng.programs()[program].args
 
 
 def test_engine_programs_have_module_names_and_scopes(engine):
@@ -86,7 +68,7 @@ def test_engine_programs_have_module_names_and_scopes(engine):
     for span, adv, want in ((4, False, "jit_decode_span"),
                             (engine.ecfg.span_rows, True,
                              "jit_decode_span_adv")):
-        low = engine._decode(span, adv).lower(*_decode_args(engine))
+        low = engine._decode(span, adv).lower(*_args(engine, "decode_span"))
         assert _module(low) == want
     text = low.as_text(debug_info=True)
     for scope in ("embed", "attn", "kv_write", ffn, "lm_head", "sample"):
@@ -101,15 +83,15 @@ def test_engine_programs_have_module_names_and_scopes(engine):
         assert not re.search(r'loc\("(?:[^"]*/)?moe/dispatch[/"]', text)
     for export, want in ((False, "jit_chunk_prefill_32"),
                          (True, "jit_chunk_prefill_32_export")):
-        low = engine._chunk_fn(32, export).lower(*_chunk_args(engine, 32))
+        low = engine._chunk_fn(32, export).lower(
+            *_args(engine, "chunk_prefill_32"))
         assert _module(low) == want
     text = low.as_text(debug_info=True)
     for scope in ("embed", "attn", "kv_write", ffn, "lm_head"):
         assert _scoped(text, scope), scope
     # the bucket program keeps the module name the benchmark keys on and
     # carries its shape class as a scope
-    low = engine._prefill_fn(16, 2).lower(
-        engine.params, jnp.ones((2, 16), jnp.int32), jnp.ones((2,), jnp.int32))
+    low = engine.programs(batch_sizes=(2,))["prefill_bucket_16x2"].lower()
     assert _module(low) == "jit_run"
     text = low.as_text(debug_info=True)
     for scope in ("prefill_bucket_16x2", "embed", "attn", ffn, "lm_head"):
@@ -126,7 +108,7 @@ def test_page_and_verify_programs_have_module_names(engine):
     low = _gather_pages_jit.lower(engine.k_pages, engine.v_pages, pages,
                                   engine.cfg.kv_heads)
     assert _module(low) == "jit_gather_pages"
-    low = engine._spec._verify(False).lower(*_verify_args(engine))
+    low = engine.programs()["verify_3"].lower()
     assert _module(low) == "jit_verify_3"
 
 
@@ -168,12 +150,14 @@ def _draft_propose(eng):
 
 # name -> engine -> (jitted program, its pool, arguments)
 POOL_PROGRAMS = {
-    "decode_span": lambda e: (e._decode(4), e.k_pages, _decode_args(e)),
-    "chunk_step": lambda e: (e._chunk_fn(32), e.k_pages, _chunk_args(e, 32)),
+    "decode_span": lambda e: (
+        e._decode(4), e.k_pages, _args(e, "decode_span")),
+    "chunk_step": lambda e: (
+        e._chunk_fn(32), e.k_pages, _args(e, "chunk_prefill_32")),
     "chunk_step_export": lambda e: (
-        e._chunk_fn(32, True), e.k_pages, _chunk_args(e, 32)),
+        e._chunk_fn(32, True), e.k_pages, _args(e, "chunk_prefill_32")),
     "spec_verify": lambda e: (
-        e._spec._verify(False), e.k_pages, _verify_args(e)),
+        e._spec._verify(False), e.k_pages, _args(e, "verify_3")),
     "spec_draft_chunk": _draft_chunk,
     "spec_draft_propose": _draft_propose,
 }

@@ -504,11 +504,9 @@ class TestEngine:
             assert pool.shape == want and pool.dtype == jnp.float32
             for pages in (engine.k_pages, engine.v_pages):
                 assert (pages.shape, pages.dtype) == (pool.shape, pool.dtype)
-            # a bare engine object answers too: tools compile its programs
-            # for a described chip without allocating anything
-            bare = object.__new__(InferenceEngine)
-            bare.cfg, bare.ecfg = cfg, ecfg
-            assert bare.abstract_pool().shape == want
+            # an engine without arrays answers too: tools compile its
+            # programs for a described chip without allocating anything
+            assert InferenceEngine.abstract(cfg, ecfg).abstract_pool() == pool
         finally:
             engine.stop()
 
@@ -599,15 +597,10 @@ class TestEngine:
             assert [p._cache_size() for p in programs] == [1, 1]
             # spans of 4, 2 and 4 by hand (another value of an argument),
             # then the arrival of a top-p request (the other program)
-            for span in (4, 2, 4):
-                sharded._run_decode(sharded._decode(span)(
-                    sharded.params, sharded.k_pages, sharded.v_pages,
-                    *(jnp.zeros((2,), jnp.int32),) * 2,
-                    jnp.zeros((2, ecfg.pages_per_seq), jnp.int32),
-                    jnp.zeros((2,), jnp.float32), jnp.ones((2,), jnp.float32),
-                    jnp.zeros((2,), jnp.int32), jax.random.PRNGKey(1),
-                    sharded.state,
-                    (*sharded._carry, jnp.ones((2,), bool))))
+            (warm, _adv) = [p for p in sharded._programs(buckets=())
+                            if p.name.startswith("decode_span")]
+            sharded._warm(warm._replace(call=sharded._decode(span))
+                          for span in (4, 2, 4))
             sampled = sharded.generate(prompts[0], max_tokens=9,
                                        temperature=0.8, top_p=0.9)
             assert len(sampled["token_ids"]) == 9
@@ -742,6 +735,92 @@ class TestEngine:
 # tokens' choices of experts (they come back with the first token)
 FIRST_TOKEN_MODELS = ("tiny-llama", "tiny-granite-hybrid",
                       "tiny-longcat-flash")
+
+
+class TestPrograms:
+    """`InferenceEngine.programs`: ONE description of what a replica
+    compiles and what each program takes. The warm-up reads the same list,
+    so it cannot drift from it; the loop's call sites are written by hand,
+    and this holds them to it: a signature changed in one place and not the
+    other fails here (until PR 56 thirteen tests each built an engine
+    behind its back and wrote the argument lists a further time)."""
+
+    ENGINE = dict(max_batch_size=2, page_size=4, max_pages=64, max_seq_len=96,
+                  prefill_buckets=(8, 16), prefill_chunk=16,
+                  cache_dtype="float32")
+    # a dense model; a stack whose window layers' rings are allocated pages,
+    # with a wide chunk; a stack whose slots are handed their state
+    FAMILIES = {"tiny-llama": {}, "tiny-smallthinker": {"max_window_pages": 40},
+                "tiny-olmo-hybrid": {}}
+
+    @staticmethod
+    def _sizes(tree):
+        return jax.tree.map(lambda a: (tuple(a.shape), jnp.dtype(a.dtype)),
+                            tree)
+
+    @pytest.mark.parametrize("name", sorted(FAMILIES))
+    def test_the_description_is_what_warmup_compiles_and_the_loop_passes(
+            self, name):
+        from ray_tpu.models import stack
+        from ray_tpu.serve import EngineConfig, InferenceEngine
+        from ray_tpu.util import tracing
+
+        cfg = get_config(name)
+        params = (stack.init_params if cfg.is_stack else init_params)(
+            cfg, jax.random.PRNGKey(0))
+        ecfg = EngineConfig(**self.ENGINE, **self.FAMILIES[name])
+        engine = InferenceEngine(params, cfg, ecfg)
+        try:
+            described = engine.programs()
+            # an engine that holds no array says the same of itself
+            bare = InferenceEngine.abstract(cfg, ecfg).programs(
+                jax.eval_shape(lambda: params))
+            assert list(bare) == list(described)
+            for program in described:
+                assert self._sizes(bare[program].args) == self._sizes(
+                    described[program].args), program
+            with tracing.start_span("warm") as root:
+                engine.warmup()
+            (warm,) = tracing.get_trace(root.trace_id)
+            regions = [c["attrs"] for c in warm["children"]
+                       if c["name"] == "engine.warmup.program"]
+            assert [r.pop("program") for r in regions] == list(described)
+            assert regions == [p.attrs for p in described.values()]
+            wide = 2 * ecfg.prefill_chunk if cfg.is_moe else 0
+            assert engine._wide == wide and (
+                f"chunk_prefill_{wide}" in described) == bool(wide)
+            assert ("install_state" in described) == (
+                name == "tiny-olmo-hybrid")
+
+            passed = {}  # program -> what the loop called it with
+
+            def record(program, call):
+                def recorded(*args):
+                    passed[program] = self._sizes(args)
+                    return call(*args)
+                return recorded
+
+            decode, chunk, bucket = (engine._decode, engine._chunk_fn,
+                                     engine._prefill_fn)
+            engine._decode = lambda n, adv=False: record(
+                "decode_span" + ("_adv" if adv else ""), decode(n, adv))
+            engine._chunk_fn = lambda C, export=False: record(
+                f"chunk_prefill_{C}", chunk(C, export))
+            engine._prefill_fn = lambda b, B=1: record(
+                f"prefill_bucket_{b}x{B}", bucket(b, B))
+            engine._join_carry = record("join_carry", engine._join_carry)
+            engine._install_state = record("install_state",
+                                           engine._install_state)
+            rng = np.random.default_rng(5)
+            for n, how in ((5, {}), (11, {"temperature": 0.8, "top_p": 0.9}),
+                           (20, {}), (40, {})):  # a wide chunk, if any; 32 + 8
+                engine.generate(rng.integers(3, cfg.vocab_size, n).tolist(),
+                                max_tokens=4, **how)
+            assert set(passed) == set(described)
+            for program, sizes in passed.items():
+                assert sizes == self._sizes(described[program].args), program
+        finally:
+            engine.stop()
 
 
 @pytest.fixture(scope="module")

@@ -18,7 +18,6 @@ more, a bfloat16 router 2e-4 or more (rms over the same positions)."""
 
 import argparse
 import dataclasses
-import hashlib
 import json
 import time
 
@@ -31,6 +30,8 @@ from benchmark import common
 from benchmark.tests.tiny import tiny_spec
 from ray_tpu.models import get_config, stack
 from ray_tpu.serve.engine import EngineConfig, InferenceEngine, Request
+
+from engine_programs import PINNED, digest
 
 CONFIG = "smallthinker-21b-a3b"
 CELL = CONFIG + ".serve-mixedlen"
@@ -349,117 +350,17 @@ def test_refusals_name_the_stack_and_the_reason(model):
 
 # -- the six accepted configurations keep their programs ----------------------
 
-# sha256 of the StableHLO text of `decode_span` (4 steps), `chunk_prefill_16`
-# and the bucket program (16 x 1) as THIS tree's parent (1786590) lowers them
-# for the CPU at `highest` matmul precision, jax as pinned below, one tiny
-# model a family the benchmark holds: `_ffn_half`'s and the expert forms' new
-# argument, `_qkv`'s, the activation's one place and the engine's second page
-# space leave every one the text it was. Since PR 42 a decode step of a family
-# that holds experts visits the experts its live rows chose
-# (tests/test_moe_step.py), so the decode programs of tiny-moe, tiny-lfm2 and
-# tiny-longcat-flash are PR 42's own (re-pinned there); since PR 43 a chunk or
-# a bucket of a family whose experts drop nothing runs each expert over the
-# rows that chose it, so the chunk and bucket programs of tiny-lfm2 and
-# tiny-longcat-flash are PR 43's own (re-pinned here and in
-# tests/test_moe_step.py; tiny-moe's drop rows and stay); since PR 51 a chunk
-# program draws its prompt's first token and hands back a row of two to four
-# numbers in place of the logits, so all six chunk programs are PR 51's own
-# (re-pinned here, in tests/test_moe_step.py and in
-# tests/test_longcat_flash_model.py); since PR 53 a decode program takes its
-# span's steps as an argument (a loop to a traced bound, of which 4 are asked
-# here) and calls its layers through an inner jit, so all six decode programs
-# are PR 53's own (re-pinned here, in tests/test_moe_step.py and in
-# tests/test_longcat_flash_model.py; tests/test_one_decode_program.py holds
-# them bit for bit to the static scans they were); since PR 54 a bucket
-# longer than its model's window attends through the flash kernels' window
-# and no longer through a masked softmax over a [T, T] score matrix
-# (models/stack.py `_dense_attend`), so tiny-sambay's bucket of 16 over a
-# window of 8 is PR 54's own (tests/test_stack_model.py still holds it to the
-# plain reference); the other bucket programs that PRs 42 and 43 left alone
-# are still the parent's of PR 41
-PARENT_PROGRAMS = {
-    ("tiny-llama", "decode"):
-        "1429c5d1a5199a98ce7766b3669606a0006fef0510cd95bdd4a8f5c340234831",
-    ("tiny-llama", "chunk"):
-        "c7969c40ee60741f3f4268b08f055ea2ec0a9e07d096ff3945d6ed44129ac609",
-    ("tiny-llama", "bucket"):
-        "89e6eca808b25dc8185be474bb000e1754b5f6711e8b35f7c565ac8b23458ea4",
-    ("tiny-moe", "decode"):
-        "f0880d7635d59adcdb4f2808d0f76d6fe7bd58545d6cfe5a2d42a6901c26aa89",
-    ("tiny-moe", "chunk"):
-        "d7fecbf97654aee0f0a8af43637c3c416930dc451b2e17674b06f1ec08272e16",
-    ("tiny-moe", "bucket"):
-        "a8d171fe1e3f6467256a9cae983b142d438b55d121b1e140760c6c65e13814a8",
-    ("tiny-lfm2", "decode"):
-        "81f95d4cf89d01dd776458be381de23baefc0dcf4f347f04ffae631cddfc8d6b",
-    ("tiny-lfm2", "chunk"):
-        "fe7e59cd01c093342381dea6a936c0a006ead242f4d76cc673cd6ed7d3279037",
-    ("tiny-lfm2", "bucket"):
-        "d3a78cc2c6fd2cd4a8f57388f1ad147180733c227c6187a7d0fdc837722b5a31",
-    ("tiny-olmo-hybrid", "decode"):
-        "6c8b0085e41c557a010de66a11f7dfca080bdcfb838785aa5578b4da8628d532",
-    ("tiny-olmo-hybrid", "chunk"):
-        "edb88536add3620b995695cc51fea0a8495b5b6374680cb95dd0814d3b7ad44b",
-    ("tiny-olmo-hybrid", "bucket"):
-        "c9e003a5a9dcef0baebf4c2e4cae0be7a651aeb41769d73009ed6d2eca7b317e",
-    ("tiny-sambay", "decode"):
-        "c74594983bf6d369b488a7ee13726586c7907ffae2a3459092d48867ad1ab0b0",
-    ("tiny-sambay", "chunk"):
-        "dd633aec09dac87e37915669ed5a2c80a7efee259ead979e33bc40a02eda58fb",
-    ("tiny-sambay", "bucket"):
-        "7ae5286ce38bd935816614228c1e23ae5f6f757d07bfbe443411142401834a6e",
-    ("tiny-longcat-flash", "decode"):
-        "316f8553de71700017a500817d535080a08455ec449a0488d2e41f78842f8100",
-    ("tiny-longcat-flash", "chunk"):
-        "da540f5bc662f6b86065eef2d53ef21a26b53d9a237c8ae2cc9e012f300b2db4",
-    ("tiny-longcat-flash", "bucket"):
-        "bd8fd2bc1a51d7aef7f731156f94709dc1112598c675a314cc5ac19ce0ae55d7",
-}
-LOWERED_WITH_JAX = "0.9.0"
+# `_ffn_half`'s and the expert forms' new argument, `_qkv`'s, the activation's
+# one place and the engine's second page space leave the decode, chunk and
+# bucket programs of every family the benchmark held before this one the text
+# they were (tests/engine_programs.py: PINNED, and whose text each is)
+ACCEPTED = sorted((name, program) for name, program in PINNED
+                  if name != "tiny-smallthinker" and program != "verify")
 
 
-def _bare_engine(name):
-    """An engine object that builds programs and allocates nothing, with
-    the shapes of its parameters and pools."""
-    from ray_tpu.models import init_params
-
-    cfg = get_config(name)
-    params = jax.eval_shape(lambda k: stack.init_params(cfg, k)
-                            if cfg.is_stack else init_params(cfg, k),
-                            jax.random.PRNGKey(0))
-    ecfg = EngineConfig(max_batch_size=2, page_size=PAGE, max_pages=16,
-                        max_seq_len=32, prefill_chunk=16, cache_dtype="float32")
-    eng = object.__new__(InferenceEngine)
-    eng.cfg, eng.ecfg, eng.mesh, eng._tp, eng._prefill_cache = (
-        cfg, ecfg, None, 1, {})
-    pool = eng.abstract_pool()
-    return eng, params, pool, None if cfg.latent_cache else pool
-
-
-@pytest.mark.parametrize("name, program", sorted(PARENT_PROGRAMS))
+@pytest.mark.parametrize("name, program", ACCEPTED)
 def test_the_accepted_families_programs_lower_to_the_parents(name, program):
-    if jax.__version__ != LOWERED_WITH_JAX:
-        pytest.skip(f"digests were taken with jax {LOWERED_WITH_JAX}")
-    eng, params, k_pool, v_pool = _bare_engine(name)
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
-    with jax.default_matmul_precision("highest"):
-        if program == "decode":
-            state = jax.eval_shape(lambda: stack.new_engine_state(
-                eng.cfg, 2, PAGE, jnp.float32, jnp.float32))
-            lowered = eng._build_decode()(4).lower(
-                params, k_pool, v_pool, i32(2), i32(2), i32(2, 8), f32(2),
-                f32(2), i32(2), jax.ShapeDtypeStruct((2,), jnp.uint32), state,
-                (i32(2), i32(2), jax.ShapeDtypeStruct((2,), jnp.bool_)))
-        elif program == "chunk":
-            start = jax.eval_shape(
-                lambda: stack.new_request_state(eng.cfg, 1, jnp.float32))
-            lowered = eng._build_chunk_prefill()(16).lower(
-                params, k_pool, v_pool, i32(16), i32(), i32(8), i32(), start)
-        else:
-            lowered = eng._prefill_fn(16, 1).lower(params, i32(1, 16), i32(1))
-    assert hashlib.sha256(lowered.as_text().encode()).hexdigest() \
-        == PARENT_PROGRAMS[name, program]
+    assert digest(name, program) == PINNED[name, program]
 
 
 # -- (g) the cell, rehearsed -------------------------------------------------

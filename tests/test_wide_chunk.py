@@ -172,11 +172,15 @@ def test_the_rule_is_the_models_shapes_and_the_mesh(name, changes, engine,
     program; a dense model, a capacity that drops, a sharded mesh, a turn
     of one chunk, no chunked prefill and speculation do not."""
     engine = dict(engine)
-    bare = object.__new__(InferenceEngine)
-    bare.cfg = dataclasses.replace(get_config(name), **changes)
-    bare.mesh = engine.pop("mesh", lambda: None)()
-    bare.ecfg = EngineConfig(page_size=4, prefill_chunk=16, **engine)
-    assert bare._wide_chunk() == wide
+    mesh = engine.pop("mesh", lambda: None)()
+    cfg = dataclasses.replace(get_config(name), **changes)
+    if cfg.window_paged:  # its window layers' pages: room for the ring
+        engine.update(max_window_pages=40, prefill_buckets=(8, 16))
+    ecfg = EngineConfig(page_size=4, prefill_chunk=16, **engine)
+    bare = InferenceEngine.abstract(cfg, ecfg, mesh)
+    assert bare._wide == wide
+    assert ("chunk_prefill_32" in dict.fromkeys(
+        p.name for p in bare._programs(buckets=()))) == bool(wide)
 
 
 @pytest.mark.parametrize("name", DENSE)
