@@ -33,6 +33,7 @@ from ..ops import (
     expert_groups,
     expert_step,
     flash_attention,
+    gather_rows,
     group_rows,
     grouped_ffn,
     grouped_fits,
@@ -526,42 +527,89 @@ def _moe_ffn_grouped(x, lp, cfg, gate, tile: int, bound: int):
     """`_moe_ffn_dropless_ids` for rows in their thousands: the same
     gating and the same sum at the same rounding points (each product
     rounded to the activations' type, the chosen experts' results weighted
-    and summed in float32), over the rows that chose a held expert and no
-    others. The tokens' choices that fall on held experts are sorted by
-    expert into a buffer of `bound` rows in tiles of `tile` (scope `sort`),
-    the grouped product runs each expert over its own tiles (`experts`;
-    ops/moe.py `grouped_ffn`, which has a backward), and a scatter-add
-    puts the weighted rows back at their tokens (`combine`). Dropless: a
-    routing that needs more than `bound` rows (a share layer's alone can:
-    `grouped_rows_bound`) poisons the layer's output with NaN, and the
-    train step, which counts every layer's choices, raises
-    (train/lm.py). -> (out, aux, expert_ids [B,T,k])."""
-    dtype = x.dtype
+    and summed in float32, rounded once), over the rows that chose a held
+    expert and no others. The tokens' choices that fall on held experts are
+    sorted by expert into a buffer of `bound` rows in tiles of `tile`
+    (scope `sort`; ops/moe.py `group_rows` gives the row -> token table and
+    its inverse), the grouped product runs each expert over its own tiles
+    (`experts`; `grouped_ffn`, which has a backward), and the weighted rows
+    are summed back at their tokens (`combine`). A row moves by a gather
+    both ways and in both passes (`_rows_in`, `_rows_out`): no row is
+    scatter-added. Dropless: a routing that needs more than `bound` rows (a
+    share layer's alone can: `grouped_rows_bound`) poisons the layer's
+    output with NaN, and the train step, which counts every layer's
+    choices, raises (train/lm.py). -> (out, aux, expert_ids [B,T,k])."""
     B, T, D = x.shape
     N, E, k = B * T, cfg.num_experts, cfg.num_selected_experts
     with jax.named_scope("route"):
         logits, weights, expert_ids = gate or _moe_gate(x, lp, cfg)
         aux = _moe_aux(logits, expert_ids, cfg.router_width)
-    xs = x.reshape(N, D)
     with jax.named_scope("sort"):
-        rows = group_rows(expert_ids.reshape(N, k), weights.reshape(N, k),
-                          cfg.experts_first, E, tile, bound)
-        sorted_x = jnp.take(xs, rows["token"], axis=0, mode="fill",
-                            fill_value=0)
+        rows = jax.lax.stop_gradient(group_rows(
+            expert_ids.reshape(N, k), weights.reshape(N, k),
+            cfg.experts_first, E, tile, bound))
+        sorted_x = _rows_in(x.reshape(N, D), rows)
     with jax.named_scope("experts"):
         y = grouped_ffn(_GATE_ACT[cfg.activation], tile, sorted_x,
                         lp["w_in"], lp["w_gate"], lp["w_out"],
                         rows["tile_expert"], rows["used"])
     with jax.named_scope("combine"):
-        out = jnp.zeros((N, D), jnp.float32).at[rows["token"]].add(
-            y.astype(jnp.float32) * rows["weight"][:, None], mode="drop")
+        out = _rows_out(y, weights.reshape(N, k), rows)
         out = jnp.where(rows["rows"] > bound, jnp.nan, out)
         # kept by a checkpoint that keeps the up products: a norm after the
         # sublayer reads it in the backward, which then sorts and multiplies
         # for the router's gradient alone and adds nothing up again
-        out = checkpoint_name(out.astype(dtype), GROUPED_RESIDUAL_NAMES[2])
+        out = checkpoint_name(out, GROUPED_RESIDUAL_NAMES[2])
         return (constrain(out.reshape(B, T, D), ("batch", "seq", "embed")),
                 aux, expert_ids)
+
+
+def _take_rows(x, token):
+    """x [N, D] at the sorted buffer's rows. A padding row (token N) reads
+    the last token's, which costs no pass to blank it and which nothing
+    sums: its weight is 0, no slot names it, and what the experts make of
+    it meets a zero cotangent."""
+    return jnp.take(x, token, axis=0, mode="clip")
+
+
+@jax.custom_vjp
+def _rows_in(x, rows):
+    """x [N, D] -> the sorted buffer [bound, D]: row r is its token's. The
+    gradient sums a token's rows through the inverse table (float32,
+    rounded once), where XLA's transpose of the gather scatter-adds."""
+    return _take_rows(x, rows["token"])
+
+
+def _rows_in_bwd(rows, d_sorted):
+    return gather_rows(d_sorted, rows), None
+
+
+_rows_in.defvjp(lambda x, rows: (_rows_in(x, rows), rows), _rows_in_bwd)
+
+
+@jax.custom_vjp
+def _rows_out(y, weights, rows):
+    """The sorted buffer's results y [bound, D], weights [N, k] float32 ->
+    out[n] = sum over j of weights[n, j] * y[slot[n, j]] in float32,
+    rounded once to y's type. A row's gradient is a gather too: its token's
+    cotangent times the row's weight. A weight's is the product of its row
+    with its token's cotangent, read row by row: scalars, each put at its
+    own choice (no two rows share one)."""
+    return gather_rows(y, rows, weights)
+
+
+def _rows_out_bwd(res, d_out):
+    y, rows = res
+    d_rows = _take_rows(d_out, rows["token"]).astype(jnp.float32)
+    d_weight = jnp.sum(y.astype(jnp.float32) * d_rows, axis=1)
+    d_weights = jnp.zeros((rows["slot"].size,), jnp.float32).at[
+        rows["choice"]].set(d_weight, mode="drop", unique_indices=True)
+    return ((d_rows * rows["weight"][:, None]).astype(y.dtype),
+            d_weights.reshape(rows["slot"].shape), None)
+
+
+_rows_out.defvjp(lambda y, weights, rows: (_rows_out(y, weights, rows),
+                                           (y, rows)), _rows_out_bwd)
 
 
 def _moe_combine(x, lp, cfg, gate=None):

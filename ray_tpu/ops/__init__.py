@@ -23,6 +23,7 @@ from .mla_attention import (  # noqa: F401
 from .moe import (  # noqa: F401
     expert_groups,
     expert_step,
+    gather_rows,
     group_rows,
     grouped_ffn,
     grouped_fits,

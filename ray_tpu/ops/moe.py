@@ -437,6 +437,13 @@ def _dispatched(kernel, act, forced, *args):
 #   moe_gmm_dx  the same with w transposed: the rows' gradient (x3)
 #   moe_gmm_dw  dw[e] = sum over e's tiles of x[tile]^T . dy[tile] (x3)
 #
+# The sort is a partial permutation and `group_rows` gives it in both
+# directions (`token`: row -> token, `slot`: a token's j-th choice -> row),
+# so a row moves by a gather whichever way it goes: into the buffer through
+# `token`, and out of it, summed at its token, through `slot`
+# (`gather_rows`, kernel `moe_combine`): no row is scatter-added, forward or
+# backward.
+#
 # The buffer's size is static (`grouped_rows_bound`); the tiles past the
 # used ones are skipped (no block fetched, zeros written), and a routing
 # that would pass the bound is for the caller to fail on: nothing here
@@ -484,24 +491,37 @@ def group_rows(expert_ids, weights, first: int, E: int, tile: int,
                bound: int):
     """expert_ids [N,k] int32 over all the router's outputs, weights [N,k]
     float32; the held experts are first .. first + E. -> the sorted
-    buffer's tables: `token` [bound] (the row's token; N: the row is
-    padding), `weight` [bound] float32 (0 for padding), `tile_expert`
-    [bound / tile] (past the used tiles: the last expert), `used` [1] (the
-    tiles that hold a group) and `rows` (the rows the routing NEEDS, which
-    is more than `bound` where it overflows: the rows past the bound are
-    then missing from the tables)."""
+    buffer's tables, a partial permutation in BOTH directions: `token`
+    [bound] (the row's token; N: the row is padding; `choice`: which of
+    the N * k choices, n * k + j, it is; no two rows name one, a padding
+    row names none: N * k + its own number) and its inverse `slot`
+    [N,k] (the row of token n's j-th choice; `bound`, the fill row, where
+    that expert is not held here or the row lies past the bound), so that a
+    row's way into the buffer and back is a gather either way
+    (`gather_rows`, which also reads `runs` [tiles of tokens + 1, E]: the
+    row where each held expert's run of a tile's tokens starts, the sort
+    keeping a group's tokens in order); `weight` [bound] float32 (0 for
+    padding), `tile_expert` [bound / tile] (past the used tiles: the last
+    expert), `used` [1] (the tiles that hold a group) and `rows` (the rows
+    the routing NEEDS, which is more than `bound` where it overflows: the
+    rows past the bound are then missing from the tables)."""
     N, k = expert_ids.shape
-    flat = expert_ids.reshape(-1) - first
-    local = jnp.where((flat >= 0) & (flat < E), flat, E).astype(jnp.int32)
+    ids = expert_ids - first
+    ids = jnp.where((ids >= 0) & (ids < E), ids, E).astype(jnp.int32)
+    local = ids.reshape(-1)
     # held choices first, expert by expert, a group's tokens in their order
     _, order = jax.lax.sort_key_val(
         local, jnp.arange(N * k, dtype=jnp.int32), is_stable=True)
-    counts = jnp.sum(local[:, None] == jnp.arange(E, dtype=jnp.int32)[None],
-                     axis=0, dtype=jnp.int32)
+    # [N,k,E]: choice j of token n is expert e; a token's choices an expert
+    chose = ids[:, :, None] == jnp.arange(E, dtype=jnp.int32)
+    per_token = jnp.sum(chose, axis=1, dtype=jnp.int32)
+    before = jnp.cumsum(per_token, axis=0) - per_token  # tokens BEFORE n
+    counts = before[-1] + per_token[-1]
     tiles = jnp.maximum(-(-counts // tile), 1)
     tile_end = jnp.cumsum(tiles)
     used = tile_end[-1]
     sorted_start = jnp.cumsum(counts) - counts
+    group_start = (tile_end - tiles) * tile
     n_tiles = bound // tile
     t = jnp.arange(n_tiles, dtype=jnp.int32)
     tile_expert = jnp.minimum(
@@ -509,10 +529,23 @@ def group_rows(expert_ids, weights, first: int, E: int, tile: int,
         E - 1)
     row = jnp.arange(bound, dtype=jnp.int32)
     e = tile_expert[row // tile]
-    at = row - (tile_end[e] - tiles[e]) * tile
+    at = row - group_start[e]
     valid = (at < counts[e]) & (row // tile < used)
     choice = order[jnp.clip(sorted_start[e] + at, 0, N * k - 1)]
+    # the inverse without a second sort: a choice's place in its group is
+    # how many choices before it (earlier tokens, then earlier choices of
+    # its own token) fell on its expert, what the stable sort counts too
+    earlier = jnp.sum(
+        (ids[:, :, None] == ids[:, None, :])
+        & (jnp.arange(k)[:, None] > jnp.arange(k)[None, :]), axis=2,
+        dtype=jnp.int32)
+    slot = jnp.sum(jnp.where(chose, (group_start + before)[:, None, :], 0),
+                   axis=2, dtype=jnp.int32) + earlier
     return {"token": jnp.where(valid, choice // k, N),
+            "choice": jnp.where(valid, choice, N * k + row),
+            "slot": jnp.where((ids < E) & (slot < bound), slot, bound),
+            "runs": group_start + jnp.concatenate(
+                [before[::_COMBINE_TOKENS], counts[None]]),
             "weight": jnp.where(valid, weights.reshape(-1)[choice], 0.0),
             "tile_expert": tile_expert,
             "used": jnp.minimum(used, n_tiles).reshape(1),
@@ -690,6 +723,185 @@ def _tgmm(x, dy, tile_expert, used, tile, experts, dtype):
     return _grouped_call(_tgmm_pallas, _tgmm_xla, tiled, x, dy, tile_expert,
                          used, tile=tile, experts=experts, dtype=dtype,
                          name="moe_gmm_dw")
+
+
+# `moe_combine`: tokens of one program, rows of one DMA (a whole tile of the
+# narrow types), rows of one product (the MXU's side)
+_COMBINE_TOKENS = 128
+_COMBINE_BLOCK = 16
+_COMBINE_CHUNK = 128
+
+
+def _combine_blocks(k: int, E: int) -> int:
+    """The most blocks a tile of tokens can touch: its choices' rows, and
+    for every held expert the two blocks its run starts and ends inside;
+    whole chunks."""
+    per_chunk = _COMBINE_CHUNK // _COMBINE_BLOCK
+    blocks = _COMBINE_TOKENS * k // _COMBINE_BLOCK + 2 * E
+    return -(-blocks // per_chunk) * per_chunk
+
+
+def _split3(p):
+    """float32 -> three bfloat16 whose sum it is (24 bits of mantissa)."""
+    hi = p.astype(jnp.bfloat16)
+    rest = p - hi.astype(_F32)
+    mid = rest.astype(jnp.bfloat16)
+    return hi, mid, (rest - mid.astype(_F32)).astype(jnp.bfloat16)
+
+
+def _combine_kernel(runs_ref, slot_ref, w_ref, y_ref, o_ref, stage_ref,
+                    base_ref, staged_ref, acc_ref, sem, *, E, rows,
+                    weighted):
+    """One tile of tokens. The sort keeps a group's tokens in order, so the
+    tile's rows of ONE expert are a run of neighbouring buffer rows
+    (`runs_ref`: where each held expert's run starts, tile by tile): the
+    blocks of `_COMBINE_BLOCK` rows that cover the runs are copied from the
+    buffer in HBM side by side into `stage_ref` (no block of an expert
+    that no token of the tile chose, none of the buffer's empty half), and
+    a chunk of staged rows is summed at its tokens by ONE product with
+    p[n, r] = w[n, j] where slot[n, j] is staged row r's buffer row (a row
+    is one choice's: one term an entry at the most, so the product is the
+    weighted float32 sum; a float32 weight goes to the MXU as its three
+    bfloat16 parts)."""
+    i = pl.program_id(0)
+    tn, k = slot_ref.shape
+    blk, chunk = _COMBINE_BLOCK, _COMBINE_CHUNK
+    half = i % 2
+
+    def block_copy(source, at, half):
+        return pltpu.make_async_copy(
+            y_ref.at[pl.ds(pl.multiple_of(source, blk), blk)],
+            stage_ref.at[half, pl.ds(pl.multiple_of(at * blk, blk), blk)],
+            sem.at[half])
+
+    def fetch_tile(t, half):
+        """Start the copies of tile t's blocks into its half of the stage."""
+        def fetch_run(e, staged):
+            lo = jnp.minimum(runs_ref[t * E + e], rows)
+            hi = jnp.minimum(runs_ref[(t + 1) * E + e], rows)
+            first = lo // blk
+            n = jnp.where(hi > lo, (hi + blk - 1) // blk - first, 0)
+
+            def fetch(b, carry):
+                base_ref[half, staged + b] = (first + b) * blk
+                block_copy((first + b) * blk, staged + b, half).start()
+                return carry
+
+            jax.lax.fori_loop(0, n, fetch, 0)
+            return staged + n
+
+        staged_ref[half] = jax.lax.fori_loop(0, E, fetch_run, 0)
+
+    @pl.when(i == 0)
+    def _first():
+        # a staged row that no slot names is multiplied by zero: finite
+        stage_ref[...] = jnp.zeros_like(stage_ref)
+        fetch_tile(0, 0)
+
+    @pl.when(i + 1 < pl.num_programs(0))
+    def _ahead():  # the next tile's copies fly under this tile's products
+        fetch_tile(i + 1, 1 - half)
+
+    staged = staged_ref[half]
+
+    def landed(_, carry):
+        block_copy(0, 0, half).wait()
+        return carry
+
+    jax.lax.fori_loop(0, staged, landed, 0)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
+    slot, w = slot_ref[...], w_ref[...]
+
+    def product(c, carry):
+        # the buffer row of each staged row of the chunk; a block past the
+        # staged ones names no slot
+        start = jnp.full((1, chunk), -chunk, jnp.int32)
+        for q in range(chunk // blk):
+            b = c * (chunk // blk) + q
+            start = jnp.where((lane // blk == q) & (b < staged),
+                              base_ref[half, b], start)
+        row = start + lane % blk
+        p = jnp.zeros((tn, chunk), _F32)
+        for j in range(k):
+            p = p + jnp.where(slot[:, j:j + 1] == row, w[:, j:j + 1], 0.0)
+        staged_rows = stage_ref[half, pl.ds(
+            pl.multiple_of(c * chunk, chunk), chunk), :]
+        if staged_rows.dtype != jnp.bfloat16:
+            parts = (p,)
+        else:
+            parts = _split3(p) if weighted else (p.astype(jnp.bfloat16),)
+        for part in parts:
+            acc_ref[...] += _select(part, staged_rows)
+        return carry
+
+    jax.lax.fori_loop(0, (staged * blk + chunk - 1) // chunk, product, 0)
+    o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _combine_pallas(y, slot, w, runs, *, weighted, dtype, name):
+    R, D = y.shape
+    N, k = slot.shape
+    E = runs.shape[1]
+    tn = _COMBINE_TOKENS
+    blocks = _combine_blocks(k, E)
+    tokens = pl.BlockSpec((tn, k), lambda i, runs: (i, 0))
+    need = (2 * blocks * _COMBINE_BLOCK * D * y.dtype.itemsize
+            + tn * D * (4 + 4 * jnp.dtype(dtype).itemsize))
+    return pl.pallas_call(
+        functools.partial(_combine_kernel, E=E, rows=R, weighted=weighted),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(N // tn,),
+            in_specs=[tokens, tokens, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tn, D), lambda i, runs: (i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, blocks * _COMBINE_BLOCK, D), y.dtype),
+                pltpu.SMEM((2, blocks), jnp.int32),
+                pltpu.SMEM((2,), jnp.int32),
+                pltpu.VMEM((tn, D), _F32),
+                pltpu.SemaphoreType.DMA((2,))],
+        ),
+        out_shape=jax.ShapeDtypeStruct((N, D), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=min(need + 16 * 2 ** 20, 110 * 2 ** 20)),
+        name=name,
+        interpret=interpret_mode(),
+    )(runs.reshape(-1), slot, w, y)
+
+
+def _combine_xla(y, slot, w, runs, *, weighted, dtype, name):
+    del runs, weighted, name
+    out = jnp.zeros((slot.shape[0], y.shape[1]), _F32)
+    for j in range(slot.shape[1]):  # never [N, k, D] at once
+        picked = jnp.take(y, slot[:, j], axis=0, mode="fill", fill_value=0)
+        out = out + picked.astype(_F32) * w[:, j:j + 1]
+    return out.astype(dtype)
+
+
+def gather_rows(y, rows, w=None):
+    """out[n] = sum over j of w[n, j] * y[slot[n, j]] in float32, rounded
+    once to y's type: the rows of the sorted buffer y [R, D] summed at
+    their tokens through `group_rows`'s tables `rows` (`slot` [N, k]; a
+    slot of R, the fill row, adds nothing); w (float32 [N, k]) left out
+    weighs every row 1. What a scatter-add through `token` would add up,
+    read from the tokens' side: the experts' results back at their tokens
+    (weighted), and the gradient of the gather into the buffer (not).
+    y is finite (the kernel multiplies a fetched row that no slot of its
+    tile names by zero)."""
+    slot, runs = rows["slot"], rows["runs"]
+    N, k = slot.shape
+    weighted = w is not None
+    w = w.astype(_F32) if weighted else jnp.ones((N, k), _F32)
+    staged = (2 * _combine_blocks(k, runs.shape[1]) * _COMBINE_BLOCK
+              * y.shape[1] * y.dtype.itemsize)
+    tiled = (y.shape[1] % _LANES == 0 and N % _COMBINE_TOKENS == 0
+             and y.shape[0] % _COMBINE_BLOCK == 0
+             and staged <= _VMEM_BYTES // 2 and runs.size <= 2 ** 15)
+    return _grouped_call(_combine_pallas, _combine_xla, tiled, y, slot, w,
+                         runs, weighted=weighted, dtype=y.dtype,
+                         name="moe_combine")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))

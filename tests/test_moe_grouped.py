@@ -240,3 +240,182 @@ def test_mixtrals_training_rows_take_the_grouped_form_with_unchanged_numbers(
     assert abs(float(loss_new) - float(loss_old)) < 1e-6
     for a, b in zip(jax.tree.leaves(g_new), jax.tree.leaves(g_old)):
         np.testing.assert_allclose(a, b, atol=2e-6)
+
+
+def _routing(case, N=64):
+    """-> (ids [N,k], weights, first, E, W, tile, bound) of a share layer,
+    of a layer that holds every expert, and of a share layer whose routing
+    passes the bound (every token chooses held experts alone)."""
+    k, W, tile = 3, 16, 8
+    first, E = (0, W) if case == "all-held" else (4, 4)
+    pick = jnp.arange(first, first + E) if case == "past-the-bound" else (
+        jnp.arange(W))
+    ids = jnp.stack([jax.random.permutation(jax.random.PRNGKey(i), pick)[:k]
+                     for i in range(N)]).astype(jnp.int32)
+    weights = jax.random.uniform(jax.random.PRNGKey(9), (N, k))
+    return (ids, weights, first, E, W, tile,
+            moe.grouped_rows_bound(N, k, E, W, tile))
+
+
+@pytest.mark.parametrize("case", ["share", "all-held", "past-the-bound"])
+def test_slot_is_the_inverse_of_token(case):
+    ids, weights, first, E, W, tile, bound = _routing(case)
+    N, k = ids.shape
+    rows = moe.group_rows(ids, weights, first, E, tile, bound)
+    token, slot = np.asarray(rows["token"]), np.asarray(rows["slot"])
+    tile_expert = np.asarray(rows["tile_expert"])
+    held = (np.asarray(ids) >= first) & (np.asarray(ids) < first + E)
+    named = slot < bound
+    assert slot.shape == (N, k) and (slot[~named] == bound).all()
+    assert not named[~held].any()  # a choice that is not held: the fill row
+    past = int(rows["rows"]) > bound
+    assert past == (case == "past-the-bound")
+    # every held choice has its row, but for those a routing past the bound
+    # leaves out; every used row is named exactly once
+    assert past or (named == held).all()
+    n, j = np.nonzero(named)
+    assert (token[slot[n, j]] == n).all()
+    assert (first + tile_expert[slot[n, j] // tile] == np.asarray(ids)[n, j]).all()
+    assert sorted(slot[named]) == sorted(np.nonzero(token < N)[0])
+    # `choice`: the same rows by their choice's number; no two rows name one
+    choice = np.asarray(rows["choice"])
+    assert (choice[slot[n, j]] == n * k + j).all()
+    assert len(set(choice)) == bound and (choice[token == N] >= N * k).all()
+    # the runs' table: a tile of tokens' rows of one expert lie side by side
+    runs = np.asarray(rows["runs"])
+    assert runs.shape == (-(-N // moe._COMBINE_TOKENS) + 1, E)
+    for e in range(E):
+        mine = np.sort(slot[named & (np.asarray(ids) == first + e)])
+        assert not len(mine) or (runs[0, e] == mine[0] and (
+            past or runs[-1, e] == mine[-1] + 1))
+
+
+def _scatter_add_layer(x, lp, cfg, tile, bound):
+    """The form `_moe_ffn_grouped` had: XLA's gather into the buffer (whose
+    transpose scatter-adds) and a float32 scatter-add back."""
+    dtype = x.dtype
+    B, T, D = x.shape
+    N, E, k = B * T, cfg.num_experts, cfg.num_selected_experts
+    _, weights, expert_ids = tr._moe_gate(x, lp, cfg)
+    rows = moe.group_rows(expert_ids.reshape(N, k), weights.reshape(N, k),
+                          cfg.experts_first, E, tile, bound)
+    sorted_x = jnp.take(x.reshape(N, D), rows["token"], axis=0, mode="fill",
+                        fill_value=0)
+    y = moe.grouped_ffn(tr._GATE_ACT[cfg.activation], tile, sorted_x,
+                        lp["w_in"], lp["w_gate"], lp["w_out"],
+                        rows["tile_expert"], rows["used"])
+    out = jnp.zeros((N, D), jnp.float32).at[rows["token"]].add(
+        y.astype(jnp.float32) * rows["weight"][:, None], mode="drop")
+    return out.astype(dtype).reshape(B, T, D)
+
+
+def _layer_loss(form):
+    def f(x, lp):
+        out = form(x, lp)
+        return jnp.sum(out * jnp.cos(
+            jnp.arange(out.size).reshape(out.shape) * 0.01))
+    return f
+
+
+@pytest.mark.parametrize("name", ["8-top-2-swiglu-softmax",
+                                  "4-held-of-16-sigmoid-bias"])
+def test_the_gather_sum_is_the_scatter_add_it_replaces_and_so_are_its_gradients(
+        name):
+    cfg = _formulation(name)
+    lp, x = _layer(cfg, pile=1.0)
+    grouped = tr.moe_grouped(cfg, 2, 256, None)
+    new = _layer_loss(lambda x, lp: tr._moe_ffn_grouped(
+        x, lp, cfg, None, *grouped)[0])
+    old = _layer_loss(lambda x, lp: _scatter_add_layer(x, lp, cfg, *grouped))
+    with jax.default_matmul_precision("highest"):
+        v_new, g_new = jax.value_and_grad(new, (0, 1))(x, lp)
+        v_old, g_old = jax.value_and_grad(old, (0, 1))(x, lp)
+    assert abs(float(v_new) - float(v_old)) < 1e-4 * abs(float(v_old))
+    assert set(g_new[1]) >= {"router", "w_in", "w_gate", "w_out"}
+    for a, b in zip(jax.tree.leaves(g_new), jax.tree.leaves(g_old)):
+        # float32 sums in another order, no more
+        np.testing.assert_allclose(a, b, atol=2e-6 * max(1.0, float(jnp.abs(b).max())))
+    assert float(jnp.abs(g_new[1]["router"]).max()) > 1e-4
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("weighted", [True, False], ids=["weighted", "plain"])
+@pytest.mark.parametrize("case", ["share", "all-held", "past-the-bound"])
+def test_the_combine_kernel_is_its_xla_form(case, weighted, dtype, kernel):
+    N = 3 * moe._COMBINE_TOKENS  # a tile's copies start under the one before
+    ids, weights, first, E, W, _, _ = _routing(case, N=N)
+    tile = 16
+    bound = moe.grouped_rows_bound(N, ids.shape[1], E, W, tile)
+    rows = moe.group_rows(ids, weights, first, E, tile, bound)
+    y = jax.random.normal(jax.random.PRNGKey(3), (bound, 128)).astype(dtype)
+    w = weights if weighted else jnp.ones_like(weights)
+    args = (y, rows["slot"], w, rows["runs"])
+    kw = dict(weighted=weighted, dtype=jnp.float32, name="moe_combine")
+    got = moe._combine_pallas(*args, **kw)
+    want = moe._combine_xla(*args, **kw)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    token = np.asarray(rows["token"])
+    scattered = jnp.zeros((N, 128)).at[rows["token"]].add(
+        y.astype(jnp.float32) * (rows["weight"] if weighted else (
+            token < N))[:, None], mode="drop")
+    np.testing.assert_allclose(got, scattered, atol=2e-6)
+    # through the dispatch, as the layer calls it: rounded once to y's type
+    got = moe.gather_rows(y, rows, weights if weighted else None)
+    assert got.dtype == y.dtype
+    np.testing.assert_allclose(got.astype(jnp.float32), want.astype(dtype),
+                               atol=2e-6 if dtype == "float32" else 0.04)
+
+
+@pytest.mark.parametrize("name", ["8-top-2-swiglu-softmax",
+                                  "4-held-of-16-sigmoid-bias"])
+def test_the_layer_and_its_gradient_scatter_add_no_rows(name):
+    """Structural: the traced value-and-grad of the layer holds no
+    scatter-add whose update is 2-D (rows); the parent's form, traced the
+    same way, holds them, so the search finds what it looks for."""
+    cfg = _formulation(name)
+    lp, x = _layer(cfg)
+    grouped = tr.moe_grouped(cfg, 2, 256, None)
+
+    def row_scatters(form):
+        jaxpr = jax.make_jaxpr(jax.value_and_grad(_layer_loss(form), (0, 1)))(
+            x, lp)
+        found = []
+
+        def walk(j):
+            for eqn in j.eqns:
+                # 2-D: rows of the stream's width (the XLA forms of the
+                # weights' kernel and the router's top k add 3-D updates)
+                if eqn.primitive.name.startswith("scatter") and (
+                        eqn.invars[2].aval.ndim == 2):
+                    found.append(eqn.primitive.name)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+        walk(jaxpr.jaxpr)
+        return found
+
+    assert not row_scatters(lambda x, lp: tr._moe_ffn_grouped(
+        x, lp, cfg, None, *grouped)[0])
+    assert len(row_scatters(
+        lambda x, lp: _scatter_add_layer(x, lp, cfg, *grouped))) == 2
+
+
+def test_the_layer_through_its_kernels_is_the_layer_through_their_xla_forms(
+        monkeypatch):
+    """The share layer's value and gradients with every kernel in interpret
+    mode (the three products, and `moe_combine` forward and backward)
+    against the XLA forms the CPU takes."""
+    cfg = _formulation("4-held-of-16-sigmoid-bias")
+    lp, x = _layer(cfg, pile=1.0)
+    grouped = tr.moe_grouped(cfg, 2, 256, None)
+    loss = _layer_loss(lambda x, lp: tr._moe_ffn_grouped(
+        x, lp, cfg, None, *grouped)[0])
+    with jax.default_matmul_precision("highest"):
+        v_xla, g_xla = jax.value_and_grad(loss, (0, 1))(x, lp)
+        monkeypatch.setenv("RAY_TPU_FORCE_PALLAS", "1")
+        jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, (0, 1)))(x, lp)
+        # out and dx, each as the TPU's branch and as the interpreted one
+        assert str(jaxpr).count("name=moe_combine") == 4
+        v_kernel, g_kernel = jax.value_and_grad(loss, (0, 1))(x, lp)
+    assert abs(float(v_kernel) - float(v_xla)) < 1e-4 * abs(float(v_xla))
+    for a, b in zip(jax.tree.leaves(g_kernel), jax.tree.leaves(g_xla)):
+        np.testing.assert_allclose(a, b, atol=TOL * max(1.0, float(jnp.abs(b).max())))

@@ -1002,12 +1002,18 @@ def test_the_trained_stacks_step_fits_and_holds_no_score_matrix(
     assert abs(total - wanted["total"]) < 0.3 and total < 14.5
     text = compiled.as_text()
     calls = collections.Counter(re.findall(
-        r"%(flash_\w+?|moe_gmm\w*?)(?:\.\d+)? = ", text))
+        r"%(flash_\w+?|moe_gmm\w*?|moe_combine)(?:\.\d+)? = ", text))
     # a scan's body and a layer alone each hold their kernels once
     assert calls["flash_fwd_window"] == calls["flash_bwd_window_dq"] == 2
     assert calls["flash_fwd"] == calls["flash_bwd_dq"] == 1
     assert not [name for name in calls if name.endswith("_dkv")]  # one pass
     assert calls["moe_gmm_dx"] == calls["moe_gmm_dw"] == 6
+    # a row leaves the sorted buffer by a gather-sum through the inverse
+    # table, forward (the weighted combine) and backward (the gradient of the
+    # gather into the buffer): no scatter over the tokens' or the buffer's rows
+    assert calls["moe_combine"] == 4
+    assert not [line for line in text.splitlines() if " scatter(" in line
+                and re.search(rf"\[({rows * T}|73728),{spec['hidden_size']}\]", line)]
     assert not re.search(rf"\[[\d,]*{T},{T}\]", text)  # no [T, T] scores
     # the largest temporaries are the float32 logits and their cotangent
     assert f"f32[{rows},{T},{spec['vocab_size']}]" in text
