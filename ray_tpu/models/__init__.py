@@ -11,14 +11,18 @@ normalised queries and keys; two dense layers, then experts routed by
 sigmoid score + bias), each with a tiny twin for the CPU (`tiny-sambay`,
 `tiny-lfm2`), and eight more families since (models/config.py registers
 them; models/stack.py's header lists the kinds). A stack whose kinds are
-all `"attn"` or `"swa"` (trained: `config.TRAINABLE_KINDS`) goes through
-`forward` / `loss_fn` / `param_axes` / `make_train_step` like the
+all `"attn"`, `"swa"` or `"mla"` (trained: `config.TRAINABLE_KINDS`) goes
+through `forward` / `loss_fn` / `param_axes` / `make_train_step` like the
 one-block models: `trinity-mini` / `tiny-trinity` (window and full gated
 GQA, a norm on both sides of every sublayer, `norm_place="both"` where
 `post_norm` is the older word for `"post"`; sigmoid-routed experts beside
-a shared one). The other kinds are served only, and refused by name. The
-benchmark reaches them through benchmark/families/ (`mistral.py`,
-`sambay.py`, `shortconv_moe.py`, .., `trinity_afmoe.py`).
+a shared one) and `xing4.0-29b-a4b` / `tiny-xing4` (latent attention under
+yarn inside four residual streams mixed round every sublayer,
+`hc_streams`, with a multi-token prediction block in the loss,
+`mtp_depth`; trained only: the engine refuses the streams by name). The
+other kinds are served only, and refused by name. The benchmark reaches
+them through benchmark/families/ (`mistral.py`, `sambay.py`,
+`shortconv_moe.py`, .., `trinity_afmoe.py`, `xing4_mhc.py`).
 """
 
 from .config import (  # noqa: F401

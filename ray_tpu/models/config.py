@@ -8,10 +8,12 @@ family. A model whose layers differ names each layer's mixer in
 `layer_kinds` and says which second halves are dense (a StackConfig).
 models/stack.py runs both on the serve path: a plain ModelConfig is the
 stack whose every layer is "attn". A StackConfig whose kinds are all in
-`TRAINABLE_KINDS` ("attn" and "swa", with dense or expert second halves)
-trains too, through the same `forward` / `loss_fn` / `param_axes` /
-`make_train_step` as the one-block models; the other kinds' ops have no
-backward yet (`StackConfig.untrainable`).
+`TRAINABLE_KINDS` ("attn", "swa" and "mla", with dense or expert second
+halves; one residual stream or several, `hc_streams`; a multi-token
+prediction block in the loss, `mtp_depth`) trains too, through the same
+`forward` / `loss_fn` / `param_axes` / `make_train_step` as the one-block
+models; the other kinds' ops have no backward yet
+(`StackConfig.untrainable`).
 
 A window layer's keys are held in one of two ways. The "window" kind
 (differential pairs, one family) keeps them in per-slot state: every decode
@@ -42,12 +44,16 @@ _LANES = 128
 # the kinds whose attention is differential over pairs of heads
 _DIFFERENTIAL = ("window", "full", "cross")
 # the kinds a stack can be TRAINED with (every op they run has a backward:
-# the flash kernels with and without a window, the grouped expert product);
+# the flash kernels with and without a window, the grouped expert product;
+# "mla": a training row has no past, so its latent attention is the plain
+# form, keys and values up-projected once into the flash kernels, and the
+# paged latent ops, which have no backward, are never reached);
 # the others wait for a backward through the op named beside them
-TRAINABLE_KINDS = ("attn", "swa")
+TRAINABLE_KINDS = ("attn", "swa", "mla")
 _NO_BACKWARD = {"mamba": "ops/ssm.py", "gmu": "ops/ssm.py",
                 "gdn": "ops/gdn.py", "ssd": "ops/ssd.py",
-                "mla": "ops/mla_attention.py", "mla2": "ops/mla_attention.py",
+                "mla2": "ops/mla_attention.py: nothing has differentiated "
+                        "the double block and its shortcut experts",
                 "conv": "the short convolution's tail state",
                 "window": "the differential pairs' plain form",
                 "full": "the differential pairs' plain form",
@@ -139,6 +145,10 @@ class ModelConfig:
     router_input = "ffn"
     window_paged = False
     d_ff_shared = 0
+    # one residual stream, one head in the loss, plain rotary tables
+    hc_streams = 1
+    mtp_depth = 0
+    rope_yarn = None
 
     @property
     def experts_routed(self) -> int:
@@ -368,6 +378,33 @@ class StackConfig(ModelConfig):
     attention_multiplier: Optional[float] = None
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
+    # the residual path (manifold-constrained hyper-connections,
+    # arXiv:2512.24880): n > 1 streams [B,T,n,D] in the residual's place.
+    # Round EVERY sublayer, from the normalised 4D-wide token through its own
+    # `phi` [nD, n*n + 2n]: the sublayer reads sum_j H_pre[j] x[j], and
+    # x+[i] = sum_j H_res[i,j] x[j] + H_post[i] y, with H_pre = sigmoid(.),
+    # H_post = 2 sigmoid(.) and H_res = `hc_sinkhorn_iters` rounds of column
+    # then row normalisation (`hc_eps` in both denominators) of
+    # exp(clip(., *hc_res_clamp)). The embedding is copied into every stream
+    # and the streams are summed before the final norm. 1: x + y, and
+    # nothing is emitted
+    hc_streams: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
+    # multi-token prediction blocks in the LOSS (DeepSeek-V3 2.2; 0 or 1):
+    # a projection of [norm(h) ; norm(E[next token])] (h the stream before
+    # the final norm), ONE more expert layer with parameters of its own, a
+    # final norm of its own and the SHARED head, scored against the token
+    # after next and weighted `mtp_weight` into the loss. A train step's
+    # alone: the serve path never reads `params["mtp"]`
+    mtp_depth: int = 0
+    mtp_weight: float = 0.1
+    # the latent kinds' rotary lanes under yarn: (factor, original max
+    # positions, beta_fast, beta_slow, mscale, mscale_all_dim) (ops/rope.py
+    # `yarn_inv_freq`, `yarn_mscale`); the scores are scaled by the square of
+    # yarn_mscale(factor, mscale_all_dim). None: theta alone
+    rope_yarn: Optional[Tuple[float, int, float, float, float, float]] = None
 
     def __post_init__(self) -> None:
         kinds = tuple(self.layer_kinds)
@@ -459,6 +496,32 @@ class StackConfig(ModelConfig):
         if self.d_ff_shared and not self.is_moe:
             raise ValueError("shared experts stand beside routed ones: "
                              "`d_ff_shared` needs `num_experts` > 0")
+        if self.hc_streams < 1 or self.hc_sinkhorn_iters < 1:
+            raise ValueError("`hc_streams` and `hc_sinkhorn_iters` are "
+                             "counts: 1 or more")
+        if self.hc_streams > 1 and ("mla2" in kinds
+                                    or self.router_input == "layer"):
+            raise ValueError(
+                "`hc_streams` > 1 is written round a layer's two sublayers "
+                "(a mixer, then its second half): not round an mla2 double "
+                'block, nor where the router reads the layer\'s input '
+                '(router_input="layer")')
+        if self.mtp_depth not in (0, 1):
+            raise ValueError("`mtp_depth` is 0 or 1: one multi-token "
+                             f"prediction block is written, not {self.mtp_depth}")
+        if self.mtp_depth and (kinds[-1:] == ("mla2",) or not self.is_moe):
+            raise ValueError(
+                "`mtp_depth`: the prediction block is one more layer of the "
+                "stack's last kind with an expert second half: it needs "
+                "`num_experts` > 0 and a last layer that is not mla2")
+        if self.rope_yarn is not None:
+            object.__setattr__(self, "rope_yarn", tuple(self.rope_yarn))
+            if set(kinds) != {"mla"} or len(self.rope_yarn) != 6:
+                raise ValueError(
+                    "`rope_yarn` (factor, original max positions, beta_fast, "
+                    "beta_slow, mscale, mscale_all_dim) scales the rotary "
+                    'lanes of "mla" layers; the other kinds\' tables take '
+                    "theta alone")
         if self.experts_first + self.num_experts > self.experts_routed:
             raise ValueError(
                 f"experts {self.experts_first}.. of {self.num_experts} held "
@@ -651,8 +714,15 @@ class StackConfig(ModelConfig):
                 + (W if self.router != "softmax" else 0)
                 + 3 * D * self.d_ff_shared}
         norms = (4 if self.norm_place == "both" else 2) * norm
+        n = self.hc_streams
+        if n > 1:  # the residual path round both sublayers: phi, b, 3 scalars
+            norms += 2 * ((n * D + 1) * (n * n + 2 * n) + 3)
+        # the prediction block: a last layer over again with experts, the
+        # projection of two normed halves, its own final norm
+        mtp = self.mtp_depth * (self._mixer_params(self.layer_kinds[-1])
+                                + half["moe"] + norms + 2 * D * D + 3 * norm)
         return (sum(self._mixer_params(k) for k in self.layer_kinds)
-                + sum(half[h] + norms for h in self.second_halves)
+                + sum(half[h] + norms for h in self.second_halves) + mtp
                 + V * D * (1 if self.tie_embeddings else 2) + norm)
 
 
@@ -1052,4 +1122,51 @@ register(StackConfig(
     attn_gate=True, norm_place="both", n_dense_layers=2, d_ff_expert=128,
     d_ff_shared=128, router="sigmoid", norm_topk=True, routed_scale=2.826,
     router_bias_rate=0.001, embedding_multiplier=128 ** 0.5,
+))
+
+
+register(StackConfig(
+    name="xing4.0-29b-a4b",
+    # XingChen-AGI/Xing4.0-29B-A4B (`xing4_0`): 29 B parameters, about 4 B
+    # active a token: the DeepSeek-V3 block (40 layers of latent attention,
+    # 32 heads of 128 + 64 against values of 128, queries through a
+    # bottleneck of 768, a 512 + 64 latent row; two dense layers of 9216,
+    # then 64 experts of 1024, 4 a token by sigmoid score + bias,
+    # renormalised, times 2, beside one shared expert) inside FOUR residual
+    # streams mixed round every sublayer (mHC: 20 Sinkhorn rounds), yarn
+    # rotary lanes (x 64 over 4096) and one multi-token prediction block
+    vocab_size=131072,
+    d_model=3584, n_layers=40, n_heads=32, d_ff=9216, max_seq_len=262144,
+    norm="rmsnorm", activation="swiglu", positional="none",
+    rope_theta=10000.0, tie_embeddings=False, norm_eps=1e-6,
+    num_experts=64, num_selected_experts=4, capacity_factor=64 / 4,
+    router_aux_coef=0.0,
+    layer_kinds=("mla",) * 40, n_dense_layers=2, d_ff_expert=1024,
+    d_ff_shared=1024, router="sigmoid", norm_topk=True, routed_scale=2.0,
+    router_bias_rate=0.001, q_lora_rank=768, kv_lora_rank=512,
+    qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128,
+    hc_streams=4, hc_sinkhorn_iters=20, hc_eps=1e-6,
+    hc_res_clamp=(-30.0, 30.0), mtp_depth=1, mtp_weight=0.1,
+    rope_yarn=(64.0, 4096, 32.0, 1.0, 1.0, 1.0),
+))
+
+register(StackConfig(
+    name="tiny-xing4",
+    # the same stack's shape at toy widths: one dense layer, then three
+    # expert layers of 8 experts top 2 beside a shared one, four streams,
+    # yarn over 32 positions stretched 8 times, a prediction block
+    vocab_size=512,
+    d_model=128, n_layers=4, n_heads=4, d_ff=256, max_seq_len=512,
+    dtype="float32", remat=False,
+    norm="rmsnorm", activation="swiglu", positional="none",
+    rope_theta=10000.0, tie_embeddings=False, norm_eps=1e-6,
+    num_experts=8, num_selected_experts=2, capacity_factor=8 / 2,
+    router_aux_coef=0.0,
+    layer_kinds=("mla",) * 4, n_dense_layers=1, d_ff_expert=128,
+    d_ff_shared=128, router="sigmoid", norm_topk=True, routed_scale=2.0,
+    router_bias_rate=0.001, q_lora_rank=32, kv_lora_rank=16,
+    qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
+    hc_streams=4, hc_sinkhorn_iters=20, hc_eps=1e-6,
+    hc_res_clamp=(-30.0, 30.0), mtp_depth=1, mtp_weight=0.1,
+    rope_yarn=(8.0, 32, 32.0, 1.0, 1.0, 1.0),
 ))

@@ -120,16 +120,28 @@ by side in one row), whose ops take the plain queries and keys of this
 file: the layout is theirs. The "attn" layers of a plain ModelConfig shard
 by the one-block models' rules (a `tp` mesh reaches the paged calls through
 the mode) and train through models/transformer.py's own loop over the same
-projections and second half. A StackConfig whose kinds are all "attn" or
-"swa" (`config.TRAINABLE_KINDS`; dense, expert and shared second halves)
-trains through THIS file: `forward` is what `loss_fn` differentiates, the
-plain `Seq` names what the layer loop's checkpoint keeps (`cfg.remat`: the
-attention half, the grouped experts' two up products and their combined
-result), counts every
+projections and second half. A StackConfig whose kinds are all "attn",
+"swa" or "mla" (`config.TRAINABLE_KINDS`; dense, expert and shared second
+halves) trains through THIS file: `forward` is what `loss_fn`
+differentiates, the plain `Seq` names what the layer loop's checkpoint keeps
+(`cfg.remat`: the attention half, the grouped experts' two up products and
+their combined result; a latent layer's three bottlenecks), counts every
 expert layer's choices for the train step (`route_counts`, `move_router_bias`)
 and `param_axes` gives its leaves their logical axes. The other kinds' ops
 have no backward yet, and `param_axes` / `make_train_step` refuse them by
 name.
+
+The residual is ONE function round both sublayers of a layer
+(models/transformer.py `_residual`): `x + y` for one stream, and for
+`cfg.hc_streams` n > 1 the mixing of n streams [B,n,T,D] (manifold-
+constrained hyper-connections: scopes `mhc_pre`, `mhc_post`; `_Mode.embed`
+copies the embedding into every stream, `_run` sums them before the final
+norm), where a checkpoint keeps each sublayer's raw coefficients and the
+attention sublayer's output and never the n-wide stream. Where
+`cfg.mtp_depth`, `forward(.., mtp_tokens=)` runs one more expert layer over
+the trunk's stream joined with the next token's embedding (`_mtp`, scope
+`mtp`) and hands `loss_fn` a second set of logits through the shared head.
+The latent kinds' rotary lanes follow `cfg.rope_yarn` where it is set.
 """
 
 from __future__ import annotations
@@ -151,12 +163,15 @@ from ..ops import (
     write_then_attend,
 )
 from ..ops.gdn import gdn_chunk, gdn_step, state_shape
-from ..ops.rope import rope_frequencies
+from ..ops.rope import rope_frequencies, yarn_inv_freq, yarn_mscale
 from ..ops.ssd import ssd_chunk, ssd_step, state_shape as ssd_state_shape
 from ..ops.ssm import ssm_scan, ssm_step
 from ..parallel.sharding import _current_mesh, split_ways
 from .config import ModelConfig
 from .transformer import (
+    HC_COEF_NAME,
+    HC_OUT_NAME,
+    MOE_CHOICE_NAMES,
     _KEPT_UNDER_REMAT,
     _dense_ffn,
     _kept_widths,
@@ -165,10 +180,12 @@ from .transformer import (
     _lm_head,
     _moe_ffn,
     _moe_gate,
+    _embed_lookup,
     _norm,
     _prologue,
     _qkv,
     _remat,
+    _residual,
     moe_ffn_groups,
     moe_ffn_ids,
     moe_ffn_step,
@@ -176,6 +193,7 @@ from .transformer import (
     moe_seq_groups,
     moe_step_visits,
 )
+from ..ops.attention import FLASH_RESIDUAL_NAMES
 from ..ops.moe import GROUPED_RESIDUAL_NAMES
 
 Params = Dict[str, Any]
@@ -190,8 +208,16 @@ _EXPERT_LEAVES = ("w_in", "w_gate", "w_out")
 # grouped experts (ops/moe.py `grouped_ffn`) and their combined result; the
 # norms, the head gate, the router, the sort, the experts' down product (the
 # router's gradient reads it), the shared expert and a dense FFN are computed
-# again
-KEPT_UNDER_REMAT = _KEPT_UNDER_REMAT + GROUPED_RESIDUAL_NAMES
+# again. A latent layer keeps its three bottlenecks (the queries', the
+# latent, the shared rotary key: a few hundred lanes a token) and projects
+# the heads' q, k and v up from them again; a layer of several residual
+# streams keeps each sublayer's raw mixing coefficients and the attention
+# sublayer's output in the 4-wide stream's place (`attn_half` is then
+# nowhere), and mixes the streams again from the layer's input; its expert
+# layers keep the tokens' choices, so that the backward sorts as the forward
+_LATENT_NAMES = ("mla_cq", "mla_c", "mla_kr")
+_STACK_NAMES = _LATENT_NAMES + (HC_COEF_NAME, HC_OUT_NAME) + MOE_CHOICE_NAMES
+KEPT_UNDER_REMAT = _KEPT_UNDER_REMAT + GROUPED_RESIDUAL_NAMES + _STACK_NAMES
 
 
 # ---------------------------------------------------------------------------
@@ -203,7 +229,8 @@ def layer_shapes(cfg: ModelConfig, kind: str,
                  half: str = "ffn") -> Dict[str, tuple]:
     """name -> (shape, init) of one layer of `kind` whose second half is
     `half` ("ffn" or "moe"); init is "w" (normal), "out" (normal, scaled
-    down with depth), "one" or "zero"."""
+    down with depth), "one", "zero", or the residual path's "hc_b" / "hc_a"
+    (`hc_start`)."""
     D, F, H, KVH, hd = cfg.d_model, cfg.d_ff, cfg.n_heads, cfg.kv_heads, cfg.hdim
     Di, N, R, K = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank, cfg.ssm_conv
     out = {"ln1": ((D,), "one"), "ln2": ((D,), "one")}
@@ -211,6 +238,15 @@ def layer_shapes(cfg: ModelConfig, kind: str,
         out.update(ln1_post=((D,), "one"), ln2_post=((D,), "one"))
     if cfg.norm == "layernorm":
         out.update(ln1_b=((D,), "zero"), ln2_b=((D,), "zero"))
+    if cfg.hc_streams > 1:
+        # the residual path round each sublayer (`hc1`: the mixer's, `hc2`:
+        # the second half's): phi a stream at a time, the bias leaning H_res
+        # to the identity, the three scalars small (a_pre, a_post, a_res)
+        n = cfg.hc_streams
+        for tag in ("hc1", "hc2"):
+            out.update({f"{tag}_phi": ((n, D, n * n + 2 * n), "w"),
+                        f"{tag}_b": ((n * n + 2 * n,), "hc_b"),
+                        f"{tag}_a": ((3,), "hc_a")})
     if half == "moe":
         # the router is as wide as the experts there are; the weights are
         # the held experts'
@@ -315,6 +351,18 @@ def layer_shapes(cfg: ModelConfig, kind: str,
     return out
 
 
+def hc_start(cfg: ModelConfig, init: str, lean: float = 4.0) -> jax.Array:
+    """Where a residual path starts: "hc_a": the three scalars at 0.01 (the
+    dynamic term small); "hc_b": H_pre and H_post even (sigmoid(0): every
+    stream read at 1/2, the sublayer's output written at 1) and H_res
+    leaning to the identity by `lean` in the exponent."""
+    n = cfg.hc_streams
+    if init == "hc_a":
+        return jnp.full((3,), 0.01, _F32)
+    return jnp.concatenate([jnp.zeros((2 * n,), _F32),
+                            (lean * jnp.eye(n, dtype=_F32)).reshape(-1)])
+
+
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     """Random float32 parameters. `layers` is a list of segments
     (cfg.segments()), each a tuple with one dict per layer of the period,
@@ -325,6 +373,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         if init in ("w", "out"):
             scale = 0.02 if init == "w" else out_scale
             return jax.random.normal(k, shape, _F32) * scale
+        if init in ("hc_b", "hc_a"):
+            return hc_start(cfg, init)
         return jnp.full(shape, 1.0 if init == "one" else 0.0, _F32)
 
     def layer(k, kind, half):
@@ -352,7 +402,23 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             _F32) * 0.02
     if cfg.norm == "layernorm":
         out["final_norm_b"] = jnp.zeros((cfg.d_model,), _F32)
+    if cfg.mtp_depth:
+        k_mtp, k_proj = jax.random.split(jax.random.fold_in(k_layers, cfg.n_layers))
+        shapes = mtp_shapes(cfg)
+        out["mtp"] = {
+            "layer": layer(k_mtp, cfg.layer_kinds[-1], "moe"),
+            **{n: leaf(k_proj, *shapes[n]) for n in sorted(shapes)}}
     return out
+
+
+def mtp_shapes(cfg: ModelConfig) -> Dict[str, tuple]:
+    """name -> (shape, init) of the prediction block's own leaves beside its
+    `layer` (one layer of the stack's last kind with an expert half,
+    unstacked): the norms of the two halves it joins, their projection
+    [2D, D] and its final norm."""
+    D = cfg.d_model
+    return {"h_norm": ((D,), "one"), "e_norm": ((D,), "one"),
+            "proj": ((2 * D, D), "w"), "final_norm": ((D,), "one")}
 
 
 # ---------------------------------------------------------------------------
@@ -485,11 +551,29 @@ def _dense_attend(q, k, v, scale, window=None):
     return _flash(q, k, v, scale=scale, block_q=block, block_k=block, **bound)
 
 
+def _expand(x, cfg):
+    """x [B,T,D] -> the residual streams [B,n,T,D], each a copy (one
+    stream: x as it is). Stream-major, so the last two axes tile as a
+    [T,D] activation's do."""
+    if cfg.hc_streams == 1:
+        return x
+    return jnp.broadcast_to(x[:, None], (x.shape[0], cfg.hc_streams,
+                                         *x.shape[1:]))
+
+
+def _collapse(x, cfg):
+    """The residual streams summed back into one, [B,T,D]."""
+    if cfg.hc_streams == 1:
+        return x
+    return jnp.sum(x.astype(_F32), axis=1).astype(x.dtype)
+
+
 class _Mode:
     """What the modes share: where the tokens of x [B,T] are."""
 
     def embed(self, params: Params, tokens: jax.Array) -> jax.Array:
-        """tokens [B,T] -> x [B,T,D]; the mode keeps the tokens' positions
+        """tokens [B,T] -> x [B,T,D] ([B,n,T,D] under n residual streams:
+        the embedding in every one); the mode keeps the tokens' positions
         `at` ([B,T]; None: 0..T-1) and the rotary tables for its attn
         layers."""
         self.at = self.positions(tokens.shape[1])
@@ -499,7 +583,7 @@ class _Mode:
         if self.cfg.window_paged:  # rotary whatever the "attn" layers are
             self.rope = rope_frequencies(
                 self.cfg.hdim, self.cfg.max_seq_len, self.cfg.rope_theta)
-        return x
+        return _expand(x, self.cfg)
 
     plain = False  # only the whole-sequence forward is (`Seq.plain`)
 
@@ -555,11 +639,12 @@ class Seq(_Mode):
 
     def init_carry(self, x, pools=None, state=None) -> Params:
         cfg = self.cfg
-        B, T, _ = x.shape
+        B, T = x.shape[0], x.shape[-2]
         carry, dtype = {}, x.dtype
         if self.route_counts:  # every expert layer's choices, by expert
             carry["route_counts"] = jnp.zeros(
-                (cfg.second_halves.count("moe"), cfg.router_width), jnp.int32)
+                (cfg.second_halves.count("moe") + cfg.mtp_depth,
+                 cfg.router_width), jnp.int32)
         if cfg.count("mamba"):
             carry["mem"] = jnp.zeros((B, T, cfg.ssm_inner), x.dtype)
         if self.chunk is not None:
@@ -1095,15 +1180,22 @@ def _ssd(h, lp, cfg, si, mode, carry):
                       lp["s_out"].astype(dtype)), carry
 
 
-def _turn(x, at, theta):
+def _turn(x, at, theta, yarn=None):
     """x [B,T,heads,R] turned to the tokens' positions `at` ([B,T]; None:
-    0..T-1): interleaved pairs (2i, 2i+1) by at * theta^(-2i/R), float32
-    inside (XLA fuses it into the projection)."""
+    0..T-1): interleaved pairs (2i, 2i+1) by at * theta^(-2i/R), or by
+    yarn's blend of that and its interpolation (`yarn`: cfg.rope_yarn),
+    float32 inside (XLA fuses it into the projection)."""
     B, T, _, R = x.shape
-    inv = 1.0 / theta ** (jnp.arange(0, R, 2, dtype=_F32) / R)
+    if yarn is None:
+        inv = 1.0 / theta ** (jnp.arange(0, R, 2, dtype=_F32) / R)
+    else:
+        inv = yarn_inv_freq(R, theta, *yarn[:4])
     at = jnp.arange(T)[None] if at is None else at
     ang = at.astype(_F32)[..., None, None] * inv
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None and yarn[4] != yarn[5]:  # the tables' own factor
+        grow = yarn_mscale(yarn[0], yarn[4]) / yarn_mscale(yarn[0], yarn[5])
+        cos, sin = cos * grow, sin * grow
     xf = x.astype(_F32).reshape(*x.shape[:-1], R // 2, 2)
     a, b = xf[..., 0], xf[..., 1]
     out = jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1)
@@ -1148,20 +1240,30 @@ def _mla(h, lp, cfg, fi, mode, carry):
     def up(rank):
         return (D / rank) ** 0.5 if cfg.mla_scale_lora else 1.0
 
+    # a train step's checkpoint keeps the three bottlenecks by name (the
+    # plain forward alone names them: a name moves the numbers in a lowered
+    # serve program's private function names)
+    named = checkpoint_name if mode.plain else (lambda a, name: a)
+    cq_name, c_name, kr_name = _LATENT_NAMES
+    yarn = cfg.rope_yarn
     if cfg.q_lora_rank:
         cq = jnp.einsum("btd,dr->btr", h, lp["wq_a"].astype(dtype))
-        cq = _scaled_rms(cq, lp["q_ln"], eps, up(cfg.q_lora_rank))
+        cq = named(_scaled_rms(cq, lp["q_ln"], eps, up(cfg.q_lora_rank)),
+                   cq_name)
         q = jnp.einsum("btr,rhk->bthk", cq, lp["wq_b"].astype(dtype))
     else:
         q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(dtype))
     c = _scaled_rms(jnp.einsum("btd,dr->btr", h, lp["wkv_a"].astype(dtype)),
                     lp["kv_ln"], eps, up(cfg.kv_lora_rank))
+    c = named(c, c_name)
     k_r = jnp.einsum("btd,dr->btr", h, lp["wkr"].astype(dtype))
-    k_r = _turn(k_r[:, :, None], mode.at, theta)[:, :, 0]
+    k_r = named(_turn(k_r[:, :, None], mode.at, theta, yarn)[:, :, 0], kr_name)
+    scale = (N + cfg.qk_rope_dim) ** -0.5
+    if yarn is not None:  # the score's own factor, squared (q's and k's)
+        scale *= yarn_mscale(yarn[0], yarn[5]) ** 2
     o, carry = mode.attend_mla(
-        carry, fi, q[..., :N], _turn(q[..., N:], mode.at, theta), c, k_r,
-        lp["wk_b"].astype(dtype), lp["wv_b"].astype(dtype),
-        (N + cfg.qk_rope_dim) ** -0.5)
+        carry, fi, q[..., :N], _turn(q[..., N:], mode.at, theta, yarn), c,
+        k_r, lp["wk_b"].astype(dtype), lp["wv_b"].astype(dtype), scale)
     return jnp.einsum("bthv,hvd->btd", o.astype(dtype),
                       lp["wo"].astype(dtype)), carry
 
@@ -1328,32 +1430,37 @@ def _layer(x, lp, cfg, kind, half, layer, idx, mode, carry):
     # the scopes are what a profile's readers key on: the mixer's kind
     # ("attn" as in the training block), then "ffn" or "moe"
     place = cfg.norm_place
-    with jax.named_scope(kind):
+
+    def mixer(x):
         h = x if place == "post" else _norm(x, lp["ln1"], lp.get("ln1_b"), cfg)
         if kind in ("attn", "swa"):
-            o, carry = _attn(h, lp, cfg, idx, mode, carry, kind == "swa")
+            o, c = _attn(h, lp, cfg, idx, mode, carry, kind == "swa")
         elif kind == "mla":
-            o, carry = _mla(h, lp, cfg, idx, mode, carry)
+            o, c = _mla(h, lp, cfg, idx, mode, carry)
         elif kind == "gdn":
-            o, carry = _gdn(h, lp, cfg, idx, mode, carry)
+            o, c = _gdn(h, lp, cfg, idx, mode, carry)
         elif kind == "ssd":
-            o, carry = _ssd(h, lp, cfg, idx, mode, carry)
+            o, c = _ssd(h, lp, cfg, idx, mode, carry)
         elif kind == "conv":
-            o, carry = _short_conv(h, lp, cfg, idx, mode, carry)
+            o, c = _short_conv(h, lp, cfg, idx, mode, carry)
         elif kind == "mamba":
-            o, carry = _mamba(h, lp, cfg, idx, mode, carry)
+            o, c = _mamba(h, lp, cfg, idx, mode, carry)
         elif kind == "gmu":
-            o = _gmu(h, lp, cfg, carry)
+            o, c = _gmu(h, lp, cfg, carry), carry
         else:
-            o, carry = _attention(h, lp, cfg, kind, layer, idx, mode, carry)
+            o, c = _attention(h, lp, cfg, kind, layer, idx, mode, carry)
         if place == "post":
             o = _norm(o, lp["ln1"], lp.get("ln1_b"), cfg)
         elif place == "both":
             o = _norm(o, lp["ln1_post"], None, cfg)
         if cfg.residual_multiplier != 1.0:
             o = o * cfg.residual_multiplier
-        x = x + o
-        if mode.plain:
+        return o, c
+
+    with jax.named_scope(kind):
+        x, carry = _residual(x, lp, cfg, "hc1", mixer,
+                             HC_OUT_NAME if mode.plain else None)
+        if mode.plain and cfg.hc_streams == 1:
             x = checkpoint_name(x, "attn_half")
     if half == "moe":
         if "route_counts" in carry:  # the layer's place among expert layers
@@ -1373,7 +1480,7 @@ def run_stack(layers, x, cfg: ModelConfig, mode, carry):
     if isinstance(layers, dict):
         layers = [(layers,)]
     seen = dict.fromkeys(_COUNTED, 0)
-    B, T = x.shape[:2]
+    B, T = x.shape[0], x.shape[-2]  # [B,T,D], or [B,n,T,D] under n streams
     mesh = _current_mesh()
     lifts = ((mode.live_rows(T) is not None and moe_step_visits(cfg, mesh))
              or (mode.kept_rows(B, T) is not None
@@ -1436,25 +1543,65 @@ def run_stack(layers, x, cfg: ModelConfig, mode, carry):
 
 
 def _run(params: Params, tokens: jax.Array, cfg: ModelConfig, mode, *carried):
-    """tokens [B,T] embedded and through every layer -> (x, carry)."""
+    """tokens [B,T] embedded and through every layer -> (x [B,T,D], the
+    residual streams summed where there are several, carry)."""
     x = mode.embed(params, tokens)
     x, carry = run_stack(params["layers"], x, cfg, mode,
                          mode.init_carry(x, *carried))
     carry.pop("mem", None)
-    return x, carry
+    return _collapse(x, cfg), carry
 
 
 def forward(params: Params, tokens: jax.Array, cfg: ModelConfig,
-            route_counts: bool = False):
+            route_counts: bool = False, mtp_tokens=None):
     """tokens [B,T] -> (logits [B,T,V] float32, 0): no cache, no state.
     What `loss_fn` differentiates: under `cfg.remat` each period of layers
-    is a checkpoint that keeps `KEPT_UNDER_REMAT`. `route_counts`: a third
+    is a checkpoint that keeps `KEPT_UNDER_REMAT`. `route_counts`: one more
     result, int32 [expert layers, router outputs]: how many of the batch's
     choices fell on each expert, layer by layer (a train step's, for the
-    router's bias and the counters)."""
-    x, carry = _run(params, tokens, cfg, Seq(cfg, route_counts=route_counts))
-    out = _lm_head(x, params, cfg), jnp.zeros((), _F32)
-    return (*out, carry["route_counts"]) if route_counts else out
+    router's bias and the counters; the prediction block's experts are the
+    last row). `mtp_tokens` [B,T] (a model with `cfg.mtp_depth`: each
+    position's NEXT token, a batch's `targets`): one more result, the
+    prediction block's logits [B,T,V] for the token after next."""
+    mode = Seq(cfg, route_counts=route_counts)
+    x, carry = _run(params, tokens, cfg, mode)
+    after = ()
+    if mtp_tokens is not None:  # its experts' choices join the carry's
+        after, carry = _mtp(params, x, mtp_tokens, cfg, mode, carry)
+        after = (after,)
+    counts = (carry["route_counts"],) if route_counts else ()
+    return (_lm_head(x, params, cfg), jnp.zeros((), _F32), *counts, *after)
+
+
+def _mtp(params: Params, h, next_tokens, cfg: ModelConfig, mode, carry):
+    """The multi-token prediction block (DeepSeek-V3 2.2, depth 1) over the
+    trunk's stream h [B,T,D] (before the final norm; the residual streams
+    already summed): g = W_p [N_h(h) ; N_e(E[next token])], copied into the
+    residual streams, through ONE more layer of the stack's last kind with
+    experts and parameters of its own, summed, its own final norm and the
+    SHARED head -> (logits [B,T,V] for the token after next, carry: the
+    block's expert choices in the last row of `route_counts`)."""
+    mp, dtype = params["mtp"], h.dtype
+    with jax.named_scope("mtp"):
+        e = _embed_lookup(params["embed"], next_tokens, dtype, mesh=mode.mesh)
+        if cfg.embedding_multiplier != 1.0:
+            e = e * cfg.embedding_multiplier
+        g = jnp.concatenate([_norm(h, mp["h_norm"], None, cfg),
+                             _norm(e, mp["e_norm"], None, cfg)], axis=-1)
+        g = jnp.einsum("btk,kd->btd", g, mp["proj"].astype(dtype))
+        kind = cfg.layer_kinds[-1]
+        # the block holds no cache rows: the trunk's kept keys stay behind
+        carry = {k: v for k, v in carry.items() if k not in ("k", "v")}
+
+        def block(c, lp):
+            x, carry = c
+            return _layer(x, lp, cfg, kind, "moe", cfg.n_layers, 0, mode,
+                          carry), None
+
+        (x, carry), _ = _remat(block, cfg, KEPT_UNDER_REMAT)(
+            (_expand(g, cfg), carry), mp["layer"])
+        return _lm_head(_collapse(x, cfg), {
+            **params, "final_norm": mp["final_norm"]}, cfg), carry
 
 
 # ---------------------------------------------------------------------------
@@ -1470,6 +1617,11 @@ _LEAF_AXES = {
     "wo": ("heads", None, "embed"), "router": ("embed", None),
     "sh_in": ("embed", "mlp"), "sh_gate": ("embed", "mlp"),
     "sh_out": ("mlp", "embed"),
+    # the latent kind: the bottlenecks' down-projections by their input, the
+    # up-projections by their heads
+    "wq_a": ("embed", None), "wkv_a": ("embed", None), "wkr": ("embed", None),
+    "wq_b": (None, "heads", None), "wk_b": (None, "heads", None),
+    "wv_b": (None, "heads", None),
 }
 _HALF_AXES = {
     "ffn": {"w_in": ("embed", "mlp"), "w_gate": ("embed", "mlp"),
@@ -1502,6 +1654,11 @@ def param_axes(cfg: ModelConfig) -> Params:
         out["lm_head"] = ("embed", "vocab")
     if cfg.norm == "layernorm":
         out["final_norm_b"] = ("norm",)
+    if cfg.mtp_depth:  # one layer, unstacked: no leading axis of repeats
+        block = layer(cfg.layer_kinds[-1], "moe")
+        out["mtp"] = {"layer": {n: a[1:] for n, a in block.items()},
+                      "h_norm": ("norm",), "e_norm": ("norm",),
+                      "final_norm": ("norm",), "proj": (None, "embed")}
     return out
 
 
@@ -1510,19 +1667,40 @@ def kept_bytes(cfg: ModelConfig, B: int, T: int, mesh=None) -> Dict[str, int]:
     rows of T tokens (`KEPT_UNDER_REMAT`): the attention half in every
     layer, a row a position; the grouped experts' up products in every
     expert layer, a row of the sorted buffer each, and their combined
-    result, a row a position (none where the experts take another form)."""
+    result, a row a position (none where the experts take another form). A
+    latent layer keeps its three bottlenecks in q's, k's and v's place, and
+    its flash output at the kernel's padded width; under several residual
+    streams a layer keeps the attention sublayer's output and both
+    sublayers' raw coefficients in the attention half's place. The
+    prediction block is one more expert layer."""
     positions = -(-B * T // split_ways(("batch", "seq"), mesh))
     act = jnp.dtype(cfg.dtype).itemsize
     grouped = moe_grouped(cfg, B, T, mesh)
     rows = grouped[1] if grouped else 0
-    widths = _kept_widths(cfg)
-    out = {name: cfg.n_layers * positions * widths[name]
-           for name in _KEPT_UNDER_REMAT}
+    widths = {**_kept_widths(cfg), **dict.fromkeys(_STACK_NAMES, 0)}
+    if cfg.latent_cache:
+        H, hd = cfg.n_heads, cfg.qk_nope_dim + cfg.qk_rope_dim
+        wide = -(-max(hd, cfg.v_head_dim) // 128) * 128
+        flash_out, flash_lse = FLASH_RESIDUAL_NAMES
+        widths.update({
+            flash_out: H * wide * act, flash_lse: 4 * H, "attn_q": 0,
+            "attn_k": 0, "attn_v": 0, "mla_cq": cfg.q_lora_rank * act,
+            "mla_c": cfg.kv_lora_rank * act, "mla_kr": cfg.qk_rope_dim * act})
+    n = cfg.hc_streams
+    if n > 1:  # float32 coefficients, both sublayers'
+        widths.update({"attn_half": 0, HC_OUT_NAME: cfg.d_model * act,
+                       HC_COEF_NAME: 2 * (n * n + 2 * n) * 4})
+    out = {name: (cfg.n_layers + cfg.mtp_depth) * positions * widths[name]
+           for name in _KEPT_UNDER_REMAT + _STACK_NAMES}
     up, gate, combined = GROUPED_RESIDUAL_NAMES
-    layers = cfg.second_halves.count("moe")
+    layers = cfg.second_halves.count("moe") + cfg.mtp_depth
     out.update({up: layers * rows * cfg.expert_ff * act,
                 gate: layers * rows * cfg.expert_ff * act,
                 combined: layers * positions * cfg.d_model * act * bool(rows)})
+    if n > 1 and rows:  # float32 weights and int32 choices, k a token
+        out.update(dict.fromkeys(
+            MOE_CHOICE_NAMES,
+            layers * positions * cfg.num_selected_experts * 4))
     return out
 
 
@@ -1551,7 +1729,13 @@ def move_router_bias(params: Params, counts, cfg: ModelConfig) -> Params:
         lp = layers[s][i]
         layers[s][i] = {**lp, "router_bias": lp["router_bias"]
                         + step[rows].astype(lp["router_bias"].dtype)}
-    return {**params, "layers": [tuple(seg) for seg in layers]}
+    params = {**params, "layers": [tuple(seg) for seg in layers]}
+    if cfg.mtp_depth:  # the prediction block's experts: the last row
+        lp = params["mtp"]["layer"]
+        params["mtp"] = {**params["mtp"], "layer": {
+            **lp, "router_bias": lp["router_bias"]
+            + step[-1].astype(lp["router_bias"].dtype)}}
+    return params
 
 
 def run_paged(params: Params, tokens: jax.Array, cfg: ModelConfig, mode,

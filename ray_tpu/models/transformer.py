@@ -544,6 +544,18 @@ def _moe_ffn_grouped(x, lp, cfg, gate, tile: int, bound: int):
     with jax.named_scope("route"):
         logits, weights, expert_ids = gate or _moe_gate(x, lp, cfg)
         aux = _moe_aux(logits, expert_ids, cfg.router_width)
+        if cfg.hc_streams > 1:
+            # a checkpoint keeps the up products IN THE BUFFER'S ORDER, so
+            # the backward's sort has to be the forward's row for row. Under
+            # one stream the router reads a norm of the kept attention half
+            # and chooses again as it chose; under several its input is
+            # MIXED again from the layer's input, a last bit of a bfloat16
+            # stream differs, a token's last choice flips and every row
+            # behind it in that expert's group meets another row's products
+            # (chip, PR 58: the experts' gradient 0.8 off). The choice itself
+            # is kept: 2 x k numbers a token
+            weights = checkpoint_name(weights, MOE_CHOICE_NAMES[0])
+            expert_ids = checkpoint_name(expert_ids, MOE_CHOICE_NAMES[1])
     with jax.named_scope("sort"):
         rows = jax.lax.stop_gradient(group_rows(
             expert_ids.reshape(N, k), weights.reshape(N, k),
@@ -810,12 +822,90 @@ def _moe_ffn_gather(x, lp, cfg, gate=None):
         return constrain(out, ("batch", "seq", "embed")), aux
 
 
+# what a layer of several residual streams keeps for its backward beside the
+# flash kernel's: the raw mixing coefficients of each sublayer ([24] a token
+# under 4 streams) and the attention sublayer's output, a row of d_model, from
+# which the backward mixes the 4-wide stream again instead of keeping it
+HC_COEF_NAME, HC_OUT_NAME = "mhc_coef", "attn_out"
+# ... and a token's choice of experts with its weights (`_moe_ffn_grouped`)
+MOE_CHOICE_NAMES = ("moe_weights", "moe_choice")
+
+
+def hc_coefficients(xs, lp, cfg, tag: str):
+    """The mixing coefficients of one sublayer's residual path (`cfg.hc_*`;
+    manifold-constrained hyper-connections) from the n streams `xs` (each
+    [B,T,D]) and the sublayer's own `<tag>_phi` [n,D,n*n+2n], `<tag>_b` and
+    `<tag>_a` (a_pre, a_post, a_res) -> (H_pre [n,B,T], H_post [n,B,T],
+    H_res [n,n,B,T]), float32. The token's n streams side by side are
+    RMS-normalised as ONE vector with no weight (it would fold into phi)
+    and projected; H_res is `cfg.hc_sinkhorn_iters` rounds of column then
+    row normalisation of exp(clipped), which the gradient flows through.
+    The tokens lie on the LAST axis of every coefficient: a [.., n, n]
+    matrix a token would fill a 16th of its tiles."""
+    n, (B, T, _) = cfg.hc_streams, xs[0].shape
+    f32 = jnp.float32
+    phi = lp[tag + "_phi"].astype(xs[0].dtype)
+    # (v / rms(v)) phi = (v phi) / rms(v): the streams are read as they lie
+    u = sum(jnp.einsum("btd,dc->btc", x, phi[j], preferred_element_type=f32)
+            for j, x in enumerate(xs))
+    ms = sum(jnp.mean(jnp.square(x.astype(f32)), axis=-1) for x in xs) / n
+    u = u * jax.lax.rsqrt(ms + cfg.hc_eps)[..., None]
+    u = checkpoint_name(u, HC_COEF_NAME)
+    u = jnp.moveaxis(u, -1, 0).reshape(-1, B * T)
+    a, b = lp[tag + "_a"].astype(f32), lp[tag + "_b"].astype(f32)[:, None]
+    pre = jax.nn.sigmoid(a[0] * u[:n] + b[:n])
+    post = 2.0 * jax.nn.sigmoid(a[1] * u[n:2 * n] + b[n:2 * n])
+    m = jnp.exp(jnp.clip(a[2] * u[2 * n:] + b[2 * n:], *cfg.hc_res_clamp))
+    m = m.reshape(n, n, B * T)  # [row i, column j, token]
+
+    def sinkhorn(m, _):  # one round: the columns, then the rows
+        m = m / (jnp.sum(m, axis=0, keepdims=True) + cfg.hc_eps)
+        return m / (jnp.sum(m, axis=1, keepdims=True) + cfg.hc_eps), None
+
+    # a loop, not 20 rounds written out: 36 residual paths a step (forward,
+    # recomputation, backward) are 1440 rounds of a few small fusions each
+    m, _ = jax.lax.scan(sinkhorn, m, None, length=cfg.hc_sinkhorn_iters)
+    return (pre.reshape(n, B, T), post.reshape(n, B, T),
+            m.reshape(n, n, B, T))
+
+
+def _residual(x, lp, cfg, tag: str, sublayer, keep: Optional[str] = None):
+    """The residual path round ONE sublayer, `sublayer`: h -> (y, what it
+    hands on). One stream (x [B,T,D]): x + y. `cfg.hc_streams` n > 1 (x
+    [B,n,T,D]): the sublayer reads sum_j H_pre[j] x[j] (scope `mhc_pre`)
+    and x+[i] = sum_j H_res[i,j] x[j] + H_post[i] y (scope `mhc_post`),
+    float32 inside, the streams stored in x's dtype; y carries the name
+    `keep` for a checkpoint to save it by. The streams are taken apart once
+    and every sum is a stream's own, so no float32 copy of all n is ever
+    whole (nor, in the backward, of their cotangent). -> (x, what the
+    sublayer handed on)."""
+    n = cfg.hc_streams
+    if n == 1:
+        y, handed = sublayer(x)
+        return x + y, handed
+    f32, dtype = jnp.float32, x.dtype
+    xs = [x[:, j] for j in range(n)]
+    with jax.named_scope("mhc_pre"):
+        pre, post, res = hc_coefficients(xs, lp, cfg, tag)
+        h = sum(pre[j][..., None] * xs[j].astype(f32) for j in range(n))
+    y, handed = sublayer(h.astype(dtype))
+    if keep:
+        y = checkpoint_name(y, keep)
+    with jax.named_scope("mhc_post"):
+        return jnp.stack([
+            (sum(res[i, j][..., None] * xs[j].astype(f32) for j in range(n))
+             + post[i][..., None] * y.astype(f32)).astype(dtype)
+            for i in range(n)], axis=1), handed
+
+
 def _ffn_half(x, lp, cfg, moe=None, experts=None, named=False):
     """A layer's second half, x + FFN(norm(x)) or the experts in its place
     (`moe`; None: what the whole model has; the shared experts beside them
     where the model has those, `cfg.d_ff_shared`), the norm AFTER the sublayer
     where the model says (`cfg.post_norm`: x + norm(FFN(x))) -> (x, aux
-    loss). `experts`: what runs the experts over the normed rows, h -> (y,
+    loss); where the model has several residual streams, their mixing in
+    the sum's place (`_residual`). `experts`: what runs the experts over the
+    normed rows, h -> (y,
     what it hands back in the aux loss's place) (None: `_moe_ffn`): the
     serve path's layers (models/stack.py), which say for each layer which
     half it has, count in their carry there. Shared by them and the
@@ -823,7 +913,8 @@ def _ffn_half(x, lp, cfg, moe=None, experts=None, named=False):
     (`_dense_ffn`)."""
     moe = cfg.is_moe if moe is None else moe
     place = cfg.norm_place
-    with jax.named_scope("moe" if moe else "ffn"):
+
+    def half(x):
         h = x if place == "post" else _norm(x, lp["ln2"], lp.get("ln2_b"), cfg)
         if moe:
             y, aux = experts(h) if experts else _moe_ffn(h, lp, cfg)
@@ -837,7 +928,10 @@ def _ffn_half(x, lp, cfg, moe=None, experts=None, named=False):
             y = _norm(y, lp["ln2_post"], None, cfg)
         if cfg.residual_multiplier != 1.0:
             y = y * cfg.residual_multiplier
-        return x + y, aux
+        return y, aux
+
+    with jax.named_scope("moe" if moe else "ffn"):
+        return _residual(x, lp, cfg, "hc2", half)
 
 
 def _block(x, lp, cfg, rope_tables, positions, mesh=None):
@@ -1072,14 +1166,17 @@ def forward(
     cfg: ModelConfig,
     positions: Optional[jax.Array] = None,
     route_counts: bool = False,
+    mtp_tokens: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """tokens [B, T] -> (logits [B, T, V] f32, aux_loss scalar).
     `route_counts` (a stack that holds experts): a third result, how many
-    choices fell on each expert in each expert layer (models/stack.py)."""
+    choices fell on each expert in each expert layer (models/stack.py).
+    `mtp_tokens` (a stack with a prediction block): each position's next
+    token; the last result is then that block's logits."""
     if cfg.is_stack:
         from . import stack
 
-        return stack.forward(params, tokens, cfg, route_counts)
+        return stack.forward(params, tokens, cfg, route_counts, mtp_tokens)
     x, rope_tables = _prologue(params, tokens, cfg, positions)
     x, aux = run_layers(params["layers"], x, cfg, rope_tables, positions)
     return _lm_head(x, params, cfg), aux
@@ -1166,18 +1263,39 @@ def loss_fn(
     forward_fn overrides the forward (e.g. a pipeline-parallel
     functools.partial(forward_pp, mesh=..., num_microbatches=...)).
     `route_counts`: -> (loss, (metrics, counts)), `forward`'s third result
-    beside the metrics (the train step's, for the router's bias)."""
+    beside the metrics (the train step's, for the router's bias). A model
+    with a multi-token prediction block (`cfg.mtp_depth`) adds
+    `cfg.mtp_weight` x that block's loss (`mtp_loss`, reported beside
+    `ce_loss`, which stays the main head's mean)."""
     fwd = forward_fn if forward_fn is not None else forward
-    if route_counts:
-        logits, aux, counts = fwd(params, batch["tokens"], cfg,
-                                  route_counts=True)
-    else:
-        logits, aux = fwd(params, batch["tokens"], cfg)
+    more = {"route_counts": True} if route_counts else {}
+    if cfg.mtp_depth:  # the block embeds each position's next token
+        more["mtp_tokens"] = batch["targets"]
+    logits, aux, *rest = fwd(params, batch["tokens"], cfg, **more)
     total, metrics = loss_from_logits(
         logits, batch["targets"], batch.get("mask"), cfg, aux,
         z_loss_coef=z_loss_coef,
     )
-    return total, ((metrics, counts) if route_counts else metrics)
+    if cfg.mtp_depth:
+        with jax.named_scope("mtp"):
+            mtp = mtp_loss(rest.pop(), batch["targets"], batch.get("mask"))
+        total = total + cfg.mtp_weight * mtp
+        metrics.update(loss=total, mtp_loss=mtp)
+    return total, ((metrics, *rest) if route_counts else metrics)
+
+
+def mtp_loss(logits: jax.Array, targets: jax.Array,
+             mask: Optional[jax.Array]) -> jax.Array:
+    """The prediction block's cross-entropy: its logits [B,T,V] at position
+    i score the token after next, `targets[i + 1]`; the last position has
+    none and is masked. The mean over the positions that have one."""
+    after_next = jnp.roll(targets, -1, axis=1)
+    has = jnp.ones_like(targets, jnp.float32).at[:, -1].set(0.0)
+    if mask is not None:  # a masked next position has no target either
+        has = has * jnp.roll(mask, -1, axis=1)
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    picked = jnp.take_along_axis(logits, after_next[..., None], axis=-1)[..., 0]
+    return jnp.sum((lse - picked) * has) / jnp.maximum(has.sum(), 1.0)
 
 
 def loss_from_logits(

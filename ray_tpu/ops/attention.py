@@ -856,7 +856,12 @@ def flash_attention(
         # keys and values of unlike widths, or a wide head that is not
         # whole tiles (latent attention's plain form: 192 and 128): zeros
         # up to whole tiles change no score, and a value's own lanes come
-        # back as they were
+        # back as they were. What it costs a TRAINING row: every product of
+        # the forward and the backward runs at 256 lanes where q k^T needs
+        # 192 and p v 128, (2 x 256) / (192 + 128) = 1.6 x the products, and
+        # q, k, v, o and the backward's float32 dq, dk, dv are 256 wide in
+        # memory (dk and dv a HEAD here: 512 MB each at 2 x 8192 x 32); a
+        # kernel that tiles 192 | 128 is the number a later change starts from
         wide = -(-max(D, Dv) // _LANES) * _LANES
         q, k, v = (jnp.pad(x, ((0, 0),) * 3 + ((0, wide - x.shape[3]),))
                    for x in (q, k, v))

@@ -3,10 +3,14 @@
 Pure XLA: elementwise, so the compiler fuses it into the surrounding
 projections; a Pallas kernel would add nothing. Implements the
 half-rotation (Llama/NeoX) convention with optional NTK/linear scaling.
+`yarn_inv_freq` / `yarn_mscale`: the inverse frequencies and the score
+factor of yarn (arXiv:2309.00071, as the DeepSeek-V3 family applies it),
+for whoever builds a table or turns by position (models/stack.py `_turn`).
 """
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -27,6 +31,32 @@ def rope_frequencies(
         pos = pos / scaling
     ang = jnp.outer(pos, inv_freq)
     return jnp.cos(ang).astype(dtype), jnp.sin(ang).astype(dtype)
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
+                  beta_fast: float = 32.0, beta_slow: float = 1.0):
+    """Inverse frequencies [dim // 2] of `dim` rotary lanes under yarn: lane
+    pair i keeps theta^(-2i/dim) where it turns more than `beta_fast` times
+    over the `original_max` positions it was trained on, takes it divided
+    by `factor` (interpolated) where it turns less than `beta_slow` times,
+    and a linear blend between the two pairs where those counts fall."""
+    extra = 1.0 / theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+
+    def pair_of(turns: float) -> float:  # the pair that turns so often
+        return (dim * math.log(original_max / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(pair_of(beta_fast)), 0)
+    hi = min(math.ceil(pair_of(beta_slow)), dim - 1)
+    ramp = (jnp.arange(dim // 2, dtype=jnp.float32) - lo) / max(hi - lo, 1e-3)
+    keep = 1.0 - jnp.clip(ramp, 0.0, 1.0)
+    return extra / factor * (1.0 - keep) + extra * keep
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """yarn's attention factor: 0.1 mscale ln(factor) + 1 (1 where nothing
+    is stretched)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
 
 
 def apply_rope(

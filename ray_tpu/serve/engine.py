@@ -1294,6 +1294,13 @@ class InferenceEngine:
             raise ValueError(
                 f"{name!r}: a stack of unlike layers has no sharding rules "
                 "yet (models/stack.py); serve it on one chip, mesh=None")
+        if self.cfg.hc_streams > 1:
+            raise ValueError(
+                f"{name!r}: a residual of {self.cfg.hc_streams} streams "
+                "(`hc_streams`) is trained, not served: the layers' function "
+                "is shared, but no decode step or chunk has been held to a "
+                "reference with the streams in its carry (models/stack.py). "
+                "Train it (train/lm.py make_train_step)")
         scfg = ecfg.speculation
         if scfg is not None and scfg.enabled and self.cfg.latent_cache:
             raise ValueError(

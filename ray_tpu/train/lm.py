@@ -181,7 +181,8 @@ def make_train_step(cfg: ModelConfig, optimizer: optax.GradientTransformation,
 
 def _after_update(cfg, params, counts, batch):
     """What a step does with its expert layers' `counts` (int32 [expert
-    layers, router outputs]) once the optimizer has moved the weights: the
+    layers, router outputs]; a multi-token prediction block's experts are
+    the last row) once the optimizer has moved the weights: the
     router's bias a step towards an even load (models/stack.py
     `move_router_bias`: the bias has no gradient, and whatever the
     optimizer made of its zeros is overwritten), and the step's numbers,

@@ -83,7 +83,8 @@ LATER_CELLS = ("longcat-flash-omni.serve-docs",  # PR 39
                "granite-4.0-h-micro.serve-chat-burst",  # PR 45
                "kanana-2-30b-a3b.serve-agent",  # PR 48
                "solar-open2-250b.serve-mixedlen",  # PR 52
-               "trinity-mini.train-packed-x4")  # PR 54: the second TRAIN cell
+               "trinity-mini.train-packed-x4",  # PR 54: the second TRAIN cell
+               "xing4.0-29b-a4b.train-packed-x2")  # PR 58: the third
 
 
 # metrics that later PRs appended for cells that were there already

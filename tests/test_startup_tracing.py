@@ -349,12 +349,13 @@ def test_the_trainers_reader_reads_the_registry_at_the_runs_end(monkeypatch):
 
 def test_the_eight_are_entries_that_move_setup_s_in_their_cells():
     # the manifest as PR 50 left it: PR 52's cell and its reader, and PR
-    # 54's cell, appended behind these (tests/test_solar_open2_model.py
-    # holds that its cell is listed by all seven serve entries,
-    # tests/test_trinity_model.py that the train cell's readers list its)
+    # 54's and PR 58's cells, appended behind these
+    # (tests/test_solar_open2_model.py holds that its cell is listed by all
+    # seven serve entries, tests/test_trinity_model.py and
+    # tests/test_xing4_model.py that the train cells' readers list theirs)
     from tests.test_benchmark_families import LATER_CELLS, manifest_without
 
-    manifest = manifest_without(LATER_CELLS[-2:])
+    manifest = manifest_without(LATER_CELLS[-3:])
     names = [m["name"] for m in manifest["per_layer"]]
     mine = [*READ, "setup_trainer_start_s"]
     assert names[-8:] == mine  # appended, in the issue's order

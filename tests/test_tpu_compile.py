@@ -970,20 +970,45 @@ def test_the_channel_decay_cells_weights_are_drawn_a_period_at_a_time(topo):
     assert memory.temp_size_in_bytes / GIB < wanted["temporaries"] + 0.25
 
 
+# the train cells whose model is a stack of unlike layers: the key of the
+# step in the configuration's `memory_analysis`, the kernels a compiled step
+# holds (a scan's body and a layer alone each hold theirs once), the names'
+# file whose shape patterns are this cell's, the other's, and the sorted
+# buffer's rows
+TRAINED_STACKS = {
+    "trinity-mini.train-packed-x4": dict(
+        step="train_step 4x8192", names="afmoe.json", other="xing4.json",
+        buffer=73728, calls={
+            "flash_fwd_window": 2, "flash_bwd_window_dq": 2, "flash_fwd": 1,
+            "flash_bwd_dq": 1, "moe_gmm_dx": 6, "moe_gmm_dw": 6,
+            "moe_combine": 4}),
+    # the dense layer, the scan's body and the prediction block: three of
+    # each flash kernel; the body's and the block's experts
+    "xing4.0-29b-a4b.train-packed-x2": dict(
+        step="train_step 2x8192", names="xing4.json", other="afmoe.json",
+        buffer=18432, calls={
+            "flash_fwd": 3, "flash_bwd_dq": 3, "moe_gmm_dx": 6,
+            "moe_gmm_dw": 6, "moe_combine": 4}),
+}
+
+
+@pytest.mark.parametrize("name", sorted(TRAINED_STACKS))
 def test_the_trained_stacks_step_fits_and_holds_no_score_matrix(
-        topo, tmp_path):
-    """`trinity-mini.train-packed-x4` as the benchmark sizes it (5 layers of
-    the published widths, 16 of 128 experts, 4 x 8192, bf16 masters, the
-    factored optimizer), the program asked for its own shapes: the chip's
-    compiler accepts the step with its window flash kernels and grouped
-    expert products, the buffer assignment's total is what the
-    configuration's file says and under 14.5 GiB, and no program holds a
-    [T, T] score matrix (the masked softmax that stood where a window
-    binds is gone)."""
+        topo, tmp_path, name):
+    """A trained stack's cell as the benchmark sizes it (`trinity-mini`: 5
+    layers of the published widths, 16 of 128 experts, 4 x 8192;
+    `xing4.0-29b-a4b`: 5 latent layers inside four residual streams and the
+    prediction block, 8 of 64 experts, 2 x 8192; bf16 masters, the factored
+    optimizer), the program asked for its own shapes: the chip's compiler
+    accepts the step with its flash kernels and grouped expert products, the
+    buffer assignment's total is what the configuration's file says and
+    under 14.5 GiB, and no program holds a [T, T] score matrix (the masked
+    softmax that stood where a window binds is gone)."""
     from benchmark import common
     from ray_tpu.train.lm import make_train_step
 
-    cell = common.load_cell("trinity-mini.train-packed-x4")
+    want = TRAINED_STACKS[name]
+    cell = common.load_cell(name)
     spec = cell["config"]
     family = common.family(spec)
     cfg = family.model_config(spec)
@@ -991,43 +1016,62 @@ def test_the_trained_stacks_step_fits_and_holds_no_score_matrix(
     opt, state, batch = _train_step_shapes(
         topo, rows, T, weights=lambda key: family.init_weights(spec, key),
         **cell["recipe"])
-    compiled = jax.jit(make_train_step(cfg, opt), donate_argnums=0).lower(
-        state, batch).compile(compiler_options={
-            "xla_dump_to": str(tmp_path), "xla_dump_hlo_as_text": True})
+    # at the chip's own matmul precision: the tests' `highest` makes the
+    # flash kernels' products float32 passes, whose scratch at heads of 256
+    # lanes (24 MB) is past the 16 MB of scoped VMEM; the chip never runs so
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(make_train_step(cfg, opt), donate_argnums=0).lower(
+            state, batch).compile(compiler_options={
+                "xla_dump_to": str(tmp_path), "xla_dump_hlo_as_text": True})
     report = max(tmp_path.glob("*memory-usage-report.txt"),
                  key=lambda f: f.stat().st_size).read_text()
     total = int(re.match(r"Total bytes used: (\d+)", report).group(1)) / GIB
-    wanted = spec["memory_analysis"][cell["name"]]["train_step 4x8192"]
+    wanted = spec["memory_analysis"][cell["name"]][want["step"]]
     print(f"buffer assignment: {total:.3f} GiB; the file says {wanted}")
     assert abs(total - wanted["total"]) < 0.3 and total < 14.5
     text = compiled.as_text()
     calls = collections.Counter(re.findall(
         r"%(flash_\w+?|moe_gmm\w*?|moe_combine)(?:\.\d+)? = ", text))
-    # a scan's body and a layer alone each hold their kernels once
-    assert calls["flash_fwd_window"] == calls["flash_bwd_window_dq"] == 2
-    assert calls["flash_fwd"] == calls["flash_bwd_dq"] == 1
-    assert not [name for name in calls if name.endswith("_dkv")]  # one pass
-    assert calls["moe_gmm_dx"] == calls["moe_gmm_dw"] == 6
+    assert {k: calls[k] for k in want["calls"]} == want["calls"]
+    assert not [k for k in calls if k.endswith("_dkv")]  # one pass
+    assert calls["flash_fwd"] + calls["flash_fwd_window"] == sum(
+        n for k, n in want["calls"].items() if k.startswith("flash_fwd"))
     # a row leaves the sorted buffer by a gather-sum through the inverse
     # table, forward (the weighted combine) and backward (the gradient of the
     # gather into the buffer): no scatter over the tokens' or the buffer's rows
-    assert calls["moe_combine"] == 4
+    # (where the sliced vocabulary has as many rows as the step has tokens,
+    # the table's own gradient is a scatter of that shape: the buffer alone)
+    over = [want["buffer"]] + [rows * T] * (rows * T != spec["vocab_size"])
     assert not [line for line in text.splitlines() if " scatter(" in line
-                and re.search(rf"\[({rows * T}|73728),{spec['hidden_size']}\]", line)]
+                and re.search(rf"\[({'|'.join(map(str, over))}),"
+                              rf"{spec['hidden_size']}\]", line)]
     assert not re.search(rf"\[[\d,]*{T},{T}\]", text)  # no [T, T] scores
     # the largest temporaries are the float32 logits and their cotangent
     assert f"f32[{rows},{T},{spec['vocab_size']}]" in text
-    # `moe_ffn_device_share.train` finds the experts' XLA operations by this
-    # cell's literal shapes (benchmark/trace_names/afmoe.json): each of its
-    # patterns still names an operation the step RUNS (not one inside a
-    # fusion, which the trace never shows). A change to `grouped_rows_bound`,
-    # `grouped_tile` or the rows a step has to re-key that group, or fail here
+    # the per-layer shares find XLA operations by this cell's literal shapes
+    # (benchmark/trace_names/<names>): each pattern still names an operation
+    # the step RUNS (not one inside a fusion, which the trace never shows). A
+    # change to `grouped_rows_bound`, `grouped_tile`, the rows a step or the
+    # streams' layout has to re-key that group, or fail here. And the OTHER
+    # trained stack's shape patterns name nothing this step runs: groups
+    # merge across the names' files, so one cell's entries must leave the
+    # other cell's shares where they were
     run, fused = [], False
     for line in text.splitlines():
         if line.endswith("{"):
             fused = "fused_computation" in line.split("(")[0]
         elif not fused and " = " in line:
-            run.append(line.strip().removeprefix("ROOT "))
-    names = common.load_json("trace_names", "afmoe.json")["groups"]
-    for entry in names["moe_ffn_train"]:
-        assert any(re.search(entry["match"], op) for op in run), entry
+            run.append(re.sub(r", metadata=\{.*", "",
+                              line.strip().removeprefix("ROOT ")))
+    for group, entries in common.load_json(
+            "trace_names", want["names"])["groups"].items():
+        if group in ("mhc_train", "moe_ffn_train"):
+            for entry in entries:
+                assert any(re.search(entry["match"], op) for op in run), entry
+    for group, entries in common.load_json(
+            "trace_names", want["other"])["groups"].items():
+        for entry in entries:
+            if group in ("mhc_train", "moe_ffn_train") \
+                    and "custom-call" not in entry["match"]:
+                hit = [op for op in run if re.search(entry["match"], op)]
+                assert not hit, (entry, hit[:2])

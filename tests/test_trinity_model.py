@@ -414,9 +414,11 @@ def test_the_cell_is_an_entry_and_its_readers_list_it():
     assert names[-7:] == own
     assert set(names[:-7]) == {m["name"] for m in
                                common.load_cell("mistral-7b.train-packed")["per_layer"]}
+    later = "xing4.0-29b-a4b.train-packed-x2"  # PR 58 joined all but the window's
     for m in manifest["per_layer"]:
         if m["name"] in own:
-            assert m["workloads"] == [CELL]
+            assert m["workloads"] == [CELL] + [later] * (
+                "flash_window" not in m["name"])
             assert m["moves"] == "train_tokens_per_s"
             assert callable(common.load_reader(m["name"]))
     assert cell["traffic"]["rows_per_step"] == 4
